@@ -2,7 +2,7 @@
 # tree): native object store + transfer plane, C++ driver API, wheel.
 PY ?= python
 
-.PHONY: all native cpp wheel test bench serve-bench spec-bench obs \
+.PHONY: all native cpp wheel test smoke bench serve-bench spec-bench obs \
 	attr chaos drain failover spec elastic ha partition autoscale \
 	autoscale-bench serve-breakdown profile lint lint-fast overload \
 	diskfault containment clean
@@ -22,8 +22,17 @@ cpp:
 wheel: native
 	$(PY) -m pip wheel --no-deps --no-build-isolation -w dist .
 
+# CPU: tests/conftest.py holds every process to JAX_PLATFORMS=cpu with
+# eight virtual devices; Pallas kernels run through the interpreter.
 test:
-	$(PY) -m pytest tests/ -q
+	$(PY) -m pytest tests/ -q -m 'not slow'
+
+# The chip: gpt2-medium trained and served through the normal entry
+# points, one process per chip.  Needs a TPU and fails without one
+# (from the sandbox: `chiprun -- python chip_smoke.py`); `--chips 4`
+# runs the sharded path on a four-chip host instead.
+smoke:
+	$(PY) chip_smoke.py
 
 # Observability suite: timeline/span propagation, runtime-metrics
 # battery, structured events, plus the PR-10 flight-recorder layer —
@@ -125,6 +134,8 @@ lint:
 lint-fast:
 	$(PY) -m ray_tpu.scripts.cli lint --changed
 
+# gpt2-medium train MFU on one chip; needs a TPU, exits non-zero
+# without one.  Kept until the benchmark replaces it.
 bench:
 	$(PY) bench.py
 

@@ -31,9 +31,7 @@ def train_loop(config):
         jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                            cfg.vocab_size),
         batch_sharding(mesh, FSDP_TP_RULES))
-    # Mesh is its own context manager (works on jax 0.4 where
-    # jax.set_mesh does not exist yet)
-    with mesh:
+    with jax.set_mesh(mesh):
         for i in range(config["steps"]):
             params, opt_state, metrics = step(params, opt_state,
                                               {"tokens": tokens})
@@ -47,14 +45,8 @@ def main():
         train_loop, train_loop_config={"steps": 3, "accum": 2},
         scaling_config=ScalingConfig(num_workers=2),
     ).fit()
-    if result.error is not None \
-            and "Multiprocess computations" in str(result.error):
-        # this jaxlib's CPU backend cannot run cross-process collectives
-        # (works on TPU and on newer jax CPU builds) — skip, don't fail
-        print("SKIP train_sharded_lm: CPU backend lacks multiprocess "
-              "collectives on this jaxlib")
-        ray_tpu.shutdown()
-        return
+    if result.error is not None:
+        raise result.error
     print("final loss:", result.metrics["loss"])
     assert result.metrics["loss"] < 10
     print("EXAMPLE_OK train_sharded_lm")

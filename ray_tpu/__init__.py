@@ -9,6 +9,11 @@ Capability mirror of Ray (see SURVEY.md for the layer map); architecture is
 TPU-first, not a port.
 """
 
+from .core import accelerator as _accelerator
+
+# before this process's first compile, and before it imports JAX
+_accelerator.place_compile_cache()
+
 from .api import (  # noqa: F401
     ActorClass,
     ActorHandle,

@@ -25,6 +25,9 @@ from .core.task_spec import DYNAMIC_RETURNS, TaskSpec
 
 _init_lock = threading.RLock()
 _local_cluster: Optional[LocalCluster] = None
+# {"JAX_PLATFORMS": what it held (None: unset)} before init() pinned this
+# driver to the CPU; shutdown() puts it back
+_env_before_init: Optional[Dict[str, Optional[str]]] = None
 
 
 def is_initialized() -> bool:
@@ -39,7 +42,7 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[int] = None,
          ignore_reinit_error: bool = False,
          system_config: Optional[Dict[str, Any]] = None) -> "ClientContext":
     """Start (or connect to) a cluster and attach this process as a driver."""
-    global _local_cluster
+    global _local_cluster, _env_before_init
     with _init_lock:
         if is_initialized():
             if ignore_reinit_error:
@@ -48,54 +51,66 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[int] = None,
                                "(pass ignore_reinit_error=True to allow)")
         if system_config:
             GlobalConfig.update(system_config)
-        if address is None:
-            res = dict(resources or {})
-            if num_cpus is not None:
-                res["CPU"] = float(num_cpus)
-            if num_tpus is not None:
-                res["TPU"] = float(num_tpus)
-            _local_cluster = LocalCluster(
-                resources=res or None,
-                object_store_memory=object_store_memory or 0)
-            controller_addr = _local_cluster.controller_addr
-            nodelet_addr = _local_cluster.nodelet_addr
-            store_path = _local_cluster.store_path
-            node_id = _local_cluster.node_id
-            session_dir = _local_cluster.session_dir
-        else:
-            if address == "auto":
-                # reference ray.init(address="auto"): resolve from the
-                # environment (ray-tpu exec/attach/start export these)
-                address = os.environ.get("RAY_TPU_ADDRESS")
-                if address is None:
+        # a driver never holds the chip: it belongs to the worker that
+        # reserved it (core/accelerator.py).  The node it starts is
+        # described by JAX_PLATFORMS as the user had it, not by the pin.
+        from .core import accelerator
+        _env_before_init = {"JAX_PLATFORMS": accelerator.pin_to_cpu()}
+        try:
+            if address is None:
+                res = dict(resources or {})
+                if num_cpus is not None:
+                    res["CPU"] = float(num_cpus)
+                if num_tpus is not None:
+                    res["TPU"] = float(num_tpus)
+                _local_cluster = LocalCluster(
+                    resources=res or None,
+                    object_store_memory=object_store_memory or 0,
+                    node_env=_env_before_init)
+                controller_addr = _local_cluster.controller_addr
+                nodelet_addr = _local_cluster.nodelet_addr
+                store_path = _local_cluster.store_path
+                node_id = _local_cluster.node_id
+                session_dir = _local_cluster.session_dir
+            else:
+                if address == "auto":
+                    # reference ray.init(address="auto"): resolve from the
+                    # environment (ray-tpu exec/attach/start export these)
+                    address = os.environ.get("RAY_TPU_ADDRESS")
+                    if address is None:
+                        raise ValueError(
+                            "address='auto' needs RAY_TPU_ADDRESS in the "
+                            "environment (ray-tpu exec/attach set it)")
+                controller_addr = address
+                if nodelet_addr is None:
+                    nodelet_addr = os.environ.get("RAY_TPU_NODELET")
+                if nodelet_addr is None:
                     raise ValueError(
-                        "address='auto' needs RAY_TPU_ADDRESS in the "
-                        "environment (ray-tpu exec/attach set it)")
-            controller_addr = address
-            if nodelet_addr is None:
-                nodelet_addr = os.environ.get("RAY_TPU_NODELET")
-            if nodelet_addr is None:
-                raise ValueError("connecting to an existing cluster requires "
-                                 "nodelet_addr of a local nodelet")
-            from .core import rpc as _rpc
-            lt = _rpc.EventLoopThread("bootstrap")
-            try:
-                host, port = nodelet_addr.rsplit(":", 1)
-                client = _rpc.BlockingClient.connect(lt, host, int(port))
-                info = client.call("node_info", timeout=10)
-                store_path = info["store_path"]
-                node_id = info["node_id"]
-                client.close()
-            finally:
-                lt.stop()
-            session_dir = os.environ.get("RAY_TPU_SESSION_DIR", "/tmp/ray_tpu")
-        core = CoreClient(controller_addr=controller_addr,
-                          nodelet_addr=nodelet_addr,
-                          store_path=store_path, node_id=node_id,
-                          session_dir=session_dir, mode="driver")
-        set_global_core(core)
-        _register_atexit_span_flush()
-        return ClientContext(core)
+                        "connecting to an existing cluster requires "
+                        "nodelet_addr of a local nodelet")
+                from .core import rpc as _rpc
+                lt = _rpc.EventLoopThread("bootstrap")
+                try:
+                    host, port = nodelet_addr.rsplit(":", 1)
+                    client = _rpc.BlockingClient.connect(lt, host, int(port))
+                    info = client.call("node_info", timeout=10)
+                    store_path = info["store_path"]
+                    node_id = info["node_id"]
+                    client.close()
+                finally:
+                    lt.stop()
+                session_dir = os.environ.get("RAY_TPU_SESSION_DIR",
+                                             "/tmp/ray_tpu")
+            core = CoreClient(controller_addr=controller_addr,
+                              nodelet_addr=nodelet_addr,
+                              store_path=store_path, node_id=node_id,
+                              session_dir=session_dir, mode="driver")
+            set_global_core(core)
+            _register_atexit_span_flush()
+            return ClientContext(core)
+        except BaseException:
+            shutdown()        # also undoes the pin
+            raise
 
 
 _atexit_flush_registered = False
@@ -131,7 +146,7 @@ def _register_atexit_span_flush() -> None:
 
 
 def shutdown():
-    global _local_cluster
+    global _local_cluster, _env_before_init
     with _init_lock:
         core = get_global_core()
         if core is not None:
@@ -146,6 +161,13 @@ def shutdown():
         if _local_cluster is not None:
             _local_cluster.shutdown()
             _local_cluster = None
+        if _env_before_init is not None:
+            for name, value in _env_before_init.items():
+                if value is None:
+                    os.environ.pop(name, None)
+                else:
+                    os.environ[name] = value
+            _env_before_init = None
 
 
 def _ensure_initialized() -> CoreClient:
@@ -690,12 +712,13 @@ def get_tpu_ids() -> List[int]:
     of the reference's `ray.get_gpu_ids`): [] outside a task or for
     tasks that requested no TPU.
 
-    Semantics differ from CUDA: TPU chips are counted resources without
-    per-chip visible-device isolation (the SPMD pattern is one worker
-    per host driving every local chip through one jax client), so the
-    indices are 0..n-1 into ``jax.local_devices()`` — NOT a disjoint
-    assignment between concurrent sub-host TPU tasks.  Schedule one TPU
-    task per host (the TPU-native layout) when exclusivity matters."""
+    Semantics differ from CUDA: chips are counted, not assigned.  A worker
+    that holds a ``TPU`` reservation is the one process of its node whose
+    JAX is on the TPU platform (core/accelerator.py), and it sees every
+    local chip, so the indices are 0..n-1 into ``jax.local_devices()``.
+    Reserve all of a host's chips in one worker (the SPMD layout): two
+    concurrent sub-host reservations would be two processes opening the
+    same chips, and the second fails at its first JAX call."""
     ctx = get_runtime_context()
     return list(range(int(ctx.get_assigned_resources().get("TPU", 0))))
 

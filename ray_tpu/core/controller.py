@@ -1056,9 +1056,23 @@ class Controller:
             self._migrating.discard(a.actor_id)
 
     async def _health_check_loop(self):
+        period = self.heartbeat_timeout_s / 3
+        woke = time.monotonic()
         while True:
-            await asyncio.sleep(self.heartbeat_timeout_s / 3)
+            await asyncio.sleep(period)
             now = time.monotonic()
+            late, woke = now - woke - period, now
+            if late > period:
+                # This process was itself frozen or its loop held up (the
+                # host stalls for seconds while a process holding the
+                # chip starts or is torn down; a long GC).  Heartbeats
+                # that arrived meanwhile are still queued behind this
+                # wake-up, so that time says nothing about the nodes:
+                # silence is counted in the time this loop was able to
+                # listen.  A node that is really dead still runs out of
+                # it, one period per round, however late every round is.
+                for rec in self.nodes.values():
+                    rec.last_heartbeat = min(now, rec.last_heartbeat + late)
             for nid, rec in list(self.nodes.items()):
                 if not rec.view.alive:
                     continue

@@ -29,14 +29,28 @@ def main():
     import signal
     faulthandler.register(signal.SIGUSR1, all_threads=True)
 
-    from .nodelet import Nodelet, detect_tpu_resources
+    import os
+
+    from . import accelerator
+    from .config import GlobalConfig
+    from .nodelet import Nodelet
 
     resources = json.loads(args.resources)
     if "CPU" not in resources:
-        import os
         resources["CPU"] = float(os.cpu_count() or 1)
-    for k, v in detect_tpu_resources().items():
-        resources.setdefault(k, v)
+    # JAX_PLATFORMS as this node was started with says whether it is a
+    # CPU node or one with chips; read it before the nodelet pins itself,
+    # and everything that inherits its environment, to the CPU.
+    reserved_platform = accelerator.reserved_platform(os.environ)
+    if GlobalConfig.tpu_chips_per_host_override:
+        resources.setdefault(
+            "TPU", float(GlobalConfig.tpu_chips_per_host_override))
+    elif GlobalConfig.tpu_autodetect:
+        for k, v in accelerator.detect_tpu_resources(
+                os.environ,
+                timeout_s=GlobalConfig.tpu_detect_timeout_s).items():
+            resources.setdefault(k, v)
+    accelerator.pin_to_cpu()
 
     async def run():
         n = Nodelet(
@@ -47,6 +61,7 @@ def main():
             port=args.port,
             object_store_memory=args.object_store_memory or None,
             labels=json.loads(args.labels),
+            reserved_platform=reserved_platform,
         )
         await n.start()
         print(f"NODELET_READY {n.address} {n.node_id.hex()} {n.store_path}",

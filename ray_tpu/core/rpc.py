@@ -502,10 +502,13 @@ class RpcServer:
 
     async def stop(self):
         if self._server:
-            self._server.close()
-            await self._server.wait_closed()
+            self._server.close()              # no new connections
         for conn in list(self.connections):
             await conn.close()
+        if self._server:
+            # Python 3.12 waits here for every accepted connection to be
+            # closed, so they are closed first
+            await self._server.wait_closed()
 
 
 def parse_endpoints(addr) -> list:

@@ -50,8 +50,7 @@ def submit_job(entrypoint: str, *,
     log_dir = os.path.join(tempfile.gettempdir(), "ray_tpu_jobs")
     os.makedirs(log_dir, exist_ok=True)
     log_path = os.path.join(log_dir, f"{job_id}.log")
-    from .core.node import _child_env
-    env = _child_env()  # strips TPU-claim vars in hermetic CPU mode
+    env = dict(os.environ)  # a job is a driver: pinned to the CPU like us
     env["RAY_TPU_ADDRESS"] = core.controller_addr
     # init(address="auto") inside the job needs the local nodelet too
     env["RAY_TPU_NODELET"] = core.nodelet_addr

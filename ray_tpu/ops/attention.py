@@ -3,7 +3,9 @@
 All shapes are ``[batch, seq, heads, head_dim]`` with KV heads a divisor of
 query heads (GQA).  `multi_head_attention` picks the implementation:
 
-  * ``"flash"``  — `ray_tpu.ops.flash_attention` (TPU Pallas kernel)
+  * ``"flash"``  — `ray_tpu.ops.flash_attention` (TPU Pallas kernel);
+    under a mesh set with `jax.set_mesh` the kernel runs per shard, batch
+    over ``dp``/``fsdp`` and heads over ``tp``
   * ``"reference"`` — pure jnp (XLA-fused; used on CPU and for odd shapes)
   * ``"ring"``   — sequence-parallel ring attention
     (`ray_tpu.ops.ring_attention`, shards over the ``sp`` mesh axis)
@@ -12,10 +14,13 @@ query heads (GQA).  `multi_head_attention` picks the implementation:
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .flash_attention import _default_blocks, fit_block, flash_attention
 
@@ -71,6 +76,32 @@ def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
         (bk >= min(128, dbk) or bk == s_kv)
 
 
+def _flash_per_shard(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                     causal: bool, sm_scale: Optional[float]) -> jnp.ndarray:
+    """The flash kernel under the ambient mesh.  The compiler cannot
+    partition a Mosaic kernel itself ("wrap the call in a shard_map"), so a
+    sharded program runs it per shard: attention is independent across
+    batch rows and heads, which is how the rules of `parallel/sharding.py`
+    lay activations out (batch over ``dp`` x ``fsdp``, heads over ``tp``).
+    An axis that does not divide its dimension stays unsharded here."""
+    kernel = functools.partial(flash_attention, causal=causal,
+                               sm_scale=sm_scale)
+    mesh = jax.sharding.get_abstract_mesh()
+    sizes = dict(mesh.shape) if mesh is not None else {}
+    if math.prod(sizes.values() or (1,)) == 1:
+        return kernel(q, k, v)
+    batch = tuple(a for a in ("dp", "fsdp") if sizes.get(a, 1) > 1)
+    if q.shape[0] % math.prod(sizes[a] for a in batch):
+        batch = ()
+    tp = sizes.get("tp", 1)
+    heads = "tp" if tp > 1 and q.shape[2] % tp == 0 \
+        and k.shape[2] % tp == 0 else None
+    spec = P(batch or None, None, heads, None)
+    # check_vma off: pallas_call declares no varying-axes rule
+    return jax.shard_map(kernel, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          causal: bool = True,
                          sm_scale: Optional[float] = None,
@@ -78,16 +109,12 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if impl == "auto":
         impl = "flash" if _flash_ok(q, k) else "reference"
     if impl == "flash":
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return _flash_per_shard(q, k, v, causal=causal, sm_scale=sm_scale)
     if impl == "reference":
         return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     if impl == "ring":
         # sequence-parallel path: shard_map over the ambient mesh's sp axis
         # (set the mesh with `jax.set_mesh` / `with mesh:` around the jit)
-        import functools
-
-        from jax.sharding import PartitionSpec as P
-
         from .ring_attention import ring_attention_shard
         mesh = jax.sharding.get_abstract_mesh()
         sp = dict(mesh.shape).get("sp", 1) if mesh is not None else 1

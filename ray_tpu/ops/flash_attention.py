@@ -24,18 +24,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific pallas extensions (memory spaces, compiler params)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
-
-# Interpreter-mode switch: RAY_TPU_PALLAS_INTERPRET=1 runs the kernels
-# through the Pallas interpreter (any backend) — the off-chip validation
-# path for kernel logic (tests use it so the kernel math is proven even
-# when no TPU is attached).
+# Test-only switch: RAY_TPU_PALLAS_INTERPRET=1 runs the kernels through the
+# Pallas interpreter on any backend, so the suite proves the kernel math on
+# the CPU.  Nothing that runs on the chip sets it (chip_smoke.py refuses to
+# start with it).
 import os as _os
+
 
 def _interpret() -> bool:
     return _os.environ.get("RAY_TPU_PALLAS_INTERPRET") == "1"
@@ -163,11 +159,6 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
         jax.ShapeDtypeStruct((b, h, s_q, 1), jnp.float32),
     )
-    compiler_params = None
-    if _HAS_PLTPU:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -188,9 +179,11 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-        ] if _HAS_PLTPU else [],
+        ],
         out_shape=out_shapes,
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
     )(q, k, v)
     return o, lse
 
@@ -290,8 +283,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)                    # [b, h, s_q, 1]
 
-    sem = (("parallel", "parallel", "parallel", "arbitrary")
-           if _HAS_PLTPU else None)
+    sem = ("parallel", "parallel", "parallel", "arbitrary")
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_k=num_k,
@@ -311,16 +303,13 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
-        if _HAS_PLTPU else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=sem)
-        if _HAS_PLTPU else None,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
-    sem5 = (("parallel", "parallel", "parallel", "arbitrary", "arbitrary")
-            if _HAS_PLTPU else None)
+    sem5 = ("parallel", "parallel", "parallel", "arbitrary", "arbitrary")
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_q=num_q,
@@ -347,12 +336,10 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
                          lambda b_, h2, ki, g_, qi: (b_, h2, ki, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)]
-        if _HAS_PLTPU else [],
+                        pltpu.VMEM((block_k, d), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=sem5)
-        if _HAS_PLTPU else None,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=sem5),
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -20,16 +20,18 @@ from .mesh import (  # noqa: F401
     local_mesh,
     mesh_shape_for,
 )
-from .sharding import (  # noqa: F401
-    ShardingRules,
-    logical_to_mesh_axes,
-    named_sharding,
-    pytree_shardings,
-    shard_pytree,
-    constrain,
-    batch_sharding,
-    DP_RULES,
-    FSDP_RULES,
-    TP_RULES,
-    FSDP_TP_RULES,
+
+_SHARDING_NAMES = (
+    "ShardingRules", "logical_to_mesh_axes", "named_sharding",
+    "pytree_shardings", "shard_pytree", "constrain", "batch_sharding",
+    "DP_RULES", "FSDP_RULES", "TP_RULES", "FSDP_TP_RULES",
 )
+
+
+def __getattr__(name):
+    # the sharding rules need jax; drivers import this package for
+    # MeshSpec alone and stay off it
+    if name in _SHARDING_NAMES:
+        from . import sharding
+        return getattr(sharding, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

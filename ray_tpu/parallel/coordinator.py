@@ -19,8 +19,6 @@ import socket
 import time
 from typing import Optional
 
-import jax
-
 from .mesh import MeshSpec, create_mesh
 from ..api import _ensure_initialized
 
@@ -73,6 +71,7 @@ def join_mesh_gang(group_name: str, world_size: int,
     else:
         addr = _wait_for_key(core, addr_key, timeout_s)
 
+    import jax
     jax.distributed.initialize(coordinator_address=addr,
                                num_processes=world_size,
                                process_id=rank)
@@ -84,6 +83,7 @@ def leave_mesh_gang(group_name: str) -> None:
     for key in _kv(core).call("kv_keys",
                               {"ns": _NS, "prefix": group_name.encode()}):
         _kv(core).call("kv_del", {"ns": _NS, "key": key})
+    import jax
     try:
         jax.distributed.shutdown()
     except Exception:

@@ -24,24 +24,19 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import numpy as np
-from jax.sharding import Mesh
+
+if TYPE_CHECKING:
+    import jax
+    from jax.sharding import Mesh
+
+# jax is imported inside the functions that need it: `MeshSpec` is part of
+# `ScalingConfig`, which a driver builds, and a driver stays off JAX (the
+# chip belongs to the worker that reserved it).
 
 MESH_AXES: Tuple[str, ...] = ("dp", "fsdp", "pp", "sp", "tp", "ep")
-
-
-def default_devices() -> List[jax.Device]:
-    """Devices meshes are built from by default.  ``RAY_TPU_DEVICE_BACKEND``
-    overrides the platform (tests pin it to the 8-device virtual CPU backend,
-    since an attached TPU plugin may ignore ``JAX_PLATFORMS``)."""
-    backend = os.environ.get("RAY_TPU_DEVICE_BACKEND")
-    if backend:
-        return list(jax.devices(backend))
-    return list(jax.devices())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,7 +110,9 @@ def create_mesh(spec: Optional[MeshSpec] = None,
     so axis order maps onto the ICI torus (inner axes = nearest neighbors);
     falls back to a plain reshape for host/CPU devices.
     """
-    devices = list(devices) if devices is not None else default_devices()
+    import jax
+    from jax.sharding import Mesh
+    devices = list(devices) if devices is not None else jax.devices()
     spec = spec or MeshSpec()
     sizes = spec.resolve(len(devices))
     shape = tuple(sizes[a] for a in MESH_AXES)
@@ -143,7 +140,8 @@ def slice_topology() -> Dict[str, object]:
     """Describe the attached TPU slice (chip count, coords) for the resource
     spec — the replacement for the reference's GPU-only accelerator detection
     (`python/ray/_private/resource_spec.py:175`)."""
-    devs = default_devices()
+    import jax
+    devs = jax.devices()
     info: Dict[str, object] = {
         "platform": devs[0].platform,
         "device_count": len(devs),

@@ -50,8 +50,7 @@ class DDPPO(Algorithm):
             raise ValueError(
                 "DDPPO has no rollout-worker actors: every mesh device is "
                 "a learner+sampler (set num_learners, not num_workers)")
-        from ..parallel.mesh import default_devices
-        devices = default_devices()
+        devices = jax.devices()
         n = cfg.num_learners or len(devices)
         if n > len(devices):
             raise ValueError(f"num_learners={n} > {len(devices)} devices")

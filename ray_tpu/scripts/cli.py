@@ -252,7 +252,8 @@ def cmd_stack(args) -> None:
                              capture_output=True)
         signalled += 1 if out.returncode == 0 else 0
     _time.sleep(1.0)
-    base = os.environ.get("RAY_TPU_TMPDIR", "/tmp/ray-tpu-sessions")
+    from ..core.node import sessions_base
+    base = sessions_base()
     sessions = sorted(glob.glob(os.path.join(base, "session_*")),
                       key=os.path.getmtime)
     if not sessions:

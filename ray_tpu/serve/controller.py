@@ -173,6 +173,10 @@ class ServeController:
         handle = api.remote(ServeReplica).options(
             max_concurrency=int(cfg.get("max_concurrent_queries", 8)),
             num_cpus=opts.get("num_cpus", 0.1),
+            # the chip reservation: the replica's worker is then the one
+            # process of its node on the TPU platform (core/accelerator.py)
+            num_tpus=opts.get("num_tpus", 0.0),
+            resources=opts.get("resources"),
             # detached: a replica must outlive the JOB that deployed
             # it (e.g. a `serve-deploy` CLI process) — Serve owns
             # replica lifecycle via scale-down/shutdown, the job GC

@@ -221,6 +221,11 @@ class WorkerGroup:
                 actor_cls.options(
                     scheduling_strategy=strategy,
                     num_cpus=bundles[i].get("CPU", 1.0),
+                    # the rest of the bundle too: a worker that does not
+                    # ASK for its bundle's TPU is leased as CPU work and
+                    # never gets the chip (core/accelerator.py)
+                    resources={k: v for k, v in bundles[i].items()
+                               if k != "CPU"},
                 ).remote(self._rank_env))
 
     def spawn_replacement(self, index: int):

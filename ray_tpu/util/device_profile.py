@@ -33,10 +33,10 @@ its existing ``serve_metrics`` push and the nodelet folds deltas into
 ``ray_tpu_device_{dispatches,device_seconds,compile_seconds,compiles}``
 counters and the ``ray_tpu_mfu_ratio`` gauge.
 
-MFU caveat: peak FLOP/s comes from ``device_profile_peak_flops`` when
-set, else a public-spec-sheet table by TPU device kind, else a nominal
-CPU figure — on the CPU test harness the ratio is an indicative
-utilization number, not a hardware truth.
+MFU needs a peak: ``device_profile_peak_flops`` when set, else the
+published figure for the chip's exact ``device_kind``.  A TPU the table
+does not name is an error, and on any other backend there is no peak and
+so no ``mfu``: a CPU run never publishes one.
 """
 
 from __future__ import annotations
@@ -45,40 +45,42 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-# bf16 peak TFLOP/s per chip by device kind (public spec sheets) —
-# kept in sync with bench.py's table; longest prefix wins so
-# "TPU v5p" is not shadowed by "TPU v5"
-_PEAK_TFLOPS = {
+# bf16 peak TFLOP/s per chip, keyed by the exact `device_kind` JAX
+# reports (Google Cloud TPU documentation, per-generation system
+# architecture pages; a v5e reports itself as "TPU v5 lite").  The one
+# table: bench.py imports it.
+PEAK_TFLOPS = {
     "TPU v4": 275.0,
-    "TPU v5": 197.0,
+    "TPU v5 lite": 197.0,
     "TPU v5e": 197.0,
     "TPU v5p": 459.0,
-    "TPU v6e": 918.0,
     "TPU v6 lite": 918.0,
+    "TPU v6e": 918.0,
 }
-#: nominal peak for non-TPU backends (CPU harness): a few hundred
-#: GFLOP/s of fused f32 — makes the MFU gauge a meaningful relative
-#: number in tests without pretending to be a spec sheet
-_FALLBACK_PEAK = 2e11
 
 
-def peak_flops() -> float:
-    """Per-device peak FLOP/s: config override, else device-kind table,
-    else the nominal fallback."""
+def peak_flops_of(device_kind: str) -> float:
+    """Published peak FLOP/s of one chip; an unknown kind is an error,
+    never a default."""
+    if device_kind not in PEAK_TFLOPS:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r} "
+            f"(known: {sorted(PEAK_TFLOPS)})")
+    return PEAK_TFLOPS[device_kind] * 1e12
+
+
+def peak_flops() -> Optional[float]:
+    """Per-device peak FLOP/s: the config override, else the table entry
+    of the attached TPU; None on a backend that has no published peak."""
     from ..core.config import GlobalConfig
     cfg = getattr(GlobalConfig, "device_profile_peak_flops", 0.0) or 0.0
     if cfg > 0:
         return float(cfg)
-    try:
-        import jax
-        kind = getattr(jax.devices()[0], "device_kind", "")
-    except Exception:
-        kind = ""
-    for key, tf in sorted(_PEAK_TFLOPS.items(),
-                          key=lambda kv: -len(kv[0])):
-        if kind.startswith(key):
-            return tf * 1e12
-    return _FALLBACK_PEAK
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    return peak_flops_of(dev.device_kind)
 
 
 def _shape_key(args: tuple, kwargs: dict) -> tuple:
@@ -133,10 +135,10 @@ class _ProgramStats:
                                      / max(1, self.sampled_n))
         return self.wall_s
 
-    def mfu(self, peak: float) -> Optional[float]:
+    def mfu(self, peak: Optional[float]) -> Optional[float]:
         dev = self.device_seconds()
         if not self.flops_per_token or not self.tokens or dev <= 0 \
-                or peak <= 0:
+                or not peak or peak <= 0:
             return None
         return (self.tokens * self.flops_per_token) / dev / peak
 

@@ -1,0 +1,123 @@
+"""The chip's own compiler, asked before any chip call: the Pallas kernels of
+the main path compiled for a described (not attached) ``v5e:2x2`` at the
+shapes the smoke and the first cells use, and `create_mesh`'s TPU branch on
+the described devices.
+
+Nothing here runs on a device, and a compile that passes is not a chip run.
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, so only the test worker
+that is handed this file loads it, and it compiles in its own process.
+"""
+
+import pytest
+
+# [batch, seq, heads, kv_heads, head_dim], bf16, default blocks 256x512:
+# gpt2-medium train (chip_smoke.py), gpt2-small long context, llama GQA at
+# two head widths, gpt2-medium at batch 8
+_SHAPES = [(4, 1024, 16, 16, 64), (2, 4096, 12, 12, 64),
+           (2, 2048, 32, 8, 64), (2, 2048, 16, 4, 128),
+           (8, 1024, 16, 16, 64)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology compile written to the persistent cache cannot
+    # be read back without a chip: keep it off around these compiles
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _kernel_inputs(shape, sharding):
+    """Arguments of the kernels' own layout, [batch, heads, seq, head_dim]."""
+    import jax
+    import jax.numpy as jnp
+    b, s, h, h_kv, d = shape
+
+    def arr(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    q, kv = arr(b, h, s, d), arr(b, h_kv, s, d)
+    row = arr(b, h, s, 1, dtype=jnp.float32)       # lse, and delta's shape
+    return q, kv, row
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("shape", _SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_flash_kernel_compiles_for_v5e(topo, shape, kernel):
+    import importlib
+
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    # `ray_tpu.ops` re-exports the function under the module's own name
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    q, kv, row = _kernel_inputs(shape, SingleDeviceSharding(topo.devices[0]))
+    bq = fa.fit_block(fa.DEFAULT_BLOCK_Q, shape[1])
+    bk = fa.fit_block(fa.DEFAULT_BLOCK_K, shape[1])
+    scale = shape[-1] ** -0.5
+    if kernel == "fwd":
+        fn = lambda q, k, v: fa._flash_fwd(q, k, v, True, scale, bq, bk)
+        args = (q, kv, kv)
+    else:
+        # the backward is two kernels; reading one result leaves the
+        # compiler the other to drop
+        pick = slice(0, 1) if kernel == "dq" else slice(1, 3)
+        fn = lambda q, k, v, o, lse, do: fa._flash_bwd(
+            q, k, v, o, lse, do, True, scale, bq, bk)[pick]
+        args = (q, kv, kv, q, row, q)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1, text[:2000]
+
+
+def test_flash_kernel_compiles_per_shard_on_the_2x2(topo):
+    """The compiler refuses to partition a Mosaic kernel; under fsdp x tp
+    `multi_head_attention` has to hand it one shard (ops/attention.py)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.attention import multi_head_attention
+    from ray_tpu.parallel import MeshSpec, create_mesh
+    mesh = create_mesh(MeshSpec(fsdp=2, tp=2), devices=topo.devices)
+    x = jax.ShapeDtypeStruct(
+        (4, 1024, 16, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("fsdp", None, "tp", None)))
+
+    def loss(q, k, v):
+        out = multi_head_attention(q, k, v, impl="flash")
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3          # fwd, dq, dkv
+
+
+def test_create_mesh_tpu_branch_on_the_described_2x2(topo):
+    """`mesh_utils.create_device_mesh` with all six named axes, four of
+    them trivial, on real (described) v5e coordinates."""
+    from ray_tpu.parallel import MESH_AXES, MeshSpec, create_mesh
+    assert topo.devices[0].platform == "tpu"
+    mesh = create_mesh(MeshSpec(fsdp=2, tp=2), devices=topo.devices)
+    assert mesh.axis_names == MESH_AXES
+    assert dict(mesh.shape) == {"dp": 1, "fsdp": 2, "pp": 1, "sp": 1,
+                                "tp": 2, "ep": 1}
+    assert sorted(d.id for d in mesh.devices.flat) == sorted(
+        d.id for d in topo.devices)
+    small = create_mesh(MeshSpec(fsdp=2, tp=2), devices=topo.devices,
+                        drop_trivial_axes=True)
+    assert small.axis_names == ("fsdp", "tp") and small.devices.shape == (2, 2)

@@ -144,7 +144,7 @@ def test_peak_flops_config_override_wins(monkeypatch):
     assert peak_flops() == 123.0
     monkeypatch.setitem(GlobalConfig._values,
                         "device_profile_peak_flops", 0.0)
-    assert peak_flops() > 0      # device table or nominal fallback
+    assert peak_flops() is None  # the CPU has no published peak: no mfu
 
 
 # ---------------------------------------------- engine integration seam

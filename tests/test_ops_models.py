@@ -148,6 +148,45 @@ def test_flash_kernel_interpret_mode_parity(monkeypatch):
                                    atol=5e-4, rtol=5e-4)
 
 
+def test_flash_kernel_runs_per_shard_under_a_mesh(monkeypatch):
+    """A Mosaic kernel cannot be partitioned by the compiler, so under a
+    mesh `multi_head_attention(impl="flash")` runs it per shard (batch over
+    fsdp, heads over tp).  Values and gradients must match the reference
+    computed without any mesh."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.attention import (multi_head_attention,
+                                       reference_attention)
+    from ray_tpu.parallel import MeshSpec, create_mesh
+
+    mesh = create_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(k1, (2, 128, 4, 32), jnp.float32)
+    k = jax.random.normal(k2, (2, 128, 2, 32), jnp.float32)  # GQA
+    v = jax.random.normal(k3, (2, 128, 2, 32), jnp.float32)
+
+    def loss(impl):
+        return lambda *a: (multi_head_attention(*a, impl=impl) ** 2).sum()
+
+    ref = reference_attention(q, k, v)
+    g_ref = jax.grad(loss("reference"), argnums=(0, 1, 2))(q, k, v)
+    sharding = NamedSharding(mesh, P("fsdp", None, "tp", None))
+    with jax.set_mesh(mesh):
+        qs, ks, vs = (jax.device_put(x, sharding) for x in (q, k, v))
+        out = jax.jit(lambda *a: multi_head_attention(*a, impl="flash"))(
+            qs, ks, vs)
+        g = jax.jit(jax.grad(loss("flash"), argnums=(0, 1, 2)))(qs, ks, vs)
+    assert out.sharding.spec == P("fsdp", None, "tp", None)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
 def test_flash_kernel_interpret_mode_bf16(monkeypatch):
     """bf16 inputs through the kernels' production dtype path: the MXU
     dots take bf16 operands with fp32 accumulation, and the bwd kernels

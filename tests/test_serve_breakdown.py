@@ -303,7 +303,10 @@ def test_serve_breakdown_end_to_end(tmp_path):
     from ray_tpu.models import TransformerConfig
     dump_dir = str(tmp_path / "incidents")
     os.environ["RAY_TPU_FLIGHT_RECORDER_DIR"] = dump_dir
-    ray_tpu.init(num_cpus=4)
+    # the CPU has no published peak, so no mfu gauge of its own: give the
+    # fold a declared one to divide by
+    ray_tpu.init(num_cpus=4,
+                 system_config={"device_profile_peak_flops": 2e11})
     try:
         serve.start()
 
@@ -405,3 +408,7 @@ def test_serve_breakdown_end_to_end(tmp_path):
     finally:
         os.environ.pop("RAY_TPU_FLIGHT_RECORDER_DIR", None)
         ray_tpu.shutdown()
+        from ray_tpu.core.config import GlobalConfig
+        GlobalConfig.update({"device_profile_peak_flops": 0.0},
+                            export_env=False)
+        os.environ.pop("RAY_TPU_DEVICE_PROFILE_PEAK_FLOPS", None)
