@@ -121,8 +121,8 @@ def _register_atexit_span_flush() -> None:
     exception) still ships its final span batch — up to one
     trace_flush_interval_s of spans otherwise evaporates with the
     process.  CoreClient.shutdown() does the same flush inline for the
-    orderly path; kv_payload() clears the dirty flag, so whichever runs
-    second is a no-op."""
+    orderly path; flush_batch() hands each span out once, so whichever
+    runs second is a no-op."""
     global _atexit_flush_registered
     if _atexit_flush_registered:
         return
@@ -133,15 +133,7 @@ def _register_atexit_span_flush() -> None:
         core = get_global_core()
         if core is None or core._closed:
             return
-        try:
-            from .util import tracing
-            payload = tracing.kv_payload()
-            if payload is not None:
-                core.controller.call("kv_put", {
-                    "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                    "value": payload, "persist": False}, timeout=2)
-        except Exception:
-            pass
+        core.final_span_flush()
     atexit.register(_flush)
 
 
@@ -765,8 +757,8 @@ def available_resources() -> Dict[str, float]:
 
 
 def timeline() -> List[dict]:
-    """Chrome-trace events, cluster-wide: driver-local profile spans +
-    every process's task-lifecycle spans (submit → schedule → dequeue →
+    """Chrome-trace events, cluster-wide: every process's
+    task-lifecycle spans (submit → schedule → dequeue →
     fetch → exec → put, merged from the controller KV) + per-node
     finished-task spans (reference: ray.timeline / chrome_tracing_dump,
     _private/state.py:414).  ``state.timeline()`` returns the same

@@ -323,7 +323,7 @@ class ClientServer:
 
     def _h_timeline(self, conn, data):
         from ..util import tracing
-        return tracing.chrome_trace_events()
+        return tracing.span_events()
 
     def _h_bye(self, conn, data):
         self._refs(conn).clear()

@@ -16,7 +16,8 @@ one place, and ``JAX_PLATFORMS`` is the only switch:
   detection, and a ``TPU`` resource given by hand is a scheduling token
   only (the test suite's mode).
 
-Nothing in this module imports JAX.
+Nothing in this module imports JAX, except `open_reserved_chip` when it is
+called in a worker on the TPU platform.
 """
 
 from __future__ import annotations
@@ -71,6 +72,29 @@ def pin_to_cpu() -> Optional[str]:
     if jax is not None:
         jax.config.update("jax_platforms", CPU)
     return before
+
+
+_chip_opened = False
+
+
+def open_reserved_chip() -> None:
+    """In a worker started on the TPU platform (it holds a ``TPU``
+    reservation), initialise the TPU backend now, as the ring span
+    ``setup:chip_open``.  Called by the runtime's own entry points just
+    before they hand over to code that will use the chip (a Serve
+    replica's constructor, the train backend's worker set-up), so the
+    opening is timed apart from the user's first program.  Nothing
+    happens in a process on any other platform, nor a second time.  A
+    backend that cannot be opened raises here what the user's first JAX
+    call would have raised."""
+    global _chip_opened
+    if _chip_opened or os.environ.get("JAX_PLATFORMS") != TPU:
+        return
+    _chip_opened = True
+    from ..util import tracing
+    with tracing.span("setup:chip_open", "setup", worker_pid=os.getpid()):
+        import jax
+        jax.devices()
 
 
 # ---------------------------------------------------------------- detection

@@ -399,8 +399,9 @@ _d("trace_enabled", bool, True,
    "Record distributed task-lifecycle spans (submit/schedule/dequeue/"
    "fetch/exec/put) for the cluster timeline.")
 _d("trace_buffer_size", int, 4096,
-   "Chrome-trace lifecycle spans buffered per process (overwrite-flushed "
-   "to the controller KV, so this also bounds the KV copy).")
+   "Chrome-trace lifecycle spans buffered per process: each span "
+   "category keeps a quarter of it (the controller's copy has the same "
+   "bound).")
 _d("trace_flush_interval_s", float, 0.25,
    "Period of each process's span flush to the controller KV.")
 _d("events_buffer_size", int, 1000,

@@ -162,6 +162,9 @@ class Controller:
         self.quarantine: Dict[str, dict] = {}
         self.pgs: Dict[bytes, PGRecord] = {}
         self.kv: Dict[str, Dict[bytes, bytes]] = {}
+        # every process's lifecycle spans (util/tracing.py), by the
+        # process's key; outside the KV, so no snapshot carries them
+        self.trace_log: Dict[str, Any] = {}
         self.object_dir: Dict[bytes, Set[str]] = {}       # oid -> node ids
         self.object_sizes: Dict[bytes, int] = {}
         self.object_waiters: Dict[bytes, List[asyncio.Event]] = {}
@@ -347,6 +350,7 @@ class Controller:
         s = self.server
         for name in ("register_node", "heartbeat", "get_cluster_view",
                      "kv_put", "kv_get", "kv_del", "kv_keys", "kv_exists",
+                     "trace_append", "trace_dump",
                      "register_actor", "wait_actor", "get_actor", "list_actors",
                      "get_named_actor", "report_actor_death", "kill_actor",
                      "create_placement_group", "wait_placement_group",
@@ -584,15 +588,14 @@ class Controller:
         return self
 
     async def _trace_flush_loop(self):
-        """The controller flushes its own lifecycle spans straight into
-        its KV — same namespace every other process flushes to over RPC."""
+        """The controller appends its own lifecycle spans straight to
+        the log every other process ships to over RPC."""
         from ..util import tracing
         while True:
             await asyncio.sleep(GlobalConfig.trace_flush_interval_s)
-            payload = tracing.kv_payload()
-            if payload is not None:
-                self.kv.setdefault(tracing.TRACE_KV_NS, {})[
-                    tracing.kv_key()] = payload
+            batch = tracing.flush_batch()
+            if batch is not None:
+                self._trace_append(batch)
 
     async def stop(self):
         await self.ha.stop()
@@ -1255,6 +1258,26 @@ class Controller:
         for actor in list(self.actors.values()):
             if actor.node_id == node_id and actor.state in (ALIVE, PENDING_CREATION):
                 await self._on_actor_failure(actor, f"node {node_id} died: {reason}")
+
+    # ----------------------------------------------------------------- spans
+    def _trace_append(self, data) -> None:
+        """One process's flush: its new spans, or with ``reset`` its
+        whole ring (it saw this controller restart or a flush fail).
+        Kept per process in the same per-category ring the process
+        keeps, never WAL-logged, and retained after the process exits."""
+        from ..util import tracing
+        ring = self.trace_log.get(data["key"])
+        if ring is None or data.get("reset"):
+            ring = self.trace_log[data["key"]] = tracing.SpanRing()
+        ring.extend(data["spans"])
+
+    async def _h_trace_append(self, conn, data):
+        self._trace_append(data)
+        return True
+
+    async def _h_trace_dump(self, conn, data):
+        return [ev for ring in self.trace_log.values()
+                for ev in ring.events()]
 
     # --------------------------------------------------------------------- kv
     async def _h_kv_put(self, conn, data):

@@ -23,6 +23,9 @@ def main():
                    help="boot as a hot standby of the leader at this "
                         "address: replicate its WAL and promote when its "
                         "lease lapses (core/ha.py)")
+    p.add_argument("--session-dir", default=None,
+                   help="where this controller leaves its span file "
+                        "(<dir>/spans/) when it is told to stop")
     p.add_argument("--lease-timeout", type=float, default=None,
                    help="override ha_lease_timeout_s for this controller")
     args = p.parse_args()
@@ -43,6 +46,9 @@ def main():
                        standby_of=args.standby_of,
                        lease_timeout_s=args.lease_timeout)
         await c.start()
+        if args.session_dir:
+            from ..util import tracing
+            tracing.write_span_file_on_sigterm(args.session_dir)
         print(f"CONTROLLER_READY {c.address}", flush=True)
         await asyncio.Event().wait()
 

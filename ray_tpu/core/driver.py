@@ -317,22 +317,33 @@ class CoreClient(DeferredRefDecs):
 
     # -------------------------------------------------------------- tracing
     async def _trace_flush_loop(self):
-        """Rewrite this process's span buffer into the controller KV when
-        dirty (overwrite semantics; see util/tracing.py)."""
+        """Ship the spans recorded since the last tick to the controller
+        (see util/tracing.py)."""
         from ..util import tracing
         if not tracing.claim_flusher():
             return
         while not self._closed:
             await asyncio.sleep(GlobalConfig.trace_flush_interval_s)
-            payload = tracing.kv_payload()
-            if payload is None:
+            batch = tracing.flush_batch()
+            if batch is None:
                 continue
-            try:
-                await self.controller.conn.notify("kv_put", {
-                    "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                    "value": payload, "persist": False})
-            except Exception:
-                tracing.mark_dirty()  # retry next tick
+            await tracing.flush_sent(lambda: self.controller.conn.call(
+                "trace_append", batch, timeout=10))
+
+    def final_span_flush(self) -> None:
+        """Whatever the flush loop hasn't shipped yet must reach the
+        controller before this process's ring evaporates — the
+        controller RETAINS exited processes' spans, so they stay in
+        state.timeline() — and the ring goes to the session's span
+        files, which outlive the cluster."""
+        from ..util import tracing
+        tracing.write_span_file(self.session_dir)
+        try:
+            batch = tracing.flush_batch()
+            if batch is not None:
+                self.controller.call("trace_append", batch, timeout=2)
+        except Exception:
+            pass
 
     def _stamp_submit(self, spec: TaskSpec) -> None:
         """Submit-time span + wall-clock stamp: downstream hops (driver
@@ -1709,10 +1720,10 @@ class CoreClient(DeferredRefDecs):
         """The controller connection failed over (leader death → promoted
         standby): connection-scoped state must be re-established — the
         ``nodes`` pubsub subscription serve routers and train executors
-        rely on lives on the dead TCP connection.  The promoted leader's
-        trace KV is also EMPTY (persist=False keys are WAL-exempt), so
-        mark the span buffer dirty: the next flush re-ships this
-        driver's full history to the new leader's timeline."""
+        rely on lives on the dead TCP connection.  The promoted leader
+        also holds NO spans (they never go through the WAL), so mark
+        the ring dirty: the next flush re-ships this driver's full
+        history to the new leader's timeline."""
         try:
             from ..util import tracing
             tracing.mark_dirty()
@@ -1771,19 +1782,7 @@ class CoreClient(DeferredRefDecs):
             self.controller.fail_fast()
         except Exception:
             pass
-        # final span flush: whatever the 0.25s flush loop hasn't shipped
-        # yet must reach the controller's trace KV before this process's
-        # buffer evaporates — the controller RETAINS exited processes'
-        # last batch, so these spans stay in state.timeline()
-        try:
-            from ..util import tracing
-            payload = tracing.kv_payload()
-            if payload is not None:
-                self.controller.call("kv_put", {
-                    "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                    "value": payload, "persist": False}, timeout=2)
-        except Exception:
-            pass
+        self.final_span_flush()
         if self.mode == "driver":
             try:
                 self.controller.call("finish_job",

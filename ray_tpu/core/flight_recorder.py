@@ -128,18 +128,14 @@ class FlightRecorder:
 
     # ------------------------------------------------------------- sources
     def _spans(self) -> List[dict]:
-        """Every process's flushed lifecycle spans from the trace KV —
-        including the retained final batch of processes that have since
-        exited — plus the controller's own not-yet-flushed buffer."""
+        """Every process's flushed lifecycle spans — including the
+        retained spans of processes that have since exited — plus the
+        controller's own not-yet-flushed ring."""
         from ..util import tracing
         events: List[dict] = []
-        for raw in self.c.kv.get(tracing.TRACE_KV_NS, {}).values():
-            try:
-                events.extend(json.loads(raw))
-            except (ValueError, TypeError):
-                continue
-        own = tracing.kv_key()
-        if own not in self.c.kv.get(tracing.TRACE_KV_NS, {}):
+        for ring in self.c.trace_log.values():
+            events.extend(ring.events())
+        if tracing.proc_key() not in self.c.trace_log:
             events.extend(tracing.span_events())
         events.sort(key=lambda e: e.get("ts", 0))
         return events

@@ -108,7 +108,7 @@ def start_controller(session_dir: str,
     log_name = "controller_standby.err" if standby_of else "controller.err"
     log = open(os.path.join(session_dir, "logs", log_name), "ab")
     cmd = [sys.executable, "-m", "ray_tpu.core.controller_main",
-           "--port", str(port)]
+           "--port", str(port), "--session-dir", session_dir]
     if heartbeat_timeout_s is not None:
         cmd += ["--heartbeat-timeout", str(heartbeat_timeout_s)]
     if persist:
@@ -197,6 +197,13 @@ class LocalCluster:
                     handle.kill()
                 except Exception:
                     pass
+        # A worker that sees its nodelet gone writes its span file and
+        # exits, within milliseconds: give it those before the sweep.
+        deadline = time.monotonic() + 0.5
+        while time.monotonic() < deadline and any(
+                pid != os.getpid()
+                for pid in session_processes(self.session_dir)):
+            time.sleep(0.01)
         # A worker orphaned while it was still starting (forked just as
         # the nodelet went down) would otherwise retry its connect for up
         # to half a minute: nothing of this session outlives shutdown.

@@ -67,6 +67,10 @@ class WorkerProc:
         self.port: Optional[int] = None
         self.registered = asyncio.Event()
         self.spawned_at = time.monotonic()
+        # wall clock of the moment the nodelet set out to start this
+        # process (`_spawn_worker` moves it back to its own entry): the
+        # start of the worker's ``setup:worker_spawn`` span
+        self.spawn_asked = time.time()
         self.state = "starting"   # starting | idle | leased | actor | dead
         self.lease_id: Optional[bytes] = None
         self.actor_id: Optional[bytes] = None
@@ -369,9 +373,9 @@ class Nodelet:
                                      "registration")
         await self.controller.call("subscribe", {"channel": "nodes"})
         await self.controller.call("subscribe", {"channel": "chaos"})
-        # a freshly restarted/promoted controller has an EMPTY trace KV
-        # (persist=False keys are WAL-exempt): re-ship this nodelet's
-        # full span buffer on the next flush tick
+        # a freshly restarted/promoted controller holds no spans (they
+        # never go through the WAL): re-ship this nodelet's full ring
+        # on the next flush tick
         from ..util import tracing as _tracing
         _tracing.mark_dirty()
         # Late joiners (and reconnects after a controller restart) pull
@@ -708,15 +712,15 @@ class Nodelet:
             return False
 
     async def _trace_flush_loop(self):
-        """Flush this nodelet's lifecycle spans to the controller KV
-        (overwrite semantics; see util/tracing.py)."""
+        """Ship the spans this nodelet recorded since the last tick to
+        the controller (see util/tracing.py)."""
         from ..util import tracing
         if not tracing.claim_flusher():
             return
         while True:
             await asyncio.sleep(GlobalConfig.trace_flush_interval_s)
             # brownout: trace flushes are optional work — hold the spans
-            # locally (overwrite semantics, nothing lost) until recovery;
+            # locally (the ring bounds them) until recovery;
             # soft: ration flushes by the heartbeat credit window
             if self._ctl_overload == "brownout":
                 continue
@@ -724,15 +728,11 @@ class Nodelet:
                 if self._ctl_credits <= 0:
                     continue
                 self._ctl_credits -= 1
-            payload = tracing.kv_payload()
-            if payload is None:
+            batch = tracing.flush_batch()
+            if batch is None:
                 continue
-            try:
-                await self.controller.notify("kv_put", {
-                    "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                    "value": payload, "persist": False})
-            except Exception:
-                tracing.mark_dirty()  # controller reconnecting: retry
+            await tracing.flush_sent(lambda: self.controller.call(
+                "trace_append", batch, timeout=10))
 
     async def _reap_loop(self):
         """Detect dead worker processes (the reference raylet gets
@@ -1173,6 +1173,7 @@ class Nodelet:
         (reference: C++ workers are their own executable too —
         cpp/src/ray/runtime/).
         """
+        asked = time.time()
         worker_id = WorkerID.from_random().binary()
         self._next_worker_seq += 1
         log_path = os.path.join(self.session_dir, "logs",
@@ -1219,6 +1220,7 @@ class Nodelet:
             logf.close()
             rtm.WORKERS_SPAWNED.inc(tags={**self._mnode, "mode": "exec"})
         w = WorkerProc(worker_id, proc, platform=platform)
+        w.spawn_asked = asked
         self.workers[worker_id] = w
         return w
 
@@ -1264,6 +1266,13 @@ class Nodelet:
         w.conn = conn
         w.state = "idle"
         w.registered.set()
+        from ..util import tracing
+        # asked for the process -> its runtime registered: fork or exec,
+        # imports, the connections back (on the TPU platform this is the
+        # chip holder's first phase of set-up)
+        tracing.record_span("setup:worker_spawn", "setup", w.spawn_asked,
+                            time.time(), worker_pid=w.proc.pid,
+                            platform=w.platform, lang=w.lang)
         conn.peer_info["worker_id"] = data["worker_id"]
         await self._notify_lease_waiters()
         return {"config": GlobalConfig.snapshot(), "node_id": self.node_id.hex()}

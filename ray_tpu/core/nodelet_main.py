@@ -64,6 +64,8 @@ def main():
             reserved_platform=reserved_platform,
         )
         await n.start()
+        from ..util import tracing
+        tracing.write_span_file_on_sigterm(args.session_dir)
         print(f"NODELET_READY {n.address} {n.node_id.hex()} {n.store_path}",
               flush=True)
         await asyncio.Event().wait()

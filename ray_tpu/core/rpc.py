@@ -144,7 +144,8 @@ _LIVENESS_OPS = frozenset({
 #: pubsub fan-in, observability pulls.  Everything else (leases, actor
 #: FSM, WAL-backed mutations, ...) defaults to the "control" lane.
 _BULK_OPS = frozenset({
-    "kv_put", "publish", "task_state", "task_state_batch",
+    "kv_put", "trace_append", "trace_dump", "publish", "task_state",
+    "task_state_batch",
     "serve_metrics", "metrics_text", "metrics_history", "task_spans",
     "tail_log", "node_stats", "stats", "chaos_injected", "report_event",
     "pub_batch"})

@@ -64,6 +64,22 @@ def current_task_spec() -> Optional[TaskSpec]:
     return _current_spec.get()
 
 
+def mark_actor_init(**args: Any) -> None:
+    """The ring span ``setup:actor_init``: this worker was handed its
+    actor -> the code the actor exists for is entered (a Serve
+    deployment's constructor, a train loop).  Called once by the entry
+    point just before it calls that code; the class's unpickling, the
+    runtime's own constructor and, where the worker holds the chip,
+    ``setup:chip_open`` lie inside it."""
+    rt = _runtime_singleton
+    if rt is None or rt._actor_handed is None:
+        return
+    from ..util import tracing
+    handed, rt._actor_handed = rt._actor_handed, None
+    tracing.record_span("setup:actor_init", "setup", handed, time.time(),
+                        worker_pid=os.getpid(), **args)
+
+
 def current_worker_runtime() -> Optional["WorkerRuntime"]:
     return _runtime_singleton
 
@@ -108,6 +124,9 @@ class WorkerRuntime:
         # than a noop task itself on the control-plane hot path
         self._ts_buf: List[Dict[str, Any]] = []
         self._ts_flush = asyncio.Event()
+        # wall clock at which the actor-creation task reached this
+        # worker, until `mark_actor_init` has made a span of it
+        self._actor_handed: Optional[float] = None
         global _runtime_singleton
         _runtime_singleton = self
 
@@ -131,9 +150,14 @@ class WorkerRuntime:
         GlobalConfig.load_snapshot(reply.get("config", {}))
         from ..util import fault_injection as fi
         fi.maybe_arm_from_config()
-        # nodelet died -> die (an exit already under way keeps its code)
-        self.nodelet.on_close = (
-            lambda conn: None if self._dying else os._exit(1))
+        # nodelet died -> die, leaving the span file behind (an exit
+        # already under way keeps its code)
+        def nodelet_gone(conn):
+            if not self._dying:
+                from ..util import tracing
+                tracing.write_span_file(self.session_dir)
+                os._exit(1)
+        self.nodelet.on_close = nodelet_gone
         asyncio.ensure_future(self._task_state_flusher())
         from ..util import tracing
         tracing.configure("worker", self.node_id)
@@ -164,8 +188,8 @@ class WorkerRuntime:
                 pass  # observability only; never kill the worker for it
 
     async def _trace_flush_loop(self):
-        """Flush this worker's lifecycle spans to the controller KV
-        (overwrite semantics; see util/tracing.py).  This worker's lazy
+        """Ship the spans this worker recorded since the last tick to
+        the controller (see util/tracing.py).  This worker's lazy
         CoreClient defers to us via claim_flusher."""
         from ..util import tracing
         if not tracing.claim_flusher():
@@ -173,38 +197,34 @@ class WorkerRuntime:
         while not self._dying:
             await asyncio.sleep(GlobalConfig.trace_flush_interval_s)
             if self.controller is not None and self.controller.closed:
-                # controller restarted or a standby was promoted: its
-                # trace KV is empty (persist=False keys never replicate
-                # through the WAL) — re-ship our FULL buffer so the new
-                # leader's timeline regains this process's history
+                # controller restarted or a standby was promoted: it
+                # holds no spans (they never go through the WAL) —
+                # re-ship our FULL ring so the new leader's timeline
+                # regains this process's history
                 tracing.mark_dirty()
-            payload = tracing.kv_payload()
-            if payload is None:
+            batch = tracing.flush_batch()
+            if batch is None:
                 continue
-            try:
-                conn = await self._controller_conn()
-                await conn.notify("kv_put", {
-                    "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                    "value": payload, "persist": False})
-            except Exception:
-                tracing.mark_dirty()
+            await tracing.flush_sent(
+                lambda: self._ship_spans(batch, timeout=10))
+
+    async def _ship_spans(self, batch: dict, timeout: float):
+        conn = await self._controller_conn()
+        return await conn.call("trace_append", batch, timeout=timeout)
 
     async def final_span_flush(self):
         """Last-gasp span flush on the way out: the flush loop ticks
         every trace_flush_interval_s, so up to one interval of spans
         (the task that was running when this worker was told to die)
-        sits only in the local buffer.  The controller RETAINS each
-        exited process's final KV batch, so flushing here is what makes
-        a killed worker's last spans appear in state.timeline()."""
+        sits only in the local ring.  The controller RETAINS each
+        exited process's spans, so flushing here is what makes a killed
+        worker's last spans appear in state.timeline() (the span file
+        that outlives the cluster is `request_exit`'s)."""
         from ..util import tracing
         try:
-            payload = tracing.kv_payload()
-            if payload is None:
-                return
-            conn = await self._controller_conn()
-            await asyncio.wait_for(conn.call("kv_put", {
-                "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                "value": payload, "persist": False}), timeout=2.0)
+            batch = tracing.flush_batch()
+            if batch is not None:
+                await self._ship_spans(batch, timeout=2.0)
         except Exception:
             pass  # exiting anyway; observability must not block death
 
@@ -775,6 +795,7 @@ class WorkerRuntime:
 
     async def _h_create_actor(self, conn, data):
         spec = TaskSpec.from_wire(data["spec"])
+        self._actor_handed = time.time()
         try:
             cls = await self._get_function(spec.function_id)
             args, kwargs, _ = await self._resolve_args(spec)
@@ -924,6 +945,11 @@ class WorkerRuntime:
         gone, and the nodelet gives the ``TPU`` reservation back only
         after it has seen the process exit (`Nodelet._on_worker_death`)."""
         self._dying = True
+        # the span file first, here and not on the loop: the hard exit
+        # below does not wait for it, and a replica's ring takes longer
+        # to write than the timer gives
+        from ..util import tracing
+        tracing.write_span_file(self.session_dir)
         # best-effort last span flush on the loop before the hard exit
         # below (the _h_exit path already awaited one; SIGTERM and crash
         # exits land here directly)

@@ -161,6 +161,10 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     )
     o, lse = pl.pallas_call(
         kernel,
+        # the HLO instruction takes this name, and a profiler trace's
+        # `XLA Ops` events are named by instruction: the kernel shows as
+        # itself there and not as `checkpoint.19` (scope_probe, PERF.md)
+        name="flash_attention_fwd",
         grid=grid,
         interpret=_interpret(),
         in_specs=[
@@ -288,6 +292,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_k=num_k,
                           q_offset=s_kv - s_q),
+        name="flash_attention_dq",
         grid=(b, h, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
@@ -314,6 +319,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_q=num_q,
                           group=group, q_offset=s_kv - s_q),
+        name="flash_attention_dkv",
         grid=(b, h_kv, num_k, group, num_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),

@@ -42,8 +42,10 @@ def join_mesh_gang(group_name: str, world_size: int,
     coordinator address; all call `jax.distributed.initialize`; the returned
     mesh covers all hosts' devices.
     """
+    from ..core import accelerator
     core = _ensure_initialized()
     if world_size <= 1:
+        accelerator.open_reserved_chip()   # timed: ``setup:chip_open``
         return create_mesh(spec)
 
     if rank is None:
@@ -75,6 +77,7 @@ def join_mesh_gang(group_name: str, world_size: int,
     jax.distributed.initialize(coordinator_address=addr,
                                num_processes=world_size,
                                process_id=rank)
+    accelerator.open_reserved_chip()
     return create_mesh(spec)
 
 

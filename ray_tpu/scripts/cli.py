@@ -298,12 +298,25 @@ def cmd_taillog(args) -> None:
 
 def cmd_timeline(args) -> None:
     """Dump the cluster-wide task timeline (lifecycle spans from every
-    process, merged via the controller KV) as Chrome-trace JSON."""
+    process, merged by the controller) as Chrome-trace JSON; with
+    ``--session-dir``, the timeline of a session that is no longer up,
+    merged from the span files its processes left under
+    ``<dir>/spans/`` when they exited."""
+    path = args.output or "timeline.json"
+    if args.session_dir:
+        from ray_tpu.util import tracing
+        dump = tracing.chrome_trace(
+            tracing.read_span_files(args.session_dir))
+        with open(path, "w") as f:
+            json.dump(dump, f)
+        spans = [e for e in dump["traceEvents"] if e.get("ph") == "X"]
+        print(f"{len(spans)} spans -> {path} "
+              f"(from {args.session_dir}/spans)")
+        return
     import ray_tpu
     from ray_tpu import state
     _connect(args)
     dump = state.timeline()
-    path = args.output or "timeline.json"
     with open(path, "w") as f:
         json.dump(dump, f)
     spans = [e for e in dump["traceEvents"] if e.get("ph") == "X"]
@@ -572,6 +585,10 @@ def render_top(nodes, history, attr, top_k: int = 10,
                 f"{dep:<18} {row.get('tokens', 0):>8} {cells} "
                 f"{('%.0f%%' % (cov * 100)) if cov is not None else '-':>5}"
                 f" {('%.3f' % peak_mfu) if peak_mfu is not None else '-':>6}")
+            eng = row.get("engine_s") or {}
+            if eng:
+                lines.append("  engine thread s: " + "  ".join(
+                    f"{k}={v:.3f}" for k, v in eng.items()))
     ctl = attr.get("controller") or {}
     ops = list(ctl.get("ops") or [])[:top_k]
     lines.append("")
@@ -868,6 +885,9 @@ def main(argv=None) -> None:
                         help="dump the cluster task timeline as a "
                              "chrome trace (Perfetto-loadable)")
     sp.add_argument("--address")
+    sp.add_argument("--session-dir",
+                    help="merge the span files of a finished (or crashed) "
+                         "session instead of asking a live cluster")
     sp.add_argument("-o", "--output")
     sp.set_defaults(fn=cmd_timeline)
 

@@ -144,6 +144,13 @@ class ContinuousBatchingEngine:
     under the engine condition variable, so no device array is ever
     raced."""
 
+    #: the engine thread's phases: `engine:<phase>` span of `_loop` (in
+    #: a profiler trace, on the device lines' clock) -> the key its
+    #: seconds are summed under in `phase_totals`
+    _THREAD_PHASES = {"schedule": "schedule", "admit": "admit_host",
+                      "dispatch": "dispatch", "readback": "readback",
+                      "publish": "publish"}
+
     def __init__(self, cfg, max_len: int, params: Any,
                  engine_cfg: DecodeEngineConfig, name: str = "",
                  replica_tag: str = "local"):
@@ -263,7 +270,12 @@ class ContinuousBatchingEngine:
         # (queue: enqueue -> first prefill chunk; admission: first
         # token -> decode slot); prefill/decode_dispatch walls come
         # from the profiler at snapshot time
-        self.phase_s = {"queue": 0.0, "admission": 0.0}
+        # cumulative seconds: per-session marks (queue, admission,
+        # first_token), the engine thread's own phases (the `engine:`
+        # spans of `_loop`), and the chunk programs of one token
+        self.phase_s = dict.fromkeys(
+            ("queue", "admission", "first_token", "prefill_tail")
+            + tuple(self._THREAD_PHASES.values()), 0.0)
 
     # ------------------------------------------------------------ client ops
 
@@ -460,20 +472,24 @@ class ContinuousBatchingEngine:
 
     def phase_totals(self) -> Dict[str, float]:
         """Cumulative serve-phase seconds — the serve_breakdown
-        attribution sources.  queue/admission come from per-session
-        marks; prefill/decode_dispatch are the profiler's per-program
+        attribution sources.  queue/admission/first_token come from
+        per-session marks (first_token: enqueued -> the first token
+        exists); prefill/decode_dispatch are the profiler's per-program
         dispatch walls (engine-thread occupancy, which is what a token
-        actually waits on)."""
+        actually waits on), prefill_tail the part of prefill spent in
+        chunk programs of ONE token; schedule/admit_host/dispatch/
+        readback/publish are the engine thread's own phases, the
+        seconds of its ``engine:`` spans."""
         wall = self._prof.wall_seconds()
         prefill = sum(wall.get(p, 0.0)
                       for p in ("prefill_chunk", "prefix_gather"))
         decode = sum(wall.get(p, 0.0)
                      for p in ("decode_step", "draft_propose", "verify",
                                "cache_insert"))
-        return {"queue": round(self.phase_s["queue"], 6),
-                "admission": round(self.phase_s["admission"], 6),
-                "prefill": round(prefill, 6),
-                "decode_dispatch": round(decode, 6)}
+        out = {k: round(v, 6) for k, v in self.phase_s.items()}
+        out["prefill"] = round(prefill, 6)
+        out["decode_dispatch"] = round(decode, 6)
+        return out
 
     def _live_locked(self) -> int:
         """Sessions a client may still come back for (not `end`ed):
@@ -650,7 +666,6 @@ class ContinuousBatchingEngine:
 
         from ..core.runtime_metrics import SERVE_PREFILL_CHUNKS
         from ..models import init_kv_cache
-        from ..util import tracing
         if sess.pcache is None:
             seeded = False
             if self._prefix is not None and sess.ptoks:
@@ -700,7 +715,7 @@ class ContinuousBatchingEngine:
         if sess.t_pf is None:          # queue phase ends at the first
             sess.t_pf = time.monotonic()  # chunk program of the prompt
             self.phase_s["queue"] += sess.t_pf - sess.t_enq
-        t0 = time.time()
+        wall0 = self._prof.wall_of("prefill_chunk")
         sess.plogits, sess.pcache = self._chunk(self.params, toks,
                                                 sess.pcache, cfg=self.cfg)
         self._shape_seen("prefill_chunk", 1, take)
@@ -709,14 +724,14 @@ class ContinuousBatchingEngine:
                                          sess.dcache,
                                          cfg=self._draft_cfg)
             self._shape_seen("draft_prefill_chunk", 1, take)
+        if take == 1:      # a prompt's tail: one program per token
+            self.phase_s["prefill_tail"] += \
+                self._prof.wall_of("prefill_chunk") - wall0
         sess.poff = off + take
         self._prof.note_tokens("prefill_chunk", take)
         with self._cond:   # stats() reads this counter
             self.prefill_chunks += 1
         SERVE_PREFILL_CHUNKS.inc(tags={"deployment": self.name})
-        tracing.record_span(f"serve_prefill_chunk::{self.name}", "serve",
-                            t0, time.time(), tokens=take,
-                            deployment=self.name)
         if sess.poff < n:
             return None
         return int(jnp.argmax(sess.plogits, axis=-1)
@@ -777,11 +792,6 @@ class ContinuousBatchingEngine:
 
         import jax.numpy as jnp
 
-        from ..core.runtime_metrics import (SERVE_DECODE_OCCUPANCY,
-                                            SERVE_SPEC_ACCEPTANCE,
-                                            SERVE_SPEC_ACCEPTED,
-                                            SERVE_SPEC_PROPOSED,
-                                            SERVE_TOKENS)
         from ..models import init_slot_cache
         from ..util import fault_injection as fi
         from ..util import tracing
@@ -795,81 +805,52 @@ class ContinuousBatchingEngine:
         tok_dev = None       # device-resident step output → next input
         active_dev = None
         active_key: Any = None
+
+        def phase(name: str):
+            """One of the engine thread's flat, non-overlapping phases:
+            a host annotation `engine:<name>` in a profiler trace and
+            seconds in `phase_s`; none is open while the thread waits
+            with nothing to do."""
+            return tracing.span("engine:" + name, "serve",
+                                into=(self.phase_s,
+                                      self._THREAD_PHASES[name]))
+
         while True:
             with self._cond:
                 while not self._shutdown:
-                    self._reap_locked()
-                    self._maybe_push_metrics()
-                    self._prefilling = [
-                        s for s in self._prefilling
-                        if not (s.ready or s.done or s.ended or s.shed)]
-                    admitted = self._admit_locked()
-                    prefills = ([] if self._draining
-                                else list(self._prefilling))
-                    batch = self._collect_locked()
+                    with phase("schedule"):
+                        self._reap_locked()
+                        self._maybe_push_metrics()
+                        self._prefilling = [
+                            s for s in self._prefilling
+                            if not (s.ready or s.done or s.ended
+                                    or s.shed)]
+                        admitted = self._admit_locked()
+                        prefills = ([] if self._draining
+                                    else list(self._prefilling))
+                        batch = self._collect_locked()
+                        active = np.zeros(self.ecfg.max_slots, bool)
+                        for s in batch:
+                            active[s.slot] = True
+                            tokens[s.slot] = s.last_tok
                     if admitted or prefills or batch:
                         break
                     self._cond.wait(0.5)
                 if self._shutdown:
                     return
-                active = np.zeros(self.ecfg.max_slots, bool)
-                for s in batch:
-                    active[s.slot] = True
-                    tokens[s.slot] = s.last_tok
             # ---- device work, OUTSIDE the lock (nobody else touches
             # the slot cache, and client ops must not stall on compute)
             t0 = time.time()
-            for sess, pcache, dcache, slot in admitted:
-                self._cache = self._insert(self._cache, pcache,
-                                           jnp.int32(slot))
-                if self._spec and dcache is not None:
-                    self._dcache = self._insert(self._dcache, dcache,
-                                                jnp.int32(slot))
-            # one chunk program per joining session per iteration: the
-            # prompt is consumed BETWEEN decode steps, never ahead of
-            # the live batch
-            ready: List[Tuple[_EngineSession, int]] = []
-            for sess in prefills:
-                try:
-                    first = self._prefill_advance(sess)
-                    if first is not None:
-                        ready.append((sess, first))
-                except Exception as e:
-                    with self._cond:
-                        sess.error = f"chunked prefill failed: {e!r}"
-                        sess.done = True
-                        sess.ready = True
-                        sess.pcache = sess.dcache = sess.plogits = None
-                        self._cond.notify_all()
-            if ready:
-                now_mono = time.monotonic()
-                now_wall = time.time()
-                with self._cond:
-                    for sess, first in ready:
-                        sess.t_ready = now_mono
-                        # per-request admission span (wall clock, like
-                        # every lifecycle span): enqueue -> first token
-                        tracing.record_span(
-                            f"serve_admission::{self.name}", "serve",
-                            now_wall - (now_mono - sess.t_enq),
-                            now_wall, rid=sess.rid, sid=sess.sid,
-                            deployment=self.name)
-                        sess.last_tok = first
-                        sess.pos = sess.poff
-                        sess.ready = True
-                        sess.prompt = sess.plogits = None
-                        if sess.pos >= self.max_len or sess.ended:
-                            sess.done = True  # nothing left to decode
-                            sess.pcache = sess.dcache = None
-                        else:
-                            self._pending.append(sess)
-                    self._cond.notify_all()
+            if admitted or prefills:
+                with phase("admit"):
+                    self._admit_and_prefill(admitted, prefills)
             if not batch:
                 continue          # admissions/prefill only: step next round
             spec_out = None
             if self._spec and not self._spec_disabled:
                 try:
-                    spec_out = self._spec_step(tokens, active, fi)
+                    with phase("dispatch"):   # and its own two reads
+                        spec_out = self._spec_step(tokens, active, fi)
                     self._spec_fail_streak = 0
                     tok_dev = None   # host owns the carry again
                 except Exception as e:
@@ -886,20 +867,23 @@ class ContinuousBatchingEngine:
                     tok_dev = None   # degrade to the plain step below
             if spec_out is None:
                 try:
-                    if admitted or tok_dev is None or \
-                            active_key != tuple(active):
-                        # membership changed: re-upload the [S]
-                        # token/mask rows; on a steady batch the step
-                        # output feeds the next step from device memory
-                        tok_dev = jnp.asarray(tokens)
-                        active_dev = jnp.asarray(active)
-                        active_key = tuple(active)
-                    tok_dev, self._cache = self._step(
-                        self.params, tok_dev, self._cache, active_dev,
-                        cfg=self.cfg)
-                    self._shape_seen("decode_step", len(tokens))
-                    new_toks = np.asarray(tok_dev)
-                    tokens[:] = new_toks
+                    with phase("dispatch"):
+                        if admitted or tok_dev is None or \
+                                active_key != tuple(active):
+                            # membership changed: re-upload the [S]
+                            # token/mask rows; on a steady batch the
+                            # step output feeds the next step from
+                            # device memory
+                            tok_dev = jnp.asarray(tokens)
+                            active_dev = jnp.asarray(active)
+                            active_key = tuple(active)
+                        tok_dev, self._cache = self._step(
+                            self.params, tok_dev, self._cache,
+                            active_dev, cfg=self.cfg)
+                        self._shape_seen("decode_step", len(tokens))
+                    with phase("readback"):
+                        new_toks = np.asarray(tok_dev)
+                        tokens[:] = new_toks
                 except Exception as e:             # pragma: no cover
                     with self._cond:
                         for s in batch:
@@ -908,70 +892,124 @@ class ContinuousBatchingEngine:
                         self._cond.notify_all()
                     tok_dev = None
                     continue
-            occupancy = len(batch)
-            # MFU numerators: useful tokens only (active slots), host-
-            # known counts — never a device sync
-            if spec_out is not None:
-                self._prof.note_tokens("draft_propose",
-                                       occupancy * self._spec_k)
-                self._prof.note_tokens("verify",
-                                       occupancy * self._spec_k)
-            else:
-                self._prof.note_tokens("decode_step", occupancy)
-            now = time.time()
-            if spec_out is not None:
-                greedy, accepted = spec_out
-                emitted = int(sum(accepted[s.slot] for s in batch))
+            with phase("publish"):
+                self._publish(batch, tokens, spec_out,
+                              None if spec_out is not None else new_toks)
+
+    def _admit_and_prefill(self, admitted, prefills) -> None:
+        """The host side of admission, on the engine thread outside the
+        lock: slot inserts of the sessions `_admit_locked` placed, ONE
+        chunk program per joining session (the prompt is consumed
+        BETWEEN decode steps, never ahead of the live batch), and the
+        hand-over of every session whose first token now exists."""
+        import jax.numpy as jnp
+
+        from ..util import tracing
+        for sess, pcache, dcache, slot in admitted:
+            self._cache = self._insert(self._cache, pcache,
+                                       jnp.int32(slot))
+            if self._spec and dcache is not None:
+                self._dcache = self._insert(self._dcache, dcache,
+                                            jnp.int32(slot))
+        ready: List[Tuple[_EngineSession, int]] = []
+        for sess in prefills:
+            try:
+                first = self._prefill_advance(sess)
+                if first is not None:
+                    ready.append((sess, first))
+            except Exception as e:
+                with self._cond:
+                    sess.error = f"chunked prefill failed: {e!r}"
+                    sess.done = True
+                    sess.ready = True
+                    sess.pcache = sess.dcache = sess.plogits = None
+                    self._cond.notify_all()
+        if not ready:
+            return
+        now_mono = time.monotonic()
+        now_wall = time.time()
+        with self._cond:
+            for sess, first in ready:
+                sess.t_ready = now_mono
+                self.phase_s["first_token"] += now_mono - sess.t_enq
+                # per-request admission span (wall clock, like every
+                # lifecycle span): enqueue -> first token
                 tracing.record_span(
-                    f"serve_spec_verify::{self.name}", "serve", t0, now,
-                    batch=occupancy, proposed=(self._spec_k - 1) * occupancy,
-                    emitted=emitted, deployment=self.name)
-            else:
-                emitted = occupancy
-                tracing.record_span(f"serve_decode_step::{self.name}",
-                                    "serve", t0, now,
-                                    batch=occupancy,
-                                    deployment=self.name)
-            SERVE_DECODE_OCCUPANCY.observe(occupancy,
-                                           {"deployment": self.name})
-            SERVE_TOKENS.inc(emitted, {"deployment": self.name})
-            with self._cond:
-                self.steps += 1
-                self.tokens += emitted
-                if spec_out is not None:
-                    greedy, accepted = spec_out
-                    for s in batch:
-                        n = int(accepted[s.slot])
-                        row = greedy[s.slot]
-                        toks = [int(row[i]) for i in range(n)]
-                        s.last_tok = toks[-1]
-                        tokens[s.slot] = s.last_tok
-                        s.pos += n
-                        if not s.ended:
-                            s.queue.extend(toks)
-                        if s.pos >= self.max_len:
-                            s.done = True
-                    self.spec_proposed += (self._spec_k - 1) * occupancy
-                    self.spec_accepted += emitted - occupancy
+                    f"serve_admission::{self.name}", "serve",
+                    now_wall - (now_mono - sess.t_enq),
+                    now_wall, rid=sess.rid, sid=sess.sid,
+                    deployment=self.name)
+                sess.last_tok = first
+                sess.pos = sess.poff
+                sess.ready = True
+                sess.prompt = sess.plogits = None
+                if sess.pos >= self.max_len or sess.ended:
+                    sess.done = True  # nothing left to decode
+                    sess.pcache = sess.dcache = None
                 else:
-                    for s in batch:
-                        tok = int(new_toks[s.slot])
-                        s.last_tok = tok
-                        s.pos += 1
-                        if not s.ended:
-                            s.queue.append(tok)
-                        if s.pos >= self.max_len:
-                            s.done = True  # cache full: reaped next turn
-                self._cond.notify_all()
+                    self._pending.append(sess)
+            self._cond.notify_all()
+
+    def _publish(self, batch, tokens, spec_out, new_toks) -> None:
+        """After a step's read-back: counters, then under the lock the
+        new tokens onto their sessions' queues and the wake-up of the
+        callers waiting for them."""
+        from ..core.runtime_metrics import (SERVE_DECODE_OCCUPANCY,
+                                            SERVE_SPEC_ACCEPTANCE,
+                                            SERVE_SPEC_ACCEPTED,
+                                            SERVE_SPEC_PROPOSED,
+                                            SERVE_TOKENS)
+        occupancy = len(batch)
+        # MFU numerators: useful tokens only (active slots), host-
+        # known counts — never a device sync
+        if spec_out is not None:
+            greedy, accepted = spec_out
+            self._prof.note_tokens("draft_propose",
+                                   occupancy * self._spec_k)
+            self._prof.note_tokens("verify", occupancy * self._spec_k)
+            emitted = int(sum(accepted[s.slot] for s in batch))
+        else:
+            self._prof.note_tokens("decode_step", occupancy)
+            emitted = occupancy
+        SERVE_DECODE_OCCUPANCY.observe(occupancy,
+                                       {"deployment": self.name})
+        SERVE_TOKENS.inc(emitted, {"deployment": self.name})
+        with self._cond:
+            self.steps += 1
+            self.tokens += emitted
             if spec_out is not None:
-                SERVE_SPEC_PROPOSED.inc((self._spec_k - 1) * occupancy,
-                                        {"deployment": self.name})
-                SERVE_SPEC_ACCEPTED.inc(emitted - occupancy,
-                                        {"deployment": self.name})
-                if self.spec_proposed:
-                    SERVE_SPEC_ACCEPTANCE.set(
-                        self.spec_accepted / self.spec_proposed,
-                        {"deployment": self.name})
+                for s in batch:
+                    n = int(accepted[s.slot])
+                    row = greedy[s.slot]
+                    toks = [int(row[i]) for i in range(n)]
+                    s.last_tok = toks[-1]
+                    tokens[s.slot] = s.last_tok
+                    s.pos += n
+                    if not s.ended:
+                        s.queue.extend(toks)
+                    if s.pos >= self.max_len:
+                        s.done = True
+                self.spec_proposed += (self._spec_k - 1) * occupancy
+                self.spec_accepted += emitted - occupancy
+            else:
+                for s in batch:
+                    tok = int(new_toks[s.slot])
+                    s.last_tok = tok
+                    s.pos += 1
+                    if not s.ended:
+                        s.queue.append(tok)
+                    if s.pos >= self.max_len:
+                        s.done = True  # cache full: reaped next turn
+            self._cond.notify_all()
+        if spec_out is not None:
+            SERVE_SPEC_PROPOSED.inc((self._spec_k - 1) * occupancy,
+                                    {"deployment": self.name})
+            SERVE_SPEC_ACCEPTED.inc(emitted - occupancy,
+                                    {"deployment": self.name})
+            if self.spec_proposed:
+                SERVE_SPEC_ACCEPTANCE.set(
+                    self.spec_accepted / self.spec_proposed,
+                    {"deployment": self.name})
 
 
 def _host_tokens(prompt) -> Optional[tuple]:

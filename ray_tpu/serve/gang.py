@@ -123,7 +123,8 @@ class GangReplicaWorker:
 
     # -- request path ------------------------------------------------------
     def handle_request(self, args: tuple, kwargs: Dict[str, Any],
-                       method: Optional[str] = None) -> Any:
+                       method: Optional[str] = None,
+                       request_id: Optional[str] = None) -> Any:
         """Leader entry point: fan out to followers, compute own shard.
 
         Followers are invoked asynchronously BEFORE the leader executes so

@@ -66,7 +66,9 @@ def call_with_retry(router, name: str, args, kwargs,
                     method: Optional[str] = None,
                     timeout_s: float = 60.0, attempts: int = 3,
                     sticky_replica_id: Optional[str] = None,
-                    prefix_tokens=None) -> Any:
+                    prefix_tokens=None,
+                    request_id: Optional[str] = None,
+                    on_assigned=None) -> Any:
     """Assign + get with replica-failure retry under ONE deadline (the
     reference router's handling of dead replicas).  A request that
     raced a replica teardown re-routes to a live replica after a table
@@ -88,7 +90,11 @@ def call_with_retry(router, name: str, args, kwargs,
     lives on one replica) never re-routes: the replica dying took the
     session with it, so the failure propagates for the caller to
     surface (the SSE lane's failover client re-admits the session on a
-    healthy replica via teacher-forced replay)."""
+    healthy replica via teacher-forced replay).
+
+    ``request_id`` rides to the replica (its spans carry it as ``rid``);
+    ``on_assigned()`` is called each time the call has been handed to a
+    replica — the end of the caller's routing leg."""
     import time as _time
 
     from ..core.config import GlobalConfig
@@ -119,6 +125,8 @@ def call_with_retry(router, name: str, args, kwargs,
             # tests predate the affinity parameter
             extra = ({"prefix_tokens": prefix_tokens}
                      if prefix_tokens is not None else {})
+            if request_id:
+                extra["request_id"] = request_id
             ref, rid = router.assign_request(
                 name, args, kwargs, method, timeout_s=budget,
                 sticky_replica_id=sticky_replica_id, **extra)
@@ -128,6 +136,8 @@ def call_with_retry(router, name: str, args, kwargs,
                     or attempt == attempts - 1 or not _shed_wait(shed):
                 raise
             continue
+        if on_assigned is not None:
+            on_assigned()
         try:
             return api.get(ref,
                            timeout=max(0.1,

@@ -108,18 +108,27 @@ class HTTPProxy:
             except (TypeError, ValueError):
                 return None
 
-        def route_call(name, payload, sticky=None):
+        def route_call(name, payload, sticky=None, rid=None, t0=None):
             from ..core.config import GlobalConfig
             from ..exceptions import TaskError
             from .handle import call_with_retry
             args = (payload,) if payload is not None else ()
+
+            def routed():
+                # request arrived -> handed to the replica: route match,
+                # body, the hop onto a pool thread, the router's pick
+                # and the submit (again after every retry)
+                tracing.record_span("proxy:route", "serve", t0,
+                                    time.time(), rid=rid, deployment=name)
             try:
                 return call_with_retry(
                     self._router, name, args, {},
                     timeout_s=GlobalConfig.serve_request_timeout_s,
                     sticky_replica_id=sticky,
                     prefix_tokens=(None if sticky
-                                   else prefix_of(payload)))
+                                   else prefix_of(payload)),
+                    request_id=rid,
+                    on_assigned=routed if rid else None)
             except TaskError as e:
                 # a replica-side typed shed (decode-engine admission
                 # backpressure, draining engine) arrives wrapped as the
@@ -129,9 +138,9 @@ class HTTPProxy:
                     raise e.cause from None
                 raise
 
-        def make_call(name, payload, sticky=None):
+        def make_call(name, payload, sticky=None, rid=None, t0=None):
             def call():
-                return route_call(name, payload, sticky)
+                return route_call(name, payload, sticky, rid, t0)
             return call
 
         async def stream_tokens(request, name, payload):
@@ -282,6 +291,7 @@ class HTTPProxy:
                 return web.json_response(self._router.route_prefixes())
             if path == "/-/healthz":
                 return web.Response(text="ok")
+            t0 = time.time()
             full_path = path
             streaming = path.endswith("/stream")
             if streaming:
@@ -345,25 +355,42 @@ class HTTPProxy:
                 except Exception as e:
                     return web.Response(status=500, text=str(e))
 
+            # per-request tracing, as the SSE lane has it: the id minted
+            # here rides to the replica beside the call (never in the
+            # payload, which is the user's), so `proxy:request`, the
+            # `proxy:route` inside it and the replica's `serve_queue::`
+            # / `serve_exec::` spans join on ``rid``
+            rid = uuid.uuid4().hex[:12]
             try:
                 result = await loop.run_in_executor(
-                    self._pool, make_call(name, payload))
+                    self._pool, make_call(name, payload, rid=rid, t0=t0))
             except ReplicaUnavailableError as e:
                 return unavailable(e)
             except Exception as e:
                 return web.Response(status=500, text=str(e))
             if isinstance(result, (bytes, bytearray)):
-                return web.Response(body=bytes(result))
-            if isinstance(result, str):
-                return web.Response(text=result)
-            if ingress and isinstance(result, dict) \
+                resp = web.Response(body=bytes(result))
+            elif isinstance(result, str):
+                resp = web.Response(text=result)
+            elif ingress and isinstance(result, dict) \
                     and isinstance(result.get("status"), int):
                 # ingress dispatchers signal HTTP status via the
                 # reserved key (404/405 must not read as 200 to load
                 # balancers and monitors)
-                return web.json_response(result,
-                                         status=result["status"])
-            return web.json_response(result)
+                resp = web.json_response(result, status=result["status"])
+            else:
+                resp = web.json_response(result)
+            # write the response here rather than after returning it, so
+            # the span ends when the bytes are with the socket (aiohttp
+            # finds it prepared and sent, and only closes the exchange)
+            try:
+                await resp.prepare(request)
+                await resp.write_eof()
+            finally:
+                tracing.record_span("proxy:request", "serve", t0,
+                                    time.time(), rid=rid, deployment=name,
+                                    status=resp.status)
+            return resp
 
         app = web.Application()
         app.router.add_route("*", "/{tail:.*}", handle)

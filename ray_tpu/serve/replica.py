@@ -50,6 +50,13 @@ class ServeReplica:
         _replica_context = ReplicaContext(deployment_name, replica_id)
         t0 = time.perf_counter()
         fc = loads_function(callable_blob)
+        # a replica on the TPU platform reserved the chip to use it: open
+        # it here, as a span of its own, not inside the user's first
+        # program; then the deployment's own constructor is entered
+        from ..core import accelerator
+        from ..core.worker_runtime import mark_actor_init
+        accelerator.open_reserved_chip()
+        mark_actor_init(deployment=deployment_name, replica=replica_id)
         if inspect.isclass(fc):
             self._callable = fc(*init_args, **init_kwargs)
             self._is_function = False
@@ -156,11 +163,19 @@ class ServeReplica:
                 f"{self.deployment_name}/{self.replica_id}")
 
     def handle_request(self, args: tuple, kwargs: Dict[str, Any],
-                       method: Optional[str] = None) -> Any:
+                       method: Optional[str] = None,
+                       request_id: Optional[str] = None) -> Any:
         from ..core.worker_runtime import current_task_spec
         from ..util import tracing
         self._chaos_site("serve.request")
         tr = self._trace_args()
+        if request_id:
+            tr["rid"] = request_id     # the proxy's id of this request
+        if args and isinstance(args[0], dict) \
+                and isinstance(args[0].get("op"), str):
+            # a protocol request (decode sessions: start / next_chunk /
+            # end ...): which operation the spans below time
+            tr["op"] = args[0]["op"]
         spec = current_task_spec()
         now = time.time()
         if spec is not None and spec.submit_time:

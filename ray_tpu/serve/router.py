@@ -160,9 +160,14 @@ class Router:
                        method: Optional[str] = None,
                        timeout_s: float = 60.0,
                        sticky_replica_id: Optional[str] = None,
-                       prefix_tokens=None):
+                       prefix_tokens=None,
+                       request_id: Optional[str] = None):
         """Pick a non-saturated replica round-robin and return the result
         ObjectRef; counts in-flight per replica.
+
+        ``request_id`` (the HTTP proxy mints one per request) rides to
+        the replica, whose ``serve_queue::``/``serve_exec::`` spans then
+        carry it as ``rid`` beside the proxy's own spans of the request.
 
         ``sticky_replica_id`` pins the request to ONE replica (decode
         sessions: a session's KV cache lives on the replica that ran
@@ -250,8 +255,9 @@ class Router:
                     self._inflight[chosen["id"]] = \
                         self._inflight.get(chosen["id"], 0) + 1
             if chosen is not None:
-                ref = chosen["handle"].handle_request.remote(
-                    args, kwargs, method)
+                call = (args, kwargs, method, request_id) if request_id \
+                    else (args, kwargs, method)
+                ref = chosen["handle"].handle_request.remote(*call)
                 return ref, chosen["id"]
             # server-derived Retry-After: while a scale-up is in
             # flight the snapshot carries the boot-time EWMA hint, so

@@ -302,6 +302,10 @@ def serve_breakdown() -> Dict[str, Any]:
       (TTFT sum + ITL sum) — the honesty metric.  Healthy is >= 0.9:
       the engine-side marks explain at least 90% of what clients
       actually waited; a gap means an uninstrumented phase;
+    * ``engine_s``: the engine's other cumulative seconds, outside the
+      coverage sum because they overlap the phases above: the engine
+      thread's own phases (schedule, admit_host, dispatch, readback,
+      publish) and the per-request sums first_token and prefill_tail;
     * ``mfu``: per-program model-FLOPs-utilization gauges.
 
     Surfaces: `ray-tpu top` breakdown panel, ``/api/serve/breakdown``,
@@ -312,7 +316,7 @@ def serve_breakdown() -> Dict[str, Any]:
     def acc(dep: str) -> Dict[str, Any]:
         return per.setdefault(dep, {
             "phases_s": dict.fromkeys(SERVE_PHASES, 0.0),
-            "tokens": 0.0, "requests": 0.0,
+            "tokens": 0.0, "requests": 0.0, "engine_s": {},
             "ttft_s": 0.0, "itl_s": 0.0, "mfu": {}})
 
     for tags, v in samples.get("ray_tpu_serve_phase_seconds_total", ()):
@@ -320,6 +324,10 @@ def serve_breakdown() -> Dict[str, Any]:
         ph = tags.get("phase", "")
         if ph in a["phases_s"]:
             a["phases_s"][ph] += v
+        else:
+            # the engine thread's own phases and the per-request sums
+            # that overlap the pipeline phases: shown, not attributed
+            a["engine_s"][ph] = a["engine_s"].get(ph, 0.0) + v
     for tags, v in samples.get("ray_tpu_serve_tokens_total", ()):
         acc(tags.get("deployment", "?"))["tokens"] += v
     for name, key in (("ray_tpu_serve_ttft_seconds_sum", "ttft_s"),
@@ -353,6 +361,8 @@ def serve_breakdown() -> Dict[str, Any]:
             "ms_per_token": {
                 k: (round(v / tokens * 1e3, 4) if tokens else None)
                 for k, v in ph.items()},
+            "engine_s": {k: round(v, 6)
+                         for k, v in sorted(a["engine_s"].items())},
             "mfu": {k: round(v, 4) for k, v in sorted(a["mfu"].items())},
         }
     return {"phases": list(SERVE_PHASES), "deployments": deployments}
@@ -476,34 +486,19 @@ def agent_stats(node_id: Optional[str] = None) -> List[Dict[str, Any]]:
 
 # ---------------------------------------------------- cluster timeline
 def _trace_span_events() -> List[Dict[str, Any]]:
-    """Every process's flushed lifecycle spans, merged from the
-    controller KV (namespace ``trace``, one key per process).  The
-    driver's own buffer is flushed synchronously first so a dump taken
-    right after a burst is complete."""
-    import json as _json
-
+    """Every process's flushed lifecycle spans, as the controller holds
+    them (one bounded ring per process, retained after the process
+    exits).  The driver's own batch is flushed synchronously first so a
+    dump taken right after a burst is complete."""
     from .util import tracing
     core = _ensure_initialized()
-    payload = tracing.kv_payload()
-    if payload is not None:
+    batch = tracing.flush_batch()
+    if batch is not None:
         try:
-            core.controller.call("kv_put", {
-                "ns": tracing.TRACE_KV_NS, "key": tracing.kv_key(),
-                "value": payload, "persist": False})
+            core.controller.call("trace_append", batch)
         except Exception:
             tracing.mark_dirty()
-    events: List[Dict[str, Any]] = []
-    for key in core.controller.call("kv_keys",
-                                    {"ns": tracing.TRACE_KV_NS,
-                                     "prefix": ""}):
-        raw = core.controller.call("kv_get", {"ns": tracing.TRACE_KV_NS,
-                                              "key": key})
-        if raw:
-            try:
-                events.extend(_json.loads(raw))
-            except ValueError:
-                continue
-    return events
+    return list(core.controller.call("trace_dump", {}))
 
 
 def _node_task_span_events() -> List[Dict[str, Any]]:
@@ -574,17 +569,11 @@ def timeline() -> Dict[str, Any]:
     clock via the heartbeat-estimated per-host offsets.  The returned
     dict serializes directly to a file loadable in
     https://ui.perfetto.dev or chrome://tracing."""
+    from .util import tracing
     events = _trace_span_events() + _node_task_span_events()
     apply_clock_offsets(events, _clock_offsets())
     events.sort(key=lambda e: e.get("ts", 0))
-    pids: List[Any] = []
-    for e in events:
-        p = e.get("pid")
-        if p not in pids:
-            pids.append(p)
-    meta = [{"ph": "M", "name": "process_name", "pid": p, "tid": 0,
-             "args": {"name": str(p)}} for p in pids]
-    return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+    return tracing.chrome_trace(events)
 
 
 def list_tasks() -> List[Dict[str, Any]]:

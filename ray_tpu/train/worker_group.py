@@ -89,7 +89,11 @@ class TrainWorker:
 
         def run():
             import inspect
+
+            from ..core.worker_runtime import mark_actor_init
             _set_session(session)
+            mark_actor_init(trial=session.trial_name,
+                            rank=str(session.world_rank))
             try:
                 if inspect.signature(train_fn).parameters:
                     train_fn(config)

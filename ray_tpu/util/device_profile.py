@@ -229,6 +229,12 @@ class DispatchProfiler:
         with self._lock:
             return {p: s.wall_s for p, s in self._stats.items()}
 
+    def wall_of(self, program: str) -> float:
+        """One program's cumulative dispatch wall seconds, lock-free:
+        for the thread that dispatches it (the engine reads it around a
+        dispatch to split that program's wall by kind of call)."""
+        return self._stat(program).wall_s
+
     def distinct_shapes(self) -> int:
         with self._lock:
             return sum(len(s.shapes) for s in self._stats.values())

@@ -1,80 +1,93 @@
-"""Cluster-wide task-lifecycle tracing (reference: core_worker/profiling.cc
-profile events -> GCS, surfaced by `ray timeline` /
-python/ray/_private/state.py:414 chrome_tracing_dump).
+"""Cluster-wide tracing: one span primitive, two sinks (reference:
+core_worker/profiling.cc profile events -> GCS, surfaced by `ray timeline`
+/ python/ray/_private/state.py:414 chrome_tracing_dump).
 
-Two layers live here:
+``span`` / ``record_span`` are the one way any runtime process (driver,
+controller, nodelet, worker) marks an interval:
 
-* ``profile`` — the legacy in-process Chrome-trace context manager
-  (perf_counter clock, local buffer only).  Useful for driver-side
-  micro-profiling; it never crosses a process boundary.
+* **The ring** — a bounded per-process buffer of Chrome-trace events on
+  the wall clock, so spans of different processes line up.  It keeps a
+  bound PER CATEGORY: a category that floods (one span per served
+  request) evicts only its own oldest spans, never the three ``setup``
+  spans of the process.  A per-process flush loop ships what was
+  recorded since the last flush (``flush_batch`` -> the controller's
+  ``trace_append``; cost in proportion to the new spans, not to the ring),
+  ``state.timeline()`` merges every process's spans into one Chrome-trace
+  JSON, and at exit each process writes its ring to
+  ``<session_dir>/spans/<kind>-<pid>.json`` so a finished session keeps
+  its timeline (``ray-tpu timeline --session-dir``).
 
-* **Distributed lifecycle spans** — every runtime process (driver,
-  controller, nodelet, worker) appends spans for the hops of a task's
-  life (submit → schedule → dequeue → fetch → exec → put, plus serve /
-  train workload spans) into a bounded per-process buffer, stamped with
-  the wall clock so cross-process merge lines up.  A per-process flush
-  loop rewrites the buffer into the controller KV (namespace
-  ``trace``, one key per process, ``persist=False`` so the WAL never
-  sees it); ``state.timeline()`` merges every process's batch into one
-  Chrome-trace JSON.  Overwrite semantics keep the controller's copy
-  bounded: the KV holds "the recent spans of each process", nothing
-  grows without bound.
+* **The profiler's host plane** — where JAX is already imported in the
+  process, ``span`` also enters ``jax.profiler.TraceAnnotation(name)``:
+  the span then lands in a `jax.profiler` trace on the device lines'
+  clock.  Names meant for that plane are ``<layer>:<phase>``, lower case.
+  This module never imports JAX itself.
+
+A span that repeats every iteration of a hot loop passes ``into=(dict,
+key)``: its seconds are added to that accumulator and it goes to the
+annotation only, never to the ring.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from ..core.config import GlobalConfig
 
-TRACE_KV_NS = "trace"
+#: a ring holds at most this many categories; later ones share "other"
+MAX_CATEGORIES = 16
+#: a flush the controller did not take is followed by a re-ship of the
+#: whole ring; flush loops wait this long before it
+RESHIP_PAUSE_S = 1.0
 
-_events: List[dict] = []
-_lock = threading.Lock()
-
-
-class profile:
-    """Context manager recording one LOCAL Chrome-trace duration event.
-
-    Both endpoints read ``time.perf_counter() * 1e6`` — one clock, one
-    unit (µs).  (An earlier revision probed for a nonexistent
-    ``time.perf_counter_us`` on enter, which would have mixed units with
-    the exit path had it ever resolved.)
-    """
-
-    def __init__(self, name: str, category: str = "task"):
-        self.name = name
-        self.category = category
-
-    def __enter__(self):
-        self.start = time.perf_counter() * 1e6
-        return self
-
-    def __exit__(self, *exc):
-        end = time.perf_counter() * 1e6
-        with _lock:
-            _events.append({
-                "name": self.name, "cat": self.category, "ph": "X",
-                "ts": self.start, "dur": max(0.0, end - self.start),
-                "pid": os.getpid(), "tid": threading.get_ident() % 10000,
-            })
+_PLAIN = (str, int, float, bool)
 
 
-def chrome_trace_events() -> List[dict]:
-    with _lock:
-        return list(_events)
+class SpanRing:
+    """Chrome-trace events by category, each category its own bounded
+    deque.  The process's ring and the controller's copy of it are the
+    same structure, so both keep the same spans."""
 
+    def __init__(self, per_category: Optional[int] = None):
+        self.per_category = per_category or max(
+            16, GlobalConfig.trace_buffer_size // 4)
+        self._cats: Dict[str, deque] = {}
 
-# --------------------------------------------------- distributed spans
+    def add(self, ev: dict) -> None:
+        cat = ev.get("cat") or "task"
+        ring = self._cats.get(cat)
+        if ring is None:
+            if len(self._cats) >= MAX_CATEGORIES:
+                cat = "other"
+            ring = self._cats.setdefault(
+                cat, deque(maxlen=self.per_category))
+        ring.append(ev)
+
+    def extend(self, events: Iterable[dict]) -> None:
+        for ev in events:
+            self.add(ev)
+
+    def events(self) -> List[dict]:
+        out = [ev for ring in self._cats.values() for ev in ring]
+        out.sort(key=lambda e: e.get("ts", 0))
+        return out
+
+    def __len__(self) -> int:
+        return sum(len(ring) for ring in self._cats.values())
+
 
 _span_lock = threading.Lock()
-_spans: Optional[deque] = None
-_dirty = False
+_ring: Optional[SpanRing] = None
+_pending: List[dict] = []     # recorded since the last flush
+_reship = False               # the controller lost our history
+_recorded = 0                 # spans ever recorded here
+_filed = -1                   # `_recorded` when the span file was written
 _proc = {"kind": "proc", "node": ""}
 _flusher_claimed = False
 
@@ -87,9 +100,9 @@ def configure(kind: str, node_id: str = "") -> None:
 
 
 def claim_flusher() -> bool:
-    """First caller owns the KV flush loop for this process (a worker
+    """First caller owns the flush loop for this process (a worker
     process hosts both a WorkerRuntime and a lazy CoreClient; only one
-    may flush or they'd race on the dirty flag)."""
+    may flush or they'd race on the pending batch)."""
     global _flusher_claimed
     with _span_lock:
         if _flusher_claimed:
@@ -109,11 +122,11 @@ def release_flusher() -> None:
         _flusher_claimed = False
 
 
-def _buffer() -> deque:
-    global _spans
-    if _spans is None:
-        _spans = deque(maxlen=max(16, GlobalConfig.trace_buffer_size))
-    return _spans
+def _buffer() -> SpanRing:
+    global _ring
+    if _ring is None:
+        _ring = SpanRing()
+    return _ring
 
 
 def proc_label() -> str:
@@ -121,84 +134,216 @@ def proc_label() -> str:
     return f"{_proc['kind']}@{node}" if node else _proc["kind"]
 
 
-def kv_key() -> str:
+def proc_key() -> str:
     return f"{_proc['kind']}:{_proc['node']}:{os.getpid()}"
 
 
 def record_span(name: str, cat: str, start_s: float, end_s: float,
                 **args: Any) -> None:
-    """Record one lifecycle span (wall-clock seconds in, Chrome µs out)."""
+    """Record one span in the ring (wall-clock seconds in, Chrome µs
+    out).  Argument values other than plain scalars are kept as text."""
     if not GlobalConfig.trace_enabled:
         return
     ev = {
         "name": name, "cat": cat, "ph": "X",
         "ts": start_s * 1e6, "dur": max(0.0, end_s - start_s) * 1e6,
         "pid": proc_label(), "tid": str(os.getpid()),
-        "args": {k: v for k, v in args.items() if v},
+        "args": {k: (v if type(v) in _PLAIN else str(v))
+                 for k, v in args.items() if v},
     }
-    global _dirty
+    global _recorded, _reship
     with _span_lock:
-        _buffer().append(ev)
-        _dirty = True
+        ring = _buffer()
+        ring.add(ev)
+        _recorded += 1
+        _pending.append(ev)
+        if len(_pending) > ring.per_category * MAX_CATEGORIES:
+            # nobody flushes here (or the controller is away): the ring
+            # is the bound, the next flush ships it whole
+            _pending.clear()
+            _reship = True
+
+
+def _annotation(name: str):
+    """`jax.profiler.TraceAnnotation(name)` where JAX is already in the
+    process, else None.  Costs a fraction of a microsecond while no
+    profiler trace is being taken."""
+    prof = sys.modules.get("jax.profiler")
+    return prof.TraceAnnotation(name) if prof is not None else None
 
 
 class span:
-    """Context manager form of :func:`record_span` (wall clock)."""
+    """One interval, as a context manager: a `jax.profiler` host
+    annotation where JAX is loaded, and either a ring span (wall clock;
+    the default) or, with ``into=(accumulator, key)``, seconds added to
+    ``accumulator[key]`` and nothing in the ring."""
 
-    def __init__(self, name: str, cat: str = "task", **args: Any):
+    __slots__ = ("name", "cat", "args", "into", "start", "_ann")
+
+    def __init__(self, name: str, cat: str = "task",
+                 into: Optional[Tuple[Dict[str, float], str]] = None,
+                 **args: Any):
         self.name = name
         self.cat = cat
         self.args = args
+        self.into = into
 
     def __enter__(self):
-        self.start = time.time()
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start = time.perf_counter() if self.into is not None \
+            else time.time()
         return self
 
     def __exit__(self, *exc):
-        record_span(self.name, self.cat, self.start, time.time(),
-                    **self.args)
+        if self.into is not None:
+            acc, key = self.into
+            acc[key] = acc.get(key, 0.0) + time.perf_counter() - self.start
+        else:
+            record_span(self.name, self.cat, self.start, time.time(),
+                        **self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 def span_events() -> List[dict]:
-    """Snapshot of this process's span buffer."""
+    """Snapshot of this process's ring, oldest first."""
     with _span_lock:
-        return list(_buffer())
+        return _buffer().events()
 
 
-def kv_payload() -> Optional[bytes]:
-    """The buffer as JSON bytes if anything changed since the last
-    flush, else None.  Clears the dirty flag — callers whose flush RPC
-    fails should :func:`mark_dirty` so the next tick retries."""
-    global _dirty
+def flush_batch() -> Optional[dict]:
+    """The request of the next ``trace_append`` call to the controller:
+    the spans recorded since the last flush, or the whole ring with
+    ``reset`` after :func:`mark_dirty`; None when there is nothing to
+    ship.  A caller whose RPC fails calls :func:`mark_dirty`."""
+    global _pending, _reship
     with _span_lock:
-        if not _dirty:
+        if _reship:
+            spans, reset = _buffer().events(), True
+        elif _pending:
+            spans, reset = _pending, False
+        else:
             return None
-        _dirty = False
-        return json.dumps(list(_buffer())).encode()
+        _pending, _reship = [], False
+    return {"key": proc_key(), "spans": spans, "reset": reset}
+
+
+async def flush_sent(call) -> None:
+    """Make and await a flush loop's ``trace_append`` call (``call()``
+    returns the awaitable).  Anything but the controller's ``True`` — no
+    connection, the call lost, shed in a brownout, answered by a standby
+    — means the controller lacks that batch: the whole ring goes again,
+    after a pause and not every tick."""
+    import asyncio
+    try:
+        ok = await call()
+    except Exception:
+        ok = False
+    if ok is not True:
+        mark_dirty()
+        await asyncio.sleep(RESHIP_PAUSE_S)
 
 
 def mark_dirty() -> None:
-    global _dirty
+    """The controller does not have (all of) this process's spans — it
+    restarted, a standby was promoted, or a flush RPC failed: the next
+    flush re-ships the whole ring."""
+    global _reship
     with _span_lock:
-        _dirty = True
+        _reship = True
 
 
-def cluster_trace_events() -> List[dict]:
-    """Driver-local profile spans PLUS every process's flushed lifecycle
-    spans PLUS every node's legacy finished-task spans — the flat-list
-    form the dashboard consumes (``state.timeline()`` wraps the same
-    spans, minus the differently-clocked local profile events, as a
-    Chrome-trace dict)."""
-    events = chrome_trace_events()
+# ------------------------------------------------------------ span files
+
+def span_file(session_dir: str) -> str:
+    return os.path.join(session_dir, "spans",
+                        f"{_proc['kind']}-{os.getpid()}.json")
+
+
+def write_span_file(session_dir: Optional[str]) -> Optional[str]:
+    """Write this process's ring to its file under ``<session_dir>/spans``
+    (called where a process makes its final flush).  Skipped when nothing
+    was recorded since the last write.  Never raises: the process is on
+    its way out."""
+    global _filed
+    if not session_dir:
+        return None
+    with _span_lock:
+        if _recorded == _filed or _ring is None:
+            return None
+        events, _filed = _ring.events(), _recorded
+    path = span_file(session_dir)
     try:
-        from .. import state
-        events += state._trace_span_events()
-        events += state._node_task_span_events()
-    except Exception:
-        pass  # not connected / nodes unreachable: driver-local only
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        tmp = f"{path}.tmp"
+        with open(tmp, "w") as f:
+            json.dump(events, f)
+        os.replace(tmp, path)
+    except (OSError, TypeError, ValueError):
+        return None
+    return path
+
+
+def write_span_file_on_sigterm(session_dir: str) -> None:
+    """For the processes whose only way out is SIGTERM (nodelet,
+    controller): on the running asyncio loop, write the span file when
+    the signal comes, then die of it as before."""
+    import asyncio
+    import signal
+
+    def on_term() -> None:
+        write_span_file(session_dir)
+        signal.signal(signal.SIGTERM, signal.SIG_DFL)
+        os.kill(os.getpid(), signal.SIGTERM)
+
+    try:
+        asyncio.get_running_loop().add_signal_handler(signal.SIGTERM,
+                                                      on_term)
+    except (NotImplementedError, RuntimeError):
+        pass
+
+
+def read_span_files(session_dir: str) -> List[dict]:
+    """Every process's span file of a session, merged, oldest first."""
+    events: List[dict] = []
+    spans_dir = os.path.join(session_dir, "spans")
+    try:
+        names = sorted(os.listdir(spans_dir))
+    except OSError:
+        return events
+    for name in names:
+        if not name.endswith(".json"):
+            continue
+        try:
+            with open(os.path.join(spans_dir, name)) as f:
+                events.extend(json.load(f))
+        except (OSError, ValueError):
+            continue
+    events.sort(key=lambda e: e.get("ts", 0))
     return events
 
 
-def dump_chrome_trace(path: str):
-    with open(path, "w") as f:
-        json.dump({"traceEvents": chrome_trace_events()}, f)
+def chrome_trace(events: List[dict]) -> Dict[str, Any]:
+    """``events`` (already ordered) as a Chrome-trace dict with one
+    ``process_name`` metadata record per distinct pid."""
+    pids: List[Any] = []
+    for e in events:
+        p = e.get("pid")
+        if p not in pids:
+            pids.append(p)
+    meta = [{"ph": "M", "name": "process_name", "pid": p, "tid": 0,
+             "args": {"name": str(p)}} for p in pids]
+    return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+
+
+def cluster_trace_events() -> List[dict]:
+    """Every process's flushed lifecycle spans plus every node's legacy
+    finished-task spans — the flat-list form the dashboard consumes
+    (``state.timeline()`` wraps the same spans as a Chrome-trace dict)."""
+    try:
+        from .. import state
+        return state._trace_span_events() + state._node_task_span_events()
+    except Exception:
+        return span_events()   # not connected: this process's ring only

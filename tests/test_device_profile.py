@@ -173,8 +173,15 @@ def test_engine_stats_carry_profile_and_phase_totals():
         assert prof["decode_step"]["compiles"] >= 1   # ledger alive
         ph = st["phase_totals"]
         assert set(ph) == {"queue", "admission", "prefill",
-                           "decode_dispatch"}
+                           "decode_dispatch", "first_token",
+                           "prefill_tail", "schedule", "admit_host",
+                           "dispatch", "readback", "publish"}
         assert ph["prefill"] > 0 and ph["decode_dispatch"] > 0
+        # the engine thread's own phases: every iteration passed
+        # through each of them
+        for key in ("schedule", "admit_host", "dispatch", "readback",
+                    "publish"):
+            assert ph[key] > 0, (key, ph)
         # wrap-once across restart: a second engine re-wraps the
         # module-level shared prefill chunk jit; its ledger starts
         # clean instead of inheriting a stacked shim

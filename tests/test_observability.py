@@ -113,24 +113,6 @@ def _phases_for(events, fname):
              if e.get("args", {}).get("trace") == trace}, trace)
 
 
-def test_profile_timestamps_monotonic():
-    """Satellite fix: profile() must read ONE clock in ONE unit (µs of
-    perf_counter) on both ends — sequential spans are then ordered and
-    durations physical."""
-    from ray_tpu.util import tracing
-    with tracing.profile("obs-mono-a"):
-        time.sleep(0.02)
-    with tracing.profile("obs-mono-b"):
-        pass
-    evs = [e for e in tracing.chrome_trace_events()
-           if e["name"].startswith("obs-mono-")]
-    a = next(e for e in evs if e["name"] == "obs-mono-a")
-    b = next(e for e in evs if e["name"] == "obs-mono-b")
-    assert a["dur"] >= 0.01 * 1e6, a   # ~20ms sleep, µs units
-    assert a["dur"] < 60 * 1e6, a      # not the perf_counter epoch mixup
-    assert b["ts"] >= a["ts"] + a["dur"] - 1.0, (a, b)
-
-
 def test_span_propagation_two_node_timeline():
     """A 2-task run on a 2-node in-process cluster produces a loadable
     Chrome trace with submit/schedule/dequeue/fetch/exec/put spans per
