@@ -9,6 +9,17 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
+The cache is stored ``[layers, batch, kv_heads, head_dim, max_len]`` —
+positions LAST — and every program that takes one extends it IN PLACE:
+the whole stacked cache is state of the one layer loop
+(:func:`_scan_cached`), written by ``dynamic_update_slice`` and held to
+its row-major layout, so a caller that donates its cache pays no copy
+at all.  Positions are last because that is the tiling a TPU gives the
+array anyway: with ``head_dim`` 64 minor, 64 of 128 lanes (and 25 heads
+of 32 sublanes) would be padding, so the device keeps ``max_len`` minor
+whatever the logical order says, and a loop that wants another order
+converts the whole cache on the way in and on the way out.
+
 Reference: Ray has no model runtime of its own (serving delegates to the
 wrapped framework); this module is the TPU-native equivalent of what its
 users bring via vLLM/TGI — sized to the in-tree transformer family.
@@ -22,16 +33,28 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.layout import Layout, with_layout_constraint
+
 from ..ops.rotary import apply_rotary, rotary_angles
 from .transformer import TransformerConfig, _ffn, _layer, _norm, _unembed
 
 Params = Any
-KVCache = Dict[str, jnp.ndarray]   # {"k","v": [L, B, max_len, hk, hd], "pos"}
+KVCache = Dict[str, jnp.ndarray]   # {"k","v": [L, B, hk, hd, max_len], "pos"}
+
+
+def _cache_shape(cfg: TransformerConfig, batch: int,
+                 max_len: int) -> Tuple[int, ...]:
+    return (cfg.n_layers, batch, cfg.kv_heads, cfg.head_dim, max_len)
+
+
+def cache_capacity(cache: KVCache) -> int:
+    """``max_len``: the positions a cache holds per row."""
+    return cache["k"].shape[-1]
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int,
                   max_len: int) -> KVCache:
-    shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)
+    shape = _cache_shape(cfg, batch, max_len)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype),
             "pos": jnp.zeros((), jnp.int32)}
@@ -44,13 +67,80 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "serve pp-sharded models stage-per-gang instead")
 
 
-def _project_kv(cfg, y, lp, cos, sin):
+def _scan_cached(layers, x: jnp.ndarray, cache: KVCache, layer_fn
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """THE layer loop of every program that writes a KV cache.
+
+    The whole stacked cache ``[L, B, hk, hd, max_len]`` is loop STATE,
+    indexed by the layer counter, and the layer weights the scanned
+    input: ``layer_fn(x, lp, k_all, v_all, l) -> (x, k_all, v_all)``
+    writes its columns into ``k_all[l]`` / ``v_all[l]`` in place.  Passing
+    the cache's layers through the scan as inputs and stacking them as
+    outputs instead builds a second cache per call, and leaves a donated
+    cache argument nothing to alias to.  The carry is held to the
+    row-major layout the cache arrives in: left to itself the compiler
+    gives loop state the layout its rows are produced in (``head_dim``
+    minor) and converts the whole cache before and after the loop.
+    → (x, k_all, v_all)."""
+    row_major = Layout(major_to_minor=tuple(range(cache["k"].ndim)))
+
+    def step(carry, lp):
+        xc, k_all, v_all, l = carry
+        k_all = with_layout_constraint(k_all, row_major)
+        v_all = with_layout_constraint(v_all, row_major)
+        xc, k_all, v_all = layer_fn(xc, lp, k_all, v_all, l)
+        return (xc, k_all, v_all, l + 1), None
+
+    (x, k_all, v_all, _), _ = jax.lax.scan(
+        step, (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)), layers)
+    return x, k_all, v_all
+
+
+def _as_columns(rows: jnp.ndarray, dtype) -> jnp.ndarray:
+    """New tokens' K or V [B, C, hk, hd] → cache columns [B, hk, hd, C]."""
+    return jnp.transpose(rows, (0, 2, 3, 1)).astype(dtype)
+
+
+def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
+                   cache: KVCache, *, rotate, write, mask
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Run ``x`` [B, C, D] (C new tokens per row) through every layer
+    against the cache: each layer writes the new tokens' K/V columns
+    (``write(c_all, l, cols [B, hk, hd, C]) -> c_all``), then attends
+    dense over layer ``l`` of the cache under ``mask`` [B|1, C, max_len].
+    ``rotate`` applies the caller's rotary angles (rope only)."""
     dt = cfg.dtype
-    k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-    if cfg.pos_emb == "rope":
-        k = apply_rotary(k, cos, sin)
-    return k, v
+    b, c, _ = x.shape
+    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+
+    def layer(xc, lp, k_all, v_all, l):
+        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
+        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
+        k_new = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
+        v_new = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
+        if cfg.pos_emb == "rope":
+            q, k_new = rotate(q), rotate(k_new)
+        k_all = write(k_all, l, _as_columns(k_new, k_all.dtype))
+        v_all = write(v_all, l, _as_columns(v_new, v_all.dtype))
+        ck = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
+        cv = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
+        # GQA: group query heads over kv heads
+        qh = q.reshape(b, c, hk, h // hk, hd)
+        scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
+                            ck.astype(dt)) / jnp.sqrt(float(hd))
+        scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
+                          cv.astype(dt))
+        attn = attn.reshape(b, c, h, hd)
+        xc = xc + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
+        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
+        z, _ = _ffn(cfg, y2, lp)
+        return xc + z, k_all, v_all
+
+    x, k_all, v_all = _scan_cached(params["layers"], x, cache, layer)
+    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+    return x, k_all, v_all
 
 
 def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
@@ -60,36 +150,35 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     _check_decodable(cfg)
     b, s = tokens.shape
     dt = cfg.dtype
+    if s > cache_capacity(cache):
+        raise ValueError(f"prompt length {s} exceeds cache capacity "
+                         f"{cache_capacity(cache)}")
     x = params["embed"]["tok"][tokens].astype(dt)
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][:s].astype(dt)
     cos, sin = (rotary_angles(s, cfg.head_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
 
-    def body(carry, lp):
-        h = carry
+    def layer(h, lp, k_all, v_all, l):
         # K/V for the cache come from the same pre-norm projection the
         # layer itself computes; run the layer for h, re-project for kv
         y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
-        k, v = _project_kv(cfg, y, lp, cos, sin)
+        k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
+        if cfg.pos_emb == "rope":
+            k = apply_rotary(k, cos, sin)
+        k_all = jax.lax.dynamic_update_slice(
+            k_all, _as_columns(k, k_all.dtype)[None], (l, 0, 0, 0, 0))
+        v_all = jax.lax.dynamic_update_slice(
+            v_all, _as_columns(v, v_all.dtype)[None], (l, 0, 0, 0, 0))
         h, _ = _layer(cfg, h, lp, cos, sin)
-        return h, (k, v)
+        return h, k_all, v_all
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
+    x, k_all, v_all = _scan_cached(params["layers"], x, cache, layer)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed(params, cfg))
-
-    if s > cache["k"].shape[2]:
-        raise ValueError(f"prompt length {s} exceeds cache capacity "
-                         f"{cache['k'].shape[2]}")
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], ks.astype(cfg.dtype), (0, 0, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], vs.astype(cfg.dtype), (0, 0, 0, 0, 0)),
-        "pos": jnp.asarray(s, jnp.int32),
-    }
-    return logits.astype(jnp.float32), cache
+    return logits.astype(jnp.float32), {
+        "k": k_all, "v": v_all, "pos": jnp.asarray(s, jnp.int32)}
 
 
 def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
@@ -109,7 +198,7 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     b, c = tokens.shape
     dt = cfg.dtype
     pos = cache["pos"]
-    max_len = cache["k"].shape[2]
+    max_len = cache_capacity(cache)
     x = params["embed"]["tok"][tokens].astype(dt)              # [B,C,D]
     if cfg.pos_emb == "learned":
         x = x + jax.lax.dynamic_slice_in_dim(
@@ -121,49 +210,26 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
         sin = jax.lax.dynamic_slice_in_dim(full_sin, pos, c, axis=0)
     else:
         cos = sin = None
-
-    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     # mask[i, t]: cached position t visible to chunk token i (causal
     # within the chunk, everything before it fully visible)
     mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
-
-    def body(carry, inputs):
-        xc = carry
-        lp, ck, cv = inputs                                    # per-layer
-        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-        if cfg.pos_emb == "rope":
-            q = apply_rotary(q, cos, sin)
-        k_new, v_new = _project_kv(cfg, y, lp, cos, sin)
-        ck = jax.lax.dynamic_update_slice(ck, k_new.astype(cfg.dtype),
-                                          (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v_new.astype(cfg.dtype),
-                                          (0, pos, 0, 0))
-        qh = q.reshape(b, c, hk, h // hk, hd)
-        scores = jnp.einsum("bskgd,btkd->bskgt", qh,
-                            ck.astype(dt)) / jnp.sqrt(float(hd))
-        scores = jnp.where(mask[None, :, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        attn = jnp.einsum("bskgt,btkd->bskgd", probs.astype(dt),
-                          cv.astype(dt))
-        attn = attn.reshape(b, c, h, hd)
-        xc = xc + jnp.einsum("bshk,hkd->bsd", attn,
-                             lp["wo"].astype(dt))
-        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        z, _ = _ffn(cfg, y2, lp)
-        xc = xc + z
-        return xc, (ck, cv)
-
-    x, (ks, vs) = jax.lax.scan(body, x,
-                               (params["layers"], cache["k"], cache["v"]))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+    x, k_all, v_all = _attend_cached(
+        cfg, params, x, cache,
+        rotate=lambda t: apply_rotary(t, cos, sin),
+        write=lambda c_all, l, cols: jax.lax.dynamic_update_slice(
+            c_all, cols[None], (l, 0, 0, 0, pos)),
+        mask=mask[None])
     logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed(params, cfg))
-    return logits.astype(jnp.float32), {"k": ks, "v": vs, "pos": pos + c}
+    return logits.astype(jnp.float32), {"k": k_all, "v": v_all,
+                                        "pos": pos + c}
 
 
 # Module-level jit: every prefill_chunked caller shares one trace/compile
-# cache (the point of chunking is a bounded, REUSED program)
-_prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg",))
+# cache (the point of chunking is a bounded, REUSED program).  The cache
+# argument is DONATED: the program extends it in place and the caller's
+# handle is dead after the call — rebind to the returned cache.
+_prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                             donate_argnames=("cache",))
 
 #: The ONE shared chunk program behind every prefill path: legacy
 #: `prefill_chunked`, failover `resume_prefill`, AND the serve engine's
@@ -182,9 +248,9 @@ def prefill_chunked(params: Params, tokens: jnp.ndarray,
     (at most two compiled shapes: ``chunk`` and the tail remainder).
     Drop-in for :func:`prefill` where compile size must stay bounded."""
     b, s = tokens.shape
-    if s > cache["k"].shape[2]:
+    if s > cache_capacity(cache):
         raise ValueError(f"prompt length {s} exceeds cache capacity "
-                         f"{cache['k'].shape[2]}")
+                         f"{cache_capacity(cache)}")
     fn = _jitted or _prefill_chunk_jit
     logits = None
     for off in range(0, s, chunk):
@@ -211,9 +277,9 @@ def resume_prefill(params: Params, tokens: jnp.ndarray,
     (numerically) the same the uninterrupted session would have produced,
     so the argmax — the next token — matches exactly."""
     b, s = tokens.shape
-    if s > cache["k"].shape[2]:
+    if s > cache_capacity(cache):
         raise ValueError(f"resume prefix length {s} exceeds cache "
-                         f"capacity {cache['k'].shape[2]}")
+                         f"capacity {cache_capacity(cache)}")
     fn = _jitted or _prefill_chunk_jit
     logits = None
     off = 0
@@ -229,60 +295,9 @@ def resume_prefill(params: Params, tokens: jnp.ndarray,
 
 def decode_step(params: Params, token: jnp.ndarray, cache: KVCache,
                 cfg: TransformerConfig) -> Tuple[jnp.ndarray, KVCache]:
-    """One token [B] int32 → (next-token logits [B, vocab], cache')."""
-    _check_decodable(cfg)
-    b = token.shape[0]
-    dt = cfg.dtype
-    pos = cache["pos"]
-    max_len = cache["k"].shape[2]
-    x = params["embed"]["tok"][token][:, None].astype(dt)     # [B,1,D]
-    if cfg.pos_emb == "learned":
-        x = x + jax.lax.dynamic_slice_in_dim(
-            params["embed"]["pos"], pos, 1, axis=0).astype(dt)
-    if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.head_dim,
-                                           cfg.rope_base)
-        cos = jax.lax.dynamic_slice_in_dim(full_cos, pos, 1, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(full_sin, pos, 1, axis=0)
-    else:
-        cos = sin = None
-
-    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    mask = (jnp.arange(max_len) <= pos)                        # [max_len]
-
-    def body(carry, inputs):
-        xc = carry
-        lp, ck, cv = inputs                                    # per-layer
-        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-        if cfg.pos_emb == "rope":
-            q = apply_rotary(q, cos, sin)
-        k_new, v_new = _project_kv(cfg, y, lp, cos, sin)
-        ck = jax.lax.dynamic_update_slice(ck, k_new.astype(cfg.dtype),
-                                          (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v_new.astype(cfg.dtype),
-                                          (0, pos, 0, 0))
-        # GQA: group query heads over kv heads
-        qh = q[:, 0].reshape(b, hk, h // hk, hd)
-        scores = jnp.einsum("bkgd,btkd->bkgt", qh,
-                            ck.astype(dt)) / jnp.sqrt(float(hd))
-        scores = jnp.where(mask[None, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        attn = jnp.einsum("bkgt,btkd->bkgd", probs.astype(dt),
-                          cv.astype(dt))
-        attn = attn.reshape(b, 1, h, hd)
-        xc = xc + jnp.einsum("bshk,hkd->bsd", attn,
-                             lp["wo"].astype(dt))
-        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        z, _ = _ffn(cfg, y2, lp)
-        xc = xc + z
-        return xc, (ck, cv)
-
-    x, (ks, vs) = jax.lax.scan(body, x,
-                               (params["layers"], cache["k"], cache["v"]))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], _unembed(params, cfg))
-    return logits.astype(jnp.float32), {"k": ks, "v": vs, "pos": pos + 1}
+    """One token [B] int32 → (next-token logits [B, vocab], cache'): a
+    chunk of one."""
+    return prefill_chunk(params, token[:, None], cache, cfg)
 
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
@@ -291,7 +306,7 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
     independent sessions share one batched program, so ``pos`` is a
     per-slot vector instead of the single scalar of
     :func:`init_kv_cache`."""
-    shape = (cfg.n_layers, slots, max_len, cfg.kv_heads, cfg.head_dim)
+    shape = _cache_shape(cfg, slots, max_len)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype),
             "pos": jnp.zeros((slots,), jnp.int32)}
@@ -332,11 +347,9 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
     suffix prefill overwrites them before ``pos`` ever reaches them.
     ``slot`` and ``upto`` are TRACED, so one compiled program serves
     every (donor slot, prefix length) pair."""
-    nl, _, max_len, hk, hd = slot_cache["k"].shape
-    k = jax.lax.dynamic_slice(slot_cache["k"], (0, slot, 0, 0, 0),
-                              (nl, 1, max_len, hk, hd))
-    v = jax.lax.dynamic_slice(slot_cache["v"], (0, slot, 0, 0, 0),
-                              (nl, 1, max_len, hk, hd))
+    one_slot = (slot_cache["k"].shape[0], 1) + slot_cache["k"].shape[2:]
+    k = jax.lax.dynamic_slice(slot_cache["k"], (0, slot, 0, 0, 0), one_slot)
+    v = jax.lax.dynamic_slice(slot_cache["v"], (0, slot, 0, 0, 0), one_slot)
     return {"k": k, "v": v, "pos": jnp.asarray(upto, jnp.int32)}
 
 
@@ -354,6 +367,54 @@ def _rotate_slots(x: jnp.ndarray, cos: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
+                   cfg: TransformerConfig
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``tokens`` [S, C]: C tokens per slot, fed at each slot's OWN
+    ``pos`` .. ``pos + C - 1`` → (final-norm activations [S, C, D],
+    k_all, v_all).  Slots sit at DIFFERENT positions, so each fed
+    token's K/V column is written by its own ``dynamic_update_slice``
+    (a scatter is not updated in place under the cache's layout).  A
+    slice whose start lies past the end is clamped onto the last
+    column, where a scatter would have dropped it: a slot's columns are
+    therefore written LAST TOKEN FIRST, so the token that belongs in
+    the last column overwrites what was clamped onto it.  Only a slot
+    already at ``max_len`` loses its last column that way, and that
+    slot is finished: nothing reads it again (a prefix match stops
+    short of a prompt's last token)."""
+    _check_decodable(cfg)
+    s, c = tokens.shape
+    dt = cfg.dtype
+    pos = cache["pos"]                                         # [S]
+    max_len = cache_capacity(cache)
+    posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
+    x = params["embed"]["tok"][tokens].astype(dt)              # [S,C,D]
+    if cfg.pos_emb == "learned":
+        x = x + params["embed"]["pos"][posm].astype(dt)
+    if cfg.pos_emb == "rope":
+        full_cos, full_sin = rotary_angles(max_len, cfg.head_dim,
+                                           cfg.rope_base)
+        cos = full_cos[posm][:, :, None, :]                    # [S,C,1,·]
+        sin = full_sin[posm][:, :, None, :]
+    else:
+        cos = sin = None
+
+    def write(c_all, l, cols):                                 # [S,hk,hd,C]
+        for slot in range(s):
+            for i in reversed(range(c)):
+                c_all = jax.lax.dynamic_update_slice(
+                    c_all, cols[None, slot:slot + 1, :, :, i:i + 1],
+                    (l, slot, 0, 0, pos[slot] + i))
+        return c_all
+
+    # mask[s, i, t]: cached position t visible to fed token i of slot s
+    mask = jnp.arange(max_len)[None, None, :] <= posm[:, :, None]
+    return _attend_cached(
+        cfg, params, x, cache,
+        rotate=lambda t: _rotate_slots(t, cos, sin),
+        write=write, mask=mask)
+
+
 def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
                       active: jnp.ndarray, cfg: TransformerConfig
                       ) -> Tuple[jnp.ndarray, KVCache]:
@@ -368,61 +429,11 @@ def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
     is overwritten by the next active step before any read, and their
     logits are discarded by the engine.
     """
-    _check_decodable(cfg)
-    s = token.shape[0]
-    dt = cfg.dtype
-    pos = cache["pos"]                                         # [S]
-    max_len = cache["k"].shape[2]
-    x = params["embed"]["tok"][token][:, None].astype(dt)      # [S,1,D]
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["pos"][pos][:, None].astype(dt)
-    if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.head_dim,
-                                           cfg.rope_base)
-        cos = full_cos[pos][:, None, None, :]                  # [S,1,1,·]
-        sin = full_sin[pos][:, None, None, :]
-    else:
-        cos = sin = None
-
-    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    slot_ix = jnp.arange(s)
-    mask = jnp.arange(max_len)[None, :] <= pos[:, None]        # [S, T]
-
-    def body(carry, inputs):
-        xc = carry
-        lp, ck, cv = inputs                                    # per-layer
-        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-        k_new = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-        v_new = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-        if cfg.pos_emb == "rope":
-            q = _rotate_slots(q, cos, sin)
-            k_new = _rotate_slots(k_new, cos, sin)
-        # per-slot write positions: scatter instead of the batch-1
-        # path's dynamic_update_slice (slots decode at DIFFERENT pos)
-        ck = ck.at[slot_ix, pos].set(k_new[:, 0].astype(cfg.dtype))
-        cv = cv.at[slot_ix, pos].set(v_new[:, 0].astype(cfg.dtype))
-        qh = q[:, 0].reshape(s, hk, h // hk, hd)
-        scores = jnp.einsum("bkgd,btkd->bkgt", qh,
-                            ck.astype(dt)) / jnp.sqrt(float(hd))
-        scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        attn = jnp.einsum("bkgt,btkd->bkgd", probs.astype(dt),
-                          cv.astype(dt))
-        attn = attn.reshape(s, 1, h, hd)
-        xc = xc + jnp.einsum("bshk,hkd->bsd", attn,
-                             lp["wo"].astype(dt))
-        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        z, _ = _ffn(cfg, y2, lp)
-        xc = xc + z
-        return xc, (ck, cv)
-
-    x, (ks, vs) = jax.lax.scan(body, x,
-                               (params["layers"], cache["k"], cache["v"]))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+    x, k_all, v_all = _forward_slots(params, token[:, None], cache, cfg)
     logits = jnp.einsum("bd,dv->bv", x[:, 0], _unembed(params, cfg))
     return logits.astype(jnp.float32), {
-        "k": ks, "v": vs, "pos": pos + active.astype(jnp.int32)}
+        "k": k_all, "v": v_all,
+        "pos": cache["pos"] + active.astype(jnp.int32)}
 
 
 def draft_propose_slots(params: Params, token: jnp.ndarray,
@@ -482,61 +493,11 @@ def verify_step_slots(params: Params, tokens: jnp.ndarray,
     written at its position; rejected-suffix writes land past the
     advanced ``pos`` and are rewritten (with the true token) before any
     masked read, the same invariant plain decode relies on for paused
-    slots.  Writes past ``max_len`` are dropped by XLA scatter
-    semantics and ``accepted`` is clamped so emission never outruns the
-    cache."""
-    _check_decodable(cfg)
-    s, c = tokens.shape
-    dt = cfg.dtype
+    slots.  Writes past ``max_len`` are dropped and ``accepted`` is
+    clamped so emission never outruns the cache."""
     pos = cache["pos"]                                         # [S]
-    max_len = cache["k"].shape[2]
-    posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
-    x = params["embed"]["tok"][tokens].astype(dt)              # [S,C,D]
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["pos"][posm].astype(dt)
-    if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.head_dim,
-                                           cfg.rope_base)
-        cos = full_cos[posm][:, :, None, :]                    # [S,C,1,·]
-        sin = full_sin[posm][:, :, None, :]
-    else:
-        cos = sin = None
-
-    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    slot_ix = jnp.arange(s)[:, None]                           # [S, 1]
-    # mask[s, i, t]: cached position t visible to fed token i of slot s
-    mask = jnp.arange(max_len)[None, None, :] <= posm[:, :, None]
-
-    def body(carry, inputs):
-        xc = carry
-        lp, ck, cv = inputs                                    # per-layer
-        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-        k_new = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-        v_new = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-        if cfg.pos_emb == "rope":
-            q = _rotate_slots(q, cos, sin)
-            k_new = _rotate_slots(k_new, cos, sin)
-        ck = ck.at[slot_ix, posm].set(k_new.astype(cfg.dtype))
-        cv = cv.at[slot_ix, posm].set(v_new.astype(cfg.dtype))
-        qh = q.reshape(s, c, hk, h // hk, hd)
-        scores = jnp.einsum("bskgd,btkd->bskgt", qh,
-                            ck.astype(dt)) / jnp.sqrt(float(hd))
-        scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        attn = jnp.einsum("bskgt,btkd->bskgd", probs.astype(dt),
-                          cv.astype(dt))
-        attn = attn.reshape(s, c, h, hd)
-        xc = xc + jnp.einsum("bshk,hkd->bsd", attn,
-                             lp["wo"].astype(dt))
-        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        z, _ = _ffn(cfg, y2, lp)
-        xc = xc + z
-        return xc, (ck, cv)
-
-    x, (ks, vs) = jax.lax.scan(body, x,
-                               (params["layers"], cache["k"], cache["v"]))
-    x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
+    max_len = cache_capacity(cache)
+    x, k_all, v_all = _forward_slots(params, tokens, cache, cfg)
     logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg))
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # [S, C]
     ok = (greedy[:, :-1] == proposals).astype(jnp.int32)
@@ -544,7 +505,7 @@ def verify_step_slots(params: Params, tokens: jnp.ndarray,
     accepted = jnp.minimum(accepted,
                            jnp.maximum(max_len - pos, 1)).astype(jnp.int32)
     adv = jnp.where(active, accepted, 0).astype(jnp.int32)
-    return greedy, accepted, {"k": ks, "v": vs, "pos": pos + adv}
+    return greedy, accepted, {"k": k_all, "v": v_all, "pos": pos + adv}
 
 
 def _sample(logits: jnp.ndarray, key: jax.Array, greedy: bool,

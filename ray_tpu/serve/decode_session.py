@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import functools
 import threading
 import time
 import weakref
@@ -184,10 +185,18 @@ class ContinuousBatchingEngine:
         # ride _maybe_push_metrics to the nodelet fold.
         from ..util.device_profile import DispatchProfiler
         self._prof = DispatchProfiler()
+        # the slot cache is DONATED to the step and to the insert (as
+        # the batch-1 cache is to the shared chunk program): each
+        # extends it in place, and the engine thread, its only owner,
+        # rebinds to the result
+        self.cache_copies = 0
         self._step = self._prof.wrap(
-            "decode_step", jax.jit(fused_step, static_argnames=("cfg",)))
-        self._insert = self._prof.wrap("cache_insert",
-                                       jax.jit(cache_insert_slot))
+            "decode_step", self._counting_copies(
+                jax.jit(fused_step, static_argnames=("cfg",),
+                        donate_argnames=("cache",)), 2))
+        self._insert = self._prof.wrap(
+            "cache_insert", self._counting_copies(
+                jax.jit(cache_insert_slot, donate_argnums=(0,)), 0))
         # ---- shared-prefix KV reuse ----
         # radix trie over live slots' prompts (serve/prefix_cache.py):
         # admission copies the longest shared prefix out of a donor
@@ -209,7 +218,8 @@ class ContinuousBatchingEngine:
         # prefill_chunked path all hit one compile cache.  The profiler
         # wrap is idempotent, so an engine restart re-wrapping the same
         # shared jit never stacks a second timer over it.
-        self._chunk = self._prof.wrap("prefill_chunk", prefill_chunk_jit)
+        self._chunk = self._prof.wrap(
+            "prefill_chunk", self._counting_copies(prefill_chunk_jit, 2))
         # ---- speculative decoding ----
         self._spec = False
         self._draft_cfg = None
@@ -276,6 +286,22 @@ class ContinuousBatchingEngine:
         self.phase_s = dict.fromkeys(
             ("queue", "admission", "first_token", "prefill_tail")
             + tuple(self._THREAD_PHASES.values()), 0.0)
+
+    def _counting_copies(self, fn, arg: int):
+        """``fn`` donates its positional argument ``arg``, a cache: count
+        in ``cache_copies`` the calls after which that cache is still
+        alive.  Donation that does not engage is silent otherwise (JAX
+        warns once, and copies)."""
+
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            out = fn(*args, **kwargs)
+            if not args[arg]["k"].is_deleted():
+                with self._cond:   # stats() reads this counter
+                    self.cache_copies += 1
+            return out
+
+        return call
 
     # ------------------------------------------------------------ client ops
 
@@ -443,6 +469,10 @@ class ContinuousBatchingEngine:
                     "reaped": self.reaped,
                     "steps": self.steps, "tokens": self.tokens,
                     "prefill_chunks": self.prefill_chunks,
+                    # decode_step / cache_insert / prefill_chunk
+                    # dispatches that did NOT consume the cache they
+                    # were given (0 while donation engages)
+                    "cache_copies": self.cache_copies,
                     # every distinct program shape this engine has
                     # dispatched — a compile-storm regression (one
                     # program per prompt/resume length) shows up here
@@ -884,17 +914,33 @@ class ContinuousBatchingEngine:
                     with phase("readback"):
                         new_toks = np.asarray(tok_dev)
                         tokens[:] = new_toks
-                except Exception as e:             # pragma: no cover
-                    with self._cond:
-                        for s in batch:
-                            s.error = f"decode engine step failed: {e!r}"
-                            s.done = True
-                        self._cond.notify_all()
+                except Exception as e:
+                    self._fail_slots(f"decode engine step failed: {e!r}")
                     tok_dev = None
                     continue
             with phase("publish"):
                 self._publish(batch, tokens, spec_out,
                               None if spec_out is not None else new_toks)
+
+    def _fail_slots(self, error: str) -> None:
+        """A donated step raised: the slot cache it was given may be
+        gone, and with it every slot's rows, not only the batch's.  Fail
+        every session that holds a slot (the reaper frees the slots next
+        turn), forget the prefixes the lost rows advertised, and go on
+        with a fresh cache.  Sessions still prefilling or waiting for a
+        slot own their batch-1 caches and are untouched."""
+        from ..models import init_slot_cache
+        with self._cond:
+            for sess in self._slots.values():
+                sess.error = error
+                sess.done = True
+            if self._prefix is not None:
+                for slot in range(self.ecfg.max_slots):
+                    self._prefix.evict(slot)
+            self._cond.notify_all()
+        self._cache = None     # free what is left before allocating anew
+        self._cache = init_slot_cache(self.cfg, self.ecfg.max_slots,
+                                      self.max_len)
 
     def _admit_and_prefill(self, admitted, prefills) -> None:
         """The host side of admission, on the engine thread outside the

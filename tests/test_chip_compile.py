@@ -121,3 +121,70 @@ def test_create_mesh_tpu_branch_on_the_described_2x2(topo):
     small = create_mesh(MeshSpec(fsdp=2, tp=2), devices=topo.devices,
                         drop_trivial_axes=True)
     assert small.axis_names == ("fsdp", "tp") and small.devices.shape == (2, 2)
+
+
+@pytest.mark.parametrize("model", ["gpt2-medium", "llama-1b", "llama-8b"])
+@pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
+                                     "prefill_chunk_1"])
+def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
+    """At gpt2 head widths (16 heads of 64) the chip keeps a KV cache with
+    ``max_len`` minor, whatever the logical order; a layer loop that
+    wanted another order converted the whole cache before and after
+    (`copy.*` led both served cells).  With the cache stored positions
+    last and the loop held to that layout, the donated program aliases
+    its cache, allocates a small fraction of one beside it, and no
+    instruction copies an array of the cache's shape.  The same holds for
+    RoPE/GQA widths: 8 kv heads of 64 and of 128.  Two layers of each
+    model: the loop's body is compiled once whatever their number."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, decode_step_slots,
+                                init_kv_cache, init_params, init_slot_cache,
+                                prefill_chunk)
+    family, size = model.split("-")
+    cfg = dataclasses.replace(
+        getattr(TransformerConfig, family)(
+            size, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+            max_seq_len=1024), n_layers=2)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 16, 1024
+    if program == "fused_step":
+        cache = described(jax.eval_shape(
+            lambda: init_slot_cache(cfg, slots, max_len)))
+
+        def fused_step(params, tok, cache, active):
+            logits, cache = decode_step_slots(params, tok, cache, active,
+                                              cfg)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.where(active, nxt, tok), cache
+        lowered = jax.jit(fused_step, donate_argnums=(2,)).lower(
+            params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+            cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    else:
+        width = int(program.rsplit("_", 1)[1])
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
+            cache, cfg=cfg)
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    want = 2 * cache["k"].size * cache["k"].dtype.itemsize
+    assert ma.alias_size_in_bytes >= want
+    assert ma.temp_size_in_bytes < want // 4, (ma.temp_size_in_bytes, want)
+    shape = ",".join(map(str, cache["k"].shape))
+    copies = re.findall(rf"= bf16\[{shape}\]\S* copy\(", compiled.as_text())
+    assert not copies, copies

@@ -222,10 +222,11 @@ def test_cache_gather_slot_roundtrip_and_truncation():
     slot_cache = cache_insert_slot(slot_cache, cache, jnp.int32(2))
     got = cache_gather_slot(slot_cache, jnp.int32(2), jnp.int32(5))
     assert int(got["pos"]) == 5
-    np.testing.assert_array_equal(np.asarray(got["k"][:, 0, :5]),
-                                  np.asarray(cache["k"][:, 0, :5]))
-    np.testing.assert_array_equal(np.asarray(got["v"][:, 0, :5]),
-                                  np.asarray(cache["v"][:, 0, :5]))
+    # [layers, batch, kv_heads, head_dim, max_len]: positions last
+    np.testing.assert_array_equal(np.asarray(got["k"][:, 0, ..., :5]),
+                                  np.asarray(cache["k"][:, 0, ..., :5]))
+    np.testing.assert_array_equal(np.asarray(got["v"][:, 0, ..., :5]),
+                                  np.asarray(cache["v"][:, 0, ..., :5]))
 
 
 def test_engine_prefix_reuse_parity_and_skipped_prefill():

@@ -118,6 +118,81 @@ def test_engine_token_parity_with_midstream_join_leave():
     st = engine.handle({"op": "stats"})["engine"]
     assert st["tokens"] >= 4 * (want - 1)
     assert st["steps"] < 4 * (want - 1)
+    # every step, chunk program and slot insert consumed the cache it
+    # was given: donation engaged, nothing was copied
+    assert st["cache_copies"] == 0
+
+
+def test_engine_failed_step_fails_slot_holders_and_serves_on():
+    """A donated step that raises may have consumed the slot cache: the
+    engine fails EVERY session that holds a slot (not only the batch),
+    forgets the prefixes the lost rows advertised, goes on with a fresh
+    cache, and serves the next request what an undisturbed engine
+    serves."""
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    cfg = _tiny_cfg()
+    ecfg = DecodeEngineConfig(max_slots=2, token_queue_depth=2)
+    core = DecodeSessionCore(cfg, max_len=64, seed=3, engine=ecfg)
+    ref = DecodeSessionCore(cfg, max_len=64, seed=3, engine=ecfg)
+    prompt, want = [3, 1, 4, 1, 5, 9, 2, 6], 8
+
+    def stream(c, sid, first, n):
+        toks = list(first)
+        while len(toks) < n:
+            out = c.handle({"op": "next_chunk", "sid": sid,
+                            "max_tokens": n - len(toks)})
+            assert "error" not in out, out
+            toks += out["tokens"]
+        return toks
+
+    r = ref.handle({"op": "start", "prompt": prompt})
+    expect = stream(ref, r["sid"], r["token"], want)
+
+    # a: decoding; b: holds the other slot but is PAUSED (its queue is
+    # full, nobody drains it), so it is in no batch when the step fails
+    b = core.handle({"op": "start", "prompt": [8, 8, 8]})
+    eng = core.engine
+    deadline = time.monotonic() + 20
+    while time.monotonic() < deadline:
+        with eng._cond:
+            sb = eng.sessions[b["sid"]]
+            if sb.slot is not None and len(sb.queue) >= 2:
+                break
+        time.sleep(0.01)
+    a = core.handle({"op": "start", "prompt": prompt})
+    real_step, raised = eng._step, threading.Event()
+
+    def failing_step(params, tok, cache, active, *, cfg):
+        if not raised.is_set():
+            raised.set()
+            # what a donated dispatch that dies leaves behind
+            for leaf in (cache["k"], cache["v"], cache["pos"]):
+                leaf.delete()
+            raise RuntimeError("injected step failure")
+        return real_step(params, tok, cache, active, cfg=cfg)
+
+    eng._step = failing_step
+    outs = {}
+    for name, sid in (("a", a["sid"]), ("b", b["sid"])):
+        deadline = time.monotonic() + 20
+        while time.monotonic() < deadline:
+            outs[name] = core.handle({"op": "next_chunk", "sid": sid,
+                                      "max_tokens": 64, "timeout_s": 0.2})
+            if "error" in outs[name]:
+                break
+    assert raised.is_set()
+    for name in ("a", "b"):
+        assert "decode engine step failed" in outs[name].get("error", ""), \
+            (name, outs[name])
+    # the engine thread is alive, the slots are free again, no prefix of
+    # the lost cache is on offer, and the next request is served in full
+    c = core.handle({"op": "start", "prompt": prompt})
+    assert stream(core, c["sid"], c["token"], want) == expect
+    st = core.handle({"op": "stats"})["engine"]
+    assert st["prefix"]["applied_hits"] == 0
+    assert st["cache_copies"] == 0
+    assert eng._thread.is_alive()
 
 
 def test_engine_slot_reclamation_backpressure_and_lru():
