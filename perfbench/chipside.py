@@ -1,7 +1,6 @@
 """What runs in the one process that holds the chip, whichever traffic kind
-started it: the program's model configuration built from a configuration
-file, the worker's report of itself, the count of compilations, and the
-profiler switch.  Imports JAX, so the runner never imports this module."""
+started it: the worker's report of itself, the count of compilations, and
+the profiler switch.  Imports JAX, so the runner never imports this module."""
 
 from __future__ import annotations
 
@@ -10,9 +9,6 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
-import jax.numpy as jnp
-
-_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
 
 def configure_jax() -> None:
@@ -22,24 +18,6 @@ def configure_jax() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _CompileCounter.install()
-
-
-def model_config(c: Dict[str, Any], use: str, **overrides):
-    """The program's `TransformerConfig` at the sizes of configuration file
-    ``c`` in the precision it states for ``use`` ("train" | "serve")."""
-    from ray_tpu.models import TransformerConfig
-    p = c["precision"][use]
-    return TransformerConfig(
-        vocab_size=c["vocab_size"], d_model=c["n_embd"],
-        n_layers=c["n_layer"], n_heads=c["n_head"], d_ff=c["n_inner"],
-        max_seq_len=c["n_positions"], pos_emb="learned", activation="gelu",
-        norm="layernorm", tie_embeddings=c["tie_word_embeddings"],
-        dtype=_DTYPES[p["compute"]], param_dtype=_DTYPES[p["params"]],
-        **overrides)
-
-
-def param_dtype(c: Dict[str, Any], use: str):
-    return _DTYPES[c["precision"][use]["params"]]
 
 
 class _CompileCounter:

@@ -18,7 +18,7 @@ import random
 import time
 from typing import Any, Dict, List
 
-from perfbench import stats
+from perfbench import manifest, stats
 from perfbench.kinds import serve_common
 
 
@@ -27,7 +27,7 @@ def plan_for(traffic: Dict[str, Any], config: Dict[str, Any], seed: int
     """One list of requests per caller."""
     rng = random.Random(seed)
     lengths = serve_common.prompt_lengths(traffic)
-    vocab = config["published"]["vocab_size"]
+    vocab = manifest.family_of(config).shapes.vocab(config)
     per, n = traffic["requests_per_client"], traffic["clients"]
     out = []
     for i in range(n):

@@ -29,12 +29,13 @@ def make_deployment(serve, spec: Dict[str, Any]):
 
         def __init__(self, spec):
             self.stamps = {"worker_ready": time.time()}
-            from perfbench import chipside
+            from perfbench import chipside, manifest
             chipside.configure_jax()
             self.spec = spec
             self.c = spec["config"]
-            self.cfg = chipside.model_config(self.c, "serve",
-                                             attention_impl="auto")
+            self.family = manifest.family_of(self.c)
+            self.cfg = self.family.model.model_config(
+                self.c, "serve", attention_impl="auto")
             self.tracer = chipside.Tracer(spec.get("trace_dir"))
             self.calls: List[tuple] = []      # (op, start, seconds)
             self.core = None
@@ -44,16 +45,17 @@ def make_deployment(serve, spec: Dict[str, Any]):
         def _load(self, seed: int) -> None:
             import jax
 
-            from perfbench import chipside, weights
+            from perfbench import weights
             from ray_tpu.serve.config import DecodeEngineConfig
             from ray_tpu.serve.decode_session import DecodeSessionCore
             if self.core is not None:          # outputs check: next seed
                 self.core.engine.shutdown()
                 self.core = self.params = None
-            c, dtype = self.c, chipside.param_dtype(self.c, "serve")
+            c, model = self.c, self.family.model
+            dtype = model.param_dtype(c, "serve")
             if not hasattr(self, "_make_w"):
                 self._make_w = jax.jit(
-                    lambda key: weights.make(key, c, dtype))
+                    lambda key: model.make(key, c, dtype))
             self.params = self._make_w(weights.key_of(seed))
             jax.block_until_ready(self.params)
             # the engine as a deployment gets it: default admission limits
@@ -175,10 +177,11 @@ def _verify(rep, prompts, streams, control: bool) -> Dict[str, float]:
                                 init_slot_cache, prefill_chunk_jit)
     from ray_tpu.models.generate import cache_insert_slot
     c, cfg, params = rep.c, rep.cfg, rep.params
+    family = rep.family
     n_new = min(len(s) for s in streams)
     streams = [list(s)[:n_new] for s in streams]
     longest = max(len(p) for p in prompts) + n_new
-    width = min(c["n_positions"], 32 * -(-longest // 32))
+    width = min(family.shapes.positions(c), 32 * -(-longest // 32))
     toks = np.zeros((len(prompts), width), np.int32)
     for i, (p, s) in enumerate(zip(prompts, streams)):
         toks[i, :len(p) + n_new] = list(p) + s
@@ -190,7 +193,7 @@ def _verify(rep, prompts, streams, control: bool) -> Dict[str, float]:
     def at_positions(precision):
         @jax.jit
         def f(params, toks, pos):
-            lg = reference.logits(params, toks, c, precision)
+            lg = family.model.logits(params, toks, c, precision)
             return jnp.take_along_axis(lg, pos[:, :, None], axis=1)
         return f(params, jnp.asarray(toks), jnp.asarray(pos))
 
@@ -311,7 +314,7 @@ def make_requests(traffic: Dict[str, Any], config: Dict[str, Any],
     order the lengths are dealt in is the mix's own where it names a
     ``schedule_seed`` (every seed then times the same sequence of sizes),
     and the seed's where it does not."""
-    from perfbench import stats
+    from perfbench import manifest, stats
     order = random.Random(traffic.get("schedule_seed", seed))
     rng = random.Random(seed)
     lengths = prompt_lengths(traffic)
@@ -319,7 +322,7 @@ def make_requests(traffic: Dict[str, Any], config: Dict[str, Any],
     p_len = (lengths * (n // len(lengths) + 1))[:n]
     order.shuffle(p_len)
     o_len = stats.sizes(traffic["output_tokens"], n, order)
-    vocab = config["published"]["vocab_size"]
+    vocab = manifest.family_of(config).shapes.vocab(config)
     return [Request(due, stats.prompt(rng, pl, vocab), ol)
             for due, pl, ol in zip(dues, p_len, o_len)]
 

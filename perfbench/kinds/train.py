@@ -23,10 +23,11 @@ def _setup(spec: Dict[str, Any]):
     import jax
     import optax
 
-    from perfbench import chipside, weights
+    from perfbench import manifest, weights
     from ray_tpu.models import init_params, make_train_step
     c, t = spec["config"], spec["traffic"]
-    cfg = chipside.model_config(c, "train", attention_impl="auto")
+    model = manifest.family_of(c).model
+    cfg = model.model_config(c, "train", attention_impl="auto")
     o = t["optimizer"]
     opt = optax.adamw(o["lr"], weight_decay=o["weight_decay"])
     mesh = None
@@ -45,8 +46,8 @@ def _setup(spec: Dict[str, Any]):
         jax.eval_shape(note, jax.random.PRNGKey(0))
         shardings = pytree_shardings(axes["axes"], mesh, FSDP_TP_RULES)
         batch_sh = batch_sharding(mesh, FSDP_TP_RULES)
-    dtype = chipside.param_dtype(c, "train")
-    make_w = jax.jit(lambda key: weights.make(key, c, dtype),
+    dtype = model.param_dtype(c, "train")
+    make_w = jax.jit(lambda key: model.make(key, c, dtype),
                      out_shardings=shardings)
     n_b, per_step, seq = (t["distinct_batches"], t["sequences_per_step"],
                           t["seq_len"])
@@ -58,8 +59,8 @@ def _setup(spec: Dict[str, Any]):
         # a jitted init reads only shapes and lands on one device
         opt_state = opt.init(params) if mesh is not None \
             else jax.jit(opt.init)(params)
-        toks = weights.tokens(jax.random.fold_in(key, 1),
-                              (n_b, per_step, seq), c)
+        toks = model.tokens(jax.random.fold_in(key, 1),
+                            (n_b, per_step, seq), c)
         batches = [{"tokens": (jax.device_put(toks[i], batch_sh)
                                if batch_sh is not None else toks[i])}
                    for i in range(n_b)]
@@ -71,7 +72,7 @@ def _setup(spec: Dict[str, Any]):
                         accum_steps=per_step // t["micro_batch"]),
         donate_argnums=(0, 1))
     return {"cfg": cfg, "mesh": mesh, "fresh": fresh, "step": step,
-            "make_w": make_w}
+            "make_w": make_w, "model": model}
 
 
 def _under(mesh):
@@ -103,20 +104,20 @@ def _compare(spec: Dict[str, Any], env: Dict[str, Any], seed: int,
 
     from perfbench import reference, weights
     from ray_tpu.models import lm_loss
-    c, t = spec["config"], spec["traffic"]
+    c, t, model = spec["config"], spec["traffic"], env["model"]
     key = weights.key_of(seed)
     params = env["make_w"](key)
-    toks = weights.tokens(jax.random.fold_in(key, 1),
-                          (t["distinct_batches"], t["sequences_per_step"],
-                           t["seq_len"]), c)[0]
+    toks = model.tokens(jax.random.fold_in(key, 1),
+                        (t["distinct_batches"], t["sequences_per_step"],
+                         t["seq_len"]), c)[0]
     n = t["check"]["sample_sequences"]
     sample = toks[:n]
-    ref = jax.jit(functools.partial(reference.loss_and_grad, c=c))
-    ref_loss = jax.jit(functools.partial(reference.loss, c=c))
+    ref = jax.jit(functools.partial(model.loss_and_grad, c=c))
+    ref_loss = jax.jit(functools.partial(model.loss, c=c))
     if control:
-        got = jax.jit(functools.partial(reference.loss_and_grad, c=c,
+        got = jax.jit(functools.partial(model.loss_and_grad, c=c,
                                         precision="fp8"))
-        got_loss = jax.jit(functools.partial(reference.loss, c=c,
+        got_loss = jax.jit(functools.partial(model.loss, c=c,
                                              precision="fp8"))
     else:
         grad = jax.jit(jax.value_and_grad(

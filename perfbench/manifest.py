@@ -3,17 +3,24 @@
 Everything that belongs to one configuration, one traffic mix or one metric
 is a file of its own, found here by the name the manifest gives it:
 
-    configs/<config>.json    the sizes as run, their source, what was changed
+    configs/<config>.json    the sizes as run, their source, what was
+                             changed, and ``family``: whose sizes they are
     traffic/<mix>.json       kind (which generator) and its parameters
     metrics/<name>.py        ``read(run) -> float | None``
     kinds/<kind>.py          ``run(ctx) -> dict`` for a traffic kind
+    families/<family>/       the one place that knows an architecture: the
+                             keys of its configuration files and what
+                             follows from them (`FAMILY_INTERFACE`)
 
-No function here, and none in the runner, branches on a cell's or a
-configuration's name.
+No function here, in the runner, in a kind or in a reader branches on a
+cell's, a configuration's or a family's name, and none but a family's own
+reads a key of a configuration file that describes the architecture.
 """
 
 from __future__ import annotations
 
+import ast
+import functools
 import importlib.util
 import json
 import os
@@ -28,8 +35,87 @@ _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 _SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 
 
+# What a family gives, every function taking the configuration file's
+# content ``c`` (a dict).  ``shapes.py`` imports no JAX: the runner, which
+# stays off JAX until the window has closed, and the metric readers load it.
+# ``model.py`` needs JAX and is loaded in the chip holder alone.
+FAMILY_INTERFACE = {
+    "shapes": (
+        "vocab",                  # (c) -> n: traffic draws token ids below n
+        "positions",              # (c) -> positions a cache row may hold
+        "count_params",           # (c) -> parameters held
+        "train_flops_per_token",  # (c, seq_len): forward and backward,
+                                  # recomputation not counted
+        "decode_step_bytes",      # (c, live_rows, bytes_per_el=2): bytes a
+                                  # decode step must read, weights and cache
+        "kernels",                # (c, batch, seq_len) -> {kernel: one
+                                  # call's operations and bytes by pass, and
+                                  # "calls": the layers that call it a step}
+    ),
+    "model": (
+        "model_config",   # (c, use, **overrides) -> the program's model
+                          # configuration, use = "train" | "serve", in the
+                          # precision the file states for it
+        "param_dtype",    # (c, use) -> the type the weights are held in
+        "make",           # (key, c, dtype) -> weights from the key, in the
+                          # layout the program takes; jitted by the caller
+        "tokens",         # (key, shape, c) -> token ids the traffic may draw
+        "logits",         # (params, tokens, c, precision="float32"|"fp8")
+        "loss",           # the same arguments -> mean next-token loss
+        "loss_and_grad",  # the same arguments -> (loss, gradient tree)
+    ),
+}
+
+
 class ManifestError(ValueError):
     pass
+
+
+class Family:
+    """``families/<name>/``, each part loaded by path when first asked for."""
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def path(self, part: str) -> str:
+        return os.path.join(BENCH_DIR, "families", self.name, part + ".py")
+
+    def _load(self, part: str):
+        path = self.path(part)
+        if not os.path.exists(path):
+            raise ManifestError(f"family {self.name!r} has no {path}")
+        return _load_by_path(f"perfbench_family_{self.name}_{part}", path)
+
+    @functools.cached_property
+    def shapes(self):
+        return self._load("shapes")
+
+    @functools.cached_property
+    def model(self):
+        return self._load("model")
+
+
+@functools.lru_cache(maxsize=None)
+def family(name: str) -> Family:
+    return Family(name)
+
+
+def family_of(config: Dict[str, Any]) -> Family:
+    """The family a configuration file's content names."""
+    if "family" not in config:
+        raise ManifestError(
+            f"configuration {config.get('name')!r} names no family")
+    return family(config["family"])
+
+
+def _load_by_path(module_name: str, path: str):
+    """Names hold dots and dashes, so a file the manifest names is loaded
+    by path and not imported by name."""
+    spec = importlib.util.spec_from_file_location(
+        re.sub(r"\W", "_", module_name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Manifest:
@@ -87,16 +173,11 @@ class Manifest:
 
 
 def metric_reader(name: str):
-    """``read`` of metrics/<name>.py.  Names hold dots, so the file is
-    loaded by path and not imported by name."""
+    """``read`` of metrics/<name>.py."""
     path = os.path.join(BENCH_DIR, "metrics", name + ".py")
     if not os.path.exists(path):
         raise ManifestError(f"metric {name!r} has no reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + re.sub(r"\W", "_", name), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_by_path("perfbench_metric_" + name, path).read
 
 
 def kind_module(kind: str):
@@ -105,6 +186,27 @@ def kind_module(kind: str):
 
 
 # ------------------------------------------------------------- validation
+
+def _family_problems(config: str, body: Dict[str, Any]) -> List[str]:
+    """A configuration file without ``family``, a family without its
+    files, a file short of a function of `FAMILY_INTERFACE`.  The files are
+    parsed, not run: this imports no JAX."""
+    if "family" not in body:
+        return [f"config {config}: its file names no family"]
+    fam, out = family(body["family"]), []
+    for part, needed in FAMILY_INTERFACE.items():
+        path = fam.path(part)
+        if not os.path.exists(path):
+            out.append(f"config {config}: family {fam.name!r} has no "
+                       f"{os.path.relpath(path, ROOT)}")
+            continue
+        with open(path) as f:
+            defined = {n.name for n in ast.parse(f.read()).body
+                       if isinstance(n, ast.FunctionDef)}
+        out += [f"config {config}: family {fam.name!r}: {part}.py lacks "
+                f"{fn}()" for fn in needed if fn not in defined]
+    return out
+
 
 def problems(m: Manifest) -> List[str]:
     """Everything about the manifest that the contract would refuse and
@@ -142,6 +244,7 @@ def problems(m: Manifest) -> List[str]:
             body = m.config(c["name"])
             if sorted(body.get("reduced", [])) != sorted(c["reduced"]):
                 out.append(f"config {c['name']}: reduced differs from file")
+            out += _family_problems(c["name"], body)
         for k in c["reduced"]:
             name_ok("reduced key", k)
     cells, pairs = {}, set()

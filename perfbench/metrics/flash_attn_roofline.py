@@ -1,8 +1,8 @@
 """flash_attn_roofline: The flash attention kernels' share of their roofline:
 the least time the chip could take for their operations and bytes
-(opsbytes.flash_attention_cost, forward and both backward kernels, all
-layers, one optimizer step, the forward counted once although remat runs it
-twice) over the kernels' summed device time per step.
+(the family's `kernels`: forward and both backward kernels, all the layers
+that call them, one optimizer step, the forward counted once although remat
+runs it twice) over the kernels' summed device time per step.
 """
 
 from perfbench import opsbytes, readers, xplane
@@ -16,9 +16,9 @@ def read(run):
     if not steps or not kernel_s:
         return None
     t = run.traffic
-    cost = opsbytes.flash_attention_cost(
-        run.config, t["sequences_per_step"], t["seq_len"])
-    peak, layers = run.peaks(), run.config["n_layer"]
+    cost = run.family.shapes.kernels(
+        run.config, t["sequences_per_step"], t["seq_len"])["flash_attention"]
+    peak, layers = run.peaks(), cost["calls"]
     least = sum(opsbytes.roofline_seconds(
         cost[p + "_flops"], cost[p + "_bytes"], peak)["seconds"]
         for p in ("fwd", "bwd")) * layers / run.device["count"]

@@ -4,7 +4,7 @@ tokens per step, over the median step time on the host clock, over chips
 times the bf16 peak.
 """
 
-from perfbench import opsbytes, readers
+from perfbench import readers
 
 
 def read(run):
@@ -12,7 +12,7 @@ def read(run):
     if step_s is None:
         return None
     m = run.raw["train"]
-    flops = opsbytes.train_flops_per_token(
+    flops = run.family.shapes.train_flops_per_token(
         run.config, run.traffic["seq_len"]) * m["tokens_per_step"]
     return 100.0 * flops / step_s / (
         run.device["count"] * run.peaks()["bf16_flops"])

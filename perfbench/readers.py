@@ -7,7 +7,7 @@ from __future__ import annotations
 import statistics
 from typing import Optional
 
-from perfbench import opsbytes, xplane
+from perfbench import xplane
 
 # program names as the trace's ``XLA Modules`` line has them
 TRAIN_STEP = r"^jit_step$"
@@ -65,4 +65,4 @@ def decode_step_bytes(run) -> Optional[float]:
     batch = counters_delta(run, "tokens") / steps
     reqs = [r for r in run.raw["requests"] if r.arrivals]
     depth = statistics.mean(len(r.prompt) + len(r.tokens) / 2 for r in reqs)
-    return opsbytes.decode_step_bytes(run.config, batch * depth)
+    return run.family.shapes.decode_step_bytes(run.config, batch * depth)

@@ -35,6 +35,7 @@ class Ctx:
                  extra=None, rehearsal=False):
         self.rehearsal = rehearsal
         self.cell, self.config, self.traffic = cell, config, traffic
+        self.family = mf.family_of(config)
         self.seed, self.seconds = seed, seconds
         self.chips = cell["chips"]
         self.extra: Dict[str, Any] = extra or {}
@@ -51,6 +52,7 @@ class Run:
                  t_init: float):
         self.ctx, self.raw = ctx, raw
         self.config, self.traffic = ctx.config, ctx.traffic
+        self.family = ctx.family
         self.seconds, self.chips = ctx.seconds, ctx.chips
         self.stamps = dict(raw["stamps"], start=t_start, init=t_init)
         self.worker = raw["worker"]

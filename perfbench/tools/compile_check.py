@@ -26,11 +26,12 @@ def main(argv) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from perfbench import chipside, manifest as mf, weights
+    from perfbench import manifest as mf
     jax.config.update("jax_enable_compilation_cache", False)
     m = mf.Manifest()
     cell = m.cell(argv[0])
     c, t = m.config(cell["config"]), m.traffic(cell["traffic"])
+    model = mf.family_of(c).model
     for kv in argv[1:]:
         k, v = kv.split("=")
         t[k] = json.loads(v)
@@ -45,10 +46,10 @@ def main(argv) -> int:
         from ray_tpu.parallel import (FSDP_TP_RULES, MeshSpec,
                                       batch_sharding, create_mesh,
                                       pytree_shardings)
-        cfg = chipside.model_config(c, "train", attention_impl="flash")
+        cfg = model.model_config(c, "train", attention_impl="flash")
         opt = optax.adamw(t["optimizer"]["lr"])
         shapes = jax.eval_shape(
-            lambda k: weights.make(k, c, jnp.float32), jax.random.PRNGKey(0))
+            lambda k: model.make(k, c, jnp.float32), jax.random.PRNGKey(0))
         mesh = None
         if t.get("mesh"):
             mesh = create_mesh(MeshSpec.parse(t["mesh"]),
@@ -90,7 +91,7 @@ def main(argv) -> int:
             compiled = step.lower(params, opt_state, batch).compile()
     else:
         from ray_tpu.models import decode_step_slots, init_slot_cache
-        cfg = chipside.model_config(c, "serve", attention_impl="flash")
+        cfg = model.model_config(c, "serve", attention_impl="flash")
         eng = t["engine"]
 
         def fused(params, tok, cache, active):
@@ -103,7 +104,7 @@ def main(argv) -> int:
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                sharding=one), tree)
         params = on_one(jax.eval_shape(
-            lambda k: weights.make(k, c, jnp.bfloat16),
+            lambda k: model.make(k, c, jnp.bfloat16),
             jax.random.PRNGKey(0)))
         cache = on_one(jax.eval_shape(functools.partial(
             init_slot_cache, cfg, eng["max_slots"], eng["max_len"])))

@@ -11,43 +11,17 @@ import sys
 import pytest
 
 from perfbench import manifest as mf
+from perfbench.tools import rehearse
 
-REHEARSAL = os.path.join("perfbench", "testdata", "rehearsal")
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
-def _rehearse(cell: str, chips: int, trace: int, seed: int):
-    arg = {"manifest": os.path.join(REHEARSAL, "BENCHMARK.json"),
-           "traffic_dir": os.path.join(REHEARSAL, "traffic"),
-           "init_kwargs": {"num_cpus": 4,
-                           "resources": {"TPU": float(chips)}}}
-    argv = ["--workload", cell, "--seed", str(seed), "--seconds", "3",
-            "--trace", str(trace)]
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}",
-               PYTHONPATH=mf.ROOT + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
-    out = subprocess.run(
-        [sys.executable, "-c",
-         "import sys; from perfbench import runner; "
-         f"sys.exit(runner.main({argv!r}, rehearsal={arg!r}))"],
-        cwd=mf.ROOT, capture_output=True, text=True, timeout=420, env=env)
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
-    assert out.returncode == 0 and lines, out.stdout + out.stderr[-3000:]
-    return lines
-
-
-@pytest.mark.parametrize("cell,chips,trace,e2e", [
-    ("tiny.train", 1, 1, None),
-    ("tiny.train", 1, 0, "train_tok_s"),
-    ("tiny.train-mesh4", 4, 0, "train_tok_s"),
-    ("tiny.serve-closed", 1, 0, "serve_tok_s"),
-    ("tiny.serve-open", 1, 0, "ttft_p95_ms"),
-    ("tiny.serve-open", 1, 1, None),
-])
-def test_cell_kind_rehearsed_on_the_cpu(cell, chips, trace, e2e):
-    lines = _rehearse(cell, chips, trace, seed=2**31 + 17 + trace)
+@pytest.mark.parametrize("cell,trace", rehearse.cases())
+def test_cell_kind_rehearsed_on_the_cpu(cell, trace):
+    m = rehearse.manifest()
+    chips = m.cell(cell)["chips"]
+    e2e = {s["name"] for s in m.metrics_for(cell, False)}
+    lines = rehearse.rehearse(cell, trace, seed=2**31 + 17 + trace)
     last = lines[-1]
     assert KEYS <= set(last)
     # a string: pytest cuts the repr of a list short, and the numbers
@@ -64,16 +38,16 @@ def test_cell_kind_rehearsed_on_the_cpu(cell, chips, trace, e2e):
         pytest.approx(phases["setup_s"])
     compared = next(ln["compared"] for ln in lines if "compared" in ln)
     assert compared and all(r["inside"] for r in compared)
-    m = last["metrics"]
+    got = last["metrics"]
     if trace:
-        assert m["compiles_in_window"]["value"] == 0
-        assert m["setup.runtime_up_s"]["value"] > 0
-        assert ("gap_p95_ms" in m) == cell.endswith("serve-open")
-        assert "setup_s" not in m and e2e is None
+        assert got["compiles_in_window"]["value"] == 0
+        assert got["setup.runtime_up_s"]["value"] > 0
+        assert ("gap_p95_ms" in got) == cell.endswith("serve-open")
+        assert not e2e & set(got)
     else:
-        assert m["setup_s"]["value"] == pytest.approx(phases["setup_s"])
-        assert m[e2e]["value"] > 0 and m[e2e]["unit"]
-        assert set(m) == {e2e, "setup_s"}
+        assert got["setup_s"]["value"] == pytest.approx(phases["setup_s"])
+        assert set(got) == e2e and len(e2e) == 2
+        assert all(v["value"] > 0 and v["unit"] for v in got.values())
 
 
 def test_command_line_needs_a_tpu():

@@ -5,13 +5,12 @@ rehearsed cell on the CPU."""
 import copy
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 from perfbench import manifest as mf
 from perfbench import spans
+from perfbench.tools import rehearse
 
 REHEARSAL = os.path.join("perfbench", "testdata", "rehearsal")
 MS = 1e-3
@@ -167,28 +166,6 @@ def _manifest_with_the_new_metrics(tmp_path) -> str:
     return str(path)
 
 
-def _rehearse(manifest: str, cell: str, seed: int):
-    """One traced run of a tiny cell on the CPU, entered as
-    test_perfbench_rehearsal.py enters it, under ``manifest``."""
-    arg = {"manifest": manifest,
-           "traffic_dir": os.path.join(REHEARSAL, "traffic"),
-           "init_kwargs": {"num_cpus": 4, "resources": {"TPU": 1.0}}}
-    argv = ["--workload", cell, "--seed", str(seed), "--seconds", "3",
-            "--trace", "1"]
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PYTHONPATH=mf.ROOT + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
-    out = subprocess.run(
-        [sys.executable, "-c",
-         "import sys; from perfbench import runner; "
-         f"sys.exit(runner.main({argv!r}, rehearsal={arg!r}))"],
-        cwd=mf.ROOT, capture_output=True, text=True, timeout=420, env=env)
-    lines = [json.loads(ln) for ln in out.stdout.splitlines()
-             if ln.startswith("{")]
-    assert out.returncode == 0 and lines, out.stdout + out.stderr[-3000:]
-    return lines
-
-
 @pytest.mark.parametrize("index,must,must_not", [
     (1, ("proxy.route_ms.batch", "replica.queue_ms.batch",
          "proxy.reply_ms.batch", "engine.host_ms_per_step.batch",
@@ -205,7 +182,8 @@ def test_new_readers_in_a_rehearsed_served_cell(tmp_path, index, must,
     here, so `setup.chip_open_s` is left out too."""
     manifest = _manifest_with_the_new_metrics(tmp_path)
     cell = json.load(open(manifest))["workloads"][index]["name"]
-    lines = _rehearse(manifest, cell, seed=2**31 + 99 + index)
+    lines = rehearse.rehearse(cell, 1, seed=2**31 + 99 + index,
+                              manifest_path=manifest)
     m = lines[-1]["metrics"]
     assert lines[-1]["correct"] is True and lines[-1]["failed"] == 0
     for name in must:

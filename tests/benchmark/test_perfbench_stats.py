@@ -8,6 +8,7 @@ import pytest
 
 from perfbench import stats
 from perfbench.kinds import serve_closed, serve_common, serve_open
+from perfbench.tools import rehearse
 
 
 @pytest.mark.parametrize("q", [0, 5, 50, 95, 99, 100])
@@ -67,7 +68,7 @@ def test_requests_of_a_mix_same_work_for_every_seed():
     traffic = {"prompt_tokens": {"dist": "uniform", "low": 8, "high": 40},
                "output_tokens": {"dist": "uniform", "low": 4, "high": 12},
                "distinct_prompt_lengths": 5}
-    config = {"published": {"vocab_size": 250}}
+    config = rehearse.manifest().config("tiny")
     a = serve_open.schedule(traffic, config, 3, 10.0, 4.0)
     b = serve_open.schedule(traffic, config, 2**31 + 3, 10.0, 4.0)
     assert len(a) == len(b) == 40
@@ -88,7 +89,7 @@ def test_a_mix_with_a_schedule_seed_times_one_sequence_for_every_seed():
     traffic = {"prompt_tokens": {"dist": "uniform", "low": 8, "high": 40},
                "output_tokens": {"dist": "uniform", "low": 4, "high": 12},
                "distinct_prompt_lengths": 5, "schedule_seed": 24}
-    config = {"published": {"vocab_size": 250}}
+    config = rehearse.manifest().config("tiny")
     a = serve_open.schedule(traffic, config, 3, 10.0, 4.0)
     b = serve_open.schedule(traffic, config, 2**31 + 3, 10.0, 4.0)
     assert [(r.due, len(r.prompt), r.n_out) for r in a] == [
@@ -104,7 +105,7 @@ def test_closed_loop_every_caller_cycles_its_own_lengths():
                "prompt_tokens": {"dist": "uniform", "low": 8, "high": 40},
                "output_tokens": {"dist": "fixed", "value": 8},
                "distinct_prompt_lengths": 12}
-    config = {"published": {"vocab_size": 250}}
+    config = rehearse.manifest().config("tiny")
     a = serve_closed.plan_for(traffic, config, 5)
     b = serve_closed.plan_for(traffic, config, 2**31 + 5)
     lengths = serve_common.prompt_lengths(traffic)
