@@ -9,8 +9,17 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
-The cache is stored ``[layers, batch, kv_heads, head_dim, max_len]`` —
-positions LAST — and every program that takes one extends it IN PLACE:
+What a cache holds is a property of the model's attention kind
+(`cache_rows`): keys and values of ``kv_heads x head_dim`` (``"k"``,
+``"v"``) for MHA/GQA, ONE array of compressed latents (``"kv"``: the
+normed key-value latent beside the rotated shared key, ``kv_lora_rank +
+qk_rope_head_dim`` values a position a layer) for latent attention.
+Beside its arrays a cache carries ``pos``.  Every function here, the
+serve engine and the prefix reuse work on whatever arrays a cache has
+(`cache_arrays`); none names one.
+
+Each array is stored ``[layers, batch, heads, width, max_len]`` —
+positions LAST — and every program that takes a cache extends it IN PLACE:
 the whole stacked cache is state of the one layer loop
 (:func:`_scan_cached`), written by ``dynamic_update_slice`` and held to
 its row-major layout, so a caller that donates its cache pays no copy
@@ -19,6 +28,13 @@ array anyway: with ``head_dim`` 64 minor, 64 of 128 lanes (and 25 heads
 of 32 sublanes) would be padding, so the device keeps ``max_len`` minor
 whatever the logical order says, and a loop that wants another order
 converts the whole cache on the way in and on the way out.
+
+The cached programs of a latent-attention model attend in the ABSORBED
+form (`ops/latent_attention.py`): the few queries of a chunk or a step
+meet the cached latents directly.  A model with no-drop routed experts
+returns, beside its logits, what its expert layers routed (the ``load``
+of `_prefill_chunk` and `_decode_step_slots`); the serve engine counts
+the decode steps'.
 
 Reference: Ray has no model runtime of its own (serving delegates to the
 wrapped framework); this module is the TPU-native equivalent of what its
@@ -35,65 +51,96 @@ import jax.numpy as jnp
 
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ..ops import latent_attention as mla
 from ..ops.rotary import apply_rotary, rotary_angles
-from .transformer import TransformerConfig, _ffn, _layer, _norm, _unembed
+from .transformer import (TransformerConfig, _ffn, _layer, _norm, _unembed,
+                          norm_eps, scan_layer_runs)
 
 Params = Any
-KVCache = Dict[str, jnp.ndarray]   # {"k","v": [L, B, hk, hd, max_len], "pos"}
+# {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
+KVCache = Dict[str, jnp.ndarray]
+Arrays = Dict[str, jnp.ndarray]     # a cache without its "pos"
 
 
-def _cache_shape(cfg: TransformerConfig, batch: int,
-                 max_len: int) -> Tuple[int, ...]:
-    return (cfg.n_layers, batch, cfg.kv_heads, cfg.head_dim, max_len)
+def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
+    """What a cache of this model holds a position a layer: (heads,
+    width) of each of its arrays."""
+    if cfg.attention == "mla":
+        return {"kv": (1, cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
+    return {"k": (cfg.kv_heads, cfg.head_dim),
+            "v": (cfg.kv_heads, cfg.head_dim)}
+
+
+def cache_arrays(cache: KVCache) -> Arrays:
+    """The cache's arrays, whatever the attention kind named them."""
+    return {name: a for name, a in cache.items() if name != "pos"}
 
 
 def cache_capacity(cache: KVCache) -> int:
     """``max_len``: the positions a cache holds per row."""
-    return cache["k"].shape[-1]
+    return next(iter(cache_arrays(cache).values())).shape[-1]
+
+
+def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
+                pos: jnp.ndarray) -> KVCache:
+    cache = {name: jnp.zeros((cfg.n_layers, batch, heads, width, max_len),
+                             cfg.dtype)
+             for name, (heads, width) in cache_rows(cfg).items()}
+    cache["pos"] = pos
+    return cache
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int,
                   max_len: int) -> KVCache:
-    shape = _cache_shape(cfg, batch, max_len)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((), jnp.int32)}
+    return _init_cache(cfg, batch, max_len, jnp.zeros((), jnp.int32))
 
 
 def _check_decodable(cfg: TransformerConfig) -> None:
+    """A cache is one stacked array over ALL layers of the pattern, walked
+    by one layer counter: what cannot be served is a model cut into
+    pipeline stages, each of which would own a slab of it."""
     if cfg.pp_stages > 1:
         raise NotImplementedError(
             "KV-cache decode over a pipeline mesh is not supported; "
             "serve pp-sharded models stage-per-gang instead")
+    if cfg.attention == "mla" and cfg.pos_emb != "rope":
+        raise NotImplementedError(
+            "latent attention keeps a rotary key beside its latent: "
+            "pos_emb must be 'rope'")
 
 
-def _scan_cached(layers, x: jnp.ndarray, cache: KVCache, layer_fn
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
+                 cache: KVCache, layer_fn):
     """THE layer loop of every program that writes a KV cache.
 
-    The whole stacked cache ``[L, B, hk, hd, max_len]`` is loop STATE,
-    indexed by the layer counter, and the layer weights the scanned
-    input: ``layer_fn(x, lp, k_all, v_all, l) -> (x, k_all, v_all)``
-    writes its columns into ``k_all[l]`` / ``v_all[l]`` in place.  Passing
+    The whole stacked cache (each array ``[L, B, heads, width, max_len]``)
+    is loop STATE, indexed by the layer counter, and the layer weights the
+    scanned input: ``layer_fn(x, lp, arrays, l) -> (x, arrays, load)``
+    writes its columns into ``arrays[name][l]`` in place.  Passing
     the cache's layers through the scan as inputs and stacking them as
     outputs instead builds a second cache per call, and leaves a donated
     cache argument nothing to alias to.  The carry is held to the
     row-major layout the cache arrives in: left to itself the compiler
     gives loop state the layout its rows are produced in (``head_dim``
-    minor) and converts the whole cache before and after the loop.
-    → (x, k_all, v_all)."""
-    row_major = Layout(major_to_minor=tuple(range(cache["k"].ndim)))
+    minor) and converts the whole cache before and after the loop.  The
+    loop itself is the model's declared pattern (`scan_layer_runs`): the
+    counter runs on from one run of layers into the next.
+    → (x, arrays, load summed over layers)."""
+    arrays = cache_arrays(cache)
+    row_major = Layout(major_to_minor=tuple(range(5)))
 
     def step(carry, lp):
-        xc, k_all, v_all, l = carry
-        k_all = with_layout_constraint(k_all, row_major)
-        v_all = with_layout_constraint(v_all, row_major)
-        xc, k_all, v_all = layer_fn(xc, lp, k_all, v_all, l)
-        return (xc, k_all, v_all, l + 1), None
+        xc, arrs, l, load = carry
+        arrs = {n: with_layout_constraint(a, row_major)
+                for n, a in arrs.items()}
+        xc, arrs, load_l = layer_fn(xc, lp, arrs, l)
+        return xc, arrs, l + 1, tuple(a + b for a, b in zip(load, load_l))
 
-    (x, k_all, v_all, _), _ = jax.lax.scan(
-        step, (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)), layers)
-    return x, k_all, v_all
+    zero = jnp.zeros((), jnp.int32)
+    x, arrays, _, load = scan_layer_runs(
+        cfg, params, (x, arrays, zero, (zero, zero)), step,
+        whole_expert_stacks=True)
+    return x, arrays, load
 
 
 def _as_columns(rows: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -101,29 +148,34 @@ def _as_columns(rows: jnp.ndarray, dtype) -> jnp.ndarray:
     return jnp.transpose(rows, (0, 2, 3, 1)).astype(dtype)
 
 
+def _layer_of(c_all: jnp.ndarray, l) -> jnp.ndarray:
+    return jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
+
+
 def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
-                   cache: KVCache, *, rotate, write, mask
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                   cache: KVCache, *, rotate, write, mask, valid=None):
     """Run ``x`` [B, C, D] (C new tokens per row) through every layer
-    against the cache: each layer writes the new tokens' K/V columns
-    (``write(c_all, l, cols [B, hk, hd, C]) -> c_all``), then attends
-    dense over layer ``l`` of the cache under ``mask`` [B|1, C, max_len].
-    ``rotate`` applies the caller's rotary angles (rope only)."""
+    against the cache: each layer writes the new tokens' columns
+    (``write(c_all, l, cols [B, heads, width, C]) -> c_all``) into each of
+    the cache's arrays, then attends dense over layer ``l`` of the cache
+    under ``mask`` [B|1, C, max_len].  ``rotate`` applies the caller's
+    rotary angles (rope only); ``valid`` [B, C] marks the rows a no-drop
+    expert layer routes (None: all).
+    → (final-norm activations, arrays, load)."""
     dt = cfg.dtype
     b, c, _ = x.shape
     h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    eps = norm_eps(cfg)
 
-    def layer(xc, lp, k_all, v_all, l):
-        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
+    def attend_mha(y, lp, arrs, l):
         q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
         k_new = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
         v_new = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
         if cfg.pos_emb == "rope":
             q, k_new = rotate(q), rotate(k_new)
-        k_all = write(k_all, l, _as_columns(k_new, k_all.dtype))
-        v_all = write(v_all, l, _as_columns(v_new, v_all.dtype))
-        ck = jax.lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
+        k_all = write(arrs["k"], l, _as_columns(k_new, arrs["k"].dtype))
+        v_all = write(arrs["v"], l, _as_columns(v_new, arrs["v"].dtype))
+        ck, cv = _layer_of(k_all, l), _layer_of(v_all, l)
         # GQA: group query heads over kv heads
         qh = q.reshape(b, c, hk, h // hk, hd)
         scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
@@ -133,14 +185,35 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
                           cv.astype(dt))
         attn = attn.reshape(b, c, h, hd)
-        xc = xc + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
-        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
-        z, _ = _ffn(cfg, y2, lp)
-        return xc + z, k_all, v_all
+        return (jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt)),
+                {"k": k_all, "v": v_all})
 
-    x, k_all, v_all = _scan_cached(params["layers"], x, cache, layer)
+    def attend_mla(y, lp, arrs, l):
+        # absorbed: the chunk's few queries over the cached latents
+        q_nope, q_rope = mla.queries(
+            y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
+            nope=cfg.qk_nope_head_dim, eps=eps, rotate=rotate)
+        new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
+                          kv_lora=cfg.kv_lora_rank, eps=eps, rotate=rotate)
+        kv_all = write(arrs["kv"], l,
+                       _as_columns(new[:, :, None, :], arrs["kv"].dtype))
+        out = mla.attend_absorbed(q_nope, q_rope, _layer_of(kv_all, l)[:, 0],
+                                  lp["wkv_b"], lp["wo"], mask)
+        return out, {"kv": kv_all}
+
+    attend = attend_mla if cfg.attention == "mla" else attend_mha
+
+    def layer(xc, lp, arrs, l):
+        y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
+        delta, arrs = attend(y, lp, arrs, l)
+        xc = xc + delta
+        y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
+        z, _, load = _ffn(cfg, y2, lp, valid)
+        return xc + z, arrs, load
+
+    x, arrays, load = _scan_cached(cfg, params, x, cache, layer)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
-    return x, k_all, v_all
+    return x, arrays, load
 
 
 def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
@@ -156,29 +229,38 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     x = params["embed"]["tok"][tokens].astype(dt)
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][:s].astype(dt)
-    cos, sin = (rotary_angles(s, cfg.head_dim, cfg.rope_base)
+    cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
+    rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
 
-    def layer(h, lp, k_all, v_all, l):
-        # K/V for the cache come from the same pre-norm projection the
-        # layer itself computes; run the layer for h, re-project for kv
-        y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
+    def columns(y, lp):
+        """What the cache holds of these tokens, from the same pre-norm
+        projection the layer itself computes."""
+        if cfg.attention == "mla":
+            new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
+                              kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
+                              rotate=rotate)
+            return {"kv": new[:, :, None, :]}
         k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
         v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
         if cfg.pos_emb == "rope":
-            k = apply_rotary(k, cos, sin)
-        k_all = jax.lax.dynamic_update_slice(
-            k_all, _as_columns(k, k_all.dtype)[None], (l, 0, 0, 0, 0))
-        v_all = jax.lax.dynamic_update_slice(
-            v_all, _as_columns(v, v_all.dtype)[None], (l, 0, 0, 0, 0))
-        h, _ = _layer(cfg, h, lp, cos, sin)
-        return h, k_all, v_all
+            k = rotate(k)
+        return {"k": k, "v": v}
 
-    x, k_all, v_all = _scan_cached(params["layers"], x, cache, layer)
+    def layer(h, lp, arrs, l):
+        # run the layer for h, re-project for the cache
+        y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
+        arrs = {n: jax.lax.dynamic_update_slice(
+            arrs[n], _as_columns(rows, arrs[n].dtype)[None], (l, 0, 0, 0, 0))
+            for n, rows in columns(y, lp).items()}
+        h, _ = _layer(cfg, h, lp, cos, sin)
+        return h, arrs, (0, 0)
+
+    x, arrays, _ = _scan_cached(cfg, params, x, cache, layer)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed(params, cfg))
-    return logits.astype(jnp.float32), {
-        "k": k_all, "v": v_all, "pos": jnp.asarray(s, jnp.int32)}
+    return logits.astype(jnp.float32), dict(
+        arrays, pos=jnp.asarray(s, jnp.int32))
 
 
 def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
@@ -194,6 +276,14 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     positions.  Chunk attention runs dense against the cache's max_len
     (O(C·max_len) per chunk) — more FLOPs than causal flash, traded for
     a bounded, cacheable compile."""
+    logits, cache, _ = _prefill_chunk(params, tokens, cache, cfg)
+    return logits, cache
+
+
+def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
+                   cfg: TransformerConfig):
+    """:func:`prefill_chunk` → (logits, cache', load): beside them what
+    the chunk's expert layers routed (zeros for a model without)."""
     _check_decodable(cfg)
     b, c = tokens.shape
     dt = cfg.dtype
@@ -204,7 +294,7 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
         x = x + jax.lax.dynamic_slice_in_dim(
             params["embed"]["pos"], pos, c, axis=0).astype(dt)
     if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.head_dim,
+        full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
                                            cfg.rope_base)
         cos = jax.lax.dynamic_slice_in_dim(full_cos, pos, c, axis=0)
         sin = jax.lax.dynamic_slice_in_dim(full_sin, pos, c, axis=0)
@@ -213,15 +303,14 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     # mask[i, t]: cached position t visible to chunk token i (causal
     # within the chunk, everything before it fully visible)
     mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
-    x, k_all, v_all = _attend_cached(
+    x, arrays, load = _attend_cached(
         cfg, params, x, cache,
         rotate=lambda t: apply_rotary(t, cos, sin),
         write=lambda c_all, l, cols: jax.lax.dynamic_update_slice(
             c_all, cols[None], (l, 0, 0, 0, pos)),
         mask=mask[None])
     logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed(params, cfg))
-    return logits.astype(jnp.float32), {"k": k_all, "v": v_all,
-                                        "pos": pos + c}
+    return logits.astype(jnp.float32), dict(arrays, pos=pos + c), load
 
 
 # Module-level jit: every prefill_chunked caller shares one trace/compile
@@ -306,10 +395,7 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
     independent sessions share one batched program, so ``pos`` is a
     per-slot vector instead of the single scalar of
     :func:`init_kv_cache`."""
-    shape = _cache_shape(cfg, slots, max_len)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((slots,), jnp.int32)}
+    return _init_cache(cfg, slots, max_len, jnp.zeros((slots,), jnp.int32))
 
 
 def cache_insert_slot(slot_cache: KVCache, cache: KVCache,
@@ -318,17 +404,13 @@ def cache_insert_slot(slot_cache: KVCache, cache: KVCache,
     ``slot`` of a slot-batched cache.  ``slot`` is a TRACED index —
     one jitted program serves every slot, so session admission never
     recompiles."""
-    return {
-        "k": jax.lax.dynamic_update_slice(
-            slot_cache["k"], cache["k"].astype(slot_cache["k"].dtype),
-            (0, slot, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            slot_cache["v"], cache["v"].astype(slot_cache["v"].dtype),
-            (0, slot, 0, 0, 0)),
-        "pos": jax.lax.dynamic_update_slice(
-            slot_cache["pos"],
-            jnp.reshape(cache["pos"], (1,)).astype(jnp.int32), (slot,)),
-    }
+    out = {name: jax.lax.dynamic_update_slice(
+        a, cache[name].astype(a.dtype), (0, slot, 0, 0, 0))
+        for name, a in cache_arrays(slot_cache).items()}
+    out["pos"] = jax.lax.dynamic_update_slice(
+        slot_cache["pos"],
+        jnp.reshape(cache["pos"], (1,)).astype(jnp.int32), (slot,))
+    return out
 
 
 def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
@@ -339,7 +421,8 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
 
     A new session whose prompt shares ``upto`` tokens with a live
     slot's prompt seeds its prefill cache from this copy and chunk-
-    prefills only the unshared suffix.  The K/V rows at positions >=
+    prefills only the unshared suffix.  It copies whatever arrays the
+    cache has (keys and values, or latents).  The rows at positions >=
     ``upto`` still hold the donor's LATER tokens, but they sit past the
     returned ``pos`` and every prefill/decode program masks reads to
     positions <= pos — the same stale-rows-are-invisible invariant
@@ -347,10 +430,11 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
     suffix prefill overwrites them before ``pos`` ever reaches them.
     ``slot`` and ``upto`` are TRACED, so one compiled program serves
     every (donor slot, prefix length) pair."""
-    one_slot = (slot_cache["k"].shape[0], 1) + slot_cache["k"].shape[2:]
-    k = jax.lax.dynamic_slice(slot_cache["k"], (0, slot, 0, 0, 0), one_slot)
-    v = jax.lax.dynamic_slice(slot_cache["v"], (0, slot, 0, 0, 0), one_slot)
-    return {"k": k, "v": v, "pos": jnp.asarray(upto, jnp.int32)}
+    out = {name: jax.lax.dynamic_slice(
+        a, (0, slot, 0, 0, 0), (a.shape[0], 1) + a.shape[2:])
+        for name, a in cache_arrays(slot_cache).items()}
+    out["pos"] = jnp.asarray(upto, jnp.int32)
+    return out
 
 
 def _rotate_slots(x: jnp.ndarray, cos: jnp.ndarray,
@@ -368,12 +452,14 @@ def _rotate_slots(x: jnp.ndarray, cos: jnp.ndarray,
 
 
 def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                   cfg: TransformerConfig
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                   cfg: TransformerConfig,
+                   active: Optional[jnp.ndarray] = None):
     """``tokens`` [S, C]: C tokens per slot, fed at each slot's OWN
     ``pos`` .. ``pos + C - 1`` → (final-norm activations [S, C, D],
-    k_all, v_all).  Slots sit at DIFFERENT positions, so each fed
-    token's K/V column is written by its own ``dynamic_update_slice``
+    arrays, load); ``active`` [S] marks the slots whose tokens a no-drop
+    expert layer routes (the others still compute: the batch shape is
+    fixed).  Slots sit at DIFFERENT positions, so each fed
+    token's column is written by its own ``dynamic_update_slice``
     (a scatter is not updated in place under the cache's layout).  A
     slice whose start lies past the end is clamped onto the last
     column, where a scatter would have dropped it: a slot's columns are
@@ -392,14 +478,14 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][posm].astype(dt)
     if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.head_dim,
+        full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
                                            cfg.rope_base)
         cos = full_cos[posm][:, :, None, :]                    # [S,C,1,·]
         sin = full_sin[posm][:, :, None, :]
     else:
         cos = sin = None
 
-    def write(c_all, l, cols):                                 # [S,hk,hd,C]
+    def write(c_all, l, cols):                    # [S, heads, width, C]
         for slot in range(s):
             for i in reversed(range(c)):
                 c_all = jax.lax.dynamic_update_slice(
@@ -409,10 +495,12 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
 
     # mask[s, i, t]: cached position t visible to fed token i of slot s
     mask = jnp.arange(max_len)[None, None, :] <= posm[:, :, None]
+    valid = None if active is None else \
+        jnp.broadcast_to(active[:, None], (s, c))
     return _attend_cached(
         cfg, params, x, cache,
         rotate=lambda t: _rotate_slots(t, cos, sin),
-        write=write, mask=mask)
+        write=write, mask=mask, valid=valid)
 
 
 def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
@@ -429,11 +517,20 @@ def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
     is overwritten by the next active step before any read, and their
     logits are discarded by the engine.
     """
-    x, k_all, v_all = _forward_slots(params, token[:, None], cache, cfg)
+    logits, cache, _ = _decode_step_slots(params, token, cache, active, cfg)
+    return logits, cache
+
+
+def _decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
+                       active: jnp.ndarray, cfg: TransformerConfig):
+    """:func:`decode_step_slots` → (logits, cache', load): beside them
+    what the ACTIVE slots' tokens were routed to, summed over the expert
+    layers (the serve engine's fused step reads it with the tokens)."""
+    x, arrays, load = _forward_slots(params, token[:, None], cache, cfg,
+                                     active)
     logits = jnp.einsum("bd,dv->bv", x[:, 0], _unembed(params, cfg))
-    return logits.astype(jnp.float32), {
-        "k": k_all, "v": v_all,
-        "pos": cache["pos"] + active.astype(jnp.int32)}
+    return logits.astype(jnp.float32), dict(
+        arrays, pos=cache["pos"] + active.astype(jnp.int32)), load
 
 
 def draft_propose_slots(params: Params, token: jnp.ndarray,
@@ -497,7 +594,7 @@ def verify_step_slots(params: Params, tokens: jnp.ndarray,
     clamped so emission never outruns the cache."""
     pos = cache["pos"]                                         # [S]
     max_len = cache_capacity(cache)
-    x, k_all, v_all = _forward_slots(params, tokens, cache, cfg)
+    x, arrays, _ = _forward_slots(params, tokens, cache, cfg, active)
     logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg))
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # [S, C]
     ok = (greedy[:, :-1] == proposals).astype(jnp.int32)
@@ -505,7 +602,7 @@ def verify_step_slots(params: Params, tokens: jnp.ndarray,
     accepted = jnp.minimum(accepted,
                            jnp.maximum(max_len - pos, 1)).astype(jnp.int32)
     adv = jnp.where(active, accepted, 0).astype(jnp.int32)
-    return greedy, accepted, {"k": k_all, "v": v_all, "pos": pos + adv}
+    return greedy, accepted, dict(arrays, pos=pos + adv)
 
 
 def _sample(logits: jnp.ndarray, key: jax.Array, greedy: bool,

@@ -1,4 +1,5 @@
-"""Decoder-only transformer (GPT-2 and Llama families), TPU-first.
+"""Decoder-only transformer (GPT-2, Llama and latent-attention
+mixture-of-experts families), TPU-first.
 
 Design (idiomatic JAX, not a torch translation):
 
@@ -13,6 +14,20 @@ Design (idiomatic JAX, not a torch translation):
     config switch.
   * compute dtype bf16, params and softmax/norm statistics fp32 — the MXU
     recipe.
+  * a model DECLARES its layer pattern (`layer_runs`): consecutive runs of
+    identical layers, each run one stacked tree and one scan.  Every layer
+    loop (`_trunk` here, `generate._scan_cached`) goes through
+    `scan_layer_runs`.  One run is ``params["layers"]``; leading dense
+    layers before expert layers are a run of their own,
+    ``params["dense_layers"]``.
+  * the attention kind is a property of the configuration: ``"mha"``
+    (MHA/GQA, keys and values of ``kv_heads x head_dim``) or ``"mla"``
+    (latent attention: low-rank queries, ONE compressed key-value latent
+    and one rotary key shared by all heads; `ops/latent_attention.py`).
+  * the router kind decides the expert layer: ``"softmax"`` is the
+    capacity einsum of `ops/moe.py` that trains over an ``ep`` mesh (and
+    drops over capacity), ``"sigmoid"`` the bias-corrected choice with
+    shared experts that drops nothing (`ops/moe.routed_ffn`).
 
 Configs: ``TransformerConfig.gpt2()`` (learned positions, GELU, LayerNorm)
 and ``TransformerConfig.llama()`` (RoPE, SwiGLU, RMSNorm, GQA).
@@ -28,6 +43,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import latent_attention as mla
 from ..ops.attention import multi_head_attention
 from ..ops.norms import layernorm, rmsnorm
 from ..ops.rotary import apply_rotary, rotary_angles
@@ -76,6 +92,24 @@ class TransformerConfig:
     expert_top_k: int = 2
     capacity_factor: float = 2.0
     router_aux_weight: float = 0.01   # Switch load-balancing loss weight
+    router: str = "softmax"           # "softmax": capacity einsum, aux
+    #   loss, drops over capacity (trains over the ep mesh) | "sigmoid":
+    #   choice by sigmoid score + correction bias, weights the chosen
+    #   scores normalised, no token dropped (ops/moe.routed_ffn)
+    moe_d_ff: Optional[int] = None    # a routed expert's width (None → ff_dim)
+    n_shared_experts: int = 0         # always-on experts of width moe_d_ff
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0       # leading layers with a dense FFN of
+    #   width ff_dim before the expert layers (only with n_experts > 0)
+    # -- latent attention (ops/latent_attention.py) -------------------------
+    attention: str = "mha"            # "mha" | "mla"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    norm_eps: Optional[float] = None  # None → the norm's own default
+    #   (rmsnorm 1e-6, layernorm 1e-5)
 
     @property
     def head_dim(self) -> int:
@@ -84,6 +118,26 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of what the rotary angles turn: a whole head, or the
+        rotary part of a latent-attention head."""
+        return self.qk_rope_head_dim if self.attention == "mla" \
+            else self.head_dim
+
+    @property
+    def expert_ff_dim(self) -> int:
+        return self.moe_d_ff or self.ff_dim
+
+    @property
+    def layer_runs(self) -> Tuple[Tuple[str, int], ...]:
+        """The declared layer pattern: (key of the run's stacked tree in
+        ``params``, layers in the run), in model order."""
+        lead = self.first_dense_layers if self.n_experts else 0
+        if lead:
+            return (("dense_layers", lead), ("layers", self.n_layers - lead))
+        return (("layers", self.n_layers),)
 
     @property
     def ff_dim(self) -> int:
@@ -131,45 +185,74 @@ class TransformerConfig:
         return TransformerConfig(**defaults)
 
 
-def _per_layer_matmul_params(cfg: TransformerConfig, active: bool) -> int:
-    """Matmul parameters per layer; for MoE, ``active`` counts only the
-    top-k experts a token actually visits (the FLOP count), while
-    ``active=False`` counts every expert (the memory count)."""
-    d, ff, hd = cfg.d_model, cfg.ff_dim, cfg.head_dim
-    attn = d * cfg.n_heads * hd + 2 * d * cfg.kv_heads * hd \
-        + cfg.n_heads * hd * d
-    base_mlp = d * ff * (3 if cfg.activation == "swiglu" else 2)
-    if cfg.n_experts:
-        mult = cfg.expert_top_k if active else cfg.n_experts
-        mlp = mult * base_mlp + d * cfg.n_experts  # + router
+def _attn_matmul_params(cfg: TransformerConfig) -> int:
+    d, h = cfg.d_model, cfg.n_heads
+    if cfg.attention == "mla":
+        nope, rope, v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim)
+        return (d * cfg.q_lora_rank + cfg.q_lora_rank * h * (nope + rope)
+                + d * (cfg.kv_lora_rank + rope)
+                + cfg.kv_lora_rank * h * (nope + v) + h * v * d)
+    hd = cfg.head_dim
+    return d * h * hd + 2 * d * cfg.kv_heads * hd + h * hd * d
+
+
+def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
+    """Matmul parameters of ONE layer of the run ``run`` of `layer_runs`;
+    for expert layers ``active`` counts only the top-k routed experts a
+    token visits (the FLOP count) beside the shared ones, ``active=False``
+    every expert held (the memory count)."""
+    d = cfg.d_model
+    per = 3 if cfg.activation == "swiglu" else 2
+    if cfg.n_experts and run == "layers":
+        routed = cfg.expert_top_k if active else cfg.n_experts
+        mlp = (routed + cfg.n_shared_experts) * d * cfg.expert_ff_dim * per \
+            + d * cfg.n_experts                                  # + router
     else:
-        mlp = base_mlp
-    return attn + mlp
+        mlp = d * cfg.ff_dim * per
+    return _attn_matmul_params(cfg) + mlp
+
+
+def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
+    """Matmul parameters of all layers, over the declared pattern."""
+    return sum(n * _run_matmul_params(cfg, run, active)
+               for run, n in cfg.layer_runs)
+
+
+def _attn_flops_dim(cfg: TransformerConfig) -> int:
+    """Width, summed over heads, of one query-key product plus one
+    probability-value product, halved: what `flops_per_token` multiplies
+    by positions (``n_heads * head_dim`` where both are one size)."""
+    if cfg.attention == "mla":
+        return cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                              + cfg.v_head_dim) // 2
+    return cfg.n_heads * cfg.head_dim
 
 
 def count_params(cfg: TransformerConfig) -> int:
     d = cfg.d_model
     norms = 2 * d * (2 if cfg.norm == "layernorm" else 1)
-    per_layer = _per_layer_matmul_params(cfg, active=False) + norms
+    if cfg.attention == "mla":
+        norms += cfg.q_lora_rank + cfg.kv_lora_rank
+    layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms
+    if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
+        layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
     emb = cfg.vocab_size * d
     if cfg.pos_emb == "learned":
         emb += cfg.max_seq_len * d
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * d
     final = d * (2 if cfg.norm == "layernorm" else 1)
-    return cfg.n_layers * per_layer + emb + head + final
+    return layers + emb + head + final
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     """Training FLOPs/token: 6*N_active_matmul + causal attention term."""
-    d = cfg.d_model
-    unembed = cfg.vocab_size * d  # tied or not, the logits matmul runs
-    n_matmul = cfg.n_layers * _per_layer_matmul_params(cfg, active=True) \
-        + unembed
+    unembed = cfg.vocab_size * cfg.d_model  # tied or not, the logits matmul runs
+    n_matmul = _matmul_params(cfg, active=True) + unembed
     # qk+pv over the visible window: half the positions when causal,
     # all of them for bidirectional encoders (causal=False)
     attn_factor = 6 if cfg.causal else 12
-    attn = attn_factor * cfg.n_layers * cfg.n_heads * cfg.head_dim \
-        * seq_len
+    attn = attn_factor * cfg.n_layers * _attn_flops_dim(cfg) * seq_len
     return 6 * n_matmul + attn
 
 
@@ -180,10 +263,16 @@ def decode_flops_per_token(cfg: TransformerConfig,
     only — no backward factor) plus the attention reads against the KV
     cache (qk^T and probs·v, 2 FLOPs per MAC each, over every cached
     position)."""
-    n_matmul = cfg.n_layers * _per_layer_matmul_params(cfg, active=True) \
+    n_matmul = _matmul_params(cfg, active=True) \
         + cfg.vocab_size * cfg.d_model   # unembed logits matmul
-    attn = 4 * cfg.n_layers * cfg.n_heads * cfg.head_dim * context_len
-    return 2 * n_matmul + attn
+    if cfg.attention == "mla":
+        # absorbed: every head's query meets the cached latent row (and
+        # its rotary key), and the probabilities the latent again
+        per_pos = cfg.n_heads * (2 * cfg.kv_lora_rank
+                                 + cfg.qk_rope_head_dim)
+    else:
+        per_pos = 2 * cfg.n_heads * cfg.head_dim
+    return 2 * n_matmul + 2 * cfg.n_layers * per_pos * context_len
 
 
 def engine_flops_table(cfg: TransformerConfig, max_len: int,
@@ -214,79 +303,104 @@ def engine_flops_table(cfg: TransformerConfig, max_len: int,
 # init
 # ---------------------------------------------------------------------------
 
+def _init_run(keys, cfg: TransformerConfig, run: str, L: int
+              ) -> Tuple[Params, Params]:
+    """One run of `layer_runs`: ``L`` identical layers as one stacked tree
+    (leading "layers" axis) and its logical axes; ``keys`` an iterator of
+    keys, one drawn a weight."""
+    d, hd, h, hk, ff = (cfg.d_model, cfg.head_dim, cfg.n_heads,
+                        cfg.kv_heads, cfg.ff_dim)
+    pt = cfg.param_dtype
+    p: Params = {"attn_norm": jnp.ones((L, d), pt),
+                 "mlp_norm": jnp.ones((L, d), pt)}
+    ax: Params = {"attn_norm": ("layers", "embed"),
+                  "mlp_norm": ("layers", "embed")}
+
+    def add(name, shape, fan_in, axes):
+        p[name] = jax.random.normal(next(keys), (L,) + shape, pt) \
+            / math.sqrt(fan_in)
+        ax[name] = ("layers",) + axes
+
+    if cfg.attention == "mla":
+        ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        add("wq_a", (d, ql), d, ("embed", None))
+        add("wq_b", (ql, h, nope + rope), ql, (None, "heads", "kv"))
+        add("wkv_a", (d, kl + rope), d, ("embed", None))
+        add("wkv_b", (kl, h, nope + vd), kl, (None, "heads", "kv"))
+        add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"))
+        p["q_norm"], p["kv_norm"] = jnp.ones((L, ql), pt), jnp.ones((L, kl), pt)
+        ax["q_norm"] = ax["kv_norm"] = ("layers", None)
+    elif cfg.attention == "mha":
+        add("wq", (d, h, hd), d, ("embed", "heads", "kv"))
+        add("wk", (d, hk, hd), d, ("embed", "heads", "kv"))
+        add("wv", (d, hk, hd), d, ("embed", "heads", "kv"))
+        add("wo", (h, hd, d), h * hd, ("heads", "kv", "embed"))
+    else:
+        raise ValueError(f"attention={cfg.attention!r}: expected 'mha' or "
+                         f"'mla'")
+    gated = cfg.activation == "swiglu"
+    if cfg.n_experts and run == "layers":
+        E, f = cfg.n_experts, cfg.expert_ff_dim
+        add("router", (d, E), d, ("embed", "expert"))
+        add("w_in", (E, d, f), d, ("expert", "embed", "mlp"))
+        add("w_out", (E, f, d), f, ("expert", "mlp", "embed"))
+        if gated:
+            add("w_gate", (E, d, f), d, ("expert", "embed", "mlp"))
+        if cfg.router == "sigmoid":
+            # moves the choice of experts, never their weights; float32
+            # like the scores it is added to
+            p["router_bias"] = jnp.zeros((L, E), pt)
+            ax["router_bias"] = ("layers", "expert")
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            add("ws_in", (d, fs), d, ("embed", "mlp"))
+            add("ws_out", (fs, d), fs, ("mlp", "embed"))
+            if gated:
+                add("ws_gate", (d, fs), d, ("embed", "mlp"))
+    else:
+        add("w_in", (d, ff), d, ("embed", "mlp"))
+        add("w_out", (ff, d), ff, ("mlp", "embed"))
+        if gated:
+            add("w_gate", (d, ff), d, ("embed", "mlp"))
+    if cfg.norm == "layernorm":
+        p["attn_norm_b"] = jnp.zeros((L, d), pt)
+        p["mlp_norm_b"] = jnp.zeros((L, d), pt)
+        ax["attn_norm_b"] = ax["mlp_norm_b"] = ("layers", "embed")
+    return p, ax
+
+
 def init_params(key: jax.Array, cfg: TransformerConfig
                 ) -> Tuple[Params, Params]:
     """Returns (params, params_axes): matching pytrees of weights and
-    logical-axis tuples.  Stacked layer weights carry a leading "layers"
-    axis (pipeline-shardable)."""
-    d, hd, h, hk, ff, L = (cfg.d_model, cfg.head_dim, cfg.n_heads,
-                           cfg.kv_heads, cfg.ff_dim, cfg.n_layers)
-    pt = cfg.param_dtype
+    logical-axis tuples.  Each run of the layer pattern is one stacked
+    tree with a leading "layers" axis (pipeline-shardable)."""
+    d, pt = cfg.d_model, cfg.param_dtype
     keys = iter(jax.random.split(key, 16))
-
-    def dense(k, shape, fan_in):
-        return (jax.random.normal(k, shape, pt) / math.sqrt(fan_in))
-
-    def stack(k, shape, fan_in):
-        return dense(k, (L,) + shape, fan_in)
-
     params: Params = {
         "embed": {"tok": jax.random.normal(next(keys), (cfg.vocab_size, d),
                                            pt) * 0.02},
-        "layers": {
-            "attn_norm": jnp.ones((L, d), pt),
-            "wq": stack(next(keys), (d, h, hd), d),
-            "wk": stack(next(keys), (d, hk, hd), d),
-            "wv": stack(next(keys), (d, hk, hd), d),
-            "wo": stack(next(keys), (h, hd, d), h * hd),
-            "mlp_norm": jnp.ones((L, d), pt),
-        },
         "final_norm": jnp.ones((d,), pt),
     }
-    axes: Params = {
-        "embed": {"tok": ("vocab", "embed")},
-        "layers": {
-            "attn_norm": ("layers", "embed"),
-            "wq": ("layers", "embed", "heads", "kv"),
-            "wk": ("layers", "embed", "heads", "kv"),
-            "wv": ("layers", "embed", "heads", "kv"),
-            "wo": ("layers", "heads", "kv", "embed"),
-            "mlp_norm": ("layers", "embed"),
-        },
-        "final_norm": ("embed",),
-    }
-    if cfg.n_experts:
-        E = cfg.n_experts
-        params["layers"]["router"] = stack(next(keys), (d, E), d)
-        axes["layers"]["router"] = ("layers", "embed", "expert")
-        params["layers"]["w_in"] = stack(next(keys), (E, d, ff), d)
-        axes["layers"]["w_in"] = ("layers", "expert", "embed", "mlp")
-        params["layers"]["w_out"] = stack(next(keys), (E, ff, d), ff)
-        axes["layers"]["w_out"] = ("layers", "expert", "mlp", "embed")
-        if cfg.activation == "swiglu":
-            params["layers"]["w_gate"] = stack(next(keys), (E, d, ff), d)
-            axes["layers"]["w_gate"] = ("layers", "expert", "embed", "mlp")
-    else:
-        params["layers"]["w_in"] = stack(next(keys), (d, ff), d)
-        axes["layers"]["w_in"] = ("layers", "embed", "mlp")
-        params["layers"]["w_out"] = stack(next(keys), (ff, d), ff)
-        axes["layers"]["w_out"] = ("layers", "mlp", "embed")
-        if cfg.activation == "swiglu":
-            params["layers"]["w_gate"] = stack(next(keys), (d, ff), d)
-            axes["layers"]["w_gate"] = ("layers", "embed", "mlp")
+    axes: Params = {"embed": {"tok": ("vocab", "embed")},
+                    "final_norm": ("embed",)}
+    # the main run draws from the model's own keys (a model of one run is
+    # initialised as it always was), a leading run from keys of its own
+    for i, (run, n) in enumerate(cfg.layer_runs):
+        run_keys = keys if run == "layers" else iter(
+            jax.random.split(jax.random.fold_in(key, i + 1), 16))
+        params[run], axes[run] = _init_run(run_keys, cfg, run, n)
     if cfg.norm == "layernorm":
-        params["layers"]["attn_norm_b"] = jnp.zeros((L, d), pt)
-        params["layers"]["mlp_norm_b"] = jnp.zeros((L, d), pt)
         params["final_norm_b"] = jnp.zeros((d,), pt)
-        axes["layers"]["attn_norm_b"] = ("layers", "embed")
-        axes["layers"]["mlp_norm_b"] = ("layers", "embed")
         axes["final_norm_b"] = ("embed",)
     if cfg.pos_emb == "learned":
         params["embed"]["pos"] = jax.random.normal(
             next(keys), (cfg.max_seq_len, d), pt) * 0.01
         axes["embed"]["pos"] = (None, "embed")
     if not cfg.tie_embeddings:
-        params["lm_head"] = dense(next(keys), (d, cfg.vocab_size), d)
+        params["lm_head"] = jax.random.normal(
+            next(keys), (d, cfg.vocab_size), pt) / math.sqrt(d)
         axes["lm_head"] = ("embed", "vocab")
     return params, axes
 
@@ -309,17 +423,54 @@ def remat_policy(remat):
     raise ValueError(f"remat={remat!r}: expected False, True, or 'dots'")
 
 
+def norm_eps(cfg: TransformerConfig) -> float:
+    """The epsilon the configuration states, else its norm's own."""
+    if cfg.norm_eps is not None:
+        return cfg.norm_eps
+    return 1e-6 if cfg.norm == "rmsnorm" else 1e-5
+
+
 def _norm(cfg, x, scale, bias):
     if cfg.norm == "rmsnorm":
-        return rmsnorm(x, scale)
-    return layernorm(x, scale, bias)
+        return rmsnorm(x, scale, norm_eps(cfg))
+    return layernorm(x, scale, bias, norm_eps(cfg))
+
+
+_EXPERT_STACKS = ("w_in", "w_gate", "w_out")
+
+
+def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
+                    whole_expert_stacks: bool = False):
+    """THE layer loop: ``body(carry, lp) -> carry`` over every layer of the
+    declared pattern, one `lax.scan` per run of identical layers
+    (`TransformerConfig.layer_runs`), the carry handed from run to run.
+    `_trunk` and `generate._scan_cached` both loop through here.
+
+    ``whole_expert_stacks`` (the cached programs): a run's routed-expert
+    weights are not scanned over.  The grouped matmul is a kernel call, and
+    a layer's ``[E, d, f]`` slice of the stack would be COPIED out for it,
+    every weight of every expert for a few rows of work.  The body gets
+    ``(stack [L, E, d, f], layer)`` instead and `ops.moe.routed_ffn` hands
+    the kernel the whole stack with the groups of the other layers empty."""
+    for run, n in cfg.layer_runs:
+        tree = params[run]
+        whole = {k: tree[k] for k in _EXPERT_STACKS
+                 if whole_expert_stacks and cfg.router == "sigmoid"
+                 and "router" in tree and k in tree}
+        xs = {k: v for k, v in tree.items() if k not in whole}
+
+        def step(c, x):
+            lp, i = x
+            return body(c, dict(lp, **{k: (v, i) for k, v in whole.items()})
+                        ), None
+
+        carry, _ = jax.lax.scan(step, carry, (xs, jnp.arange(n)))
+    return carry
 
 
 def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
            cos, sin) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One transformer block; returns (x, router_aux_loss)."""
-    b, s, d = x.shape
-    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     dt = cfg.dtype
 
     norm = functools.partial(_norm, cfg)
@@ -328,42 +479,86 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
             norm, policy=jax.checkpoint_policies.nothing_saveable)
 
     y = norm(x, lp["attn_norm"], lp.get("attn_norm_b"))
-    q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-    if cfg.pos_emb == "rope":
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
-    attn = multi_head_attention(q, k, v, causal=cfg.causal,
-                                impl=cfg.attention_impl)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
+    if cfg.attention == "mla":
+        # nothing is cached here: the plain form, every head's keys and
+        # values built from the latents
+        rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
+        q_nope, q_rope = mla.queries(
+            y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
+            nope=cfg.qk_nope_head_dim, eps=norm_eps(cfg), rotate=rotate)
+        latent = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
+                             kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
+                             rotate=rotate)
+        x = x + mla.attend_plain(q_nope, q_rope, latent, lp["wkv_b"],
+                                 lp["wo"], causal=cfg.causal,
+                                 impl=cfg.attention_impl)
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
+        if cfg.pos_emb == "rope":
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
+        attn = multi_head_attention(q, k, v, causal=cfg.causal,
+                                    impl=cfg.attention_impl)
+        x = x + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
 
     y = norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
-    z, aux = _ffn(cfg, y, lp)
+    z, aux, _ = _ffn(cfg, y, lp)
     return x + z, aux
 
 
-def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params
-         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
+    """A dense feed-forward: SwiGLU where the model gates, else GELU."""
+    dt = cfg.dtype
+    up = jnp.einsum("bsd,df->bsf", y, w_in.astype(dt))
+    if cfg.activation == "swiglu":
+        gate = jnp.einsum("bsd,df->bsf", y, w_gate.astype(dt))
+        z = jax.nn.silu(gate) * up
+    else:
+        z = jax.nn.gelu(up)
+    return jnp.einsum("bsf,fd->bsd", z, w_out.astype(dt))
+
+
+_NO_LOAD = (0, 0)
+
+
+def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+         valid: Optional[jnp.ndarray] = None):
     """Post-attention FFN on a normed input — ONE implementation shared
     by training/prefill (`_layer`) and KV-cache decode
     (`models/generate.py`), so the architectures can't desynchronize.
-    → (residual delta, router aux loss)."""
-    dt = cfg.dtype
+    Which FFN a layer has follows from its run's tree (a router or none),
+    which expert layer from the model's router kind.  ``valid`` [b, s]
+    marks the rows that count (a decode batch's live slots); only the
+    no-drop expert layer, whose cost follows the rows routed, looks at it.
+    → (residual delta, router aux loss, (experts touched, largest expert
+    load) of this layer, zeros where it routes nothing)."""
     aux = jnp.zeros((), jnp.float32)
-    if cfg.n_experts:
+    if "router" not in lp:
+        return (_glu(cfg, y, lp["w_in"], lp.get("w_gate"), lp["w_out"]),
+                aux, _NO_LOAD)
+    if cfg.router == "softmax":
         from ..ops.moe import moe_ffn
         z, aux = moe_ffn(
             y, lp["router"], lp["w_in"], lp["w_out"], lp.get("w_gate"),
             top_k=cfg.expert_top_k, capacity_factor=cfg.capacity_factor)
-        return z, aux
-    if cfg.activation == "swiglu":
-        up = jnp.einsum("bsd,df->bsf", y, lp["w_in"].astype(dt))
-        gate = jnp.einsum("bsd,df->bsf", y, lp["w_gate"].astype(dt))
-        z = jax.nn.silu(gate) * up
-    else:
-        z = jax.nn.gelu(jnp.einsum("bsd,df->bsf", y, lp["w_in"].astype(dt)))
-    return jnp.einsum("bsf,fd->bsd", z, lp["w_out"].astype(dt)), aux
+        return z, aux, _NO_LOAD
+    if cfg.router != "sigmoid":
+        raise ValueError(f"router={cfg.router!r}: expected 'softmax' or "
+                         f"'sigmoid'")
+    from ..ops.moe import routed_ffn, sigmoid_route
+    b, s, d = y.shape
+    flat = y.reshape(b * s, d)
+    idx, w = sigmoid_route(flat, lp["router"], lp["router_bias"],
+                           cfg.expert_top_k, cfg.routed_scaling_factor)
+    z, load = routed_ffn(flat, idx, w, lp["w_in"], lp["w_out"],
+                         lp.get("w_gate"),
+                         None if valid is None else valid.reshape(b * s))
+    z = z.reshape(b, s, d)
+    if cfg.n_shared_experts:
+        z = z + _glu(cfg, y, lp["ws_in"], lp.get("ws_gate"), lp["ws_out"])
+    return z, aux, tuple(load)
 
 
 def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
@@ -398,7 +593,7 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
                          f"'gather' or 'one_hot'")
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][:s].astype(dt)
-    cos, sin = (rotary_angles(s, cfg.head_dim, cfg.rope_base)
+    cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
 
     layer = functools.partial(_layer, cfg)
@@ -409,7 +604,7 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
     def body(carry, lp):
         h, aux = carry
         h, aux_l = layer(h, lp, cos, sin)
-        return (h, aux + aux_l), None
+        return h, aux + aux_l
 
     if cfg.pp_stages > 1:
         from ..parallel.pipeline import (microbatch, pipeline_apply,
@@ -417,10 +612,16 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         if cfg.n_layers % cfg.pp_stages:
             raise ValueError(f"{cfg.n_layers} layers not divisible by "
                              f"{cfg.pp_stages} pipeline stages")
+        if len(cfg.layer_runs) > 1:
+            raise NotImplementedError(
+                "a pipeline over a layer pattern of more than one run "
+                "(leading dense layers) is not supported: stages are "
+                "equal slabs of ONE stacked tree")
         n_micro = cfg.pp_microbatches or cfg.pp_stages
 
         def stage_fn(slab, state):
-            out, _ = jax.lax.scan(body, state, slab)
+            out, _ = jax.lax.scan(lambda c, lp: (body(c, lp), None), state,
+                                  slab)
             return out
 
         x_mb = (microbatch(x, n_micro),
@@ -431,8 +632,8 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         x = unmicrobatch(h_mb)
         aux = aux_mb.sum() / (n_micro * cfg.n_layers)
     else:
-        (x, aux), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+        x, aux = scan_layer_runs(
+            cfg, params, (x, jnp.zeros((), jnp.float32)), body)
         aux = aux / cfg.n_layers
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return x, aux
@@ -481,7 +682,10 @@ def lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
     # model's seq length divisible by sequence-parallel mesh axes (sp)
     tokens = batch["tokens"]
     b, s = tokens.shape
-    aux_weight = cfg.router_aux_weight if cfg.n_experts else 0.0
+    # the sigmoid router's correction bias is a constant here: no
+    # auxiliary loss (its update is a training procedure of its own)
+    aux_weight = cfg.router_aux_weight \
+        if cfg.n_experts and cfg.router == "softmax" else 0.0
     mask = batch.get("mask")
 
     if cfg.loss_chunk and s % cfg.loss_chunk:

@@ -1,0 +1,107 @@
+"""Multi-head latent attention (MLA): low-rank queries, and ONE compressed
+key-value latent plus ONE rotary key a position, shared by all heads.
+
+    c_q            = rmsnorm(y W_qa)                       [.., q_lora]
+    q_nope | q_rope = c_q W_qb          per head           [.., h, nope|rope]
+    c_kv | k_r     = y W_kva                               [.., kv_lora|rope]
+    latent         = rmsnorm(c_kv) | rotary(k_r)           what a cache holds
+    k_nope | v     = rmsnorm(c_kv) W_kvb   per head        [.., h, nope|v]
+    scores         = (q_nope . k_nope + rotary(q_rope) . rotary(k_r))
+                     / sqrt(nope + rope)
+
+Two forms of the same function of (queries, latents):
+
+* `attend_plain` builds every head's keys and values from the latents and
+  runs ordinary attention: the form for training and for a whole-sequence
+  forward, where nothing is cached.
+* `attend_absorbed` never builds them.  ``q_nope . (c W_k) = (q_nope W_k^T)
+  . c`` and ``(p c) W_v = p (c W_v)``: the key up-projection is folded into
+  the query and the value up-projection applied after the probabilities,
+  so the few queries of a chunk or a decode step attend over the cached
+  latents directly.  Per cached position a layer then reads ``kv_lora +
+  rope`` values, not ``heads x (qk + v)``.
+
+Shapes are ``[batch, seq, heads, dim]`` like `ops/attention.py`; a cache
+layer is ``[batch, kv_lora + rope, positions]``, positions last, as
+`models/generate.py` stores it.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Callable, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .attention import multi_head_attention
+from .norms import rmsnorm
+
+Rotate = Callable[[jnp.ndarray], jnp.ndarray]   # [b, s, heads, rope] -> same
+
+
+def queries(y: jnp.ndarray, wq_a, q_norm, wq_b, *, nope: int, eps: float,
+            rotate: Rotate) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Normed input ``y`` [b, s, d] -> (q_nope [b, s, h, nope], q_rope
+    [b, s, h, rope] already rotated)."""
+    dt = y.dtype
+    c_q = rmsnorm(jnp.einsum("bsd,dr->bsr", y, wq_a.astype(dt)), q_norm, eps)
+    q = jnp.einsum("bsr,rhk->bshk", c_q, wq_b.astype(dt))
+    return q[..., :nope], rotate(q[..., nope:])
+
+
+def latents(y: jnp.ndarray, wkv_a, kv_norm, *, kv_lora: int, eps: float,
+            rotate: Rotate) -> jnp.ndarray:
+    """Normed input ``y`` [b, s, d] -> [b, s, kv_lora + rope]: the normed
+    key-value latent beside the rotated shared key.  This, and nothing
+    else, is what a cache of this attention kind holds."""
+    dt = y.dtype
+    ckv = jnp.einsum("bsd,dr->bsr", y, wkv_a.astype(dt))
+    c = rmsnorm(ckv[..., :kv_lora], kv_norm, eps)
+    k_r = rotate(ckv[..., None, kv_lora:])[..., 0, :]
+    return jnp.concatenate([c, k_r], axis=-1)
+
+
+def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
+                 latent: jnp.ndarray, wkv_b, wo, *, causal: bool = True,
+                 impl: str = "auto") -> jnp.ndarray:
+    """Every head's keys and values built from ``latent`` [b, s, kv_lora +
+    rope], ordinary attention over them -> [b, s, d]."""
+    dt = q_nope.dtype
+    nope, rope = q_nope.shape[-1], q_rope.shape[-1]
+    kv_lora, h = wkv_b.shape[0], wkv_b.shape[1]
+    kv = jnp.einsum("bsr,rhk->bshk", latent[..., :kv_lora], wkv_b.astype(dt))
+    k_r = jnp.broadcast_to(latent[:, :, None, kv_lora:],
+                           latent.shape[:2] + (h, rope))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
+    v = kv[..., nope:]
+    if v.shape[-1] != q.shape[-1]:
+        impl = "reference"      # the flash kernel has one head size
+    attn = multi_head_attention(q, k, v, causal=causal, impl=impl,
+                                sm_scale=1.0 / math.sqrt(nope + rope))
+    return jnp.einsum("bshk,hkd->bsd", attn, wo.astype(dt))
+
+
+def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
+                    cached: jnp.ndarray, wkv_b, wo, mask: jnp.ndarray
+                    ) -> jnp.ndarray:
+    """``cached`` [b, kv_lora + rope, T] is one layer of a latent cache,
+    ``mask`` [b|1, s, T] which positions each query may see -> [b, s, d].
+    The probabilities meet all ``kv_lora + rope`` cached rows (the rotary
+    rows' part of the result is dropped): slicing the latent rows out of
+    the cache first would copy them."""
+    dt = q_nope.dtype
+    nope, rope = q_nope.shape[-1], q_rope.shape[-1]
+    kv_lora = wkv_b.shape[0]
+    w = wkv_b.astype(dt)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w[..., :nope])
+    q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
+    scores = jnp.einsum("bshr,brt->bsht", q_abs, cached.astype(dt),
+                        preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(nope + rope)
+    scores = jnp.where(mask[:, :, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o_lat = jnp.einsum("bsht,brt->bshr", probs.astype(dt), cached.astype(dt))
+    attn = jnp.einsum("bshr,rhv->bshv", o_lat[..., :kv_lora], w[..., nope:])
+    return jnp.einsum("bshv,hvd->bsd", attn, wo.astype(dt))

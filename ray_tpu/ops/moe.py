@@ -1,9 +1,22 @@
-"""Mixture-of-experts FFN with top-k routing and capacity-based dispatch.
+"""Mixture-of-experts FFNs: two expert layers, chosen by a model's router
+kind (`models/transformer.py` `_ffn`), never by a switch.
 
-The reference has no expert parallelism at all (SURVEY.md §2.4 row 5 —
-"Absent"); this is the TPU-native deliverable for that row.  The design is
-the GShard/Switch einsum formulation, which is what maps onto the MXU and
-onto GSPMD's all_to_all insertion:
+**Sigmoid router, no token dropped** (`sigmoid_route`, `routed_ffn`): the
+layer of models that route by a sigmoid score plus a correction bias and
+hold all their experts on the chip that serves them.  Token-expert pairs
+are SORTED by expert and the three expert matmuls are grouped matmuls
+(`jax.lax.ragged_dot`: row block i of the sorted pairs meets expert i's
+weights), so the cost follows the pairs routed and the experts they touch,
+not the number of experts; nothing of size tokens x experts x capacity is
+built and no load, however uneven, loses a token.  It returns what it
+routed (`Load`) for the serve engine's counters.
+
+**Softmax router with capacity** (`route`, `moe_ffn`): the GShard/Switch
+einsum formulation for the softmax presets that TRAIN over the mesh's
+``ep`` axis; its only callers are those presets.  The reference has no
+expert parallelism at all (SURVEY.md §2.4 row 5 — "Absent"); this is the
+TPU-native deliverable for that row.  It maps onto the MXU and onto
+GSPMD's all_to_all insertion:
 
   * router logits → top-k gate weights per token,
   * a dense one-hot *dispatch* tensor [batch, seq, experts, capacity]
@@ -22,10 +35,100 @@ standard Switch-Transformer overflow policy.
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+class Load(NamedTuple):
+    """What one `routed_ffn` call routed (int32 scalars): experts that got
+    at least one pair, and the pairs of the fullest expert."""
+    experts_touched: jnp.ndarray
+    load_max: jnp.ndarray
+
+
+def sigmoid_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
+                  top_k: int, scaling: float = 1.0
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """y [n, d] -> (experts [n, k] int32, weights [n, k] float32).
+
+    Scores are ``sigmoid(y W_r)`` in float32.  The ``top_k`` experts are
+    chosen by score PLUS ``bias`` [E]; a chosen expert's weight is its
+    score alone, normalised over the chosen and times ``scaling``: the
+    bias moves the choice and never the weight."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", y.astype(jnp.float32), router_w.astype(jnp.float32)))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scaling
+    return idx.astype(jnp.int32), w
+
+
+def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
+               w_in: jnp.ndarray, w_out: jnp.ndarray,
+               w_gate: Optional[jnp.ndarray] = None,
+               valid: Optional[jnp.ndarray] = None
+               ) -> Tuple[jnp.ndarray, Load]:
+    """Every token through each of its chosen experts, none dropped.
+
+    y [n, d]; idx, w [n, k] from a router; w_in [E, d, f], w_out [E, f, d],
+    w_gate [E, d, f] selects SwiGLU (None -> GELU), or each of the three as
+    ``(stack [L, E, .., ..], layer)``: the whole stack of a run of layers
+    and which of them this is (a slice of the stack would be copied out
+    for the kernel; the stack is handed over whole, as ``L * E`` groups of
+    which only this layer's hold rows); ``valid`` [n] bool marks
+    the rows that count (None: all): a row that does not is routed nowhere,
+    touches no expert and gets zeros.  -> (out [n, d], `Load`).
+
+    The n*k pairs are sorted by expert, so expert e's rows are one block;
+    rows past the last block (the invalid ones, sorted to the end under
+    the sentinel expert E) belong to no group."""
+    layer, n_layers = None, 1
+    if isinstance(w_in, tuple):
+        layer, n_layers = w_in[1], w_in[0].shape[0]
+        w_in, w_out, w_gate = (
+            a if a is None else a[0].reshape((-1,) + a[0].shape[2:])
+            for a in (w_in, w_out, w_gate))
+    n_experts = w_in.shape[0] // n_layers
+    dt = y.dtype
+    # the TPU's grouped matmul takes row blocks of 8; with fewer pairs (one
+    # token's) the compiler falls back to a dense product over EVERY
+    # expert.  Rows that count for nothing fill up: they belong to no group
+    fill = (-idx.shape[0]) % (8 // math.gcd(idx.shape[1], 8))
+    if fill:
+        if valid is None:
+            valid = jnp.ones((idx.shape[0],), bool)
+        y, idx, w, valid = (jnp.pad(a, [(0, fill)] + [(0, 0)] * (a.ndim - 1))
+                            for a in (y, idx, w, valid))
+    n, k = idx.shape
+    pair_expert = idx.reshape(-1)
+    if valid is not None:
+        pair_expert = jnp.where(jnp.repeat(valid, k), pair_expert, n_experts)
+    order = jnp.argsort(pair_expert, stable=True)                 # [n*k]
+    sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[pair_expert].add(1)
+    sizes = sizes[:n_experts]
+    groups = sizes
+    if layer is not None:     # this layer's groups among the stack's
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * n_experts,), jnp.int32), sizes,
+            (layer * n_experts,))
+    xs = y[order // k]                                            # [n*k, d]
+    up = jax.lax.ragged_dot(xs, w_in.astype(dt), groups)
+    if w_gate is not None:
+        z = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate.astype(dt), groups)) * up
+    else:
+        z = jax.nn.gelu(up)
+    out = jax.lax.ragged_dot(z, w_out.astype(dt), groups)         # [n*k, d]
+    # back to token order: pair j of token i sits at sorted row inv[i*k+j]
+    inv = jnp.argsort(order)
+    out = out[inv].reshape(n, k, -1)
+    if valid is not None:          # rows of no group hold nothing defined
+        out = jnp.where(valid[:, None, None], out, 0)
+    out = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), w)
+    out = out[:n - fill]
+    return out.astype(dt), Load((sizes > 0).sum().astype(jnp.int32),
+                                sizes.max())
 
 
 def expert_capacity(seq_tokens: int, n_experts: int, top_k: int,

@@ -158,9 +158,10 @@ class ContinuousBatchingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models import (cache_insert_slot, decode_step_slots,
-                              draft_propose_slots, prefill_chunk_jit,
-                              verify_step_slots)
+        from ..models import (cache_insert_slot, draft_propose_slots,
+                              prefill_chunk_jit, verify_step_slots)
+        from ..models.generate import _decode_step_slots, cache_arrays
+        self._cache_arrays = cache_arrays
         self.cfg = cfg
         self.max_len = max_len
         self.params = params
@@ -168,15 +169,28 @@ class ContinuousBatchingEngine:
         self.name = name or "decode"
         self._tag = replica_tag
 
+        # a model whose expert layers drop nothing reports what a step
+        # routed: (experts touched, largest expert load), each summed
+        # over the expert layers, ride BEHIND the tokens in the step's
+        # one int32 vector, so the loop still makes one read per step
+        self._moe_layers = moe_layers = dict(cfg.layer_runs)["layers"] \
+            if cfg.n_experts and cfg.router == "sigmoid" else 0
+        slots = engine_cfg.max_slots
+
         def fused_step(params, tok, cache, active, *, cfg):
             # decode + greedy sample + carry in ONE program: the loop
             # pays a single dispatch and a single [S]-int32 device→host
             # read per step (separate argmax/where calls measurably
             # dominated the step on small models)
-            logits, cache = decode_step_slots(params, tok, cache,
-                                              active, cfg)
+            if moe_layers:
+                tok = tok[:slots]     # the last step's counts ride behind
+            logits, cache, load = _decode_step_slots(params, tok, cache,
+                                                     active, cfg)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jnp.where(active, nxt, tok), cache
+            out = jnp.where(active, nxt, tok)
+            if moe_layers:
+                out = jnp.concatenate([out, jnp.stack(load)])
+            return out, cache
 
         # ---- dispatch profiler (util/device_profile.py) ----
         # every jitted program below goes through a wrap-once timing
@@ -270,6 +284,13 @@ class ContinuousBatchingEngine:
         self.tokens = 0
         self.reaped = 0          # sessions evicted by the idle reaper
         self.prefill_chunks = 0  # chunk programs run for admissions
+        # what the no-drop expert layers routed in decode steps, summed
+        # over steps and expert layers: `stats()["moe"]`, and every
+        # `_MOE_SPAN_S` seconds one ring span `moe:load` with the sums
+        # since the last (a span a step cost too much: PERF.md, PR 25)
+        self.moe = dict.fromkeys(
+            ("steps", "experts_touched", "pairs", "load_max"), 0)
+        self._moe_span = dict(self.moe, t=time.time())
         # analytic FLOPs/token per program -> the profiler's MFU
         # numerators (models.engine_flops_table; pure-copy programs 0)
         from ..models import engine_flops_table
@@ -296,7 +317,8 @@ class ContinuousBatchingEngine:
         @functools.wraps(fn)
         def call(*args, **kwargs):
             out = fn(*args, **kwargs)
-            if not args[arg]["k"].is_deleted():
+            if not all(a.is_deleted()
+                       for a in self._cache_arrays(args[arg]).values()):
                 with self._cond:   # stats() reads this counter
                     self.cache_copies += 1
             return out
@@ -473,6 +495,11 @@ class ContinuousBatchingEngine:
                     # dispatches that did NOT consume the cache they
                     # were given (0 while donation engages)
                     "cache_copies": self.cache_copies,
+                    # decode steps' routing, summed over expert layers
+                    # (zeros for a model without a no-drop expert layer)
+                    "moe": dict(self.moe, layers=self._moe_layers,
+                                experts=self.cfg.n_experts),
+                    "cache": self._cache_stats(),
                     # every distinct program shape this engine has
                     # dispatched — a compile-storm regression (one
                     # program per prompt/resume length) shows up here
@@ -499,6 +526,16 @@ class ContinuousBatchingEngine:
                     # dispatch/compile/MFU ledger + phase attribution
                     "device_profile": self._prof.snapshot(),
                     "phase_totals": self.phase_totals()}
+
+    def _cache_stats(self) -> Dict[str, int]:
+        """Bytes of the slot cache's arrays (whatever the model's
+        attention kind holds) and of one position of one slot over all
+        layers; zeros until the first session allocates it."""
+        nbytes = sum(int(a.nbytes) for a in
+                     self._cache_arrays(self._cache or {}).values())
+        return {"bytes": nbytes,
+                "bytes_per_position":
+                    nbytes // (self.ecfg.max_slots * self.max_len)}
 
     def phase_totals(self) -> Dict[str, float]:
         """Cumulative serve-phase seconds — the serve_breakdown
@@ -565,9 +602,23 @@ class ContinuousBatchingEngine:
         if self._thread is None or not self._thread.is_alive():
             _ENGINES.add(self)
             self._thread = threading.Thread(
-                target=self._loop, daemon=True,
+                target=self._thread_main, daemon=True,
                 name=f"decode-engine:{self.name}")
             self._thread.start()
+
+    def _thread_main(self) -> None:
+        """The loop, and after a `shutdown` the release of what it held
+        on the device.  The engine's jitted closures refer back to it, so
+        the object itself lives until the cycle collector runs: a replica
+        that loads new weights after shutting an engine down (the
+        benchmark's outputs check does, seed after seed) would otherwise
+        hold the old model's weights and cache beside the new."""
+        try:
+            self._loop()
+        finally:
+            if self._shutdown:
+                self.params = self._draft_params = None
+                self._cache = self._dcache = None
 
     def _reap_locked(self) -> None:
         """Vacate slots of ended/finished sessions (between steps), and
@@ -792,8 +843,7 @@ class ContinuousBatchingEngine:
         # the draft cache's pos is re-synced from the target every
         # iteration: its rejected speculative writes sit past the true
         # pos and are rewritten before any masked read
-        dcache = {"k": self._dcache["k"], "v": self._dcache["v"],
-                  "pos": self._cache["pos"]}
+        dcache = dict(self._dcache, pos=self._cache["pos"])
         # the draft scans spec_k steps but only spec_k - 1 proposals are
         # verified: the k-th step's K/V WRITE is what matters — on a
         # fully-accepted iteration the last emitted token's row must
@@ -831,7 +881,10 @@ class ContinuousBatchingEngine:
             if self._spec:
                 self._dcache = init_slot_cache(
                     self._draft_cfg, self.ecfg.max_slots, self.max_len)
-        tokens = np.zeros(self.ecfg.max_slots, np.int32)
+        slots = self.ecfg.max_slots
+        # a step's routing counts ride behind its tokens (`fused_step`):
+        # the host's row is as long, so both ways in are one shape
+        tokens = np.zeros(slots + (2 if self._moe_layers else 0), np.int32)
         tok_dev = None       # device-resident step output → next input
         active_dev = None
         active_key: Any = None
@@ -880,7 +933,8 @@ class ContinuousBatchingEngine:
             if self._spec and not self._spec_disabled:
                 try:
                     with phase("dispatch"):   # and its own two reads
-                        spec_out = self._spec_step(tokens, active, fi)
+                        spec_out = self._spec_step(tokens[:slots], active,
+                                                   fi)
                     self._spec_fail_streak = 0
                     tok_dev = None   # host owns the carry again
                 except Exception as e:
@@ -910,10 +964,12 @@ class ContinuousBatchingEngine:
                         tok_dev, self._cache = self._step(
                             self.params, tok_dev, self._cache,
                             active_dev, cfg=self.cfg)
-                        self._shape_seen("decode_step", len(tokens))
+                        self._shape_seen("decode_step", slots)
                     with phase("readback"):
                         new_toks = np.asarray(tok_dev)
                         tokens[:] = new_toks
+                    if self._moe_layers:
+                        self._count_moe(len(batch), new_toks[slots:])
                 except Exception as e:
                     self._fail_slots(f"decode engine step failed: {e!r}")
                     tok_dev = None
@@ -921,6 +977,26 @@ class ContinuousBatchingEngine:
             with phase("publish"):
                 self._publish(batch, tokens, spec_out,
                               None if spec_out is not None else new_toks)
+
+    _MOE_SPAN_S = 2.0
+
+    def _count_moe(self, occupancy: int, load) -> None:
+        """One decode step's routing into the counters, and the sums since
+        the last `moe:load` span into the next one when it is due."""
+        from ..util import tracing
+        with self._cond:   # stats() reads these
+            self.moe["steps"] += 1
+            self.moe["experts_touched"] += int(load[0])
+            self.moe["load_max"] += int(load[1])
+            self.moe["pairs"] += (occupancy * self.cfg.expert_top_k
+                                  * self._moe_layers)
+        now, last = time.time(), self._moe_span
+        if now - last["t"] >= self._MOE_SPAN_S:
+            tracing.record_span(
+                "moe:load", "serve", last["t"], now, deployment=self.name,
+                layers=self._moe_layers, experts=self.cfg.n_experts,
+                **{k: self.moe[k] - last[k] for k in self.moe})
+            self._moe_span = dict(self.moe, t=now)
 
     def _fail_slots(self, error: str) -> None:
         """A donated step raised: the slot cache it was given may be

@@ -8,7 +8,8 @@ this matters on TPU serving).  Two layers consult this index:
 
 * **Engine-side** (`decode_session.ContinuousBatchingEngine`): keys are
   the prompts of live decode slots, values are slot indices.  Admission
-  looks up the longest shared prefix, copies that many K/V rows out of
+  looks up the longest shared prefix, copies that many cached rows (keys
+  and values, or latents: whatever arrays the cache has) out of
   the donor slot (`models.cache_gather_slot`), and chunk-prefills only
   the suffix — prefix-hit TTFT drops to O(suffix) instead of O(prompt).
 * **Router-side** (`serve/router.py`): keys are recently-routed session

@@ -188,3 +188,81 @@ def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
     shape = ",".join(map(str, cache["k"].shape))
     copies = re.findall(rf"= bf16\[{shape}\]\S* copy\(", compiled.as_text())
     assert not copies, copies
+
+
+@pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
+                                     "prefill_chunk_1"])
+def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
+                                                                 program):
+    """Latent attention and routed experts at the published widths of the
+    served cell's model (one dense and two expert layers): the donated
+    program aliases its one cache array and copies none of its shape; the
+    expert matmuls are the chip's grouped kernel also for ONE token (4
+    pairs, filled up to a row block: fewer fall to a dense product over all
+    64 experts); and no layer's slice of the expert stack is copied out
+    for the kernel (it takes the stack whole), which at first cost 18 of a
+    chunk program's 25 ms (PERF.md, PR 28)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params, init_slot_cache, prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots
+    cfg = TransformerConfig(
+        vocab_size=154880, d_model=2048, n_layers=3, n_heads=20, d_ff=10240,
+        max_seq_len=4096, pos_emb="rope", rope_base=1e6,
+        activation="swiglu", norm="rmsnorm", norm_eps=1e-5,
+        tie_embeddings=False, attention="mla", q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+        v_head_dim=256, n_experts=64, expert_top_k=4, router="sigmoid",
+        moe_d_ff=1536, n_shared_experts=1, routed_scaling_factor=1.8,
+        first_dense_layers=1, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 16, 4096
+    if program == "fused_step":
+        cache = described(jax.eval_shape(
+            lambda: init_slot_cache(cfg, slots, max_len)))
+
+        def fused_step(params, tok, cache, active):
+            logits, cache, load = _decode_step_slots(params, tok[:slots],
+                                                     cache, active, cfg)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.concatenate([jnp.where(active, nxt, tok[:slots]),
+                                    jnp.stack(load)]), cache
+        lowered = jax.jit(fused_step, donate_argnums=(2,)).lower(
+            params, described(jax.ShapeDtypeStruct((slots + 2,), jnp.int32)),
+            cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    else:
+        width = int(program.rsplit("_", 1)[1])
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
+            cache, cfg=cfg)
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert set(cache) == {"kv", "pos"}
+    want = cache["kv"].size * cache["kv"].dtype.itemsize
+    assert ma.alias_size_in_bytes >= want
+    # beside the cache only activations: no second cache, no expert stack
+    # (one layer's is 604 MB)
+    assert ma.temp_size_in_bytes < 64 << 20, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    shape = ",".join(map(str, cache["kv"].shape))
+    assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text)
+    # three grouped matmuls a layer, each given the whole stack of 2 x 64
+    calls = re.findall(r"ragged-dot-none[.\d]* = [^\n]*", text)
+    assert len(calls) == 3 and all(
+        "bf16[128,2048,1536]" in c or "bf16[128,1536,2048]" in c
+        for c in calls), calls
