@@ -3,10 +3,13 @@ published widths and at the rehearsal's tiny size) and against the program's
 own arithmetic; its configuration and traffic files against what they
 state; its plain reference against the program through chunked prefill,
 single-token tails and slot decode over the latent cache, and its gradient
-against the program's.  The fp8 control and the rehearsals of
-``tiny-glm.serve-closed`` are the parametrised cases of
-test_perfbench_reference.py and test_perfbench_rehearsal.py, which found
-the family by the rehearsal's manifest.
+against the program's.  The tiny configuration has a manifest of its own,
+``testdata/rehearsal/BENCHMARK.tiny-glm.json``, beside the rehearsal's (a PR
+that changes the program adds files to the benchmark and edits none), so the
+shared parametrised cases of test_perfbench_reference.py and
+test_perfbench_rehearsal.py do not find it: they are called from here, on
+this family.  Its limits files lie in the rehearsal's ``limits/``, where
+every rehearsed cell's are looked up.
 
 Tolerances.  Float32 against float32 (two implementations of the same
 equations, both at ``highest``): 1e-4 on logits of order 1, 2e-4 relative
@@ -28,6 +31,13 @@ from perfbench import manifest as mf
 from perfbench import reference, verdict, weights
 from perfbench.tools import rehearse
 
+import test_perfbench_reference as shared_reference
+import test_perfbench_rehearsal as shared_rehearsal
+
+TINY_MANIFEST = os.path.join(mf.ROOT, rehearse.REHEARSAL,
+                             "BENCHMARK.tiny-glm.json")
+CELL = "tiny-glm.serve-closed"
+
 # by hand, from the published config.json: d 2048, 20 heads, q_lora 768,
 # kv_lora 512, nope 192, rope 64, v 256
 ATTN = (2048 * 768 + 768 * 20 * (192 + 64) + 2048 * (512 + 64)
@@ -39,6 +49,11 @@ OUTSIDE = ATTN + EXPERT + 2048 * 64 + 64 + NORMS     # shared, router, bias
 AS_RUN = DENSE_LAYER + 5 * (OUTSIDE + 64 * EXPERT) + 2 * 154880 * 2048 + 2048
 
 
+def _tiny_manifest() -> mf.Manifest:
+    return mf.Manifest(TINY_MANIFEST, os.path.join(
+        mf.ROOT, rehearse.REHEARSAL, "traffic"))
+
+
 @pytest.fixture(scope="module")
 def real():
     c = mf.Manifest().config("glm-4.7-flash")
@@ -47,7 +62,7 @@ def real():
 
 @pytest.fixture(scope="module")
 def tiny():
-    c = rehearse.manifest().config("tiny-glm")
+    c = _tiny_manifest().config("tiny-glm")
     return c, mf.family_of(c)
 
 
@@ -256,7 +271,7 @@ def test_served_path_in_bfloat16_passes_and_the_fp8_control_fails(tiny):
     want = model.logits(params, toks, c).reshape(-1, v)[keep]
     got = jnp.asarray(got.reshape(-1, v))[keep]
     ctl = model.logits(params, toks, c, "fp8").reshape(-1, v)[keep]
-    limits = rehearse.manifest().limits("tiny-glm.serve-closed")
+    limits = _tiny_manifest().limits("tiny-glm.serve-closed")
     sane = {"requests_completed": True}
     program = {k: float(x) for k, x in reference.logit_numbers(
         got, want, got.argmax(-1)).items()}
@@ -291,6 +306,68 @@ def test_how_often_rounding_the_routers_input_flips_an_expert(tiny, std,
     assert int(exact.sum()) == 4096 * c["num_experts_per_tok"]
     flipped = float((exact != rounded).any(-1).mean())
     assert (std == 1.0 or 0.0 < flipped) and flipped < most, flipped
+
+
+def test_tiny_manifest_has_no_problem():
+    m = _tiny_manifest()
+    assert mf.problems(m) == []
+    assert [w["name"] for w in m.data["workloads"]] == [CELL]
+    # the readers this PR adds are rehearsed under the names the cell has
+    real = {x["name"] for x in mf.Manifest().data["per_layer"]
+            if x.get("workloads") == ["glm-4.7-flash.serve-agent-closed"]}
+    assert real and real <= {x["name"] for x in m.data["per_layer"]}
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_cell_rehearsed_on_the_cpu(monkeypatch, trace):
+    """test_perfbench_rehearsal.py's case, under this family's manifest;
+    the traced run also finds the engine's ``moe:load`` spans (2 pairs a
+    token over 8 experts at the tiny size), and the readers of the device
+    trace find no device plane on the CPU and leave their metrics out."""
+    lines = []
+
+    def rehearsed(*a, **kw):
+        lines.extend(rehearse_cell(*a, manifest_path=TINY_MANIFEST, **kw))
+        return lines
+
+    rehearse_cell = rehearse.rehearse
+    monkeypatch.setattr(rehearse, "manifest", _tiny_manifest)
+    monkeypatch.setattr(rehearse, "rehearse", rehearsed)
+    shared_rehearsal.test_cell_kind_rehearsed_on_the_cpu(CELL, trace)
+    if trace:
+        got = lines[-1]["metrics"]
+        experts = _tiny_manifest().config("tiny-glm")["n_routed_experts"]
+        assert 1 <= got["moe.experts_touched.agent"]["value"] <= experts
+        assert 1 <= got["moe.load_max_over_mean.agent"]["value"] <= experts
+        for name in ("decode_step_roofline.agent",
+                     "prefill_chunk.device_ms.agent",
+                     "engine.prefill_share.agent"):
+            assert name not in got, name
+
+
+def test_reference_is_the_programs_function_in_float32(tiny):
+    c, fam = tiny
+    shared_reference.test_reference_is_the_programs_function_in_float32(
+        (c, fam.model))
+
+
+@pytest.mark.parametrize("seed", shared_reference.SEEDS)
+def test_training_program_passes_and_fp8_control_fails(tiny, seed):
+    c, fam = tiny
+    shared_reference.test_training_program_passes_and_fp8_control_fails(
+        (c, fam.model), seed)
+
+
+@pytest.mark.parametrize("seed", shared_reference.SEEDS)
+def test_serving_program_passes_and_fp8_control_fails(tiny, seed):
+    c, fam = tiny
+    shared_reference.test_serving_program_passes_and_fp8_control_fails(
+        (c, fam.model), seed)
+
+
+def test_weights_come_from_the_seed_alone(tiny):
+    c, fam = tiny
+    shared_reference.test_weights_come_from_the_seed_alone((c, fam.model))
 
 
 def test_limits_files_say_where_their_readings_come_from():
