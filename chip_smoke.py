@@ -267,7 +267,7 @@ def make_deployment(serve, spec: dict):
 
         def _warmup(self):
             """One short session through the engine compiles its programs
-            (a full prefill chunk, single-token tail steps, the decode
+            (a full prefill chunk and a padded one: one shape; the decode
             step, the slot insert) before the timed requests arrive."""
             chunk = self.core.engine.ecfg.prefill_chunk_tokens
             t0 = time.perf_counter()

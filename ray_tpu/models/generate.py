@@ -48,6 +48,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.experimental.layout import Layout, with_layout_constraint
 
@@ -264,7 +265,8 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 
 
 def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                  cfg: TransformerConfig) -> Tuple[jnp.ndarray, KVCache]:
+                  cfg: TransformerConfig, n_valid: Optional[jnp.ndarray] = None
+                  ) -> Tuple[jnp.ndarray, KVCache]:
     """Extend the cache with a CHUNK of prompt tokens [B, C] starting at
     ``cache['pos']`` → (logits of the chunk's last position, cache').
 
@@ -275,15 +277,33 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     helper killer (SURVEY §9); chunking caps the compiled program at C
     positions.  Chunk attention runs dense against the cache's max_len
     (O(C·max_len) per chunk) — more FLOPs than causal flash, traded for
-    a bounded, cacheable compile."""
-    logits, cache, _ = _prefill_chunk(params, tokens, cache, cfg)
+    a bounded, cacheable compile.
+
+    ``n_valid`` (int32 scalar, TRACED, 1 <= n_valid <= C) makes the chunk
+    a PADDED one: only its first ``n_valid`` tokens are real (see
+    :func:`_prefill_chunk`), so a prompt's remainder is one program of
+    the chunk's own shape, whatever its length."""
+    logits, cache, _ = _prefill_chunk(params, tokens, cache, cfg, n_valid)
     return logits, cache
 
 
 def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                   cfg: TransformerConfig):
+                   cfg: TransformerConfig,
+                   n_valid: Optional[jnp.ndarray] = None):
     """:func:`prefill_chunk` → (logits, cache', load): beside them what
-    the chunk's expert layers routed (zeros for a model without)."""
+    the chunk's expert layers routed (zeros for a model without).
+
+    With ``n_valid`` the rows from ``n_valid`` on are padding: ``pos``
+    advances by ``n_valid``, the logits are row ``n_valid - 1``'s, and a
+    no-drop expert layer routes the real rows only (a padded row touches
+    no expert and adds nothing to ``load``).  The causal mask needs no
+    word of it: row ``i`` sees positions ``<= pos + i``, so no real row
+    sees a padded column, and the padded columns written at ``[pos +
+    n_valid, pos + C)`` lie above the returned ``pos``, where every
+    program masks its reads and the next chunk or decode step writes
+    first.  The WINDOW ``[pos, pos + C)`` has to lie inside the cache
+    (and a learned position table): a slice that starts too late is
+    clamped, silently, onto earlier positions (:func:`chunk_window`)."""
     _check_decodable(cfg)
     b, c = tokens.shape
     dt = cfg.dtype
@@ -303,14 +323,49 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     # mask[i, t]: cached position t visible to chunk token i (causal
     # within the chunk, everything before it fully visible)
     mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
+    valid = None if n_valid is None else \
+        jnp.broadcast_to(jnp.arange(c) < n_valid, (b, c))
     x, arrays, load = _attend_cached(
         cfg, params, x, cache,
         rotate=lambda t: apply_rotary(t, cos, sin),
         write=lambda c_all, l, cols: jax.lax.dynamic_update_slice(
             c_all, cols[None], (l, 0, 0, 0, pos)),
-        mask=mask[None])
-    logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed(params, cfg))
-    return logits.astype(jnp.float32), dict(arrays, pos=pos + c), load
+        mask=mask[None], valid=valid)
+    if n_valid is None:
+        last, step = x[:, -1], c
+    else:
+        last = jax.lax.dynamic_index_in_dim(x, n_valid - 1, axis=1,
+                                            keepdims=False)
+        step = jnp.asarray(n_valid, pos.dtype)
+    logits = jnp.einsum("bd,dv->bv", last, _unembed(params, cfg))
+    return logits.astype(jnp.float32), dict(arrays, pos=pos + step), load
+
+
+def chunk_window(off: int, n: int, chunk: int,
+                 capacity: int) -> Tuple[int, int]:
+    """THE policy by which a prompt of ``n`` tokens, ``off`` of them
+    already in the cache, is cut into chunk programs of ONE width:
+    → ``(start, n_valid)``, the next program's first position and how
+    many of its ``chunk`` rows are real tokens (the rest is padding).
+
+    Whole chunks while they last, then the remainder as one padded chunk.
+    A window that would pass ``capacity`` (the cache's ``max_len``, and no
+    more than a learned position table) starts at ``capacity - chunk``
+    instead and runs the overlapped tokens ``[start, off)`` again: a
+    position's columns depend only on the tokens at or before it, so the
+    rewrite stores what was there.  Needs ``chunk <= capacity`` and
+    ``off < n <= capacity``; the caller sets the cache's ``pos`` to
+    ``start`` where that is not ``off``."""
+    start = min(off, capacity - chunk)
+    return start, min(n - start, chunk)
+
+
+def padded_chunk(tokens, start: int, n_valid: int, chunk: int):
+    """Host tokens ``[B, n]`` (numpy) → the chunk program's ``[B, chunk]``
+    int32 input: ``tokens[:, start:start + n_valid]``, zeros behind."""
+    buf = np.zeros((tokens.shape[0], chunk), np.int32)
+    buf[:, :n_valid] = tokens[:, start:start + n_valid]
+    return buf
 
 
 # Module-level jit: every prefill_chunked caller shares one trace/compile
@@ -323,9 +378,12 @@ _prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg",),
 #: The ONE shared chunk program behind every prefill path: legacy
 #: `prefill_chunked`, failover `resume_prefill`, AND the serve engine's
 #: chunked admission (serve/decode_session.py) all dispatch through this
-#: handle, so a replica compiles at most two prefill shapes per model
-#: config ([B, chunk] blocks + [B, 1] tail steps) no matter how many
-#: prompts, resumes, or admissions it serves.
+#: handle.  The engine and `resume_prefill` always pass ``n_valid`` (a
+#: whole chunk passes ``chunk``, a prompt's remainder its length), so a
+#: replica compiles ONE prefill shape per model config, [B, chunk], no
+#: matter how many prompts, resumes, or admissions of whatever length it
+#: serves; callers that pass none (`prefill_chunked`, `decode_step`) get
+#: the unpadded program of their own shape.
 prefill_chunk_jit = _prefill_chunk_jit
 
 
@@ -358,27 +416,31 @@ def resume_prefill(params: Params, tokens: jnp.ndarray,
     fresh cache, and that prefix has an *arbitrary* length — one compile
     per resume length (the whole-prompt :func:`prefill` behavior) would
     turn every failover into a compile storm.  This walks the prefix
-    through exactly TWO reusable chunk programs: ``[B, chunk]`` blocks,
-    then ``[B, 1]`` steps for the remainder — so resuming at any point of
-    any stream reuses the same compiled code.
+    through ONE reusable chunk program, the serve engine's: ``[B,
+    chunk]`` blocks, the remainder as one more of them, padded
+    (:func:`chunk_window`) — so resuming at any point of any stream
+    reuses the same compiled code and pays one program for its tail.
 
     Greedy replay is deterministic: the logits of the last position are
     (numerically) the same the uninterrupted session would have produced,
     so the argmax — the next token — matches exactly."""
     b, s = tokens.shape
-    if s > cache_capacity(cache):
+    capacity = cache_capacity(cache)
+    if s > capacity:
         raise ValueError(f"resume prefix length {s} exceeds cache "
-                         f"capacity {cache_capacity(cache)}")
+                         f"capacity {capacity}")
     fn = _jitted or _prefill_chunk_jit
+    host = np.asarray(tokens)
+    chunk = min(chunk, capacity)
     logits = None
     off = 0
-    while off + chunk <= s:
-        logits, cache = fn(params, tokens[:, off:off + chunk], cache,
-                           cfg=cfg)
-        off += chunk
     while off < s:
-        logits, cache = fn(params, tokens[:, off:off + 1], cache, cfg=cfg)
-        off += 1
+        start, n_valid = chunk_window(off, s, chunk, capacity)
+        if start != off:
+            cache = dict(cache, pos=np.int32(start))
+        logits, cache = fn(params, padded_chunk(host, start, n_valid, chunk),
+                           cache, cfg=cfg, n_valid=np.int32(n_valid))
+        off = start + n_valid
     return logits, cache
 
 

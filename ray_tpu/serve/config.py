@@ -81,12 +81,11 @@ class DecodeEngineConfig:
     session_idle_ttl_s: float = 120.0
     # -- chunked-prefill admission ----------------------------------------
     # a joining session's prompt is consumed [1, chunk] tokens at a time
-    # BETWEEN shared decode steps on the engine thread (remainder in
-    # [1, 1] tail steps) — admission, failover resume, and the legacy
-    # prefill_chunked path all reuse the same two compiled chunk shapes,
-    # and a join never stalls live streams by more than one chunk
-    # interval.  Matches models.resume_prefill's default so resumes and
-    # admissions share programs.
+    # BETWEEN shared decode steps on the engine thread (the remainder
+    # as one more chunk, padded) — admission and failover resume reuse
+    # the one compiled chunk shape, and a join never stalls live streams
+    # by more than one chunk interval.  Matches models.resume_prefill's
+    # default so resumes and admissions share the program.
     prefill_chunk_tokens: int = 32
     # bound on one `start`/`resume` call: enqueue -> first token (the
     # prompt is prefilled by the engine thread; a wedged engine must not

@@ -29,11 +29,13 @@ Two decode data planes live here:
     steps (``DecodeEngineConfig.prefill_chunk_tokens``), so a join
     stalls live streams by at most one chunk interval instead of a
     whole prompt forward, and TTFT-under-load stops being
-    O(prompt_len) of batch stall.  Admission, failover resume
-    (``op: resume``), and the legacy ``prefill_chunked`` path all
-    dispatch the SAME module-level chunk programs
-    (`models.prefill_chunk_jit`) — at most two compiled prefill shapes
-    per model, whatever the traffic.
+    O(prompt_len) of batch stall.  A prompt's remainder is ONE more
+    program of the same width, padded, the count of its real tokens a
+    traced argument (`models.generate.chunk_window`).  Admission and
+    failover resume (``op: resume``) dispatch the SAME module-level
+    chunk program (`models.prefill_chunk_jit`) — one compiled prefill
+    shape per model, whatever the traffic, and no prompt length
+    compiles anything.
   - **Speculative decoding** (``DecodeEngineConfig.spec_draft`` /
     ``spec_k``): a draft model proposes k tokens per iteration in one
     scanned dispatch (`models.draft_propose_slots`) and the target
@@ -96,8 +98,8 @@ class _EngineSession:
     for a free slot), and *decoding* (cache inserted into its slot of
     the shared batched cache)."""
 
-    __slots__ = ("sid", "slot", "queue", "last_tok", "pos", "done",
-                 "error", "ended", "seq", "last_poll",
+    __slots__ = ("sid", "slot", "queue", "first_tok", "last_tok", "pos",
+                 "done", "error", "ended", "seq", "last_poll",
                  "prompt", "poff", "pcache", "dcache", "plogits",
                  "ready", "shed", "ptoks", "rid", "t_enq", "t_pf",
                  "t_ready")
@@ -116,7 +118,11 @@ class _EngineSession:
         self.ptoks: tuple = ()
         self.slot: Optional[int] = None
         self.queue: collections.deque = collections.deque()
-        self.last_tok: Optional[int] = None  # set when prefill completes
+        # the first token, set when prefill completes: what `start`
+        # replies with.  ``last_tok`` is the newest one, which a decode
+        # step may already have moved on by the time the caller wakes
+        self.first_tok: Optional[int] = None
+        self.last_tok: Optional[int] = None
         self.pos = 0                  # host mirror of cache pos
         self.done = False             # no more tokens will be produced
         self.error: Optional[str] = None
@@ -128,7 +134,7 @@ class _EngineSession:
         self.seq = seq_base + 1
         self.last_poll = time.monotonic()  # leak-reaper clock
         # ---- chunked-admission state (cleared once decoding) ----
-        self.prompt = prompt          # [1, S] int32 still to prefill
+        self.prompt = prompt          # [1, S] int32 on the HOST (numpy)
         self.poff = 0                 # tokens consumed so far
         self.pcache: Any = None       # target batch-1 cache being built
         self.dcache: Any = None       # draft batch-1 cache (speculating)
@@ -261,6 +267,14 @@ class ContinuousBatchingEngine:
             self._verify = self._prof.wrap(
                 "verify", jax.jit(verify_step_slots,
                                   static_argnames=("cfg",)))
+        # the positions a chunk program's window may cover: the cache's,
+        # and no more than a learned position table (the draft's too);
+        # the ONE chunk width follows
+        self._capacity = min([max_len] + [
+            c.max_seq_len for c in (cfg, self._draft_cfg)
+            if c is not None and c.pos_emb == "learned"])
+        self._chunk_tokens = min(
+            max(1, int(engine_cfg.prefill_chunk_tokens)), self._capacity)
         self._spec_k = max(2, int(engine_cfg.spec_k))
         self._spec_disabled = False
         self._spec_fail_streak = 0
@@ -284,6 +298,10 @@ class ContinuousBatchingEngine:
         self.tokens = 0
         self.reaped = 0          # sessions evicted by the idle reaper
         self.prefill_chunks = 0  # chunk programs run for admissions
+        # ... of them those with fewer real tokens than the chunk holds
+        # (a prompt's remainder), and the padding rows those carried
+        self.prefill_tails = 0
+        self.prefill_pad_tokens = 0
         # what the no-drop expert layers routed in decode steps, summed
         # over steps and expert layers: `stats()["moe"]`, and every
         # `_MOE_SPAN_S` seconds one ring span `moe:load` with the sums
@@ -303,7 +321,8 @@ class ContinuousBatchingEngine:
         # from the profiler at snapshot time
         # cumulative seconds: per-session marks (queue, admission,
         # first_token), the engine thread's own phases (the `engine:`
-        # spans of `_loop`), and the chunk programs of one token
+        # spans of `_loop`), and the chunk programs that carried a
+        # prompt's remainder (padded)
         self.phase_s = dict.fromkeys(
             ("queue", "admission", "first_token", "prefill_tail")
             + tuple(self._THREAD_PHASES.values()), 0.0)
@@ -333,10 +352,13 @@ class ContinuousBatchingEngine:
               rid: str = "") -> Dict[str, Any]:
         """Enqueue one batch-1 prompt for chunked admission and block
         until the ENGINE THREAD has prefilled it — `[1, chunk]` blocks
-        (tail in `[1, 1]` steps) interleaved between shared decode
-        steps, so a joining session never stalls live streams by more
-        than one chunk interval and admission reuses the same two
-        compiled chunk shapes as failover resume.  Returns the sid and
+        (the remainder one more of them, padded) interleaved between
+        shared decode steps, so a joining session never stalls live
+        streams by more than one chunk interval and admission reuses
+        the one compiled chunk shape of failover resume.  ``prompt`` is
+        [1, S] token ids, taken to the HOST: the engine fills each
+        chunk's buffer from it (a slice of a device array would be a
+        small program of its own per length).  Returns the sid and
         first token; the session's remaining tokens start flowing once
         a slot frees (iteration-level admission).
 
@@ -346,21 +368,18 @@ class ContinuousBatchingEngine:
         ``seq_base`` so the client can splice the resumed stream in
         without duplicates or gaps.  Resume IS admission here — both
         walk the same chunk programs, so resumes never compile-storm."""
-        import jax.numpy as jnp
+        import numpy as np
 
         from ..exceptions import ReplicaUnavailableError
         s_len = int(prompt.shape[1])
-        if s_len > self.max_len:
+        if s_len > self._capacity:
             raise ValueError(f"prompt length {s_len} exceeds cache "
-                             f"capacity {self.max_len}")
-        # ``ptoks`` is the HOST copy of the prompt (the prefix-index
-        # key).  handle() passes it from the request's own list —
-        # reading it back off the device array here would be an extra
-        # sync on the admission path
+                             f"capacity {self._capacity}")
+        prompt = np.asarray(prompt, np.int32)
+        # ``ptoks`` is the prefix-index key; handle() passes it from the
+        # request's own list
         if ptoks is None and self._prefix is not None:
-            import numpy as np
-            ptoks = tuple(int(t) for t in np.asarray(prompt)[0])
-        prompt = jnp.asarray(prompt, jnp.int32)
+            ptoks = tuple(int(t) for t in prompt[0])
         with self._cond:
             if self._draining:
                 raise ReplicaUnavailableError(self.name)
@@ -403,7 +422,7 @@ class ContinuousBatchingEngine:
             if not sess.ready:   # reaped/force-ended mid-admission
                 self.sessions.pop(sid, None)
                 raise ReplicaUnavailableError(self.name)
-            reply = {"sid": sid, "token": [sess.last_tok],
+            reply = {"sid": sid, "token": [sess.first_tok],
                      "proto": "chunk", "seq": seq_base}
             if sess.done:
                 reply["done"] = True  # prompt/replay prefix filled the cache
@@ -491,6 +510,10 @@ class ContinuousBatchingEngine:
                     "reaped": self.reaped,
                     "steps": self.steps, "tokens": self.tokens,
                     "prefill_chunks": self.prefill_chunks,
+                    # ... those that carried a prompt's remainder, and
+                    # the padding rows they computed for nothing
+                    "prefill_tails": self.prefill_tails,
+                    "prefill_pad_tokens": self.prefill_pad_tokens,
                     # decode_step / cache_insert / prefill_chunk
                     # dispatches that did NOT consume the cache they
                     # were given (0 while donation engages)
@@ -544,7 +567,8 @@ class ContinuousBatchingEngine:
         exists); prefill/decode_dispatch are the profiler's per-program
         dispatch walls (engine-thread occupancy, which is what a token
         actually waits on), prefill_tail the part of prefill spent in
-        chunk programs of ONE token; schedule/admit_host/dispatch/
+        the chunk programs that carried a prompt's REMAINDER (fewer
+        real tokens than the chunk holds); schedule/admit_host/dispatch/
         readback/publish are the engine thread's own phases, the
         seconds of its ``engine:`` spans."""
         wall = self._prof.wall_seconds()
@@ -743,10 +767,13 @@ class ContinuousBatchingEngine:
         live streams by at most one chunk interval instead of a whole
         prompt.  Returns the session's first token once the prompt is
         fully consumed, else None."""
+        import numpy as np
+
         import jax.numpy as jnp
 
         from ..core.runtime_metrics import SERVE_PREFILL_CHUNKS
         from ..models import init_kv_cache
+        from ..models.generate import chunk_window, padded_chunk
         if sess.pcache is None:
             seeded = False
             if self._prefix is not None and sess.ptoks:
@@ -788,30 +815,43 @@ class ContinuousBatchingEngine:
                 if self._spec:
                     sess.dcache = init_kv_cache(self._draft_cfg, 1,
                                                 self.max_len)
-        chunk = max(1, int(self.ecfg.prefill_chunk_tokens))
+        chunk = self._chunk_tokens
         n = int(sess.prompt.shape[1])
-        off = sess.poff
-        take = chunk if n - off >= chunk else 1
-        toks = sess.prompt[:, off:off + take]
+        # ONE shape per model: whole chunks, then the remainder as one
+        # more, padded, its count of real tokens a traced argument
+        start, n_valid = chunk_window(sess.poff, n, chunk, self._capacity)
+        toks = padded_chunk(sess.prompt, start, n_valid, chunk)
+        count = np.int32(n_valid)
+        tail = n_valid < chunk      # a prompt's remainder, padded
+        if start != sess.poff:
+            # the window would pass the capacity (a prefix-seeded offset
+            # is any value): it starts earlier and runs the overlapped
+            # tokens again, which rewrites what their columns hold
+            sess.pcache = dict(sess.pcache, pos=np.int32(start))
+            if self._spec:
+                sess.dcache = dict(sess.dcache, pos=np.int32(start))
         if sess.t_pf is None:          # queue phase ends at the first
             sess.t_pf = time.monotonic()  # chunk program of the prompt
             self.phase_s["queue"] += sess.t_pf - sess.t_enq
         wall0 = self._prof.wall_of("prefill_chunk")
-        sess.plogits, sess.pcache = self._chunk(self.params, toks,
-                                                sess.pcache, cfg=self.cfg)
-        self._shape_seen("prefill_chunk", 1, take)
+        sess.plogits, sess.pcache = self._chunk(
+            self.params, toks, sess.pcache, cfg=self.cfg, n_valid=count)
+        self._shape_seen("prefill_chunk", 1, chunk)
         if self._spec:
-            _, sess.dcache = self._chunk(self._draft_params, toks,
-                                         sess.dcache,
-                                         cfg=self._draft_cfg)
-            self._shape_seen("draft_prefill_chunk", 1, take)
-        if take == 1:      # a prompt's tail: one program per token
+            _, sess.dcache = self._chunk(
+                self._draft_params, toks, sess.dcache,
+                cfg=self._draft_cfg, n_valid=count)
+            self._shape_seen("draft_prefill_chunk", 1, chunk)
+        if tail:
             self.phase_s["prefill_tail"] += \
                 self._prof.wall_of("prefill_chunk") - wall0
-        sess.poff = off + take
-        self._prof.note_tokens("prefill_chunk", take)
-        with self._cond:   # stats() reads this counter
+        sess.poff = start + n_valid
+        self._prof.note_tokens("prefill_chunk", n_valid)
+        with self._cond:   # stats() reads these counters
             self.prefill_chunks += 1
+            if tail:
+                self.prefill_tails += 1
+                self.prefill_pad_tokens += chunk - n_valid
         SERVE_PREFILL_CHUNKS.inc(tags={"deployment": self.name})
         if sess.poff < n:
             return None
@@ -1061,7 +1101,7 @@ class ContinuousBatchingEngine:
                     now_wall - (now_mono - sess.t_enq),
                     now_wall, rid=sess.rid, sid=sess.sid,
                     deployment=self.name)
-                sess.last_tok = first
+                sess.first_tok = sess.last_tok = first
                 sess.pos = sess.poff
                 sess.ready = True
                 sess.prompt = sess.plogits = None
@@ -1266,12 +1306,15 @@ class DecodeSessionCore:
         return self._engine
 
     def handle(self, req: Dict[str, Any]) -> Dict[str, Any]:
+        import numpy as np
+
         import jax.numpy as jnp
 
         from ..models import init_kv_cache
         op = req["op"]
         if op == "start":
-            prompt = jnp.asarray(req["prompt"], jnp.int32)
+            # on the HOST: the engine fills its chunk buffers from it
+            prompt = np.asarray(req["prompt"], np.int32)
             if prompt.ndim == 1:
                 prompt = prompt[None]
             if self._engine_cfg is not None:
@@ -1281,6 +1324,7 @@ class DecodeSessionCore:
                         ptoks=_host_tokens(req["prompt"]),
                         rid=str(req.get("_rid") or ""))
                 return self._group_start(prompt, req["prompt"])
+            prompt = jnp.asarray(prompt)
             cache = init_kv_cache(self.cfg, prompt.shape[0],
                                   self.max_len)
             logits, cache = self._prefill(self.params, prompt,
@@ -1306,7 +1350,7 @@ class DecodeSessionCore:
                 prompt = prompt[0]     # batched form: engine is B=1
             generated = list(req.get("generated") or [])
             replay = list(prompt) + generated
-            prefix = jnp.asarray([replay], jnp.int32)
+            prefix = np.asarray([replay], np.int32)
             return self.engine.start(
                 prefix, self.max_sessions, seq_base=len(generated),
                 teacher_forced=True,

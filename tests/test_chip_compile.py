@@ -125,7 +125,7 @@ def test_create_mesh_tpu_branch_on_the_described_2x2(topo):
 
 @pytest.mark.parametrize("model", ["gpt2-medium", "llama-1b", "llama-8b"])
 @pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
-                                     "prefill_chunk_1"])
+                                     "prefill_chunk_1", "prefill_padded_32"])
 def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
     """At gpt2 head widths (16 heads of 64) the chip keeps a KV cache with
     ``max_len`` minor, whatever the logical order; a layer loop that
@@ -176,10 +176,13 @@ def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
         width = int(program.rsplit("_", 1)[1])
         cache = described(jax.eval_shape(
             lambda: init_kv_cache(cfg, 1, max_len)))
+        # the engine's one shape: the count of real tokens a traced scalar
+        padded = {"n_valid": described(jax.ShapeDtypeStruct((), jnp.int32))} \
+            if program.startswith("prefill_padded") else {}
         lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
                           donate_argnames=("cache",)).lower(
             params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
-            cache, cfg=cfg)
+            cache, cfg=cfg, **padded)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     want = 2 * cache["k"].size * cache["k"].dtype.itemsize
@@ -191,7 +194,7 @@ def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
 
 
 @pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
-                                     "prefill_chunk_1"])
+                                     "prefill_chunk_1", "prefill_padded_32"])
 def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
                                                                  program):
     """Latent attention and routed experts at the published widths of the
@@ -246,10 +249,13 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
         width = int(program.rsplit("_", 1)[1])
         cache = described(jax.eval_shape(
             lambda: init_kv_cache(cfg, 1, max_len)))
+        # the engine's one shape: the count of real tokens a traced scalar
+        padded = {"n_valid": described(jax.ShapeDtypeStruct((), jnp.int32))} \
+            if program.startswith("prefill_padded") else {}
         lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
                           donate_argnames=("cache",)).lower(
             params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
-            cache, cfg=cfg)
+            cache, cfg=cfg, **padded)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert set(cache) == {"kv", "pos"}

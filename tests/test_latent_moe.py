@@ -26,7 +26,8 @@ from ray_tpu.models import (TransformerConfig, cache_gather_slot,
                             init_kv_cache, init_params, init_slot_cache,
                             prefill, prefill_chunk_jit)
 from ray_tpu.models.generate import (_decode_step_slots, _prefill_chunk,
-                                     cache_arrays, cache_capacity, cache_rows)
+                                     cache_arrays, cache_capacity, cache_rows,
+                                     chunk_window, padded_chunk)
 from ray_tpu.ops import latent_attention as mla
 from ray_tpu.ops.moe import moe_ffn, routed_ffn, sigmoid_route
 from ray_tpu.ops.rotary import apply_rotary, rotary_angles
@@ -109,19 +110,23 @@ def test_cache_is_one_array_of_latents_positions_last(model):
 
 
 def test_chunked_prefill_tails_and_slot_decode_match_the_full_forward(model):
-    """Chunks of 32, single-token tails, slot insert, then decode steps over
-    slots at DIFFERENT depths: every logit against the uncached forward."""
+    """Chunks of 32, the tail as one more of them, padded (the engine's
+    walk, `chunk_window`), slot insert with the padded columns above
+    ``pos``, then decode steps over slots at DIFFERENT depths: every logit
+    against the uncached forward."""
     cfg, params, _, toks, full = model
-    lengths = (67, 35)              # 2 chunks + 3 tails; 1 chunk + 3 tails
+    lengths = (67, 35)              # 2 chunks + a tail of 3; 1 chunk + 3
     slots = init_slot_cache(cfg, 2, 96)
     insert = jax.jit(cache_insert_slot)
+    host = np.asarray(toks)
     for b, n in enumerate(lengths):
         pc, off = init_kv_cache(cfg, 1, 96), 0
         while off < n:
-            take = 32 if n - off >= 32 else 1
-            lg, pc = prefill_chunk_jit(params, toks[b:b + 1, off:off + take],
-                                       pc, cfg=cfg)
-            off += take
+            start, n_valid = chunk_window(off, n, 32, 96)
+            lg, pc = prefill_chunk_jit(
+                params, padded_chunk(host[b:b + 1], start, n_valid, 32), pc,
+                cfg=cfg, n_valid=np.int32(n_valid))
+            off = start + n_valid
             assert float(jnp.abs(lg[0] - full[b, off - 1]).max()) < TOL
         assert int(pc["pos"]) == n
         slots = insert(slots, pc, jnp.int32(b))

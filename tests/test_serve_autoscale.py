@@ -269,8 +269,11 @@ def test_engine_prefix_reuse_parity_and_skipped_prefill():
     assert b == ostream(oracle, pb, 10)
     assert st["prefix"]["applied_hits"] == 1, st["prefix"]
     assert st["prefix"]["tokens_reused"] == len(system)
-    # B's admission burned chunks only for its 3-token suffix
-    assert st["prefill_chunks"] - chunks_after_a == len(pb) - len(system)
+    # B's admission burned ONE chunk program, padded, for its 3-token
+    # suffix (A's 14 tokens were one too)
+    assert chunks_after_a == 1
+    assert st["prefill_chunks"] - chunks_after_a == 1
+    assert st["prefill_pad_tokens"] == (32 - 14) + (32 - 3)
     from ray_tpu import metrics
     text = metrics.prometheus_text()
     assert "ray_tpu_serve_prefix_hits_total" in text

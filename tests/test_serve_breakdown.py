@@ -119,24 +119,24 @@ def test_nodelet_folds_phases_tokens_and_shapes():
     tok0 = _one("ray_tpu_serve_tokens_total", deployment=dep)
     _fold(n, {"deployment": dep, "replica": "r0", "occupied": 0,
               "waiting": 0, "max_slots": 8, "tokens": 40,
-              "distinct_program_shapes": 5,
+              "distinct_program_shapes": 4,
               "phase_totals": {"queue": 0.5, "admission": 0.25,
                                "prefill": 1.0, "decode_dispatch": 2.0}})
     assert _one("ray_tpu_serve_tokens_total", deployment=dep) \
         == tok0 + 40
     assert _one("ray_tpu_serve_program_shapes", deployment=dep,
-                replica="r0") == 5.0
+                replica="r0") == 4.0
     assert _one("ray_tpu_serve_phase_seconds_total", deployment=dep,
                 phase="decode_dispatch") == pytest.approx(2.0)
     _fold(n, {"deployment": dep, "replica": "r0", "occupied": 0,
               "waiting": 0, "max_slots": 8, "tokens": 70,
-              "distinct_program_shapes": 6,
+              "distinct_program_shapes": 5,
               "phase_totals": {"queue": 0.5, "admission": 0.25,
                                "prefill": 1.5, "decode_dispatch": 3.5}})
     assert _one("ray_tpu_serve_tokens_total", deployment=dep) \
         == tok0 + 70
     assert _one("ray_tpu_serve_program_shapes", deployment=dep,
-                replica="r0") == 6.0
+                replica="r0") == 5.0
     assert _one("ray_tpu_serve_phase_seconds_total", deployment=dep,
                 phase="decode_dispatch") == pytest.approx(3.5)
     assert _one("ray_tpu_serve_phase_seconds_total", deployment=dep,

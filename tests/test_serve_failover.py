@@ -277,9 +277,9 @@ def test_call_with_retry_honors_retry_after(monkeypatch):
 
 def test_resume_prefill_matches_whole_prompt_prefill():
     """models satellite: the bounded-compile resume prefill (fixed-size
-    chunk programs + single-token tail) produces the same last-position
-    argmax and the same continuation as the whole-prompt prefill, for a
-    prefix length that exercises both program shapes."""
+    chunk programs, the remainder one more, padded) produces the same
+    last-position argmax and the same continuation as the whole-prompt
+    prefill, for a prefix length that has a remainder."""
     import jax
     import jax.numpy as jnp
 
@@ -288,7 +288,7 @@ def test_resume_prefill_matches_whole_prompt_prefill():
     cfg = _tiny_cfg()
     params, _ = init_params(jax.random.PRNGKey(7), cfg)
     prefix = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9]],
-                         jnp.int32)   # 13 = 3 chunks of 4 + 1 tail step
+                         jnp.int32)   # 13 = 3 chunks of 4 + a tail of 1
     lr, cr = prefill(params, prefix, cfg, init_kv_cache(cfg, 1, 64))
     ls, cs = resume_prefill(params, prefix, cfg,
                             init_kv_cache(cfg, 1, 64), chunk=4)

@@ -269,9 +269,9 @@ def test_engine_phase_totals_first_token_and_prefill_tail():
     """The engine at tiny size: `phase_totals` carries the engine thread's
     own phases beside the pipeline's, `first_token` (enqueued -> first
     token exists) covers the queue wait and the prefill inside it, and a
-    prompt of one chunk plus three tokens spends prefill seconds in
-    single-token tail programs.  None of it touches the ring: the per-step
-    work records no span."""
+    prompt of one chunk plus three tokens spends prefill seconds in the
+    ONE padded program that carries its remainder.  None of it touches the
+    ring: the per-step work records no span."""
     import jax.numpy as jnp
 
     from ray_tpu.models import TransformerConfig
@@ -296,11 +296,14 @@ def test_engine_phase_totals_first_token_and_prefill_tail():
                 "publish", "first_token", "prefill_tail", "queue",
                 "admission", "prefill", "decode_dispatch"} == set(ph)
         assert ph["first_token"] > 0 and ph["first_token"] >= ph["queue"]
-        assert 0 < ph["prefill_tail"] <= ph["prefill"]
-        # three single-token programs after one whole chunk
-        shapes = core.engine.stats()["program_shapes"]
-        assert f"prefill_chunk:1x{chunk}" in shapes
-        assert "prefill_chunk:1x1" in shapes
+        assert 0 < ph["prefill_tail"] < ph["prefill"]
+        # one whole chunk, then the three tokens as one more of its shape
+        st = core.engine.stats()
+        assert [s for s in st["program_shapes"]
+                if s.startswith("prefill_chunk")] == [
+                    f"prefill_chunk:1x{chunk}"]
+        assert (st["prefill_chunks"], st["prefill_tails"],
+                st["prefill_pad_tokens"]) == (2, 1, chunk - 3)
         new = {e["name"] for e in tracing.span_events()} - before
         assert not any(n.startswith(("serve_decode_step", "engine:",
                                      "serve_prefill_chunk"))
