@@ -2,9 +2,8 @@
 # tree): native object store + transfer plane, C++ driver API, wheel.
 PY ?= python
 
-.PHONY: all native cpp wheel test smoke bench serve-bench spec-bench obs \
-	attr chaos drain failover spec elastic ha partition autoscale \
-	autoscale-bench serve-breakdown profile lint lint-fast overload \
+.PHONY: all native cpp wheel test smoke obs chaos drain failover spec \
+	elastic ha partition autoscale profile lint lint-fast overload \
 	diskfault containment clean
 
 all: native cpp
@@ -42,12 +41,6 @@ obs:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_observability.py \
 		tests/test_runtime_metrics.py tests/test_events.py \
 		tests/test_control_plane_obs.py -q
-
-# Per-RPC attribution snapshot: scripted task/actor wave, prints the
-# controller handler table and appends it to the SCALE_r06 ledger
-# (ROADMAP item 4's "before" evidence).
-attr:
-	JAX_PLATFORMS=cpu $(PY) bench.py --attr
 
 # Chaos suite: seeded fault-injection units + all four end-to-end
 # recovery scenarios (each runs twice with the same seeds — injection
@@ -134,46 +127,12 @@ lint:
 lint-fast:
 	$(PY) -m ray_tpu.scripts.cli lint --changed
 
-# gpt2-medium train MFU on one chip; needs a TPU, exits non-zero
-# without one.  Kept until the benchmark replaces it.
-bench:
-	$(PY) bench.py
-
-# Serve decode benchmark: generation TTFT plus the continuous-batching
-# streaming lane (1/4/8 concurrent SSE sessions; agg_tok_s and
-# stream_ms_per_tok_p50) through the full proxy -> router -> replica
-# path on the CPU harness.
-serve-bench:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve
-
-# Chunked-prefill + speculative-decoding benchmark (engine level, CPU
-# harness): spec-on vs spec-off ms/tok A/B with byte-identical-output
-# assertion, and TTFT-under-load (long-prompt join into a saturated
-# 8-session batch; stall inflicted on incumbents vs their steady chunk
-# cadence).  Results merge into SERVE_BENCH.json detail.
-spec-bench:
-	JAX_PLATFORMS=cpu $(PY) bench.py --spec-bench
-
 # Autoscale suite: pure policy units (trend/hysteresis/cooldown/SUSPECT
 # down-weight/victim pick), prefix-trie units, engine shared-prefix
 # admission parity, controller loop + chaos-dropped-decision retry,
 # router prefix affinity, per-deployment metrics-history filter.
 autoscale:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_serve_autoscale.py -q
-
-# Bursty multi-tenant chat scenario (shared prefixes, sessions joining
-# and leaving): replica-count-vs-load timeline + prefix-hit/cold TTFT,
-# merged into SERVE_BENCH.json's `autoscale` block.
-autoscale-bench:
-	JAX_PLATFORMS=cpu $(PY) bench.py --autoscale-bench
-
-# Serve attribution table (PR-16 data-plane flight instruments):
-# streamed generation through the full path, reduced to per-phase
-# ms/token (queue / admission / prefill / decode_dispatch /
-# stream_drain) with the >=0.9 coverage bar; merges into
-# SERVE_BENCH.json's `breakdown` block.
-serve-breakdown:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve-breakdown
 
 # Dispatch-profiler / tracing suite: wrap-once shims, compile ledger,
 # MFU table, per-request TTFT/ITL propagation, breakdown coverage,

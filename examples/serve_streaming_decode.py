@@ -21,8 +21,8 @@ def main():
 
             from ray_tpu.models import TransformerConfig
             from ray_tpu.serve.decode_session import DecodeSessionCore
-            # DecodeSessionCore jits prefill/decode once per replica and
-            # locks the session table (the replica runs threaded)
+            # DecodeSessionCore compiles its engine's programs once per
+            # replica and locks its tables (the replica runs threaded)
             self.core = DecodeSessionCore(
                 TransformerConfig.tiny(max_seq_len=64,
                                        attention_impl="reference",

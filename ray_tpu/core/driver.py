@@ -597,7 +597,7 @@ class CoreClient(DeferredRefDecs):
         # every cross-node get of an existing object ate a full 5 s
         # first_slice before the revive loop looked at the directory
         # (measured: 64 MiB node-to-node fetch = 5.09 s wall, ~0.06 s of
-        # it transfer — bench_broadcast.py caught it).
+        # it transfer).
         self._revive_borrowed(oids)  # zero RPCs when none borrowed+missing
         # timeout=0 must stay a non-blocking poll (0 is falsy: no `or`)
         first_slice = 5.0 if timeout is None else min(timeout, 5.0)

@@ -2,7 +2,7 @@
 
 The controller KV is control-plane metadata, not a data plane — yet a
 20k-task wave was measured pushing 812 MB of function-table blobs
-through ``kv_put`` (SCALE_r06 ``rpc_attr_before``).  Writers now divert
+through ``kv_put`` (`state.rpc_attribution`).  Writers now divert
 any value above ``kv_inline_max_bytes`` into the object store and store
 this small marker in KV instead; readers (``_get_function``, spill
 readers) detect the marker and fetch the payload through the normal

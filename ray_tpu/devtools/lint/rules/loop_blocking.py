@@ -3,7 +3,7 @@
 The controller and every nodelet are single asyncio loops; one blocking
 call in a handler stalls heartbeats, leases, WAL replication, and every
 other handler behind it (the actor-scheduler busy-spin of PR 8 and the
-565 ms ``wait_actor`` parks of SCALE_r06 are the measured cost).  This
+565 ms ``wait_actor`` parks once measured are the cost).  This
 rule walks every ``async def`` (skipping nested sync ``def``/``lambda``
 bodies, which usually run off-loop via ``to_thread``/executors) and
 flags:

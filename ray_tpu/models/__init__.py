@@ -20,7 +20,6 @@ from .generate import (  # noqa: F401
     prefill_chunk,
     prefill_chunk_jit,
     prefill_chunked,
-    resume_prefill,
     verify_step_slots,
 )
 from .transformer import (  # noqa: F401

@@ -354,8 +354,8 @@ def chunk_window(off: int, n: int, chunk: int,
     instead and runs the overlapped tokens ``[start, off)`` again: a
     position's columns depend only on the tokens at or before it, so the
     rewrite stores what was there.  Needs ``chunk <= capacity`` and
-    ``off < n <= capacity``; the caller sets the cache's ``pos`` to
-    ``start`` where that is not ``off``."""
+    ``off < n <= capacity``; :func:`prefill_chunk_step` sets the cache's
+    ``pos`` to ``start`` where that is not ``off``."""
     start = min(off, capacity - chunk)
     return start, min(n - start, chunk)
 
@@ -368,79 +368,69 @@ def padded_chunk(tokens, start: int, n_valid: int, chunk: int):
     return buf
 
 
-# Module-level jit: every prefill_chunked caller shares one trace/compile
-# cache (the point of chunking is a bounded, REUSED program).  The cache
-# argument is DONATED: the program extends it in place and the caller's
-# handle is dead after the call — rebind to the returned cache.
-_prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg",),
-                             donate_argnames=("cache",))
+#: The ONE shared chunk program, a module-level jit so that every caller
+#: shares one trace/compile cache (the point of chunking is a bounded,
+#: REUSED program).  The cache argument is DONATED: the program extends it
+#: in place and the caller's handle is dead after the call — rebind to the
+#: returned cache.  Its callers: :func:`prefill_chunk_step` — the serve
+#: engine's admission and failover resume (serve/decode_session.py
+#: `_prefill_advance`, one step between decode steps) and
+#: :func:`prefill_chunked` (the whole prefix at once) — always passes
+#: ``n_valid`` (a whole chunk passes ``chunk``, a prompt's remainder its
+#: length), so a replica compiles ONE prefill shape per model config,
+#: [B, chunk], no matter how many prompts, resumes, or admissions of
+#: whatever length it serves.  The benchmark's outputs check (perfbench,
+#: `_verify`) walks a prompt through this handle on its own, without
+#: ``n_valid``: the unpadded program of the shape it is given, which is
+#: also what :func:`decode_step` traces (a chunk of one).
+prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                            donate_argnames=("cache",))
 
-#: The ONE shared chunk program behind every prefill path: legacy
-#: `prefill_chunked`, failover `resume_prefill`, AND the serve engine's
-#: chunked admission (serve/decode_session.py) all dispatch through this
-#: handle.  The engine and `resume_prefill` always pass ``n_valid`` (a
-#: whole chunk passes ``chunk``, a prompt's remainder its length), so a
-#: replica compiles ONE prefill shape per model config, [B, chunk], no
-#: matter how many prompts, resumes, or admissions of whatever length it
-#: serves; callers that pass none (`prefill_chunked`, `decode_step`) get
-#: the unpadded program of their own shape.
-prefill_chunk_jit = _prefill_chunk_jit
+
+def prefill_chunk_step(fn, params: Params, tokens: np.ndarray, off: int,
+                       cache: KVCache, cfg: TransformerConfig, *,
+                       chunk: int, capacity: int):
+    """ONE step of the host walk of a prompt through the chunk program
+    ``fn`` (:data:`prefill_chunk_jit`, or a wrap of it): host ``tokens``
+    ``[B, n]``, ``off`` of them already in ``cache`` → ``(logits, cache',
+    new offset, n_valid)``.  The window and the padding are
+    :func:`chunk_window`'s and :func:`padded_chunk`'s; where the window
+    starts before ``off`` (it would have passed ``capacity``) the cache's
+    ``pos`` is set back to it, and the overlapped tokens run again, which
+    rewrites what their columns hold."""
+    start, n_valid = chunk_window(off, tokens.shape[1], chunk, capacity)
+    if start != off:
+        cache = dict(cache, pos=np.int32(start))
+    logits, cache = fn(params, padded_chunk(tokens, start, n_valid, chunk),
+                       cache, cfg=cfg, n_valid=np.int32(n_valid))
+    return logits, cache, start + n_valid, n_valid
 
 
 def prefill_chunked(params: Params, tokens: jnp.ndarray,
                     cfg: TransformerConfig, cache: KVCache,
-                    *, chunk: int = 512,
+                    *, chunk: int,
                     _jitted=None) -> Tuple[jnp.ndarray, KVCache]:
-    """Whole-prompt prefill as ceil(s/chunk) reusable chunk programs
-    (at most two compiled shapes: ``chunk`` and the tail remainder).
-    Drop-in for :func:`prefill` where compile size must stay bounded."""
-    b, s = tokens.shape
-    if s > cache_capacity(cache):
-        raise ValueError(f"prompt length {s} exceeds cache capacity "
-                         f"{cache_capacity(cache)}")
-    fn = _jitted or _prefill_chunk_jit
-    logits = None
-    for off in range(0, s, chunk):
-        logits, cache = fn(params, tokens[:, off:off + chunk], cache,
-                           cfg=cfg)
-    return logits, cache
-
-
-def resume_prefill(params: Params, tokens: jnp.ndarray,
-                   cfg: TransformerConfig, cache: KVCache,
-                   *, chunk: int = 32,
-                   _jitted=None) -> Tuple[jnp.ndarray, KVCache]:
-    """Teacher-forced prefix prefill for decode-session failover.
-
-    A resumed session replays ``prompt + tokens-generated-so-far`` into a
-    fresh cache, and that prefix has an *arbitrary* length — one compile
-    per resume length (the whole-prompt :func:`prefill` behavior) would
-    turn every failover into a compile storm.  This walks the prefix
-    through ONE reusable chunk program, the serve engine's: ``[B,
-    chunk]`` blocks, the remainder as one more of them, padded
-    (:func:`chunk_window`) — so resuming at any point of any stream
-    reuses the same compiled code and pays one program for its tail.
-
-    Greedy replay is deterministic: the logits of the last position are
-    (numerically) the same the uninterrupted session would have produced,
+    """Whole-prefix prefill as ceil(s/chunk) programs of ONE shape:
+    ``[B, chunk]`` blocks, the remainder as one more of them, padded.
+    Drop-in for :func:`prefill` where compile size must stay bounded, and
+    what a failover resume amounts to: a replayed prefix (prompt + tokens
+    generated so far) has an *arbitrary* length, and no length compiles
+    anything.  Greedy replay is deterministic: the logits of the last
+    position are (numerically) those the uninterrupted session produced,
     so the argmax — the next token — matches exactly."""
-    b, s = tokens.shape
+    s = tokens.shape[1]
     capacity = cache_capacity(cache)
     if s > capacity:
-        raise ValueError(f"resume prefix length {s} exceeds cache "
-                         f"capacity {capacity}")
-    fn = _jitted or _prefill_chunk_jit
+        raise ValueError(f"prompt length {s} exceeds cache capacity "
+                         f"{capacity}")
+    fn = _jitted or prefill_chunk_jit
     host = np.asarray(tokens)
     chunk = min(chunk, capacity)
-    logits = None
-    off = 0
+    logits, off = None, 0
     while off < s:
-        start, n_valid = chunk_window(off, s, chunk, capacity)
-        if start != off:
-            cache = dict(cache, pos=np.int32(start))
-        logits, cache = fn(params, padded_chunk(host, start, n_valid, chunk),
-                           cache, cfg=cfg, n_valid=np.int32(n_valid))
-        off = start + n_valid
+        logits, cache, off, _ = prefill_chunk_step(
+            fn, params, host, off, cache, cfg, chunk=chunk,
+            capacity=capacity)
     return logits, cache
 
 
@@ -726,8 +716,9 @@ def generate(params: Params, prompt: jnp.ndarray, *,
         raise ValueError(f"max_new_tokens must be >= 1, "
                          f"got {max_new_tokens}")
     total = max_len or (s + max_new_tokens)
-    if total < s + max_new_tokens:
+    if total < s + max_new_tokens - 1:
         # a short cache would silently clamp writes onto the last slot
+        # (the last token is sampled, never written)
         raise ValueError(
             f"max_len={total} < prompt ({s}) + max_new_tokens "
             f"({max_new_tokens})")

@@ -746,9 +746,9 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
     uses: (a) effective batches beyond HBM (activation memory scales
     with the microbatch), and (b) on memory-bound chips the Adam-moment
     read/write traffic amortizes over ``accum_steps`` × more tokens —
-    measured on the v5e as the difference between gpt2-medium's
-    batch-bound 0.3865 MFU and the accumulated operating point
-    (TPU_PROBE15_r05.jsonl)."""
+    the operating point `gpt2-medium.train-1024` runs (32 sequences
+    a step in micro-batches of 8; PERF.md section 4 has its memory and
+    section 5 its step time)."""
 
     def grad_fn(params, batch):
         return jax.value_and_grad(

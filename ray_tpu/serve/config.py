@@ -84,8 +84,7 @@ class DecodeEngineConfig:
     # BETWEEN shared decode steps on the engine thread (the remainder
     # as one more chunk, padded) — admission and failover resume reuse
     # the one compiled chunk shape, and a join never stalls live streams
-    # by more than one chunk interval.  Matches models.resume_prefill's
-    # default so resumes and admissions share the program.
+    # by more than one chunk interval.
     prefill_chunk_tokens: int = 32
     # bound on one `start`/`resume` call: enqueue -> first token (the
     # prompt is prefilled by the engine thread; a wedged engine must not

@@ -4,59 +4,50 @@ The serving-side face of the model runtime (reference: Ray Serve
 delegates streaming decode to external engines like vLLM —
 /root/reference/doc/source/serve/index.md; here it is in-tree): a
 replica holds per-session KV caches so `start` pays one prefill and
-every `next` is a single decode step.  Used by the streaming-decode
-example and `bench.py --serve`; wrap it in a `@serve.deployment` whose
-``__call__`` forwards to :meth:`handle`.
+every later token is one slot of a shared decode step.  Used by the
+streaming-decode examples, `chip_smoke.py` and the benchmark's serve
+cells; wrap a :class:`DecodeSessionCore` in a `@serve.deployment` whose
+``__call__`` forwards to :meth:`DecodeSessionCore.handle`.
 
-Two decode data planes live here:
+ONE decode data plane lives here, and one protocol with string sids
+over it (:class:`DecodeSessionCore`): the **continuous-batching
+engine**, a fixed-slot batched KV cache (`models.init_slot_cache`) and
+ONE jitted batched decode step shared by every live session.  A
+background loop decodes all active slots each iteration; sessions join
+and vacate BETWEEN steps (iteration-level admission — vLLM's scheduling
+insight), never recompiling: the batch shape is pinned at ``max_slots``
+and the slot index of admission is a traced argument.  Decoded tokens
+land in per-session bounded queues that the proxy drains via
+``next_chunk`` (N tokens per RPC round trip), so neither a batch-1
+decode step nor an RPC per token is on the path.  A replica therefore
+has exactly one thing to route, autoscale and journal, and never
+compiles a whole-prompt prefill program: the whole-prompt reference
+(`models.generate`) is the tests' oracle, and shares no code with the
+programs below.
 
-* **Continuous-batching engine** (default): a fixed-slot batched KV
-  cache (`models.init_slot_cache`) and ONE jitted batched decode step
-  shared by every live session.  A background loop decodes all active
-  slots each iteration; sessions join and vacate BETWEEN steps
-  (iteration-level admission — vLLM's scheduling insight), never
-  recompiling: the batch shape is pinned at ``max_slots`` and the slot
-  index of admission is a traced argument.  Decoded tokens land in
-  per-session bounded queues that the proxy drains via ``next_chunk``
-  (N tokens per RPC round trip) — this is what closes the measured 4×
-  serve-vs-raw decode gap: batch-1 decode steps and one RPC per token
-  both disappear.
+Two model-side optimisations compound inside the loop:
 
-  Two model-side optimisations compound inside the loop:
-
-  - **Chunked-prefill admission**: a joining session's prompt is
-    consumed ``[1, chunk]`` tokens at a time between shared decode
-    steps (``DecodeEngineConfig.prefill_chunk_tokens``), so a join
-    stalls live streams by at most one chunk interval instead of a
-    whole prompt forward, and TTFT-under-load stops being
-    O(prompt_len) of batch stall.  A prompt's remainder is ONE more
-    program of the same width, padded, the count of its real tokens a
-    traced argument (`models.generate.chunk_window`).  Admission and
-    failover resume (``op: resume``) dispatch the SAME module-level
-    chunk program (`models.prefill_chunk_jit`) — one compiled prefill
-    shape per model, whatever the traffic, and no prompt length
-    compiles anything.
-  - **Speculative decoding** (``DecodeEngineConfig.spec_draft`` /
-    ``spec_k``): a draft model proposes k tokens per iteration in one
-    scanned dispatch (`models.draft_propose_slots`) and the target
-    verifies all of them plus a bonus token in one k+1-wide batched
-    forward (`models.verify_step_slots`) — 2 dispatches for 1..k+1
-    tokens per slot.  Greedy acceptance is exact-match, so streams
-    (and the PR-5 seq-based replay journal) stay byte-identical to
-    plain decode; any draft/verify fault falls back to a plain step
-    (chaos site ``serve.spec_verify``), never corrupting a stream.
-
-* **Eager per-call path** (``engine=False`` ONLY): the original
-  pop-as-lease session table, one eager `next` per token.  Kept solely
-  for non-LM deployments and as the parity oracle in tests — an
-  engine-enabled core routes EVERYTHING through the engine (B>1 prompt
-  batches become per-row engine sessions behind a group sid), so a
-  replica has exactly one decode data plane to route, autoscale, and
-  journal, and never compiles the whole-prompt prefill program at all.
-
-prefill/decode compile ONCE per replica (config static, cache position
-dynamic) — eager per-step dispatch costs ~100x on small models, which
-the round-4 TTFT benchmark measured directly (700 ms → 4.8 ms/token).
+- **Chunked-prefill admission**: a joining session's prompt is
+  consumed ``[1, chunk]`` tokens at a time between shared decode steps
+  (``DecodeEngineConfig.prefill_chunk_tokens``), so a join stalls live
+  streams by at most one chunk interval instead of a whole prompt
+  forward, and TTFT-under-load stops being O(prompt_len) of batch
+  stall.  A prompt's remainder is ONE more program of the same width,
+  padded, the count of its real tokens a traced argument
+  (`models.generate.prefill_chunk_step`).  Admission and failover
+  resume (``op: resume``) dispatch the SAME module-level chunk program
+  (`models.prefill_chunk_jit`) — one compiled prefill shape per model,
+  whatever the traffic, and no prompt length compiles anything.
+- **Speculative decoding** (``DecodeEngineConfig.spec_draft`` /
+  ``spec_k``): a draft model proposes k tokens per iteration in one
+  scanned dispatch (`models.draft_propose_slots`) and the target
+  verifies all of them plus a bonus token in one k+1-wide batched
+  forward (`models.verify_step_slots`) — 2 dispatches for 1..k+1
+  tokens per slot.  Greedy acceptance is exact-match, so streams (and
+  the seq-based replay journal of serve/failover.py) stay
+  byte-identical to plain decode; any draft/verify fault falls back to
+  a plain step (chaos site ``serve.spec_verify``), never corrupting a
+  stream.
 """
 
 from __future__ import annotations
@@ -234,10 +225,10 @@ class ContinuousBatchingEngine:
         self.prefix_tokens_reused = 0  # prefill tokens skipped
         self._last_metrics_push = 0.0
         # the chunk program is the MODULE-LEVEL shared jit: admission
-        # here, failover resume (models.resume_prefill), and the legacy
-        # prefill_chunked path all hit one compile cache.  The profiler
-        # wrap is idempotent, so an engine restart re-wrapping the same
-        # shared jit never stacks a second timer over it.
+        # and failover resume here and `models.prefill_chunked` all hit
+        # one compile cache.  The profiler wrap is idempotent, so an
+        # engine restart re-wrapping the same shared jit never stacks a
+        # second timer over it.
         self._chunk = self._prof.wrap(
             "prefill_chunk", self._counting_copies(prefill_chunk_jit, 2))
         # ---- speculative decoding ----
@@ -423,7 +414,7 @@ class ContinuousBatchingEngine:
                 self.sessions.pop(sid, None)
                 raise ReplicaUnavailableError(self.name)
             reply = {"sid": sid, "token": [sess.first_tok],
-                     "proto": "chunk", "seq": seq_base}
+                     "seq": seq_base}
             if sess.done:
                 reply["done"] = True  # prompt/replay prefix filled the cache
         return reply
@@ -767,13 +758,11 @@ class ContinuousBatchingEngine:
         live streams by at most one chunk interval instead of a whole
         prompt.  Returns the session's first token once the prompt is
         fully consumed, else None."""
-        import numpy as np
-
         import jax.numpy as jnp
 
         from ..core.runtime_metrics import SERVE_PREFILL_CHUNKS
         from ..models import init_kv_cache
-        from ..models.generate import chunk_window, padded_chunk
+        from ..models.generate import prefill_chunk_step
         if sess.pcache is None:
             seeded = False
             if self._prefix is not None and sess.ptoks:
@@ -816,36 +805,26 @@ class ContinuousBatchingEngine:
                     sess.dcache = init_kv_cache(self._draft_cfg, 1,
                                                 self.max_len)
         chunk = self._chunk_tokens
-        n = int(sess.prompt.shape[1])
-        # ONE shape per model: whole chunks, then the remainder as one
-        # more, padded, its count of real tokens a traced argument
-        start, n_valid = chunk_window(sess.poff, n, chunk, self._capacity)
-        toks = padded_chunk(sess.prompt, start, n_valid, chunk)
-        count = np.int32(n_valid)
-        tail = n_valid < chunk      # a prompt's remainder, padded
-        if start != sess.poff:
-            # the window would pass the capacity (a prefix-seeded offset
-            # is any value): it starts earlier and runs the overlapped
-            # tokens again, which rewrites what their columns hold
-            sess.pcache = dict(sess.pcache, pos=np.int32(start))
-            if self._spec:
-                sess.dcache = dict(sess.dcache, pos=np.int32(start))
         if sess.t_pf is None:          # queue phase ends at the first
             sess.t_pf = time.monotonic()  # chunk program of the prompt
             self.phase_s["queue"] += sess.t_pf - sess.t_enq
         wall0 = self._prof.wall_of("prefill_chunk")
-        sess.plogits, sess.pcache = self._chunk(
-            self.params, toks, sess.pcache, cfg=self.cfg, n_valid=count)
+        # ONE shape per model: whole chunks, then the remainder as one
+        # more, padded, its count of real tokens a traced argument
+        off, window = sess.poff, dict(chunk=chunk, capacity=self._capacity)
+        sess.plogits, sess.pcache, sess.poff, n_valid = prefill_chunk_step(
+            self._chunk, self.params, sess.prompt, off, sess.pcache,
+            self.cfg, **window)
         self._shape_seen("prefill_chunk", 1, chunk)
         if self._spec:
-            _, sess.dcache = self._chunk(
-                self._draft_params, toks, sess.dcache,
-                cfg=self._draft_cfg, n_valid=count)
+            _, sess.dcache, _, _ = prefill_chunk_step(
+                self._chunk, self._draft_params, sess.prompt, off,
+                sess.dcache, self._draft_cfg, **window)
             self._shape_seen("draft_prefill_chunk", 1, chunk)
+        tail = n_valid < chunk      # a prompt's remainder, padded
         if tail:
             self.phase_s["prefill_tail"] += \
                 self._prof.wall_of("prefill_chunk") - wall0
-        sess.poff = start + n_valid
         self._prof.note_tokens("prefill_chunk", n_valid)
         with self._cond:   # stats() reads these counters
             self.prefill_chunks += 1
@@ -853,7 +832,7 @@ class ContinuousBatchingEngine:
                 self.prefill_tails += 1
                 self.prefill_pad_tokens += chunk - n_valid
         SERVE_PREFILL_CHUNKS.inc(tags={"deployment": self.name})
-        if sess.poff < n:
+        if sess.poff < int(sess.prompt.shape[1]):
             return None
         return int(jnp.argmax(sess.plogits, axis=-1)
                    .astype(jnp.int32)[0])
@@ -1191,53 +1170,52 @@ def _host_tokens(prompt) -> Optional[tuple]:
 
 
 class DecodeSessionCore:
-    """Session store + compiled prefill/decode over one model.
+    """The decode-session protocol over ONE continuous-batching engine
+    and one model: what a replica's ``__call__`` forwards to.
 
     Protocol (msgpack/JSON-native):
-      {"op": "start", "prompt": [S ints] | [[S ints]xB]} ->
-          {"sid": str|int, "token": [B ints]} (+ {"proto": "chunk",
-          "seq": 0} when the continuous-batching engine owns the
-          session)
+      {"op": "start", "prompt": [S ints] | [[S ints]]} ->
+          {"sid": str, "token": [1 int], "seq": 0} (+ {"done": true}
+          when the prompt filled the cache)
       {"op": "resume", "prompt": [S ints], "generated": [G ints]} ->
-          same shape as an engine start, with "seq": G — failover
+          same shape as a start, with "seq": G — failover
           re-admission: teacher-forced prefix prefill of
           prompt+generated into a fresh engine slot; the returned token
           is exactly the one the uninterrupted session would have
           produced next (greedy decode is deterministic)
-      {"op": "next", "sid": ...} -> {"token": [B ints]}
       {"op": "next_chunk", "sid": str, "max_tokens": N} ->
           {"tokens": [<=N ints], "done": bool, "seq": first token's
           seq} (+ {"migrating": true} when the replica is draining and
           the session must be resumed elsewhere)
-      {"op": "end", "sid": ...} -> {"ended": bool}
+      {"op": "next", "sid": str} -> {"token": [1 int]} (+ {"eos": true}):
+          the one-token form of ``next_chunk``
+      {"op": "end", "sid": str} -> {"ended": bool}
       {"op": "stats"} -> engine/session counters (tests, dashboards)
 
-    Engine sessions (single-prompt starts, the serving hot path) carry
-    STRING sids of the form ``<replica_tag>:<n>`` — the prefix is the
-    owning replica, which the proxy/router use for sid-sticky routing.
-    Batched (B>1) prompts on an engine core are admitted row-by-row as
-    engine sessions behind a ``grp:<n>`` sid that keeps the legacy
-    reply shape.  Only ``engine=False`` cores (non-LM deployments, the
-    parity oracle in tests) still run the eager integer-sid path:
-    pop-as-lease (a pipelined second `next` on the SAME sid — or a
-    stale/unknown sid — gets an ``{"error": ...}`` reply instead of
-    racing the first), LRU-bounded ``max_sessions``.
+    Sids are STRINGS of the form ``<replica_tag>:<n>`` — the prefix is
+    the owning replica, which the proxy/router use for sid-sticky
+    routing.  A sid the engine does not hold (ended, evicted, never
+    started, not a string) gets an ``{"error": ...}`` reply, never an
+    exception.  A start with B>1 prompts admits each row as its own
+    engine session behind a ``grp:<n>`` sid, whose ``next`` replies
+    ``{"token": [B ints]}``; ``max_sessions`` bounds the abandoned
+    sessions the engine keeps (LRU).
     """
 
     def __init__(self, cfg, max_len: int, seed: int = 0,
                  params: Any = None, max_sessions: int = 64,
-                 prefill_chunk: int = 0,
-                 engine: Any = True):
-        """``prefill_chunk > 0`` prefills in fixed-size chunks through
-        one small reusable program instead of a whole-prompt compile —
-        for models whose full-prompt flash prefill is a compile-helper
-        killer (llama-family GQA, SURVEY §9); it also overrides the
-        engine's ``prefill_chunk_tokens`` so the legacy path and the
-        engine's chunked admission share one chunk shape.  ``engine``
-        is True (default), False, or a :class:`DecodeEngineConfig`."""
+                 engine: Optional[DecodeEngineConfig] = None):
+        """``engine`` is the engine's :class:`DecodeEngineConfig`
+        (``None``: its defaults); ``params`` the model's weights
+        (``None``: ``init_params(PRNGKey(seed), cfg)``)."""
         import jax
 
         from ..models import init_params
+        if engine is None:
+            engine = DecodeEngineConfig()
+        if not isinstance(engine, DecodeEngineConfig):
+            raise TypeError(f"engine must be a DecodeEngineConfig or None, "
+                            f"got {engine!r}")
         self.cfg = cfg
         self.max_len = max_len
         self.max_sessions = max_sessions
@@ -1245,52 +1223,21 @@ class DecodeSessionCore:
             params, _ = init_params(jax.random.PRNGKey(seed), cfg)
         self.params = params
         self._lock = threading.Lock()
-        self.sessions: Dict[int, Any] = {}   # insertion-ordered = LRU
-        self._next_sid = 0
-        # B>1 prompt batches on an engine core: each row is its own
-        # engine session; the group keeps the legacy one-reply-per-step
-        # protocol shape (sid + [B] tokens) over the SINGLE data plane
+        # B>1 prompt batches: each row is its own engine session; the
+        # group keeps a one-reply-per-step shape (sid + [B] tokens)
         self._groups: Dict[str, List[str]] = {}
         self._next_gid = 0
-        if engine is False or engine is None:
-            self._engine_cfg = None
-        elif isinstance(engine, DecodeEngineConfig):
-            self._engine_cfg = engine
-        else:
-            self._engine_cfg = DecodeEngineConfig()
-        if self._engine_cfg is None:
-            # the eager per-call path survives ONLY as the explicit
-            # opt-out (`engine=False`): non-LM deployments and the
-            # parity oracle in tests.  Engine cores never compile the
-            # whole-prompt prefill or the batch-1 decode step at all —
-            # exactly one decode data plane per replica.
-            from ..models import decode_step, prefill, prefill_chunked
-            if prefill_chunk > 0:
-                def chunked(params, prompt, *, cfg, cache):
-                    return prefill_chunked(params, prompt, cfg, cache,
-                                           chunk=prefill_chunk)
-
-                self._prefill = chunked
-            else:
-                self._prefill = jax.jit(prefill, static_argnames=("cfg",))
-            self._decode = jax.jit(decode_step, static_argnames=("cfg",))
-        if self._engine_cfg is not None and prefill_chunk > 0:
-            # one chunk width per replica: the engine's admission/resume
-            # programs and the legacy prefill_chunked path must share
-            # shapes, or each path compiles its own chunk program
-            import dataclasses as _dc
-            self._engine_cfg = _dc.replace(
-                self._engine_cfg, prefill_chunk_tokens=prefill_chunk)
+        self._engine_cfg = engine
         self._engine: Optional[ContinuousBatchingEngine] = None
 
     @property
-    def engine(self) -> Optional[ContinuousBatchingEngine]:
+    def engine(self) -> ContinuousBatchingEngine:
         """The continuous-batching engine, created on first use (slot
         cache memory is only paid by cores that actually serve).
         Creation is locked: two concurrent `start` ops racing the lazy
         init would strand one session in an engine nothing references
         — and hand out colliding ``<tag>:0`` sids."""
-        if self._engine is None and self._engine_cfg is not None:
+        if self._engine is None:
             with self._lock:
                 if self._engine is None:
                     name, tag = "decode", "local"
@@ -1307,44 +1254,23 @@ class DecodeSessionCore:
 
     def handle(self, req: Dict[str, Any]) -> Dict[str, Any]:
         import numpy as np
-
-        import jax.numpy as jnp
-
-        from ..models import init_kv_cache
         op = req["op"]
         if op == "start":
             # on the HOST: the engine fills its chunk buffers from it
             prompt = np.asarray(req["prompt"], np.int32)
             if prompt.ndim == 1:
                 prompt = prompt[None]
-            if self._engine_cfg is not None:
-                if prompt.shape[0] == 1:
-                    return self.engine.start(
-                        prompt, self.max_sessions,
-                        ptoks=_host_tokens(req["prompt"]),
-                        rid=str(req.get("_rid") or ""))
-                return self._group_start(prompt, req["prompt"])
-            prompt = jnp.asarray(prompt)
-            cache = init_kv_cache(self.cfg, prompt.shape[0],
-                                  self.max_len)
-            logits, cache = self._prefill(self.params, prompt,
-                                          cfg=self.cfg, cache=cache)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            with self._lock:
-                sid = self._next_sid
-                self._next_sid += 1
-                self.sessions[sid] = (cache, tok)
-                while len(self.sessions) > self.max_sessions:
-                    self.sessions.pop(next(iter(self.sessions)))
-            return {"sid": sid, "token": tok.tolist()}
+            if prompt.shape[0] == 1:
+                return self.engine.start(
+                    prompt, self.max_sessions,
+                    ptoks=_host_tokens(req["prompt"]),
+                    rid=str(req.get("_rid") or ""))
+            return self._group_start(prompt, req["prompt"])
         if op == "resume":
             # failover re-admission (serve/failover.py): replay the
             # journal — prompt + every token the client already has —
             # through a teacher-forced prefix prefill into a fresh
             # engine slot, continuing seqs at len(generated)
-            if self._engine_cfg is None:
-                return {"error": "resume requires the continuous-"
-                                 "batching engine (engine=False core)"}
             prompt = req["prompt"]
             if prompt and isinstance(prompt[0], (list, tuple)):
                 prompt = prompt[0]     # batched form: engine is B=1
@@ -1357,53 +1283,38 @@ class DecodeSessionCore:
                 ptoks=tuple(int(t) for t in replay),
                 rid=str(req.get("_rid") or ""))
         if op == "stats":
-            out = {"legacy_sessions": len(self.sessions),
-                   "groups": len(self._groups)}
+            out = {"groups": len(self._groups)}
             if self._engine is not None:
                 out["engine"] = self._engine.stats()
             return out
         sid = req.get("sid")
         if isinstance(sid, str) and sid.startswith("grp:"):
             return self._group_op(op, sid)
+        # an engine that was never started holds no session either: the
+        # lookups below give the engine's own unknown-session replies
         if op == "end":
-            if isinstance(sid, str):
-                if self._engine is None:
-                    return {"ended": False}
-                return {"ended": self._engine.end(sid)}
-            with self._lock:
-                return {"ended":
-                        self.sessions.pop(sid, None) is not None}
+            return {"ended": self.engine.end(sid)}
         if op == "next_chunk":
-            if not isinstance(sid, str) or self._engine is None:
-                # legacy sessions have no token queue: one step per call
-                out = self._legacy_next(sid)
-                if "error" in out:
-                    return out
-                return {"tokens": out["token"], "done": False}
-            return self._engine.next_chunk(
+            return self.engine.next_chunk(
                 sid, req.get("max_tokens", 16), req.get("timeout_s"))
-        # op == "next"
-        if isinstance(sid, str) and self._engine is not None:
-            out = self._engine.next_chunk(sid, 1)
-            if "error" in out:
-                return out
-            if not out["tokens"]:
-                return {"error": f"session {sid!r} finished "
-                                 f"(cache capacity reached)"}
-            reply = {"token": out["tokens"]}
-            if out["done"]:
-                reply["eos"] = True
-            return reply
-        return self._legacy_next(sid)
+        # op == "next": the one-token form of next_chunk
+        out = self.engine.next_chunk(sid, 1)
+        if "error" in out:
+            return out
+        if not out["tokens"]:
+            return {"error": f"session {sid!r} finished "
+                             f"(cache capacity reached)"}
+        reply = {"token": out["tokens"]}
+        if out["done"]:
+            reply["eos"] = True
+        return reply
 
     def _group_start(self, prompt, raw_prompt=None) -> Dict[str, Any]:
         """B>1 prompts through the ONE data plane: admit each row as
         its own engine session and hand back a group sid whose `next`
-        pops one token per member — the legacy per-call protocol shape
-        ({sid, token: [B]}) without the legacy prefill/decode programs.
-        A member shed mid-admission (slots + wait queue full) releases
-        the members already admitted and re-raises, so a group is all
-        or nothing."""
+        pops one token per member ({sid, token: [B]}).  A member shed
+        mid-admission (slots + wait queue full) releases the members
+        already admitted and re-raises, so a group is all or nothing."""
         sids, toks = [], []
         try:
             for row in range(int(prompt.shape[0])):
@@ -1430,7 +1341,7 @@ class DecodeSessionCore:
     def _group_op(self, op: str, gid: str) -> Dict[str, Any]:
         with self._lock:
             sids = self._groups.get(gid)
-        if sids is None or self._engine is None:
+        if sids is None:
             return {"error": f"unknown session {gid!r} (ended, "
                              f"evicted, or never started)"}
         if op == "end":
@@ -1441,7 +1352,7 @@ class DecodeSessionCore:
             return {"ended": True}
         # op in ("next", "next_chunk"): one decode step for every
         # member (rows share a prompt length, so they reach the cache
-        # cap together, like the legacy shared-pos batch did)
+        # cap together)
         toks = []
         for s in sids:
             out = self._engine.next_chunk(s, 1)
@@ -1454,18 +1365,3 @@ class DecodeSessionCore:
         if op == "next_chunk":
             return {"tokens": toks, "done": False}
         return {"token": toks}
-
-    def _legacy_next(self, sid) -> Dict[str, Any]:
-        import jax.numpy as jnp
-        with self._lock:
-            entry = self.sessions.pop(sid, None)
-        if entry is None:
-            return {"error": f"unknown session {sid!r} (ended, "
-                             f"evicted, or decoding in another request)"}
-        cache, tok = entry
-        logits, cache = self._decode(self.params, tok, cache,
-                                     cfg=self.cfg)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        with self._lock:
-            self.sessions[sid] = (cache, tok)
-        return {"token": tok.tolist()}

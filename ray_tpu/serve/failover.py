@@ -89,8 +89,7 @@ class FailoverSession:
         self._timeout = failover_timeout_s
         self._transient_retries = max(0, int(transient_retries))
         self.journal: List[int] = []   # every token delivered, in order
-        self.sid: Optional[Any] = None
-        self.chunked = False
+        self.sid: Optional[str] = None
         self.done = False
         self.failovers = 0
         self._sticky: Optional[str] = None
@@ -100,18 +99,17 @@ class FailoverSession:
 
     def start(self) -> Any:
         """Issue the start op; returns the raw reply for the caller to
-        emit.  Engine (``proto: "chunk"``) replies arm the journal;
-        anything else (legacy core, error replies) passes through for
-        the caller's fallback handling."""
+        emit.  A dict without ``"error"`` IS a session: its sid pins the
+        owner and its token opens the journal.  Anything else (an error
+        reply) passes through, and the session stays unstarted
+        (``sid is None``)."""
         out = self._call(self._payload, None)
         if not isinstance(out, dict) or "error" in out:
             return out
         self.sid = out.get("sid")
-        if out.get("proto") == "chunk":
-            self.chunked = True
-            self._sticky = self._owner_of(self.sid)
-            self.journal.extend(out.get("token") or ())
-            self.done = bool(out.get("done"))
+        self._sticky = self._owner_of(self.sid)
+        self.journal.extend(out.get("token") or ())
+        self.done = bool(out.get("done"))
         return out
 
     def next_tokens(self, max_tokens: int) -> Dict[str, Any]:
@@ -206,9 +204,10 @@ class FailoverSession:
         fresh tokens, or None on a forward gap (lost destructive pop)."""
         toks = list(out.get("tokens") if out.get("tokens") is not None
                     else out.get("token") or ())
-        seq = out.get("seq")
-        if seq is None:
-            seq = len(self.journal)    # legacy reply: trust ordering
+        if "seq" not in out:
+            raise StreamFailedError(
+                f"protocol violation from {self._name}: no seq in {out!r}")
+        seq = out["seq"]
         if seq > len(self.journal):
             return None
         fresh = toks[len(self.journal) - seq:]

@@ -151,21 +151,18 @@ class HTTPProxy:
             per token, so clients get tokens as they decode instead of
             one request per token.
 
-            Two transport lanes: replicas whose `start` reply announces
-            ``proto: "chunk"`` (the continuous-batching engine) are
-            drained via ``next_chunk`` — ONE sid-sticky router round
-            trip per N buffered tokens — while legacy replicas fall back
-            to one `next` RPC per token.  Either way the CLIENT contract
-            is unchanged: one SSE event per token.
-
-            The chunked lane rides a :class:`FailoverSession`
-            (serve/failover.py): the proxy journals every emitted token,
-            and an owner-replica death or drain mid-stream is healed by
-            a teacher-forced resume on a healthy replica — the client
-            sees a stall, never an error and never a duplicate/missing
-            token.  A vanished CLIENT is cancelled eagerly: the loop
-            checks the transport each chunk and releases the session
-            instead of decoding to max_tokens into a full queue."""
+            The stream rides a :class:`FailoverSession`
+            (serve/failover.py): tokens are drained via ``next_chunk``
+            — ONE sid-sticky router round trip per N buffered tokens —
+            and emitted one SSE event per token; the proxy journals
+            every emitted token, and an owner-replica death or drain
+            mid-stream is healed by a teacher-forced resume on a
+            healthy replica — the client sees a stall, never an error
+            and never a duplicate/missing token.  A start that replies
+            ``{"error": ...}`` ends the stream with that error in band.
+            A vanished CLIENT is cancelled eagerly: the loop checks the
+            transport each chunk and releases the session instead of
+            decoding to max_tokens into a full queue."""
             from ..core.config import GlobalConfig
             from .failover import FailoverSession
             max_new = int(payload.pop("max_new_tokens", 64))
@@ -198,8 +195,6 @@ class HTTPProxy:
             if isinstance(out, dict) and "error" not in out:
                 ttft = time.time() - t0
             t_last = time.time()
-            if isinstance(out, dict):
-                out.pop("proto", None)
             resp = web.StreamResponse(headers={
                 "Content-Type": "text/event-stream",
                 "Cache-Control": "no-cache"})
@@ -221,50 +216,27 @@ class HTTPProxy:
             try:
                 await resp.prepare(request)
                 await emit(out)
-                if sess.chunked and sid is not None \
-                        and "error" not in out:
-                    emitted = len(sess.journal)  # start carried token #1
-                    while emitted < max_new and not sess.done:
-                        if client_gone():
-                            break   # client disconnected: cancel now
-                        out = await loop.run_in_executor(
-                            self._pool, sess.next_tokens,
-                            min(chunk, max_new - emitted))
-                        for tok in out["tokens"][:max_new - emitted]:
-                            await emit({"token": [tok]})
-                            emitted += 1
-                            now = time.time()
-                            itl.append(now - t_last)
-                            t_last = now
-                elif sid is not None and "error" not in out:
-                    for _ in range(max_new - 1):
-                        if client_gone():
-                            break
-                        out = await loop.run_in_executor(
-                            self._pool,
-                            make_call(name, {"op": "next", "sid": sid}))
-                        await emit(out)
+                emitted = len(sess.journal)  # start carried token #1
+                while sess.sid is not None and emitted < max_new \
+                        and not sess.done:
+                    if client_gone():
+                        break   # client disconnected: cancel now
+                    out = await loop.run_in_executor(
+                        self._pool, sess.next_tokens,
+                        min(chunk, max_new - emitted))
+                    for tok in out["tokens"][:max_new - emitted]:
+                        await emit({"token": [tok]})
+                        emitted += 1
                         now = time.time()
                         itl.append(now - t_last)
                         t_last = now
-                        if not isinstance(out, dict) \
-                                or "error" in out or out.get("eos"):
-                            break
             except Exception as e:
                 try:
                     await emit({"error": str(e)})
                 except Exception:
                     pass    # connection already gone
             finally:
-                if sess.chunked:
-                    await loop.run_in_executor(self._pool, sess.end)
-                elif sid is not None:
-                    try:
-                        await loop.run_in_executor(
-                            self._pool,
-                            make_call(name, {"op": "end", "sid": sid}))
-                    except Exception:
-                        pass   # owner died mid-stream: nothing to free
+                await loop.run_in_executor(self._pool, sess.end)
             # request timeline span + one latency sample to the nodelet
             # fold — after the stream, off the token path
             try:
@@ -348,6 +320,13 @@ class HTTPProxy:
                     return web.Response(
                         status=400,
                         text="/stream needs a JSON object body")
+                p = payload.get("prompt")
+                if isinstance(p, list) and len(p) > 1 \
+                        and isinstance(p[0], list):
+                    # a `grp:` group has no owner to stick to and no
+                    # journal to resume from
+                    return web.Response(
+                        status=400, text="/stream takes one prompt")
                 try:
                     return await stream_tokens(request, name, payload)
                 except ReplicaUnavailableError as e:

@@ -308,8 +308,7 @@ def serve_breakdown() -> Dict[str, Any]:
       publish) and the per-request sums first_token and prefill_tail;
     * ``mfu``: per-program model-FLOPs-utilization gauges.
 
-    Surfaces: `ray-tpu top` breakdown panel, ``/api/serve/breakdown``,
-    ``bench.py --serve-breakdown`` (SERVE_BENCH.json)."""
+    Surfaces: `ray-tpu top` breakdown panel, ``/api/serve/breakdown``."""
     samples = _prom_samples(cluster_metrics_text())
     per: Dict[str, Dict[str, Any]] = {}
 
@@ -373,8 +372,7 @@ def rpc_attribution() -> Dict[str, Any]:
     alive nodelet, the per-op dispatch table (count, errors, total
     handler seconds, avg/p50/p99/max latency, payload bytes — sorted by
     total time), plus WAL append/fsync timing and asyncio loop lag.
-    The 'where does control-plane time go' view SCALE_r06 reads before
-    and after (ROADMAP item 4)."""
+    The 'where does control-plane time go' view."""
     core = _ensure_initialized()
     out: Dict[str, Any] = {"nodes": {}}
     try:

@@ -47,8 +47,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 # bf16 peak TFLOP/s per chip, keyed by the exact `device_kind` JAX
 # reports (Google Cloud TPU documentation, per-generation system
-# architecture pages; a v5e reports itself as "TPU v5 lite").  The one
-# table: bench.py imports it.
+# architecture pages; a v5e reports itself as "TPU v5 lite").
 PEAK_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,

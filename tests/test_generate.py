@@ -158,15 +158,16 @@ def test_chunked_prefill_parity_with_whole_prefill():
                                 cfg.vocab_size)
     whole_logits, whole_cache = prefill(
         params, prompt, cfg, init_kv_cache(cfg, 2, 32))
-    # chunk=4 over 13 tokens: three full chunks + tail of 1
+    # chunk=4 over 13 tokens: three full chunks + a padded tail of 1
     chunk_logits, chunk_cache = prefill_chunked(
         params, prompt, cfg, init_kv_cache(cfg, 2, 32), chunk=4)
     assert int(chunk_cache["pos"]) == 13 == int(whole_cache["pos"])
     np.testing.assert_allclose(np.asarray(chunk_logits),
                                np.asarray(whole_logits),
                                rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(chunk_cache["k"]),
-                               np.asarray(whole_cache["k"]),
+    # positions last; the columns past ``pos`` hold the tail's padding
+    np.testing.assert_allclose(np.asarray(chunk_cache["k"][..., :13]),
+                               np.asarray(whole_cache["k"][..., :13]),
                                rtol=2e-4, atol=2e-4)
     # and decode continues identically from a chunk-built cache
     tok = jnp.argmax(chunk_logits, axis=-1).astype(jnp.int32)
@@ -176,24 +177,41 @@ def test_chunked_prefill_parity_with_whole_prefill():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_decode_session_chunked_prefill_tokens_match():
-    """DecodeSessionCore(prefill_chunk=N) serves the same tokens as the
-    whole-prefill session."""
+@pytest.mark.parametrize("chunk", [None, 4])
+def test_decode_session_chunked_prefill_tokens_match(chunk):
+    """A DecodeSessionCore — the default engine and one that prefills
+    in chunks of 4 — serves the whole-prompt reference's tokens."""
+    from greedy_reference import greedy_stream
+    from ray_tpu.serve.config import DecodeEngineConfig
     from ray_tpu.serve.decode_session import DecodeSessionCore
 
     cfg = TransformerConfig.tiny(max_seq_len=64,
                                  attention_impl="reference",
                                  dtype=jnp.float32)
-    a = DecodeSessionCore(cfg, max_len=64, seed=3)
-    b = DecodeSessionCore(cfg, max_len=64, seed=3, prefill_chunk=4)
+    core = DecodeSessionCore(
+        cfg, max_len=64, seed=3,
+        engine=chunk and DecodeEngineConfig(prefill_chunk_tokens=chunk))
     prompt = list(range(10))
-    ra = a.handle({"op": "start", "prompt": prompt})
-    rb = b.handle({"op": "start", "prompt": prompt})
-    assert ra["token"] == rb["token"]
-    for _ in range(5):
-        ta = a.handle({"op": "next", "sid": ra["sid"]})["token"]
-        tb = b.handle({"op": "next", "sid": rb["sid"]})["token"]
-        assert ta == tb
+    try:
+        r = core.handle({"op": "start", "prompt": prompt})
+        toks = list(r["token"])
+        for _ in range(5):
+            toks += core.handle({"op": "next", "sid": r["sid"]})["token"]
+        assert toks == greedy_stream(cfg, prompt, 6, max_len=64, seed=3)
+        assert core.engine.ecfg.prefill_chunk_tokens == (chunk or 32)
+    finally:
+        core.engine.shutdown()
+
+
+@pytest.mark.parametrize("engine", [False, True, "on"])
+def test_decode_session_engine_is_a_config_or_none(engine):
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+
+    cfg = TransformerConfig.tiny(max_seq_len=64,
+                                 attention_impl="reference",
+                                 dtype=jnp.float32)
+    with pytest.raises(TypeError, match="engine"):
+        DecodeSessionCore(cfg, max_len=64, seed=3, engine=engine)
 
 
 def test_chunked_prefill_rejects_overlong_prompt():

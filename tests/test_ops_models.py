@@ -1,5 +1,6 @@
 """Ops + model tests (CPU reference paths; the Pallas kernel itself is
-TPU-only and exercised by bench.py / TPU-gated tests)."""
+TPU-only: `tests/test_chip_compile.py` asks the chip's compiler, the
+benchmark's train cells run it)."""
 
 import jax
 import jax.numpy as jnp

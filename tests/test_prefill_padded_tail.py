@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from greedy_reference import greedy_stream
 from ray_tpu.models import (TransformerConfig, init_kv_cache, init_params,
-                            prefill_chunk_jit, resume_prefill)
+                            prefill, prefill_chunk_jit, prefill_chunked)
 from ray_tpu.models.generate import (_prefill_chunk, cache_arrays,
                                      chunk_window, padded_chunk)
 
@@ -197,10 +198,12 @@ def test_seeded_offset_and_capacity_edge_write_only_their_positions(
 
 @pytest.mark.parametrize("n", (7, 8, 23, 29, 30))
 @pytest.mark.parametrize("name", MODELS)
-def test_resume_prefill_pays_one_program_for_its_remainder(name, n):
-    """`resume_prefill` walks `chunk_window` too, in a cache whose capacity
-    (30) is no multiple of the chunk: the whole-prompt walk's logits and
-    columns, from ceil(n / chunk) calls of ONE shape."""
+def test_prefill_chunked_pays_one_program_for_its_remainder(name, n):
+    """`prefill_chunked` walks `chunk_window`, in a cache whose capacity
+    (30) is no multiple of the chunk, up to the capacity's edge (29, 30:
+    the last window starts at 22 and runs tokens again): the logits and
+    columns of the single-token walk AND of the whole-prompt `prefill`,
+    from ceil(n / chunk) calls of ONE shape."""
     cfg, params, toks = _model(name)
     calls = []
 
@@ -210,14 +213,18 @@ def test_resume_prefill_pays_one_program_for_its_remainder(name, n):
                                  n_valid=n_valid)
 
     want_logits, want = _single_tokens(cfg, params, toks, n)
-    logits, got = resume_prefill(params, jnp.asarray(toks[:, :n]), cfg,
-                                 init_kv_cache(cfg, 1, 30), chunk=CHUNK,
-                                 _jitted=counted)
+    logits, got = prefill_chunked(params, jnp.asarray(toks[:, :n]), cfg,
+                                  init_kv_cache(cfg, 1, 30), chunk=CHUNK,
+                                  _jitted=counted)
     assert len(calls) == -(-n // CHUNK)
     assert {c[0] for c in calls} == {(1, CHUNK)}
     assert all(pos + CHUNK <= 30 for _, pos, _ in calls)
     assert float(jnp.abs(logits - want_logits).max()) < TOL
     _assert_same_cache(got, want, n)
+    whole_logits, whole = prefill(params, jnp.asarray(toks[:, :n]), cfg,
+                                  init_kv_cache(cfg, 1, 30))
+    assert float(jnp.abs(logits - whole_logits).max()) < TOL
+    _assert_same_cache(got, whole, n)
 
 
 # ------------------------------------------------------------- the engine
@@ -233,16 +240,9 @@ def _engine_core(name, **ecfg):
 
 
 def _reference_stream(name, prompt, want):
-    """Whole-prompt prefill and batch-1 decode steps: the eager core."""
-    from ray_tpu.serve.decode_session import DecodeSessionCore
+    """Whole-prompt prefill and batch-1 decode steps."""
     cfg, params, _ = _model(name)
-    legacy = DecodeSessionCore(cfg, max_len=MAX_LEN, params=params,
-                               engine=False)
-    r = legacy.handle({"op": "start", "prompt": prompt})
-    toks = list(r["token"])
-    while len(toks) < want:
-        toks += legacy.handle({"op": "next", "sid": r["sid"]})["token"]
-    return toks
+    return greedy_stream(cfg, prompt, want, max_len=MAX_LEN, params=params)
 
 
 def _stream(core, prompt, want, op="start", **more):

@@ -1,17 +1,16 @@
 """Control-plane scale test (reference model: release/benchmarks/README.md
 many-tasks / many-actors / many-PGs rows, scaled to one host).
 
-Rates land in README.md §perf; the assertions here are floors loose
-enough to pass on a loaded single-core CI box while still proving the
+`ray-tpu microbenchmark` prints the rates; the assertions here are floors
+loose enough to pass on a loaded single-core CI box while still proving the
 scale dimensions: a task burst, an actor population, a PG create/remove
 cycle on a multi-nodelet cluster, and a past-2^31-bytes single get.
 
 Default tiers keep CI wall-clock sane; ``RAY_TPU_SCALE_FULL=1`` raises
 them to the reference-scale ledger tiers (500k queued tasks, 5k actors,
-500 PGs, 4 GiB get — measured runs recorded in SCALE_r05.json; the
-cliffs they found — actor-cap scheduler blindness, start_actor
-thundering herd, the CPython one-shot buffer-copy collapse past 2 GiB —
-are fixed and referenced there).
+500 PGs, 4 GiB get; the cliffs such runs found — actor-cap scheduler
+blindness, start_actor thundering herd, the CPython one-shot
+buffer-copy collapse past 2 GiB — are fixed).
 """
 
 import os

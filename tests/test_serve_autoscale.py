@@ -13,6 +13,7 @@ import time
 
 import pytest
 
+from greedy_reference import greedy_stream
 from ray_tpu.serve import autoscaler
 from ray_tpu.serve.autoscaler import FleetSample, ReplicaView
 from ray_tpu.serve.prefix_cache import PrefixIndex
@@ -232,12 +233,11 @@ def test_cache_gather_slot_roundtrip_and_truncation():
 def test_engine_prefix_reuse_parity_and_skipped_prefill():
     """Two sessions sharing a 12-token system prompt: the second admits
     through a donor-slot gather and prefills only its suffix — byte-
-    identical streams to the eager oracle, one applied hit, and the
+    identical streams to the greedy reference, one applied hit, and the
     shared tokens never re-run a prefill chunk."""
     from ray_tpu.serve.decode_session import DecodeSessionCore
     cfg = _tiny_cfg()
     core = DecodeSessionCore(cfg, max_len=64, seed=3)
-    oracle = DecodeSessionCore(cfg, max_len=64, seed=3, engine=False)
     system = [7, 3, 9, 4, 8, 1, 6, 2, 5, 0, 7, 7]
     pa, pb = system + [11, 13], system + [17, 19, 23]
 
@@ -253,20 +253,13 @@ def test_engine_prefix_reuse_parity_and_skipped_prefill():
         c.handle({"op": "end", "sid": r["sid"]})
         return toks[:n]
 
-    def ostream(c, p, n):
-        r = c.handle({"op": "start", "prompt": p})
-        toks = list(r["token"])
-        for _ in range(n - 1):
-            toks += c.handle({"op": "next", "sid": r["sid"]})["token"]
-        return toks[:n]
-
     a = stream(core, pa, 10)
     chunks_after_a = core.handle({"op": "stats"})["engine"][
         "prefill_chunks"]
     b = stream(core, pb, 10)
     st = core.handle({"op": "stats"})["engine"]
-    assert a == ostream(oracle, pa, 10)
-    assert b == ostream(oracle, pb, 10)
+    assert a == greedy_stream(cfg, pa, 10, max_len=64, seed=3)
+    assert b == greedy_stream(cfg, pb, 10, max_len=64, seed=3)
     assert st["prefix"]["applied_hits"] == 1, st["prefix"]
     assert st["prefix"]["tokens_reused"] == len(system)
     # B's admission burned ONE chunk program, padded, for its 3-token
@@ -297,13 +290,12 @@ def test_engine_prefix_cache_disabled_stays_cold():
 
 
 def test_group_start_routes_batched_prompts_through_engine():
-    """The legacy B>1 data plane is gone: a batched start becomes
-    per-row engine sessions behind a grp: sid with the legacy reply
-    shape, and token streams match the eager oracle row-for-row."""
+    """A batched start becomes per-row engine sessions behind a grp:
+    sid whose replies carry one token a row, and the rows' streams match
+    the greedy reference row for row."""
     from ray_tpu.serve.decode_session import DecodeSessionCore
     cfg = _tiny_cfg()
     core = DecodeSessionCore(cfg, max_len=64, seed=3)
-    oracle = DecodeSessionCore(cfg, max_len=64, seed=3, engine=False)
     prompts = [[3, 1, 4, 1], [2, 7, 1, 8]]
     r = core.handle({"op": "start", "prompt": prompts})
     assert isinstance(r["sid"], str) and r["sid"].startswith("grp:")
@@ -312,16 +304,9 @@ def test_group_start_routes_batched_prompts_through_engine():
     for _ in range(5):
         got.append(core.handle({"op": "next", "sid": r["sid"]})["token"])
     assert core.handle({"op": "end", "sid": r["sid"]})["ended"]
-    ro = oracle.handle({"op": "start", "prompt": prompts})
-    want = [list(ro["token"])]
-    for _ in range(5):
-        want.append(oracle.handle({"op": "next",
-                                   "sid": ro["sid"]})["token"])
-    assert got == want
-    # engine cores never build the eager whole-prompt programs at all
-    assert not hasattr(core, "_prefill")
-    st = core.handle({"op": "stats"})
-    assert st["legacy_sessions"] == 0
+    rows = [greedy_stream(cfg, p, 6, max_len=64, seed=3) for p in prompts]
+    assert got == [list(step) for step in zip(*rows)]
+    assert core.handle({"op": "stats"})["groups"] == 0
     # unknown group after end
     out = core.handle({"op": "next", "sid": r["sid"]})
     assert "error" in out

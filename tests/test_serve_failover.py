@@ -3,7 +3,7 @@
 The proxy/router layer journals every emitted token (serve/failover.py);
 when a session's owner replica dies (chaos kill, node death) or drains,
 the stream is re-admitted on a healthy replica via a teacher-forced
-prefix prefill (``{"op": "resume"}`` → ``models.resume_prefill``) and
+prefix prefill (``{"op": "resume"}`` → the engine's chunked admission) and
 deduped by seq — the client sees a stall, never an error and never a
 repeated/dropped token (greedy decode makes replay deterministic).
 
@@ -116,8 +116,7 @@ def test_failover_session_replica_death_resume():
         seen.append((payload["op"], sticky))
         op = payload["op"]
         if op == "start":
-            return {"sid": "A#1:0", "token": [10], "proto": "chunk",
-                    "seq": 0}
+            return {"sid": "A#1:0", "token": [10], "seq": 0}
         if op == "next_chunk":
             state["n"] += 1
             if state["n"] == 1:
@@ -128,14 +127,13 @@ def test_failover_session_replica_death_resume():
         if op == "resume":
             assert payload["prompt"] == [1, 2]
             assert payload["generated"] == [10, 11, 12]
-            return {"sid": "B#2:0", "token": [13], "proto": "chunk",
-                    "seq": 3}
+            return {"sid": "B#2:0", "token": [13], "seq": 3}
         raise AssertionError(op)
 
     s = FailoverSession(call, {"op": "start", "prompt": [1, 2]},
                         deployment="t", transient_retries=0)
     out = s.start()
-    assert s.chunked and out["sid"] == "A#1:0"
+    assert s.journal == [10] and out["sid"] == "A#1:0"
     assert s.next_tokens(4) == {"tokens": [11, 12], "done": False}
     assert s.next_tokens(4) == {"tokens": [13], "done": False}
     assert s.failovers == 1
@@ -160,13 +158,11 @@ def test_failover_session_drain_migrate_dedupe_and_gap():
     def call(payload, sticky=None):
         op = payload["op"]
         if op == "start":
-            return {"sid": "A:0", "token": [5], "proto": "chunk",
-                    "seq": 0}
+            return {"sid": "A:0", "token": [5], "seq": 0}
         if op == "resume":
             resumes.append(list(payload["generated"]))
             g = len(payload["generated"])
-            return {"sid": f"B:{g}", "token": [100 + g],
-                    "proto": "chunk", "seq": g}
+            return {"sid": f"B:{g}", "token": [100 + g], "seq": g}
         if op == "next_chunk":
             return script.pop(0)
         return {"ended": True}
@@ -208,8 +204,7 @@ def test_failover_session_exhaustion_surfaces_stream_failed():
 
     def call(payload, sticky=None):
         if payload["op"] == "start":
-            return {"sid": "A:0", "token": [1], "proto": "chunk",
-                    "seq": 0}
+            return {"sid": "A:0", "token": [1], "seq": 0}
         if payload["op"] == "resume":
             calls["resume"] += 1
             raise WorkerCrashedError("still dead")
@@ -275,8 +270,8 @@ def test_call_with_retry_honors_retry_after(monkeypatch):
 
 # ------------------------------------ teacher-forced replay parity (seeds)
 
-def test_resume_prefill_matches_whole_prompt_prefill():
-    """models satellite: the bounded-compile resume prefill (fixed-size
+def test_prefill_chunked_matches_whole_prompt_prefill():
+    """models satellite: the bounded-compile prefix prefill (fixed-size
     chunk programs, the remainder one more, padded) produces the same
     last-position argmax and the same continuation as the whole-prompt
     prefill, for a prefix length that has a remainder."""
@@ -284,14 +279,14 @@ def test_resume_prefill_matches_whole_prompt_prefill():
     import jax.numpy as jnp
 
     from ray_tpu.models import (decode_step, init_kv_cache, init_params,
-                                prefill, resume_prefill)
+                                prefill, prefill_chunked)
     cfg = _tiny_cfg()
     params, _ = init_params(jax.random.PRNGKey(7), cfg)
     prefix = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9]],
                          jnp.int32)   # 13 = 3 chunks of 4 + a tail of 1
     lr, cr = prefill(params, prefix, cfg, init_kv_cache(cfg, 1, 64))
-    ls, cs = resume_prefill(params, prefix, cfg,
-                            init_kv_cache(cfg, 1, 64), chunk=4)
+    ls, cs = prefill_chunked(params, prefix, cfg,
+                             init_kv_cache(cfg, 1, 64), chunk=4)
     assert int(cs["pos"]) == int(cr["pos"]) == 13
     tok_r = jnp.argmax(lr, -1).astype(jnp.int32)
     tok_s = jnp.argmax(ls, -1).astype(jnp.int32)
@@ -331,7 +326,7 @@ def test_engine_resume_replay_parity():
     # engines to resume INTO: plain, and (PR-6) one that speculates —
     # chunked teacher-forced admission + exact greedy verification must
     # keep the replayed continuation byte-identical either way
-    engines = {1: True, 7: True,
+    engines = {1: None, 7: None,
                12: DecodeEngineConfig(spec_draft="shared", spec_k=4),
                6: DecodeEngineConfig(prefill_chunk_tokens=4,
                                      spec_draft="shared", spec_k=3)}
@@ -605,7 +600,7 @@ def test_drain_handoff_migrates_live_stream_zero_dropped(failover_app):
         sess = FailoverSession(call, {"op": "start", "prompt": prompt},
                                deployment="draingen")
         out = sess.start()
-        assert sess.chunked, out
+        assert sess.journal == out["token"], out
         while len(sess.journal) < want and not sess.done:
             if pause_after is not None and on_pause is not None \
                     and len(sess.journal) >= pause_after:
@@ -738,8 +733,8 @@ def test_drain_node_with_live_streams_zero_dropped(run):
                                    {"op": "start", "prompt": prompt},
                                    deployment="dgen",
                                    failover_timeout_s=90.0)
-            sess.start()
-            assert sess.chunked
+            out = sess.start()
+            assert sess.journal == out["token"], out
             fetch = 2 if pace else 4   # paced streams span the drain
             while len(sess.journal) < want and not sess.done:
                 sess.next_tokens(min(fetch, want - len(sess.journal)))

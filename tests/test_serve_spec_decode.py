@@ -14,6 +14,7 @@ import time
 
 import pytest
 
+from greedy_reference import greedy_stream
 from ray_tpu.core.config import GlobalConfig
 
 
@@ -27,20 +28,9 @@ def _tiny_cfg(max_seq_len=64, **kw):
 
 
 def _ref_streams(cfg, prompts, want, seed=3, max_len=64):
-    """Sequential batch-1 references through the legacy core."""
-    from ray_tpu.serve.decode_session import DecodeSessionCore
-    legacy = DecodeSessionCore(cfg, max_len=max_len, seed=seed,
-                               engine=False)
-    refs = []
-    for p in prompts:
-        r = legacy.handle({"op": "start", "prompt": p})
-        toks = list(r["token"])
-        while len(toks) < want:
-            toks += legacy.handle({"op": "next",
-                                   "sid": r["sid"]})["token"]
-        legacy.handle({"op": "end", "sid": r["sid"]})
-        refs.append(toks)
-    return refs
+    """Sequential batch-1 greedy references."""
+    return [greedy_stream(cfg, p, want, max_len=max_len, seed=seed)
+            for p in prompts]
 
 
 def _drain(core, sid, toks, want):

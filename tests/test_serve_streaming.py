@@ -30,7 +30,15 @@ def streaming_app():
         def __call__(self, req):
             return self.core.handle(req)
 
+    @serve.deployment
+    class Closed:
+        """Speaks the session protocol and admits nobody."""
+
+        def __call__(self, req):
+            return {"error": f"closed to {req['op']}"}
+
     serve.run(Gen.bind(), name="gen")
+    serve.run(Closed.bind(), name="closed")
     yield serve.api.http_address()
     serve.shutdown()
     ray_tpu.shutdown()
@@ -72,10 +80,24 @@ def test_stream_emits_token_events(streaming_app):
     assert "error" in out
 
 
-def test_stream_rejects_non_json(streaming_app):
+def test_stream_of_a_refused_start_ends_with_the_error_in_band(
+        streaming_app):
     import requests
-    r = requests.post(f"{streaming_app}/gen/stream", data="plain",
-                      timeout=30)
+    with requests.post(f"{streaming_app}/closed/stream",
+                       json={"prompt": [5, 6, 7], "max_new_tokens": 6},
+                       stream=True, timeout=60) as r:
+        assert r.status_code == 200
+        events = _sse_events(r)
+    assert events == [{"error": "closed to start"}, "DONE"]
+
+
+@pytest.mark.parametrize("body", [
+    {"data": "plain"},                                  # no JSON object
+    {"json": {"prompt": [[1, 2], [3, 4]]}},             # a group of two
+])
+def test_stream_rejects_non_json(streaming_app, body):
+    import requests
+    r = requests.post(f"{streaming_app}/gen/stream", timeout=30, **body)
     assert r.status_code == 400
 
 
