@@ -84,8 +84,14 @@ class DecodeEngineConfig:
     # BETWEEN shared decode steps on the engine thread (the remainder
     # as one more chunk, padded) — admission and failover resume reuse
     # the one compiled chunk shape, and a join never stalls live streams
-    # by more than one chunk interval.
-    prefill_chunk_tokens: int = 32
+    # by more than one chunk interval.  None (the default) derives the
+    # width from the chip: the widest power of two under its ridge point
+    # for the weights' item size (128 for bfloat16 on a v5e; 32 on a
+    # backend with no published peaks), so the interval is about two
+    # reads of the weights, about two small-batch decode steps
+    # (`decode_session.prefill_chunk_width`); a test or a deployment
+    # may pin it.  The engine's own `ecfg` holds the width in use.
+    prefill_chunk_tokens: Optional[int] = None
     # bound on one `start`/`resume` call: enqueue -> first token (the
     # prompt is prefilled by the engine thread; a wedged engine must not
     # hang the caller forever — timeout sheds with the typed 503)

@@ -29,15 +29,18 @@ Two model-side optimisations compound inside the loop:
 
 - **Chunked-prefill admission**: a joining session's prompt is
   consumed ``[1, chunk]`` tokens at a time between shared decode steps
-  (``DecodeEngineConfig.prefill_chunk_tokens``), so a join stalls live
-  streams by at most one chunk interval instead of a whole prompt
-  forward, and TTFT-under-load stops being O(prompt_len) of batch
-  stall.  A prompt's remainder is ONE more program of the same width,
-  padded, the count of its real tokens a traced argument
-  (`models.generate.prefill_chunk_step`).  Admission and failover
-  resume (``op: resume``) dispatch the SAME module-level chunk program
-  (`models.prefill_chunk_jit`) — one compiled prefill shape per model,
-  whatever the traffic, and no prompt length compiles anything.
+  (``DecodeEngineConfig.prefill_chunk_tokens``; unset, the widest power
+  of two under the chip's ridge point: :func:`prefill_chunk_width`), so
+  a join stalls live streams by at most one chunk interval, about two
+  reads of the weights and so about two small-batch decode steps,
+  instead of a whole prompt forward, and TTFT-under-load stops being
+  O(prompt_len) of batch stall.  A prompt's remainder is ONE more
+  program of the same width, padded, the count of its real tokens a
+  traced argument (`models.generate.prefill_chunk_step`).  Admission
+  and failover resume (``op: resume``) dispatch the SAME module-level
+  chunk program (`models.prefill_chunk_jit`) — one compiled prefill
+  shape per model, whatever the traffic, and no prompt length compiles
+  anything.
 - **Speculative decoding** (``DecodeEngineConfig.spec_draft`` /
   ``spec_k``): a draft model proposes k tokens per iteration in one
   scanned dispatch (`models.draft_propose_slots`) and the target
@@ -54,6 +57,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import dataclasses
 import functools
 import threading
 import time
@@ -79,6 +83,40 @@ def _shutdown_engines() -> None:
                 t.join(timeout=5.0)
         except Exception:
             pass
+
+
+def prefill_chunk_width(pinned: Optional[int], params: Any,
+                        capacity: int,
+                        device_kind: Optional[str] = None) -> int:
+    """The width of the ONE chunk program: ``pinned`` where a caller set
+    ``DecodeEngineConfig.prefill_chunk_tokens``, else the largest power
+    of two not above the chip's ridge point for weights of ``params``'
+    item size (`util.device_profile.ridge_rows`: 240.5 rows for bfloat16
+    on a v5e, so 128), else, on a backend with no published peaks, 32;
+    never more than ``capacity``.
+
+    Under the ridge a program's matmuls are reads of the weights they
+    touch, so prefill tokens a second grow with the width while a
+    program, and with it the stall of the live streams, stays within
+    about two reads of the weights: about two small-batch decode steps
+    (measured on a v5e at 128 rows: 2.2 steps of the latent + routed
+    model, PERF.md, PR 31).  Past it time grows with the rows.
+    ``device_kind`` is the attached device's unless a test names one."""
+    import math
+
+    import jax
+
+    from ..util.device_profile import ridge_rows
+    if pinned is not None:
+        width = int(pinned)
+    else:
+        leaves = jax.tree_util.tree_leaves(params)
+        n = sum(int(a.size) for a in leaves)
+        itemsize = sum(int(a.size) * a.dtype.itemsize
+                       for a in leaves) / max(1, n)
+        ridge = ridge_rows(itemsize, device_kind)
+        width = 2 ** math.floor(math.log2(ridge)) if ridge else 32
+    return min(max(1, width), capacity)
 
 
 class _EngineSession:
@@ -162,7 +200,6 @@ class ContinuousBatchingEngine:
         self.cfg = cfg
         self.max_len = max_len
         self.params = params
-        self.ecfg = engine_cfg
         self.name = name or "decode"
         self._tag = replica_tag
 
@@ -260,12 +297,13 @@ class ContinuousBatchingEngine:
                                   static_argnames=("cfg",)))
         # the positions a chunk program's window may cover: the cache's,
         # and no more than a learned position table (the draft's too);
-        # the ONE chunk width follows
+        # the ONE chunk width follows, and `ecfg` holds it resolved
         self._capacity = min([max_len] + [
             c.max_seq_len for c in (cfg, self._draft_cfg)
             if c is not None and c.pos_emb == "learned"])
-        self._chunk_tokens = min(
-            max(1, int(engine_cfg.prefill_chunk_tokens)), self._capacity)
+        self.ecfg = dataclasses.replace(
+            engine_cfg, prefill_chunk_tokens=prefill_chunk_width(
+                engine_cfg.prefill_chunk_tokens, params, self._capacity))
         self._spec_k = max(2, int(engine_cfg.spec_k))
         self._spec_disabled = False
         self._spec_fail_streak = 0
@@ -501,6 +539,9 @@ class ContinuousBatchingEngine:
                     "reaped": self.reaped,
                     "steps": self.steps, "tokens": self.tokens,
                     "prefill_chunks": self.prefill_chunks,
+                    # the rows of each (`prefill_chunk_width`)
+                    "prefill_chunk_tokens":
+                        self.ecfg.prefill_chunk_tokens,
                     # ... those that carried a prompt's remainder, and
                     # the padding rows they computed for nothing
                     "prefill_tails": self.prefill_tails,
@@ -804,7 +845,7 @@ class ContinuousBatchingEngine:
                 if self._spec:
                     sess.dcache = init_kv_cache(self._draft_cfg, 1,
                                                 self.max_len)
-        chunk = self._chunk_tokens
+        chunk = self.ecfg.prefill_chunk_tokens
         if sess.t_pf is None:          # queue phase ends at the first
             sess.t_pf = time.monotonic()  # chunk program of the prompt
             self.phase_s["queue"] += sess.t_pf - sess.t_enq
@@ -1011,8 +1052,11 @@ class ContinuousBatchingEngine:
                                   * self._moe_layers)
         now, last = time.time(), self._moe_span
         if now - last["t"] >= self._MOE_SPAN_S:
+            # a category of its own: the ring keeps a bound a category,
+            # and the two `serve` spans of every `next_chunk` call push a
+            # span out of a full ring within seconds of a busy window
             tracing.record_span(
-                "moe:load", "serve", last["t"], now, deployment=self.name,
+                "moe:load", "moe", last["t"], now, deployment=self.name,
                 layers=self._moe_layers, experts=self.cfg.n_experts,
                 **{k: self.moe[k] - last[k] for k in self.moe})
             self._moe_span = dict(self.moe, t=now)

@@ -45,9 +45,10 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-# bf16 peak TFLOP/s per chip, keyed by the exact `device_kind` JAX
-# reports (Google Cloud TPU documentation, per-generation system
-# architecture pages; a v5e reports itself as "TPU v5 lite").
+# bf16 peak TFLOP/s and HBM GB/s per chip, keyed by the exact
+# `device_kind` JAX reports (Google Cloud TPU documentation,
+# per-generation system architecture pages; a v5e reports itself as
+# "TPU v5 lite").
 PEAK_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,
@@ -55,6 +56,14 @@ PEAK_TFLOPS = {
     "TPU v5p": 459.0,
     "TPU v6 lite": 918.0,
     "TPU v6e": 918.0,
+}
+PEAK_HBM_GBPS = {
+    "TPU v4": 1200.0,
+    "TPU v5 lite": 819.0,
+    "TPU v5e": 819.0,
+    "TPU v5p": 2765.0,
+    "TPU v6 lite": 1640.0,
+    "TPU v6e": 1640.0,
 }
 
 
@@ -68,6 +77,13 @@ def peak_flops_of(device_kind: str) -> float:
     return PEAK_TFLOPS[device_kind] * 1e12
 
 
+def _tpu_kind() -> Optional[str]:
+    """`device_kind` of the attached TPU; None on any other backend."""
+    import jax
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else None
+
+
 def peak_flops() -> Optional[float]:
     """Per-device peak FLOP/s: the config override, else the table entry
     of the attached TPU; None on a backend that has no published peak."""
@@ -75,11 +91,25 @@ def peak_flops() -> Optional[float]:
     cfg = getattr(GlobalConfig, "device_profile_peak_flops", 0.0) or 0.0
     if cfg > 0:
         return float(cfg)
-    import jax
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
+    kind = _tpu_kind()
+    return None if kind is None else peak_flops_of(kind)
+
+
+def ridge_rows(weight_itemsize: float,
+               device_kind: Optional[str] = None) -> Optional[float]:
+    """Rows at which a matmul against weights of ``weight_itemsize``
+    bytes an element stops being a read of those weights: ``2 * rows``
+    FLOP an element against ``itemsize`` bytes, so ``peak FLOP/s *
+    itemsize / (2 * HBM bytes/s)``.  Below it a program costs one read
+    of the weights it touches whatever its rows; above it time grows
+    with them.  The table entry of ``device_kind`` (default: the
+    attached TPU's; an unnamed TPU is an error); None on a backend that
+    has no published peaks."""
+    kind = device_kind if device_kind is not None else _tpu_kind()
+    if kind is None:
         return None
-    return peak_flops_of(dev.device_kind)
+    return peak_flops_of(kind) * weight_itemsize \
+        / (2.0 * PEAK_HBM_GBPS[kind] * 1e9)
 
 
 def _shape_key(args: tuple, kwargs: dict) -> tuple:

@@ -125,7 +125,9 @@ def test_create_mesh_tpu_branch_on_the_described_2x2(topo):
 
 @pytest.mark.parametrize("model", ["gpt2-medium", "llama-1b", "llama-8b"])
 @pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
-                                     "prefill_chunk_1", "prefill_padded_32"])
+                                     "prefill_chunk_1", "prefill_padded_32",
+                                     "prefill_padded_128",
+                                     "prefill_padded_256"])
 def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
     """At gpt2 head widths (16 heads of 64) the chip keeps a KV cache with
     ``max_len`` minor, whatever the logical order; a layer loop that
@@ -194,7 +196,9 @@ def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
 
 
 @pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
-                                     "prefill_chunk_1", "prefill_padded_32"])
+                                     "prefill_chunk_1", "prefill_padded_32",
+                                     "prefill_padded_128",
+                                     "prefill_padded_256"])
 def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
                                                                  program):
     """Latent attention and routed experts at the published widths of the
@@ -262,8 +266,13 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
     want = cache["kv"].size * cache["kv"].dtype.itemsize
     assert ma.alias_size_in_bytes >= want
     # beside the cache only activations: no second cache, no expert stack
-    # (one layer's is 604 MB)
-    assert ma.temp_size_in_bytes < 64 << 20, ma.temp_size_in_bytes
+    # (one layer's is 604 MB).  A chunk's float32 scores are `[width, 20,
+    # 4096]`, 42 MB at the 128 rows the engine derives for a v5e and 84 MB
+    # at 256; the compiler keeps two such arrays (170 MB in all at 256)
+    rows = slots if program == "fused_step" else width
+    scores = rows * cfg.n_heads * max_len * 4
+    assert ma.temp_size_in_bytes < (64 << 20) + 2 * scores, \
+        ma.temp_size_in_bytes
     text = compiled.as_text()
     shape = ",".join(map(str, cache["kv"].shape))
     assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text)

@@ -13,6 +13,7 @@ rounding: 2e-5 on logits of order 1.
 
 import dataclasses
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -318,6 +319,37 @@ def test_softmax_presets_keep_the_capacity_einsum():
     p2, _ = init_params(jax.random.PRNGKey(0), sig)
     assert "ragged_dot" in str(jax.make_jaxpr(
         functools.partial(forward, cfg=sig))(p2, toks))
+
+
+def test_moe_load_spans_outlive_a_busy_serve_ring(monkeypatch):
+    """The engine's `moe:load` spans are what the benchmark's `.agent`
+    readers sum after the run, from the ring as the process left it: they
+    sit in a category of their own, so the `serve` spans of a busy window
+    (two a `next_chunk` call) cannot push them out of the bounded ring."""
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import (ContinuousBatchingEngine,
+                                              DecodeSessionCore)
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(ContinuousBatchingEngine, "_MOE_SPAN_S", 0.0)
+    cfg = tiny()
+    params, _ = init_params(jax.random.PRNGKey(0), cfg)
+    core = DecodeSessionCore(cfg, max_len=96, params=params,
+                             engine=DecodeEngineConfig(max_slots=2))
+    try:
+        out = core.handle({"op": "start", "prompt": list(range(5, 14))})
+        got = len(out["token"])
+        while got < 4:
+            got += len(core.handle({"op": "next_chunk", "sid": out["sid"],
+                                    "max_tokens": 4})["tokens"])
+        core.handle({"op": "end", "sid": out["sid"]})
+    finally:
+        core.engine.shutdown()
+    now = time.time()
+    for i in range(2 * tracing._buffer().per_category):
+        tracing.record_span(f"serve_exec::flood{i}", "serve", now, now)
+    loads = [e for e in tracing.span_events() if e["name"] == "moe:load"]
+    assert loads and all(e["cat"] == "moe" for e in loads)
+    assert sum(e["args"]["steps"] for e in loads) > 0
 
 
 def test_engine_serves_the_latent_model_in_place():

@@ -229,13 +229,13 @@ def test_prefill_chunked_pays_one_program_for_its_remainder(name, n):
 
 # ------------------------------------------------------------- the engine
 
-def _engine_core(name, **ecfg):
+def _engine_core(name, chunk=CHUNK, **ecfg):
     from ray_tpu.serve.config import DecodeEngineConfig
     from ray_tpu.serve.decode_session import DecodeSessionCore
     cfg, params, _ = _model(name)
     return DecodeSessionCore(
         cfg, max_len=MAX_LEN, params=params,
-        engine=DecodeEngineConfig(prefill_chunk_tokens=CHUNK, max_slots=2,
+        engine=DecodeEngineConfig(prefill_chunk_tokens=chunk, max_slots=2,
                                   **ecfg))
 
 
@@ -256,15 +256,19 @@ def _stream(core, prompt, want, op="start", **more):
     return r["sid"], toks
 
 
+@pytest.mark.parametrize("chunk", [CHUNK, 24, MAX_LEN])
 @pytest.mark.parametrize("name", MODELS)
-def test_engine_streams_the_same_tokens_from_one_prefill_shape(name):
-    """Prompts of chunk - 1, chunk, chunk + 1 and 2 chunk + 7 tokens: the
-    streams of whole-prompt prefill + decode steps; one `prefill_chunk`
-    shape; counters that read what the prompts imply."""
+def test_engine_streams_the_same_tokens_from_one_prefill_shape(name, chunk):
+    """Prompts of 7, 8, 9 and 23 tokens through chunks of 8 (chunk - 1,
+    chunk, chunk + 1, 2 chunk + 7), of 24 (wider than every prompt: each
+    is ONE padded program, what the width derived for a chip makes of a
+    short prompt) and of the capacity: the streams of whole-prompt
+    prefill + decode steps; one `prefill_chunk` shape; counters that
+    read what the prompts imply."""
     toks = _model(name)[2][0]
     lengths = (CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK + 7)
     want = 5
-    core = _engine_core(name, prefix_cache=False)
+    core = _engine_core(name, chunk, prefix_cache=False)
     try:
         for n in lengths:
             prompt = [int(t) for t in toks[:n]]
@@ -274,12 +278,17 @@ def test_engine_streams_the_same_tokens_from_one_prefill_shape(name):
         st = core.handle({"op": "stats"})["engine"]
         assert [s for s in st["program_shapes"]
                 if s.startswith("prefill_chunk")] == [
-                    f"prefill_chunk:1x{CHUNK}"]
-        assert st["prefill_chunks"] == sum(-(-n // CHUNK) for n in lengths)
-        assert st["prefill_tails"] == sum(1 for n in lengths if n % CHUNK)
-        assert st["prefill_pad_tokens"] == sum(-n % CHUNK for n in lengths)
+                    f"prefill_chunk:1x{chunk}"]
+        assert st["prefill_chunk_tokens"] == chunk
+        assert st["prefill_chunks"] == sum(-(-n // chunk) for n in lengths)
+        assert st["prefill_tails"] == sum(1 for n in lengths if n % chunk)
+        assert st["prefill_pad_tokens"] == sum(-n % chunk for n in lengths)
         ph = st["phase_totals"]
-        assert 0 < ph["prefill_tail"] < ph["prefill"]
+        if chunk > max(lengths):     # a program a prompt, each padded
+            assert st["prefill_chunks"] == len(lengths)
+            assert 0 < ph["prefill_tail"] == ph["prefill"]
+        else:
+            assert 0 < ph["prefill_tail"] < ph["prefill"]
         (row,) = [r for r in st["device_profile"]
                   if r["program"] == "prefill_chunk"]
         assert row["tokens"] == sum(lengths) and row["shapes"] == 1
