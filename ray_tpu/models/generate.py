@@ -18,7 +18,22 @@ Beside its arrays a cache carries ``pos``.  Every function here, the
 serve engine and the prefix reuse work on whatever arrays a cache has
 (`cache_arrays`); none names one.
 
-Each array is stored ``[layers, batch, heads, width, max_len]`` —
+A cache holds TWO KINDS OF STATE where a model mixes window and full
+layers (`TransformerConfig.layer_kinds`): the full layers' arrays
+``[L_full, batch, heads, width, max_len]`` hold the whole context, the
+window layers' (``k_win``, ``v_win``) are a RING ``[L_win, batch, heads,
+width, ring]`` of ``sliding_window + window_chunk`` rows whatever
+``max_len`` (`window_ring`): position ``p`` lives in column ``p mod ring``,
+a chunk that straddles the seam is written in two pieces, and a column is
+masked by the POSITION IT HOLDS (`_ring_mask`), which follows from the
+last position written.  The ring is wider than the window by the widest
+chunk a program may feed, so whatever a program writes ahead of a row's
+``pos`` (a padded chunk's tail, an inactive slot's token, a rejected
+proposal) overwrites only positions that no later query's window reaches.
+Both kinds sit behind the same functions, and each kind has a layer
+counter of its own in the one layer loop.
+
+Each array is stored ``[layers, batch, heads, width, rows]`` —
 positions LAST — and every program that takes a cache extends it IN PLACE:
 the whole stacked cache is state of the one layer loop
 (:func:`_scan_cached`), written by ``dynamic_update_slice`` and held to
@@ -54,8 +69,9 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops import latent_attention as mla
 from ..ops.rotary import apply_rotary, rotary_angles
-from .transformer import (TransformerConfig, _ffn, _layer, _norm, _unembed,
-                          norm_eps, scan_layer_runs)
+from .transformer import (TransformerConfig, _attn_out, _ffn, _layer, _norm,
+                          _post, _qkv, _scale_embedding, _unembed, norm_eps,
+                          scan_layer_runs)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -63,13 +79,29 @@ KVCache = Dict[str, jnp.ndarray]
 Arrays = Dict[str, jnp.ndarray]     # a cache without its "pos"
 
 
+_RING = "_win"      # suffix of a window layer's arrays: rings
+
+
 def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     """What a cache of this model holds a position a layer: (heads,
-    width) of each of its arrays."""
+    width) of each of its arrays.  Window layers have arrays of their own
+    (rings, named ``*_win``) beside the full layers'."""
     if cfg.attention == "mla":
         return {"kv": (1, cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
-    return {"k": (cfg.kv_heads, cfg.head_dim),
-            "v": (cfg.kv_heads, cfg.head_dim)}
+    row = (cfg.kv_heads, cfg.head_dim)
+    return {name: row for kind in ("full", "window") if kind in cfg.kinds
+            for name in _kv_names(kind)}
+
+
+def _kv_names(kind: str) -> Tuple[str, str]:
+    """The key and value arrays of a layer of attention kind ``kind``."""
+    return ("k" + _RING, "v" + _RING) if kind == "window" else ("k", "v")
+
+
+def window_ring(cfg: TransformerConfig, max_len: int) -> int:
+    """Rows of a window layer's ring: the window and the widest chunk a
+    program may write ahead of it, and no more than the context."""
+    return min(max_len, cfg.sliding_window + cfg.window_chunk)
 
 
 def cache_arrays(cache: KVCache) -> Arrays:
@@ -78,15 +110,29 @@ def cache_arrays(cache: KVCache) -> Arrays:
 
 
 def cache_capacity(cache: KVCache) -> int:
-    """``max_len``: the positions a cache holds per row."""
-    return next(iter(cache_arrays(cache).values())).shape[-1]
+    """``max_len``: the positions a cache holds per row (what its full
+    layers' arrays hold; a ring is shorter)."""
+    return max(a.shape[-1] for a in cache_arrays(cache).values())
+
+
+def cache_bytes(cache: KVCache) -> Dict[str, int]:
+    """Bytes of a cache's arrays by state kind: ``full`` (rows for the
+    whole context) and ``ring`` (window layers)."""
+    out = {"full": 0, "ring": 0}
+    for name, a in cache_arrays(cache).items():
+        out["ring" if name.endswith(_RING) else "full"] += int(a.nbytes)
+    return out
 
 
 def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                 pos: jnp.ndarray) -> KVCache:
-    cache = {name: jnp.zeros((cfg.n_layers, batch, heads, width, max_len),
-                             cfg.dtype)
-             for name, (heads, width) in cache_rows(cfg).items()}
+    cache = {}
+    for name, (heads, width) in cache_rows(cfg).items():
+        ring = name.endswith(_RING)
+        cache[name] = jnp.zeros(
+            (cfg.kinds.count("window" if ring else "full"), batch, heads,
+             width, window_ring(cfg, max_len) if ring else max_len),
+            cfg.dtype)
     cache["pos"] = pos
     return cache
 
@@ -97,9 +143,10 @@ def init_kv_cache(cfg: TransformerConfig, batch: int,
 
 
 def _check_decodable(cfg: TransformerConfig) -> None:
-    """A cache is one stacked array over ALL layers of the pattern, walked
-    by one layer counter: what cannot be served is a model cut into
-    pipeline stages, each of which would own a slab of it."""
+    """A cache is one stacked array a state kind over ALL layers of the
+    pattern that hold that kind, walked by one layer counter a kind: what
+    cannot be served is a model cut into pipeline stages, each of which
+    would own a slab of it, and what a ring cannot hold."""
     if cfg.pp_stages > 1:
         raise NotImplementedError(
             "KV-cache decode over a pipeline mesh is not supported; "
@@ -108,16 +155,94 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         raise NotImplementedError(
             "latent attention keeps a rotary key beside its latent: "
             "pos_emb must be 'rope'")
+    kinds = set(cfg.kinds)
+    if len(cfg.kinds) != cfg.n_layers or kinds - {"full", "window"}:
+        raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
+                         f"{cfg.n_layers} of 'full' | 'window'")
+    if "window" in kinds:
+        if cfg.attention == "mla":
+            raise NotImplementedError(
+                "window layers over a latent cache are not supported")
+        if cfg.sliding_window < 1 or cfg.window_chunk < 1:
+            raise ValueError("window layers need sliding_window and "
+                             "window_chunk of at least 1")
+        if "full" not in kinds:
+            raise NotImplementedError(
+                "a model of window layers only is not served: the rows a "
+                "session may reach (max_len) are read off a full layer's "
+                "array, and a ring does not state them")
+
+
+def _check_chunk(cfg: TransformerConfig, c: int) -> None:
+    """What a ring cannot serve is refused, not answered wrongly: a
+    program that feeds more new tokens a row than the ring is wider than
+    the window (a chunk, or a speculative verify of that many) would
+    overwrite positions its own queries still see."""
+    if "window" in cfg.kinds and c > cfg.window_chunk:
+        raise ValueError(
+            f"a cached program of {c} new tokens a row over window layers "
+            f"whose ring leaves room for window_chunk={cfg.window_chunk}: "
+            f"the ring would lose positions the chunk still attends")
+
+
+def _ring_mask(pos, c: int, ring: int, window: int) -> jnp.ndarray:
+    """``pos`` [...] first new position a row, ``c`` new tokens a row →
+    [..., c, ring] bool: ring column visible to each new token.  After the
+    write the last position held is ``top = pos + c - 1`` and column j
+    holds the latest position <= top that is j mod ring (negative: never
+    written); token i at ``pos + i`` sees what lies at or before it and
+    inside its window."""
+    pos = jnp.asarray(pos)
+    top = (pos + (c - 1))[..., None]
+    held = top - (top - jnp.arange(ring)) % ring              # [..., ring]
+    q = (pos[..., None] + jnp.arange(c))[..., None]           # [..., c, 1]
+    held = held[..., None, :]
+    return (held >= 0) & (held <= q) & (q - held < window)
+
+
+def _ring_write_chunk(pos, c: int, ring: int):
+    """→ ``write(c_all, l, cols [B, heads, width, c])`` for a chunk whose
+    rows all start at the scalar ``pos``: column ``(pos + i) mod ring`` gets
+    token i.  A chunk that straddles the seam cannot be one slice, and a
+    branch on it would copy the ring, so EVERY chunk is two read-modify-
+    write slices of ``c`` columns: one that ends no later than the seam,
+    one that starts at column 0 (it rewrites what is there where the chunk
+    does not wrap)."""
+    r = pos % ring
+
+    def write(c_all, l, cols):
+        if c == 1:
+            return jax.lax.dynamic_update_slice(c_all, cols[None],
+                                                (l, 0, 0, 0, r))
+        if c > ring:
+            raise ValueError(f"chunk of {c} over a ring of {ring} rows")
+        twice = jnp.concatenate([cols, cols], axis=-1)
+        o = jnp.arange(c)
+        a = jnp.minimum(r, ring - c)
+        # (first ring column of the slice, token at its offset 0 mod c,
+        #  offsets that take a token)
+        for start, k, takes in ((a, (a - r) % c, o >= r - a),
+                                (0, (ring - r) % c, o + (ring - r) < c)):
+            new = jax.lax.dynamic_slice_in_dim(twice, k, c, axis=-1)[None]
+            old = jax.lax.dynamic_slice(
+                c_all, (l, 0, 0, 0, start), new.shape)
+            c_all = jax.lax.dynamic_update_slice(
+                c_all, jnp.where(takes, new, old), (l, 0, 0, 0, start))
+        return c_all
+
+    return write
 
 
 def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                  cache: KVCache, layer_fn):
     """THE layer loop of every program that writes a KV cache.
 
-    The whole stacked cache (each array ``[L, B, heads, width, max_len]``)
-    is loop STATE, indexed by the layer counter, and the layer weights the
-    scanned input: ``layer_fn(x, lp, arrays, l) -> (x, arrays, load)``
-    writes its columns into ``arrays[name][l]`` in place.  Passing
+    The whole stacked cache (each array ``[L, B, heads, width, rows]``)
+    is loop STATE, indexed by the layer counter of its state kind, and the
+    layer weights the scanned input: ``layer_fn(x, lp, arrays, l, kind)
+    -> (x, arrays, load)`` writes its columns into layer ``l`` of its
+    kind's arrays in place (``l`` counts the layers of that kind: a full
+    layer's arrays and a window layer's rings are stacked apart).  Passing
     the cache's layers through the scan as inputs and stacking them as
     outputs instead builds a second cache per call, and leaves a donated
     cache argument nothing to alias to.  The carry is held to the
@@ -130,17 +255,19 @@ def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     arrays = cache_arrays(cache)
     row_major = Layout(major_to_minor=tuple(range(5)))
 
-    def step(carry, lp):
-        xc, arrs, l, load = carry
+    def step(carry, lp, kind):
+        xc, arrs, seen, load = carry
         arrs = {n: with_layout_constraint(a, row_major)
                 for n, a in arrs.items()}
-        xc, arrs, load_l = layer_fn(xc, lp, arrs, l)
-        return xc, arrs, l + 1, tuple(a + b for a, b in zip(load, load_l))
+        xc, arrs, load_l = layer_fn(xc, lp, arrs, seen[kind], kind)
+        return (xc, arrs, dict(seen, **{kind: seen[kind] + 1}),
+                tuple(a + b for a, b in zip(load, load_l)))
 
     zero = jnp.zeros((), jnp.int32)
     x, arrays, _, load = scan_layer_runs(
-        cfg, params, (x, arrays, zero, (zero, zero)), step,
-        whole_expert_stacks=True)
+        cfg, params,
+        (x, arrays, dict.fromkeys(sorted(set(cfg.kinds)), zero),
+         (zero,) * 3), step, whole_expert_stacks=True)
     return x, arrays, load
 
 
@@ -157,60 +284,60 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                    cache: KVCache, *, rotate, write, mask, valid=None):
     """Run ``x`` [B, C, D] (C new tokens per row) through every layer
     against the cache: each layer writes the new tokens' columns
-    (``write(c_all, l, cols [B, heads, width, C]) -> c_all``) into each of
-    the cache's arrays, then attends dense over layer ``l`` of the cache
-    under ``mask`` [B|1, C, max_len].  ``rotate`` applies the caller's
-    rotary angles (rope only); ``valid`` [B, C] marks the rows a no-drop
-    expert layer routes (None: all).
+    (``write[kind](c_all, l, cols [B, heads, width, C]) -> c_all``) into
+    each of its state kind's arrays, then attends dense over layer ``l``
+    of them under ``mask[kind]`` [B|1, C, rows]; ``write`` and ``mask``
+    are keyed by the layer's attention kind (``"full"``, ``"window"``).
+    ``rotate`` applies the caller's rotary angles (on the layers the model
+    rotates); ``valid`` [B, C] marks the rows a no-drop expert layer
+    routes (None: all).
     → (final-norm activations, arrays, load)."""
     dt = cfg.dtype
     b, c, _ = x.shape
     h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     eps = norm_eps(cfg)
 
-    def attend_mha(y, lp, arrs, l):
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-        k_new = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-        v_new = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-        if cfg.pos_emb == "rope":
-            q, k_new = rotate(q), rotate(k_new)
-        k_all = write(arrs["k"], l, _as_columns(k_new, arrs["k"].dtype))
-        v_all = write(arrs["v"], l, _as_columns(v_new, arrs["v"].dtype))
+    def attend_mha(y, lp, arrs, l, kind):
+        kn, vn = _kv_names(kind)
+        q, k_new, v_new = _qkv(cfg, y, lp,
+                               rotate if cfg.rotates(kind) else None)
+        k_all = write[kind](arrs[kn], l, _as_columns(k_new, arrs[kn].dtype))
+        v_all = write[kind](arrs[vn], l, _as_columns(v_new, arrs[vn].dtype))
         ck, cv = _layer_of(k_all, l), _layer_of(v_all, l)
         # GQA: group query heads over kv heads
         qh = q.reshape(b, c, hk, h // hk, hd)
         scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
                             ck.astype(dt)) / jnp.sqrt(float(hd))
-        scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
+        scores = jnp.where(mask[kind][:, :, None, None, :], scores, -1e30)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
         attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
                           cv.astype(dt))
         attn = attn.reshape(b, c, h, hd)
-        return (jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt)),
-                {"k": k_all, "v": v_all})
+        return (_attn_out(cfg, y, attn, lp),
+                dict(arrs, **{kn: k_all, vn: v_all}))
 
-    def attend_mla(y, lp, arrs, l):
+    def attend_mla(y, lp, arrs, l, kind):
         # absorbed: the chunk's few queries over the cached latents
         q_nope, q_rope = mla.queries(
             y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
             nope=cfg.qk_nope_head_dim, eps=eps, rotate=rotate)
         new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
                           kv_lora=cfg.kv_lora_rank, eps=eps, rotate=rotate)
-        kv_all = write(arrs["kv"], l,
-                       _as_columns(new[:, :, None, :], arrs["kv"].dtype))
+        kv_all = write[kind](arrs["kv"], l, _as_columns(
+            new[:, :, None, :], arrs["kv"].dtype))
         out = mla.attend_absorbed(q_nope, q_rope, _layer_of(kv_all, l)[:, 0],
-                                  lp["wkv_b"], lp["wo"], mask)
+                                  lp["wkv_b"], lp["wo"], mask[kind])
         return out, {"kv": kv_all}
 
     attend = attend_mla if cfg.attention == "mla" else attend_mha
 
-    def layer(xc, lp, arrs, l):
+    def layer(xc, lp, arrs, l, kind):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        delta, arrs = attend(y, lp, arrs, l)
-        xc = xc + delta
+        delta, arrs = attend(y, lp, arrs, l, kind)
+        xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
-        return xc + z, arrs, load
+        return xc + _post(cfg, z, lp, "post_mlp_norm"), arrs, load
 
     x, arrays, load = _scan_cached(cfg, params, x, cache, layer)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
@@ -227,14 +354,14 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     if s > cache_capacity(cache):
         raise ValueError(f"prompt length {s} exceeds cache capacity "
                          f"{cache_capacity(cache)}")
-    x = params["embed"]["tok"][tokens].astype(dt)
+    x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][:s].astype(dt)
     cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
     rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
 
-    def columns(y, lp):
+    def columns(y, lp, kind):
         """What the cache holds of these tokens, from the same pre-norm
         projection the layer itself computes."""
         if cfg.attention == "mla":
@@ -242,20 +369,27 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
                               kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
                               rotate=rotate)
             return {"kv": new[:, :, None, :]}
-        k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-        if cfg.pos_emb == "rope":
-            k = rotate(k)
-        return {"k": k, "v": v}
+        _, k, v = _qkv(cfg, y, lp, rotate if cfg.rotates(kind) else None)
+        return dict(zip(_kv_names(kind), (k, v)))
 
-    def layer(h, lp, arrs, l):
+    def place(c_all, l, cols):
+        """The prompt's columns into layer ``l``: from column 0, or, of a
+        prompt longer than a ring, the last ``ring`` positions, each at its
+        position mod ring (a turn by a count known when tracing)."""
+        rows = c_all.shape[-1]
+        if s > rows:
+            cols = jnp.roll(cols[..., s - rows:], (s - rows) % rows, axis=-1)
+        return jax.lax.dynamic_update_slice(c_all, cols[None],
+                                            (l, 0, 0, 0, 0))
+
+    def layer(h, lp, arrs, l, kind):
         # run the layer for h, re-project for the cache
         y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
-        arrs = {n: jax.lax.dynamic_update_slice(
-            arrs[n], _as_columns(rows, arrs[n].dtype)[None], (l, 0, 0, 0, 0))
-            for n, rows in columns(y, lp).items()}
-        h, _ = _layer(cfg, h, lp, cos, sin)
-        return h, arrs, (0, 0)
+        arrs = dict(arrs, **{
+            n: place(arrs[n], l, _as_columns(rows, arrs[n].dtype))
+            for n, rows in columns(y, lp, kind).items()})
+        h, _ = _layer(cfg, h, lp, cos, sin, kind)
+        return h, arrs, (0, 0, 0)
 
     x, arrays, _ = _scan_cached(cfg, params, x, cache, layer)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
@@ -306,10 +440,11 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     clamped, silently, onto earlier positions (:func:`chunk_window`)."""
     _check_decodable(cfg)
     b, c = tokens.shape
+    _check_chunk(cfg, c)
     dt = cfg.dtype
     pos = cache["pos"]
     max_len = cache_capacity(cache)
-    x = params["embed"]["tok"][tokens].astype(dt)              # [B,C,D]
+    x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
     if cfg.pos_emb == "learned":
         x = x + jax.lax.dynamic_slice_in_dim(
             params["embed"]["pos"], pos, c, axis=0).astype(dt)
@@ -323,14 +458,19 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     # mask[i, t]: cached position t visible to chunk token i (causal
     # within the chunk, everything before it fully visible)
     mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
+    mask = {"full": mask[None]}
+    write = {"full": lambda c_all, l, cols: jax.lax.dynamic_update_slice(
+        c_all, cols[None], (l, 0, 0, 0, pos))}
+    if "window" in cfg.kinds:
+        ring = window_ring(cfg, max_len)
+        mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)[None]
+        write["window"] = _ring_write_chunk(pos, c, ring)
     valid = None if n_valid is None else \
         jnp.broadcast_to(jnp.arange(c) < n_valid, (b, c))
     x, arrays, load = _attend_cached(
         cfg, params, x, cache,
         rotate=lambda t: apply_rotary(t, cos, sin),
-        write=lambda c_all, l, cols: jax.lax.dynamic_update_slice(
-            c_all, cols[None], (l, 0, 0, 0, pos)),
-        mask=mask[None], valid=valid)
+        write=write, mask=mask, valid=valid)
     if n_valid is None:
         last, step = x[:, -1], c
     else:
@@ -481,7 +621,13 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
     paused slots and rejected speculative writes rely on — and the
     suffix prefill overwrites them before ``pos`` ever reaches them.
     ``slot`` and ``upto`` are TRACED, so one compiled program serves
-    every (donor slot, prefix length) pair."""
+    every (donor slot, prefix length) pair.
+
+    A window layer's RING is copied as it stands: it holds the donor's
+    LAST positions, not its first ``upto``.  The copy is exact only while
+    the donor stands at ``upto`` or its whole context still fits its
+    window; the caller checks that (`serve/decode_session.py`
+    ``_prefix_exact``) and refuses the reuse where it does not hold."""
     out = {name: jax.lax.dynamic_slice(
         a, (0, slot, 0, 0, 0), (a.shape[0], 1) + a.shape[2:])
         for name, a in cache_arrays(slot_cache).items()}
@@ -522,11 +668,12 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     short of a prompt's last token)."""
     _check_decodable(cfg)
     s, c = tokens.shape
+    _check_chunk(cfg, c)
     dt = cfg.dtype
     pos = cache["pos"]                                         # [S]
     max_len = cache_capacity(cache)
     posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
-    x = params["embed"]["tok"][tokens].astype(dt)              # [S,C,D]
+    x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][posm].astype(dt)
     if cfg.pos_emb == "rope":
@@ -537,16 +684,25 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     else:
         cos = sin = None
 
-    def write(c_all, l, cols):                    # [S, heads, width, C]
-        for slot in range(s):
-            for i in reversed(range(c)):
-                c_all = jax.lax.dynamic_update_slice(
-                    c_all, cols[None, slot:slot + 1, :, :, i:i + 1],
-                    (l, slot, 0, 0, pos[slot] + i))
-        return c_all
+    def column_writes(column):
+        def write(c_all, l, cols):                # [S, heads, width, C]
+            for slot in range(s):
+                for i in reversed(range(c)):
+                    c_all = jax.lax.dynamic_update_slice(
+                        c_all, cols[None, slot:slot + 1, :, :, i:i + 1],
+                        (l, slot, 0, 0, column(pos[slot] + i)))
+            return c_all
+        return write
 
     # mask[s, i, t]: cached position t visible to fed token i of slot s
-    mask = jnp.arange(max_len)[None, None, :] <= posm[:, :, None]
+    mask = {"full": jnp.arange(max_len)[None, None, :] <= posm[:, :, None]}
+    write = {"full": column_writes(lambda p: p)}
+    if "window" in cfg.kinds:
+        # a ring has no end to be clamped onto: position p is column p mod
+        # ring, and a column is masked by the position it holds
+        ring = window_ring(cfg, max_len)
+        mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)
+        write["window"] = column_writes(lambda p: p % ring)
     valid = None if active is None else \
         jnp.broadcast_to(active[:, None], (s, c))
     return _attend_cached(

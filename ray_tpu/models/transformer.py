@@ -27,7 +27,24 @@ Design (idiomatic JAX, not a torch translation):
   * the router kind decides the expert layer: ``"softmax"`` is the
     capacity einsum of `ops/moe.py` that trains over an ``ep`` mesh (and
     drops over capacity), ``"sigmoid"`` the bias-corrected choice with
-    shared experts that drops nothing (`ops/moe.routed_ffn`).
+    shared experts that drops nothing (`ops/moe.routed_ffn`).  A sigmoid
+    model may HOLD a share of its experts (``experts_held`` of
+    ``n_experts`` from ``expert_offset``: one chip's part of an expert-
+    parallel layer): the router stays ``n_experts`` wide, the layer
+    computes its own experts' part, and nothing stands in for the rest.
+  * a head's width is its own (``head_size``; ``d_model // n_heads``
+    where the model states none), and what an attention block adds to the
+    plain one is a property each: RMS norms over a head's queries and keys
+    (``qk_norm``), a sigmoid gate on the heads' output (``attn_gate``),
+    norms after attention and feed-forward as well as before
+    (``sandwich_norm``), an embedding multiplier (``embed_scale``).
+  * a layer's attention KIND (``layer_kinds``: ``"window"`` | ``"full"``)
+    may change from layer to layer and repeat inside a run: a window layer
+    sees the last ``sliding_window`` positions, and with ``rope_layers =
+    "window"`` only window layers are rotated.  `layer_segments` cuts the
+    runs where the kind changes; a segment that is part of a run loops
+    over indices INTO the run's stacked tree (a slice of it would be a
+    copy of the weights).
 
 Configs: ``TransformerConfig.gpt2()`` (learned positions, GELU, LayerNorm)
 and ``TransformerConfig.llama()`` (RoPE, SwiGLU, RMSNorm, GQA).
@@ -110,10 +127,44 @@ class TransformerConfig:
     v_head_dim: int = 0
     norm_eps: Optional[float] = None  # None → the norm's own default
     #   (rmsnorm 1e-6, layernorm 1e-5)
+    # -- what an MHA/GQA block may add to the plain one ---------------------
+    head_size: Optional[int] = None   # a head's width (None → d_model //
+    #   n_heads); queries are n_heads * head_size wide, not d_model
+    qk_norm: bool = False             # RMS norm over each head's q and k
+    attn_gate: bool = False           # heads' output * sigmoid(y W_g)
+    sandwich_norm: bool = False       # norms after attention and FFN too
+    embed_scale: float = 1.0          # multiplies the token embedding
+    # -- window and full attention mixed ------------------------------------
+    layer_kinds: Optional[Tuple[str, ...]] = None  # a layer "window" |
+    #   "full", in model order (None → all full); may repeat inside a run
+    sliding_window: int = 0           # a window layer's position i sees
+    #   j <= i with i - j < sliding_window
+    rope_layers: str = "all"          # "all" | "window": which kinds of
+    #   layer a rope model rotates (the others carry no position at all)
+    window_chunk: int = 128           # the widest run of new tokens one
+    #   cached program may feed: a window layer's cache is a ring of
+    #   sliding_window + window_chunk rows (models/generate.py)
+    # -- a share of the experts (one chip of an expert-parallel layer) ------
+    experts_held: Optional[int] = None  # None → all n_experts
+    expert_offset: int = 0            # the first expert held
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Each layer's attention kind, in model order."""
+        return self.layer_kinds or ("full",) * self.n_layers
+
+    def rotates(self, kind: str) -> bool:
+        """Whether a layer of this kind turns its queries and keys."""
+        return self.pos_emb == "rope" and (
+            self.rope_layers == "all" or kind == "window")
 
     @property
     def kv_heads(self) -> int:
@@ -138,6 +189,22 @@ class TransformerConfig:
         if lead:
             return (("dense_layers", lead), ("layers", self.n_layers - lead))
         return (("layers", self.n_layers),)
+
+    @property
+    def layer_segments(self) -> Tuple[Tuple[str, int, int, str], ...]:
+        """`layer_runs` cut where the attention kind changes: (run, first
+        layer within the run, layers, kind), in model order.  A model of
+        one kind has one segment a run."""
+        out, first = [], 0
+        for run, n in self.layer_runs:
+            kinds = self.kinds[first:first + n]
+            start = 0
+            for i in range(1, n + 1):
+                if i == n or kinds[i] != kinds[start]:
+                    out.append((run, start, i - start, kinds[start]))
+                    start = i
+            first += n
+        return tuple(out)
 
     @property
     def ff_dim(self) -> int:
@@ -194,7 +261,8 @@ def _attn_matmul_params(cfg: TransformerConfig) -> int:
                 + d * (cfg.kv_lora_rank + rope)
                 + cfg.kv_lora_rank * h * (nope + v) + h * v * d)
     hd = cfg.head_dim
-    return d * h * hd + 2 * d * cfg.kv_heads * hd + h * hd * d
+    gate = d * h * hd if cfg.attn_gate else 0
+    return d * h * hd + 2 * d * cfg.kv_heads * hd + h * hd * d + gate
 
 
 def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
@@ -205,7 +273,10 @@ def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
     d = cfg.d_model
     per = 3 if cfg.activation == "swiglu" else 2
     if cfg.n_experts and run == "layers":
-        routed = cfg.expert_top_k if active else cfg.n_experts
+        routed = cfg.n_experts_held
+        if active:   # of a token's top-k experts, the share held here
+            routed = cfg.expert_top_k if routed == cfg.n_experts \
+                else cfg.expert_top_k * routed / cfg.n_experts
         mlp = (routed + cfg.n_shared_experts) * d * cfg.expert_ff_dim * per \
             + d * cfg.n_experts                                  # + router
     else:
@@ -229,11 +300,24 @@ def _attn_flops_dim(cfg: TransformerConfig) -> int:
     return cfg.n_heads * cfg.head_dim
 
 
+def _attended(cfg: TransformerConfig, context_len: float,
+              windows: int = 1) -> float:
+    """Positions one query at depth ``context_len`` attends, summed over
+    the layers: a window layer stops at ``windows`` x its window (2 where
+    the caller halves the sum for a causal sequence's mean)."""
+    return sum(min(context_len, windows * cfg.sliding_window)
+               if kind == "window" else context_len for kind in cfg.kinds)
+
+
 def count_params(cfg: TransformerConfig) -> int:
     d = cfg.d_model
     norms = 2 * d * (2 if cfg.norm == "layernorm" else 1)
+    if cfg.sandwich_norm:            # two more scales, no bias
+        norms += 2 * d
     if cfg.attention == "mla":
         norms += cfg.q_lora_rank + cfg.kv_lora_rank
+    elif cfg.qk_norm:
+        norms += 2 * cfg.head_dim
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms
     if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
         layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
@@ -252,7 +336,8 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     # qk+pv over the visible window: half the positions when causal,
     # all of them for bidirectional encoders (causal=False)
     attn_factor = 6 if cfg.causal else 12
-    attn = attn_factor * cfg.n_layers * _attn_flops_dim(cfg) * seq_len
+    attn = attn_factor * _attn_flops_dim(cfg) * _attended(
+        cfg, seq_len, 2 if cfg.causal else 1)
     return 6 * n_matmul + attn
 
 
@@ -272,7 +357,7 @@ def decode_flops_per_token(cfg: TransformerConfig,
                                  + cfg.qk_rope_head_dim)
     else:
         per_pos = 2 * cfg.n_heads * cfg.head_dim
-    return 2 * n_matmul + 2 * cfg.n_layers * per_pos * context_len
+    return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len)
 
 
 def engine_flops_table(cfg: TransformerConfig, max_len: int,
@@ -337,17 +422,28 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
         add("wk", (d, hk, hd), d, ("embed", "heads", "kv"))
         add("wv", (d, hk, hd), d, ("embed", "heads", "kv"))
         add("wo", (h, hd, d), h * hd, ("heads", "kv", "embed"))
+        if cfg.attn_gate:
+            add("wg", (d, h, hd), d, ("embed", "heads", "kv"))
+        if cfg.qk_norm:
+            p["q_norm"], p["k_norm"] = jnp.ones((L, hd), pt), \
+                jnp.ones((L, hd), pt)
+            ax["q_norm"] = ax["k_norm"] = ("layers", None)
     else:
         raise ValueError(f"attention={cfg.attention!r}: expected 'mha' or "
                          f"'mla'")
+    if cfg.sandwich_norm:
+        p["post_attn_norm"], p["post_mlp_norm"] = jnp.ones((L, d), pt), \
+            jnp.ones((L, d), pt)
+        ax["post_attn_norm"] = ax["post_mlp_norm"] = ("layers", "embed")
     gated = cfg.activation == "swiglu"
     if cfg.n_experts and run == "layers":
-        E, f = cfg.n_experts, cfg.expert_ff_dim
+        # the router scores ALL experts; the stacks hold this chip's
+        E, held, f = cfg.n_experts, cfg.n_experts_held, cfg.expert_ff_dim
         add("router", (d, E), d, ("embed", "expert"))
-        add("w_in", (E, d, f), d, ("expert", "embed", "mlp"))
-        add("w_out", (E, f, d), f, ("expert", "mlp", "embed"))
+        add("w_in", (held, d, f), d, ("expert", "embed", "mlp"))
+        add("w_out", (held, f, d), f, ("expert", "mlp", "embed"))
         if gated:
-            add("w_gate", (E, d, f), d, ("expert", "embed", "mlp"))
+            add("w_gate", (held, d, f), d, ("expert", "embed", "mlp"))
         if cfg.router == "sigmoid":
             # moves the choice of experts, never their weights; float32
             # like the scores it is added to
@@ -441,10 +537,16 @@ _EXPERT_STACKS = ("w_in", "w_gate", "w_out")
 
 def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
                     whole_expert_stacks: bool = False):
-    """THE layer loop: ``body(carry, lp) -> carry`` over every layer of the
-    declared pattern, one `lax.scan` per run of identical layers
-    (`TransformerConfig.layer_runs`), the carry handed from run to run.
-    `_trunk` and `generate._scan_cached` both loop through here.
+    """THE layer loop: ``body(carry, lp, kind) -> carry`` over every layer
+    of the declared pattern, one `lax.scan` per segment of identical layers
+    (`TransformerConfig.layer_segments`: a run, cut where the attention
+    kind changes), the carry handed from segment to segment.  `_trunk` and
+    `generate._scan_cached` both loop through here.
+
+    A segment that is a whole run scans over the run's stacked tree.  One
+    that is PART of a run (the kind repeats inside the run) scans over
+    layer indices into the tree: a slice of the stack would be a copy of
+    those layers' weights on every call.
 
     ``whole_expert_stacks`` (the cached programs): a run's routed-expert
     weights are not scanned over.  The grouped matmul is a kernel call, and
@@ -452,26 +554,70 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
     every weight of every expert for a few rows of work.  The body gets
     ``(stack [L, E, d, f], layer)`` instead and `ops.moe.routed_ffn` hands
     the kernel the whole stack with the groups of the other layers empty."""
-    for run, n in cfg.layer_runs:
+    run_len = dict(cfg.layer_runs)
+    for run, first, n, kind in cfg.layer_segments:
         tree = params[run]
         whole = {k: tree[k] for k in _EXPERT_STACKS
                  if whole_expert_stacks and cfg.router == "sigmoid"
                  and "router" in tree and k in tree}
         xs = {k: v for k, v in tree.items() if k not in whole}
 
-        def step(c, x):
-            lp, i = x
-            return body(c, dict(lp, **{k: (v, i) for k, v in whole.items()})
-                        ), None
+        def layer(c, lp, i):
+            return body(c, dict(lp, **{k: (v, i) for k, v in whole.items()}),
+                        kind)
 
-        carry, _ = jax.lax.scan(step, carry, (xs, jnp.arange(n)))
+        if n == run_len[run]:
+            carry, _ = jax.lax.scan(
+                lambda c, x: (layer(c, *x), None), carry,
+                (xs, jnp.arange(n)))
+        else:
+            carry, _ = jax.lax.scan(
+                lambda c, i: (layer(c, {
+                    k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
+                    for k, v in xs.items()}, i), None),
+                carry, first + jnp.arange(n))
     return carry
 
 
-def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
-           cos, sin) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One transformer block; returns (x, router_aux_loss)."""
+def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate):
+    """Normed input [b, s, d] -> (q [b, s, h, hd], k, v [b, s, hk, hd]) of
+    an MHA/GQA block: the three projections, the per-head RMS norms where
+    the model has them, then ``rotate`` (None: this layer turns nothing)."""
     dt = cfg.dtype
+    q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
+    k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
+    v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
+    if cfg.qk_norm:
+        q = rmsnorm(q, lp["q_norm"], norm_eps(cfg))
+        k = rmsnorm(k, lp["k_norm"], norm_eps(cfg))
+    if rotate is not None:
+        q, k = rotate(q), rotate(k)
+    return q, k, v
+
+
+def _attn_out(cfg: TransformerConfig, y: jnp.ndarray, attn: jnp.ndarray,
+              lp: Params) -> jnp.ndarray:
+    """Heads' output [b, s, h, hd] -> the block's [b, s, d]: gated by
+    ``sigmoid(y W_g)`` where the model gates, then the output projection."""
+    dt = cfg.dtype
+    if cfg.attn_gate:
+        gate = jnp.einsum("bsd,dhk->bshk", y, lp["wg"].astype(dt))
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+    return jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
+
+
+def _post(cfg: TransformerConfig, delta: jnp.ndarray, lp: Params,
+          name: str) -> jnp.ndarray:
+    """A sandwich model's norm on what a block adds to the residual."""
+    if not cfg.sandwich_norm:
+        return delta
+    return _norm(cfg, delta, lp[name], None)
+
+
+def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
+           cos, sin, kind: str = "full") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One transformer block of attention kind ``kind``; returns (x,
+    router_aux_loss)."""
 
     norm = functools.partial(_norm, cfg)
     if cfg.norm_remat:
@@ -489,23 +635,22 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
         latent = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
                              kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
                              rotate=rotate)
-        x = x + mla.attend_plain(q_nope, q_rope, latent, lp["wkv_b"],
-                                 lp["wo"], causal=cfg.causal,
-                                 impl=cfg.attention_impl)
+        x = x + _post(cfg, mla.attend_plain(
+            q_nope, q_rope, latent, lp["wkv_b"], lp["wo"],
+            causal=cfg.causal, impl=cfg.attention_impl), lp,
+            "post_attn_norm")
     else:
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-        k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
-        if cfg.pos_emb == "rope":
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
-        attn = multi_head_attention(q, k, v, causal=cfg.causal,
-                                    impl=cfg.attention_impl)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
+        q, k, v = _qkv(cfg, y, lp, functools.partial(
+            apply_rotary, cos=cos, sin=sin) if cfg.rotates(kind) else None)
+        attn = multi_head_attention(
+            q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
+            window=cfg.sliding_window if kind == "window" else None)
+        x = x + _post(cfg, _attn_out(cfg, y, attn, lp), lp,
+                      "post_attn_norm")
 
     y = norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
     z, aux, _ = _ffn(cfg, y, lp)
-    return x + z, aux
+    return x + _post(cfg, z, lp, "post_mlp_norm"), aux
 
 
 def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
@@ -520,7 +665,7 @@ def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
     return jnp.einsum("bsf,fd->bsd", z, w_out.astype(dt))
 
 
-_NO_LOAD = (0, 0)
+_NO_LOAD = (0, 0, 0)
 
 
 def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
@@ -533,7 +678,8 @@ def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
     marks the rows that count (a decode batch's live slots); only the
     no-drop expert layer, whose cost follows the rows routed, looks at it.
     → (residual delta, router aux loss, (experts touched, largest expert
-    load) of this layer, zeros where it routes nothing)."""
+    load, pairs that landed on an expert held here) of this layer, zeros
+    where it routes nothing)."""
     aux = jnp.zeros((), jnp.float32)
     if "router" not in lp:
         return (_glu(cfg, y, lp["w_in"], lp.get("w_gate"), lp["w_out"]),
@@ -554,7 +700,8 @@ def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
                            cfg.expert_top_k, cfg.routed_scaling_factor)
     z, load = routed_ffn(flat, idx, w, lp["w_in"], lp["w_out"],
                          lp.get("w_gate"),
-                         None if valid is None else valid.reshape(b * s))
+                         None if valid is None else valid.reshape(b * s),
+                         expert_offset=cfg.expert_offset)
     z = z.reshape(b, s, d)
     if cfg.n_shared_experts:
         z = z + _glu(cfg, y, lp["ws_in"], lp.get("ws_gate"), lp["ws_out"])
@@ -591,17 +738,18 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
     else:  # a typo must not silently mean the gather path (cf. remat_policy)
         raise ValueError(f"embed_impl={cfg.embed_impl!r}: expected "
                          f"'gather' or 'one_hot'")
+    x = _scale_embedding(cfg, x)
     if cfg.pos_emb == "learned":
         x = x + params["embed"]["pos"][:s].astype(dt)
     cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
 
-    layer = functools.partial(_layer, cfg)
     policy = remat_policy(cfg.remat)
-    if policy is not None:
-        layer = jax.checkpoint(layer, static_argnums=(), policy=policy)
 
-    def body(carry, lp):
+    def body(carry, lp, kind="full"):
+        layer = functools.partial(_layer, cfg, kind=kind)
+        if policy is not None:
+            layer = jax.checkpoint(layer, static_argnums=(), policy=policy)
         h, aux = carry
         h, aux_l = layer(h, lp, cos, sin)
         return h, aux + aux_l
@@ -637,6 +785,13 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         aux = aux / cfg.n_layers
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return x, aux
+
+
+def _scale_embedding(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """The embedding multiplier of a model that states one."""
+    if cfg.embed_scale == 1.0:
+        return x
+    return (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
 
 
 def _unembed(params: Params, cfg: TransformerConfig) -> jnp.ndarray:

@@ -36,9 +36,11 @@ def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
 
 def reference_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = True,
-                        sm_scale: Optional[float] = None) -> jnp.ndarray:
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jnp.ndarray:
     """Plain softmax(QKᵀ)V with fp32 statistics; the correctness oracle for
-    the flash kernel and the CPU execution path."""
+    the flash kernel and the CPU execution path.  ``window`` (causal only):
+    row i sees the keys j <= i with i - j < window."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     n_rep = q.shape[2] // k.shape[2]
@@ -50,6 +52,8 @@ def reference_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         s_q, s_k = q.shape[1], k.shape[1]
         rows = jnp.arange(s_q)[:, None] + (s_k - s_q)
         mask = rows >= jnp.arange(s_k)[None, :]
+        if window is not None:
+            mask &= rows - jnp.arange(s_k)[None, :] < window
         # additive bias rather than jnp.where: a select against an invariant
         # constant inside a partial-manual shard_map scan (the pp pipeline)
         # trips an XLA partitioner CHECK ("invalid binary opcode copy");
@@ -105,7 +109,22 @@ def _flash_per_shard(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          causal: bool = True,
                          sm_scale: Optional[float] = None,
-                         impl: str = "auto") -> jnp.ndarray:
+                         impl: str = "auto",
+                         window: Optional[int] = None) -> jnp.ndarray:
+    """``window`` (a sliding-window layer, causal): a sequence no longer
+    than the window is plain causal attention, whatever the implementation;
+    a longer one takes the reference path, the one implementation with a
+    window mask (the flash and ring kernels have none: a windowed model
+    serves through the cached programs of `models/generate.py`)."""
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal mask")
+    if window is not None and k.shape[1] > window:
+        if impl not in ("auto", "reference"):
+            raise NotImplementedError(
+                f"attention impl {impl!r} has no window mask; a sequence "
+                f"of {k.shape[1]} > window {window} needs 'reference'")
+        return reference_attention(q, k, v, causal=True, sm_scale=sm_scale,
+                                   window=window)
     if impl == "auto":
         impl = "flash" if _flash_ok(q, k) else "reference"
     if impl == "flash":

@@ -2,8 +2,12 @@
 kind (`models/transformer.py` `_ffn`), never by a switch.
 
 **Sigmoid router, no token dropped** (`sigmoid_route`, `routed_ffn`): the
-layer of models that route by a sigmoid score plus a correction bias and
-hold all their experts on the chip that serves them.  Token-expert pairs
+layer of models that route by a sigmoid score plus a correction bias.  It
+is TOLD which experts it holds (the stacks it is given, from
+``expert_offset``), takes the router's choice over ALL experts, and
+computes its own experts' part of the result: a pair whose expert lives on
+another chip joins no group here and adds nothing, and no code stands in
+for the other chips or their exchange.  Token-expert pairs
 are SORTED by expert and the three expert matmuls are grouped matmuls
 (`jax.lax.ragged_dot`: row block i of the sorted pairs meets expert i's
 weights), so the cost follows the pairs routed and the experts they touch,
@@ -42,10 +46,12 @@ import jax.numpy as jnp
 
 
 class Load(NamedTuple):
-    """What one `routed_ffn` call routed (int32 scalars): experts that got
-    at least one pair, and the pairs of the fullest expert."""
+    """What one `routed_ffn` call routed (int32 scalars), of the experts
+    HELD: those that got at least one pair, the pairs of the fullest, and
+    the pairs that landed on any of them."""
     experts_touched: jnp.ndarray
     load_max: jnp.ndarray
+    pairs: jnp.ndarray
 
 
 def sigmoid_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
@@ -68,11 +74,16 @@ def sigmoid_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
 def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
                w_in: jnp.ndarray, w_out: jnp.ndarray,
                w_gate: Optional[jnp.ndarray] = None,
-               valid: Optional[jnp.ndarray] = None
-               ) -> Tuple[jnp.ndarray, Load]:
-    """Every token through each of its chosen experts, none dropped.
+               valid: Optional[jnp.ndarray] = None, *,
+               expert_offset: int = 0) -> Tuple[jnp.ndarray, Load]:
+    """Every token through each of its chosen experts that is held here,
+    none dropped.
 
-    y [n, d]; idx, w [n, k] from a router; w_in [E, d, f], w_out [E, f, d],
+    y [n, d]; idx, w [n, k] from a router over ALL the model's experts;
+    the stacks hold the ``E`` experts ``expert_offset .. expert_offset + E
+    - 1`` (all of them where the model is not shared out): a pair whose
+    expert is another chip's joins no group and adds nothing, as a row
+    that is not ``valid``.  w_in [E, d, f], w_out [E, f, d],
     w_gate [E, d, f] selects SwiGLU (None -> GELU), or each of the three as
     ``(stack [L, E, .., ..], layer)``: the whole stack of a run of layers
     and which of them this is (a slice of the stack would be copied out
@@ -102,9 +113,11 @@ def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
         y, idx, w, valid = (jnp.pad(a, [(0, fill)] + [(0, 0)] * (a.ndim - 1))
                             for a in (y, idx, w, valid))
     n, k = idx.shape
-    pair_expert = idx.reshape(-1)
+    pair_expert = idx.reshape(-1) - expert_offset
+    here = (pair_expert >= 0) & (pair_expert < n_experts)
     if valid is not None:
-        pair_expert = jnp.where(jnp.repeat(valid, k), pair_expert, n_experts)
+        here &= jnp.repeat(valid, k)
+    pair_expert = jnp.where(here, pair_expert, n_experts)
     order = jnp.argsort(pair_expert, stable=True)                 # [n*k]
     sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[pair_expert].add(1)
     sizes = sizes[:n_experts]
@@ -123,12 +136,12 @@ def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     # back to token order: pair j of token i sits at sorted row inv[i*k+j]
     inv = jnp.argsort(order)
     out = out[inv].reshape(n, k, -1)
-    if valid is not None:          # rows of no group hold nothing defined
-        out = jnp.where(valid[:, None, None], out, 0)
+    # rows of no group hold nothing defined
+    out = jnp.where(here.reshape(n, k, 1), out, 0)
     out = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), w)
     out = out[:n - fill]
     return out.astype(dt), Load((sizes > 0).sum().astype(jnp.int32),
-                                sizes.max())
+                                sizes.max(), sizes.sum())
 
 
 def expert_capacity(seq_tokens: int, n_experts: int, top_k: int,

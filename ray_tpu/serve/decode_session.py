@@ -195,8 +195,9 @@ class ContinuousBatchingEngine:
 
         from ..models import (cache_insert_slot, draft_propose_slots,
                               prefill_chunk_jit, verify_step_slots)
-        from ..models.generate import _decode_step_slots, cache_arrays
-        self._cache_arrays = cache_arrays
+        from ..models.generate import (_decode_step_slots, cache_arrays,
+                                       cache_bytes)
+        self._cache_arrays, self._cache_bytes = cache_arrays, cache_bytes
         self.cfg = cfg
         self.max_len = max_len
         self.params = params
@@ -204,9 +205,10 @@ class ContinuousBatchingEngine:
         self._tag = replica_tag
 
         # a model whose expert layers drop nothing reports what a step
-        # routed: (experts touched, largest expert load), each summed
-        # over the expert layers, ride BEHIND the tokens in the step's
-        # one int32 vector, so the loop still makes one read per step
+        # routed: (experts touched, largest expert load, pairs that
+        # landed on an expert held here), each summed over the expert
+        # layers, ride BEHIND the tokens in the step's one int32 vector,
+        # so the loop still makes one read per step
         self._moe_layers = moe_layers = dict(cfg.layer_runs)["layers"] \
             if cfg.n_experts and cfg.router == "sigmoid" else 0
         slots = engine_cfg.max_slots
@@ -301,9 +303,16 @@ class ContinuousBatchingEngine:
         self._capacity = min([max_len] + [
             c.max_seq_len for c in (cfg, self._draft_cfg)
             if c is not None and c.pos_emb == "learned"])
+        # ... and no wider than the room a window layer's ring leaves
+        # beside its window (target and draft alike)
+        room = min([self._capacity] + [
+            c.window_chunk for c in (cfg, self._draft_cfg)
+            if c is not None and "window" in c.kinds])
         self.ecfg = dataclasses.replace(
             engine_cfg, prefill_chunk_tokens=prefill_chunk_width(
-                engine_cfg.prefill_chunk_tokens, params, self._capacity))
+                engine_cfg.prefill_chunk_tokens, params, room))
+        self._window = cfg.sliding_window if "window" in cfg.kinds else 0
+        self._window_layers = cfg.kinds.count("window")
         self._spec_k = max(2, int(engine_cfg.spec_k))
         self._spec_disabled = False
         self._spec_fail_streak = 0
@@ -338,6 +347,16 @@ class ContinuousBatchingEngine:
         self.moe = dict.fromkeys(
             ("steps", "experts_touched", "pairs", "load_max"), 0)
         self._moe_span = dict(self.moe, t=time.time())
+        # cache rows the live slots' decode steps attended, summed over
+        # layers (a window layer stops at its window), beside what they
+        # would have attended were every layer full: `stats()["cache"]`
+        # and, as `moe:load`, one ring span `cache:rows` every
+        # `_MOE_SPAN_S` seconds with the sums since the last
+        self.rows = dict.fromkeys(("steps", "rows_read", "rows_if_full"), 0)
+        self._rows_span = dict(self.rows, t=time.time())
+        # slot -> the session whose prompt the prefix index advertises
+        # there (its `pos` is how far that slot's rings have moved on)
+        self._donors: Dict[int, _EngineSession] = {}
         # analytic FLOPs/token per program -> the profiler's MFU
         # numerators (models.engine_flops_table; pure-copy programs 0)
         from ..models import engine_flops_table
@@ -553,7 +572,7 @@ class ContinuousBatchingEngine:
                     # decode steps' routing, summed over expert layers
                     # (zeros for a model without a no-drop expert layer)
                     "moe": dict(self.moe, layers=self._moe_layers,
-                                experts=self.cfg.n_experts),
+                                experts=self.cfg.n_experts_held),
                     "cache": self._cache_stats(),
                     # every distinct program shape this engine has
                     # dispatched — a compile-storm regression (one
@@ -583,14 +602,18 @@ class ContinuousBatchingEngine:
                     "phase_totals": self.phase_totals()}
 
     def _cache_stats(self) -> Dict[str, int]:
-        """Bytes of the slot cache's arrays (whatever the model's
-        attention kind holds) and of one position of one slot over all
-        layers; zeros until the first session allocates it."""
-        nbytes = sum(int(a.nbytes) for a in
-                     self._cache_arrays(self._cache or {}).values())
-        return {"bytes": nbytes,
+        """Bytes of the slot cache by state kind (``bytes_full``: the
+        arrays that hold ``max_len`` rows a slot; ``bytes_ring``: the
+        window layers' rings), what ONE further position of a slot costs
+        (the full arrays' bytes a row: a ring grows with nothing), and the
+        rows the decode steps read; zeros until the first session
+        allocates the cache."""
+        kinds = self._cache_bytes(self._cache or {})
+        return {"bytes": kinds["full"] + kinds["ring"],
+                "bytes_full": kinds["full"], "bytes_ring": kinds["ring"],
                 "bytes_per_position":
-                    nbytes // (self.ecfg.max_slots * self.max_len)}
+                    kinds["full"] // (self.ecfg.max_slots * self.max_len),
+                **self.rows}
 
     def phase_totals(self) -> Dict[str, float]:
         """Cumulative serve-phase seconds — the serve_breakdown
@@ -724,8 +747,10 @@ class ContinuousBatchingEngine:
                 # (its rows are about to be overwritten by
                 # cache_insert_slot)
                 self._prefix.evict(slot)
+                self._donors.pop(slot, None)
                 if sess.ptoks:
                     self._prefix.insert(sess.ptoks, slot)
+                    self._donors[slot] = sess
             admitted.append((sess, sess.pcache, sess.dcache, slot))
             sess.pcache = sess.dcache = None
         return admitted
@@ -792,6 +817,29 @@ class ContinuousBatchingEngine:
         surfaces in stats() so a per-path compile storm is visible."""
         self._shapes.add((kind,) + tuple(int(d) for d in dims))
 
+    def _prefix_exact(self, donor: int, depth: int) -> bool:
+        """Whether slot ``donor`` still holds what a session seeded with
+        its first ``depth`` positions attends.  A full layer's rows below
+        ``depth`` are never rewritten; a window layer's ring has moved on
+        with the donor: whatever was written there (ahead of its ``pos``
+        included) left the positions ``>= pos - sliding_window`` intact.
+        So a donor whose whole context still fits its window serves any
+        prefix, and one that stands at the prefix (it has decoded no more
+        than one token past it) serves a session whose chunk windows
+        start at ``depth`` or later: the seeded session's first query
+        needs the positions from ``depth - sliding_window + 1`` on, and a
+        window set back at the capacity edge (`chunk_window`) would need
+        earlier ones.  Any other donor is refused, and the prompt
+        prefills from its start."""
+        if not self._window:
+            return True
+        sess = self._donors.get(donor)
+        if sess is None:
+            return False
+        return sess.pos <= self._window or (
+            sess.pos <= depth + 1 and
+            depth + self.ecfg.prefill_chunk_tokens <= self._capacity)
+
     def _prefill_advance(self, sess: _EngineSession) -> Optional[int]:
         """Run ONE fixed-shape chunk program of a joining session's
         prompt (target + draft when speculating) on the engine thread —
@@ -821,7 +869,8 @@ class ContinuousBatchingEngine:
                 # when the slot is reassigned, and freed slots' rows
                 # below the match depth are never written in between
                 if donor is not None and \
-                        depth >= max(1, self.ecfg.prefix_cache_min_tokens):
+                        depth >= max(1, self.ecfg.prefix_cache_min_tokens) \
+                        and self._prefix_exact(donor, depth):
                     from ..core.runtime_metrics import (
                         SERVE_PREFIX_HITS, SERVE_PREFIX_TOKENS_REUSED)
                     sess.pcache = self._gather(self._cache,
@@ -944,7 +993,7 @@ class ContinuousBatchingEngine:
         slots = self.ecfg.max_slots
         # a step's routing counts ride behind its tokens (`fused_step`):
         # the host's row is as long, so both ways in are one shape
-        tokens = np.zeros(slots + (2 if self._moe_layers else 0), np.int32)
+        tokens = np.zeros(slots + (3 if self._moe_layers else 0), np.int32)
         tok_dev = None       # device-resident step output → next input
         active_dev = None
         active_key: Any = None
@@ -1029,7 +1078,8 @@ class ContinuousBatchingEngine:
                         new_toks = np.asarray(tok_dev)
                         tokens[:] = new_toks
                     if self._moe_layers:
-                        self._count_moe(len(batch), new_toks[slots:])
+                        self._count_moe(new_toks[slots:])
+                    self._count_rows(batch)
                 except Exception as e:
                     self._fail_slots(f"decode engine step failed: {e!r}")
                     tok_dev = None
@@ -1040,26 +1090,53 @@ class ContinuousBatchingEngine:
 
     _MOE_SPAN_S = 2.0
 
-    def _count_moe(self, occupancy: int, load) -> None:
+    def _count_moe(self, load) -> None:
         """One decode step's routing into the counters, and the sums since
         the last `moe:load` span into the next one when it is due."""
-        from ..util import tracing
         with self._cond:   # stats() reads these
             self.moe["steps"] += 1
             self.moe["experts_touched"] += int(load[0])
             self.moe["load_max"] += int(load[1])
-            self.moe["pairs"] += (occupancy * self.cfg.expert_top_k
-                                  * self._moe_layers)
-        now, last = time.time(), self._moe_span
-        if now - last["t"] >= self._MOE_SPAN_S:
-            # a category of its own: the ring keeps a bound a category,
-            # and the two `serve` spans of every `next_chunk` call push a
-            # span out of a full ring within seconds of a busy window
-            tracing.record_span(
-                "moe:load", "moe", last["t"], now, deployment=self.name,
-                layers=self._moe_layers, experts=self.cfg.n_experts,
-                **{k: self.moe[k] - last[k] for k in self.moe})
-            self._moe_span = dict(self.moe, t=now)
+            self.moe["pairs"] += int(load[2])
+        # a category of its own: the ring keeps a bound a category, and
+        # the two `serve` spans of every `next_chunk` call push a span
+        # out of a full ring within seconds of a busy window
+        self._moe_span = self._sums_span(
+            "moe:load", "moe", self.moe, self._moe_span,
+            layers=self._moe_layers, experts=self.cfg.n_experts_held)
+
+    def _count_rows(self, batch) -> None:
+        """The cache rows one decode step's live slots attended (each at
+        its position before the step, its own new row included), and the
+        sums since the last `cache:rows` span into the next when due."""
+        full = self.cfg.n_layers - self._window_layers
+        depth = sum(s.pos + 1 for s in batch)
+        seen = sum(min(s.pos + 1, self._window) for s in batch)
+        with self._cond:   # stats() reads these
+            self.rows["steps"] += 1
+            self.rows["rows_read"] += full * depth \
+                + self._window_layers * seen
+            self.rows["rows_if_full"] += self.cfg.n_layers * depth
+        self._rows_span = self._sums_span(
+            "cache:rows", "cache", self.rows, self._rows_span,
+            lambda: {"bytes_" + kind: n for kind, n in
+                     self._cache_bytes(self._cache).items()})
+
+    def _sums_span(self, name: str, category: str, sums: Dict[str, int],
+                   last: Dict[str, Any], args=dict, **more
+                   ) -> Dict[str, Any]:
+        """Every `_MOE_SPAN_S` seconds ONE ring span ``name`` whose
+        arguments are ``sums`` since ``last`` (a span a step cost too
+        much: PERF.md, PR 25) beside ``args()`` and ``more`` → what the
+        next one counts from."""
+        from ..util import tracing
+        now = time.time()
+        if now - last["t"] < self._MOE_SPAN_S:
+            return last
+        tracing.record_span(
+            name, category, last["t"], now, deployment=self.name,
+            **args(), **more, **{k: sums[k] - last[k] for k in sums})
+        return dict(sums, t=now)
 
     def _fail_slots(self, error: str) -> None:
         """A donated step raised: the slot cache it was given may be

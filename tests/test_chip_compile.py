@@ -281,3 +281,93 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
     assert len(calls) == 3 and all(
         "bf16[128,2048,1536]" in c or "bf16[128,1536,2048]" in c
         for c in calls), calls
+
+
+@pytest.mark.parametrize("program", ["fused_step", "prefill_padded_128",
+                                     "prefill_chunk_128", "prefill_chunk_1"])
+def test_window_and_full_layers_copy_no_cache_no_ring_no_weights(topo,
+                                                                 program):
+    """Window layers' rings beside a full layer's rows, at the published
+    widths of the mixed cell's model (one dense and three expert layers, the
+    kind changing INSIDE the run of expert layers, 32 of 256 experts held):
+    the donated program aliases every array of both state kinds and copies
+    none of their shapes (the two-piece write over the ring's seam is a
+    read-modify-write of a chunk's columns, no branch that would copy the
+    ring); the layer loop over PART of a run indexes the stacked weights and
+    copies no layer's slice of an expert stack; and beside the cache there
+    is room for activations only."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params, init_slot_cache, prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots, cache_arrays
+    cfg = TransformerConfig(
+        vocab_size=25024, d_model=3072, n_layers=4, n_heads=48, n_kv_heads=8,
+        head_size=128, d_ff=12288, max_seq_len=262144, pos_emb="rope",
+        rope_layers="window", activation="swiglu", norm="rmsnorm",
+        norm_eps=1e-5, tie_embeddings=False, qk_norm=True, attn_gate=True,
+        sandwich_norm=True, embed_scale=3072 ** 0.5,
+        layer_kinds=("window", "window", "window", "full"),
+        sliding_window=4096, window_chunk=128, n_experts=256,
+        experts_held=32, expert_top_k=4, router="sigmoid", moe_d_ff=3072,
+        n_shared_experts=1, routed_scaling_factor=2.448,
+        first_dense_layers=1, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    assert [s[1:3] for s in cfg.layer_segments] == [(0, 1), (0, 2), (2, 1)]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 16, 16896
+    if program == "fused_step":
+        cache = described(jax.eval_shape(
+            lambda: init_slot_cache(cfg, slots, max_len)))
+
+        def fused_step(params, tok, cache, active):
+            logits, cache, load = _decode_step_slots(params, tok[:slots],
+                                                     cache, active, cfg)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.concatenate([jnp.where(active, nxt, tok[:slots]),
+                                    jnp.stack(load)]), cache
+        lowered = jax.jit(fused_step, donate_argnums=(2,)).lower(
+            params, described(jax.ShapeDtypeStruct((slots + 3,), jnp.int32)),
+            cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    else:
+        width = int(program.rsplit("_", 1)[1])
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        padded = {"n_valid": described(jax.ShapeDtypeStruct((), jnp.int32))} \
+            if program.startswith("prefill_padded") else {}
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
+            cache, cfg=cfg, **padded)
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    arrays = cache_arrays(cache)
+    assert {n: a.shape[-1] for n, a in arrays.items()} == {
+        "k": 16896, "v": 16896, "k_win": 4224, "v_win": 4224}
+    want = sum(a.size * a.dtype.itemsize for a in arrays.values())
+    assert ma.alias_size_in_bytes >= want
+    # float32 scores of the full layer's rows, twice, and activations
+    rows = slots if program == "fused_step" else width
+    scores = rows * cfg.n_heads * max_len * 4
+    assert ma.temp_size_in_bytes < (64 << 20) + 2 * scores, \
+        ma.temp_size_in_bytes
+    text = compiled.as_text()
+    for a in arrays.values():
+        shape = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    # three grouped matmuls a segment of expert layers, each given the whole
+    # stack of 3 x 32 experts; no slice of it is copied out
+    calls = re.findall(r"ragged-dot-none[.\d]* = [^\n]*", text)
+    assert len(calls) == 6 and all(
+        "bf16[96,3072,3072]" in c for c in calls), calls
+    assert not re.findall(r"= bf16\[(?:\d+,)?32,3072,3072\]\S* copy\(", text)

@@ -288,21 +288,23 @@ def test_programs_return_what_their_expert_layers_routed(model):
     cfg, params, _, toks, _ = model
     _, pc, load = jax.jit(functools.partial(_prefill_chunk, cfg=cfg))(
         params, toks[:1, :32], init_kv_cache(cfg, 1, 64))
-    touched, load_max = (int(x) for x in load)
+    touched, load_max, pairs = (int(x) for x in load)
     assert 2 * 2 <= touched <= 2 * 8 and 2 * 8 <= load_max <= 2 * 32
+    # every expert is held: each of the 32 tokens' 2 pairs, in 2 layers
+    assert pairs == 2 * 32 * 2
     slots = jax.jit(cache_insert_slot)(init_slot_cache(cfg, 4, 64), pc,
                                        jnp.int32(2))
     active = jnp.arange(4) == 2
     _, _, load = jax.jit(functools.partial(_decode_step_slots, cfg=cfg))(
         params, jnp.full((4,), toks[0, 32]), slots, active)
     # one live slot: its token's 2 experts in each of the 2 expert layers
-    assert [int(x) for x in load] == [4, 2]
+    assert [int(x) for x in load] == [4, 2, 4]
     # a model without such a layer reports zeros
     dense = TransformerConfig.tiny(dtype=jnp.float32)
     p2, _ = init_params(jax.random.PRNGKey(0), dense)
     _, _, load = _prefill_chunk(p2, toks[:1, :8], init_kv_cache(dense, 1, 32),
                                 dense)
-    assert [int(x) for x in load] == [0, 0]
+    assert [int(x) for x in load] == [0, 0, 0]
 
 
 def test_softmax_presets_keep_the_capacity_einsum():
