@@ -9,11 +9,12 @@ computes its own experts' part of the result: a pair whose expert lives on
 another chip joins no group here and adds nothing, and no code stands in
 for the other chips or their exchange.  Token-expert pairs
 are SORTED by expert and the three expert matmuls are grouped matmuls
-(`jax.lax.ragged_dot`: row block i of the sorted pairs meets expert i's
-weights), so the cost follows the pairs routed and the experts they touch,
-not the number of experts; nothing of size tokens x experts x capacity is
-built and no load, however uneven, loses a token.  It returns what it
-routed (`Load`) for the serve engine's counters.
+(`ops/grouped_matmul.py`: row block i of the sorted pairs meets expert i's
+weights; on a TPU this repo's kernel, which reads each touched expert's
+weights once, elsewhere `jax.lax.ragged_dot`), so the cost follows the
+experts the pairs touch, not the number of experts; nothing of size
+tokens x experts x capacity is built and no load, however uneven, loses a
+token.  It returns what it routed (`Load`) for the serve engine's counters.
 
 **Softmax router with capacity** (`route`, `moe_ffn`): the GShard/Switch
 einsum formulation for the softmax presets that TRAIN over the mesh's
@@ -43,6 +44,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from .grouped_matmul import grouped_matmul
 
 
 class Load(NamedTuple):
@@ -87,31 +90,20 @@ def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     w_gate [E, d, f] selects SwiGLU (None -> GELU), or each of the three as
     ``(stack [L, E, .., ..], layer)``: the whole stack of a run of layers
     and which of them this is (a slice of the stack would be copied out
-    for the kernel; the stack is handed over whole, as ``L * E`` groups of
-    which only this layer's hold rows); ``valid`` [n] bool marks
-    the rows that count (None: all): a row that does not is routed nowhere,
-    touches no expert and gets zeros.  -> (out [n, d], `Load`).
+    for the kernel; the kernel indexes the stack where it lies); ``valid``
+    [n] bool marks the rows that count (None: all): a row that does not is
+    routed nowhere, touches no expert and gets zeros.  -> (out [n, d],
+    `Load`).
 
     The n*k pairs are sorted by expert, so expert e's rows are one block;
     rows past the last block (the invalid ones, sorted to the end under
     the sentinel expert E) belong to no group."""
-    layer, n_layers = None, 1
-    if isinstance(w_in, tuple):
-        layer, n_layers = w_in[1], w_in[0].shape[0]
-        w_in, w_out, w_gate = (
-            a if a is None else a[0].reshape((-1,) + a[0].shape[2:])
-            for a in (w_in, w_out, w_gate))
-    n_experts = w_in.shape[0] // n_layers
     dt = y.dtype
-    # the TPU's grouped matmul takes row blocks of 8; with fewer pairs (one
-    # token's) the compiler falls back to a dense product over EVERY
-    # expert.  Rows that count for nothing fill up: they belong to no group
-    fill = (-idx.shape[0]) % (8 // math.gcd(idx.shape[1], 8))
-    if fill:
-        if valid is None:
-            valid = jnp.ones((idx.shape[0],), bool)
-        y, idx, w, valid = (jnp.pad(a, [(0, fill)] + [(0, 0)] * (a.ndim - 1))
-                            for a in (y, idx, w, valid))
+
+    def cast(a):        # [E, .., ..] or (stack, layer), in y's dtype
+        return (a[0].astype(dt), a[1]) if isinstance(a, tuple) else \
+            a.astype(dt)
+    n_experts = (w_in[0] if isinstance(w_in, tuple) else w_in).shape[-3]
     n, k = idx.shape
     pair_expert = idx.reshape(-1) - expert_offset
     here = (pair_expert >= 0) & (pair_expert < n_experts)
@@ -121,25 +113,19 @@ def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     order = jnp.argsort(pair_expert, stable=True)                 # [n*k]
     sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[pair_expert].add(1)
     sizes = sizes[:n_experts]
-    groups = sizes
-    if layer is not None:     # this layer's groups among the stack's
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * n_experts,), jnp.int32), sizes,
-            (layer * n_experts,))
     xs = y[order // k]                                            # [n*k, d]
-    up = jax.lax.ragged_dot(xs, w_in.astype(dt), groups)
+    up = grouped_matmul(xs, cast(w_in), sizes)
     if w_gate is not None:
-        z = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate.astype(dt), groups)) * up
+        z = jax.nn.silu(grouped_matmul(xs, cast(w_gate), sizes)) * up
     else:
         z = jax.nn.gelu(up)
-    out = jax.lax.ragged_dot(z, w_out.astype(dt), groups)         # [n*k, d]
+    out = grouped_matmul(z, cast(w_out), sizes)                   # [n*k, d]
     # back to token order: pair j of token i sits at sorted row inv[i*k+j]
     inv = jnp.argsort(order)
     out = out[inv].reshape(n, k, -1)
     # rows of no group hold nothing defined
     out = jnp.where(here.reshape(n, k, 1), out, 0)
     out = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), w)
-    out = out[:n - fill]
     return out.astype(dt), Load((sizes > 0).sum().astype(jnp.int32),
                                 sizes.max(), sizes.sum())
 

@@ -195,6 +195,48 @@ def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
     assert not copies, copies
 
 
+def _grouped_calls(text):
+    """The compiled program's calls of this repo's grouped matmul kernel;
+    XLA's own grouped kernel must not be there beside them."""
+    import re
+    assert "ragged-dot" not in text
+    return [c for c in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+            if "grouped_matmul" in c]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shape", [
+    (512, 5, 64, 2048, 1536), (512, 5, 64, 1536, 2048),    # GLM's chunk
+    (64, 5, 64, 2048, 1536), (4, 5, 64, 1536, 2048),       # its step, a token
+    (512, 4, 32, 3072, 3072), (64, 4, 32, 3072, 3072),     # Trinity's
+    (1024, 4, 32, 3072, 3072),                             # a chunk of 256
+], ids=lambda s: "x".join(map(str, s)))
+def test_grouped_matmul_compiles_for_v5e(topo, shape, dtype):
+    """The grouped matmul kernel at the served cells' call shapes: lowered
+    for the described v5e it IS the kernel (the lowering platform chooses,
+    `jax.lax.platform_dependent`), the stack is indexed where it lies (no
+    temporary of a layer's experts), and its blocks fit the VMEM it asks
+    for."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    m, n_layers, n_groups, k, n = shape
+    dt = jnp.dtype(dtype)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(sh, d):
+        return jax.ShapeDtypeStruct(sh, d, sharding=one_chip)
+    compiled = jax.jit(
+        lambda a, st, l, g: grouped_matmul(a, (st, l), g)).lower(
+        arg((m, k), dt), arg((n_layers, n_groups, k, n), dt),
+        arg((), jnp.int32), arg((n_groups,), jnp.int32)).compile()
+    assert len(_grouped_calls(compiled.as_text())) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        4 << 20) + 2 * max(m, 128) * n * dt.itemsize
+
+
 @pytest.mark.parametrize("program", ["fused_step", "prefill_chunk_32",
                                      "prefill_chunk_1", "prefill_padded_32",
                                      "prefill_padded_128",
@@ -204,11 +246,10 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
     """Latent attention and routed experts at the published widths of the
     served cell's model (one dense and two expert layers): the donated
     program aliases its one cache array and copies none of its shape; the
-    expert matmuls are the chip's grouped kernel also for ONE token (4
-    pairs, filled up to a row block: fewer fall to a dense product over all
-    64 experts); and no layer's slice of the expert stack is copied out
-    for the kernel (it takes the stack whole), which at first cost 18 of a
-    chunk program's 25 ms (PERF.md, PR 28)."""
+    expert matmuls are this repo's grouped kernel (`ops/grouped_matmul.py`)
+    also for ONE token (4 pairs); and no layer's slice of the expert stack
+    is copied out for the kernel (it indexes the stack where it lies),
+    which at first cost 18 of a chunk program's 25 ms (PERF.md, PR 28)."""
     import re
 
     import jax
@@ -276,11 +317,14 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
     text = compiled.as_text()
     shape = ",".join(map(str, cache["kv"].shape))
     assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text)
-    # three grouped matmuls a layer, each given the whole stack of 2 x 64
-    calls = re.findall(r"ragged-dot-none[.\d]* = [^\n]*", text)
+    # three grouped matmuls a layer, this repo's kernel and not XLA's, each
+    # given the whole stack of 2 x 64 where it lies
+    calls = _grouped_calls(text)
     assert len(calls) == 3 and all(
-        "bf16[128,2048,1536]" in c or "bf16[128,1536,2048]" in c
+        "bf16[2,64,2048,1536]" in c or "bf16[2,64,1536,2048]" in c
         for c in calls), calls
+    assert not re.findall(r"= bf16\[(?:\d+,)?64,(?:2048,1536|1536,2048)\]\S*"
+                          r" copy\(", text)
 
 
 @pytest.mark.parametrize("program", ["fused_step", "prefill_padded_128",
@@ -367,7 +411,7 @@ def test_window_and_full_layers_copy_no_cache_no_ring_no_weights(topo,
         assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
     # three grouped matmuls a segment of expert layers, each given the whole
     # stack of 3 x 32 experts; no slice of it is copied out
-    calls = re.findall(r"ragged-dot-none[.\d]* = [^\n]*", text)
+    calls = _grouped_calls(text)
     assert len(calls) == 6 and all(
-        "bf16[96,3072,3072]" in c for c in calls), calls
+        "bf16[3,32,3072,3072]" in c for c in calls), calls
     assert not re.findall(r"= bf16\[(?:\d+,)?32,3072,3072\]\S* copy\(", text)
