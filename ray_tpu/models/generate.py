@@ -33,6 +33,19 @@ proposal) overwrites only positions that no later query's window reaches.
 Both kinds sit behind the same functions, and each kind has a layer
 counter of its own in the one layer loop.
 
+A THIRD KIND OF STATE IS NOT POSITIONS AT ALL: a ``"conv"`` layer
+(`ops/short_conv.py`) carries the last ``conv_kernel - 1`` inputs of its
+convolution, ``[L_conv, batch, 1, conv_kernel - 1, d_model]`` (``*_state``;
+channels last, where the device wants its lanes), whatever ``max_len``.
+It sits behind the same functions too (`cache_arrays`, `cache_bytes`, the
+slot insert and gather), but unlike a key or a value a state written AHEAD
+of a row's ``pos`` is not harmless: there is no later write that repairs
+it.  So every program advances a row's state by its VALID tokens only: a
+padded chunk takes the carry-out at its last real token, a slot that is
+not active keeps its state bit for bit, and what would need a state to be
+taken back is refused (`_check_state_rewind`): a speculative proposal
+that may be rejected, a chunk window set back at the cache's end.
+
 Each array is stored ``[layers, batch, heads, width, rows]`` —
 positions LAST — and every program that takes a cache extends it IN PLACE:
 the whole stacked cache is state of the one layer loop
@@ -69,6 +82,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops import latent_attention as mla
 from ..ops.rotary import apply_rotary, rotary_angles
+from ..ops.short_conv import conv_block, conv_inputs, short_conv
 from .transformer import (TransformerConfig, _attn_out, _ffn, _layer, _norm,
                           _post, _qkv, _scale_embedding, _unembed, norm_eps,
                           scan_layer_runs)
@@ -80,17 +94,23 @@ Arrays = Dict[str, jnp.ndarray]     # a cache without its "pos"
 
 
 _RING = "_win"      # suffix of a window layer's arrays: rings
+_STATE = "_state"   # suffix of a conv layer's array: no positions at all
+_CONV_STATE = "conv" + _STATE
 
 
 def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     """What a cache of this model holds a position a layer: (heads,
     width) of each of its arrays.  Window layers have arrays of their own
-    (rings, named ``*_win``) beside the full layers'."""
+    (rings, named ``*_win``) beside the full layers'; conv layers one
+    (``*_state``) whose "positions" are the model's channels and whose
+    width is the ``conv_kernel - 1`` inputs a sequence carries."""
+    state = {_CONV_STATE: (1, cfg.conv_kernel - 1)} \
+        if "conv" in cfg.kinds else {}
     if cfg.attention == "mla":
-        return {"kv": (1, cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
+        return dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
     row = (cfg.kv_heads, cfg.head_dim)
-    return {name: row for kind in ("full", "window") if kind in cfg.kinds
-            for name in _kv_names(kind)}
+    return dict({name: row for kind in ("full", "window")
+                 if kind in cfg.kinds for name in _kv_names(kind)}, **state)
 
 
 def _kv_names(kind: str) -> Tuple[str, str]:
@@ -111,28 +131,37 @@ def cache_arrays(cache: KVCache) -> Arrays:
 
 def cache_capacity(cache: KVCache) -> int:
     """``max_len``: the positions a cache holds per row (what its full
-    layers' arrays hold; a ring is shorter)."""
-    return max(a.shape[-1] for a in cache_arrays(cache).values())
+    layers' arrays hold; a ring is shorter, a state holds none)."""
+    return max(a.shape[-1] for name, a in cache_arrays(cache).items()
+               if not name.endswith(_STATE))
 
 
 def cache_bytes(cache: KVCache) -> Dict[str, int]:
     """Bytes of a cache's arrays by state kind: ``full`` (rows for the
-    whole context) and ``ring`` (window layers)."""
-    out = {"full": 0, "ring": 0}
+    whole context), ``ring`` (window layers) and ``state`` (conv layers)."""
+    out = {"full": 0, "ring": 0, "state": 0}
     for name, a in cache_arrays(cache).items():
-        out["ring" if name.endswith(_RING) else "full"] += int(a.nbytes)
+        out[_state_kind(name)] += int(a.nbytes)
     return out
+
+
+def _state_kind(name: str) -> str:
+    """``full`` | ``ring`` | ``state``: what kind of state an array is."""
+    return "ring" if name.endswith(_RING) else \
+        "state" if name.endswith(_STATE) else "full"
 
 
 def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                 pos: jnp.ndarray) -> KVCache:
     cache = {}
+    # (layers that hold the kind, what its arrays have for positions)
+    stacks = {"full": ("full", max_len),
+              "ring": ("window", window_ring(cfg, max_len)),
+              "state": ("conv", cfg.d_model)}
     for name, (heads, width) in cache_rows(cfg).items():
-        ring = name.endswith(_RING)
+        kind, rows = stacks[_state_kind(name)]
         cache[name] = jnp.zeros(
-            (cfg.kinds.count("window" if ring else "full"), batch, heads,
-             width, window_ring(cfg, max_len) if ring else max_len),
-            cfg.dtype)
+            (cfg.kinds.count(kind), batch, heads, width, rows), cfg.dtype)
     cache["pos"] = pos
     return cache
 
@@ -156,9 +185,18 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "latent attention keeps a rotary key beside its latent: "
             "pos_emb must be 'rope'")
     kinds = set(cfg.kinds)
-    if len(cfg.kinds) != cfg.n_layers or kinds - {"full", "window"}:
+    if len(cfg.kinds) != cfg.n_layers or \
+            kinds - {"full", "window", "conv"}:
         raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
-                         f"{cfg.n_layers} of 'full' | 'window'")
+                         f"{cfg.n_layers} of 'full' | 'window' | 'conv'")
+    if "conv" in kinds and cfg.conv_kernel < 2:
+        raise ValueError("conv layers need conv_kernel of at least 2")
+    if "full" not in kinds:
+        raise NotImplementedError(
+            "a model without a full-attention layer (of window layers only, "
+            "of conv layers, of both) is not served: the rows a session may "
+            "reach (max_len) are read off a full layer's array, and neither "
+            "a ring nor a state has them")
     if "window" in kinds:
         if cfg.attention == "mla":
             raise NotImplementedError(
@@ -166,11 +204,6 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         if cfg.sliding_window < 1 or cfg.window_chunk < 1:
             raise ValueError("window layers need sliding_window and "
                              "window_chunk of at least 1")
-        if "full" not in kinds:
-            raise NotImplementedError(
-                "a model of window layers only is not served: the rows a "
-                "session may reach (max_len) are read off a full layer's "
-                "array, and a ring does not state them")
 
 
 def _check_chunk(cfg: TransformerConfig, c: int) -> None:
@@ -183,6 +216,18 @@ def _check_chunk(cfg: TransformerConfig, c: int) -> None:
             f"a cached program of {c} new tokens a row over window layers "
             f"whose ring leaves room for window_chunk={cfg.window_chunk}: "
             f"the ring would lose positions the chunk still attends")
+
+
+def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
+    """What would need a conv layer's state taken BACK is refused, not
+    answered wrongly: a state has no position to mask and no later write
+    that repairs it, so tokens fed and then disowned (a rejected
+    proposal) or fed twice (a chunk window set back at the cache's end)
+    have already shifted it."""
+    if "conv" in cfg.kinds:
+        raise ValueError(
+            f"{what} over conv layers: their state cannot be taken back "
+            f"to an earlier token (models/generate.py)")
 
 
 def _ring_mask(pos, c: int, ring: int, window: int) -> jnp.ndarray:
@@ -280,14 +325,23 @@ def _layer_of(c_all: jnp.ndarray, l) -> jnp.ndarray:
     return jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
 
 
+def _place_state(s_all: jnp.ndarray, l, state: jnp.ndarray) -> jnp.ndarray:
+    """Every row's state [B, taps - 1, D] into conv layer ``l``."""
+    return jax.lax.dynamic_update_slice(
+        s_all, state[None, :, None].astype(s_all.dtype), (l, 0, 0, 0, 0))
+
+
 def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
-                   cache: KVCache, *, rotate, write, mask, valid=None):
+                   cache: KVCache, *, rotate, write, mask, valid=None,
+                   n_new=None):
     """Run ``x`` [B, C, D] (C new tokens per row) through every layer
-    against the cache: each layer writes the new tokens' columns
+    against the cache: each attention layer writes the new tokens' columns
     (``write[kind](c_all, l, cols [B, heads, width, C]) -> c_all``) into
     each of its state kind's arrays, then attends dense over layer ``l``
     of them under ``mask[kind]`` [B|1, C, rows]; ``write`` and ``mask``
     are keyed by the layer's attention kind (``"full"``, ``"window"``).
+    A conv layer reads its state, and advances it by ``n_new`` [B] tokens
+    (None: all C): the row's real tokens, none for a row that stands.
     ``rotate`` applies the caller's rotary angles (on the layers the model
     rotates); ``valid`` [B, C] marks the rows a no-drop expert layer
     routes (None: all).
@@ -329,11 +383,20 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                                   lp["wkv_b"], lp["wo"], mask[kind])
         return out, {"kv": kv_all}
 
+    def conv(y, lp, arrs, l, kind):
+        s_all = arrs[_CONV_STATE]              # [L_conv, B, 1, taps - 1, D]
+        delta, state = conv_block(
+            y, lp["conv_in"], lp["conv_w"], lp["conv_out"],
+            _layer_of(s_all, l)[:, 0], n_new)
+        return delta, dict(arrs, **{_CONV_STATE: _place_state(
+            s_all, l, state)})
+
     attend = attend_mla if cfg.attention == "mla" else attend_mha
 
     def layer(xc, lp, arrs, l, kind):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        delta, arrs = attend(y, lp, arrs, l, kind)
+        delta, arrs = (conv if kind == "conv" else attend)(
+            y, lp, arrs, l, kind)
         xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
@@ -385,9 +448,15 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     def layer(h, lp, arrs, l, kind):
         # run the layer for h, re-project for the cache
         y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
-        arrs = dict(arrs, **{
-            n: place(arrs[n], l, _as_columns(rows, arrs[n].dtype))
-            for n, rows in columns(y, lp, kind).items()})
+        if kind == "conv":     # the state the prompt's last token leaves
+            _, state = short_conv(conv_inputs(y, lp["conv_in"])[0],
+                                  lp["conv_w"])
+            arrs = dict(arrs, **{_CONV_STATE: _place_state(
+                arrs[_CONV_STATE], l, state)})
+        else:
+            arrs = dict(arrs, **{
+                n: place(arrs[n], l, _as_columns(rows, arrs[n].dtype))
+                for n, rows in columns(y, lp, kind).items()})
         h, _ = _layer(cfg, h, lp, cos, sin, kind)
         return h, arrs, (0, 0, 0)
 
@@ -435,9 +504,11 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     sees a padded column, and the padded columns written at ``[pos +
     n_valid, pos + C)`` lie above the returned ``pos``, where every
     program masks its reads and the next chunk or decode step writes
-    first.  The WINDOW ``[pos, pos + C)`` has to lie inside the cache
-    (and a learned position table): a slice that starts too late is
-    clamped, silently, onto earlier positions (:func:`chunk_window`)."""
+    first.  A conv layer's state has no such cover: its carry-out is taken
+    at row ``n_valid - 1``, not at the chunk's last row.  The WINDOW
+    ``[pos, pos + C)`` has to lie inside the cache (and a learned position
+    table): a slice that starts too late is clamped, silently, onto
+    earlier positions (:func:`chunk_window`)."""
     _check_decodable(cfg)
     b, c = tokens.shape
     _check_chunk(cfg, c)
@@ -470,7 +541,9 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     x, arrays, load = _attend_cached(
         cfg, params, x, cache,
         rotate=lambda t: apply_rotary(t, cos, sin),
-        write=write, mask=mask, valid=valid)
+        write=write, mask=mask, valid=valid,
+        n_new=None if n_valid is None else
+        jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), (b,)))
     if n_valid is None:
         last, step = x[:, -1], c
     else:
@@ -540,6 +613,9 @@ def prefill_chunk_step(fn, params: Params, tokens: np.ndarray, off: int,
     rewrites what their columns hold."""
     start, n_valid = chunk_window(off, tokens.shape[1], chunk, capacity)
     if start != off:
+        _check_state_rewind(cfg, "a chunk window set back at the cache's "
+                                 "end (the prompt ends within one chunk "
+                                 "of max_len)")
         cache = dict(cache, pos=np.int32(start))
     logits, cache = fn(params, padded_chunk(tokens, start, n_valid, chunk),
                        cache, cfg=cfg, n_valid=np.int32(n_valid))
@@ -626,8 +702,10 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
     A window layer's RING is copied as it stands: it holds the donor's
     LAST positions, not its first ``upto``.  The copy is exact only while
     the donor stands at ``upto`` or its whole context still fits its
-    window; the caller checks that (`serve/decode_session.py`
-    ``_prefix_exact``) and refuses the reuse where it does not hold."""
+    window; a conv layer's STATE is the donor's at its LAST token, exact
+    only while the donor stands at ``upto``.  The caller checks both
+    (`serve/decode_session.py` ``_prefix_exact``) and refuses the reuse
+    where they do not hold."""
     out = {name: jax.lax.dynamic_slice(
         a, (0, slot, 0, 0, 0), (a.shape[0], 1) + a.shape[2:])
         for name, a in cache_arrays(slot_cache).items()}
@@ -655,8 +733,10 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     """``tokens`` [S, C]: C tokens per slot, fed at each slot's OWN
     ``pos`` .. ``pos + C - 1`` → (final-norm activations [S, C, D],
     arrays, load); ``active`` [S] marks the slots whose tokens a no-drop
-    expert layer routes (the others still compute: the batch shape is
-    fixed).  Slots sit at DIFFERENT positions, so each fed
+    expert layer routes and whose conv states advance (the others still
+    compute: the batch shape is fixed, and their key and value columns
+    land ahead of their ``pos``, but their states stay as they are).
+    Slots sit at DIFFERENT positions, so each fed
     token's column is written by its own ``dynamic_update_slice``
     (a scatter is not updated in place under the cache's layout).  A
     slice whose start lies past the end is clamped onto the last
@@ -669,6 +749,9 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     _check_decodable(cfg)
     s, c = tokens.shape
     _check_chunk(cfg, c)
+    if c > 1:     # only a verify feeds a slot more than its one token
+        _check_state_rewind(cfg, "a speculative verify (its rejected "
+                                 "tokens are fed all the same)")
     dt = cfg.dtype
     pos = cache["pos"]                                         # [S]
     max_len = cache_capacity(cache)
@@ -708,7 +791,8 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     return _attend_cached(
         cfg, params, x, cache,
         rotate=lambda t: _rotate_slots(t, cos, sin),
-        write=write, mask=mask, valid=valid)
+        write=write, mask=mask, valid=valid,
+        n_new=None if active is None else active.astype(jnp.int32) * c)
 
 
 def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
@@ -722,8 +806,8 @@ def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
     advances only on active slots.  Inactive slots still compute (the
     batch shape is FIXED — that is what keeps this a single compiled
     program) but their K/V write lands at their un-advanced ``pos`` and
-    is overwritten by the next active step before any read, and their
-    logits are discarded by the engine.
+    is overwritten by the next active step before any read, their conv
+    states are not advanced, and their logits are discarded by the engine.
     """
     logits, cache, _ = _decode_step_slots(params, token, cache, active, cfg)
     return logits, cache
@@ -759,6 +843,8 @@ def draft_propose_slots(params: Params, token: jnp.ndarray,
     overwritten before any masked read — the same invariant paused
     slots rely on).  → (proposals [S, k], cache') with ``pos`` advanced
     by ``k`` on active slots."""
+    _check_state_rewind(cfg, "a draft's proposals (the draft's state "
+                             "would keep the rejected ones)")
 
     def step(carry, _):
         tok, c = carry

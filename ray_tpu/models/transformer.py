@@ -6,20 +6,17 @@ Design (idiomatic JAX, not a torch translation):
   * parameters are a plain pytree of jnp arrays; alongside it a matching
     ``params_axes`` tree of *logical axis* tuples feeds the sharding engine
     (`ray_tpu.parallel.sharding`) — TP/FSDP/PP are rules-table changes.
-  * the layer stack is ONE set of stacked weights scanned with ``lax.scan``
-    (fast compile, natural pipeline-parallel partitioning over the leading
-    "layers" axis), with optional per-layer ``jax.checkpoint`` remat.
+  * the layer stack is stacked weights scanned with ``lax.scan`` (fast
+    compile, pipeline-parallel partitioning over the leading "layers"
+    axis), with optional per-layer ``jax.checkpoint`` remat.
   * attention dispatches to the Pallas flash kernel on TPU
-    (`ray_tpu.ops.attention`), with sequence-parallel ring attention as a
-    config switch.
-  * compute dtype bf16, params and softmax/norm statistics fp32 — the MXU
-    recipe.
+    (`ray_tpu.ops.attention`), ring attention a config switch; compute
+    dtype bf16, params and softmax/norm statistics fp32.
   * a model DECLARES its layer pattern (`layer_runs`): consecutive runs of
-    identical layers, each run one stacked tree and one scan.  Every layer
-    loop (`_trunk` here, `generate._scan_cached`) goes through
-    `scan_layer_runs`.  One run is ``params["layers"]``; leading dense
-    layers before expert layers are a run of their own,
-    ``params["dense_layers"]``.
+    layers, each run one stacked tree.  Every layer loop (`_trunk` here,
+    `generate._scan_cached`) goes through `scan_layer_runs`.  One run is
+    ``params["layers"]``; leading dense layers before expert layers are a
+    run of their own, ``params["dense_layers"]``.
   * the attention kind is a property of the configuration: ``"mha"``
     (MHA/GQA, keys and values of ``kv_heads x head_dim``) or ``"mla"``
     (latent attention: low-rank queries, ONE compressed key-value latent
@@ -32,19 +29,19 @@ Design (idiomatic JAX, not a torch translation):
     ``n_experts`` from ``expert_offset``: one chip's part of an expert-
     parallel layer): the router stays ``n_experts`` wide, the layer
     computes its own experts' part, and nothing stands in for the rest.
-  * a head's width is its own (``head_size``; ``d_model // n_heads``
-    where the model states none), and what an attention block adds to the
-    plain one is a property each: RMS norms over a head's queries and keys
-    (``qk_norm``), a sigmoid gate on the heads' output (``attn_gate``),
-    norms after attention and feed-forward as well as before
-    (``sandwich_norm``), an embedding multiplier (``embed_scale``).
-  * a layer's attention KIND (``layer_kinds``: ``"window"`` | ``"full"``)
+  * a head's width is its own (``head_size``), and what an attention
+    block adds to the plain one is a property each: ``qk_norm``,
+    ``attn_gate``, ``sandwich_norm``, ``embed_scale``.
+  * a layer's KIND (``layer_kinds``: ``"window"`` | ``"full"`` | ``"conv"``)
     may change from layer to layer and repeat inside a run: a window layer
-    sees the last ``sliding_window`` positions, and with ``rope_layers =
-    "window"`` only window layers are rotated.  `layer_segments` cuts the
-    runs where the kind changes; a segment that is part of a run loops
-    over indices INTO the run's stacked tree (a slice of it would be a
-    copy of the weights).
+    sees the last ``sliding_window`` positions (``rope_layers = "window"``:
+    only those are rotated); a conv layer's operator is no attention at
+    all but a gated short convolution (`ops/short_conv.py`).  A run that
+    mixes the two operators holds BOTH weight sets, each stacked over its
+    own layers only.  `layer_segments` cuts the runs where the kind
+    changes; a segment that is part of a run loops over indices INTO the
+    run's stacks, each operator's by its own counter (`_scan_part`; a
+    slice would be a copy of the weights).
 
 Configs: ``TransformerConfig.gpt2()`` (learned positions, GELU, LayerNorm)
 and ``TransformerConfig.llama()`` (RoPE, SwiGLU, RMSNorm, GQA).
@@ -87,20 +84,14 @@ class TransformerConfig:
     attention_impl: str = "auto"      # "auto"|"flash"|"reference"|"ring"
     causal: bool = True               # False → bidirectional (encoders)
     remat: Any = True                 # False | True (full) | "dots":
-    #   "dots" saves matmul outputs and recomputes only elementwise ops in
-    #   the backward pass — most of full remat's memory win at zero extra
-    #   MXU work (matmuls are never recomputed).  On one v5e chip this is
-    #   what lets gpt2-small train at batch 32 instead of 8.
+    #   saves matmul outputs and recomputes only elementwise ops in the
+    #   backward pass (most of full remat's memory win, no extra MXU work)
     embed_impl: str = "gather"        # "gather" | "one_hot" (MXU-matmul
     #   embedding: gather-bwd is a serialized scatter-add on TPU)
     norm_remat: bool = False          # recompute layernorm/rmsnorm in bwd
-    #   instead of saving their fp32 intermediates — on v5e those saves
-    #   ([b, s, d] fp32 x 2 per layer) are what keep gpt2-small from
-    #   fitting batch 16 without full remat
+    #   instead of saving their fp32 intermediates ([b, s, d] x 2 a layer)
     loss_chunk: int = 0               # >0 → chunked cross entropy: logits
     #   materialize [b, chunk, vocab] at a time (rematerialized in bwd)
-    #   instead of the full [b, s, vocab] fp32 tensor — the biggest HBM
-    #   spike of LM training at GPT-2 vocab sizes
     # -- pipeline parallelism (SURVEY §2.4 row 3; parallel/pipeline.py) -----
     pp_stages: int = 1                # >1 → GPipe schedule over mesh "pp"
     pp_microbatches: Optional[int] = None  # None → pp_stages
@@ -134,9 +125,12 @@ class TransformerConfig:
     attn_gate: bool = False           # heads' output * sigmoid(y W_g)
     sandwich_norm: bool = False       # norms after attention and FFN too
     embed_scale: float = 1.0          # multiplies the token embedding
-    # -- window and full attention mixed ------------------------------------
+    # -- kinds of layer mixed -----------------------------------------------
     layer_kinds: Optional[Tuple[str, ...]] = None  # a layer "window" |
-    #   "full", in model order (None → all full); may repeat inside a run
+    #   "full" | "conv", in model order (None → all full); may repeat
+    #   inside a run
+    conv_kernel: int = 3              # a conv layer's taps; its state is
+    #   the last conv_kernel - 1 inputs of the convolution a sequence
     sliding_window: int = 0           # a window layer's position i sees
     #   j <= i with i - j < sliding_window
     rope_layers: str = "all"          # "all" | "window": which kinds of
@@ -158,7 +152,7 @@ class TransformerConfig:
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        """Each layer's attention kind, in model order."""
+        """Each layer's kind, in model order."""
         return self.layer_kinds or ("full",) * self.n_layers
 
     def rotates(self, kind: str) -> bool:
@@ -266,10 +260,10 @@ def _attn_matmul_params(cfg: TransformerConfig) -> int:
 
 
 def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
-    """Matmul parameters of ONE layer of the run ``run`` of `layer_runs`;
-    for expert layers ``active`` counts only the top-k routed experts a
-    token visits (the FLOP count) beside the shared ones, ``active=False``
-    every expert held (the memory count)."""
+    """Matmul parameters of the FEED-FORWARD of one layer of the run ``run``
+    of `layer_runs`; for expert layers ``active`` counts only the top-k
+    routed experts a token visits (the FLOP count) beside the shared ones,
+    ``active=False`` every expert held (the memory count)."""
     d = cfg.d_model
     per = 3 if cfg.activation == "swiglu" else 2
     if cfg.n_experts and run == "layers":
@@ -281,13 +275,16 @@ def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
             + d * cfg.n_experts                                  # + router
     else:
         mlp = d * cfg.ff_dim * per
-    return _attn_matmul_params(cfg) + mlp
+    return mlp
 
 
 def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
-    """Matmul parameters of all layers, over the declared pattern."""
+    """Matmul parameters of all layers, over the declared pattern: each
+    layer's feed-forward and its kind's operator."""
     return sum(n * _run_matmul_params(cfg, run, active)
-               for run, n in cfg.layer_runs)
+               for run, n in cfg.layer_runs) + sum(
+        4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
+        else _attn_matmul_params(cfg) for kind in cfg.kinds)
 
 
 def _attn_flops_dim(cfg: TransformerConfig) -> int:
@@ -306,7 +303,8 @@ def _attended(cfg: TransformerConfig, context_len: float,
     the layers: a window layer stops at ``windows`` x its window (2 where
     the caller halves the sum for a causal sequence's mean)."""
     return sum(min(context_len, windows * cfg.sliding_window)
-               if kind == "window" else context_len for kind in cfg.kinds)
+               if kind == "window" else context_len for kind in cfg.kinds
+               if kind != "conv")       # a conv layer attends nothing
 
 
 def count_params(cfg: TransformerConfig) -> int:
@@ -314,11 +312,11 @@ def count_params(cfg: TransformerConfig) -> int:
     norms = 2 * d * (2 if cfg.norm == "layernorm" else 1)
     if cfg.sandwich_norm:            # two more scales, no bias
         norms += 2 * d
-    if cfg.attention == "mla":
-        norms += cfg.q_lora_rank + cfg.kv_lora_rank
-    elif cfg.qk_norm:
-        norms += 2 * cfg.head_dim
-    layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms
+    n_conv = cfg.kinds.count("conv")
+    own = cfg.q_lora_rank + cfg.kv_lora_rank if cfg.attention == "mla" \
+        else 2 * cfg.head_dim if cfg.qk_norm else 0   # an attention layer's
+    layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
+        + (cfg.n_layers - n_conv) * own + n_conv * d * cfg.conv_kernel
     if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
         layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
     emb = cfg.vocab_size * d
@@ -344,10 +342,9 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 def decode_flops_per_token(cfg: TransformerConfig,
                            context_len: int) -> float:
     """Inference forward FLOPs for ONE token at cache position
-    ``context_len``: 2*N_active_matmul for the weight matmuls (forward
-    only — no backward factor) plus the attention reads against the KV
-    cache (qk^T and probs·v, 2 FLOPs per MAC each, over every cached
-    position)."""
+    ``context_len``: 2*N_active_matmul for the weight matmuls plus the
+    attention layers' reads against the KV cache (qk^T and probs·v, 2
+    FLOPs per MAC each, over every cached position)."""
     n_matmul = _matmul_params(cfg, active=True) \
         + cfg.vocab_size * cfg.d_model   # unembed logits matmul
     if cfg.attention == "mla":
@@ -364,10 +361,8 @@ def engine_flops_table(cfg: TransformerConfig, max_len: int,
                        draft_cfg: "TransformerConfig" = None) -> dict:
     """Analytic FLOPs-per-token for each of the serve engine's jitted
     programs (the dispatch profiler's MFU numerators), evaluated at the
-    mid-stream cache position ``max_len // 2`` — the average context a
-    token attends over a full stream.  Pure-copy programs (cache
-    insert/gather) are 0: they move bytes, not FLOPs, and the profiler
-    reports no MFU for them."""
+    mid-stream cache position ``max_len // 2``.  Pure-copy programs (cache
+    insert/gather) are 0: the profiler reports no MFU for them."""
     mid = max(1, max_len // 2)
     target = decode_flops_per_token(cfg, mid)
     table = {
@@ -390,9 +385,11 @@ def engine_flops_table(cfg: TransformerConfig, max_len: int,
 
 def _init_run(keys, cfg: TransformerConfig, run: str, L: int
               ) -> Tuple[Params, Params]:
-    """One run of `layer_runs`: ``L`` identical layers as one stacked tree
-    (leading "layers" axis) and its logical axes; ``keys`` an iterator of
-    keys, one drawn a weight."""
+    """One run of `layer_runs`: ``L`` layers as one stacked tree (leading
+    "layers" axis) and its logical axes; ``keys`` an iterator of keys, one
+    drawn a weight.  An operator's weights are stacked over ITS layers of
+    the run only (`operator_layers`): attention's ``La``, a conv's ``Lc``."""
+    La, Lc = operator_layers(cfg, run)
     d, hd, h, hk, ff = (cfg.d_model, cfg.head_dim, cfg.n_heads,
                         cfg.kv_heads, cfg.ff_dim)
     pt = cfg.param_dtype
@@ -401,34 +398,39 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     ax: Params = {"attn_norm": ("layers", "embed"),
                   "mlp_norm": ("layers", "embed")}
 
-    def add(name, shape, fan_in, axes):
-        p[name] = jax.random.normal(next(keys), (L,) + shape, pt) \
+    def add(name, shape, fan_in, axes, n=L):
+        p[name] = jax.random.normal(next(keys), (n,) + shape, pt) \
             / math.sqrt(fan_in)
         ax[name] = ("layers",) + axes
 
-    if cfg.attention == "mla":
+    if Lc:      # the gated short convolution (ops/short_conv.py)
+        k = cfg.conv_kernel
+        add("conv_in", (d, 3 * d), d, ("embed", None), Lc)
+        add("conv_w", (d, k), k, (None, None), Lc)
+        add("conv_out", (d, d), d, (None, "embed"), Lc)
+    if La and cfg.attention == "mla":
         ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-        add("wq_a", (d, ql), d, ("embed", None))
-        add("wq_b", (ql, h, nope + rope), ql, (None, "heads", "kv"))
-        add("wkv_a", (d, kl + rope), d, ("embed", None))
-        add("wkv_b", (kl, h, nope + vd), kl, (None, "heads", "kv"))
-        add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"))
-        p["q_norm"], p["kv_norm"] = jnp.ones((L, ql), pt), jnp.ones((L, kl), pt)
+        add("wq_a", (d, ql), d, ("embed", None), La)
+        add("wq_b", (ql, h, nope + rope), ql, (None, "heads", "kv"), La)
+        add("wkv_a", (d, kl + rope), d, ("embed", None), La)
+        add("wkv_b", (kl, h, nope + vd), kl, (None, "heads", "kv"), La)
+        add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"), La)
+        p["q_norm"], p["kv_norm"] = jnp.ones((La, ql), pt), jnp.ones((La, kl), pt)
         ax["q_norm"] = ax["kv_norm"] = ("layers", None)
-    elif cfg.attention == "mha":
-        add("wq", (d, h, hd), d, ("embed", "heads", "kv"))
-        add("wk", (d, hk, hd), d, ("embed", "heads", "kv"))
-        add("wv", (d, hk, hd), d, ("embed", "heads", "kv"))
-        add("wo", (h, hd, d), h * hd, ("heads", "kv", "embed"))
+    elif La and cfg.attention == "mha":
+        add("wq", (d, h, hd), d, ("embed", "heads", "kv"), La)
+        add("wk", (d, hk, hd), d, ("embed", "heads", "kv"), La)
+        add("wv", (d, hk, hd), d, ("embed", "heads", "kv"), La)
+        add("wo", (h, hd, d), h * hd, ("heads", "kv", "embed"), La)
         if cfg.attn_gate:
-            add("wg", (d, h, hd), d, ("embed", "heads", "kv"))
+            add("wg", (d, h, hd), d, ("embed", "heads", "kv"), La)
         if cfg.qk_norm:
-            p["q_norm"], p["k_norm"] = jnp.ones((L, hd), pt), \
-                jnp.ones((L, hd), pt)
+            p["q_norm"], p["k_norm"] = jnp.ones((La, hd), pt), \
+                jnp.ones((La, hd), pt)
             ax["q_norm"] = ax["k_norm"] = ("layers", None)
-    else:
+    elif La:
         raise ValueError(f"attention={cfg.attention!r}: expected 'mha' or "
                          f"'mla'")
     if cfg.sandwich_norm:
@@ -539,21 +541,19 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
                     whole_expert_stacks: bool = False):
     """THE layer loop: ``body(carry, lp, kind) -> carry`` over every layer
     of the declared pattern, one `lax.scan` per segment of identical layers
-    (`TransformerConfig.layer_segments`: a run, cut where the attention
-    kind changes), the carry handed from segment to segment.  `_trunk` and
+    (`TransformerConfig.layer_segments`: a run, cut where the kind
+    changes), the carry handed from segment to segment.  `_trunk` and
     `generate._scan_cached` both loop through here.
 
     A segment that is a whole run scans over the run's stacked tree.  One
     that is PART of a run (the kind repeats inside the run) scans over
-    layer indices into the tree: a slice of the stack would be a copy of
-    those layers' weights on every call.
+    layer indices into its stacks (`_scan_part`).
 
     ``whole_expert_stacks`` (the cached programs): a run's routed-expert
     weights are not scanned over.  The grouped matmul is a kernel call, and
-    a layer's ``[E, d, f]`` slice of the stack would be COPIED out for it,
-    every weight of every expert for a few rows of work.  The body gets
-    ``(stack [L, E, d, f], layer)`` instead and `ops.moe.routed_ffn` hands
-    the kernel the whole stack with the groups of the other layers empty."""
+    a layer's ``[E, d, f]`` slice of the stack would be COPIED out for it.
+    The body gets ``(stack [L, E, d, f], layer)`` instead and
+    `ops.moe.routed_ffn` hands the kernel the whole stack and the layer."""
     run_len = dict(cfg.layer_runs)
     for run, first, n, kind in cfg.layer_segments:
         tree = params[run]
@@ -571,11 +571,11 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
                 lambda c, x: (layer(c, *x), None), carry,
                 (xs, jnp.arange(n)))
         else:
-            carry, _ = jax.lax.scan(
-                lambda c, i: (layer(c, {
-                    k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
-                    for k, v in xs.items()}, i), None),
-                carry, first + jnp.arange(n))
+            # (kept to the lines the loop above it stands on: a Pallas
+            # kernel's compile-cache key holds its call stack's line
+            # numbers, and the train step's runs through here; PERF.md,
+            # PR 30)
+            carry = _scan_part(cfg, run, first, n, kind, xs, layer, carry)
     return carry
 
 
@@ -616,9 +616,9 @@ def _post(cfg: TransformerConfig, delta: jnp.ndarray, lp: Params,
 
 def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
            cos, sin, kind: str = "full") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One transformer block of attention kind ``kind``; returns (x,
-    router_aux_loss)."""
-
+    """One block whose operator is ``kind``'s -> (x, router_aux_loss)."""
+    if kind == "conv":
+        return _conv_layer(cfg, x, lp)
     norm = functools.partial(_norm, cfg)
     if cfg.norm_remat:
         norm = jax.checkpoint(
@@ -760,11 +760,11 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         if cfg.n_layers % cfg.pp_stages:
             raise ValueError(f"{cfg.n_layers} layers not divisible by "
                              f"{cfg.pp_stages} pipeline stages")
-        if len(cfg.layer_runs) > 1:
+        if len(cfg.layer_segments) > 1:
             raise NotImplementedError(
                 "a pipeline over a layer pattern of more than one run "
-                "(leading dense layers) is not supported: stages are "
-                "equal slabs of ONE stacked tree")
+                "or kind (leading dense layers, mixed layer_kinds) is not "
+                "supported: stages are equal slabs of ONE stacked tree")
         n_micro = cfg.pp_microbatches or cfg.pp_stages
 
         def stage_fn(slab, state):
@@ -966,3 +966,74 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     return step
+
+
+# ---------------------------------------------------------------------------
+# two operators in one run (appended here: see `scan_layer_runs`' note on
+# the line numbers above)
+# ---------------------------------------------------------------------------
+
+#: a conv layer's weights in a run's tree
+_CONV_KEYS = ("conv_in", "conv_w", "conv_out")
+
+
+def operator_layers(cfg: TransformerConfig, run: str,
+                    upto: Optional[int] = None) -> Tuple[int, int]:
+    """(attention layers, conv layers) among the first ``upto`` layers
+    (None: all) of the run ``run`` of `layer_runs`: how long each
+    operator's stacks in that run's tree are, and where a segment's first
+    layer stands in them."""
+    first = 0
+    for name, n in cfg.layer_runs:
+        if name == run:
+            kinds = cfg.kinds[first:first + (n if upto is None else upto)]
+            conv = kinds.count("conv")
+            return len(kinds) - conv, conv
+        first += n
+    raise KeyError(run)
+
+
+def _scan_part(cfg: TransformerConfig, run: str, first: int, n: int,
+               kind: str, xs: Params, layer, carry):
+    """`scan_layer_runs` over the ``n`` layers from ``first`` of a run
+    whose layers are not all of one kind: a scan over layer INDICES into
+    the run's stacks (a slice of a stack would be a copy of those layers'
+    weights on every call).  What every layer has (a stack as long as the
+    run) is indexed by the layer, this kind's operator by the count of ITS
+    layers before (an operator's stacks hold no other's layers: the conv
+    weights by name, any other shorter stack is attention's), and the
+    other operator's weights are left out."""
+    conv = kind == "conv"
+    run_len = dict(cfg.layer_runs)[run]
+    at = operator_layers(cfg, run, first)[conv]
+    starts = {}         # key -> this segment's first layer in its stack
+    for k, v in xs.items():
+        whose = "conv" if k in _CONV_KEYS else \
+            "all" if v.shape[0] == run_len else "attention"
+        if whose == "all":
+            starts[k] = first
+        elif (whose == "conv") == conv:
+            starts[k] = at
+
+    def step(c, j):
+        lp = {k: jax.lax.dynamic_index_in_dim(xs[k], i + j, 0,
+                                              keepdims=False)
+              for k, i in starts.items()}
+        return layer(c, lp, first + j), None
+
+    carry, _ = jax.lax.scan(step, carry, jnp.arange(n))
+    return carry
+
+
+def _conv_layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`_layer` for a conv layer over a whole sequence (no state carried
+    in): the gated short convolution in attention's place, then the
+    layer's feed-forward as every layer has it."""
+    from ..ops.short_conv import conv_block
+    y = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
+    delta, _ = conv_block(y, lp["conv_in"], lp["conv_w"], lp["conv_out"])
+    x = x + _post(cfg, delta, lp, "post_attn_norm")
+    y = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
+    z, aux, _ = _ffn(cfg, y, lp)
+    return x + _post(cfg, z, lp, "post_mlp_norm"), aux

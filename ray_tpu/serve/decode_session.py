@@ -290,6 +290,15 @@ class ContinuousBatchingEngine:
                     f"draft vocab {self._draft_cfg.vocab_size} != target "
                     f"vocab {cfg.vocab_size}: proposals must be target "
                     f"token ids")
+            for c in (cfg, self._draft_cfg):
+                if "conv" in c.kinds:
+                    # refused here, not at the first request: a rejected
+                    # proposal has already shifted a conv layer's state
+                    raise ValueError(
+                        "speculative decoding over a model with conv "
+                        "layers is not supported: their state cannot be "
+                        "taken back to the last accepted token "
+                        "(models/generate.py `_check_state_rewind`)")
             self._spec = True
             self._draft = self._prof.wrap(
                 "draft_propose", jax.jit(draft_propose_slots,
@@ -313,6 +322,9 @@ class ContinuousBatchingEngine:
                 engine_cfg.prefill_chunk_tokens, params, room))
         self._window = cfg.sliding_window if "window" in cfg.kinds else 0
         self._window_layers = cfg.kinds.count("window")
+        # layers whose state is no positions: the last conv_kernel - 1
+        # inputs of a convolution, whatever the context
+        self._conv_layers = cfg.kinds.count("conv")
         self._spec_k = max(2, int(engine_cfg.spec_k))
         self._spec_disabled = False
         self._spec_fail_streak = 0
@@ -604,13 +616,14 @@ class ContinuousBatchingEngine:
     def _cache_stats(self) -> Dict[str, int]:
         """Bytes of the slot cache by state kind (``bytes_full``: the
         arrays that hold ``max_len`` rows a slot; ``bytes_ring``: the
-        window layers' rings), what ONE further position of a slot costs
-        (the full arrays' bytes a row: a ring grows with nothing), and the
-        rows the decode steps read; zeros until the first session
-        allocates the cache."""
+        window layers' rings; ``bytes_state``: the conv layers' states),
+        what ONE further position of a slot costs (the full arrays' bytes
+        a row: a ring and a state grow with nothing), and the rows the
+        decode steps read; zeros until the first session allocates the
+        cache."""
         kinds = self._cache_bytes(self._cache or {})
-        return {"bytes": kinds["full"] + kinds["ring"],
-                "bytes_full": kinds["full"], "bytes_ring": kinds["ring"],
+        return {"bytes": sum(kinds.values()),
+                **{"bytes_" + kind: n for kind, n in kinds.items()},
                 "bytes_per_position":
                     kinds["full"] // (self.ecfg.max_slots * self.max_len),
                 **self.rows}
@@ -817,10 +830,10 @@ class ContinuousBatchingEngine:
         surfaces in stats() so a per-path compile storm is visible."""
         self._shapes.add((kind,) + tuple(int(d) for d in dims))
 
-    def _prefix_exact(self, donor: int, depth: int) -> bool:
-        """Whether slot ``donor`` still holds what a session seeded with
-        its first ``depth`` positions attends.  A full layer's rows below
-        ``depth`` are never rewritten; a window layer's ring has moved on
+    def _prefix_exact(self, donor: int, depth: int, n: int) -> bool:
+        """Whether slot ``donor`` still holds what a session of ``n``
+        prompt tokens seeded with its first ``depth`` positions attends.
+        A full layer's rows below ``depth`` are never rewritten; a window layer's ring has moved on
         with the donor: whatever was written there (ahead of its ``pos``
         included) left the positions ``>= pos - sliding_window`` intact.
         So a donor whose whole context still fits its window serves any
@@ -829,16 +842,25 @@ class ContinuousBatchingEngine:
         start at ``depth`` or later: the seeded session's first query
         needs the positions from ``depth - sliding_window + 1`` on, and a
         window set back at the capacity edge (`chunk_window`) would need
-        earlier ones.  Any other donor is refused, and the prompt
-        prefills from its start."""
-        if not self._window:
+        earlier ones.  A conv layer's state is the donor's at its LAST
+        token and nothing of it can be masked: such a donor serves a
+        prefix only while it STANDS at it (``pos == depth``: it has
+        decoded nothing past it), and none of the seeded session's chunk
+        windows, which start at ``depth`` and not at a multiple of the
+        chunk, may be set back (a state cannot run tokens twice).  Any
+        other donor is refused, and the prompt prefills from its start."""
+        if not self._window and not self._conv_layers:
             return True
         sess = self._donors.get(donor)
         if sess is None:
             return False
-        return sess.pos <= self._window or (
-            sess.pos <= depth + 1 and
-            depth + self.ecfg.prefill_chunk_tokens <= self._capacity)
+        chunk = self.ecfg.prefill_chunk_tokens
+        if self._conv_layers and (
+                sess.pos != depth or
+                depth + -(-(n - depth) // chunk) * chunk > self._capacity):
+            return False
+        return not self._window or sess.pos <= self._window or (
+            sess.pos <= depth + 1 and depth + chunk <= self._capacity)
 
     def _prefill_advance(self, sess: _EngineSession) -> Optional[int]:
         """Run ONE fixed-shape chunk program of a joining session's
@@ -870,7 +892,8 @@ class ContinuousBatchingEngine:
                 # below the match depth are never written in between
                 if donor is not None and \
                         depth >= max(1, self.ecfg.prefix_cache_min_tokens) \
-                        and self._prefix_exact(donor, depth):
+                        and self._prefix_exact(donor, depth,
+                                               len(sess.ptoks)):
                     from ..core.runtime_metrics import (
                         SERVE_PREFIX_HITS, SERVE_PREFIX_TOKENS_REUSED)
                     sess.pcache = self._gather(self._cache,
@@ -1107,15 +1130,18 @@ class ContinuousBatchingEngine:
 
     def _count_rows(self, batch) -> None:
         """The cache rows one decode step's live slots attended (each at
-        its position before the step, its own new row included), and the
-        sums since the last `cache:rows` span into the next when due."""
-        full = self.cfg.n_layers - self._window_layers
+        its position before the step, its own new row included; a conv
+        layer reads the ``conv_kernel - 1`` rows of its state whatever the
+        position), and the sums since the last `cache:rows` span into the
+        next when due."""
+        full = self.cfg.n_layers - self._window_layers - self._conv_layers
         depth = sum(s.pos + 1 for s in batch)
         seen = sum(min(s.pos + 1, self._window) for s in batch)
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
             self.rows["rows_read"] += full * depth \
-                + self._window_layers * seen
+                + self._window_layers * seen + self._conv_layers \
+                * (self.cfg.conv_kernel - 1) * len(batch)
             self.rows["rows_if_full"] += self.cfg.n_layers * depth
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,

@@ -415,3 +415,97 @@ def test_window_and_full_layers_copy_no_cache_no_ring_no_weights(topo,
     assert len(calls) == 6 and all(
         "bf16[3,32,3072,3072]" in c for c in calls), calls
     assert not re.findall(r"= bf16\[(?:\d+,)?32,3072,3072\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["fused_step", "prefill_padded_128",
+                                     "prefill_chunk_1"])
+def test_conv_states_beside_rows_copy_no_cache_no_state_no_weights(topo,
+                                                                   program):
+    """Conv layers' states beside an attention layer's rows, at the
+    published widths of the reasoning cell's model (one dense conv layer,
+    then an attention layer and three conv layers in ONE run of expert
+    layers, all 32 experts held): the donated program aliases every array
+    of both state kinds and copies none of their shapes (a state is read,
+    advanced by the row's valid tokens and written back in place); the
+    layer loop over PART of a run indexes each operator's stack by that
+    operator's own counter and copies no stack of either, nor a layer's
+    slice of an expert stack."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params, init_slot_cache, prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots, cache_arrays
+    cfg = TransformerConfig(
+        vocab_size=65536, d_model=2048, n_layers=5, n_heads=32, n_kv_heads=8,
+        d_ff=7168, max_seq_len=128000, pos_emb="rope", rope_base=1e6,
+        activation="swiglu", norm="rmsnorm", norm_eps=1e-5,
+        tie_embeddings=True, qk_norm=True,
+        layer_kinds=("conv", "full", "conv", "conv", "conv"), conv_kernel=3,
+        n_experts=32, expert_top_k=4, router="sigmoid", moe_d_ff=1792,
+        first_dense_layers=1, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    assert [s[1:] for s in cfg.layer_segments] == [
+        (0, 1, "conv"), (0, 1, "full"), (1, 3, "conv")]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    assert params["layers"]["conv_in"].shape == (3, 2048, 6144)
+    assert params["layers"]["wq"].shape == (1, 2048, 32, 64)
+    slots, max_len = 32, 4096
+    if program == "fused_step":
+        cache = described(jax.eval_shape(
+            lambda: init_slot_cache(cfg, slots, max_len)))
+
+        def fused_step(params, tok, cache, active):
+            logits, cache, load = _decode_step_slots(params, tok[:slots],
+                                                     cache, active, cfg)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.concatenate([jnp.where(active, nxt, tok[:slots]),
+                                    jnp.stack(load)]), cache
+        lowered = jax.jit(fused_step, donate_argnums=(2,)).lower(
+            params, described(jax.ShapeDtypeStruct((slots + 3,), jnp.int32)),
+            cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    else:
+        width = int(program.rsplit("_", 1)[1])
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        padded = {"n_valid": described(jax.ShapeDtypeStruct((), jnp.int32))} \
+            if program.startswith("prefill_padded") else {}
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
+            cache, cfg=cfg, **padded)
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    arrays = cache_arrays(cache)
+    batch = slots if program == "fused_step" else 1
+    assert {n: a.shape for n, a in arrays.items()} == {
+        "k": (1, batch, 8, 64, 4096), "v": (1, batch, 8, 64, 4096),
+        "conv_state": (4, batch, 1, 2, 2048)}
+    want = sum(a.size * a.dtype.itemsize for a in arrays.values())
+    assert ma.alias_size_in_bytes >= want
+    assert ma.temp_size_in_bytes < 64 << 20, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    for a in arrays.values():
+        shape = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    # neither operator's stack, whole or a layer's slice, is copied
+    for shape in (r"(?:\d+,)?2048,6144", r"(?:\d+,)?2048,2048",
+                  r"(?:\d+,)?2048,32,64", r"(?:\d+,)?32,64,2048"):
+        assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    # three grouped matmuls a segment of expert layers, each given the whole
+    # stack of 4 x 32 experts; no slice of it is copied out
+    calls = _grouped_calls(text)
+    assert len(calls) == 6 and all(
+        re.search(r"bf16\[4,32,(2048,1792|1792,2048)\]", c)
+        for c in calls), calls
+    assert not re.findall(
+        r"= bf16\[(?:\d+,)?32,(?:2048,1792|1792,2048)\]\S* copy\(", text)

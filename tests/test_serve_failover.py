@@ -358,10 +358,13 @@ def test_engine_idle_reaper_evicts_abandoned_sessions():
     from ray_tpu.serve.config import DecodeEngineConfig
     from ray_tpu.serve.decode_session import DecodeSessionCore
     cfg = _tiny_cfg(max_seq_len=256)
+    # the ttl is many polls long: under six test workers one turn of the
+    # loop below (a 0.2 s wait, the stats, a 0.1 s sleep) has taken more
+    # than a second, and at a ttl of 1.0 the POLLED session was reaped too
     core = DecodeSessionCore(
         cfg, max_len=256, seed=1,
         engine=DecodeEngineConfig(max_slots=2, token_queue_depth=4,
-                                  session_idle_ttl_s=1.0))
+                                  session_idle_ttl_s=4.0))
     dead = core.handle({"op": "start", "prompt": [1, 2, 3]})
     live = core.handle({"op": "start", "prompt": [4, 5, 6]})
     deadline = time.monotonic() + 60

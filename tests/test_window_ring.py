@@ -132,7 +132,8 @@ def test_a_cache_has_two_kinds_of_state(model):
     assert window_ring(cfg, 10) == 10       # never more than the context
     assert cache_capacity(cache) == 64
     assert cache_bytes(cache) == {"full": 2 * 3 * 2 * 24 * 64 * 4,
-                                  "ring": 2 * 4 * 3 * 2 * 24 * 12 * 4}
+                                  "ring": 2 * 4 * 3 * 2 * 24 * 12 * 4,
+                                  "state": 0}      # no conv layer here
     assert init_kv_cache(cfg, 1, 64)["k_win"].shape == (4, 1, 2, 24, 12)
     one_kind = init_slot_cache(TransformerConfig.tiny(), 3, 64)
     assert cache_bytes(one_kind)["ring"] == 0
@@ -469,9 +470,13 @@ def test_prefix_reuse_only_where_the_donors_ring_is_still_exact(model):
     from ray_tpu.serve.config import DecodeEngineConfig
     from ray_tpu.serve.decode_session import DecodeSessionCore
     cfg, params = model[0], model[1]
+    # a short token queue: the engine decodes AHEAD of its caller until
+    # the queue is full, and under six test workers a donor of 5 tokens
+    # had run past the window of 8 before its caller's `end` arrived
     core = DecodeSessionCore(cfg, max_len=96, params=params,
                              engine=DecodeEngineConfig(
-                                 max_slots=2, prefix_cache_min_tokens=2))
+                                 max_slots=2, prefix_cache_min_tokens=2,
+                                 token_queue_depth=2))
     try:
         def hits():
             return core.engine.stats()["prefix"]["applied_hits"]
