@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .flash_attention import _default_blocks, fit_block, flash_attention
+from .flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, fit_block,
+                              flash_attention, tile_ok)
 
 
 def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -68,16 +69,15 @@ def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
     if jax.default_backend() != "tpu":
         return False
     s_q, s_kv, d = q.shape[1], k.shape[1], q.shape[-1]
+    # heads of 64 share the 128 lanes in pairs and a multiple of 128 fills
+    # them; the kernels lay out no other head size densely
     if d % 64:
         return False
-    dbq, dbk = _default_blocks()
-    bq, bk = fit_block(dbq, s_q), fit_block(dbk, s_kv)
-    # eligible when a block no smaller than the configured one (capped at
-    # the classic 128 floor) divides the seq, or the whole (short) seq is
-    # one block — so env-configured sub-128 sweeps still take the flash
-    # path instead of silently measuring unfused attention
-    return (bq >= min(128, dbq) or bq == s_q) and \
-        (bk >= min(128, dbk) or bk == s_kv)
+    # the kernels' own rule for the tiles `flash_attention` would pick: a
+    # multiple of 128 rows divides the sequence (the statistics travel with
+    # the sequence on the lanes), or the whole (short) sequence is one tile
+    return tile_ok(fit_block(DEFAULT_BLOCK_Q, s_q), s_q) and \
+        tile_ok(fit_block(DEFAULT_BLOCK_K, s_kv), s_kv)
 
 
 def _flash_per_shard(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,

@@ -1,25 +1,74 @@
-"""Blockwise fused attention (flash attention) as a Pallas TPU kernel.
+"""Blockwise fused attention (flash attention) as Pallas TPU kernels.
 
 The reference framework has no fused attention of its own — it delegates all
 model math to torch (SURVEY.md §2.3); in a TPU-native stack the attention
-inner loop is the single hottest op, so it gets a hand-written kernel:
+inner loop is the single hottest op, so it gets hand-written kernels: a
+softmax forward and a custom-VJP backward of two kernels (dq; dk, dv).
 
-  * online-softmax forward with fp32 accumulators in VMEM scratch,
-  * custom-VJP backward (separate dq and dk/dv kernels),
-  * grouped-query attention handled by index maps (no KV repetition),
-  * causal blocks above the diagonal skipped via ``pl.when``.
+**Layout.**  Inputs are ``[batch, seq, heads, head_dim]``, the framework's
+activation layout, and the kernels read them where they lie: that array IS
+``[batch, seq, heads x head_dim]``, and a block ``(1, rows, G x head_dim)``
+of it is ``G`` whole heads side by side on the lanes.  The wrapper transposes
+and copies nothing, no operand has a minor dimension narrower than the 128
+lanes (two heads of 64 share them; a head is a lane slice of the block), and
+the row statistics (``lse``, ``delta``) travel as ``[batch, head blocks, G,
+seq]``: the sequence on the lanes, never ``[.., seq, 1]``.
 
-Inputs are ``[batch, seq, heads, head_dim]`` (framework activation layout);
-the kernel operates in ``[batch, heads, seq, head_dim]``.  bf16 in/out, fp32
-softmax statistics.  Sequence length must be divisible by the block sizes —
+**Heads a grid step.**  A grid step works on ``G`` query heads in a static
+loop, so the scheduler has one head's matmuls to put beside another's
+exponentials (`make_plan`): with several kv heads a step takes each one's
+whole GQA group, with one kv head a divisor of the group, so kv heads are
+never repeated.  ``G`` is the largest such count up to `_MAX_HEADS` whose lane
+width is a multiple of 128 (or all heads) and whose blocks fit
+`_VMEM_BLOCK_BUDGET`.  A head count that no such block divides (25 heads of
+64) runs whole lane tiles of heads a step all the same (8: three blocks of
+eight and a block of one), and the last block, which lies partly outside
+the array, gets a kernel body of its own for the heads inside (`_inside`):
+nothing is computed from what lies outside, Pallas drops what would be
+written there, and no operand is padded or copied.
+
+**Blocks.**  The grid walks q blocks (forward, dq) or k blocks (dkv) of
+``block_q`` / ``block_k`` rows; the OTHER operand arrives as one major block,
+the whole sequence when `_VMEM_BLOCK_BUDGET` allows (then it is fetched once
+a head block, not once a q block), else the largest multiple of the tile
+that fits.  Inside, a loop walks tiles of ``block_q x block_k`` over the part
+of the major block that causality leaves live, each with one compare and
+select against a hoisted iota.  (A second, unmasked body for the tiles the
+diagonal does not cross was built and measured: it saves 0.1-0.4 % of the
+kernels' time, the vector units are not what binds, and costs a second copy
+of every unrolled body to trace and lower at each start of a program and to
+compile; PERF.md, PR 35.  Without ``causal`` nothing is masked.)  Major
+blocks that are wholly dead have their index clamped to the last live one,
+so they cost no transfer.  The softmax scale is folded into the resident operand where that
+is exact (a power of two: head sizes 64 and 256), and the dead-row select
+exists only where ``s_q > s_kv`` makes dead rows possible.
+
+**No reduction across lanes in a tile.**  The forward makes two passes over
+a grid step's live tiles (`_fwd_kernel`): row maxima from the scores computed
+transposed, then exponentials and sums against them; the dkv kernel computes
+its scores transposed too (``k q^T``), so its statistics are rows as stored
+and none of its four matmuls transposes a score tile; dq takes the
+statistics as columns, transposed once a q block.
+
+The default tile (`DEFAULT_BLOCK_Q` x `DEFAULT_BLOCK_K`), `_MAX_HEADS` and the
+two-pass forward were measured on one v5e chip on the three kernels alone at
+``[8, 1024, 16, 64]`` and ``[2, 1024, 25, 64]`` (device time from a profiler
+trace; PERF.md, PR 35); ``block_q`` / ``block_k`` stay as arguments for tests
+and sweeps.  bf16 in and out (operands enter the MXU in their storage
+dtype), float32 scores, statistics and accumulators.  A tile divides its
+sequence and is a multiple of 128 rows, or it is the whole sequence, of any
+length from 8 rows (ViT's 197 tokens: one tile, sliced statically, the row
+reductions taking their general forms): `flash_attention` clamps the block
+(halving) to a divisor, raises where that is neither (`tile_ok`), and
 callers (`ray_tpu.ops.attention.multi_head_attention`) fall back to the
-reference jnp implementation otherwise.
+reference jnp implementation there.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,32 +87,14 @@ def _interpret() -> bool:
 
 
 DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 512
+DEFAULT_BLOCK_K = 256
 _NEG_INF = -1e30  # avoids -inf - -inf = nan in the online softmax
-
-
-def _env_block(name: str, default: int) -> int:
-    raw = _os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        val = int(raw)
-    except ValueError:
-        raise ValueError(f"{name}={raw!r}: must be a positive integer")
-    if val < 8:
-        raise ValueError(f"{name}={val}: flash block sizes must be >= 8")
-    return val
-
-
-def _default_blocks() -> Tuple[int, int]:
-    """Block sizes resolve at trace time, overridable via env
-    (RAY_TPU_FLASH_BLOCK_Q/K) for on-chip tuning sweeps.  Defaults were
-    measured on v5e (gpt2-small train step): 128x128 made the grid so
-    fine (b*h*8*8 = 6k steps per layer call) that per-step fixed costs
-    beat the MXU work; 256x512 keeps VMEM modest (score block = 512 KiB
-    fp32) with 16x fewer grid steps."""
-    return (_env_block("RAY_TPU_FLASH_BLOCK_Q", DEFAULT_BLOCK_Q),
-            _env_block("RAY_TPU_FLASH_BLOCK_K", DEFAULT_BLOCK_K))
+_LANES = 128
+_MAX_HEADS = 8    # heads unrolled in one kernel body (code size, compile time)
+# what a kernel's pipelined blocks and scratch may take of the chip's VMEM
+# (128 MiB on a v5e, of which the compiler scopes a kernel `_VMEM_LIMIT`)
+_VMEM_BLOCK_BUDGET = 14 << 20
+_VMEM_LIMIT = 48 << 20
 
 
 def fit_block(block: int, s: int) -> int:
@@ -76,90 +107,423 @@ def fit_block(block: int, s: int) -> int:
     return b
 
 
-def _dims(q, k):
-    b, h, s_q, d = q.shape
-    h_kv, s_kv = k.shape[1], k.shape[2]
+def tile_ok(block: int, s: int) -> bool:
+    """Whether the kernels walk a sequence of ``s`` rows in tiles of
+    ``block``: whole lanes (the row statistics travel with the sequence on
+    the lanes, and the tile loop slices them there), or the whole sequence
+    as one tile of any length from 8 rows."""
+    return s % block == 0 and (block % _LANES == 0 or block == s) \
+        and block >= 8
+
+
+class Plan(NamedTuple):
+    """The static shape of one call's three kernels."""
+    h: int            # query heads
+    h_kv: int
+    d: int
+    hq: int           # query heads a grid step
+    hk: int           # kv heads a grid step
+    block_q: int      # tile rows
+    block_k: int      # tile columns
+    major_k: int      # k, v rows resident in the forward and dq kernels
+    major_q: int      # q, do rows resident in the dkv kernel
+
+    @property
+    def head_blocks(self) -> int:
+        return -(-self.h // self.hq)
+
+
+def _lane_ok(n: int, d: int, total: int) -> bool:
+    return n == total or (n * d) % _LANES == 0
+
+
+def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize) -> int:
+    """VMEM of the hungrier of the forward / dq and the dkv kernel: double
+    buffered blocks, scratch, and the float32 tiles of one head."""
+    dp = -(-d // _LANES) * _LANES
+    tiles = 6 * bq * bk * 4
+    walk_q = (2 * 3 * bq * hq * d * itemsize            # q, do / o, dq
+              + 2 * 2 * major_k * hk * d * itemsize     # k, v
+              + hq * bq * (dp * (4 + itemsize) + 4 * _LANES * 4))
+    walk_k = (2 * 2 * major_q * hq * d * itemsize       # q, do
+              + 2 * 4 * bk * hk * d * itemsize          # k, v, dk, dv
+              + 2 * 2 * hq * major_q * 4                # lse, delta
+              + hk * bk * dp * (8 + itemsize))
+    return max(walk_q, walk_k) + tiles
+
+
+def _major(block: int, s: int, fits) -> int:
+    """The largest multiple of ``block`` dividing ``s`` that ``fits``."""
+    n = s // block
+    for parts in range(1, n + 1):
+        if n % parts == 0 and fits(block * (n // parts)):
+            return block * (n // parts)
+    return block
+
+
+def make_plan(h: int, h_kv: int, d: int, s_q: int, s_kv: int, itemsize: int,
+              block_q: int, block_k: int) -> Plan:
+    """Heads a step and major blocks from the shape alone (module docstring).
+    Whole-sequence residency is preferred over more heads a step: it removes
+    the re-reads, more heads only amortise what is then a small fixed cost."""
     assert h % h_kv == 0, f"query heads {h} not a multiple of kv heads {h_kv}"
-    return b, h, h_kv, h // h_kv, s_q, s_kv, d
+    group = h // h_kv
+    cands = [(hq, 1) for hq in range(1, group + 1) if group % hq == 0]
+    cands += [(hk * group, hk) for hk in range(2, h_kv + 1) if h_kv % hk == 0]
+    cands = sorted((hq, hk) for hq, hk in cands
+                   if _lane_ok(hq, d, h) and _lane_ok(hk, d, h_kv))
+    capped = [c for c in cands if c[0] <= _MAX_HEADS]
+    if not capped and group == 1 and _LANES % d == 0:
+        # no divisor of the heads fills whole lanes (an odd count of narrow
+        # heads): whole lane tiles of heads a step, and the last step's
+        # block lies partly outside the array (`_inside`)
+        n = _LANES // d
+        capped = [(m, m) for m in range(n, _MAX_HEADS + 1, n)]
+    capped = capped or cands[:1]
+
+    def bytes_of(hq, hk, mk, mq):
+        return _block_bytes(hq, hk, d, block_q, block_k, mk, mq, itemsize)
+
+    for hq, hk in reversed(capped):
+        if bytes_of(hq, hk, s_kv, s_q) <= _VMEM_BLOCK_BUDGET:
+            return Plan(h, h_kv, d, hq, hk, block_q, block_k, s_kv, s_q)
+    hq, hk = capped[0]
+    major_k = _major(block_k, s_kv, lambda m: bytes_of(
+        hq, hk, m, block_q) <= _VMEM_BLOCK_BUDGET)
+    major_q = _major(block_q, s_q, lambda m: bytes_of(
+        hq, hk, block_k, m) <= _VMEM_BLOCK_BUDGET)
+    return Plan(h, h_kv, d, hq, hk, block_q, block_k, major_k, major_q)
+
+
+def _folds(sm_scale: float) -> bool:
+    """A power-of-two scale multiplies an operand exactly in any float
+    dtype, so it is applied to the resident operand once and not to every
+    score."""
+    return math.frexp(sm_scale)[0] == 0.5
+
+
+def _nt(a, b):
+    """``a @ b.T`` with float32 accumulation: operands enter the MXU in
+    their storage dtype (bf16); casting them to float32 first would force
+    the multi-pass float32 path (measured 0.9x of unfused attention on a
+    v5e)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _scores(a, b, sm_scale, seen):
+    """A tile of scores ``a @ b.T``: scaled here unless the scale is folded
+    into an operand (`_fill_scaled`), and `_NEG_INF` outside ``seen`` (None:
+    not causal)."""
+    s = _nt(a, b)
+    if not _folds(sm_scale):
+        s = s * sm_scale
+    return s if seen is None else jnp.where(seen, s, _NEG_INF)
+
+
+def _fill_scaled(dst_ref, src_ref, heads: int, d: int, sm_scale):
+    """Each head's lanes of a block into ``dst_ref[head]``, times the scale
+    where that is exact."""
+    for n in range(heads):
+        x = _heads(src_ref, slice(None), n, d)
+        dst_ref[n] = x * jnp.asarray(sm_scale, x.dtype) if _folds(sm_scale) \
+            else x
+
+
+def _query_minus_key(shape, query_dim: int):
+    """Query index less key index over a tile of scores, the queries along
+    ``query_dim``: a query sees the keys where this is at least the tile's
+    own offset (the caller's one compare a tile)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, query_dim)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - query_dim))
+
+
+def _row_to_col(row):
+    """``(1, n)`` -> ``(n, 128)``, the column on every lane, through a
+    whole-tile transpose."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
+
+
+def _wide(col, like):
+    """A lane-replicated column ``(n, 128)`` against tiles ``(n, w)``, any
+    ``w``."""
+    w = like.shape[1]
+    if w > _LANES:
+        col = jnp.concatenate([col] * -(-w // _LANES), axis=1)
+    return col[:, :w]
+
+
+def _div(a, b: int):
+    """``a // b`` of a traced int that is not negative: `lax.div`, which
+    truncates; ``//`` would add its fix-ups for signs, a sub-function each
+    to trace and lower."""
+    return a if b == 1 else jax.lax.div(a, jnp.int32(b))
+
+
+def _cdiv_pos(a, b: int):
+    """``ceil(max(a, 0) / b)`` of a traced int."""
+    return _div(jnp.maximum(a, 0) + (b - 1), b)
+
+
+def _k_tiles(qi, ki, p: Plan, causal: bool, q_offset: int):
+    """How many tiles of major k block ``ki`` q block ``qi`` attends (the
+    first ones; local tile indices)."""
+    n = p.major_k // p.block_k
+    if not causal:
+        return n
+    live = _cdiv_pos(qi * p.block_q + q_offset + p.block_q, p.block_k)
+    return jnp.clip(live - ki * n, 0, n)
+
+
+def _first_q_tile(ki, qm, p: Plan, causal: bool, q_offset: int):
+    """The first tile of major q block ``qm`` that attends k block ``ki``
+    (every later one does)."""
+    n = p.major_q // p.block_q
+    if not causal:
+        return 0
+    live = _div(jnp.maximum(ki * p.block_k - q_offset, 0), p.block_q)
+    return jnp.clip(live - qm * n, 0, n)
+
+
+def _last_live_k(qi, p: Plan, q_offset: int):
+    """Index of the last major k block q block ``qi`` attends."""
+    live = _cdiv_pos(qi * p.block_q + q_offset + p.block_q, p.block_k)
+    return _div(jnp.maximum(live - 1, 0), p.major_k // p.block_k)
+
+
+def _first_live_q(ki, p: Plan, q_offset: int):
+    """Index of the first major q block that attends k block ``ki``."""
+    return _div(jnp.maximum(ki * p.block_k - q_offset, 0), p.major_q)
+
+
+def _heads(ref, rows, n, d):
+    """Head ``n``'s lanes of a ``(1, rows, heads x d)`` block."""
+    return ref[0, rows, n * d:(n + 1) * d]
+
+
+def _inside(block, p: Plan, body):
+    """``body(query heads)`` for the heads of head block ``block`` that lie
+    inside the array.  Only a head count that no lane-dense block divides
+    (`make_plan`) has a last block with fewer: that block gets a body of its
+    own, so no head is computed from what lies outside."""
+    last = p.head_blocks - 1
+    rest = p.h - last * p.hq
+    if rest == p.hq:
+        body(p.hq)
+    else:
+        pl.when(block < last)(lambda: body(p.hq))
+        pl.when(block == last)(lambda: body(rest))
+
+
+def _tile_rows(t, size: int, total: int):
+    """Rows ``[t size, (t + 1) size)`` of a resident block of ``total``; a
+    block that is one tile (the only way a tile is no multiple of the
+    hardware's) is sliced statically."""
+    if size == total:
+        return slice(None)
+    return pl.ds(pl.multiple_of(t * size, size), size)
+
+
+def _for_tiles(lo, hi, body):
+    """``body(t)`` for tiles ``lo <= t < hi``; the state lives in refs."""
+    def step(t, carry):
+        body(t)
+        return carry
+    jax.lax.fori_loop(lo, hi, step, 0)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                sm_scale, causal, block_q, block_k, num_k, q_offset):
+def _fold_width(n: int) -> int:
+    """Columns `_fold_lanes` leaves of ``n``: the 128 lanes, or all of a
+    tile that is not whole lanes (a short sequence that is one tile)."""
+    return n if n % _LANES else _LANES
+
+
+def _fold_lanes(x, op):
+    """``[rows, n]`` -> ``[rows, _fold_width(n)]``: ``op`` over the columns
+    that share a lane.  Vector work only: a reduction ACROSS the lanes of
+    every row's vector is a chain of lane rotations, the slowest thing a
+    tile can ask for."""
+    w = _fold_width(x.shape[1])
+    return functools.reduce(
+        op, [x[:, c:c + w] for c in range(0, x.shape[1], w)])
+
+
+def _fold_rows(x, op, reduce_rows):
+    """``[n, cols]`` -> ``[8, cols]``: ``op`` over the rows that share a
+    sublane, vector against vector, by halves (a 256-row tile is 15
+    operations to trace and lower, not 63: the kernels are traced at every
+    start of a program, compile cache or not); a tile that is not whole
+    sublanes (a short sequence that is one tile) takes ``reduce_rows`` to
+    one row."""
+    if x.shape[0] % 8:
+        return reduce_rows(x, axis=0, keepdims=True)
+    while x.shape[0] % 16 == 0:
+        half = x.shape[0] // 2
+        x = op(x[:half], x[half:])
+    return functools.reduce(op, [x[r:r + 8] for r in range(0, x.shape[0], 8)])
+
+
+def _lane_sums(x):
+    """``[rows, w]`` float32 -> ``[rows, 128]``, each row's sum on every
+    lane: a product with ones on the MXU, which has the room (float32 at
+    `HIGHEST`: the summands are not rounded)."""
+    return jax.lax.dot_general(
+        x, jnp.ones((x.shape[1], _LANES), x.dtype), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, acc_ref, m_ref,
+                l_ref, mt_ref, lw_ref, *, p: Plan, sm_scale, causal,
+                q_offset, dead_rows):
+    """Two passes over the live tiles of the resident k, v rows, and no
+    reduction across lanes in either.  The first finds each query's
+    maximum from the scores TRANSPOSED (``k q^T``: the keys run down the
+    sublanes, so the maximum folds vector against vector into 8 rows);
+    the second exponentiates the scores against it and accumulates, the
+    row sums folded lane-wise and summed by the MXU (`_lane_sums`).  No
+    tile rescales the accumulator; across major blocks (a sequence that
+    is not resident) the usual online rescale happens once a grid step.
+    Measured on the v5e (PERF.md, PR 35): the one-pass online softmax, two
+    lane reductions a tile, held the forward at 1.05 ms where dq, with
+    more matmuls, takes 0.45; two passes with the reductions once a grid
+    step 0.73, of which 0.29 were those reductions.  The scores are
+    computed twice; the MXU has the room."""
     qi, ki = pl.program_id(2), pl.program_id(3)
+    last_k = pl.num_programs(3) - 1
+    bq, bk, d = p.block_q, p.block_k, p.d
+    per_kv = p.hq // p.hk
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def run(nq):
+        @pl.when(ki == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            _fill_scaled(qs_ref, q_ref, nq, d, sm_scale)
 
-    live = ((ki * block_k <= qi * block_q + block_q - 1 + q_offset)
-            if causal else (ki >= 0))
-
-    @pl.when(live)
-    def _compute():
-        # MXU-native precision: keep inputs in their storage dtype (bf16)
-        # and accumulate fp32 via preferred_element_type — casting inputs
-        # to fp32 first would force the multi-pass fp32 MXU path (~4-8x
-        # slower; measured 0.9x vs unfused attention on v5e before this).
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            rows = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # A row fully masked within a live block (causal with s_q > s_kv:
-        # rows above the diagonal of their first k-block) has m_new ==
-        # _NEG_INF, making exp(s - m_new) == 1 for every masked column —
-        # zero those rows instead of averaging V uniformly.
-        p = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+            rel = _query_minus_key((bq, bk), 0)
+            rel_t = _query_minus_key((bk, bq), 1)
 
-    @pl.when(ki == num_k - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-        # Dead rows (m still _NEG_INF) get lse = 0 so the backward kernels'
-        # exp(s - lse) = exp(_NEG_INF) underflows to zero gradient; the
-        # natural m + log(l) would be ~ -1e30 - 69, making s - lse positive.
-        m = m_ref[:, :1]
-        lse_ref[0, 0] = jnp.where(
-            m <= _NEG_INF * 0.5, 0.0,
-            m + jnp.log(jnp.maximum(l_ref[:, :1], 1e-30)))
+        def seen(t, rel):
+            # visible: q_offset + qi bq + r >= (ki n + t) bk + c
+            return rel >= (ki * p.major_k + t * bk) - qi * bq - q_offset
+
+        def row_max(t):
+            rows = _tile_rows(t, bk, p.major_k)
+            vis = seen(t, rel_t) if causal else None
+            for g in range(nq):
+                st = _scores(_heads(k_ref, rows, g // per_kv, d), qs_ref[g],
+                             sm_scale, vis)
+                mt_ref[g] = jnp.maximum(mt_ref[g],
+                                        _fold_rows(st, jnp.maximum, jnp.max))
+
+        def accumulate(t):
+            rows = _tile_rows(t, bk, p.major_k)
+            vis = seen(t, rel) if causal else None
+            for g in range(nq):
+                s = _scores(qs_ref[g], _heads(k_ref, rows, g // per_kv, d),
+                            sm_scale, vis)
+                m = _wide(m_ref[g], s)
+                e = jnp.exp(s - m)
+                if dead_rows:
+                    # A row that sees no key so far (causal with s_q > s_kv:
+                    # rows above the diagonal of their first k tile) has m ==
+                    # _NEG_INF, making exp(s - m) == 1 for every masked column
+                    # — zero those rows instead of averaging V uniformly.
+                    e = jnp.where(m <= _NEG_INF * 0.5, 0.0, e)
+                lw_ref[g] += _fold_lanes(e, jnp.add)
+                acc_ref[g] += _nn(e.astype(v_ref.dtype),
+                                  _heads(v_ref, rows, g // per_kv, d))
+
+        live = _k_tiles(qi, ki, p, causal, q_offset)
+        mt_ref[...] = jnp.full_like(mt_ref, _NEG_INF)
+        lw_ref[...] = jnp.zeros_like(lw_ref)
+        _for_tiles(0, live, row_max)
+        for g in range(nq):
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, _row_to_col(
+                jnp.max(mt_ref[g], axis=0, keepdims=True)))
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[g] = m_new
+            l_ref[g] = l_ref[g] * alpha
+            acc_ref[g] = acc_ref[g] * _wide(alpha, acc_ref[g])
+        _for_tiles(0, live, accumulate)
+        for g in range(nq):
+            l_ref[g] += _lane_sums(lw_ref[g])
+
+        @pl.when(ki == last_k)
+        def _finish():
+            for g in range(nq):
+                m, l = m_ref[g], l_ref[g]
+                if dead_rows:
+                    # Dead rows (m still _NEG_INF) get lse = 0 so the backward
+                    # kernels' exp(s - lse) = exp(_NEG_INF) underflows to zero
+                    # gradient; the natural m + log(l) would be ~ -1e30 - 69,
+                    # making s - lse positive.
+                    lse = jnp.where(m <= _NEG_INF * 0.5, 0.0,
+                                    m + jnp.log(jnp.maximum(l, 1e-30)))
+                    l = jnp.where(l == 0.0, 1.0, l)
+                else:
+                    lse = m + jnp.log(l)
+                o_ref[0, :, g * d:(g + 1) * d] = (
+                    acc_ref[g] / _wide(l, acc_ref[g])).astype(o_ref.dtype)
+                lse_ref[0, 0, g:g + 1, :] = jnp.transpose(lse)[:1]
+
+    _inside(pl.program_id(1), p, run)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    b, h, h_kv, group, s_q, s_kv, d = _dims(q, k)
-    num_q, num_k = s_q // block_q, s_kv // block_k
-    grid = (b, h, num_q, num_k)
+def _specs_walk_q(p: Plan, causal: bool, q_offset: int):
+    """Block specs of the kernels whose grid is ``(batch, head block, q
+    block, major k block)``: q-shaped, kv-shaped, statistics."""
+    wq, wk = p.hq * p.d, p.hk * p.d
+    # head blocks that share a kv head (hk == 1), else each has its own
+    per_kv = p.h // p.h_kv // p.hq if p.hk == 1 else 1
 
+    def kv_index(b_, hb, qi, ki):
+        if causal:      # a dead block costs no transfer
+            ki = jnp.minimum(ki, _last_live_k(qi, p, q_offset))
+        return b_, ki, _div(hb, per_kv)
+
+    q_spec = pl.BlockSpec((1, p.block_q, wq),
+                          lambda b_, hb, qi, ki: (b_, qi, hb))
+    kv_spec = pl.BlockSpec((1, p.major_k, wk), kv_index)
+    row_spec = pl.BlockSpec((1, 1, p.hq, p.block_q),
+                            lambda b_, hb, qi, ki: (b_, hb, 0, qi))
+    return q_spec, kv_spec, row_spec
+
+
+def _params(n_grid: int, n_arbitrary: int):
+    sem = ("parallel",) * (n_grid - n_arbitrary) + ("arbitrary",) * n_arbitrary
+    return pltpu.CompilerParams(dimension_semantics=sem,
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _flash_fwd(q, k, v, causal, sm_scale, p: Plan):
+    """``q [b, s_q, h d]``, ``k``, ``v [b, s_kv, h_kv d]`` -> ``o`` like q
+    and ``lse [b, head blocks, hq, s_q]`` float32.  Under `jax.jit` so that
+    a program that holds the forward twice (the primal and the `custom_vjp`
+    rule; a remat's repeat) traces the unrolled kernel body once."""
+    b, s_q, _ = q.shape
+    s_kv = k.shape[1]
+    q_offset = s_kv - s_q
+    grid = (b, p.head_blocks, s_q // p.block_q, s_kv // p.major_k)
+    q_spec, kv_spec, row_spec = _specs_walk_q(p, causal, q_offset)
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, num_k=num_k,
-        q_offset=s_kv - s_q)
-    out_shapes = (
-        jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
-        jax.ShapeDtypeStruct((b, h, s_q, 1), jnp.float32),
-    )
-    o, lse = pl.pallas_call(
+        _fwd_kernel, p=p, sm_scale=sm_scale, causal=causal,
+        q_offset=q_offset, dead_rows=causal and s_q > s_kv)
+    return pl.pallas_call(
         kernel,
         # the HLO instruction takes this name, and a profiler trace's
         # `XLA Ops` events are named by instruction: the kernel shows as
@@ -167,29 +531,22 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         name="flash_attention_fwd",
         grid=grid,
         interpret=_interpret(),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, qi, ki, g=group: (b_, h_ // g, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, qi, ki, g=group: (b_, h_ // g, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, p.d), q.dtype),
+            pltpu.VMEM((p.hq, p.block_q, p.d), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, _LANES), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, _LANES), jnp.float32),
+            pltpu.VMEM((p.hq, 8, p.block_q), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, _fold_width(p.block_k)), jnp.float32),
         ],
-        out_shape=out_shapes,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        out_shape=(
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, p.head_blocks, p.hq, s_q), jnp.float32),
+        ),
+        compiler_params=_params(4, 1),
     )(q, k, v)
-    return o, lse
 
 
 # ---------------------------------------------------------------------------
@@ -197,180 +554,180 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, sm_scale, causal, block_q, block_k, num_k,
-                   q_offset):
+                   qs_ref, lse_col, delta_col, dq_acc, *, p: Plan, sm_scale,
+                   causal, q_offset):
     qi, ki = pl.program_id(2), pl.program_id(3)
+    last_k = pl.num_programs(3) - 1
+    bq, bk, d = p.block_q, p.block_k, p.d
+    per_kv = p.hq // p.hk
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+    def run(nq):
+        @pl.when(ki == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+            _fill_scaled(qs_ref, q_ref, nq, d, sm_scale)
+            for g in range(nq):
+                lse_col[g] = _row_to_col(lse_ref[0, 0, g:g + 1, :])[:, :1]
+                delta_col[g] = _row_to_col(
+                    delta_ref[0, 0, g:g + 1, :])[:, :1]
 
-    live = ((ki * block_k <= qi * block_q + block_q - 1 + q_offset)
-            if causal else (ki >= 0))
+        rel = _query_minus_key((bq, bk), 0) if causal else None
 
-    @pl.when(live)
-    def _compute():
-        # bf16 MXU inputs + fp32 accumulation throughout (see _fwd_kernel)
-        k = k_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do_ref[0, 0], v_ref[0, 0],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        def tile(t):
+            rows = _tile_rows(t, bk, p.major_k)
+            seen = (rel >= (ki * p.major_k + t * bk) - qi * bq - q_offset) \
+                if causal else None
+            for g in range(nq):
+                k = _heads(k_ref, rows, g // per_kv, d)
+                e = jnp.exp(_scores(qs_ref[g], k, sm_scale, seen)
+                            - lse_col[g])
+                dp = _nt(_heads(do_ref, slice(None), g, d),
+                         _heads(v_ref, rows, g // per_kv, d))
+                ds = (e * (dp - delta_col[g])).astype(k.dtype)
+                dq_acc[g] += _nn(ds, k)
 
-    @pl.when(ki == num_k - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        _for_tiles(0, _k_tiles(qi, ki, p, causal, q_offset), tile)
+
+        @pl.when(ki == last_k)
+        def _finish():
+            for g in range(nq):
+                dq_ref[0, :, g * d:(g + 1) * d] = (dq_acc[g] * sm_scale).astype(
+                    dq_ref.dtype)
+
+    _inside(pl.program_id(1), p, run)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    sm_scale, causal, block_q, block_k, num_q, group,
-                    q_offset):
-    ki, gi, qi = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+                    dk_ref, dv_ref, ks_ref, dk_acc, dv_acc, *, p: Plan,
+                    sm_scale, causal, q_offset):
+    """Scores transposed, ``[block_k, block_q]``: the statistics are rows
+    as stored, and dv = p^T do, dk = ds^T q are plain matmuls."""
+    ki, gi, qm = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    last_step = (gi == pl.num_programs(3) - 1) & (qm == pl.num_programs(4) - 1)
+    bq, bk, d = p.block_q, p.block_k, p.d
+    per_kv = p.hq // p.hk
 
-    @pl.when((qi == 0) & (gi == 0))
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    def run(nq):
+        @pl.when((gi == 0) & (qm == 0))
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
+            _fill_scaled(ks_ref, k_ref, nq // per_kv, d, sm_scale)
 
-    live = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
-            if causal else (qi >= 0))
+        rel = _query_minus_key((bk, bq), 1) if causal else None
 
-    @pl.when(live)
-    def _compute():
-        # bf16 MXU inputs + fp32 accumulation throughout (see _fwd_kernel)
-        q = q_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q, k_ref[0, 0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)                                   # [bq, bk]
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # [bk, d]
-        dp = jax.lax.dot_general(do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # [bk, d]
+        def tile(t):
+            rows = _tile_rows(t, bq, p.major_q)
+            seen = (rel >= ki * bk - (qm * p.major_q + t * bq) - q_offset) \
+                if causal else None
+            for g in range(nq):
+                n = g // per_kv
+                q = _heads(q_ref, rows, g, d)
+                do = _heads(do_ref, rows, g, d)
+                e = jnp.exp(_scores(ks_ref[n], q, sm_scale, seen)
+                            - lse_ref[0, 0, g:g + 1, rows])      # [bk, bq]
+                dv_acc[n] += _nn(e.astype(do.dtype), do)
+                dp = _nt(_heads(v_ref, slice(None), n, d), do)
+                ds = (e * (dp - delta_ref[0, 0, g:g + 1, rows])).astype(q.dtype)
+                dk_acc[n] += _nn(ds, q)
 
-    @pl.when((qi == num_q - 1) & (gi == group - 1))
-    def _finish():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        _for_tiles(_first_q_tile(ki, qm, p, causal, q_offset),
+                   p.major_q // bq, tile)
+
+        @pl.when(last_step)
+        def _finish():
+            for n in range(nq // per_kv):
+                dk_ref[0, :, n * d:(n + 1) * d] = (dk_acc[n] * sm_scale).astype(
+                    dk_ref.dtype)
+                dv_ref[0, :, n * d:(n + 1) * d] = dv_acc[n].astype(dv_ref.dtype)
+
+    _inside(pl.program_id(1), p, run)
 
 
-def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
-    b, h, h_kv, group, s_q, s_kv, d = _dims(q, k)
-    num_q, num_k = s_q // block_q, s_kv // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                    # [b, h, s_q, 1]
+def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, p: Plan):
+    b, s_q, _ = q.shape
+    s_kv = k.shape[1]
+    q_offset = s_kv - s_q
+    # the row sums of do * o, laid out like lse: [b, head blocks, hq, s_q]
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(b, s_q, p.h, p.d), axis=-1)
+    delta = jnp.pad(jnp.swapaxes(delta, 1, 2),
+                    ((0, 0), (0, p.head_blocks * p.hq - p.h), (0, 0)))
+    delta = delta.reshape(lse.shape)
 
-    sem = ("parallel", "parallel", "parallel", "arbitrary")
+    q_spec, kv_spec, row_spec = _specs_walk_q(p, causal, q_offset)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_k=num_k,
-                          q_offset=s_kv - s_q),
+        functools.partial(_bwd_dq_kernel, p=p, sm_scale=sm_scale,
+                          causal=causal, q_offset=q_offset),
         name="flash_attention_dq",
-        grid=(b, h, num_q, num_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, qi, ki, g=group: (b_, h_ // g, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, qi, ki, g=group: (b_, h_ // g, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+        grid=(b, p.head_blocks, s_q // p.block_q, s_kv // p.major_k),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((p.hq, p.block_q, p.d), q.dtype),
+            pltpu.VMEM((p.hq, p.block_q, 1), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, 1), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, p.d), jnp.float32),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
+        compiler_params=_params(4, 1),
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
-    sem5 = ("parallel", "parallel", "parallel", "arbitrary", "arbitrary")
+    # grid (batch, kv head block, k block, q head blocks of it, major q)
+    q_steps = 1 if p.hk > 1 else p.h // p.h_kv // p.hq
+
+    def q_index(b_, h2, ki, g_, qm):
+        if causal:      # a dead block costs no transfer
+            qm = jnp.maximum(qm, _first_live_q(ki, p, q_offset))
+        return b_, qm, h2 * q_steps + g_
+
+    def row_index(b_, h2, ki, g_, qm):
+        b_, qm, hb = q_index(b_, h2, ki, g_, qm)
+        return b_, hb, 0, qm
+
+    qd_spec = pl.BlockSpec((1, p.major_q, p.hq * p.d), q_index)
+    k_spec = pl.BlockSpec((1, p.block_k, p.hk * p.d),
+                          lambda b_, h2, ki, g_, qm: (b_, ki, h2))
+    rows_spec = pl.BlockSpec((1, 1, p.hq, p.major_q), row_index)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q=num_q,
-                          group=group, q_offset=s_kv - s_q),
+        functools.partial(_bwd_dkv_kernel, p=p, sm_scale=sm_scale,
+                          causal=causal, q_offset=q_offset),
         name="flash_attention_dkv",
-        grid=(b, h_kv, num_k, group, num_q),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h2, ki, g_, qi, G=group: (b_, h2 * G + g_, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h2, ki, g_, qi: (b_, h2, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h2, ki, g_, qi: (b_, h2, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h2, ki, g_, qi, G=group: (b_, h2 * G + g_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h2, ki, g_, qi, G=group: (b_, h2 * G + g_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h2, ki, g_, qi, G=group: (b_, h2 * G + g_, qi, 0)),
+        grid=(b, -(-p.h_kv // p.hk), s_kv // p.block_k, q_steps,
+              s_q // p.major_q),
+        in_specs=[qd_spec, k_spec, k_spec, qd_spec, rows_spec, rows_spec],
+        out_specs=[k_spec, k_spec],
+        scratch_shapes=[
+            pltpu.VMEM((p.hk, p.block_k, p.d), k.dtype),
+            pltpu.VMEM((p.hk, p.block_k, p.d), jnp.float32),
+            pltpu.VMEM((p.hk, p.block_k, p.d), jnp.float32),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h2, ki, g_, qi: (b_, h2, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h2, ki, g_, qi: (b_, h2, ki, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=sem5),
+        compiler_params=_params(5, 2),
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
-# custom-vjp wrapper (operates in [b, h, s, d])
+# custom-vjp wrapper (operates on [b, s, heads x d])
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k):
-    o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
-    return o
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, sm_scale, plan):
+    return _flash_fwd(q, k, v, causal, sm_scale, plan)[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+def _flash_fwd_rule(q, k, v, causal, sm_scale, plan):
+    o, lse = _flash_fwd(q, k, v, causal, sm_scale, plan)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
+def _flash_bwd_rule(causal, sm_scale, plan, res, do):
     q, k, v, o, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, causal, sm_scale,
-                            block_q, block_k)
-    return dq, dk, dv
+    return _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, plan)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -384,31 +741,31 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """Fused attention over ``[batch, seq, heads, head_dim]`` inputs.
 
     KV heads may be a divisor of query heads (GQA/MQA).  Differentiable via
-    flash backward kernels.  Block sizes default from `_default_blocks()`
-    (env-tunable) when not given, and are clamped (halving search) to the
-    largest divisor of each seq length; raises only when no divisor >= 8
-    exists — use `multi_head_attention` for automatic fallback.
+    flash backward kernels.  ``block_q`` / ``block_k`` (the score tile;
+    `DEFAULT_BLOCK_Q` / `DEFAULT_BLOCK_K` when not given) are clamped
+    (halving search) to the largest divisor of each seq length; raises
+    where that is no tile the kernels walk (`tile_ok`: a multiple of 128
+    rows, or the whole sequence) — use `multi_head_attention` for automatic
+    fallback.
     """
-    dq, dk_ = _default_blocks()
-    if block_q is None:
-        block_q = dq
-    if block_k is None:
-        block_k = dk_
-    s_q, s_kv = q.shape[1], k.shape[1]
+    b, s_q, h, d = q.shape
+    s_kv, h_kv = k.shape[1], k.shape[2]
+    block_q = DEFAULT_BLOCK_Q if block_q is None else block_q
+    block_k = DEFAULT_BLOCK_K if block_k is None else block_k
     bq, bk = fit_block(block_q, s_q), fit_block(block_k, s_kv)
-    if bq < 8 or bk < 8:   # no MXU-reasonable divisor exists
+    if not (tile_ok(bq, s_q) and tile_ok(bk, s_kv)):
         raise ValueError(
-            f"seq lengths ({s_q}, {s_kv}) have no block divisor >= 8 "
-            f"under ({block_q}, {block_k})")
+            f"seq lengths ({s_q}, {s_kv}) under blocks ({block_q}, "
+            f"{block_k}) give tiles ({bq}, {bk}): a tile is a multiple of "
+            f"{_LANES} rows or the whole sequence (8 rows or more)")
     if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
+        sm_scale = d ** -0.5
     # the kernels feed q/k/v straight into MXU dots in their storage dtype
     # (bf16 in + fp32 accumulation); normalize mixed-dtype inputs (e.g. an
     # fp32 query against a bf16 KV cache) to the query's dtype up front
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash(qt, kt, vt, causal, sm_scale, bq, bk)
-    return jnp.swapaxes(out, 1, 2)
+    plan = make_plan(h, h_kv, d, s_q, s_kv, q.dtype.itemsize, bq, bk)
+    out = _flash(q.reshape(b, s_q, h * d), k.reshape(b, s_kv, h_kv * d),
+                 v.reshape(b, s_kv, h_kv * d), causal, float(sm_scale), plan)
+    return out.reshape(b, s_q, h, d)

@@ -9,14 +9,17 @@ only one process at a time may load the TPU library, so only the test worker
 that is handed this file loads it, and it compiles in its own process.
 """
 
+import math
+
 import pytest
 
-# [batch, seq, heads, kv_heads, head_dim], bf16, default blocks 256x512:
+# [batch, seq, heads, kv_heads, head_dim], bf16, the default tile:
 # gpt2-medium train (chip_smoke.py), gpt2-small long context, llama GQA at
-# two head widths, gpt2-medium at batch 8
+# two head widths, gpt2-medium at batch 8 (the one-chip train cell's micro
+# batch), gpt2-xl's per-chip micro batch under fsdp=4 (25 heads: 3 x 8 + 1)
 _SHAPES = [(4, 1024, 16, 16, 64), (2, 4096, 12, 12, 64),
            (2, 2048, 32, 8, 64), (2, 2048, 16, 4, 128),
-           (8, 1024, 16, 16, 64)]
+           (8, 1024, 16, 16, 64), (2, 1024, 25, 25, 64)]
 
 
 @pytest.fixture(scope="module")
@@ -41,8 +44,17 @@ def topo():
     compilation_cache.reset_cache()
 
 
-def _kernel_inputs(shape, sharding):
-    """Arguments of the kernels' own layout, [batch, heads, seq, head_dim]."""
+def _flash_plan(fa, shape):
+    b, s, h, h_kv, d = shape
+    return fa.make_plan(h, h_kv, d, s, s, 2,
+                        fa.fit_block(fa.DEFAULT_BLOCK_Q, s),
+                        fa.fit_block(fa.DEFAULT_BLOCK_K, s))
+
+
+def _kernel_inputs(shape, plan, sharding):
+    """Arguments of the kernels' own layout: the activations as they lie,
+    ``[batch, seq, heads x head_dim]``, and the row statistics with the
+    sequence on the lanes."""
     import jax
     import jax.numpy as jnp
     b, s, h, h_kv, d = shape
@@ -50,8 +62,8 @@ def _kernel_inputs(shape, sharding):
     def arr(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
-    q, kv = arr(b, h, s, d), arr(b, h_kv, s, d)
-    row = arr(b, h, s, 1, dtype=jnp.float32)       # lse, and delta's shape
+    q, kv = arr(b, s, h * d), arr(b, s, h_kv * d)
+    row = arr(b, plan.head_blocks, plan.hq, s, dtype=jnp.float32)   # lse
     return q, kv, row
 
 
@@ -65,22 +77,102 @@ def test_flash_kernel_compiles_for_v5e(topo, shape, kernel):
 
     # `ray_tpu.ops` re-exports the function under the module's own name
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
-    q, kv, row = _kernel_inputs(shape, SingleDeviceSharding(topo.devices[0]))
-    bq = fa.fit_block(fa.DEFAULT_BLOCK_Q, shape[1])
-    bk = fa.fit_block(fa.DEFAULT_BLOCK_K, shape[1])
+    plan = _flash_plan(fa, shape)
+    q, kv, row = _kernel_inputs(shape, plan,
+                                SingleDeviceSharding(topo.devices[0]))
     scale = shape[-1] ** -0.5
     if kernel == "fwd":
-        fn = lambda q, k, v: fa._flash_fwd(q, k, v, True, scale, bq, bk)
+        fn = lambda q, k, v: fa._flash_fwd(q, k, v, True, scale, plan)
         args = (q, kv, kv)
     else:
         # the backward is two kernels; reading one result leaves the
         # compiler the other to drop
         pick = slice(0, 1) if kernel == "dq" else slice(1, 3)
         fn = lambda q, k, v, o, lse, do: fa._flash_bwd(
-            q, k, v, o, lse, do, True, scale, bq, bk)[pick]
+            q, k, v, o, lse, do, True, scale, plan)[pick]
         args = (q, kv, kv, q, row, q)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 1, text[:2000]
+
+
+@pytest.mark.parametrize("shape", [(8, 1024, 16, 16, 64),
+                                   (2, 1024, 25, 25, 64)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_flash_calls_take_dense_operands_and_no_copy(topo, shape):
+    """The train cells' attention, forward and backward, from activations
+    as a projection leaves them (``[b, s, heads x 64]``): every operand and
+    result of the three kernels has at least 128 lanes of data in its minor
+    dimension (no ``[.., s, 1]`` statistics, no 64-wide heads), and the
+    wrapper puts no transpose and no layout copy of an activation-sized
+    array between them.  (At 25 heads the activations are 1600 wide, no
+    multiple of 128: the compiler itself holds such an array sequence-minor
+    wherever it may, parameters included, and converts it for any consumer
+    that wants rows; only the operands' shapes are asserted there.)"""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.flash_attention import flash_attention
+    b, s, h, h_kv, d = shape
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        out = flash_attention(*(a.reshape(b, s, h, d) for a in (q, k, v)))
+        return (out.reshape(b, s, h * d).astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    calls = re.findall(r"= (\([^=]*\)|\S+) custom-call\(([^)]*)\), "
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 3, calls
+    big = b * s * h * d
+    for result, operands in calls:
+        shapes = re.findall(r"(?:bf16|f32)\[([\d,]+)\]", result)
+        for name in re.findall(r"%([\w.\-]+)", operands):
+            made = re.search(rf"%{re.escape(name)} = (\S+) (\w[\w\-]*)\(",
+                             text)
+            shapes += re.findall(r"\[([\d,]+)\]", made.group(1))
+            dims = [int(n) for n in re.findall(
+                r"\[([\d,]+)\]", made.group(1))[0].split(",")]
+            assert (h * d) % 128 or not (
+                made.group(2) in ("copy", "transpose")
+                and math.prod(dims) >= big), made.group(0)
+        assert shapes and all(int(sh.split(",")[-1]) >= 128
+                              for sh in shapes), (result, operands, shapes)
+
+
+@pytest.mark.parametrize("shape", [
+    # batch, s_q, s_kv, heads, kv heads, head size, causal
+    (2, 192, 192, 12, 12, 64, True), (2, 197, 197, 12, 12, 64, False),
+    (2, 100, 197, 4, 2, 64, True), (2, 200, 200, 8, 2, 128, True),
+    (2, 192, 384, 12, 12, 64, True),
+], ids=lambda s: "x".join(map(str, s)))
+def test_flash_whole_sequence_tile_compiles_for_v5e(topo, shape):
+    """A sequence the default tile does not divide (192; ViT's 197 tokens)
+    is ONE tile of its own length: the chip's compiler takes the three
+    kernels at tiles that are no multiple of the lanes or the sublanes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.attention import multi_head_attention
+    b, s_q, s_kv, h, h_kv, d, causal = shape
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(s, heads):
+        return jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16,
+                                    sharding=one)
+
+    def loss(q, k, v):
+        out = multi_head_attention(q, k, v, causal=causal, impl="flash")
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arr(s_q, h), arr(s_kv, h_kv), arr(s_kv, h_kv)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
 
 
 def test_flash_kernel_compiles_per_shard_on_the_2x2(topo):

@@ -149,6 +149,229 @@ def test_flash_kernel_interpret_mode_parity(monkeypatch):
                                    atol=5e-4, rtol=5e-4)
 
 
+# (batch, s_q, s_kv, heads, kv heads, head size, causal, block_q, block_k,
+#  dtype, the module's constants held otherwise) -> (query heads, kv heads)
+# a grid step
+_FLASH_CASES = {
+    "one-head-a-step-d128": ((1, 256, 256, 1, 1, 128, True, 128, 128,
+                              "float32", {}), (1, 1)),
+    "four-heads-a-step": ((1, 256, 256, 4, 4, 64, True, 128, 128,
+                           "float32", {}), (4, 4)),
+    # 5 heads under a limit of 4 stand for gpt2-xl's 25 under 8: a block of
+    # four, then one whose last three heads lie outside the array
+    "odd-heads-5-for-25": ((1, 256, 256, 5, 5, 64, True, 128, 128,
+                            "float32", {"_MAX_HEADS": 4}), (4, 4)),
+    "odd-heads-5-in-pairs": ((1, 256, 256, 5, 5, 64, True, 128, 128,
+                              "float32", {"_MAX_HEADS": 2}), (2, 2)),
+    "odd-heads-all-5-in-a-step": ((1, 256, 256, 5, 5, 64, True, 128, 128,
+                                   "float32", {}), (5, 5)),
+    "gqa-8-2": ((1, 256, 256, 8, 2, 64, True, 128, 128, "float32", {}),
+                (8, 2)),
+    "mqa-16-1-two-q-steps": ((1, 128, 128, 16, 1, 64, True, 128, 128,
+                              "float32", {}), (8, 1)),
+    "gqa-4-2-d128": ((1, 256, 256, 4, 2, 128, True, 128, 128, "float32",
+                      {}), (4, 2)),
+    "non-causal": ((1, 256, 256, 2, 2, 64, False, 128, 128, "float32",
+                    {}), (2, 2)),
+    # all 3 heads in one block: 192 lanes, the array's whole width
+    "non-causal-all-heads-192-lanes": ((1, 128, 256, 3, 3, 64, False, 128,
+                                        128, "float32", {}), (3, 3)),
+    "s_q-less-than-s_kv": ((1, 128, 256, 2, 2, 64, True, 128, 128,
+                            "float32", {}), (2, 2)),
+    "s_q-more-than-s_kv": ((1, 256, 128, 2, 2, 64, True, 128, 128,
+                            "float32", {}), (2, 2)),
+    "s_q-more-odd-heads": ((1, 256, 128, 5, 5, 64, True, 128, 128,
+                            "float32", {"_MAX_HEADS": 4}), (4, 4)),
+    "tile-128x256-batch-2": ((2, 256, 256, 4, 1, 64, True, 128, 256,
+                              "float32", {}), (4, 1)),
+    # k, v (dkv: q, do) in major blocks smaller than the sequence: dead
+    # blocks' indices are clamped and the state crosses grid steps
+    "major-blocks-not-resident": ((1, 512, 512, 2, 2, 64, True, 128, 128,
+                                   "float32", {"_VMEM_BLOCK_BUDGET": 1 << 20}), (2, 2)),
+    "major-blocks-s_q-more": ((1, 512, 256, 2, 2, 64, True, 128, 128,
+                               "float32", {"_VMEM_BLOCK_BUDGET": 1 << 20}), (2, 2)),
+    "bfloat16-gqa": ((1, 256, 256, 4, 2, 64, True, 128, 128, "bfloat16",
+                      {}), (4, 2)),
+    "bfloat16-odd-heads-d128-scale": ((1, 256, 256, 3, 3, 128, True, 128,
+                                       128, "bfloat16", {}), (3, 3)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH_CASES))
+def test_flash_kernel_cases_match_reference(monkeypatch, case):
+    """Forward and all three gradients of the flash kernels (interpreter)
+    against `reference_attention`: heads a grid step 1 and more, an odd head
+    count whose last step lies half outside the array, GQA through index
+    maps, head sizes 64 and 128 (a scale folded into the operand, and one
+    that is not a power of two left on the scores), causal and not, s_q <,
+    = and > s_kv, tiles the diagonal crosses beside tiles it does not, and
+    major blocks that are not the whole sequence."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import reference_attention
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    (b, s_q, s_kv, h, h_kv, d, causal, bq, bk, dtype, held), heads = \
+        _FLASH_CASES[case]
+    for name, value in held.items():
+        monkeypatch.setattr(fa, name, value)
+    dt = jnp.dtype(dtype)
+    plan = fa.make_plan(h, h_kv, d, s_q, s_kv, dt.itemsize, bq, bk)
+    assert (plan.hq, plan.hk) == heads
+    assert (plan.major_k < s_kv and plan.major_q < s_q) == (
+        "_VMEM_BLOCK_BUDGET" in held)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(k1, (b, s_q, h, d), dt)
+    k = jax.random.normal(k2, (b, s_kv, h_kv, d), dt)
+    v = jax.random.normal(k3, (b, s_kv, h_kv, d), dt)
+    # rows that see no key (causal, s_q > s_kv): the kernel gives 0, the
+    # reference a mean of v; compared on the rows that attend
+    live = slice(max(s_q - s_kv, 0) if causal else 0, None)
+
+    def loss(fn):
+        return lambda *a: (fn(*a)[:, live].astype(jnp.float32) ** 2).sum()
+    flash = lambda *a: fa.flash_attention(*a, causal=causal, block_q=bq,
+                                          block_k=bk)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref = lambda *a: reference_attention(*a, causal=causal)
+    out = flash(q, k, v)
+    assert out.dtype == dt and out.shape == q.shape
+    assert not np.asarray(out[:, :live.start], np.float32).any()
+    tol_o, tol_g = (2e-5, 5e-4) if dtype == "float32" else (3e-2, 0.25)
+    np.testing.assert_allclose(np.asarray(out[:, live], np.float32),
+                               np.asarray(ref(*f32)[:, live]),
+                               atol=tol_o, rtol=tol_o)
+    g_f = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_r = jax.grad(loss(ref), argnums=(0, 1, 2))(*f32)
+    for a, r in zip(g_f, g_r):
+        assert a.dtype == dt
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
+                                   atol=tol_g, rtol=tol_g)
+
+
+# (batch, s_q, s_kv, heads, kv heads, head size, causal, dtype): sequences
+# whose default tile is the WHOLE sequence, of a length that is no multiple
+# of the lanes, of the sublanes, or of anything
+_WHOLE_SEQUENCE_CASES = {
+    "192-causal-12-heads": (1, 192, 192, 12, 12, 64, True, "float32"),
+    "vit-197-non-causal-12-heads": (2, 197, 197, 12, 12, 64, False,
+                                    "float32"),
+    "197-causal": (1, 197, 197, 2, 2, 64, True, "float32"),
+    "q-100-on-k-197-gqa": (1, 100, 197, 4, 2, 64, True, "float32"),
+    "q-197-on-k-72-dead-rows": (1, 197, 72, 2, 2, 64, True, "float32"),
+    "q-192-whole-k-384-in-tiles": (1, 192, 384, 2, 2, 64, True, "float32"),
+    "q-256-in-a-tile-k-197-whole": (1, 256, 197, 2, 2, 64, False,
+                                    "float32"),
+    "200-gqa-d128-bfloat16": (1, 200, 200, 4, 2, 128, True, "bfloat16"),
+    "8-rows": (1, 8, 8, 2, 2, 64, True, "float32"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WHOLE_SEQUENCE_CASES))
+def test_flash_takes_a_whole_short_sequence_as_one_tile(monkeypatch, case):
+    """`multi_head_attention(impl="flash")` on sequences the default tile
+    does not divide (192; ViT's 197 tokens; 100 queries on 197 keys): the
+    whole sequence is one tile, whatever its length, forward and gradients
+    as the reference's."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import (multi_head_attention,
+                                       reference_attention)
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    b, s_q, s_kv, h, h_kv, d, causal, dtype = _WHOLE_SEQUENCE_CASES[case]
+    tiles = (fa.fit_block(fa.DEFAULT_BLOCK_Q, s_q),
+             fa.fit_block(fa.DEFAULT_BLOCK_K, s_kv))
+    assert s_q in tiles or s_kv in tiles
+    assert fa.tile_ok(tiles[0], s_q) and fa.tile_ok(tiles[1], s_kv)
+    dt = jnp.dtype(dtype)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(k1, (b, s_q, h, d), dt)
+    k = jax.random.normal(k2, (b, s_kv, h_kv, d), dt)
+    v = jax.random.normal(k3, (b, s_kv, h_kv, d), dt)
+    live = slice(max(s_q - s_kv, 0) if causal else 0, None)
+
+    def loss(fn):
+        return lambda *a: (fn(*a)[:, live].astype(jnp.float32) ** 2).sum()
+    flash = lambda *a: multi_head_attention(*a, causal=causal, impl="flash")
+    ref = lambda *a: reference_attention(*a, causal=causal)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    tol_o, tol_g = (2e-5, 5e-4) if dtype == "float32" else (3e-2, 0.25)
+    out = flash(q, k, v)
+    assert out.dtype == dt and out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out[:, live], np.float32),
+                               np.asarray(ref(*f32)[:, live]),
+                               atol=tol_o, rtol=tol_o)
+    g_f = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_r = jax.grad(loss(ref), argnums=(0, 1, 2))(*f32)
+    for a, r in zip(g_f, g_r):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
+                                   atol=tol_g, rtol=tol_g)
+
+
+@pytest.mark.parametrize("s_q,s_kv,ok", [
+    (1024, 1024, True), (384, 384, True),       # tiles of 256 and of 128
+    (192, 192, True), (197, 197, True),         # one tile, any length
+    (197, 1024, True), (1024, 200, True), (8, 8, True),
+    (320, 320, False), (1024, 320, False),      # only 64 divides 320
+    (264, 264, False),                          # only 8 divides 264
+    (4, 4, False), (1, 1024, False),            # under 8 rows
+])
+def test_flash_is_chosen_where_the_kernels_take_the_tiles(monkeypatch, s_q,
+                                                          s_kv, ok):
+    """The dispatcher's test and the kernels' own are one rule (`tile_ok`):
+    what ``impl="auto"`` sends to the kernels on a TPU they run, and what
+    `flash_attention` would refuse goes to the reference."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((1, s_q, 2, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, s_kv, 2, 64), jnp.bfloat16)
+    assert attention._flash_ok(q, k) == ok
+    if not ok:
+        with pytest.raises(ValueError, match="a tile is a multiple of 128"):
+            jax.eval_shape(fa.flash_attention, q, k, k)
+    assert not attention._flash_ok(
+        jax.ShapeDtypeStruct((1, s_q, 2, 96), jnp.bfloat16), k)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # (heads, kv heads, head size, s_q, s_kv) -> (hq, hk, major_k, major_q)
+    ((16, 16, 64, 1024, 1024), (8, 8, 1024, 1024)),     # gpt2-medium
+    ((25, 25, 64, 1024, 1024), (8, 8, 1024, 1024)),     # gpt2-xl: 3 x 8 + 1
+    ((12, 12, 64, 4096, 4096), (4, 4, 4096, 4096)),     # long context
+    ((32, 8, 64, 2048, 2048), (8, 2, 2048, 2048)),      # GQA: 2 kv heads
+    ((16, 4, 128, 2048, 2048), (4, 1, 2048, 2048)),     # one kv head a step
+    ((48, 8, 128, 1024, 1024), (6, 1, 1024, 1024)),
+    ((12, 12, 64, 65536, 65536), (2, 2, 8192, 8192)),   # not resident
+], ids=lambda x: "x".join(map(str, x)))
+def test_flash_plan_follows_the_shape(shape, want):
+    """Heads a grid step and the resident rows come from the shape alone:
+    lane-dense blocks (a multiple of 128 lanes), whole GQA groups, under
+    the VMEM budget; residency before more heads."""
+    import importlib
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    h, h_kv, d, s_q, s_kv = shape
+    p = fa.make_plan(h, h_kv, d, s_q, s_kv, 2, 256, 256)
+    assert (p.hq, p.hk, p.major_k, p.major_q) == want
+    assert (p.hq * d) % 128 == 0 and (p.hk * d) % 128 == 0
+    assert p.hq == p.hk * (h // h_kv) or (
+        p.hk == 1 and (h // h_kv) % p.hq == 0)
+    assert fa._block_bytes(p.hq, p.hk, d, 256, 256, p.major_k, p.major_q,
+                           2) <= fa._VMEM_BLOCK_BUDGET
+
+
 def test_flash_kernel_runs_per_shard_under_a_mesh(monkeypatch):
     """A Mosaic kernel cannot be partitioned by the compiler, so under a
     mesh `multi_head_attention(impl="flash")` runs it per shard (batch over
