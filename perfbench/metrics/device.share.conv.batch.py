@@ -1,0 +1,13 @@
+"""device.share.conv.batch: The ``conv`` scope: the gated short convolution
+(`ops/short_conv.py` `conv_inputs`, `short_conv`, `conv_block`), as a share of
+all programs' device seconds in the traced window (`perfbench/parts.py`: the
+``XLA Ops`` events placed by the op maps the program's compile ledger left,
+each marked by a ``program:compiled`` span).  None where the program left no
+map.
+"""
+
+from perfbench import parts
+
+
+def read(run):
+    return parts.share(run, "conv")

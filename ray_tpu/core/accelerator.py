@@ -159,7 +159,23 @@ def place_compile_cache() -> None:
     the chip gets ``<checkout>/.jax_compile_cache`` — a fixed path, since
     the path is part of the cache key.  Processes pinned to the CPU get no
     default: the CPU suite's compiles are short, and a described-topology
-    compile written there cannot be read back without a chip."""
+    compile written there cannot be read back without a chip.
+
+    Whichever directory it is, an entry's key holds the program's metadata
+    (``jax_compilation_cache_include_metadata_in_key``).  JAX strips it by
+    default, and a program whose instructions did not change is then a HIT
+    on an executable compiled before an edit to its `jax.named_scope`
+    names: its text carries the OLD ``op_name`` paths, and the compile
+    ledger's op map (`util/device_profile.py`) places a trace's ops by
+    them.  The price: an edit that moves a line on a program's call path
+    compiles that program once more (as the programs with a Pallas kernel
+    always did: a kernel's body holds its source locations)."""
+    jax = sys.modules.get("jax")
+    if "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY" not in os.environ:
+        os.environ["JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"] = "true"
+        if jax is not None:
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
             or os.environ.get("JAX_PLATFORMS") == CPU:
         return
@@ -167,6 +183,5 @@ def place_compile_cache() -> None:
         os.path.abspath(__file__))))
     path = os.path.join(checkout, ".jax_compile_cache")
     os.environ["JAX_COMPILATION_CACHE_DIR"] = path
-    jax = sys.modules.get("jax")
     if jax is not None and not jax.config.jax_compilation_cache_dir:
         jax.config.update("jax_compilation_cache_dir", path)

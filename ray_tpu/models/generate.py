@@ -230,6 +230,7 @@ def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
             f"to an earlier token (models/generate.py)")
 
 
+@jax.named_scope("attention")
 def _ring_mask(pos, c: int, ring: int, window: int) -> jnp.ndarray:
     """``pos`` [...] first new position a row, ``c`` new tokens a row →
     [..., c, ring] bool: ring column visible to each new token.  After the
@@ -255,6 +256,7 @@ def _ring_write_chunk(pos, c: int, ring: int):
     does not wrap)."""
     r = pos % ring
 
+    @jax.named_scope("cache_write")
     def write(c_all, l, cols):
         if c == 1:
             return jax.lax.dynamic_update_slice(c_all, cols[None],
@@ -316,6 +318,7 @@ def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     return x, arrays, load
 
 
+@jax.named_scope("cache_write")
 def _as_columns(rows: jnp.ndarray, dtype) -> jnp.ndarray:
     """New tokens' K or V [B, C, hk, hd] → cache columns [B, hk, hd, C]."""
     return jnp.transpose(rows, (0, 2, 3, 1)).astype(dtype)
@@ -325,6 +328,7 @@ def _layer_of(c_all: jnp.ndarray, l) -> jnp.ndarray:
     return jax.lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
 
 
+@jax.named_scope("cache_write")
 def _place_state(s_all: jnp.ndarray, l, state: jnp.ndarray) -> jnp.ndarray:
     """Every row's state [B, taps - 1, D] into conv layer ``l``."""
     return jax.lax.dynamic_update_slice(
@@ -357,16 +361,18 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                                rotate if cfg.rotates(kind) else None)
         k_all = write[kind](arrs[kn], l, _as_columns(k_new, arrs[kn].dtype))
         v_all = write[kind](arrs[vn], l, _as_columns(v_new, arrs[vn].dtype))
-        ck, cv = _layer_of(k_all, l), _layer_of(v_all, l)
-        # GQA: group query heads over kv heads
-        qh = q.reshape(b, c, hk, h // hk, hd)
-        scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
-                            ck.astype(dt)) / jnp.sqrt(float(hd))
-        scores = jnp.where(mask[kind][:, :, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
-                          cv.astype(dt))
-        attn = attn.reshape(b, c, h, hd)
+        with jax.named_scope("attention"):
+            ck, cv = _layer_of(k_all, l), _layer_of(v_all, l)
+            # GQA: group query heads over kv heads
+            qh = q.reshape(b, c, hk, h // hk, hd)
+            scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
+                                ck.astype(dt)) / jnp.sqrt(float(hd))
+            scores = jnp.where(mask[kind][:, :, None, None, :], scores,
+                               -1e30)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
+                              cv.astype(dt))
+            attn = attn.reshape(b, c, h, hd)
         return (_attn_out(cfg, y, attn, lp),
                 dict(arrs, **{kn: k_all, vn: v_all}))
 
@@ -417,9 +423,10 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     if s > cache_capacity(cache):
         raise ValueError(f"prompt length {s} exceeds cache capacity "
                          f"{cache_capacity(cache)}")
-    x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["pos"][:s].astype(dt)
+    with jax.named_scope("embed"):
+        x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
+        if cfg.pos_emb == "learned":
+            x = x + params["embed"]["pos"][:s].astype(dt)
     cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
     rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
@@ -435,6 +442,7 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         _, k, v = _qkv(cfg, y, lp, rotate if cfg.rotates(kind) else None)
         return dict(zip(_kv_names(kind), (k, v)))
 
+    @jax.named_scope("cache_write")
     def place(c_all, l, cols):
         """The prompt's columns into layer ``l``: from column 0, or, of a
         prompt longer than a ring, the last ``ring`` positions, each at its
@@ -462,9 +470,16 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 
     x, arrays, _ = _scan_cached(cfg, params, x, cache, layer)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
-    logits = jnp.einsum("bd,dv->bv", x[:, -1], _unembed(params, cfg))
-    return logits.astype(jnp.float32), dict(
+    return _last_logits(params, x[:, -1], cfg), dict(
         arrays, pos=jnp.asarray(s, jnp.int32))
+
+
+@jax.named_scope("head")
+def _last_logits(params: Params, x: jnp.ndarray, cfg: TransformerConfig
+                 ) -> jnp.ndarray:
+    """Final-norm activations [..., D] -> float32 logits [..., vocab]."""
+    return jnp.einsum("...d,dv->...v", x,
+                      _unembed(params, cfg)).astype(jnp.float32)
 
 
 def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
@@ -515,23 +530,31 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     dt = cfg.dtype
     pos = cache["pos"]
     max_len = cache_capacity(cache)
-    x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
-    if cfg.pos_emb == "learned":
-        x = x + jax.lax.dynamic_slice_in_dim(
-            params["embed"]["pos"], pos, c, axis=0).astype(dt)
+    with jax.named_scope("embed"):
+        x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
+        if cfg.pos_emb == "learned":
+            x = x + jax.lax.dynamic_slice_in_dim(
+                params["embed"]["pos"], pos, c, axis=0).astype(dt)
     if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
-                                           cfg.rope_base)
-        cos = jax.lax.dynamic_slice_in_dim(full_cos, pos, c, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(full_sin, pos, c, axis=0)
+        with jax.named_scope("projections"):
+            full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
+                                               cfg.rope_base)
+            cos = jax.lax.dynamic_slice_in_dim(full_cos, pos, c, axis=0)
+            sin = jax.lax.dynamic_slice_in_dim(full_sin, pos, c, axis=0)
     else:
         cos = sin = None
     # mask[i, t]: cached position t visible to chunk token i (causal
     # within the chunk, everything before it fully visible)
-    mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
+    with jax.named_scope("attention"):
+        mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
     mask = {"full": mask[None]}
-    write = {"full": lambda c_all, l, cols: jax.lax.dynamic_update_slice(
-        c_all, cols[None], (l, 0, 0, 0, pos))}
+
+    @jax.named_scope("cache_write")
+    def write_full(c_all, l, cols):
+        return jax.lax.dynamic_update_slice(c_all, cols[None],
+                                            (l, 0, 0, 0, pos))
+
+    write = {"full": write_full}
     if "window" in cfg.kinds:
         ring = window_ring(cfg, max_len)
         mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)[None]
@@ -550,8 +573,8 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
         last = jax.lax.dynamic_index_in_dim(x, n_valid - 1, axis=1,
                                             keepdims=False)
         step = jnp.asarray(n_valid, pos.dtype)
-    logits = jnp.einsum("bd,dv->bv", last, _unembed(params, cfg))
-    return logits.astype(jnp.float32), dict(arrays, pos=pos + step), load
+    return _last_logits(params, last, cfg), dict(arrays,
+                                                 pos=pos + step), load
 
 
 def chunk_window(off: int, n: int, chunk: int,
@@ -666,6 +689,7 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
     return _init_cache(cfg, slots, max_len, jnp.zeros((slots,), jnp.int32))
 
 
+@jax.named_scope("cache_write")
 def cache_insert_slot(slot_cache: KVCache, cache: KVCache,
                       slot: jnp.ndarray) -> KVCache:
     """Write a batch-1 session cache (from :func:`prefill`) into slot
@@ -681,6 +705,7 @@ def cache_insert_slot(slot_cache: KVCache, cache: KVCache,
     return out
 
 
+@jax.named_scope("cache_write")
 def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
                       upto: jnp.ndarray) -> KVCache:
     """Extract slot ``slot`` of a slot-batched cache as a batch-1 cache
@@ -713,6 +738,7 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
     return out
 
 
+@jax.named_scope("projections")
 def _rotate_slots(x: jnp.ndarray, cos: jnp.ndarray,
                   sin: jnp.ndarray) -> jnp.ndarray:
     """apply_rotary for PER-SLOT positions: cos/sin are [S, 1, 1, hd//2]
@@ -756,18 +782,21 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     pos = cache["pos"]                                         # [S]
     max_len = cache_capacity(cache)
     posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
-    x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["pos"][posm].astype(dt)
+    with jax.named_scope("embed"):
+        x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
+        if cfg.pos_emb == "learned":
+            x = x + params["embed"]["pos"][posm].astype(dt)
     if cfg.pos_emb == "rope":
-        full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
-                                           cfg.rope_base)
-        cos = full_cos[posm][:, :, None, :]                    # [S,C,1,·]
-        sin = full_sin[posm][:, :, None, :]
+        with jax.named_scope("projections"):
+            full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
+                                               cfg.rope_base)
+            cos = full_cos[posm][:, :, None, :]                # [S,C,1,·]
+            sin = full_sin[posm][:, :, None, :]
     else:
         cos = sin = None
 
     def column_writes(column):
+        @jax.named_scope("cache_write")
         def write(c_all, l, cols):                # [S, heads, width, C]
             for slot in range(s):
                 for i in reversed(range(c)):
@@ -778,7 +807,9 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         return write
 
     # mask[s, i, t]: cached position t visible to fed token i of slot s
-    mask = {"full": jnp.arange(max_len)[None, None, :] <= posm[:, :, None]}
+    with jax.named_scope("attention"):
+        mask = {"full": jnp.arange(max_len)[None, None, :]
+                <= posm[:, :, None]}
     write = {"full": column_writes(lambda p: p)}
     if "window" in cfg.kinds:
         # a ring has no end to be clamped onto: position p is column p mod
@@ -820,8 +851,7 @@ def _decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
     layers (the serve engine's fused step reads it with the tokens)."""
     x, arrays, load = _forward_slots(params, token[:, None], cache, cfg,
                                      active)
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], _unembed(params, cfg))
-    return logits.astype(jnp.float32), dict(
+    return _last_logits(params, x[:, 0], cfg), dict(
         arrays, pos=cache["pos"] + active.astype(jnp.int32)), load
 
 
@@ -849,8 +879,9 @@ def draft_propose_slots(params: Params, token: jnp.ndarray,
     def step(carry, _):
         tok, c = carry
         logits, c = decode_step_slots(params, tok, c, active, cfg)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(active, nxt, tok)
+        with jax.named_scope("head"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = jnp.where(active, nxt, tok)
         return (nxt, c), nxt
 
     (_, cache), toks = jax.lax.scan(step, (token, cache), None, length=k)
@@ -889,16 +920,18 @@ def verify_step_slots(params: Params, tokens: jnp.ndarray,
     pos = cache["pos"]                                         # [S]
     max_len = cache_capacity(cache)
     x, arrays, _ = _forward_slots(params, tokens, cache, cfg, active)
-    logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg))
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # [S, C]
-    ok = (greedy[:, :-1] == proposals).astype(jnp.int32)
-    accepted = 1 + jnp.sum(jnp.cumprod(ok, axis=1), axis=1)
-    accepted = jnp.minimum(accepted,
-                           jnp.maximum(max_len - pos, 1)).astype(jnp.int32)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg))
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, C]
+        ok = (greedy[:, :-1] == proposals).astype(jnp.int32)
+        accepted = 1 + jnp.sum(jnp.cumprod(ok, axis=1), axis=1)
+        accepted = jnp.minimum(
+            accepted, jnp.maximum(max_len - pos, 1)).astype(jnp.int32)
     adv = jnp.where(active, accepted, 0).astype(jnp.int32)
     return greedy, accepted, dict(arrays, pos=pos + adv)
 
 
+@jax.named_scope("head")
 def _sample(logits: jnp.ndarray, key: jax.Array, greedy: bool,
             temperature: jnp.ndarray, top_k: Optional[int]) -> jnp.ndarray:
     if greedy:

@@ -528,6 +528,12 @@ def norm_eps(cfg: TransformerConfig) -> float:
     return 1e-6 if cfg.norm == "rmsnorm" else 1e-5
 
 
+# Every part of the model runs under a `jax.named_scope` of its name (one
+# of `util.device_profile.MODEL_PARTS`; the innermost counts): the name is
+# in every compiled instruction's metadata, which is how a profiler trace's
+# device ops are placed in the model.  Metadata only: no instruction changes.
+
+@jax.named_scope("norm")
 def _norm(cfg, x, scale, bias):
     if cfg.norm == "rmsnorm":
         return rmsnorm(x, scale, norm_eps(cfg))
@@ -579,6 +585,7 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
     return carry
 
 
+@jax.named_scope("projections")
 def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate):
     """Normed input [b, s, d] -> (q [b, s, h, hd], k, v [b, s, hk, hd]) of
     an MHA/GQA block: the three projections, the per-head RMS norms where
@@ -595,6 +602,7 @@ def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate):
     return q, k, v
 
 
+@jax.named_scope("projections")
 def _attn_out(cfg: TransformerConfig, y: jnp.ndarray, attn: jnp.ndarray,
               lp: Params) -> jnp.ndarray:
     """Heads' output [b, s, h, hd] -> the block's [b, s, d]: gated by
@@ -653,6 +661,7 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
     return x + _post(cfg, z, lp, "post_mlp_norm"), aux
 
 
+@jax.named_scope("ffn")
 def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
     """A dense feed-forward: SwiGLU where the model gates, else GELU."""
     dt = cfg.dtype
@@ -712,36 +721,8 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Everything up to (and including) the final norm:
     tokens [b, s] → (hidden [b, s, d] in cfg.dtype, mean router aux)."""
-    b, s = tokens.shape
-    dt = cfg.dtype
-    if cfg.embed_impl == "one_hot":
-        # gather's backward is a scatter-add into [vocab, d] — serialized
-        # and slow on TPU; the one-hot formulation turns fwd AND bwd into
-        # MXU matmuls.  Chunked over tokens so the one-hot buffer peaks
-        # at [chunk, vocab] (~100 MB bf16 at vocab 50k) instead of
-        # [b*s, vocab] (~820 MB at b8/s1024) — XLA may fuse it away, but
-        # the bound must not depend on that.
-        emb = params["embed"]["tok"].astype(dt)
-        flat = tokens.reshape(-1)
-        chunk = 1024
-        if flat.size <= chunk:
-            x = jax.nn.one_hot(flat, cfg.vocab_size, dtype=dt) @ emb
-        else:
-            pad = (-flat.size) % chunk
-            chunks = jnp.pad(flat, (0, pad)).reshape(-1, chunk)
-            x = jax.lax.map(
-                lambda t: jax.nn.one_hot(t, cfg.vocab_size, dtype=dt)
-                @ emb, chunks).reshape(-1, cfg.d_model)[:flat.size]
-        x = x.reshape(b, s, cfg.d_model)
-    elif cfg.embed_impl == "gather":
-        x = params["embed"]["tok"][tokens].astype(dt)
-    else:  # a typo must not silently mean the gather path (cf. remat_policy)
-        raise ValueError(f"embed_impl={cfg.embed_impl!r}: expected "
-                         f"'gather' or 'one_hot'")
-    x = _scale_embedding(cfg, x)
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["pos"][:s].astype(dt)
-    cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
+    x = _embed(params, tokens, cfg)
+    cos, sin = (rotary_angles(tokens.shape[1], cfg.rope_dim, cfg.rope_base)
                 if cfg.pos_emb == "rope" else (None, None))
 
     policy = remat_policy(cfg.remat)
@@ -787,6 +768,44 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
     return x, aux
 
 
+@jax.named_scope("embed")
+def _embed(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
+           ) -> jnp.ndarray:
+    """tokens [b, s] -> the first layer's input [b, s, d] in cfg.dtype: the
+    token table's rows, scaled, plus the learned positions where the model
+    has them."""
+    b, s = tokens.shape
+    dt = cfg.dtype
+    if cfg.embed_impl == "one_hot":
+        # gather's backward is a scatter-add into [vocab, d] — serialized
+        # and slow on TPU; the one-hot formulation turns fwd AND bwd into
+        # MXU matmuls.  Chunked over tokens so the one-hot buffer peaks
+        # at [chunk, vocab] (~100 MB bf16 at vocab 50k) instead of
+        # [b*s, vocab] (~820 MB at b8/s1024) — XLA may fuse it away, but
+        # the bound must not depend on that.
+        emb = params["embed"]["tok"].astype(dt)
+        flat = tokens.reshape(-1)
+        chunk = 1024
+        if flat.size <= chunk:
+            x = jax.nn.one_hot(flat, cfg.vocab_size, dtype=dt) @ emb
+        else:
+            pad = (-flat.size) % chunk
+            chunks = jnp.pad(flat, (0, pad)).reshape(-1, chunk)
+            x = jax.lax.map(
+                lambda t: jax.nn.one_hot(t, cfg.vocab_size, dtype=dt)
+                @ emb, chunks).reshape(-1, cfg.d_model)[:flat.size]
+        x = x.reshape(b, s, cfg.d_model)
+    elif cfg.embed_impl == "gather":
+        x = params["embed"]["tok"][tokens].astype(dt)
+    else:  # a typo must not silently mean the gather path (cf. remat_policy)
+        raise ValueError(f"embed_impl={cfg.embed_impl!r}: expected "
+                         f"'gather' or 'one_hot'")
+    x = _scale_embedding(cfg, x)
+    if cfg.pos_emb == "learned":
+        x = x + params["embed"]["pos"][:s].astype(dt)
+    return x
+
+
 def _scale_embedding(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
     """The embedding multiplier of a model that states one."""
     if cfg.embed_scale == 1.0:
@@ -794,6 +813,7 @@ def _scale_embedding(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
     return (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
 
 
+@jax.named_scope("head")
 def _unembed(params: Params, cfg: TransformerConfig) -> jnp.ndarray:
     w = (params["embed"]["tok"].T if cfg.tie_embeddings
          else params["lm_head"])
@@ -810,8 +830,9 @@ def forward_with_aux(params: Params, tokens: jnp.ndarray,
     x, aux = _trunk(params, tokens, cfg)
     # fp32 MXU accumulation straight out of the dot — rounding the logits
     # through bf16 first would cost ~3 decimal digits on a 50k-way softmax
-    logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg),
+                            preferred_element_type=jnp.float32)
     return logits, aux
 
 
@@ -850,45 +871,48 @@ def lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
                          f"loss_chunk={cfg.loss_chunk}")
     if cfg.loss_chunk:
         x, aux = _trunk(params, tokens, cfg)
-        w_out = _unembed(params, cfg)
-        # target for the LAST position is a dummy masked to weight 0
-        targets = jnp.concatenate(
-            [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
-        valid = jnp.concatenate(
-            [jnp.ones((b, s - 1), jnp.float32),
-             jnp.zeros((b, 1), jnp.float32)], axis=1)
-        if mask is not None:
-            shifted = jnp.concatenate(
-                [mask[:, 1:], jnp.zeros((b, 1), mask.dtype)], axis=1)
-            valid = valid * shifted.astype(jnp.float32)
-        n = s // cfg.loss_chunk
-        xc = jnp.swapaxes(x.reshape(b, n, cfg.loss_chunk, -1), 0, 1)
-        tc = jnp.swapaxes(targets.reshape(b, n, cfg.loss_chunk), 0, 1)
-        vc = jnp.swapaxes(valid.reshape(b, n, cfg.loss_chunk), 0, 1)
+        with jax.named_scope("head"):
+            w_out = _unembed(params, cfg)
+            # target for the LAST position is a dummy masked to weight 0
+            targets = jnp.concatenate(
+                [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
+            valid = jnp.concatenate(
+                [jnp.ones((b, s - 1), jnp.float32),
+                 jnp.zeros((b, 1), jnp.float32)], axis=1)
+            if mask is not None:
+                shifted = jnp.concatenate(
+                    [mask[:, 1:], jnp.zeros((b, 1), mask.dtype)], axis=1)
+                valid = valid * shifted.astype(jnp.float32)
+            n = s // cfg.loss_chunk
+            xc = jnp.swapaxes(x.reshape(b, n, cfg.loss_chunk, -1), 0, 1)
+            tc = jnp.swapaxes(targets.reshape(b, n, cfg.loss_chunk), 0, 1)
+            vc = jnp.swapaxes(valid.reshape(b, n, cfg.loss_chunk), 0, 1)
 
-        def chunk_sum(xi, ti, vi):
-            logits = jnp.einsum("bcd,dv->bcv", xi, w_out,
-                                preferred_element_type=jnp.float32)
-            ls = optax.softmax_cross_entropy_with_integer_labels(logits, ti)
-            return (ls * vi).sum()
+            def chunk_sum(xi, ti, vi):
+                logits = jnp.einsum("bcd,dv->bcv", xi, w_out,
+                                    preferred_element_type=jnp.float32)
+                ls = optax.softmax_cross_entropy_with_integer_labels(
+                    logits, ti)
+                return (ls * vi).sum()
 
-        def body(acc, inp):
-            xi, ti, vi = inp
-            return acc + jax.checkpoint(chunk_sum)(xi, ti, vi), None
+            def body(acc, inp):
+                xi, ti, vi = inp
+                return acc + jax.checkpoint(chunk_sum)(xi, ti, vi), None
 
-        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                                (xc, tc, vc))
-        return total / jnp.maximum(valid.sum(), 1.0) + aux_weight * aux
+            total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                                    (xc, tc, vc))
+            return total / jnp.maximum(valid.sum(), 1.0) + aux_weight * aux
 
     logits, aux = forward_with_aux(params, tokens, cfg)
-    logits = logits[:, :-1]
-    targets = tokens[:, 1:]
-    losses = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
-    aux_term = aux_weight * aux
-    if mask is not None:
-        m = mask[:, 1:].astype(jnp.float32)
-        return (losses * m).sum() / jnp.maximum(m.sum(), 1.0) + aux_term
-    return losses.mean() + aux_term
+    with jax.named_scope("head"):
+        logits = logits[:, :-1]
+        losses = optax.softmax_cross_entropy_with_integer_labels(
+            logits, tokens[:, 1:])
+        aux_term = aux_weight * aux
+        if mask is not None:
+            m = mask[:, 1:].astype(jnp.float32)
+            return (losses * m).sum() / jnp.maximum(m.sum(), 1.0) + aux_term
+        return losses.mean() + aux_term
 
 
 def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
@@ -940,31 +964,44 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
                 else:
                     count = jnp.float32(micro
                                         * (mb["tokens"].shape[1] - 1))
-                gsum = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32) * count,
-                    gsum, grads)
-                return (gsum, lsum + loss * count, csum + count), None
+                with jax.named_scope("optimizer"):
+                    gsum = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(jnp.float32) * count,
+                        gsum, grads)
+                    return (gsum, lsum + loss * count, csum + count), None
 
-            zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            with jax.named_scope("optimizer"):
+                zeros = jax.tree_util.tree_map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (gsum, lsum, csum), _ = jax.lax.scan(
                 micro_step, (zeros, jnp.float32(0.0), jnp.float32(0.0)),
                 mbatch)
-            csum = jnp.maximum(csum, 1.0)
-            # back to the dtype grad_fn itself produces (param dtype) so
-            # optimizer state dtypes — and therefore buffer donation —
-            # match the accum_steps=1 path
-            grads = jax.tree_util.tree_map(
-                lambda g, p: (g / csum).astype(p.dtype), gsum, params)
-            loss = lsum / csum
+            with jax.named_scope("optimizer"):
+                csum = jnp.maximum(csum, 1.0)
+                # back to the dtype grad_fn itself produces (param dtype)
+                # so optimizer state dtypes — and therefore buffer
+                # donation — match the accum_steps=1 path
+                grads = jax.tree_util.tree_map(
+                    lambda g, p: (g / csum).astype(p.dtype), gsum, params)
+                loss = lsum / csum
         else:
             loss, grads = grad_fn(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree_util.tree_leaves(grads)))
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree_util.tree_leaves(grads)))
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
+    # in the compile ledger by its name alone: the op map of every
+    # executable JAX loads for a jit of ``step``, and no timing shim (a
+    # sampled ``block_until_ready`` would empty a loop that keeps steps in
+    # flight).  The function itself is returned and gets NO attribute:
+    # `jax.jit` copies a function's ``__dict__`` onto what it returns, so
+    # a ``step.lower`` here would stand in for the caller's jit's own and
+    # lower the step without its shardings and donation.
+    from ..util.device_profile import watch
+    watch("train_step", step)
     return step
 
 

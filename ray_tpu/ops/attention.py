@@ -106,6 +106,7 @@ def _flash_per_shard(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
+@jax.named_scope("attention")
 def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          causal: bool = True,
                          sm_scale: Optional[float] = None,

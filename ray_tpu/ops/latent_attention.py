@@ -40,6 +40,7 @@ from .norms import rmsnorm
 Rotate = Callable[[jnp.ndarray], jnp.ndarray]   # [b, s, heads, rope] -> same
 
 
+@jax.named_scope("projections")
 def queries(y: jnp.ndarray, wq_a, q_norm, wq_b, *, nope: int, eps: float,
             rotate: Rotate) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Normed input ``y`` [b, s, d] -> (q_nope [b, s, h, nope], q_rope
@@ -50,6 +51,7 @@ def queries(y: jnp.ndarray, wq_a, q_norm, wq_b, *, nope: int, eps: float,
     return q[..., :nope], rotate(q[..., nope:])
 
 
+@jax.named_scope("projections")
 def latents(y: jnp.ndarray, wkv_a, kv_norm, *, kv_lora: int, eps: float,
             rotate: Rotate) -> jnp.ndarray:
     """Normed input ``y`` [b, s, d] -> [b, s, kv_lora + rope]: the normed
@@ -62,6 +64,7 @@ def latents(y: jnp.ndarray, wkv_a, kv_norm, *, kv_lora: int, eps: float,
     return jnp.concatenate([c, k_r], axis=-1)
 
 
+@jax.named_scope("attention")
 def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                  latent: jnp.ndarray, wkv_b, wo, *, causal: bool = True,
                  impl: str = "auto") -> jnp.ndarray:
@@ -70,7 +73,9 @@ def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     dt = q_nope.dtype
     nope, rope = q_nope.shape[-1], q_rope.shape[-1]
     kv_lora, h = wkv_b.shape[0], wkv_b.shape[1]
-    kv = jnp.einsum("bsr,rhk->bshk", latent[..., :kv_lora], wkv_b.astype(dt))
+    with jax.named_scope("projections"):
+        kv = jnp.einsum("bsr,rhk->bshk", latent[..., :kv_lora],
+                        wkv_b.astype(dt))
     k_r = jnp.broadcast_to(latent[:, :, None, kv_lora:],
                            latent.shape[:2] + (h, rope))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -80,9 +85,11 @@ def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
         impl = "reference"      # the flash kernel has one head size
     attn = multi_head_attention(q, k, v, causal=causal, impl=impl,
                                 sm_scale=1.0 / math.sqrt(nope + rope))
-    return jnp.einsum("bshk,hkd->bsd", attn, wo.astype(dt))
+    with jax.named_scope("projections"):
+        return jnp.einsum("bshk,hkd->bsd", attn, wo.astype(dt))
 
 
+@jax.named_scope("attention")
 def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                     cached: jnp.ndarray, wkv_b, wo, mask: jnp.ndarray
                     ) -> jnp.ndarray:
@@ -104,4 +111,5 @@ def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     probs = jax.nn.softmax(scores, axis=-1)
     o_lat = jnp.einsum("bsht,brt->bshr", probs.astype(dt), cached.astype(dt))
     attn = jnp.einsum("bshr,rhv->bshv", o_lat[..., :kv_lora], w[..., nope:])
-    return jnp.einsum("bshv,hvd->bsd", attn, wo.astype(dt))
+    with jax.named_scope("projections"):
+        return jnp.einsum("bshv,hvd->bsd", attn, wo.astype(dt))

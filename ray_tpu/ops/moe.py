@@ -57,6 +57,7 @@ class Load(NamedTuple):
     pairs: jnp.ndarray
 
 
+@jax.named_scope("experts")
 def sigmoid_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
                   top_k: int, scaling: float = 1.0
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -74,6 +75,7 @@ def sigmoid_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
     return idx.astype(jnp.int32), w
 
 
+@jax.named_scope("experts")
 def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
                w_in: jnp.ndarray, w_out: jnp.ndarray,
                w_gate: Optional[jnp.ndarray] = None,
@@ -138,6 +140,7 @@ def expert_capacity(seq_tokens: int, n_experts: int, top_k: int,
     return max(8, ((cap + 7) // 8) * 8)
 
 
+@jax.named_scope("experts")
 def route(y: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
           capacity: int):
     """Compute dispatch/combine tensors.
@@ -174,6 +177,7 @@ def route(y: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
     return dispatch, combine, aux
 
 
+@jax.named_scope("experts")
 def moe_ffn(y: jnp.ndarray, router_w: jnp.ndarray, w_in: jnp.ndarray,
             w_out: jnp.ndarray, w_gate: Optional[jnp.ndarray] = None, *,
             top_k: int = 2, capacity_factor: float = 2.0,

@@ -8,9 +8,11 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("projections")
 def rotary_angles(seq_len: int, head_dim: int, base: float = 10000.0,
                   offset: int = 0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(cos, sin) tables of shape [seq_len, head_dim//2]."""
@@ -21,6 +23,7 @@ def rotary_angles(seq_len: int, head_dim: int, base: float = 10000.0,
     return jnp.cos(angles), jnp.sin(angles)
 
 
+@jax.named_scope("projections")
 def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray,
                  sin: jnp.ndarray) -> jnp.ndarray:
     """Rotate [batch, seq, heads, head_dim] by per-position angles.

@@ -28,9 +28,11 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("conv")
 def conv_inputs(y: jnp.ndarray, w_in: jnp.ndarray
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Normed input ``y`` [b, s, d] -> (``u`` = B * X, the convolution's
@@ -40,6 +42,7 @@ def conv_inputs(y: jnp.ndarray, w_in: jnp.ndarray
     return b * x, c
 
 
+@jax.named_scope("conv")
 def short_conv(u: jnp.ndarray, w: jnp.ndarray,
                state: Optional[jnp.ndarray] = None,
                n_new: Optional[jnp.ndarray] = None
@@ -69,6 +72,7 @@ def short_conv(u: jnp.ndarray, w: jnp.ndarray,
     return v.astype(u.dtype), carry
 
 
+@jax.named_scope("conv")
 def conv_block(y: jnp.ndarray, w_in: jnp.ndarray, w: jnp.ndarray,
                w_out: jnp.ndarray, state: Optional[jnp.ndarray] = None,
                n_new: Optional[jnp.ndarray] = None

@@ -301,7 +301,10 @@ def cmd_timeline(args) -> None:
     process, merged by the controller) as Chrome-trace JSON; with
     ``--session-dir``, the timeline of a session that is no longer up,
     merged from the span files its processes left under
-    ``<dir>/spans/`` when they exited."""
+    ``<dir>/spans/`` when they exited.  A ``program:compiled`` span
+    (category ``setup``) marks each op map the compile ledger left in
+    ``<dir>/programs/``: what places a profiler trace's device ops in
+    the model (README, Observability)."""
     path = args.output or "timeline.json"
     if args.session_dir:
         from ray_tpu.util import tracing

@@ -222,16 +222,18 @@ class ContinuousBatchingEngine:
                 tok = tok[:slots]     # the last step's counts ride behind
             logits, cache, load = _decode_step_slots(params, tok, cache,
                                                      active, cfg)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out = jnp.where(active, nxt, tok)
-            if moe_layers:
-                out = jnp.concatenate([out, jnp.stack(load)])
+            with jax.named_scope("head"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out = jnp.where(active, nxt, tok)
+                if moe_layers:
+                    out = jnp.concatenate([out, jnp.stack(load)])
             return out, cache
 
         # ---- dispatch profiler (util/device_profile.py) ----
         # every jitted program below goes through a wrap-once timing
         # shim: dispatch counts, sampled device time, and the compile
-        # ledger (first-seen argument shapes) per program.  Snapshots
+        # ledger (first-seen argument shapes, and each compiled
+        # program's op map for the traces) per program.  Snapshots
         # ride _maybe_push_metrics to the nodelet fold.
         from ..util.device_profile import DispatchProfiler
         self._prof = DispatchProfiler()

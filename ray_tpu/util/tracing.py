@@ -15,7 +15,12 @@ controller, nodelet, worker) marks an interval:
   ``state.timeline()`` merges every process's spans into one Chrome-trace
   JSON, and at exit each process writes its ring to
   ``<session_dir>/spans/<kind>-<pid>.json`` so a finished session keeps
-  its timeline (``ray-tpu timeline --session-dir``).
+  its timeline (``ray-tpu timeline --session-dir``).  Beside it go the
+  **op maps** of the programs the process compiled
+  (``<session_dir>/programs/<kind>-<pid>.<program>.json``, from
+  `util/device_profile.py`'s compile ledger: instruction name ->
+  ``op_name`` path, what places a profiler trace's device ops in the
+  model), each marked on the timeline by one ``program:compiled`` span.
 
 * **The profiler's host plane** — where JAX is already imported in the
   process, ``span`` also enters ``jax.profiler.TraceAnnotation(name)``:
@@ -36,7 +41,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 from ..core.config import GlobalConfig
 
@@ -89,6 +94,8 @@ _reship = False               # the controller lost our history
 _recorded = 0                 # spans ever recorded here
 _filed = -1                   # `_recorded` when the span file was written
 _proc = {"kind": "proc", "node": ""}
+_programs: Dict[str, List[dict]] = {}   # program -> its op maps, one a shape
+_unmapped: List[tuple] = []             # (program, make, t0, seconds) owed
 _flusher_claimed = False
 
 
@@ -262,28 +269,89 @@ def span_file(session_dir: str) -> str:
                         f"{_proc['kind']}-{os.getpid()}.json")
 
 
-def write_span_file(session_dir: Optional[str]) -> Optional[str]:
-    """Write this process's ring to its file under ``<session_dir>/spans``
-    (called where a process makes its final flush).  Skipped when nothing
-    was recorded since the last write.  Never raises: the process is on
-    its way out."""
-    global _filed
-    if not session_dir:
-        return None
+def record_program(program: str, make: Callable[[], dict], t0: float,
+                   seconds: float) -> None:
+    """Keep one executable JAX loaded for ``program`` at ``t0``, for this
+    process's program files.  ``make()`` gives its op map
+    (`device_profile.op_map` over the executable's text, which took
+    ``seconds`` to take): it is called where the maps are read or written
+    (`program_maps`, `write_span_file`) and NOT here, because the caller
+    stands inside a compile of a program's warm-up and the map of a large
+    program takes tenths of a second."""
     with _span_lock:
-        if _recorded == _filed or _ring is None:
-            return None
-        events, _filed = _ring.events(), _recorded
-    path = span_file(session_dir)
+        _unmapped.append((program, make, t0, seconds))
+
+
+def _map_pending() -> None:
+    """Make the op maps still owed, each marked by its ``program:compiled``
+    span at the time of its compile; an executable JAX loaded again (the
+    same module and shape) adds nothing."""
+    with _span_lock:
+        todo = list(_unmapped)
+        del _unmapped[:]
+    for program, make, t0, seconds in todo:
+        began = time.time()
+        try:
+            entry = make()
+        except Exception:
+            continue
+        seconds += time.time() - began
+        with _span_lock:
+            maps = _programs.setdefault(program, [])
+            if any((m["module"], m["shape"])
+                   == (entry["module"], entry["shape"]) for m in maps):
+                continue
+            maps.append(entry)
+        record_span("program:compiled", "setup", t0, t0 + seconds,
+                    program=program, module=entry["module"],
+                    instructions=len(entry["instructions"]),
+                    named=entry["named"], seconds=round(seconds, 4))
+
+
+def program_maps() -> Dict[str, List[dict]]:
+    """program -> the op maps of this process's programs, oldest first."""
+    _map_pending()
+    with _span_lock:
+        return {p: list(maps) for p, maps in _programs.items()}
+
+
+def program_file(session_dir: str, program: str) -> str:
+    return os.path.join(session_dir, "programs",
+                        f"{_proc['kind']}-{os.getpid()}.{program}.json")
+
+
+def _write_json(path: str, body: Any) -> bool:
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.tmp"
         with open(tmp, "w") as f:
-            json.dump(events, f)
+            json.dump(body, f)
         os.replace(tmp, path)
     except (OSError, TypeError, ValueError):
+        return False
+    return True
+
+
+def write_span_file(session_dir: Optional[str]) -> Optional[str]:
+    """Write this process's ring to its file under ``<session_dir>/spans``
+    and its programs' op maps to ``<session_dir>/programs`` (called where
+    a process makes its final flush).  Skipped when nothing was recorded
+    since the last write (a map comes with a span).  Never raises: the
+    process is on its way out."""
+    global _filed
+    if not session_dir:
         return None
-    return path
+    _map_pending()
+    with _span_lock:
+        if _recorded == _filed or _ring is None:
+            return None
+        events, _filed = _ring.events(), _recorded
+        programs = {p: list(maps) for p, maps in _programs.items()}
+    for program, maps in programs.items():
+        _write_json(program_file(session_dir, program),
+                    {"program": program, "maps": maps})
+    path = span_file(session_dir)
+    return path if _write_json(path, events) else None
 
 
 def write_span_file_on_sigterm(session_dir: str) -> None:

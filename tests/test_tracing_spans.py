@@ -21,7 +21,8 @@ def fresh_ring(monkeypatch):
     """The module's ring state, emptied for one test and put back."""
     for name, value in (("_ring", None), ("_pending", []),
                         ("_reship", False), ("_recorded", 0),
-                        ("_filed", -1)):
+                        ("_filed", -1), ("_programs", {}),
+                        ("_unmapped", [])):
         monkeypatch.setattr(tracing, name, value)
     return tracing
 
