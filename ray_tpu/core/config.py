@@ -430,11 +430,6 @@ _d("flight_recorder_min_interval_s", float, 5.0,
    "Per-trigger rate limit between automatic captures (a flapping link "
    "must not turn the recorder into its own incident); manual "
    "`ray-tpu debug capture` bypasses it.")
-_d("device_profile_sample_every", int, 10,
-   "The dispatch profiler block-until-readys every Nth dispatch of each "
-   "jitted program to sample true device time (util/device_profile.py); "
-   "the other N-1 dispatches stay fully async so the hot loop stays "
-   "hot.  1 = sync every dispatch (tests).")
 _d("device_profile_peak_flops", float, 0.0,
    "Per-device peak FLOP/s for the profiler's MFU denominator; 0 = "
    "auto (TPU spec-sheet table by device kind, nominal fallback on "

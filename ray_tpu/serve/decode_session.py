@@ -25,6 +25,15 @@ compiles a whole-prompt prefill program: the whole-prompt reference
 (`models.generate`) is the tests' oracle, and shares no code with the
 programs below.
 
+The loop keeps ONE fused step in flight beyond the one whose tokens it
+has read: step n+1 is dispatched from the device-resident carry (the
+step's output feeds the next step, sampling is the argmax inside the
+program, every live slot's position advances by one and which slots are
+live is the host's own knowledge), THEN step n is read and published.
+The chip works while the host reads, publishes and schedules; only token
+VALUES lag the host by a step.  A speculating engine forms its next
+input on the host from what it accepted, so it reads every step at once.
+
 Two model-side optimisations compound inside the loop:
 
 - **Chunked-prefill admission**: a joining session's prompt is
@@ -62,7 +71,7 @@ import functools
 import threading
 import time
 import weakref
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from .config import DecodeEngineConfig
 
@@ -128,7 +137,7 @@ class _EngineSession:
     the shared batched cache)."""
 
     __slots__ = ("sid", "slot", "queue", "first_tok", "last_tok", "pos",
-                 "done", "error", "ended", "seq", "last_poll",
+                 "unread", "done", "error", "ended", "seq", "last_poll",
                  "prompt", "poff", "pcache", "dcache", "plogits",
                  "ready", "shed", "ptoks", "rid", "t_enq", "t_pf",
                  "t_ready")
@@ -152,7 +161,10 @@ class _EngineSession:
         # step may already have moved on by the time the caller wakes
         self.first_tok: Optional[int] = None
         self.last_tok: Optional[int] = None
-        self.pos = 0                  # host mirror of cache pos
+        # host mirror of cache pos, as of the last step DISPATCHED; of
+        # the tokens dispatched, those the host has not read yet
+        self.pos = 0
+        self.unread = 0
         self.done = False             # no more tokens will be produced
         self.error: Optional[str] = None
         self.ended = False            # client sent `end`
@@ -170,6 +182,22 @@ class _EngineSession:
         self.plogits: Any = None      # last chunk's final-position logits
         self.ready = False            # first token produced; start() may return
         self.shed = False             # drained mid-admission: typed 503
+
+
+class _Step(NamedTuple):
+    """A fused step dispatched and not read yet."""
+    batch: List[Tuple[_EngineSession, int]]   # its live sessions, each
+    #                                           with the slot it held THEN
+    out: Any          # [slots (+3)] int32 on the device: tokens (+ routing)
+    rows: Tuple[int, int]     # `_rows_of` its batch
+    # when the chip started on it, where the host can tell: a
+    # ``perf_counter`` reading (nothing was queued before it), `_BEHIND`
+    # (queued right behind the step before it: when that one's read
+    # returns, if that read has to wait), None (other programs between)
+    start: Optional[float]
+
+
+_BEHIND = -1.0
 
 
 class ContinuousBatchingEngine:
@@ -249,6 +277,12 @@ class ContinuousBatchingEngine:
         self._insert = self._prof.wrap(
             "cache_insert", self._counting_copies(
                 jax.jit(cache_insert_slot, donate_argnums=(0,)), 0))
+        # a joining slot's first token into the device-resident carry:
+        # ``firsts`` is -1 wherever the carry stays (a token id is never
+        # negative).  The carry is not donated: it may be the output of
+        # the step in flight, which the host has yet to read.
+        self._join = jax.jit(
+            lambda carry, firsts: jnp.where(firsts >= 0, firsts, carry))
         # ---- shared-prefix KV reuse ----
         # radix trie over live slots' prompts (serve/prefix_cache.py):
         # admission copies the longest shared prefix out of a donor
@@ -368,6 +402,19 @@ class ContinuousBatchingEngine:
         # `_MOE_SPAN_S` seconds with the sums since the last
         self.rows = dict.fromkeys(("steps", "rows_read", "rows_if_full"), 0)
         self._rows_span = dict(self.rows, t=time.time())
+        # fused steps dispatched, and of them those dispatched while the
+        # step before them had not been read: `stats()["steps_ahead"]`
+        # and one ring span `engine:ahead` every `_MOE_SPAN_S` seconds
+        self.ahead = dict.fromkeys(("steps", "steps_ahead"), 0)
+        self._ahead_span = dict(self.ahead, t=time.time())
+        # ---- the engine thread's own, between iterations ----
+        self._carry: Any = None       # the last step's output, on the device
+        self._flight: Optional[_Step] = None   # dispatched, not read
+        self._active_dev: Any = None  # the live-slot mask on the device
+        self._active_key = b""        # ... and what it was made from
+        # when the newest read returned, and whether it had to wait for
+        # the chip (then that is when its step ended)
+        self._read_end, self._read_waited = 0.0, False
         # slot -> the session whose prompt the prefix index advertises
         # there (its `pos` is how far that slot's rings have moved on)
         self._donors: Dict[int, _EngineSession] = {}
@@ -571,6 +618,9 @@ class ContinuousBatchingEngine:
                     "draining": self._draining,
                     "reaped": self.reaped,
                     "steps": self.steps, "tokens": self.tokens,
+                    # fused steps dispatched before the step ahead of
+                    # them was read (0 for a speculating engine)
+                    "steps_ahead": self.ahead["steps_ahead"],
                     "prefill_chunks": self.prefill_chunks,
                     # the rows of each (`prefill_chunk_width`)
                     "prefill_chunk_tokens":
@@ -713,6 +763,7 @@ class ContinuousBatchingEngine:
             if self._shutdown:
                 self.params = self._draft_params = None
                 self._cache = self._dcache = None
+                self._carry = self._flight = self._active_dev = None
 
     def _reap_locked(self) -> None:
         """Vacate slots of ended/finished sessions (between steps), and
@@ -771,15 +822,16 @@ class ContinuousBatchingEngine:
         return admitted
 
     def _collect_locked(self) -> List[_EngineSession]:
-        """Slots decoding THIS step: live sessions with queue room.
-        A draining engine stops stepping — every live session is being
-        handed to a healthy replica, and the replay there regenerates
-        anything this engine would have decoded."""
+        """Slots decoding THIS step: live sessions with a position left
+        in the cache and queue room, the token of a step still in flight
+        counted.  A draining engine stops stepping — every live session
+        is being handed to a healthy replica, and the replay there
+        regenerates anything this engine would have decoded."""
         if self._draining:
             return []
         return [s for s in self._slots.values()
-                if not s.done and
-                len(s.queue) < self.ecfg.token_queue_depth]
+                if not s.done and s.pos < self.max_len and
+                len(s.queue) + s.unread < self.ecfg.token_queue_depth]
 
     def _maybe_push_metrics(self, force: bool = False) -> None:
         """Fire-and-forget occupancy/waiting/prefix sample to this
@@ -952,6 +1004,19 @@ class ContinuousBatchingEngine:
         return int(jnp.argmax(sess.plogits, axis=-1)
                    .astype(jnp.int32)[0])
 
+    def _chaos_site(self, site: str, fi) -> None:
+        """Chaos site ``serve.<what>``: an armed rule sleeps here
+        (``delay``) or raises."""
+        act = None if fi.ACTIVE is None else fi.ACTIVE.point(
+            site, self.name)
+        if act is None:
+            return
+        if act["action"] in ("delay", "latency"):
+            time.sleep(max(0.0, act["delay_s"]))
+        else:
+            raise RuntimeError(f"chaos: injected {site[6:]} failure for "
+                               f"{self.name}")
+
     def _spec_step(self, tokens, active, fi):
         """One speculative iteration over the whole batch: the draft
         proposes ``spec_k`` tokens per slot in one scanned dispatch and
@@ -963,15 +1028,7 @@ class ContinuousBatchingEngine:
         import numpy as np
 
         import jax.numpy as jnp
-        if fi.ACTIVE is not None:
-            act = fi.ACTIVE.point("serve.spec_verify", self.name)
-            if act is not None:
-                if act["action"] in ("delay", "latency"):
-                    time.sleep(max(0.0, act["delay_s"]))
-                else:
-                    raise RuntimeError(
-                        f"chaos: injected spec_verify failure for "
-                        f"{self.name}")
+        self._chaos_site("serve.spec_verify", fi)
         tok_dev = jnp.asarray(tokens)
         active_dev = jnp.asarray(active)
         # the draft cache's pos is re-synced from the target every
@@ -1004,8 +1061,6 @@ class ContinuousBatchingEngine:
     def _loop(self) -> None:
         import numpy as np
 
-        import jax.numpy as jnp
-
         from ..models import init_slot_cache
         from ..util import fault_injection as fi
         from ..util import tracing
@@ -1017,11 +1072,11 @@ class ContinuousBatchingEngine:
                     self._draft_cfg, self.ecfg.max_slots, self.max_len)
         slots = self.ecfg.max_slots
         # a step's routing counts ride behind its tokens (`fused_step`):
-        # the host's row is as long, so both ways in are one shape
+        # the host's row is as long, so both ways in are one shape.  Only
+        # a speculating engine keeps the row current (it reads every step
+        # at once); the plain engine's carry lives on the device
         tokens = np.zeros(slots + (3 if self._moe_layers else 0), np.int32)
-        tok_dev = None       # device-resident step output → next input
-        active_dev = None
-        active_key: Any = None
+        self._carry = self._fresh_carry()
 
         def phase(name: str):
             """One of the engine thread's flat, non-overlapping phases:
@@ -1049,8 +1104,10 @@ class ContinuousBatchingEngine:
                         active = np.zeros(self.ecfg.max_slots, bool)
                         for s in batch:
                             active[s.slot] = True
-                            tokens[s.slot] = s.last_tok
-                    if admitted or prefills or batch:
+                            if self._spec:
+                                tokens[s.slot] = s.last_tok
+                    if admitted or prefills or batch \
+                            or self._flight is not None:
                         break
                     self._cond.wait(0.5)
                 if self._shutdown:
@@ -1061,16 +1118,13 @@ class ContinuousBatchingEngine:
             if admitted or prefills:
                 with phase("admit"):
                     self._admit_and_prefill(admitted, prefills)
-            if not batch:
-                continue          # admissions/prefill only: step next round
             spec_out = None
-            if self._spec and not self._spec_disabled:
+            if batch and self._spec and not self._spec_disabled:
                 try:
                     with phase("dispatch"):   # and its own two reads
                         spec_out = self._spec_step(tokens[:slots], active,
                                                    fi)
                     self._spec_fail_streak = 0
-                    tok_dev = None   # host owns the carry again
                 except Exception as e:
                     with self._cond:   # stats() reads these
                         self.spec_fallbacks += 1
@@ -1082,36 +1136,126 @@ class ContinuousBatchingEngine:
                         f"serve_spec_fallback::{self.name}", "serve",
                         t0, time.time(), error=repr(e),
                         deployment=self.name)
-                    tok_dev = None   # degrade to the plain step below
-            if spec_out is None:
-                try:
+                self._carry = None   # the host owns the carry again: a
+                #                      failed iteration degrades to the
+                #                      plain step below
+            step = new_toks = None
+            try:
+                if batch and spec_out is None:
                     with phase("dispatch"):
-                        if admitted or tok_dev is None or \
-                                active_key != tuple(active):
-                            # membership changed: re-upload the [S]
-                            # token/mask rows; on a steady batch the
-                            # step output feeds the next step from
-                            # device memory
-                            tok_dev = jnp.asarray(tokens)
-                            active_dev = jnp.asarray(active)
-                            active_key = tuple(active)
-                        tok_dev, self._cache = self._step(
-                            self.params, tok_dev, self._cache,
-                            active_dev, cfg=self.cfg)
-                        self._shape_seen("decode_step", slots)
+                        step = self._dispatch(
+                            batch, active, tokens, admitted,
+                            alone=not (admitted or prefills))
+                if not self._spec:
+                    # ONE STEP AHEAD: what is read now is the step before
+                    # the one just queued, and the chip works on through
+                    # the read, the publish and the next schedule.  (A
+                    # speculating engine makes its next input on the host
+                    # from this step's tokens: it reads what it queued.)
+                    step, self._flight = self._flight, step
+                if step is not None:
                     with phase("readback"):
-                        new_toks = np.asarray(tok_dev)
-                        tokens[:] = new_toks
+                        new_toks = self._read(step, fi)
+                        if self._spec:
+                            tokens[:] = new_toks
                     if self._moe_layers:
                         self._count_moe(new_toks[slots:])
-                    self._count_rows(batch)
-                except Exception as e:
-                    self._fail_slots(f"decode engine step failed: {e!r}")
-                    tok_dev = None
-                    continue
-            with phase("publish"):
-                self._publish(batch, tokens, spec_out,
-                              None if spec_out is not None else new_toks)
+                    self._count_rows(step.rows)
+            except Exception as e:
+                # seen at the dispatch or one read late: either way the
+                # step queued behind the one that raised goes with it
+                self._fail_slots(f"decode engine step failed: {e!r}")
+                continue
+            if spec_out is not None:
+                with phase("publish"):
+                    self._publish(batch, tokens, spec_out, None)
+            elif step is not None:
+                with phase("publish"):
+                    self._publish(step.batch, tokens, None, new_toks)
+
+    def _fresh_carry(self):
+        """The plain engine's carry before any step: zeros on the device
+        (a joining slot's first token is written into it).  A speculating
+        engine's is the host's row, uploaded when a plain step needs it."""
+        import jax.numpy as jnp
+        if self._spec:
+            return None
+        return jnp.zeros(self.ecfg.max_slots
+                         + (3 if self._moe_layers else 0), jnp.int32)
+
+    def _dispatch(self, batch, active, tokens, admitted, alone: bool
+                  ) -> _Step:
+        """Queue one fused step over ``batch`` and keep, at DISPATCH
+        time, what the host knows of it: each live slot's position moves
+        on by one and one more of its tokens is unread, so the next
+        schedule (``max_len`` ends, the queue bound, `_prefix_exact`, the
+        donors' positions) sees the cache as the chip will leave it.
+        Only the token VALUES come later, with the read.
+
+        A change of membership drains nothing: a slot that left or
+        paused is a new ``active`` mask (its carry entry stays put,
+        `fused_step` passes an idle slot's token through), a slot that
+        joined (``admitted`` this turn) is its first token, a host-known
+        int, written into the carry behind its `cache_insert_slot`.  ``alone``: no other
+        program was queued since the last step (the device-time sample
+        needs to know what ran between two reads)."""
+        import numpy as np
+
+        import jax.numpy as jnp
+        key = active.tobytes()
+        if self._spec:
+            # the host's row is current (every step is read at once):
+            # re-upload it on a membership change, as ever
+            if admitted or self._carry is None \
+                    or key != self._active_key:
+                self._carry = jnp.asarray(tokens)
+        elif admitted:
+            firsts = np.full(len(tokens), -1, np.int32)
+            for sess, _, _, slot in admitted:
+                firsts[slot] = sess.last_tok
+            self._carry = self._join(self._carry, firsts)
+        if key != self._active_key:
+            self._active_dev, self._active_key = jnp.asarray(active), key
+        rows = self._rows_of(batch)    # at the positions BEFORE this step
+        flight = self._flight
+        with self._cond:
+            for s in batch:
+                s.pos += 1
+                s.unread += 1
+            self.ahead["steps"] += 1
+            self.ahead["steps_ahead"] += flight is not None
+        self._ahead_span = self._sums_span(
+            "engine:ahead", "ahead", self.ahead, self._ahead_span)
+        start = None
+        if alone:
+            start = time.perf_counter() \
+                if flight is None or flight.out.is_ready() else _BEHIND
+        self._carry, self._cache = self._step(
+            self.params, self._carry, self._cache, self._active_dev,
+            cfg=self.cfg)
+        self._shape_seen("decode_step", self.ecfg.max_slots)
+        return _Step([(s, s.slot) for s in batch], self._carry, rows,
+                     start)
+
+    def _read(self, step: _Step, fi):
+        """``step``'s tokens (and routing counts) on the host.  Where the
+        read has to wait for the chip and the host can tell when the chip
+        started on the step, the time between is one step's device time:
+        the profiler's sample, taken where the loop waits anyway.  A
+        device fault surfaces here, one read after its dispatch (chaos
+        site ``serve.decode_step``)."""
+        import numpy as np
+        self._chaos_site("serve.decode_step", fi)
+        waited = not step.out.is_ready()
+        new_toks = np.asarray(step.out)
+        now = time.perf_counter()
+        start = step.start
+        if start == _BEHIND:
+            start = self._read_end if self._read_waited else None
+        if waited and start is not None:
+            self._prof.note_device_seconds("decode_step", now - start)
+        self._read_end, self._read_waited = now, waited
+        return new_toks
 
     _MOE_SPAN_S = 2.0
 
@@ -1130,21 +1274,26 @@ class ContinuousBatchingEngine:
             "moe:load", "moe", self.moe, self._moe_span,
             layers=self._moe_layers, experts=self.cfg.n_experts_held)
 
-    def _count_rows(self, batch) -> None:
-        """The cache rows one decode step's live slots attended (each at
-        its position before the step, its own new row included; a conv
-        layer reads the ``conv_kernel - 1`` rows of its state whatever the
-        position), and the sums since the last `cache:rows` span into the
-        next when due."""
+    def _rows_of(self, batch) -> Tuple[int, int]:
+        """The cache rows the live slots of a decode step about to be
+        dispatched attend (each at its position before the step, its own
+        new row included; a conv layer reads the ``conv_kernel - 1`` rows
+        of its state whatever the position), beside what they would
+        attend were every layer a full one."""
         full = self.cfg.n_layers - self._window_layers - self._conv_layers
         depth = sum(s.pos + 1 for s in batch)
         seen = sum(min(s.pos + 1, self._window) for s in batch)
+        return (full * depth + self._window_layers * seen
+                + self._conv_layers * (self.cfg.conv_kernel - 1)
+                * len(batch), self.cfg.n_layers * depth)
+
+    def _count_rows(self, rows: Tuple[int, int]) -> None:
+        """A read step's `_rows_of` into the counters, and the sums since
+        the last `cache:rows` span into the next when due."""
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
-            self.rows["rows_read"] += full * depth \
-                + self._window_layers * seen + self._conv_layers \
-                * (self.cfg.conv_kernel - 1) * len(batch)
-            self.rows["rows_if_full"] += self.cfg.n_layers * depth
+            self.rows["rows_read"] += rows[0]
+            self.rows["rows_if_full"] += rows[1]
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,
             lambda: {"bytes_" + kind: n for kind, n in
@@ -1171,8 +1320,11 @@ class ContinuousBatchingEngine:
         gone, and with it every slot's rows, not only the batch's.  Fail
         every session that holds a slot (the reaper frees the slots next
         turn), forget the prefixes the lost rows advertised, and go on
-        with a fresh cache.  Sessions still prefilling or waiting for a
-        slot own their batch-1 caches and are untouched."""
+        with a fresh cache and carry.  The step in flight, queued behind
+        the one that raised or ahead of the one that could not be
+        queued, ran on the same cache: its output is dropped unread, so
+        no token of either is published.  Sessions still prefilling or
+        waiting for a slot own their batch-1 caches and are untouched."""
         from ..models import init_slot_cache
         with self._cond:
             for sess in self._slots.values():
@@ -1182,6 +1334,8 @@ class ContinuousBatchingEngine:
                 for slot in range(self.ecfg.max_slots):
                     self._prefix.evict(slot)
             self._cond.notify_all()
+        self._flight = None
+        self._carry = self._fresh_carry()
         self._cache = None     # free what is left before allocating anew
         self._cache = init_slot_cache(self.cfg, self.ecfg.max_slots,
                                       self.max_len)
@@ -1243,7 +1397,10 @@ class ContinuousBatchingEngine:
     def _publish(self, batch, tokens, spec_out, new_toks) -> None:
         """After a step's read-back: counters, then under the lock the
         new tokens onto their sessions' queues and the wake-up of the
-        callers waiting for them."""
+        callers waiting for them.  ``batch`` is a speculative
+        iteration's sessions, or a plain step's ``(session, slot)`` as
+        they were when it was dispatched: by now the session may have
+        ended and the slot be another's (its token is dropped)."""
         from ..core.runtime_metrics import (SERVE_DECODE_OCCUPANCY,
                                             SERVE_SPEC_ACCEPTANCE,
                                             SERVE_SPEC_ACCEPTED,
@@ -1282,13 +1439,13 @@ class ContinuousBatchingEngine:
                 self.spec_proposed += (self._spec_k - 1) * occupancy
                 self.spec_accepted += emitted - occupancy
             else:
-                for s in batch:
-                    tok = int(new_toks[s.slot])
+                for s, slot in batch:
+                    tok = int(new_toks[slot])
                     s.last_tok = tok
-                    s.pos += 1
+                    s.unread -= 1      # `pos` moved on at the dispatch
                     if not s.ended:
                         s.queue.append(tok)
-                    if s.pos >= self.max_len:
+                    if s.pos >= self.max_len and not s.unread:
                         s.done = True  # cache full: reaped next turn
             self._cond.notify_all()
         if spec_out is not None:

@@ -6,11 +6,15 @@ jitted program (decode step, prefill chunk, cache insert/gather,
 draft/verify) is wrapped ONCE in a timing shim that records, per program:
 
 * dispatch count and cumulative dispatch wall time (always);
-* block-until-ready device time, sampled every Nth dispatch
-  (``device_profile_sample_every``) so the hot loop stays hot — the
+* device time, from the samples the program's CALLER hands in
+  (:meth:`DispatchProfiler.note_device_seconds`): the shim itself never
+  waits for the device on a shape it has seen, because a loop that keeps
+  a step in flight (the decode engine) would be emptied by every such
+  wait.  The engine samples where it reads a step's tokens anyway; the
   estimate extrapolates the sampled mean over all dispatches;
 * the argument-shape key of each dispatch, and the wall time of every
-  FIRST-SEEN shape — the **compile ledger**.  A novel shape means XLA
+  FIRST-SEEN shape (the one dispatch the shim does wait out: it
+  compiles) — the **compile ledger**.  A novel shape means XLA
   traces + compiles inside that dispatch, so its wall time is the
   observed compile cost and the recompile count is exactly the distinct
   shape count.  A ledger growing with traffic instead of staying O(1)
@@ -41,8 +45,7 @@ draft/verify) is wrapped ONCE in a timing shim that records, per program:
   s for a program of a thousand instructions: not a warm-up's to pay).
 
 The train step is in the ledger by `watch` alone
-(`models.make_train_step`): no shim, so never a ``block_until_ready``
-(the sampled wrap would empty a loop that keeps steps in flight).
+(`models.make_train_step`): no shim at all.
 
 The wrap is idempotent: wrapping an already-wrapped callable re-wraps
 the ORIGINAL underneath, never stacking shims — critical because the
@@ -173,8 +176,8 @@ class _ProgramStats:
         self.program = program
         self.dispatches = 0
         self.wall_s = 0.0
-        self.sampled_s = 0.0        # block-until-ready sample total
-        self.sampled_n = 0          # dispatches actually sampled
+        self.sampled_s = 0.0        # device seconds the caller sampled
+        self.sampled_n = 0          # ... over this many dispatches
         self.compile_s = 0.0        # wall time of first-seen shapes
         self.compiles = 0           # distinct argument-shape keys seen
         self.shapes: set = set()
@@ -183,17 +186,20 @@ class _ProgramStats:
 
     def device_seconds(self) -> float:
         """Extrapolated device time: sampled mean × all dispatches.
-        Until the first sample lands, dispatch wall time is the bound
-        (async dispatch makes it an underestimate, never zero)."""
+        Without a sample (a program whose caller hands none in),
+        dispatch wall time is the bound (async dispatch makes it an
+        underestimate, never zero)."""
         if self.sampled_n:
             return self.sampled_s * (self.dispatches
                                      / max(1, self.sampled_n))
         return self.wall_s
 
     def mfu(self, peak: Optional[float]) -> Optional[float]:
+        """None without a device-time sample: a share of the peak over
+        dispatch walls would read far past it."""
         dev = self.device_seconds()
         if not self.flops_per_token or not self.tokens or dev <= 0 \
-                or not peak or peak <= 0:
+                or not self.sampled_n or not peak or peak <= 0:
             return None
         return (self.tokens * self.flops_per_token) / dev / peak
 
@@ -201,8 +207,7 @@ class _ProgramStats:
 class DispatchProfiler:
     """Wrap-once timing shims over a set of named jitted programs."""
 
-    def __init__(self, sample_every: Optional[int] = None):
-        self._sample_every = sample_every
+    def __init__(self):
         self._stats: Dict[str, _ProgramStats] = {}
         self._lock = threading.Lock()
 
@@ -214,13 +219,6 @@ class DispatchProfiler:
                 st = self._stats.setdefault(program,
                                             _ProgramStats(program))
         return st
-
-    def _every(self) -> int:
-        if self._sample_every is not None:
-            return max(1, int(self._sample_every))
-        from ..core.config import GlobalConfig
-        return max(1, int(getattr(GlobalConfig,
-                                  "device_profile_sample_every", 10)))
 
     def wrap(self, program: str, fn: Callable) -> Callable:
         """Return ``fn`` timed under ``program``.  Idempotent: a
@@ -237,10 +235,9 @@ class DispatchProfiler:
         def dispatch(*args, **kwargs):
             key = _shape_key(args, kwargs)
             novel = key not in st.shapes
-            sample = novel or (st.dispatches + 1) % self._every() == 0
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
-            if sample:
+            if novel:
                 try:
                     import jax
                     out = jax.block_until_ready(out)
@@ -251,14 +248,10 @@ class DispatchProfiler:
             st.wall_s += dt
             if novel:
                 # first dispatch of a shape pays trace + compile: its
-                # wall time IS the observed compile cost (excluded from
-                # the device-time sample pool so MFU is steady-state)
+                # wall time IS the observed compile cost
                 st.shapes.add(key)
                 st.compiles += 1
                 st.compile_s += dt
-            elif sample:
-                st.sampled_s += dt
-                st.sampled_n += 1
             return out
 
         dispatch._rt_profiled_inner = fn
@@ -273,6 +266,15 @@ class DispatchProfiler:
         never costs a device sync."""
         if n > 0:
             self._stat(program).tokens += n
+
+    def note_device_seconds(self, program: str, seconds: float) -> None:
+        """One dispatch of ``program`` took the device ``seconds``: a
+        sample from the caller, taken where it waits for the program's
+        output anyway (the engine: between the returns of two
+        consecutive steps' reads)."""
+        st = self._stat(program)
+        st.sampled_s += seconds
+        st.sampled_n += 1
 
     def set_flops_per_token(self, program: str, flops: float) -> None:
         self._stat(program).flops_per_token = float(flops or 0.0)

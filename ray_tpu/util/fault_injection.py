@@ -65,6 +65,13 @@ site                        actions
 ``serve.request``           ``crash`` (replica dies mid-request), ``error``,
                             ``delay``/``latency``
 ``serve.health_check``      ``error`` (health check fails)
+``serve.decode_step``      ``error`` fails the read of a fused decode step's
+                            tokens, where an asynchronous device fault
+                            surfaces (serve/decode_session.py): the step
+                            queued behind it goes with it, every session
+                            that holds a slot fails once and the engine
+                            serves on from a fresh cache; ``delay``
+                            stretches the read
 ``serve.session_failover``  attacks decode-stream RECOVERY itself
                             (serve/failover.py): ``error`` fails the
                             resume (the stream surfaces the in-band
@@ -214,6 +221,7 @@ KNOWN_SITES: Dict[str, Optional[frozenset]] = {
     "serve.session_failover": frozenset({"error", "fail"}),
     "serve.autoscale": frozenset({"drop", "error", "fail"}),
     "serve.spec_verify": frozenset({"error", "fail"}),
+    "serve.decode_step": frozenset({"error", "fail"}),
     "serve.slo_eval": frozenset({"error", "fail"}),
     "drain.evacuate": None,
     "drain.deadline": None,

@@ -138,7 +138,7 @@ def test_ledger_writes_one_map_a_program_and_shape(fresh_ledger, tmp_path):
     def ledger_probe(x, w):
         return jnp.tanh(x @ w)
 
-    prof = dp.DispatchProfiler(sample_every=1000)
+    prof = dp.DispatchProfiler()
     before = len(_compiled_spans("probe"))
     # what the engine hands `wrap`: a closure over a jit that DONATES
     jitted = jax.jit(ledger_probe, donate_argnums=(0,))

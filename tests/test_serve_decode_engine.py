@@ -212,6 +212,283 @@ def test_engine_failed_step_fails_slot_holders_and_serves_on():
     assert eng._thread.is_alive()
 
 
+# ------------------------------------------------------- one step ahead
+#
+# The plain engine dispatches step n+1 before it reads step n: positions,
+# the queue bound and the live mask are kept at DISPATCH time, and only
+# token values reach the host a step late.  Every stream below is held to
+# the whole-prompt greedy reference.
+
+def _core(max_len=64, **engine):
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    return DecodeSessionCore(_tiny_cfg(), max_len=max_len, seed=3,
+                             engine=DecodeEngineConfig(**engine))
+
+
+def _drain(core, sid, toks, n):
+    while len(toks) < n:
+        out = core.handle({"op": "next_chunk", "sid": sid,
+                           "max_tokens": n - len(toks)})
+        assert "error" not in out, out
+        toks += out["tokens"]
+        if out["done"]:
+            break
+    return toks
+
+
+def _wait(cond, what, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.005)
+
+
+def _churn_join_and_end(want):
+    """Sessions join and end while the others' steps are in flight; the
+    last one takes the slot the first one left."""
+    core = _core(max_slots=3)
+    prompts = [list(range(10)), [5, 6, 7], [9] * 12, [1, 2]]
+    r = [core.handle({"op": "start", "prompt": prompts[0]})]
+    s = [list(r[0]["token"])]
+    _drain(core, r[0]["sid"], s[0], 3)
+    r.append(core.handle({"op": "start", "prompt": prompts[1]}))
+    s.append(list(r[1]["token"]))
+    _drain(core, r[0]["sid"], s[0], 5)
+    assert core.handle({"op": "end", "sid": r[0]["sid"]})["ended"]
+    for p in prompts[2:]:
+        r.append(core.handle({"op": "start", "prompt": p}))
+        s.append(list(r[-1]["token"]))
+    for i in (1, 2, 3):
+        _drain(core, r[i]["sid"], s[i], want)
+    return core, list(zip(prompts, s))
+
+
+def _churn_end_in_flight_slot_reused_at_once(want):
+    """ONE slot: `end` arrives exactly while a step that holds the slot is
+    in flight (from inside the read of the step before it), and a session
+    that was waiting takes the slot in the very next schedule, its insert
+    ordered behind the dead session's last step by the donated cache."""
+    core = _core(max_slots=1, token_queue_depth=6)
+    eng = core.engine
+    a = core.handle({"op": "start", "prompt": [3, 1, 4, 1, 5]})
+    sess = eng.sessions[a["sid"]]
+
+    def paused():
+        return len(sess.queue) == 6 and eng._flight is None
+
+    _wait(paused, "the slot never paused")
+    sa = _drain(core, a["sid"], list(a["token"]), 4)
+    b = core.handle({"op": "start", "prompt": [2, 7, 1, 8]})  # waits
+    _wait(paused, "the slot never paused again")
+    real_read, ended = eng._read, threading.Event()
+
+    def read_and_end(step, fi):
+        if not ended.is_set() and eng._flight is not None \
+                and any(sess.sid == a["sid"]
+                        for sess, _ in eng._flight.batch):
+            assert eng.end(a["sid"])
+            ended.set()
+        return real_read(step, fi)
+
+    eng._read = read_and_end
+    _drain(core, a["sid"], sa, 7)    # room for two steps: the loop wakes
+    assert ended.wait(30), "no step was ever in flight at a read"
+    sb = _drain(core, b["sid"], list(b["token"]), want)
+    assert eng.stats()["occupied_slots"] == 1
+    return core, [([3, 1, 4, 1, 5], sa), ([2, 7, 1, 8], sb)]
+
+
+def _churn_runs_into_max_len(want):
+    """A session whose step in flight takes it to `max_len` is in no
+    later dispatch, is done only once that last token is published, and
+    its neighbour decodes on."""
+    core = _core(max_len=32, max_slots=2)
+    long, short = list(range(1, 21)), [4, 2]
+    a = core.handle({"op": "start", "prompt": long})
+    b = core.handle({"op": "start", "prompt": short})
+    sa = _drain(core, a["sid"], list(a["token"]), 64)
+    # the prefill's token, then one a position the cache had left
+    assert len(sa) == 1 + 32 - len(long)
+    out = core.handle({"op": "next_chunk", "sid": a["sid"]})
+    assert out["tokens"] == [] and out["done"]
+    sb = _drain(core, b["sid"], list(b["token"]), want)
+    return core, [(long, sa), (short, sb)]
+
+
+def _churn_paused_at_queue_depth_then_resumed(want):
+    """A caller that stops polling: its slot pauses with exactly
+    `token_queue_depth` tokens buffered (the one in flight counted), the
+    loop goes quiet, and the stream resumes where it stopped from the
+    carry entry the idle slot kept on the device."""
+    core = _core(max_slots=2, token_queue_depth=2)
+    eng = core.engine
+    a = core.handle({"op": "start", "prompt": [8, 8, 8]})
+    sess = eng.sessions[a["sid"]]
+    _wait(lambda: len(sess.queue) == 2 and eng._flight is None,
+          "the slot never paused")
+    steps = eng.stats()["steps"]
+    time.sleep(0.2)
+    assert eng.stats()["steps"] == steps == 2 and sess.unread == 0
+    sa = _drain(core, a["sid"], list(a["token"]), want)
+    return core, [([8, 8, 8], sa)]
+
+
+@pytest.mark.parametrize("churn", [
+    _churn_join_and_end, _churn_end_in_flight_slot_reused_at_once,
+    _churn_runs_into_max_len, _churn_paused_at_queue_depth_then_resumed],
+    ids=lambda f: f.__name__[len("_churn_"):])
+def test_step_ahead_streams_equal_the_greedy_reference(churn):
+    want = 12
+    core, streams = churn(want)
+    try:
+        for prompt, got in streams:
+            assert got == greedy_stream(_tiny_cfg(), prompt, len(got),
+                                        max_len=core.max_len, seed=3)
+        st = core.handle({"op": "stats"})["engine"]
+        assert st["steps_ahead"] > 0 and st["cache_copies"] == 0
+    finally:
+        core.engine.shutdown()
+
+
+@pytest.mark.parametrize("draft", [None, "shared"], ids=["plain", "spec"])
+def test_steps_ahead_counter_and_span(draft, monkeypatch):
+    """On a steady batch every fused step but the first is dispatched
+    before the one ahead of it is read, and the `engine:ahead` ring span
+    carries the sums; a speculating engine makes its next input on the
+    host and counts none."""
+    from ray_tpu.serve.decode_session import ContinuousBatchingEngine
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(ContinuousBatchingEngine, "_MOE_SPAN_S", 0.0)
+
+    def spans():
+        return [e for e in tracing.span_events()
+                if e["name"] == "engine:ahead"]
+
+    before = len(spans())
+    core = _core(spec_draft=draft, spec_k=3) if draft else _core()
+    try:
+        prompt = [3, 1, 4, 1]
+        r = core.handle({"op": "start", "prompt": prompt})
+        got = _drain(core, r["sid"], list(r["token"]), 64)
+        assert got == greedy_stream(_tiny_cfg(), prompt, len(got),
+                                    max_len=64, seed=3)
+        st = core.handle({"op": "stats"})["engine"]
+        mine = spans()[before:]
+        if draft:
+            assert st["spec"]["proposed"] > 0 and st["steps_ahead"] == 0
+            assert not mine      # no plain step was dispatched at all
+            return
+        assert st["steps"] == 64 - len(prompt)
+        assert st["steps_ahead"] == st["steps"] - 1
+        assert all(e["cat"] == "ahead" for e in mine)
+        assert sum(e["args"].get("steps", 0) for e in mine) == st["steps"]
+        assert sum(e["args"].get("steps_ahead", 0) for e in mine) \
+            == st["steps_ahead"]
+    finally:
+        core.engine.shutdown()
+
+
+def test_step_fault_with_a_step_queued_behind_it(chaos_cleanup):
+    """Chaos site ``serve.decode_step``: the read of step 3 raises (where
+    an asynchronous device fault surfaces) with step 4 already queued
+    behind it.  Both outputs are dropped: every session that holds a slot
+    fails exactly once, no token of either step is published, and the
+    engine serves the next request from a fresh cache and carry."""
+    from ray_tpu.util import fault_injection as fi
+    core = _core(max_slots=2, token_queue_depth=2)
+    eng = core.engine
+    prompt, want = [3, 1, 4, 1, 5, 9, 2, 6], 8
+    fails = []
+    real_fail = eng._fail_slots
+    eng._fail_slots = lambda err: (fails.append(err), real_fail(err))[1]
+    try:
+        # both hold a slot and stand paused, nobody polls: the loop is
+        # quiet while the plan is armed, then both decode on together
+        a = core.handle({"op": "start", "prompt": prompt})
+        b = core.handle({"op": "start", "prompt": [8, 8, 8]})
+        sa, sb = eng.sessions[a["sid"]], eng.sessions[b["sid"]]
+        _wait(lambda: len(sa.queue) == len(sb.queue) == 2
+              and eng._flight is None, "the slots never paused")
+        fi.arm([{"site": "serve.decode_step", "action": "error",
+                 "match": {"nth": 3}}])
+        steps = eng.steps
+        with eng._cond:
+            eng.ecfg.token_queue_depth = 64
+            eng._cond.notify_all()
+        _wait(lambda: sa.error and sb.error, "the fault never fired")
+        assert len(fails) == 1 and "injected decode_step" in fails[0]
+        # nobody has polled: what was published is what the queues hold,
+        # and it is two steps short of what was dispatched
+        assert eng.steps == steps + 2
+        assert eng.ahead["steps"] == eng.steps + 2
+        assert len(sa.queue) == len(sb.queue) == 4
+        assert eng.tokens == len(sa.queue) + len(sb.queue)
+        assert eng._flight is None
+        for sid in (a["sid"], b["sid"]):
+            out = core.handle({"op": "next_chunk", "sid": sid})
+            assert "decode engine step failed" in out["error"], out
+        c = core.handle({"op": "start", "prompt": prompt})
+        got = _drain(core, c["sid"], list(c["token"]), want)
+        assert got == greedy_stream(_tiny_cfg(), prompt, want, max_len=64,
+                                    seed=3)
+        st = core.handle({"op": "stats"})["engine"]
+        assert st["cache_copies"] == 0 and len(fails) == 1
+        assert eng._thread.is_alive()
+    finally:
+        eng.shutdown()
+
+
+def test_step_ahead_under_more_callers_than_slots_and_cores():
+    """Stress: twelve caller threads over three slots, a short switch
+    interval, each streaming its prompt and ending early or running on,
+    so joins, ends and reassignments land at every point of the loop's
+    turn.  A lost update of a position, of the unread count or of a
+    queue would show as a stream off the greedy reference, a token
+    unaccounted for, or a slot never freed."""
+    import sys
+    core = _core(max_slots=3, max_waiting=16)
+    eng = core.engine
+    prompts = [[(3 * i + j) % 50 + 1 for j in range(2 + i % 5)]
+               for i in range(12)]
+    wants = [4 + (5 * i) % 17 for i in range(12)]
+    got, errors = [None] * 12, []
+
+    def caller(i):
+        try:
+            r = core.handle({"op": "start", "prompt": prompts[i]})
+            got[i] = _drain(core, r["sid"], list(r["token"]), wants[i])
+            assert core.handle({"op": "end", "sid": r["sid"]})["ended"]
+        except BaseException as e:   # reported by the main thread
+            errors.append((i, repr(e)))
+
+    threads = [threading.Thread(target=caller, args=(i,))
+               for i in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    try:
+        assert not errors and not any(t.is_alive() for t in threads), errors
+        for i in range(12):
+            assert got[i] == greedy_stream(_tiny_cfg(), prompts[i],
+                                           wants[i], max_len=64, seed=3), i
+        _wait(lambda: eng.stats()["occupied_slots"] == 0
+              and eng._flight is None, "a slot was never freed")
+        st = eng.stats()
+        assert st["sessions"] == 0 and st["cache_copies"] == 0
+        assert st["steps_ahead"] > 0
+        # every dispatched step was read and published
+        assert eng.ahead["steps"] == st["steps"]
+    finally:
+        eng.shutdown()
+
+
 def test_engine_slot_reclamation_backpressure_and_lru():
     """Ended sessions vacate their slot between steps (a waiting/new
     session takes it over); with every slot held and the wait queue at
