@@ -16,7 +16,10 @@ normed key-value latent beside the rotated shared key, ``kv_lora_rank +
 qk_rope_head_dim`` values a position a layer) for latent attention.
 Beside its arrays a cache carries ``pos``.  Every function here, the
 serve engine and the prefix reuse work on whatever arrays a cache has
-(`cache_arrays`); none names one.
+(`cache_arrays`); none names one.  Each array has a row of its own
+(`cache_rows`: heads and width): a model whose window layers have other
+key-value heads than its full layers, or values of another width than its
+keys, holds arrays of as many shapes.
 
 A cache holds TWO KINDS OF STATE where a model mixes window and full
 layers (`TransformerConfig.layer_kinds`): the full layers' arrays
@@ -81,11 +84,12 @@ import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops import latent_attention as mla
+from ..ops.attention import sink_softmax
 from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
 from .transformer import (TransformerConfig, _attn_out, _ffn, _layer, _norm,
                           _post, _qkv, _scale_embedding, _unembed, norm_eps,
-                          scan_layer_runs)
+                          rope_tables, scan_layer_runs)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -103,14 +107,34 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     width) of each of its arrays.  Window layers have arrays of their own
     (rings, named ``*_win``) beside the full layers'; conv layers one
     (``*_state``) whose "positions" are the model's channels and whose
-    width is the ``conv_kernel - 1`` inputs a sequence carries."""
+    width is the ``conv_kernel - 1`` inputs a sequence carries.  Each
+    array has a row of its own: a kind's key-value heads
+    (`TransformerConfig.kv_heads_of`), and a value's width beside a key's."""
     state = {_CONV_STATE: (1, cfg.conv_kernel - 1)} \
         if "conv" in cfg.kinds else {}
     if cfg.attention == "mla":
         return dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
-    row = (cfg.kv_heads, cfg.head_dim)
-    return dict({name: row for kind in ("full", "window")
-                 if kind in cfg.kinds for name in _kv_names(kind)}, **state)
+    rows = {}
+    for kind in ("full", "window"):
+        if kind in cfg.kinds:
+            hk = cfg.kv_heads_of(kind)
+            rows.update(zip(_kv_names(kind), ((hk, cfg.head_dim),
+                                              (hk, cfg.value_dim))))
+    return dict(rows, **state)
+
+
+def position_bytes(cfg: TransformerConfig) -> Dict[str, int]:
+    """Bytes ONE layer of each state kind holds a position a sequence
+    (``full``, ``ring``), or a sequence whatever its positions (``state``:
+    a conv layer's ``conv_kernel - 1`` inputs of ``d_model``): what a
+    decode step reads of a row it attends, by the row's kind."""
+    out = {"full": 0, "ring": 0, "state": 0}
+    item = jnp.dtype(cfg.dtype).itemsize
+    for name, (heads, width) in cache_rows(cfg).items():
+        kind = _state_kind(name)
+        out[kind] += heads * width * item * (
+            cfg.d_model if kind == "state" else 1)
+    return out
 
 
 def _kv_names(kind: str) -> Tuple[str, str]:
@@ -197,6 +221,21 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "of conv layers, of both) is not served: the rows a session may "
             "reach (max_len) are read off a full layer's array, and neither "
             "a ring nor a state has them")
+    if cfg.attention == "mla" and (
+            cfg.split_kv or cfg.sink_kinds or cfg.value_scale != 1.0
+            or cfg.rope_fraction != 1.0):
+        raise NotImplementedError(
+            "key-value heads by layer kind, a sink, a value scale and a "
+            "rotated share of a head are MHA/GQA's; latent attention has "
+            "none of them")
+    for kind in kinds - {"conv"}:
+        if cfg.attention == "mha" and cfg.n_heads % cfg.kv_heads_of(kind):
+            raise ValueError(
+                f"{cfg.n_heads} query heads over {cfg.kv_heads_of(kind)} "
+                f"key-value heads of a {kind} layer")
+    if cfg.pos_emb == "rope" and cfg.rope_dim % 2:
+        raise ValueError(f"rotary over {cfg.rope_dim} dims of a head: "
+                         f"pairs need an even number")
     if "window" in kinds:
         if cfg.attention == "mla":
             raise NotImplementedError(
@@ -335,6 +374,13 @@ def _place_state(s_all: jnp.ndarray, l, state: jnp.ndarray) -> jnp.ndarray:
         s_all, state[None, :, None].astype(s_all.dtype), (l, 0, 0, 0, 0))
 
 
+def _rotators(turn, angles: Dict[str, Any]) -> Dict[str, Any]:
+    """`rope_tables`' ``{kind: (cos, sin)}`` -> ``{kind: t -> turn(t, cos,
+    sin)}``: what `_attend_cached` takes as ``rotate``."""
+    return {kind: functools.partial(turn, cos=cos, sin=sin)
+            for kind, (cos, sin) in angles.items()}
+
+
 def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                    cache: KVCache, *, rotate, write, mask, valid=None,
                    n_new=None):
@@ -346,19 +392,19 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     are keyed by the layer's attention kind (``"full"``, ``"window"``).
     A conv layer reads its state, and advances it by ``n_new`` [B] tokens
     (None: all C): the row's real tokens, none for a row that stands.
-    ``rotate`` applies the caller's rotary angles (on the layers the model
-    rotates); ``valid`` [B, C] marks the rows a no-drop expert layer
-    routes (None: all).
+    ``rotate[kind]`` applies the caller's rotary angles at that kind's
+    base (absent: the kind rotates nothing); ``valid`` [B, C] marks the
+    rows a no-drop expert layer routes (None: all).
     → (final-norm activations, arrays, load)."""
     dt = cfg.dtype
     b, c, _ = x.shape
-    h, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    h, hd = cfg.n_heads, cfg.head_dim
     eps = norm_eps(cfg)
 
     def attend_mha(y, lp, arrs, l, kind):
         kn, vn = _kv_names(kind)
-        q, k_new, v_new = _qkv(cfg, y, lp,
-                               rotate if cfg.rotates(kind) else None)
+        hk = cfg.kv_heads_of(kind)      # the layer's kind's, as its arrays'
+        q, k_new, v_new = _qkv(cfg, y, lp, rotate.get(kind), kind)
         k_all = write[kind](arrs[kn], l, _as_columns(k_new, arrs[kn].dtype))
         v_all = write[kind](arrs[vn], l, _as_columns(v_new, arrs[vn].dtype))
         with jax.named_scope("attention"):
@@ -369,10 +415,12 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                                 ck.astype(dt)) / jnp.sqrt(float(hd))
             scores = jnp.where(mask[kind][:, :, None, None, :], scores,
                                -1e30)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            probs = sink_softmax(
+                scores.astype(jnp.float32), lp["sink"].reshape(
+                    hk, h // hk, 1) if kind in cfg.sink_kinds else None)
             attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
                               cv.astype(dt))
-            attn = attn.reshape(b, c, h, hd)
+            attn = attn.reshape(b, c, h, cv.shape[-2])
         return (_attn_out(cfg, y, attn, lp),
                 dict(arrs, **{kn: k_all, vn: v_all}))
 
@@ -380,9 +428,10 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         # absorbed: the chunk's few queries over the cached latents
         q_nope, q_rope = mla.queries(
             y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
-            nope=cfg.qk_nope_head_dim, eps=eps, rotate=rotate)
+            nope=cfg.qk_nope_head_dim, eps=eps, rotate=rotate[kind])
         new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
-                          kv_lora=cfg.kv_lora_rank, eps=eps, rotate=rotate)
+                          kv_lora=cfg.kv_lora_rank, eps=eps,
+                          rotate=rotate[kind])
         kv_all = write[kind](arrs["kv"], l, _as_columns(
             new[:, :, None, :], arrs["kv"].dtype))
         out = mla.attend_absorbed(q_nope, q_rope, _layer_of(kv_all, l)[:, 0],
@@ -427,9 +476,9 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
         if cfg.pos_emb == "learned":
             x = x + params["embed"]["pos"][:s].astype(dt)
-    cos, sin = (rotary_angles(s, cfg.rope_dim, cfg.rope_base)
-                if cfg.pos_emb == "rope" else (None, None))
-    rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
+    angles = rope_tables(cfg, lambda base: rotary_angles(s, cfg.rope_dim,
+                                                         base))
+    rotate = _rotators(apply_rotary, angles)
 
     def columns(y, lp, kind):
         """What the cache holds of these tokens, from the same pre-norm
@@ -437,9 +486,9 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         if cfg.attention == "mla":
             new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
                               kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
-                              rotate=rotate)
+                              rotate=rotate[kind])
             return {"kv": new[:, :, None, :]}
-        _, k, v = _qkv(cfg, y, lp, rotate if cfg.rotates(kind) else None)
+        _, k, v = _qkv(cfg, y, lp, rotate.get(kind), kind)
         return dict(zip(_kv_names(kind), (k, v)))
 
     @jax.named_scope("cache_write")
@@ -465,7 +514,7 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             arrs = dict(arrs, **{
                 n: place(arrs[n], l, _as_columns(rows, arrs[n].dtype))
                 for n, rows in columns(y, lp, kind).items()})
-        h, _ = _layer(cfg, h, lp, cos, sin, kind)
+        h, _ = _layer(cfg, h, lp, angles, kind)
         return h, arrs, (0, 0, 0)
 
     x, arrays, _ = _scan_cached(cfg, params, x, cache, layer)
@@ -535,14 +584,11 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
         if cfg.pos_emb == "learned":
             x = x + jax.lax.dynamic_slice_in_dim(
                 params["embed"]["pos"], pos, c, axis=0).astype(dt)
-    if cfg.pos_emb == "rope":
-        with jax.named_scope("projections"):
-            full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
-                                               cfg.rope_base)
-            cos = jax.lax.dynamic_slice_in_dim(full_cos, pos, c, axis=0)
-            sin = jax.lax.dynamic_slice_in_dim(full_sin, pos, c, axis=0)
-    else:
-        cos = sin = None
+    with jax.named_scope("projections"):
+        # the chunk's rows of each rotating kind's table (its own base)
+        angles = rope_tables(cfg, lambda base: tuple(
+            jax.lax.dynamic_slice_in_dim(t, pos, c, axis=0)
+            for t in rotary_angles(max_len, cfg.rope_dim, base)))
     # mask[i, t]: cached position t visible to chunk token i (causal
     # within the chunk, everything before it fully visible)
     with jax.named_scope("attention"):
@@ -563,7 +609,7 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
         jnp.broadcast_to(jnp.arange(c) < n_valid, (b, c))
     x, arrays, load = _attend_cached(
         cfg, params, x, cache,
-        rotate=lambda t: apply_rotary(t, cos, sin),
+        rotate=_rotators(apply_rotary, angles),
         write=write, mask=mask, valid=valid,
         n_new=None if n_valid is None else
         jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), (b,)))
@@ -786,14 +832,11 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
         if cfg.pos_emb == "learned":
             x = x + params["embed"]["pos"][posm].astype(dt)
-    if cfg.pos_emb == "rope":
-        with jax.named_scope("projections"):
-            full_cos, full_sin = rotary_angles(max_len, cfg.rope_dim,
-                                               cfg.rope_base)
-            cos = full_cos[posm][:, :, None, :]                # [S,C,1,·]
-            sin = full_sin[posm][:, :, None, :]
-    else:
-        cos = sin = None
+    with jax.named_scope("projections"):
+        # each slot's rows of each rotating kind's table: [S, C, 1, ·]
+        angles = rope_tables(cfg, lambda base: tuple(
+            t[posm][:, :, None, :]
+            for t in rotary_angles(max_len, cfg.rope_dim, base)))
 
     def column_writes(column):
         @jax.named_scope("cache_write")
@@ -821,7 +864,7 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         jnp.broadcast_to(active[:, None], (s, c))
     return _attend_cached(
         cfg, params, x, cache,
-        rotate=lambda t: _rotate_slots(t, cos, sin),
+        rotate=_rotators(_rotate_slots, angles),
         write=write, mask=mask, valid=valid,
         n_new=None if active is None else active.astype(jnp.int32) * c)
 

@@ -42,6 +42,14 @@ Design (idiomatic JAX, not a torch translation):
     changes; a segment that is part of a run loops over indices INTO the
     run's stacks, each operator's by its own counter (`_scan_part`; a
     slice would be a copy of the weights).
+  * what an MHA/GQA head is may go BY THE LAYER'S KIND, each a property
+    with the plain model as its default: a window layer's key-value heads
+    (``window_kv_heads``: ``wk`` / ``wv`` of two shapes in one run, each
+    stacked over its own kind's layers), its rotary base
+    (``window_rope_base``), a learned sink in its softmax
+    (``sink_kinds``); and for every kind a value head's width
+    (``v_head_dim``), the rotated share of a head (``rope_fraction``) and
+    a scale on the values (``value_scale``).
 
 Configs: ``TransformerConfig.gpt2()`` (learned positions, GELU, LayerNorm)
 and ``TransformerConfig.llama()`` (RoPE, SwiGLU, RMSNorm, GQA).
@@ -141,6 +149,18 @@ class TransformerConfig:
     # -- a share of the experts (one chip of an expert-parallel layer) ------
     experts_held: Optional[int] = None  # None → all n_experts
     expert_offset: int = 0            # the first expert held
+    # -- what an MHA/GQA head may differ in, by the layer's kind -------------
+    # (``v_head_dim`` above, where set, is an MHA/GQA value head's width too)
+    window_kv_heads: Optional[int] = None  # a window layer's key-value
+    #   heads (None → n_kv_heads): where they differ a run holds wk / wv of
+    #   two shapes, each stacked over its own kind's layers
+    window_rope_base: Optional[float] = None  # a window layer's rotary
+    #   base (None → rope_base)
+    rope_fraction: float = 1.0        # the share of a head's FIRST dims that
+    #   is rotated (truncated to a whole number; the rest carry no position)
+    sink_kinds: Tuple[str, ...] = ()  # the kinds of layer whose softmax has
+    #   a learned logit a query head in its denominator that takes no value
+    value_scale: float = 1.0          # multiplies the value projection
 
     @property
     def head_dim(self) -> int:
@@ -164,12 +184,35 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    def kv_heads_of(self, kind: str) -> int:
+        """Key-value heads of an attention layer of this kind."""
+        return self.window_kv_heads if kind == "window" \
+            and self.window_kv_heads else self.kv_heads
+
+    @property
+    def split_kv(self) -> bool:
+        """Whether window and full layers' key and value projections have
+        different shapes (and so stacks of their own: ``wk_win``,
+        ``wv_win`` beside ``wk``, ``wv``)."""
+        return self.kv_heads_of("window") != self.kv_heads
+
+    @property
+    def value_dim(self) -> int:
+        """An MHA/GQA value head's width (the key's unless stated)."""
+        return self.v_head_dim or self.head_dim
+
+    def rope_base_of(self, kind: str) -> float:
+        return self.window_rope_base if kind == "window" \
+            and self.window_rope_base else self.rope_base
+
     @property
     def rope_dim(self) -> int:
-        """Width of what the rotary angles turn: a whole head, or the
-        rotary part of a latent-attention head."""
+        """Width of what the rotary angles turn: a whole head or its
+        first ``rope_fraction``, or the rotary part of a latent-attention
+        head."""
         return self.qk_rope_head_dim if self.attention == "mla" \
-            else self.head_dim
+            else int(self.rope_fraction * self.head_dim) \
+            if self.rope_fraction != 1.0 else self.head_dim
 
     @property
     def expert_ff_dim(self) -> int:
@@ -246,7 +289,7 @@ class TransformerConfig:
         return TransformerConfig(**defaults)
 
 
-def _attn_matmul_params(cfg: TransformerConfig) -> int:
+def _attn_matmul_params(cfg: TransformerConfig, kind: str = "full") -> int:
     d, h = cfg.d_model, cfg.n_heads
     if cfg.attention == "mla":
         nope, rope, v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -254,9 +297,9 @@ def _attn_matmul_params(cfg: TransformerConfig) -> int:
         return (d * cfg.q_lora_rank + cfg.q_lora_rank * h * (nope + rope)
                 + d * (cfg.kv_lora_rank + rope)
                 + cfg.kv_lora_rank * h * (nope + v) + h * v * d)
-    hd = cfg.head_dim
-    gate = d * h * hd if cfg.attn_gate else 0
-    return d * h * hd + 2 * d * cfg.kv_heads * hd + h * hd * d + gate
+    hd, vd, hk = cfg.head_dim, cfg.value_dim, cfg.kv_heads_of(kind)
+    gate = d * h * vd if cfg.attn_gate else 0
+    return d * h * hd + d * hk * (hd + vd) + h * vd * d + gate
 
 
 def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
@@ -284,7 +327,7 @@ def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
     return sum(n * _run_matmul_params(cfg, run, active)
                for run, n in cfg.layer_runs) + sum(
         4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
-        else _attn_matmul_params(cfg) for kind in cfg.kinds)
+        else _attn_matmul_params(cfg, kind) for kind in cfg.kinds)
 
 
 def _attn_flops_dim(cfg: TransformerConfig) -> int:
@@ -294,7 +337,7 @@ def _attn_flops_dim(cfg: TransformerConfig) -> int:
     if cfg.attention == "mla":
         return cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
                               + cfg.v_head_dim) // 2
-    return cfg.n_heads * cfg.head_dim
+    return cfg.n_heads * (cfg.head_dim + cfg.value_dim) // 2
 
 
 def _attended(cfg: TransformerConfig, context_len: float,
@@ -316,7 +359,8 @@ def count_params(cfg: TransformerConfig) -> int:
     own = cfg.q_lora_rank + cfg.kv_lora_rank if cfg.attention == "mla" \
         else 2 * cfg.head_dim if cfg.qk_norm else 0   # an attention layer's
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
-        + (cfg.n_layers - n_conv) * own + n_conv * d * cfg.conv_kernel
+        + (cfg.n_layers - n_conv) * own + n_conv * d * cfg.conv_kernel \
+        + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads
     if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
         layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
     emb = cfg.vocab_size * d
@@ -353,7 +397,7 @@ def decode_flops_per_token(cfg: TransformerConfig,
         per_pos = cfg.n_heads * (2 * cfg.kv_lora_rank
                                  + cfg.qk_rope_head_dim)
     else:
-        per_pos = 2 * cfg.n_heads * cfg.head_dim
+        per_pos = cfg.n_heads * (cfg.head_dim + cfg.value_dim)
     return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len)
 
 
@@ -388,10 +432,13 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     """One run of `layer_runs`: ``L`` layers as one stacked tree (leading
     "layers" axis) and its logical axes; ``keys`` an iterator of keys, one
     drawn a weight.  An operator's weights are stacked over ITS layers of
-    the run only (`operator_layers`): attention's ``La``, a conv's ``Lc``."""
+    the run only (`stack_kinds`, `kind_layers`): attention's ``La``, a
+    conv's ``Lc``; where window and full layers differ in key-value heads
+    (`TransformerConfig.split_kv`) each kind's ``wk`` / ``wv`` over its
+    own layers, and the sinks over the layers that have one."""
     La, Lc = operator_layers(cfg, run)
-    d, hd, h, hk, ff = (cfg.d_model, cfg.head_dim, cfg.n_heads,
-                        cfg.kv_heads, cfg.ff_dim)
+    d, hd, vd, h, ff = (cfg.d_model, cfg.head_dim, cfg.value_dim,
+                        cfg.n_heads, cfg.ff_dim)
     pt = cfg.param_dtype
     p: Params = {"attn_norm": jnp.ones((L, d), pt),
                  "mlp_norm": jnp.ones((L, d), pt)}
@@ -421,11 +468,20 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
         ax["q_norm"] = ax["kv_norm"] = ("layers", None)
     elif La and cfg.attention == "mha":
         add("wq", (d, h, hd), d, ("embed", "heads", "kv"), La)
-        add("wk", (d, hk, hd), d, ("embed", "heads", "kv"), La)
-        add("wv", (d, hk, hd), d, ("embed", "heads", "kv"), La)
-        add("wo", (h, hd, d), h * hd, ("heads", "kv", "embed"), La)
+        for kind in ("full", "window") if cfg.split_kv else ("full",):
+            kn, vn = kv_weight_names(cfg, kind)
+            n, hk = kind_layers(cfg, run, stack_kinds(cfg, kn)), \
+                cfg.kv_heads_of(kind)
+            if n:
+                add(kn, (d, hk, hd), d, ("embed", "heads", "kv"), n)
+                add(vn, (d, hk, vd), d, ("embed", "heads", "kv"), n)
+        add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"), La)
         if cfg.attn_gate:
-            add("wg", (d, h, hd), d, ("embed", "heads", "kv"), La)
+            add("wg", (d, h, vd), d, ("embed", "heads", "kv"), La)
+        n_sink = kind_layers(cfg, run, cfg.sink_kinds)
+        if n_sink:      # a logit a query head: zero weighs as a score of 0
+            p["sink"] = jnp.zeros((n_sink, h), pt)
+            ax["sink"] = ("layers", "heads")
         if cfg.qk_norm:
             p["q_norm"], p["k_norm"] = jnp.ones((La, hd), pt), \
                 jnp.ones((La, hd), pt)
@@ -586,26 +642,58 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
 
 
 @jax.named_scope("projections")
-def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate):
-    """Normed input [b, s, d] -> (q [b, s, h, hd], k, v [b, s, hk, hd]) of
-    an MHA/GQA block: the three projections, the per-head RMS norms where
-    the model has them, then ``rotate`` (None: this layer turns nothing)."""
+def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate,
+         kind: str = "full"):
+    """Normed input [b, s, d] -> (q [b, s, h, hd], k [b, s, hk, hd], v [b,
+    s, hk, vd]) of an MHA/GQA block of kind ``kind``: the three
+    projections (the value's scaled where the model scales it), the
+    per-head RMS norms where the model has them, then ``rotate`` over a
+    head's first `rope_dim` dims (None: this layer turns nothing)."""
     dt = cfg.dtype
+    kn, vn = kv_weight_names(cfg, kind)
     q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dt))
+    k = jnp.einsum("bsd,dhk->bshk", y, lp[kn].astype(dt))
+    v = jnp.einsum("bsd,dhk->bshk", y, lp[vn].astype(dt))
+    if cfg.value_scale != 1.0:
+        v = (v.astype(jnp.float32) * cfg.value_scale).astype(dt)
     if cfg.qk_norm:
         q = rmsnorm(q, lp["q_norm"], norm_eps(cfg))
         k = rmsnorm(k, lp["k_norm"], norm_eps(cfg))
     if rotate is not None:
-        q, k = rotate(q), rotate(k)
+        q, k = _rotate_heads(cfg, rotate, q), _rotate_heads(cfg, rotate, k)
     return q, k, v
+
+
+def _rotate_heads(cfg: TransformerConfig, rotate, t: jnp.ndarray):
+    """``rotate`` over the first `rope_dim` dims of each head of ``t``
+    [..., heads, head_dim]; the rest of a head carries no position."""
+    if cfg.rope_dim == t.shape[-1]:
+        return rotate(t)
+    return jnp.concatenate([rotate(t[..., :cfg.rope_dim]),
+                            t[..., cfg.rope_dim:]], axis=-1)
+
+
+def rope_tables(cfg: TransformerConfig, make) -> Dict[str, Any]:
+    """``{kind: make(base)}`` over the attention kinds the model rotates,
+    each at its kind's base (`TransformerConfig.rope_base_of`); kinds of
+    one base share one table."""
+    if cfg.pos_emb != "rope":
+        return {}
+    by_base: Dict[float, Any] = {}
+    out = {}
+    for kind in ("full", "window"):
+        if kind in cfg.kinds and cfg.rotates(kind):
+            base = cfg.rope_base_of(kind)
+            if base not in by_base:
+                by_base[base] = make(base)
+            out[kind] = by_base[base]
+    return out
 
 
 @jax.named_scope("projections")
 def _attn_out(cfg: TransformerConfig, y: jnp.ndarray, attn: jnp.ndarray,
               lp: Params) -> jnp.ndarray:
-    """Heads' output [b, s, h, hd] -> the block's [b, s, d]: gated by
+    """Heads' output [b, s, h, vd] -> the block's [b, s, d]: gated by
     ``sigmoid(y W_g)`` where the model gates, then the output projection."""
     dt = cfg.dtype
     if cfg.attn_gate:
@@ -623,8 +711,10 @@ def _post(cfg: TransformerConfig, delta: jnp.ndarray, lp: Params,
 
 
 def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
-           cos, sin, kind: str = "full") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One block whose operator is ``kind``'s -> (x, router_aux_loss)."""
+           angles, kind: str = "full") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One block whose operator is ``kind``'s -> (x, router_aux_loss);
+    ``angles`` the (cos, sin) of each kind that rotates (`rope_tables`)."""
+    cos, sin = angles.get(kind, (None, None))
     if kind == "conv":
         return _conv_layer(cfg, x, lp)
     norm = functools.partial(_norm, cfg)
@@ -649,10 +739,12 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
             "post_attn_norm")
     else:
         q, k, v = _qkv(cfg, y, lp, functools.partial(
-            apply_rotary, cos=cos, sin=sin) if cfg.rotates(kind) else None)
+            apply_rotary, cos=cos, sin=sin) if kind in angles else None,
+            kind)
         attn = multi_head_attention(
             q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
-            window=cfg.sliding_window if kind == "window" else None)
+            window=cfg.sliding_window if kind == "window" else None,
+            sink=lp["sink"] if kind in cfg.sink_kinds else None)
         x = x + _post(cfg, _attn_out(cfg, y, attn, lp), lp,
                       "post_attn_norm")
 
@@ -722,8 +814,8 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
     """Everything up to (and including) the final norm:
     tokens [b, s] → (hidden [b, s, d] in cfg.dtype, mean router aux)."""
     x = _embed(params, tokens, cfg)
-    cos, sin = (rotary_angles(tokens.shape[1], cfg.rope_dim, cfg.rope_base)
-                if cfg.pos_emb == "rope" else (None, None))
+    angles = rope_tables(cfg, lambda base: rotary_angles(
+        tokens.shape[1], cfg.rope_dim, base))
 
     policy = remat_policy(cfg.remat)
 
@@ -732,7 +824,7 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         if policy is not None:
             layer = jax.checkpoint(layer, static_argnums=(), policy=policy)
         h, aux = carry
-        h, aux_l = layer(h, lp, cos, sin)
+        h, aux_l = layer(h, lp, angles)
         return h, aux + aux_l
 
     if cfg.pp_stages > 1:
@@ -1012,22 +1104,60 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
 
 #: a conv layer's weights in a run's tree
 _CONV_KEYS = ("conv_in", "conv_w", "conv_out")
+#: an attention layer's (MHA/GQA and latent), of whatever attention kind
+_ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
+              "wq_b", "wkv_a", "wkv_b", "kv_norm")
+_WINDOW_KV = ("wk_win", "wv_win")
+
+
+def kv_weight_names(cfg: TransformerConfig, kind: str) -> Tuple[str, str]:
+    """The key and value projections of an attention layer of ``kind`` in
+    its run's tree: a window layer's have stacks of their own where their
+    shape is not the full layers' (`TransformerConfig.split_kv`)."""
+    return _WINDOW_KV if kind == "window" and cfg.split_kv else ("wk", "wv")
+
+
+def stack_kinds(cfg: TransformerConfig, key: str
+                ) -> Optional[Tuple[str, ...]]:
+    """The kinds of layer over which the stack ``key`` of a run's tree
+    runs (None: every layer of the run): an operator's weights hold no
+    other operator's layers, and a weight whose shape follows the layer's
+    kind only that kind's."""
+    if key in _CONV_KEYS:
+        return ("conv",)
+    if key == "sink":
+        return cfg.sink_kinds
+    if key in _WINDOW_KV:
+        return ("window",)
+    if key in _ATTN_KEYS:
+        return ("full",) if cfg.split_kv and key in ("wk", "wv") \
+            else ("full", "window")
+    return None
+
+
+def kind_layers(cfg: TransformerConfig, run: str,
+                kinds: Optional[Tuple[str, ...]],
+                upto: Optional[int] = None) -> int:
+    """Layers of the kinds ``kinds`` (None: any) among the first ``upto``
+    layers (None: all) of the run ``run`` of `layer_runs`: how long a stack
+    over those kinds is in that run's tree, and where a segment's first
+    layer stands in it."""
+    first = 0
+    for name, n in cfg.layer_runs:
+        if name == run:
+            mine = cfg.kinds[first:first + (n if upto is None else upto)]
+            return len(mine) if kinds is None else \
+                sum(k in kinds for k in mine)
+        first += n
+    raise KeyError(run)
 
 
 def operator_layers(cfg: TransformerConfig, run: str,
                     upto: Optional[int] = None) -> Tuple[int, int]:
     """(attention layers, conv layers) among the first ``upto`` layers
-    (None: all) of the run ``run`` of `layer_runs`: how long each
-    operator's stacks in that run's tree are, and where a segment's first
-    layer stands in them."""
-    first = 0
-    for name, n in cfg.layer_runs:
-        if name == run:
-            kinds = cfg.kinds[first:first + (n if upto is None else upto)]
-            conv = kinds.count("conv")
-            return len(kinds) - conv, conv
-        first += n
-    raise KeyError(run)
+    (None: all) of the run ``run`` of `layer_runs`."""
+    return (kind_layers(cfg, run, ("full", "window"), upto),
+            kind_layers(cfg, run, ("conv",), upto))
 
 
 def _scan_part(cfg: TransformerConfig, run: str, first: int, n: int,
@@ -1035,22 +1165,15 @@ def _scan_part(cfg: TransformerConfig, run: str, first: int, n: int,
     """`scan_layer_runs` over the ``n`` layers from ``first`` of a run
     whose layers are not all of one kind: a scan over layer INDICES into
     the run's stacks (a slice of a stack would be a copy of those layers'
-    weights on every call).  What every layer has (a stack as long as the
-    run) is indexed by the layer, this kind's operator by the count of ITS
-    layers before (an operator's stacks hold no other's layers: the conv
-    weights by name, any other shorter stack is attention's), and the
-    other operator's weights are left out."""
-    conv = kind == "conv"
-    run_len = dict(cfg.layer_runs)[run]
-    at = operator_layers(cfg, run, first)[conv]
+    weights on every call).  Each stack is indexed by the count of ITS
+    layers before (`stack_kinds`: what every layer has by the layer, an
+    operator's weights by that operator's layers, a kind's own by that
+    kind's), and a stack that holds none of this kind's is left out."""
     starts = {}         # key -> this segment's first layer in its stack
-    for k, v in xs.items():
-        whose = "conv" if k in _CONV_KEYS else \
-            "all" if v.shape[0] == run_len else "attention"
-        if whose == "all":
-            starts[k] = first
-        elif (whose == "conv") == conv:
-            starts[k] = at
+    for k in xs:
+        kinds = stack_kinds(cfg, k)
+        if kinds is None or kind in kinds:
+            starts[k] = kind_layers(cfg, run, kinds, first)
 
     def step(c, j):
         lp = {k: jax.lax.dynamic_index_in_dim(xs[k], i + j, 0,

@@ -38,10 +38,14 @@ def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
 def reference_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = True,
                         sm_scale: Optional[float] = None,
-                        window: Optional[int] = None) -> jnp.ndarray:
+                        window: Optional[int] = None,
+                        sink: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Plain softmax(QKᵀ)V with fp32 statistics; the correctness oracle for
     the flash kernel and the CPU execution path.  ``window`` (causal only):
-    row i sees the keys j <= i with i - j < window."""
+    row i sees the keys j <= i with i - j < window.  ``sink`` [heads]: a
+    learned logit a query head that joins every row's softmax denominator
+    and takes no value (a row's probabilities then sum to less than 1).
+    The values' head width may differ from the queries' and keys'."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     n_rep = q.shape[2] // k.shape[2]
@@ -60,15 +64,36 @@ def reference_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         # trips an XLA partitioner CHECK ("invalid binary opcode copy");
         # adds fuse into the matmul epilogue anyway
         s = s + (1.0 - mask.astype(jnp.float32)) * -1e30
-    p = jax.nn.softmax(s, axis=-1)
+    p = sink_softmax(s, None if sink is None else sink[None, :, None, None])
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
 
-def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
+def sink_softmax(scores: jnp.ndarray, sink: Optional[jnp.ndarray]
+                 ) -> jnp.ndarray:
+    """Float32 scores [..., rows] -> probabilities over the rows.  With
+    ``sink`` (a learned logit a query head, shaped to broadcast against
+    ``scores[..., :1]``) the denominator holds ``exp(sink)`` too: the sink
+    takes no value, so a row then sums to less than 1."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    sink = sink.astype(jnp.float32)
+    m = jnp.maximum(scores.max(axis=-1, keepdims=True), sink)
+    e = jnp.exp(scores - m)
+    return e / (e.sum(axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def _flash_ok(q: jnp.ndarray, k: jnp.ndarray,
+              v: Optional[jnp.ndarray] = None,
+              sink: Optional[jnp.ndarray] = None) -> bool:
     if jax.default_backend() != "tpu":
         return False
     s_q, s_kv, d = q.shape[1], k.shape[1], q.shape[-1]
+    # the kernels take ONE head width (`flash_attention.make_plan`) and
+    # have no sink: values of another width and a sink softmax are the
+    # plain implementation's
+    if sink is not None or (v is not None and v.shape[-1] != d):
+        return False
     # heads of 64 share the 128 lanes in pairs and a multiple of 128 fills
     # them; the kernels lay out no other head size densely
     if d % 64:
@@ -111,27 +136,36 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          causal: bool = True,
                          sm_scale: Optional[float] = None,
                          impl: str = "auto",
-                         window: Optional[int] = None) -> jnp.ndarray:
-    """``window`` (a sliding-window layer, causal): a sequence no longer
+                         window: Optional[int] = None,
+                         sink: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``sink`` [heads] (a logit a query head in the softmax's denominator)
+    and values of another head width than the keys' exist in the plain
+    implementation only, as the window mask does.
+    ``window`` (a sliding-window layer, causal): a sequence no longer
     than the window is plain causal attention, whatever the implementation;
     a longer one takes the reference path, the one implementation with a
     window mask (the flash and ring kernels have none: a windowed model
     serves through the cached programs of `models/generate.py`)."""
     if window is not None and not causal:
         raise ValueError("a sliding window is a causal mask")
-    if window is not None and k.shape[1] > window:
-        if impl not in ("auto", "reference"):
-            raise NotImplementedError(
-                f"attention impl {impl!r} has no window mask; a sequence "
-                f"of {k.shape[1]} > window {window} needs 'reference'")
-        return reference_attention(q, k, v, causal=True, sm_scale=sm_scale,
-                                   window=window)
+    if window is not None and k.shape[1] <= window:
+        window = None
+    plain_only = window is not None or sink is not None \
+        or v.shape[-1] != q.shape[-1]
+    if plain_only and impl not in ("auto", "reference"):
+        raise NotImplementedError(
+            f"attention impl {impl!r} has no window mask (a sequence of "
+            f"{k.shape[1]} > window {window}), no sink and one head width "
+            f"(values of {v.shape[-1]} beside keys of {q.shape[-1]}): "
+            f"needs 'reference'")
     if impl == "auto":
-        impl = "flash" if _flash_ok(q, k) else "reference"
+        impl = "flash" if window is None and _flash_ok(q, k, v, sink) \
+            else "reference"
     if impl == "flash":
         return _flash_per_shard(q, k, v, causal=causal, sm_scale=sm_scale)
     if impl == "reference":
-        return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                                   window=window, sink=sink)
     if impl == "ring":
         # sequence-parallel path: shard_map over the ambient mesh's sp axis
         # (set the mesh with `jax.set_mesh` / `with mesh:` around the jit)

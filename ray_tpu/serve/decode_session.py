@@ -140,11 +140,17 @@ class _EngineSession:
                  "unread", "done", "error", "ended", "seq", "last_poll",
                  "prompt", "poff", "pcache", "dcache", "plogits",
                  "ready", "shed", "ptoks", "rid", "t_enq", "t_pf",
-                 "t_ready")
+                 "t_ready", "cond", "want")
 
-    def __init__(self, sid: str, prompt: Any, seq_base: int = 0,
-                 rid: str = ""):
+    def __init__(self, sid: str, prompt: Any, lock: Any,
+                 seq_base: int = 0, rid: str = ""):
         self.sid = sid
+        # what THIS session's `next_chunk` callers wait on, over the
+        # engine's one lock: a step's publish wakes a session's own
+        # caller and only when it has something to do (`wake`), not
+        # every caller of the replica for every token of every session
+        self.cond = threading.Condition(lock)
+        self.want = 0     # tokens the caller waiting in `next_chunk` asked for
         # ---- per-request phase marks (monotonic clock) ----
         self.rid = rid                # proxy-minted request id ("" = none)
         self.t_enq = time.monotonic()  # enqueued for chunked admission
@@ -183,13 +189,22 @@ class _EngineSession:
         self.ready = False            # first token produced; start() may return
         self.shed = False             # drained mid-admission: typed 503
 
+    def wake(self, was_empty: bool = False) -> None:
+        """Under the engine's lock, after a change to what a caller in
+        `next_chunk` waits for: the session's end, or new tokens.  Those
+        wake it when they are the first it can take (its linger starts)
+        or fill what it asked for; between the two it sleeps out its
+        linger on its own clock."""
+        if was_empty or self.done or len(self.queue) >= self.want:
+            self.cond.notify_all()
+
 
 class _Step(NamedTuple):
     """A fused step dispatched and not read yet."""
     batch: List[Tuple[_EngineSession, int]]   # its live sessions, each
     #                                           with the slot it held THEN
     out: Any          # [slots (+3)] int32 on the device: tokens (+ routing)
-    rows: Tuple[int, int]     # `_rows_of` its batch
+    rows: Tuple[int, ...]     # `_rows_of` its batch
     # when the chip started on it, where the host can tell: a
     # ``perf_counter`` reading (nothing was queued before it), `_BEHIND`
     # (queued right behind the step before it: when that one's read
@@ -370,7 +385,11 @@ class ContinuousBatchingEngine:
         self._cache = None            # allocated lazily on first start
         self._dcache = None           # draft slot cache (speculating)
         self._shapes: set = set()     # distinct compiled program shapes
-        self._cond = threading.Condition()
+        # ONE lock.  `_cond` is what the engine thread and the callers in
+        # `start` wait on; a caller in `next_chunk` waits on its
+        # session's own condition over the same lock
+        self._lock = threading.RLock()
+        self._cond = threading.Condition(self._lock)
         self.sessions: Dict[str, _EngineSession] = {}  # insertion = LRU
         self._pending: List[_EngineSession] = []   # prefilled, want slot
         self._prefilling: List[_EngineSession] = []
@@ -399,9 +418,14 @@ class ContinuousBatchingEngine:
         # layers (a window layer stops at its window), beside what they
         # would have attended were every layer full: `stats()["cache"]`
         # and, as `moe:load`, one ring span `cache:rows` every
-        # `_MOE_SPAN_S` seconds with the sums since the last
-        self.rows = dict.fromkeys(("steps", "rows_read", "rows_if_full"), 0)
+        # `_MOE_SPAN_S` seconds with the sums since the last; and the
+        # same in BYTES, a row costing what its layer's kind holds a
+        # position (`_row_bytes`), beside what the rows would cost were
+        # every layer a full one at the model's widest key-value heads
+        self.rows = dict.fromkeys(("steps",) + self._ROW_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
+        from ..models.generate import position_bytes
+        self._row_bytes = position_bytes(cfg)
         # fused steps dispatched, and of them those dispatched while the
         # step before them had not been read: `stats()["steps_ahead"]`
         # and one ring span `engine:ahead` every `_MOE_SPAN_S` seconds
@@ -498,8 +522,8 @@ class ContinuousBatchingEngine:
                 raise ReplicaUnavailableError(self.name)
             sid = f"{self._tag}:{self._next_sid}"
             self._next_sid += 1
-            sess = _EngineSession(sid, prompt, seq_base=seq_base,
-                                  rid=rid)
+            sess = _EngineSession(sid, prompt, self._lock,
+                                  seq_base=seq_base, rid=rid)
             sess.ptoks = ptoks or ()
             # LRU bound on ABANDONED sessions: evict the oldest
             # slot-less finished session (ended clients pop themselves)
@@ -553,6 +577,7 @@ class ContinuousBatchingEngine:
                 return {"error": f"unknown session {sid!r} (ended, "
                                  f"evicted, or never started)"}
             sess.last_poll = time.monotonic()
+            sess.want = max_tokens
             while True:
                 if sess.error is not None:
                     return {"error": sess.error, "done": True}
@@ -575,7 +600,8 @@ class ContinuousBatchingEngine:
                     wait = deadline - now
                 if wait <= 0:
                     break
-                self._cond.wait(wait)
+                sess.cond.wait(wait)
+            held = len(sess.queue) + sess.unread
             first_seq = sess.seq
             toks = [sess.queue.popleft()
                     for _ in range(min(len(sess.queue), max_tokens))]
@@ -592,8 +618,11 @@ class ContinuousBatchingEngine:
                 sess.done = True
                 sess.ended = True
                 self.sessions.pop(sid, None)
-            # draining may un-pause a slot whose queue was full
-            self._cond.notify_all()
+            if (toks and held >= self.ecfg.token_queue_depth) \
+                    or "migrating" in out:
+                # the drain un-paused a slot whose queue was full (or
+                # handed the session off): the loop may be waiting
+                self._cond.notify_all()
         return out
 
     def end(self, sid: str) -> bool:
@@ -603,6 +632,7 @@ class ContinuousBatchingEngine:
                 return False
             sess.ended = True
             sess.done = True
+            sess.wake()
             self._cond.notify_all()   # engine loop vacates the slot
         return True
 
@@ -670,9 +700,9 @@ class ContinuousBatchingEngine:
         arrays that hold ``max_len`` rows a slot; ``bytes_ring``: the
         window layers' rings; ``bytes_state``: the conv layers' states),
         what ONE further position of a slot costs (the full arrays' bytes
-        a row: a ring and a state grow with nothing), and the rows the
-        decode steps read; zeros until the first session allocates the
-        cache."""
+        a row: a ring and a state grow with nothing), and the rows and
+        bytes the decode steps read (`_ROW_SUMS`); zeros until the first
+        session allocates the cache."""
         kinds = self._cache_bytes(self._cache or {})
         return {"bytes": sum(kinds.values()),
                 **{"bytes_" + kind: n for kind, n in kinds.items()},
@@ -728,7 +758,7 @@ class ContinuousBatchingEngine:
                 self.sessions.pop(sess.sid, None)
             self._prefilling.clear()
             n = self._live_locked()
-            self._cond.notify_all()   # wake blocked next_chunk waits
+            self._wake_all_locked()   # blocked next_chunk waits too
         return n
 
     def live_sessions(self) -> int:
@@ -738,7 +768,14 @@ class ContinuousBatchingEngine:
     def shutdown(self) -> None:
         with self._cond:
             self._shutdown = True
-            self._cond.notify_all()
+            self._wake_all_locked()
+
+    def _wake_all_locked(self) -> None:
+        """The loop, the callers in `start` and every session's caller
+        in `next_chunk`: for what changes under all of them at once."""
+        self._cond.notify_all()
+        for sess in self.sessions.values():
+            sess.cond.notify_all()
 
     # ------------------------------------------------------------ engine loop
 
@@ -778,6 +815,7 @@ class ContinuousBatchingEngine:
                 if not sess.ended and now - sess.last_poll > ttl:
                     sess.done = True      # slot vacated just below
                     sess.ended = True
+                    sess.wake()
                     self.sessions.pop(sid, None)
                     self.reaped += 1
         for slot, sess in list(self._slots.items()):
@@ -1274,26 +1312,39 @@ class ContinuousBatchingEngine:
             "moe:load", "moe", self.moe, self._moe_span,
             layers=self._moe_layers, experts=self.cfg.n_experts_held)
 
-    def _rows_of(self, batch) -> Tuple[int, int]:
-        """The cache rows the live slots of a decode step about to be
-        dispatched attend (each at its position before the step, its own
-        new row included; a conv layer reads the ``conv_kernel - 1`` rows
-        of its state whatever the position), beside what they would
-        attend were every layer a full one."""
+    #: what `_rows_of` counts a step, in its order
+    _ROW_SUMS = ("rows_read", "rows_if_full", "bytes_read",
+                 "bytes_if_uniform")
+
+    def _rows_of(self, batch) -> Tuple[int, ...]:
+        """`_ROW_SUMS` of a decode step about to be dispatched: the cache
+        rows its live slots attend (each at its position before the step,
+        its own new row included; a conv layer reads the ``conv_kernel -
+        1`` rows of its state whatever the position), beside what they
+        would attend were every layer a full one; then the same in bytes:
+        a full layer's row and a window layer's at what each holds a
+        position (they differ where the kinds' key-value heads do), a conv
+        layer's state whole, beside every layer's rows at the widest of
+        the model's rows (a model of one kind of row reads 100 %)."""
         full = self.cfg.n_layers - self._window_layers - self._conv_layers
         depth = sum(s.pos + 1 for s in batch)
         seen = sum(min(s.pos + 1, self._window) for s in batch)
+        per_full, per_ring, state = (
+            self._row_bytes[k] for k in ("full", "ring", "state"))
         return (full * depth + self._window_layers * seen
                 + self._conv_layers * (self.cfg.conv_kernel - 1)
-                * len(batch), self.cfg.n_layers * depth)
+                * len(batch), self.cfg.n_layers * depth,
+                full * depth * per_full + self._window_layers * seen
+                * per_ring + self._conv_layers * state * len(batch),
+                self.cfg.n_layers * depth * max(per_full, per_ring))
 
-    def _count_rows(self, rows: Tuple[int, int]) -> None:
+    def _count_rows(self, rows: Tuple[int, ...]) -> None:
         """A read step's `_rows_of` into the counters, and the sums since
         the last `cache:rows` span into the next when due."""
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
-            self.rows["rows_read"] += rows[0]
-            self.rows["rows_if_full"] += rows[1]
+            for k, n in zip(self._ROW_SUMS, rows):
+                self.rows[k] += n
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,
             lambda: {"bytes_" + kind: n for kind, n in
@@ -1330,6 +1381,7 @@ class ContinuousBatchingEngine:
             for sess in self._slots.values():
                 sess.error = error
                 sess.done = True
+                sess.wake()
             if self._prefix is not None:
                 for slot in range(self.ecfg.max_slots):
                     self._prefix.evict(slot)
@@ -1432,10 +1484,12 @@ class ContinuousBatchingEngine:
                     s.last_tok = toks[-1]
                     tokens[s.slot] = s.last_tok
                     s.pos += n
+                    was_empty = not s.queue
                     if not s.ended:
                         s.queue.extend(toks)
                     if s.pos >= self.max_len:
                         s.done = True
+                    s.wake(was_empty)
                 self.spec_proposed += (self._spec_k - 1) * occupancy
                 self.spec_accepted += emitted - occupancy
             else:
@@ -1443,11 +1497,12 @@ class ContinuousBatchingEngine:
                     tok = int(new_toks[slot])
                     s.last_tok = tok
                     s.unread -= 1      # `pos` moved on at the dispatch
+                    was_empty = not s.queue
                     if not s.ended:
                         s.queue.append(tok)
                     if s.pos >= self.max_len and not s.unread:
                         s.done = True  # cache full: reaped next turn
-            self._cond.notify_all()
+                    s.wake(was_empty)
         if spec_out is not None:
             SERVE_SPEC_PROPOSED.inc((self._spec_k - 1) * occupancy,
                                     {"deployment": self.name})

@@ -601,3 +601,105 @@ def test_conv_states_beside_rows_copy_no_cache_no_state_no_weights(topo,
         for c in calls), calls
     assert not re.findall(
         r"= bf16\[(?:\d+,)?32,(?:2048,1792|1792,2048)\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["fused_step", "prefill_padded_128"])
+def test_two_cache_shapes_by_layer_kind_copy_no_cache_no_weights(topo,
+                                                                 program):
+    """Window layers of 8 key-value heads (rings of 128 + 128 rows, a sink
+    a query head) beside full layers of 4, keys of 192 and values of 128, at
+    the long-reasoning cell's own configuration and shapes (its file, all 1
+    + 6 layers, 32 slots x 9728): the donated program aliases all FOUR cache
+    arrays (two row shapes, two widths) and copies none of their shapes; the
+    layer loop over PART of a run indexes each kind's own key and value
+    stacks and copies no stack, nor a layer's slice of an expert stack; and
+    beside the cache there is room for activations only."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import (init_kv_cache, init_params, init_slot_cache,
+                                prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots, cache_arrays
+    c = mf.Manifest().config("mimo-v2-flash")
+    cfg = mf.family_of(c).model.model_config(c, "serve")
+    assert [s[1:] for s in cfg.layer_segments] == [
+        (0, 1, "full"), (0, 5, "window"), (5, 1, "full")]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    assert params["layers"]["wk_win"].shape == (5, 4096, 8, 192)
+    assert params["layers"]["wv"].shape == (1, 4096, 4, 128)
+    assert params["layers"]["sink"].shape == (5, 64)
+    slots, max_len = 32, 9728
+    if program == "fused_step":
+        cache = described(jax.eval_shape(
+            lambda: init_slot_cache(cfg, slots, max_len)))
+
+        def fused_step(params, tok, cache, active):
+            logits, cache, load = _decode_step_slots(params, tok[:slots],
+                                                     cache, active, cfg)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.concatenate([jnp.where(active, nxt, tok[:slots]),
+                                    jnp.stack(load)]), cache
+        lowered = jax.jit(fused_step, donate_argnums=(2,)).lower(
+            params, described(jax.ShapeDtypeStruct((slots + 3,), jnp.int32)),
+            cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    else:
+        width = int(program.rsplit("_", 1)[1])
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        padded = {"n_valid": described(jax.ShapeDtypeStruct((), jnp.int32))} \
+            if program.startswith("prefill_padded") else {}
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
+            cache, cfg=cfg, **padded)
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    arrays = cache_arrays(cache)
+    batch = slots if program == "fused_step" else 1
+    assert {n: a.shape for n, a in arrays.items()} == {
+        "k": (2, batch, 4, 192, 9728), "v": (2, batch, 4, 128, 9728),
+        "k_win": (5, batch, 8, 192, 256), "v_win": (5, batch, 8, 128, 256)}
+    want = sum(a.size * a.dtype.itemsize for a in arrays.values())
+    assert want == batch * (9728 * 5120 + 5 * 256 * 5120)
+    assert ma.alias_size_in_bytes >= want
+    # beside the cache only activations.  The float32 scores of a full
+    # layer are `[rows, 64, 9728]`: 80 MB at a step's 32 rows, 319 MB at a
+    # chunk's 128, and the compiler keeps two such arrays
+    rows = slots if program == "fused_step" else width
+    scores = rows * cfg.n_heads * max_len * 4
+    assert ma.temp_size_in_bytes < (96 << 20) + 2 * scores, \
+        ma.temp_size_in_bytes
+    text = compiled.as_text()
+    for a in arrays.values():
+        shape = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    # no kind's key stack, nor the queries' or the output's, whole or a
+    # layer's slice, is copied.  A layer's VALUE projection is: the
+    # compiler lays `[4096, 4 | 8, 128]` out by tiles of its few heads and
+    # wants another order for the dot, one 4-8 MB copy a layer (three
+    # instructions, one of them in the window layers' loop: 0.1 ms of a
+    # step; PERF.md section 7)
+    for shape in (r"(?:\d+,)?4096,[48],192", r"(?:\d+,)?4096,64,192",
+                  r"(?:\d+,)?64,128,4096"):
+        assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    assert len(re.findall(r"= bf16\[(?:\d+,)?4096,[48],128\]\S* copy\(",
+                          text)) <= 3
+    # three grouped matmuls a segment of expert layers, each given the whole
+    # stack of 6 x 16 experts; no slice of it is copied out
+    calls = _grouped_calls(text)
+    assert len(calls) == 6 and all(
+        re.search(r"bf16\[6,16,(4096,2048|2048,4096)\]", c)
+        for c in calls), calls
+    assert not re.findall(
+        r"= bf16\[(?:\d+,)?16,(?:4096,2048|2048,4096)\]\S* copy\(", text)
