@@ -18,6 +18,7 @@ import uuid
 from typing import Dict, Optional
 
 from . import accelerator
+from .config import GlobalConfig
 
 
 def sessions_base() -> str:
@@ -198,8 +199,13 @@ class LocalCluster:
                 except Exception:
                     pass
         # A worker that sees its nodelet gone writes its span file and
-        # exits, within milliseconds: give it those before the sweep.
-        deadline = time.monotonic() + 0.5
+        # exits: within milliseconds, but for a serve replica, whose ring
+        # and whose programs' op maps take 0.5-0.7 s (PERF.md, PR 41: the
+        # half second given here before cut the chip holder's files off
+        # in one run of three).  Give it the grace a stopping nodelet
+        # gives its workers before the sweep; the wait ends with the last
+        # process.
+        deadline = time.monotonic() + GlobalConfig.worker_shutdown_grace_s
         while time.monotonic() < deadline and any(
                 pid != os.getpid()
                 for pid in session_processes(self.session_dir)):

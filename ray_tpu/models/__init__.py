@@ -20,6 +20,8 @@ from .generate import (  # noqa: F401
     prefill_chunk,
     prefill_chunk_jit,
     prefill_chunked,
+    prefill_lanes,
+    prefill_lanes_jit,
     verify_step_slots,
 )
 from .transformer import (  # noqa: F401

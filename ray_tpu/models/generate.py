@@ -285,21 +285,39 @@ def _ring_mask(pos, c: int, ring: int, window: int) -> jnp.ndarray:
     return (held >= 0) & (held <= q) & (q - held < window)
 
 
-def _ring_write_chunk(pos, c: int, ring: int):
-    """→ ``write(c_all, l, cols [B, heads, width, c])`` for a chunk whose
-    rows all start at the scalar ``pos``: column ``(pos + i) mod ring`` gets
-    token i.  A chunk that straddles the seam cannot be one slice, and a
-    branch on it would copy the ring, so EVERY chunk is two read-modify-
-    write slices of ``c`` columns: one that ends no later than the seam,
-    one that starts at column 0 (it rewrites what is there where the chunk
-    does not wrap)."""
+def _full_write_chunk(pos, lane=0, live=None):
+    """→ ``write(c_all, l, cols [1 | B, heads, width, c])``: the chunk's
+    columns as ONE slice from column ``pos`` of a full layer's array, from
+    batch row ``lane`` on.  ``live`` (a traced bool; None: always) False
+    leaves the array bit for bit as it was: the lane stands."""
+
+    @jax.named_scope("cache_write")
+    def write(c_all, l, cols):
+        at = (l, lane, 0, 0, pos)
+        if live is not None:
+            cols = jnp.where(live, cols, jax.lax.dynamic_slice(
+                c_all, at, (1,) + cols.shape)[0])
+        return jax.lax.dynamic_update_slice(c_all, cols[None], at)
+
+    return write
+
+
+def _ring_write_chunk(pos, c: int, ring: int, lane=0, live=None):
+    """→ ``write(c_all, l, cols [1 | B, heads, width, c])`` for a chunk whose
+    rows all start at the scalar ``pos`` (batch rows ``lane`` on): column
+    ``(pos + i) mod ring`` gets token i.  A chunk that straddles the seam
+    cannot be one slice, and a branch on it would copy the ring, so EVERY
+    chunk is two read-modify-write slices of ``c`` columns: one that ends no
+    later than the seam, one that starts at column 0 (it rewrites what is
+    there where the chunk does not wrap).  ``live`` as `_full_write_chunk`'s:
+    False and no column takes a token."""
     r = pos % ring
 
     @jax.named_scope("cache_write")
     def write(c_all, l, cols):
-        if c == 1:
+        if c == 1 and live is None:
             return jax.lax.dynamic_update_slice(c_all, cols[None],
-                                                (l, 0, 0, 0, r))
+                                                (l, lane, 0, 0, r))
         if c > ring:
             raise ValueError(f"chunk of {c} over a ring of {ring} rows")
         twice = jnp.concatenate([cols, cols], axis=-1)
@@ -309,11 +327,13 @@ def _ring_write_chunk(pos, c: int, ring: int):
         #  offsets that take a token)
         for start, k, takes in ((a, (a - r) % c, o >= r - a),
                                 (0, (ring - r) % c, o + (ring - r) < c)):
+            if live is not None:
+                takes = takes & live
             new = jax.lax.dynamic_slice_in_dim(twice, k, c, axis=-1)[None]
             old = jax.lax.dynamic_slice(
-                c_all, (l, 0, 0, 0, start), new.shape)
+                c_all, (l, lane, 0, 0, start), new.shape)
             c_all = jax.lax.dynamic_update_slice(
-                c_all, jnp.where(takes, new, old), (l, 0, 0, 0, start))
+                c_all, jnp.where(takes, new, old), (l, lane, 0, 0, start))
         return c_all
 
     return write
@@ -381,9 +401,39 @@ def _rotators(turn, angles: Dict[str, Any]) -> Dict[str, Any]:
             for kind, (cos, sin) in angles.items()}
 
 
+@jax.named_scope("attention")
+def _lane_of(c_all: jnp.ndarray, l, lane: int) -> jnp.ndarray:
+    """Batch row ``lane`` of layer ``l``: [1, heads, width, rows], held to
+    the cache's own row-major layout.  Left to itself the compiler cuts the
+    row out inside the dot that reads it and hands that dot the WHOLE cache
+    in the layout it would like: a copy of the cache a lane (v5e compiler,
+    heads of 128)."""
+    return with_layout_constraint(
+        jax.lax.dynamic_slice(c_all, (l, lane, 0, 0, 0),
+                              (1, 1) + c_all.shape[2:])[0],
+        Layout(major_to_minor=tuple(range(4))))
+
+
+@jax.named_scope("attention")
+def _by_lane(live: jnp.ndarray, attend, operands) -> jnp.ndarray:
+    """``attend(*operands(lane)) -> [1, ...]`` for every batch row whose
+    ``live`` [B] is set, zeros for the others, stacked [B, ...]: a row that
+    stands costs its operands alone (`lax.cond`), and what ``attend`` builds
+    on the way (a chunk's float32 scores) is one row's at a time.  The
+    operands are cut out of the batch OUTSIDE the branch: a branch handed
+    the whole cache is handed it in the layout its dot would like, a copy
+    of the cache (v5e compiler, key-value heads of 128)."""
+    rows = [operands(lane) for lane in range(live.shape[0])]
+    like = jax.eval_shape(attend, *rows[0])
+    return jnp.concatenate([jax.lax.cond(
+        live[lane], attend,
+        lambda *_: jnp.zeros(like.shape, like.dtype), *row)
+        for lane, row in enumerate(rows)], axis=0)
+
+
 def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                    cache: KVCache, *, rotate, write, mask, valid=None,
-                   n_new=None):
+                   n_new=None, lanes=None):
     """Run ``x`` [B, C, D] (C new tokens per row) through every layer
     against the cache: each attention layer writes the new tokens' columns
     (``write[kind](c_all, l, cols [B, heads, width, C]) -> c_all``) into
@@ -394,7 +444,11 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     (None: all C): the row's real tokens, none for a row that stands.
     ``rotate[kind]`` applies the caller's rotary angles at that kind's
     base (absent: the kind rotates nothing); ``valid`` [B, C] marks the
-    rows a no-drop expert layer routes (None: all).
+    rows a no-drop expert layer routes (None: all).  ``lanes`` [B] bool
+    (None: the whole batch in one piece) makes the ATTENTION, the one part
+    that reads no weight, a batch row's at a time and only where it is set
+    (`_by_lane`); everything that reads a weight still runs once over the
+    ``B x C`` stacked rows.
     → (final-norm activations, arrays, load)."""
     dt = cfg.dtype
     b, c, _ = x.shape
@@ -407,20 +461,28 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         q, k_new, v_new = _qkv(cfg, y, lp, rotate.get(kind), kind)
         k_all = write[kind](arrs[kn], l, _as_columns(k_new, arrs[kn].dtype))
         v_all = write[kind](arrs[vn], l, _as_columns(v_new, arrs[vn].dtype))
-        with jax.named_scope("attention"):
-            ck, cv = _layer_of(k_all, l), _layer_of(v_all, l)
+        sink = lp["sink"].reshape(hk, h // hk, 1) \
+            if kind in cfg.sink_kinds else None
+
+        @jax.named_scope("attention")
+        def heads(q, ck, cv, m):     # the batch's rows, or one of them
             # GQA: group query heads over kv heads
-            qh = q.reshape(b, c, hk, h // hk, hd)
+            qh = q.reshape(-1, c, hk, h // hk, hd)
             scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
                                 ck.astype(dt)) / jnp.sqrt(float(hd))
-            scores = jnp.where(mask[kind][:, :, None, None, :], scores,
-                               -1e30)
-            probs = sink_softmax(
-                scores.astype(jnp.float32), lp["sink"].reshape(
-                    hk, h // hk, 1) if kind in cfg.sink_kinds else None)
+            scores = jnp.where(m[:, :, None, None, :], scores, -1e30)
+            probs = sink_softmax(scores.astype(jnp.float32), sink)
             attn = jnp.einsum("bskgt,bkdt->bskgd", probs.astype(dt),
                               cv.astype(dt))
-            attn = attn.reshape(b, c, h, cv.shape[-2])
+            return attn.reshape(-1, c, h, cv.shape[-2])
+
+        if lanes is None:
+            attn = heads(q, _layer_of(k_all, l), _layer_of(v_all, l),
+                         mask[kind])
+        else:
+            attn = _by_lane(lanes, heads, lambda p: (
+                q[p:p + 1], _lane_of(k_all, l, p), _lane_of(v_all, l, p),
+                mask[kind][p:p + 1]))
         return (_attn_out(cfg, y, attn, lp),
                 dict(arrs, **{kn: k_all, vn: v_all}))
 
@@ -434,8 +496,19 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                           rotate=rotate[kind])
         kv_all = write[kind](arrs["kv"], l, _as_columns(
             new[:, :, None, :], arrs["kv"].dtype))
-        out = mla.attend_absorbed(q_nope, q_rope, _layer_of(kv_all, l)[:, 0],
-                                  lp["wkv_b"], lp["wo"], mask[kind])
+        if lanes is None:
+            out = mla.attend_absorbed(
+                q_nope, q_rope, _layer_of(kv_all, l)[:, 0], lp["wkv_b"],
+                lp["wo"], mask[kind])
+        else:
+            q_abs = mla.absorb(q_nope, q_rope, lp["wkv_b"])
+            scale = float(np.sqrt(cfg.qk_nope_head_dim
+                                  + cfg.qk_rope_head_dim))
+            out = mla.unabsorb(_by_lane(
+                lanes, functools.partial(mla.attend_latents, scale=scale),
+                lambda p: (q_abs[p:p + 1], _lane_of(kv_all, l, p)[:, 0],
+                           mask[kind][p:p + 1])),
+                lp["wkv_b"], lp["wo"], cfg.qk_nope_head_dim)
         return out, {"kv": kv_all}
 
     def conv(y, lp, arrs, l, kind):
@@ -595,12 +668,7 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
         mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
     mask = {"full": mask[None]}
 
-    @jax.named_scope("cache_write")
-    def write_full(c_all, l, cols):
-        return jax.lax.dynamic_update_slice(c_all, cols[None],
-                                            (l, 0, 0, 0, pos))
-
-    write = {"full": write_full}
+    write = {"full": _full_write_chunk(pos)}
     if "window" in cfg.kinds:
         ring = window_ring(cfg, max_len)
         mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)[None]
@@ -642,6 +710,18 @@ def chunk_window(off: int, n: int, chunk: int,
     return start, min(n - start, chunk)
 
 
+def _window_of(cfg: TransformerConfig, n: int, off: int, chunk: int,
+               capacity: int) -> Tuple[int, int]:
+    """`chunk_window` for a model whose every state can run tokens twice:
+    a window set back before ``off`` over conv layers is refused."""
+    start, n_valid = chunk_window(off, n, chunk, capacity)
+    if start != off:
+        _check_state_rewind(cfg, "a chunk window set back at the cache's "
+                                 "end (the prompt ends within one chunk "
+                                 "of max_len)")
+    return start, n_valid
+
+
 def padded_chunk(tokens, start: int, n_valid: int, chunk: int):
     """Host tokens ``[B, n]`` (numpy) → the chunk program's ``[B, chunk]``
     int32 input: ``tokens[:, start:start + n_valid]``, zeros behind."""
@@ -655,13 +735,14 @@ def padded_chunk(tokens, start: int, n_valid: int, chunk: int):
 #: REUSED program).  The cache argument is DONATED: the program extends it
 #: in place and the caller's handle is dead after the call — rebind to the
 #: returned cache.  Its callers: :func:`prefill_chunk_step` — the serve
-#: engine's admission and failover resume (serve/decode_session.py
-#: `_prefill_advance`, one step between decode steps) and
-#: :func:`prefill_chunked` (the whole prefix at once) — always passes
+#: engine's admission and failover resume of a session that prefills
+#: ALONE (serve/decode_session.py `_prefill_advance`, one step between
+#: decode steps; two or more at once go through :data:`prefill_lanes_jit`)
+#: and :func:`prefill_chunked` (the whole prefix at once) — always passes
 #: ``n_valid`` (a whole chunk passes ``chunk``, a prompt's remainder its
-#: length), so a replica compiles ONE prefill shape per model config,
-#: [B, chunk], no matter how many prompts, resumes, or admissions of
-#: whatever length it serves.  The benchmark's outputs check (perfbench,
+#: length), so a replica compiles ONE batch-1 prefill shape per model
+#: config, [B, chunk], no matter how many prompts, resumes, or admissions
+#: of whatever length it serves.  The benchmark's outputs check (perfbench,
 #: `_verify`) walks a prompt through this handle on its own, without
 #: ``n_valid``: the unpadded program of the shape it is given, which is
 #: also what :func:`decode_step` traces (a chunk of one).
@@ -680,11 +761,8 @@ def prefill_chunk_step(fn, params: Params, tokens: np.ndarray, off: int,
     starts before ``off`` (it would have passed ``capacity``) the cache's
     ``pos`` is set back to it, and the overlapped tokens run again, which
     rewrites what their columns hold."""
-    start, n_valid = chunk_window(off, tokens.shape[1], chunk, capacity)
+    start, n_valid = _window_of(cfg, tokens.shape[1], off, chunk, capacity)
     if start != off:
-        _check_state_rewind(cfg, "a chunk window set back at the cache's "
-                                 "end (the prompt ends within one chunk "
-                                 "of max_len)")
         cache = dict(cache, pos=np.int32(start))
     logits, cache = fn(params, padded_chunk(tokens, start, n_valid, chunk),
                        cache, cfg=cfg, n_valid=np.int32(n_valid))
@@ -799,6 +877,34 @@ def _rotate_slots(x: jnp.ndarray, cos: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+def _row_inputs(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
+                cfg: TransformerConfig, max_len: int):
+    """What a program whose batch rows sit at DIFFERENT positions gives
+    `_attend_cached`: ``tokens`` [S, C] fed at ``pos`` [S] .. ``pos + C - 1``
+    → (embedded ``x`` [S, C, D], each row's rotary angles by kind [S, C, 1,
+    ·] for `_rotate_slots`, ``mask[kind]`` [S, C, rows]: the cached positions
+    (of a ring: the columns, by the position each holds once the C tokens
+    are written) visible to fed token i of row s)."""
+    c = tokens.shape[1]
+    dt = cfg.dtype
+    posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
+    with jax.named_scope("embed"):
+        x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
+        if cfg.pos_emb == "learned":
+            x = x + params["embed"]["pos"][posm].astype(dt)
+    with jax.named_scope("projections"):
+        angles = rope_tables(cfg, lambda base: tuple(
+            t[posm][:, :, None, :]
+            for t in rotary_angles(max_len, cfg.rope_dim, base)))
+    with jax.named_scope("attention"):
+        mask = {"full": jnp.arange(max_len)[None, None, :]
+                <= posm[:, :, None]}
+    if "window" in cfg.kinds:
+        mask["window"] = _ring_mask(pos, c, window_ring(cfg, max_len),
+                                    cfg.sliding_window)
+    return x, angles, mask
+
+
 def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
                    cfg: TransformerConfig,
                    active: Optional[jnp.ndarray] = None):
@@ -824,19 +930,9 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     if c > 1:     # only a verify feeds a slot more than its one token
         _check_state_rewind(cfg, "a speculative verify (its rejected "
                                  "tokens are fed all the same)")
-    dt = cfg.dtype
     pos = cache["pos"]                                         # [S]
     max_len = cache_capacity(cache)
-    posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
-    with jax.named_scope("embed"):
-        x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
-        if cfg.pos_emb == "learned":
-            x = x + params["embed"]["pos"][posm].astype(dt)
-    with jax.named_scope("projections"):
-        # each slot's rows of each rotating kind's table: [S, C, 1, ·]
-        angles = rope_tables(cfg, lambda base: tuple(
-            t[posm][:, :, None, :]
-            for t in rotary_angles(max_len, cfg.rope_dim, base)))
+    x, angles, mask = _row_inputs(params, tokens, pos, cfg, max_len)
 
     def column_writes(column):
         @jax.named_scope("cache_write")
@@ -849,16 +945,11 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
             return c_all
         return write
 
-    # mask[s, i, t]: cached position t visible to fed token i of slot s
-    with jax.named_scope("attention"):
-        mask = {"full": jnp.arange(max_len)[None, None, :]
-                <= posm[:, :, None]}
     write = {"full": column_writes(lambda p: p)}
     if "window" in cfg.kinds:
         # a ring has no end to be clamped onto: position p is column p mod
         # ring, and a column is masked by the position it holds
         ring = window_ring(cfg, max_len)
-        mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)
         write["window"] = column_writes(lambda p: p % ring)
     valid = None if active is None else \
         jnp.broadcast_to(active[:, None], (s, c))
@@ -867,6 +958,112 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         rotate=_rotators(_rotate_slots, angles),
         write=write, mask=mask, valid=valid,
         n_new=None if active is None else active.astype(jnp.int32) * c)
+
+
+def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
+                   cfg: TransformerConfig, n_valid: jnp.ndarray):
+    """`_prefill_chunk` for up to P SESSIONS AT ONCE: ``tokens`` [P, C], a
+    padded chunk a LANE, over a lane cache that is a slot cache of P rows
+    (`init_slot_cache`; per-lane ``pos`` [P], each lane's first position),
+    ``n_valid`` [P] int32 the real rows of each lane's chunk, 0 .. C
+    → (logits [P, vocab], cache', load).
+
+    A lane is `_prefill_chunk`'s batch-1 program in everything that is its
+    own: its logits are row ``n_valid - 1``'s, its ``pos`` advances by
+    ``n_valid``, its padded rows route to no expert, a conv state advances
+    by its real rows, and its columns are ONE chunk-wide slice an array a
+    layer (a ring's two read-modify-write slices).  A lane with ``n_valid``
+    0 STANDS: it writes nothing (every array of it stays bit for bit),
+    routes nothing, skips its attention, and its logits mean nothing.  What
+    the lanes share is every read of a weight: the embedding, the
+    projections, the dense and routed FFNs and the head run once over the
+    ``P x C`` stacked rows, so P sessions' chunks cost one pass over the
+    weights where P batch-1 programs cost P.  The attention, which reads
+    no weight, stays a lane's (`_by_lane`): its float32 scores are ``[C,
+    heads, max_len]`` whatever P."""
+    _check_decodable(cfg)
+    lanes, c = tokens.shape
+    _check_chunk(cfg, c)
+    pos = cache["pos"]                                         # [P]
+    max_len = cache_capacity(cache)
+    live = n_valid > 0
+    x, angles, mask = _row_inputs(params, tokens, pos, cfg, max_len)
+
+    def lane_writes(one):
+        def write(c_all, l, cols):                # [P, heads, width, C]
+            for lane in range(lanes):
+                c_all = one(lane)(c_all, l, cols[lane:lane + 1])
+            return c_all
+        return write
+
+    write = {"full": lane_writes(lambda p: _full_write_chunk(
+        pos[p], p, live[p]))}
+    if "window" in cfg.kinds:
+        ring = window_ring(cfg, max_len)
+        write["window"] = lane_writes(lambda p: _ring_write_chunk(
+            pos[p], c, ring, p, live[p]))
+    x, arrays, load = _attend_cached(
+        cfg, params, x, cache, rotate=_rotators(_rotate_slots, angles),
+        write=write, mask=mask,
+        valid=jnp.arange(c)[None, :] < n_valid[:, None],
+        n_new=n_valid, lanes=live)
+    last = jnp.take_along_axis(
+        x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
+    return _last_logits(params, last, cfg), dict(
+        arrays, pos=pos + n_valid.astype(pos.dtype)), load
+
+
+def prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
+                  cfg: TransformerConfig, n_valid: jnp.ndarray
+                  ) -> Tuple[jnp.ndarray, KVCache]:
+    """:func:`_prefill_lanes` → (logits [P, vocab], cache')."""
+    logits, cache, _ = _prefill_lanes(params, tokens, cache, cfg, n_valid)
+    return logits, cache
+
+
+def _lanes_program(params, tokens, cache, cfg, n_valid):
+    return prefill_lanes(params, tokens, cache, cfg, n_valid)
+
+
+# A trace and the compile ledger know a program by its function's name
+# (``jit_prefill_chunk``), and the lanes program IS the chunk program of
+# more than one session: what reads the one reads the other.
+_lanes_program.__name__ = "prefill_chunk"
+
+#: The chunk program of SEVERAL sessions (serve/decode_session.py, while two
+#: or more prompts prefill): module-level and donating as
+#: :data:`prefill_chunk_jit`, and under its name in a trace.
+prefill_lanes_jit = jax.jit(_lanes_program, static_argnames=("cfg",),
+                            donate_argnames=("cache",))
+
+
+def prefill_lanes_step(fn, params: Params, prompts, cache: KVCache,
+                       cfg: TransformerConfig, *, chunk: int, capacity: int):
+    """ONE step of the host walk of up to P prompts through the lanes
+    program ``fn`` (:data:`prefill_lanes_jit`, or a wrap of it), as
+    :func:`prefill_chunk_step` walks one: ``prompts[p]`` is ``(tokens [1,
+    n] on the host, off)`` for a lane that advances, None for one that
+    stands → ``(logits [P, vocab], cache', moved)`` with ``moved[p]`` =
+    ``(new offset, n_valid)`` or None.  Each lane's window and padding are
+    `chunk_window`'s and `padded_chunk`'s, and the host passes every lane's
+    first position: the cache's ``pos`` is SET to the windows' starts (a
+    standing lane's to 0; nothing of it is read or written)."""
+    buf = np.zeros((len(prompts), chunk), np.int32)
+    starts = np.zeros(len(prompts), np.int32)
+    n_valid = np.zeros(len(prompts), np.int32)
+    for p, lane in enumerate(prompts):
+        if lane is None:
+            continue
+        tokens, off = lane
+        starts[p], n_valid[p] = _window_of(cfg, tokens.shape[1], off, chunk,
+                                           capacity)
+        buf[p] = padded_chunk(tokens, starts[p], n_valid[p], chunk)
+    logits, cache = fn(params, buf, dict(cache, pos=starts), cfg=cfg,
+                       n_valid=n_valid)
+    return logits, cache, [
+        None if lane is None else (int(starts[p] + n_valid[p]),
+                                   int(n_valid[p]))
+        for p, lane in enumerate(prompts)]
 
 
 def decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,

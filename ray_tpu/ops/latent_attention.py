@@ -97,19 +97,47 @@ def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     ``mask`` [b|1, s, T] which positions each query may see -> [b, s, d].
     The probabilities meet all ``kv_lora + rope`` cached rows (the rotary
     rows' part of the result is dropped): slicing the latent rows out of
-    the cache first would copy them."""
-    dt = q_nope.dtype
-    nope, rope = q_nope.shape[-1], q_rope.shape[-1]
-    kv_lora = wkv_b.shape[0]
-    w = wkv_b.astype(dt)
-    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w[..., :nope])
-    q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
+    the cache first would copy them.  Its three parts stand alone for a
+    caller that attends a row of the batch at a time BETWEEN the two that
+    read weights: `absorb`, `attend_latents`, `unabsorb`."""
+    nope = q_nope.shape[-1]
+    o_lat = attend_latents(absorb(q_nope, q_rope, wkv_b), cached, mask,
+                           math.sqrt(nope + q_rope.shape[-1]))
+    return unabsorb(o_lat, wkv_b, wo, nope)
+
+
+@jax.named_scope("attention")
+def absorb(q_nope: jnp.ndarray, q_rope: jnp.ndarray, wkv_b) -> jnp.ndarray:
+    """Queries [b, s, h, nope] and [b, s, h, rope] (rotated) -> [b, s, h,
+    kv_lora + rope]: the key up-projection folded into the query."""
+    nope = q_nope.shape[-1]
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope,
+                       wkv_b.astype(q_nope.dtype)[..., :nope])
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+@jax.named_scope("attention")
+def attend_latents(q_abs: jnp.ndarray, cached: jnp.ndarray,
+                   mask: jnp.ndarray, scale: float) -> jnp.ndarray:
+    """Absorbed queries [b, s, h, kv_lora + rope] over ``cached`` [b, kv_lora
+    + rope, T] under ``mask``, scores over ``scale`` -> [b, s, h, kv_lora +
+    rope]: the part that reads the cache, and no weight."""
+    dt = q_abs.dtype
     scores = jnp.einsum("bshr,brt->bsht", q_abs, cached.astype(dt),
                         preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(nope + rope)
+    scores = scores / scale
     scores = jnp.where(mask[:, :, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bsht,brt->bshr", probs.astype(dt), cached.astype(dt))
-    attn = jnp.einsum("bshr,rhv->bshv", o_lat[..., :kv_lora], w[..., nope:])
+    return jnp.einsum("bsht,brt->bshr", probs.astype(dt), cached.astype(dt))
+
+
+@jax.named_scope("attention")
+def unabsorb(o_lat: jnp.ndarray, wkv_b, wo, nope: int) -> jnp.ndarray:
+    """`attend_latents`' [b, s, h, kv_lora + rope] -> the block's [b, s, d]:
+    the value up-projection on the latent rows, then the output's."""
+    dt = o_lat.dtype
+    kv_lora = wkv_b.shape[0]
+    attn = jnp.einsum("bshr,rhv->bshv", o_lat[..., :kv_lora],
+                      wkv_b.astype(dt)[..., nope:])
     with jax.named_scope("projections"):
         return jnp.einsum("bshv,hvd->bsd", attn, wo.astype(dt))

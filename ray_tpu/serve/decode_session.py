@@ -37,7 +37,7 @@ input on the host from what it accepted, so it reads every step at once.
 Two model-side optimisations compound inside the loop:
 
 - **Chunked-prefill admission**: a joining session's prompt is
-  consumed ``[1, chunk]`` tokens at a time between shared decode steps
+  consumed ``chunk`` tokens at a time between shared decode steps
   (``DecodeEngineConfig.prefill_chunk_tokens``; unset, the widest power
   of two under the chip's ridge point: :func:`prefill_chunk_width`), so
   a join stalls live streams by at most one chunk interval, about two
@@ -47,9 +47,15 @@ Two model-side optimisations compound inside the loop:
   program of the same width, padded, the count of its real tokens a
   traced argument (`models.generate.prefill_chunk_step`).  Admission
   and failover resume (``op: resume``) dispatch the SAME module-level
-  chunk program (`models.prefill_chunk_jit`) — one compiled prefill
-  shape per model, whatever the traffic, and no prompt length compiles
-  anything.
+  chunk program (`models.prefill_chunk_jit`, ``[1, chunk]``), and no
+  prompt length compiles anything.  ONE program a loop turn, however
+  many sessions join: while two or more prompts prefill, up to
+  :func:`prefill_lane_count` of them (arrival order) advance in one
+  program of ``[lanes, chunk]`` over a lane cache
+  (`models.prefill_lanes_jit`), which reads every weight once for the
+  lot; the others wait with no cache at all.  Two compiled prefill
+  shapes per model, whatever the traffic, both run once by the engine
+  itself before it serves its first session (`_warm_lanes`).
 - **Speculative decoding** (``DecodeEngineConfig.spec_draft`` /
   ``spec_k``): a draft model proposes k tokens per iteration in one
   scanned dispatch (`models.draft_propose_slots`) and the target
@@ -94,15 +100,30 @@ def _shutdown_engines() -> None:
             pass
 
 
+def _weights_ridge(params: Any, device_kind: Optional[str]
+                   ) -> Optional[float]:
+    """The chip's ridge point in rows for weights of ``params``' mean item
+    size (`util.device_profile.ridge_rows`: 240.5 for bfloat16 on a v5e);
+    None on a backend with no published peaks."""
+    import jax
+
+    from ..util.device_profile import ridge_rows
+    leaves = jax.tree_util.tree_leaves(params)
+    n = sum(int(a.size) for a in leaves)
+    itemsize = sum(int(a.size) * a.dtype.itemsize
+                   for a in leaves) / max(1, n)
+    return ridge_rows(itemsize, device_kind)
+
+
 def prefill_chunk_width(pinned: Optional[int], params: Any,
                         capacity: int,
                         device_kind: Optional[str] = None) -> int:
     """The width of the ONE chunk program: ``pinned`` where a caller set
     ``DecodeEngineConfig.prefill_chunk_tokens``, else the largest power
     of two not above the chip's ridge point for weights of ``params``'
-    item size (`util.device_profile.ridge_rows`: 240.5 rows for bfloat16
-    on a v5e, so 128), else, on a backend with no published peaks, 32;
-    never more than ``capacity``.
+    item size (`_weights_ridge`: 240.5 rows for bfloat16 on a v5e, so
+    128), else, on a backend with no published peaks, 32; never more than
+    ``capacity``.
 
     Under the ridge a program's matmuls are reads of the weights they
     touch, so prefill tokens a second grow with the width while a
@@ -112,26 +133,43 @@ def prefill_chunk_width(pinned: Optional[int], params: Any,
     model, PERF.md, PR 31).  Past it time grows with the rows.
     ``device_kind`` is the attached device's unless a test names one."""
     import math
-
-    import jax
-
-    from ..util.device_profile import ridge_rows
     if pinned is not None:
         width = int(pinned)
     else:
-        leaves = jax.tree_util.tree_leaves(params)
-        n = sum(int(a.size) for a in leaves)
-        itemsize = sum(int(a.size) * a.dtype.itemsize
-                       for a in leaves) / max(1, n)
-        ridge = ridge_rows(itemsize, device_kind)
+        ridge = _weights_ridge(params, device_kind)
         width = 2 ** math.floor(math.log2(ridge)) if ridge else 32
     return min(max(1, width), capacity)
+
+
+#: lanes of the chunk program on a backend with no published peaks
+_LANES_UNKNOWN_CHIP = 4
+
+
+def prefill_lane_count(chunk: int, params: Any,
+                       device_kind: Optional[str] = None) -> int:
+    """How many joining sessions ONE chunk program advances
+    (`models.generate.prefill_lanes`), from what `prefill_chunk_width`
+    reads: the power of two nearest to TWICE the ridge over the chunk (4
+    lanes of 128 rows for bfloat16 on a v5e, 512 rows), no more than 8.
+
+    Stacked rows read the weights once for the lot, so under the ridge a
+    further lane is nearly free; past it the dense matmuls cost what they
+    cost apart and only the routed experts (whose rows spread over many
+    experts) still gain, while a lane that stands pays its rows of them.
+    Measured on a v5e at 128 rows (PERF.md, PR 41): 2, 4 and 8 lanes."""
+    import math
+    ridge = _weights_ridge(params, device_kind)
+    if not ridge:
+        return _LANES_UNKNOWN_CHIP
+    return min(8, max(2, 2 ** round(math.log2(2.0 * ridge / chunk))))
 
 
 class _EngineSession:
     """One live session inside the engine, through three phases:
     *prefilling* (the engine thread consumes its prompt one fixed-shape
-    chunk program at a time, between decode steps), *waiting* (prompt
+    chunk at a time, between decode steps: alone on a batch-1 cache of
+    its own, in a LANE of the engine's lane cache while others prefill
+    beside it, or queued for a lane with no cache at all), *waiting* (prompt
     fully prefilled into a batch-1 cache, first token produced, queued
     for a free slot), and *decoding* (cache inserted into its slot of
     the shared batched cache)."""
@@ -140,7 +178,7 @@ class _EngineSession:
                  "unread", "done", "error", "ended", "seq", "last_poll",
                  "prompt", "poff", "pcache", "dcache", "plogits",
                  "ready", "shed", "ptoks", "rid", "t_enq", "t_pf",
-                 "t_ready", "cond", "want")
+                 "t_ready", "cond", "want", "lane")
 
     def __init__(self, sid: str, prompt: Any, lock: Any,
                  seq_base: int = 0, rid: str = ""):
@@ -184,6 +222,9 @@ class _EngineSession:
         self.prompt = prompt          # [1, S] int32 on the HOST (numpy)
         self.poff = 0                 # tokens consumed so far
         self.pcache: Any = None       # target batch-1 cache being built
+        # ... or its row of the engine's lane cache, while two or more
+        # sessions prefill (`ContinuousBatchingEngine._lanes_advance`)
+        self.lane: Optional[int] = None
         self.dcache: Any = None       # draft batch-1 cache (speculating)
         self.plogits: Any = None      # last chunk's final-position logits
         self.ready = False            # first token produced; start() may return
@@ -236,8 +277,9 @@ class ContinuousBatchingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models import (cache_insert_slot, draft_propose_slots,
-                              prefill_chunk_jit, verify_step_slots)
+        from ..models import (cache_gather_slot, cache_insert_slot,
+                              draft_propose_slots, prefill_chunk_jit,
+                              prefill_lanes_jit, verify_step_slots)
         from ..models.generate import (_decode_step_slots, cache_arrays,
                                        cache_bytes)
         self._cache_arrays, self._cache_bytes = cache_arrays, cache_bytes
@@ -304,13 +346,13 @@ class ContinuousBatchingEngine:
         # slot and prefills only the unshared suffix.  Engine-thread
         # only, like the slot cache itself.
         self._prefix = None
-        self._gather = None
         if getattr(engine_cfg, "prefix_cache", True):
-            from ..models import cache_gather_slot
             from .prefix_cache import PrefixIndex
             self._prefix = PrefixIndex()
-            self._gather = self._prof.wrap("prefix_gather",
-                                           jax.jit(cache_gather_slot))
+        # one row of a slot-batched cache as a batch-1 cache: a donor's
+        # prefix out of the slot cache, a finished prompt out of its lane
+        self._gather = self._prof.wrap("prefix_gather",
+                                       jax.jit(cache_gather_slot))
         self.prefix_hits = 0          # admissions seeded from a donor
         self.prefix_tokens_reused = 0  # prefill tokens skipped
         self._last_metrics_push = 0.0
@@ -321,6 +363,10 @@ class ContinuousBatchingEngine:
         # second timer over it.
         self._chunk = self._prof.wrap(
             "prefill_chunk", self._counting_copies(prefill_chunk_jit, 2))
+        # ... and the chunk program of SEVERAL sessions, under the same
+        # name in the profiler, the compile ledger and a trace
+        self._chunk_lanes = self._prof.wrap(
+            "prefill_chunk", self._counting_copies(prefill_lanes_jit, 2))
         # ---- speculative decoding ----
         self._spec = False
         self._draft_cfg = None
@@ -371,6 +417,16 @@ class ContinuousBatchingEngine:
         self.ecfg = dataclasses.replace(
             engine_cfg, prefill_chunk_tokens=prefill_chunk_width(
                 engine_cfg.prefill_chunk_tokens, params, room))
+        # ---- lanes: ONE chunk program for up to `_n_lanes` joining
+        # sessions.  The lane cache (a slot cache of that many rows) is
+        # held only while two or more sessions prefill; `_lane_sess[i]`
+        # is who holds lane i.  A speculating engine builds a draft cache
+        # beside every target cache: it keeps one program a session.
+        self._n_lanes = 0 if self._spec else prefill_lane_count(
+            self.ecfg.prefill_chunk_tokens, params)
+        self._pool: Any = None
+        self._lane_sess: List[Optional[_EngineSession]] = \
+            [None] * self._n_lanes
         self._window = cfg.sliding_window if "window" in cfg.kinds else 0
         self._window_layers = cfg.kinds.count("window")
         # layers whose state is no positions: the last conv_kernel - 1
@@ -402,7 +458,14 @@ class ContinuousBatchingEngine:
         self.steps = 0
         self.tokens = 0
         self.reaped = 0          # sessions evicted by the idle reaper
-        self.prefill_chunks = 0  # chunk programs run for admissions
+        # chunks of prompts consumed (one a session a program), and the
+        # chunk programs that consumed them: as many while prompts prefill
+        # one at a time, up to `_n_lanes` chunks a program while several
+        # do.  One ring span `engine:lanes` every `_MOE_SPAN_S` seconds
+        # carries both since the last.
+        self.prefill_chunks = 0
+        self.prefill_programs = 0
+        self._lanes_span = {"programs": 0, "chunks": 0, "t": time.time()}
         # ... of them those with fewer real tokens than the chunk holds
         # (a prompt's remainder), and the padding rows those carried
         self.prefill_tails = 0
@@ -484,11 +547,13 @@ class ContinuousBatchingEngine:
               ptoks: Optional[tuple] = None,
               rid: str = "") -> Dict[str, Any]:
         """Enqueue one batch-1 prompt for chunked admission and block
-        until the ENGINE THREAD has prefilled it — `[1, chunk]` blocks
-        (the remainder one more of them, padded) interleaved between
+        until the ENGINE THREAD has prefilled it — blocks of ``chunk``
+        tokens (the remainder one more of them, padded), alone in a
+        `[1, chunk]` program or beside other joining sessions' in a
+        `[lanes, chunk]` one (`_admit_and_prefill`), interleaved between
         shared decode steps, so a joining session never stalls live
         streams by more than one chunk interval and admission reuses
-        the one compiled chunk shape of failover resume.  ``prompt`` is
+        the compiled chunk shape of failover resume.  ``prompt`` is
         [1, S] token ids, taken to the HOST: the engine fills each
         chunk's buffer from it (a slice of a device array would be a
         small program of its own per length).  Returns the sid and
@@ -652,6 +717,10 @@ class ContinuousBatchingEngine:
                     # them was read (0 for a speculating engine)
                     "steps_ahead": self.ahead["steps_ahead"],
                     "prefill_chunks": self.prefill_chunks,
+                    # the programs that ran them (fewer where lanes
+                    # engaged), and the lanes of one
+                    "prefill_programs": self.prefill_programs,
+                    "prefill_lanes": self._n_lanes,
                     # the rows of each (`prefill_chunk_width`)
                     "prefill_chunk_tokens":
                         self.ecfg.prefill_chunk_tokens,
@@ -799,7 +868,7 @@ class ContinuousBatchingEngine:
         finally:
             if self._shutdown:
                 self.params = self._draft_params = None
-                self._cache = self._dcache = None
+                self._cache = self._dcache = self._pool = None
                 self._carry = self._flight = self._active_dev = None
 
     def _reap_locked(self) -> None:
@@ -954,65 +1023,91 @@ class ContinuousBatchingEngine:
         return not self._window or sess.pos <= self._window or (
             sess.pos <= depth + 1 and depth + chunk <= self._capacity)
 
-    def _prefill_advance(self, sess: _EngineSession) -> Optional[int]:
-        """Run ONE fixed-shape chunk program of a joining session's
-        prompt (target + draft when speculating) on the engine thread —
-        interleaved between shared decode steps, so admission stalls
-        live streams by at most one chunk interval instead of a whole
-        prompt.  Returns the session's first token once the prompt is
-        fully consumed, else None."""
+    def _seed_cache(self, sess: _EngineSession) -> None:
+        """The batch-1 cache a session's prompt starts from: the longest
+        prefix it shares with a live slot's prompt where one is on offer
+        (``poff`` = its depth), else zeros."""
         import jax.numpy as jnp
 
-        from ..core.runtime_metrics import SERVE_PREFILL_CHUNKS
         from ..models import init_kv_cache
-        from ..models.generate import prefill_chunk_step
-        if sess.pcache is None:
-            seeded = False
-            if self._prefix is not None and sess.ptoks:
-                # shared-prefix admission: the longest prefix this
-                # prompt shares with a LIVE slot's prompt is already in
-                # the slot cache — copy those K/V rows (one compiled
-                # gather, slot + depth traced) and prefill only the
-                # unshared suffix.  Cap at len-1: the last prompt
-                # token's logits must be recomputed to emit the first
-                # token.
-                donor, depth = self._prefix.longest_match(
-                    sess.ptoks, cap=len(sess.ptoks) - 1)
-                # an indexed donor is valid whether its session is
-                # still decoding or ended: entries are only replaced
-                # when the slot is reassigned, and freed slots' rows
-                # below the match depth are never written in between
-                if donor is not None and \
-                        depth >= max(1, self.ecfg.prefix_cache_min_tokens) \
-                        and self._prefix_exact(donor, depth,
-                                               len(sess.ptoks)):
-                    from ..core.runtime_metrics import (
-                        SERVE_PREFIX_HITS, SERVE_PREFIX_TOKENS_REUSED)
-                    sess.pcache = self._gather(self._cache,
+        if self._prefix is not None and sess.ptoks:
+            # shared-prefix admission: the longest prefix this prompt
+            # shares with a LIVE slot's prompt is already in the slot
+            # cache — copy those K/V rows (one compiled gather, slot +
+            # depth traced) and prefill only the unshared suffix.  Cap at
+            # len-1: the last prompt token's logits must be recomputed to
+            # emit the first token.
+            donor, depth = self._prefix.longest_match(
+                sess.ptoks, cap=len(sess.ptoks) - 1)
+            # an indexed donor is valid whether its session is still
+            # decoding or ended: entries are only replaced when the slot
+            # is reassigned, and freed slots' rows below the match depth
+            # are never written in between
+            if donor is not None and \
+                    depth >= max(1, self.ecfg.prefix_cache_min_tokens) \
+                    and self._prefix_exact(donor, depth, len(sess.ptoks)):
+                from ..core.runtime_metrics import (
+                    SERVE_PREFIX_HITS, SERVE_PREFIX_TOKENS_REUSED)
+                sess.pcache = self._gather(self._cache, jnp.int32(donor),
+                                           jnp.int32(depth))
+                if self._spec:
+                    sess.dcache = self._gather(self._dcache,
                                                jnp.int32(donor),
                                                jnp.int32(depth))
-                    if self._spec:
-                        sess.dcache = self._gather(self._dcache,
-                                                   jnp.int32(donor),
-                                                   jnp.int32(depth))
-                    sess.poff = depth
-                    seeded = True
-                    with self._cond:   # stats() reads these counters
-                        self.prefix_hits += 1
-                        self.prefix_tokens_reused += depth
-                    self._shape_seen("prefix_gather", 1)
-                    SERVE_PREFIX_HITS.inc(tags={"deployment": self.name})
-                    SERVE_PREFIX_TOKENS_REUSED.inc(
-                        depth, tags={"deployment": self.name})
-            if not seeded:
-                sess.pcache = init_kv_cache(self.cfg, 1, self.max_len)
-                if self._spec:
-                    sess.dcache = init_kv_cache(self._draft_cfg, 1,
-                                                self.max_len)
+                sess.poff = depth
+                with self._cond:   # stats() reads these counters
+                    self.prefix_hits += 1
+                    self.prefix_tokens_reused += depth
+                self._shape_seen("prefix_gather", 1)
+                SERVE_PREFIX_HITS.inc(tags={"deployment": self.name})
+                SERVE_PREFIX_TOKENS_REUSED.inc(
+                    depth, tags={"deployment": self.name})
+                return
+        sess.pcache = init_kv_cache(self.cfg, 1, self.max_len)
+        if self._spec:
+            sess.dcache = init_kv_cache(self._draft_cfg, 1, self.max_len)
+
+    def _count_chunks(self, riders: List[Tuple[_EngineSession, int]],
+                      wall: float) -> None:
+        """ONE chunk program that took ``wall`` seconds of the engine
+        thread and carried ``riders``: (session, real rows of its chunk)."""
+        from ..core.runtime_metrics import SERVE_PREFILL_CHUNKS
         chunk = self.ecfg.prefill_chunk_tokens
-        if sess.t_pf is None:          # queue phase ends at the first
-            sess.t_pf = time.monotonic()  # chunk program of the prompt
-            self.phase_s["queue"] += sess.t_pf - sess.t_enq
+        now = time.monotonic()
+        for sess, _ in riders:
+            if sess.t_pf is None:      # queue phase ends at the first
+                sess.t_pf = now        # chunk program of the prompt
+                self.phase_s["queue"] += sess.t_pf - sess.t_enq
+        # a prompt's remainder, padded: its share of the program
+        tails = [n for _, n in riders if n < chunk]
+        self.phase_s["prefill_tail"] += wall * len(tails) / len(riders)
+        self._prof.note_tokens("prefill_chunk", sum(n for _, n in riders))
+        with self._cond:   # stats() reads these counters
+            self.prefill_programs += 1
+            self.prefill_chunks += len(riders)
+            self.prefill_tails += len(tails)
+            self.prefill_pad_tokens += sum(chunk - n for n in tails)
+        self._lanes_span = self._sums_span(
+            "engine:lanes", "lanes",
+            {"programs": self.prefill_programs,
+             "chunks": self.prefill_chunks}, self._lanes_span)
+        SERVE_PREFILL_CHUNKS.inc(len(riders),
+                                 tags={"deployment": self.name})
+
+    def _prefill_advance(self, sess: _EngineSession) -> Optional[int]:
+        """Run ONE fixed-shape chunk program of ONE joining session's
+        prompt over its own batch-1 cache (target + draft when
+        speculating) on the engine thread — interleaved between shared
+        decode steps, so admission stalls live streams by at most one
+        chunk interval instead of a whole prompt.  Returns the session's
+        first token, still on the device, once the prompt is fully
+        consumed, else None."""
+        import jax.numpy as jnp
+
+        from ..models.generate import prefill_chunk_step
+        if sess.pcache is None:
+            self._seed_cache(sess)
+        chunk = self.ecfg.prefill_chunk_tokens
         wall0 = self._prof.wall_of("prefill_chunk")
         # ONE shape per model: whole chunks, then the remainder as one
         # more, padded, its count of real tokens a traced argument
@@ -1026,21 +1121,162 @@ class ContinuousBatchingEngine:
                 self._chunk, self._draft_params, sess.prompt, off,
                 sess.dcache, self._draft_cfg, **window)
             self._shape_seen("draft_prefill_chunk", 1, chunk)
-        tail = n_valid < chunk      # a prompt's remainder, padded
-        if tail:
-            self.phase_s["prefill_tail"] += \
-                self._prof.wall_of("prefill_chunk") - wall0
-        self._prof.note_tokens("prefill_chunk", n_valid)
-        with self._cond:   # stats() reads these counters
-            self.prefill_chunks += 1
-            if tail:
-                self.prefill_tails += 1
-                self.prefill_pad_tokens += chunk - n_valid
-        SERVE_PREFILL_CHUNKS.inc(tags={"deployment": self.name})
+        self._count_chunks([(sess, n_valid)],
+                           self._prof.wall_of("prefill_chunk") - wall0)
         if sess.poff < int(sess.prompt.shape[1]):
             return None
-        return int(jnp.argmax(sess.plogits, axis=-1)
-                   .astype(jnp.int32)[0])
+        return jnp.argmax(sess.plogits, axis=-1).astype(jnp.int32)[0]
+
+    # -------------------------------------------------- lanes: one chunk
+    # program for several joining sessions
+
+    def _free_lanes_locked(self) -> None:
+        """Lanes whose session prefills no longer (ready, ended, reaped,
+        shed, failed) are free, and with nobody left prefilling the lane
+        cache goes: an idle engine holds what it held without lanes."""
+        for lane, sess in enumerate(self._lane_sess):
+            if sess is not None and sess not in self._prefilling:
+                self._lane_sess[lane] = sess.lane = None
+        if not self._prefilling:
+            self._pool = None
+
+    def _enter_lane(self, sess: _EngineSession, lane: int) -> None:
+        """``sess`` takes ``lane``: the batch-1 cache it has (a lone
+        session overtaken by a second one) or starts from (`_seed_cache`:
+        a donor's prefix, or zeros, which also clear what the lane's last
+        holder left in a conv state) goes into the lane by the slot
+        insert, its ``pos`` with it."""
+        import jax.numpy as jnp
+
+        from ..models import init_slot_cache
+        if sess.pcache is None:
+            self._seed_cache(sess)
+        if self._pool is None:
+            self._pool = init_slot_cache(self.cfg, self._n_lanes,
+                                         self.max_len)
+        self._pool = self._insert(self._pool, sess.pcache, jnp.int32(lane))
+        self._shape_seen("lane_insert", self._n_lanes)
+        sess.pcache = None
+        sess.lane, self._lane_sess[lane] = lane, sess
+
+    def _leave_lane(self, sess: _EngineSession) -> None:
+        """``sess``'s lane as the batch-1 cache that `_prefill_advance`
+        and `_admit_locked` take (the slot gather, truncated to what the
+        session has consumed); the lane is free at once."""
+        import jax.numpy as jnp
+        sess.pcache = self._gather(self._pool, jnp.int32(sess.lane),
+                                   jnp.int32(sess.poff))
+        self._shape_seen("lane_gather", self._n_lanes)
+        self._lane_sess[sess.lane] = sess.lane = None
+
+    def _fail_prefill(self, sess: _EngineSession, e: Exception) -> None:
+        with self._cond:
+            sess.error = f"chunked prefill failed: {e!r}"
+            sess.done = True
+            sess.ready = True
+            sess.pcache = sess.dcache = sess.plogits = None
+            self._cond.notify_all()
+
+    def _lanes_advance(self, prefills: List[_EngineSession], fi
+                       ) -> List[Tuple[_EngineSession, Any, int]]:
+        """ONE chunk program for every session that holds a lane, after
+        the sessions without one have taken the free lanes in arrival
+        order (the others wait in `_prefilling` with no cache at all)
+        → the sessions whose prompt it consumed, each with the program's
+        first tokens (on the device) and where its own stands in them,
+        and, as ``pcache``, its lane gathered out.  A session that
+        cannot enter or move (a window that would rewind a conv state)
+        fails alone; a program that raises fails the sessions in it and
+        takes the lane cache with it (it was donated)."""
+        import jax.numpy as jnp
+
+        from ..models.generate import _window_of, prefill_lanes_step
+        chunk = self.ecfg.prefill_chunk_tokens
+        for sess in prefills:
+            try:
+                if sess.lane is None:
+                    if None not in self._lane_sess:
+                        continue
+                    self._enter_lane(sess, self._lane_sess.index(None))
+                _window_of(self.cfg, int(sess.prompt.shape[1]), sess.poff,
+                           chunk, self._capacity)
+            except Exception as e:
+                if sess.lane is not None:
+                    self._lane_sess[sess.lane] = sess.lane = None
+                self._fail_prefill(sess, e)
+        riders = [s for s in self._lane_sess if s is not None]
+        if not riders:
+            return []
+        wall0 = self._prof.wall_of("prefill_chunk")
+        try:
+            self._chaos_site("serve.prefill_chunk", fi)
+            logits, self._pool, moved = prefill_lanes_step(
+                self._chunk_lanes, self.params,
+                [None if s is None else (s.prompt, s.poff)
+                 for s in self._lane_sess], self._pool, self.cfg,
+                chunk=chunk, capacity=self._capacity)
+        except Exception as e:
+            self._pool = None
+            for sess in riders:
+                self._lane_sess[sess.lane] = sess.lane = None
+                self._fail_prefill(sess, e)
+            return []
+        self._shape_seen("prefill_chunk", self._n_lanes, chunk)
+        for sess in riders:
+            sess.poff = moved[sess.lane][0]
+        self._count_chunks([(s, moved[s.lane][1]) for s in riders],
+                           self._prof.wall_of("prefill_chunk") - wall0)
+        done = [s for s in riders if s.poff >= int(s.prompt.shape[1])]
+        if not done:
+            return []
+        firsts = jnp.argmax(logits, axis=-1)       # ONE read, later
+        ready = [(sess, firsts, sess.lane) for sess in done]
+        for sess in done:
+            self._leave_lane(sess)
+        return ready
+
+    def _warm_lanes(self) -> None:
+        """Before the first session is served, once EVERY program and
+        shape the lane path uses: the lane cache's zeros, a batch-1 cache
+        inserted into a lane, the lanes program (every lane standing: it
+        changes nothing), the first tokens' read, a lane gathered out.
+        Whoever warms a replica sends it one session, which cannot reach
+        a path that takes two; with this a second prompt that joins a
+        prefilling one compiles nothing.  An engine that cannot run the
+        lane path (no room for the lane cache) goes on with one program a
+        session."""
+        import numpy as np
+
+        import jax
+        import jax.numpy as jnp
+
+        from ..models import init_kv_cache, init_slot_cache
+        from ..models.generate import prefill_lanes_step
+        from ..util import tracing
+        if self._n_lanes < 2:
+            return
+
+        def bare(fn):    # not traffic: the profiler's ledgers stay its own
+            return getattr(fn, "_rt_profiled_inner", fn)
+
+        t0 = time.time()
+        try:
+            pool = bare(self._insert)(
+                init_slot_cache(self.cfg, self._n_lanes, self.max_len),
+                init_kv_cache(self.cfg, 1, self.max_len), jnp.int32(0))
+            logits, pool, _ = prefill_lanes_step(
+                bare(self._chunk_lanes), self.params,
+                [None] * self._n_lanes, pool, self.cfg,
+                chunk=self.ecfg.prefill_chunk_tokens,
+                capacity=self._capacity)
+            np.asarray(jnp.argmax(logits, axis=-1))
+            jax.block_until_ready(
+                bare(self._gather)(pool, jnp.int32(0), jnp.int32(0)))
+        except Exception as e:
+            self._n_lanes, self._lane_sess = 0, []
+            tracing.record_span(
+                f"serve_lanes_off::{self.name}", "serve", t0, time.time(),
+                error=repr(e), deployment=self.name)
 
     def _chaos_site(self, site: str, fi) -> None:
         """Chaos site ``serve.<what>``: an armed rule sleeps here
@@ -1115,6 +1351,11 @@ class ContinuousBatchingEngine:
         # at once); the plain engine's carry lives on the device
         tokens = np.zeros(slots + (3 if self._moe_layers else 0), np.int32)
         self._carry = self._fresh_carry()
+        self._warm_lanes()
+        # the ring spans' sums count from here, not from the warm-up
+        for last in (self._moe_span, self._rows_span, self._ahead_span,
+                     self._lanes_span):
+            last["t"] = time.time()
 
         def phase(name: str):
             """One of the engine thread's flat, non-overlapping phases:
@@ -1135,6 +1376,7 @@ class ContinuousBatchingEngine:
                             s for s in self._prefilling
                             if not (s.ready or s.done or s.ended
                                     or s.shed)]
+                        self._free_lanes_locked()
                         admitted = self._admit_locked()
                         prefills = ([] if self._draining
                                     else list(self._prefilling))
@@ -1153,9 +1395,10 @@ class ContinuousBatchingEngine:
             # ---- device work, OUTSIDE the lock (nobody else touches
             # the slot cache, and client ops must not stall on compute)
             t0 = time.time()
+            handed = []
             if admitted or prefills:
                 with phase("admit"):
-                    self._admit_and_prefill(admitted, prefills)
+                    handed = self._admit_and_prefill(admitted, prefills, fi)
             spec_out = None
             if batch and self._spec and not self._spec_disabled:
                 try:
@@ -1177,13 +1420,21 @@ class ContinuousBatchingEngine:
                 self._carry = None   # the host owns the carry again: a
                 #                      failed iteration degrades to the
                 #                      plain step below
-            step = new_toks = None
+            step = new_toks = failed = None
             try:
                 if batch and spec_out is None:
                     with phase("dispatch"):
                         step = self._dispatch(
                             batch, active, tokens, admitted,
                             alone=not (admitted or prefills))
+            except Exception as e:
+                failed = e
+            if handed:     # the step is queued behind the chunk programs
+                with phase("admit"):
+                    self._hand_over(handed)
+            try:
+                if failed is not None:
+                    raise failed
                 if not self._spec:
                     # ONE STEP AHEAD: what is read now is the step before
                     # the one just queued, and the chip works on through
@@ -1392,40 +1643,72 @@ class ContinuousBatchingEngine:
         self._cache = init_slot_cache(self.cfg, self.ecfg.max_slots,
                                       self.max_len)
 
-    def _admit_and_prefill(self, admitted, prefills) -> None:
+    def _admit_and_prefill(self, admitted, prefills, fi
+                           ) -> List[Tuple[_EngineSession, Any, int]]:
         """The host side of admission, on the engine thread outside the
-        lock: slot inserts of the sessions `_admit_locked` placed, ONE
-        chunk program per joining session (the prompt is consumed
-        BETWEEN decode steps, never ahead of the live batch), and the
-        hand-over of every session whose first token now exists."""
-        import jax.numpy as jnp
+        lock: slot inserts of the sessions `_admit_locked` placed and the
+        turn's chunk programs (a prompt is consumed BETWEEN decode steps,
+        never ahead of the live batch) → the sessions whose prompt the
+        LANES program consumed, for `_hand_over`: that program and the
+        gathers behind it are dispatched here and nothing of them read,
+        so the turn's decode step goes out behind them first.
 
-        from ..util import tracing
+        How many programs follows from what the loop sees, no option:
+        ONE session prefilling runs the batch-1 chunk program over its own
+        cache; TWO OR MORE share ONE program over the lane cache, up to
+        `_n_lanes` of them in arrival order (`_lanes_advance`), which
+        reads every weight once for the lot; when one is left it goes back
+        to a batch-1 cache and the lane cache is dropped, so a lone prompt
+        never pays for lanes that stand.  A speculating engine (a draft
+        cache beside every target cache) keeps one program a session."""
+        import jax.numpy as jnp
         for sess, pcache, dcache, slot in admitted:
             self._cache = self._insert(self._cache, pcache,
                                        jnp.int32(slot))
             if self._spec and dcache is not None:
                 self._dcache = self._insert(self._dcache, dcache,
                                             jnp.int32(slot))
-        ready: List[Tuple[_EngineSession, int]] = []
+        if self._n_lanes >= 2 and len(prefills) >= 2:
+            return self._lanes_advance(prefills, fi)
+        ready = []
         for sess in prefills:
             try:
+                if sess.lane is not None:   # the last of several
+                    self._leave_lane(sess)
+                self._pool = None           # nobody holds a lane now
                 first = self._prefill_advance(sess)
                 if first is not None:
-                    ready.append((sess, first))
+                    ready.append((sess, first, ()))
             except Exception as e:
-                with self._cond:
-                    sess.error = f"chunked prefill failed: {e!r}"
-                    sess.done = True
-                    sess.ready = True
-                    sess.pcache = sess.dcache = sess.plogits = None
-                    self._cond.notify_all()
-        if not ready:
-            return
+                self._fail_prefill(sess, e)
+        # a prompt that prefilled alone is handed over at once, as ever:
+        # its caller's first token does not wait for the step's dispatch
+        self._hand_over(ready)
+        return []
+
+    def _hand_over(self, ready: List[Tuple[_EngineSession, Any, int]]
+                   ) -> None:
+        """Every session whose prompt this turn's chunk programs consumed
+        gets its first token and goes on to wait for a slot.  Here the
+        host READS the first tokens (``firsts[at]`` on the device, one
+        array a program); for a lanes program that is behind the dispatch
+        of the turn's decode step: the chip works on the step while the
+        host waits for the chunk program, takes the lock and wakes the
+        callers.  A chunk program that failed on the device surfaces in
+        this read."""
+        import numpy as np
+
+        from ..util import tracing
+        firsts = []
+        for sess, dev, at in ready:
+            try:
+                firsts.append((sess, int(np.asarray(dev)[at])))
+            except Exception as e:
+                self._fail_prefill(sess, e)
         now_mono = time.monotonic()
         now_wall = time.time()
         with self._cond:
-            for sess, first in ready:
+            for sess, first in firsts:
                 sess.t_ready = now_mono
                 self.phase_s["first_token"] += now_mono - sess.t_enq
                 # per-request admission span (wall clock, like every

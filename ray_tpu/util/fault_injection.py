@@ -72,6 +72,12 @@ site                        actions
                             that holds a slot fails once and the engine
                             serves on from a fresh cache; ``delay``
                             stretches the read
+``serve.prefill_chunk``     ``error`` fails the chunk program that advances
+                            several joining sessions at once (the lanes
+                            program, serve/decode_session.py): the sessions
+                            in it fail, each with its own error, the lane
+                            cache goes with them, and those that waited for
+                            a lane prefill on; ``delay`` stretches it
 ``serve.session_failover``  attacks decode-stream RECOVERY itself
                             (serve/failover.py): ``error`` fails the
                             resume (the stream surfaces the in-band
@@ -222,6 +228,7 @@ KNOWN_SITES: Dict[str, Optional[frozenset]] = {
     "serve.autoscale": frozenset({"drop", "error", "fail"}),
     "serve.spec_verify": frozenset({"error", "fail"}),
     "serve.decode_step": frozenset({"error", "fail"}),
+    "serve.prefill_chunk": frozenset({"error", "fail"}),
     "serve.slo_eval": frozenset({"error", "fail"}),
     "drain.evacuate": None,
     "drain.deadline": None,

@@ -56,9 +56,16 @@ def test_rehearsal_walks_every_phase_and_fails_without_a_chip():
     assert by_phase["serve_reference"]["worst_logit_gap"] <= 0.1
     assert by_phase["serve_reference"]["equals_generate_share"] == 1.0
     assert serve["prompt_lens"] == [8, 20, 37, 64]
-    # the decode step and ONE chunk shape: prompts of 8-64 tokens, whole
-    # chunks and padded remainders alike
-    assert serve["distinct_program_shapes"] == 2
+    # the decode step and ONE chunk width: prompts of 8-64 tokens, whole
+    # chunks and padded remainders alike, a session's alone or, where the
+    # prompts prefill together, up to four sessions' in the lanes program
+    # (with the insert into a lane and the gather out of one)
+    assert len(serve["program_shapes"]) == serve["distinct_program_shapes"]
+    assert {"decode_step", "prefill_chunk"} <= {
+        s.split(":")[0] for s in serve["program_shapes"]} <= {
+        "decode_step", "prefill_chunk", "lane_insert", "lane_gather"}
+    assert len({s.split("x")[-1] for s in serve["program_shapes"]
+                if s.startswith("prefill_chunk:")}) == 1
     # the hand-over: the training worker's process was gone before the
     # replica started, and the replica is another process
     assert by_phase["handover"]["pid"] == train["pid"] != serve["pid"]

@@ -12,7 +12,8 @@ import pytest
 from ray_tpu.models import TransformerConfig
 from ray_tpu.serve.config import DecodeEngineConfig
 from ray_tpu.serve.decode_session import (DecodeSessionCore,
-                                          prefill_chunk_width)
+                                          prefill_chunk_width,
+                                          prefill_lane_count)
 from ray_tpu.util.device_profile import (PEAK_HBM_GBPS, PEAK_TFLOPS,
                                          ridge_rows)
 
@@ -58,6 +59,23 @@ def test_ridge_rows_has_no_default():
 ])
 def test_the_rule(pinned, dtype, capacity, kind, want):
     got = prefill_chunk_width(pinned, _weights(dtype), capacity, kind)
+    assert got == want and type(got) is int
+
+
+@pytest.mark.parametrize("chunk,dtype,kind,want", [
+    (128, jnp.bfloat16, "TPU v5 lite", 4),    # 2 x 240.5 / 128 = 3.8
+    (256, jnp.float32, "TPU v5 lite", 4),     # 2 x 481.1 / 256
+    (128, jnp.bfloat16, "TPU v5p", 2),        # 2 x 166.0 / 128 = 2.6
+    (512, jnp.bfloat16, "TPU v6 lite", 2),    # 2 x 559.8 / 512 = 2.2
+    (32, jnp.bfloat16, "TPU v5 lite", 8),     # a pinned narrow chunk: capped
+    (1024, jnp.bfloat16, "TPU v5 lite", 2),   # a pinned wide one: at least 2
+    (32, jnp.bfloat16, None, 4),              # no peaks here
+])
+def test_the_lanes_follow_the_ridge_over_the_chunk(chunk, dtype, kind, want):
+    """`prefill_lane_count`: the power of two nearest to twice the ridge
+    over the chunk, from 2 to 8 (measured on a v5e at 128 rows: 2, 4 and 8
+    lanes, `PERF.md` section 6, PR 41); 4 where no peaks are published."""
+    got = prefill_lane_count(chunk, _weights(dtype), kind)
     assert got == want and type(got) is int
 
 

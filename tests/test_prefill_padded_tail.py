@@ -332,9 +332,10 @@ def test_no_prompt_length_compiles_once_the_engine_is_warm(name):
     """What the benchmark's ``compiles_in_window`` counts: after one
     session (a whole chunk, a remainder, slot insert, a step) and one
     seeded admission, prompts of every other length, one seeded at the
-    capacity's edge and a resume compile NOTHING: chunks are filled on
-    the host, the count of real tokens is traced, and a rewound ``pos`` is
-    the same argument to the program."""
+    capacity's edge, a resume and several prompts started together compile
+    NOTHING: chunks are filled on the host, the count of real tokens is
+    traced, a rewound ``pos`` is the same argument to the program, and the
+    lane path was warmed by the engine at its loop's start."""
     from jax import monitoring
     toks = _model(name)[2][0]
     count = [None]            # None: not counting yet
@@ -364,9 +365,46 @@ def test_no_prompt_length_compiles_once_the_engine_is_warm(name):
         rr = core.handle({"op": "resume", "prompt": warm,
                           "generated": [1, 2, 3]})
         core.handle({"op": "end", "sid": rr["sid"]})
-        assert core.handle({"op": "stats"})["engine"]["prefix"][
-            "applied_hits"] >= 2
-        assert count[0] == 0
+        # several prompts AT ONCE: the lanes program, the lane cache, the
+        # insert into a lane and the gather out of one were run by the
+        # engine itself before it served the warm-up session
+        import threading
+        import time
+        eng = core.engine
+        before = core.handle({"op": "stats"})["engine"]
+
+        def held(real):      # no chunk program before all three are there
+            def program(*args, **kwargs):
+                give_up = time.monotonic() + 60
+                while not all_there and time.monotonic() < give_up:
+                    if len(eng._prefilling) == 3:
+                        all_there.append(True)
+                    time.sleep(0.002)
+                return real(*args, **kwargs)
+            return program
+
+        all_there = []
+
+        eng._chunk, eng._chunk_lanes = held(eng._chunk), held(
+            eng._chunk_lanes)
+        together = [[int(t) % 190 + 7 * i + 1 for t in toks[:28 + i]]
+                    for i in range(3)]
+        got = {}
+        callers = [threading.Thread(
+            target=lambda i=i: got.update({i: _stream(core, together[i], 2)}),
+            daemon=True) for i in range(3)]
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(120)
+        compiled, count[0] = count[0], None    # the reference compiles
+        for i, prompt in enumerate(together):
+            assert got[i][1] == _reference_stream(name, prompt, 2)
+        st = core.handle({"op": "stats"})["engine"]
+        assert st["prefill_chunks"] - before["prefill_chunks"] == 12
+        assert st["prefill_programs"] - before["prefill_programs"] < 12
+        assert st["prefix"]["applied_hits"] >= 2
+        assert compiled == 0
     finally:
         core.engine.shutdown()
         from jax._src import monitoring as _m

@@ -439,6 +439,315 @@ def test_step_fault_with_a_step_queued_behind_it(chaos_cleanup):
         eng.shutdown()
 
 
+# ------------------------------------------------------------ lanes
+#
+# While two or more prompts prefill, ONE chunk program advances up to
+# `prefill_lanes` of them over the engine's lane cache; a lone prompt keeps
+# the batch-1 program over its own cache.  Every stream below is held to
+# the whole-prompt greedy reference, every lane is free again at the end
+# and the lane cache gone.
+
+def _prompt(i, n):
+    return [(7 * i + 3 * j) % 200 + 1 for j in range(n)]
+
+
+class _Together:
+    """Callers that `start` at once, each on a thread of its own: the
+    engine's chunk programs wait for a permit each (`step`), so a test
+    decides what the loop has seen before its next program."""
+
+    def __init__(self, core, **more):
+        self.core, self.eng = core, core.engine
+        sid, _ = self._stream(_prompt(99, 11), 2)     # warm: every program
+        core.handle({"op": "end", "sid": sid})
+        _wait(lambda: self.eng._flight is None and not self.eng._slots,
+              "the warm-up session never left")
+        self.base = dict(self.eng.stats())
+        self.permits = threading.Semaphore(0)
+        self.ran, self.came = [], 0      # programs run; come to the gate
+        for attr in ("_chunk", "_chunk_lanes"):
+            setattr(self.eng, attr, self._held(attr, getattr(self.eng, attr)))
+        self.out, self.threads = {}, []
+
+    def _held(self, attr, real):
+        def program(*args, **kwargs):
+            self.came += 1
+            while not self.permits.acquire(timeout=0.2):
+                assert not self.eng._shutdown, "shut down at the gate"
+            self.ran.append(attr)
+            return real(*args, **kwargs)
+        return program
+
+    def _stream(self, prompt, want):
+        r = self.core.handle({"op": "start", "prompt": prompt})
+        return r["sid"], _drain(self.core, r["sid"], list(r["token"]), want)
+
+    def start(self, prompts, want=5):
+        def call(i, prompt):
+            try:
+                self.out[i] = self._stream(prompt, want)[1]
+            except Exception as e:
+                self.out[i] = e
+        for prompt in prompts:
+            t = threading.Thread(target=call, daemon=True,
+                                 args=(len(self.threads), prompt))
+            self.threads.append(t)
+            t.start()
+            _wait(lambda: self.session(prompt) is not None,
+                  "a caller never enqueued")
+            self._settle()     # the loop has seen it, or stands at the gate
+
+    def _settle(self):
+        """Until the loop stands at the gate with its next chunk program,
+        or has no prompt left to prefill."""
+        def quiet():
+            with self.eng._cond:
+                return not any(not (s.ready or s.done)
+                               for s in self.eng._prefilling)
+        _wait(lambda: self.came > len(self.ran) or quiet(),
+              "the loop never came to the gate")
+
+    def step(self, n=1):
+        """Let ``n`` chunk programs run, one at a time."""
+        for _ in range(n):
+            before = len(self.ran)
+            self.permits.release()
+            _wait(lambda: len(self.ran) > before, "no program ran")
+            self._settle()
+
+    def session(self, prompt):
+        with self.eng._cond:
+            return next((s for s in self.eng.sessions.values()
+                         if s.ptoks == tuple(prompt)), None)
+
+    def finish(self):
+        self.permits.release(10_000)
+        for t in self.threads:
+            t.join(120)
+            assert not t.is_alive()
+        eng = self.eng
+        _wait(lambda: not eng._prefilling and eng._pool is None
+              and not any(eng._lane_sess), "a lane was never freed")
+        st = eng.stats()
+        assert st["cache_copies"] == 0
+        return {k: st[k] - self.base[k]
+                for k in ("prefill_chunks", "prefill_programs")}
+
+
+def _lane_core(max_len=64, **engine):
+    engine.setdefault("max_slots", 3)
+    engine.setdefault("prefill_chunk_tokens", 4)
+    return _core(max_len=max_len, **engine)
+
+
+def _greedy(prompt, n, max_len=64):
+    return greedy_stream(_tiny_cfg(), prompt, n, max_len=max_len, seed=3)
+
+
+def test_more_sessions_than_lanes_stream_what_each_streams_alone():
+    """Seven prompts of 5 to 30 tokens at once over 4 lanes and 3 slots:
+    the first runs its first chunks alone on a batch-1 cache and enters a
+    lane by the slot insert when the others join, three wait for a lane
+    with no cache at all, finished lanes leave by the slot gather and wait
+    for a slot as ever; fewer programs than chunks.  A lone session before
+    and after them: a program a chunk, on the batch-1 program."""
+    core = _lane_core()
+    try:
+        t = _Together(core)
+        assert t.eng.stats()["prefill_lanes"] == 4
+        assert t.base["prefill_programs"] == t.base["prefill_chunks"] == 3
+        prompts = [_prompt(i, n)
+                   for i, n in enumerate((23, 9, 30, 14, 5, 27, 18))]
+        t.start(prompts[:1])
+        t.step()                          # its first chunk: alone
+        first = t.session(prompts[0])
+        assert t.ran == ["_chunk"] and first.pcache is not None
+        t.start(prompts[1:])
+        t.step()      # its second was on its way before the others came
+        assert t.ran == ["_chunk"] * 2 and first.poff == 8
+        t.step()                          # the lanes program, full
+        assert t.ran[-1] == "_chunk_lanes" and first.pcache is None
+        assert first.lane == 0 and first.poff == 12
+        assert [s.ptoks for s in t.eng._lane_sess] == [
+            tuple(p) for p in prompts[:4]]
+        assert all(t.session(p).lane is None and t.session(p).pcache is None
+                   and t.session(p).poff == 0 for p in prompts[4:])
+        d = t.finish()
+        for i, prompt in enumerate(prompts):
+            assert t.out[i] == _greedy(prompt, 5), i
+        chunks = sum(-(-len(p) // 4) for p in prompts)
+        assert d["prefill_chunks"] == chunks
+        assert chunks / 4 <= d["prefill_programs"] < chunks / 2
+        shapes = core.engine.stats()["program_shapes"]
+        assert {"prefill_chunk:1x4", "prefill_chunk:4x4"} <= set(shapes)
+        alone = _prompt(50, 30)
+        sid, got = t._stream(alone, 5)
+        assert got == _greedy(alone, 5)
+        assert t.ran[-8:] == ["_chunk"] * 8
+    finally:
+        core.engine.shutdown()
+
+
+def test_the_last_of_several_goes_back_to_a_cache_of_its_own():
+    """Two prompts at once, one short: when it is done the long one leaves
+    its lane for a batch-1 cache (the slot gather), the lane cache is
+    dropped, and the rest of the prompt runs on the batch-1 program."""
+    core = _lane_core()
+    try:
+        t = _Together(core)
+        prompts = [_prompt(1, 30), _prompt(2, 6)]
+        t.start(prompts)
+        t.step(3)     # the long one alone, then two lanes programs
+        assert t.ran == ["_chunk", "_chunk_lanes", "_chunk_lanes"]
+        long = t.session(prompts[0])
+        # ... and the loop stands before the next: out of its lane
+        assert long.poff == 12 and long.lane is None
+        assert long.pcache is not None and t.eng._pool is None
+        t.step()
+        assert t.ran[-1] == "_chunk" and long.poff == 16
+        d = t.finish()
+        assert [t.out[0], t.out[1]] == [_greedy(p, 5) for p in prompts]
+        assert d == {"prefill_chunks": 8 + 2, "prefill_programs": 8}
+    finally:
+        core.engine.shutdown()
+
+
+def test_a_prefix_seeded_session_and_one_at_the_capacity_edge_in_lanes():
+    """A live donor of 29 tokens; two prompts at once, one of which shares
+    27 of them and ends one short of the capacity (its one window is set
+    back to 24 and runs three tokens again, in its lane), the other at
+    position 0 beside it: the donor's rows reach the lane by the slot
+    gather and the slot insert, and both stream what they stream alone."""
+    core = _lane_core(max_len=32, prefix_cache_min_tokens=4, max_slots=3,
+                      prefill_chunk_tokens=8)
+    try:
+        t = _Together(core)
+        t.permits.release(4)
+        donor = _prompt(5, 29)
+        t._stream(donor, 2)                        # stays live
+        edge = donor[:27] + _prompt(6, 4)
+        other = _prompt(7, 13)
+        hits = t.eng.stats()["prefix"]["applied_hits"]
+        t.start([other, edge])
+        t.step(2)
+        assert t.ran[-1] == "_chunk_lanes"
+        d = t.finish()
+        assert t.out[0] == _greedy(other, 5, 32)
+        assert t.out[1] == _greedy(edge, 2, 32)   # the cache ends there
+        st = t.eng.stats()
+        assert st["prefix"]["applied_hits"] == hits + 1
+        assert st["prefix"]["tokens_reused"] >= 27
+        # other: 2 chunks; edge: ONE window [24, 31); donor: 4, alone
+        assert d["prefill_chunks"] == 4 + 2 + 1
+    finally:
+        core.engine.shutdown()
+
+
+@pytest.mark.parametrize("how", ["ended", "reaped", "shed", "raised"])
+def test_a_session_lost_mid_prompt_frees_its_lane_and_hurts_no_neighbour(
+        how, chaos_cleanup):
+    """Six long prompts at once: four hold lanes, two wait.  One of the
+    four is ended by its client, or reaped, while the lanes program that
+    carries it is in flight; or the replica drains (every prefilling
+    session is shed); or the program raises (chaos site
+    ``serve.prefill_chunk``: the sessions IN it fail, each with today's
+    error, and the lane cache goes with them).  What is left streams what
+    it streams alone, and every lane is free at the end."""
+    from ray_tpu.exceptions import ReplicaUnavailableError
+    from ray_tpu.util import fault_injection as fi
+    core = _lane_core(session_idle_ttl_s=3600.0)
+    try:
+        t = _Together(core)
+        prompts = [_prompt(i, 26 + i) for i in range(6)]
+        t.start(prompts[:1])
+        t.step()
+        t.start(prompts[1:])
+        t.step(2)                    # the first lanes program has run
+        victim = t.session(prompts[1])
+        assert victim.lane == 1 and victim.poff == 4
+        lost = {1}
+        if how == "ended":
+            assert t.eng.end(victim.sid)
+        elif how == "reaped":
+            with t.eng._cond:
+                victim.last_poll -= 7200.0
+        elif how == "shed":
+            assert t.eng.begin_drain() == 0
+            lost = set(range(6))
+        else:
+            fi.arm([{"site": "serve.prefill_chunk", "action": "error",
+                     "match": {"nth": 1}}])
+            lost = {0, 1, 2, 3}
+        t.step()
+        if how in ("ended", "reaped"):
+            # the lane is free at the next schedule and the first in the
+            # queue takes it
+            _wait(lambda: t.session(prompts[4]).lane == 1,
+                  "the freed lane was not taken")
+        t.finish()
+        for i, prompt in enumerate(prompts):
+            if i not in lost:
+                assert t.out[i] == _greedy(prompt, 5), (how, i)
+            elif how == "raised":
+                assert isinstance(t.out[i], RuntimeError) and \
+                    "chunked prefill failed" in str(t.out[i]) and \
+                    "injected prefill_chunk" in str(t.out[i]), t.out[i]
+            else:
+                assert isinstance(t.out[i], ReplicaUnavailableError), (
+                    how, i, t.out[i])
+        assert t.eng._thread.is_alive()
+        assert t.eng.stats()["reaped"] == (how == "reaped")
+    finally:
+        core.engine.shutdown()
+
+
+def test_a_speculating_engine_keeps_one_program_a_session():
+    """A draft cache stands beside every target cache: no lanes, and
+    prompts at once advance a program each, as before."""
+    core = _lane_core(spec_draft="shared", spec_k=3)
+    try:
+        t = _Together(core)
+        assert t.eng.stats()["prefill_lanes"] == 0
+        prompts = [_prompt(i, n) for i, n in enumerate((13, 9, 18))]
+        t.start(prompts)
+        d = t.finish()
+        for i, prompt in enumerate(prompts):
+            assert t.out[i] == _greedy(prompt, 5), i
+        # target and draft each: the gate counts both
+        assert set(t.ran) == {"_chunk"}
+        assert d["prefill_programs"] == d["prefill_chunks"] == 4 + 3 + 5
+    finally:
+        core.engine.shutdown()
+
+
+def test_chunks_and_programs_counter_and_span(monkeypatch):
+    """`engine:lanes` carries the chunks and the programs since the last
+    span; their sums are the counters of `stats()`."""
+    from ray_tpu.serve.decode_session import ContinuousBatchingEngine
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(ContinuousBatchingEngine, "_MOE_SPAN_S", 0.0)
+
+    def spans():
+        return [e for e in tracing.span_events()
+                if e["name"] == "engine:lanes"]
+
+    before = len(spans())
+    core = _lane_core()
+    try:
+        t = _Together(core)
+        t.start([_prompt(i, 17) for i in range(3)])
+        t.finish()
+        st = core.engine.stats()
+        mine = spans()[before:]
+        assert all(e["cat"] == "lanes" for e in mine)
+        assert sum(e["args"].get("chunks", 0) for e in mine) \
+            == st["prefill_chunks"] == 3 + 3 * 5
+        assert sum(e["args"].get("programs", 0) for e in mine) \
+            == st["prefill_programs"] < st["prefill_chunks"]
+    finally:
+        core.engine.shutdown()
+
+
 def test_step_ahead_under_more_callers_than_slots_and_cores():
     """Stress: twelve caller threads over three slots, a short switch
     interval, each streaming its prompt and ending early or running on,

@@ -264,6 +264,49 @@ def test_finished_session_keeps_its_timeline(tmp_path):
     assert {"submit::f", "exec::f", "setup:worker_spawn"} <= names, names
 
 
+def test_shutdown_waits_for_a_worker_that_is_writing_its_files(tmp_path):
+    """A serve replica owes its programs' op maps when the session ends
+    and takes half a second and more to make them and to write its ring
+    (on the chip: PERF.md, PR 41).  The sweep that ends a session gives
+    an orphaned worker the grace a stopping nodelet gives, so the span
+    file and the program file of a worker that needs a second are whole
+    when `shutdown` returns."""
+    script = tmp_path / "drive.py"
+    script.write_text(
+        "import ray_tpu\n"
+        "from ray_tpu import api\n"
+        "ray_tpu.init(num_cpus=2)\n"
+        "@ray_tpu.remote\n"
+        "class A:\n"
+        "    def owe(self):\n"
+        "        import os, time\n"
+        "        from ray_tpu.util import tracing\n"
+        "        def make():\n"
+        "            time.sleep(1.0)\n"
+        "            return {'module': 'jit_slow', 'shape': 's',\n"
+        "                    'instructions': {}, 'named': 0}\n"
+        "        tracing.record_program('slow', make, time.time(), 0.0)\n"
+        "        return os.getpid()\n"
+        "a = A.remote()\n"
+        "print('PID', ray_tpu.get(a.owe.remote(), timeout=120))\n"
+        "print('SESSION', api._local_cluster.session_dir)\n"
+        "ray_tpu.shutdown()\n")
+    done = subprocess.run(
+        [sys.executable, str(script)], capture_output=True, text=True,
+        timeout=300, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT))
+    assert done.returncode == 0, done.stdout + done.stderr[-3000:]
+    said = dict(ln.split() for ln in done.stdout.splitlines()
+                if ln.startswith(("PID", "SESSION")))
+    session, pid = said["SESSION"], said["PID"]
+    assert f"worker-{pid}.json" in os.listdir(
+        os.path.join(session, "spans"))
+    assert os.listdir(os.path.join(session, "programs")) \
+        == [f"worker-{pid}.slow.json"]
+    assert "program:compiled" in {
+        e["name"] for e in tracing.read_span_files(session)}
+
+
 # ------------------------------------------------------- the engine thread
 
 def test_engine_phase_totals_first_token_and_prefill_tail():
