@@ -9,6 +9,12 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
+A cache holds up to FOUR KINDS OF STATE behind the same functions (`cache_rows`,
+`position_bytes`, `cache_bytes`, the slot insert and gather): rows for the
+whole context (``full``), a ring of a window's rows (``ring``), a conv
+layer's last inputs (``state``) and a row a chunk of positions
+(``summary``); they follow one by one below.
+
 What a cache holds is a property of the model's attention kind
 (`cache_rows`): keys and values of ``kv_heads x head_dim`` (``"k"``,
 ``"v"``) for MHA/GQA, ONE array of compressed latents (``"kv"``: the
@@ -49,6 +55,31 @@ not active keeps its state bit for bit, and what would need a state to be
 taken back is refused (`_check_state_rewind`): a speculative proposal
 that may be rejected, a chunk window set back at the cache's end.
 
+A FOURTH KIND OF STATE HAS A ROW A CHUNK OF POSITIONS: a summary layer
+(``"eva"``, `ops/eva_attention.py`) attends its own block-aligned window of
+``sliding_window`` positions exactly and every earlier window through ONE
+pooled key and value a chunk of ``summary_chunk`` positions, under one
+softmax.  Its cache is a ring (``k_win``, ``v_win``: ``sliding_window +
+window_chunk`` rows under a BLOCK mask, `_ring_mask`) beside summary arrays
+(``k_sum``, ``v_sum``: ``[L_eva, batch, heads, width, max_len /
+summary_chunk]``, `cache_rows`), masked by ``(j + 1) chunk <= (t // window)
+window``.  A summary is a function of ring rows that are still held when its
+chunk completes (``summary_chunk`` divides the window and the ring), so
+EVERY program pools the chunks its new tokens reach from the ring as written
+(`_summary_write`) and the mask hides a row until its whole window lies
+behind the query: no branch on where a chunk ends, and, unlike a conv state,
+a summary pooled over tokens written ahead of a row's ``pos`` is pooled
+again by the program that feeds the true ones, so a speculative verify and
+a chunk window set back need not be refused for it.
+
+A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
+summary arrays say it (`cache_capacity`, which then needs the model's
+``summary_chunk``), and nothing stands in for a full layer.  What still
+cannot be served is a model of window layers or conv layers alone
+(`_check_decodable`).  A model with several prediction heads
+(``pred_heads``) hands out every head's logits; a served token is drawn
+from head 0's (`next_token_logits`).
+
 Each array is stored ``[layers, batch, heads, width, rows]`` —
 positions LAST — and every program that takes a cache extends it IN PLACE:
 the whole stacked cache is state of the one layer loop
@@ -83,13 +114,14 @@ import numpy as np
 
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ..ops import eva_attention as eva
 from ..ops import latent_attention as mla
 from ..ops.attention import sink_softmax
 from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
-from .transformer import (TransformerConfig, _attn_out, _ffn, _layer, _norm,
-                          _post, _qkv, _scale_embedding, _unembed, norm_eps,
-                          rope_tables, scan_layer_runs)
+from .transformer import (ATTENTION_KINDS, TransformerConfig, _attn_out,
+                          _ffn, _layer, _norm, _post, _qkv, _scale_embedding,
+                          _unembed, norm_eps, rope_tables, scan_layer_runs)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -99,7 +131,9 @@ Arrays = Dict[str, jnp.ndarray]     # a cache without its "pos"
 
 _RING = "_win"      # suffix of a window layer's arrays: rings
 _STATE = "_state"   # suffix of a conv layer's array: no positions at all
+_SUMMARY = "_sum"   # suffix of a summary layer's arrays: a row a CHUNK
 _CONV_STATE = "conv" + _STATE
+_SUM_NAMES = ("k" + _SUMMARY, "v" + _SUMMARY)
 
 
 def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
@@ -115,31 +149,34 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     if cfg.attention == "mla":
         return dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
     rows = {}
-    for kind in ("full", "window"):
+    for kind in ATTENTION_KINDS:
         if kind in cfg.kinds:
             hk = cfg.kv_heads_of(kind)
-            rows.update(zip(_kv_names(kind), ((hk, cfg.head_dim),
-                                              (hk, cfg.value_dim))))
+            row = ((hk, cfg.head_dim), (hk, cfg.value_dim))
+            rows.update(zip(_kv_names(kind), row))
+            if kind == "eva":   # a pooled key and value a chunk, as wide
+                rows.update(zip(_SUM_NAMES, row))
     return dict(rows, **state)
 
 
 def position_bytes(cfg: TransformerConfig) -> Dict[str, int]:
     """Bytes ONE layer of each state kind holds a position a sequence
-    (``full``, ``ring``), or a sequence whatever its positions (``state``:
+    (``full``, ``ring``), a chunk of positions (``summary``, of a model
+    that has summaries), or a sequence whatever its positions (``state``:
     a conv layer's ``conv_kernel - 1`` inputs of ``d_model``): what a
     decode step reads of a row it attends, by the row's kind."""
     out = {"full": 0, "ring": 0, "state": 0}
     item = jnp.dtype(cfg.dtype).itemsize
     for name, (heads, width) in cache_rows(cfg).items():
         kind = _state_kind(name)
-        out[kind] += heads * width * item * (
+        out[kind] = out.get(kind, 0) + heads * width * item * (
             cfg.d_model if kind == "state" else 1)
     return out
 
 
 def _kv_names(kind: str) -> Tuple[str, str]:
     """The key and value arrays of a layer of attention kind ``kind``."""
-    return ("k" + _RING, "v" + _RING) if kind == "window" else ("k", "v")
+    return ("k", "v") if kind == "full" else ("k" + _RING, "v" + _RING)
 
 
 def window_ring(cfg: TransformerConfig, max_len: int) -> int:
@@ -153,39 +190,56 @@ def cache_arrays(cache: KVCache) -> Arrays:
     return {name: a for name, a in cache.items() if name != "pos"}
 
 
-def cache_capacity(cache: KVCache) -> int:
+def cache_capacity(cache: KVCache,
+                   cfg: Optional[TransformerConfig] = None) -> int:
     """``max_len``: the positions a cache holds per row (what its full
-    layers' arrays hold; a ring is shorter, a state holds none)."""
-    return max(a.shape[-1] for name, a in cache_arrays(cache).items()
+    layers' arrays hold; a ring is shorter, a state holds none).  A cache
+    with NO full layer in it has a row a ``cfg.summary_chunk`` positions in
+    its summaries: they say it, and need ``cfg`` for it."""
+    arrays = cache_arrays(cache)
+    if _SUM_NAMES[0] in arrays and "k" not in arrays:
+        return arrays[_SUM_NAMES[0]].shape[-1] * cfg.summary_chunk
+    return max(a.shape[-1] for name, a in arrays.items()
                if not name.endswith(_STATE))
 
 
 def cache_bytes(cache: KVCache) -> Dict[str, int]:
     """Bytes of a cache's arrays by state kind: ``full`` (rows for the
-    whole context), ``ring`` (window layers) and ``state`` (conv layers)."""
+    whole context), ``ring`` (window layers), ``state`` (conv layers) and,
+    where the cache has them, ``summary`` (a row a chunk)."""
     out = {"full": 0, "ring": 0, "state": 0}
     for name, a in cache_arrays(cache).items():
-        out[_state_kind(name)] += int(a.nbytes)
+        kind = _state_kind(name)
+        out[kind] = out.get(kind, 0) + int(a.nbytes)
     return out
 
 
 def _state_kind(name: str) -> str:
-    """``full`` | ``ring`` | ``state``: what kind of state an array is."""
-    return "ring" if name.endswith(_RING) else \
-        "state" if name.endswith(_STATE) else "full"
+    """``full`` | ``ring`` | ``state`` | ``summary``: what kind of state
+    an array is."""
+    for suffix, kind in ((_RING, "ring"), (_STATE, "state"),
+                         (_SUMMARY, "summary")):
+        if name.endswith(suffix):
+            return kind
+    return "full"
 
 
 def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                 pos: jnp.ndarray) -> KVCache:
     cache = {}
+    if "eva" in cfg.kinds and max_len % cfg.summary_chunk:
+        raise ValueError(f"max_len {max_len} is no whole number of chunks "
+                         f"of {cfg.summary_chunk} positions")
     # (layers that hold the kind, what its arrays have for positions)
-    stacks = {"full": ("full", max_len),
-              "ring": ("window", window_ring(cfg, max_len)),
-              "state": ("conv", cfg.d_model)}
+    stacks = {"full": (("full",), max_len),
+              "ring": (("window", "eva"), window_ring(cfg, max_len)),
+              "state": (("conv",), cfg.d_model),
+              "summary": (("eva",), max_len // max(1, cfg.summary_chunk))}
     for name, (heads, width) in cache_rows(cfg).items():
-        kind, rows = stacks[_state_kind(name)]
+        kinds, rows = stacks[_state_kind(name)]
         cache[name] = jnp.zeros(
-            (cfg.kinds.count(kind), batch, heads, width, rows), cfg.dtype)
+            (sum(k in kinds for k in cfg.kinds), batch, heads, width, rows),
+            cfg.dtype)
     cache["pos"] = pos
     return cache
 
@@ -210,17 +264,34 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "pos_emb must be 'rope'")
     kinds = set(cfg.kinds)
     if len(cfg.kinds) != cfg.n_layers or \
-            kinds - {"full", "window", "conv"}:
+            kinds - {"full", "window", "conv", "eva"}:
         raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
-                         f"{cfg.n_layers} of 'full' | 'window' | 'conv'")
+                         f"{cfg.n_layers} of 'full' | 'window' | 'conv' | "
+                         f"'eva'")
     if "conv" in kinds and cfg.conv_kernel < 2:
         raise ValueError("conv layers need conv_kernel of at least 2")
-    if "full" not in kinds:
+    if not kinds & {"full", "eva"}:
         raise NotImplementedError(
-            "a model without a full-attention layer (of window layers only, "
-            "of conv layers, of both) is not served: the rows a session may "
-            "reach (max_len) are read off a full layer's array, and neither "
-            "a ring nor a state has them")
+            "a model without a full-attention layer or a summary layer (of "
+            "window layers only, of conv layers, of both) is not served: "
+            "the rows a session may reach (max_len) are read off a full "
+            "layer's array or a summary layer's, and neither a ring nor a "
+            "state has them")
+    if "eva" in kinds:
+        c = cfg.summary_chunk
+        if cfg.attention != "mha" or "window" in kinds or cfg.split_kv \
+                or "eva" in cfg.sink_kinds:
+            raise NotImplementedError(
+                "summary layers are MHA/GQA layers with one ring to a "
+                "model: no latent cache, no sliding-window layer beside "
+                "them, no sink")
+        if c < 1 or cfg.sliding_window < 1 or cfg.sliding_window % c \
+                or cfg.window_chunk < 1 or cfg.window_chunk % c:
+            raise ValueError(
+                f"summary layers pool whole chunks: summary_chunk {c} has "
+                f"to divide sliding_window {cfg.sliding_window} and "
+                f"window_chunk {cfg.window_chunk} (a chunk's rows never "
+                f"straddle the ring's seam)")
     if cfg.attention == "mla" and (
             cfg.split_kv or cfg.sink_kinds or cfg.value_scale != 1.0
             or cfg.rope_fraction != 1.0):
@@ -250,7 +321,7 @@ def _check_chunk(cfg: TransformerConfig, c: int) -> None:
     program that feeds more new tokens a row than the ring is wider than
     the window (a chunk, or a speculative verify of that many) would
     overwrite positions its own queries still see."""
-    if "window" in cfg.kinds and c > cfg.window_chunk:
+    if {"window", "eva"} & set(cfg.kinds) and c > cfg.window_chunk:
         raise ValueError(
             f"a cached program of {c} new tokens a row over window layers "
             f"whose ring leaves room for window_chunk={cfg.window_chunk}: "
@@ -270,19 +341,124 @@ def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
 
 
 @jax.named_scope("attention")
-def _ring_mask(pos, c: int, ring: int, window: int) -> jnp.ndarray:
+def _ring_mask(pos, c: int, ring: int, window: int,
+               block: bool = False) -> jnp.ndarray:
     """``pos`` [...] first new position a row, ``c`` new tokens a row →
     [..., c, ring] bool: ring column visible to each new token.  After the
     write the last position held is ``top = pos + c - 1`` and column j
     holds the latest position <= top that is j mod ring (negative: never
     written); token i at ``pos + i`` sees what lies at or before it and
-    inside its window."""
+    inside its window: the last ``window`` positions, or, with ``block``
+    (a summary layer), its own block-aligned ``window`` of them."""
     pos = jnp.asarray(pos)
     top = (pos + (c - 1))[..., None]
     held = top - (top - jnp.arange(ring)) % ring              # [..., ring]
     q = (pos[..., None] + jnp.arange(c))[..., None]           # [..., c, 1]
     held = held[..., None, :]
+    if block:
+        return eva.block_mask(q, held, window)
     return (held >= 0) & (held <= q) & (q - held < window)
+
+
+@jax.named_scope("attention")
+def _eva_masks(cfg: TransformerConfig, pos, c: int,
+               max_len: int) -> Dict[str, jnp.ndarray]:
+    """A summary layer's two masks for ``c`` new tokens a row from ``pos``
+    [...]: ``"eva"`` [..., c, ring] over its ring (the token's own block)
+    and ``"summary"`` [..., c, max_len / chunk] over the summaries (the
+    chunks of the blocks before it)."""
+    pos = jnp.asarray(pos)
+    return {"eva": _ring_mask(pos, c, window_ring(cfg, max_len),
+                              cfg.sliding_window, block=True),
+            "summary": eva.summary_mask(
+                pos[..., None] + jnp.arange(c), max_len // cfg.summary_chunk,
+                cfg.sliding_window, cfg.summary_chunk)}
+
+
+def _summary_write(cfg: TransformerConfig, pos, c: int, ring: int,
+                   lane=None, live=None, by_row: bool = False):
+    """The summaries of the chunks that the ``c`` new tokens from the scalar
+    ``pos`` (batch rows ``lane`` on; every array's batch rows where ``lane``
+    is None) reach, in two halves for `_write_summaries`, which pools every
+    row's chunks at once: → ``(read, place)``, ``read(arrs, l, kc, vc) ->
+    (keys, values)`` [b, heads, width, n, chunk] of the ``n = ceil(c /
+    chunk)`` chunks from the first new token's, and ``place(arrs, l, kbar,
+    vbar [b, heads, width, n]) -> {k_sum, v_sum}`` from row ``pos // chunk``
+    on.
+
+    A summary is a function of ring rows that are still held when its chunk
+    completes, so the chunk a program's newest token lies in is summarised
+    on EVERY program, no branch on where it ends: until the chunk is
+    complete (and its whole block behind every query) the mask hides the
+    row, and what was pooled over tokens written ahead of a row's ``pos``
+    (a padded chunk's tail, a slot that is not active, a rejected proposal)
+    is pooled again by the program that writes the true ones.  What lies
+    before the new tokens in their first chunk is read from the RING AS
+    WRITTEN (``arrs`` holds the rings after their write), ONE slice that
+    cannot straddle the seam (``chunk`` divides the ring); the rest are the
+    program's own new columns ``kc``, ``vc`` [B, heads, width, c].  A chunk
+    that the new tokens only begin is the next program's.  ``live`` as
+    `_full_write_chunk`'s.  ``by_row``: a row a slice, last first (a slot
+    at the cache's end is clamped onto the last row, which no query ever
+    sees, and the row that belongs there overwrites it)."""
+    ch = cfg.summary_chunk
+    n = -(-c // ch)
+    first = pos // ch
+    whole = lane is None
+    lane = 0 if whole else lane
+
+    def read(arrs, l, *cols):
+        def chunks(r_all, new):
+            new = new if whole else new[lane:lane + 1]
+            got = jax.lax.dynamic_slice(
+                r_all, (l, lane, 0, 0, (first * ch) % ring),
+                (1,) + new.shape[:-1] + (ch,))[0]
+            if c > 1:       # the new tokens go on past the slice's end
+                got = jax.lax.dynamic_update_slice(jnp.concatenate(
+                    [got, jnp.zeros(got.shape[:-1] + (n * ch,), got.dtype)],
+                    axis=-1), new.astype(got.dtype),
+                    (0, 0, 0, pos % ch))[..., :n * ch]
+            return got.reshape(got.shape[:-1] + (n, ch))
+
+        return tuple(chunks(arrs[name], new)
+                     for name, new in zip(_kv_names("eva"), cols))
+
+    def place(arrs, l, *pooled):
+        out = {}
+        for name, new in zip(_SUM_NAMES, pooled):
+            s_all = arrs[name]
+            new = new.astype(s_all.dtype)[None]
+            for i in reversed(range(n)) if by_row else (None,):
+                at = (l, lane, 0, 0, first + (i or 0))
+                piece = new if i is None else new[..., i:i + 1]
+                if live is not None:
+                    piece = jnp.where(live, piece, jax.lax.dynamic_slice(
+                        s_all, at, piece.shape))
+                s_all = jax.lax.dynamic_update_slice(s_all, piece, at)
+            out[name] = s_all
+        return out
+
+    return read, place
+
+
+@jax.named_scope("cache_write")
+@jax.named_scope("summary")
+def _write_summaries(rows, arrs: Arrays, l, kc, vc, phi, mu) -> Arrays:
+    """``rows``: `_summary_write`'s two halves for each part of the batch
+    (one for the whole of it, or one a batch row where rows stand at
+    positions of their own) → the summary arrays with every part's chunks
+    in them: all read, POOLED AT ONCE (`ops.eva_attention.pool_chunks`: a
+    few operations a layer however many rows), then placed part by part."""
+    got = [read(arrs, l, kc, vc) for read, _ in rows]
+    pooled = eva.pool_chunks(*(jnp.concatenate(x, axis=0)
+                               for x in zip(*got)), phi, mu)
+    at = 0
+    for (_, place), (k, _) in zip(rows, got):
+        b = k.shape[0]
+        arrs = dict(arrs, **place(arrs, l,
+                                  *(x[at:at + b] for x in pooled)))
+        at += b
+    return {name: arrs[name] for name in _SUM_NAMES}
 
 
 def _full_write_chunk(pos, lane=0, live=None):
@@ -439,7 +615,10 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     (``write[kind](c_all, l, cols [B, heads, width, C]) -> c_all``) into
     each of its state kind's arrays, then attends dense over layer ``l``
     of them under ``mask[kind]`` [B|1, C, rows]; ``write`` and ``mask``
-    are keyed by the layer's attention kind (``"full"``, ``"window"``).
+    are keyed by the layer's attention kind (``"full"``, ``"window"``,
+    ``"eva"``; a summary layer has ``"summary"`` beside its own: the mask
+    over its summary rows, and `_summary_write`'s halves for
+    `_write_summaries`).
     A conv layer reads its state, and advances it by ``n_new`` [B] tokens
     (None: all C): the row's real tokens, none for a row that stands.
     ``rotate[kind]`` applies the caller's rotary angles at that kind's
@@ -486,6 +665,32 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         return (_attn_out(cfg, y, attn, lp),
                 dict(arrs, **{kn: k_all, vn: v_all}))
 
+    def attend_eva(y, lp, arrs, l, kind):
+        # the new tokens into the ring, the chunks they reach pooled from
+        # the ring as written, then ONE softmax over the ring's rows (the
+        # token's own block) and the summaries (the blocks before it)
+        kn, vn = _kv_names(kind)
+        hk = cfg.kv_heads_of(kind)
+        q, k_new, v_new = _qkv(cfg, y, lp, rotate.get(kind), kind)
+        kc = _as_columns(k_new, arrs[kn].dtype)
+        vc = _as_columns(v_new, arrs[vn].dtype)
+        arrs = dict(arrs, **{kn: write[kind](arrs[kn], l, kc),
+                             vn: write[kind](arrs[vn], l, vc)})
+        arrs.update(_write_summaries(write["summary"], arrs, l, kc, vc,
+                                     lp["adaptive_phi"],
+                                     lp["adaptive_mu_k"]))
+        names = (kn, vn) + _SUM_NAMES
+        masks = (mask[kind], mask["summary"])
+        qh = q.reshape(-1, c, hk, h // hk, hd)
+        if lanes is None:
+            attn = eva.attend_two(
+                qh, *(_layer_of(arrs[n], l) for n in names), *masks)
+        else:
+            attn = _by_lane(lanes, eva.attend_two, lambda p: (
+                qh[p:p + 1], *(_lane_of(arrs[n], l, p) for n in names),
+                *(m[p:p + 1] for m in masks)))
+        return _attn_out(cfg, y, attn.reshape(b, c, h, -1), lp), arrs
+
     def attend_mla(y, lp, arrs, l, kind):
         # absorbed: the chunk's few queries over the cached latents
         q_nope, q_rope = mla.queries(
@@ -520,11 +725,11 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             s_all, l, state)})
 
     attend = attend_mla if cfg.attention == "mla" else attend_mha
+    operator = {"conv": conv, "eva": attend_eva}
 
     def layer(xc, lp, arrs, l, kind):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        delta, arrs = (conv if kind == "conv" else attend)(
-            y, lp, arrs, l, kind)
+        delta, arrs = operator.get(kind, attend)(y, lp, arrs, l, kind)
         xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
@@ -541,10 +746,10 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
     holding the prompt's K/V with pos = prompt length)."""
     _check_decodable(cfg)
     b, s = tokens.shape
-    dt = cfg.dtype
-    if s > cache_capacity(cache):
+    dt = cfg.stream_dtype
+    if s > cache_capacity(cache, cfg):
         raise ValueError(f"prompt length {s} exceeds cache capacity "
-                         f"{cache_capacity(cache)}")
+                         f"{cache_capacity(cache, cfg)}")
     with jax.named_scope("embed"):
         x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
         if cfg.pos_emb == "learned":
@@ -575,6 +780,22 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         return jax.lax.dynamic_update_slice(c_all, cols[None],
                                             (l, 0, 0, 0, 0))
 
+    @jax.named_scope("cache_write")
+    @jax.named_scope("summary")
+    def summaries(arrs, l, lp, k, v):
+        """Every chunk the prompt reaches, pooled from its keys and values
+        ``k``, ``v`` [B, s, heads, width] as the ring's type holds them and
+        placed from row 0 (the chunk the prompt ends in is pooled again by
+        whatever program feeds its next token: `_summary_write`)."""
+        pooled = eva.pool_chunks(
+            *(eva.as_chunks(_as_columns(rows, arrs[name].dtype),
+                            cfg.summary_chunk)
+              for name, rows in zip(_kv_names("eva"), (k, v))),
+            lp["adaptive_phi"], lp["adaptive_mu_k"])
+        return {name: jax.lax.dynamic_update_slice(
+            arrs[name], new.astype(arrs[name].dtype)[None], (l, 0, 0, 0, 0))
+            for name, new in zip(_SUM_NAMES, pooled)}
+
     def layer(h, lp, arrs, l, kind):
         # run the layer for h, re-project for the cache
         y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
@@ -584,9 +805,12 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             arrs = dict(arrs, **{_CONV_STATE: _place_state(
                 arrs[_CONV_STATE], l, state)})
         else:
+            new = columns(y, lp, kind)
             arrs = dict(arrs, **{
                 n: place(arrs[n], l, _as_columns(rows, arrs[n].dtype))
-                for n, rows in columns(y, lp, kind).items()})
+                for n, rows in new.items()})
+            if kind == "eva":
+                arrs = dict(arrs, **summaries(arrs, l, lp, *new.values()))
         h, _ = _layer(cfg, h, lp, angles, kind)
         return h, arrs, (0, 0, 0)
 
@@ -599,9 +823,29 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
 @jax.named_scope("head")
 def _last_logits(params: Params, x: jnp.ndarray, cfg: TransformerConfig
                  ) -> jnp.ndarray:
-    """Final-norm activations [..., D] -> float32 logits [..., vocab]."""
+    """Final-norm activations [..., D] -> float32 logits [..., vocab] (of
+    a model with several prediction heads: every head's, head 0's first;
+    `next_token_logits`)."""
+    if cfg.fp32_logits:     # accumulated and handed out float32
+        return jnp.einsum("...d,dv->...v", x, _unembed(params, cfg),
+                          preferred_element_type=jnp.float32)
     return jnp.einsum("...d,dv->...v", x,
                       _unembed(params, cfg)).astype(jnp.float32)
+
+
+def greedy_tokens(logits: jnp.ndarray,
+                  cfg: TransformerConfig) -> jnp.ndarray:
+    """The largest of the next token's logits (`next_token_logits`)."""
+    return jnp.argmax(next_token_logits(logits, cfg), axis=-1)
+
+
+def next_token_logits(logits: jnp.ndarray,
+                      cfg: TransformerConfig) -> jnp.ndarray:
+    """What the NEXT token is drawn from: the logits [..., columns] as they
+    are, or, of a model with several prediction heads, head 0's
+    ``vocab_size`` columns (head p's, at ``p * vocab_size`` on, predict the
+    token p further on; no program here accepts more than one a step)."""
+    return logits if cfg.pred_heads == 1 else logits[..., :cfg.vocab_size]
 
 
 def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
@@ -649,9 +893,9 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     _check_decodable(cfg)
     b, c = tokens.shape
     _check_chunk(cfg, c)
-    dt = cfg.dtype
+    dt = cfg.stream_dtype
     pos = cache["pos"]
-    max_len = cache_capacity(cache)
+    max_len = cache_capacity(cache, cfg)
     with jax.named_scope("embed"):
         x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
         if cfg.pos_emb == "learned":
@@ -669,10 +913,15 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     mask = {"full": mask[None]}
 
     write = {"full": _full_write_chunk(pos)}
+    ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
-        ring = window_ring(cfg, max_len)
         mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)[None]
         write["window"] = _ring_write_chunk(pos, c, ring)
+    if "eva" in cfg.kinds:
+        mask.update((k, m[None])
+                    for k, m in _eva_masks(cfg, pos, c, max_len).items())
+        write.update(eva=_ring_write_chunk(pos, c, ring),
+                     summary=[_summary_write(cfg, pos, c, ring)])
     valid = None if n_valid is None else \
         jnp.broadcast_to(jnp.arange(c) < n_valid, (b, c))
     x, arrays, load = _attend_cached(
@@ -782,7 +1031,7 @@ def prefill_chunked(params: Params, tokens: jnp.ndarray,
     position are (numerically) those the uninterrupted session produced,
     so the argmax — the next token — matches exactly."""
     s = tokens.shape[1]
-    capacity = cache_capacity(cache)
+    capacity = cache_capacity(cache, cfg)
     if s > capacity:
         raise ValueError(f"prompt length {s} exceeds cache capacity "
                          f"{capacity}")
@@ -886,7 +1135,7 @@ def _row_inputs(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     (of a ring: the columns, by the position each holds once the C tokens
     are written) visible to fed token i of row s)."""
     c = tokens.shape[1]
-    dt = cfg.dtype
+    dt = cfg.stream_dtype
     posm = pos[:, None] + jnp.arange(c)[None, :]               # [S, C]
     with jax.named_scope("embed"):
         x = _scale_embedding(cfg, params["embed"]["tok"][tokens].astype(dt))
@@ -902,6 +1151,8 @@ def _row_inputs(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     if "window" in cfg.kinds:
         mask["window"] = _ring_mask(pos, c, window_ring(cfg, max_len),
                                     cfg.sliding_window)
+    if "eva" in cfg.kinds:
+        mask.update(_eva_masks(cfg, pos, c, max_len))
     return x, angles, mask
 
 
@@ -931,7 +1182,7 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         _check_state_rewind(cfg, "a speculative verify (its rejected "
                                  "tokens are fed all the same)")
     pos = cache["pos"]                                         # [S]
-    max_len = cache_capacity(cache)
+    max_len = cache_capacity(cache, cfg)
     x, angles, mask = _row_inputs(params, tokens, pos, cfg, max_len)
 
     def column_writes(column):
@@ -946,11 +1197,15 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         return write
 
     write = {"full": column_writes(lambda p: p)}
+    ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
         # a ring has no end to be clamped onto: position p is column p mod
         # ring, and a column is masked by the position it holds
-        ring = window_ring(cfg, max_len)
         write["window"] = column_writes(lambda p: p % ring)
+    if "eva" in cfg.kinds:
+        write["eva"] = column_writes(lambda p: p % ring)
+        write["summary"] = [_summary_write(cfg, pos[slot], c, ring, slot,
+                                           by_row=True) for slot in range(s)]
     valid = None if active is None else \
         jnp.broadcast_to(active[:, None], (s, c))
     return _attend_cached(
@@ -985,7 +1240,7 @@ def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
     lanes, c = tokens.shape
     _check_chunk(cfg, c)
     pos = cache["pos"]                                         # [P]
-    max_len = cache_capacity(cache)
+    max_len = cache_capacity(cache, cfg)
     live = n_valid > 0
     x, angles, mask = _row_inputs(params, tokens, pos, cfg, max_len)
 
@@ -998,10 +1253,15 @@ def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
 
     write = {"full": lane_writes(lambda p: _full_write_chunk(
         pos[p], p, live[p]))}
+    ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
-        ring = window_ring(cfg, max_len)
         write["window"] = lane_writes(lambda p: _ring_write_chunk(
             pos[p], c, ring, p, live[p]))
+    if "eva" in cfg.kinds:
+        write["eva"] = lane_writes(lambda p: _ring_write_chunk(
+            pos[p], c, ring, p, live[p]))
+        write["summary"] = [_summary_write(cfg, pos[p], c, ring, p, live[p])
+                            for p in range(lanes)]
     x, arrays, load = _attend_cached(
         cfg, params, x, cache, rotate=_rotators(_rotate_slots, angles),
         write=write, mask=mask,
@@ -1120,7 +1380,7 @@ def draft_propose_slots(params: Params, token: jnp.ndarray,
         tok, c = carry
         logits, c = decode_step_slots(params, tok, c, active, cfg)
         with jax.named_scope("head"):
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = greedy_tokens(logits, cfg).astype(jnp.int32)
             nxt = jnp.where(active, nxt, tok)
         return (nxt, c), nxt
 
@@ -1158,11 +1418,12 @@ def verify_step_slots(params: Params, tokens: jnp.ndarray,
     slots.  Writes past ``max_len`` are dropped and ``accepted`` is
     clamped so emission never outruns the cache."""
     pos = cache["pos"]                                         # [S]
-    max_len = cache_capacity(cache)
+    max_len = cache_capacity(cache, cfg)
     x, arrays, _ = _forward_slots(params, tokens, cache, cfg, active)
     with jax.named_scope("head"):
-        logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg))
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, C]
+        greedy = greedy_tokens(
+            jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg)),
+            cfg).astype(jnp.int32)                              # [S, C]
         ok = (greedy[:, :-1] == proposals).astype(jnp.int32)
         accepted = 1 + jnp.sum(jnp.cumprod(ok, axis=1), axis=1)
         accepted = jnp.minimum(
@@ -1199,14 +1460,16 @@ def _generate_impl(params, prompt, temperature, key, *, cfg,
     def step(carry, _):
         logits, cache, key = carry
         key, skey = jax.random.split(key)
-        tok = _sample(logits, skey, greedy, temperature, top_k)
+        tok = _sample(next_token_logits(logits, cfg), skey, greedy,
+                      temperature, top_k)
         logits, cache = decode_step(params, tok, cache, cfg)
         return (logits, cache, key), tok
 
     (logits, _, key), toks = jax.lax.scan(
         step, (logits, cache, key), None, length=max_new_tokens - 1)
     _, skey = jax.random.split(key)
-    last = _sample(logits, skey, greedy, temperature, top_k)
+    last = _sample(next_token_logits(logits, cfg), skey, greedy,
+                   temperature, top_k)
     # scan with length=0 yields a [0, B] array, so this is total for
     # every max_new_tokens >= 1
     toks = jnp.concatenate([toks, last[None]], axis=0)

@@ -32,11 +32,17 @@ Design (idiomatic JAX, not a torch translation):
   * a head's width is its own (``head_size``), and what an attention
     block adds to the plain one is a property each: ``qk_norm``,
     ``attn_gate``, ``sandwich_norm``, ``embed_scale``.
-  * a layer's KIND (``layer_kinds``: ``"window"`` | ``"full"`` | ``"conv"``)
-    may change from layer to layer and repeat inside a run: a window layer
-    sees the last ``sliding_window`` positions (``rope_layers = "window"``:
-    only those are rotated); a conv layer's operator is no attention at
-    all but a gated short convolution (`ops/short_conv.py`).  A run that
+  * a layer's KIND (``layer_kinds``: ``"window"`` | ``"full"`` | ``"conv"``
+    | ``"eva"``) may change from layer to layer and repeat inside a run: a
+    window layer sees the last ``sliding_window`` positions (``rope_layers
+    = "window"``: only those are rotated); a conv layer's operator is no
+    attention at all but a gated short convolution (`ops/short_conv.py`);
+    an ``"eva"`` layer sees its own BLOCK-ALIGNED window of
+    ``sliding_window`` positions exactly and every earlier window through
+    one pooled key and value a ``summary_chunk`` positions, under one
+    softmax (`ops/eva_attention.py`; two weights a layer more,
+    ``adaptive_phi`` and ``adaptive_mu_k``).  A model may have NO full
+    layer at all.  A run that
     mixes the two operators holds BOTH weight sets, each stacked over its
     own layers only.  `layer_segments` cuts the runs where the kind
     changes; a segment that is part of a run loops over indices INTO the
@@ -50,6 +56,15 @@ Design (idiomatic JAX, not a torch translation):
     (``sink_kinds``); and for every kind a value head's width
     (``v_head_dim``), the rotated share of a head (``rope_fraction``) and
     a scale on the values (``value_scale``).
+
+  * what a block, the stream and the head are may differ too, each a
+    property with the plain model as its default: an RMSNorm that
+    multiplies by ``1 + g`` (``norm_unit_offset``), a residual stream held
+    in float32 while the matmuls run in ``dtype`` (``fp32_residual``), a
+    head accumulated in float32 (``fp32_logits``), and an unembedding of
+    ``pred_heads x vocab_size`` columns (head p predicts the token ``1 +
+    p`` positions on; `lm_loss` is the heads' mean, a served token head
+    0's).
 
 Configs: ``TransformerConfig.gpt2()`` (learned positions, GELU, LayerNorm)
 and ``TransformerConfig.llama()`` (RoPE, SwiGLU, RMSNorm, GQA).
@@ -67,6 +82,7 @@ import jax.numpy as jnp
 
 from ..ops import latent_attention as mla
 from ..ops.attention import multi_head_attention
+from ..ops.eva_attention import eva_attention
 from ..ops.norms import layernorm, rmsnorm
 from ..ops.rotary import apply_rotary, rotary_angles
 
@@ -161,10 +177,34 @@ class TransformerConfig:
     sink_kinds: Tuple[str, ...] = ()  # the kinds of layer whose softmax has
     #   a learned logit a query head in its denominator that takes no value
     value_scale: float = 1.0          # multiplies the value projection
+    # -- attention through chunk summaries (ops/eva_attention.py) -----------
+    summary_chunk: int = 0            # an "eva" layer's position sees its
+    #   own BLOCK of sliding_window positions (block-aligned, not sliding)
+    #   exactly and every earlier block through ONE pooled key and value a
+    #   summary_chunk positions, under one softmax; two weights a layer
+    #   more (adaptive_phi, adaptive_mu_k: [kv_heads, head_dim])
+    # -- what a block, the stream and the head may differ in -----------------
+    norm_unit_offset: bool = False    # an RMSNorm multiplies by 1 + g
+    fp32_residual: bool = False       # the residual stream is float32 (the
+    #   norms hand the matmuls cfg.dtype), not cfg.dtype
+    fp32_logits: bool = False         # the served head accumulates float32
+    pred_heads: int = 1               # the unembedding has pred_heads x
+    #   vocab_size columns: head p predicts the token at t + 1 + p, and a
+    #   served token is drawn from head 0
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def stream_dtype(self):
+        """What the residual stream is held in."""
+        return jnp.float32 if self.fp32_residual else self.dtype
+
+    @property
+    def logit_size(self) -> int:
+        """Columns of the unembedding: every prediction head's."""
+        return self.vocab_size * self.pred_heads
 
     @property
     def n_experts_held(self) -> int:
@@ -345,8 +385,15 @@ def _attended(cfg: TransformerConfig, context_len: float,
     """Positions one query at depth ``context_len`` attends, summed over
     the layers: a window layer stops at ``windows`` x its window (2 where
     the caller halves the sum for a causal sequence's mean)."""
-    return sum(min(context_len, windows * cfg.sliding_window)
-               if kind == "window" else context_len for kind in cfg.kinds
+    def rows(kind):
+        if kind == "window":
+            return min(context_len, windows * cfg.sliding_window)
+        if kind == "eva":   # at most its block, and the chunks before it
+            return min(context_len, cfg.sliding_window) \
+                + context_len / cfg.summary_chunk
+        return context_len
+
+    return sum(rows(kind) for kind in cfg.kinds
                if kind != "conv")       # a conv layer attends nothing
 
 
@@ -360,20 +407,21 @@ def count_params(cfg: TransformerConfig) -> int:
         else 2 * cfg.head_dim if cfg.qk_norm else 0   # an attention layer's
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
         + (cfg.n_layers - n_conv) * own + n_conv * d * cfg.conv_kernel \
-        + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads
+        + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads \
+        + cfg.kinds.count("eva") * 2 * cfg.kv_heads * cfg.head_dim
     if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
         layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
     emb = cfg.vocab_size * d
     if cfg.pos_emb == "learned":
         emb += cfg.max_seq_len * d
-    head = 0 if cfg.tie_embeddings else cfg.vocab_size * d
+    head = 0 if cfg.tie_embeddings else cfg.logit_size * d
     final = d * (2 if cfg.norm == "layernorm" else 1)
     return layers + emb + head + final
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     """Training FLOPs/token: 6*N_active_matmul + causal attention term."""
-    unembed = cfg.vocab_size * cfg.d_model  # tied or not, the logits matmul runs
+    unembed = cfg.logit_size * cfg.d_model  # tied or not, the logits matmul runs
     n_matmul = _matmul_params(cfg, active=True) + unembed
     # qk+pv over the visible window: half the positions when causal,
     # all of them for bidirectional encoders (causal=False)
@@ -390,7 +438,7 @@ def decode_flops_per_token(cfg: TransformerConfig,
     attention layers' reads against the KV cache (qk^T and probs·v, 2
     FLOPs per MAC each, over every cached position)."""
     n_matmul = _matmul_params(cfg, active=True) \
-        + cfg.vocab_size * cfg.d_model   # unembed logits matmul
+        + cfg.logit_size * cfg.d_model   # unembed logits matmul
     if cfg.attention == "mla":
         # absorbed: every head's query meets the cached latent row (and
         # its rotary key), and the probabilities the latent again
@@ -440,8 +488,8 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     d, hd, vd, h, ff = (cfg.d_model, cfg.head_dim, cfg.value_dim,
                         cfg.n_heads, cfg.ff_dim)
     pt = cfg.param_dtype
-    p: Params = {"attn_norm": jnp.ones((L, d), pt),
-                 "mlp_norm": jnp.ones((L, d), pt)}
+    p: Params = {"attn_norm": _unit_scale(cfg, (L, d)),
+                 "mlp_norm": _unit_scale(cfg, (L, d))}
     ax: Params = {"attn_norm": ("layers", "embed"),
                   "mlp_norm": ("layers", "embed")}
 
@@ -486,6 +534,11 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
             p["q_norm"], p["k_norm"] = jnp.ones((La, hd), pt), \
                 jnp.ones((La, hd), pt)
             ax["q_norm"] = ax["k_norm"] = ("layers", None)
+        n_eva = kind_layers(cfg, run, ("eva",))
+        if n_eva:       # a chunk's pooling: a direction and an offset a head
+            hk = cfg.kv_heads
+            add("adaptive_phi", (hk, hd), hd, ("heads", "kv"), n_eva)
+            add("adaptive_mu_k", (hk, hd), hd, ("heads", "kv"), n_eva)
     elif La:
         raise ValueError(f"attention={cfg.attention!r}: expected 'mha' or "
                          f"'mla'")
@@ -535,7 +588,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig
     params: Params = {
         "embed": {"tok": jax.random.normal(next(keys), (cfg.vocab_size, d),
                                            pt) * 0.02},
-        "final_norm": jnp.ones((d,), pt),
+        "final_norm": _unit_scale(cfg, (d,)),
     }
     axes: Params = {"embed": {"tok": ("vocab", "embed")},
                     "final_norm": ("embed",)}
@@ -552,9 +605,12 @@ def init_params(key: jax.Array, cfg: TransformerConfig
         params["embed"]["pos"] = jax.random.normal(
             next(keys), (cfg.max_seq_len, d), pt) * 0.01
         axes["embed"]["pos"] = (None, "embed")
+    if cfg.pred_heads > 1 and cfg.tie_embeddings:
+        raise ValueError("several prediction heads need an unembedding of "
+                         "their own (tie_embeddings=False)")
     if not cfg.tie_embeddings:
         params["lm_head"] = jax.random.normal(
-            next(keys), (d, cfg.vocab_size), pt) / math.sqrt(d)
+            next(keys), (d, cfg.logit_size), pt) / math.sqrt(d)
         axes["lm_head"] = ("embed", "vocab")
     return params, axes
 
@@ -591,9 +647,14 @@ def norm_eps(cfg: TransformerConfig) -> float:
 
 @jax.named_scope("norm")
 def _norm(cfg, x, scale, bias):
-    if cfg.norm == "rmsnorm":
-        return rmsnorm(x, scale, norm_eps(cfg))
-    return layernorm(x, scale, bias, norm_eps(cfg))
+    if cfg.norm_unit_offset:    # the weight is the scale's distance from 1
+        if cfg.norm != "rmsnorm":
+            raise ValueError("norm_unit_offset is an RMSNorm's")
+        scale = 1.0 + scale.astype(jnp.float32)
+    y = rmsnorm(x, scale, norm_eps(cfg)) if cfg.norm == "rmsnorm" \
+        else layernorm(x, scale, bias, norm_eps(cfg))
+    # a float32 stream is normed for matmuls in the compute type
+    return y.astype(cfg.dtype) if cfg.fp32_residual else y
 
 
 _EXPERT_STACKS = ("w_in", "w_gate", "w_out")
@@ -681,7 +742,7 @@ def rope_tables(cfg: TransformerConfig, make) -> Dict[str, Any]:
         return {}
     by_base: Dict[float, Any] = {}
     out = {}
-    for kind in ("full", "window"):
+    for kind in ATTENTION_KINDS:
         if kind in cfg.kinds and cfg.rotates(kind):
             base = cfg.rope_base_of(kind)
             if base not in by_base:
@@ -741,10 +802,16 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
         q, k, v = _qkv(cfg, y, lp, functools.partial(
             apply_rotary, cos=cos, sin=sin) if kind in angles else None,
             kind)
-        attn = multi_head_attention(
-            q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
-            window=cfg.sliding_window if kind == "window" else None,
-            sink=lp["sink"] if kind in cfg.sink_kinds else None)
+        if kind == "eva":       # the plain form: dense over the sequence
+            attn = eva_attention(q, k, v, lp["adaptive_phi"],
+                                 lp["adaptive_mu_k"],
+                                 window=cfg.sliding_window,
+                                 chunk=cfg.summary_chunk)
+        else:
+            attn = multi_head_attention(
+                q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
+                window=cfg.sliding_window if kind == "window" else None,
+                sink=lp["sink"] if kind in cfg.sink_kinds else None)
         x = x + _post(cfg, _attn_out(cfg, y, attn, lp), lp,
                       "post_attn_norm")
 
@@ -867,7 +934,7 @@ def _embed(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
     token table's rows, scaled, plus the learned positions where the model
     has them."""
     b, s = tokens.shape
-    dt = cfg.dtype
+    dt = cfg.stream_dtype
     if cfg.embed_impl == "one_hot":
         # gather's backward is a scatter-add into [vocab, d] — serialized
         # and slow on TPU; the one-hot formulation turns fwd AND bwd into
@@ -961,6 +1028,8 @@ def lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
         # [b, s, vocab] logits — the OOM cliff loss_chunk exists to avoid
         raise ValueError(f"seq length {s} is not divisible by "
                          f"loss_chunk={cfg.loss_chunk}")
+    if cfg.pred_heads > 1:
+        return _multi_head_loss(params, tokens, mask, cfg)
     if cfg.loss_chunk:
         x, aux = _trunk(params, tokens, cfg)
         with jax.named_scope("head"):
@@ -1108,6 +1177,10 @@ _CONV_KEYS = ("conv_in", "conv_w", "conv_out")
 _ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
               "wq_b", "wkv_a", "wkv_b", "kv_norm")
 _WINDOW_KV = ("wk_win", "wv_win")
+#: a chunk's pooling, of the layers that attend through summaries
+_EVA_KEYS = ("adaptive_phi", "adaptive_mu_k")
+#: the kinds of layer whose operator is attention
+ATTENTION_KINDS = ("full", "window", "eva")
 
 
 def kv_weight_names(cfg: TransformerConfig, kind: str) -> Tuple[str, str]:
@@ -1129,9 +1202,13 @@ def stack_kinds(cfg: TransformerConfig, key: str
         return cfg.sink_kinds
     if key in _WINDOW_KV:
         return ("window",)
+    if key in _EVA_KEYS:
+        return ("eva",)
     if key in _ATTN_KEYS:
-        return ("full",) if cfg.split_kv and key in ("wk", "wv") \
-            else ("full", "window")
+        if cfg.split_kv and key in ("wk", "wv"):
+            return ("full",)
+        # (a summary layer is named only by a model that has one)
+        return ATTENTION_KINDS if "eva" in cfg.kinds else ("full", "window")
     return None
 
 
@@ -1156,7 +1233,7 @@ def operator_layers(cfg: TransformerConfig, run: str,
                     upto: Optional[int] = None) -> Tuple[int, int]:
     """(attention layers, conv layers) among the first ``upto`` layers
     (None: all) of the run ``run`` of `layer_runs`."""
-    return (kind_layers(cfg, run, ("full", "window"), upto),
+    return (kind_layers(cfg, run, ATTENTION_KINDS, upto),
             kind_layers(cfg, run, ("conv",), upto))
 
 
@@ -1197,3 +1274,34 @@ def _conv_layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params
     y = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
     z, aux, _ = _ffn(cfg, y, lp)
     return x + _post(cfg, z, lp, "post_mlp_norm"), aux
+
+
+def _unit_scale(cfg: TransformerConfig, shape) -> jnp.ndarray:
+    """A norm's weight that multiplies by one: ones, or, where the norm adds
+    the unit itself (``norm_unit_offset``), zeros."""
+    return (jnp.zeros if cfg.norm_unit_offset else jnp.ones)(
+        shape, cfg.param_dtype)
+
+
+def _multi_head_loss(params: Params, tokens: jnp.ndarray, mask,
+                     cfg: TransformerConfig) -> jnp.ndarray:
+    """`lm_loss` of a model with several prediction heads: head ``p``'s
+    cross entropy against the token ``1 + p`` positions on, each head's mean
+    over the positions that have such a token, the heads' mean."""
+    import optax
+    if cfg.loss_chunk:
+        raise NotImplementedError("a chunked loss over several prediction "
+                                  "heads")
+    logits, _ = forward_with_aux(params, tokens, cfg)
+    s, v = tokens.shape[1], cfg.vocab_size
+    with jax.named_scope("head"):
+        total = 0.0
+        for p in range(cfg.pred_heads):
+            losses = optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :s - 1 - p, p * v:(p + 1) * v], tokens[:, 1 + p:])
+            if mask is None:
+                total += losses.mean()
+            else:
+                m = mask[:, 1 + p:].astype(jnp.float32)
+                total += (losses * m).sum() / jnp.maximum(m.sum(), 1.0)
+        return total / cfg.pred_heads

@@ -281,8 +281,11 @@ class ContinuousBatchingEngine:
                               draft_propose_slots, prefill_chunk_jit,
                               prefill_lanes_jit, verify_step_slots)
         from ..models.generate import (_decode_step_slots, cache_arrays,
-                                       cache_bytes)
+                                       cache_bytes, greedy_tokens)
         self._cache_arrays, self._cache_bytes = cache_arrays, cache_bytes
+        # a token is drawn from the next token's logits: head 0's, of a
+        # model with several prediction heads
+        self._greedy = functools.partial(greedy_tokens, cfg=cfg)
         self.cfg = cfg
         self.max_len = max_len
         self.params = params
@@ -308,7 +311,7 @@ class ContinuousBatchingEngine:
             logits, cache, load = _decode_step_slots(params, tok, cache,
                                                      active, cfg)
             with jax.named_scope("head"):
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                nxt = greedy_tokens(logits, cfg).astype(jnp.int32)
                 out = jnp.where(active, nxt, tok)
                 if moe_layers:
                     out = jnp.concatenate([out, jnp.stack(load)])
@@ -413,7 +416,7 @@ class ContinuousBatchingEngine:
         # beside its window (target and draft alike)
         room = min([self._capacity] + [
             c.window_chunk for c in (cfg, self._draft_cfg)
-            if c is not None and "window" in c.kinds])
+            if c is not None and {"window", "eva"} & set(c.kinds)])
         self.ecfg = dataclasses.replace(
             engine_cfg, prefill_chunk_tokens=prefill_chunk_width(
                 engine_cfg.prefill_chunk_tokens, params, room))
@@ -432,6 +435,12 @@ class ContinuousBatchingEngine:
         # layers whose state is no positions: the last conv_kernel - 1
         # inputs of a convolution, whatever the context
         self._conv_layers = cfg.kinds.count("conv")
+        # layers that attend their own BLOCK of `sliding_window` rows (a
+        # ring) and every earlier block through a summary row a
+        # `summary_chunk` positions
+        self._eva_layers = cfg.kinds.count("eva")
+        self._block, self._chunk_rows = cfg.sliding_window, \
+            cfg.summary_chunk
         self._spec_k = max(2, int(engine_cfg.spec_k))
         self._spec_disabled = False
         self._spec_fail_streak = 0
@@ -776,7 +785,8 @@ class ContinuousBatchingEngine:
         return {"bytes": sum(kinds.values()),
                 **{"bytes_" + kind: n for kind, n in kinds.items()},
                 "bytes_per_position":
-                    kinds["full"] // (self.ecfg.max_slots * self.max_len),
+                    (kinds["full"] + kinds.get("summary", 0))
+                    // (self.ecfg.max_slots * self.max_len),
                 **self.rows}
 
     def phase_totals(self) -> Dict[str, float]:
@@ -1009,13 +1019,33 @@ class ContinuousBatchingEngine:
         decoded nothing past it), and none of the seeded session's chunk
         windows, which start at ``depth`` and not at a multiple of the
         chunk, may be set back (a state cannot run tokens twice).  Any
-        other donor is refused, and the prompt prefills from its start."""
-        if not self._window and not self._conv_layers:
+        other donor is refused, and the prompt prefills from its start.
+
+        A SUMMARY layer (`models/generate.py`, the fourth state kind) has
+        both: summary rows, one a chunk of positions, and a ring of its
+        block.  The summaries of the chunks below ``depth`` are never
+        rewritten (a donor writes the row of the chunk its newest token
+        lies in and no earlier one), so every donor serves them, and the
+        rows from ``depth``'s chunk on are the seeded session's own to
+        write before any of its queries sees them.  The ring holds the
+        rows of the prefix's LAST block (``depth // sliding_window``; what
+        the seeded session's first queries attend exactly, and what its
+        first summary is pooled from) only while the donor still STANDS in
+        that block: once it has passed the block's end its ring has moved
+        on.  A prefix that ends on a block's edge needs no ring row at all.
+        Either way the first chunk window must not be set back before
+        ``depth``: it would need the block before."""
+        if not self._window and not self._conv_layers \
+                and not self._eva_layers:
             return True
         sess = self._donors.get(donor)
         if sess is None:
             return False
         chunk = self.ecfg.prefill_chunk_tokens
+        if self._eva_layers:
+            return depth + chunk <= self._capacity and (
+                depth % self._block == 0
+                or sess.pos // self._block == depth // self._block)
         if self._conv_layers and (
                 sess.pos != depth or
                 depth + -(-(n - depth) // chunk) * chunk > self._capacity):
@@ -1125,7 +1155,7 @@ class ContinuousBatchingEngine:
                            self._prof.wall_of("prefill_chunk") - wall0)
         if sess.poff < int(sess.prompt.shape[1]):
             return None
-        return jnp.argmax(sess.plogits, axis=-1).astype(jnp.int32)[0]
+        return self._greedy(sess.plogits).astype(jnp.int32)[0]
 
     # -------------------------------------------------- lanes: one chunk
     # program for several joining sessions
@@ -1229,7 +1259,7 @@ class ContinuousBatchingEngine:
         done = [s for s in riders if s.poff >= int(s.prompt.shape[1])]
         if not done:
             return []
-        firsts = jnp.argmax(logits, axis=-1)       # ONE read, later
+        firsts = self._greedy(logits)              # ONE read, later
         ready = [(sess, firsts, sess.lane) for sess in done]
         for sess in done:
             self._leave_lane(sess)
@@ -1269,7 +1299,7 @@ class ContinuousBatchingEngine:
                 [None] * self._n_lanes, pool, self.cfg,
                 chunk=self.ecfg.prefill_chunk_tokens,
                 capacity=self._capacity)
-            np.asarray(jnp.argmax(logits, axis=-1))
+            np.asarray(self._greedy(logits))
             jax.block_until_ready(
                 bare(self._gather)(pool, jnp.int32(0), jnp.int32(0)))
         except Exception as e:
@@ -1563,9 +1593,11 @@ class ContinuousBatchingEngine:
             "moe:load", "moe", self.moe, self._moe_span,
             layers=self._moe_layers, experts=self.cfg.n_experts_held)
 
-    #: what `_rows_of` counts a step, in its order
+    #: what `_rows_of` counts a step, in its order (the last two: of
+    #: `rows_read` and `bytes_read`, the part that is summary rows)
     _ROW_SUMS = ("rows_read", "rows_if_full", "bytes_read",
-                 "bytes_if_uniform")
+                 "bytes_if_uniform", "summary_rows_read",
+                 "summary_bytes_read")
 
     def _rows_of(self, batch) -> Tuple[int, ...]:
         """`_ROW_SUMS` of a decode step about to be dispatched: the cache
@@ -1576,18 +1608,36 @@ class ContinuousBatchingEngine:
         a full layer's row and a window layer's at what each holds a
         position (they differ where the kinds' key-value heads do), a conv
         layer's state whole, beside every layer's rows at the widest of
-        the model's rows (a model of one kind of row reads 100 %)."""
-        full = self.cfg.n_layers - self._window_layers - self._conv_layers
+        the model's rows (a model of one kind of row reads 100 %).  A
+        SUMMARY layer's slot at position ``t`` reads the ``t % block + 1``
+        ring rows of its own block and a summary row for each of the ``(t
+        // block) * block / chunk`` chunks before it, where a full layer
+        would read a row a position: the last two sums are those summary
+        rows and their bytes."""
+        eva = self._eva_layers
+        full = self.cfg.n_layers - self._window_layers - self._conv_layers \
+            - eva
         depth = sum(s.pos + 1 for s in batch)
         seen = sum(min(s.pos + 1, self._window) for s in batch)
         per_full, per_ring, state = (
             self._row_bytes[k] for k in ("full", "ring", "state"))
-        return (full * depth + self._window_layers * seen
-                + self._conv_layers * (self.cfg.conv_kernel - 1)
-                * len(batch), self.cfg.n_layers * depth,
-                full * depth * per_full + self._window_layers * seen
-                * per_ring + self._conv_layers * state * len(batch),
-                self.cfg.n_layers * depth * max(per_full, per_ring))
+        rows = full * depth + self._window_layers * seen \
+            + self._conv_layers * (self.cfg.conv_kernel - 1) * len(batch)
+        nbytes = full * depth * per_full + self._window_layers * seen \
+            * per_ring + self._conv_layers * state * len(batch)
+        widest = max(per_full, per_ring)
+        pooled = pooled_bytes = 0
+        if eva:
+            block, per_sum = self._block, self._row_bytes["summary"]
+            own = eva * sum(s.pos % block + 1 for s in batch)
+            pooled = eva * sum(s.pos // block for s in batch) \
+                * (block // self._chunk_rows)
+            pooled_bytes = pooled * per_sum
+            rows += own + pooled
+            nbytes += own * per_ring + pooled_bytes
+            widest = max(widest, per_sum)
+        return (rows, self.cfg.n_layers * depth, nbytes,
+                self.cfg.n_layers * depth * widest, pooled, pooled_bytes)
 
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
         """A read step's `_rows_of` into the counters, and the sums since

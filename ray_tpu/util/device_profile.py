@@ -331,6 +331,12 @@ class DispatchProfiler:
 #: to.  Lower case, one word; a reader of a trace knows this set.
 MODEL_PARTS = ("embed", "norm", "projections", "attention", "cache_write",
                "ffn", "experts", "conv", "head", "optimizer")
+# A scope may stand INSIDE a part, for a reader that wants it apart (the part
+# counts it too: the parts still add up): ``summary``, a summary layer's
+# pooling of a chunk and the write of its row (`models/generate.py`
+# `_write_summaries`), inside ``cache_write``.  Not a part of its own while
+# the benchmark's list (`perfbench/parts.py` ``PARTS``, held equal to
+# `MODEL_PARTS` by its tests) has ten.
 
 # opcodes that are no work of their own: never an event of a trace
 _FREE_OPS = frozenset(("parameter", "constant", "get-tuple-element", "tuple",
