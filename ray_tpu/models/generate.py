@@ -83,7 +83,12 @@ from head 0's (`next_token_logits`).
 Each array is stored ``[layers, batch, heads, width, rows]`` —
 positions LAST — and every program that takes a cache extends it IN PLACE:
 the whole stacked cache is state of the one layer loop
-(:func:`_scan_cached`), written by ``dynamic_update_slice`` and held to
+(:func:`_scan_cached`), written by ``dynamic_update_slice`` (a chunk's
+columns: one slice an array a layer) or, where every slot writes one
+column at a position of its own (the decode step), by ONE aliased kernel
+call an array a layer (`ops/cache_write.py`: the 128-row block of each
+slot that holds its column is read, changed and written back; never a
+scatter, which is not updated in place under this layout) and held to
 its row-major layout, so a caller that donates its cache pays no copy
 at all.  Positions are last because that is the tiling a TPU gives the
 array anyway: with ``head_dim`` 64 minor, 64 of 128 lanes (and 25 heads
@@ -117,6 +122,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..ops import eva_attention as eva
 from ..ops import latent_attention as mla
 from ..ops.attention import sink_softmax
+from ..ops.cache_write import device_calls, write_columns
 from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
 from .transformer import (ATTENTION_KINDS, TransformerConfig, _attn_out,
@@ -212,6 +218,18 @@ def cache_bytes(cache: KVCache) -> Dict[str, int]:
         kind = _state_kind(name)
         out[kind] = out.get(kind, 0) + int(a.nbytes)
     return out
+
+
+def column_write_counts(cache: KVCache) -> Tuple[int, int]:
+    """A decode step over this slot cache (one fed token a slot) → (the
+    columns it writes: a slot a layer of every array that holds positions,
+    summary rows among them; the device calls that write them on this
+    process's backend, `ops.cache_write.device_calls`): host counts from
+    shapes."""
+    arrays = [a.shape for name, a in cache_arrays(cache).items()
+              if _state_kind(name) != "state"]
+    return (sum(shape[0] * shape[1] for shape in arrays),
+            sum(shape[0] * device_calls(shape) for shape in arrays))
 
 
 def _state_kind(name: str) -> str:
@@ -376,7 +394,7 @@ def _eva_masks(cfg: TransformerConfig, pos, c: int,
 
 
 def _summary_write(cfg: TransformerConfig, pos, c: int, ring: int,
-                   lane=None, live=None, by_row: bool = False):
+                   lane=None, live=None):
     """The summaries of the chunks that the ``c`` new tokens from the scalar
     ``pos`` (batch rows ``lane`` on; every array's batch rows where ``lane``
     is None) reach, in two halves for `_write_summaries`, which pools every
@@ -398,9 +416,7 @@ def _summary_write(cfg: TransformerConfig, pos, c: int, ring: int,
     cannot straddle the seam (``chunk`` divides the ring); the rest are the
     program's own new columns ``kc``, ``vc`` [B, heads, width, c].  A chunk
     that the new tokens only begin is the next program's.  ``live`` as
-    `_full_write_chunk`'s.  ``by_row``: a row a slice, last first (a slot
-    at the cache's end is clamped onto the last row, which no query ever
-    sees, and the row that belongs there overwrites it)."""
+    `_full_write_chunk`'s."""
     ch = cfg.summary_chunk
     n = -(-c // ch)
     first = pos // ch
@@ -428,15 +444,36 @@ def _summary_write(cfg: TransformerConfig, pos, c: int, ring: int,
         for name, new in zip(_SUM_NAMES, pooled):
             s_all = arrs[name]
             new = new.astype(s_all.dtype)[None]
-            for i in reversed(range(n)) if by_row else (None,):
-                at = (l, lane, 0, 0, first + (i or 0))
-                piece = new if i is None else new[..., i:i + 1]
-                if live is not None:
-                    piece = jnp.where(live, piece, jax.lax.dynamic_slice(
-                        s_all, at, piece.shape))
-                s_all = jax.lax.dynamic_update_slice(s_all, piece, at)
-            out[name] = s_all
+            at = (l, lane, 0, 0, first)
+            if live is not None:
+                new = jnp.where(live, new, jax.lax.dynamic_slice(
+                    s_all, at, new.shape))
+            out[name] = jax.lax.dynamic_update_slice(s_all, new, at)
         return out
+
+    return read, place
+
+
+def _summary_write_slots(cfg: TransformerConfig, pos, c: int, ring: int):
+    """`_summary_write` for slots that stand at positions of their OWN
+    (``pos`` [S]): every slot's chunks read by its own slice, all slots'
+    rows (``pos // chunk`` on) placed by `ops.cache_write.write_columns`:
+    one call an array at one fed token a slot; a row a slice, last first,
+    where a verify feeds more (a slot at the cache's end is clamped onto
+    the last row, which no query ever sees, and the row that belongs there
+    overwrites it)."""
+    ch = cfg.summary_chunk
+    reads = [_summary_write(cfg, pos[slot], c, ring, slot)[0]
+             for slot in range(pos.shape[0])]
+
+    def read(arrs, l, *cols):
+        return tuple(jnp.concatenate(x, axis=0) for x in zip(
+            *(one(arrs, l, *cols) for one in reads)))
+
+    def place(arrs, l, *pooled):                # [S, heads, width, n]
+        rows = (pos // ch)[:, None] + jnp.arange(-(-c // ch))
+        return {name: write_columns(arrs[name], l, new, rows)
+                for name, new in zip(_SUM_NAMES, pooled)}
 
     return read, place
 
@@ -1165,10 +1202,20 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     expert layer routes and whose conv states advance (the others still
     compute: the batch shape is fixed, and their key and value columns
     land ahead of their ``pos``, but their states stay as they are).
-    Slots sit at DIFFERENT positions, so each fed
-    token's column is written by its own ``dynamic_update_slice``
-    (a scatter is not updated in place under the cache's layout).  A
-    slice whose start lies past the end is clamped onto the last
+    Slots sit at DIFFERENT positions, so no one slice holds their new
+    columns.  An XLA scatter (or a ``vmap`` of the update, which lowers to
+    one) is not updated in place under the cache's layout and puts the
+    cache's conversions back inside the layer loop (PR 26 took it out);
+    a ``dynamic_update_slice`` a slot costs the same whatever it moves
+    (every tile of ``heads x width``: 3.4-16 us, ``slots x arrays``
+    of them a layer).  So every array's columns go through
+    `ops.cache_write.write_columns`: where a slot is fed ONE token and the
+    array's rows are whole blocks of 128, ONE kernel call an array a layer
+    that aliases the array and moves only the 128-row block of each slot
+    that holds its column (``slots x heads x width x 128`` elements in and
+    out); elsewhere (a verify's several tokens a slot, a tiny test model's
+    rows, any platform but the TPU) the slices, a column each.  Either
+    way a column whose start lies past the end is clamped onto the last
     column, where a scatter would have dropped it: a slot's columns are
     therefore written LAST TOKEN FIRST, so the token that belongs in
     the last column overwrites what was clamped onto it.  Only a slot
@@ -1185,15 +1232,12 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     max_len = cache_capacity(cache, cfg)
     x, angles, mask = _row_inputs(params, tokens, pos, cfg, max_len)
 
+    fed = pos[:, None] + jnp.arange(c)                         # [S, C]
+
     def column_writes(column):
         @jax.named_scope("cache_write")
         def write(c_all, l, cols):                # [S, heads, width, C]
-            for slot in range(s):
-                for i in reversed(range(c)):
-                    c_all = jax.lax.dynamic_update_slice(
-                        c_all, cols[None, slot:slot + 1, :, :, i:i + 1],
-                        (l, slot, 0, 0, column(pos[slot] + i)))
-            return c_all
+            return write_columns(c_all, l, cols, column(fed))
         return write
 
     write = {"full": column_writes(lambda p: p)}
@@ -1204,8 +1248,7 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
         write["window"] = column_writes(lambda p: p % ring)
     if "eva" in cfg.kinds:
         write["eva"] = column_writes(lambda p: p % ring)
-        write["summary"] = [_summary_write(cfg, pos[slot], c, ring, slot,
-                                           by_row=True) for slot in range(s)]
+        write["summary"] = [_summary_write_slots(cfg, pos, c, ring)]
     valid = None if active is None else \
         jnp.broadcast_to(active[:, None], (s, c))
     return _attend_cached(

@@ -245,7 +245,7 @@ class _Step(NamedTuple):
     batch: List[Tuple[_EngineSession, int]]   # its live sessions, each
     #                                           with the slot it held THEN
     out: Any          # [slots (+3)] int32 on the device: tokens (+ routing)
-    rows: Tuple[int, ...]     # `_rows_of` its batch
+    rows: Tuple[int, ...]     # `_rows_of` its batch, `_WRITE_SUMS`
     # when the chip started on it, where the host can tell: a
     # ``perf_counter`` reading (nothing was queued before it), `_BEHIND`
     # (queued right behind the step before it: when that one's read
@@ -493,8 +493,11 @@ class ContinuousBatchingEngine:
         # `_MOE_SPAN_S` seconds with the sums since the last; and the
         # same in BYTES, a row costing what its layer's kind holds a
         # position (`_row_bytes`), beside what the rows would cost were
-        # every layer a full one at the model's widest key-value heads
-        self.rows = dict.fromkeys(("steps",) + self._ROW_SUMS, 0)
+        # every layer a full one at the model's widest key-value heads;
+        # and the columns the steps WROTE beside the device calls that
+        # wrote them (one an array a layer where the kernel engages)
+        self.rows = dict.fromkeys(
+            ("steps",) + self._ROW_SUMS + self._WRITE_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
         from ..models.generate import position_bytes
         self._row_bytes = position_bytes(cfg)
@@ -779,7 +782,8 @@ class ContinuousBatchingEngine:
         window layers' rings; ``bytes_state``: the conv layers' states),
         what ONE further position of a slot costs (the full arrays' bytes
         a row: a ring and a state grow with nothing), and the rows and
-        bytes the decode steps read (`_ROW_SUMS`); zeros until the first
+        bytes the decode steps read (`_ROW_SUMS`) and the columns they wrote
+        (`_WRITE_SUMS`); zeros until the first
         session allocates the cache."""
         kinds = self._cache_bytes(self._cache or {})
         return {"bytes": sum(kinds.values()),
@@ -1366,6 +1370,7 @@ class ContinuousBatchingEngine:
         import numpy as np
 
         from ..models import init_slot_cache
+        from ..models.generate import column_write_counts
         from ..util import fault_injection as fi
         from ..util import tracing
         if self._cache is None:
@@ -1374,6 +1379,8 @@ class ContinuousBatchingEngine:
             if self._spec:
                 self._dcache = init_slot_cache(
                     self._draft_cfg, self.ecfg.max_slots, self.max_len)
+        # `_WRITE_SUMS` of one fused step: the cache's shapes say it
+        self._writes_a_step = column_write_counts(self._cache)
         slots = self.ecfg.max_slots
         # a step's routing counts ride behind its tokens (`fused_step`):
         # the host's row is as long, so both ways in are one shape.  Only
@@ -1535,7 +1542,8 @@ class ContinuousBatchingEngine:
             self._carry = self._join(self._carry, firsts)
         if key != self._active_key:
             self._active_dev, self._active_key = jnp.asarray(active), key
-        rows = self._rows_of(batch)    # at the positions BEFORE this step
+        # at the positions BEFORE this step; and what it writes
+        rows = self._rows_of(batch) + self._writes_a_step
         flight = self._flight
         with self._cond:
             for s in batch:
@@ -1598,6 +1606,12 @@ class ContinuousBatchingEngine:
     _ROW_SUMS = ("rows_read", "rows_if_full", "bytes_read",
                  "bytes_if_uniform", "summary_rows_read",
                  "summary_bytes_read")
+    #: ... and beside them what a step WRITES, whatever its positions
+    #: (`models.generate.column_write_counts`): a column a slot, live or
+    #: not, a layer of every array that holds positions, and the device
+    #: calls that write them (one kernel call an array a layer, or a slice
+    #: a column)
+    _WRITE_SUMS = ("column_writes", "column_write_calls")
 
     def _rows_of(self, batch) -> Tuple[int, ...]:
         """`_ROW_SUMS` of a decode step about to be dispatched: the cache
@@ -1640,11 +1654,12 @@ class ContinuousBatchingEngine:
                 self.cfg.n_layers * depth * widest, pooled, pooled_bytes)
 
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
-        """A read step's `_rows_of` into the counters, and the sums since
-        the last `cache:rows` span into the next when due."""
+        """A read step's `_rows_of` and column writes into the counters,
+        and the sums since the last `cache:rows` span into the next when
+        due."""
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
-            for k, n in zip(self._ROW_SUMS, rows):
+            for k, n in zip(self._ROW_SUMS + self._WRITE_SUMS, rows):
                 self.rows[k] += n
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,

@@ -64,6 +64,33 @@ def _no_runtime_left_behind():
         ray_tpu.shutdown()
 
 
+#: a process may hold `vm.max_map_count` (65530) memory mappings, and every
+#: program XLA compiles for the CPU keeps a few: an xdist worker that is
+#: handed three model families' files in a row passes it, and the next
+#: compile dies (a segmentation fault, an abort or a MemoryError)
+_MAPS_HIGH = 30000
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _compiled_programs_let_go():
+    """After a module, where this process's mappings have grown past
+    `_MAPS_HIGH`, drop JAX's caches of compiled programs (the next module
+    compiles what it runs anyway)."""
+    yield
+    import gc
+    import sys
+    if "jax" not in sys.modules:
+        return
+    try:
+        with open("/proc/self/maps") as f:
+            maps = sum(1 for _ in f)
+    except OSError:
+        return
+    if maps > _MAPS_HIGH:
+        sys.modules["jax"].clear_caches()
+        gc.collect()
+
+
 @pytest.fixture
 def local_cluster():
     """A started single-node runtime, shut down afterwards."""

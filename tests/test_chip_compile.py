@@ -294,6 +294,85 @@ def test_served_program_converts_no_cache_on_the_v5e(topo, program, model):
     shape = ",".join(map(str, cache["k"].shape))
     copies = re.findall(rf"= bf16\[{shape}\]\S* copy\(", compiled.as_text())
     assert not copies, copies
+    if program == "fused_step":
+        _one_write_an_array(compiled.as_text(), cache)
+
+
+def _one_write_an_array(text, cache):
+    """A decode step's compiled text: every slot's new column goes through
+    `ops/cache_write.py`'s kernel, called in place on each array of the
+    cache, and of the ``slots`` one-column ``dynamic-update-slice``s an
+    array a layer that the step held before it (32 at gpt2-medium's 16
+    slots) at most one an array is left."""
+    import re
+    calls = [c for c in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+             if "cache_column_write" in c]
+    # the arrays that hold positions (a conv state is placed whole)
+    shapes = [",".join(map(str, a.shape)) for name, a in cache.items()
+              if name != "pos" and not name.endswith("_state")]
+    for shape in set(shapes):
+        mine = [c for c in calls if re.match(rf"= bf16\[{shape}\]", c)]
+        assert mine and all(
+            "output_to_operand_aliasing={{}: (3, {})}" in c for c in mine), (
+            shape, calls)
+        slices = re.findall(
+            rf"= bf16\[{shape}\]\S* dynamic-update-slice\(", text)
+        assert len(slices) <= shapes.count(shape), (shape, len(slices))
+
+
+def test_byte_model_step_writes_one_call_an_array_on_the_v5e(topo):
+    """The byte cell's step (its file's widths, two of its layers, 12 slots
+    x 26624): rings of 2176 rows beside 1664 summary rows, 32 heads of 128.
+    The donated step aliases all four arrays, keeps under a quarter of the
+    cache beside it, copies none, and writes each through ONE kernel call a
+    layer: the ring's column ``pos % ring`` and the summary's row ``pos //
+    16`` of all 12 slots (48 slices a layer before)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import init_params, init_slot_cache
+    from ray_tpu.models.generate import _decode_step_slots, cache_arrays
+    c = mf.Manifest().config("evabyte")
+    cfg = mf.family_of(c).model.model_config(
+        dict(c, num_hidden_layers=2), "serve")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 12, 26624
+    cache = described(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)))
+
+    def fused_step(params, tok, cache, active):
+        logits, cache, _ = _decode_step_slots(params, tok, cache, active,
+                                              cfg)
+        nxt = jnp.argmax(logits[..., :cfg.vocab_size], axis=-1)
+        return jnp.where(active, nxt.astype(jnp.int32), tok), cache
+    compiled = jax.jit(fused_step, donate_argnums=(2,)).lower(
+        params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    ).compile()
+    arrays = cache_arrays(cache)
+    assert {n: a.shape for n, a in arrays.items()} == {
+        "k_win": (2, 12, 32, 128, 2176), "v_win": (2, 12, 32, 128, 2176),
+        "k_sum": (2, 12, 32, 128, 1664), "v_sum": (2, 12, 32, 128, 1664)}
+    want = sum(a.size * a.dtype.itemsize for a in arrays.values())
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= want
+    assert ma.temp_size_in_bytes < want // 4, (ma.temp_size_in_bytes, want)
+    text = compiled.as_text()
+    for a in arrays.values():
+        shape = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    _one_write_an_array(text, cache)
 
 
 def _lower_lanes(described, params, cfg, program, max_len):
@@ -440,6 +519,8 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
     assert ma.temp_size_in_bytes < (64 << 20) + 2 * scores, \
         ma.temp_size_in_bytes
     text = compiled.as_text()
+    if program == "fused_step":
+        _one_write_an_array(text, cache)
     shape = ",".join(map(str, cache["kv"].shape))
     assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text)
     # three grouped matmuls a layer, this repo's kernel and not XLA's, each
@@ -535,6 +616,8 @@ def test_window_and_full_layers_copy_no_cache_no_ring_no_weights(topo,
     assert ma.temp_size_in_bytes < (64 << 20) + 2 * scores, \
         ma.temp_size_in_bytes
     text = compiled.as_text()
+    if program == "fused_step":
+        _one_write_an_array(text, cache)
     for a in arrays.values():
         shape = ",".join(map(str, a.shape))
         assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
@@ -628,6 +711,8 @@ def test_conv_states_beside_rows_copy_no_cache_no_state_no_weights(topo,
     assert ma.alias_size_in_bytes >= want
     assert ma.temp_size_in_bytes < 64 << 20, ma.temp_size_in_bytes
     text = compiled.as_text()
+    if program == "fused_step":
+        _one_write_an_array(text, cache)
     for a in arrays.values():
         shape = ",".join(map(str, a.shape))
         assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
@@ -728,6 +813,8 @@ def test_two_cache_shapes_by_layer_kind_copy_no_cache_no_weights(topo,
     assert ma.temp_size_in_bytes < (96 << 20) + 2 * scores, \
         ma.temp_size_in_bytes
     text = compiled.as_text()
+    if program == "fused_step":
+        _one_write_an_array(text, cache)
     for a in arrays.values():
         shape = ",".join(map(str, a.shape))
         assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
