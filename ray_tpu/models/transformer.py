@@ -83,6 +83,7 @@ import jax.numpy as jnp
 from ..ops import latent_attention as mla
 from ..ops.attention import multi_head_attention
 from ..ops.eva_attention import eva_attention
+from ..ops.flash_attention import FLASH_LSE, FLASH_OUT
 from ..ops.norms import layernorm, rmsnorm
 from ..ops.rotary import apply_rotary, rotary_angles
 
@@ -107,7 +108,9 @@ class TransformerConfig:
     param_dtype: Any = jnp.float32
     attention_impl: str = "auto"      # "auto"|"flash"|"reference"|"ring"
     causal: bool = True               # False → bidirectional (encoders)
-    remat: Any = True                 # False | True (full) | "dots":
+    remat: Any = True                 # False | True (full: a layer keeps
+    #   its input and, where attention is the flash kernel, the kernel's
+    #   output and row statistics; all else is recomputed) | "dots":
     #   saves matmul outputs and recomputes only elementwise ops in the
     #   backward pass (most of full remat's memory win, no extra MXU work)
     embed_impl: str = "gather"        # "gather" | "one_hot" (MXU-matmul
@@ -622,13 +625,26 @@ def init_params(key: jax.Array, cfg: TransformerConfig
 def remat_policy(remat):
     """Resolve a config's ``remat`` field to a jax.checkpoint policy, or
     None when remat is off.  Shared by every model family (transformer,
-    ViT) so the accepted values can't diverge."""
+    ViT) so the accepted values can't diverge.
+
+    Full remat (``True``) recomputes what is cheap and keeps what is dear:
+    beside the layer's input it saves the flash kernel's output and row
+    statistics (`flash_attention.FLASH_OUT`, `FLASH_LSE`: one ``[b, s, d]``
+    activation and ``[b, head blocks, 8, s]`` float32 a layer), which is
+    all the backward kernels want of the forward one, so the kernel runs
+    once a layer (an eighth of the MXU's peak: 65 of 843 ms of
+    gpt2-medium's step, PERF.md PR 44).  ``"dots"`` saves the two beside
+    its matmul outputs.  A layer whose attention is not the kernel has no
+    such name in it, and the policy saves what it saved without them."""
     if not remat:
         return None
-    if remat == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    flash = jax.checkpoint_policies.save_only_these_names(
+        FLASH_OUT, FLASH_LSE)
+    if remat == "dots":     # a kernel call is no dot: saved by its names
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable, flash)
     if remat is True:
-        return jax.checkpoint_policies.nothing_saveable
+        return flash
     # an unrecognized string must not silently mean full remat
     raise ValueError(f"remat={remat!r}: expected False, True, or 'dots'")
 

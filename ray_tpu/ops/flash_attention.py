@@ -72,6 +72,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -95,6 +96,11 @@ _MAX_HEADS = 8    # heads unrolled in one kernel body (code size, compile time)
 # (128 MiB on a v5e, of which the compiler scopes a kernel `_VMEM_LIMIT`)
 _VMEM_BLOCK_BUDGET = 14 << 20
 _VMEM_LIMIT = 48 << 20
+# `checkpoint_name`s of what the backward kernels take from the forward: the
+# two values a layer's checkpoint keeps under full remat
+# (`models.transformer.remat_policy`), so the forward kernel is not run again
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 
 def fit_block(block: int, s: int) -> int:
@@ -722,7 +728,10 @@ def _flash(q, k, v, causal, sm_scale, plan):
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, plan):
     o, lse = _flash_fwd(q, k, v, causal, sm_scale, plan)
-    return o, (q, k, v, o, lse)
+    # the NAMED output is the primal too: once a checkpoint policy saves
+    # both names, its recompute pass wants no output of the forward call
+    o = checkpoint_name(o, FLASH_OUT)
+    return o, (q, k, v, o, checkpoint_name(lse, FLASH_LSE))
 
 
 def _flash_bwd_rule(causal, sm_scale, plan, res, do):

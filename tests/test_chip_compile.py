@@ -144,6 +144,58 @@ def test_flash_calls_take_dense_operands_and_no_copy(topo, shape):
                               for sh in shapes), (result, operands, shapes)
 
 
+@pytest.mark.parametrize("shape,parent_temp", [
+    # the train cells' micro-batch a chip: gpt2-medium, gpt2-xl under fsdp=4;
+    # `temp_size_in_bytes` of the same gradient under `nothing_saveable`
+    # (what ``remat=True`` was before PR 44, compiled here, JAX 0.9.0)
+    ((8, 1024, 16, 16, 64), 156866560), ((2, 1024, 25, 25, 64), 28762624),
+], ids=["gpt2-medium-8x1024", "gpt2-xl-2x1024-a-chip"])
+def test_full_remat_layer_gradient_holds_one_flash_forward(topo, shape,
+                                                           parent_temp):
+    """The gradient of one layer under the checkpoint ``remat=True`` gives
+    it (`remat_policy`), as the chip's compiler leaves it: ONE forward
+    kernel beside dq and dkv (the recompute pass wants no output of the
+    call once its output and statistics are saved), and temporaries that
+    grow by no more than those two arrays over what they were when the
+    kernel ran twice."""
+    import functools
+    import importlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import TransformerConfig, init_params, transformer
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    b, s, h, _, d = shape
+    cfg = TransformerConfig(vocab_size=128, d_model=h * d, n_layers=1,
+                            n_heads=h, max_seq_len=s, attention_impl="flash")
+    one = SingleDeviceSharding(topo.devices[0])
+    stacked = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)[0])["layers"]
+    lp = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape[1:], a.dtype, sharding=one), stacked)
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one)
+    layer = jax.checkpoint(functools.partial(transformer._layer, cfg),
+                           policy=transformer.remat_policy(True))
+
+    def loss(x, lp):
+        return (layer(x, lp, {})[0].astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, lp).compile()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\".*",
+                       compiled.as_text())
+    assert sorted(re.search(r"flash_attention_\w+", c).group(0)
+                  for c in calls) == ["flash_attention_dkv",
+                                      "flash_attention_dq",
+                                      "flash_attention_fwd"]
+    plan = _flash_plan(fa, shape)
+    kept = b * s * h * d * 2 + b * plan.head_blocks * plan.hq * s * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= parent_temp + kept
+
+
 @pytest.mark.parametrize("shape", [
     # batch, s_q, s_kv, heads, kv heads, head size, causal
     (2, 192, 192, 12, 12, 64, True), (2, 197, 197, 12, 12, 64, False),

@@ -16,6 +16,26 @@ from ray_tpu.parallel import (FSDP_TP_RULES, MeshSpec, create_mesh,
                               pytree_shardings)
 
 
+def _output_and_grads(attend, q, k, v, live=slice(None)):
+    """``attend(q, k, v)`` and the gradients of the tests' loss (its squares
+    summed over the ``live`` rows), from ONE compiled run: eager, the
+    forward ran once for the output and again inside the gradient, every
+    operation (or interpreted kernel) a compile of its own."""
+    def loss(*a):
+        out = attend(*a)
+        return (out[:, live].astype(jnp.float32) ** 2).sum(), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return out, grads
+
+
+def _reference_output_and_grads(q, k, v, causal=True, live=slice(None)):
+    import functools
+    return _output_and_grads(
+        functools.partial(reference_attention, causal=causal), q, k, v, live)
+
+
 def test_norms_match_numpy():
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 32), jnp.float32)
     scale = jnp.ones((32,)) * 2.0
@@ -128,7 +148,6 @@ def test_flash_kernel_interpret_mode_parity(monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import reference_attention
     from ray_tpu.ops.flash_attention import flash_attention
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -136,13 +155,11 @@ def test_flash_kernel_interpret_mode_parity(monkeypatch):
     k = jax.random.normal(k2, (1, 128, 2, 32), jnp.float32)  # GQA
     v = jax.random.normal(k3, (1, 128, 2, 32), jnp.float32)
     out = flash_attention(q, k, v, causal=True)
-    ref = reference_attention(q, k, v, causal=True)
+    ref, g_r = _reference_output_and_grads(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
     g_f = jax.grad(lambda *a: (flash_attention(*a, causal=True) ** 2)
-                   .sum(), argnums=(0, 1, 2))(q, k, v)
-    g_r = jax.grad(lambda *a: (reference_attention(*a, causal=True) ** 2)
                    .sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_f, g_r):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -212,7 +229,6 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import reference_attention
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     (b, s_q, s_kv, h, h_kv, d, causal, bq, bk, dtype, held), heads = \
         _FLASH_CASES[case]
@@ -231,25 +247,98 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     # reference a mean of v; compared on the rows that attend
     live = slice(max(s_q - s_kv, 0) if causal else 0, None)
 
-    def loss(fn):
-        return lambda *a: (fn(*a)[:, live].astype(jnp.float32) ** 2).sum()
     flash = lambda *a: fa.flash_attention(*a, causal=causal, block_q=bq,
                                           block_k=bk)
-    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
-    ref = lambda *a: reference_attention(*a, causal=causal)
-    out = flash(q, k, v)
+    out_r, g_r = _reference_output_and_grads(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal, live)
+    # the three kernels in one program (the forward that is no gradient's:
+    # `test_flash_takes_a_whole_short_sequence_as_one_tile` and the parity
+    # tests above)
+    out, g_f = _output_and_grads(flash, q, k, v, live)
     assert out.dtype == dt and out.shape == q.shape
     assert not np.asarray(out[:, :live.start], np.float32).any()
     tol_o, tol_g = (2e-5, 5e-4) if dtype == "float32" else (3e-2, 0.25)
     np.testing.assert_allclose(np.asarray(out[:, live], np.float32),
-                               np.asarray(ref(*f32)[:, live]),
+                               np.asarray(out_r[:, live]),
                                atol=tol_o, rtol=tol_o)
-    g_f = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_r = jax.grad(loss(ref), argnums=(0, 1, 2))(*f32)
     for a, r in zip(g_f, g_r):
         assert a.dtype == dt
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
                                    atol=tol_g, rtol=tol_g)
+
+
+def _pallas_calls(jaxpr, times=1, found=None):
+    """Kernel name -> calls a run of ``jaxpr`` makes (a scan's body counted
+    by its length)."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            found[name] = found.get(name, 0) + times
+        inner = times * (eqn.params["length"]
+                         if eqn.primitive.name == "scan" else 1)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, inner, found)
+    return found
+
+
+@pytest.mark.parametrize("case", ["one-device", "fsdp-4", "reference"])
+def test_full_remat_runs_the_flash_forward_once_a_layer(monkeypatch, case):
+    """Under ``remat=True`` a layer's checkpoint keeps the flash kernel's
+    output and row statistics (`remat_policy`), so the gradient runs the
+    forward kernel once a layer, as ``remat=False`` does, where
+    `nothing_saveable` ran it twice; the same per shard under a mesh
+    (`_flash_per_shard`'s `shard_map` passes the policy through) and under
+    ``"dots"``.  The gradients are ``remat=False``'s (to the rounding of
+    the CPU's own fusions: the arithmetic is the same).  Where attention is
+    not the kernel the policy finds no name to save and the lowered
+    gradient is `nothing_saveable`'s, character for character."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    import contextlib
+    import dataclasses
+
+    from ray_tpu.models import transformer
+    layers = 2
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=128, n_layers=layers, n_heads=2,
+        max_seq_len=128, dtype=jnp.float32,
+        attention_impl="reference" if case == "reference" else "flash")
+    params, _ = init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 128),
+                                          0, 64)}
+    mesh = contextlib.nullcontext() if case != "fsdp-4" else jax.set_mesh(
+        create_mesh(MeshSpec(fsdp=4), devices=jax.devices()[:4]))
+
+    def traced(remat):
+        c = dataclasses.replace(cfg, remat=remat)
+        return jax.jit(jax.grad(lambda p: lm_loss(p, batch, c))).trace(params)
+
+    def parent():       # full remat as it was: a fresh trace under the patch
+        monkeypatch.setattr(
+            transformer, "remat_policy",
+            lambda remat: jax.checkpoint_policies.nothing_saveable)
+        return traced(True)
+
+    with mesh:
+        if case == "reference":
+            ours = traced(True)
+            assert "pallas_call" not in str(ours.jaxpr)
+            assert ours.lower().as_text() == parent().lower().as_text()
+            return
+        programs = {remat: traced(remat) for remat in (True, False, "dots")}
+        assert ("shard_map" in str(programs[True].jaxpr)) == (case == "fsdp-4")
+        want = {f"flash_attention_{k}": layers for k in ("fwd", "dq", "dkv")}
+        for remat, program in programs.items():
+            assert _pallas_calls(program.jaxpr.jaxpr) == want, remat
+        assert _pallas_calls(parent().jaxpr.jaxpr) == {
+            **want, "flash_attention_fwd": 2 * layers}
+        if case == "fsdp-4":    # the arithmetic is the one device's
+            return
+        got, ref = (programs[remat].lower().compile()(params)
+                    for remat in (True, False))
+    for a, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-4,
+                                   atol=1e-6 * float(jnp.abs(r).max()))
 
 
 # (batch, s_q, s_kv, heads, kv heads, head size, causal, dtype): sequences
@@ -282,8 +371,7 @@ def test_flash_takes_a_whole_short_sequence_as_one_tile(monkeypatch, case):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import (multi_head_attention,
-                                       reference_attention)
+    from ray_tpu.ops.attention import multi_head_attention
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
     b, s_q, s_kv, h, h_kv, d, causal, dtype = _WHOLE_SEQUENCE_CASES[case]
     tiles = (fa.fit_block(fa.DEFAULT_BLOCK_Q, s_q),
@@ -300,16 +388,15 @@ def test_flash_takes_a_whole_short_sequence_as_one_tile(monkeypatch, case):
     def loss(fn):
         return lambda *a: (fn(*a)[:, live].astype(jnp.float32) ** 2).sum()
     flash = lambda *a: multi_head_attention(*a, causal=causal, impl="flash")
-    ref = lambda *a: reference_attention(*a, causal=causal)
-    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    out_r, g_r = _reference_output_and_grads(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal, live)
     tol_o, tol_g = (2e-5, 5e-4) if dtype == "float32" else (3e-2, 0.25)
     out = flash(q, k, v)
     assert out.dtype == dt and out.shape == q.shape
     np.testing.assert_allclose(np.asarray(out[:, live], np.float32),
-                               np.asarray(ref(*f32)[:, live]),
+                               np.asarray(out_r[:, live]),
                                atol=tol_o, rtol=tol_o)
     g_f = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_r = jax.grad(loss(ref), argnums=(0, 1, 2))(*f32)
     for a, r in zip(g_f, g_r):
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
                                    atol=tol_g, rtol=tol_g)
@@ -382,8 +469,7 @@ def test_flash_kernel_runs_per_shard_under_a_mesh(monkeypatch):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_tpu.ops.attention import (multi_head_attention,
-                                       reference_attention)
+    from ray_tpu.ops.attention import multi_head_attention
     from ray_tpu.parallel import MeshSpec, create_mesh
 
     mesh = create_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
@@ -395,8 +481,7 @@ def test_flash_kernel_runs_per_shard_under_a_mesh(monkeypatch):
     def loss(impl):
         return lambda *a: (multi_head_attention(*a, impl=impl) ** 2).sum()
 
-    ref = reference_attention(q, k, v)
-    g_ref = jax.grad(loss("reference"), argnums=(0, 1, 2))(q, k, v)
+    ref, g_ref = _reference_output_and_grads(q, k, v)
     sharding = NamedSharding(mesh, P("fsdp", None, "tp", None))
     with jax.set_mesh(mesh):
         qs, ks, vs = (jax.device_put(x, sharding) for x in (q, k, v))
@@ -422,7 +507,6 @@ def test_flash_kernel_interpret_mode_bf16(monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import reference_attention
     from ray_tpu.ops.flash_attention import flash_attention
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -431,19 +515,14 @@ def test_flash_kernel_interpret_mode_bf16(monkeypatch):
     v = jax.random.normal(k3, (1, 128, 2, 32), jnp.bfloat16)
     out = flash_attention(q, k, v, causal=True)
     assert out.dtype == jnp.bfloat16
-    ref = reference_attention(q.astype(jnp.float32), k.astype(jnp.float32),
-                              v.astype(jnp.float32), causal=True)
+    ref, g_r = _reference_output_and_grads(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref), atol=3e-2, rtol=3e-2)
 
     loss_f = lambda *a: (flash_attention(*a, causal=True)
                          .astype(jnp.float32) ** 2).sum()
-    loss_r = lambda *a: (reference_attention(*a, causal=True)
-                         .astype(jnp.float32) ** 2).sum()
     g_f = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
-    g_r = jax.grad(loss_r, argnums=(0, 1, 2))(
-        q.astype(jnp.float32), k.astype(jnp.float32),
-        v.astype(jnp.float32))
     for a, b in zip(g_f, g_r):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b), atol=0.25, rtol=0.25)
@@ -471,8 +550,9 @@ def test_one_hot_embed_parity():
     p, _ = init_params(jax.random.PRNGKey(0), c1)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 32),
                                           0, 64)}
-    l1, g1 = jax.value_and_grad(lambda pp: lm_loss(pp, batch, c1))(p)
-    l2, g2 = jax.value_and_grad(lambda pp: lm_loss(pp, batch, c2))(p)
+    # compiled: eager, every operation of both passes compiles on its own
+    l1, g1 = jax.jit(jax.value_and_grad(lambda pp: lm_loss(pp, batch, c1)))(p)
+    l2, g2 = jax.jit(jax.value_and_grad(lambda pp: lm_loss(pp, batch, c2)))(p)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
@@ -496,8 +576,10 @@ def test_chunked_lm_loss_parity():
 
     for batch in ({"tokens": tokens},
                   {"tokens": tokens, "mask": mask}):
-        lf, gf = jax.value_and_grad(lm_loss)(params, batch, cfg_full)
-        lc, gc = jax.value_and_grad(lm_loss)(params, batch, cfg_chunk)
+        lf, gf = jax.jit(jax.value_and_grad(lm_loss), static_argnums=2)(
+            params, batch, cfg_full)
+        lc, gc = jax.jit(jax.value_and_grad(lm_loss), static_argnums=2)(
+            params, batch, cfg_chunk)
         np.testing.assert_allclose(float(lf), float(lc), rtol=1e-6)
         for a, b in zip(jax.tree_util.tree_leaves(gf),
                         jax.tree_util.tree_leaves(gc)):
@@ -522,7 +604,9 @@ def test_vit_learns_and_shards():
     params, axes = init_vit_params(key, cfg)  # axes validated by the
     # pytree_shardings call below (tuple leaves, same tree shape)
 
-    def make_batch(k, n=64):
+    @jax.jit        # thirty batches: one compile, not every operation's
+    def make_batch(k):
+        n = 64
         kk, kl = jax.random.split(k)
         labels = jax.random.randint(kl, (n,), 0, 4)
         imgs = jnp.zeros((n, 16, 16, 1))
@@ -549,7 +633,8 @@ def test_vit_learns_and_shards():
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0] * 0.5, (losses[0], losses[-1])
     eval_batch = make_batch(jax.random.PRNGKey(999))
-    logits = vit_forward(params, eval_batch["image"], cfg)
+    forward = jax.jit(lambda p, images: vit_forward(p, images, cfg))
+    logits = forward(params, eval_batch["image"])
     acc = float((jnp.argmax(logits, -1) == eval_batch["label"]).mean())
     assert acc > 0.8, acc
 
@@ -562,7 +647,7 @@ def test_vit_learns_and_shards():
         s_step = jax.jit(make_vit_train_step(cfg, opt))
         sharded, s_opt_state, m = s_step(sharded, s_opt_state,
                                          eval_batch)
-        out = vit_forward(sharded, eval_batch["image"], cfg)
+        out = forward(sharded, eval_batch["image"])
     assert float(m["loss"]) > 0.0
     assert out.shape == (64, 4)
 
