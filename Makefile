@@ -2,7 +2,7 @@
 # tree): native object store + transfer plane, C++ driver API, wheel.
 PY ?= python
 
-.PHONY: all native cpp wheel test smoke obs chaos drain failover spec \
+.PHONY: all native cpp wheel test smoke obs chaos drain failover \
 	elastic ha partition autoscale profile lint lint-fast overload \
 	diskfault containment clean
 
@@ -104,13 +104,6 @@ ha:
 # completing via the relay rung ×2 seeds.
 partition:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_partition.py -q
-
-# Spec suite: chunked-prefill admission + speculative decoding —
-# verify-program exactness, chunk-boundary/admission parity, shared and
-# adversarial (random) draft parity, chaos degrade-to-plain, resume
-# into a speculating engine, program-shape dedup.
-spec:
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_serve_spec_decode.py -q
 
 # Static analysis in one shot: the framework-invariant suite — all
 # eight rules (PR-13: loop-blocking / thread-race / chaos-site /

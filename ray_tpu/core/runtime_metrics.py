@@ -194,22 +194,13 @@ SERVE_AUTOSCALE_DECISIONS = m.Counter(
     "Applied serve autoscale decisions by direction (up | down); "
     "nodelet-folded from serve controller pushes so history/top see "
     "scale activity", ("deployment", "direction"))
-SERVE_SPEC_PROPOSED = m.Counter(
-    "ray_tpu_serve_spec_tokens_proposed_total",
-    "Draft-model tokens offered to speculative verification by serve "
-    "decode engines", ("deployment",))
-SERVE_SPEC_ACCEPTED = m.Counter(
-    "ray_tpu_serve_spec_tokens_accepted_total",
-    "Draft-model tokens the target's batched verify step accepted "
-    "(exact greedy match; the bonus token per iteration is not counted)",
-    ("deployment",))
 # -- data-plane dispatch profiling (util/device_profile.py snapshots
 # ride the replica's `serve_metrics` push; the nodelet folds cumulative
 # deltas here so compile ledgers and MFU reach cluster scrape) ---------
 DEVICE_DISPATCHES = m.Counter(
     "ray_tpu_device_dispatches_total",
     "Jitted-program dispatches by the data plane (decode step, prefill "
-    "chunk, draft/verify, cache insert/gather), folded from replica "
+    "chunk, cache insert/gather), folded from replica "
     "dispatch-profiler snapshots", ("program", "deployment"))
 DEVICE_SECONDS = m.Counter(
     "ray_tpu_device_seconds_total",
@@ -233,7 +224,7 @@ SERVE_PHASE_SECONDS = m.Counter(
     "Serve data-plane time by named phase (cold_start: lazy replica "
     "construction; queue: enqueue to first prefill chunk; admission: "
     "first token to decode slot; prefill: chunk program wall; "
-    "decode_dispatch: decode/draft/verify/insert program wall) — the "
+    "decode_dispatch: decode/insert program wall) — the "
     "serve_breakdown attribution table's source",
     ("deployment", "phase"))
 CONTROLLER_FAILOVERS = m.Counter(
@@ -482,11 +473,6 @@ SERVE_PROGRAM_SHAPES = m.Gauge(
     "dispatched (engine_stats program_shapes, finally at cluster "
     "scrape) — O(1) when healthy; growth with traffic is the "
     "compile-storm signature", ("deployment", "replica"))
-SERVE_SPEC_ACCEPTANCE = m.Gauge(
-    "ray_tpu_serve_spec_acceptance_ratio",
-    "Cumulative speculative-decoding acceptance ratio (accepted / "
-    "proposed draft tokens) per serve decode engine — the knob that "
-    "decides whether spec_k is paying for itself", ("deployment",))
 
 
 # ------------------------------------------------------------- snapshots

@@ -12,7 +12,6 @@ from .generate import (  # noqa: F401
     cache_insert_slot,
     decode_step,
     decode_step_slots,
-    draft_propose_slots,
     generate,
     init_kv_cache,
     init_slot_cache,
@@ -22,7 +21,6 @@ from .generate import (  # noqa: F401
     prefill_chunked,
     prefill_lanes,
     prefill_lanes_jit,
-    verify_step_slots,
 )
 from .transformer import (  # noqa: F401
     TransformerConfig,

@@ -37,8 +37,8 @@ a chunk that straddles the seam is written in two pieces, and a column is
 masked by the POSITION IT HOLDS (`_ring_mask`), which follows from the
 last position written.  The ring is wider than the window by the widest
 chunk a program may feed, so whatever a program writes ahead of a row's
-``pos`` (a padded chunk's tail, an inactive slot's token, a rejected
-proposal) overwrites only positions that no later query's window reaches.
+``pos`` (a padded chunk's tail, an inactive slot's token) overwrites only
+positions that no later query's window reaches.
 Both kinds sit behind the same functions, and each kind has a layer
 counter of its own in the one layer loop.
 
@@ -52,8 +52,8 @@ of a row's ``pos`` is not harmless: there is no later write that repairs
 it.  So every program advances a row's state by its VALID tokens only: a
 padded chunk takes the carry-out at its last real token, a slot that is
 not active keeps its state bit for bit, and what would need a state to be
-taken back is refused (`_check_state_rewind`): a speculative proposal
-that may be rejected, a chunk window set back at the cache's end.
+taken back is refused (`_check_state_rewind`): a chunk window set back at
+the cache's end.
 
 A FOURTH KIND OF STATE HAS A ROW A CHUNK OF POSITIONS: a summary layer
 (``"eva"``, `ops/eva_attention.py`) attends its own block-aligned window of
@@ -69,8 +69,8 @@ EVERY program pools the chunks its new tokens reach from the ring as written
 (`_summary_write`) and the mask hides a row until its whole window lies
 behind the query: no branch on where a chunk ends, and, unlike a conv state,
 a summary pooled over tokens written ahead of a row's ``pos`` is pooled
-again by the program that feeds the true ones, so a speculative verify and
-a chunk window set back need not be refused for it.
+again by the program that feeds the true ones, so a chunk window set back
+need not be refused for it.
 
 A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
 summary arrays say it (`cache_capacity`, which then needs the model's
@@ -337,8 +337,7 @@ def _check_decodable(cfg: TransformerConfig) -> None:
 def _check_chunk(cfg: TransformerConfig, c: int) -> None:
     """What a ring cannot serve is refused, not answered wrongly: a
     program that feeds more new tokens a row than the ring is wider than
-    the window (a chunk, or a speculative verify of that many) would
-    overwrite positions its own queries still see."""
+    the window would overwrite positions its own queries still see."""
     if {"window", "eva"} & set(cfg.kinds) and c > cfg.window_chunk:
         raise ValueError(
             f"a cached program of {c} new tokens a row over window layers "
@@ -349,9 +348,8 @@ def _check_chunk(cfg: TransformerConfig, c: int) -> None:
 def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
     """What would need a conv layer's state taken BACK is refused, not
     answered wrongly: a state has no position to mask and no later write
-    that repairs it, so tokens fed and then disowned (a rejected
-    proposal) or fed twice (a chunk window set back at the cache's end)
-    have already shifted it."""
+    that repairs it, so tokens fed twice (a chunk window set back at the
+    cache's end) have already shifted it."""
     if "conv" in cfg.kinds:
         raise ValueError(
             f"{what} over conv layers: their state cannot be taken back "
@@ -409,9 +407,9 @@ def _summary_write(cfg: TransformerConfig, pos, c: int, ring: int,
     on EVERY program, no branch on where it ends: until the chunk is
     complete (and its whole block behind every query) the mask hides the
     row, and what was pooled over tokens written ahead of a row's ``pos``
-    (a padded chunk's tail, a slot that is not active, a rejected proposal)
-    is pooled again by the program that writes the true ones.  What lies
-    before the new tokens in their first chunk is read from the RING AS
+    (a padded chunk's tail, a slot that is not active) is pooled again by
+    the program that writes the true ones.  What lies before
+    the new tokens in their first chunk is read from the RING AS
     WRITTEN (``arrs`` holds the rings after their write), ONE slice that
     cannot straddle the seam (``chunk`` divides the ring); the rest are the
     program's own new columns ``kc``, ``vc`` [B, heads, width, c].  A chunk
@@ -454,25 +452,22 @@ def _summary_write(cfg: TransformerConfig, pos, c: int, ring: int,
     return read, place
 
 
-def _summary_write_slots(cfg: TransformerConfig, pos, c: int, ring: int):
-    """`_summary_write` for slots that stand at positions of their OWN
-    (``pos`` [S]): every slot's chunks read by its own slice, all slots'
-    rows (``pos // chunk`` on) placed by `ops.cache_write.write_columns`:
-    one call an array at one fed token a slot; a row a slice, last first,
-    where a verify feeds more (a slot at the cache's end is clamped onto
-    the last row, which no query ever sees, and the row that belongs there
-    overwrites it)."""
-    ch = cfg.summary_chunk
-    reads = [_summary_write(cfg, pos[slot], c, ring, slot)[0]
+def _summary_write_slots(cfg: TransformerConfig, pos, ring: int):
+    """`_summary_write` of ONE new token a slot, for slots that stand at
+    positions of their OWN (``pos`` [S]): every slot's chunk read by its
+    own slice, all slots' rows (``pos // chunk``) placed by ONE
+    `ops.cache_write.write_columns` an array (a slot at the cache's end is
+    clamped onto the last row, which no query ever sees)."""
+    reads = [_summary_write(cfg, pos[slot], 1, ring, slot)[0]
              for slot in range(pos.shape[0])]
 
     def read(arrs, l, *cols):
         return tuple(jnp.concatenate(x, axis=0) for x in zip(
             *(one(arrs, l, *cols) for one in reads)))
 
-    def place(arrs, l, *pooled):                # [S, heads, width, n]
-        rows = (pos // ch)[:, None] + jnp.arange(-(-c // ch))
-        return {name: write_columns(arrs[name], l, new, rows)
+    def place(arrs, l, *pooled):                # [S, heads, width, 1]
+        return {name: write_columns(arrs[name], l, new[..., 0],
+                                    pos // cfg.summary_chunk)
                 for name, new in zip(_SUM_NAMES, pooled)}
 
     return read, place
@@ -1129,8 +1124,8 @@ def cache_gather_slot(slot_cache: KVCache, slot: jnp.ndarray,
     ``upto`` still hold the donor's LATER tokens, but they sit past the
     returned ``pos`` and every prefill/decode program masks reads to
     positions <= pos — the same stale-rows-are-invisible invariant
-    paused slots and rejected speculative writes rely on — and the
-    suffix prefill overwrites them before ``pos`` ever reaches them.
+    paused slots rely on — and the suffix prefill overwrites them before
+    ``pos`` ever reaches them.
     ``slot`` and ``upto`` are TRACED, so one compiled program serves
     every (donor slot, prefix length) pair.
 
@@ -1193,15 +1188,14 @@ def _row_inputs(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
     return x, angles, mask
 
 
-def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                   cfg: TransformerConfig,
-                   active: Optional[jnp.ndarray] = None):
-    """``tokens`` [S, C]: C tokens per slot, fed at each slot's OWN
-    ``pos`` .. ``pos + C - 1`` → (final-norm activations [S, C, D],
-    arrays, load); ``active`` [S] marks the slots whose tokens a no-drop
-    expert layer routes and whose conv states advance (the others still
-    compute: the batch shape is fixed, and their key and value columns
-    land ahead of their ``pos``, but their states stay as they are).
+def _forward_slots(params: Params, token: jnp.ndarray, cache: KVCache,
+                   cfg: TransformerConfig, active: jnp.ndarray):
+    """``token`` [S]: ONE token a slot, fed at each slot's OWN ``pos``
+    → (final-norm activations [S, D], arrays, load); ``active`` [S] marks
+    the slots whose tokens a no-drop expert layer routes and whose conv
+    states advance (the others still compute: the batch shape is fixed,
+    and their key and value columns land ahead of their ``pos``, but
+    their states stay as they are).
     Slots sit at DIFFERENT positions, so no one slice holds their new
     columns.  An XLA scatter (or a ``vmap`` of the update, which lowers to
     one) is not updated in place under the cache's layout and puts the
@@ -1209,53 +1203,47 @@ def _forward_slots(params: Params, tokens: jnp.ndarray, cache: KVCache,
     a ``dynamic_update_slice`` a slot costs the same whatever it moves
     (every tile of ``heads x width``: 3.4-16 us, ``slots x arrays``
     of them a layer).  So every array's columns go through
-    `ops.cache_write.write_columns`: where a slot is fed ONE token and the
-    array's rows are whole blocks of 128, ONE kernel call an array a layer
-    that aliases the array and moves only the 128-row block of each slot
-    that holds its column (``slots x heads x width x 128`` elements in and
-    out); elsewhere (a verify's several tokens a slot, a tiny test model's
-    rows, any platform but the TPU) the slices, a column each.  Either
-    way a column whose start lies past the end is clamped onto the last
-    column, where a scatter would have dropped it: a slot's columns are
-    therefore written LAST TOKEN FIRST, so the token that belongs in
-    the last column overwrites what was clamped onto it.  Only a slot
-    already at ``max_len`` loses its last column that way, and that
-    slot is finished: nothing reads it again (a prefix match stops
-    short of a prompt's last token)."""
+    `ops.cache_write.write_columns`: where the array's rows are whole
+    blocks of 128, ONE kernel call an array a layer that aliases the array
+    and moves only the 128-row block of each slot that holds its column
+    (``slots x heads x width x 128`` elements in and out); elsewhere (a
+    tiny test model's rows, any platform but the TPU) the slices, a column
+    each.  Either way a column whose start lies past the end is clamped
+    onto the last column, where a scatter would have dropped it.  Only a
+    slot already at ``max_len`` loses its last column that way, and that
+    slot is finished: nothing reads it again (a prefix match stops short
+    of a prompt's last token)."""
     _check_decodable(cfg)
-    s, c = tokens.shape
-    _check_chunk(cfg, c)
-    if c > 1:     # only a verify feeds a slot more than its one token
-        _check_state_rewind(cfg, "a speculative verify (its rejected "
-                                 "tokens are fed all the same)")
+    _check_chunk(cfg, 1)
     pos = cache["pos"]                                         # [S]
+    if token.shape != pos.shape:
+        raise ValueError(
+            f"a slot decode step feeds ONE token a slot: token "
+            f"{token.shape} over a cache of {pos.shape[0]} slots")
     max_len = cache_capacity(cache, cfg)
-    x, angles, mask = _row_inputs(params, tokens, pos, cfg, max_len)
-
-    fed = pos[:, None] + jnp.arange(c)                         # [S, C]
+    x, angles, mask = _row_inputs(params, token[:, None], pos, cfg, max_len)
 
     def column_writes(column):
         @jax.named_scope("cache_write")
-        def write(c_all, l, cols):                # [S, heads, width, C]
-            return write_columns(c_all, l, cols, column(fed))
+        def write(c_all, l, cols):                # [S, heads, width, 1]
+            return write_columns(c_all, l, cols[..., 0], column)
         return write
 
-    write = {"full": column_writes(lambda p: p)}
+    write = {"full": column_writes(pos)}
     ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
         # a ring has no end to be clamped onto: position p is column p mod
         # ring, and a column is masked by the position it holds
-        write["window"] = column_writes(lambda p: p % ring)
+        write["window"] = column_writes(pos % ring)
     if "eva" in cfg.kinds:
-        write["eva"] = column_writes(lambda p: p % ring)
-        write["summary"] = [_summary_write_slots(cfg, pos, c, ring)]
-    valid = None if active is None else \
-        jnp.broadcast_to(active[:, None], (s, c))
-    return _attend_cached(
+        write["eva"] = column_writes(pos % ring)
+        write["summary"] = [_summary_write_slots(cfg, pos, ring)]
+    x, arrays, load = _attend_cached(
         cfg, params, x, cache,
         rotate=_rotators(_rotate_slots, angles),
-        write=write, mask=mask, valid=valid,
-        n_new=None if active is None else active.astype(jnp.int32) * c)
+        write=write, mask=mask, valid=active[:, None],
+        n_new=active.astype(jnp.int32))
+    return x[:, 0], arrays, load
 
 
 def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
@@ -1392,87 +1380,9 @@ def _decode_step_slots(params: Params, token: jnp.ndarray, cache: KVCache,
     """:func:`decode_step_slots` → (logits, cache', load): beside them
     what the ACTIVE slots' tokens were routed to, summed over the expert
     layers (the serve engine's fused step reads it with the tokens)."""
-    x, arrays, load = _forward_slots(params, token[:, None], cache, cfg,
-                                     active)
-    return _last_logits(params, x[:, 0], cfg), dict(
+    x, arrays, load = _forward_slots(params, token, cache, cfg, active)
+    return _last_logits(params, x, cfg), dict(
         arrays, pos=cache["pos"] + active.astype(jnp.int32)), load
-
-
-def draft_propose_slots(params: Params, token: jnp.ndarray,
-                        cache: KVCache, active: jnp.ndarray,
-                        cfg: TransformerConfig, k: int
-                        ) -> Tuple[jnp.ndarray, KVCache]:
-    """Draft ``k`` greedy tokens per slot in ONE compiled program.
-
-    The proposer side of speculative decoding: a ``lax.scan`` over
-    :func:`decode_step_slots` feeds each argmax back in, so one dispatch
-    produces ``k`` proposals per slot regardless of ``k`` — on the
-    dispatch-bound serving path that is the entire point (k eager draft
-    steps would cost k dispatches and erase the win).
-
-    ``token`` [S] int32 (each slot's pending token), ``cache`` the
-    DRAFT model's slot cache whose ``pos`` the engine re-syncs from the
-    target cache every iteration (rejected speculative writes are then
-    overwritten before any masked read — the same invariant paused
-    slots rely on).  → (proposals [S, k], cache') with ``pos`` advanced
-    by ``k`` on active slots."""
-    _check_state_rewind(cfg, "a draft's proposals (the draft's state "
-                             "would keep the rejected ones)")
-
-    def step(carry, _):
-        tok, c = carry
-        logits, c = decode_step_slots(params, tok, c, active, cfg)
-        with jax.named_scope("head"):
-            nxt = greedy_tokens(logits, cfg).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, tok)
-        return (nxt, c), nxt
-
-    (_, cache), toks = jax.lax.scan(step, (token, cache), None, length=k)
-    return jnp.swapaxes(toks, 0, 1), cache                     # [S, k]
-
-
-def verify_step_slots(params: Params, tokens: jnp.ndarray,
-                      proposals: jnp.ndarray, cache: KVCache,
-                      active: jnp.ndarray, cfg: TransformerConfig
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray, KVCache]:
-    """Speculative-decoding verification: one batched forward over
-    ``C`` tokens per slot checks a draft's ``C - 1`` proposals and
-    yields 1..C accepted tokens per slot.
-
-    ``tokens`` [S, C] int32 — per slot ``[last_tok, d_1, .., d_{C-1}]``
-    (the slot's pending token followed by the draft's proposals);
-    ``proposals`` [S, C-1] are the ``d_i`` alone; ``cache`` a slot
-    cache with per-slot ``pos`` [S]; ``active`` [S] bool.
-
-    → ``(greedy [S, C], accepted [S], cache')`` where ``greedy[s, i]``
-    is the target's argmax after consuming ``tokens[s, :i+1]`` and
-    ``accepted[s]`` = 1 + the longest proposal prefix matching that
-    greedy chain (clamped to remaining cache capacity) — exactly the
-    tokens slot ``s`` emits this iteration, ``greedy[s, :accepted[s]]``.
-    ``pos`` advances by ``accepted`` on active slots only.
-
-    Greedy speculative decoding is EXACT: every emitted token is the
-    target's own greedy choice given the accepted prefix — the draft
-    only decides how many of them one dispatch yields — so the stream
-    is byte-identical to plain decode.  K/V of every fed token is
-    written at its position; rejected-suffix writes land past the
-    advanced ``pos`` and are rewritten (with the true token) before any
-    masked read, the same invariant plain decode relies on for paused
-    slots.  Writes past ``max_len`` are dropped and ``accepted`` is
-    clamped so emission never outruns the cache."""
-    pos = cache["pos"]                                         # [S]
-    max_len = cache_capacity(cache, cfg)
-    x, arrays, _ = _forward_slots(params, tokens, cache, cfg, active)
-    with jax.named_scope("head"):
-        greedy = greedy_tokens(
-            jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg)),
-            cfg).astype(jnp.int32)                              # [S, C]
-        ok = (greedy[:, :-1] == proposals).astype(jnp.int32)
-        accepted = 1 + jnp.sum(jnp.cumprod(ok, axis=1), axis=1)
-        accepted = jnp.minimum(
-            accepted, jnp.maximum(max_len - pos, 1)).astype(jnp.int32)
-    adv = jnp.where(active, accepted, 0).astype(jnp.int32)
-    return greedy, accepted, dict(arrays, pos=pos + adv)
 
 
 @jax.named_scope("head")

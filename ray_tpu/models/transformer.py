@@ -452,8 +452,7 @@ def decode_flops_per_token(cfg: TransformerConfig,
     return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len)
 
 
-def engine_flops_table(cfg: TransformerConfig, max_len: int,
-                       draft_cfg: "TransformerConfig" = None) -> dict:
+def engine_flops_table(cfg: TransformerConfig, max_len: int) -> dict:
     """Analytic FLOPs-per-token for each of the serve engine's jitted
     programs (the dispatch profiler's MFU numerators), evaluated at the
     mid-stream cache position ``max_len // 2``.  Pure-copy programs (cache
@@ -463,14 +462,9 @@ def engine_flops_table(cfg: TransformerConfig, max_len: int,
     table = {
         "decode_step": target,
         "prefill_chunk": target,   # per prompt token, same forward
-        "verify": target,          # k+1-wide target forward per token
         "cache_insert": 0.0,
         "prefix_gather": 0.0,
     }
-    if draft_cfg is not None:
-        draft = decode_flops_per_token(draft_cfg, mid)
-        table["draft_propose"] = draft
-        table["draft_prefill_chunk"] = draft
     return table
 
 

@@ -32,11 +32,10 @@ where handing them over ``[..., width, 1]`` would pad each value to 128
 lanes in memory: a third stream as large as the blocks.
 
 The path ADAPTS to what the call sees, no knob: ``rows % 128 != 0`` (tiny
-test models), more than one column a slot (a speculative verify, whose
-columns may straddle two blocks) and every platform but the TPU
-(`jax.lax.platform_dependent`) keep the slices, which are this module's
-reference too.  `RAY_TPU_PALLAS_INTERPRET=1` runs the kernel through the
-interpreter (tests).
+test models) and every platform but the TPU (`jax.lax.platform_dependent`)
+keep the slices, which are this module's reference too.
+`RAY_TPU_PALLAS_INTERPRET=1` runs the kernel through the interpreter
+(tests).
 """
 
 from __future__ import annotations
@@ -51,22 +50,21 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _LANES, _VMEM_BLOCK_BUDGET, _interpret
 
 
-def kernel_shape(shape: Tuple[int, ...], columns: int = 1) -> bool:
-    """Whether an array of this shape, written ``columns`` columns a slot,
-    is one the kernel takes (on a TPU, or under the interpreter): whole
-    128-row blocks and one column a slot."""
-    return columns == 1 and shape[-1] % _LANES == 0
+def kernel_shape(shape: Tuple[int, ...]) -> bool:
+    """Whether an array of this shape is one the kernel takes (on a TPU, or
+    under the interpreter): whole 128-row blocks."""
+    return shape[-1] % _LANES == 0
 
 
-def device_calls(shape: Tuple[int, ...], columns: int = 1) -> int:
-    """The device calls that write a layer's ``slots x columns`` columns of
-    an array of this shape on THIS process's backend: one where the kernel
-    engages, a slice a column elsewhere (a host count from shapes, what the
-    serve engine's ``column_write_calls`` sums)."""
+def device_calls(shape: Tuple[int, ...]) -> int:
+    """The device calls that write a layer's column a slot of an array of
+    this shape on THIS process's backend: one where the kernel engages, a
+    slice a slot elsewhere (a host count from shapes, what the serve
+    engine's ``column_write_calls`` sums)."""
     on_tpu = jax.default_backend() == "tpu" or _interpret()
-    if on_tpu and kernel_shape(shape, columns):
+    if on_tpu and kernel_shape(shape):
         return 1
-    return shape[1] * columns
+    return shape[1]
 
 
 def _head_block(heads: int, width: int, itemsize: int) -> int:
@@ -118,20 +116,15 @@ def _pallas(c_all, l, cols, col):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
-    )(l.reshape(1), jnp.clip(col[:, 0], 0, rows - 1),
-      jnp.swapaxes(cols, 2, 3), c_all)
+    )(l.reshape(1), jnp.clip(col, 0, rows - 1), cols[:, :, None, :], c_all)
 
 
 def _slices(c_all, l, cols, col):
-    """A ``dynamic_update_slice`` a column, slot by slot and within a slot
-    LAST COLUMN FIRST: a start past the end is clamped onto the last
-    column, where the column that belongs there then overwrites it."""
-    slots, _, _, columns = cols.shape
-    for s in range(slots):
-        for i in reversed(range(columns)):
-            c_all = jax.lax.dynamic_update_slice(
-                c_all, cols[None, s:s + 1, :, :, i:i + 1],
-                (l, s, 0, 0, col[s, i]))
+    """A ``dynamic_update_slice`` a slot: a start past the end is clamped
+    onto the last column."""
+    for s in range(cols.shape[0]):
+        c_all = jax.lax.dynamic_update_slice(
+            c_all, cols[None, s:s + 1, :, :, None], (l, s, 0, 0, col[s]))
     return c_all
 
 
@@ -141,14 +134,11 @@ def write_columns(c_all: jnp.ndarray, l, cols: jnp.ndarray,
     in column ``col`` [S] int32 of layer ``l``, slot by slot: the result,
     bit for bit, of ``dynamic_update_slice(c_all, cols[None, s:s+1, ...,
     None], (l, s, 0, 0, col[s]))`` over the slots (a column past the end
-    lands on the last one).  ``cols`` [S, heads, width, C] with ``col`` [S,
-    C] writes C columns a slot, last first (`_slices`).  One kernel call
-    where `kernel_shape` and the platform allow, the slices elsewhere."""
-    if cols.ndim == 3:
-        cols, col = cols[..., None], col[:, None]
+    lands on the last one).  One kernel call where `kernel_shape` and the
+    platform allow, the slices elsewhere."""
     cols = cols.astype(c_all.dtype)
     l, col = jnp.asarray(l, jnp.int32), col.astype(jnp.int32)
-    if not kernel_shape(c_all.shape, cols.shape[-1]):
+    if not kernel_shape(c_all.shape):
         return _slices(c_all, l, cols, col)
     if _interpret():
         return _pallas(c_all, l, cols, col)

@@ -106,23 +106,15 @@ class DecodeEngineConfig:
     # minimum shared tokens worth a gather dispatch (a 1-2 token match
     # costs more in dispatch than it saves in prefill)
     prefix_cache_min_tokens: int = 4
-    # -- speculative decoding ---------------------------------------------
-    # draft model proposing tokens for the target to verify in one
-    # batched k-token forward.  None disables; "shared" weight-shares
-    # the target (exact self-speculation — acceptance 1.0, the win is
-    # dispatch amortization: 2 dispatches per k+1 tokens); a
-    # (TransformerConfig, params) tuple supplies a real draft; a bare
-    # TransformerConfig gets fresh seed-0 params (tests).  Greedy
-    # verification is exact-match, so token streams stay byte-identical
-    # to plain decode whatever the draft quality.
-    spec_draft: Any = None
-    # draft tokens proposed per engine iteration (the verify program is
-    # k+1 tokens wide; each iteration emits 1..k+1 tokens per slot)
-    spec_k: int = 4
-    # consecutive draft/verify failures before the engine stops
-    # speculating and stays on plain decode (each failure already falls
-    # back to a plain step for that iteration — streams never corrupt)
-    spec_fail_disable: int = 3
+
+    def __post_init__(self):
+        if self.token_queue_depth < 1:
+            # a session admitted in a turn that dispatches no step never
+            # gets its first token into the carry: the engine would hang
+            raise ValueError(
+                f"token_queue_depth must be at least 1, got "
+                f"{self.token_queue_depth}: a session's queue has to hold "
+                f"the token of the step that carries it")
 
 
 @dataclasses.dataclass

@@ -31,41 +31,28 @@ step's output feeds the next step, sampling is the argmax inside the
 program, every live slot's position advances by one and which slots are
 live is the host's own knowledge), THEN step n is read and published.
 The chip works while the host reads, publishes and schedules; only token
-VALUES lag the host by a step.  A speculating engine forms its next
-input on the host from what it accepted, so it reads every step at once.
+VALUES lag the host by a step.
 
-Two model-side optimisations compound inside the loop:
-
-- **Chunked-prefill admission**: a joining session's prompt is
-  consumed ``chunk`` tokens at a time between shared decode steps
-  (``DecodeEngineConfig.prefill_chunk_tokens``; unset, the widest power
-  of two under the chip's ridge point: :func:`prefill_chunk_width`), so
-  a join stalls live streams by at most one chunk interval, about two
-  reads of the weights and so about two small-batch decode steps,
-  instead of a whole prompt forward, and TTFT-under-load stops being
-  O(prompt_len) of batch stall.  A prompt's remainder is ONE more
-  program of the same width, padded, the count of its real tokens a
-  traced argument (`models.generate.prefill_chunk_step`).  Admission
-  and failover resume (``op: resume``) dispatch the SAME module-level
-  chunk program (`models.prefill_chunk_jit`, ``[1, chunk]``), and no
-  prompt length compiles anything.  ONE program a loop turn, however
-  many sessions join: while two or more prompts prefill, up to
-  :func:`prefill_lane_count` of them (arrival order) advance in one
-  program of ``[lanes, chunk]`` over a lane cache
-  (`models.prefill_lanes_jit`), which reads every weight once for the
-  lot; the others wait with no cache at all.  Two compiled prefill
-  shapes per model, whatever the traffic, both run once by the engine
-  itself before it serves its first session (`_warm_lanes`).
-- **Speculative decoding** (``DecodeEngineConfig.spec_draft`` /
-  ``spec_k``): a draft model proposes k tokens per iteration in one
-  scanned dispatch (`models.draft_propose_slots`) and the target
-  verifies all of them plus a bonus token in one k+1-wide batched
-  forward (`models.verify_step_slots`) — 2 dispatches for 1..k+1
-  tokens per slot.  Greedy acceptance is exact-match, so streams (and
-  the seq-based replay journal of serve/failover.py) stay
-  byte-identical to plain decode; any draft/verify fault falls back to
-  a plain step (chaos site ``serve.spec_verify``), never corrupting a
-  stream.
+**Chunked-prefill admission** runs inside the same loop: a joining
+session's prompt is consumed ``chunk`` tokens at a time between shared
+decode steps (``DecodeEngineConfig.prefill_chunk_tokens``; unset, the
+widest power of two under the chip's ridge point:
+:func:`prefill_chunk_width`), so a join stalls live streams by at most
+one chunk interval, about two reads of the weights and so about two
+small-batch decode steps, instead of a whole prompt forward, and
+TTFT-under-load stops being O(prompt_len) of batch stall.  A prompt's remainder is ONE more
+program of the same width, padded, the count of its real tokens a
+traced argument (`models.generate.prefill_chunk_step`).  Admission
+and failover resume (``op: resume``) dispatch the SAME module-level
+chunk program (`models.prefill_chunk_jit`, ``[1, chunk]``), and no
+prompt length compiles anything.  ONE program a loop turn, however
+many sessions join: while two or more prompts prefill, up to
+:func:`prefill_lane_count` of them (arrival order) advance in one
+program of ``[lanes, chunk]`` over a lane cache
+(`models.prefill_lanes_jit`), which reads every weight once for the
+lot; the others wait with no cache at all.  Two compiled prefill
+shapes per model, whatever the traffic, both run once by the engine
+itself before it serves its first session (`_warm_lanes`).
 """
 
 from __future__ import annotations
@@ -176,7 +163,7 @@ class _EngineSession:
 
     __slots__ = ("sid", "slot", "queue", "first_tok", "last_tok", "pos",
                  "unread", "done", "error", "ended", "seq", "last_poll",
-                 "prompt", "poff", "pcache", "dcache", "plogits",
+                 "prompt", "poff", "pcache", "plogits",
                  "ready", "shed", "ptoks", "rid", "t_enq", "t_pf",
                  "t_ready", "cond", "want", "lane")
 
@@ -225,7 +212,6 @@ class _EngineSession:
         # ... or its row of the engine's lane cache, while two or more
         # sessions prefill (`ContinuousBatchingEngine._lanes_advance`)
         self.lane: Optional[int] = None
-        self.dcache: Any = None       # draft batch-1 cache (speculating)
         self.plogits: Any = None      # last chunk's final-position logits
         self.ready = False            # first token produced; start() may return
         self.shed = False             # drained mid-admission: typed 503
@@ -278,8 +264,7 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
 
         from ..models import (cache_gather_slot, cache_insert_slot,
-                              draft_propose_slots, prefill_chunk_jit,
-                              prefill_lanes_jit, verify_step_slots)
+                              prefill_chunk_jit, prefill_lanes_jit)
         from ..models.generate import (_decode_step_slots, cache_arrays,
                                        cache_bytes, greedy_tokens)
         self._cache_arrays, self._cache_bytes = cache_arrays, cache_bytes
@@ -370,62 +355,23 @@ class ContinuousBatchingEngine:
         # name in the profiler, the compile ledger and a trace
         self._chunk_lanes = self._prof.wrap(
             "prefill_chunk", self._counting_copies(prefill_lanes_jit, 2))
-        # ---- speculative decoding ----
-        self._spec = False
-        self._draft_cfg = None
-        self._draft_params = None
-        spec = engine_cfg.spec_draft
-        if spec:
-            if spec in ("shared", True):
-                self._draft_cfg, self._draft_params = cfg, params
-            elif isinstance(spec, tuple):
-                self._draft_cfg, self._draft_params = spec
-            else:   # a bare TransformerConfig: fresh params (tests)
-                from ..models import init_params
-                self._draft_cfg = spec
-                self._draft_params, _ = init_params(
-                    jax.random.PRNGKey(0), spec)
-            if self._draft_cfg.vocab_size != cfg.vocab_size:
-                raise ValueError(
-                    f"draft vocab {self._draft_cfg.vocab_size} != target "
-                    f"vocab {cfg.vocab_size}: proposals must be target "
-                    f"token ids")
-            for c in (cfg, self._draft_cfg):
-                if "conv" in c.kinds:
-                    # refused here, not at the first request: a rejected
-                    # proposal has already shifted a conv layer's state
-                    raise ValueError(
-                        "speculative decoding over a model with conv "
-                        "layers is not supported: their state cannot be "
-                        "taken back to the last accepted token "
-                        "(models/generate.py `_check_state_rewind`)")
-            self._spec = True
-            self._draft = self._prof.wrap(
-                "draft_propose", jax.jit(draft_propose_slots,
-                                         static_argnames=("cfg", "k")))
-            self._verify = self._prof.wrap(
-                "verify", jax.jit(verify_step_slots,
-                                  static_argnames=("cfg",)))
         # the positions a chunk program's window may cover: the cache's,
-        # and no more than a learned position table (the draft's too);
-        # the ONE chunk width follows, and `ecfg` holds it resolved
-        self._capacity = min([max_len] + [
-            c.max_seq_len for c in (cfg, self._draft_cfg)
-            if c is not None and c.pos_emb == "learned"])
+        # and no more than a learned position table; the ONE chunk width
+        # follows, and `ecfg` holds it resolved
+        self._capacity = min(max_len, cfg.max_seq_len) \
+            if cfg.pos_emb == "learned" else max_len
         # ... and no wider than the room a window layer's ring leaves
-        # beside its window (target and draft alike)
-        room = min([self._capacity] + [
-            c.window_chunk for c in (cfg, self._draft_cfg)
-            if c is not None and {"window", "eva"} & set(c.kinds)])
+        # beside its window
+        room = min(self._capacity, cfg.window_chunk) \
+            if {"window", "eva"} & set(cfg.kinds) else self._capacity
         self.ecfg = dataclasses.replace(
             engine_cfg, prefill_chunk_tokens=prefill_chunk_width(
                 engine_cfg.prefill_chunk_tokens, params, room))
         # ---- lanes: ONE chunk program for up to `_n_lanes` joining
         # sessions.  The lane cache (a slot cache of that many rows) is
         # held only while two or more sessions prefill; `_lane_sess[i]`
-        # is who holds lane i.  A speculating engine builds a draft cache
-        # beside every target cache: it keeps one program a session.
-        self._n_lanes = 0 if self._spec else prefill_lane_count(
+        # is who holds lane i.
+        self._n_lanes = prefill_lane_count(
             self.ecfg.prefill_chunk_tokens, params)
         self._pool: Any = None
         self._lane_sess: List[Optional[_EngineSession]] = \
@@ -441,14 +387,7 @@ class ContinuousBatchingEngine:
         self._eva_layers = cfg.kinds.count("eva")
         self._block, self._chunk_rows = cfg.sliding_window, \
             cfg.summary_chunk
-        self._spec_k = max(2, int(engine_cfg.spec_k))
-        self._spec_disabled = False
-        self._spec_fail_streak = 0
-        self.spec_proposed = 0   # draft tokens offered to verification
-        self.spec_accepted = 0   # draft tokens the target agreed with
-        self.spec_fallbacks = 0  # iterations degraded to plain decode
         self._cache = None            # allocated lazily on first start
-        self._dcache = None           # draft slot cache (speculating)
         self._shapes: set = set()     # distinct compiled program shapes
         # ONE lock.  `_cond` is what the engine thread and the callers in
         # `start` wait on; a caller in `next_chunk` waits on its
@@ -520,8 +459,7 @@ class ContinuousBatchingEngine:
         # analytic FLOPs/token per program -> the profiler's MFU
         # numerators (models.engine_flops_table; pure-copy programs 0)
         from ..models import engine_flops_table
-        for prog, f in engine_flops_table(
-                cfg, max_len, draft_cfg=self._draft_cfg).items():
+        for prog, f in engine_flops_table(cfg, max_len).items():
             self._prof.set_flops_per_token(prog, f)
         # engine-side phase accumulators of the serve_breakdown table
         # (queue: enqueue -> first prefill chunk; admission: first
@@ -715,7 +653,6 @@ class ContinuousBatchingEngine:
 
     def stats(self) -> Dict[str, Any]:
         with self._cond:
-            prop, acc = self.spec_proposed, self.spec_accepted
             return {"max_slots": self.ecfg.max_slots,
                     "occupied_slots": len(self._slots),
                     "waiting": len(self._pending),
@@ -726,7 +663,7 @@ class ContinuousBatchingEngine:
                     "reaped": self.reaped,
                     "steps": self.steps, "tokens": self.tokens,
                     # fused steps dispatched before the step ahead of
-                    # them was read (0 for a speculating engine)
+                    # them was read
                     "steps_ahead": self.ahead["steps_ahead"],
                     "prefill_chunks": self.prefill_chunks,
                     # the programs that ran them (fewer where lanes
@@ -764,13 +701,6 @@ class ContinuousBatchingEngine:
                                "hit_rate": None, "tokens_matched": 0}),
                         applied_hits=self.prefix_hits,
                         tokens_reused=self.prefix_tokens_reused),
-                    "spec": {"enabled": self._spec,
-                             "disabled": self._spec_disabled,
-                             "k": self._spec_k,
-                             "proposed": prop, "accepted": acc,
-                             "acceptance":
-                                 round(acc / prop, 4) if prop else None,
-                             "fallbacks": self.spec_fallbacks},
                     # data-plane flight instruments: per-program
                     # dispatch/compile/MFU ledger + phase attribution
                     "device_profile": self._prof.snapshot(),
@@ -808,8 +738,7 @@ class ContinuousBatchingEngine:
         prefill = sum(wall.get(p, 0.0)
                       for p in ("prefill_chunk", "prefix_gather"))
         decode = sum(wall.get(p, 0.0)
-                     for p in ("decode_step", "draft_propose", "verify",
-                               "cache_insert"))
+                     for p in ("decode_step", "cache_insert"))
         out = {k: round(v, 6) for k, v in self.phase_s.items()}
         out["prefill"] = round(prefill, 6)
         out["decode_dispatch"] = round(decode, 6)
@@ -837,7 +766,7 @@ class ContinuousBatchingEngine:
                 sess.shed = True
                 sess.done = True
                 sess.ended = True
-                sess.pcache = sess.dcache = sess.plogits = None
+                sess.pcache = sess.plogits = None
                 self.sessions.pop(sess.sid, None)
             self._prefilling.clear()
             n = self._live_locked()
@@ -881,8 +810,8 @@ class ContinuousBatchingEngine:
             self._loop()
         finally:
             if self._shutdown:
-                self.params = self._draft_params = None
-                self._cache = self._dcache = self._pool = None
+                self.params = None
+                self._cache = self._pool = None
                 self._carry = self._flight = self._active_dev = None
 
     def _reap_locked(self) -> None:
@@ -913,14 +842,14 @@ class ContinuousBatchingEngine:
                 # session's system prompt stays a warm donor until the
                 # slot is actually reclaimed by a new admission
 
-    def _admit_locked(self) -> List[Tuple[_EngineSession, Any, Any, int]]:
+    def _admit_locked(self) -> List[Tuple[_EngineSession, Any, int]]:
         admitted = []
         if self._draining:
             return admitted   # evacuating: no new slot occupancy
         while self._free and self._pending:
             sess = self._pending.pop(0)
             if sess.ended or sess.done:
-                sess.pcache = sess.dcache = None
+                sess.pcache = None
                 continue              # ended while waiting
             slot = self._free.pop()
             sess.slot = slot
@@ -938,8 +867,8 @@ class ContinuousBatchingEngine:
                 if sess.ptoks:
                     self._prefix.insert(sess.ptoks, slot)
                     self._donors[slot] = sess
-            admitted.append((sess, sess.pcache, sess.dcache, slot))
-            sess.pcache = sess.dcache = None
+            admitted.append((sess, sess.pcache, slot))
+            sess.pcache = None
         return admitted
 
     def _collect_locked(self) -> List[_EngineSession]:
@@ -1084,10 +1013,6 @@ class ContinuousBatchingEngine:
                     SERVE_PREFIX_HITS, SERVE_PREFIX_TOKENS_REUSED)
                 sess.pcache = self._gather(self._cache, jnp.int32(donor),
                                            jnp.int32(depth))
-                if self._spec:
-                    sess.dcache = self._gather(self._dcache,
-                                               jnp.int32(donor),
-                                               jnp.int32(depth))
                 sess.poff = depth
                 with self._cond:   # stats() reads these counters
                     self.prefix_hits += 1
@@ -1098,8 +1023,6 @@ class ContinuousBatchingEngine:
                     depth, tags={"deployment": self.name})
                 return
         sess.pcache = init_kv_cache(self.cfg, 1, self.max_len)
-        if self._spec:
-            sess.dcache = init_kv_cache(self._draft_cfg, 1, self.max_len)
 
     def _count_chunks(self, riders: List[Tuple[_EngineSession, int]],
                       wall: float) -> None:
@@ -1130,12 +1053,11 @@ class ContinuousBatchingEngine:
 
     def _prefill_advance(self, sess: _EngineSession) -> Optional[int]:
         """Run ONE fixed-shape chunk program of ONE joining session's
-        prompt over its own batch-1 cache (target + draft when
-        speculating) on the engine thread — interleaved between shared
-        decode steps, so admission stalls live streams by at most one
-        chunk interval instead of a whole prompt.  Returns the session's
-        first token, still on the device, once the prompt is fully
-        consumed, else None."""
+        prompt over its own batch-1 cache on the engine thread —
+        interleaved between shared decode steps, so admission stalls live
+        streams by at most one chunk interval instead of a whole prompt.
+        Returns the session's first token, still on the device, once the
+        prompt is fully consumed, else None."""
         import jax.numpy as jnp
 
         from ..models.generate import prefill_chunk_step
@@ -1145,16 +1067,10 @@ class ContinuousBatchingEngine:
         wall0 = self._prof.wall_of("prefill_chunk")
         # ONE shape per model: whole chunks, then the remainder as one
         # more, padded, its count of real tokens a traced argument
-        off, window = sess.poff, dict(chunk=chunk, capacity=self._capacity)
         sess.plogits, sess.pcache, sess.poff, n_valid = prefill_chunk_step(
-            self._chunk, self.params, sess.prompt, off, sess.pcache,
-            self.cfg, **window)
+            self._chunk, self.params, sess.prompt, sess.poff, sess.pcache,
+            self.cfg, chunk=chunk, capacity=self._capacity)
         self._shape_seen("prefill_chunk", 1, chunk)
-        if self._spec:
-            _, sess.dcache, _, _ = prefill_chunk_step(
-                self._chunk, self._draft_params, sess.prompt, off,
-                sess.dcache, self._draft_cfg, **window)
-            self._shape_seen("draft_prefill_chunk", 1, chunk)
         self._count_chunks([(sess, n_valid)],
                            self._prof.wall_of("prefill_chunk") - wall0)
         if sess.poff < int(sess.prompt.shape[1]):
@@ -1208,7 +1124,7 @@ class ContinuousBatchingEngine:
             sess.error = f"chunked prefill failed: {e!r}"
             sess.done = True
             sess.ready = True
-            sess.pcache = sess.dcache = sess.plogits = None
+            sess.pcache = sess.plogits = None
             self._cond.notify_all()
 
     def _lanes_advance(self, prefills: List[_EngineSession], fi
@@ -1325,47 +1241,6 @@ class ContinuousBatchingEngine:
             raise RuntimeError(f"chaos: injected {site[6:]} failure for "
                                f"{self.name}")
 
-    def _spec_step(self, tokens, active, fi):
-        """One speculative iteration over the whole batch: the draft
-        proposes ``spec_k`` tokens per slot in one scanned dispatch and
-        the target verifies all of them (plus one bonus token) in one
-        k+1-wide batched forward.  Returns host arrays
-        ``(greedy [S, k+1], accepted [S])``; raises on any draft/verify
-        fault (the loop falls back to a plain step — a broken draft can
-        slow a stream, never corrupt it)."""
-        import numpy as np
-
-        import jax.numpy as jnp
-        self._chaos_site("serve.spec_verify", fi)
-        tok_dev = jnp.asarray(tokens)
-        active_dev = jnp.asarray(active)
-        # the draft cache's pos is re-synced from the target every
-        # iteration: its rejected speculative writes sit past the true
-        # pos and are rewritten before any masked read
-        dcache = dict(self._dcache, pos=self._cache["pos"])
-        # the draft scans spec_k steps but only spec_k - 1 proposals are
-        # verified: the k-th step's K/V WRITE is what matters — on a
-        # fully-accepted iteration the last emitted token's row must
-        # already be in the draft cache, or every later proposal chain
-        # attends a hole and acceptance collapses
-        props, dcache = self._draft(self._draft_params, tok_dev, dcache,
-                                    active_dev, cfg=self._draft_cfg,
-                                    k=self._spec_k)
-        self._shape_seen("draft_propose", len(tokens), self._spec_k)
-        props = props[:, :self._spec_k - 1]
-        fed = jnp.concatenate([tok_dev[:, None], props], axis=1)
-        greedy_dev, acc_dev, new_cache = self._verify(
-            self.params, fed, props, self._cache, active_dev,
-            cfg=self.cfg)
-        self._shape_seen("verify", len(tokens), self._spec_k)
-        # materialize BEFORE committing the caches: an async device
-        # fault surfaces here and leaves the pre-spec state untouched
-        greedy = np.asarray(greedy_dev)
-        accepted = np.asarray(acc_dev)
-        self._cache = new_cache
-        self._dcache = dcache
-        return greedy, accepted
-
     def _loop(self) -> None:
         import numpy as np
 
@@ -1376,17 +1251,9 @@ class ContinuousBatchingEngine:
         if self._cache is None:
             self._cache = init_slot_cache(self.cfg, self.ecfg.max_slots,
                                           self.max_len)
-            if self._spec:
-                self._dcache = init_slot_cache(
-                    self._draft_cfg, self.ecfg.max_slots, self.max_len)
         # `_WRITE_SUMS` of one fused step: the cache's shapes say it
         self._writes_a_step = column_write_counts(self._cache)
         slots = self.ecfg.max_slots
-        # a step's routing counts ride behind its tokens (`fused_step`):
-        # the host's row is as long, so both ways in are one shape.  Only
-        # a speculating engine keeps the row current (it reads every step
-        # at once); the plain engine's carry lives on the device
-        tokens = np.zeros(slots + (3 if self._moe_layers else 0), np.int32)
         self._carry = self._fresh_carry()
         self._warm_lanes()
         # the ring spans' sums count from here, not from the warm-up
@@ -1421,8 +1288,6 @@ class ContinuousBatchingEngine:
                         active = np.zeros(self.ecfg.max_slots, bool)
                         for s in batch:
                             active[s.slot] = True
-                            if self._spec:
-                                tokens[s.slot] = s.last_tok
                     if admitted or prefills or batch \
                             or self._flight is not None:
                         break
@@ -1431,38 +1296,16 @@ class ContinuousBatchingEngine:
                     return
             # ---- device work, OUTSIDE the lock (nobody else touches
             # the slot cache, and client ops must not stall on compute)
-            t0 = time.time()
             handed = []
             if admitted or prefills:
                 with phase("admit"):
                     handed = self._admit_and_prefill(admitted, prefills, fi)
-            spec_out = None
-            if batch and self._spec and not self._spec_disabled:
-                try:
-                    with phase("dispatch"):   # and its own two reads
-                        spec_out = self._spec_step(tokens[:slots], active,
-                                                   fi)
-                    self._spec_fail_streak = 0
-                except Exception as e:
-                    with self._cond:   # stats() reads these
-                        self.spec_fallbacks += 1
-                        self._spec_fail_streak += 1
-                        if self._spec_fail_streak >= \
-                                max(1, self.ecfg.spec_fail_disable):
-                            self._spec_disabled = True
-                    tracing.record_span(
-                        f"serve_spec_fallback::{self.name}", "serve",
-                        t0, time.time(), error=repr(e),
-                        deployment=self.name)
-                self._carry = None   # the host owns the carry again: a
-                #                      failed iteration degrades to the
-                #                      plain step below
             step = new_toks = failed = None
             try:
-                if batch and spec_out is None:
+                if batch:
                     with phase("dispatch"):
                         step = self._dispatch(
-                            batch, active, tokens, admitted,
+                            batch, active, admitted,
                             alone=not (admitted or prefills))
             except Exception as e:
                 failed = e
@@ -1472,18 +1315,13 @@ class ContinuousBatchingEngine:
             try:
                 if failed is not None:
                     raise failed
-                if not self._spec:
-                    # ONE STEP AHEAD: what is read now is the step before
-                    # the one just queued, and the chip works on through
-                    # the read, the publish and the next schedule.  (A
-                    # speculating engine makes its next input on the host
-                    # from this step's tokens: it reads what it queued.)
-                    step, self._flight = self._flight, step
+                # ONE STEP AHEAD: what is read now is the step before the
+                # one just queued, and the chip works on through the read,
+                # the publish and the next schedule
+                step, self._flight = self._flight, step
                 if step is not None:
                     with phase("readback"):
                         new_toks = self._read(step, fi)
-                        if self._spec:
-                            tokens[:] = new_toks
                     if self._moe_layers:
                         self._count_moe(new_toks[slots:])
                     self._count_rows(step.rows)
@@ -1492,25 +1330,19 @@ class ContinuousBatchingEngine:
                 # step queued behind the one that raised goes with it
                 self._fail_slots(f"decode engine step failed: {e!r}")
                 continue
-            if spec_out is not None:
+            if step is not None:
                 with phase("publish"):
-                    self._publish(batch, tokens, spec_out, None)
-            elif step is not None:
-                with phase("publish"):
-                    self._publish(step.batch, tokens, None, new_toks)
+                    self._publish(step.batch, new_toks)
 
     def _fresh_carry(self):
-        """The plain engine's carry before any step: zeros on the device
-        (a joining slot's first token is written into it).  A speculating
-        engine's is the host's row, uploaded when a plain step needs it."""
+        """The carry before any step: zeros on the device (a joining
+        slot's first token is written into it; a step's routing counts
+        ride behind its tokens, `fused_step`)."""
         import jax.numpy as jnp
-        if self._spec:
-            return None
         return jnp.zeros(self.ecfg.max_slots
                          + (3 if self._moe_layers else 0), jnp.int32)
 
-    def _dispatch(self, batch, active, tokens, admitted, alone: bool
-                  ) -> _Step:
+    def _dispatch(self, batch, active, admitted, alone: bool) -> _Step:
         """Queue one fused step over ``batch`` and keep, at DISPATCH
         time, what the host knows of it: each live slot's position moves
         on by one and one more of its tokens is unread, so the next
@@ -1528,18 +1360,12 @@ class ContinuousBatchingEngine:
         import numpy as np
 
         import jax.numpy as jnp
-        key = active.tobytes()
-        if self._spec:
-            # the host's row is current (every step is read at once):
-            # re-upload it on a membership change, as ever
-            if admitted or self._carry is None \
-                    or key != self._active_key:
-                self._carry = jnp.asarray(tokens)
-        elif admitted:
-            firsts = np.full(len(tokens), -1, np.int32)
-            for sess, _, _, slot in admitted:
+        if admitted:
+            firsts = np.full(self._carry.shape[0], -1, np.int32)
+            for sess, _, slot in admitted:
                 firsts[slot] = sess.last_tok
             self._carry = self._join(self._carry, firsts)
+        key = active.tobytes()
         if key != self._active_key:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
@@ -1724,15 +1550,12 @@ class ContinuousBatchingEngine:
         `_n_lanes` of them in arrival order (`_lanes_advance`), which
         reads every weight once for the lot; when one is left it goes back
         to a batch-1 cache and the lane cache is dropped, so a lone prompt
-        never pays for lanes that stand.  A speculating engine (a draft
-        cache beside every target cache) keeps one program a session."""
+        never pays for lanes that stand.  (`_n_lanes` falls to 0 only
+        where `_warm_lanes` found no room for the lane cache.)"""
         import jax.numpy as jnp
-        for sess, pcache, dcache, slot in admitted:
+        for sess, pcache, slot in admitted:
             self._cache = self._insert(self._cache, pcache,
                                        jnp.int32(slot))
-            if self._spec and dcache is not None:
-                self._dcache = self._insert(self._dcache, dcache,
-                                            jnp.int32(slot))
         if self._n_lanes >= 2 and len(prefills) >= 2:
             return self._lanes_advance(prefills, fi)
         ready = []
@@ -1789,77 +1612,39 @@ class ContinuousBatchingEngine:
                 sess.prompt = sess.plogits = None
                 if sess.pos >= self.max_len or sess.ended:
                     sess.done = True  # nothing left to decode
-                    sess.pcache = sess.dcache = None
+                    sess.pcache = None
                 else:
                     self._pending.append(sess)
             self._cond.notify_all()
 
-    def _publish(self, batch, tokens, spec_out, new_toks) -> None:
+    def _publish(self, batch, new_toks) -> None:
         """After a step's read-back: counters, then under the lock the
         new tokens onto their sessions' queues and the wake-up of the
-        callers waiting for them.  ``batch`` is a speculative
-        iteration's sessions, or a plain step's ``(session, slot)`` as
-        they were when it was dispatched: by now the session may have
-        ended and the slot be another's (its token is dropped)."""
+        callers waiting for them.  ``batch`` is the step's ``(session,
+        slot)`` as they were when it was dispatched: by now the session
+        may have ended and the slot be another's (its token is dropped)."""
         from ..core.runtime_metrics import (SERVE_DECODE_OCCUPANCY,
-                                            SERVE_SPEC_ACCEPTANCE,
-                                            SERVE_SPEC_ACCEPTED,
-                                            SERVE_SPEC_PROPOSED,
                                             SERVE_TOKENS)
         occupancy = len(batch)
         # MFU numerators: useful tokens only (active slots), host-
         # known counts — never a device sync
-        if spec_out is not None:
-            greedy, accepted = spec_out
-            self._prof.note_tokens("draft_propose",
-                                   occupancy * self._spec_k)
-            self._prof.note_tokens("verify", occupancy * self._spec_k)
-            emitted = int(sum(accepted[s.slot] for s in batch))
-        else:
-            self._prof.note_tokens("decode_step", occupancy)
-            emitted = occupancy
+        self._prof.note_tokens("decode_step", occupancy)
         SERVE_DECODE_OCCUPANCY.observe(occupancy,
                                        {"deployment": self.name})
-        SERVE_TOKENS.inc(emitted, {"deployment": self.name})
+        SERVE_TOKENS.inc(occupancy, {"deployment": self.name})
         with self._cond:
             self.steps += 1
-            self.tokens += emitted
-            if spec_out is not None:
-                for s in batch:
-                    n = int(accepted[s.slot])
-                    row = greedy[s.slot]
-                    toks = [int(row[i]) for i in range(n)]
-                    s.last_tok = toks[-1]
-                    tokens[s.slot] = s.last_tok
-                    s.pos += n
-                    was_empty = not s.queue
-                    if not s.ended:
-                        s.queue.extend(toks)
-                    if s.pos >= self.max_len:
-                        s.done = True
-                    s.wake(was_empty)
-                self.spec_proposed += (self._spec_k - 1) * occupancy
-                self.spec_accepted += emitted - occupancy
-            else:
-                for s, slot in batch:
-                    tok = int(new_toks[slot])
-                    s.last_tok = tok
-                    s.unread -= 1      # `pos` moved on at the dispatch
-                    was_empty = not s.queue
-                    if not s.ended:
-                        s.queue.append(tok)
-                    if s.pos >= self.max_len and not s.unread:
-                        s.done = True  # cache full: reaped next turn
-                    s.wake(was_empty)
-        if spec_out is not None:
-            SERVE_SPEC_PROPOSED.inc((self._spec_k - 1) * occupancy,
-                                    {"deployment": self.name})
-            SERVE_SPEC_ACCEPTED.inc(emitted - occupancy,
-                                    {"deployment": self.name})
-            if self.spec_proposed:
-                SERVE_SPEC_ACCEPTANCE.set(
-                    self.spec_accepted / self.spec_proposed,
-                    {"deployment": self.name})
+            self.tokens += occupancy
+            for s, slot in batch:
+                tok = int(new_toks[slot])
+                s.last_tok = tok
+                s.unread -= 1      # `pos` moved on at the dispatch
+                was_empty = not s.queue
+                if not s.ended:
+                    s.queue.append(tok)
+                if s.pos >= self.max_len and not s.unread:
+                    s.done = True  # cache full: reaped next turn
+                s.wake(was_empty)
 
 
 def _host_tokens(prompt) -> Optional[tuple]:

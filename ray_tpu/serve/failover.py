@@ -13,9 +13,7 @@ next seq.  Resume IS chunked admission since PR-6: the target engine's
 thread walks the replay prefix through the same fixed-shape chunk
 programs every admission uses (``models.prefill_chunk_jit`` →
 ``models.cache_insert_slot``), so a resume never stalls the healthy
-replica's live streams and never compiles a new program — and a
-resume into a SPECULATING engine is byte-identical too, because greedy
-speculative acceptance is exact-match against the target's own chain.
+replica's live streams and never compiles a new program.
 The client sees a stall — never an error, never a repeated or dropped
 token.
 

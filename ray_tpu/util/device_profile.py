@@ -2,8 +2,8 @@
 
 PR-10's flight recorder made the control plane explainable after the
 fact; this module does the same for the DATA plane.  Every registered
-jitted program (decode step, prefill chunk, cache insert/gather,
-draft/verify) is wrapped ONCE in a timing shim that records, per program:
+jitted program (decode step, prefill chunk, cache insert/gather) is
+wrapped ONCE in a timing shim that records, per program:
 
 * dispatch count and cumulative dispatch wall time (always);
 * device time, from the samples the program's CALLER hands in

@@ -226,7 +226,6 @@ KNOWN_SITES: Dict[str, Optional[frozenset]] = {
     "serve.health_check": frozenset({"error", "fail"}),
     "serve.session_failover": frozenset({"error", "fail"}),
     "serve.autoscale": frozenset({"drop", "error", "fail"}),
-    "serve.spec_verify": frozenset({"error", "fail"}),
     "serve.decode_step": frozenset({"error", "fail"}),
     "serve.prefill_chunk": frozenset({"error", "fail"}),
     "serve.slo_eval": frozenset({"error", "fail"}),

@@ -74,7 +74,7 @@ _LONGEST_FIRST = (
     "test_apex.py", "test_hf_trainer.py", "test_eva_attention.py",
     "test_cache_in_place.py", "test_pixel_pong.py", "test_scale.py",
     "test_offline_rl.py", "test_rl_plumbing.py", "test_tune.py",
-    "test_serve_spec_decode.py", "test_alpha_zero.py", "test_maml.py",
+    "test_alpha_zero.py", "test_maml.py",
     "test_train.py", "test_refcounting.py", "test_slateq.py",
     "test_serve_autoscale.py", "test_serve.py",
     "test_perfbench_chunks_per_program.py", "test_partition.py",
@@ -198,7 +198,7 @@ _COMPILE_BOUND = frozenset((
     "test_prefill_padded_tail.py", "test_mixed_kv_heads.py",
     "test_latent_moe.py", "test_prefill_lanes.py",
     "test_serve_decode_engine.py", "test_generate.py",
-    "test_serve_spec_decode.py", "test_perfbench_family_mimo_v2_flash.py",
+    "test_perfbench_family_mimo_v2_flash.py",
     "test_perfbench_family_evabyte.py", "test_perfbench_reference.py"))
 
 

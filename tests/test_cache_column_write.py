@@ -7,7 +7,7 @@ INTERPRET=1`); what the chip's compiler makes of it is `tests/test_chip_
 compile.py`'s.  The five cache shapes the served cells hold, at tiny sizes
 with whole 128-row blocks: rows of several heads, one row of latents, rings,
 two key-value head counts with keys wider than values, rings beside summary
-rows.  The three cases that keep the slices.  A decode step of a model with
+rows.  The two cases that keep the slices.  A decode step of a model with
 no full layer either way.  And the serve engine's two counts.
 """
 
@@ -94,7 +94,7 @@ def test_kernel_is_the_slices_bit_for_bit(interpreted, case):
 
     got = by(cw.write_columns)
     want = by(lambda c_all, l, cols, col: cw._slices(
-        c_all, jnp.int32(l), cols[..., None], col[:, None]))
+        c_all, jnp.int32(l), cols, col))
     for name in arrays:
         a, b = np.asarray(got[name]), np.asarray(want[name])
         assert a.dtype == b.dtype and np.array_equal(
@@ -111,41 +111,37 @@ def _writes(fn, *args):
     return text.count("pallas_call"), text.count("dynamic_update_slice")
 
 
-def _operands(rows, columns=1, slots=3):
+def _operands(rows, slots=3):
+    """[S, heads, width] and [S], the decode step's own operands."""
     c_all = jnp.zeros((2, slots, 2, 8, rows), jnp.float32)
-    cols = jnp.ones((slots, 2, 8, columns), jnp.float32)
-    col = jnp.arange(slots * columns, dtype=jnp.int32).reshape(
-        slots, columns) * 50
+    cols = jnp.ones((slots, 2, 8), jnp.float32)
+    col = jnp.arange(slots, dtype=jnp.int32) * 50
     return c_all, 1, cols, col
 
 
-@pytest.mark.parametrize("why", ["rows_not_whole_blocks", "several_columns",
-                                 "not_a_tpu"])
+@pytest.mark.parametrize("why", ["rows_not_whole_blocks", "not_a_tpu"])
 def test_the_slices_still_run_where_the_kernel_does_not(monkeypatch, why):
-    """``rows % 128 != 0`` and more than one column a slot keep the slices
-    even under the interpreter; a platform that is no TPU keeps them
-    whatever the shape.  The result is theirs either way."""
+    """``rows % 128 != 0`` keeps the slices even under the interpreter; a
+    platform that is no TPU keeps them whatever the shape.  The result is
+    theirs either way."""
     if why != "not_a_tpu":
         monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    rows, columns = {"rows_not_whole_blocks": (96, 1),
-                     "several_columns": (128, 3),
-                     "not_a_tpu": (128, 1)}[why]
-    args = _operands(rows, columns)
+    rows = {"rows_not_whole_blocks": 96, "not_a_tpu": 128}[why]
+    args = _operands(rows)
     c_all, l, cols, col = args
-    assert cw.kernel_shape(c_all.shape, columns) == (why == "not_a_tpu")
-    assert cw.device_calls(c_all.shape, columns) == 3 * columns
+    assert cw.kernel_shape(c_all.shape) == (why == "not_a_tpu")
+    assert cw.device_calls(c_all.shape) == 3
     if why == "not_a_tpu":
         # both branches are traced; the CPU lowers the slices alone
         text = jax.jit(cw.write_columns).lower(*args).as_text()
         assert text.count("dynamic_update_slice") == 3 \
             and "cache_column_write" not in text
     else:
-        assert _writes(cw.write_columns, *args) == (0, 3 * columns)
+        assert _writes(cw.write_columns, *args) == (0, 3)
     got = np.asarray(cw.write_columns(*args))
     want = np.zeros(c_all.shape, np.float32)
     for s in range(3):
-        for i in range(columns):
-            want[1, s, :, :, min(int(col[s, i]), rows - 1)] = 1.0
+        want[1, s, :, :, min(int(col[s]), rows - 1)] = 1.0
     assert np.array_equal(got, want)
 
 
@@ -153,11 +149,6 @@ def test_the_kernel_engages_under_the_interpreter(interpreted):
     args = _operands(128)
     assert _writes(cw.write_columns, *args) == (1, 0)
     assert cw.device_calls(args[0].shape) == 1
-    # [S, heads, width] and [S], the decode step's own operands
-    c_all, l, cols, col = args
-    assert np.array_equal(
-        np.asarray(cw.write_columns(c_all, l, cols[..., 0], col[:, 0])),
-        np.asarray(cw.write_columns(*args)))
 
 
 def test_heads_go_by_blocks_where_all_would_pass_the_budget(interpreted,
@@ -174,7 +165,7 @@ def test_heads_go_by_blocks_where_all_would_pass_the_budget(interpreted,
     cols = jax.random.normal(key, (3, 6, 8), jnp.float32)
     col = jnp.array([3, 200, 999], jnp.int32)
     got = cw.write_columns(c_all, 0, cols, col)
-    want = cw._slices(c_all, jnp.int32(0), cols[..., None], col[:, None])
+    want = cw._slices(c_all, jnp.int32(0), cols, col)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -17,7 +17,7 @@ import pytest
 from ray_tpu.models import (TransformerConfig, cache_insert_slot,
                             decode_step, decode_step_slots, init_kv_cache,
                             init_params, init_slot_cache, prefill,
-                            prefill_chunk_jit, verify_step_slots)
+                            prefill_chunk_jit)
 
 _CONFIGS = {
     "learned": dict(pos_emb="learned", n_kv_heads=4, activation="gelu",
@@ -160,10 +160,10 @@ def test_slot_path_serves_what_the_unbatched_path_serves(kind):
 
 @pytest.mark.parametrize("kind", sorted(_CONFIGS))
 def test_columns_past_the_end_are_dropped(kind):
-    """A verify step that feeds four tokens to a slot two positions from
-    the end emits exactly what two plain steps emit (the two columns past
-    `max_len` must not land on the last position), and a step over a slot
-    that is already full leaves every column a reader may still use."""
+    """A slot two positions from the end takes its last two slot steps as
+    two batch-1 steps do, and a step over a slot that is already full (its
+    one column, past `max_len`, is clamped onto the last position) leaves
+    every column a reader may still use."""
     max_len = 16
     cfg = _cfg(kind, max_len)
     params, _ = init_params(jax.random.PRNGKey(5), cfg)
@@ -171,22 +171,17 @@ def test_columns_past_the_end_are_dropped(kind):
     lg, one = prefill(params, jnp.asarray([prompt], jnp.int32), cfg,
                       init_kv_cache(cfg, 1, max_len))
     tok = jnp.argmax(lg, -1).astype(jnp.int32)
-    cache = cache_insert_slot(init_slot_cache(cfg, 2, max_len), one,
+    after = cache_insert_slot(init_slot_cache(cfg, 2, max_len), one,
                               jnp.int32(1))
-    plain = []
-    t, c1 = tok, one
+    active = jnp.asarray([False, True])
+    t, c1, toks = tok, one, jnp.asarray([0, int(tok[0])], jnp.int32)
     for _ in range(2):
         lg, c1 = decode_step(params, t, c1, cfg)
         t = jnp.argmax(lg, -1).astype(jnp.int32)
-        plain.append(int(t[0]))
-    # the draft agrees with the target on the first proposal, then not
-    fed = jnp.asarray([[0, 0, 0, 0],
-                       [int(tok[0]), plain[0], 1, 2]], jnp.int32)
-    active = jnp.asarray([False, True])
-    greedy, accepted, after = verify_step_slots(
-        params, fed, fed[:, 1:], cache, active, cfg)
-    assert int(accepted[1]) == 2                    # clamped to the room
-    assert np.asarray(greedy[1, :2]).tolist() == plain
+        lgs, after = decode_step_slots(params, toks, after, active, cfg)
+        toks = jnp.where(active, jnp.argmax(lgs, -1).astype(jnp.int32),
+                         toks)
+        assert int(toks[1]) == int(t[0])
     assert np.asarray(after["pos"]).tolist() == [0, 16]
     np.testing.assert_allclose(np.asarray(after["k"][:, 1]),
                                np.asarray(c1["k"][:, 0]),

@@ -9,8 +9,7 @@ program's code) on seeded random weights at a tiny size (window 32, chunk 4,
 chunk programs of 8): `forward`; whole-prompt `prefill`; chunked prefill whose
 chunks straddle a window's edge and whose prompt ends mid-chunk; the lanes
 program with a lane that stands; slot decode through three windows beside a
-slot that is not live; slot insert and gather; the verify program with a
-rejected proposal; `_prefix_exact`'s cases.  The two limits of the equations
+slot that is not live; slot insert and gather; `_prefix_exact`'s cases.  The two limits of the equations
 against `ops/attention.py`'s plain attention.  And every configuration the
 benchmark already had: its `cache_rows` and its engine's `_rows_of` as they
 were.
@@ -32,7 +31,7 @@ from perfbench.tools import rehearse
 from ray_tpu.models import (cache_gather_slot, cache_insert_slot,
                             decode_step_slots, forward, init_kv_cache,
                             init_slot_cache, prefill, prefill_chunk_jit,
-                            prefill_lanes_jit, verify_step_slots)
+                            prefill_lanes_jit)
 from ray_tpu.models.generate import (_state_kind, cache_bytes,
                                      cache_capacity, cache_rows,
                                      position_bytes, prefill_chunk_step,
@@ -202,48 +201,6 @@ def test_slots_decode_through_three_windows(world):
     assert at == {0: T, 2: T}
     logits, _ = _chunked(w, 0, 70, seed, off=45)
     np.testing.assert_allclose(logits[0], w.want[0, 69], **TOL)
-
-
-def test_verify_repairs_what_a_rejected_proposal_wrote(world):
-    """Five tokens a slot from positions 29 and 62: they cross a window's
-    edge and complete summary chunks.  Slot 0's first proposal is the
-    reference's own choice and is accepted, slot 1's is not: every summary
-    the five tokens reached was pooled over rejected tokens too, and the
-    steps that follow pool them again over what they feed."""
-    cfg, w = world.cfg, world
-    model = mf.family_of(w.c).model
-    slots = init_slot_cache(cfg, 2, MAX_LEN)
-    starts = (29, 62)
-    for s, n in enumerate(starts):
-        _, one = _chunked(w, s, n, init_kv_cache(cfg, 1, MAX_LEN))
-        slots = cache_insert_slot(slots, one, jnp.int32(s))
-    fed = np.stack([np.asarray(w.toks[s, n:n + 5])
-                    for s, n in enumerate(starts)])
-    fed[0, 1] = w.want[0, 29, :40].argmax()
-    fed[1, 1] = (w.want[1, 62, :40].argmax() + 1) % 40
-    greedy, accepted, slots = jax.jit(functools.partial(
-        verify_step_slots, cfg=cfg))(
-        w.params, jnp.asarray(fed), jnp.asarray(fed[:, 1:]), slots,
-        jnp.ones((2,), bool))
-    assert [int(a) for a in accepted] == [2, 1]
-    at = [n + int(a) for n, a in zip(starts, accepted)]
-    assert [int(p) for p in slots["pos"]] == at
-    # the reference on what each slot was fed: all five for the choices,
-    # then what was accepted with the sequence's own tokens behind it
-    toks = np.asarray(w.toks).copy()
-    for s, n in enumerate(starts):
-        toks[s, n:n + 5] = fed[s]
-    want = np.asarray(model.logits(w.params, jnp.asarray(toks), w.c))
-    for s, n in enumerate(starts):
-        assert list(greedy[s]) == list(want[s, n:n + 5, :40].argmax(-1))
-        toks[s, at[s]:] = np.asarray(w.toks[s, at[s]:])
-    want = np.asarray(model.logits(w.params, jnp.asarray(toks), w.c))
-    for _ in range(12):
-        tok = jnp.asarray([toks[s, at[s]] for s in range(2)])
-        logits, slots = w.step(w.params, tok, slots, jnp.ones((2,), bool))
-        for s in range(2):
-            np.testing.assert_allclose(logits[s], want[s, at[s]], **TOL)
-            at[s] += 1
 
 
 def test_prefix_exact_says_which_donor_still_holds_a_prefix():

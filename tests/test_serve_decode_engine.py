@@ -67,7 +67,55 @@ def test_decode_step_slots_matches_batch1_decode():
     assert int(slot_cache["pos"][2]) == 9
 
 
+@pytest.mark.parametrize("shape", [(4, 2), (4, 1), (3,)])
+def test_decode_step_slots_takes_one_token_a_slot(shape):
+    """A slot step feeds ONE token a slot: tokens of another shape (two
+    columns a slot, a column axis of one, a slot too few) are refused by
+    name, not broadcast into the batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import (decode_step_slots, init_params,
+                                init_slot_cache)
+    cfg = _tiny_cfg()
+    params, _ = init_params(jax.random.PRNGKey(3), cfg)
+    cache = init_slot_cache(cfg, 4, 64)
+    active = jnp.ones((4,), bool)
+    with pytest.raises(ValueError, match="ONE token a slot"):
+        decode_step_slots(params, jnp.zeros(shape, jnp.int32), cache,
+                          active, cfg)
+
+
 # ---------------------------------------------------- engine-level (no cluster)
+
+@pytest.mark.parametrize("depth", [0, -1])
+def test_a_token_queue_that_holds_no_token_is_refused(depth):
+    """`token_queue_depth` under 1 is no configuration: a session admitted
+    in a turn that dispatches no step never gets its first token into the
+    carry and its caller waits for ever (`tests/test_engine_wakeups.py`
+    found it); the settings refuse it where they are made."""
+    import dataclasses
+
+    from ray_tpu.serve.config import DecodeEngineConfig
+    with pytest.raises(ValueError, match="token_queue_depth"):
+        DecodeEngineConfig(token_queue_depth=depth)
+    with pytest.raises(ValueError, match="token_queue_depth"):
+        dataclasses.replace(DecodeEngineConfig(), token_queue_depth=depth)
+
+
+@pytest.mark.parametrize("key", ["spec_draft", "spec_k", "spec_fail_disable"])
+def test_the_settings_of_speculative_decoding_are_refused_by_name(key):
+    """Engine settings arrive as the dataclass alone (`DecodeSessionCore`
+    takes nothing else): a deployment that still names one of the three
+    settings that went with speculative decoding fails where it builds
+    them, with the key in the message, and no mapping gets past that."""
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    with pytest.raises(TypeError, match=key):
+        DecodeEngineConfig(**{key: 2})
+    with pytest.raises(TypeError, match="DecodeEngineConfig"):
+        DecodeSessionCore(_tiny_cfg(), max_len=64, engine={key: 2})
+
 
 def test_engine_token_parity_with_midstream_join_leave():
     """Acceptance: continuous-batched decode emits byte-identical token
@@ -214,7 +262,7 @@ def test_engine_failed_step_fails_slot_holders_and_serves_on():
 
 # ------------------------------------------------------- one step ahead
 #
-# The plain engine dispatches step n+1 before it reads step n: positions,
+# The engine dispatches step n+1 before it reads step n: positions,
 # the queue bound and the live mask are kept at DISPATCH time, and only
 # token values reach the host a step late.  Every stream below is held to
 # the whole-prompt greedy reference.
@@ -351,12 +399,10 @@ def test_step_ahead_streams_equal_the_greedy_reference(churn):
         core.engine.shutdown()
 
 
-@pytest.mark.parametrize("draft", [None, "shared"], ids=["plain", "spec"])
-def test_steps_ahead_counter_and_span(draft, monkeypatch):
+def test_steps_ahead_counter_and_span(monkeypatch):
     """On a steady batch every fused step but the first is dispatched
     before the one ahead of it is read, and the `engine:ahead` ring span
-    carries the sums; a speculating engine makes its next input on the
-    host and counts none."""
+    carries the sums."""
     from ray_tpu.serve.decode_session import ContinuousBatchingEngine
     from ray_tpu.util import tracing
     monkeypatch.setattr(ContinuousBatchingEngine, "_MOE_SPAN_S", 0.0)
@@ -366,7 +412,7 @@ def test_steps_ahead_counter_and_span(draft, monkeypatch):
                 if e["name"] == "engine:ahead"]
 
     before = len(spans())
-    core = _core(spec_draft=draft, spec_k=3) if draft else _core()
+    core = _core()
     try:
         prompt = [3, 1, 4, 1]
         r = core.handle({"op": "start", "prompt": prompt})
@@ -375,16 +421,128 @@ def test_steps_ahead_counter_and_span(draft, monkeypatch):
                                     max_len=64, seed=3)
         st = core.handle({"op": "stats"})["engine"]
         mine = spans()[before:]
-        if draft:
-            assert st["spec"]["proposed"] > 0 and st["steps_ahead"] == 0
-            assert not mine      # no plain step was dispatched at all
-            return
         assert st["steps"] == 64 - len(prompt)
         assert st["steps_ahead"] == st["steps"] - 1
         assert all(e["cat"] == "ahead" for e in mine)
         assert sum(e["args"].get("steps", 0) for e in mine) == st["steps"]
         assert sum(e["args"].get("steps_ahead", 0) for e in mine) \
             == st["steps_ahead"]
+    finally:
+        core.engine.shutdown()
+
+
+def test_stats_keep_what_the_benchmark_and_its_readers_take():
+    """`engine.stats()` carries every key `perfbench` and the dashboards
+    read, and none of a mode the engine does not have."""
+    core = _core()
+    try:
+        r = core.handle({"op": "start", "prompt": [2, 7, 1]})
+        _drain(core, r["sid"], list(r["token"]), 4)
+        st = core.handle({"op": "stats"})["engine"]
+        assert {"steps", "tokens", "prefill_chunks", "prefill_programs",
+                "phase_totals", "program_shapes", "prefix", "steps_ahead",
+                "prefill_lanes", "moe", "cache", "cache_copies",
+                "device_profile"} <= set(st)
+        assert "spec" not in st and st["prefill_lanes"] >= 2
+        assert {p["program"] for p in st["device_profile"]} <= {
+            "decode_step", "prefill_chunk", "cache_insert", "prefix_gather"}
+        assert {"prefill", "decode_dispatch", "queue", "admission"} <= set(
+            st["phase_totals"])
+    finally:
+        core.engine.shutdown()
+
+
+def _tiny_with_state(state):
+    """(cfg, params) of a two-layer float32 model whose cache holds
+    ``state``: rows for the whole context alone (keys and values, or one
+    array of latents), or beside them a window layer's ring (12 rows), a
+    conv layer's state; for summary rows beside rings, which no full layer
+    need stand by, the rehearsal's tiny byte model."""
+    import dataclasses
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import TransformerConfig, init_params
+    if state == "eva":
+        from perfbench import manifest as mf
+        from perfbench.tools import rehearse
+        with open(os.path.join(mf.ROOT, rehearse.REHEARSAL, "configs",
+                               "tiny-evabyte.json")) as f:
+            c = json.load(f)
+        model = mf.family_of(c).model
+        cfg = dataclasses.replace(
+            model.model_config(c, "serve", attention_impl="reference"),
+            dtype=jnp.float32, param_dtype=jnp.float32)
+        return cfg, model.make(jax.random.PRNGKey(11), c, jnp.float32)
+    cfg = TransformerConfig.tiny(
+        attention_impl="reference", dtype=jnp.float32, **{
+            "full": {},
+            "window": dict(layer_kinds=("window", "full"), sliding_window=8,
+                           window_chunk=4),
+            "conv": dict(layer_kinds=("conv", "full"), conv_kernel=3),
+            "latent": dict(attention="mla", q_lora_rank=24, kv_lora_rank=16,
+                           qk_nope_head_dim=12, qk_rope_head_dim=8,
+                           v_head_dim=16),
+        }[state])
+    return cfg, init_params(jax.random.PRNGKey(11), cfg)[0]
+
+
+@pytest.mark.parametrize("state", ["full", "window", "eva", "conv",
+                                   "latent"])
+def test_a_session_stepped_to_max_len_ends_there(state):
+    """For each kind of cached state (rows for the whole context, rings,
+    rings beside summary rows, conv states, latents): a session decodes
+    until its slot is full, one token a position the cache had left, and is
+    done; its slot's ``pos`` stands AT ``max_len`` and no further, however
+    many steps its neighbour goes on for (a full slot is in no later
+    batch, and the one column a step still writes for it is clamped onto
+    its last row); the neighbour, which runs to the end behind it, and a
+    session that then reuses a slot that was full stream what they stream
+    alone."""
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    cfg, params = _tiny_with_state(state)
+    assert {"full": set(cfg.kinds) == {"full"} and cfg.attention != "mla",
+            "latent": cfg.attention == "mla"}.get(
+                state, state in cfg.kinds), cfg.kinds
+    max_len = 64
+    core = DecodeSessionCore(cfg, max_len=max_len, params=params,
+                             engine=DecodeEngineConfig(max_slots=2))
+
+    def ref(prompt, n):
+        return greedy_stream(cfg, prompt, n, max_len=max_len, params=params)
+
+    try:
+        v = cfg.vocab_size
+        long = [(5 * i + 3) % v for i in range(53)]
+        short = [(7 * i + 1) % v for i in range(6)]
+        a = core.handle({"op": "start", "prompt": long})
+        b = core.handle({"op": "start", "prompt": short})
+        eng = core.engine
+        sess_b = eng.sessions[b["sid"]]
+        sa = _drain(core, a["sid"], list(a["token"]), 2 * max_len)
+        # the prefill's token, then one a position the cache had left
+        assert len(sa) == 1 + max_len - len(long)
+        assert sa == ref(long, len(sa))
+        out = core.handle({"op": "next_chunk", "sid": a["sid"]})
+        assert out["tokens"] == [] and out["done"]
+        sb = _drain(core, b["sid"], list(b["token"]), 12)
+        assert sb == ref(short, 12)
+        # every slot's column is written every step, live or not: b
+        # decodes on to its own end (its queue holds what is left), and
+        # after all its steps a's slot still stands where it ended
+        _wait(lambda: sess_b.done and eng._flight is None,
+              "the neighbour never reached the end")
+        with eng._cond:
+            assert eng._cache["pos"].tolist() == [max_len, max_len]
+        for r in (a, b):
+            core.handle({"op": "end", "sid": r["sid"]})
+        again = core.handle({"op": "start", "prompt": short[::-1]})
+        sc = _drain(core, again["sid"], list(again["token"]), 12)
+        assert sc == ref(short[::-1], 12)
+        assert eng.stats()["cache_copies"] == 0
     finally:
         core.engine.shutdown()
 
@@ -446,6 +604,73 @@ def test_step_fault_with_a_step_queued_behind_it(chaos_cleanup):
 # the batch-1 program over its own cache.  Every stream below is held to
 # the whole-prompt greedy reference, every lane is free again at the end
 # and the lane cache gone.
+
+# -------------------------------------------------- chunked-prefill admission
+
+def test_chunked_admission_token_parity_across_chunk_boundaries():
+    """Acceptance: chunked admission emits byte-identical streams for
+    prompt lengths straddling the chunk boundary (below, exact, above,
+    multiple), including a mid-stream join under load — and the whole
+    run compiles the one prefill chunk shape."""
+    cfg = _tiny_cfg()
+    want = 10
+    prompts = [[5, 6, 7], [1, 2, 3, 4], [9, 8, 7, 6, 5],
+               [3] * 8, [4] * 9]   # chunk=4: 3 | 4 | 5 | 8 | 9
+    refs = [_greedy(p, want) for p in prompts]
+    core = _core(prefill_chunk_tokens=4)
+    # staggered: s0 streams alone, s1..s4 join while s0 is mid-stream
+    r0 = core.handle({"op": "start", "prompt": prompts[0]})
+    s0 = _drain(core, r0["sid"], list(r0["token"]), 5)
+    mids = [core.handle({"op": "start", "prompt": p})
+            for p in prompts[1:]]
+    outs = [_drain(core, r["sid"], list(r["token"]), want)
+            for r in mids]
+    s0 = _drain(core, r0["sid"], s0, want)
+    for r in (r0, *mids):
+        core.handle({"op": "end", "sid": r["sid"]})
+    assert [s0] + outs == refs
+    st = core.handle({"op": "stats"})["engine"]
+    assert st["prefill_chunks"] >= 5
+    pf_shapes = [s for s in st["program_shapes"]
+                 if s.startswith("prefill_chunk")]
+    assert pf_shapes == ["prefill_chunk:1x4"], (
+        f"admission must reuse the ONE fixed chunk shape (a remainder "
+        f"is padded into it), compiled: {pf_shapes}")
+    # 3 | 4 | 5 | 8 | 9 tokens: 1 + 1 + 2 + 2 + 3 programs, of which
+    # those of 3, 5 and 9 end in a padded remainder
+    assert (st["prefill_chunks"], st["prefill_tails"],
+            st["prefill_pad_tokens"]) == (9, 3, 1 + 3 + 3)
+    assert "distinct_program_shapes" in st
+
+
+def test_chunked_admission_and_resume_share_program_shapes():
+    """Satellite: a failover resume after chunked admissions adds NO
+    new prefill program shape — admission and resume walk the same
+    fixed-shape chunk programs, so resumes can never compile-storm."""
+    want = 10
+    prompt = [5, 6, 7, 8, 9]
+    ref = _greedy(prompt, want)
+    core = _core(prefill_chunk_tokens=4)
+    r = core.handle({"op": "start", "prompt": prompt})
+    _drain(core, r["sid"], list(r["token"]), want)
+    core.handle({"op": "end", "sid": r["sid"]})
+    shapes_before = set(
+        core.handle({"op": "stats"})["engine"]["program_shapes"])
+    # resume mid-stream at an awkward cut (prefix length 5+7=12: three
+    # chunk blocks; the admission's 5 were one block and a padded one)
+    rr = core.handle({"op": "resume", "prompt": prompt,
+                      "generated": ref[:7]})
+    assert rr["seq"] == 7
+    toks = ref[:7] + list(rr["token"])
+    toks = _drain(core, rr["sid"], toks, want)
+    assert toks == ref
+    core.handle({"op": "end", "sid": rr["sid"]})
+    shapes_after = set(
+        core.handle({"op": "stats"})["engine"]["program_shapes"])
+    new = {s for s in shapes_after - shapes_before
+           if s.startswith("prefill_chunk")}
+    assert not new, f"resume compiled new prefill shapes: {new}"
+
 
 def _prompt(i, n):
     return [(7 * i + 3 * j) % 200 + 1 for j in range(n)]
@@ -697,25 +922,6 @@ def test_a_session_lost_mid_prompt_frees_its_lane_and_hurts_no_neighbour(
                     how, i, t.out[i])
         assert t.eng._thread.is_alive()
         assert t.eng.stats()["reaped"] == (how == "reaped")
-    finally:
-        core.engine.shutdown()
-
-
-def test_a_speculating_engine_keeps_one_program_a_session():
-    """A draft cache stands beside every target cache: no lanes, and
-    prompts at once advance a program each, as before."""
-    core = _lane_core(spec_draft="shared", spec_k=3)
-    try:
-        t = _Together(core)
-        assert t.eng.stats()["prefill_lanes"] == 0
-        prompts = [_prompt(i, n) for i, n in enumerate((13, 9, 18))]
-        t.start(prompts)
-        d = t.finish()
-        for i, prompt in enumerate(prompts):
-            assert t.out[i] == _greedy(prompt, 5), i
-        # target and draft each: the gate counts both
-        assert set(t.ran) == {"_chunk"}
-        assert d["prefill_programs"] == d["prefill_chunks"] == 4 + 3 + 5
     finally:
         core.engine.shutdown()
 
@@ -1129,6 +1335,21 @@ def test_engine_metrics_registered_in_process():
     text = metrics.prometheus_text()
     assert "ray_tpu_serve_tokens_total" in text
     assert "ray_tpu_serve_decode_batch_occupancy" in text
+
+
+def test_prefill_chunk_counter_exported():
+    """The chunk counter lands in the process registry."""
+    from ray_tpu import metrics
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    core = DecodeSessionCore(_tiny_cfg(), max_len=64, seed=1)
+    r = core.handle({"op": "start", "prompt": [1, 2, 3]})
+    out = core.handle({"op": "next_chunk", "sid": r["sid"],
+                       "max_tokens": 8})
+    assert len(out["tokens"]) >= 1
+    core.handle({"op": "end", "sid": r["sid"]})
+    text = metrics.prometheus_text()
+    assert "ray_tpu_serve_prefill_chunks_total" in text
+    assert "ray_tpu_serve_spec" not in text
 
 
 # ------------------------------------------------------------------- chaos

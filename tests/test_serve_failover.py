@@ -323,13 +323,13 @@ def test_engine_resume_replay_parity():
 
     from ray_tpu.serve.config import DecodeEngineConfig
 
-    # engines to resume INTO: plain, and (PR-6) one that speculates —
-    # chunked teacher-forced admission + exact greedy verification must
-    # keep the replayed continuation byte-identical either way
+    # engines to resume INTO: the defaults, and ones of other slot counts
+    # and chunk widths — chunked teacher-forced admission must keep the
+    # replayed continuation byte-identical whatever the batch's shape
     engines = {1: None, 7: None,
-               12: DecodeEngineConfig(spec_draft="shared", spec_k=4),
+               12: DecodeEngineConfig(max_slots=2),
                6: DecodeEngineConfig(prefill_chunk_tokens=4,
-                                     spec_draft="shared", spec_k=3)}
+                                     max_slots=3)}
     for cut, engine in engines.items():
         fresh = DecodeSessionCore(cfg, max_len=64, seed=3,
                                   engine=engine)

@@ -20,11 +20,9 @@ import pytest
 
 from greedy_reference import greedy_stream
 from ray_tpu.models import (TransformerConfig, cache_gather_slot,
-                            cache_insert_slot, decode_step_slots,
-                            draft_propose_slots, forward, init_kv_cache,
-                            init_params, init_slot_cache, prefill,
-                            prefill_chunk_jit, prefill_chunked,
-                            verify_step_slots)
+                            cache_insert_slot, decode_step_slots, forward,
+                            init_kv_cache, init_params, init_slot_cache,
+                            prefill, prefill_chunk_jit, prefill_chunked)
 from ray_tpu.models.generate import (cache_arrays, cache_bytes,
                                      cache_capacity, cache_rows,
                                      prefill_chunk_step)
@@ -302,13 +300,6 @@ def test_slot_insert_and_gather_carry_the_state(model):
 
 def test_what_a_state_cannot_serve_is_refused(model):
     cfg, params, _, toks, _ = model
-    slots = init_slot_cache(cfg, 2, 64)
-    active = jnp.array([True, True])
-    with pytest.raises(ValueError, match="cannot be taken back"):
-        verify_step_slots(params, toks[:, :3], toks[:, 1:3], slots, active,
-                          cfg)
-    with pytest.raises(ValueError, match="cannot be taken back"):
-        draft_propose_slots(params, toks[:, 0], slots, active, cfg, 2)
     # a prompt that ends within a chunk of the cache's end: the window
     # would be set back over tokens the state has already taken
     cache = init_kv_cache(cfg, 1, 20)
@@ -336,27 +327,6 @@ def init_and_step(cfg):
         cfg, layer_kinds=KINDS, conv_kernel=3))
     return prefill(params, jnp.zeros((1, 4), jnp.int32), cfg,
                    {"k": jnp.zeros((1, 1, 2, 16, 8)), "pos": jnp.int32(0)})
-
-
-@pytest.mark.parametrize("which", ["shared", "plain draft of a conv target",
-                                   "conv draft of a plain target"])
-def test_an_engine_with_a_draft_over_conv_layers_raises_when_built(
-        model, which):
-    from ray_tpu.serve.config import DecodeEngineConfig
-    from ray_tpu.serve.decode_session import DecodeSessionCore
-    cfg, params = model[0], model[1]
-    plain = dataclasses.replace(cfg, layer_kinds=None)
-    target, draft = {
-        "shared": (cfg, "shared"),
-        "plain draft of a conv target": (cfg, plain),
-        "conv draft of a plain target": (plain, cfg)}[which]
-    tparams = params if target is cfg else init_params(
-        jax.random.PRNGKey(2), plain)[0]
-    core = DecodeSessionCore(target, max_len=64, params=tparams,
-                             engine=DecodeEngineConfig(max_slots=2,
-                                                       spec_draft=draft))
-    with pytest.raises(ValueError, match="speculative decoding over"):
-        core.engine      # built on first use: refused there, not later
 
 
 # ------------------------------------------------------------- the engine

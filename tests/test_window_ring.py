@@ -21,7 +21,7 @@ from greedy_reference import greedy_stream
 from ray_tpu.models import (TransformerConfig, cache_gather_slot,
                             cache_insert_slot, decode_step_slots, forward,
                             init_kv_cache, init_params, init_slot_cache,
-                            prefill, prefill_chunk_jit, verify_step_slots)
+                            prefill, prefill_chunk_jit)
 from ray_tpu.models.generate import (_ring_mask, _ring_write_chunk,
                                      cache_arrays, cache_bytes,
                                      cache_capacity, cache_rows, window_ring)
@@ -327,10 +327,6 @@ def test_what_a_ring_cannot_serve_is_refused_not_answered(model):
     with pytest.raises(ValueError, match="window_chunk"):
         prefill_chunk_jit(params, toks[:1, :5], init_kv_cache(cfg, 1, 128),
                           cfg=cfg)
-    slots = init_slot_cache(cfg, 2, 128)
-    with pytest.raises(ValueError, match="window_chunk"):
-        verify_step_slots(params, toks[:, :5], toks[:, 1:5], slots,
-                          jnp.ones((2,), bool), cfg)
     only = dataclasses.replace(cfg, layer_kinds=("window",) * 5)
     with pytest.raises(NotImplementedError, match="window layers only"):
         prefill_chunk_jit(params, toks[:1, :4], init_kv_cache(only, 1, 128),
@@ -339,41 +335,6 @@ def test_what_a_ring_cannot_serve_is_refused_not_answered(model):
     with pytest.raises(ValueError, match="layer_kinds"):
         prefill_chunk_jit(params, toks[:1, :4], init_kv_cache(cfg, 1, 128),
                           cfg=bad)
-
-
-def test_speculative_verify_over_a_ring_is_exact(model):
-    """A verify of ``window_chunk`` tokens a slot writes its rejected
-    proposals ahead of ``pos``, into ring columns whose old positions no
-    later query sees: the accepted tokens and the logits after them are
-    those of plain decode."""
-    cfg, params, _, toks, want = model
-    slots = init_slot_cache(cfg, 2, 128)
-    insert = jax.jit(cache_insert_slot)
-    for i, n in enumerate((20, 9)):
-        _, c = _walk(cfg, params, toks[i:i + 1], [4] * (n // 4) + [1] * (n % 4))
-        slots = insert(slots, c, jnp.int32(i))
-    step = jax.jit(functools.partial(decode_step_slots, cfg=cfg))
-    verify = jax.jit(functools.partial(verify_step_slots, cfg=cfg))
-    active = jnp.ones((2,), bool)
-    pos = [20, 9]
-    for _ in range(6):
-        # proposals: the true next token, then garbage, garbage
-        fed = jnp.asarray([[toks[i, pos[i]], toks[i, pos[i] + 1], 250, 251]
-                           for i in range(2)])
-        greedy, accepted, slots = verify(params, fed, fed[:, 1:], slots,
-                                         active)
-        for i in range(2):
-            assert int(jnp.argmax(want[i, pos[i]])) == int(greedy[i, 0])
-            n = int(accepted[i])
-            assert 1 <= n <= 2 or int(greedy[i, 1]) == 250
-            pos[i] += n
-        assert [int(p) for p in slots["pos"]] == pos
-        # the next plain step reads rows the rejected proposals scribbled on
-        tok = jnp.asarray([toks[i, pos[i]] for i in range(2)])
-        lg, slots = step(params, tok, slots, active)
-        for i in range(2):
-            assert float(jnp.abs(lg[i] - want[i, pos[i]]).max()) < TOL
-            pos[i] += 1
 
 
 # ------------------------------------------------------- through the engine
