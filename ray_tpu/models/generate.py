@@ -9,11 +9,12 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
-A cache holds up to FOUR KINDS OF STATE behind the same functions (`cache_rows`,
+A cache holds up to FIVE KINDS OF STATE behind the same functions (`cache_rows`,
 `position_bytes`, `cache_bytes`, the slot insert and gather): rows for the
 whole context (``full``), a ring of a window's rows (``ring``), a conv
-layer's last inputs (``state``) and a row a chunk of positions
-(``summary``); they follow one by one below.
+layer's last inputs (``state``), a row a chunk of positions (``summary``)
+and an indexer's keys on the indexing layers alone (``index``); they follow
+one by one below.
 
 What a cache holds is a property of the model's attention kind
 (`cache_rows`): keys and values of ``kv_heads x head_dim`` (``"k"``,
@@ -72,6 +73,23 @@ a summary pooled over tokens written ahead of a row's ``pos`` is pooled
 again by the program that feeds the true ones, so a chunk window set back
 need not be refused for it.
 
+A FIFTH KIND OF STATE CHOOSES WHAT THE OTHERS' ROWS ARE READ FOR: a latent-
+attention model with an indexer (`ops/sparse_index.py`; layer kinds
+``"index"`` | ``"shared"``) holds, beside the latents ``"kv"`` of EVERY
+layer, the indexer's keys of the INDEXING layers alone: ``k_idx``
+``[L_index, batch, 1, index_head_dim, max_len]``, one key a position.  Every
+cached program writes the new positions' index keys as it writes their
+latents (the decode step through `ops/cache_write.py`, one call an array a
+layer), scores the rows up to each query's own position, takes the exact
+``index_topk`` best (`sparse_index.select`) and hands that choice down the
+layer loop (the ``sel`` of `_scan_cached`'s carry) to the shared layers
+behind the indexing one; each attends under the choice as its mask, a
+query's own set of columns.  Only a position a query may SEE can be chosen
+(the same mask as a full layer's: a column past a row's ``pos``, a padded
+chunk's tail, an inactive slot's token, a former session's stale rows lie
+outside it), so what a program writes ahead of ``pos`` is as harmless here
+as in a full layer.
+
 A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
 summary arrays say it (`cache_capacity`, which then needs the model's
 ``summary_chunk``), and nothing stands in for a full layer.  What still
@@ -121,13 +139,16 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops import eva_attention as eva
 from ..ops import latent_attention as mla
+from ..ops import sparse_index
 from ..ops.attention import sink_softmax
 from ..ops.cache_write import device_calls, write_columns
 from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
-from .transformer import (ATTENTION_KINDS, TransformerConfig, _attn_out,
-                          _ffn, _layer, _norm, _post, _qkv, _scale_embedding,
-                          _unembed, norm_eps, rope_tables, scan_layer_runs)
+from .transformer import (ATTENTION_KINDS, SPARSE_KINDS, TransformerConfig,
+                          _attn_out, _ffn, _layer, _norm, _post, _qkv,
+                          _scale_embedding, _unembed, check_kinds,
+                          index_inputs, norm_eps, rope_tables,
+                          scan_layer_runs)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -138,8 +159,13 @@ Arrays = Dict[str, jnp.ndarray]     # a cache without its "pos"
 _RING = "_win"      # suffix of a window layer's arrays: rings
 _STATE = "_state"   # suffix of a conv layer's array: no positions at all
 _SUMMARY = "_sum"   # suffix of a summary layer's arrays: a row a CHUNK
+_INDEX = "_idx"     # suffix of an indexing layer's array: the indexer's keys
 _CONV_STATE = "conv" + _STATE
 _SUM_NAMES = ("k" + _SUMMARY, "v" + _SUMMARY)
+_INDEX_ARRAY = "k" + _INDEX
+#: the kinds of layer whose arrays hold a row a position for the whole
+#: context, written and masked alike
+_ROW_KINDS = ("full",) + SPARSE_KINDS
 
 
 def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
@@ -153,7 +179,10 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     state = {_CONV_STATE: (1, cfg.conv_kernel - 1)} \
         if "conv" in cfg.kinds else {}
     if cfg.attention == "mla":
-        return dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
+        rows = dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
+        if "index" in cfg.kinds:    # ONE key a position, no value
+            rows[_INDEX_ARRAY] = (1, cfg.index_head_dim)
+        return rows
     rows = {}
     for kind in ATTENTION_KINDS:
         if kind in cfg.kinds:
@@ -167,8 +196,9 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
 
 def position_bytes(cfg: TransformerConfig) -> Dict[str, int]:
     """Bytes ONE layer of each state kind holds a position a sequence
-    (``full``, ``ring``), a chunk of positions (``summary``, of a model
-    that has summaries), or a sequence whatever its positions (``state``:
+    (``full``, ``ring``; ``index``, of a model with an indexer), a chunk of
+    positions (``summary``, of a model that has summaries), or a sequence
+    whatever its positions (``state``:
     a conv layer's ``conv_kernel - 1`` inputs of ``d_model``): what a
     decode step reads of a row it attends, by the row's kind."""
     out = {"full": 0, "ring": 0, "state": 0}
@@ -212,7 +242,8 @@ def cache_capacity(cache: KVCache,
 def cache_bytes(cache: KVCache) -> Dict[str, int]:
     """Bytes of a cache's arrays by state kind: ``full`` (rows for the
     whole context), ``ring`` (window layers), ``state`` (conv layers) and,
-    where the cache has them, ``summary`` (a row a chunk)."""
+    where the cache has them, ``summary`` (a row a chunk) and ``index`` (an
+    indexer's keys)."""
     out = {"full": 0, "ring": 0, "state": 0}
     for name, a in cache_arrays(cache).items():
         kind = _state_kind(name)
@@ -233,10 +264,10 @@ def column_write_counts(cache: KVCache) -> Tuple[int, int]:
 
 
 def _state_kind(name: str) -> str:
-    """``full`` | ``ring`` | ``state`` | ``summary``: what kind of state
-    an array is."""
+    """``full`` | ``ring`` | ``state`` | ``summary`` | ``index``: what kind
+    of state an array is."""
     for suffix, kind in ((_RING, "ring"), (_STATE, "state"),
-                         (_SUMMARY, "summary")):
+                         (_SUMMARY, "summary"), (_INDEX, "index")):
         if name.endswith(suffix):
             return kind
     return "full"
@@ -249,7 +280,8 @@ def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         raise ValueError(f"max_len {max_len} is no whole number of chunks "
                          f"of {cfg.summary_chunk} positions")
     # (layers that hold the kind, what its arrays have for positions)
-    stacks = {"full": (("full",), max_len),
+    stacks = {"full": (_ROW_KINDS, max_len),
+              "index": (("index",), max_len),
               "ring": (("window", "eva"), window_ring(cfg, max_len)),
               "state": (("conv",), cfg.d_model),
               "summary": (("eva",), max_len // max(1, cfg.summary_chunk))}
@@ -282,13 +314,14 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "pos_emb must be 'rope'")
     kinds = set(cfg.kinds)
     if len(cfg.kinds) != cfg.n_layers or \
-            kinds - {"full", "window", "conv", "eva"}:
+            kinds - {"full", "window", "conv", "eva", *SPARSE_KINDS}:
         raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
                          f"{cfg.n_layers} of 'full' | 'window' | 'conv' | "
-                         f"'eva'")
+                         f"'eva', or of 'index' | 'shared'")
+    check_kinds(cfg)
     if "conv" in kinds and cfg.conv_kernel < 2:
         raise ValueError("conv layers need conv_kernel of at least 2")
-    if not kinds & {"full", "eva"}:
+    if not kinds & {"full", "eva", "index"}:
         raise NotImplementedError(
             "a model without a full-attention layer or a summary layer (of "
             "window layers only, of conv layers, of both) is not served: "
@@ -548,15 +581,20 @@ def _ring_write_chunk(pos, c: int, ring: int, lane=0, live=None):
 
 
 def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
-                 cache: KVCache, layer_fn):
+                 cache: KVCache, layer_fn, sel=()):
     """THE layer loop of every program that writes a KV cache.
 
     The whole stacked cache (each array ``[L, B, heads, width, rows]``)
     is loop STATE, indexed by the layer counter of its state kind, and the
-    layer weights the scanned input: ``layer_fn(x, lp, arrays, l, kind)
-    -> (x, arrays, load)`` writes its columns into layer ``l`` of its
+    layer weights the scanned input: ``layer_fn(x, lp, arrays, l, kind, sel)
+    -> (x, arrays, load, sel)`` writes its columns into layer ``l`` of its
     kind's arrays in place (``l`` counts the layers of that kind: a full
-    layer's arrays and a window layer's rings are stacked apart).  Passing
+    layer's arrays and a window layer's rings are stacked apart; an
+    indexing and a shared layer share the latents' array and one count).
+    ``sel`` is what ONE LAYER COMPUTES FOR THE LAYERS BEHIND IT, carried
+    beside the rest: of a model with an indexer ``(the chosen positions a
+    query [B, C, rows] bool, the indexing layers so far)``, the second the
+    layer counter of the indexer's keys; of any other model nothing.  Passing
     the cache's layers through the scan as inputs and stacking them as
     outputs instead builds a second cache per call, and leaves a donated
     cache argument nothing to alias to.  The carry is held to the
@@ -570,18 +608,20 @@ def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     row_major = Layout(major_to_minor=tuple(range(5)))
 
     def step(carry, lp, kind):
-        xc, arrs, seen, load = carry
+        xc, arrs, seen, load, sel = carry
         arrs = {n: with_layout_constraint(a, row_major)
                 for n, a in arrs.items()}
-        xc, arrs, load_l = layer_fn(xc, lp, arrs, seen[kind], kind)
+        l = sum(seen[k] for k in SPARSE_KINDS if k in seen) \
+            if kind in SPARSE_KINDS else seen[kind]
+        xc, arrs, load_l, sel = layer_fn(xc, lp, arrs, l, kind, sel)
         return (xc, arrs, dict(seen, **{kind: seen[kind] + 1}),
-                tuple(a + b for a, b in zip(load, load_l)))
+                tuple(a + b for a, b in zip(load, load_l)), sel)
 
     zero = jnp.zeros((), jnp.int32)
-    x, arrays, _, load = scan_layer_runs(
+    x, arrays, _, load, _ = scan_layer_runs(
         cfg, params,
         (x, arrays, dict.fromkeys(sorted(set(cfg.kinds)), zero),
-         (zero,) * 3), step, whole_expert_stacks=True)
+         (zero,) * 3, sel), step, whole_expert_stacks=True)
     return x, arrays, load
 
 
@@ -607,6 +647,16 @@ def _rotators(turn, angles: Dict[str, Any]) -> Dict[str, Any]:
     sin)}``: what `_attend_cached` takes as ``rotate``."""
     return {kind: functools.partial(turn, cos=cos, sin=sin)
             for kind, (cos, sin) in angles.items()}
+
+
+def _key_block(c: int, heads: int, rows: int) -> int:
+    """The block of cached rows a latent layer's queries read at a time (0:
+    all at once), for ``c`` queries of ``heads`` heads over ``rows``
+    positions: blocked where their float32 scores would pass 160 MiB, which
+    no program of a context of a few thousand rows does, and a decode step
+    (one query a slot) does at none."""
+    return sparse_index.key_block(rows) \
+        if c * heads * rows * 4 > 160 << 20 else 0
 
 
 @jax.named_scope("attention")
@@ -650,7 +700,10 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     are keyed by the layer's attention kind (``"full"``, ``"window"``,
     ``"eva"``; a summary layer has ``"summary"`` beside its own: the mask
     over its summary rows, and `_summary_write`'s halves for
-    `_write_summaries`).
+    `_write_summaries`).  An ``"index"`` layer writes its indexer's keys
+    too, scores the rows ``mask[kind]`` lets a query see, and attends the
+    ``index_topk`` best of them, as do the ``"shared"`` layers behind it:
+    the selection IS their mask, a query's own set of columns.
     A conv layer reads its state, and advances it by ``n_new`` [B] tokens
     (None: all C): the row's real tokens, none for a row that stands.
     ``rotate[kind]`` applies the caller's rotary angles at that kind's
@@ -723,9 +776,9 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                 *(m[p:p + 1] for m in masks)))
         return _attn_out(cfg, y, attn.reshape(b, c, h, -1), lp), arrs
 
-    def attend_mla(y, lp, arrs, l, kind):
+    def attend_mla(y, lp, arrs, l, kind, sel):
         # absorbed: the chunk's few queries over the cached latents
-        q_nope, q_rope = mla.queries(
+        q_nope, q_rope, c_q = mla.queries(
             y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
             nope=cfg.qk_nope_head_dim, eps=eps, rotate=rotate[kind])
         new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
@@ -733,20 +786,44 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                           rotate=rotate[kind])
         kv_all = write[kind](arrs["kv"], l, _as_columns(
             new[:, :, None, :], arrs["kv"].dtype))
+        arrs = dict(arrs, kv=kv_all)
+        seen = mask[kind]
+        block = _key_block(c, h, kv_all.shape[-1])
+        if kind == "index":
+            # the new positions' index keys, every visible row scored, the
+            # exact best chosen: this layer's mask and the shared layers'
+            _, li = sel
+            q_i, k_i, w = index_inputs(cfg, y, c_q, lp, rotate[kind])
+            ik_all = write[kind](arrs[_INDEX_ARRAY], li, _as_columns(
+                k_i[:, :, None, :], arrs[_INDEX_ARRAY].dtype))
+            arrs[_INDEX_ARRAY] = ik_all
+            choose = functools.partial(sparse_index.selection_mask,
+                                       topk=cfg.index_topk,
+                                       blocked=bool(block))
+            if lanes is None:
+                chosen = choose(q_i, w, _layer_of(ik_all, li)[:, 0], seen)
+            else:
+                chosen = _by_lane(lanes, choose, lambda p: (
+                    q_i[p:p + 1], w[p:p + 1], _lane_of(ik_all, li, p)[:, 0],
+                    seen[p:p + 1]))
+            sel = (chosen, li + 1)
+        if kind in SPARSE_KINDS:
+            seen = sel[0]
         if lanes is None:
             out = mla.attend_absorbed(
                 q_nope, q_rope, _layer_of(kv_all, l)[:, 0], lp["wkv_b"],
-                lp["wo"], mask[kind])
+                lp["wo"], seen, block)
         else:
             q_abs = mla.absorb(q_nope, q_rope, lp["wkv_b"])
             scale = float(np.sqrt(cfg.qk_nope_head_dim
                                   + cfg.qk_rope_head_dim))
             out = mla.unabsorb(_by_lane(
-                lanes, functools.partial(mla.attend_latents, scale=scale),
+                lanes, functools.partial(mla.attend_latents, scale=scale,
+                                         key_block=block),
                 lambda p: (q_abs[p:p + 1], _lane_of(kv_all, l, p)[:, 0],
-                           mask[kind][p:p + 1])),
+                           seen[p:p + 1])),
                 lp["wkv_b"], lp["wo"], cfg.qk_nope_head_dim)
-        return out, {"kv": kv_all}
+        return out, arrs, sel
 
     def conv(y, lp, arrs, l, kind):
         s_all = arrs[_CONV_STATE]              # [L_conv, B, 1, taps - 1, D]
@@ -756,18 +833,24 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         return delta, dict(arrs, **{_CONV_STATE: _place_state(
             s_all, l, state)})
 
-    attend = attend_mla if cfg.attention == "mla" else attend_mha
     operator = {"conv": conv, "eva": attend_eva}
 
-    def layer(xc, lp, arrs, l, kind):
+    def layer(xc, lp, arrs, l, kind, sel):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        delta, arrs = operator.get(kind, attend)(y, lp, arrs, l, kind)
+        if kind not in operator and cfg.attention == "mla":
+            delta, arrs, sel = attend_mla(y, lp, arrs, l, kind, sel)
+        else:
+            delta, arrs = operator.get(kind, attend_mha)(y, lp, arrs, l,
+                                                         kind)
         xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
-        return xc + _post(cfg, z, lp, "post_mlp_norm"), arrs, load
+        return xc + _post(cfg, z, lp, "post_mlp_norm"), arrs, load, sel
 
-    x, arrays, load = _scan_cached(cfg, params, x, cache, layer)
+    # of a model with an indexer: no position chosen yet, no indexing layer
+    sel = (jnp.zeros((b, c, cache["kv"].shape[-1]), bool),
+           jnp.zeros((), jnp.int32)) if cfg.index_topk else ()
+    x, arrays, load = _scan_cached(cfg, params, x, cache, layer, sel)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return x, arrays, load
 
@@ -828,7 +911,7 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             arrs[name], new.astype(arrs[name].dtype)[None], (l, 0, 0, 0, 0))
             for name, new in zip(_SUM_NAMES, pooled)}
 
-    def layer(h, lp, arrs, l, kind):
+    def layer(h, lp, arrs, l, kind, sel):
         # run the layer for h, re-project for the cache
         y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
         if kind == "conv":     # the state the prompt's last token leaves
@@ -843,10 +926,23 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
                 for n, rows in new.items()})
             if kind == "eva":
                 arrs = dict(arrs, **summaries(arrs, l, lp, *new.values()))
-        h, _ = _layer(cfg, h, lp, angles, kind)
-        return h, arrs, (0, 0, 0)
+        if kind == "index":     # the prompt's index keys, at this
+            # indexing layer's own count
+            k_i = sparse_index.index_keys(
+                y, lp["wi_k"], lp["ik_norm"], lp["ik_norm_b"],
+                rotate=rotate[kind], rope=cfg.rope_dim)
+            arrs = dict(arrs, **{_INDEX_ARRAY: place(
+                arrs[_INDEX_ARRAY], sel[1], _as_columns(
+                    k_i[:, :, None, :], arrs[_INDEX_ARRAY].dtype))})
+        h, _, chosen = _layer(cfg, h, lp, angles, kind,
+                              sel[0] if sel else None)
+        if sel:
+            sel = (chosen, sel[1] + (kind == "index"))
+        return h, arrs, (0, 0, 0), sel
 
-    x, arrays, _ = _scan_cached(cfg, params, x, cache, layer)
+    sel = (jnp.zeros((b, s, s), bool), jnp.zeros((), jnp.int32)) \
+        if cfg.index_topk else ()
+    x, arrays, _ = _scan_cached(cfg, params, x, cache, layer, sel)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return _last_logits(params, x[:, -1], cfg), dict(
         arrays, pos=jnp.asarray(s, jnp.int32))
@@ -942,9 +1038,9 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     # within the chunk, everything before it fully visible)
     with jax.named_scope("attention"):
         mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
-    mask = {"full": mask[None]}
+    mask = dict.fromkeys(_ROW_KINDS, mask[None])
 
-    write = {"full": _full_write_chunk(pos)}
+    write = dict.fromkeys(_ROW_KINDS, _full_write_chunk(pos))
     ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
         mask["window"] = _ring_mask(pos, c, ring, cfg.sliding_window)[None]
@@ -1178,8 +1274,8 @@ def _row_inputs(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
             t[posm][:, :, None, :]
             for t in rotary_angles(max_len, cfg.rope_dim, base)))
     with jax.named_scope("attention"):
-        mask = {"full": jnp.arange(max_len)[None, None, :]
-                <= posm[:, :, None]}
+        mask = dict.fromkeys(_ROW_KINDS, jnp.arange(max_len)[None, None, :]
+                             <= posm[:, :, None])
     if "window" in cfg.kinds:
         mask["window"] = _ring_mask(pos, c, window_ring(cfg, max_len),
                                     cfg.sliding_window)
@@ -1229,7 +1325,7 @@ def _forward_slots(params: Params, token: jnp.ndarray, cache: KVCache,
             return write_columns(c_all, l, cols[..., 0], column)
         return write
 
-    write = {"full": column_writes(pos)}
+    write = dict.fromkeys(_ROW_KINDS, column_writes(pos))
     ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
         # a ring has no end to be clamped onto: position p is column p mod
@@ -1282,8 +1378,8 @@ def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
             return c_all
         return write
 
-    write = {"full": lane_writes(lambda p: _full_write_chunk(
-        pos[p], p, live[p]))}
+    write = dict.fromkeys(_ROW_KINDS, lane_writes(
+        lambda p: _full_write_chunk(pos[p], p, live[p])))
     ring = window_ring(cfg, max_len)
     if "window" in cfg.kinds:
         write["window"] = lane_writes(lambda p: _ring_write_chunk(

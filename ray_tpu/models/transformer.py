@@ -57,6 +57,19 @@ Design (idiomatic JAX, not a torch translation):
     (``v_head_dim``), the rotated share of a head (``rope_fraction``) and
     a scale on the values (``value_scale``).
 
+  * a LATENT-attention model has layer kinds of its own (``"index"`` |
+    ``"shared"``, `SPARSE_KINDS`: learned sparse attention,
+    `ops/sparse_index.py`): an INDEXING layer scores every earlier position
+    with a few small heads of its own (``index_heads`` of
+    ``index_head_dim``) and keeps the ``index_topk`` best; it and the
+    ``"shared"`` layers behind it attend those alone.  So ONE LAYER
+    COMPUTES A VALUE THAT LATER LAYERS CONSUME: the selection rides in the
+    carry of `scan_layer_runs` from an indexing layer to the shared layers
+    behind it, across the boundary of two runs too.  Indexer weights are
+    stacked over the indexing layers only, and a served cache holds the
+    indexer's keys on those layers alone: the fifth of its five kinds of
+    state (`models/generate.py`).
+
   * what a block, the stream and the head are may differ too, each a
     property with the plain model as its default: an RMSNorm that
     multiplies by ``1 + g`` (``norm_unit_offset``), a residual stream held
@@ -81,6 +94,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import latent_attention as mla
+from ..ops import sparse_index
 from ..ops.attention import multi_head_attention
 from ..ops.eva_attention import eva_attention
 from ..ops.flash_attention import FLASH_LSE, FLASH_OUT
@@ -154,8 +168,8 @@ class TransformerConfig:
     embed_scale: float = 1.0          # multiplies the token embedding
     # -- kinds of layer mixed -----------------------------------------------
     layer_kinds: Optional[Tuple[str, ...]] = None  # a layer "window" |
-    #   "full" | "conv", in model order (None → all full); may repeat
-    #   inside a run
+    #   "full" | "conv" | "eva", or of a model with an indexer "index" |
+    #   "shared", in model order (None → all full); may repeat inside a run
     conv_kernel: int = 3              # a conv layer's taps; its state is
     #   the last conv_kernel - 1 inputs of the convolution a sequence
     sliding_window: int = 0           # a window layer's position i sees
@@ -186,6 +200,14 @@ class TransformerConfig:
     #   exactly and every earlier block through ONE pooled key and value a
     #   summary_chunk positions, under one softmax; two weights a layer
     #   more (adaptive_phi, adaptive_mu_k: [kv_heads, head_dim])
+    # -- learned sparse attention (ops/sparse_index.py; latent attention) ----
+    index_heads: int = 0              # an indexing layer's scoring heads
+    index_head_dim: int = 0           # ... their width: ONE key a position
+    #   of this width is what an indexing layer caches beside its latent
+    index_topk: int = 0               # positions a query attends (0: no
+    #   indexer): ``layer_kinds`` is then "index" (scores, chooses, attends
+    #   its choice) | "shared" (attends the choice of the nearest indexing
+    #   layer before it; holds no indexer weights) for every layer
     # -- what a block, the stream and the head may differ in -----------------
     norm_unit_offset: bool = False    # an RMSNorm multiplies by 1 + g
     fp32_residual: bool = False       # the residual stream is float32 (the
@@ -370,7 +392,15 @@ def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
     return sum(n * _run_matmul_params(cfg, run, active)
                for run, n in cfg.layer_runs) + sum(
         4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
-        else _attn_matmul_params(cfg, kind) for kind in cfg.kinds)
+        else _attn_matmul_params(cfg, kind) for kind in cfg.kinds) \
+        + cfg.kinds.count("index") * _indexer_matmul_params(cfg)
+
+
+def _indexer_matmul_params(cfg: TransformerConfig) -> int:
+    """An indexing layer's three projections: queries from the query
+    latent, one key and the head weights from the block's input."""
+    return cfg.q_lora_rank * cfg.index_heads * cfg.index_head_dim \
+        + cfg.d_model * (cfg.index_head_dim + cfg.index_heads)
 
 
 def _attn_flops_dim(cfg: TransformerConfig) -> int:
@@ -394,6 +424,8 @@ def _attended(cfg: TransformerConfig, context_len: float,
         if kind == "eva":   # at most its block, and the chunks before it
             return min(context_len, cfg.sliding_window) \
                 + context_len / cfg.summary_chunk
+        if kind in SPARSE_KINDS:    # the chosen positions
+            return min(context_len, windows * cfg.index_topk)
         return context_len
 
     return sum(rows(kind) for kind in cfg.kinds
@@ -411,7 +443,8 @@ def count_params(cfg: TransformerConfig) -> int:
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
         + (cfg.n_layers - n_conv) * own + n_conv * d * cfg.conv_kernel \
         + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads \
-        + cfg.kinds.count("eva") * 2 * cfg.kv_heads * cfg.head_dim
+        + cfg.kinds.count("eva") * 2 * cfg.kv_heads * cfg.head_dim \
+        + cfg.kinds.count("index") * 2 * cfg.index_head_dim  # the key's norm
     if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
         layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
     emb = cfg.vocab_size * d
@@ -431,7 +464,15 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     attn_factor = 6 if cfg.causal else 12
     attn = attn_factor * _attn_flops_dim(cfg) * _attended(
         cfg, seq_len, 2 if cfg.causal else 1)
+    # an indexing layer's heads meet every position's ONE key (no values)
+    attn += attn_factor // 2 * _index_flops_dim(cfg) * seq_len
     return 6 * n_matmul + attn
+
+
+def _index_flops_dim(cfg: TransformerConfig) -> int:
+    """Width, summed over heads and indexing layers, of the indexer's one
+    product a query a position."""
+    return cfg.kinds.count("index") * cfg.index_heads * cfg.index_head_dim
 
 
 def decode_flops_per_token(cfg: TransformerConfig,
@@ -449,7 +490,8 @@ def decode_flops_per_token(cfg: TransformerConfig,
                                  + cfg.qk_rope_head_dim)
     else:
         per_pos = cfg.n_heads * (cfg.head_dim + cfg.value_dim)
-    return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len)
+    return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len) \
+        + 2 * _index_flops_dim(cfg) * context_len
 
 
 def engine_flops_table(cfg: TransformerConfig, max_len: int) -> dict:
@@ -490,9 +532,9 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     ax: Params = {"attn_norm": ("layers", "embed"),
                   "mlp_norm": ("layers", "embed")}
 
-    def add(name, shape, fan_in, axes, n=L):
-        p[name] = jax.random.normal(next(keys), (n,) + shape, pt) \
-            / math.sqrt(fan_in)
+    def add(name, shape, fan_in, axes, n=L, key=None):
+        p[name] = jax.random.normal(next(keys) if key is None else key,
+                                    (n,) + shape, pt) / math.sqrt(fan_in)
         ax[name] = ("layers",) + axes
 
     if Lc:      # the gated short convolution (ops/short_conv.py)
@@ -511,6 +553,17 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
         add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"), La)
         p["q_norm"], p["kv_norm"] = jnp.ones((La, ql), pt), jnp.ones((La, kl), pt)
         ax["q_norm"] = ax["kv_norm"] = ("layers", None)
+        n_idx = kind_layers(cfg, run, ("index",))
+        if n_idx:       # the indexer: over the indexing layers only
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            kq, kk, kw = jax.random.split(next(keys), 3)    # ONE of the
+            #   run's keys: a model draws its other weights as it did
+            add("wi_q", (ql, hi, di), ql, (None, "heads", "kv"), n_idx, kq)
+            add("wi_k", (d, di), d, ("embed", None), n_idx, kk)
+            add("wi_w", (d, hi), d, ("embed", "heads"), n_idx, kw)
+            p["ik_norm"] = jnp.ones((n_idx, di), pt)
+            p["ik_norm_b"] = jnp.zeros((n_idx, di), pt)
+            ax["ik_norm"] = ax["ik_norm_b"] = ("layers", None)
     elif La and cfg.attention == "mha":
         add("wq", (d, h, hd), d, ("embed", "heads", "kv"), La)
         for kind in ("full", "window") if cfg.split_kv else ("full",):
@@ -581,6 +634,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig
     logical-axis tuples.  Each run of the layer pattern is one stacked
     tree with a leading "layers" axis (pipeline-shardable)."""
     d, pt = cfg.d_model, cfg.param_dtype
+    check_kinds(cfg)
     keys = iter(jax.random.split(key, 16))
     params: Params = {
         "embed": {"tok": jax.random.normal(next(keys), (cfg.vocab_size, d),
@@ -782,12 +836,15 @@ def _post(cfg: TransformerConfig, delta: jnp.ndarray, lp: Params,
 
 
 def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
-           angles, kind: str = "full") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One block whose operator is ``kind``'s -> (x, router_aux_loss);
-    ``angles`` the (cos, sin) of each kind that rotates (`rope_tables`)."""
+           angles, kind: str = "full", sel=None):
+    """One block whose operator is ``kind``'s -> (x, router_aux_loss, sel);
+    ``angles`` the (cos, sin) of each kind that rotates (`rope_tables`).
+    ``sel`` [b, s, s] bool (None: the model has no indexer) is the
+    selection the layer loop carries: an ``"index"`` layer makes a new one
+    and attends it, a ``"shared"`` layer attends the one it is handed."""
     cos, sin = angles.get(kind, (None, None))
     if kind == "conv":
-        return _conv_layer(cfg, x, lp)
+        return _conv_layer(cfg, x, lp) + (sel,)
     norm = functools.partial(_norm, cfg)
     if cfg.norm_remat:
         norm = jax.checkpoint(
@@ -798,15 +855,22 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
         # nothing is cached here: the plain form, every head's keys and
         # values built from the latents
         rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
-        q_nope, q_rope = mla.queries(
+        q_nope, q_rope, c_q = mla.queries(
             y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
             nope=cfg.qk_nope_head_dim, eps=norm_eps(cfg), rotate=rotate)
         latent = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
                              kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
                              rotate=rotate)
+        if kind == "index":
+            s = y.shape[1]
+            q_i, k_i, w = index_inputs(cfg, y, c_q, lp, rotate)
+            sel = sparse_index.selection_mask(
+                q_i, w, jnp.swapaxes(k_i, 1, 2),
+                jnp.tril(jnp.ones((s, s), bool))[None], cfg.index_topk)
         x = x + _post(cfg, mla.attend_plain(
             q_nope, q_rope, latent, lp["wkv_b"], lp["wo"],
-            causal=cfg.causal, impl=cfg.attention_impl), lp,
+            causal=cfg.causal, impl=cfg.attention_impl,
+            selection=sel if kind in SPARSE_KINDS else None), lp,
             "post_attn_norm")
     else:
         q, k, v = _qkv(cfg, y, lp, functools.partial(
@@ -827,7 +891,7 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
 
     y = norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
     z, aux, _ = _ffn(cfg, y, lp)
-    return x + _post(cfg, z, lp, "post_mlp_norm"), aux
+    return x + _post(cfg, z, lp, "post_mlp_norm"), aux, sel
 
 
 @jax.named_scope("ffn")
@@ -890,6 +954,7 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Everything up to (and including) the final norm:
     tokens [b, s] → (hidden [b, s, d] in cfg.dtype, mean router aux)."""
+    check_kinds(cfg)
     x = _embed(params, tokens, cfg)
     angles = rope_tables(cfg, lambda base: rotary_angles(
         tokens.shape[1], cfg.rope_dim, base))
@@ -900,9 +965,9 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         layer = functools.partial(_layer, cfg, kind=kind)
         if policy is not None:
             layer = jax.checkpoint(layer, static_argnums=(), policy=policy)
-        h, aux = carry
-        h, aux_l = layer(h, lp, angles)
-        return h, aux + aux_l
+        h, aux, sel = carry
+        h, aux_l, sel = layer(h, lp, angles, sel=sel)
+        return h, aux + aux_l, sel
 
     if cfg.pp_stages > 1:
         from ..parallel.pipeline import (microbatch, pipeline_apply,
@@ -910,7 +975,7 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         if cfg.n_layers % cfg.pp_stages:
             raise ValueError(f"{cfg.n_layers} layers not divisible by "
                              f"{cfg.pp_stages} pipeline stages")
-        if len(cfg.layer_segments) > 1:
+        if len(cfg.layer_segments) > 1 or cfg.index_topk:
             raise NotImplementedError(
                 "a pipeline over a layer pattern of more than one run "
                 "or kind (leading dense layers, mixed layer_kinds) is not "
@@ -918,8 +983,8 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         n_micro = cfg.pp_microbatches or cfg.pp_stages
 
         def stage_fn(slab, state):
-            out, _ = jax.lax.scan(lambda c, lp: (body(c, lp), None), state,
-                                  slab)
+            out, _ = jax.lax.scan(
+                lambda c, lp: (body(c + (None,), lp)[:2], None), state, slab)
             return out
 
         x_mb = (microbatch(x, n_micro),
@@ -930,8 +995,10 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
         x = unmicrobatch(h_mb)
         aux = aux_mb.sum() / (n_micro * cfg.n_layers)
     else:
-        x, aux = scan_layer_runs(
-            cfg, params, (x, jnp.zeros((), jnp.float32)), body)
+        b, s = tokens.shape     # the selection an indexing layer hands on
+        sel = jnp.zeros((b, s, s), bool) if cfg.index_topk else None
+        x, aux, _ = scan_layer_runs(
+            cfg, params, (x, jnp.zeros((), jnp.float32), sel), body)
         aux = aux / cfg.n_layers
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return x, aux
@@ -1189,8 +1256,50 @@ _ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
 _WINDOW_KV = ("wk_win", "wv_win")
 #: a chunk's pooling, of the layers that attend through summaries
 _EVA_KEYS = ("adaptive_phi", "adaptive_mu_k")
+#: an indexer's weights, of the indexing layers alone
+_INDEX_KEYS = ("wi_q", "wi_k", "wi_w", "ik_norm", "ik_norm_b")
+#: the kinds of layer of a latent-attention model with an indexer: one that
+#: scores and chooses, one that attends the choice of the last such before it
+SPARSE_KINDS = ("index", "shared")
 #: the kinds of layer whose operator is attention
-ATTENTION_KINDS = ("full", "window", "eva")
+ATTENTION_KINDS = ("full", "window", "eva") + SPARSE_KINDS
+
+
+def check_kinds(cfg: TransformerConfig) -> None:
+    """What an indexer needs of a configuration, refused with a message
+    where it lacks it."""
+    sparse = set(cfg.kinds) & set(SPARSE_KINDS)
+    if not sparse and not cfg.index_topk:
+        return
+    if not sparse or set(cfg.kinds) - sparse or cfg.attention != "mla" \
+            or min(cfg.index_topk, cfg.index_heads, cfg.index_head_dim) < 1 \
+            or cfg.index_head_dim < cfg.qk_rope_head_dim:
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r} with index_topk "
+            f"{cfg.index_topk}: an indexer is a latent-attention model's "
+            f"(attention='mla'), every layer of which is 'index' or 'shared' "
+            f"and which states index_topk, index_heads and index_head_dim "
+            f"(at least the rotary part's {cfg.qk_rope_head_dim})")
+    if cfg.kinds[0] != "index":
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r}: the first layer is 'shared', "
+            f"and no indexing layer stands before it whose choice it could "
+            f"attend")
+
+
+def index_inputs(cfg: TransformerConfig, y: jnp.ndarray, c_q: jnp.ndarray,
+                 lp: Params, rotate):
+    """An indexing layer's own projections of the normed input ``y`` [b, s,
+    d] and the query latent ``c_q`` -> (qI [b, s, heads, dim], kI [b, s,
+    dim], w [b, s, heads] float32); the rotary part is the latent
+    attention's (the first `rope_dim` dims, the same angles)."""
+    rope = cfg.rope_dim
+    return (sparse_index.index_queries(c_q, lp["wi_q"], rotate=rotate,
+                                       rope=rope),
+            sparse_index.index_keys(y, lp["wi_k"], lp["ik_norm"],
+                                    lp["ik_norm_b"], rotate=rotate,
+                                    rope=rope),
+            sparse_index.head_weights(y, lp["wi_w"], cfg.index_head_dim))
 
 
 def kv_weight_names(cfg: TransformerConfig, kind: str) -> Tuple[str, str]:
@@ -1214,11 +1323,15 @@ def stack_kinds(cfg: TransformerConfig, key: str
         return ("window",)
     if key in _EVA_KEYS:
         return ("eva",)
+    if key in _INDEX_KEYS:
+        return ("index",)
     if key in _ATTN_KEYS:
         if cfg.split_kv and key in ("wk", "wv"):
             return ("full",)
-        # (a summary layer is named only by a model that has one)
-        return ATTENTION_KINDS if "eva" in cfg.kinds else ("full", "window")
+        # (a summary, indexing or shared layer is named only by a model
+        # that has one)
+        return ("full", "window") + tuple(
+            k for k in ATTENTION_KINDS[2:] if k in cfg.kinds)
     return None
 
 

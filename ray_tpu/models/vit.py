@@ -155,7 +155,7 @@ def vit_forward(params: Params, images: jnp.ndarray,
         layer = jax.checkpoint(layer, policy=policy)
 
     def body(h, lp):
-        h, _aux = layer(h, lp, {})       # learned positions: no rotary
+        h, _aux, _sel = layer(h, lp, {})    # learned positions: no rotary
         return h, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])

@@ -14,6 +14,8 @@ Two forms of the same function of (queries, latents):
 * `attend_plain` builds every head's keys and values from the latents and
   runs ordinary attention: the form for training and for a whole-sequence
   forward, where nothing is cached.
+  A model with an indexer (`ops/sparse_index.py`) hands it a
+  ``selection``: the positions each query attends, in the mask's place.
 * `attend_absorbed` never builds them.  ``q_nope . (c W_k) = (q_nope W_k^T)
   . c`` and ``(p c) W_v = p (c W_v)``: the key up-projection is folded into
   the query and the value up-projection applied after the probabilities,
@@ -29,26 +31,30 @@ layer is ``[batch, kv_lora + rope, positions]``, positions last, as
 from __future__ import annotations
 
 import math
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .attention import multi_head_attention
 from .norms import rmsnorm
+from .sparse_index import rows_seen
 
 Rotate = Callable[[jnp.ndarray], jnp.ndarray]   # [b, s, heads, rope] -> same
 
 
 @jax.named_scope("projections")
 def queries(y: jnp.ndarray, wq_a, q_norm, wq_b, *, nope: int, eps: float,
-            rotate: Rotate) -> Tuple[jnp.ndarray, jnp.ndarray]:
+            rotate: Rotate
+            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Normed input ``y`` [b, s, d] -> (q_nope [b, s, h, nope], q_rope
-    [b, s, h, rope] already rotated)."""
+    [b, s, h, rope] already rotated, and the query latent ``c_q`` [b, s,
+    q_lora] they were made from: an indexer's queries come from it too,
+    `ops/sparse_index.py`)."""
     dt = y.dtype
     c_q = rmsnorm(jnp.einsum("bsd,dr->bsr", y, wq_a.astype(dt)), q_norm, eps)
     q = jnp.einsum("bsr,rhk->bshk", c_q, wq_b.astype(dt))
-    return q[..., :nope], rotate(q[..., nope:])
+    return q[..., :nope], rotate(q[..., nope:]), c_q
 
 
 @jax.named_scope("projections")
@@ -67,9 +73,12 @@ def latents(y: jnp.ndarray, wkv_a, kv_norm, *, kv_lora: int, eps: float,
 @jax.named_scope("attention")
 def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                  latent: jnp.ndarray, wkv_b, wo, *, causal: bool = True,
-                 impl: str = "auto") -> jnp.ndarray:
+                 impl: str = "auto",
+                 selection: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Every head's keys and values built from ``latent`` [b, s, kv_lora +
-    rope], ordinary attention over them -> [b, s, d]."""
+    rope], ordinary attention over them -> [b, s, d].  ``selection`` [b, s,
+    s] bool (a model with an indexer): the positions each query attends,
+    which then IS the mask (a selection is causal by how it was made)."""
     dt = q_nope.dtype
     nope, rope = q_nope.shape[-1], q_rope.shape[-1]
     kv_lora, h = wkv_b.shape[0], wkv_b.shape[1]
@@ -83,16 +92,24 @@ def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     v = kv[..., nope:]
     if v.shape[-1] != q.shape[-1]:
         impl = "reference"      # the flash kernel has one head size
-    attn = multi_head_attention(q, k, v, causal=causal, impl=impl,
-                                sm_scale=1.0 / math.sqrt(nope + rope))
+    if selection is not None:
+        scores = jnp.einsum("bshk,bthk->bhst", q, k,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(nope + rope)
+        probs = jax.nn.softmax(
+            jnp.where(selection[:, None], scores, -1e30), axis=-1)
+        attn = jnp.einsum("bhst,bthv->bshv", probs.astype(dt), v)
+    else:
+        attn = multi_head_attention(q, k, v, causal=causal, impl=impl,
+                                    sm_scale=1.0 / math.sqrt(nope + rope))
     with jax.named_scope("projections"):
         return jnp.einsum("bshk,hkd->bsd", attn, wo.astype(dt))
 
 
 @jax.named_scope("attention")
 def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
-                    cached: jnp.ndarray, wkv_b, wo, mask: jnp.ndarray
-                    ) -> jnp.ndarray:
+                    cached: jnp.ndarray, wkv_b, wo, mask: jnp.ndarray,
+                    key_block: int = 0) -> jnp.ndarray:
     """``cached`` [b, kv_lora + rope, T] is one layer of a latent cache,
     ``mask`` [b|1, s, T] which positions each query may see -> [b, s, d].
     The probabilities meet all ``kv_lora + rope`` cached rows (the rotary
@@ -102,7 +119,7 @@ def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     read weights: `absorb`, `attend_latents`, `unabsorb`."""
     nope = q_nope.shape[-1]
     o_lat = attend_latents(absorb(q_nope, q_rope, wkv_b), cached, mask,
-                           math.sqrt(nope + q_rope.shape[-1]))
+                           math.sqrt(nope + q_rope.shape[-1]), key_block)
     return unabsorb(o_lat, wkv_b, wo, nope)
 
 
@@ -118,10 +135,17 @@ def absorb(q_nope: jnp.ndarray, q_rope: jnp.ndarray, wkv_b) -> jnp.ndarray:
 
 @jax.named_scope("attention")
 def attend_latents(q_abs: jnp.ndarray, cached: jnp.ndarray,
-                   mask: jnp.ndarray, scale: float) -> jnp.ndarray:
+                   mask: jnp.ndarray, scale: float,
+                   key_block: int = 0) -> jnp.ndarray:
     """Absorbed queries [b, s, h, kv_lora + rope] over ``cached`` [b, kv_lora
     + rope, T] under ``mask``, scores over ``scale`` -> [b, s, h, kv_lora +
-    rope]: the part that reads the cache, and no weight."""
+    rope]: the part that reads the cache, and no weight.  ``key_block`` (a
+    divisor of T; 0: all rows at once): the rows are read so many at a time
+    under a running softmax, and NO BLOCK PAST THE LAST ROW ANY QUERY MAY
+    SEE (`_attend_blocks`): a chunk's float32 scores of 64 heads over 33 k
+    rows would be 1.1 GB, most of them of rows its mask hides."""
+    if key_block:
+        return _attend_blocks(q_abs, cached, mask, scale, key_block)
     dt = q_abs.dtype
     scores = jnp.einsum("bshr,brt->bsht", q_abs, cached.astype(dt),
                         preferred_element_type=jnp.float32)
@@ -129,6 +153,41 @@ def attend_latents(q_abs: jnp.ndarray, cached: jnp.ndarray,
     scores = jnp.where(mask[:, :, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bsht,brt->bshr", probs.astype(dt), cached.astype(dt))
+
+
+def _attend_blocks(q_abs, cached, mask, scale: float, block: int):
+    """`attend_latents` a block of ``block`` cached rows at a time: the
+    softmax's maximum and sum run along (each block's probabilities are
+    taken against the maximum so far, and what was summed before is scaled
+    down when it rises), and the loop ends with the last block that holds a
+    row some query may see."""
+    dt = q_abs.dtype
+    b, s, h, r = q_abs.shape
+
+    def some_rows(j, carry):
+        top, total, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(cached, j * block, block,
+                                            axis=2).astype(dt)
+        m = jax.lax.dynamic_slice_in_dim(mask, j * block, block, axis=2)
+        scores = jnp.einsum("bshr,brt->bsht", q_abs, rows,
+                            preferred_element_type=jnp.float32) / scale
+        scores = jnp.where(m[:, :, None, :], scores, -1e30)
+        new_top = jnp.maximum(top, scores.max(-1))
+        # (a hidden score stays at -1e30: it weighs 0 once a real one is in)
+        p = jnp.exp(scores - new_top[..., None])
+        p = jnp.where(m[:, :, None, :], p, 0.0)
+        fade = jnp.exp(top - new_top)
+        return (new_top, total * fade + p.sum(-1),
+                acc * fade[..., None] + jnp.einsum(
+                    "bsht,brt->bshr", p.astype(dt), rows,
+                    preferred_element_type=jnp.float32))
+
+    top, total, acc = jax.lax.fori_loop(
+        0, (rows_seen(mask) + block - 1) // block, some_rows,
+        (jnp.full((b, s, h), -1e30, jnp.float32),
+         jnp.zeros((b, s, h), jnp.float32),
+         jnp.zeros((b, s, h, r), jnp.float32)))
+    return (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dt)
 
 
 @jax.named_scope("attention")

@@ -80,9 +80,6 @@ def _shutdown_engines() -> None:
     for eng in list(_ENGINES):
         try:
             eng.shutdown()
-            t = eng._thread
-            if t is not None and t.is_alive():
-                t.join(timeout=5.0)
         except Exception:
             pass
 
@@ -387,6 +384,10 @@ class ContinuousBatchingEngine:
         self._eva_layers = cfg.kinds.count("eva")
         self._block, self._chunk_rows = cfg.sliding_window, \
             cfg.summary_chunk
+        # of a model whose layers attend the `index_topk` positions an
+        # indexer chose, the layers that run the indexer: each reads ONE
+        # key of every position at or before a query
+        self._index_layers = cfg.kinds.count("index")
         self._cache = None            # allocated lazily on first start
         self._shapes: set = set()     # distinct compiled program shapes
         # ONE lock.  `_cond` is what the engine thread and the callers in
@@ -436,7 +437,8 @@ class ContinuousBatchingEngine:
         # and the columns the steps WROTE beside the device calls that
         # wrote them (one an array a layer where the kernel engages)
         self.rows = dict.fromkeys(
-            ("steps",) + self._ROW_SUMS + self._WRITE_SUMS, 0)
+            ("steps",) + self._ROW_SUMS + self._INDEX_SUMS
+            + self._WRITE_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
         from ..models.generate import position_bytes
         self._row_bytes = position_bytes(cfg)
@@ -709,7 +711,8 @@ class ContinuousBatchingEngine:
     def _cache_stats(self) -> Dict[str, int]:
         """Bytes of the slot cache by state kind (``bytes_full``: the
         arrays that hold ``max_len`` rows a slot; ``bytes_ring``: the
-        window layers' rings; ``bytes_state``: the conv layers' states),
+        window layers' rings; ``bytes_state``: the conv layers' states;
+        ``bytes_index``: an indexer's keys, where the model has one),
         what ONE further position of a slot costs (the full arrays' bytes
         a row: a ring and a state grow with nothing), and the rows and
         bytes the decode steps read (`_ROW_SUMS`) and the columns they wrote
@@ -719,7 +722,8 @@ class ContinuousBatchingEngine:
         return {"bytes": sum(kinds.values()),
                 **{"bytes_" + kind: n for kind, n in kinds.items()},
                 "bytes_per_position":
-                    (kinds["full"] + kinds.get("summary", 0))
+                    (kinds["full"] + kinds.get("summary", 0)
+                     + kinds.get("index", 0))
                     // (self.ecfg.max_slots * self.max_len),
                 **self.rows}
 
@@ -778,9 +782,16 @@ class ContinuousBatchingEngine:
             return self._live_locked()
 
     def shutdown(self) -> None:
+        """Stop the loop and, from any thread but the loop's own, wait (at
+        most 5 s) for it to end: what the engine held on the device is
+        released by then (`_thread_main`), so a caller that loads new
+        weights next never holds two models' at once."""
         with self._cond:
             self._shutdown = True
             self._wake_all_locked()
+        t = self._thread
+        if t is not None and t is not threading.current_thread():
+            t.join(timeout=5.0)
 
     def _wake_all_locked(self) -> None:
         """The loop, the callers in `start` and every session's caller
@@ -1369,7 +1380,8 @@ class ContinuousBatchingEngine:
         if key != self._active_key:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
-        rows = self._rows_of(batch) + self._writes_a_step
+        rows = self._rows_of(batch) + self._index_rows_of(batch) \
+            + self._writes_a_step
         flight = self._flight
         with self._cond:
             for s in batch:
@@ -1432,6 +1444,9 @@ class ContinuousBatchingEngine:
     _ROW_SUMS = ("rows_read", "rows_if_full", "bytes_read",
                  "bytes_if_uniform", "summary_rows_read",
                  "summary_bytes_read")
+    #: ... and what `_index_rows_of` does: the index keys an indexer scored
+    #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
+    _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
     #: ... and beside them what a step WRITES, whatever its positions
     #: (`models.generate.column_write_counts`): a column a slot, live or
     #: not, a layer of every array that holds positions, and the device
@@ -1453,7 +1468,14 @@ class ContinuousBatchingEngine:
         ring rows of its own block and a summary row for each of the ``(t
         // block) * block / chunk`` chunks before it, where a full layer
         would read a row a position: the last two sums are those summary
-        rows and their bytes."""
+        rows and their bytes.  A layer under an INDEXER's choice (every
+        layer of a model that has one) attends the ``min(t + 1,
+        index_topk)`` latents chosen, not ``t + 1``; what the choice costs
+        is counted beside them: each indexing layer reads ONE index key of
+        every position at or before the query (``index_rows_read``; their
+        bytes are among ``bytes_read`` too)."""
+        if self.cfg.index_topk:     # every layer is under the choice
+            return self._chosen_rows_of(batch)
         eva = self._eva_layers
         full = self.cfg.n_layers - self._window_layers - self._conv_layers \
             - eva
@@ -1479,13 +1501,35 @@ class ContinuousBatchingEngine:
         return (rows, self.cfg.n_layers * depth, nbytes,
                 self.cfg.n_layers * depth * widest, pooled, pooled_bytes)
 
+    def _chosen_rows_of(self, batch) -> Tuple[int, ...]:
+        """`_rows_of` for a model with an indexer (all its layers are
+        indexing or shared ones, `models.transformer.check_kinds`)."""
+        layers, per = self.cfg.n_layers, self._row_bytes["full"]
+        depth = sum(s.pos + 1 for s in batch)
+        chosen = layers * sum(min(s.pos + 1, self.cfg.index_topk)
+                              for s in batch)
+        return (chosen, layers * depth,
+                chosen * per
+                + self._index_layers * depth * self._row_bytes["index"],
+                layers * depth * per, 0, 0)
+
+    def _index_rows_of(self, batch) -> Tuple[int, int]:
+        """`_INDEX_SUMS` of a decode step about to be dispatched: each
+        indexing layer scores ONE index key of every position at or before
+        a live slot's query; zeros for a model without an indexer."""
+        if not self.cfg.index_topk:
+            return (0, 0)
+        scored = self._index_layers * sum(s.pos + 1 for s in batch)
+        return scored, scored * self._row_bytes["index"]
+
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
         """A read step's `_rows_of` and column writes into the counters,
         and the sums since the last `cache:rows` span into the next when
         due."""
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
-            for k, n in zip(self._ROW_SUMS + self._WRITE_SUMS, rows):
+            for k, n in zip(self._ROW_SUMS + self._INDEX_SUMS
+                            + self._WRITE_SUMS, rows):
                 self.rows[k] += n
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,

@@ -334,7 +334,9 @@ MODEL_PARTS = ("embed", "norm", "projections", "attention", "cache_write",
 # A scope may stand INSIDE a part, for a reader that wants it apart (the part
 # counts it too: the parts still add up): ``summary``, a summary layer's
 # pooling of a chunk and the write of its row (`models/generate.py`
-# `_write_summaries`), inside ``cache_write``.  Not a part of its own while
+# `_write_summaries`), inside ``cache_write``; ``indexer``, what learned
+# sparse attention adds to a layer (`ops/sparse_index.py`: index projections,
+# scores, the choice), inside ``attention``.  Not parts of their own while
 # the benchmark's list (`perfbench/parts.py` ``PARTS``, held equal to
 # `MODEL_PARTS` by its tests) has ten.
 

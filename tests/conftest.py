@@ -51,7 +51,9 @@ import pytest  # noqa: E402
 #: run of PR 44 on the CPU: 788 down to 25), longest first.  They go to the
 #: head of the run in this order (`pytest_collection_modifyitems`); every
 #: other file, none over 25 s, follows where it was.  A new file of
-#: compile-heavy model tests belongs here, by its weight.  `test_scale.py`
+#: compile-heavy model tests belongs here, by its weight (PR 46's two by
+#: their times alone: `test_sparse_index.py` 142 s,
+#: `test_perfbench_family_glm_moe_dsa.py` 117 s).  `test_scale.py`
 #: (230-290 s) stands later than its weight: its floor on tasks a second
 #: (400; 480 read beside the runtime's own tests, 365 and 378 beside five
 #: workers compiling) wants the light end of the run, where it still ends
@@ -60,7 +62,8 @@ _LONGEST_FIRST = (
     "test_perfbench_family_afmoe.py", "test_perfbench_family_lfm2_moe.py",
     "test_chip_compile.py", "test_perfbench_family_glm4_moe_lite.py",
     "test_ops_models.py", "test_dqn_sac.py", "test_examples.py",
-    "test_perfbench_rehearsal.py",
+    "test_perfbench_rehearsal.py", "test_sparse_index.py",
+    "test_perfbench_family_glm_moe_dsa.py",
     "test_perfbench_family_mimo_v2_flash.py", "test_mixed_kv_heads.py",
     "test_serve_decode_engine.py", "test_prefill_padded_tail.py",
     "test_window_ring.py", "test_short_conv_state.py", "test_gbdt.py",

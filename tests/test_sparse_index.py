@@ -1,0 +1,546 @@
+"""Learned sparse attention (`ops/sparse_index.py`) and the fifth kind of
+cache state (`models/generate.py`): the op against a NumPy statement of its
+equations (scores, ties, contexts shorter than ``index_topk``); the plain and
+the absorbed form under one selection; every cached program (whole-prompt
+prefill, chunks, lanes with a lane that stands, slots at depths of their own)
+and the engine against the full `forward` at contexts several times
+``index_topk``; stale rows past a slot's ``pos`` that are never chosen; the
+prefix reuse that copies the index keys; a layer pattern whose shared layers
+cross the boundary of the dense and the expert run; three planted faults that
+each FAIL; and what a configuration is refused for.
+
+The model is the rehearsal's ``tiny-glm-moe-dsa`` in float32 (an indexer of 2
+heads of 16 that keeps 8 positions; layer 0 dense and indexing, 1 and 2
+expert layers that share its choice, 3 indexing, 4 shared; 4 of 8 experts
+held).  The plain REFERENCE's agreement is
+tests/benchmark/test_perfbench_family_glm_moe_dsa.py's.
+"""
+
+import dataclasses
+import functools
+import json
+import os
+import threading
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import manifest as mf
+from perfbench.tools import rehearse
+from ray_tpu.models import (TransformerConfig, cache_gather_slot,
+                            cache_insert_slot, decode_step_slots, forward,
+                            init_kv_cache, init_params, init_slot_cache,
+                            lm_loss, prefill, prefill_chunk_jit,
+                            prefill_lanes_jit)
+from ray_tpu.models.generate import (_state_kind, cache_bytes, cache_rows,
+                                     position_bytes, prefill_chunk_step,
+                                     prefill_lanes_step)
+from ray_tpu.models.transformer import (count_params, decode_flops_per_token,
+                                        stack_kinds)
+from ray_tpu.ops import latent_attention as mla
+from ray_tpu.ops import sparse_index
+from ray_tpu.serve.decode_session import ContinuousBatchingEngine
+
+T, MAX_LEN, CHUNK, TOPK = 100, 128, 8, 8
+TOL = dict(atol=3e-4, rtol=0)
+
+
+@pytest.fixture(scope="module")
+def world():
+    with open(os.path.join(mf.ROOT, rehearse.REHEARSAL, "configs",
+                           "tiny-glm-moe-dsa.json")) as f:
+        c = json.load(f)
+    model = mf.family_of(c).model
+    cfg = dataclasses.replace(model.model_config(c, "serve"),
+                              dtype=jnp.float32, param_dtype=jnp.float32,
+                              remat=False)
+    params = jax.jit(lambda k: model.make(k, c, jnp.float32))(
+        jax.random.PRNGKey(7))
+    toks = model.tokens(jax.random.PRNGKey(8), (2, T), c)
+    want = jax.jit(functools.partial(forward, cfg=cfg))(params, toks)
+    return types.SimpleNamespace(
+        c=c, cfg=cfg, params=params, toks=toks, want=np.asarray(want),
+        step=jax.jit(functools.partial(decode_step_slots, cfg=cfg)))
+
+
+# ------------------------------------------------------------------ the op
+
+def _numpy_choice(scores, allowed, topk):
+    """S_t by a full stable sort, a row at a time."""
+    out = np.zeros(scores.shape, bool)
+    for i, (row, ok) in enumerate(zip(scores, allowed)):
+        order = np.argsort(np.where(ok, -row, np.inf), kind="stable")
+        out[i, order[:topk]] = True
+    return out & allowed
+
+
+def test_scores_and_choice_are_the_numpy_statement():
+    rng = np.random.default_rng(0)
+    b, s, h, d, t = 2, 24, 3, 16, 60
+    q = rng.standard_normal((b, s, h, d)).astype(np.float32)
+    k = rng.standard_normal((b, d, t)).astype(np.float32)
+    w = rng.standard_normal((b, s, h)).astype(np.float32)
+    want = np.einsum("bsht,bsh->bst", np.maximum(
+        np.einsum("bshk,bkt->bsht", q, k), 0), w)
+    got = np.asarray(sparse_index.index_scores(*map(jnp.asarray, (q, w, k))))
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    # queries at positions 36 .. 59: each sees what lies at or before it
+    allowed = np.arange(t)[None, None, :] <= (36 + np.arange(s))[None, :, None]
+    allowed = np.broadcast_to(allowed, (b, s, t))
+    for topk in (1, 7, 16, 60, 200):
+        mask = np.asarray(sparse_index.select(jnp.asarray(got), jnp.asarray(
+            allowed), topk))
+        np.testing.assert_array_equal(mask.reshape(-1, t), _numpy_choice(
+            got.reshape(-1, t), allowed.reshape(-1, t), topk))
+        assert (mask.sum(-1) == np.minimum(allowed.sum(-1), topk)).all()
+
+
+@pytest.mark.parametrize("levels", [2, 3, 5])
+def test_equal_scores_go_to_the_earlier_position(levels):
+    """Scores of a few distinct values (zeros of both signs among them):
+    most of a choice is decided among equals."""
+    rng = np.random.default_rng(levels)
+    scores = rng.integers(0, levels, (40, 90)).astype(np.float32) - 1.0
+    scores[scores == 0] *= rng.choice([-1.0, 1.0], (scores == 0).sum())
+    allowed = np.arange(90)[None, :] <= rng.integers(0, 90, (40, 1))
+    for topk in (4, 16, 33):
+        got = np.asarray(sparse_index.select(
+            jnp.asarray(scores), jnp.asarray(allowed), topk))
+        np.testing.assert_array_equal(
+            got, _numpy_choice(scores, allowed, topk))
+
+
+def test_a_context_within_topk_is_causal_full_attention(world):
+    """For t < index_topk every layer attends all it may see: a model whose
+    indexer keeps more positions than the sequence has is the model with
+    every row attended."""
+    w = world
+    wide = dataclasses.replace(w.cfg, index_topk=T)
+    wider = dataclasses.replace(w.cfg, index_topk=4 * T)
+    a, b = (np.asarray(forward(w.params, w.toks, cfg))
+            for cfg in (wide, wider))
+    np.testing.assert_allclose(a, b, **TOL)
+    # ... and the first TOPK positions of the model itself are those
+    np.testing.assert_allclose(w.want[:, :TOPK], a[:, :TOPK], **TOL)
+    assert np.abs(w.want[:, 4 * TOPK:] - a[:, 4 * TOPK:]).max() > 0.05
+
+
+def test_blocked_reads_are_the_dense_ones():
+    """Index scores and latent attention a block of cached rows at a time,
+    no block past the last row a query may see."""
+    rng = np.random.default_rng(3)
+    b, s, h, r, t = 1, 5, 4, 24, 384
+    assert sparse_index.key_block(t) == 128 and not sparse_index.key_block(
+        128) and not sparse_index.key_block(100)
+    q = jnp.asarray(rng.standard_normal((b, s, h, r)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((b, r, t)), jnp.float32)
+    wts = jnp.asarray(rng.standard_normal((b, s, h)), jnp.float32)
+    for first in (0, 120, 250):         # 1, 1-2 and 2-3 blocks are read
+        mask = jnp.arange(t)[None, None, :] <= (first + jnp.arange(s))[
+            None, :, None]
+        np.testing.assert_allclose(
+            mla.attend_latents(q, rows, mask, 3.0, key_block=128),
+            mla.attend_latents(q, rows, mask, 3.0), atol=2e-5)
+        seen = int(sparse_index.rows_seen(mask))
+        assert seen == first + s
+        dense = sparse_index.index_scores(q, wts, rows)
+        blocked = np.asarray(sparse_index.index_scores(q, wts, rows, seen))
+        upto = -(-seen // 128) * 128
+        np.testing.assert_allclose(blocked[..., :upto], dense[..., :upto],
+                                   atol=2e-5)
+        assert not blocked[..., upto:].any()
+        np.testing.assert_array_equal(
+            sparse_index.selection_mask(q, wts, rows, mask, 16, blocked=True),
+            sparse_index.selection_mask(q, wts, rows, mask, 16))
+
+
+# ------------------------------------------------- the model and its cache
+
+def test_pattern_weights_and_counts(world):
+    cfg, params = world.cfg, world.params
+    assert cfg.kinds == ("index", "shared", "shared", "index", "shared")
+    # the shared layers 1 and 2 are EXPERT layers behind the dense layer 0:
+    # its choice crosses the boundary of the two runs
+    assert cfg.layer_segments == (
+        ("dense_layers", 0, 1, "index"), ("layers", 0, 2, "shared"),
+        ("layers", 2, 1, "index"), ("layers", 3, 1, "shared"))
+    # an indexer's weights over the indexing layers alone
+    for run, n in (("dense_layers", 1), ("layers", 1)):
+        assert params[run]["wi_q"].shape == (n, 24, 2, 16)
+        assert params[run]["wi_k"].shape == (n, 64, 16)
+        assert params[run]["wi_w"].shape == (n, 64, 2)
+        assert params[run]["ik_norm"].shape == (n, 16)
+    assert params["layers"]["wq_a"].shape[0] == 4
+    assert stack_kinds(cfg, "wi_q") == ("index",)
+    assert stack_kinds(cfg, "wq_a") == ("full", "window", "index", "shared")
+    made, _ = init_params(jax.random.PRNGKey(0), cfg)
+    assert jax.tree_util.tree_map(jnp.shape, made) == \
+        jax.tree_util.tree_map(jnp.shape, params)
+    assert count_params(cfg) == sum(
+        x.size for x in jax.tree_util.tree_leaves(params))
+    # a step at depth t: every layer the chosen rows, an indexer all of them
+    per_pos = 4 * (2 * 16 + 8)
+    assert decode_flops_per_token(cfg, 50) - decode_flops_per_token(
+        cfg, 40) == 2 * 2 * 2 * 16 * 10
+    assert decode_flops_per_token(cfg, 6) - decode_flops_per_token(
+        cfg, 5) == 2 * per_pos * 5 + 2 * 2 * 2 * 16
+
+
+def test_a_cache_has_a_fifth_kind_of_state(world):
+    cfg = world.cfg
+    assert cache_rows(cfg) == {"kv": (1, 24), "k_idx": (1, 16)}
+    assert _state_kind("k_idx") == "index" and _state_kind("kv") == "full"
+    assert position_bytes(cfg) == {"full": 24 * 4, "ring": 0, "state": 0,
+                                   "index": 16 * 4}
+    cache = init_slot_cache(cfg, 3, MAX_LEN)
+    assert cache["kv"].shape == (5, 3, 1, 24, MAX_LEN)      # every layer
+    assert cache["k_idx"].shape == (2, 3, 1, 16, MAX_LEN)   # indexing ones
+    assert cache_bytes(cache) == {
+        "full": 5 * 3 * 24 * MAX_LEN * 4, "ring": 0, "state": 0,
+        "index": 2 * 3 * 16 * MAX_LEN * 4}
+
+
+def test_rows_a_step_attends_and_what_the_choice_costs(world):
+    cfg = world.cfg
+    eng = types.SimpleNamespace(
+        cfg=cfg, _index_layers=2, _row_bytes=position_bytes(cfg),
+        _chosen_rows_of=None, _index_rows_of=None)
+    for name in ("_chosen_rows_of", "_index_rows_of"):
+        setattr(eng, name, functools.partial(
+            getattr(ContinuousBatchingEngine, name), eng))
+    batch = [types.SimpleNamespace(pos=p) for p in (3, 7, 50)]
+    depth, chosen = 4 + 8 + 51, 4 + 8 + 8
+    assert ContinuousBatchingEngine._index_rows_of(eng, batch) == (
+        2 * depth, 2 * depth * 64)
+    assert ContinuousBatchingEngine._rows_of(eng, batch) == (
+        5 * chosen, 5 * depth, 5 * chosen * 96 + 2 * depth * 64,
+        5 * depth * 96, 0, 0)
+
+
+def _chunked(w, row: int, n: int, cache, off: int = 0):
+    host = np.asarray(w.toks[row:row + 1, :n])
+    logits = None
+    while off < n:
+        logits, cache, off, _ = prefill_chunk_step(
+            prefill_chunk_jit, w.params, host, off, cache, w.cfg,
+            chunk=CHUNK, capacity=MAX_LEN)
+    return logits, cache
+
+
+def test_plain_and_absorbed_forms_agree(world):
+    """Whole-prompt prefill (the plain form under the selection as a mask,
+    its index keys placed) then decode steps (absorbed, the choice made
+    over the cached index keys) against the full forward."""
+    w = world
+    logits, cache = jax.jit(functools.partial(prefill, cfg=w.cfg))(
+        w.params, w.toks[:, :60], cache=init_kv_cache(w.cfg, 2, MAX_LEN))
+    np.testing.assert_allclose(logits, w.want[:, 59], **TOL)
+    slots = dict(cache, pos=jnp.full((2,), 60, jnp.int32))
+    for t in range(60, 70):
+        logits, slots = w.step(w.params, w.toks[:, t], slots,
+                               jnp.ones((2,), bool))
+        np.testing.assert_allclose(logits, w.want[:, t], **TOL)
+
+
+def test_chunks_and_a_prompt_that_ends_mid_chunk(world):
+    w = world
+    for row, n in ((0, 61), (1, 40)):
+        logits, cache = _chunked(w, row, n, init_kv_cache(w.cfg, 1, MAX_LEN))
+        np.testing.assert_allclose(logits[0], w.want[row, n - 1], **TOL)
+        assert int(cache["pos"]) == n
+
+
+def test_lanes_with_a_lane_that_stands(world):
+    w = world
+    cache = init_slot_cache(w.cfg, 3, MAX_LEN)
+    prompts = [(np.asarray(w.toks[0:1, :45]), 0), None,
+               (np.asarray(w.toks[1:2, :30]), 0)]
+    logits = {}
+    while any(p is not None for p in prompts):
+        lg, cache, moved = prefill_lanes_step(
+            prefill_lanes_jit, w.params, prompts, cache, w.cfg, chunk=CHUNK,
+            capacity=MAX_LEN)
+        for p, m in enumerate(moved):
+            if m is not None:
+                logits[p] = np.asarray(lg[p])
+                prompts[p] = (prompts[p][0], m[0]) \
+                    if m[0] < prompts[p][0].shape[1] else None
+    np.testing.assert_allclose(logits[0], w.want[0, 44], **TOL)
+    np.testing.assert_allclose(logits[2], w.want[1, 29], **TOL)
+    assert not np.asarray(cache["kv"][:, 1]).any() \
+        and not np.asarray(cache["k_idx"][:, 1]).any()
+
+
+def _two_slots(w, depths):
+    slots = init_slot_cache(w.cfg, 2, MAX_LEN)
+    insert = jax.jit(cache_insert_slot)
+    for row, n in enumerate(depths):
+        _, one = _chunked(w, row, n, init_kv_cache(w.cfg, 1, MAX_LEN))
+        slots = insert(slots, one, jnp.int32(row))
+    return slots
+
+
+def test_slots_at_depths_of_their_own_and_one_that_stands(world):
+    w = world
+    slots = _two_slots(w, (70, 21))
+    active = jnp.asarray([True, True])
+    for j in range(6):
+        logits, slots = w.step(
+            w.params, jnp.stack([w.toks[0, 70 + j], w.toks[1, 21 + j]]),
+            slots, active)
+        np.testing.assert_allclose(logits[0], w.want[0, 70 + j], **TOL)
+        np.testing.assert_allclose(logits[1], w.want[1, 21 + j], **TOL)
+    # slot 1 stands (its token lands ahead of its pos and is never chosen)
+    logits, slots = w.step(
+        w.params, jnp.stack([w.toks[0, 76], jnp.int32(5)]), slots,
+        jnp.asarray([True, False]))
+    np.testing.assert_allclose(logits[0], w.want[0, 76], **TOL)
+    assert slots["pos"].tolist() == [77, 27]
+    logits, slots = w.step(w.params, jnp.stack([w.toks[0, 77], w.toks[1, 27]]),
+                           slots, active)
+    np.testing.assert_allclose(logits[1], w.want[1, 27], **TOL)
+
+
+def test_stale_rows_past_a_slots_pos_are_never_chosen(world):
+    """A slot that held a LONGER session: its rows past the new session's
+    ``pos`` hold index keys that would outscore every true row (they are
+    made huge here) and latents of another context; no query may choose
+    them."""
+    w = world
+    slots = _two_slots(w, (90, 85))
+    _, short = _chunked(w, 1, 21, init_kv_cache(w.cfg, 1, MAX_LEN))
+    # the short session's rows into the slot the long one leaves, the long
+    # one's rows from 21 on left where they are and its index keys blown up
+    stale = jax.jit(cache_insert_slot)(slots, {
+        name: a.at[..., 21:].set(slots[name][:, 1:2, ..., 21:] * (
+            1e3 if name == "k_idx" else 1.0))
+        for name, a in short.items() if name != "pos"} | {
+            "pos": short["pos"]}, jnp.int32(1))
+    assert float(jnp.abs(stale["k_idx"][:, 1, ..., 21:85]).max()) > 100
+    for j in range(4):
+        logits, stale = w.step(
+            w.params, jnp.stack([w.toks[0, 90 + j], w.toks[1, 21 + j]]),
+            stale, jnp.ones((2,), bool))
+        np.testing.assert_allclose(logits[1], w.want[1, 21 + j], **TOL)
+        np.testing.assert_allclose(logits[0], w.want[0, 90 + j], **TOL)
+
+
+def test_gathered_prefix_carries_the_index_keys(world):
+    """`cache_gather_slot` copies the fifth kind with the rest: a session
+    seeded with a donor's first 40 positions continues as its own context
+    would, the donor's later rows past its ``pos`` unseen."""
+    w = world
+    slots = _two_slots(w, (72, 30))
+    seeded = jax.jit(cache_gather_slot)(slots, jnp.int32(0), jnp.int32(40))
+    assert set(seeded) == {"kv", "k_idx", "pos"}
+    np.testing.assert_array_equal(seeded["k_idx"][:, 0], slots["k_idx"][:, 0])
+    host = np.concatenate([np.asarray(w.toks[0:1, :40]),
+                           np.asarray(w.toks[1:2, 40:60])], axis=1)
+    want = np.asarray(forward(w.params, jnp.asarray(host), w.cfg))
+    off, cache = 40, seeded
+    while off < 60:
+        logits, cache, off, _ = prefill_chunk_step(
+            prefill_chunk_jit, w.params, host, off, cache, w.cfg,
+            chunk=CHUNK, capacity=MAX_LEN)
+    np.testing.assert_allclose(logits[0], want[0, 59], **TOL)
+
+
+@pytest.mark.parametrize("dense,kinds", [
+    (2, ("index", "shared", "shared", "index", "shared", "shared")),
+    (1, ("index", "index", "shared", "shared")),
+    (0, ("index", "shared", "shared")),
+])
+def test_other_patterns_serve_as_they_forward(dense, kinds):
+    """Leading dense layers that SHARE (two dense layers, the second under
+    the first's choice, and an expert layer under it too), an expert run
+    that begins with an indexing layer, a model of one run."""
+    cfg = TransformerConfig.tiny(
+        vocab_size=97, d_model=32, n_layers=len(kinds), n_heads=2,
+        n_kv_heads=None, attention="mla", q_lora_rank=16, kv_lora_rank=12,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, n_experts=4,
+        expert_top_k=2, router="sigmoid", moe_d_ff=16, n_shared_experts=1,
+        first_dense_layers=dense, d_ff=48, index_heads=2, index_head_dim=8,
+        index_topk=6, layer_kinds=kinds, dtype=jnp.float32, max_seq_len=64)
+    params, _ = init_params(jax.random.PRNGKey(1), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (1, 40), 0, 97)
+    want = forward(params, toks, cfg)
+    logits, cache = prefill(params, toks[:, :30], cfg,
+                            init_kv_cache(cfg, 1, 64))
+    np.testing.assert_allclose(logits, want[:, 29], **TOL)
+    assert cache["k_idx"].shape[0] == kinds.count("index")
+    slots = dict(cache, pos=jnp.full((1,), 30, jnp.int32))
+    for t in range(30, 36):
+        logits, slots = decode_step_slots(params, toks[:, t], slots,
+                                          jnp.ones((1,), bool), cfg)
+        np.testing.assert_allclose(logits, want[:, t], **TOL)
+    assert np.isfinite(float(lm_loss(params, {"tokens": toks}, cfg)))
+
+
+# ------------------------------------------------------- through the engine
+
+def _stream(core, prompt, n, out=None, key=None):
+    r = core.handle({"op": "start", "prompt": prompt})
+    assert "error" not in r, r
+    toks = list(r["token"])
+    while len(toks) < n:
+        more = core.handle({"op": "next_chunk", "sid": r["sid"],
+                            "max_tokens": n - len(toks)})
+        assert "error" not in more, more
+        toks += more["tokens"]
+        if more.get("done"):
+            break
+    core.handle({"op": "end", "sid": r["sid"]})
+    if out is not None:
+        out[key] = toks[:n]
+    return toks[:n]
+
+
+def _forced(w, prompt, stream):
+    """The full forward's own choice at every generated position of
+    ``prompt + stream``."""
+    seq = jnp.asarray([prompt + stream[:-1]], jnp.int32)
+    logits = np.asarray(forward(w.params, seq, w.cfg))[0]
+    return logits[len(prompt) - 1:].argmax(-1).tolist()
+
+
+def _core(w, **engine):
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    return DecodeSessionCore(
+        w.cfg, max_len=MAX_LEN, params=w.params,
+        engine=DecodeEngineConfig(prefill_chunk_tokens=CHUNK, **engine))
+
+
+def test_engine_serves_the_forwards_tokens(world, monkeypatch):
+    """Four sessions at once (prompts of 4-10 times index_topk) through
+    chunk programs, the lanes program and the fused slot step: every token
+    is the full forward's choice at its position; the engine counts the
+    rows a chosen layer attends and the index keys the choice costs."""
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(ContinuousBatchingEngine, "_MOE_SPAN_S", 0.0)
+    w = world
+    core = _core(w, max_slots=3)
+    try:
+        prompts = [np.asarray(w.toks[i % 2, a:a + n]).tolist()
+                   for i, (a, n) in enumerate(
+                       ((0, 80), (3, 33), (11, 57), (20, 64)))]
+        got = {}
+        threads = [threading.Thread(target=_stream,
+                                    args=(core, p, 12, got, i))
+                   for i, p in enumerate(prompts)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(300)
+        for i, p in enumerate(prompts):
+            assert got[i] == _forced(w, p, got[i]), i
+        st = core.engine.stats()
+        assert st["cache_copies"] == 0
+        assert st["prefill_programs"] < st["prefill_chunks"]    # lanes ran
+        cache = st["cache"]
+        assert cache["bytes_index"] == 2 * 3 * 16 * MAX_LEN * 4
+        assert cache["bytes_full"] == 5 * 3 * 24 * MAX_LEN * 4
+        assert cache["bytes_per_position"] == 5 * 24 * 4 + 2 * 16 * 4
+        # every step's live slots stood past index_topk: 8 rows a layer
+        assert cache["rows_read"] == 5 * TOPK * st["tokens"]
+        assert cache["rows_if_full"] > 4 * cache["rows_read"]
+        assert cache["index_rows_read"] * 5 == cache["rows_if_full"] * 2
+        assert cache["index_bytes_read"] == cache["index_rows_read"] * 64
+        assert cache["bytes_read"] == cache["rows_read"] * 96 \
+            + cache["index_bytes_read"]
+        span = [e for e in tracing.span_events()
+                if e["name"] == "cache:rows"][-1]["args"]
+        assert span["bytes_index"] == cache["bytes_index"]
+        assert span["index_rows_read"] * 5 == span["rows_if_full"] * 2
+    finally:
+        core.engine.shutdown()
+
+
+def test_a_slot_reused_after_a_longer_session_and_a_shared_prefix(world):
+    w = world
+    core = _core(w, max_slots=1, prefix_cache_min_tokens=4,
+                 token_queue_depth=2)
+    try:
+        long_ = np.asarray(w.toks[0, :90]).tolist()
+        assert _stream(core, long_, 10) == _forced(
+            w, long_, _stream(core, long_, 10))
+        # the ONE slot again, for a session a quarter as long
+        short = np.asarray(w.toks[1, :24]).tolist()
+        got = _stream(core, short, 10)
+        assert got == _forced(w, short, got)
+        # ... and one that shares the short one's first 20 tokens: seeded
+        # from the slot (index keys with the latents), the rest prefilled
+        hits = core.engine.stats()["prefix"]["applied_hits"]
+        fork = short[:20] + np.asarray(w.toks[0, 30:50]).tolist()
+        got = _stream(core, fork, 10)
+        assert core.engine.stats()["prefix"]["applied_hits"] == hits + 1
+        assert got == _forced(w, fork, got)
+        assert core.engine.stats()["cache_copies"] == 0
+    finally:
+        core.engine.shutdown()
+
+
+# --------------------------------------------- planted faults, and refusals
+
+def _without_indexer(tree):
+    return {k: v for k, v in tree.items()
+            if not k.startswith(("wi_", "ik_"))}
+
+
+def _faults(w):
+    """Three wrong programs on the same weights -> {name: logits}."""
+    late = ("index", "shared", "shared", "shared", "shared")
+    return {
+        # every layer attends all rows
+        "the selection ignored": forward(
+            w.params, w.toks, dataclasses.replace(w.cfg, index_topk=4 * T)),
+        # layer 4 attends layer 0's choice where layer 3's is due (layer 3
+        # attends it too: it makes none of its own)
+        "the shared layers attend the wrong indexing layer's choice": forward(
+            dict(w.params, layers=_without_indexer(w.params["layers"])),
+            w.toks, dataclasses.replace(w.cfg, layer_kinds=late)),
+        "index_topk halved": forward(
+            w.params, w.toks, dataclasses.replace(w.cfg,
+                                                  index_topk=TOPK // 2)),
+    }
+
+
+def test_three_planted_faults_each_fail(world):
+    w = world
+    for name, got in _faults(w).items():
+        got = np.asarray(got)
+        # what the comparisons above hold the programs to
+        assert not np.allclose(got, w.want, **TOL), name
+        # ... by far: a tenth of the logits' spread at the worst position,
+        # and nothing wrong before the first position that has a choice
+        assert np.abs(got - w.want).max() > 0.1 * w.want.std(), name
+        np.testing.assert_allclose(got[:, :TOPK // 2], w.want[:, :TOPK // 2],
+                                   **TOL)
+
+
+def test_what_a_configuration_is_refused_for(world):
+    cfg = world.cfg
+    toks = world.toks[:1, :8]
+    for bad, match in (
+            (dict(layer_kinds=("shared", "index", "shared", "index",
+                               "shared")), "first layer is 'shared'"),
+            (dict(layer_kinds=None), "indexer"),
+            (dict(index_topk=0), "indexer"),
+            (dict(layer_kinds=("index", "full", "shared", "index",
+                               "shared")), "indexer"),
+            (dict(index_head_dim=4), "indexer")):
+        broken = dataclasses.replace(cfg, **bad)
+        with pytest.raises(ValueError, match=match):
+            init_params(jax.random.PRNGKey(0), broken)
+        with pytest.raises(ValueError, match=match):
+            forward(world.params, toks, broken)
+        with pytest.raises(ValueError, match=match):
+            prefill_chunk_jit(world.params, toks,
+                              init_kv_cache(cfg, 1, MAX_LEN), cfg=broken)
+    with pytest.raises(ValueError, match="indexer"):
+        init_params(jax.random.PRNGKey(0), TransformerConfig.tiny(
+            index_topk=4, index_heads=2, index_head_dim=8,
+            layer_kinds=("index", "shared")))
