@@ -654,7 +654,11 @@ def _key_block(c: int, heads: int, rows: int) -> int:
     all at once), for ``c`` queries of ``heads`` heads over ``rows``
     positions: blocked where their float32 scores would pass 160 MiB, which
     no program of a context of a few thousand rows does, and a decode step
-    (one query a slot) does at none."""
+    (one query a slot) does at none.  A blocked read is ONE KERNEL CALL a
+    layer over the state array where it lies (`ops/latent_attention.py`
+    `attend_cache`) wherever the program is lowered for a TPU and the
+    shapes are whole tiles (`kernel_shape`), and `_attend_blocks`' loop a
+    lane at a time elsewhere (`attend_mla`)."""
     return sparse_index.key_block(rows) \
         if c * heads * rows * 4 > 160 << 20 else 0
 
@@ -711,8 +715,10 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     rows a no-drop expert layer routes (None: all).  ``lanes`` [B] bool
     (None: the whole batch in one piece) makes the ATTENTION, the one part
     that reads no weight, a batch row's at a time and only where it is set
-    (`_by_lane`); everything that reads a weight still runs once over the
-    ``B x C`` stacked rows.
+    (`_by_lane`; a latent layer's blocked read on a TPU: the kernel's list
+    of a lane's blocks, `ops/latent_attention.py` `attend_cache`);
+    everything that reads a weight still runs once over the ``B x C``
+    stacked rows.
     → (final-norm activations, arrays, load)."""
     dt = cfg.dtype
     b, c, _ = x.shape
@@ -809,20 +815,36 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             sel = (chosen, li + 1)
         if kind in SPARSE_KINDS:
             seen = sel[0]
-        if lanes is None:
-            out = mla.attend_absorbed(
-                q_nope, q_rope, _layer_of(kv_all, l)[:, 0], lp["wkv_b"],
-                lp["wo"], seen, block)
-        else:
-            q_abs = mla.absorb(q_nope, q_rope, lp["wkv_b"])
-            scale = float(np.sqrt(cfg.qk_nope_head_dim
-                                  + cfg.qk_rope_head_dim))
-            out = mla.unabsorb(_by_lane(
-                lanes, functools.partial(mla.attend_latents, scale=scale,
-                                         key_block=block),
+        nope = cfg.qk_nope_head_dim
+        scale = float(np.sqrt(nope + cfg.qk_rope_head_dim))
+
+        def loops(q_nope, q_rope, kv_all, seen, wkv_b, wo, *live):
+            # XLA's forms: all rows at once, or `_attend_blocks`' loop
+            if not live:
+                return mla.attend_absorbed(
+                    q_nope, q_rope, _layer_of(kv_all, l)[:, 0], wkv_b, wo,
+                    seen, block)
+            q_abs = mla.absorb(q_nope, q_rope, wkv_b)
+            return mla.unabsorb(_by_lane(
+                live[0], functools.partial(mla.attend_latents, scale=scale,
+                                           key_block=block),
                 lambda p: (q_abs[p:p + 1], _lane_of(kv_all, l, p)[:, 0],
                            seen[p:p + 1])),
-                lp["wkv_b"], lp["wo"], cfg.qk_nope_head_dim)
+                wkv_b, wo, nope)
+
+        def in_place(q_nope, q_rope, kv_all, seen, wkv_b, wo, *live):
+            # the blocked read as one kernel call over the state array
+            return mla.unabsorb(mla.attend_cache(
+                mla.absorb(q_nope, q_rope, wkv_b, heads_major=True), kv_all,
+                l, seen, live[0] if live else None, scale, cfg.kv_lora_rank,
+                block), wkv_b, wo, nope, heads_major=True)
+
+        operands = (q_nope, q_rope, kv_all, seen, lp["wkv_b"], lp["wo"]) \
+            + (() if lanes is None else (lanes,))
+        if mla.kernel_shape(q_nope.shape, cfg.kv_lora_rank, block):
+            out = mla.on_the_chip(in_place, loops, *operands)
+        else:
+            out = loops(*operands)
         return out, arrs, sel
 
     def conv(y, lp, arrs, l, kind):

@@ -9,7 +9,8 @@ key-value latent plus ONE rotary key a position, shared by all heads.
     scores         = (q_nope . k_nope + rotary(q_rope) . rotary(k_r))
                      / sqrt(nope + rope)
 
-Two forms of the same function of (queries, latents):
+Two forms of the same function of (queries, latents), the second read in
+three ways:
 
 * `attend_plain` builds every head's keys and values from the latents and
   runs ordinary attention: the form for training and for a whole-sequence
@@ -21,7 +22,22 @@ Two forms of the same function of (queries, latents):
   the query and the value up-projection applied after the probabilities,
   so the few queries of a chunk or a decode step attend over the cached
   latents directly.  Per cached position a layer then reads ``kv_lora +
-  rope`` values, not ``heads x (qk + v)``.
+  rope`` values, not ``heads x (qk + v)``.  Between `absorb` and `unabsorb`
+  the cached rows are read
+
+  - all at once (`attend_latents`): a decode step, and a chunk whose
+    float32 scores are a few tens of MB (a context of a few thousand rows);
+  - a block of rows at a time under a running softmax, no block past the
+    last row a query may see (`_attend_blocks`, plain `jax.numpy` in a
+    loop): where a chunk's scores over every row would be a GB, on any
+    platform but the TPU, and the tests' reference;
+  - the same blocks by ONE KERNEL CALL (`attend_cache`) where the program
+    is lowered for a TPU: the score block, its running maximum and sum and
+    the float32 accumulator stay in VMEM between the two dots, the cache
+    is read where it lies (no lane's layer is cut out of the state array),
+    and the second dot meets the ``kv_lora`` latent rows alone: 82 % of
+    the MXU's peak where XLA's loop, the cut in front of it, ran at 68 %
+    (4 lanes at 6-12 k rows, PERF.md, PR 47).
 
 Shapes are ``[batch, seq, heads, dim]`` like `ops/attention.py`; a cache
 layer is ``[batch, kv_lora + rope, positions]``, positions last, as
@@ -30,13 +46,18 @@ layer is ``[batch, kv_lora + rope, positions]``, positions last, as
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .attention import multi_head_attention
+from .flash_attention import _LANES, _NEG_INF, _VMEM_LIMIT, _interpret
+from .grouped_matmul import _cumsum
 from .norms import rmsnorm
 from .sparse_index import rows_seen
 
@@ -113,10 +134,11 @@ def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     """``cached`` [b, kv_lora + rope, T] is one layer of a latent cache,
     ``mask`` [b|1, s, T] which positions each query may see -> [b, s, d].
     The probabilities meet all ``kv_lora + rope`` cached rows (the rotary
-    rows' part of the result is dropped): slicing the latent rows out of
-    the cache first would copy them.  Its three parts stand alone for a
-    caller that attends a row of the batch at a time BETWEEN the two that
-    read weights: `absorb`, `attend_latents`, `unabsorb`."""
+    rows' part of the result is dropped): in XLA, slicing the latent rows
+    out of the cache first would copy them (`attend_cache`'s kernel slices
+    the block it holds in VMEM, which costs nothing).  Its three parts stand
+    alone for a caller that attends a row of the batch at a time BETWEEN
+    the two that read weights: `absorb`, `attend_latents`, `unabsorb`."""
     nope = q_nope.shape[-1]
     o_lat = attend_latents(absorb(q_nope, q_rope, wkv_b), cached, mask,
                            math.sqrt(nope + q_rope.shape[-1]), key_block)
@@ -124,12 +146,17 @@ def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
 
 
 @jax.named_scope("attention")
-def absorb(q_nope: jnp.ndarray, q_rope: jnp.ndarray, wkv_b) -> jnp.ndarray:
+def absorb(q_nope: jnp.ndarray, q_rope: jnp.ndarray, wkv_b,
+           heads_major: bool = False) -> jnp.ndarray:
     """Queries [b, s, h, nope] and [b, s, h, rope] (rotated) -> [b, s, h,
-    kv_lora + rope]: the key up-projection folded into the query."""
+    kv_lora + rope]: the key up-projection folded into the query.
+    ``heads_major``: ``[b, h, s, kv_lora + rope]``, as `attend_cache` takes
+    them (the product written so, not turned afterwards)."""
     nope = q_nope.shape[-1]
-    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope,
-                       wkv_b.astype(q_nope.dtype)[..., :nope])
+    q_lat = jnp.einsum("bshn,rhn->bhsr" if heads_major else "bshn,rhn->bshr",
+                       q_nope, wkv_b.astype(q_nope.dtype)[..., :nope])
+    if heads_major:
+        q_rope = jnp.swapaxes(q_rope, 1, 2)
     return jnp.concatenate([q_lat, q_rope], axis=-1)
 
 
@@ -190,13 +217,195 @@ def _attend_blocks(q_abs, cached, mask, scale: float, block: int):
     return (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dt)
 
 
+#: query heads a grid step of `attend_cache`: with a chunk's 128 queries the
+#: left operand of both dots is 1024 rows
+_HEAD_TILE = 8
+#: cached rows a grid step at most (a divisor of `sparse_index.KEY_BLOCK`)
+_ROW_TILE = 512
+
+
+def kernel_shape(q_shape: Tuple[int, ...], kv_lora: int, block: int) -> bool:
+    """Whether `attend_cache` takes queries ``[b, s, h, r]`` a block of
+    ``block`` cached rows at a time (`sparse_index.key_block`'s: 0, or whole
+    lanes that divide the cache's rows) on a TPU, or under the interpreter:
+    whole tiles.  The queries of a head tile stack to the rows of one
+    operand (``s`` a multiple of a bfloat16 tile's 16 sublanes), and the
+    latent rows are a sublane-aligned slice of the cached block that fills
+    the output's lanes."""
+    _, s, h, _ = q_shape
+    return bool(block) and s % 16 == 0 and h % min(h, _HEAD_TILE) == 0 \
+        and kv_lora % _LANES == 0
+
+
+def _cache_work(rows: jnp.ndarray, block: int, most: int):
+    """Visible rows a lane [B] -> the list of (lane, block of ``block``
+    rows) the kernel walks, lanes ascending and each one's blocks
+    ascending: (the lane an item writes [W], the lane and the block it
+    reads [W] [W], the list's length), ``W = B x most`` the most there can
+    be.  A lane with no row to see (it stands) has ONE item, which reads
+    what the item before it read (a repeated block index moves nothing) and
+    writes zeros."""
+    lanes = rows.shape[0]
+    blocks = jnp.maximum((rows + block - 1) // block, 1)
+    ends = _cumsum(blocks)
+    # what a standing lane's item reads: the last block of the nearest
+    # lane before it that runs (none: the first block of lane 0)
+    src, last = [], []
+    for p in range(lanes):
+        runs = rows[p] > 0
+        src.append(jnp.where(runs, p, src[-1] if p else 0))
+        last.append(jnp.where(runs, blocks[p] - 1, last[-1] if p else 0))
+    item = jnp.arange(lanes * most, dtype=jnp.int32)
+    lane = jnp.minimum((item[:, None] >= ends[None, :]).sum(1),
+                       lanes - 1).astype(jnp.int32)
+    mine = lane[:, None] == jnp.arange(lanes)[None, :]
+
+    def of_lane(per_lane):              # [B] -> [W], by comparisons
+        return jnp.where(mine, per_lane[None, :], 0).sum(1).astype(jnp.int32)
+
+    at = jnp.clip(item - of_lane(ends - blocks), 0, most - 1)
+    return (lane, of_lane(jnp.stack(src)),
+            jnp.where(of_lane(rows) > 0, at, of_lane(jnp.stack(last))),
+            ends[-1])
+
+
+def _cache_kernel(l_ref, lane_ref, src_ref, at_ref, rows_ref, q_ref, kv_ref,
+                  seen_ref, o_ref, top_ref, sum_ref, acc_ref, *,
+                  inv_scale: float, kv_lora: int):
+    del l_ref, src_ref, at_ref
+    item, items = pl.program_id(1), pl.num_programs(1)
+    lane = lane_ref[item]
+    first = (item == 0) | (lane_ref[jnp.maximum(item - 1, 0)] != lane)
+    last = (item == items - 1) | (lane_ref[
+        jnp.minimum(item + 1, lane_ref.shape[0] - 1)] != lane)
+    hb, c, r = q_ref.shape
+
+    @pl.when(first)
+    def _():
+        top_ref[...] = jnp.full(top_ref.shape, _NEG_INF, jnp.float32)
+        sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(rows_ref[lane] > 0)
+    def _():
+        dt = q_ref.dtype
+        rows = kv_ref[...].astype(dt)                          # [r, tk]
+        scores = jnp.dot(q_ref[...].reshape(hb * c, r), rows,
+                         preferred_element_type=jnp.float32) * inv_scale
+        seen = (seen_ref[...].astype(jnp.int32) != 0)[None]   # [1, c, tk]
+        scores = jnp.where(seen, scores.reshape(hb, c, -1), _NEG_INF)
+        top = top_ref[...]                                     # [hb, c, 1]
+        new_top = jnp.maximum(top, scores.max(-1, keepdims=True))
+        # (a hidden score stays at -1e30: it weighs 0 once a real one is in)
+        p = jnp.where(seen, jnp.exp(scores - new_top), 0.0)
+        fade = jnp.exp(top - new_top)
+        top_ref[...] = new_top
+        sum_ref[...] = sum_ref[...] * fade + p.sum(-1, keepdims=True)
+        # the probabilities over the block's LATENT rows only: a sublane
+        # slice of the block where it lies, contracted over its lanes
+        acc_ref[...] = acc_ref[...] * fade.reshape(hb * c, 1) \
+            + jax.lax.dot_general(
+                p.astype(dt).reshape(hb * c, -1), rows[:kv_lora],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _():
+        total = jnp.maximum(sum_ref[...], 1e-30).reshape(hb * c, 1)
+        o_ref[...] = (acc_ref[...] / total).reshape(hb, c, kv_lora).astype(
+            o_ref.dtype)
+
+
 @jax.named_scope("attention")
-def unabsorb(o_lat: jnp.ndarray, wkv_b, wo, nope: int) -> jnp.ndarray:
-    """`attend_latents`' [b, s, h, kv_lora + rope] -> the block's [b, s, d]:
-    the value up-projection on the latent rows, then the output's."""
+def attend_cache(q_abs: jnp.ndarray, kv_all: jnp.ndarray, l,
+                 mask: jnp.ndarray, live: Optional[jnp.ndarray],
+                 scale: float, kv_lora: int, block: int) -> jnp.ndarray:
+    """`_attend_blocks` as ONE kernel call over the cache WHERE IT LIES:
+    absorbed queries HEADS-MAJOR ``[b, h, s, kv_lora + rope]`` over layer
+    ``l`` of ``kv_all`` [L, b, 1, kv_lora + rope, T] under ``mask`` [b | 1,
+    s, T], a batch row at a time and only where ``live`` [b] is set (None:
+    everywhere; zeros elsewhere) -> ``[b, h, s, kv_lora]``, the latent rows'
+    part of `attend_latents`' result (all `unabsorb` reads).  ``block``: a
+    divisor of T that `kernel_shape` accepted; a grid step takes at most
+    `_ROW_TILE` rows of it.
+
+    The same arithmetic: operands in the compute type, float32 scores over
+    ``scale``, the mask a query's own set of columns, float32 running
+    maximum and sum, probabilities cast for the second dot, float32
+    accumulation.  What differs is where it happens: a grid step's score
+    block (`_HEAD_TILE` heads x ``s`` queries stacked to the rows of ONE
+    left operand, x `_ROW_TILE` cached rows), its maximum, sum and
+    accumulator stay in VMEM from the first dot to the second; the cached
+    block arrives by an index map over the whole state array (layer, batch
+    row and block are prefetched scalars: no lane's layer is cut out), and
+    the second dot leaves the rotary rows out.  The grid walks
+    `_cache_work`'s list, its length a traced scalar as
+    `ops/grouped_matmul.py`'s: no block past the last row a lane's queries
+    may see, one item for a lane that stands, no grid step that does
+    nothing.  Tiles (my chip runs, PR 47, 4 lanes at 6-12 k rows: 8 x 512
+    4.13 ms, 8 x 1024 4.19, 16 x 512 4.00-4.11, 8 x 256 5.23; the 8 heads
+    as two or more chains of fewer rows 4.29-5.22): the dots bind, at 82 %
+    of the MXU's peak (87 % of what a contraction of 576 leaves of it: the
+    MXU runs it as 640)."""
+    b, h, s, r = q_abs.shape
+    t = kv_all.shape[-1]
+    hb, block = min(h, _HEAD_TILE), math.gcd(block, _ROW_TILE)
+    seen = jnp.broadcast_to(mask, (b, s, t))
+    rows = jax.vmap(rows_seen)(seen)                # a lane's own, [b]
+    if live is not None:
+        rows = jnp.where(live, rows, 0)
+    lane, src, at, items = _cache_work(rows, block, t // block)
+    return pl.pallas_call(
+        functools.partial(_cache_kernel, inv_scale=1.0 / scale,
+                          kv_lora=kv_lora),
+        name="latent_attention_cache",
+        out_shape=jax.ShapeDtypeStruct((b, h, s, kv_lora), q_abs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(h // hb, items),
+            in_specs=[
+                pl.BlockSpec((None, hb, s, r),
+                             lambda j, i, l, ln, src, at, n: (src[i], j, 0, 0)),
+                pl.BlockSpec((None, None, None, r, block),
+                             lambda j, i, l, ln, src, at, n:
+                             (l[0], src[i], 0, 0, at[i])),
+                pl.BlockSpec((None, s, block),
+                             lambda j, i, l, ln, src, at, n:
+                             (src[i], 0, at[i])),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, hb, s, kv_lora),
+                lambda j, i, l, ln, src, at, n: (ln[i], j, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((hb, s, 1), jnp.float32),
+                            pltpu.VMEM((hb, s, 1), jnp.float32),
+                            pltpu.VMEM((hb * s, kv_lora), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+    )(jnp.asarray(l, jnp.int32).reshape(1), lane, src, at, rows, q_abs,
+      kv_all, seen.astype(jnp.int8))
+
+
+@jax.named_scope("attention")
+def unabsorb(o_lat: jnp.ndarray, wkv_b, wo, nope: int,
+             heads_major: bool = False) -> jnp.ndarray:
+    """`attend_latents`' [b, s, h, kv_lora + rope] (``heads_major``:
+    `attend_cache`'s [b, h, s, kv_lora]) -> the block's [b, s, d]: the value
+    up-projection on the latent rows, then the output's."""
     dt = o_lat.dtype
     kv_lora = wkv_b.shape[0]
-    attn = jnp.einsum("bshr,rhv->bshv", o_lat[..., :kv_lora],
-                      wkv_b.astype(dt)[..., nope:])
+    attn = jnp.einsum("bhsr,rhv->bshv" if heads_major else "bshr,rhv->bshv",
+                      o_lat[..., :kv_lora], wkv_b.astype(dt)[..., nope:])
     with jax.named_scope("projections"):
         return jnp.einsum("bshv,hvd->bsd", attn, wo.astype(dt))
+
+
+def on_the_chip(kernel, loop, *operands):
+    """``kernel(*operands)`` where the program is lowered for a TPU (or
+    under the interpreter), ``loop(*operands)`` elsewhere: the one program
+    text serves both, as `ops/cache_write.py` `write_columns` does."""
+    if _interpret():
+        return kernel(*operands)
+    return jax.lax.platform_dependent(*operands, tpu=kernel, default=loop)

@@ -585,6 +585,81 @@ def test_latent_expert_model_copies_no_cache_and_no_expert_stack(topo,
                           r" copy\(", text)
 
 
+@pytest.mark.parametrize("program", ["prefill_lanes_4x128",
+                                     "prefill_padded_128"])
+@pytest.mark.parametrize("heads,max_len,blocked", [(64, 6144, True),
+                                                   (20, 4096, False)])
+def test_blocked_latent_chunks_attend_in_one_kernel_call(topo, program,
+                                                         heads, max_len,
+                                                         blocked):
+    """A latent model with an indexer (latents of 576, an indexing layer and
+    two that share its choice: two layer bodies) at a chunk of 128 rows.
+    Where the chunk's float32 scores would pass 160 MiB (64 heads over 6144
+    rows: `generate._key_block`) the lanes program and the batch-1 chunk
+    program hold `ops/latent_attention.py` `attend_cache`'s call ONCE a
+    layer body, no float32 array as large as a score block (128 x 64 x 1024)
+    and no cut of a lane's layer out of the cache; at glm-4.7-flash's heads
+    and rows (42 MB of scores, read at once) they hold no such call."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params, prefill_chunk)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=512, n_layers=3, n_heads=heads, d_ff=1024,
+        max_seq_len=max_len, pos_emb="rope", rope_base=1e6,
+        activation="swiglu", norm="rmsnorm", norm_eps=1e-5,
+        tie_embeddings=False, attention="mla", q_lora_rank=256,
+        kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+        v_head_dim=256, index_heads=4, index_head_dim=128, index_topk=2048,
+        layer_kinds=("index", "shared", "shared"), dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    if program.startswith("prefill_lanes"):
+        cache, lowered, _, width = _lower_lanes(described, params, cfg,
+                                                program, max_len)
+    else:
+        width = int(program.rsplit("_", 1)[1])
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, width), jnp.int32)),
+            cache, cfg=cfg,
+            n_valid=described(jax.ShapeDtypeStruct((), jnp.int32)))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [c for c in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+             if "latent_attention_cache" in c]
+    if not blocked:
+        assert not calls
+        return
+    kv = cache["kv"]
+    assert len(calls) == 2 and all(
+        "bf16[" + ",".join(map(str, kv.shape)) + "]" in c for c in calls), \
+        calls
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for n, a in cache.items() if n != "pos")
+    # no score block: no float32 array of a chunk's queries x heads over a
+    # block of rows (XLA's loop: [1, 128, 64, 1024]) or over all of them
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        dims = sorted(d for d in map(int, dims.split(",")) if d > 1)
+        assert dims not in (sorted((width, heads, 1024)),
+                            sorted((width, heads, max_len))), dims
+    # the cache is read where it lies: no array of one lane's layer
+    assert not re.findall(rf"= bf16\[(?:1,)*576,{max_len}\]", text)
+
+
 @pytest.mark.parametrize("program", ["fused_step", "prefill_padded_128",
                                      "prefill_chunk_128", "prefill_chunk_1",
                                      "prefill_lanes_4x128"])

@@ -18,6 +18,7 @@ tests/benchmark/test_perfbench_family_glm_moe_dsa.py's.
 
 import dataclasses
 import functools
+import importlib
 import json
 import os
 import threading
@@ -157,6 +158,80 @@ def test_blocked_reads_are_the_dense_ones():
             sparse_index.selection_mask(q, wts, rows, mask, 16))
 
 
+# ------------------------------------- the blocked read as one kernel call
+
+_KERNEL_CASES = {
+    # lanes' first positions, which lanes run, a query's own columns or all
+    "a query's own columns": ((100, 37, 370), (1, 1, 1), True),
+    "the last row seen in the middle of a block": ((150, 3, 300), (1, 1, 1),
+                                                   False),
+    "the last row seen in the last block": ((496, 480, 385), (1, 1, 1),
+                                            False),
+    "a lane that stands among lanes that run": ((100, 200, 370), (1, 0, 1),
+                                                True),
+    "a context within index_topk": ((0, 0, 0), (1, 1, 1), False),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_the_kernel_attends_what_the_loop_attends(monkeypatch, case, dtype,
+                                                  tol):
+    """`attend_cache` (through the interpreter) against `_attend_blocks` and
+    against the unblocked `attend_latents`, a lane at a time: the mask a
+    query's own set of columns, no block past the last row a lane sees
+    (those blocks hold NaN here), a standing lane's cache never touched."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    first, runs, own = _KERNEL_CASES[case]
+    layers, lanes, h, c, kv_lora, rope, t, block = 2, 3, 16, 16, 128, 16, \
+        512, 128
+    r, dt = kv_lora + rope, jnp.dtype(dtype)
+    assert mla.kernel_shape((lanes, c, h, r), kv_lora, block)
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.standard_normal((lanes, c, h, r)), dt)
+    kv = rng.standard_normal((layers, lanes, 1, r, t)).astype(np.float32)
+    mask = np.arange(t)[None, None, :] <= (
+        np.asarray(first)[:, None] + np.arange(c)[None, :])[:, :, None]
+    if own:
+        mask &= rng.random((lanes, c, t)) < 0.4
+    live = np.asarray(runs, bool)
+    poisoned = kv.copy()
+    for p in range(lanes):
+        seen = int(sparse_index.rows_seen(jnp.asarray(mask[p]))) \
+            if live[p] else 0
+        poisoned[:, p, :, :, -(-seen // block) * block:] = np.nan
+    poisoned[0] = np.nan                        # another layer's rows
+    got = jax.jit(lambda q, kv, m, live: mla.attend_cache(
+        jnp.swapaxes(q, 1, 2), kv, 1, m, live, 3.0, kv_lora, block))(
+        q, jnp.asarray(poisoned, dt), jnp.asarray(mask), jnp.asarray(live))
+    got = np.asarray(jnp.swapaxes(got, 1, 2).astype(jnp.float32))
+    assert got.shape == (lanes, c, h, kv_lora)
+    for p in range(lanes):
+        if not live[p]:
+            assert not got[p].any()
+            continue
+        one = (q[p:p + 1], jnp.asarray(kv[1, p], dt),
+               jnp.asarray(mask[p:p + 1]), 3.0)
+        for want in (mla.attend_latents(*one, key_block=block),
+                     mla.attend_latents(*one)):
+            np.testing.assert_allclose(
+                got[p], np.asarray(want[0, ..., :kv_lora], np.float32),
+                atol=tol)
+
+
+def test_the_kernels_work_list_has_no_block_past_a_lanes_rows():
+    rows = jnp.asarray([300, 0, 0, 1, 512], jnp.int32)
+    lane, src, at, items = (np.asarray(x) for x in mla._cache_work(
+        rows, 128, 4))
+    assert int(items) == 3 + 1 + 1 + 1 + 4
+    n = int(items)
+    assert lane[:n].tolist() == [0, 0, 0, 1, 2, 3, 4, 4, 4, 4]
+    # a lane that stands reads what the item before it read: nothing moves
+    assert src[:n].tolist() == [0, 0, 0, 0, 0, 3, 4, 4, 4, 4]
+    assert at[:n].tolist() == [0, 1, 2, 2, 2, 0, 0, 1, 2, 3]
+    assert lane.shape == (20,) and at.max() <= 3 and src.max() <= 4
+
+
 # ------------------------------------------------- the model and its cache
 
 def test_pattern_weights_and_counts(world):
@@ -272,6 +347,57 @@ def test_lanes_with_a_lane_that_stands(world):
     np.testing.assert_allclose(logits[2], w.want[1, 29], **TOL)
     assert not np.asarray(cache["kv"][:, 1]).any() \
         and not np.asarray(cache["k_idx"][:, 1]).any()
+
+
+@pytest.mark.parametrize("program", ["lanes", "chunk"])
+def test_programs_with_the_kernel_are_the_programs_with_the_loop(
+        world, monkeypatch, program):
+    """The lanes program (a lane that stands) and the batch-1 chunk program
+    at a shape the kernel takes (latents of 128, blocks of 128 rows, read
+    blocked whatever the scores' size): the kernel through the interpreter
+    against XLA's loop, logits and every array of the cache."""
+    generate = importlib.import_module("ray_tpu.models.generate")
+    w = world
+    cfg = dataclasses.replace(w.cfg, kv_lora_rank=128)
+    params = jax.jit(lambda k: init_params(k, cfg)[0])(jax.random.PRNGKey(5))
+    monkeypatch.setattr(
+        generate, "_key_block",
+        lambda c, heads, rows: sparse_index.key_block(rows))
+    calls, kernel = [], mla.attend_cache
+    monkeypatch.setattr(mla, "attend_cache", lambda *a: calls.append(
+        a[0].shape) or kernel(*a))
+    chunk, max_len = 16, 384
+
+    def walk(interpret):
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", interpret)
+        logits = {}
+        if program == "chunk":
+            fn = jax.jit(generate.prefill_chunk, static_argnames=("cfg",))
+            logits[0], cache = generate.prefill_chunked(
+                params, w.toks[0:1, :61], cfg,
+                init_kv_cache(cfg, 1, max_len), chunk=chunk, _jitted=fn)
+            return logits, cache
+        fn = jax.jit(generate._lanes_program, static_argnames=("cfg",))
+        cache = init_slot_cache(cfg, 3, max_len)
+        prompts = [(np.asarray(w.toks[0:1, :45]), 0), None,
+                   (np.asarray(w.toks[1:2, :30]), 0)]
+        while any(p is not None for p in prompts):
+            lg, cache, moved = prefill_lanes_step(
+                fn, params, prompts, cache, cfg, chunk=chunk,
+                capacity=max_len)
+            for p, m in enumerate(moved):
+                if m is not None:
+                    logits[p] = lg[p]
+                    prompts[p] = (prompts[p][0], m[0]) \
+                        if m[0] < prompts[p][0].shape[1] else None
+        return logits, cache
+
+    (want, cache_w), (got, cache_g) = walk("0"), walk("1")
+    for p in want:
+        np.testing.assert_allclose(got[p], want[p], **TOL)
+    for name in cache_w:
+        np.testing.assert_allclose(cache_g[name], cache_w[name], **TOL)
+    assert calls
 
 
 def _two_slots(w, depths):
