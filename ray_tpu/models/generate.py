@@ -71,7 +71,12 @@ EVERY program pools the chunks its new tokens reach from the ring as written
 behind the query: no branch on where a chunk ends, and, unlike a conv state,
 a summary pooled over tokens written ahead of a row's ``pos`` is pooled
 again by the program that feeds the true ones, so a chunk window set back
-need not be refused for it.
+need not be refused for it.  A DECODE STEP (one query a slot) attends both
+row sets by ONE kernel call a layer that fetches only the 128-row blocks a
+slot's masks let it see (`ops/cache_attention.py`), wherever the program is
+lowered for a TPU and the arrays' rows are whole blocks; a chunk's queries,
+and every other platform, take the dense form under the same masks
+(`ops/eva_attention.py` `attend_two`).
 
 A FIFTH KIND OF STATE CHOOSES WHAT THE OTHERS' ROWS ARE READ FOR: a latent-
 attention model with an indexer (`ops/sparse_index.py`; layer kinds
@@ -137,6 +142,7 @@ import numpy as np
 
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ..ops import cache_attention
 from ..ops import eva_attention as eva
 from ..ops import latent_attention as mla
 from ..ops import sparse_index
@@ -261,6 +267,51 @@ def column_write_counts(cache: KVCache) -> Tuple[int, int]:
               if _state_kind(name) != "state"]
     return (sum(shape[0] * shape[1] for shape in arrays),
             sum(shape[0] * device_calls(shape) for shape in arrays))
+
+
+def rows_fetched(cache: KVCache, cfg: TransformerConfig):
+    """A decode step over this slot cache → ``count(positions)``: the cache
+    rows its attention MOVES from memory for live slots that stand at
+    ``positions`` (a key and the value beside it are one row, as the serve
+    engine's ``rows_read`` counts them; an indexer's keys are counted by
+    neither).  Dense dots under a mask move every row of every slot's arrays,
+    whatever the positions and live or not; where `ops/cache_attention.py`'s
+    kernel engages on this process's backend (a summary layer's step) a live
+    slot's blocks alone, the host's count of the kernel's work list
+    (`cache_attention.fetched_blocks`).  Host counts from shapes."""
+    arrays = cache_arrays(cache)
+    names = ("kv",) if cfg.attention == "mla" else tuple(
+        _kv_names(kind)[0] for kind in ATTENTION_KINDS if kind in cfg.kinds) \
+        + (_SUM_NAMES[:1] if "eva" in cfg.kinds else ())
+    rows = {name: arrays[name].shape[-1] for name in names}
+    if _CONV_STATE in arrays:       # a state's rows are its taps
+        rows[_CONV_STATE] = arrays[_CONV_STATE].shape[-2]
+    blocked = ()
+    if "eva" in cfg.kinds:
+        kn, vn = _kv_names("eva")
+        hk = cfg.kv_heads_of("eva")
+        if cache_attention.engages(
+                (1, 1, hk, cfg.n_heads // hk, cfg.head_dim),
+                [(arrays[kn], arrays[vn], None),
+                 (*(arrays[n] for n in _SUM_NAMES), None)]):
+            blocked = (rows.pop(kn), rows.pop(_SUM_NAMES[0]))
+    dense = sum(arrays[name].shape[0] * arrays[name].shape[1] * n
+                for name, n in rows.items())
+    if not blocked:
+        return lambda positions: dense
+    layers, (ring, sums) = arrays[kn].shape[0], blocked
+    window, chunk = cfg.sliding_window, cfg.summary_chunk
+
+    def count(positions) -> int:
+        blocks = 0
+        for pos in positions:
+            first = pos // window * window
+            blocks += cache_attention.fetched_blocks(
+                first % ring, pos - first + 1, ring) \
+                + cache_attention.fetched_blocks(0, first // chunk, sums)
+        return dense + layers * blocks * cache_attention.BLOCK
+
+    return count
 
 
 def _state_kind(name: str) -> str:
@@ -773,13 +824,31 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         names = (kn, vn) + _SUM_NAMES
         masks = (mask[kind], mask["summary"])
         qh = q.reshape(-1, c, hk, h // hk, hd)
-        if lanes is None:
-            attn = eva.attend_two(
-                qh, *(_layer_of(arrs[n], l) for n in names), *masks)
-        else:
+
+        def dense(qh, k_a, v_a, k_b, v_b, m_a, m_b, *live):
+            # every row of both sets, under the masks
+            return eva.attend_two(qh, *(_layer_of(a, l) for a in (
+                k_a, v_a, k_b, v_b)), m_a, m_b)
+
+        def in_place(qh, k_a, v_a, k_b, v_b, m_a, m_b, *live):
+            # one query a slot: the blocks a slot sees, where they lie
+            return cache_attention.attend_blocks(
+                qh, [(k_a, v_a, m_a), (k_b, v_b, m_b)], l, *live)
+
+        if lanes is not None:
             attn = _by_lane(lanes, eva.attend_two, lambda p: (
                 qh[p:p + 1], *(_lane_of(arrs[n], l, p) for n in names),
                 *(m[p:p + 1] for m in masks)))
+        elif cache_attention.kernel_shape(qh.shape, [
+                (arrs[kn], arrs[vn], masks[0]),
+                (*(arrs[n] for n in _SUM_NAMES), masks[1])]):
+            # (a row whose token is not real stands: its result is thrown
+            # away, so nothing of its cache is fetched for it)
+            attn = mla.on_the_chip(
+                in_place, dense, qh, *(arrs[n] for n in names), *masks,
+                *(() if valid is None else (valid[:, 0],)))
+        else:
+            attn = dense(qh, *(arrs[n] for n in names), *masks)
         return _attn_out(cfg, y, attn.reshape(b, c, h, -1), lp), arrs
 
     def attend_mla(y, lp, arrs, l, kind, sel):

@@ -228,7 +228,7 @@ class _Step(NamedTuple):
     batch: List[Tuple[_EngineSession, int]]   # its live sessions, each
     #                                           with the slot it held THEN
     out: Any          # [slots (+3)] int32 on the device: tokens (+ routing)
-    rows: Tuple[int, ...]     # `_rows_of` its batch, `_WRITE_SUMS`
+    rows: Tuple[int, ...]     # `_rows_of` its batch .. `_WRITE_SUMS`
     # when the chip started on it, where the host can tell: a
     # ``perf_counter`` reading (nothing was queued before it), `_BEHIND`
     # (queued right behind the step before it: when that one's read
@@ -434,11 +434,13 @@ class ContinuousBatchingEngine:
         # same in BYTES, a row costing what its layer's kind holds a
         # position (`_row_bytes`), beside what the rows would cost were
         # every layer a full one at the model's widest key-value heads;
+        # and the rows the steps' attention MOVED to attend those (every
+        # row of every slot where dense dots read the arrays whole);
         # and the columns the steps WROTE beside the device calls that
         # wrote them (one an array a layer where the kernel engages)
         self.rows = dict.fromkeys(
             ("steps",) + self._ROW_SUMS + self._INDEX_SUMS
-            + self._WRITE_SUMS, 0)
+            + self._FETCH_SUMS + self._WRITE_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
         from ..models.generate import position_bytes
         self._row_bytes = position_bytes(cfg)
@@ -1256,7 +1258,7 @@ class ContinuousBatchingEngine:
         import numpy as np
 
         from ..models import init_slot_cache
-        from ..models.generate import column_write_counts
+        from ..models.generate import column_write_counts, rows_fetched
         from ..util import fault_injection as fi
         from ..util import tracing
         if self._cache is None:
@@ -1264,6 +1266,8 @@ class ContinuousBatchingEngine:
                                           self.max_len)
         # `_WRITE_SUMS` of one fused step: the cache's shapes say it
         self._writes_a_step = column_write_counts(self._cache)
+        # `_FETCH_SUMS` of one, from its live slots' positions
+        self._fetched = rows_fetched(self._cache, self.cfg)
         slots = self.ecfg.max_slots
         self._carry = self._fresh_carry()
         self._warm_lanes()
@@ -1381,7 +1385,7 @@ class ContinuousBatchingEngine:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
         rows = self._rows_of(batch) + self._index_rows_of(batch) \
-            + self._writes_a_step
+            + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
         flight = self._flight
         with self._cond:
             for s in batch:
@@ -1447,6 +1451,9 @@ class ContinuousBatchingEngine:
     #: ... and what `_index_rows_of` does: the index keys an indexer scored
     #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
     _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
+    #: ... and the rows the step's attention MOVES from the cache to attend
+    #: `rows_read` of them (`models.generate.rows_fetched`)
+    _FETCH_SUMS = ("rows_fetched",)
     #: ... and beside them what a step WRITES, whatever its positions
     #: (`models.generate.column_write_counts`): a column a slot, live or
     #: not, a layer of every array that holds positions, and the device
@@ -1529,7 +1536,7 @@ class ContinuousBatchingEngine:
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
             for k, n in zip(self._ROW_SUMS + self._INDEX_SUMS
-                            + self._WRITE_SUMS, rows):
+                            + self._FETCH_SUMS + self._WRITE_SUMS, rows):
                 self.rows[k] += n
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,

@@ -427,6 +427,64 @@ def test_byte_model_step_writes_one_call_an_array_on_the_v5e(topo):
     _one_write_an_array(text, cache)
 
 
+def test_byte_model_step_attends_the_blocks_a_slot_sees_on_the_v5e(topo):
+    """The same step: its attention is ONE call of `ops/cache_attention.py`'s
+    kernel a summary layer (one in the layer loop's body) over the four
+    arrays where they lie, and no dot over a slot's 2176 ring rows or 1664
+    summary rows is left (their float32 scores went with them); the chunk
+    program and the lanes program, whose rows hold a chunk of queries, lower
+    without the kernel, as the parent's."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import (init_kv_cache, init_params, init_slot_cache,
+                                prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots
+    c = mf.Manifest().config("evabyte")
+    cfg = mf.family_of(c).model.model_config(
+        dict(c, num_hidden_layers=2), "serve")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 12, 26624
+    cache = described(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)))
+
+    def fused_step(params, tok, cache, active):
+        logits, cache, _ = _decode_step_slots(params, tok, cache, active,
+                                              cfg)
+        return jnp.argmax(logits[..., :cfg.vocab_size], axis=-1), cache
+    text = jax.jit(fused_step, donate_argnums=(2,)).lower(
+        params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    ).compile().as_text()
+    calls = [x for x in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+             if "cache_block_attention" in x]
+    assert len(calls) == 1, calls
+    for shape in ("2,12,32,128,2176", "2,12,32,128,1664"):
+        assert calls[0].count(f"bf16[{shape}]") == 2, calls[0]   # k and v
+    scores = re.findall(r"f32\[[\d,]*,(?:2176|1664)\]", text)
+    assert not scores, scores[:4]
+    # a chunk of queries a row: the dense form under `_by_lane`, or whole
+    chunk = jax.jit(prefill_chunk, static_argnames=("cfg",)).lower(
+        params, described(jax.ShapeDtypeStruct((1, 128), jnp.int32)),
+        described(jax.eval_shape(lambda: init_kv_cache(cfg, 1, max_len))),
+        cfg=cfg, n_valid=described(jax.ShapeDtypeStruct((), jnp.int32)))
+    _, lanes, _, _ = _lower_lanes(described, params, cfg,
+                                  "prefill_lanes_4x128", max_len)
+    for lowered in (chunk, lanes):
+        assert "cache_block_attention" not in lowered.as_text()
+
+
 def _lower_lanes(described, params, cfg, program, max_len):
     """``prefill_lanes_<P>x<C>``: the chunk program over P lanes of C rows
     (`models.generate.prefill_lanes`, what the engine runs while two or more

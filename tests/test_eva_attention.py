@@ -34,7 +34,8 @@ from ray_tpu.models import (cache_gather_slot, cache_insert_slot,
                             prefill_lanes_jit)
 from ray_tpu.models.generate import (_state_kind, cache_bytes,
                                      cache_capacity, cache_rows,
-                                     position_bytes, prefill_chunk_step,
+                                     greedy_tokens, position_bytes,
+                                     prefill_chunk_step,
                                      prefill_lanes_step, window_ring)
 from ray_tpu.ops.attention import reference_attention
 from ray_tpu.ops.eva_attention import eva_attention
@@ -201,6 +202,50 @@ def test_slots_decode_through_three_windows(world):
     assert at == {0: T, 2: T}
     logits, _ = _chunked(w, 0, 70, seed, off=45)
     np.testing.assert_allclose(logits[0], w.want[0, 69], **TOL)
+
+
+def test_a_step_through_the_block_kernel_is_the_dense_step(world,
+                                                           monkeypatch):
+    """The fused step with `ops/cache_attention.py`'s kernel (through the
+    interpreter; rings of 384 rows for windows of 256 and 128 summary rows,
+    whole blocks of 128) against the step without it: slot 0 decodes from
+    250 through the window's edge at 256 (its ring's live range restarts at
+    one row, its first summaries appear), slot 1 stands, slot 2 decodes from
+    300; the same greedy tokens, the logits inside the file's tolerance."""
+    max_len = 512
+    cfg = dataclasses.replace(world.cfg, sliding_window=256,
+                              window_chunk=128, max_seq_len=max_len)
+    assert window_ring(cfg, max_len) == 384
+    toks = mf.family_of(world.c).model.tokens(
+        jax.random.PRNGKey(9), (2, 300), world.c)
+    slots = init_slot_cache(cfg, 3, max_len)
+    first = {}
+    for slot, n in ((0, 250), (2, 300)):
+        logits, one = prefill(world.params, toks[slot // 2:slot // 2 + 1, :n],
+                              cfg, init_kv_cache(cfg, 1, max_len))
+        slots = cache_insert_slot(slots, one, jnp.int32(slot))
+        first[slot] = int(greedy_tokens(logits, cfg)[0])
+    live = jnp.asarray([True, False, True])
+
+    def run(step):
+        cache, tok = slots, jnp.asarray([first[0], 0, first[2]])
+        out = []
+        for _ in range(12):
+            logits, cache = step(world.params, tok, cache, live)
+            tok = jnp.where(live, greedy_tokens(logits, cfg), 0)
+            out.append((np.asarray(tok), np.asarray(logits)))
+        return out, cache
+
+    def fresh():    # a trace is cached by the function: a new one a path
+        return jax.jit(functools.partial(decode_step_slots, cfg=cfg))
+
+    dense, _ = run(fresh())
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    kernel, cache = run(fresh())
+    assert cache["pos"].tolist() == [262, 0, 312]
+    for (t_d, l_d), (t_k, l_k) in zip(dense, kernel):
+        assert t_d.tolist() == t_k.tolist()
+        np.testing.assert_allclose(l_k[[0, 2]], l_d[[0, 2]], **TOL)
 
 
 def test_prefix_exact_says_which_donor_still_holds_a_prefix():
