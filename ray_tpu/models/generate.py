@@ -9,12 +9,15 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
-A cache holds up to FIVE KINDS OF STATE behind the same functions (`cache_rows`,
+A cache holds up to SIX KINDS OF STATE behind the same functions (`cache_rows`,
 `position_bytes`, `cache_bytes`, the slot insert and gather): rows for the
 whole context (``full``), a ring of a window's rows (``ring``), a conv
-layer's last inputs (``state``), a row a chunk of positions (``summary``)
-and an indexer's keys on the indexing layers alone (``index``); they follow
-one by one below.
+layer's last inputs (``state``), a row a chunk of positions (``summary``),
+an indexer's keys on the indexing layers alone (``index``) and a KDA
+layer's matrix of state a head beside its convolutions' last inputs
+(``delta``); they follow one by one below.  A cache's arrays may differ in
+TYPE (`array_dtype`): a delta state is float32 whatever the model computes
+in.
 
 What a cache holds is a property of the model's attention kind
 (`cache_rows`): keys and values of ``kv_heads x head_dim`` (``"k"``,
@@ -95,11 +98,25 @@ chunk's tail, an inactive slot's token, a former session's stale rows lie
 outside it), so what a program writes ahead of ``pos`` is as harmless here
 as in a full layer.
 
+A SIXTH KIND OF STATE IS A MATRIX A HEAD: a ``"kda"`` layer
+(`ops/delta_rule.py`, `transformer.kda_operator`) carries ``s_delta``
+``[L_kda, batch, heads, dim, dim]`` in FLOAT32, which every token decays,
+corrects and reads WHOLE, and the last ``kda_conv_kernel - 1`` inputs of its
+three convolutions, ``conv_delta`` ``[L_kda, batch, 1, taps - 1, 3 x heads x
+dim]`` in the model's type: 2 MB and 72 KB a slot a layer at 32 heads of
+128, whatever ``max_len``.  It lives by the conv state's rules: every
+program advances a row by its VALID tokens only (a padded chunk's tail
+neither decays nor writes, a slot that is not active keeps both arrays bit
+for bit), and `_check_state_rewind` refuses what would need it taken back.
+A decode step and a chunk run the same recurrence in two forms
+(`delta_rule.step`, `delta_rule.chunk`), and the fused step writes the state
+into its layer of the donated array in place.
+
 A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
 summary arrays say it (`cache_capacity`, which then needs the model's
 ``summary_chunk``), and nothing stands in for a full layer.  What still
-cannot be served is a model of window layers or conv layers alone
-(`_check_decodable`).  A model with several prediction heads
+cannot be served is a model of window layers, conv layers or KDA layers
+alone (`_check_decodable`).  A model with several prediction heads
 (``pred_heads``) hands out every head's logits; a served token is drawn
 from head 0's (`next_token_logits`).
 
@@ -153,8 +170,8 @@ from ..ops.short_conv import conv_block, conv_inputs, short_conv
 from .transformer import (ATTENTION_KINDS, SPARSE_KINDS, TransformerConfig,
                           _attn_out, _ffn, _layer, _norm, _post, _qkv,
                           _scale_embedding, _unembed, check_kinds,
-                          index_inputs, norm_eps, rope_tables,
-                          scan_layer_runs)
+                          index_inputs, kda_operator, latent_queries,
+                          norm_eps, rope_tables, scan_layer_runs)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -166,7 +183,12 @@ _RING = "_win"      # suffix of a window layer's arrays: rings
 _STATE = "_state"   # suffix of a conv layer's array: no positions at all
 _SUMMARY = "_sum"   # suffix of a summary layer's arrays: a row a CHUNK
 _INDEX = "_idx"     # suffix of an indexing layer's array: the indexer's keys
+_DELTA = "_delta"   # suffix of a KDA layer's arrays: no positions either
 _CONV_STATE = "conv" + _STATE
+_DELTA_STATE = "s" + _DELTA         # a matrix a head, float32
+_DELTA_CONV = "conv" + _DELTA       # its convolutions' last inputs
+#: the kinds of state that hold no positions: a sequence's whatever its length
+_NO_POSITIONS = ("state", "delta")
 _SUM_NAMES = ("k" + _SUMMARY, "v" + _SUMMARY)
 _INDEX_ARRAY = "k" + _INDEX
 #: the kinds of layer whose arrays hold a row a position for the whole
@@ -184,6 +206,9 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     (`TransformerConfig.kv_heads_of`), and a value's width beside a key's."""
     state = {_CONV_STATE: (1, cfg.conv_kernel - 1)} \
         if "conv" in cfg.kinds else {}
+    if "kda" in cfg.kinds:      # (key dims, value dims last) a head; taps
+        state.update({_DELTA_STATE: (cfg.kda_heads, cfg.kda_head_dim),
+                      _DELTA_CONV: (1, cfg.kda_conv_kernel - 1)})
     if cfg.attention == "mla":
         rows = dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
         if "index" in cfg.kinds:    # ONE key a position, no value
@@ -205,15 +230,30 @@ def position_bytes(cfg: TransformerConfig) -> Dict[str, int]:
     (``full``, ``ring``; ``index``, of a model with an indexer), a chunk of
     positions (``summary``, of a model that has summaries), or a sequence
     whatever its positions (``state``:
-    a conv layer's ``conv_kernel - 1`` inputs of ``d_model``): what a
-    decode step reads of a row it attends, by the row's kind."""
+    a conv layer's ``conv_kernel - 1`` inputs of ``d_model``; ``delta``: a
+    KDA layer's float32 matrix a head and its convolutions' inputs): what a
+    decode step reads of a row it attends, by the row's kind, each array at
+    its own element size."""
     out = {"full": 0, "ring": 0, "state": 0}
-    item = jnp.dtype(cfg.dtype).itemsize
     for name, (heads, width) in cache_rows(cfg).items():
         kind = _state_kind(name)
-        out[kind] = out.get(kind, 0) + heads * width * item * (
-            cfg.d_model if kind == "state" else 1)
+        out[kind] = out.get(kind, 0) + heads * width \
+            * jnp.dtype(array_dtype(cfg, name)).itemsize \
+            * (_own_rows(cfg, name) or 1)
     return out
+
+
+def array_dtype(cfg: TransformerConfig, name: str):
+    """The element type of the cache array ``name``: the model's, but a
+    delta state's float32 (a state held in less is another result)."""
+    return jnp.float32 if name == _DELTA_STATE else cfg.dtype
+
+
+def _own_rows(cfg: TransformerConfig, name: str) -> Optional[int]:
+    """The last axis of an array that holds no positions (None: it does):
+    a convolution's channels, a delta state's value dims."""
+    return {_CONV_STATE: cfg.d_model, _DELTA_STATE: cfg.kda_head_dim,
+            _DELTA_CONV: 3 * cfg.kda_heads * cfg.kda_head_dim}.get(name)
 
 
 def _kv_names(kind: str) -> Tuple[str, str]:
@@ -242,14 +282,15 @@ def cache_capacity(cache: KVCache,
     if _SUM_NAMES[0] in arrays and "k" not in arrays:
         return arrays[_SUM_NAMES[0]].shape[-1] * cfg.summary_chunk
     return max(a.shape[-1] for name, a in arrays.items()
-               if not name.endswith(_STATE))
+               if _state_kind(name) not in _NO_POSITIONS)
 
 
 def cache_bytes(cache: KVCache) -> Dict[str, int]:
     """Bytes of a cache's arrays by state kind: ``full`` (rows for the
     whole context), ``ring`` (window layers), ``state`` (conv layers) and,
-    where the cache has them, ``summary`` (a row a chunk) and ``index`` (an
-    indexer's keys)."""
+    where the cache has them, ``summary`` (a row a chunk), ``index`` (an
+    indexer's keys) and ``delta`` (KDA layers: float32 states and the
+    convolutions' inputs)."""
     out = {"full": 0, "ring": 0, "state": 0}
     for name, a in cache_arrays(cache).items():
         kind = _state_kind(name)
@@ -264,7 +305,7 @@ def column_write_counts(cache: KVCache) -> Tuple[int, int]:
     process's backend, `ops.cache_write.device_calls`): host counts from
     shapes."""
     arrays = [a.shape for name, a in cache_arrays(cache).items()
-              if _state_kind(name) != "state"]
+              if _state_kind(name) not in _NO_POSITIONS]
     return (sum(shape[0] * shape[1] for shape in arrays),
             sum(shape[0] * device_calls(shape) for shape in arrays))
 
@@ -315,10 +356,11 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
 
 
 def _state_kind(name: str) -> str:
-    """``full`` | ``ring`` | ``state`` | ``summary`` | ``index``: what kind
-    of state an array is."""
+    """``full`` | ``ring`` | ``state`` | ``summary`` | ``index`` |
+    ``delta``: what kind of state an array is."""
     for suffix, kind in ((_RING, "ring"), (_STATE, "state"),
-                         (_SUMMARY, "summary"), (_INDEX, "index")):
+                         (_SUMMARY, "summary"), (_INDEX, "index"),
+                         (_DELTA, "delta")):
         if name.endswith(suffix):
             return kind
     return "full"
@@ -334,13 +376,13 @@ def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     stacks = {"full": (_ROW_KINDS, max_len),
               "index": (("index",), max_len),
               "ring": (("window", "eva"), window_ring(cfg, max_len)),
-              "state": (("conv",), cfg.d_model),
+              "state": (("conv",), None), "delta": (("kda",), None),
               "summary": (("eva",), max_len // max(1, cfg.summary_chunk))}
     for name, (heads, width) in cache_rows(cfg).items():
         kinds, rows = stacks[_state_kind(name)]
         cache[name] = jnp.zeros(
-            (sum(k in kinds for k in cfg.kinds), batch, heads, width, rows),
-            cfg.dtype)
+            (sum(k in kinds for k in cfg.kinds), batch, heads, width,
+             _own_rows(cfg, name) or rows), array_dtype(cfg, name))
     cache["pos"] = pos
     return cache
 
@@ -359,23 +401,25 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         raise NotImplementedError(
             "KV-cache decode over a pipeline mesh is not supported; "
             "serve pp-sharded models stage-per-gang instead")
-    if cfg.attention == "mla" and cfg.pos_emb != "rope":
+    if cfg.attention == "mla" and cfg.pos_emb == "learned":
         raise NotImplementedError(
-            "latent attention keeps a rotary key beside its latent: "
-            "pos_emb must be 'rope'")
+            "latent attention keeps a shared key beside its latent, turned "
+            "or as projected: pos_emb is 'rope' or 'none', not a learned "
+            "table")
     kinds = set(cfg.kinds)
     if len(cfg.kinds) != cfg.n_layers or \
-            kinds - {"full", "window", "conv", "eva", *SPARSE_KINDS}:
+            kinds - {"full", "window", "conv", "eva", "kda", *SPARSE_KINDS}:
         raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
                          f"{cfg.n_layers} of 'full' | 'window' | 'conv' | "
-                         f"'eva', or of 'index' | 'shared'")
+                         f"'eva' | 'kda', or of 'index' | 'shared'")
     check_kinds(cfg)
     if "conv" in kinds and cfg.conv_kernel < 2:
         raise ValueError("conv layers need conv_kernel of at least 2")
     if not kinds & {"full", "eva", "index"}:
         raise NotImplementedError(
             "a model without a full-attention layer or a summary layer (of "
-            "window layers only, of conv layers, of both) is not served: "
+            "window layers only, of conv or KDA layers, of those) is not "
+            "served: "
             "the rows a session may reach (max_len) are read off a full "
             "layer's array or a summary layer's, and neither a ring nor a "
             "state has them")
@@ -401,7 +445,7 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "key-value heads by layer kind, a sink, a value scale and a "
             "rotated share of a head are MHA/GQA's; latent attention has "
             "none of them")
-    for kind in kinds - {"conv"}:
+    for kind in kinds - {"conv", "kda"}:
         if cfg.attention == "mha" and cfg.n_heads % cfg.kv_heads_of(kind):
             raise ValueError(
                 f"{cfg.n_heads} query heads over {cfg.kv_heads_of(kind)} "
@@ -430,14 +474,14 @@ def _check_chunk(cfg: TransformerConfig, c: int) -> None:
 
 
 def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
-    """What would need a conv layer's state taken BACK is refused, not
-    answered wrongly: a state has no position to mask and no later write
-    that repairs it, so tokens fed twice (a chunk window set back at the
-    cache's end) have already shifted it."""
-    if "conv" in cfg.kinds:
+    """What would need a conv or a KDA layer's state taken BACK is
+    refused, not answered wrongly: a state has no position to mask and no
+    later write that repairs it, so tokens fed twice (a chunk window set
+    back at the cache's end) have already shifted it."""
+    if {"conv", "kda"} & set(cfg.kinds):
         raise ValueError(
-            f"{what} over conv layers: their state cannot be taken back "
-            f"to an earlier token (models/generate.py)")
+            f"{what} over conv or KDA layers: their state cannot be taken "
+            f"back to an earlier token (models/generate.py)")
 
 
 @jax.named_scope("attention")
@@ -693,6 +737,18 @@ def _place_state(s_all: jnp.ndarray, l, state: jnp.ndarray) -> jnp.ndarray:
         s_all, state[None, :, None].astype(s_all.dtype), (l, 0, 0, 0, 0))
 
 
+@jax.named_scope("cache_write")
+def _place_delta(arrs: Arrays, l, state: jnp.ndarray, conv: jnp.ndarray
+                 ) -> Arrays:
+    """Every row's delta state [B, heads, dim, dim] and convolution inputs
+    [B, taps - 1, channels] into KDA layer ``l`` of their arrays."""
+    return dict(arrs, **{
+        _DELTA_STATE: jax.lax.dynamic_update_slice(
+            arrs[_DELTA_STATE], state[None].astype(jnp.float32),
+            (l, 0, 0, 0, 0)),
+        _DELTA_CONV: _place_state(arrs[_DELTA_CONV], l, conv)})
+
+
 def _rotators(turn, angles: Dict[str, Any]) -> Dict[str, Any]:
     """`rope_tables`' ``{kind: (cos, sin)}`` -> ``{kind: t -> turn(t, cos,
     sin)}``: what `_attend_cached` takes as ``rotate``."""
@@ -853,12 +909,10 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
 
     def attend_mla(y, lp, arrs, l, kind, sel):
         # absorbed: the chunk's few queries over the cached latents
-        q_nope, q_rope, c_q = mla.queries(
-            y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
-            nope=cfg.qk_nope_head_dim, eps=eps, rotate=rotate[kind])
+        turn = rotate.get(kind, mla.no_turn)
+        q_nope, q_rope, c_q = latent_queries(cfg, y, lp, turn)
         new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
-                          kv_lora=cfg.kv_lora_rank, eps=eps,
-                          rotate=rotate[kind])
+                          kv_lora=cfg.kv_lora_rank, eps=eps, rotate=turn)
         kv_all = write[kind](arrs["kv"], l, _as_columns(
             new[:, :, None, :], arrs["kv"].dtype))
         arrs = dict(arrs, kv=kv_all)
@@ -924,7 +978,15 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         return delta, dict(arrs, **{_CONV_STATE: _place_state(
             s_all, l, state)})
 
-    operator = {"conv": conv, "eva": attend_eva}
+    def kda(y, lp, arrs, l, kind):
+        # the delta state and the convolutions' inputs of layer l, read
+        # whole, advanced by the rows' valid tokens, written back
+        delta, state, taps = kda_operator(
+            cfg, y, lp, _layer_of(arrs[_DELTA_STATE], l),
+            _layer_of(arrs[_DELTA_CONV], l)[:, 0], n_new)
+        return delta, _place_delta(arrs, l, state, taps)
+
+    operator = {"conv": conv, "eva": attend_eva, "kda": kda}
 
     def layer(xc, lp, arrs, l, kind, sel):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
@@ -970,7 +1032,7 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         if cfg.attention == "mla":
             new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
                               kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
-                              rotate=rotate[kind])
+                              rotate=rotate.get(kind, mla.no_turn))
             return {"kv": new[:, :, None, :]}
         _, k, v = _qkv(cfg, y, lp, rotate.get(kind), kind)
         return dict(zip(_kv_names(kind), (k, v)))
@@ -1010,6 +1072,12 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
                                   lp["conv_w"])
             arrs = dict(arrs, **{_CONV_STATE: _place_state(
                 arrs[_CONV_STATE], l, state)})
+        elif kind == "kda":     # ... and its delta state, from zeros
+            s_all, c_all = arrs[_DELTA_STATE], arrs[_DELTA_CONV]
+            arrs = _place_delta(arrs, l, *kda_operator(
+                cfg, y, lp, jnp.zeros(s_all.shape[1:], s_all.dtype),
+                jnp.zeros(c_all.shape[1:2] + c_all.shape[3:], c_all.dtype)
+            )[1:])
         else:
             new = columns(y, lp, kind)
             arrs = dict(arrs, **{

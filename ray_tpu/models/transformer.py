@@ -67,8 +67,21 @@ Design (idiomatic JAX, not a torch translation):
     carry of `scan_layer_runs` from an indexing layer to the shared layers
     behind it, across the boundary of two runs too.  Indexer weights are
     stacked over the indexing layers only, and a served cache holds the
-    indexer's keys on those layers alone: the fifth of its five kinds of
+    indexer's keys on those layers alone: the fifth of its six kinds of
     state (`models/generate.py`).
+
+  * a ``"kda"`` layer's operator is no attention either but a GATED DELTA
+    RULE (`ops/delta_rule.py`; `kda_operator`): queries, keys and values
+    of ``kda_heads x kda_head_dim`` through a depthwise causal convolution
+    of ``kda_conv_kernel`` taps, then a MATRIX of state a head in float32
+    that every token decays channel by channel, corrects and reads.  Its
+    weights are stacked over the KDA layers alone, beside the stacks of the
+    attention layers of the same run (MHA/GQA or latent attention), and a
+    served cache holds its state and its convolutions' last inputs: the
+    sixth kind, whose arrays differ from the rest in TYPE.  A latent-
+    attention model may project its queries directly (``q_lora_rank`` 0:
+    no query latent, no query norm) and turn nothing (``pos_emb`` neither
+    ``"rope"`` nor ``"learned"``: no position enters the model at all).
 
   * what a block, the stream and the head are may differ too, each a
     property with the plain model as its default: an RMSNorm that
@@ -113,7 +126,8 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None  # None → MHA
     d_ff: Optional[int] = None        # None → 4*d_model (gelu) / 8/3 (swiglu)
     max_seq_len: int = 1024
-    pos_emb: str = "learned"          # "learned" | "rope"
+    pos_emb: str = "learned"          # "learned" | "rope" | "none": no
+    #   position enters the model (its state layers give it order)
     activation: str = "gelu"          # "gelu" | "swiglu"
     norm: str = "layernorm"           # "layernorm" | "rmsnorm"
     tie_embeddings: bool = True
@@ -152,7 +166,7 @@ class TransformerConfig:
     #   width ff_dim before the expert layers (only with n_experts > 0)
     # -- latent attention (ops/latent_attention.py) -------------------------
     attention: str = "mha"            # "mha" | "mla"
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0              # 0: queries projected directly
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -168,8 +182,9 @@ class TransformerConfig:
     embed_scale: float = 1.0          # multiplies the token embedding
     # -- kinds of layer mixed -----------------------------------------------
     layer_kinds: Optional[Tuple[str, ...]] = None  # a layer "window" |
-    #   "full" | "conv" | "eva", or of a model with an indexer "index" |
-    #   "shared", in model order (None → all full); may repeat inside a run
+    #   "full" | "conv" | "eva" | "kda", or of a model with an indexer
+    #   "index" | "shared", in model order (None → all full); may repeat
+    #   inside a run
     conv_kernel: int = 3              # a conv layer's taps; its state is
     #   the last conv_kernel - 1 inputs of the convolution a sequence
     sliding_window: int = 0           # a window layer's position i sees
@@ -208,6 +223,14 @@ class TransformerConfig:
     #   indexer): ``layer_kinds`` is then "index" (scores, chooses, attends
     #   its choice) | "shared" (attends the choice of the nearest indexing
     #   layer before it; holds no indexer weights) for every layer
+    # -- a gated delta rule in attention's place (ops/delta_rule.py) ---------
+    kda_heads: int = 0                # a "kda" layer's heads (0: none) ...
+    kda_head_dim: int = 0             # ... of this width, key and value: a
+    #   sequence carries kda_heads x kda_head_dim x kda_head_dim float32
+    kda_conv_kernel: int = 4          # taps of the depthwise convolution its
+    #   queries, keys and values go through (SiLU after)
+    kda_gate_rank: int = 0            # rank of the decay's and the output
+    #   gate's two-step projections
     # -- what a block, the stream and the head may differ in -----------------
     norm_unit_offset: bool = False    # an RMSNorm multiplies by 1 + g
     fp32_residual: bool = False       # the residual stream is float32 (the
@@ -359,7 +382,9 @@ def _attn_matmul_params(cfg: TransformerConfig, kind: str = "full") -> int:
     if cfg.attention == "mla":
         nope, rope, v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                          cfg.v_head_dim)
-        return (d * cfg.q_lora_rank + cfg.q_lora_rank * h * (nope + rope)
+        ql = cfg.q_lora_rank        # no query latent: one projection
+        return ((d * ql + ql * h * (nope + rope) if ql
+                 else d * h * (nope + rope))
                 + d * (cfg.kv_lora_rank + rope)
                 + cfg.kv_lora_rank * h * (nope + v) + h * v * d)
     hd, vd, hk = cfg.head_dim, cfg.value_dim, cfg.kv_heads_of(kind)
@@ -392,8 +417,21 @@ def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
     return sum(n * _run_matmul_params(cfg, run, active)
                for run, n in cfg.layer_runs) + sum(
         4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
+        else _kda_matmul_params(cfg) if kind == "kda"
         else _attn_matmul_params(cfg, kind) for kind in cfg.kinds) \
         + cfg.kinds.count("index") * _indexer_matmul_params(cfg)
+
+
+def _kda_matmul_params(cfg: TransformerConfig) -> int:
+    """A KDA layer's projections: queries, keys and values in, the heads
+    out, the decay's and the gate's two steps, the step size a head."""
+    d, e, r = cfg.d_model, cfg.kda_heads * cfg.kda_head_dim, cfg.kda_gate_rank
+    return 4 * d * e + 2 * (d * r + r * e) + d * cfg.kda_heads
+
+
+def _kda_state_size(cfg: TransformerConfig) -> int:
+    """Floats of delta state a sequence, summed over the KDA layers."""
+    return cfg.kinds.count("kda") * cfg.kda_heads * cfg.kda_head_dim ** 2
 
 
 def _indexer_matmul_params(cfg: TransformerConfig) -> int:
@@ -429,7 +467,7 @@ def _attended(cfg: TransformerConfig, context_len: float,
         return context_len
 
     return sum(rows(kind) for kind in cfg.kinds
-               if kind != "conv")       # a conv layer attends nothing
+               if kind not in ("conv", "kda"))  # neither attends anything
 
 
 def count_params(cfg: TransformerConfig) -> int:
@@ -437,11 +475,15 @@ def count_params(cfg: TransformerConfig) -> int:
     norms = 2 * d * (2 if cfg.norm == "layernorm" else 1)
     if cfg.sandwich_norm:            # two more scales, no bias
         norms += 2 * d
-    n_conv = cfg.kinds.count("conv")
+    n_conv, n_kda = cfg.kinds.count("conv"), cfg.kinds.count("kda")
+    e = cfg.kda_heads * cfg.kda_head_dim    # a KDA layer's own: three
+    #   convolutions, a decay a head, its bias, the heads' norm
+    kda = 3 * e * cfg.kda_conv_kernel + cfg.kda_heads + e + cfg.kda_head_dim
     own = cfg.q_lora_rank + cfg.kv_lora_rank if cfg.attention == "mla" \
         else 2 * cfg.head_dim if cfg.qk_norm else 0   # an attention layer's
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
-        + (cfg.n_layers - n_conv) * own + n_conv * d * cfg.conv_kernel \
+        + (cfg.n_layers - n_conv - n_kda) * own + n_kda * kda \
+        + n_conv * d * cfg.conv_kernel \
         + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads \
         + cfg.kinds.count("eva") * 2 * cfg.kv_heads * cfg.head_dim \
         + cfg.kinds.count("index") * 2 * cfg.index_head_dim  # the key's norm
@@ -466,7 +508,8 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
         cfg, seq_len, 2 if cfg.causal else 1)
     # an indexing layer's heads meet every position's ONE key (no values)
     attn += attn_factor // 2 * _index_flops_dim(cfg) * seq_len
-    return 6 * n_matmul + attn
+    # a delta state's decay, read, correction and write a token: 7 a float
+    return 6 * n_matmul + attn + 3 * 7 * _kda_state_size(cfg)
 
 
 def _index_flops_dim(cfg: TransformerConfig) -> int:
@@ -490,8 +533,9 @@ def decode_flops_per_token(cfg: TransformerConfig,
                                  + cfg.qk_rope_head_dim)
     else:
         per_pos = cfg.n_heads * (cfg.head_dim + cfg.value_dim)
+    # (a KDA layer's cost does not grow with the context)
     return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len) \
-        + 2 * _index_flops_dim(cfg) * context_len
+        + 2 * _index_flops_dim(cfg) * context_len + 7 * _kda_state_size(cfg)
 
 
 def engine_flops_table(cfg: TransformerConfig, max_len: int) -> dict:
@@ -524,6 +568,7 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     (`TransformerConfig.split_kv`) each kind's ``wk`` / ``wv`` over its
     own layers, and the sinks over the layers that have one."""
     La, Lc = operator_layers(cfg, run)
+    Lk = kind_layers(cfg, run, ("kda",))
     d, hd, vd, h, ff = (cfg.d_model, cfg.head_dim, cfg.value_dim,
                         cfg.n_heads, cfg.ff_dim)
     pt = cfg.param_dtype
@@ -542,17 +587,25 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
         add("conv_in", (d, 3 * d), d, ("embed", None), Lc)
         add("conv_w", (d, k), k, (None, None), Lc)
         add("conv_out", (d, d), d, (None, "embed"), Lc)
+    if Lk:      # the gated delta rule (ops/delta_rule.py): ONE of the
+        # run's keys, so a model draws its other weights as it did
+        _init_kda(add, p, ax, cfg, Lk, next(keys))
     if La and cfg.attention == "mla":
         ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-        add("wq_a", (d, ql), d, ("embed", None), La)
-        add("wq_b", (ql, h, nope + rope), ql, (None, "heads", "kv"), La)
+        if ql:
+            add("wq_a", (d, ql), d, ("embed", None), La)
+            add("wq_b", (ql, h, nope + rope), ql, (None, "heads", "kv"), La)
+            p["q_norm"] = jnp.ones((La, ql), pt)
+            ax["q_norm"] = ("layers", None)
+        else:   # no query latent: the heads' queries from the input
+            add("wq", (d, h, nope + rope), d, ("embed", "heads", "kv"), La)
         add("wkv_a", (d, kl + rope), d, ("embed", None), La)
         add("wkv_b", (kl, h, nope + vd), kl, (None, "heads", "kv"), La)
         add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"), La)
-        p["q_norm"], p["kv_norm"] = jnp.ones((La, ql), pt), jnp.ones((La, kl), pt)
-        ax["q_norm"] = ax["kv_norm"] = ("layers", None)
+        p["kv_norm"] = jnp.ones((La, kl), pt)
+        ax["kv_norm"] = ("layers", None)
         n_idx = kind_layers(cfg, run, ("index",))
         if n_idx:       # the indexer: over the indexing layers only
             hi, di = cfg.index_heads, cfg.index_head_dim
@@ -843,8 +896,8 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
     selection the layer loop carries: an ``"index"`` layer makes a new one
     and attends it, a ``"shared"`` layer attends the one it is handed."""
     cos, sin = angles.get(kind, (None, None))
-    if kind == "conv":
-        return _conv_layer(cfg, x, lp) + (sel,)
+    if kind in _STATE_LAYERS:
+        return _state_layer(cfg, x, lp, kind) + (sel,)
     norm = functools.partial(_norm, cfg)
     if cfg.norm_remat:
         norm = jax.checkpoint(
@@ -854,10 +907,9 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
     if cfg.attention == "mla":
         # nothing is cached here: the plain form, every head's keys and
         # values built from the latents
-        rotate = functools.partial(apply_rotary, cos=cos, sin=sin)
-        q_nope, q_rope, c_q = mla.queries(
-            y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
-            nope=cfg.qk_nope_head_dim, eps=norm_eps(cfg), rotate=rotate)
+        rotate = functools.partial(apply_rotary, cos=cos, sin=sin) \
+            if kind in angles else mla.no_turn
+        q_nope, q_rope, c_q = latent_queries(cfg, y, lp, rotate)
         latent = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
                              kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
                              rotate=rotate)
@@ -1250,6 +1302,11 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
 
 #: a conv layer's weights in a run's tree
 _CONV_KEYS = ("conv_in", "conv_w", "conv_out")
+#: a KDA layer's (`_init_kda`)
+_KDA_KEYS = ("kda_in", "kda_conv", "kda_lo", "kda_fb", "kda_gb", "kda_a_log",
+             "kda_dt_bias", "kda_norm", "kda_out")
+#: the kinds of layer whose operator is no attention and carries a state
+_STATE_LAYERS = ("conv", "kda")
 #: an attention layer's (MHA/GQA and latent), of whatever attention kind
 _ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
               "wq_b", "wkv_a", "wkv_b", "kv_norm")
@@ -1268,6 +1325,13 @@ ATTENTION_KINDS = ("full", "window", "eva") + SPARSE_KINDS
 def check_kinds(cfg: TransformerConfig) -> None:
     """What an indexer needs of a configuration, refused with a message
     where it lacks it."""
+    if "kda" in cfg.kinds and (
+            min(cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank) < 1
+            or cfg.kda_conv_kernel < 2):
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r}: a 'kda' layer needs kda_heads, "
+            f"kda_head_dim and kda_gate_rank of at least 1 and a "
+            f"kda_conv_kernel of at least 2")
     sparse = set(cfg.kinds) & set(SPARSE_KINDS)
     if not sparse and not cfg.index_topk:
         return
@@ -1317,6 +1381,8 @@ def stack_kinds(cfg: TransformerConfig, key: str
     kind only that kind's."""
     if key in _CONV_KEYS:
         return ("conv",)
+    if key in _KDA_KEYS:
+        return ("kda",)
     if key == "sink":
         return cfg.sink_kinds
     if key in _WINDOW_KV:
@@ -1385,14 +1451,19 @@ def _scan_part(cfg: TransformerConfig, run: str, first: int, n: int,
     return carry
 
 
-def _conv_layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """`_layer` for a conv layer over a whole sequence (no state carried
-    in): the gated short convolution in attention's place, then the
-    layer's feed-forward as every layer has it."""
+def _state_layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
+                 kind: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`_layer` for a conv or a KDA layer over a whole sequence (no state
+    carried in): the gated short convolution, or the gated delta rule in
+    its plain form, in attention's place, then the layer's feed-forward
+    as every layer has it."""
     from ..ops.short_conv import conv_block
     y = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
-    delta, _ = conv_block(y, lp["conv_in"], lp["conv_w"], lp["conv_out"])
+    if kind == "kda":
+        delta, _, _ = kda_operator(cfg, y, lp)
+    else:
+        delta, _ = conv_block(y, lp["conv_in"], lp["conv_w"],
+                              lp["conv_out"])
     x = x + _post(cfg, delta, lp, "post_attn_norm")
     y = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
     z, aux, _ = _ffn(cfg, y, lp)
@@ -1428,3 +1499,97 @@ def _multi_head_loss(params: Params, tokens: jnp.ndarray, mask,
                 m = mask[:, 1 + p:].astype(jnp.float32)
                 total += (losses * m).sum() / jnp.maximum(m.sum(), 1.0)
         return total / cfg.pred_heads
+
+
+def latent_queries(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+                   rotate):
+    """A latent-attention layer's queries of the normed input ``y`` ->
+    (q_nope, q_rope turned by ``rotate``, the query latent or None):
+    through a query latent and its norm, or, of a model that has none
+    (``q_lora_rank`` 0), projected directly."""
+    nope = cfg.qk_nope_head_dim
+    if cfg.q_lora_rank:
+        return mla.queries(y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
+                           nope=nope, eps=norm_eps(cfg), rotate=rotate)
+    return mla.direct_queries(y, lp["wq"], nope=nope, rotate=rotate) + (None,)
+
+
+def _init_kda(add, p: Params, ax: Params, cfg: TransformerConfig, n: int,
+              key) -> None:
+    """A run's KDA weights, stacked over its ``n`` KDA layers: the three
+    input projections as one (``kda_in``: q | k | v), their convolutions as
+    one (``kda_conv``), the three small projections of the input as one
+    (``kda_lo``: the decay's first step | the output gate's | the step size
+    a head), the two second steps, the decay a head and its bias a channel,
+    the heads' norm, the output projection.  What decides how long a state
+    remembers is drawn as published: ``A_log = log U(1, 16)``, ``dt = exp
+    U(log 0.001, log 0.1)``, ``dt_bias = dt + log(-expm1(-dt))``."""
+    d, h, hd, r = (cfg.d_model, cfg.kda_heads, cfg.kda_head_dim,
+                   cfg.kda_gate_rank)
+    e, taps, pt = h * hd, cfg.kda_conv_kernel, cfg.param_dtype
+    ks = iter(jax.random.split(key, 8))
+    add("kda_in", (d, 3 * e), d, ("embed", None), n, next(ks))
+    add("kda_conv", (3 * e, taps), taps, (None, None), n, next(ks))
+    add("kda_lo", (d, 2 * r + h), d, ("embed", None), n, next(ks))
+    add("kda_fb", (r, e), r, (None, None), n, next(ks))
+    add("kda_gb", (r, e), r, (None, None), n, next(ks))
+    add("kda_out", (e, d), e, (None, "embed"), n, next(ks))
+    p["kda_a_log"] = jnp.log(jax.random.uniform(
+        next(ks), (n, h), pt, 1.0, 16.0))
+    dt = jnp.exp(jax.random.uniform(next(ks), (n, e), pt, math.log(1e-3),
+                                    math.log(1e-1)))
+    p["kda_dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+    p["kda_norm"] = jnp.ones((n, hd), pt)
+    ax["kda_a_log"] = ax["kda_dt_bias"] = ax["kda_norm"] = ("layers", None)
+
+
+def kda_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+                 state: Optional[jnp.ndarray] = None,
+                 conv: Optional[jnp.ndarray] = None,
+                 n_new: Optional[jnp.ndarray] = None):
+    """A KDA layer's operator on a normed input ``y`` [b, s, d] -> (what
+    the layer adds to the residual [b, s, d], the delta state' [b, heads,
+    dim, dim] float32, the convolutions' last inputs' [b, taps - 1, 3 x
+    heads x dim]).  ``state`` None is the PLAIN form over a whole sequence
+    from a zero state (`delta_rule.sequence`); with a carried ``state`` and
+    ``conv`` one token a row is `delta_rule.step` and a chunk the chunkwise
+    form (`delta_rule.chunk`), both advancing a row by its ``n_new`` [b]
+    valid tokens only (None: all).
+
+    The input and output projections stand under ``projections``; all the
+    operator adds beside them (the convolutions, which keep their ``conv``
+    part; normalisations, gates, the rule itself, the heads' norm and gate)
+    under ``kda`` INSIDE ``attention``."""
+    from ..ops import delta_rule
+    from ..ops.short_conv import short_conv
+    dt, eps = cfg.dtype, norm_eps(cfg)
+    h, hd, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+    b, s, _ = y.shape
+    with jax.named_scope("projections"):
+        u = jnp.einsum("bsd,de->bse", y, lp["kda_in"].astype(dt))
+    with jax.named_scope("attention"), jax.named_scope("kda"):
+        u, conv = short_conv(u, lp["kda_conv"], conv, n_new,
+                             activation=jax.nn.silu)
+        q, k, v = (t.reshape(b, s, h, hd) for t in jnp.split(u, 3, axis=-1))
+        q, k = delta_rule.l2norm(q) * hd ** -0.5, delta_rule.l2norm(k)
+        lo = jnp.einsum("bsd,de->bse", y, lp["kda_lo"].astype(dt))
+        f = jnp.einsum("bsr,re->bse", lo[..., :r], lp["kda_fb"].astype(dt))
+        gate = jnp.einsum("bsr,re->bse", lo[..., r:2 * r],
+                          lp["kda_gb"].astype(dt))
+        a, beta = delta_rule.gates(
+            f.reshape(b, s, h, hd), lo[..., 2 * r:], lp["kda_a_log"],
+            lp["kda_dt_bias"].reshape(h, hd))
+        if state is None:
+            o, state = delta_rule.sequence(q, k, v, a, beta)
+        elif s == 1:
+            o, state = delta_rule.step(
+                q[:, 0], k[:, 0], v[:, 0], a[:, 0], beta[:, 0], state,
+                None if n_new is None else n_new > 0)
+            o = o[:, None]
+        else:
+            o, state = delta_rule.chunk(q, k, v, a, beta, state, n_new)
+        o = rmsnorm(o, lp["kda_norm"], eps) * jax.nn.sigmoid(
+            gate.reshape(b, s, h, hd).astype(jnp.float32))
+    with jax.named_scope("projections"):
+        return (jnp.einsum("bse,ed->bsd", o.reshape(b, s, h * hd).astype(dt),
+                           lp["kda_out"].astype(dt)), state, conv)

@@ -3,6 +3,7 @@ key-value latent plus ONE rotary key a position, shared by all heads.
 
     c_q            = rmsnorm(y W_qa)                       [.., q_lora]
     q_nope | q_rope = c_q W_qb          per head           [.., h, nope|rope]
+                     (or y W_q directly, of a model with no query latent)
     c_kv | k_r     = y W_kva                               [.., kv_lora|rope]
     latent         = rmsnorm(c_kv) | rotary(k_r)           what a cache holds
     k_nope | v     = rmsnorm(c_kv) W_kvb   per head        [.., h, nope|v]
@@ -76,6 +77,21 @@ def queries(y: jnp.ndarray, wq_a, q_norm, wq_b, *, nope: int, eps: float,
     c_q = rmsnorm(jnp.einsum("bsd,dr->bsr", y, wq_a.astype(dt)), q_norm, eps)
     q = jnp.einsum("bsr,rhk->bshk", c_q, wq_b.astype(dt))
     return q[..., :nope], rotate(q[..., nope:]), c_q
+
+
+def no_turn(t: jnp.ndarray) -> jnp.ndarray:
+    """The ``rotate`` of a model that turns nothing (no position enters
+    it): the shared key and the queries' second part as projected."""
+    return t
+
+
+@jax.named_scope("projections")
+def direct_queries(y: jnp.ndarray, wq, *, nope: int, rotate: Rotate
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`queries` of a model with NO query latent: ``y`` [b, s, d] through
+    ``wq`` [d, h, nope + rope] -> (q_nope, q_rope turned by ``rotate``)."""
+    q = jnp.einsum("bsd,dhk->bshk", y, wq.astype(y.dtype))
+    return q[..., :nope], rotate(q[..., nope:])
 
 
 @jax.named_scope("projections")

@@ -378,6 +378,9 @@ class ContinuousBatchingEngine:
         # layers whose state is no positions: the last conv_kernel - 1
         # inputs of a convolution, whatever the context
         self._conv_layers = cfg.kinds.count("conv")
+        # ... or a matrix a head that a gated delta rule reads and writes
+        # WHOLE every step (beside its convolutions' last inputs)
+        self._kda_layers = cfg.kinds.count("kda")
         # layers that attend their own BLOCK of `sliding_window` rows (a
         # ring) and every earlier block through a summary row a
         # `summary_chunk` positions
@@ -440,7 +443,7 @@ class ContinuousBatchingEngine:
         # wrote them (one an array a layer where the kernel engages)
         self.rows = dict.fromkeys(
             ("steps",) + self._ROW_SUMS + self._INDEX_SUMS
-            + self._FETCH_SUMS + self._WRITE_SUMS, 0)
+            + self._STATE_SUMS + self._FETCH_SUMS + self._WRITE_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
         from ..models.generate import position_bytes
         self._row_bytes = position_bytes(cfg)
@@ -714,7 +717,8 @@ class ContinuousBatchingEngine:
         """Bytes of the slot cache by state kind (``bytes_full``: the
         arrays that hold ``max_len`` rows a slot; ``bytes_ring``: the
         window layers' rings; ``bytes_state``: the conv layers' states;
-        ``bytes_index``: an indexer's keys, where the model has one),
+        ``bytes_index``: an indexer's keys, ``bytes_delta``: KDA layers'
+        float32 states and convolution inputs, where the model has them),
         what ONE further position of a slot costs (the full arrays' bytes
         a row: a ring and a state grow with nothing), and the rows and
         bytes the decode steps read (`_ROW_SUMS`) and the columns they wrote
@@ -982,7 +986,7 @@ class ContinuousBatchingEngine:
         Either way the first chunk window must not be set back before
         ``depth``: it would need the block before."""
         if not self._window and not self._conv_layers \
-                and not self._eva_layers:
+                and not self._eva_layers and not self._kda_layers:
             return True
         sess = self._donors.get(donor)
         if sess is None:
@@ -992,7 +996,9 @@ class ContinuousBatchingEngine:
             return depth + chunk <= self._capacity and (
                 depth % self._block == 0
                 or sess.pos // self._block == depth // self._block)
-        if self._conv_layers and (
+        # (a KDA layer's state as a conv layer's: the donor's at its last
+        # token)
+        if (self._conv_layers or self._kda_layers) and (
                 sess.pos != depth or
                 depth + -(-(n - depth) // chunk) * chunk > self._capacity):
             return False
@@ -1385,7 +1391,7 @@ class ContinuousBatchingEngine:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
         rows = self._rows_of(batch) + self._index_rows_of(batch) \
-            + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
+            + self._state_rows_of(batch) + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
         flight = self._flight
         with self._cond:
             for s in batch:
@@ -1451,6 +1457,10 @@ class ContinuousBatchingEngine:
     #: ... and what `_index_rows_of` does: the index keys an indexer scored
     #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
     _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
+    #: ... and what a delta state costs a step: the (slot, KDA layer)
+    #: states advanced, and their bytes READ AND WRITTEN (NOT among
+    #: `bytes_read`: no position is attended)
+    _STATE_SUMS = ("state_rows", "state_bytes_moved")
     #: ... and the rows the step's attention MOVES from the cache to attend
     #: `rows_read` of them (`models.generate.rows_fetched`)
     _FETCH_SUMS = ("rows_fetched",)
@@ -1485,7 +1495,7 @@ class ContinuousBatchingEngine:
             return self._chosen_rows_of(batch)
         eva = self._eva_layers
         full = self.cfg.n_layers - self._window_layers - self._conv_layers \
-            - eva
+            - eva - self.cfg.kinds.count("kda")     # (`_state_rows_of`)
         depth = sum(s.pos + 1 for s in batch)
         seen = sum(min(s.pos + 1, self._window) for s in batch)
         per_full, per_ring, state = (
@@ -1529,6 +1539,14 @@ class ContinuousBatchingEngine:
         scored = self._index_layers * sum(s.pos + 1 for s in batch)
         return scored, scored * self._row_bytes["index"]
 
+    def _state_rows_of(self, batch) -> Tuple[int, int]:
+        """`_STATE_SUMS` of a decode step about to be dispatched: every
+        live slot's delta state and convolution inputs on every KDA layer
+        are read whole AND written whole, whatever the slot's position;
+        zeros for a model without such layers."""
+        states = self._kda_layers * len(batch)
+        return states, 2 * states * self._row_bytes.get("delta", 0)
+
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
         """A read step's `_rows_of` and column writes into the counters,
         and the sums since the last `cache:rows` span into the next when
@@ -1536,7 +1554,8 @@ class ContinuousBatchingEngine:
         with self._cond:   # stats() reads these
             self.rows["steps"] += 1
             for k, n in zip(self._ROW_SUMS + self._INDEX_SUMS
-                            + self._FETCH_SUMS + self._WRITE_SUMS, rows):
+                            + self._STATE_SUMS + self._FETCH_SUMS
+                            + self._WRITE_SUMS, rows):
                 self.rows[k] += n
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,

@@ -336,7 +336,10 @@ MODEL_PARTS = ("embed", "norm", "projections", "attention", "cache_write",
 # pooling of a chunk and the write of its row (`models/generate.py`
 # `_write_summaries`), inside ``cache_write``; ``indexer``, what learned
 # sparse attention adds to a layer (`ops/sparse_index.py`: index projections,
-# scores, the choice), inside ``attention``.  Not parts of their own while
+# scores, the choice), inside ``attention``; ``kda``, what a gated delta rule
+# adds to a layer beside its projections (`models/transformer.py`
+# `kda_operator`, `ops/delta_rule.py`), inside ``attention`` too, but for its
+# convolutions, which keep ``conv``.  Not parts of their own while
 # the benchmark's list (`perfbench/parts.py` ``PARTS``, held equal to
 # `MODEL_PARTS` by its tests) has ten.
 
