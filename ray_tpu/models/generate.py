@@ -109,8 +109,12 @@ program advances a row by its VALID tokens only (a padded chunk's tail
 neither decays nor writes, a slot that is not active keeps both arrays bit
 for bit), and `_check_state_rewind` refuses what would need it taken back.
 A decode step and a chunk run the same recurrence in two forms
-(`delta_rule.step`, `delta_rule.chunk`), and the fused step writes the state
-into its layer of the donated array in place.
+(`delta_rule.step`, `delta_rule.chunk`), and the fused step advances the
+state in its layer of the donated array in place: where the states are
+whole 128 x 128 tiles and the program is lowered for a TPU, in ONE kernel
+call a layer over the stacked array (`delta_rule.step_in_place`: no cut of
+the layer before it, no placement after it; a live slot's states read once
+and written once, a standing slot's not at all; `state_fetched` counts it).
 
 A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
 summary arrays say it (`cache_capacity`, which then needs the model's
@@ -159,7 +163,7 @@ import numpy as np
 
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from ..ops import cache_attention
+from ..ops import cache_attention, delta_rule
 from ..ops import eva_attention as eva
 from ..ops import latent_attention as mla
 from ..ops import sparse_index
@@ -353,6 +357,26 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
         return dense + layers * blocks * cache_attention.BLOCK
 
     return count
+
+
+def state_fetched(cache: KVCache, cfg: TransformerConfig):
+    """A decode step over this slot cache → ``count(live slots)``: the bytes
+    of delta state its program MOVES (`position_bytes`' ``delta`` a slot a
+    KDA layer, the convolutions' inputs counted at the states' passes, as
+    the serve engine's ``state_bytes_moved`` counts them): where
+    `ops/delta_rule.py`'s kernel engages on this process's backend a live
+    slot's states once read and once written and a standing slot's not at
+    all; in XLA's form every slot's, live or not, read twice (the two
+    products, then the decay and correction) and written once.  Host counts
+    from shapes; 0 for a model without KDA layers."""
+    s_all = cache_arrays(cache).get(_DELTA_STATE)
+    if s_all is None:
+        return lambda live: 0
+    layers, slots = s_all.shape[:2]
+    per = layers * position_bytes(cfg)["delta"]
+    if delta_rule.engages(1, s_all):
+        return lambda live: 2 * live * per
+    return lambda live: 3 * slots * per
 
 
 def _state_kind(name: str) -> str:
@@ -981,9 +1005,16 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     def kda(y, lp, arrs, l, kind):
         # the delta state and the convolutions' inputs of layer l, read
         # whole, advanced by the rows' valid tokens, written back
-        delta, state, taps = kda_operator(
-            cfg, y, lp, _layer_of(arrs[_DELTA_STATE], l),
-            _layer_of(arrs[_DELTA_CONV], l)[:, 0], n_new)
+        s_all, taps = arrs[_DELTA_STATE], _layer_of(arrs[_DELTA_CONV], l)[:, 0]
+        if delta_rule.kernel_shape(c, s_all):
+            # one token a row: the live rows' states advanced where they lie
+            delta, s_all, taps = kda_operator(cfg, y, lp, s_all, taps, n_new,
+                                              layer=l)
+            return delta, dict(arrs, **{
+                _DELTA_STATE: s_all,
+                _DELTA_CONV: _place_state(arrs[_DELTA_CONV], l, taps)})
+        delta, state, taps = kda_operator(cfg, y, lp, _layer_of(s_all, l),
+                                          taps, n_new)
         return delta, _place_delta(arrs, l, state, taps)
 
     operator = {"conv": conv, "eva": attend_eva, "kda": kda}

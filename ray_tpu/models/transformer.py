@@ -1546,7 +1546,7 @@ def _init_kda(add, p: Params, ax: Params, cfg: TransformerConfig, n: int,
 def kda_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
                  state: Optional[jnp.ndarray] = None,
                  conv: Optional[jnp.ndarray] = None,
-                 n_new: Optional[jnp.ndarray] = None):
+                 n_new: Optional[jnp.ndarray] = None, layer=None):
     """A KDA layer's operator on a normed input ``y`` [b, s, d] -> (what
     the layer adds to the residual [b, s, d], the delta state' [b, heads,
     dim, dim] float32, the convolutions' last inputs' [b, taps - 1, 3 x
@@ -1554,7 +1554,10 @@ def kda_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
     from a zero state (`delta_rule.sequence`); with a carried ``state`` and
     ``conv`` one token a row is `delta_rule.step` and a chunk the chunkwise
     form (`delta_rule.chunk`), both advancing a row by its ``n_new`` [b]
-    valid tokens only (None: all).
+    valid tokens only (None: all).  With ``layer`` (one token a row only)
+    ``state`` is the STACK of every KDA layer's states [L, b, heads, dim,
+    dim] and so is the state handed back, layer ``layer`` of it advanced
+    where it lies (`delta_rule.step_in_place`).
 
     The input and output projections stand under ``projections``; all the
     operator adds beside them (the convolutions, which keep their ``conv``
@@ -1582,9 +1585,11 @@ def kda_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
         if state is None:
             o, state = delta_rule.sequence(q, k, v, a, beta)
         elif s == 1:
-            o, state = delta_rule.step(
+            rule = delta_rule.step if layer is None else functools.partial(
+                delta_rule.step_in_place, l=layer)
+            o, state = rule(
                 q[:, 0], k[:, 0], v[:, 0], a[:, 0], beta[:, 0], state,
-                None if n_new is None else n_new > 0)
+                live=None if n_new is None else n_new > 0)
             o = o[:, None]
         else:
             o, state = delta_rule.chunk(q, k, v, a, beta, state, n_new)

@@ -1264,7 +1264,8 @@ class ContinuousBatchingEngine:
         import numpy as np
 
         from ..models import init_slot_cache
-        from ..models.generate import column_write_counts, rows_fetched
+        from ..models.generate import (column_write_counts, rows_fetched,
+                                       state_fetched)
         from ..util import fault_injection as fi
         from ..util import tracing
         if self._cache is None:
@@ -1274,6 +1275,7 @@ class ContinuousBatchingEngine:
         self._writes_a_step = column_write_counts(self._cache)
         # `_FETCH_SUMS` of one, from its live slots' positions
         self._fetched = rows_fetched(self._cache, self.cfg)
+        self._state_fetched = state_fetched(self._cache, self.cfg)
         slots = self.ecfg.max_slots
         self._carry = self._fresh_carry()
         self._warm_lanes()
@@ -1391,7 +1393,8 @@ class ContinuousBatchingEngine:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
         rows = self._rows_of(batch) + self._index_rows_of(batch) \
-            + self._state_rows_of(batch) + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
+            + self._state_rows_of(batch) \
+            + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
         flight = self._flight
         with self._cond:
             for s in batch:
@@ -1458,9 +1461,10 @@ class ContinuousBatchingEngine:
     #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
     _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
     #: ... and what a delta state costs a step: the (slot, KDA layer)
-    #: states advanced, and their bytes READ AND WRITTEN (NOT among
-    #: `bytes_read`: no position is attended)
-    _STATE_SUMS = ("state_rows", "state_bytes_moved")
+    #: states advanced, their bytes READ AND WRITTEN (NOT among
+    #: `bytes_read`: no position is attended), and the bytes of state the
+    #: step's program MOVES to do that (`models.generate.state_fetched`)
+    _STATE_SUMS = ("state_rows", "state_bytes_moved", "state_bytes_fetched")
     #: ... and the rows the step's attention MOVES from the cache to attend
     #: `rows_read` of them (`models.generate.rows_fetched`)
     _FETCH_SUMS = ("rows_fetched",)
@@ -1539,13 +1543,15 @@ class ContinuousBatchingEngine:
         scored = self._index_layers * sum(s.pos + 1 for s in batch)
         return scored, scored * self._row_bytes["index"]
 
-    def _state_rows_of(self, batch) -> Tuple[int, int]:
+    def _state_rows_of(self, batch) -> Tuple[int, int, int]:
         """`_STATE_SUMS` of a decode step about to be dispatched: every
         live slot's delta state and convolution inputs on every KDA layer
-        are read whole AND written whole, whatever the slot's position;
-        zeros for a model without such layers."""
+        are read whole AND written whole, whatever the slot's position,
+        and the program moves what its form of the rule on this backend
+        does; zeros for a model without such layers."""
         states = self._kda_layers * len(batch)
-        return states, 2 * states * self._row_bytes.get("delta", 0)
+        return (states, 2 * states * self._row_bytes.get("delta", 0),
+                self._state_fetched(len(batch)))
 
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
         """A read step's `_rows_of` and column writes into the counters,

@@ -39,7 +39,8 @@ from ray_tpu.models import (TransformerConfig, cache_gather_slot,
 from ray_tpu.models.generate import (_state_kind, array_dtype, cache_bytes,
                                      cache_capacity, cache_rows,
                                      column_write_counts, position_bytes,
-                                     prefill_chunk_step, prefill_lanes_step)
+                                     prefill_chunk_step, prefill_lanes_step,
+                                     state_fetched)
 from ray_tpu.models.transformer import (count_params, decode_flops_per_token,
                                         stack_kinds)
 from ray_tpu.ops import delta_rule
@@ -185,6 +186,110 @@ def test_strong_decays_overflow_nothing_in_the_chunkwise_form():
         np.testing.assert_allclose(new, state, atol=1e-5, rtol=0)
 
 
+# ------------------------------------- the step where the stacked states lie
+
+#: cases of `test_the_step_kernel_is_the_step`: slots, heads, who is live
+#: (None: all), a VMEM budget (None: the module's), every log decay (None:
+#: drawn), steps in a row
+_KERNEL_CASES = {
+    "one head a grid step": dict(b=2, h=1),
+    "several heads a grid step": dict(b=2, h=16),
+    "two blocks of heads a slot": dict(
+        b=3, h=16, live=[True, False, True], budget=4 * 8 * 128 * 128 * 4),
+    "one slot stands between two that run": dict(
+        b=4, h=8, live=[False, True, False, True]),
+    "none live": dict(b=2, h=8, live=[False, False]),
+    "a decay of 1e-7": dict(b=2, h=2, decay=float(np.log(1e-7))),
+    "300 steps in a row": dict(b=1, h=2, steps=300),
+}
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_the_step_kernel_is_the_step(case, monkeypatch):
+    """`step_in_place`'s kernel through the interpreter, at states of 128 x
+    128, against `step` on the layer cut out and against the NumPy
+    recurrence: the layer beside it and a slot that stands keep their states
+    bit for bit (a standing slot's ``o`` is zeros), and 300 steps in a row
+    drift from `sequence` by no more than rounding."""
+    spec = dict(dict(live=None, budget=None, decay=None, steps=1),
+                **_KERNEL_CASES[case])
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    if spec["budget"]:
+        monkeypatch.setattr(delta_rule, "_VMEM_BLOCK_BUDGET", spec["budget"])
+        assert delta_rule._head_block(spec["h"], 128, 128) == 8
+    b, h, steps = spec["b"], spec["h"], spec["steps"]
+    q, k, v, a, beta, s0 = _inputs(len(case), b=b, s=steps, h=h, dk=128,
+                                   dv=128)
+    if spec["decay"] is not None:
+        a = np.full_like(a, spec["decay"])
+        assert np.exp(a).max() < 1.1e-7
+    live = None if spec["live"] is None else jnp.asarray(spec["live"])
+    assert delta_rule.engages(1, jnp.zeros((2, b, h, 128, 128)))
+
+    if steps > 1:       # from zeros, as `sequence`
+        def many(s_all, xs):
+            def one(s_all, x):
+                o, s_all = delta_rule.step_in_place(*x, s_all, 1)
+                return s_all, o
+            return jax.lax.scan(one, s_all, xs)
+        new, got = jax.jit(many)(
+            jnp.zeros((2,) + s0.shape), tuple(
+                jnp.swapaxes(t, 0, 1) for t in (q, k, v, a, beta)))
+        want, state = delta_rule.sequence(q, k, v, a, beta)
+        np.testing.assert_allclose(jnp.swapaxes(got, 0, 1), want, atol=2e-6,
+                                   rtol=0)
+        np.testing.assert_allclose(new[1], state, atol=2e-6, rtol=0)
+        want, state = _numpy_rule(q, k, v, a, beta, np.zeros_like(s0))
+        np.testing.assert_allclose(jnp.swapaxes(got, 0, 1), want, atol=2e-6,
+                                   rtol=0)
+        np.testing.assert_allclose(new[1], state, atol=2e-6, rtol=0)
+        return
+    s_all = np.stack([s0[::-1], s0])
+    one = [t[:, 0] for t in (q, k, v, a, beta)]
+    got, new = jax.jit(lambda *x: delta_rule.step_in_place(*x, 1, live))(
+        *one, s_all)
+    np.testing.assert_array_equal(new[0], s_all[0])     # the layer beside it
+    runs = np.ones(b, bool) if live is None else np.asarray(live)
+    np.testing.assert_array_equal(new[1][~runs], s0[~runs])     # bit for bit
+    np.testing.assert_array_equal(got[~runs], 0.0)
+    want, state = delta_rule.step(*one, s0, live)
+    np.testing.assert_allclose(got[runs], want[runs], atol=2e-6, rtol=0)
+    np.testing.assert_allclose(new[1], state, atol=2e-6, rtol=0)
+    want, state = _numpy_rule(*(t[:, :1] for t in (q, k, v, a, beta)), s0)
+    np.testing.assert_allclose(got[runs], want[runs, 0], atol=3e-6, rtol=0)
+    np.testing.assert_allclose(new[1][runs], state[runs], atol=3e-6, rtol=0)
+
+
+def test_what_the_step_kernel_takes_and_refuses(monkeypatch):
+    """One token a row against float32 states of whole 128 x 128 tiles, on a
+    TPU or under the interpreter; anything else is `step` between a cut and
+    a placement, whose result is `step`'s bit for bit."""
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    assert delta_rule.kernel_shape(1, f32((20, 32, 32, 128, 128)))
+    assert delta_rule.kernel_shape(1, f32((2, 1, 3, 256, 128)))
+    for tokens, states in (
+            (1, f32((2, 4, 4, 16, 16))),            # the rehearsal's heads
+            (1, f32((2, 4, 4, 64, 128))),           # dk no whole tile
+            (1, f32((2, 4, 4, 128, 192))),          # nor dv
+            (1, jax.ShapeDtypeStruct((2, 4, 4, 128, 128), jnp.bfloat16)),
+            (128, f32((20, 4, 32, 128, 128)))):     # a chunk
+        assert not delta_rule.kernel_shape(tokens, states), (tokens, states)
+        assert not delta_rule.engages(tokens, states)
+    taken = f32((2, 2, 4, 128, 128))
+    monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    assert not delta_rule.engages(1, taken)            # this backend: a CPU
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    assert delta_rule.engages(1, taken)
+    # a refused shape under the interpreter all the same: the slices
+    q, k, v, a, beta, s0 = _inputs(2, s=1)
+    one = [t[:, 0] for t in (q, k, v, a, beta)]
+    live = jnp.asarray([False, True])
+    got, new = delta_rule.step_in_place(*one, np.stack([s0, s0]), 0, live)
+    want, state = delta_rule.step(*one, s0, live)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(new, np.stack([state, s0]))
+
+
 # ---------------------------------------------- the model and what it holds
 
 def test_pattern_weights_and_counts(world):
@@ -250,7 +355,7 @@ def test_a_cache_has_a_sixth_kind_of_state_of_a_type_of_its_own(world):
     assert column_write_counts(held)[0] == 2 * 3
 
 
-def test_rows_a_step_attends_and_the_state_it_moves(world):
+def test_rows_a_step_attends_and_the_state_it_moves(world, monkeypatch):
     eng = types.SimpleNamespace(
         cfg=world.cfg, _window=0, _window_layers=0, _conv_layers=0,
         _eva_layers=0, _kda_layers=4, _row_bytes=position_bytes(world.cfg))
@@ -258,10 +363,26 @@ def test_rows_a_step_attends_and_the_state_it_moves(world):
     rows = ContinuousBatchingEngine._rows_of(eng, batch)
     assert rows == (2 * 110, 6 * 110, 2 * 110 * 96, 6 * 110 * 96, 0, 0)
     per = 4 * 16 * 16 * 4 + 3 * 3 * 64 * 4          # float32 model
+    # states of 16 x 16: XLA's form, three passes over all 3 slots' states
+    eng._state_fetched = state_fetched(
+        init_slot_cache(world.cfg, 3, MAX_LEN), world.cfg)
     assert ContinuousBatchingEngine._state_rows_of(eng, batch) == (
-        4 * 2, 2 * 4 * 2 * per)
-    assert ContinuousBatchingEngine._STATE_SUMS == ("state_rows",
-                                                    "state_bytes_moved")
+        4 * 2, 2 * 4 * 2 * per, 3 * 4 * 3 * per)
+    assert ContinuousBatchingEngine._STATE_SUMS == (
+        "state_rows", "state_bytes_moved", "state_bytes_fetched")
+    # states of 128 x 128 where the kernel runs: the live slots' alone,
+    # twice; on this backend without the interpreter XLA's form again
+    wide = dataclasses.replace(world.cfg, kda_head_dim=128)
+    cache = init_slot_cache(wide, 3, MAX_LEN)
+    per = position_bytes(wide)["delta"]
+    assert per == 4 * 128 * 128 * 4 + 3 * 3 * 4 * 128 * 4
+    assert state_fetched(cache, wide)(2) == 3 * 4 * 3 * per
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    assert [state_fetched(cache, wide)(n) for n in (2, 0)] == [
+        2 * 4 * 2 * per, 0]
+    # no KDA layer, no state
+    plain = dataclasses.replace(world.cfg, layer_kinds=("full",) * 6)
+    assert state_fetched(init_slot_cache(plain, 2, 64), plain)(2) == 0
 
 
 def test_the_kda_scope_stands_inside_attention_and_conv(world):
