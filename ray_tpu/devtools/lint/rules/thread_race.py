@@ -13,7 +13,10 @@ the instance lock.  This rule checks it structurally:
   (``LintContext.graphs`` — PR-14 generalized the closure this rule
   used to compute privately).
 * "instance locks" are attributes assigned ``threading.Lock()`` /
-  ``RLock()`` / ``Condition()`` (any dotted spelling).
+  ``RLock()`` / ``Condition()`` (any dotted spelling), or an instance
+  of a class of the repo's own whose name ends in ``Lock`` (a wrapper
+  that takes one of those its own way: ``serve/decode_session.py``
+  ``_LoopLock``).
 * a mutation (``self.x = ...`` / ``self.x += ...``) counts as locked
   when lexically inside ``with self.<lock>:`` — or when the enclosing
   method's name ends in ``_locked`` (the repo convention for
@@ -226,4 +229,5 @@ class ThreadRaceRule(Rule):
         if not isinstance(value, ast.Call):
             return False
         dotted = self.dotted(value.func)
-        return dotted.split(".")[-1] in _LOCK_FACTORIES
+        name = dotted.split(".")[-1]
+        return name in _LOCK_FACTORIES or name.endswith("Lock")

@@ -239,6 +239,32 @@ class _Step(NamedTuple):
 _BEHIND = -1.0
 
 
+class _LoopLock:
+    """The engine's lock as its LOOP thread takes it: without blocking
+    where nobody holds it (one `acquire(False)`), else a wait that is
+    counted: a host annotation ``wait:lock`` in a profiler trace, seconds
+    in ``phase_s["lock_wait"]`` (inside whichever ``engine:`` phase is
+    open) and one in ``waits["lock_waits"]``.  The callers' threads take
+    ``eng._cond`` as ever: what THEY wait is not counted."""
+
+    __slots__ = ("eng",)
+
+    def __init__(self, eng: "ContinuousBatchingEngine"):
+        self.eng = eng
+
+    def __enter__(self) -> None:
+        eng = self.eng
+        if eng._lock.acquire(False):
+            return
+        from ..util import tracing
+        with tracing.span("wait:lock", into=(eng.phase_s, "lock_wait")):
+            eng._lock.acquire()
+        eng.waits["lock_waits"] += 1
+
+    def __exit__(self, *exc) -> None:
+        self.eng._lock.release()
+
+
 class ContinuousBatchingEngine:
     """Replica-resident continuous-batching decode loop.
 
@@ -253,6 +279,10 @@ class ContinuousBatchingEngine:
     _THREAD_PHASES = {"schedule": "schedule", "admit": "admit_host",
                       "dispatch": "dispatch", "readback": "readback",
                       "publish": "publish"}
+    #: a step's read that takes this long is a stall, not a turn (at least
+    #: twice the longest ordinary turn of any cell: a lanes program of
+    #: 49 ms and a step of 16-20; PERF.md section 6, PR 51)
+    _LONG_READ_S = 0.25
 
     def __init__(self, cfg, max_len: int, params: Any,
                  engine_cfg: DecodeEngineConfig, name: str = "",
@@ -398,6 +428,7 @@ class ContinuousBatchingEngine:
         # session's own condition over the same lock
         self._lock = threading.RLock()
         self._cond = threading.Condition(self._lock)
+        self._loop_lock = _LoopLock(self)   # how the LOOP thread takes it
         self.sessions: Dict[str, _EngineSession] = {}  # insertion = LRU
         self._pending: List[_EngineSession] = []   # prefilled, want slot
         self._prefilling: List[_EngineSession] = []
@@ -476,9 +507,14 @@ class ContinuousBatchingEngine:
         # first_token), the engine thread's own phases (the `engine:`
         # spans of `_loop`), and the chunk programs that carried a
         # prompt's remainder (padded)
+        # ... and what the engine thread waited for: its CPU seconds of
+        # `schedule`, its waits for the engine's lock (`_LoopLock`), the
+        # step reads that stalled (`_read`)
         self.phase_s = dict.fromkeys(
-            ("queue", "admission", "first_token", "prefill_tail")
+            ("queue", "admission", "first_token", "prefill_tail",
+             "schedule_cpu", "lock_wait", "long_read")
             + tuple(self._THREAD_PHASES.values()), 0.0)
+        self.waits = {"lock_waits": 0, "long_reads": 0}
 
     def _counting_copies(self, fn, arg: int):
         """``fn`` donates its positional argument ``arg``, a cache: count
@@ -711,7 +747,17 @@ class ContinuousBatchingEngine:
                     # data-plane flight instruments: per-program
                     # dispatch/compile/MFU ledger + phase attribution
                     "device_profile": self._prof.snapshot(),
-                    "phase_totals": self.phase_totals()}
+                    "phase_totals": self.phase_totals(),
+                    # how often the engine thread waited for the lock or
+                    # sat in a stalled read, and this process's garbage
+                    # collections and late wake-ups (`tracing.host_totals`)
+                    "waits": self._waits()}
+
+    def _waits(self) -> Dict[str, int]:
+        from ..util import tracing
+        host = tracing.host_totals()
+        return dict(self.waits, gc_collections=host["gc_collections"],
+                    late_wakeups=host["late_wakeups"])
 
     def _cache_stats(self) -> Dict[str, int]:
         """Bytes of the slot cache by state kind (``bytes_full``: the
@@ -743,7 +789,16 @@ class ContinuousBatchingEngine:
         the chunk programs that carried a prompt's REMAINDER (fewer
         real tokens than the chunk holds); schedule/admit_host/dispatch/
         readback/publish are the engine thread's own phases, the
-        seconds of its ``engine:`` spans."""
+        seconds of its ``engine:`` spans, and schedule_cpu the thread's
+        own CPU seconds of ``schedule`` (wall less CPU: runnable and
+        not running);
+        lock_wait is what the thread waited for the engine's lock
+        (INSIDE whichever phase was open, and at a turn's top under
+        none), long_read the step reads of `_LONG_READ_S` or more
+        (inside readback); gc and late_wakeup are this PROCESS's
+        garbage collections and its watch thread's late wake-ups
+        (`tracing.host_totals`)."""
+        from ..util import tracing
         wall = self._prof.wall_seconds()
         prefill = sum(wall.get(p, 0.0)
                       for p in ("prefill_chunk", "prefix_gather"))
@@ -752,6 +807,9 @@ class ContinuousBatchingEngine:
         out = {k: round(v, 6) for k, v in self.phase_s.items()}
         out["prefill"] = round(prefill, 6)
         out["decode_dispatch"] = round(decode, 6)
+        host = tracing.host_totals()
+        out["gc"] = round(host["gc_s"], 6)
+        out["late_wakeup"] = round(host["late_wakeup_s"], 6)
         return out
 
     def _live_locked(self) -> int:
@@ -1033,7 +1091,7 @@ class ContinuousBatchingEngine:
                 sess.pcache = self._gather(self._cache, jnp.int32(donor),
                                            jnp.int32(depth))
                 sess.poff = depth
-                with self._cond:   # stats() reads these counters
+                with self._loop_lock:   # stats() reads these counters
                     self.prefix_hits += 1
                     self.prefix_tokens_reused += depth
                 self._shape_seen("prefix_gather", 1)
@@ -1058,7 +1116,7 @@ class ContinuousBatchingEngine:
         tails = [n for _, n in riders if n < chunk]
         self.phase_s["prefill_tail"] += wall * len(tails) / len(riders)
         self._prof.note_tokens("prefill_chunk", sum(n for _, n in riders))
-        with self._cond:   # stats() reads these counters
+        with self._loop_lock:   # stats() reads these counters
             self.prefill_programs += 1
             self.prefill_chunks += len(riders)
             self.prefill_tails += len(tails)
@@ -1139,7 +1197,7 @@ class ContinuousBatchingEngine:
         self._lane_sess[sess.lane] = sess.lane = None
 
     def _fail_prefill(self, sess: _EngineSession, e: Exception) -> None:
-        with self._cond:
+        with self._loop_lock:
             sess.error = f"chunked prefill failed: {e!r}"
             sess.done = True
             sess.ready = True
@@ -1288,13 +1346,19 @@ class ContinuousBatchingEngine:
             """One of the engine thread's flat, non-overlapping phases:
             a host annotation `engine:<name>` in a profiler trace and
             seconds in `phase_s`; none is open while the thread waits
-            with nothing to do."""
+            with nothing to do.  `schedule` holds the lock throughout
+            and dispatches nothing, so its CPU seconds are kept beside
+            its wall seconds (`schedule_cpu`): the rest is time the
+            thread stood runnable and did not run.  That phase alone:
+            `time.thread_time()` is a system call, 6 us on the chip's
+            host (PERF.md section 6, PR 51)."""
             return tracing.span("engine:" + name, "serve",
                                 into=(self.phase_s,
-                                      self._THREAD_PHASES[name]))
+                                      self._THREAD_PHASES[name]),
+                                cpu=name == "schedule")
 
         while True:
-            with self._cond:
+            with self._loop_lock:
                 while not self._shutdown:
                     with phase("schedule"):
                         self._reap_locked()
@@ -1396,7 +1460,7 @@ class ContinuousBatchingEngine:
             + self._state_rows_of(batch) \
             + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
         flight = self._flight
-        with self._cond:
+        with self._loop_lock:
             for s in batch:
                 s.pos += 1
                 s.unread += 1
@@ -1423,10 +1487,27 @@ class ContinuousBatchingEngine:
         device fault surfaces here, one read after its dispatch (chaos
         site ``serve.decode_step``)."""
         import numpy as np
+
+        from ..util import tracing
+        began = time.perf_counter()
+        late = tracing.host_totals()["late_wakeups"]
         self._chaos_site("serve.decode_step", fi)
         waited = not step.out.is_ready()
         new_toks = np.asarray(step.out)
         now = time.perf_counter()
+        if now - began >= self._LONG_READ_S:
+            # a stall.  With no late wake-up of the watch thread beside
+            # it, the device or the transfer; with one, the interpreter
+            # was held or the host did not run the process
+            self.phase_s["long_read"] += now - began
+            self.waits["long_reads"] += 1
+            wall = time.time()
+            tracing.record_span(
+                "engine:long_read", "stall", wall - (now - began), wall,
+                deployment=self.name, step=self.steps,
+                waited_ms=round(1e3 * (now - began), 3),
+                live=len(step.batch),
+                late_wakeups=tracing.host_totals()["late_wakeups"] - late)
         start = step.start
         if start == _BEHIND:
             start = self._read_end if self._read_waited else None
@@ -1440,7 +1521,7 @@ class ContinuousBatchingEngine:
     def _count_moe(self, load) -> None:
         """One decode step's routing into the counters, and the sums since
         the last `moe:load` span into the next one when it is due."""
-        with self._cond:   # stats() reads these
+        with self._loop_lock:   # stats() reads these
             self.moe["steps"] += 1
             self.moe["experts_touched"] += int(load[0])
             self.moe["load_max"] += int(load[1])
@@ -1557,7 +1638,7 @@ class ContinuousBatchingEngine:
         """A read step's `_rows_of` and column writes into the counters,
         and the sums since the last `cache:rows` span into the next when
         due."""
-        with self._cond:   # stats() reads these
+        with self._loop_lock:   # stats() reads these
             self.rows["steps"] += 1
             for k, n in zip(self._ROW_SUMS + self._INDEX_SUMS
                             + self._STATE_SUMS + self._FETCH_SUMS
@@ -1595,7 +1676,7 @@ class ContinuousBatchingEngine:
         no token of either is published.  Sessions still prefilling or
         waiting for a slot own their batch-1 caches and are untouched."""
         from ..models import init_slot_cache
-        with self._cond:
+        with self._loop_lock:
             for sess in self._slots.values():
                 sess.error = error
                 sess.done = True
@@ -1671,7 +1752,7 @@ class ContinuousBatchingEngine:
                 self._fail_prefill(sess, e)
         now_mono = time.monotonic()
         now_wall = time.time()
-        with self._cond:
+        with self._loop_lock:
             for sess, first in firsts:
                 sess.t_ready = now_mono
                 self.phase_s["first_token"] += now_mono - sess.t_enq
@@ -1708,7 +1789,7 @@ class ContinuousBatchingEngine:
         SERVE_DECODE_OCCUPANCY.observe(occupancy,
                                        {"deployment": self.name})
         SERVE_TOKENS.inc(occupancy, {"deployment": self.name})
-        with self._cond:
+        with self._loop_lock:
             self.steps += 1
             self.tokens += occupancy
             for s, slot in batch:

@@ -306,6 +306,13 @@ def serve_breakdown() -> Dict[str, Any]:
       coverage sum because they overlap the phases above: the engine
       thread's own phases (schedule, admit_host, dispatch, readback,
       publish) and the per-request sums first_token and prefill_tail;
+      and what the host's turn waited for: lock_wait (the engine
+      thread's waits for the engine's lock), schedule_cpu (the
+      thread's own CPU seconds of schedule: wall less CPU is time it
+      stood runnable and did not run), long_read (step reads of 250 ms
+      or more), gc and late_wakeup (the replica process's garbage
+      collections and its watch thread's late wake-ups,
+      `tracing.host_totals`);
     * ``mfu``: per-program model-FLOPs-utilization gauges.
 
     Surfaces: `ray-tpu top` breakdown panel, ``/api/serve/breakdown``."""

@@ -30,11 +30,22 @@ controller, nodelet, worker) marks an interval:
 
 A span that repeats every iteration of a hot loop passes ``into=(dict,
 key)``: its seconds are added to that accumulator and it goes to the
-annotation only, never to the ring.
+annotation only, never to the ring; with ``cpu=True`` the thread's own CPU
+seconds of the interval go to ``dict[key + "_cpu"]`` beside them (wall less
+CPU: the thread was runnable and did not run, or slept).
+
+**The host watch** — one a process, started and stopped with the flush
+loop's claim (`claim_flusher` / `release_flusher`): a ``gc.callbacks`` hook
+(``host:gc``: every collection's seconds, a ring span and a profiler
+annotation for those of `GC_SPAN_FLOOR_S` or more) and a thread that sleeps
+`WATCH_TICK_S` and records ``host:late_wakeup`` when it wakes
+`LATE_WAKEUP_S` or more late (the interpreter was held, or the host did not
+run the process).  `host_totals` has their cumulative sums.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import sys
@@ -50,6 +61,12 @@ MAX_CATEGORIES = 16
 #: a flush the controller did not take is followed by a re-ship of the
 #: whole ring; flush loops wait this long before it
 RESHIP_PAUSE_S = 1.0
+#: a collection shorter than this is counted and leaves no ring span
+GC_SPAN_FLOOR_S = 1e-3
+#: the host watch's sleep, and how late a wake-up is before it is recorded
+#: (a replica's full collections at a window's start take 0.09 s)
+WATCH_TICK_S = 0.02
+LATE_WAKEUP_S = 0.05
 
 _PLAIN = (str, int, float, bool)
 
@@ -97,6 +114,11 @@ _proc = {"kind": "proc", "node": ""}
 _programs: Dict[str, List[dict]] = {}   # program -> its op maps, one a shape
 _unmapped: List[tuple] = []             # (program, make, t0, seconds) owed
 _flusher_claimed = False
+_host = {"gc_s": 0.0, "gc_collections": 0,
+         "late_wakeup_s": 0.0, "late_wakeups": 0}
+_host_owed: deque = deque()   # collections the ring has yet to be given
+_gc_open: Optional[tuple] = None        # (perf_counter, wall, annotation)
+_watch: Optional[Tuple[threading.Thread, threading.Event]] = None
 
 
 def configure(kind: str, node_id: str = "") -> None:
@@ -115,7 +137,8 @@ def claim_flusher() -> bool:
         if _flusher_claimed:
             return False
         _flusher_claimed = True
-        return True
+    _start_host_watch()
+    return True
 
 
 def release_flusher() -> None:
@@ -127,6 +150,92 @@ def release_flusher() -> None:
     global _flusher_claimed
     with _span_lock:
         _flusher_claimed = False
+    _stop_host_watch()
+
+
+# ----------------------------------------------------------- the host watch
+
+def host_totals() -> Dict[str, float]:
+    """Cumulative, of this process since its first claim: seconds and count
+    of garbage collections, and of the watch thread's late wake-ups."""
+    return dict(_host)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """`gc.callbacks` hook.  It runs on whichever thread's allocation
+    started the collection, possibly INSIDE `record_span` under
+    `_span_lock`: it takes no lock and records no span itself, the
+    finished collection waits in `_host_owed` for `_drain_host`."""
+    global _gc_open
+    if phase == "start":
+        ann = _annotation("host:gc")
+        if ann is not None:
+            ann.__enter__()
+        _gc_open = (time.perf_counter(), time.time(), ann)
+        return
+    if _gc_open is None:        # hooked while a collection was under way
+        return
+    (t0, wall, ann), _gc_open = _gc_open, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    took = time.perf_counter() - t0
+    _host["gc_s"] += took
+    _host["gc_collections"] += 1
+    if took >= GC_SPAN_FLOOR_S:
+        _host_owed.append((wall, wall + took, info.get("generation"),
+                           info.get("collected")))
+
+
+def _drain_host() -> None:
+    """The collections `_on_gc` left, into the ring as ``host:gc`` spans
+    (the watch thread every tick; every flush and the span file too, so a
+    collection of a process's last second is kept)."""
+    while _host_owed:
+        try:
+            t0, t1, generation, collected = _host_owed.popleft()
+        except IndexError:
+            return
+        record_span("host:gc", "host", t0, t1, generation=generation,
+                    collected=collected)
+
+
+def _watch_loop(stop: threading.Event) -> None:
+    while True:
+        t = time.perf_counter()
+        stopped = stop.wait(WATCH_TICK_S)
+        late = time.perf_counter() - t - WATCH_TICK_S
+        if late >= LATE_WAKEUP_S and not stopped:
+            _host["late_wakeup_s"] += late
+            _host["late_wakeups"] += 1
+            now = time.time()
+            record_span("host:late_wakeup", "host", now - late, now,
+                        late_ms=round(1e3 * late, 3))
+        _drain_host()
+        if stopped:
+            return
+
+
+def _start_host_watch() -> None:
+    global _watch
+    if _watch is not None or not GlobalConfig.trace_enabled:
+        return
+    stop = threading.Event()
+    thread = threading.Thread(target=_watch_loop, args=(stop,),
+                              name="rt-host-watch", daemon=True)
+    _watch = (thread, stop)
+    gc.callbacks.append(_on_gc)
+    thread.start()
+
+
+def _stop_host_watch() -> None:
+    global _watch
+    if _watch is None:
+        return
+    (thread, stop), _watch = _watch, None
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    stop.set()
+    thread.join(timeout=1.0)
 
 
 def _buffer() -> SpanRing:
@@ -183,17 +292,21 @@ class span:
     """One interval, as a context manager: a `jax.profiler` host
     annotation where JAX is loaded, and either a ring span (wall clock;
     the default) or, with ``into=(accumulator, key)``, seconds added to
-    ``accumulator[key]`` and nothing in the ring."""
+    ``accumulator[key]`` and nothing in the ring; ``cpu=True`` then adds
+    the calling thread's own CPU seconds (`time.thread_time`) to
+    ``accumulator[key + "_cpu"]``."""
 
-    __slots__ = ("name", "cat", "args", "into", "start", "_ann")
+    __slots__ = ("name", "cat", "args", "into", "cpu", "start", "_cpu0",
+                 "_ann")
 
     def __init__(self, name: str, cat: str = "task",
                  into: Optional[Tuple[Dict[str, float], str]] = None,
-                 **args: Any):
+                 cpu: bool = False, **args: Any):
         self.name = name
         self.cat = cat
         self.args = args
         self.into = into
+        self.cpu = cpu
 
     def __enter__(self):
         self._ann = _annotation(self.name)
@@ -201,11 +314,16 @@ class span:
             self._ann.__enter__()
         self.start = time.perf_counter() if self.into is not None \
             else time.time()
+        if self.cpu:
+            self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
         if self.into is not None:
             acc, key = self.into
+            if self.cpu:    # read INSIDE the wall interval: never above it
+                acc[key + "_cpu"] = acc.get(key + "_cpu", 0.0) \
+                    + time.thread_time() - self._cpu0
             acc[key] = acc.get(key, 0.0) + time.perf_counter() - self.start
         else:
             record_span(self.name, self.cat, self.start, time.time(),
@@ -226,6 +344,7 @@ def flush_batch() -> Optional[dict]:
     ``reset`` after :func:`mark_dirty`; None when there is nothing to
     ship.  A caller whose RPC fails calls :func:`mark_dirty`."""
     global _pending, _reship
+    _drain_host()
     with _span_lock:
         if _reship:
             spans, reset = _buffer().events(), True
@@ -342,6 +461,7 @@ def write_span_file(session_dir: Optional[str]) -> Optional[str]:
     if not session_dir:
         return None
     _map_pending()
+    _drain_host()
     with _span_lock:
         if _recorded == _filed or _ring is None:
             return None

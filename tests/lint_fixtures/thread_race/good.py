@@ -48,3 +48,36 @@ class Suppressed:
 
     def stop(self):
         self.flag = True  # rtpu: allow[thread-race]
+
+
+class _CountingLock:
+    """A wrapper of the class's own that takes its lock its own way (the
+    decode engine's `_LoopLock`: a wait is counted)."""
+
+    def __init__(self, owner):
+        self.owner = owner
+
+    def __enter__(self):
+        self.owner._lock.acquire()
+
+    def __exit__(self, *exc):
+        self.owner._lock.release()
+
+
+class WrappedLockEngine:
+    """The thread takes the lock through an attribute made by a class
+    whose name ends in ``Lock``: locked like any other."""
+
+    def __init__(self):
+        self._lock = threading.RLock()
+        self._mine = _CountingLock(self)
+        self.steps = 0
+        threading.Thread(target=self._loop, daemon=True).start()
+
+    def _loop(self):
+        with self._mine:
+            self.steps += 1
+
+    def stats(self):
+        with self._lock:
+            return self.steps

@@ -193,7 +193,8 @@ def test_engine_stats_carry_profile_and_phase_totals():
         assert prof["decode_step"]["dispatches"] >= 1
         assert prof["decode_step"]["compiles"] >= 1   # ledger alive
         ph = st["phase_totals"]
-        assert set(ph) == {"queue", "admission", "prefill",
+        # (the whole set is pinned in tests/test_tracing_spans.py)
+        assert set(ph) >= {"queue", "admission", "prefill",
                            "decode_dispatch", "first_token",
                            "prefill_tail", "schedule", "admit_host",
                            "dispatch", "readback", "publish"}

@@ -186,3 +186,110 @@ def test_a_drain_wakes_the_loop_only_where_it_unpaused_a_slot():
             cfg, [8, 8, 8], 4, max_len=256, seed=3)
     finally:
         eng.shutdown()
+
+
+# ---------------------------------------- what the loop's own turn waited for
+# (`phase_totals`: lock_wait, long_read; `stats()["waits"]`; PR 51)
+
+def _two_decoding(core, eng):
+    """Two sessions that hold a slot and stand paused at 2 tokens with
+    nobody polling: the loop sleeps, and decodes on when `_resume`d."""
+    a = core.handle({"op": "start", "prompt": [3, 1, 4]})
+    b = core.handle({"op": "start", "prompt": [8, 8, 8]})
+    sa, sb = eng.sessions[a["sid"]], eng.sessions[b["sid"]]
+    _wait(lambda: len(sa.queue) == len(sb.queue) == 2
+          and eng._flight is None, "the slots never paused")
+    return sa, sb
+
+
+def _long_reads():
+    from ray_tpu.util import tracing
+    return [e for e in tracing.span_events()
+            if e["name"] == "engine:long_read"]
+
+
+def test_an_undisturbed_loop_waits_for_no_lock_and_no_read():
+    """50 steps of two sessions with no caller at the lock: the loop's
+    every acquisition is the one `acquire(False)`, no read is long, and the
+    wall seconds of `schedule` are the thread's own CPU seconds or more."""
+    cfg, core = _core(max_len=512, token_queue_depth=2)
+    eng = core.engine
+    try:
+        _two_decoding(core, eng)
+        before, waits, spans = dict(eng.phase_s), dict(eng.waits), \
+            len(_long_reads())
+        steps = eng.steps
+        _resume(eng, depth=400)
+        _wait(lambda: eng.steps >= steps + 50, "the loop did not go on")
+        assert eng.waits == waits == {"lock_waits": 0, "long_reads": 0}
+        assert eng.phase_s["lock_wait"] == before["lock_wait"] == 0.0
+        assert eng.phase_s["long_read"] == before["long_read"] == 0.0
+        assert len(_long_reads()) == spans
+        ph = eng.phase_totals()
+        assert 0 < ph["schedule_cpu"] <= ph["schedule"] + 1e-6, ph
+        # `schedule` alone: `thread_time()` is a system call
+        assert [k for k in ph if k.endswith("_cpu")] == ["schedule_cpu"]
+        assert set(eng.stats()["waits"]) == {
+            "lock_waits", "long_reads", "gc_collections", "late_wakeups"}
+    finally:
+        eng.shutdown()
+
+
+def test_a_held_lock_is_the_loops_lock_wait():
+    """A thread that holds the engine's lock for 50 ms while two sessions
+    decode: the loop stands at its next acquisition for what is left of
+    them, counted once or more and in seconds."""
+    cfg, core = _core(max_len=512, token_queue_depth=2)
+    eng = core.engine
+    try:
+        _two_decoding(core, eng)
+        steps = eng.steps
+        _resume(eng, depth=400)
+        _wait(lambda: eng.steps >= steps + 5, "the loop did not go on")
+        assert eng.waits["lock_waits"] == 0
+        with eng._cond:
+            held = eng.steps
+            time.sleep(0.05)
+            # it stood still: at most the step whose publish was under way
+            assert eng.steps <= held + 1
+        _wait(lambda: eng.steps >= held + 5, "the loop did not go on")
+        assert eng.waits["lock_waits"] >= 1
+        assert 0.02 <= eng.phase_s["lock_wait"] <= 0.5
+        assert eng.phase_totals()["lock_wait"] == round(
+            eng.phase_s["lock_wait"], 6)
+        assert eng.stats()["waits"]["lock_waits"] >= 1
+    finally:
+        eng.shutdown()
+
+
+def test_a_stalled_read_is_one_long_read_span():
+    """Chaos site ``serve.decode_step`` with a delay of 300 ms (it stands
+    where a device or a transfer would stall the read): ONE ring span
+    `engine:long_read` with what the loop can say of it, and the seconds
+    in `long_read`.  The sleeping thread lets the interpreter go, so no
+    late wake-up stands beside it: the rule's verdict is the device."""
+    from ray_tpu.util import fault_injection as fi
+    cfg, core = _core(max_len=512, token_queue_depth=2)
+    eng = core.engine
+    try:
+        _two_decoding(core, eng)
+        spans = len(_long_reads())
+        fi.arm([{"site": "serve.decode_step", "action": "delay",
+                 "delay_s": 0.3, "match": {"nth": 3}}])
+        steps = eng.steps
+        _resume(eng, depth=400)
+        _wait(lambda: eng.steps >= steps + 10, "the loop did not go on")
+        assert eng.waits["long_reads"] == 1
+        assert 0.3 <= eng.phase_s["long_read"] < 2.0
+        assert eng.phase_s["long_read"] <= eng.phase_s["readback"]
+        (ev,) = _long_reads()[spans:]
+        assert ev["cat"] == "stall"
+        args = ev["args"]
+        assert 300.0 <= args["waited_ms"] < 2000.0 and args["live"] == 2
+        assert abs(ev["dur"] * 1e-3 - args["waited_ms"]) < 1.0
+        assert steps <= args["step"] <= steps + 3
+        assert not args.get("late_wakeups")     # zero is left out
+        assert eng.stats()["waits"]["long_reads"] == 1
+    finally:
+        fi.disarm()
+        eng.shutdown()

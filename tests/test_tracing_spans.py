@@ -338,7 +338,13 @@ def test_engine_phase_totals_first_token_and_prefill_tail():
         ph = core.engine.phase_totals()
         assert {"schedule", "admit_host", "dispatch", "readback",
                 "publish", "first_token", "prefill_tail", "queue",
-                "admission", "prefill", "decode_dispatch"} == set(ph)
+                "admission", "prefill", "decode_dispatch",
+                # what the host's turn waited for (PR 51)
+                "schedule_cpu", "lock_wait", "long_read", "gc",
+                "late_wakeup"} == set(ph)
+        # a thread's CPU seconds of an interval never pass its wall
+        # seconds (both clocks tick in nanoseconds; rounding: 1 us)
+        assert 0 <= ph["schedule_cpu"] <= ph["schedule"] + 1e-6, ph
         assert ph["first_token"] > 0 and ph["first_token"] >= ph["queue"]
         assert 0 < ph["prefill_tail"] < ph["prefill"]
         # one whole chunk, then the three tokens as one more of its shape
