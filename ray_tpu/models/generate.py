@@ -76,10 +76,14 @@ a summary pooled over tokens written ahead of a row's ``pos`` is pooled
 again by the program that feeds the true ones, so a chunk window set back
 need not be refused for it.  A DECODE STEP (one query a slot) attends both
 row sets by ONE kernel call a layer that fetches only the 128-row blocks a
-slot's masks let it see (`ops/cache_attention.py`), wherever the program is
-lowered for a TPU and the arrays' rows are whole blocks; a chunk's queries,
-and every other platform, take the dense form under the same masks
-(`ops/eva_attention.py` `attend_two`).
+slot's masks let it see (`ops/cache_attention.py` `attend_blocks`), and a
+CHUNK PROGRAM (a chunk of queries a lane, the lanes program and the lone one
+alike) by one that fetches the blocks SOME query of the lane's chunk sees
+(`attend_chunk_blocks`; a full or window layer's one row set likewise,
+`_attend_cached` `attend_mha`, unless a sink joins its softmax), wherever the
+program is lowered for a TPU and the arrays' rows are whole blocks; every
+other platform and shape takes the dense form under the same masks
+(`ops/eva_attention.py` `attend_two`, `attend_mha`'s `heads`).
 
 A FIFTH KIND OF STATE CHOOSES WHAT THE OTHERS' ROWS ARE READ FOR: a latent-
 attention model with an indexer (`ops/sparse_index.py`; layer kinds
@@ -333,12 +337,8 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
         rows[_CONV_STATE] = arrays[_CONV_STATE].shape[-2]
     blocked = ()
     if "eva" in cfg.kinds:
-        kn, vn = _kv_names("eva")
-        hk = cfg.kv_heads_of("eva")
-        if cache_attention.engages(
-                (1, 1, hk, cfg.n_heads // hk, cfg.head_dim),
-                [(arrays[kn], arrays[vn], None),
-                 (*(arrays[n] for n in _SUM_NAMES), None)]):
+        kn = _kv_names("eva")[0]
+        if cache_attention.engages(*_chunk_sets(cfg, arrays, "eva", 1, 1)):
             blocked = (rows.pop(kn), rows.pop(_SUM_NAMES[0]))
     dense = sum(arrays[name].shape[0] * arrays[name].shape[1] * n
                 for name, n in rows.items())
@@ -357,6 +357,71 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
         return dense + layers * blocks * cache_attention.BLOCK
 
     return count
+
+
+def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
+    """A chunk program of ``chunk`` rows a lane over a cache of these arrays
+    → ``count(pos, n_valid) -> (fetched, read)`` for ONE lane that feeds
+    ``n_valid`` real tokens from position ``pos``: the cache rows the
+    lane's attention MOVES from memory, and those of them that SOME REAL
+    query of the chunk sees (a key and the value beside it one row, summed
+    over the layers, as `rows_fetched` counts a step's).  Dense dots under a
+    mask move every row of the lane's arrays a layer; where
+    `ops/cache_attention.py`'s chunk kernel engages on this process's
+    backend, a layer kind by its shapes (`_chunk_sets`), the 128-row blocks
+    in which some query of the WHOLE chunk, padded rows too, has a visible
+    row: the host's count of the kernel's work list from positions.  None
+    for a model of latent layers: their chunk programs read through
+    `ops/latent_attention.py`, which nobody counts yet."""
+    if cfg.attention == "mla":
+        return None
+    arrays = cache_arrays(cache)
+    window, pooled = cfg.sliding_window, cfg.summary_chunk
+    kinds = []      # (kind, its layers, rows of each set, kernel engages)
+    for kind in ATTENTION_KINDS:
+        if kind in cfg.kinds and kind not in SPARSE_KINDS:
+            q_shape, sets = _chunk_sets(cfg, arrays, kind, 1, chunk)
+            kinds.append((kind, sets[0][0].shape[0],
+                          [k.shape[-1] for k, _, _ in sets],
+                          cache_attention.engages(
+                              q_shape, sets, kind in cfg.sink_kinds)))
+
+    def seen(kind, pos, n):
+        """(first row, rows) of each set that a query of ``pos .. pos + n
+        - 1`` sees: a range, which wraps where the set is a ring."""
+        if kind == "full":
+            return [(0, pos + n)]
+        if kind == "window":
+            first = max(0, pos - window + 1)
+            return [(first, pos + n - first)]
+        first = pos // window * window
+        return [(first, pos + n - first),
+                (0, (pos + n - 1) // window * window // pooled)]
+
+    def count(pos: int, n_valid: int) -> Tuple[int, int]:
+        fetched = read = 0
+        for kind, layers, rows, engaged in kinds:
+            read += layers * sum(n for _, n in seen(kind, pos, n_valid))
+            fetched += layers * (cache_attention.BLOCK * sum(
+                cache_attention.fetched_blocks(first % size, n, size)
+                for (first, n), size in zip(seen(kind, pos, chunk), rows))
+                if engaged else sum(rows))
+        return fetched, read
+
+    return count
+
+
+def _chunk_sets(cfg: TransformerConfig, arrays: Arrays, kind: str, b: int,
+                c: int):
+    """What `ops/cache_attention.py` `kernel_shape` is asked about a layer
+    of attention kind ``kind`` whose ``b`` rows feed ``c`` tokens each: the
+    queries' shape and the kind's row sets (keys, values, no mask), its own
+    arrays and, of a summary layer, the summaries beside them."""
+    hk = cfg.kv_heads_of(kind)
+    sets = [tuple(arrays[n] for n in _kv_names(kind)) + (None,)]
+    if kind == "eva":
+        sets.append(tuple(arrays[n] for n in _SUM_NAMES) + (None,))
+    return (b, c, hk, cfg.n_heads // hk, cfg.head_dim), sets
 
 
 def state_fetched(cache: KVCache, cfg: TransformerConfig):
@@ -797,10 +862,12 @@ def _key_block(c: int, heads: int, rows: int) -> int:
 @jax.named_scope("attention")
 def _lane_of(c_all: jnp.ndarray, l, lane: int) -> jnp.ndarray:
     """Batch row ``lane`` of layer ``l``: [1, heads, width, rows], held to
-    the cache's own row-major layout.  Left to itself the compiler cuts the
-    row out inside the dot that reads it and hands that dot the WHOLE cache
-    in the layout it would like: a copy of the cache a lane (v5e compiler,
-    heads of 128)."""
+    the cache's own row-major layout: a COPY of the lane's rows (63 MB a
+    lane a layer of the byte cell's rings and summaries), which only the
+    forms that no kernel serves still pay (`_by_lane`).  Left to itself the
+    compiler cuts the row out inside the dot that reads it and hands that
+    dot the WHOLE cache in the layout it would like: a copy of the cache a
+    lane (v5e compiler, heads of 128)."""
     return with_layout_constraint(
         jax.lax.dynamic_slice(c_all, (l, lane, 0, 0, 0),
                               (1, 1) + c_all.shape[2:])[0],
@@ -813,9 +880,12 @@ def _by_lane(live: jnp.ndarray, attend, operands) -> jnp.ndarray:
     ``live`` [B] is set, zeros for the others, stacked [B, ...]: a row that
     stands costs its operands alone (`lax.cond`), and what ``attend`` builds
     on the way (a chunk's float32 scores) is one row's at a time.  The
-    operands are cut out of the batch OUTSIDE the branch: a branch handed
-    the whole cache is handed it in the layout its dot would like, a copy
-    of the cache (v5e compiler, key-value heads of 128)."""
+    DENSE path of the lanes program: a CPU's, a latent layer's loop, a layer
+    with a sink, shapes that are no whole blocks; everything else attends
+    through a kernel's list of the lane's blocks.  The operands are cut out
+    of the batch OUTSIDE the branch: a branch handed the whole cache is
+    handed it in the layout its dot would like, a copy of the cache (v5e
+    compiler, key-value heads of 128)."""
     rows = [operands(lane) for lane in range(live.shape[0])]
     like = jax.eval_shape(attend, *rows[0])
     return jnp.concatenate([jax.lax.cond(
@@ -830,8 +900,11 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     """Run ``x`` [B, C, D] (C new tokens per row) through every layer
     against the cache: each attention layer writes the new tokens' columns
     (``write[kind](c_all, l, cols [B, heads, width, C]) -> c_all``) into
-    each of its state kind's arrays, then attends dense over layer ``l``
-    of them under ``mask[kind]`` [B|1, C, rows]; ``write`` and ``mask``
+    each of its state kind's arrays, then attends layer ``l`` of them
+    under ``mask[kind]`` [B|1, C, rows] (dense, or where
+    `ops/cache_attention.py` has a kernel for the shapes and the program
+    is lowered for a TPU, the 128-row blocks the mask shows a row's queries,
+    where they lie); ``write`` and ``mask``
     are keyed by the layer's attention kind (``"full"``, ``"window"``,
     ``"eva"``; a summary layer has ``"summary"`` beside its own: the mask
     over its summary rows, and `_summary_write`'s halves for
@@ -846,8 +919,9 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     rows a no-drop expert layer routes (None: all).  ``lanes`` [B] bool
     (None: the whole batch in one piece) makes the ATTENTION, the one part
     that reads no weight, a batch row's at a time and only where it is set
-    (`_by_lane`; a latent layer's blocked read on a TPU: the kernel's list
-    of a lane's blocks, `ops/latent_attention.py` `attend_cache`);
+    (a kernel's list of a lane's blocks: `ops/cache_attention.py`
+    `attend_chunk_blocks`, a latent layer's blocked read
+    `ops/latent_attention.py` `attend_cache`; `_by_lane` elsewhere);
     everything that reads a weight still runs once over the ``B x C``
     stacked rows.
     → (final-norm activations, arrays, load)."""
@@ -877,13 +951,29 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                               cv.astype(dt))
             return attn.reshape(-1, c, h, cv.shape[-2])
 
-        if lanes is None:
-            attn = heads(q, _layer_of(k_all, l), _layer_of(v_all, l),
-                         mask[kind])
-        else:
-            attn = _by_lane(lanes, heads, lambda p: (
+        def dense(q, k_all, v_all, m, *live):
+            # every row of the arrays, under the mask: a lane cut out at a
+            # time (`_by_lane`), or the batch whole
+            if not live:
+                return heads(q, _layer_of(k_all, l), _layer_of(v_all, l), m)
+            return _by_lane(live[0], heads, lambda p: (
                 q[p:p + 1], _lane_of(k_all, l, p), _lane_of(v_all, l, p),
-                mask[kind][p:p + 1]))
+                m[p:p + 1]))
+
+        def in_place(q, k_all, v_all, m, *live):
+            # a chunk's queries a lane: the blocks some query of the lane
+            # sees, where they lie
+            return cache_attention.attend_chunk_blocks(
+                q.reshape(-1, c, hk, h // hk, hd), [(k_all, v_all, m)], l,
+                *live).reshape(-1, c, h, v_all.shape[-2])
+
+        operands = (q, k_all, v_all, mask[kind]) \
+            + (() if lanes is None else (lanes,))
+        if c > 1 and cache_attention.kernel_shape(
+                *_chunk_sets(cfg, arrs, kind, b, c), sink is not None):
+            attn = mla.on_the_chip(in_place, dense, *operands)
+        else:
+            attn = dense(*operands)
         return (_attn_out(cfg, y, attn, lp),
                 dict(arrs, **{kn: k_all, vn: v_all}))
 
@@ -906,29 +996,32 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         qh = q.reshape(-1, c, hk, h // hk, hd)
 
         def dense(qh, k_a, v_a, k_b, v_b, m_a, m_b, *live):
-            # every row of both sets, under the masks
-            return eva.attend_two(qh, *(_layer_of(a, l) for a in (
-                k_a, v_a, k_b, v_b)), m_a, m_b)
+            # every row of both sets, under the masks: a lane cut out at a
+            # time (`_by_lane`), or the batch whole
+            if lanes is None:
+                return eva.attend_two(qh, *(_layer_of(a, l) for a in (
+                    k_a, v_a, k_b, v_b)), m_a, m_b)
+            return _by_lane(live[0], eva.attend_two, lambda p: (
+                qh[p:p + 1],
+                *(_lane_of(a, l, p) for a in (k_a, v_a, k_b, v_b)),
+                m_a[p:p + 1], m_b[p:p + 1]))
 
         def in_place(qh, k_a, v_a, k_b, v_b, m_a, m_b, *live):
-            # one query a slot: the blocks a slot sees, where they lie
-            return cache_attention.attend_blocks(
-                qh, [(k_a, v_a, m_a), (k_b, v_b, m_b)], l, *live)
+            # the blocks a slot's one query sees, or some query of a
+            # lane's chunk, where they lie
+            attend = cache_attention.attend_blocks if c == 1 \
+                else cache_attention.attend_chunk_blocks
+            return attend(qh, [(k_a, v_a, m_a), (k_b, v_b, m_b)], l, *live)
 
-        if lanes is not None:
-            attn = _by_lane(lanes, eva.attend_two, lambda p: (
-                qh[p:p + 1], *(_lane_of(arrs[n], l, p) for n in names),
-                *(m[p:p + 1] for m in masks)))
-        elif cache_attention.kernel_shape(qh.shape, [
-                (arrs[kn], arrs[vn], masks[0]),
-                (*(arrs[n] for n in _SUM_NAMES), masks[1])]):
-            # (a row whose token is not real stands: its result is thrown
-            # away, so nothing of its cache is fetched for it)
-            attn = mla.on_the_chip(
-                in_place, dense, qh, *(arrs[n] for n in names), *masks,
-                *(() if valid is None else (valid[:, 0],)))
+        # (a step's row whose token is not real stands: its result is
+        # thrown away, so nothing of its cache is fetched for it)
+        live = (lanes,) if lanes is not None else \
+            (valid[:, 0],) if c == 1 and valid is not None else ()
+        operands = (qh, *(arrs[n] for n in names), *masks, *live)
+        if cache_attention.kernel_shape(*_chunk_sets(cfg, arrs, kind, b, c)):
+            attn = mla.on_the_chip(in_place, dense, *operands)
         else:
-            attn = dense(qh, *(arrs[n] for n in names), *masks)
+            attn = dense(*operands)
         return _attn_out(cfg, y, attn.reshape(b, c, h, -1), lp), arrs
 
     def attend_mla(y, lp, arrs, l, kind, sel):
@@ -1177,9 +1270,11 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     compiles a program proportional to the full sequence — the
     llama-1b GQA variant of that compile is a known remote-compile-
     helper killer (SURVEY §9); chunking caps the compiled program at C
-    positions.  Chunk attention runs dense against the cache's max_len
-    (O(C·max_len) per chunk) — more FLOPs than causal flash, traded for
-    a bounded, cacheable compile.
+    positions.  Chunk attention runs against the cache: on a TPU over the
+    128-row blocks the chunk's queries see (`ops/cache_attention.py`
+    `attend_chunk_blocks`, O(C·pos)), dense against the cache's max_len
+    elsewhere (O(C·max_len) per chunk) — more FLOPs than causal flash,
+    traded for a bounded, cacheable compile.
 
     ``n_valid`` (int32 scalar, TRACED, 1 <= n_valid <= C) makes the chunk
     a PADDED one: only its first ``n_valid`` tokens are real (see
@@ -1551,8 +1646,11 @@ def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
     projections, the dense and routed FFNs and the head run once over the
     ``P x C`` stacked rows, so P sessions' chunks cost one pass over the
     weights where P batch-1 programs cost P.  The attention, which reads
-    no weight, stays a lane's (`_by_lane`): its float32 scores are ``[C,
-    heads, max_len]`` whatever P."""
+    no weight, stays a lane's: one kernel call a layer walks each live
+    lane's blocks in turn (`ops/cache_attention.py` `attend_chunk_blocks`:
+    the scores never leave VMEM), or, on the dense path, `_by_lane` cuts a
+    lane out at a time and its float32 scores are ``[C, heads, max_len]``
+    whatever P."""
     _check_decodable(cfg)
     lanes, c = tokens.shape
     _check_chunk(cfg, c)

@@ -1,4 +1,6 @@
-"""A decode step's attention over the cache blocks a slot SEES, and no other.
+"""A program's attention over the cache blocks its rows SEE, and no other:
+a decode step's one query a slot (`attend_blocks`), a chunk program's chunk
+of queries a lane (`attend_chunk_blocks`).
 
 One query a slot (a fused decode step) attends a few hundred to a few
 thousand cached rows, but the arrays that hold them are as long as the
@@ -31,9 +33,21 @@ maximum and sum across all sets, probabilities cast to the compute type for
 the value product, float32 accumulation, normalised after the values are
 summed.
 
+A CHUNK's ``C`` queries a lane (`attend_chunk_blocks`, call name
+``cache_chunk_attention``) walk the same list, built by one reduction more:
+a block is listed where ANY of the lane's queries has a set row in it
+(masks ``[P | 1, C, T]``).  The dense forms they replace cut a lane's
+arrays out of the cache first (63 MB a lane a layer of the byte cell's
+rings and summaries) and carry float32 scores ``[C, heads, T]`` through
+memory; here a block moves once, and the scores stay in VMEM, TRANSPOSED:
+the block's rows down the sublanes, a key-value head's ``g x C`` queries
+along the lanes, so that no reduction crosses lanes.  An item takes as many
+heads as the budget holds (`_chunk_heads`), the rest a second grid axis.
+
 `fetched_blocks` is the host's count of the same blocks from positions
-(what the serve engine's ``rows_fetched`` sums); `engages` says whether this
-process's backend runs the kernel, as `ops/cache_write.py` `device_calls`.
+(what the serve engine's ``rows_fetched`` and ``chunk_rows_fetched`` sum);
+`engages` says whether this process's backend runs the kernel, as
+`ops/cache_write.py` `device_calls`.
 """
 
 from __future__ import annotations
@@ -57,28 +71,67 @@ BLOCK = 128
 RowSet = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]  # keys, values, mask
 
 
-def kernel_shape(q_shape: Tuple[int, ...], sets: Sequence[RowSet]) -> bool:
-    """Whether `attend_blocks` takes queries ``[S, 1, hk, g, hd]`` over
-    ``sets`` (on a TPU, or under the interpreter): one query a slot, every
-    set's rows whole blocks, key and value widths whole sublane tiles of the
-    cache's type, and an item's two blocks (two of each in flight) inside
-    the budget."""
-    if q_shape[1] != 1:
+def kernel_shape(q_shape: Tuple[int, ...], sets: Sequence[RowSet],
+                 sink: bool = False) -> bool:
+    """Whether queries ``[S, C, hk, g, hd]`` over ``sets`` have a kernel
+    here (on a TPU, or under the interpreter): `attend_blocks` for ONE
+    query a slot (``C`` 1), `attend_chunk_blocks` for a chunk's ``C``.
+    Either way every set's rows are whole blocks, key and value widths whole
+    sublane tiles of the cache's type, and no attention ``sink`` joins the
+    softmax (the kernels have no word for one).  A step's item is every
+    head's two blocks (two of each in flight) inside the budget; a chunk's
+    item is `_chunk_heads` heads of them, its ``C`` queries whole lane
+    tiles, and some number of heads has to fit.
+
+    No class of shapes is refused for its speed: alone on the chip (4 lanes
+    x 2 layers a call unless said, dense / kernel in ms; my chip runs, PR
+    52) heads 32 / 32 of 128 over 17 + 13 blocks 2.378 / 0.407 (one lane
+    0.479 / 0.073), 48 / 8 of 128 over 132 blocks 9.454 / 1.155 and over 33
+    blocks 1.362 / 0.700, 64 / 4 of 192 and 128 over 76 blocks 6.324 /
+    1.606, 32 / 8 of 64 over 32 blocks 0.624 / 0.092, 25 / 25 of 64 over 8
+    blocks 0.201 / 0.039, and one lane of 16 / 16 of 64 over 8 blocks
+    0.031 / 0.011-0.014."""
+    if sink:
         return False
+    _, c, hk, g, _ = q_shape
     for k, v, _ in sets:
         tile = 32 // k.dtype.itemsize
-        if k.shape[-1] % BLOCK or k.shape[-2] % tile or v.shape[-2] % tile \
-                or 2 * (k.shape[2] * (k.shape[-2] + v.shape[-2]) * BLOCK
-                        * k.dtype.itemsize) > _VMEM_BLOCK_BUDGET:
+        if k.shape[-1] % BLOCK or k.shape[-2] % tile or v.shape[-2] % tile:
             return False
-    return True
+        if c == 1 and 2 * (hk * (k.shape[-2] + v.shape[-2]) * BLOCK
+                           * k.dtype.itemsize) > _VMEM_BLOCK_BUDGET:
+            return False
+    if c == 1:
+        return True
+    # (a chunk's queries lie along the lanes, a mask's columns beside them)
+    return c % BLOCK == 0 and _chunk_heads(q_shape, sets) > 0
 
 
-def engages(q_shape: Tuple[int, ...], sets: Sequence[RowSet]) -> bool:
-    """Whether a step lowered by THIS process's backend runs the kernel (a
-    host answer from shapes, as `ops.cache_write.device_calls`)."""
+def _chunk_heads(q_shape: Tuple[int, ...], sets: Sequence[RowSet]) -> int:
+    """Key-value heads a grid step of `attend_chunk_blocks` (a divisor of
+    ``hk``; 0: not one head fits): the most whose pipelined blocks (every
+    set's key and value block, the queries, the output and the mask, two of
+    each in flight) and float32 scratch (maximum and sum, a sublane tile
+    each, and the accumulator) stay inside `_VMEM_BLOCK_BUDGET`."""
+    _, c, hk, g, hd = q_shape
+    rows, vd = c * g, sets[0][1].shape[-2]
+    size = sets[0][0].dtype.itemsize
+    a_head = sum(2 * (k.shape[-2] + v.shape[-2]) * BLOCK * k.dtype.itemsize
+                 for k, v, _ in sets) \
+        + 2 * rows * (hd + vd) * size + rows * (2 * 8 + vd) * 4
+    for hb in range(hk, 0, -1):
+        if hk % hb == 0 and hb * a_head + 2 * c * BLOCK \
+                <= _VMEM_BLOCK_BUDGET:
+            return hb
+    return 0
+
+
+def engages(q_shape: Tuple[int, ...], sets: Sequence[RowSet],
+            sink: bool = False) -> bool:
+    """Whether a program lowered by THIS process's backend runs the kernel
+    (a host answer from shapes, as `ops.cache_write.device_calls`)."""
     return (jax.default_backend() == "tpu" or _interpret()) \
-        and kernel_shape(q_shape, sets)
+        and kernel_shape(q_shape, sets, sink)
 
 
 def fetched_blocks(first: int, rows: int, size: int) -> int:
@@ -102,13 +155,14 @@ def _set_starts(masks: Sequence[jnp.ndarray]) -> Tuple[Tuple[int, ...], int]:
 
 
 def block_work(masks: Sequence[jnp.ndarray], live: Optional[jnp.ndarray]):
-    """Masks ``[S, 1, T_i]`` bool of the row sets (``live`` [S] bool: the
+    """Masks ``[S, C, T_i]`` bool of the row sets (``live`` [S] bool: the
     slots that run; None: all) -> the list of blocks the kernel walks,
     ``(item [W], runs [W], held [sets, W], items)``, all int32.  A block has
     the FLAT number ``slot x nb + (blocks of the sets before its own) +
     block``, ``nb`` the blocks a slot over all sets; ``item[w]`` is the w-th
-    block in flat order whose mask has a set row, so slots ascend, a slot's
-    sets ascend and a set's blocks ascend.  A slot with no such block has
+    block in flat order whose mask has a set row FOR ANY of the slot's C
+    queries, so slots ascend, a slot's sets ascend and a set's blocks
+    ascend.  A slot with no such block has
     ONE item, its block 0 with ``runs`` 0: it moves nothing and writes
     zeros.  ``held[i, w]`` is what set ``i``'s index maps name at item
     ``w``: the item's own block where it is of set ``i``, else the last
@@ -119,7 +173,8 @@ def block_work(masks: Sequence[jnp.ndarray], live: Optional[jnp.ndarray]):
     step."""
     slots = masks[0].shape[0]
     seen = jnp.concatenate(
-        [m.reshape(slots, -1, BLOCK).any(-1) for m in masks], axis=1)
+        [m.reshape(slots, m.shape[1], -1, BLOCK).any((1, 3)) for m in masks],
+        axis=1)
     if live is not None:
         seen = seen & live[:, None]
     starts, nb = _set_starts(masks)
@@ -250,3 +305,130 @@ def attend_blocks(q: jnp.ndarray, sets: Sequence[RowSet], l,
       jnp.concatenate(masks, axis=-1).astype(jnp.int32),
       *(a for k, v, _ in sets for a in (k, v)))
     return out[:, None]
+
+
+def _chunk_kernel(l_ref, item_ref, runs_ref, held_ref, q_ref, m_ref, *refs,
+                  nb: int, starts: Tuple[int, ...], scale: float, group: int):
+    del l_ref, held_ref
+    n = len(starts)
+    kv_refs, o_ref = refs[:2 * n], refs[2 * n]
+    top_ref, sum_ref, acc_ref = refs[2 * n + 1:]
+    at, items = pl.program_id(1), pl.num_programs(1)
+    lane, block = item_ref[at] // nb, item_ref[at] % nb
+    first = (at == 0) | (item_ref[jnp.maximum(at - 1, 0)] // nb != lane)
+    last = (at == items - 1) | (item_ref[
+        jnp.minimum(at + 1, item_ref.shape[0] - 1)] // nb != lane)
+    runs = runs_ref[at] > 0
+    dt = q_ref.dtype
+
+    @pl.when(first)
+    def _():
+        top_ref[...] = jnp.full(top_ref.shape, _NEG_INF, jnp.float32)
+        sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    for i, (start, end) in enumerate(zip(starts, starts[1:] + (nb,))):
+
+        @pl.when(runs & (block >= start) & (block < end))
+        def _(k_ref=kv_refs[2 * i], v_ref=kv_refs[2 * i + 1]):
+            # TRANSPOSED: the block's rows run down the sublanes, a head's
+            # g x C queries along the lanes, so a query's maximum and sum
+            # fold vector against vector and no reduction crosses lanes, on
+            # the v5e the dearest thing the vector units do (the queries
+            # down the sublanes and a lane reduction an item: 5.25 ms a
+            # call of 4 lanes x 2 full layers of 64 / 4 heads where this
+            # form takes 1.61, 0.52 against 0.41 at 32 / 32; my chip runs,
+            # PR 52).  Both products take the cached block as it lies:
+            # ``k^T q`` contracts the keys' first axis, ``v p`` is plain.
+            # Every head of the item in ONE batched product: a Python loop
+            # that spells the heads out is no faster (0.415 ms where this
+            # takes 0.407) and is traced and lowered again at every start
+            # of the program, 3.5 s of a warm set-up of 26.
+            seen = m_ref[...].astype(jnp.int32) != 0            # [BLOCK, C]
+            if group > 1:       # group-major lanes: the same mask g times
+                seen = jnp.concatenate([seen] * group, axis=1)
+            seen = seen[None]                           # [1, BLOCK, R]
+            s = jnp.einsum("hdt,hdr->htr", k_ref[...].astype(dt), q_ref[...],
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen, s, _NEG_INF)
+            top = top_ref[...]                          # [hb, 1, R]
+            new_top = jnp.maximum(top, s.max(1, keepdims=True))
+            # (a hidden score stays at -1e30: it weighs 0 once a real one
+            # is in; a query that sees nothing here adds nothing)
+            p = jnp.where(seen, jnp.exp(s - new_top), 0.0)
+            fade = jnp.exp(top - new_top)
+            top_ref[...] = new_top
+            sum_ref[...] = sum_ref[...] * fade + p.sum(1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * fade + jnp.einsum(
+                "hdt,htr->hdr", v_ref[...].astype(dt), p.astype(dt),
+                preferred_element_type=jnp.float32)     # [hb, vd, R]
+
+    @pl.when(last)
+    def _():
+        total = jnp.maximum(sum_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / total).astype(o_ref.dtype)
+
+
+@jax.named_scope("attention")
+def attend_chunk_blocks(q: jnp.ndarray, sets: Sequence[RowSet], l,
+                        live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """`attend_blocks` for the ``C`` queries of a CHUNK a lane: ``q`` [P, C,
+    hk, g, hd] over layer ``l`` of the row ``sets`` (masks ``[P | 1, C, T]``;
+    `kernel_shape` accepted them) under ONE softmax a query, only where
+    ``live`` [P] is set (None: every lane; zeros elsewhere) -> [P, C, hk, g,
+    vd] in ``q``'s type: the dense forms' result over the same rows
+    (`ops.eva_attention.attend_two`, `models/generate.py` `heads`), moving
+    only the blocks in which SOME query of the lane's chunk has a set row
+    (`block_work`), each once, where the dense forms cut a lane's arrays
+    out of the cache and carry float32 scores ``[C, heads, T]`` through
+    memory.
+
+    The grid is (blocks of `_chunk_heads` heads, the work list); an item's
+    scores ``[heads, BLOCK, g x C]`` (the queries along the lanes), their
+    maximum, sum and accumulator stay in VMEM from the first product to the
+    second.  A lane that stands is one item
+    that moves nothing and writes zeros."""
+    lanes, c, hk, g, hd = q.shape
+    vd = sets[0][1].shape[-2]
+    hb = _chunk_heads(q.shape, sets)
+    masks = [jnp.broadcast_to(m, (lanes, c, m.shape[-1])) for _, _, m in sets]
+    item, runs, held, items = block_work(masks, live)
+    starts, nb = _set_starts(masks)
+
+    def kv_spec(i, width):
+        return pl.BlockSpec(
+            (None, None, hb, width, BLOCK),
+            lambda j, w, l, item, runs, held: (
+                l[0], held[i, w] // nb, j, 0, held[i, w] % nb - starts[i]))
+
+    by_lane = lambda j, w, l, item, runs, held: (item[w] // nb, j, 0, 0)
+    # a head's queries along the lanes, group-major: [P, hk, hd, g x C]
+    cols = jnp.transpose(q, (0, 2, 4, 3, 1)).reshape(lanes, hk, hd, g * c)
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, nb=nb, starts=starts,
+                          scale=hd ** -0.5, group=g),
+        name="cache_chunk_attention",
+        out_shape=jax.ShapeDtypeStruct((lanes, hk, vd, g * c), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(hk // hb, items),
+            in_specs=[
+                pl.BlockSpec((None, hb, hd, g * c), by_lane),
+                pl.BlockSpec((None, BLOCK, c),
+                             lambda j, w, l, item, runs, held: (
+                                 item[w] // nb, item[w] % nb, 0)),
+            ] + [kv_spec(i, a.shape[-2])
+                 for i, (k, v, _) in enumerate(sets) for a in (k, v)],
+            out_specs=pl.BlockSpec((None, hb, vd, g * c), by_lane),
+            scratch_shapes=[pltpu.VMEM((hb, 1, g * c), jnp.float32),
+                            pltpu.VMEM((hb, 1, g * c), jnp.float32),
+                            pltpu.VMEM((hb, vd, g * c), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+    )(jnp.asarray(l, jnp.int32).reshape(1), item, runs, held, cols,
+      jnp.swapaxes(jnp.concatenate(masks, axis=-1), 1, 2).astype(jnp.int8),
+      *(a for k, v, _ in sets for a in (k, v)))
+    return jnp.transpose(out.reshape(lanes, hk, vd, g, c), (0, 4, 1, 3, 2))

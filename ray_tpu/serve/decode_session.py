@@ -448,7 +448,12 @@ class ContinuousBatchingEngine:
         # carries both since the last.
         self.prefill_chunks = 0
         self.prefill_programs = 0
-        self._lanes_span = {"programs": 0, "chunks": 0, "t": time.time()}
+        # ... and the cache rows those programs' attention moved from
+        # memory, beside the rows a real query of theirs saw
+        # (`models.generate.chunk_rows_fetched`; zeros where nobody counts)
+        self.chunk_rows_fetched = self.chunk_rows_read = 0
+        self._chunk_fetched = None      # the counter, set by the loop
+        self._lanes_span = dict(self._lane_sums(), t=time.time())
         # ... of them those with fewer real tokens than the chunk holds
         # (a prompt's remainder), and the padding rows those carried
         self.prefill_tails = 0
@@ -713,6 +718,10 @@ class ContinuousBatchingEngine:
                     # engaged), and the lanes of one
                     "prefill_programs": self.prefill_programs,
                     "prefill_lanes": self._n_lanes,
+                    # the cache rows their attention moved, and those a
+                    # real query of theirs saw
+                    "chunk_rows_fetched": self.chunk_rows_fetched,
+                    "chunk_rows_read": self.chunk_rows_read,
                     # the rows of each (`prefill_chunk_width`)
                     "prefill_chunk_tokens":
                         self.ecfg.prefill_chunk_tokens,
@@ -1101,6 +1110,15 @@ class ContinuousBatchingEngine:
                 return
         sess.pcache = init_kv_cache(self.cfg, 1, self.max_len)
 
+    def _lane_sums(self) -> Dict[str, int]:
+        """What an `engine:lanes` span sums: chunk programs, the chunks they
+        consumed, the cache rows their attention moved and, of those, the
+        rows a real query saw."""
+        return {"programs": self.prefill_programs,
+                "chunks": self.prefill_chunks,
+                "chunk_rows_fetched": self.chunk_rows_fetched,
+                "chunk_rows_read": self.chunk_rows_read}
+
     def _count_chunks(self, riders: List[Tuple[_EngineSession, int]],
                       wall: float) -> None:
         """ONE chunk program that took ``wall`` seconds of the engine
@@ -1116,15 +1134,18 @@ class ContinuousBatchingEngine:
         tails = [n for _, n in riders if n < chunk]
         self.phase_s["prefill_tail"] += wall * len(tails) / len(riders)
         self._prof.note_tokens("prefill_chunk", sum(n for _, n in riders))
+        # (a rider's `poff` is already past the chunk it rode)
+        moved = [self._chunk_fetched(sess.poff - n, n) for sess, n in riders
+                 ] if self._chunk_fetched else []
         with self._loop_lock:   # stats() reads these counters
             self.prefill_programs += 1
             self.prefill_chunks += len(riders)
             self.prefill_tails += len(tails)
             self.prefill_pad_tokens += sum(chunk - n for n in tails)
+            self.chunk_rows_fetched += sum(f for f, _ in moved)
+            self.chunk_rows_read += sum(r for _, r in moved)
         self._lanes_span = self._sums_span(
-            "engine:lanes", "lanes",
-            {"programs": self.prefill_programs,
-             "chunks": self.prefill_chunks}, self._lanes_span)
+            "engine:lanes", "lanes", self._lane_sums(), self._lanes_span)
         SERVE_PREFILL_CHUNKS.inc(len(riders),
                                  tags={"deployment": self.name})
 
@@ -1322,7 +1343,8 @@ class ContinuousBatchingEngine:
         import numpy as np
 
         from ..models import init_slot_cache
-        from ..models.generate import (column_write_counts, rows_fetched,
+        from ..models.generate import (chunk_rows_fetched,
+                                       column_write_counts, rows_fetched,
                                        state_fetched)
         from ..util import fault_injection as fi
         from ..util import tracing
@@ -1334,6 +1356,9 @@ class ContinuousBatchingEngine:
         # `_FETCH_SUMS` of one, from its live slots' positions
         self._fetched = rows_fetched(self._cache, self.cfg)
         self._state_fetched = state_fetched(self._cache, self.cfg)
+        # `_lane_sums`' rows of one chunk a lane, from its position
+        self._chunk_fetched = chunk_rows_fetched(
+            self._cache, self.cfg, self.ecfg.prefill_chunk_tokens)
         slots = self.ecfg.max_slots
         self._carry = self._fresh_carry()
         self._warm_lanes()

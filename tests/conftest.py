@@ -76,7 +76,8 @@ _LONGEST_FIRST = (
     "test_pipeline_moe.py", "test_serve_failover.py",
     "test_program_parts.py", "test_perfbench_reference.py",
     "test_apex.py", "test_hf_trainer.py", "test_eva_attention.py",
-    "test_cache_in_place.py", "test_pixel_pong.py", "test_scale.py",
+    "test_cache_attention.py", "test_cache_in_place.py",
+    "test_pixel_pong.py", "test_scale.py",
     "test_offline_rl.py", "test_rl_plumbing.py", "test_tune.py",
     "test_alpha_zero.py", "test_maml.py",
     "test_train.py", "test_refcounting.py", "test_slateq.py",

@@ -433,7 +433,8 @@ def test_byte_model_step_attends_the_blocks_a_slot_sees_on_the_v5e(topo):
     arrays where they lie, and no dot over a slot's 2176 ring rows or 1664
     summary rows is left (their float32 scores went with them); the chunk
     program and the lanes program, whose rows hold a chunk of queries, lower
-    without the kernel, as the parent's."""
+    without THAT kernel (theirs is
+    `test_byte_model_chunk_programs_attend_the_blocks_a_lane_sees_on_the_v5e`)."""
     import re
 
     import jax
@@ -474,7 +475,7 @@ def test_byte_model_step_attends_the_blocks_a_slot_sees_on_the_v5e(topo):
         assert calls[0].count(f"bf16[{shape}]") == 2, calls[0]   # k and v
     scores = re.findall(r"f32\[[\d,]*,(?:2176|1664)\]", text)
     assert not scores, scores[:4]
-    # a chunk of queries a row: the dense form under `_by_lane`, or whole
+    # a chunk of queries a row has a kernel of its own
     chunk = jax.jit(prefill_chunk, static_argnames=("cfg",)).lower(
         params, described(jax.ShapeDtypeStruct((1, 128), jnp.int32)),
         described(jax.eval_shape(lambda: init_kv_cache(cfg, 1, max_len))),
@@ -483,6 +484,77 @@ def test_byte_model_step_attends_the_blocks_a_slot_sees_on_the_v5e(topo):
                                   "prefill_lanes_4x128", max_len)
     for lowered in (chunk, lanes):
         assert "cache_block_attention" not in lowered.as_text()
+
+
+def _chunk_attention_calls(text):
+    """The `ops/cache_attention.py` `attend_chunk_blocks` calls of a
+    compiled program's text."""
+    import re
+    return [x for x in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+            if "cache_chunk_attention" in x]
+
+
+def test_byte_model_chunk_programs_attend_the_blocks_a_lane_sees_on_the_v5e(
+        topo):
+    """The byte cell's LANES program (4 x 128) and its lone chunk program
+    (1 x 128): the attention is ONE call of `ops/cache_attention.py`'s chunk
+    kernel a summary layer (one in the layer loop's body) over the four
+    arrays where they lie; no lane's layer of a ring or of the summaries is
+    cut out of the cache (`_lane_of`'s 63 MB a lane a layer), no float32
+    score of 2176 or 1664 rows is left, and what the program keeps beside
+    the cache is a few MB.  Lowered for a CPU the same programs hold no such
+    call: the dense form, as the parent's."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import (init_kv_cache, init_params, init_slot_cache,
+                                prefill_chunk, prefill_lanes)
+    c = mf.Manifest().config("evabyte")
+    cfg = mf.family_of(c).model.model_config(
+        dict(c, num_hidden_layers=2), "serve")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg)[0],
+                            jax.random.PRNGKey(0))
+    params, max_len = described(shapes), 26624
+    _, lanes, _, _ = _lower_lanes(described, params, cfg,
+                                  "prefill_lanes_4x128", max_len)
+    lone = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                   donate_argnames=("cache",)).lower(
+        params, described(jax.ShapeDtypeStruct((1, 128), jnp.int32)),
+        described(jax.eval_shape(lambda: init_kv_cache(cfg, 1, max_len))),
+        cfg=cfg, n_valid=described(jax.ShapeDtypeStruct((), jnp.int32)))
+    for rows, lowered in ((4, lanes), (1, lone)):
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        calls = _chunk_attention_calls(text)
+        assert len(calls) == 1, calls
+        for shape in (f"2,{rows},32,128,2176", f"2,{rows},32,128,1664"):
+            assert calls[0].count(f"bf16[{shape}]") == 2, calls[0]  # k, v
+        cuts = re.findall(
+            r"= bf16\[(?:1,)*32,128,(?:2176|1664)\]\S* [a-z-]+\(", text)
+        assert not cuts, cuts[:4]
+        scores = re.findall(r"f32\[[\d,]*,(?:2176|1664)\]", text)
+        assert not scores, scores[:4]
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    # this process's own backend: the CPU's lowering is the dense form
+    for program, tokens, cache in (
+            (prefill_lanes, (4, 128), init_slot_cache(cfg, 4, 256)),
+            (prefill_chunk, (1, 128), init_kv_cache(cfg, 1, 256))):
+        cpu = jax.jit(program, static_argnames=("cfg",)).lower(
+            shapes, jax.ShapeDtypeStruct(tokens, jnp.int32),
+            jax.eval_shape(lambda: cache), cfg=cfg,
+            n_valid=jax.ShapeDtypeStruct(tokens[:1] if program is
+                                         prefill_lanes else (), jnp.int32))
+        assert "cache_chunk_attention" not in cpu.as_text()
 
 
 def test_delta_states_are_advanced_in_one_call_where_they_lie(topo):
@@ -891,6 +963,25 @@ def test_window_and_full_layers_copy_no_cache_no_ring_no_weights(topo,
     for a in arrays.values():
         shape = ",".join(map(str, a.shape))
         assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
+    # a chunk program's attention is one kernel call a run of layers (a
+    # window layer, two more, the full layer) over the kind's arrays where
+    # they lie: no lane's layer is cut out of a ring or of the full rows,
+    # and no float32 score of either is left
+    attends = _chunk_attention_calls(text)
+    if rows == 1 or program == "fused_step":    # one query a row: dense
+        assert not attends
+    else:
+        batch = arrays["k"].shape[1]
+        assert len(attends) == 3, attends
+        assert [a.count(f"bf16[3,{batch},8,128,4224]") for a in attends] \
+            == [2, 2, 0], attends
+        assert attends[2].count(f"bf16[1,{batch},8,128,16896]") == 2
+        if batch > 1:       # (`_lane_of`'s cuts; one row's arrays ARE so)
+            assert not re.findall(
+                r"= bf16\[(?:1,)*8,128,(?:4224|16896)\]\S* [a-z-]+\(",
+                text)
+        assert not re.findall(r"f32\[[\d,]*,(?:4224|16896)\]", text)
+        assert ma.temp_size_in_bytes < 64 << 20, ma.temp_size_in_bytes
     # three grouped matmuls a segment of expert layers, each given the whole
     # stack of 3 x 32 experts; no slice of it is copied out
     calls = _grouped_calls(text)
@@ -1099,6 +1190,20 @@ def test_two_cache_shapes_by_layer_kind_copy_no_cache_no_weights(topo,
         assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text), shape
     assert len(re.findall(r"= bf16\[(?:\d+,)?4096,[48],128\]\S* copy\(",
                           text)) <= 3
+    # a chunk program's FULL layers (no sink) attend through the chunk
+    # kernel, one call a run of them, over the full rows where they lie;
+    # the window layers, whose softmax a sink joins, keep the dense form
+    attends = _chunk_attention_calls(text)
+    if rows == 1 or program == "fused_step":    # one query a row: dense
+        assert not attends
+    else:
+        assert len(attends) == 2, attends
+        for a in attends:
+            assert f"bf16[2,{batch},4,192,9728]" in a \
+                and f"bf16[2,{batch},4,128,9728]" in a, a
+            assert ",256]" not in a, a
+        assert not re.findall(r"f32\[[\d,]*,9728\]", text)
+        assert re.findall(r"f32\[[\d,]*,256\]", text)     # the rings'
     # three grouped matmuls a segment of expert layers, each given the whole
     # stack of 6 x 16 experts; no slice of it is copied out
     calls = _grouped_calls(text)

@@ -305,3 +305,124 @@ def test_the_lanes_program_is_the_chunk_program_in_a_trace():
         == "jit_prefill_chunk"
     assert prefill_lanes_jit.__name__ == prefill_chunk_jit.__name__ \
         == "prefill_chunk"
+
+
+# ------------------------------------------------- through the chunk kernel
+# (`ops/cache_attention.py` `attend_chunk_blocks`, the Pallas interpreter):
+# chunks of 128 rows over arrays of whole 128-row blocks, the shapes the
+# chip serves, at toy widths
+
+WIDE = 128
+KERNEL_MODELS = ("byte_rings", "gqa_window_full")
+
+
+def _kernel_config(name: str):
+    """→ (cfg, max_len): a model whose every attention layer has a chunk
+    kernel at chunks of 128."""
+    if name == "byte_rings":    # rings of 256 + 128 rows, 256 summary rows
+        from perfbench import manifest as mf
+        rehearsal = os.path.join(mf.ROOT, "perfbench", "testdata",
+                                 "rehearsal")
+        c = mf.Manifest(
+            os.path.join(rehearsal, "BENCHMARK.tiny-evabyte.json"),
+            os.path.join(rehearsal, "traffic")).config("tiny-evabyte")
+        return dataclasses.replace(
+            mf.family_of(c).model.model_config(
+                c, "serve", attention_impl="reference"),
+            dtype=jnp.float32, param_dtype=jnp.float32, sliding_window=256,
+            window_chunk=WIDE, max_seq_len=1024), 1024
+    # two key-value heads of 16 under four query heads: rings of 128 + 128
+    # rows on the window layers, 512 rows on the full one
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2,
+        head_size=16, d_ff=160, max_seq_len=512, pos_emb="rope",
+        rope_base=1e4, rope_layers="window", activation="swiglu",
+        norm="rmsnorm", norm_eps=1e-5, tie_embeddings=False, remat=False,
+        qk_norm=True, layer_kinds=("window", "full", "window"),
+        sliding_window=128, window_chunk=WIDE, dtype=jnp.float32,
+        param_dtype=jnp.float32, attention_impl="reference"), 512
+
+
+@pytest.mark.parametrize("name", KERNEL_MODELS)
+def test_lanes_through_the_chunk_kernel_give_what_each_session_gets_alone(
+        name, monkeypatch):
+    """Each session ALONE through XLA's dense form (this process lowers for
+    the CPU), then the lanes program and the lone chunk program through the
+    kernel: A (300 tokens: two whole chunks and a padded one of 44 real
+    rows), B (150) joining at the second program, C from position 130 (a
+    seeded prefix) to 500, which wraps both models' rings; lane 2 stands
+    throughout and stays bit for bit."""
+    from ray_tpu.models import prefill_chunk, prefill_lanes
+    from ray_tpu.ops import cache_attention
+    cfg, max_len = _kernel_config(name)
+    params, _ = init_params(jax.random.PRNGKey(5), cfg)
+    toks = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (LANES, 512), 1, cfg.vocab_size), np.int32)
+
+    def alone(fn, prompt, off=0, cache=None):
+        cache = cache or init_kv_cache(cfg, 1, max_len)
+        out = []
+        while off < prompt.shape[1]:
+            logits, cache, off, _ = prefill_chunk_step(
+                fn, params, prompt, off, cache, cfg, chunk=WIDE,
+                capacity=max_len)
+            out.append(np.asarray(logits[0]))
+        return out, cache
+
+    prompts = {0: toks[0:1, :300], 1: toks[1:2, :150], 3: toks[3:4, :500]}
+    joins, offs = {0: 0, 1: 1, 3: 0}, {0: 0, 1: 0, 3: 130}
+    dense = jax.jit(prefill_chunk, static_argnames=("cfg",))
+    _, seed = alone(dense, toks[3:4, :130])
+    want = {p: alone(dense, prompts[p], offs[p], seed if p == 3 else None)
+            for p in prompts}
+    assert "cache_chunk_attention" not in dense.lower(
+        params, toks[:1, :WIDE], init_kv_cache(cfg, 1, max_len),
+        cfg=cfg, n_valid=np.int32(WIDE)).as_text()
+
+    # a trace is cached by the function: fresh ones under the interpreter
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    calls = []
+    real = cache_attention.attend_chunk_blocks
+    monkeypatch.setattr(cache_attention, "attend_chunk_blocks",
+                        lambda *a: calls.append(a[0].shape) or real(*a))
+    lanes_fn = jax.jit(lambda *a, **k: prefill_lanes(*a, **k),
+                       static_argnames=("cfg",))
+    lone_fn = jax.jit(lambda *a, **k: prefill_chunk(*a, **k),
+                      static_argnames=("cfg",))
+    # the lone chunk program: A's walk once more, through the kernel
+    lone, _ = alone(lone_fn, prompts[0])
+    traced = len(calls)     # once a run of layers that the loop scans
+    assert traced and calls[0][:2] == (1, WIDE), calls
+    for a, b in zip(lone, want[0][0]):
+        assert float(np.abs(a - b).max()) < 5e-5
+
+    pool = init_slot_cache(cfg, LANES, max_len)
+    pool = jax.jit(cache_insert_slot)(pool, seed, jnp.int32(3))
+    junk = {n: jax.random.normal(jax.random.PRNGKey(3), a[:, :1].shape)
+            for n, a in cache_arrays(pool).items()}
+    pool = jax.jit(cache_insert_slot)(
+        pool, dict(junk, pos=jnp.int32(5)), jnp.int32(2))
+    stood = _lane(pool, 2)
+    got, off = {p: [] for p in prompts}, dict(offs)
+    for program in range(9):
+        lanes = [(prompts[p], off[p]) if p in prompts
+                 and program >= joins[p] and off[p] < prompts[p].shape[1]
+                 else None for p in range(LANES)]
+        if not any(lanes):
+            break
+        logits, pool, moved = prefill_lanes_step(
+            lanes_fn, params, lanes, pool, cfg, chunk=WIDE,
+            capacity=max_len)
+        for p, m in enumerate(moved):
+            if m is not None:
+                off[p] = m[0]
+                got[p].append(np.asarray(logits[p]))
+    assert program == 3 and len(calls) == 2 * traced
+    assert calls[-1][:2] == (LANES, WIDE)
+    for p in prompts:
+        assert len(got[p]) == len(want[p][0])
+        for a, b in zip(got[p], want[p][0]):
+            assert float(np.abs(a - b).max()) < 5e-5, p
+        _assert_lane_is(pool, p, want[p][1], 5e-5)
+    for n, a in _lane(pool, 2).items():
+        assert np.array_equal(a, stood[n]), n
