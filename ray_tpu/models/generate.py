@@ -9,15 +9,17 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
-A cache holds up to SIX KINDS OF STATE behind the same functions (`cache_rows`,
-`position_bytes`, `cache_bytes`, the slot insert and gather): rows for the
-whole context (``full``), a ring of a window's rows (``ring``), a conv
-layer's last inputs (``state``), a row a chunk of positions (``summary``),
-an indexer's keys on the indexing layers alone (``index``) and a KDA
-layer's matrix of state a head beside its convolutions' last inputs
-(``delta``); they follow one by one below.  A cache's arrays may differ in
-TYPE (`array_dtype`): a delta state is float32 whatever the model computes
-in.
+A cache holds up to SEVEN KINDS OF STATE behind the same functions
+(`cache_rows`, `position_bytes`, `cache_bytes`, the slot insert and gather):
+rows for the whole context (``full``), a ring of a window's rows (``ring``),
+a conv layer's last inputs (``state``), a row a chunk of positions
+(``summary``), an indexer's keys on the indexing layers alone (``index``), a
+KDA layer's matrix of state a head beside its convolutions' last inputs
+(``delta``) and a state-space mixer's matrix of state a head beside its
+convolution's (``ssm``), which ONE LAYER holds together with rows of the
+first kind; they follow one by one below.  A cache's arrays may differ in
+TYPE (`array_dtype`): a delta state and a state-space state are float32
+whatever the model computes in.
 
 What a cache holds is a property of the model's attention kind
 (`cache_rows`): keys and values of ``kv_heads x head_dim`` (``"k"``,
@@ -120,6 +122,23 @@ call a layer over the stacked array (`delta_rule.step_in_place`: no cut of
 the layer before it, no placement after it; a live slot's states read once
 and written once, a standing slot's not at all; `state_fetched` counts it).
 
+A SEVENTH KIND OF STATE STANDS BESIDE ROWS IN ONE LAYER: an ``"ssm+full"``
+layer (`transformer.ssm_operator`, `ops/ssd.py`) runs a state-space mixer AND
+full attention off one norm, so the same layer owns ``s_ssm`` ``[L, batch,
+heads, state, dim]`` in FLOAT32 and ``conv_ssm`` ``[L, batch, 1, taps - 1,
+channels]`` WITHOUT positions (4 MB and 30 KB a slot a layer at 32 heads of
+128 with a state of 256, whatever ``max_len``) and keys and values ``k``,
+``v`` WITH them (the first kind's arrays, one layer counter for all four).
+Each half lives by its own kind's rules at once: the rows are written ahead
+of ``pos`` harmlessly and masked by position, the state and the convolution's
+inputs advance by a row's VALID tokens only, and `_check_state_rewind`
+refuses a chunk window set back although the rows alone could run it again.
+The fused step advances the state where it lies in one kernel call a layer
+(`ssd.step_in_place`) wherever `delta_rule.step_in_place` would its own, and
+`state_fetched` counts both.  Such a layer may not share a model with plain
+``"full"`` layers (their rows would share an array under two counters:
+`_check_decodable`).
+
 A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
 summary arrays say it (`cache_capacity`, which then needs the model's
 ``summary_chunk``), and nothing stands in for a full layer.  What still
@@ -167,7 +186,7 @@ import numpy as np
 
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from ..ops import cache_attention, delta_rule
+from ..ops import cache_attention, delta_rule, ssd
 from ..ops import eva_attention as eva
 from ..ops import latent_attention as mla
 from ..ops import sparse_index
@@ -175,11 +194,12 @@ from ..ops.attention import sink_softmax
 from ..ops.cache_write import device_calls, write_columns
 from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
-from .transformer import (ATTENTION_KINDS, SPARSE_KINDS, TransformerConfig,
-                          _attn_out, _ffn, _layer, _norm, _post, _qkv,
-                          _scale_embedding, _unembed, check_kinds,
-                          index_inputs, kda_operator, latent_queries,
-                          norm_eps, rope_tables, scan_layer_runs)
+from .transformer import (ATTENTION_KINDS, SPARSE_KINDS, SSM_KINDS,
+                          TransformerConfig, _attn_out, _ffn, _layer, _norm,
+                          _post, _qkv, _scale_embedding, _scaled,
+                          _ssm_widths, _unembed, check_kinds, index_inputs,
+                          kda_operator, latent_queries, norm_eps,
+                          rope_tables, scan_layer_runs, ssm_operator)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -195,13 +215,19 @@ _DELTA = "_delta"   # suffix of a KDA layer's arrays: no positions either
 _CONV_STATE = "conv" + _STATE
 _DELTA_STATE = "s" + _DELTA         # a matrix a head, float32
 _DELTA_CONV = "conv" + _DELTA       # its convolutions' last inputs
+_SSM = "_ssm"       # suffix of a state-space mixer's arrays: no positions
+_SSM_STATE = "s" + _SSM             # a matrix a head, float32
+_SSM_CONV = "conv" + _SSM           # its convolution's last inputs
+_SSM_ARRAYS = (_SSM_STATE, _SSM_CONV)
 #: the kinds of state that hold no positions: a sequence's whatever its length
-_NO_POSITIONS = ("state", "delta")
+_NO_POSITIONS = ("state", "delta", "ssm")
 _SUM_NAMES = ("k" + _SUMMARY, "v" + _SUMMARY)
 _INDEX_ARRAY = "k" + _INDEX
 #: the kinds of layer whose arrays hold a row a position for the whole
 #: context, written and masked alike
-_ROW_KINDS = ("full",) + SPARSE_KINDS
+_ROW_KINDS = ("full",) + SPARSE_KINDS + SSM_KINDS
+#: the arrays that are a float32 matrix a head, whatever the model's type
+_MATRIX_STATES = (_DELTA_STATE, _SSM_STATE)
 
 
 def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
@@ -217,6 +243,9 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     if "kda" in cfg.kinds:      # (key dims, value dims last) a head; taps
         state.update({_DELTA_STATE: (cfg.kda_heads, cfg.kda_head_dim),
                       _DELTA_CONV: (1, cfg.kda_conv_kernel - 1)})
+    if set(cfg.kinds) & set(SSM_KINDS):     # (state, dims last) a head; taps
+        state.update({_SSM_STATE: (cfg.ssm_heads, cfg.ssm_state),
+                      _SSM_CONV: (1, cfg.ssm_conv_kernel - 1)})
     if cfg.attention == "mla":
         rows = dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
         if "index" in cfg.kinds:    # ONE key a position, no value
@@ -239,7 +268,8 @@ def position_bytes(cfg: TransformerConfig) -> Dict[str, int]:
     positions (``summary``, of a model that has summaries), or a sequence
     whatever its positions (``state``:
     a conv layer's ``conv_kernel - 1`` inputs of ``d_model``; ``delta``: a
-    KDA layer's float32 matrix a head and its convolutions' inputs): what a
+    KDA layer's float32 matrix a head and its convolutions' inputs; ``ssm``:
+    a state-space mixer's, likewise): what a
     decode step reads of a row it attends, by the row's kind, each array at
     its own element size."""
     out = {"full": 0, "ring": 0, "state": 0}
@@ -253,20 +283,23 @@ def position_bytes(cfg: TransformerConfig) -> Dict[str, int]:
 
 def array_dtype(cfg: TransformerConfig, name: str):
     """The element type of the cache array ``name``: the model's, but a
-    delta state's float32 (a state held in less is another result)."""
-    return jnp.float32 if name == _DELTA_STATE else cfg.dtype
+    delta state's and a state-space state's float32 (a state held in less
+    is another result)."""
+    return jnp.float32 if name in _MATRIX_STATES else cfg.dtype
 
 
 def _own_rows(cfg: TransformerConfig, name: str) -> Optional[int]:
     """The last axis of an array that holds no positions (None: it does):
-    a convolution's channels, a delta state's value dims."""
+    a convolution's channels, a matrix state's value dims."""
     return {_CONV_STATE: cfg.d_model, _DELTA_STATE: cfg.kda_head_dim,
-            _DELTA_CONV: 3 * cfg.kda_heads * cfg.kda_head_dim}.get(name)
+            _DELTA_CONV: 3 * cfg.kda_heads * cfg.kda_head_dim,
+            _SSM_STATE: cfg.ssm_head_dim,
+            _SSM_CONV: _ssm_widths(cfg)[1]}.get(name)
 
 
 def _kv_names(kind: str) -> Tuple[str, str]:
     """The key and value arrays of a layer of attention kind ``kind``."""
-    return ("k", "v") if kind == "full" else ("k" + _RING, "v" + _RING)
+    return ("k", "v") if kind in _ROW_KINDS else ("k" + _RING, "v" + _RING)
 
 
 def window_ring(cfg: TransformerConfig, max_len: int) -> int:
@@ -297,8 +330,8 @@ def cache_bytes(cache: KVCache) -> Dict[str, int]:
     """Bytes of a cache's arrays by state kind: ``full`` (rows for the
     whole context), ``ring`` (window layers), ``state`` (conv layers) and,
     where the cache has them, ``summary`` (a row a chunk), ``index`` (an
-    indexer's keys) and ``delta`` (KDA layers: float32 states and the
-    convolutions' inputs)."""
+    indexer's keys), ``delta`` (KDA layers: float32 states and the
+    convolutions' inputs) and ``ssm`` (state-space mixers: the same)."""
     out = {"full": 0, "ring": 0, "state": 0}
     for name, a in cache_arrays(cache).items():
         kind = _state_kind(name)
@@ -389,7 +422,7 @@ def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
     def seen(kind, pos, n):
         """(first row, rows) of each set that a query of ``pos .. pos + n
         - 1`` sees: a range, which wraps where the set is a ring."""
-        if kind == "full":
+        if kind in _ROW_KINDS:
             return [(0, pos + n)]
         if kind == "window":
             first = max(0, pos - window + 1)
@@ -426,30 +459,38 @@ def _chunk_sets(cfg: TransformerConfig, arrays: Arrays, kind: str, b: int,
 
 def state_fetched(cache: KVCache, cfg: TransformerConfig):
     """A decode step over this slot cache → ``count(live slots)``: the bytes
-    of delta state its program MOVES (`position_bytes`' ``delta`` a slot a
-    KDA layer, the convolutions' inputs counted at the states' passes, as
-    the serve engine's ``state_bytes_moved`` counts them): where
-    `ops/delta_rule.py`'s kernel engages on this process's backend a live
-    slot's states once read and once written and a standing slot's not at
-    all; in XLA's form every slot's, live or not, read twice (the two
-    products, then the decay and correction) and written once.  Host counts
-    from shapes; 0 for a model without KDA layers."""
-    s_all = cache_arrays(cache).get(_DELTA_STATE)
-    if s_all is None:
-        return lambda live: 0
-    layers, slots = s_all.shape[:2]
-    per = layers * position_bytes(cfg)["delta"]
-    if delta_rule.engages(1, s_all):
-        return lambda live: 2 * live * per
-    return lambda live: 3 * slots * per
+    of delta and state-space state its program MOVES (`position_bytes`'
+    ``delta`` / ``ssm`` a slot a layer that carries one, the convolutions'
+    inputs counted at the states' passes, as the serve engine's
+    ``state_bytes_moved`` counts them): where the rule's kernel
+    (`ops/delta_rule.py`, `ops/ssd.py`) engages on this process's backend a
+    live slot's states once read and once written and a standing slot's not
+    at all; in XLA's form every slot's, live or not, read twice (as it
+    lowered the delta rule: the products, then the decay and the write) and
+    written once.  Host counts from shapes; 0 for a model without such
+    layers."""
+    arrays = cache_arrays(cache)
+    kernel = {_DELTA_STATE: delta_rule.engages,
+              _SSM_STATE: functools.partial(ssd.engages,
+                                            groups=cfg.ssm_groups)}
+    in_place = standing = 0
+    for name, engages in kernel.items():
+        if name in arrays:
+            layers, slots = arrays[name].shape[:2]
+            per = layers * position_bytes(cfg)[_state_kind(name)]
+            if engages(1, arrays[name]):
+                in_place += 2 * per
+            else:
+                standing += 3 * slots * per
+    return lambda live: live * in_place + standing
 
 
 def _state_kind(name: str) -> str:
     """``full`` | ``ring`` | ``state`` | ``summary`` | ``index`` |
-    ``delta``: what kind of state an array is."""
+    ``delta`` | ``ssm``: what kind of state an array is."""
     for suffix, kind in ((_RING, "ring"), (_STATE, "state"),
                          (_SUMMARY, "summary"), (_INDEX, "index"),
-                         (_DELTA, "delta")):
+                         (_DELTA, "delta"), (_SSM, "ssm")):
         if name.endswith(suffix):
             return kind
     return "full"
@@ -466,6 +507,7 @@ def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
               "index": (("index",), max_len),
               "ring": (("window", "eva"), window_ring(cfg, max_len)),
               "state": (("conv",), None), "delta": (("kda",), None),
+              "ssm": (SSM_KINDS, None),
               "summary": (("eva",), max_len // max(1, cfg.summary_chunk))}
     for name, (heads, width) in cache_rows(cfg).items():
         kinds, rows = stacks[_state_kind(name)]
@@ -497,14 +539,20 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "table")
     kinds = set(cfg.kinds)
     if len(cfg.kinds) != cfg.n_layers or \
-            kinds - {"full", "window", "conv", "eva", "kda", *SPARSE_KINDS}:
+            kinds - {"full", "window", "conv", "eva", "kda", *SPARSE_KINDS,
+                     *SSM_KINDS}:
         raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
                          f"{cfg.n_layers} of 'full' | 'window' | 'conv' | "
-                         f"'eva' | 'kda', or of 'index' | 'shared'")
+                         f"'eva' | 'kda' | 'ssm+full', or of 'index' | "
+                         f"'shared'")
+    if kinds & set(SSM_KINDS) and "full" in kinds:
+        raise NotImplementedError(
+            "a model that mixes 'full' and 'ssm+full' layers is not served: "
+            "their rows would share one array under two layer counters")
     check_kinds(cfg)
     if "conv" in kinds and cfg.conv_kernel < 2:
         raise ValueError("conv layers need conv_kernel of at least 2")
-    if not kinds & {"full", "eva", "index"}:
+    if not kinds & {"full", "eva", "index", *SSM_KINDS}:
         raise NotImplementedError(
             "a model without a full-attention layer or a summary layer (of "
             "window layers only, of conv or KDA layers, of those) is not "
@@ -563,14 +611,15 @@ def _check_chunk(cfg: TransformerConfig, c: int) -> None:
 
 
 def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
-    """What would need a conv or a KDA layer's state taken BACK is
-    refused, not answered wrongly: a state has no position to mask and no
-    later write that repairs it, so tokens fed twice (a chunk window set
-    back at the cache's end) have already shifted it."""
-    if {"conv", "kda"} & set(cfg.kinds):
+    """What would need a conv layer's, a KDA layer's or a state-space
+    mixer's state taken BACK is refused, not answered wrongly: a state has
+    no position to mask and no later write that repairs it, so tokens fed
+    twice (a chunk window set back at the cache's end) have already shifted
+    it; the rows a layer may hold beside such a state do not help it."""
+    if {"conv", "kda", *SSM_KINDS} & set(cfg.kinds):
         raise ValueError(
-            f"{what} over conv or KDA layers: their state cannot be taken "
-            f"back to an earlier token (models/generate.py)")
+            f"{what} over conv, KDA or state-space layers: their state "
+            f"cannot be taken back to an earlier token (models/generate.py)")
 
 
 @jax.named_scope("attention")
@@ -827,15 +876,16 @@ def _place_state(s_all: jnp.ndarray, l, state: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.named_scope("cache_write")
-def _place_delta(arrs: Arrays, l, state: jnp.ndarray, conv: jnp.ndarray
-                 ) -> Arrays:
-    """Every row's delta state [B, heads, dim, dim] and convolution inputs
-    [B, taps - 1, channels] into KDA layer ``l`` of their arrays."""
+def _place_delta(arrs: Arrays, l, state: jnp.ndarray, conv: jnp.ndarray,
+                 names=(_DELTA_STATE, _DELTA_CONV)) -> Arrays:
+    """Every row's matrix state [B, heads, dim, dim] and convolution inputs
+    [B, taps - 1, channels] into layer ``l`` of the arrays ``names`` (a KDA
+    layer's; a state-space mixer's: ``_SSM_STATE``, ``_SSM_CONV``)."""
+    s_name, c_name = names
     return dict(arrs, **{
-        _DELTA_STATE: jax.lax.dynamic_update_slice(
-            arrs[_DELTA_STATE], state[None].astype(jnp.float32),
-            (l, 0, 0, 0, 0)),
-        _DELTA_CONV: _place_state(arrs[_DELTA_CONV], l, conv)})
+        s_name: jax.lax.dynamic_update_slice(
+            arrs[s_name], state[None].astype(jnp.float32), (l, 0, 0, 0, 0)),
+        c_name: _place_state(arrs[c_name], l, conv)})
 
 
 def _rotators(turn, angles: Dict[str, Any]) -> Dict[str, Any]:
@@ -1095,20 +1145,33 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         return delta, dict(arrs, **{_CONV_STATE: _place_state(
             s_all, l, state)})
 
-    def kda(y, lp, arrs, l, kind):
-        # the delta state and the convolutions' inputs of layer l, read
-        # whole, advanced by the rows' valid tokens, written back
-        s_all, taps = arrs[_DELTA_STATE], _layer_of(arrs[_DELTA_CONV], l)[:, 0]
-        if delta_rule.kernel_shape(c, s_all):
-            # one token a row: the live rows' states advanced where they lie
-            delta, s_all, taps = kda_operator(cfg, y, lp, s_all, taps, n_new,
+    def matrix_state(operator, names, in_place):
+        """A layer's operator over a float32 matrix of state a head and its
+        convolutions' inputs (the arrays ``names``): layer l of both read
+        whole, advanced by the rows' valid tokens, written back; where
+        ``in_place`` takes the stack, one token a row, the live rows' states
+        are advanced where they lie."""
+        s_name, c_name = names
+
+        def run(y, lp, arrs, l, kind=None):
+            s_all, taps = arrs[s_name], _layer_of(arrs[c_name], l)[:, 0]
+            if in_place(c, s_all):
+                delta, s_all, taps = operator(cfg, y, lp, s_all, taps, n_new,
                                               layer=l)
-            return delta, dict(arrs, **{
-                _DELTA_STATE: s_all,
-                _DELTA_CONV: _place_state(arrs[_DELTA_CONV], l, taps)})
-        delta, state, taps = kda_operator(cfg, y, lp, _layer_of(s_all, l),
+                return delta, dict(arrs, **{
+                    s_name: s_all,
+                    c_name: _place_state(arrs[c_name], l, taps)})
+            delta, state, taps = operator(cfg, y, lp, _layer_of(s_all, l),
                                           taps, n_new)
-        return delta, _place_delta(arrs, l, state, taps)
+            return delta, _place_delta(arrs, l, state, taps, names)
+
+        return run
+
+    kda = matrix_state(kda_operator, (_DELTA_STATE, _DELTA_CONV),
+                       delta_rule.kernel_shape)
+    # a state-space mixer's half of an "ssm+full" layer
+    ssm = matrix_state(ssm_operator, _SSM_ARRAYS, functools.partial(
+        ssd.kernel_shape, groups=cfg.ssm_groups))
 
     operator = {"conv": conv, "eva": attend_eva, "kda": kda}
 
@@ -1119,6 +1182,9 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         else:
             delta, arrs = operator.get(kind, attend_mha)(y, lp, arrs, l,
                                                          kind)
+            if kind in SSM_KINDS:   # the mixer off the same norm: the sum
+                mixed, arrs = ssm(y, lp, arrs, l)
+                delta = delta + mixed
         xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
@@ -1188,6 +1254,15 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             arrs[name], new.astype(arrs[name].dtype)[None], (l, 0, 0, 0, 0))
             for name, new in zip(_SUM_NAMES, pooled)}
 
+    def from_zeros(operator, names, y, lp, arrs, l):
+        """The matrix state and convolution inputs (the arrays ``names``)
+        that the prompt leaves in layer l, from a zero state."""
+        s_all, c_all = (arrs[n] for n in names)
+        return _place_delta(arrs, l, *operator(
+            cfg, y, lp, jnp.zeros(s_all.shape[1:], s_all.dtype),
+            jnp.zeros(c_all.shape[1:2] + c_all.shape[3:], c_all.dtype)
+        )[1:], names)
+
     def layer(h, lp, arrs, l, kind, sel):
         # run the layer for h, re-project for the cache
         y = _norm(cfg, h, lp["attn_norm"], lp.get("attn_norm_b"))
@@ -1197,11 +1272,8 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             arrs = dict(arrs, **{_CONV_STATE: _place_state(
                 arrs[_CONV_STATE], l, state)})
         elif kind == "kda":     # ... and its delta state, from zeros
-            s_all, c_all = arrs[_DELTA_STATE], arrs[_DELTA_CONV]
-            arrs = _place_delta(arrs, l, *kda_operator(
-                cfg, y, lp, jnp.zeros(s_all.shape[1:], s_all.dtype),
-                jnp.zeros(c_all.shape[1:2] + c_all.shape[3:], c_all.dtype)
-            )[1:])
+            arrs = from_zeros(kda_operator, (_DELTA_STATE, _DELTA_CONV), y,
+                              lp, arrs, l)
         else:
             new = columns(y, lp, kind)
             arrs = dict(arrs, **{
@@ -1209,6 +1281,8 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
                 for n, rows in new.items()})
             if kind == "eva":
                 arrs = dict(arrs, **summaries(arrs, l, lp, *new.values()))
+            if kind in SSM_KINDS:   # ... beside the rows, the mixer's state
+                arrs = from_zeros(ssm_operator, _SSM_ARRAYS, y, lp, arrs, l)
         if kind == "index":     # the prompt's index keys, at this
             # indexing layer's own count
             k_i = sparse_index.index_keys(
@@ -1238,10 +1312,11 @@ def _last_logits(params: Params, x: jnp.ndarray, cfg: TransformerConfig
     a model with several prediction heads: every head's, head 0's first;
     `next_token_logits`)."""
     if cfg.fp32_logits:     # accumulated and handed out float32
-        return jnp.einsum("...d,dv->...v", x, _unembed(params, cfg),
-                          preferred_element_type=jnp.float32)
-    return jnp.einsum("...d,dv->...v", x,
-                      _unembed(params, cfg)).astype(jnp.float32)
+        return _scaled(jnp.einsum("...d,dv->...v", x, _unembed(params, cfg),
+                                  preferred_element_type=jnp.float32),
+                       cfg.logit_scale)
+    return _scaled(jnp.einsum("...d,dv->...v", x, _unembed(
+        params, cfg)).astype(jnp.float32), cfg.logit_scale)
 
 
 def greedy_tokens(logits: jnp.ndarray,

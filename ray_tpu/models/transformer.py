@@ -33,7 +33,9 @@ Design (idiomatic JAX, not a torch translation):
     block adds to the plain one is a property each: ``qk_norm``,
     ``attn_gate``, ``sandwich_norm``, ``embed_scale``.
   * a layer's KIND (``layer_kinds``: ``"window"`` | ``"full"`` | ``"conv"``
-    | ``"eva"``) may change from layer to layer and repeat inside a run: a
+    | ``"eva"`` | ``"kda"`` | ``"ssm+full"``, or of a model with an indexer
+    ``"index"`` | ``"shared"``) may change from layer to layer and repeat
+    inside a run: a
     window layer sees the last ``sliding_window`` positions (``rope_layers
     = "window"``: only those are rotated); a conv layer's operator is no
     attention at all but a gated short convolution (`ops/short_conv.py`);
@@ -67,7 +69,7 @@ Design (idiomatic JAX, not a torch translation):
     carry of `scan_layer_runs` from an indexing layer to the shared layers
     behind it, across the boundary of two runs too.  Indexer weights are
     stacked over the indexing layers only, and a served cache holds the
-    indexer's keys on those layers alone: the fifth of its six kinds of
+    indexer's keys on those layers alone: the fifth of its seven kinds of
     state (`models/generate.py`).
 
   * a ``"kda"`` layer's operator is no attention either but a GATED DELTA
@@ -82,6 +84,28 @@ Design (idiomatic JAX, not a torch translation):
     attention model may project its queries directly (``q_lora_rank`` 0:
     no query latent, no query norm) and turn nothing (``pos_emb`` neither
     ``"rope"`` nor ``"learned"``: no position enters the model at all).
+
+  * an ``"ssm+full"`` layer has TWO operators side by side off ONE norm, and
+    what it adds to the residual is their SUM: a state-space mixer
+    (`ssm_operator`, `ops/ssd.py`: values of ``ssm_heads x ssm_head_dim``,
+    keys and queries of ``ssm_state`` shared by ``ssm_groups`` groups of
+    heads, through one depthwise causal convolution with a bias, then a
+    ``ssm_state x ssm_head_dim`` float32 matrix of state a head under an
+    input-dependent step, a gate and a norm by group) AND full MHA/GQA
+    attention, each with weights of its own (the mixer's stacked over the
+    layers of this kind alone, attention's with the other attention
+    layers').  Its parameter and FLOP counts are both operators'; a served
+    cache holds for it a state WITHOUT positions and rows WITH them: the
+    seventh kind beside the first (`models/generate.py`).
+
+  * a model may state FIXED MULTIPLIERS at named places (muP), each a field
+    that defaults to 1 and costs nothing there: the embedding's
+    (``embed_scale``), the logits' (``logit_scale``), attention's input,
+    keys and output (``attn_in_scale``, ``key_scale``, ``attn_out_scale``),
+    a feed-forward's gate and output (``ffn_gate_scale``,
+    ``ffn_out_scale``), a state-space mixer's input, output and the five
+    segments of its input projection (``ssm_in_scale``, ``ssm_out_scale``,
+    ``ssm_scales``).
 
   * what a block, the stream and the head are may differ too, each a
     property with the plain model as its default: an RMSNorm that
@@ -182,9 +206,9 @@ class TransformerConfig:
     embed_scale: float = 1.0          # multiplies the token embedding
     # -- kinds of layer mixed -----------------------------------------------
     layer_kinds: Optional[Tuple[str, ...]] = None  # a layer "window" |
-    #   "full" | "conv" | "eva" | "kda", or of a model with an indexer
-    #   "index" | "shared", in model order (None → all full); may repeat
-    #   inside a run
+    #   "full" | "conv" | "eva" | "kda" | "ssm+full", or of a model with an
+    #   indexer "index" | "shared", in model order (None → all full); may
+    #   repeat inside a run
     conv_kernel: int = 3              # a conv layer's taps; its state is
     #   the last conv_kernel - 1 inputs of the convolution a sequence
     sliding_window: int = 0           # a window layer's position i sees
@@ -231,6 +255,28 @@ class TransformerConfig:
     #   queries, keys and values go through (SiLU after)
     kda_gate_rank: int = 0            # rank of the decay's and the output
     #   gate's two-step projections
+    # -- a state-space mixer BESIDE attention (ops/ssd.py) -------------------
+    ssm_heads: int = 0                # an "ssm+full" layer's state heads ...
+    ssm_head_dim: int = 0             # ... of this width (the mixer is
+    #   ssm_heads x ssm_head_dim wide, whatever d_model)
+    ssm_state: int = 0                # a key's and a query's width: a
+    #   sequence carries ssm_heads x ssm_state x ssm_head_dim float32
+    ssm_groups: int = 1               # groups of heads that share one key
+    #   and one query; the gated norm runs over each group's channels
+    ssm_conv_kernel: int = 4          # taps of the depthwise convolution
+    #   (with a bias, SiLU after) over values, keys and queries
+    # -- fixed multipliers at named places (1: none, and no instruction) -----
+    logit_scale: float = 1.0          # multiplies the logits
+    attn_in_scale: float = 1.0        # an MHA/GQA block's normed input
+    attn_out_scale: float = 1.0       # ... and what it adds to the residual
+    key_scale: float = 1.0            # its keys, before they are turned
+    ffn_gate_scale: float = 1.0       # a dense feed-forward's gate
+    #   projection, before the activation
+    ffn_out_scale: float = 1.0        # ... and what it adds to the residual
+    ssm_in_scale: float = 1.0         # a state-space mixer's normed input
+    ssm_out_scale: float = 1.0        # ... and what it adds to the residual
+    ssm_scales: Tuple[float, ...] = (1.0,) * 5  # its input projection by
+    #   segment: gate | values | keys | queries | step
     # -- what a block, the stream and the head may differ in -----------------
     norm_unit_offset: bool = False    # an RMSNorm multiplies by 1 + g
     fp32_residual: bool = False       # the residual stream is float32 (the
@@ -418,7 +464,9 @@ def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
                for run, n in cfg.layer_runs) + sum(
         4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
         else _kda_matmul_params(cfg) if kind == "kda"
-        else _attn_matmul_params(cfg, kind) for kind in cfg.kinds) \
+        else _attn_matmul_params(cfg, kind)
+        + (_ssm_matmul_params(cfg) if kind in SSM_KINDS else 0)
+        for kind in cfg.kinds) \
         + cfg.kinds.count("index") * _indexer_matmul_params(cfg)
 
 
@@ -466,8 +514,9 @@ def _attended(cfg: TransformerConfig, context_len: float,
             return min(context_len, windows * cfg.index_topk)
         return context_len
 
-    return sum(rows(kind) for kind in cfg.kinds
-               if kind not in ("conv", "kda"))  # neither attends anything
+    # (a conv and a KDA layer attend nothing; a layer with a state-space
+    # mixer BESIDE attention attends as a full one)
+    return sum(rows(kind) for kind in cfg.kinds if kind not in _STATE_LAYERS)
 
 
 def count_params(cfg: TransformerConfig) -> int:
@@ -483,6 +532,7 @@ def count_params(cfg: TransformerConfig) -> int:
         else 2 * cfg.head_dim if cfg.qk_norm else 0   # an attention layer's
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
         + (cfg.n_layers - n_conv - n_kda) * own + n_kda * kda \
+        + sum(k in SSM_KINDS for k in cfg.kinds) * _ssm_own_params(cfg) \
         + n_conv * d * cfg.conv_kernel \
         + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads \
         + cfg.kinds.count("eva") * 2 * cfg.kv_heads * cfg.head_dim \
@@ -509,7 +559,8 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     # an indexing layer's heads meet every position's ONE key (no values)
     attn += attn_factor // 2 * _index_flops_dim(cfg) * seq_len
     # a delta state's decay, read, correction and write a token: 7 a float
-    return 6 * n_matmul + attn + 3 * 7 * _kda_state_size(cfg)
+    return 6 * n_matmul + attn + 3 * 7 * _kda_state_size(cfg) \
+        + 3 * _SSM_STATE_OPS * _ssm_state_size(cfg)
 
 
 def _index_flops_dim(cfg: TransformerConfig) -> int:
@@ -535,7 +586,8 @@ def decode_flops_per_token(cfg: TransformerConfig,
         per_pos = cfg.n_heads * (cfg.head_dim + cfg.value_dim)
     # (a KDA layer's cost does not grow with the context)
     return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len) \
-        + 2 * _index_flops_dim(cfg) * context_len + 7 * _kda_state_size(cfg)
+        + 2 * _index_flops_dim(cfg) * context_len + 7 * _kda_state_size(cfg) \
+        + _SSM_STATE_OPS * _ssm_state_size(cfg)
 
 
 def engine_flops_table(cfg: TransformerConfig, max_len: int) -> dict:
@@ -590,6 +642,9 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     if Lk:      # the gated delta rule (ops/delta_rule.py): ONE of the
         # run's keys, so a model draws its other weights as it did
         _init_kda(add, p, ax, cfg, Lk, next(keys))
+    if kind_layers(cfg, run, SSM_KINDS):    # the state-space mixer, likewise
+        _init_ssm(add, p, ax, cfg, kind_layers(cfg, run, SSM_KINDS),
+                  next(keys))
     if La and cfg.attention == "mla":
         ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -829,8 +884,10 @@ def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate,
     head's first `rope_dim` dims (None: this layer turns nothing)."""
     dt = cfg.dtype
     kn, vn = kv_weight_names(cfg, kind)
+    y = _scaled(y, cfg.attn_in_scale)
     q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", y, lp[kn].astype(dt))
+    k = _scaled(jnp.einsum("bsd,dhk->bshk", y, lp[kn].astype(dt)),
+                cfg.key_scale)
     v = jnp.einsum("bsd,dhk->bshk", y, lp[vn].astype(dt))
     if cfg.value_scale != 1.0:
         v = (v.astype(jnp.float32) * cfg.value_scale).astype(dt)
@@ -877,7 +934,8 @@ def _attn_out(cfg: TransformerConfig, y: jnp.ndarray, attn: jnp.ndarray,
     if cfg.attn_gate:
         gate = jnp.einsum("bsd,dhk->bshk", y, lp["wg"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-    return jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
+    return _scaled(jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt)),
+                   cfg.attn_out_scale)
 
 
 def _post(cfg: TransformerConfig, delta: jnp.ndarray, lp: Params,
@@ -938,8 +996,10 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
                 q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
                 window=cfg.sliding_window if kind == "window" else None,
                 sink=lp["sink"] if kind in cfg.sink_kinds else None)
-        x = x + _post(cfg, _attn_out(cfg, y, attn, lp), lp,
-                      "post_attn_norm")
+        delta = _attn_out(cfg, y, attn, lp)
+        if kind in SSM_KINDS:   # the mixer off the same norm: the sum
+            delta = delta + ssm_operator(cfg, y, lp)[0]
+        x = x + _post(cfg, delta, lp, "post_attn_norm")
 
     y = norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
     z, aux, _ = _ffn(cfg, y, lp)
@@ -952,11 +1012,13 @@ def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
     dt = cfg.dtype
     up = jnp.einsum("bsd,df->bsf", y, w_in.astype(dt))
     if cfg.activation == "swiglu":
-        gate = jnp.einsum("bsd,df->bsf", y, w_gate.astype(dt))
+        gate = _scaled(jnp.einsum("bsd,df->bsf", y, w_gate.astype(dt)),
+                       cfg.ffn_gate_scale)
         z = jax.nn.silu(gate) * up
     else:
         z = jax.nn.gelu(up)
-    return jnp.einsum("bsf,fd->bsd", z, w_out.astype(dt))
+    return _scaled(jnp.einsum("bsf,fd->bsd", z, w_out.astype(dt)),
+                   cfg.ffn_out_scale)
 
 
 _NO_LOAD = (0, 0, 0)
@@ -1096,9 +1158,15 @@ def _embed(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
 
 def _scale_embedding(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
     """The embedding multiplier of a model that states one."""
-    if cfg.embed_scale == 1.0:
+    return _scaled(x, cfg.embed_scale)
+
+
+def _scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
+    """``x`` times a model's fixed multiplier, in float32 and back to
+    ``x``'s type; 1 is no multiplier and no instruction."""
+    if by == 1.0:
         return x
-    return (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
+    return (x.astype(jnp.float32) * by).astype(x.dtype)
 
 
 @jax.named_scope("head")
@@ -1119,8 +1187,9 @@ def forward_with_aux(params: Params, tokens: jnp.ndarray,
     # fp32 MXU accumulation straight out of the dot — rounding the logits
     # through bf16 first would cost ~3 decimal digits on a 50k-way softmax
     with jax.named_scope("head"):
-        logits = jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg),
-                            preferred_element_type=jnp.float32)
+        logits = _scaled(jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg),
+                                    preferred_element_type=jnp.float32),
+                         cfg.logit_scale)
     return logits, aux
 
 
@@ -1179,8 +1248,9 @@ def lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
             vc = jnp.swapaxes(valid.reshape(b, n, cfg.loss_chunk), 0, 1)
 
             def chunk_sum(xi, ti, vi):
-                logits = jnp.einsum("bcd,dv->bcv", xi, w_out,
-                                    preferred_element_type=jnp.float32)
+                logits = _scaled(jnp.einsum(
+                    "bcd,dv->bcv", xi, w_out,
+                    preferred_element_type=jnp.float32), cfg.logit_scale)
                 ls = optax.softmax_cross_entropy_with_integer_labels(
                     logits, ti)
                 return (ls * vi).sum()
@@ -1305,8 +1375,14 @@ _CONV_KEYS = ("conv_in", "conv_w", "conv_out")
 #: a KDA layer's (`_init_kda`)
 _KDA_KEYS = ("kda_in", "kda_conv", "kda_lo", "kda_fb", "kda_gb", "kda_a_log",
              "kda_dt_bias", "kda_norm", "kda_out")
+#: a state-space mixer's (`_init_ssm`)
+_SSM_KEYS = ("ssm_in", "ssm_conv", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
+             "ssm_d", "ssm_norm", "ssm_out")
 #: the kinds of layer whose operator is no attention and carries a state
 _STATE_LAYERS = ("conv", "kda")
+#: the kinds of layer with a state-space mixer BESIDE their attention: two
+#: operators off one norm, a state and rows at once
+SSM_KINDS = ("ssm+full",)
 #: an attention layer's (MHA/GQA and latent), of whatever attention kind
 _ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
               "wq_b", "wkv_a", "wkv_b", "kv_norm")
@@ -1319,12 +1395,23 @@ _INDEX_KEYS = ("wi_q", "wi_k", "wi_w", "ik_norm", "ik_norm_b")
 #: scores and chooses, one that attends the choice of the last such before it
 SPARSE_KINDS = ("index", "shared")
 #: the kinds of layer whose operator is attention
-ATTENTION_KINDS = ("full", "window", "eva") + SPARSE_KINDS
+ATTENTION_KINDS = ("full", "window", "eva") + SPARSE_KINDS + SSM_KINDS
 
 
 def check_kinds(cfg: TransformerConfig) -> None:
     """What an indexer needs of a configuration, refused with a message
     where it lacks it."""
+    if set(cfg.kinds) & set(SSM_KINDS) and (
+            min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                cfg.ssm_groups) < 1 or cfg.ssm_heads % cfg.ssm_groups
+            or cfg.ssm_conv_kernel < 2 or len(cfg.ssm_scales) != 5
+            or cfg.attention != "mha"):
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r}: an 'ssm+full' layer needs "
+            f"ssm_heads, ssm_head_dim and ssm_state of at least 1, whole "
+            f"groups of heads (ssm_groups divides ssm_heads), an "
+            f"ssm_conv_kernel of at least 2, five ssm_scales and MHA/GQA "
+            f"attention beside it")
     if "kda" in cfg.kinds and (
             min(cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank) < 1
             or cfg.kda_conv_kernel < 2):
@@ -1383,6 +1470,8 @@ def stack_kinds(cfg: TransformerConfig, key: str
         return ("conv",)
     if key in _KDA_KEYS:
         return ("kda",)
+    if key in _SSM_KEYS:
+        return SSM_KINDS
     if key == "sink":
         return cfg.sink_kinds
     if key in _WINDOW_KV:
@@ -1598,3 +1687,135 @@ def kda_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
     with jax.named_scope("projections"):
         return (jnp.einsum("bse,ed->bsd", o.reshape(b, s, h * hd).astype(dt),
                            lp["kda_out"].astype(dt)), state, conv)
+
+
+# ---------------------------------------------------------------------------
+# a state-space mixer beside attention (`SSM_KINDS`)
+# ---------------------------------------------------------------------------
+
+#: operations a float of state a token: decay, the key's outer product with
+#: the value added, the query's product summed
+_SSM_STATE_OPS = 5
+
+
+def _ssm_widths(cfg: TransformerConfig) -> Tuple[int, int]:
+    """(the mixer's width ``ssm_heads x ssm_head_dim``, its convolution's
+    channels: values | keys | queries of every group)."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    return inner, inner + 2 * cfg.ssm_groups * cfg.ssm_state
+
+
+def _ssm_matmul_params(cfg: TransformerConfig) -> int:
+    """A state-space mixer's two projections: in (gate | values, keys,
+    queries | a step a head) and out."""
+    inner, channels = _ssm_widths(cfg)
+    return cfg.d_model * (inner + channels + cfg.ssm_heads) \
+        + inner * cfg.d_model
+
+
+def _ssm_own_params(cfg: TransformerConfig) -> int:
+    """... and what it holds beside them: the convolution's taps and bias,
+    a step bias, a decay and a skip a head, the gated norm's weight."""
+    inner, channels = _ssm_widths(cfg)
+    return channels * (cfg.ssm_conv_kernel + 1) + 3 * cfg.ssm_heads + inner
+
+
+def _ssm_state_size(cfg: TransformerConfig) -> int:
+    """Floats of state-space state a sequence, summed over the layers."""
+    return sum(k in SSM_KINDS for k in cfg.kinds) * cfg.ssm_heads \
+        * cfg.ssm_state * cfg.ssm_head_dim
+
+
+def _init_ssm(add, p: Params, ax: Params, cfg: TransformerConfig, n: int,
+              key) -> None:
+    """A run's state-space weights, stacked over its ``n`` layers that have
+    a mixer: the input projection as one (``ssm_in``: gate | values | keys |
+    queries | step), the convolution over values, keys and queries with its
+    bias, the step's bias, the decay and the skip a head, the gated norm's
+    weight, the output projection.  What decides how long a state remembers
+    is drawn as Mamba-2 draws it: ``A_log = log U(1, 16)``, ``dt = exp
+    U(log 0.001, log 0.1)``, ``dt_bias = dt + log(-expm1(-dt))``."""
+    d, h, pt = cfg.d_model, cfg.ssm_heads, cfg.param_dtype
+    inner, channels = _ssm_widths(cfg)
+    taps = cfg.ssm_conv_kernel
+    ks = iter(jax.random.split(key, 6))
+    add("ssm_in", (d, inner + channels + h), d, ("embed", None), n, next(ks))
+    add("ssm_conv", (channels, taps), taps, (None, None), n, next(ks))
+    add("ssm_out", (inner, d), inner, (None, "embed"), n, next(ks))
+    p["ssm_a_log"] = jnp.log(jax.random.uniform(
+        next(ks), (n, h), pt, 1.0, 16.0))
+    dt = jnp.exp(jax.random.uniform(next(ks), (n, h), pt, math.log(1e-3),
+                                    math.log(1e-1)))
+    p["ssm_dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+    p["ssm_conv_b"] = jnp.zeros((n, channels), pt)
+    p["ssm_d"] = jnp.ones((n, h), pt)
+    p["ssm_norm"] = jnp.ones((n, inner), pt)
+    for name in ("ssm_a_log", "ssm_dt_bias", "ssm_conv_b", "ssm_d",
+                 "ssm_norm"):
+        ax[name] = ("layers", None)
+
+
+def ssm_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+                 state: Optional[jnp.ndarray] = None,
+                 conv: Optional[jnp.ndarray] = None,
+                 n_new: Optional[jnp.ndarray] = None, layer=None):
+    """A state-space mixer on a normed input ``y`` [b, s, d] -> (what it
+    adds to the residual [b, s, d], the state' [b, heads, state, dim]
+    float32, the convolution's last inputs' [b, taps - 1, channels]).
+    ``state`` None is the PLAIN form over a whole sequence from a zero state
+    (`ssd.sequence`); with a carried ``state`` and ``conv`` one token a row
+    is `ssd.step` and a chunk the chunkwise form (`ssd.chunk`), both
+    advancing a row by its ``n_new`` [b] valid tokens only (None: all).
+    With ``layer`` (one token a row only) ``state`` is the STACK of every
+    such layer's states [L, b, heads, state, dim] and so is the state handed
+    back, layer ``layer`` of it advanced where it lies
+    (`ssd.step_in_place`).
+
+    ALL of it stands under ``ssm``: inside, the two projections under
+    ``projections``, the convolution under ``conv``, and the rest (the
+    gates, the recurrence, the gated norm) under ``attention``, the part of
+    a layer's sequence mixers."""
+    from ..ops import ssd
+    from ..ops.short_conv import short_conv
+    dt, eps = cfg.dtype, norm_eps(cfg)
+    h, hd, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                   cfg.ssm_groups)
+    inner, channels = _ssm_widths(cfg)
+    b, s, _ = y.shape
+    with jax.named_scope("ssm"):
+        with jax.named_scope("projections"):
+            u = jnp.einsum("bsd,de->bse", _scaled(y, cfg.ssm_in_scale),
+                           lp["ssm_in"].astype(dt))
+            if any(m != 1.0 for m in cfg.ssm_scales):   # by segment
+                widths = (inner, inner, g * n, g * n, h)
+                u = (u.astype(jnp.float32) * jnp.concatenate([
+                    jnp.full((w,), m, jnp.float32)
+                    for w, m in zip(widths, cfg.ssm_scales)])).astype(dt)
+        z, step = u[..., :inner], u[..., inner + channels:]
+        u, conv = short_conv(u[..., inner:inner + channels], lp["ssm_conv"],
+                             conv, n_new, activation=jax.nn.silu,
+                             bias=lp["ssm_conv_b"])
+        with jax.named_scope("attention"):
+            x = u[..., :inner].reshape(b, s, h, hd)
+            B, C = (t.reshape(b, s, g, n)
+                    for t in jnp.split(u[..., inner:], 2, axis=-1))
+            step, a = ssd.gates(step, lp["ssm_dt_bias"], lp["ssm_a_log"])
+            if state is None:
+                o, state = ssd.sequence(x, B, C, step, a, lp["ssm_d"])
+            elif s == 1:
+                rule = ssd.step if layer is None else functools.partial(
+                    ssd.step_in_place, l=layer)
+                o, state = rule(
+                    x[:, 0], B[:, 0], C[:, 0], step[:, 0], a[:, 0],
+                    lp["ssm_d"], state,
+                    live=None if n_new is None else n_new > 0)
+                o = o[:, None]
+            else:
+                o, state = ssd.chunk(x, B, C, step, a, lp["ssm_d"], state,
+                                     n_new)
+            o = ssd.gated_norm(o.reshape(b, s, inner), z, lp["ssm_norm"], g,
+                               eps)
+        with jax.named_scope("projections"):
+            return (_scaled(jnp.einsum("bse,ed->bsd", o.astype(dt),
+                                       lp["ssm_out"].astype(dt)),
+                            cfg.ssm_out_scale), state, conv)

@@ -320,7 +320,15 @@ def _step_kernel(l_ref, plan_ref, x_ref, w_ref, s_ref, o_ref, out_ref, *,
         out_ref[...] = s_ref[...]
 
 
-def _step_pallas(q, k, v, a, beta, s_all, l, live):
+def in_place_call(kernel, name: str, x, w, s_all, l, live):
+    """ONE aliased `pl.pallas_call` named ``name`` over the stacked states
+    ``s_all`` [L, slots, heads, dk, dv] where they lie: the grid over (slot
+    in `_plan`'s order, block of heads); a grid step is handed its slot's
+    vectors ``x`` [slots, nx, heads, dk] and ``w`` [slots, nw, heads, dv],
+    the block of layer ``l``'s states and ``kernel(l_ref, plan_ref, x_ref,
+    w_ref, s_ref, o_ref, out_ref, group=)`` -> (``o`` [slots, heads, dv]
+    float32, the stack).  What `step_in_place` here and `ops/ssd.py`'s
+    share."""
     _, slots, heads, dk, dv = s_all.shape
     hb = _head_block(heads, dk, dv)
     nb = heads // hb
@@ -332,17 +340,16 @@ def _step_pallas(q, k, v, a, beta, s_all, l, live):
             0, 0))
     by_slot = lambda i, j, l, plan: (plan[0, i], 0, j, 0)
     return pl.pallas_call(
-        functools.partial(_step_kernel,
-                          group=_GROUP if hb % _GROUP == 0 else hb),
-        name="delta_rule_step",
+        functools.partial(kernel, group=_GROUP if hb % _GROUP == 0 else hb),
+        name=name,
         out_shape=(jax.ShapeDtypeStruct((slots, heads, dv), _F32),
                    jax.ShapeDtypeStruct(s_all.shape, s_all.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots, nb),
             in_specs=[
-                pl.BlockSpec((None, 3, hb, dk), by_slot),
-                pl.BlockSpec((None, 2, hb, dv), by_slot),
+                pl.BlockSpec((None, x.shape[1], hb, dk), by_slot),
+                pl.BlockSpec((None, w.shape[1], hb, dv), by_slot),
                 states,
             ],
             out_specs=(pl.BlockSpec(
@@ -355,9 +362,14 @@ def _step_pallas(q, k, v, a, beta, s_all, l, live):
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-    )(l.reshape(1), _plan(live), jnp.stack([q, k, a], axis=1),
-      jnp.stack([v, jnp.broadcast_to(beta[..., None], v.shape)], axis=1),
-      s_all)
+    )(l.reshape(1), _plan(live), x, w, s_all)
+
+
+def _step_pallas(q, k, v, a, beta, s_all, l, live):
+    return in_place_call(
+        _step_kernel, "delta_rule_step", jnp.stack([q, k, a], axis=1),
+        jnp.stack([v, jnp.broadcast_to(beta[..., None], v.shape)], axis=1),
+        s_all, l, live)
 
 
 def _step_slices(q, k, v, a, beta, s_all, l, live):

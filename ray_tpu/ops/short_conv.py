@@ -23,7 +23,8 @@ token's, and a row that does not advance keeps its state bit for bit.
 A ``"kda"`` layer (`ops/delta_rule.py`) runs its queries, keys and values
 through the same convolution, over channels of its own and with an
 ``activation`` after it (`short_conv`: any width of channels, the carried
-inputs and ``n_new`` as above).
+inputs and ``n_new`` as above); a state-space mixer (`ops/ssd.py`) its
+values, keys and queries, with a ``bias`` a channel before the activation.
 
 Plain `jax.numpy`: ``L`` shifted multiply-adds that XLA fuses between the
 two matmuls.
@@ -51,7 +52,8 @@ def conv_inputs(y: jnp.ndarray, w_in: jnp.ndarray
 def short_conv(u: jnp.ndarray, w: jnp.ndarray,
                state: Optional[jnp.ndarray] = None,
                n_new: Optional[jnp.ndarray] = None,
-               activation: Optional[Callable] = None
+               activation: Optional[Callable] = None,
+               bias: Optional[jnp.ndarray] = None
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``u`` [b, s, d], taps ``w`` [d, L] (tap ``L - 1`` meets the current
     token), ``state`` [b, L - 1, d] the inputs before the chunk (None: the
@@ -61,8 +63,9 @@ def short_conv(u: jnp.ndarray, w: jnp.ndarray,
     row ADVANCES: state' holds the inputs ``n_new - (L-1) .. n_new - 1`` of
     the chunk, reaching back into ``state`` where the chunk is shorter.  0
     returns the row's state as it came.  ``v`` is computed for all ``s``
-    rows whatever ``n_new`` (row ``t`` sees only rows ``<= t``), and goes
-    through ``activation`` (None: as it is) in float32."""
+    rows whatever ``n_new`` (row ``t`` sees only rows ``<= t``), takes
+    ``bias`` [d] (None: none) and goes through ``activation`` (None: as it
+    is) in float32."""
     b, s, d = u.shape
     taps = w.shape[-1]
     if state is None:
@@ -71,6 +74,8 @@ def short_conv(u: jnp.ndarray, w: jnp.ndarray,
     w32 = w.astype(jnp.float32)
     v = sum(w32[:, j] * ext[:, j:j + s].astype(jnp.float32)
             for j in range(taps))
+    if bias is not None:
+        v = v + bias.astype(jnp.float32)
     if activation is not None:
         v = activation(v)
     if n_new is None:

@@ -411,6 +411,10 @@ class ContinuousBatchingEngine:
         # ... or a matrix a head that a gated delta rule reads and writes
         # WHOLE every step (beside its convolutions' last inputs)
         self._kda_layers = cfg.kinds.count("kda")
+        # ... or a state-space mixer does, in a layer that ALSO attends
+        # rows of the full kind: counted once among each
+        from ..models.transformer import SSM_KINDS
+        self._ssm_layers = sum(k in SSM_KINDS for k in cfg.kinds)
         # layers that attend their own BLOCK of `sliding_window` rows (a
         # ring) and every earlier block through a summary row a
         # `summary_chunk` positions
@@ -773,7 +777,8 @@ class ContinuousBatchingEngine:
         arrays that hold ``max_len`` rows a slot; ``bytes_ring``: the
         window layers' rings; ``bytes_state``: the conv layers' states;
         ``bytes_index``: an indexer's keys, ``bytes_delta``: KDA layers'
-        float32 states and convolution inputs, where the model has them),
+        float32 states and convolution inputs, ``bytes_ssm``: state-space
+        mixers', where the model has them),
         what ONE further position of a slot costs (the full arrays' bytes
         a row: a ring and a state grow with nothing), and the rows and
         bytes the decode steps read (`_ROW_SUMS`) and the columns they wrote
@@ -1037,6 +1042,8 @@ class ContinuousBatchingEngine:
         windows, which start at ``depth`` and not at a multiple of the
         chunk, may be set back (a state cannot run tokens twice).  Any
         other donor is refused, and the prompt prefills from its start.
+        A layer with a state-space mixer BESIDE its attention is such a
+        state layer, although it has rows too.
 
         A SUMMARY layer (`models/generate.py`, the fourth state kind) has
         both: summary rows, one a chunk of positions, and a ring of its
@@ -1053,7 +1060,8 @@ class ContinuousBatchingEngine:
         Either way the first chunk window must not be set back before
         ``depth``: it would need the block before."""
         if not self._window and not self._conv_layers \
-                and not self._eva_layers and not self._kda_layers:
+                and not self._eva_layers and not self._kda_layers \
+                and not self._ssm_layers:
             return True
         sess = self._donors.get(donor)
         if sess is None:
@@ -1064,8 +1072,9 @@ class ContinuousBatchingEngine:
                 depth % self._block == 0
                 or sess.pos // self._block == depth // self._block)
         # (a KDA layer's state as a conv layer's: the donor's at its last
-        # token)
-        if (self._conv_layers or self._kda_layers) and (
+        # token; a state-space mixer's too, whatever rows its layer holds
+        # beside it: those any donor would serve, the state only this one)
+        if (self._conv_layers or self._kda_layers or self._ssm_layers) and (
                 sess.pos != depth or
                 depth + -(-(n - depth) // chunk) * chunk > self._capacity):
             return False
@@ -1566,8 +1575,10 @@ class ContinuousBatchingEngine:
     #: ... and what `_index_rows_of` does: the index keys an indexer scored
     #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
     _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
-    #: ... and what a delta state costs a step: the (slot, KDA layer)
-    #: states advanced, their bytes READ AND WRITTEN (NOT among
+    #: ... and what a delta or a state-space state costs a step: the (slot,
+    #: layer that carries one) states advanced, their bytes READ AND WRITTEN
+    #: (a layer that holds rows beside its state has those among
+    #: `_ROW_SUMS`, neither twice; NOT among
     #: `bytes_read`: no position is attended), and the bytes of state the
     #: step's program MOVES to do that (`models.generate.state_fetched`)
     _STATE_SUMS = ("state_rows", "state_bytes_moved", "state_bytes_fetched")
@@ -1651,13 +1662,17 @@ class ContinuousBatchingEngine:
 
     def _state_rows_of(self, batch) -> Tuple[int, int, int]:
         """`_STATE_SUMS` of a decode step about to be dispatched: every
-        live slot's delta state and convolution inputs on every KDA layer
-        are read whole AND written whole, whatever the slot's position,
-        and the program moves what its form of the rule on this backend
-        does; zeros for a model without such layers."""
-        states = self._kda_layers * len(batch)
-        return (states, 2 * states * self._row_bytes.get("delta", 0),
-                self._state_fetched(len(batch)))
+        live slot's matrix of state and convolution inputs on every KDA
+        layer and every layer with a state-space mixer are read whole AND
+        written whole, whatever the slot's position, and the program moves
+        what its form of the rule on this backend does; zeros for a model
+        without such layers."""
+        live = len(batch)
+        return ((self._kda_layers + self._ssm_layers) * live,
+                2 * live * (
+                    self._kda_layers * self._row_bytes.get("delta", 0)
+                    + self._ssm_layers * self._row_bytes.get("ssm", 0)),
+                self._state_fetched(live))
 
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
         """A read step's `_rows_of` and column writes into the counters,

@@ -65,6 +65,7 @@ _LONGEST_FIRST = (
     "test_perfbench_rehearsal.py", "test_sparse_index.py",
     "test_perfbench_family_glm_moe_dsa.py",
     "test_perfbench_family_kimi_linear.py", "test_delta_rule.py",
+    "test_perfbench_family_falcon_h1.py", "test_ssd.py",
     "test_perfbench_family_mimo_v2_flash.py", "test_mixed_kv_heads.py",
     "test_serve_decode_engine.py", "test_prefill_padded_tail.py",
     "test_window_ring.py", "test_short_conv_state.py", "test_gbdt.py",

@@ -642,6 +642,84 @@ def test_delta_states_are_advanced_in_one_call_where_they_lie(topo):
         assert "triangular_solve" in text
 
 
+def test_state_space_states_are_advanced_in_one_call_where_they_lie(topo):
+    """A Falcon-H1-shaped fused step (three layers of kind ``ssm+full`` at
+    the published widths: 32 state heads of 128 with a state of 256 in 2
+    groups, float32, beside 20 query heads over 4 key-value heads of 128):
+    the loop's body holds ONE call of `ops/ssd.py`'s kernel over the STACKED
+    states, which go aliased from argument to result with the keys and
+    values of the SAME layers; nothing else reads or writes an array of the
+    states' shape or of a layer's.  The chunk and lanes programs, whose rows
+    hold a chunk of tokens, lower without the kernel: the chunkwise form
+    between a cut and a placement."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import (init_kv_cache, init_params, init_slot_cache,
+                                prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots
+    c = dict(mf.Manifest().config("falcon-h1-34b"), num_hidden_layers=3)
+    cfg = mf.family_of(c).model.model_config(c, "serve")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 8, 1024
+    cache = described(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)))
+    assert cache["s_ssm"].shape == (3, slots, 32, 256, 128)
+    assert cache["k"].shape == (3, slots, 4, 128, max_len)
+
+    def fused_step(params, tok, cache, active):
+        logits, cache, _ = _decode_step_slots(params, tok, cache, active,
+                                              cfg)
+        return jnp.argmax(logits, axis=-1), cache
+    compiled = jax.jit(fused_step, donate_argnums=(2,)).lower(
+        params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    ).compile()
+    arrays = [a for name, a in cache.items() if name != "pos"]
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in arrays)
+    text = compiled.as_text()
+    calls = [x for x in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+             if "ssd_step" in x]
+    assert len(calls) == 1, [x[:200] for x in calls]
+    assert re.match(rf"= \(f32\[{slots},32,128\]\S* "
+                    rf"f32\[3,{slots},32,256,128\]\S*\) custom-call\(",
+                    calls[0]), calls[0][:200]
+    assert "output_to_operand_aliasing={{1}: (4, {})}" in calls[0]
+    state = rf"f32\[(?:3,)?{slots},32,256,128\]"
+    holders = set(re.findall(rf"(%[\w.\-]+) = [^\n]*?{state}", text))
+    touched = {}
+    for name, rest in re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = ([^\n]*)$",
+                                 text, re.M):
+        op = re.search(r" ([a-z][a-z\-]*)\(([^\n]*)", " " + rest)
+        if name in holders or holders & set(
+                re.findall(r"%[\w.\-]+", op.group(2).split("), ")[0])):
+            touched.setdefault(op.group(1), []).append(name)
+    assert set(touched) <= {"parameter", "get-tuple-element", "tuple",
+                            "while", "custom-call"}, {
+        k: v[:3] for k, v in touched.items()}
+    assert all("ssd_step" in n for n in touched["custom-call"])
+    chunk = jax.jit(prefill_chunk, static_argnames=("cfg",)).lower(
+        params, described(jax.ShapeDtypeStruct((1, 128), jnp.int32)),
+        described(jax.eval_shape(lambda: init_kv_cache(cfg, 1, max_len))),
+        cfg=cfg, n_valid=described(jax.ShapeDtypeStruct((), jnp.int32)))
+    _, lanes, _, _ = _lower_lanes(described, params, cfg,
+                                  "prefill_lanes_4x128", max_len)
+    for lowered in (chunk, lanes):
+        assert "ssd_step" not in lowered.as_text()
+
+
 def _lower_lanes(described, params, cfg, program, max_len):
     """``prefill_lanes_<P>x<C>``: the chunk program over P lanes of C rows
     (`models.generate.prefill_lanes`, what the engine runs while two or more

@@ -358,7 +358,8 @@ def test_a_cache_has_a_sixth_kind_of_state_of_a_type_of_its_own(world):
 def test_rows_a_step_attends_and_the_state_it_moves(world, monkeypatch):
     eng = types.SimpleNamespace(
         cfg=world.cfg, _window=0, _window_layers=0, _conv_layers=0,
-        _eva_layers=0, _kda_layers=4, _row_bytes=position_bytes(world.cfg))
+        _eva_layers=0, _kda_layers=4, _ssm_layers=0,
+        _row_bytes=position_bytes(world.cfg))
     batch = [types.SimpleNamespace(pos=9), types.SimpleNamespace(pos=99)]
     rows = ContinuousBatchingEngine._rows_of(eng, batch)
     assert rows == (2 * 110, 6 * 110, 2 * 110 * 96, 6 * 110 * 96, 0, 0)
