@@ -165,7 +165,12 @@ converts the whole cache on the way in and on the way out.
 
 The cached programs of a latent-attention model attend in the ABSORBED
 form (`ops/latent_attention.py`): the few queries of a chunk or a step
-meet the cached latents directly.  A model with no-drop routed experts
+meet the cached latents directly, and wherever the program is lowered for a
+TPU and the shapes are whole tiles they read them through ONE kernel call a
+layer that walks the blocks a lane's chunk or a slot's one query may see,
+where they lie (`attend_cache`, `_key_block`: a chunk, the lanes program and
+the decode step alike; `rows_fetched` and `chunk_rows_fetched` count what
+that moves).  A model with no-drop routed experts
 returns, beside its logits, what its expert layers routed (the ``load``
 of `_prefill_chunk` and `_decode_step_slots`); the serve engine counts
 the decode steps'.
@@ -357,10 +362,14 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
     ``positions`` (a key and the value beside it are one row, as the serve
     engine's ``rows_read`` counts them; an indexer's keys are counted by
     neither).  Dense dots under a mask move every row of every slot's arrays,
-    whatever the positions and live or not; where `ops/cache_attention.py`'s
-    kernel engages on this process's backend (a summary layer's step) a live
-    slot's blocks alone, the host's count of the kernel's work list
-    (`cache_attention.fetched_blocks`).  Host counts from shapes."""
+    whatever the positions and live or not; where a kernel engages on this
+    process's backend a live slot's blocks alone, the host's count of the
+    kernel's work list: `ops/cache_attention.py`'s for a summary layer's step
+    (`cache_attention.fetched_blocks`), `ops/latent_attention.py`
+    `attend_cache`'s for a latent layer's (`_latent_tile`: the tiles up to
+    the slot's position; under an indexer's choice the kernel stops at the
+    last CHOSEN row, which the host does not know: the count is then the
+    most it moves).  Host counts from shapes."""
     arrays = cache_arrays(cache)
     names = ("kv",) if cfg.attention == "mla" else tuple(
         _kv_names(kind)[0] for kind in ATTENTION_KINDS if kind in cfg.kinds) \
@@ -373,8 +382,15 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
         kn = _kv_names("eva")[0]
         if cache_attention.engages(*_chunk_sets(cfg, arrays, "eva", 1, 1)):
             blocked = (rows.pop(kn), rows.pop(_SUM_NAMES[0]))
+    tile = _latent_tile(cfg, arrays, 1) if cfg.attention == "mla" else 0
+    if tile:
+        del rows["kv"]
     dense = sum(arrays[name].shape[0] * arrays[name].shape[1] * n
                 for name, n in rows.items())
+    if tile:
+        latent = arrays["kv"].shape[0]
+        return lambda positions: dense + latent * sum(
+            mla.fetched_rows(pos + 1, tile) for pos in positions)
     if not blocked:
         return lambda positions: dense
     layers, (ring, sums) = arrays[kn].shape[0], blocked
@@ -392,6 +408,20 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
     return count
 
 
+def _latent_tile(cfg: TransformerConfig, arrays: Arrays, c: int) -> int:
+    """The cached rows a grid step of `ops/latent_attention.py`
+    `attend_cache` takes where a program that feeds ``c`` tokens a row of
+    this cache reads its latent layers through it ON THIS PROCESS'S BACKEND
+    (`mla.engages`, what `_key_block` and `mla.on_the_chip` decide where the
+    program is lowered), 0 where the layers read through XLA's forms: every
+    row of the array then."""
+    kv = arrays["kv"]
+    q_shape = (kv.shape[1], c, cfg.n_heads, kv.shape[-2])
+    block = _key_block(q_shape, cfg.kv_lora_rank, kv.shape[-1])
+    return mla.row_tile(q_shape, block) \
+        if mla.engages(q_shape, cfg.kv_lora_rank, block) else 0
+
+
 def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
     """A chunk program of ``chunk`` rows a lane over a cache of these arrays
     → ``count(pos, n_valid) -> (fetched, read)`` for ONE lane that feeds
@@ -403,12 +433,19 @@ def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
     `ops/cache_attention.py`'s chunk kernel engages on this process's
     backend, a layer kind by its shapes (`_chunk_sets`), the 128-row blocks
     in which some query of the WHOLE chunk, padded rows too, has a visible
-    row: the host's count of the kernel's work list from positions.  None
-    for a model of latent layers: their chunk programs read through
-    `ops/latent_attention.py`, which nobody counts yet."""
-    if cfg.attention == "mla":
-        return None
+    row: the host's count of the kernel's work list from positions.  A model
+    of latent layers likewise through `ops/latent_attention.py`
+    `attend_cache` (`_latent_tile`): the tiles up to the padded chunk's last
+    row where it engages, every row of the lane's layer where it does not;
+    under an indexer's choice both sums are of the rows the queries MAY see,
+    the most a choice reaches."""
     arrays = cache_arrays(cache)
+    if cfg.attention == "mla":
+        layers, rows = arrays["kv"].shape[0], arrays["kv"].shape[-1]
+        tile = _latent_tile(cfg, arrays, chunk)
+        return lambda pos, n_valid: (
+            layers * (mla.fetched_rows(pos + chunk, tile) if tile else rows),
+            layers * (pos + n_valid))
     window, pooled = cfg.sliding_window, cfg.summary_chunk
     kinds = []      # (kind, its layers, rows of each set, kernel engages)
     for kind in ATTENTION_KINDS:
@@ -895,18 +932,19 @@ def _rotators(turn, angles: Dict[str, Any]) -> Dict[str, Any]:
             for kind, (cos, sin) in angles.items()}
 
 
-def _key_block(c: int, heads: int, rows: int) -> int:
-    """The block of cached rows a latent layer's queries read at a time (0:
-    all at once), for ``c`` queries of ``heads`` heads over ``rows``
-    positions: blocked where their float32 scores would pass 160 MiB, which
-    no program of a context of a few thousand rows does, and a decode step
-    (one query a slot) does at none.  A blocked read is ONE KERNEL CALL a
-    layer over the state array where it lies (`ops/latent_attention.py`
-    `attend_cache`) wherever the program is lowered for a TPU and the
-    shapes are whole tiles (`kernel_shape`), and `_attend_blocks`' loop a
-    lane at a time elsewhere (`attend_mla`)."""
-    return sparse_index.key_block(rows) \
-        if c * heads * rows * 4 > 160 << 20 else 0
+def _key_block(q_shape: Tuple[int, ...], kv_lora: int, rows: int) -> int:
+    """The block of cached rows a latent layer's queries ``[b, c, heads, ·]``
+    read at a time through ONE KERNEL CALL a layer over the state array where
+    it lies (`ops/latent_attention.py` `attend_cache`), whatever the number
+    of queries a row, a decode step's one included: `sparse_index.key_block`
+    of a cache whose ``rows`` are worth blocking, 0 where they are not or the
+    shapes are no whole tiles (`kernel_shape`: a chunk's heads that fill no
+    head tile).  The SHAPE answers here and the PLATFORM where the program is
+    lowered (`mla.on_the_chip`): anywhere but on a TPU, and at a shape the
+    kernel refuses, the layer reads through XLA's forms, which ask their own
+    question of their own scores (`sparse_index.loop_block`)."""
+    block = sparse_index.key_block(rows)
+    return block if mla.kernel_shape(q_shape, kv_lora, block) else 0
 
 
 @jax.named_scope("attention")
@@ -1084,7 +1122,8 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             new[:, :, None, :], arrs["kv"].dtype))
         arrs = dict(arrs, kv=kv_all)
         seen = mask[kind]
-        block = _key_block(c, h, kv_all.shape[-1])
+        rows = kv_all.shape[-1]
+        block = _key_block(q_nope.shape, cfg.kv_lora_rank, rows)
         if kind == "index":
             # the new positions' index keys, every visible row scored, the
             # exact best chosen: this layer's mask and the shared layers'
@@ -1093,9 +1132,10 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             ik_all = write[kind](arrs[_INDEX_ARRAY], li, _as_columns(
                 k_i[:, :, None, :], arrs[_INDEX_ARRAY].dtype))
             arrs[_INDEX_ARRAY] = ik_all
-            choose = functools.partial(sparse_index.selection_mask,
-                                       topk=cfg.index_topk,
-                                       blocked=bool(block))
+            choose = functools.partial(
+                sparse_index.selection_mask, topk=cfg.index_topk,
+                blocked=bool(sparse_index.loop_block(c, cfg.index_heads,
+                                                     rows)))
             if lanes is None:
                 chosen = choose(q_i, w, _layer_of(ik_all, li)[:, 0], seen)
             else:
@@ -1110,20 +1150,21 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
 
         def loops(q_nope, q_rope, kv_all, seen, wkv_b, wo, *live):
             # XLA's forms: all rows at once, or `_attend_blocks`' loop
-            if not live:
+            whole = sparse_index.loop_block(c, h, rows)
+            if lanes is None:
                 return mla.attend_absorbed(
                     q_nope, q_rope, _layer_of(kv_all, l)[:, 0], wkv_b, wo,
-                    seen, block)
+                    seen, whole)
             q_abs = mla.absorb(q_nope, q_rope, wkv_b)
             return mla.unabsorb(_by_lane(
                 live[0], functools.partial(mla.attend_latents, scale=scale,
-                                           key_block=block),
+                                           key_block=whole),
                 lambda p: (q_abs[p:p + 1], _lane_of(kv_all, l, p)[:, 0],
                            seen[p:p + 1])),
                 wkv_b, wo, nope)
 
         def in_place(q_nope, q_rope, kv_all, seen, wkv_b, wo, *live):
-            # the blocked read as one kernel call over the state array
+            # the visible blocks by one kernel call over the state array
             return mla.unabsorb(mla.attend_cache(
                 mla.absorb(q_nope, q_rope, wkv_b, heads_major=True), kv_all,
                 l, seen, live[0] if live else None, scale, cfg.kv_lora_rank,
@@ -1131,7 +1172,11 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
 
         operands = (q_nope, q_rope, kv_all, seen, lp["wkv_b"], lp["wo"]) \
             + (() if lanes is None else (lanes,))
-        if mla.kernel_shape(q_nope.shape, cfg.kv_lora_rank, block):
+        if block:
+            # (a step's row whose token is not real stands: its result is
+            # thrown away, so the kernel fetches nothing of its cache)
+            if lanes is None and c == 1 and valid is not None:
+                operands += (valid[:, 0],)
             out = mla.on_the_chip(in_place, loops, *operands)
         else:
             out = loops(*operands)

@@ -11,7 +11,8 @@ key-value latent plus ONE rotary key a position, shared by all heads.
                      / sqrt(nope + rope)
 
 Two forms of the same function of (queries, latents), the second read in
-three ways:
+three ways, which the SHAPE and the PLATFORM choose between (`kernel_shape`,
+`on_the_chip`), never a size of scores:
 
 * `attend_plain` builds every head's keys and values from the latents and
   runs ordinary attention: the form for training and for a whole-sequence
@@ -26,19 +27,25 @@ three ways:
   rope`` values, not ``heads x (qk + v)``.  Between `absorb` and `unabsorb`
   the cached rows are read
 
-  - all at once (`attend_latents`): a decode step, and a chunk whose
-    float32 scores are a few tens of MB (a context of a few thousand rows);
-  - a block of rows at a time under a running softmax, no block past the
-    last row a query may see (`_attend_blocks`, plain `jax.numpy` in a
-    loop): where a chunk's scores over every row would be a GB, on any
-    platform but the TPU, and the tests' reference;
-  - the same blocks by ONE KERNEL CALL (`attend_cache`) where the program
-    is lowered for a TPU: the score block, its running maximum and sum and
-    the float32 accumulator stay in VMEM between the two dots, the cache
-    is read where it lies (no lane's layer is cut out of the state array),
-    and the second dot meets the ``kv_lora`` latent rows alone: 82 % of
-    the MXU's peak where XLA's loop, the cut in front of it, ran at 68 %
-    (4 lanes at 6-12 k rows, PERF.md, PR 47).
+  - by ONE KERNEL CALL a layer (`attend_cache`) wherever the program is
+    lowered for a TPU and the shapes are whole tiles (`kernel_shape`), a
+    chunk's queries and a DECODE STEP's one query a slot alike: a block of
+    rows at a time under a running softmax, no block past the last row a
+    lane's or a slot's queries may see and nothing for one that stands;
+    the score block, its running maximum and sum and the float32
+    accumulator stay in VMEM between the two dots, the cache is read where
+    it lies (no lane's layer is cut out of the state array), and the second
+    dot meets the ``kv_lora`` latent rows alone: 82 % of the MXU's peak
+    where XLA's loop, the cut in front of it, ran at 68 % (4 lanes at 6-12 k
+    rows, PERF.md, PR 47).  A step's heads, which all read the same rows
+    under the same mask, are one head tile's query rows there (PR 54);
+  - all at once (`attend_latents`) on any platform but the TPU, at a shape
+    the kernel refuses (a chunk whose heads fill no head tile), and as the
+    tests' reference: a decode step, and a chunk whose float32 scores are
+    a few tens of MB (a context of a few thousand rows);
+  - the kernel's blocks in plain `jax.numpy` (`_attend_blocks`, a loop),
+    in those same places, where a chunk's scores over every row would be a
+    GB (`sparse_index.loop_block`).
 
 Shapes are ``[batch, seq, heads, dim]`` like `ops/attention.py`; a cache
 layer is ``[batch, kv_lora + rope, positions]``, positions last, as
@@ -182,11 +189,13 @@ def attend_latents(q_abs: jnp.ndarray, cached: jnp.ndarray,
                    key_block: int = 0) -> jnp.ndarray:
     """Absorbed queries [b, s, h, kv_lora + rope] over ``cached`` [b, kv_lora
     + rope, T] under ``mask``, scores over ``scale`` -> [b, s, h, kv_lora +
-    rope]: the part that reads the cache, and no weight.  ``key_block`` (a
-    divisor of T; 0: all rows at once): the rows are read so many at a time
-    under a running softmax, and NO BLOCK PAST THE LAST ROW ANY QUERY MAY
-    SEE (`_attend_blocks`): a chunk's float32 scores of 64 heads over 33 k
-    rows would be 1.1 GB, most of them of rows its mask hides."""
+    rope]: the part that reads the cache, and no weight, in XLA'S FORMS
+    (`attend_cache` is the kernel's).  ``key_block`` (a divisor of T; 0: all
+    rows at once; `sparse_index.loop_block` says which): the rows are read
+    so many at a time under a running softmax, and NO BLOCK PAST THE LAST
+    ROW ANY QUERY MAY SEE (`_attend_blocks`): a chunk's float32 scores of
+    64 heads over 33 k rows would be 1.1 GB, most of them of rows its mask
+    hides."""
     if key_block:
         return _attend_blocks(q_abs, cached, mask, scale, key_block)
     dt = q_abs.dtype
@@ -236,8 +245,10 @@ def _attend_blocks(q_abs, cached, mask, scale: float, block: int):
 #: query heads a grid step of `attend_cache`: with a chunk's 128 queries the
 #: left operand of both dots is 1024 rows
 _HEAD_TILE = 8
-#: cached rows a grid step at most (a divisor of `sparse_index.KEY_BLOCK`)
+#: cached rows a grid step under a chunk's `_STACKED` query rows (a divisor
+#: of `sparse_index.KEY_BLOCK`): the float32 score block is then 2 MiB
 _ROW_TILE = 512
+_STACKED = 1024
 
 
 def kernel_shape(q_shape: Tuple[int, ...], kv_lora: int, block: int) -> bool:
@@ -247,10 +258,38 @@ def kernel_shape(q_shape: Tuple[int, ...], kv_lora: int, block: int) -> bool:
     whole tiles.  The queries of a head tile stack to the rows of one
     operand (``s`` a multiple of a bfloat16 tile's 16 sublanes), and the
     latent rows are a sublane-aligned slice of the cached block that fills
-    the output's lanes."""
+    the output's lanes.  A STEP (``s`` 1) has any number of heads: they are
+    the one tile's rows (`attend_cache`)."""
     _, s, h, _ = q_shape
-    return bool(block) and s % 16 == 0 and h % min(h, _HEAD_TILE) == 0 \
-        and kv_lora % _LANES == 0
+    tiles = s == 1 or (s % 16 == 0 and h % min(h, _HEAD_TILE) == 0)
+    return bool(block) and tiles and kv_lora % _LANES == 0
+
+
+def engages(q_shape: Tuple[int, ...], kv_lora: int, block: int) -> bool:
+    """Whether a program lowered by THIS process's backend reads a latent
+    layer through `attend_cache` (a host answer from shapes, as
+    `ops.cache_attention.engages`)."""
+    return (jax.default_backend() == "tpu" or _interpret()) \
+        and kernel_shape(q_shape, kv_lora, block)
+
+
+def row_tile(q_shape: Tuple[int, ...], block: int) -> int:
+    """Cached rows a grid step of `attend_cache` for queries ``[b, s, h,
+    r]`` over blocks of ``block``: `_ROW_TILE` under a chunk's `_STACKED`
+    query rows, and as many more as the rows are fewer, up to the block: a
+    step stacks its heads alone (32), and what an item costs beside its
+    rows' bytes (a grid step, the block made the stationary operand of two
+    dots) is paid half as often over a block of 1024."""
+    _, s, h, _ = q_shape
+    stacked = -(-h // 16) * 16 if s == 1 else min(h, _HEAD_TILE) * s
+    return math.gcd(block, _ROW_TILE * max(1, _STACKED // stacked))
+
+
+def fetched_rows(seen: int, tile: int) -> int:
+    """Rows `attend_cache` moves for a lane whose queries see ``seen`` rows
+    (0: it stands, and its one item moves nothing) a tile of ``tile`` at a
+    time: `_cache_work`'s items for it, counted on the host."""
+    return -(-seen // tile) * tile
 
 
 def _cache_work(rows: jnp.ndarray, block: int, most: int):
@@ -265,24 +304,25 @@ def _cache_work(rows: jnp.ndarray, block: int, most: int):
     blocks = jnp.maximum((rows + block - 1) // block, 1)
     ends = _cumsum(blocks)
     # what a standing lane's item reads: the last block of the nearest
-    # lane before it that runs (none: the first block of lane 0)
-    src, last = [], []
-    for p in range(lanes):
-        runs = rows[p] > 0
-        src.append(jnp.where(runs, p, src[-1] if p else 0))
-        last.append(jnp.where(runs, blocks[p] - 1, last[-1] if p else 0))
+    # lane before it that runs (none: the first block of lane 0); triangles
+    # of comparisons as `_cumsum`'s, whatever the lanes' number
+    at_lane = jnp.arange(lanes, dtype=jnp.int32)
+    runs = rows > 0
+    src = jnp.where((at_lane[None, :] <= at_lane[:, None]) & runs[None, :],
+                    at_lane[None, :], 0).max(1)
+    last = jnp.where((src[:, None] == at_lane[None, :]) & runs[None, :],
+                     blocks[None, :] - 1, 0).sum(1)
     item = jnp.arange(lanes * most, dtype=jnp.int32)
     lane = jnp.minimum((item[:, None] >= ends[None, :]).sum(1),
                        lanes - 1).astype(jnp.int32)
-    mine = lane[:, None] == jnp.arange(lanes)[None, :]
+    mine = lane[:, None] == at_lane[None, :]
 
     def of_lane(per_lane):              # [B] -> [W], by comparisons
         return jnp.where(mine, per_lane[None, :], 0).sum(1).astype(jnp.int32)
 
     at = jnp.clip(item - of_lane(ends - blocks), 0, most - 1)
-    return (lane, of_lane(jnp.stack(src)),
-            jnp.where(of_lane(rows) > 0, at, of_lane(jnp.stack(last))),
-            ends[-1])
+    return (lane, of_lane(src),
+            jnp.where(of_lane(rows) > 0, at, of_lane(last)), ends[-1])
 
 
 def _cache_kernel(l_ref, lane_ref, src_ref, at_ref, rows_ref, q_ref, kv_ref,
@@ -308,7 +348,8 @@ def _cache_kernel(l_ref, lane_ref, src_ref, at_ref, rows_ref, q_ref, kv_ref,
         rows = kv_ref[...].astype(dt)                          # [r, tk]
         scores = jnp.dot(q_ref[...].reshape(hb * c, r), rows,
                          preferred_element_type=jnp.float32) * inv_scale
-        seen = (seen_ref[...].astype(jnp.int32) != 0)[None]   # [1, c, tk]
+        # (one row where all the tile's queries see the same: a step's)
+        seen = (seen_ref[...].astype(jnp.int32) != 0)[None]  # [1, c|1, tk]
         scores = jnp.where(seen, scores.reshape(hb, c, -1), _NEG_INF)
         top = top_ref[...]                                     # [hb, c, 1]
         new_top = jnp.maximum(top, scores.max(-1, keepdims=True))
@@ -333,6 +374,7 @@ def _cache_kernel(l_ref, lane_ref, src_ref, at_ref, rows_ref, q_ref, kv_ref,
 
 
 @jax.named_scope("attention")
+@functools.partial(jax.jit, static_argnames=("scale", "kv_lora", "block"))
 def attend_cache(q_abs: jnp.ndarray, kv_all: jnp.ndarray, l,
                  mask: jnp.ndarray, live: Optional[jnp.ndarray],
                  scale: float, kv_lora: int, block: int) -> jnp.ndarray:
@@ -342,8 +384,8 @@ def attend_cache(q_abs: jnp.ndarray, kv_all: jnp.ndarray, l,
     s, T], a batch row at a time and only where ``live`` [b] is set (None:
     everywhere; zeros elsewhere) -> ``[b, h, s, kv_lora]``, the latent rows'
     part of `attend_latents`' result (all `unabsorb` reads).  ``block``: a
-    divisor of T that `kernel_shape` accepted; a grid step takes at most
-    `_ROW_TILE` rows of it.
+    divisor of T that `kernel_shape` accepted; a grid step takes
+    `row_tile`'s rows of it.
 
     The same arithmetic: operands in the compute type, float32 scores over
     ``scale``, the mask a query's own set of columns, float32 running
@@ -362,46 +404,67 @@ def attend_cache(q_abs: jnp.ndarray, kv_all: jnp.ndarray, l,
     4.13 ms, 8 x 1024 4.19, 16 x 512 4.00-4.11, 8 x 256 5.23; the 8 heads
     as two or more chains of fewer rows 4.29-5.22): the dots bind, at 82 %
     of the MXU's peak (87 % of what a contraction of 576 leaves of it: the
-    MXU runs it as 640)."""
+    MXU runs it as 640).
+
+    A DECODE STEP (``s`` 1: one query a slot) is the same call: all heads
+    of a latent layer read the SAME rows under the SAME mask, so a slot's
+    heads ARE one head tile's query rows (``[b, 1, h, r]``, zero rows up to
+    a whole bfloat16 tile, whose result is dropped) and its mask stays one
+    row.  A slot at depth ``t`` then costs ``t`` rows a tile at a time and
+    one that stands an item that moves nothing, where dense dots under a
+    mask move every row of every slot whatever stands in them.
+
+    Jitted in its own right: a model's latent layers sit in loop segments
+    of their own (seven in Kimi-Linear's three served programs), and a
+    process traces the work list and the kernel and lowers them ONCE a
+    program and not once a segment (warm `setup.warmup_s` read +3.0 s with
+    21 lowerings; PERF.md, PR 54)."""
     b, h, s, r = q_abs.shape
     t = kv_all.shape[-1]
-    hb, block = min(h, _HEAD_TILE), math.gcd(block, _ROW_TILE)
-    seen = jnp.broadcast_to(mask, (b, s, t))
+    tile = row_tile((b, s, h, r), block)
+    q = q_abs
+    if s == 1:
+        q = jnp.pad(q_abs.reshape(b, 1, h, r),
+                    ((0, 0), (0, 0), (0, -h % 16), (0, 0)))
+    tiled, c = q.shape[1:3]
+    hb = min(tiled, _HEAD_TILE)
+    seen = jnp.broadcast_to(mask, (b,) + mask.shape[1:])
     rows = jax.vmap(rows_seen)(seen)                # a lane's own, [b]
     if live is not None:
         rows = jnp.where(live, rows, 0)
-    lane, src, at, items = _cache_work(rows, block, t // block)
-    return pl.pallas_call(
+    lane, src, at, items = _cache_work(rows, tile, t // tile)
+    out = pl.pallas_call(
         functools.partial(_cache_kernel, inv_scale=1.0 / scale,
                           kv_lora=kv_lora),
         name="latent_attention_cache",
-        out_shape=jax.ShapeDtypeStruct((b, h, s, kv_lora), q_abs.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, tiled, c, kv_lora), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(h // hb, items),
+            grid=(tiled // hb, items),
             in_specs=[
-                pl.BlockSpec((None, hb, s, r),
+                pl.BlockSpec((None, hb, c, r),
                              lambda j, i, l, ln, src, at, n: (src[i], j, 0, 0)),
-                pl.BlockSpec((None, None, None, r, block),
+                pl.BlockSpec((None, None, None, r, tile),
                              lambda j, i, l, ln, src, at, n:
                              (l[0], src[i], 0, 0, at[i])),
-                pl.BlockSpec((None, s, block),
+                pl.BlockSpec((None, seen.shape[1], tile),
                              lambda j, i, l, ln, src, at, n:
                              (src[i], 0, at[i])),
             ],
             out_specs=pl.BlockSpec(
-                (None, hb, s, kv_lora),
+                (None, hb, c, kv_lora),
                 lambda j, i, l, ln, src, at, n: (ln[i], j, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((hb, s, 1), jnp.float32),
-                            pltpu.VMEM((hb, s, 1), jnp.float32),
-                            pltpu.VMEM((hb * s, kv_lora), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((hb, c, 1), jnp.float32),
+                            pltpu.VMEM((hb, c, 1), jnp.float32),
+                            pltpu.VMEM((hb * c, kv_lora), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-    )(jnp.asarray(l, jnp.int32).reshape(1), lane, src, at, rows, q_abs,
+    )(jnp.asarray(l, jnp.int32).reshape(1), lane, src, at, rows, q,
       kv_all, seen.astype(jnp.int8))
+    return out[:, :, :h].reshape(b, h, 1, kv_lora) if s == 1 else out
 
 
 @jax.named_scope("attention")

@@ -93,6 +93,24 @@ def key_block(rows: int) -> int:
     return block if 128 <= block < rows else 0
 
 
+#: float32 scores XLA's forms build over every row at once; past it their
+#: rows are read a block at a time in a loop, which over a context of a few
+#: thousand rows costs more than the hidden rows it skips
+_WHOLE_SCORES = 160 << 20
+
+
+def loop_block(queries: int, heads: int, rows: int) -> int:
+    """The block of cached rows XLA'S forms read at a time for ``queries``
+    queries of ``heads`` heads over ``rows`` positions (0: all at once):
+    blocked where their float32 scores would pass `_WHOLE_SCORES`, which no
+    program of a context of a few thousand rows does, and a decode step
+    (one query a slot) does at none.  Asked of a latent layer's reads where
+    no kernel serves them (`latent_attention.attend_latents`) and of an
+    indexer's scores (`index_scores`), each about its own."""
+    return key_block(rows) if queries * heads * rows * 4 > _WHOLE_SCORES \
+        else 0
+
+
 def rows_seen(mask: jnp.ndarray) -> jnp.ndarray:
     """``mask`` [..., T] bool -> int32 scalar: one past the last column any
     query may see."""

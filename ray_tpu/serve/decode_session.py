@@ -454,7 +454,7 @@ class ContinuousBatchingEngine:
         self.prefill_programs = 0
         # ... and the cache rows those programs' attention moved from
         # memory, beside the rows a real query of theirs saw
-        # (`models.generate.chunk_rows_fetched`; zeros where nobody counts)
+        # (`models.generate.chunk_rows_fetched`)
         self.chunk_rows_fetched = self.chunk_rows_read = 0
         self._chunk_fetched = None      # the counter, set by the loop
         self._lanes_span = dict(self._lane_sums(), t=time.time())
@@ -1144,8 +1144,7 @@ class ContinuousBatchingEngine:
         self.phase_s["prefill_tail"] += wall * len(tails) / len(riders)
         self._prof.note_tokens("prefill_chunk", sum(n for _, n in riders))
         # (a rider's `poff` is already past the chunk it rode)
-        moved = [self._chunk_fetched(sess.poff - n, n) for sess, n in riders
-                 ] if self._chunk_fetched else []
+        moved = [self._chunk_fetched(sess.poff - n, n) for sess, n in riders]
         with self._loop_lock:   # stats() reads these counters
             self.prefill_programs += 1
             self.prefill_chunks += len(riders)

@@ -336,10 +336,15 @@ def test_the_hosts_count_of_a_chunks_rows_is_the_kernels_own_list(
         assert read <= fetched <= arrays_rows
 
 
-def test_latent_layers_chunks_are_counted_by_nobody():
+def test_latent_layers_chunks_are_counted_dense_where_no_kernel_reads_them():
+    """A model of latent layers at a shape `ops/latent_attention.py`'s
+    kernel does not take (latents of 16): every row of the lane's layer is
+    moved, and a real query sees the rows up to its own
+    (tests/test_sparse_index.py has the count where the kernel engages)."""
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_layers=1, n_heads=2, d_ff=64,
         max_seq_len=64, pos_emb="rope", attention="mla", q_lora_rank=8,
         kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
         v_head_dim=8, dtype=jnp.float32, attention_impl="reference")
-    assert chunk_rows_fetched(init_kv_cache(cfg, 1, 64), cfg, 32) is None
+    count = chunk_rows_fetched(init_kv_cache(cfg, 1, 64), cfg, 32)
+    assert count(0, 32) == (64, 32) and count(32, 5) == (64, 37)

@@ -720,6 +720,66 @@ def test_state_space_states_are_advanced_in_one_call_where_they_lie(topo):
         assert "ssd_step" not in lowered.as_text()
 
 
+def test_thinking_cells_latent_layers_read_the_blocks_they_see(topo):
+    """The thinking cell's programs whole (Kimi-Linear at the published
+    widths, 27 layers, 32 slots x 5632 rows): the fused step holds SEVEN
+    calls of `ops/latent_attention.py` `attend_cache`, one a latent layer
+    (each is a loop segment of its own), over the stacked latents where they
+    lie, with ONE int8 mask row a slot, and no array of scores over a slot's
+    5632 rows in either type; the lanes program of 4 x 128 holds seven too
+    and neither a lane's latent layer cut out of the cache nor a score
+    block."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import init_params, init_slot_cache
+    from ray_tpu.models.generate import _decode_step_slots
+    c = mf.Manifest().config("kimi-linear-48b-a3b")
+    cfg = mf.family_of(c).model.model_config(c, "serve")
+    assert cfg.kinds.count("full") == 7
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 32, 5632
+    cache = described(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)))
+    assert cache["kv"].shape == (7, slots, 1, 576, max_len)
+
+    def fused_step(params, tok, cache, active):
+        logits, cache, _ = _decode_step_slots(params, tok, cache, active,
+                                              cfg)
+        return jnp.argmax(logits[..., :cfg.vocab_size], axis=-1), cache
+    step = jax.jit(fused_step, donate_argnums=(2,)).lower(
+        params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    ).compile()
+    _, lanes, n_lanes, _ = _lower_lanes(described, params, cfg,
+                                        "prefill_lanes_4x128", max_len)
+    for compiled, rows, mask_rows in ((step, slots, 1),
+                                      (lanes.compile(), n_lanes, 128)):
+        text = compiled.as_text()
+        calls = [x for x in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+                 if "latent_attention_cache" in x]
+        assert len(calls) == 7, len(calls)
+        for call in calls:
+            assert f"bf16[7,{rows},1,576,{max_len}]" in call, call[:300]
+            assert f"s8[{rows},{mask_rows},{max_len}]" in call, call[:300]
+        # no scores over all of a row's positions, no lane's layer cut out
+        assert not re.findall(rf"= (?:f32|bf16)\[[\d,]*,{max_len}\]\S* "
+                              rf"(?:fusion|convolution|dot)\(", text)
+        assert not re.findall(rf"= bf16\[(?:1,)*576,{max_len}\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 def _lower_lanes(described, params, cfg, program, max_len):
     """``prefill_lanes_<P>x<C>``: the chunk program over P lanes of C rows
     (`models.generate.prefill_lanes`, what the engine runs while two or more
@@ -887,12 +947,13 @@ def test_blocked_latent_chunks_attend_in_one_kernel_call(topo, program,
                                                          blocked):
     """A latent model with an indexer (latents of 576, an indexing layer and
     two that share its choice: two layer bodies) at a chunk of 128 rows.
-    Where the chunk's float32 scores would pass 160 MiB (64 heads over 6144
-    rows: `generate._key_block`) the lanes program and the batch-1 chunk
-    program hold `ops/latent_attention.py` `attend_cache`'s call ONCE a
-    layer body, no float32 array as large as a score block (128 x 64 x 1024)
-    and no cut of a lane's layer out of the cache; at glm-4.7-flash's heads
-    and rows (42 MB of scores, read at once) they hold no such call."""
+    Where the heads fill whole head tiles (64 heads over 6144 rows:
+    `generate._key_block`, `mla.kernel_shape`) the lanes program and the
+    batch-1 chunk program hold `ops/latent_attention.py` `attend_cache`'s
+    call ONCE a layer body, no float32 array as large as a score block (128
+    x 64 x 1024) and no cut of a lane's layer out of the cache; at
+    glm-4.7-flash's 20 heads (no whole tile of 8) they hold no such call:
+    42 MB of scores, read at once."""
     import re
 
     import jax

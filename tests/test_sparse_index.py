@@ -21,6 +21,7 @@ import functools
 import importlib
 import json
 import os
+import re
 import threading
 import types
 
@@ -232,6 +233,240 @@ def test_the_kernels_work_list_has_no_block_past_a_lanes_rows():
     assert lane.shape == (20,) and at.max() <= 3 and src.max() <= 4
 
 
+# a slot's visible rows: one, a block's edge, one past it, the cache's last
+# row, the middle of a block; the last slot stands
+_STEP_ROWS = (1, 128, 129, 512, 300, 77)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("chosen", [False, True],
+                         ids=["every row before it", "an indexer's choice"])
+@pytest.mark.parametrize("heads", [32, 64, 20])
+def test_a_steps_heads_are_the_rows_of_one_tile(monkeypatch, heads, chosen,
+                                                dtype, tol):
+    """`attend_cache` for ONE query a slot (through the interpreter): the
+    heads of a slot are the one head tile's query rows (20 are padded to 32
+    and the padding dropped), the mask ONE row a slot, never ``heads``; each
+    live slot equals `attend_latents` over all rows at once, no block past a
+    slot's last visible row is read (they hold NaN here), and a slot that
+    stands gives zeros and touches nothing of its cache (all NaN)."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    layers, kv_lora, rope, t, block = 2, 128, 16, 512, 128
+    slots, r, dt = len(_STEP_ROWS), kv_lora + rope, jnp.dtype(dtype)
+    assert mla.kernel_shape((slots, 1, heads, r), kv_lora, block)
+    assert mla.row_tile((slots, 1, heads, r), block) == block
+    rng = np.random.default_rng(heads)
+    q = jnp.asarray(rng.standard_normal((slots, 1, heads, r)), dt)
+    kv = rng.standard_normal((layers, slots, 1, r, t)).astype(np.float32)
+    mask = np.arange(t)[None, None, :] < np.asarray(_STEP_ROWS)[:, None, None]
+    if chosen:      # a choice keeps a query's own row, and some before it
+        last = mask & ~np.roll(mask, -1, axis=-1)
+        mask = (mask & (rng.random((slots, 1, t)) < 0.4)) | last
+    live = np.arange(slots) < slots - 1
+    poisoned = kv.copy()
+    for p, rows in enumerate(_STEP_ROWS):
+        poisoned[:, p, :, :, -(-rows // block) * block if live[p] else 0:] \
+            = np.nan
+    poisoned[0] = np.nan                        # another layer's rows
+
+    def step(q, kv, m, live):
+        return mla.attend_cache(jnp.swapaxes(q, 1, 2), kv, 1, m, live, 3.0,
+                                kv_lora, block)
+    operands = (q, jnp.asarray(poisoned, dt), jnp.asarray(mask),
+                jnp.asarray(live))
+    text = str(jax.make_jaxpr(step)(*operands))
+    assert "pallas_call" in text and f"i8[{slots},1,{t}]" in text
+    assert not re.findall(rf"i8\[{slots},(?!1,)\d+,{t}\]", text)
+    got = np.asarray(jax.jit(step)(*operands).astype(jnp.float32))
+    assert got.shape == (slots, heads, 1, kv_lora)
+    assert not got[~live].any() and np.isfinite(got).all()
+    want = mla.attend_latents(q, jnp.asarray(kv[1, :, 0], dt),
+                              jnp.asarray(mask), 3.0)
+    np.testing.assert_allclose(
+        got[live, :, 0], np.asarray(want[live, 0, :, :kv_lora], np.float32),
+        atol=tol)
+
+
+@pytest.mark.parametrize("cell,heads,rows,step,chunk", [
+    ("kimi-linear-48b-a3b.serve-think-closed", 32, 5632, 512, 512),
+    ("glm-4.7-flash.serve-agent-closed", 20, 4096, 1024, 0),
+    ("glm-5.2.serve-longdoc-closed", 64, 33792, 1024, 1024)])
+def test_what_chooses_the_kernel_is_the_shape(cell, heads, rows, step, chunk):
+    """`generate._key_block` at the three latent cells' shapes: a decode
+    step reads through the kernel whatever its heads (they are the rows), a
+    chunk of 128 where its heads fill a head tile (20 do not: the agent
+    cell's chunks read through XLA's forms); no size of scores is asked.
+    XLA's forms ask their own question, which the change did not move."""
+    generate = importlib.import_module("ray_tpu.models.generate")
+    assert generate._key_block((32, 1, heads, 192), 512, rows) == step
+    assert generate._key_block((4, 128, heads, 192), 512, rows) == chunk
+    assert mla.row_tile((32, 1, heads, 576), step) == step
+    assert not chunk or mla.row_tile((4, 128, heads, 576), chunk) == 512
+    assert not sparse_index.loop_block(1, heads, rows)
+    assert bool(sparse_index.loop_block(128, heads, rows)) == (
+        128 * heads * rows * 4 > 160 << 20) == (rows == 33792)
+    with open(generate.__file__) as f:      # the threshold left this file
+        assert "160 <<" not in f.read()
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_the_hosts_count_of_latent_rows_is_the_kernels_own_list(
+        monkeypatch, program):
+    """`rows_fetched` and `chunk_rows_fetched` of a latent model from
+    positions: where `attend_cache` engages on this process's backend, the
+    rows of `_cache_work`'s items for the same masks (a lane that stands
+    has one item that moves nothing), summed over the layers; where it does
+    not, every row of every slot's (of the lane's) layer."""
+    generate = importlib.import_module("ray_tpu.models.generate")
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=64, n_layers=3, n_heads=4, d_ff=64,
+        max_seq_len=1536, pos_emb="rope", attention="mla", q_lora_rank=8,
+        kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=16,
+        v_head_dim=16, dtype=jnp.float32, attention_impl="reference")
+    slots, rows, c = 5, 1536, 16 if program == "chunk" else 1
+    cache = init_slot_cache(cfg, slots, rows)
+    count = {"step": lambda: generate.rows_fetched(cache, cfg),
+             "chunk": lambda: generate.chunk_rows_fetched(cache, cfg, c)}[
+                 program]
+    dense = count()
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    engaged = count()
+    tile = mla.row_tile((slots, c, 4, 144), sparse_index.key_block(rows))
+    assert tile == 512 == sparse_index.key_block(rows)
+    rng = np.random.default_rng(9)
+    for _ in range(6):
+        pos = rng.integers(0, rows - c, slots)
+        live = rng.random(slots) < 0.7
+        seen = np.where(live, pos + c, 0)
+        _, _, _, items = mla._cache_work(jnp.asarray(seen, jnp.int32), tile,
+                                         rows // tile)
+        moved = 3 * tile * (int(items) - int((~live).sum()))
+        if program == "step":
+            assert engaged(pos[live].tolist()) == moved
+            assert dense(pos[live].tolist()) == 3 * slots * rows
+            continue
+        n_valid = rng.integers(1, c + 1, slots)
+        got = [engaged(int(p), int(n)) for p, n in zip(pos[live],
+                                                       n_valid[live])]
+        assert sum(f for f, _ in got) == moved
+        assert [r for _, r in got] == [3 * int(p + n) for p, n in zip(
+            pos[live], n_valid[live])]
+        assert [dense(int(p), int(n)) for p, n in zip(
+            pos[live], n_valid[live])] == [(3 * rows, r) for _, r in got]
+
+
+_SHARE = "cache.latent_rows_fetched_share.batch"
+
+
+def _span(name, end_s, **args):
+    return {"name": name, "ts": (end_s - 2) * 1e6, "dur": 2e6,
+            "args": dict(args, deployment="bench")}
+
+
+@pytest.mark.parametrize("counted,want", [
+    ("neither", None),          # a program before PR 48: no key is there
+    ("steps", 100.0 * 2 * 900 / (2 * 2000)),    # this PR's parent
+    ("both", 100.0 * 2 * (900 + 640) / (2 * (1024 + 768)))])
+def test_the_latent_share_reads_steps_and_chunk_programs(counted, want):
+    """`perfbench/metrics/cache.latent_rows_fetched_share.batch.py` on
+    hand-made ring spans: rows seen over rows moved, the window's
+    ``cache:rows`` and ``engine:lanes`` spans summed; its entry in the root
+    manifest is the last, and lists the three cells of latent models."""
+    read = mf.metric_reader(_SHARE)
+    run = lambda events: types.SimpleNamespace(
+        stamps={"open": 10.0, "close": 55.0}, _ring_spans=events)
+    assert read(run([])) is None
+    step = {"rows_fetched": 2000 if counted == "steps" else 1024,
+            "rows_read": 900} if counted != "neither" else {"steps": 3}
+    lanes = {"chunk_rows_fetched": 768, "chunk_rows_read": 640} \
+        if counted == "both" else {"programs": 2}
+    events = [_span("cache:rows", 9.5, rows_fetched=7, rows_read=1),
+              _span("engine:lanes", 56.0, chunk_rows_fetched=5,
+                    chunk_rows_read=5),
+              {"name": "cache:rows", "ts": 20e6, "dur": 2e6},   # no args
+              _span("moe:load", 20.0, rows_fetched=10 ** 12)]
+    events += [_span("cache:rows", 12.0 + 2 * i, **step) for i in range(2)]
+    events += [_span("engine:lanes", 13.0 + 2 * i, **lanes) for i in range(2)]
+    got = read(run(events))
+    assert got is None if want is None else got == pytest.approx(want)
+    root = mf.Manifest()
+    assert root.data["per_layer"][-1] == {
+        "name": _SHARE, "unit": "%", "better": "higher",
+        "source": "program_span", "layer": "kernels",
+        "moves": "serve_tok_s", "workloads": [
+            "kimi-linear-48b-a3b.serve-think-closed",
+            "glm-4.7-flash.serve-agent-closed",
+            "glm-5.2.serve-longdoc-closed"]}
+    e2e = next(e for e in root.data["end_to_end"]
+               if e["name"] == "serve_tok_s")
+    assert set(root.data["per_layer"][-1]["workloads"]) <= set(
+        e2e["workloads"])
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_the_engine_counts_the_rows_its_latent_layers_moved(monkeypatch,
+                                                            path):
+    """One session of a tiny latent model (2 layers of 384 rows of latents
+    of 128) prefills 200 tokens through chunks of 128 and decodes from 200
+    past a block's edge in an engine of 2 slots: `engine.stats()` and the
+    ring spans carry what the steps and the chunk programs moved and what
+    their queries saw, and the reader gives the ratio of the sums."""
+    from ray_tpu.serve.config import DecodeEngineConfig
+    from ray_tpu.serve.decode_session import DecodeSessionCore
+    from ray_tpu.util import tracing
+    if path == "kernel":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(ContinuousBatchingEngine, "_MOE_SPAN_S", 0.0)
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=2, d_ff=64,
+        max_seq_len=384, pos_emb="rope", rope_base=1e4, activation="swiglu",
+        norm="rmsnorm", tie_embeddings=False, remat=False, attention="mla",
+        q_lora_rank=8, kv_lora_rank=128, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, dtype=jnp.float32,
+        param_dtype=jnp.float32, attention_impl="reference")
+    slots, max_len, n, out = 2, 384, 200, 60
+    core = DecodeSessionCore(cfg, max_len=max_len, seed=3,
+                             engine=DecodeEngineConfig(
+                                 max_slots=slots, prefill_chunk_tokens=128))
+    names = ("cache:rows", "engine:lanes")
+    try:
+        before = len([e for e in tracing.span_events()
+                      if e["name"] in names])
+        r = core.handle({"op": "start",
+                         "prompt": [3 + i % 50 for i in range(n)]})
+        assert "error" not in r, r
+        got = len(r["token"])
+        while got < out:
+            more = core.handle({"op": "next_chunk", "sid": r["sid"],
+                                "max_tokens": out - got})
+            assert "error" not in more, more
+            got += len(more["tokens"])
+        core.handle({"op": "end", "sid": r["sid"]})
+    finally:
+        core.engine.shutdown()
+    stats = core.engine.stats()
+    steps, layers = stats["cache"]["steps"], cfg.n_layers
+    assert steps >= out - 1 and stats["prefill_programs"] == 2
+    # the steps stood at 200, 201, ...: a row a position up to their own
+    at = range(n, n + steps)
+    assert stats["cache"]["rows_read"] == layers * sum(p + 1 for p in at)
+    assert stats["chunk_rows_read"] == layers * (128 + 200)
+    if path == "kernel":    # blocks of 128: 2 up to 255, then 3; 1 and 2
+        assert stats["cache"]["rows_fetched"] == layers * 128 * sum(
+            2 if p < 256 else 3 for p in at)
+        assert stats["chunk_rows_fetched"] == layers * (128 + 256)
+    else:                   # every row of both slots; of the session's layer
+        assert stats["cache"]["rows_fetched"] == layers * steps * slots * 384
+        assert stats["chunk_rows_fetched"] == layers * 2 * 384
+    spans = [e for e in tracing.span_events() if e["name"] in names][before:]
+    share = mf.metric_reader(_SHARE)(types.SimpleNamespace(
+        stamps={"open": 0.0, "close": 1e12}, _ring_spans=spans))
+    assert share == pytest.approx(
+        100.0 * (stats["cache"]["rows_read"] + stats["chunk_rows_read"])
+        / (stats["cache"]["rows_fetched"] + stats["chunk_rows_fetched"]))
+    assert (share > 75) == (path == "kernel")
+
+
 # ------------------------------------------------- the model and its cache
 
 def test_pattern_weights_and_counts(world):
@@ -349,28 +584,54 @@ def test_lanes_with_a_lane_that_stands(world):
         and not np.asarray(cache["k_idx"][:, 1]).any()
 
 
-@pytest.mark.parametrize("program", ["lanes", "chunk"])
+@pytest.mark.parametrize("program", ["lanes", "chunk", "step"])
 def test_programs_with_the_kernel_are_the_programs_with_the_loop(
         world, monkeypatch, program):
-    """The lanes program (a lane that stands) and the batch-1 chunk program
-    at a shape the kernel takes (latents of 128, blocks of 128 rows, read
-    blocked whatever the scores' size): the kernel through the interpreter
-    against XLA's loop, logits and every array of the cache."""
+    """The lanes program (a lane that stands), the batch-1 chunk program and
+    the DECODE STEP (one query a slot under the indexer's choice, a slot that
+    stands) at a shape the kernel takes (latents of 128, blocks of 128
+    rows): the kernel through the interpreter against XLA's forms, the
+    loop over blocks for the chunks (read blocked whatever the scores' size)
+    and all rows at once for the step: logits and every array of the cache."""
     generate = importlib.import_module("ray_tpu.models.generate")
     w = world
     cfg = dataclasses.replace(w.cfg, kv_lora_rank=128)
     params = jax.jit(lambda k: init_params(k, cfg)[0])(jax.random.PRNGKey(5))
-    monkeypatch.setattr(
-        generate, "_key_block",
-        lambda c, heads, rows: sparse_index.key_block(rows))
+    if program != "step":
+        monkeypatch.setattr(
+            sparse_index, "loop_block",
+            lambda queries, heads, rows: sparse_index.key_block(rows))
     calls, kernel = [], mla.attend_cache
     monkeypatch.setattr(mla, "attend_cache", lambda *a: calls.append(
         a[0].shape) or kernel(*a))
     chunk, max_len = 16, 384
 
+    def steps():
+        """Four steps of the program under test over three slots filled by
+        chunked prefills, slot 1 standing."""
+        fn = jax.jit(generate.decode_step_slots, static_argnames=("cfg",))
+        slots, logits = start, {}
+        for j in range(4):
+            lg, slots = fn(
+                params, jnp.stack([w.toks[0, 70 + j], jnp.int32(5),
+                                   w.toks[1, 30 + j]]), slots,
+                jnp.asarray([True, False, True]), cfg=cfg)
+            logits[j] = lg[jnp.asarray([0, 2])]
+        return logits, slots
+
+    if program == "step":
+        start = init_slot_cache(cfg, 3, max_len)
+        for row, (src, n) in enumerate(((0, 70), (1, 21), (1, 30))):
+            _, one = generate.prefill_chunked(
+                params, w.toks[src:src + 1, :n], cfg,
+                init_kv_cache(cfg, 1, max_len), chunk=chunk)
+            start = cache_insert_slot(start, one, jnp.int32(row))
+
     def walk(interpret):
         monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", interpret)
         logits = {}
+        if program == "step":
+            return steps()
         if program == "chunk":
             fn = jax.jit(generate.prefill_chunk, static_argnames=("cfg",))
             logits[0], cache = generate.prefill_chunked(
