@@ -41,7 +41,15 @@ width, ring]`` of ``sliding_window + window_chunk`` rows whatever
 ``max_len`` (`window_ring`): position ``p`` lives in column ``p mod ring``,
 a chunk that straddles the seam is written in two pieces, and a column is
 masked by the POSITION IT HOLDS (`_ring_mask`), which follows from the
-last position written.  The ring is wider than the window by the widest
+last position written.  A LATENT-attention model's window layers hold a ring
+too, of LATENTS (``kv_win`` ``[L_win, batch, 1, window_kv_lora_rank + rope,
+ring]`` beside the full layers' ``kv`` and, of a model with an indexer, their
+``k_idx``: three arrays, three row widths, two layer counters): a row of its
+kind's own width (`TransformerConfig.latent_of`), a ring of whole 128-row
+blocks whatever the window (513 + 128 rows are 768: the kernels that read
+and write it take whole blocks, and the mask, not the ring's width, says
+what is seen), read by the same absorbed forms and the same kernel as the
+full layers' rows (`attend_cache` under `_ring_mask`).  The ring is wider than the window by the widest
 chunk a program may feed, so whatever a program writes ahead of a row's
 ``pos`` (a padded chunk's tail, an inactive slot's token) overwrites only
 positions that no later query's window reaches.
@@ -201,9 +209,10 @@ from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
 from .transformer import (ATTENTION_KINDS, SPARSE_KINDS, SSM_KINDS,
                           TransformerConfig, _attn_out, _ffn, _layer, _norm,
-                          _post, _qkv, _scale_embedding, _scaled,
-                          _ssm_widths, _unembed, check_kinds, index_inputs,
-                          kda_operator, latent_queries, norm_eps,
+                          _post, _qkv, _scale_embedding,
+                          _ssm_widths, _unembed, check_kinds, head_gate,
+                          index_inputs, kda_operator, latent_queries,
+                          latent_rows, latent_scope, latent_weights, norm_eps,
                           rope_tables, scan_layer_runs, ssm_operator)
 
 Params = Any
@@ -227,6 +236,8 @@ _SSM_ARRAYS = (_SSM_STATE, _SSM_CONV)
 #: the kinds of state that hold no positions: a sequence's whatever its length
 _NO_POSITIONS = ("state", "delta", "ssm")
 _SUM_NAMES = ("k" + _SUMMARY, "v" + _SUMMARY)
+#: rows a ring of latents is a whole number of (`window_ring`)
+LATENT_RING_BLOCK = 128
 _INDEX_ARRAY = "k" + _INDEX
 #: the kinds of layer whose arrays hold a row a position for the whole
 #: context, written and masked alike
@@ -252,7 +263,12 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
         state.update({_SSM_STATE: (cfg.ssm_heads, cfg.ssm_state),
                       _SSM_CONV: (1, cfg.ssm_conv_kernel - 1)})
     if cfg.attention == "mla":
-        rows = dict(state, kv=(1, cfg.kv_lora_rank + cfg.qk_rope_head_dim))
+        rows = dict(state)
+        for kind in ("full", "window"):     # a kind's own latent beside
+            ck = cfg.latent_of(kind)        # the shared rotary key
+            if kind != "window" or "window" in cfg.kinds:
+                rows[_latent_name(kind)] = (
+                    1, ck.kv_lora_rank + ck.qk_rope_head_dim)
         if "index" in cfg.kinds:    # ONE key a position, no value
             rows[_INDEX_ARRAY] = (1, cfg.index_head_dim)
         return rows
@@ -302,6 +318,12 @@ def _own_rows(cfg: TransformerConfig, name: str) -> Optional[int]:
             _SSM_CONV: _ssm_widths(cfg)[1]}.get(name)
 
 
+def _latent_name(kind: str) -> str:
+    """The latents' array of a latent layer of kind ``kind``: a window
+    layer's is a ring of its own row width."""
+    return "kv" + _RING if kind == "window" else "kv"
+
+
 def _kv_names(kind: str) -> Tuple[str, str]:
     """The key and value arrays of a layer of attention kind ``kind``."""
     return ("k", "v") if kind in _ROW_KINDS else ("k" + _RING, "v" + _RING)
@@ -309,8 +331,14 @@ def _kv_names(kind: str) -> Tuple[str, str]:
 
 def window_ring(cfg: TransformerConfig, max_len: int) -> int:
     """Rows of a window layer's ring: the window and the widest chunk a
-    program may write ahead of it, and no more than the context."""
-    return min(max_len, cfg.sliding_window + cfg.window_chunk)
+    program may write ahead of it, and no more than the context.  A ring of
+    LATENTS is whole blocks of `LATENT_RING_BLOCK` rows (the kernels that
+    read and write it take whole blocks, and a window need be no multiple
+    of anything): the mask, not the ring's width, says what is seen."""
+    rows = cfg.sliding_window + cfg.window_chunk
+    if cfg.attention == "mla":
+        rows = -(-rows // LATENT_RING_BLOCK) * LATENT_RING_BLOCK
+    return min(max_len, rows)
 
 
 def cache_arrays(cache: KVCache) -> Arrays:
@@ -366,12 +394,12 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
     process's backend a live slot's blocks alone, the host's count of the
     kernel's work list: `ops/cache_attention.py`'s for a summary layer's step
     (`cache_attention.fetched_blocks`), `ops/latent_attention.py`
-    `attend_cache`'s for a latent layer's (`_latent_tile`: the tiles up to
+    `attend_cache`'s for a latent layer's (`_latent_tiles`: the tiles up to
     the slot's position; under an indexer's choice the kernel stops at the
     last CHOSEN row, which the host does not know: the count is then the
     most it moves).  Host counts from shapes."""
     arrays = cache_arrays(cache)
-    names = ("kv",) if cfg.attention == "mla" else tuple(
+    names = _latent_names(arrays) if cfg.attention == "mla" else tuple(
         _kv_names(kind)[0] for kind in ATTENTION_KINDS if kind in cfg.kinds) \
         + (_SUM_NAMES[:1] if "eva" in cfg.kinds else ())
     rows = {name: arrays[name].shape[-1] for name in names}
@@ -382,15 +410,17 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
         kn = _kv_names("eva")[0]
         if cache_attention.engages(*_chunk_sets(cfg, arrays, "eva", 1, 1)):
             blocked = (rows.pop(kn), rows.pop(_SUM_NAMES[0]))
-    tile = _latent_tile(cfg, arrays, 1) if cfg.attention == "mla" else 0
-    if tile:
-        del rows["kv"]
+    # a latent array the kernel reads: (its layers, the kernel's tile, the
+    # kind whose rows it holds)
+    tiled = [(arrays[name].shape[0], tile, kind, rows.pop(name))
+             for kind, name, tile in _latent_tiles(cfg, arrays, 1) if tile]
     dense = sum(arrays[name].shape[0] * arrays[name].shape[1] * n
                 for name, n in rows.items())
-    if tile:
-        latent = arrays["kv"].shape[0]
-        return lambda positions: dense + latent * sum(
-            mla.fetched_rows(pos + 1, tile) for pos in positions)
+    if tiled:
+        return lambda positions: dense + sum(
+            layers * mla.fetched_rows(
+                _latent_seen(cfg, kind, pos, 1, size), tile)
+            for layers, tile, kind, size in tiled for pos in positions)
     if not blocked:
         return lambda positions: dense
     layers, (ring, sums) = arrays[kn].shape[0], blocked
@@ -408,18 +438,47 @@ def rows_fetched(cache: KVCache, cfg: TransformerConfig):
     return count
 
 
-def _latent_tile(cfg: TransformerConfig, arrays: Arrays, c: int) -> int:
-    """The cached rows a grid step of `ops/latent_attention.py`
-    `attend_cache` takes where a program that feeds ``c`` tokens a row of
-    this cache reads its latent layers through it ON THIS PROCESS'S BACKEND
-    (`mla.engages`, what `_key_block` and `mla.on_the_chip` decide where the
-    program is lowered), 0 where the layers read through XLA's forms: every
-    row of the array then."""
-    kv = arrays["kv"]
-    q_shape = (kv.shape[1], c, cfg.n_heads, kv.shape[-2])
-    block = _key_block(q_shape, cfg.kv_lora_rank, kv.shape[-1])
-    return mla.row_tile(q_shape, block) \
-        if mla.engages(q_shape, cfg.kv_lora_rank, block) else 0
+def _latent_names(arrays: Arrays) -> Tuple[str, ...]:
+    """A latent cache's arrays of latents: the full layers' and, where the
+    model has window layers, their ring."""
+    return tuple(n for n in map(_latent_name, ("full", "window"))
+                 if n in arrays)
+
+
+def _latent_tiles(cfg: TransformerConfig, arrays: Arrays, c: int):
+    """(kind, its latents' array, the cached rows a grid step of
+    `ops/latent_attention.py` `attend_cache` takes of it) for each array of
+    a latent cache (none for any other), where a program that feeds ``c``
+    tokens a row reads the kind's layers through the kernel ON THIS
+    PROCESS'S BACKEND (`mla.engages`, what `_key_block` and
+    `mla.on_the_chip` decide where the program is lowered); the tile is 0
+    where the layers read through XLA's forms: every row of the array
+    then."""
+    out = []
+    for kind in ("full", "window") if cfg.attention == "mla" else ():
+        name, ck = _latent_name(kind), cfg.latent_of(kind)
+        if name in arrays:
+            kv = arrays[name]
+            q_shape = (kv.shape[1], c, ck.n_heads, kv.shape[-2])
+            block = _key_block(q_shape, ck.kv_lora_rank, kv.shape[-1])
+            out.append((kind, name, mla.row_tile(q_shape, block)
+                        if mla.engages(q_shape, ck.kv_lora_rank, block)
+                        else 0))
+    return out
+
+
+def _latent_seen(cfg: TransformerConfig, kind: str, pos: int, n: int,
+                 rows: int) -> int:
+    """One past the last column of a latent array of ``rows`` rows that
+    some query of the ``n`` tokens fed from ``pos`` may see (what
+    `sparse_index.rows_seen` reads off their mask): the positions up to the
+    last one fed, or, of a window layer's ring, the column of the last one
+    fed unless the window wraps the seam."""
+    top = pos + n - 1
+    if kind != "window":
+        return top + 1
+    first = max(0, pos - cfg.sliding_window + 1)
+    return top % rows + 1 if first // rows == top // rows else rows
 
 
 def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
@@ -435,17 +494,22 @@ def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
     in which some query of the WHOLE chunk, padded rows too, has a visible
     row: the host's count of the kernel's work list from positions.  A model
     of latent layers likewise through `ops/latent_attention.py`
-    `attend_cache` (`_latent_tile`): the tiles up to the padded chunk's last
+    `attend_cache` (`_latent_tiles`): the tiles up to the padded chunk's last
     row where it engages, every row of the lane's layer where it does not;
     under an indexer's choice both sums are of the rows the queries MAY see,
     the most a choice reaches."""
     arrays = cache_arrays(cache)
     if cfg.attention == "mla":
-        layers, rows = arrays["kv"].shape[0], arrays["kv"].shape[-1]
-        tile = _latent_tile(cfg, arrays, chunk)
+        latent = [(kind, arrays[name].shape[0], arrays[name].shape[-1], tile)
+                  for kind, name, tile in _latent_tiles(cfg, arrays, chunk)]
         return lambda pos, n_valid: (
-            layers * (mla.fetched_rows(pos + chunk, tile) if tile else rows),
-            layers * (pos + n_valid))
+            sum(layers * (mla.fetched_rows(
+                _latent_seen(cfg, kind, pos, chunk, rows), tile)
+                if tile else rows) for kind, layers, rows, tile in latent),
+            sum(layers * (pos + n_valid - (
+                max(0, pos - cfg.sliding_window + 1)
+                if kind == "window" else 0))
+                for kind, layers, _, _ in latent))
     window, pooled = cfg.sliding_window, cfg.summary_chunk
     kinds = []      # (kind, its layers, rows of each set, kernel engages)
     for kind in ATTENTION_KINDS:
@@ -628,9 +692,6 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         raise ValueError(f"rotary over {cfg.rope_dim} dims of a head: "
                          f"pairs need an even number")
     if "window" in kinds:
-        if cfg.attention == "mla":
-            raise NotImplementedError(
-                "window layers over a latent cache are not supported")
         if cfg.sliding_window < 1 or cfg.window_chunk < 1:
             raise ValueError("window layers need sliding_window and "
                              "window_chunk of at least 1")
@@ -1113,17 +1174,20 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         return _attn_out(cfg, y, attn.reshape(b, c, h, -1), lp), arrs
 
     def attend_mla(y, lp, arrs, l, kind, sel):
-        # absorbed: the chunk's few queries over the cached latents
+        # absorbed: the chunk's few queries over the cached latents, a
+        # window layer's at its own sizes over its ring (`latent_of`)
         turn = rotate.get(kind, mla.no_turn)
-        q_nope, q_rope, c_q = latent_queries(cfg, y, lp, turn)
-        new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
-                          kv_lora=cfg.kv_lora_rank, eps=eps, rotate=turn)
-        kv_all = write[kind](arrs["kv"], l, _as_columns(
-            new[:, :, None, :], arrs["kv"].dtype))
-        arrs = dict(arrs, kv=kv_all)
+        ck, lw, name = (cfg.latent_of(kind), latent_weights(cfg, lp, kind),
+                        _latent_name(kind))
+        heads, kv_lora = ck.n_heads, ck.kv_lora_rank
+        q_nope, q_rope, c_q = latent_queries(ck, y, lw, turn, kind)
+        new = latent_rows(ck, y, lw, turn, kind)
+        kv_all = write[kind](arrs[name], l, _as_columns(
+            new[:, :, None, :], arrs[name].dtype))
+        arrs = dict(arrs, **{name: kv_all})
         seen = mask[kind]
         rows = kv_all.shape[-1]
-        block = _key_block(q_nope.shape, cfg.kv_lora_rank, rows)
+        block = _key_block(q_nope.shape, kv_lora, rows)
         if kind == "index":
             # the new positions' index keys, every visible row scored, the
             # exact best chosen: this layer's mask and the shared layers'
@@ -1145,32 +1209,33 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             sel = (chosen, li + 1)
         if kind in SPARSE_KINDS:
             seen = sel[0]
-        nope = cfg.qk_nope_head_dim
-        scale = float(np.sqrt(nope + cfg.qk_rope_head_dim))
+        nope = ck.qk_nope_head_dim
+        scale = float(np.sqrt(nope + ck.qk_rope_head_dim))
+        gate = head_gate(cfg, y, lw)    # a value a head, or None
 
         def loops(q_nope, q_rope, kv_all, seen, wkv_b, wo, *live):
             # XLA's forms: all rows at once, or `_attend_blocks`' loop
-            whole = sparse_index.loop_block(c, h, rows)
+            whole = sparse_index.loop_block(c, heads, rows)
             if lanes is None:
                 return mla.attend_absorbed(
                     q_nope, q_rope, _layer_of(kv_all, l)[:, 0], wkv_b, wo,
-                    seen, whole)
+                    seen, whole, gate)
             q_abs = mla.absorb(q_nope, q_rope, wkv_b)
             return mla.unabsorb(_by_lane(
                 live[0], functools.partial(mla.attend_latents, scale=scale,
                                            key_block=whole),
                 lambda p: (q_abs[p:p + 1], _lane_of(kv_all, l, p)[:, 0],
                            seen[p:p + 1])),
-                wkv_b, wo, nope)
+                wkv_b, wo, nope, gate=gate)
 
         def in_place(q_nope, q_rope, kv_all, seen, wkv_b, wo, *live):
             # the visible blocks by one kernel call over the state array
             return mla.unabsorb(mla.attend_cache(
                 mla.absorb(q_nope, q_rope, wkv_b, heads_major=True), kv_all,
-                l, seen, live[0] if live else None, scale, cfg.kv_lora_rank,
-                block), wkv_b, wo, nope, heads_major=True)
+                l, seen, live[0] if live else None, scale, kv_lora,
+                block), wkv_b, wo, nope, heads_major=True, gate=gate)
 
-        operands = (q_nope, q_rope, kv_all, seen, lp["wkv_b"], lp["wo"]) \
+        operands = (q_nope, q_rope, kv_all, seen, lw["wkv_b"], lw["wo"]) \
             + (() if lanes is None else (lanes,))
         if block:
             # (a step's row whose token is not real stands: its result is
@@ -1223,7 +1288,8 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     def layer(xc, lp, arrs, l, kind, sel):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
         if kind not in operator and cfg.attention == "mla":
-            delta, arrs, sel = attend_mla(y, lp, arrs, l, kind, sel)
+            with latent_scope(cfg, kind):
+                delta, arrs, sel = attend_mla(y, lp, arrs, l, kind, sel)
         else:
             delta, arrs = operator.get(kind, attend_mha)(y, lp, arrs, l,
                                                          kind)
@@ -1265,10 +1331,10 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         """What the cache holds of these tokens, from the same pre-norm
         projection the layer itself computes."""
         if cfg.attention == "mla":
-            new = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
-                              kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
-                              rotate=rotate.get(kind, mla.no_turn))
-            return {"kv": new[:, :, None, :]}
+            new = latent_rows(cfg.latent_of(kind),
+                              y, latent_weights(cfg, lp, kind),
+                              rotate.get(kind, mla.no_turn), kind)
+            return {_latent_name(kind): new[:, :, None, :]}
         _, k, v = _qkv(cfg, y, lp, rotate.get(kind), kind)
         return dict(zip(_kv_names(kind), (k, v)))
 
@@ -1357,10 +1423,10 @@ def _last_logits(params: Params, x: jnp.ndarray, cfg: TransformerConfig
     a model with several prediction heads: every head's, head 0's first;
     `next_token_logits`)."""
     if cfg.fp32_logits:     # accumulated and handed out float32
-        return _scaled(jnp.einsum("...d,dv->...v", x, _unembed(params, cfg),
+        return mla.times(jnp.einsum("...d,dv->...v", x, _unembed(params, cfg),
                                   preferred_element_type=jnp.float32),
                        cfg.logit_scale)
-    return _scaled(jnp.einsum("...d,dv->...v", x, _unembed(
+    return mla.times(jnp.einsum("...d,dv->...v", x, _unembed(
         params, cfg)).astype(jnp.float32), cfg.logit_scale)
 
 

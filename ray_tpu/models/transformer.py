@@ -70,7 +70,28 @@ Design (idiomatic JAX, not a torch translation):
     behind it, across the boundary of two runs too.  Indexer weights are
     stacked over the indexing layers only, and a served cache holds the
     indexer's keys on those layers alone: the fifth of its seven kinds of
-    state (`models/generate.py`).
+    state (`models/generate.py`).  EVERY full layer may index for itself
+    (no ``"shared"`` layer at all), and ``"window"`` layers may stand among
+    them: the selection passes a window layer by untouched.
+
+  * a latent layer's SIZES may go BY THE LAYER'S KIND, as an MHA/GQA head's
+    do: a ``"window"`` layer of a latent-attention model has its own head
+    count, query and key-value ranks and unturned head width
+    (``window_heads``, ``window_q_lora_rank``, ``window_kv_lora_rank``,
+    ``window_qk_nope_head_dim``; `TransformerConfig.latent_of`) and rotary
+    base (``window_rope_base``), its weights in stacks of their own over the
+    window layers alone (``*_win``, `latent_weights`: a run holds TWO LATENT
+    SHAPES), its position ``t`` sees ``j`` with ``0 <= t - j <
+    sliding_window``, and a served cache holds its latents in a RING of
+    their own row width beside the full layers' rows (`models/generate.py`:
+    the second state kind, over a latent).  All of its operator stands
+    under the scope ``window_latent`` (`latent_scope`), AROUND the model's
+    parts as ``ssm`` stands around a mixer's.  Two more properties of a
+    latent block, each costing nothing at its default: a gate a HEAD
+    (``head_gate``: one sigmoid of the block's input a head on the heads'
+    output; ``attn_gate`` is MHA/GQA's, a value a channel) and a fixed
+    multiplier on both latents after their norms (``latent_rescale``:
+    ``sqrt(d_model / rank)``, each kind at its own ranks).
 
   * a ``"kda"`` layer's operator is no attention either but a GATED DELTA
     RULE (`ops/delta_rule.py`; `kda_operator`): queries, keys and values
@@ -122,6 +143,7 @@ and ``TransformerConfig.llama()`` (RoPE, SwiGLU, RMSNorm, GQA).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -197,6 +219,19 @@ class TransformerConfig:
     v_head_dim: int = 0
     norm_eps: Optional[float] = None  # None → the norm's own default
     #   (rmsnorm 1e-6, layernorm 1e-5)
+    # -- what a latent layer is, by the layer's kind (`latent_of`) ------------
+    window_heads: Optional[int] = None      # a "window" latent layer's heads,
+    window_q_lora_rank: Optional[int] = None    # ... its query latent,
+    window_kv_lora_rank: Optional[int] = None   # ... its key-value latent
+    window_qk_nope_head_dim: Optional[int] = None   # ... and the part of a
+    #   head's query that is not turned (None → the model's, each): a model
+    #   that states one holds the window layers' weights in stacks of their
+    #   own (``*_win``) and their latents in a ring of their own row width
+    head_gate: bool = False           # a latent layer's heads' output times
+    #   sigmoid(y W_g), ONE value a head (``attn_gate`` is MHA/GQA's, a
+    #   value a channel)
+    latent_rescale: bool = False      # both latents times sqrt(d_model /
+    #   rank) after their norm, each kind's at its own rank
     # -- what an MHA/GQA block may add to the plain one ---------------------
     head_size: Optional[int] = None   # a head's width (None → d_model //
     #   n_heads); queries are n_heads * head_size wide, not d_model
@@ -335,6 +370,37 @@ class TransformerConfig:
         """An MHA/GQA value head's width (the key's unless stated)."""
         return self.v_head_dim or self.head_dim
 
+    @property
+    def window_latent(self) -> bool:
+        """Whether the model has window layers over a LATENT cache: their
+        weights are stacks of their own and their latents a ring."""
+        return self.attention == "mla" and "window" in self.kinds
+
+    def latent_of(self, kind: str) -> "TransformerConfig":
+        """The configuration whose latent sizes (``n_heads``,
+        ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``) are those
+        of a latent layer of this kind: the model's own but for a window
+        layer that states its own."""
+        if kind != "window" or self.attention != "mla":
+            return self
+        own = {name: getattr(self, "window_" + field)
+               for name, field in (("n_heads", "heads"),
+                                   ("q_lora_rank", "q_lora_rank"),
+                                   ("kv_lora_rank", "kv_lora_rank"),
+                                   ("qk_nope_head_dim", "qk_nope_head_dim"))}
+        return dataclasses.replace(
+            self, **{k: v for k, v in own.items() if v is not None})
+
+    def latent_scales(self, kind: str) -> Tuple[float, float]:
+        """The fixed multipliers on a latent layer's (query latent,
+        key-value latent) after their norms: 1 unless the model rescales."""
+        if not self.latent_rescale:
+            return 1.0, 1.0
+        ck = self.latent_of(kind)
+        return (math.sqrt(self.d_model / ck.q_lora_rank)
+                if ck.q_lora_rank else 1.0,
+                math.sqrt(self.d_model / ck.kv_lora_rank))
+
     def rope_base_of(self, kind: str) -> float:
         return self.window_rope_base if kind == "window" \
             and self.window_rope_base else self.rope_base
@@ -426,13 +492,15 @@ class TransformerConfig:
 def _attn_matmul_params(cfg: TransformerConfig, kind: str = "full") -> int:
     d, h = cfg.d_model, cfg.n_heads
     if cfg.attention == "mla":
-        nope, rope, v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                         cfg.v_head_dim)
+        cfg = cfg.latent_of(kind)   # the kind's own latent sizes
+        h, nope, rope, v = (cfg.n_heads, cfg.qk_nope_head_dim,
+                            cfg.qk_rope_head_dim, cfg.v_head_dim)
         ql = cfg.q_lora_rank        # no query latent: one projection
         return ((d * ql + ql * h * (nope + rope) if ql
                  else d * h * (nope + rope))
                 + d * (cfg.kv_lora_rank + rope)
-                + cfg.kv_lora_rank * h * (nope + v) + h * v * d)
+                + cfg.kv_lora_rank * h * (nope + v) + h * v * d
+                + (d * h if cfg.head_gate else 0))
     hd, vd, hk = cfg.head_dim, cfg.value_dim, cfg.kv_heads_of(kind)
     gate = d * h * vd if cfg.attn_gate else 0
     return d * h * hd + d * hk * (hd + vd) + h * vd * d + gate
@@ -489,21 +557,34 @@ def _indexer_matmul_params(cfg: TransformerConfig) -> int:
         + cfg.d_model * (cfg.index_head_dim + cfg.index_heads)
 
 
-def _attn_flops_dim(cfg: TransformerConfig) -> int:
+def _attn_flops_dim(cfg: TransformerConfig, kind: str = "full") -> int:
     """Width, summed over heads, of one query-key product plus one
     probability-value product, halved: what `flops_per_token` multiplies
-    by positions (``n_heads * head_dim`` where both are one size)."""
+    by positions (``n_heads * head_dim`` where both are one size); a latent
+    layer's at its kind's own sizes."""
     if cfg.attention == "mla":
+        cfg = cfg.latent_of(kind)
         return cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
                               + cfg.v_head_dim) // 2
     return cfg.n_heads * (cfg.head_dim + cfg.value_dim) // 2
 
 
+def _attention_work(cfg: TransformerConfig, width_of, context_len: float,
+                    windows: int = 1) -> float:
+    """``width_of(kind)`` x the positions a query attends (`_attended`),
+    summed over the layers, a kind at a time: a window latent layer's heads
+    are its own."""
+    return sum(width_of(kind) * _attended(cfg, context_len, windows, (kind,))
+               for kind in sorted(set(cfg.kinds)))
+
+
 def _attended(cfg: TransformerConfig, context_len: float,
-              windows: int = 1) -> float:
+              windows: int = 1, kinds: Optional[Tuple[str, ...]] = None
+              ) -> float:
     """Positions one query at depth ``context_len`` attends, summed over
-    the layers: a window layer stops at ``windows`` x its window (2 where
-    the caller halves the sum for a causal sequence's mean)."""
+    the layers (of the kinds ``kinds``; None: all): a window layer stops at
+    ``windows`` x its window (2 where the caller halves the sum for a causal
+    sequence's mean)."""
     def rows(kind):
         if kind == "window":
             return min(context_len, windows * cfg.sliding_window)
@@ -516,7 +597,8 @@ def _attended(cfg: TransformerConfig, context_len: float,
 
     # (a conv and a KDA layer attend nothing; a layer with a state-space
     # mixer BESIDE attention attends as a full one)
-    return sum(rows(kind) for kind in cfg.kinds if kind not in _STATE_LAYERS)
+    return sum(rows(kind) for kind in cfg.kinds if kind not in _STATE_LAYERS
+               and (kinds is None or kind in kinds))
 
 
 def count_params(cfg: TransformerConfig) -> int:
@@ -528,10 +610,15 @@ def count_params(cfg: TransformerConfig) -> int:
     e = cfg.kda_heads * cfg.kda_head_dim    # a KDA layer's own: three
     #   convolutions, a decay a head, its bias, the heads' norm
     kda = 3 * e * cfg.kda_conv_kernel + cfg.kda_heads + e + cfg.kda_head_dim
-    own = cfg.q_lora_rank + cfg.kv_lora_rank if cfg.attention == "mla" \
-        else 2 * cfg.head_dim if cfg.qk_norm else 0   # an attention layer's
+    def own(kind):      # an attention layer's: the latents' norms, or a
+        #   head's query and key norms
+        ck = cfg.latent_of(kind)
+        return ck.q_lora_rank + ck.kv_lora_rank if cfg.attention == "mla" \
+            else 2 * cfg.head_dim if cfg.qk_norm else 0
+
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
-        + (cfg.n_layers - n_conv - n_kda) * own + n_kda * kda \
+        + sum(own(k) for k in cfg.kinds if k not in _STATE_LAYERS) \
+        + n_kda * kda \
         + sum(k in SSM_KINDS for k in cfg.kinds) * _ssm_own_params(cfg) \
         + n_conv * d * cfg.conv_kernel \
         + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads \
@@ -554,8 +641,9 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     # qk+pv over the visible window: half the positions when causal,
     # all of them for bidirectional encoders (causal=False)
     attn_factor = 6 if cfg.causal else 12
-    attn = attn_factor * _attn_flops_dim(cfg) * _attended(
-        cfg, seq_len, 2 if cfg.causal else 1)
+    attn = _attention_work(
+        cfg, lambda kind: attn_factor * _attn_flops_dim(cfg, kind), seq_len,
+        2 if cfg.causal else 1)
     # an indexing layer's heads meet every position's ONE key (no values)
     attn += attn_factor // 2 * _index_flops_dim(cfg) * seq_len
     # a delta state's decay, read, correction and write a token: 7 a float
@@ -577,15 +665,17 @@ def decode_flops_per_token(cfg: TransformerConfig,
     FLOPs per MAC each, over every cached position)."""
     n_matmul = _matmul_params(cfg, active=True) \
         + cfg.logit_size * cfg.d_model   # unembed logits matmul
-    if cfg.attention == "mla":
-        # absorbed: every head's query meets the cached latent row (and
-        # its rotary key), and the probabilities the latent again
-        per_pos = cfg.n_heads * (2 * cfg.kv_lora_rank
-                                 + cfg.qk_rope_head_dim)
-    else:
-        per_pos = cfg.n_heads * (cfg.head_dim + cfg.value_dim)
+    def per_pos(kind):
+        if cfg.attention == "mla":
+            # absorbed: every head's query meets the cached latent row (and
+            # its rotary key), and the probabilities the latent again
+            ck = cfg.latent_of(kind)
+            return ck.n_heads * (2 * ck.kv_lora_rank + ck.qk_rope_head_dim)
+        return cfg.n_heads * (cfg.head_dim + cfg.value_dim)
+
     # (a KDA layer's cost does not grow with the context)
-    return 2 * n_matmul + 2 * per_pos * _attended(cfg, context_len) \
+    return 2 * n_matmul + _attention_work(
+        cfg, lambda kind: 2 * per_pos(kind), context_len) \
         + 2 * _index_flops_dim(cfg) * context_len + 7 * _kda_state_size(cfg) \
         + _SSM_STATE_OPS * _ssm_state_size(cfg)
 
@@ -646,21 +736,9 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
         _init_ssm(add, p, ax, cfg, kind_layers(cfg, run, SSM_KINDS),
                   next(keys))
     if La and cfg.attention == "mla":
-        ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
-        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                          cfg.v_head_dim)
-        if ql:
-            add("wq_a", (d, ql), d, ("embed", None), La)
-            add("wq_b", (ql, h, nope + rope), ql, (None, "heads", "kv"), La)
-            p["q_norm"] = jnp.ones((La, ql), pt)
-            ax["q_norm"] = ("layers", None)
-        else:   # no query latent: the heads' queries from the input
-            add("wq", (d, h, nope + rope), d, ("embed", "heads", "kv"), La)
-        add("wkv_a", (d, kl + rope), d, ("embed", None), La)
-        add("wkv_b", (kl, h, nope + vd), kl, (None, "heads", "kv"), La)
-        add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"), La)
-        p["kv_norm"] = jnp.ones((La, kl), pt)
-        ax["kv_norm"] = ("layers", None)
+        n_win = kind_layers(cfg, run, ("window",)) if cfg.window_latent else 0
+        ql = cfg.q_lora_rank
+        _init_latent(add, p, ax, cfg, La - n_win, keys)
         n_idx = kind_layers(cfg, run, ("index",))
         if n_idx:       # the indexer: over the indexing layers only
             hi, di = cfg.index_heads, cfg.index_head_dim
@@ -672,6 +750,19 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
             p["ik_norm"] = jnp.ones((n_idx, di), pt)
             p["ik_norm_b"] = jnp.zeros((n_idx, di), pt)
             ax["ik_norm"] = ax["ik_norm_b"] = ("layers", None)
+        if n_win or cfg.head_gate:
+            # a window layer's latent weights, over the window layers alone
+            # at their own sizes, and the gates a head: ONE of the run's
+            # keys, so a model without either draws its weights as it did
+            ks = iter(jax.random.split(next(keys), 8))
+            if n_win:
+                _init_latent(add, p, ax, cfg.latent_of("window"), n_win, ks,
+                             _WIN)
+            for n, suffix, kind in ((La - n_win, "", "full"),
+                                    (n_win, _WIN, "window")):
+                if cfg.head_gate and n:
+                    add("wg" + suffix, (d, cfg.latent_of(kind).n_heads), d,
+                        ("embed", "heads"), n, next(ks))
     elif La and cfg.attention == "mha":
         add("wq", (d, h, hd), d, ("embed", "heads", "kv"), La)
         for kind in ("full", "window") if cfg.split_kv else ("full",):
@@ -884,9 +975,9 @@ def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate,
     head's first `rope_dim` dims (None: this layer turns nothing)."""
     dt = cfg.dtype
     kn, vn = kv_weight_names(cfg, kind)
-    y = _scaled(y, cfg.attn_in_scale)
+    y = mla.times(y, cfg.attn_in_scale)
     q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
-    k = _scaled(jnp.einsum("bsd,dhk->bshk", y, lp[kn].astype(dt)),
+    k = mla.times(jnp.einsum("bsd,dhk->bshk", y, lp[kn].astype(dt)),
                 cfg.key_scale)
     v = jnp.einsum("bsd,dhk->bshk", y, lp[vn].astype(dt))
     if cfg.value_scale != 1.0:
@@ -934,7 +1025,7 @@ def _attn_out(cfg: TransformerConfig, y: jnp.ndarray, attn: jnp.ndarray,
     if cfg.attn_gate:
         gate = jnp.einsum("bsd,dhk->bshk", y, lp["wg"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-    return _scaled(jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt)),
+    return mla.times(jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt)),
                    cfg.attn_out_scale)
 
 
@@ -964,24 +1055,27 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
     y = norm(x, lp["attn_norm"], lp.get("attn_norm_b"))
     if cfg.attention == "mla":
         # nothing is cached here: the plain form, every head's keys and
-        # values built from the latents
+        # values built from the latents (a window layer's at its own sizes,
+        # from its own stacks, under a window mask)
         rotate = functools.partial(apply_rotary, cos=cos, sin=sin) \
             if kind in angles else mla.no_turn
-        q_nope, q_rope, c_q = latent_queries(cfg, y, lp, rotate)
-        latent = mla.latents(y, lp["wkv_a"], lp["kv_norm"],
-                             kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
-                             rotate=rotate)
-        if kind == "index":
-            s = y.shape[1]
-            q_i, k_i, w = index_inputs(cfg, y, c_q, lp, rotate)
-            sel = sparse_index.selection_mask(
-                q_i, w, jnp.swapaxes(k_i, 1, 2),
-                jnp.tril(jnp.ones((s, s), bool))[None], cfg.index_topk)
-        x = x + _post(cfg, mla.attend_plain(
-            q_nope, q_rope, latent, lp["wkv_b"], lp["wo"],
-            causal=cfg.causal, impl=cfg.attention_impl,
-            selection=sel if kind in SPARSE_KINDS else None), lp,
-            "post_attn_norm")
+        ck, lw = cfg.latent_of(kind), latent_weights(cfg, lp, kind)
+        with latent_scope(cfg, kind):
+            q_nope, q_rope, c_q = latent_queries(ck, y, lw, rotate, kind)
+            latent = latent_rows(ck, y, lw, rotate, kind)
+            if kind == "index":
+                s = y.shape[1]
+                q_i, k_i, w = index_inputs(cfg, y, c_q, lp, rotate)
+                sel = sparse_index.selection_mask(
+                    q_i, w, jnp.swapaxes(k_i, 1, 2),
+                    jnp.tril(jnp.ones((s, s), bool))[None], cfg.index_topk)
+            delta = mla.attend_plain(
+                q_nope, q_rope, latent, lw["wkv_b"], lw["wo"],
+                causal=cfg.causal, impl=cfg.attention_impl,
+                selection=sel if kind in SPARSE_KINDS else None,
+                window=cfg.sliding_window if kind == "window" else None,
+                gate=head_gate(cfg, y, lw))
+        x = x + _post(cfg, delta, lp, "post_attn_norm")
     else:
         q, k, v = _qkv(cfg, y, lp, functools.partial(
             apply_rotary, cos=cos, sin=sin) if kind in angles else None,
@@ -1012,12 +1106,12 @@ def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
     dt = cfg.dtype
     up = jnp.einsum("bsd,df->bsf", y, w_in.astype(dt))
     if cfg.activation == "swiglu":
-        gate = _scaled(jnp.einsum("bsd,df->bsf", y, w_gate.astype(dt)),
+        gate = mla.times(jnp.einsum("bsd,df->bsf", y, w_gate.astype(dt)),
                        cfg.ffn_gate_scale)
         z = jax.nn.silu(gate) * up
     else:
         z = jax.nn.gelu(up)
-    return _scaled(jnp.einsum("bsf,fd->bsd", z, w_out.astype(dt)),
+    return mla.times(jnp.einsum("bsf,fd->bsd", z, w_out.astype(dt)),
                    cfg.ffn_out_scale)
 
 
@@ -1158,15 +1252,7 @@ def _embed(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
 
 def _scale_embedding(cfg: TransformerConfig, x: jnp.ndarray) -> jnp.ndarray:
     """The embedding multiplier of a model that states one."""
-    return _scaled(x, cfg.embed_scale)
-
-
-def _scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
-    """``x`` times a model's fixed multiplier, in float32 and back to
-    ``x``'s type; 1 is no multiplier and no instruction."""
-    if by == 1.0:
-        return x
-    return (x.astype(jnp.float32) * by).astype(x.dtype)
+    return mla.times(x, cfg.embed_scale)
 
 
 @jax.named_scope("head")
@@ -1187,7 +1273,7 @@ def forward_with_aux(params: Params, tokens: jnp.ndarray,
     # fp32 MXU accumulation straight out of the dot — rounding the logits
     # through bf16 first would cost ~3 decimal digits on a 50k-way softmax
     with jax.named_scope("head"):
-        logits = _scaled(jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg),
+        logits = mla.times(jnp.einsum("bsd,dv->bsv", x, _unembed(params, cfg),
                                     preferred_element_type=jnp.float32),
                          cfg.logit_scale)
     return logits, aux
@@ -1248,7 +1334,7 @@ def lm_loss(params: Params, batch: Dict[str, jnp.ndarray],
             vc = jnp.swapaxes(valid.reshape(b, n, cfg.loss_chunk), 0, 1)
 
             def chunk_sum(xi, ti, vi):
-                logits = _scaled(jnp.einsum(
+                logits = mla.times(jnp.einsum(
                     "bcd,dv->bcv", xi, w_out,
                     preferred_element_type=jnp.float32), cfg.logit_scale)
                 ls = optax.softmax_cross_entropy_with_integer_labels(
@@ -1387,6 +1473,12 @@ SSM_KINDS = ("ssm+full",)
 _ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
               "wq_b", "wkv_a", "wkv_b", "kv_norm")
 _WINDOW_KV = ("wk_win", "wv_win")
+_WIN = "_win"
+#: a latent layer's weights (`_init_latent`, the gate a head), and a window
+#: latent layer's own stacks of them where a model holds them apart
+_LATENT_KEYS = ("wq_a", "wq_b", "q_norm", "wq", "wkv_a", "wkv_b", "kv_norm",
+                "wo", "wg")
+_WINDOW_LATENT = tuple(k + _WIN for k in _LATENT_KEYS)
 #: a chunk's pooling, of the layers that attend through summaries
 _EVA_KEYS = ("adaptive_phi", "adaptive_mu_k")
 #: an indexer's weights, of the indexing layers alone
@@ -1422,20 +1514,24 @@ def check_kinds(cfg: TransformerConfig) -> None:
     sparse = set(cfg.kinds) & set(SPARSE_KINDS)
     if not sparse and not cfg.index_topk:
         return
-    if not sparse or set(cfg.kinds) - sparse or cfg.attention != "mla" \
+    if not sparse or set(cfg.kinds) - sparse - {"window"} \
+            or cfg.attention != "mla" \
             or min(cfg.index_topk, cfg.index_heads, cfg.index_head_dim) < 1 \
             or cfg.index_head_dim < cfg.qk_rope_head_dim:
         raise ValueError(
             f"layer_kinds {cfg.layer_kinds!r} with index_topk "
             f"{cfg.index_topk}: an indexer is a latent-attention model's "
-            f"(attention='mla'), every layer of which is 'index' or 'shared' "
-            f"and which states index_topk, index_heads and index_head_dim "
-            f"(at least the rotary part's {cfg.qk_rope_head_dim})")
-    if cfg.kinds[0] != "index":
+            f"(attention='mla'), every layer of which is 'index', 'shared' "
+            f"or 'window' and which states index_topk, index_heads and "
+            f"index_head_dim (at least the rotary part's "
+            f"{cfg.qk_rope_head_dim})")
+    if next(k for k in cfg.kinds if k in SPARSE_KINDS) != "index":
+        where = "the first layer is 'shared'" if cfg.kinds[0] == "shared" \
+            else "a 'shared' layer stands behind 'window' layers alone " \
+                 "(a window layer makes no choice and hands none on)"
         raise ValueError(
-            f"layer_kinds {cfg.layer_kinds!r}: the first layer is 'shared', "
-            f"and no indexing layer stands before it whose choice it could "
-            f"attend")
+            f"layer_kinds {cfg.layer_kinds!r}: {where}, and no indexing "
+            f"layer stands before it whose choice it could attend")
 
 
 def index_inputs(cfg: TransformerConfig, y: jnp.ndarray, c_q: jnp.ndarray,
@@ -1474,7 +1570,7 @@ def stack_kinds(cfg: TransformerConfig, key: str
         return SSM_KINDS
     if key == "sink":
         return cfg.sink_kinds
-    if key in _WINDOW_KV:
+    if key in _WINDOW_KV or key in _WINDOW_LATENT:
         return ("window",)
     if key in _EVA_KEYS:
         return ("eva",)
@@ -1484,9 +1580,10 @@ def stack_kinds(cfg: TransformerConfig, key: str
         if cfg.split_kv and key in ("wk", "wv"):
             return ("full",)
         # (a summary, indexing or shared layer is named only by a model
-        # that has one)
-        return ("full", "window") + tuple(
-            k for k in ATTENTION_KINDS[2:] if k in cfg.kinds)
+        # that has one; a latent model's window layers have stacks of
+        # their own)
+        return ("full",) + (() if cfg.window_latent else ("window",)) \
+            + tuple(k for k in ATTENTION_KINDS[2:] if k in cfg.kinds)
     return None
 
 
@@ -1590,17 +1687,89 @@ def _multi_head_loss(params: Params, tokens: jnp.ndarray, mask,
         return total / cfg.pred_heads
 
 
+def _init_latent(add, p: Params, ax: Params, cfg: TransformerConfig, n: int,
+                 keys, suffix: str = "") -> None:
+    """A run's latent-attention weights at ``cfg``'s latent sizes (a kind's
+    own: `TransformerConfig.latent_of`), stacked over ``n`` layers under
+    their names + ``suffix``, a key of ``keys`` drawn a projection."""
+    d, h, pt = cfg.d_model, cfg.n_heads, cfg.param_dtype
+    ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    # (what reads a RESCALED latent is drawn as if it read the model's
+    # width: the multiplier then leaves a head's query, key and value at the
+    # variance every other projection's output has)
+    up = (lambda rank: d) if cfg.latent_rescale else (lambda rank: rank)
+    if ql:
+        add("wq_a" + suffix, (d, ql), d, ("embed", None), n, next(keys))
+        add("wq_b" + suffix, (ql, h, nope + rope), up(ql),
+            (None, "heads", "kv"), n, next(keys))
+        p["q_norm" + suffix] = jnp.ones((n, ql), pt)
+        ax["q_norm" + suffix] = ("layers", None)
+    else:   # no query latent: the heads' queries from the input
+        add("wq" + suffix, (d, h, nope + rope), d, ("embed", "heads", "kv"),
+            n, next(keys))
+    add("wkv_a" + suffix, (d, kl + rope), d, ("embed", None), n, next(keys))
+    add("wkv_b" + suffix, (kl, h, nope + vd), up(kl), (None, "heads", "kv"),
+        n, next(keys))
+    add("wo" + suffix, (h, vd, d), h * vd, ("heads", "kv", "embed"), n,
+        next(keys))
+    p["kv_norm" + suffix] = jnp.ones((n, kl), pt)
+    ax["kv_norm" + suffix] = ("layers", None)
+
+
+def latent_weights(cfg: TransformerConfig, lp: Params, kind: str) -> Params:
+    """A latent layer's weights under their plain names: a window layer's
+    are its run's ``*_win`` stacks where the model holds those apart."""
+    if kind != "window" or not cfg.window_latent:
+        return lp
+    return dict(lp, **{k[:-len(_WIN)]: v for k, v in lp.items()
+                       if k.endswith(_WIN)})
+
+
 def latent_queries(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
-                   rotate):
+                   rotate, kind: str = "full"):
     """A latent-attention layer's queries of the normed input ``y`` ->
     (q_nope, q_rope turned by ``rotate``, the query latent or None):
-    through a query latent and its norm, or, of a model that has none
-    (``q_lora_rank`` 0), projected directly."""
+    through a query latent, its norm and the model's fixed multiplier on it,
+    or, of a model that has none (``q_lora_rank`` 0), projected directly.
+    ``cfg`` and ``lp`` are the layer's kind's (`TransformerConfig.latent_of`,
+    `latent_weights`)."""
     nope = cfg.qk_nope_head_dim
     if cfg.q_lora_rank:
         return mla.queries(y, lp["wq_a"], lp["q_norm"], lp["wq_b"],
-                           nope=nope, eps=norm_eps(cfg), rotate=rotate)
+                           nope=nope, eps=norm_eps(cfg), rotate=rotate,
+                           scale=cfg.latent_scales(kind)[0])
     return mla.direct_queries(y, lp["wq"], nope=nope, rotate=rotate) + (None,)
+
+
+def latent_rows(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate,
+                kind: str = "full") -> jnp.ndarray:
+    """What a latent layer's cache holds of the normed input ``y`` [b, s, d]
+    -> [b, s, kv_lora + rope] (`mla.latents`), the latent under the model's
+    fixed multiplier; ``cfg`` and ``lp`` the layer's kind's."""
+    return mla.latents(y, lp["wkv_a"], lp["kv_norm"],
+                       kv_lora=cfg.kv_lora_rank, eps=norm_eps(cfg),
+                       rotate=rotate, scale=cfg.latent_scales(kind)[1])
+
+
+@jax.named_scope("projections")
+def head_gate(cfg: TransformerConfig, y: jnp.ndarray, lp: Params):
+    """``sigmoid(y W_g)`` [b, s, heads], one value a head, of a model whose
+    latent layers gate their heads' output (None: it does not)."""
+    if not cfg.head_gate:
+        return None
+    gate = jnp.einsum("bsd,dh->bsh", y, lp["wg"].astype(y.dtype))
+    return jax.nn.sigmoid(gate.astype(jnp.float32)).astype(y.dtype)
+
+
+def latent_scope(cfg: TransformerConfig, kind: str):
+    """The scope ``window_latent`` AROUND all of a window latent layer's
+    operator (its parts keep their own names inside it, as ``ssm`` stands
+    around a mixer's), nothing for any other layer."""
+    return jax.named_scope("window_latent") \
+        if kind == "window" and cfg.window_latent \
+        else contextlib.nullcontext()
 
 
 def _init_kda(add, p: Params, ax: Params, cfg: TransformerConfig, n: int,
@@ -1784,7 +1953,7 @@ def ssm_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
     b, s, _ = y.shape
     with jax.named_scope("ssm"):
         with jax.named_scope("projections"):
-            u = jnp.einsum("bsd,de->bse", _scaled(y, cfg.ssm_in_scale),
+            u = jnp.einsum("bsd,de->bse", mla.times(y, cfg.ssm_in_scale),
                            lp["ssm_in"].astype(dt))
             if any(m != 1.0 for m in cfg.ssm_scales):   # by segment
                 widths = (inner, inner, g * n, g * n, h)
@@ -1816,6 +1985,6 @@ def ssm_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
             o = ssd.gated_norm(o.reshape(b, s, inner), z, lp["ssm_norm"], g,
                                eps)
         with jax.named_scope("projections"):
-            return (_scaled(jnp.einsum("bse,ed->bsd", o.astype(dt),
+            return (mla.times(jnp.einsum("bse,ed->bsd", o.astype(dt),
                                        lp["ssm_out"].astype(dt)),
                             cfg.ssm_out_scale), state, conv)

@@ -47,6 +47,16 @@ three ways, which the SHAPE and the PLATFORM choose between (`kernel_shape`,
     in those same places, where a chunk's scores over every row would be a
     GB (`sparse_index.loop_block`).
 
+A model may multiply both latents by a fixed ``scale`` after their norms
+(`queries`, `latents`), gate each head's output by one value a head
+(``gate``: `attend_plain`, `unabsorb`) and give a kind of layer a WINDOW:
+`attend_plain` masks by it, and the cached forms read that kind's latents
+from a RING (`models/generate.py`) under a mask by the position a column
+holds: `attend_cache` and XLA's forms take a mask as it comes, so a window
+that wraps the ring's seam is columns at both ends of it, and a row of
+another width than the full layers' (a latent of 1024 beside the rotary key:
+1088 values) is the same call at another shape.
+
 Shapes are ``[batch, seq, heads, dim]`` like `ops/attention.py`; a cache
 layer is ``[batch, kv_lora + rope, positions]``, positions last, as
 `models/generate.py` stores it.
@@ -74,16 +84,25 @@ Rotate = Callable[[jnp.ndarray], jnp.ndarray]   # [b, s, heads, rope] -> same
 
 @jax.named_scope("projections")
 def queries(y: jnp.ndarray, wq_a, q_norm, wq_b, *, nope: int, eps: float,
-            rotate: Rotate
+            rotate: Rotate, scale: float = 1.0
             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Normed input ``y`` [b, s, d] -> (q_nope [b, s, h, nope], q_rope
     [b, s, h, rope] already rotated, and the query latent ``c_q`` [b, s,
-    q_lora] they were made from: an indexer's queries come from it too,
-    `ops/sparse_index.py`)."""
+    q_lora] they were made from, times a model's fixed ``scale``: an
+    indexer's queries come from it too, `ops/sparse_index.py`)."""
     dt = y.dtype
-    c_q = rmsnorm(jnp.einsum("bsd,dr->bsr", y, wq_a.astype(dt)), q_norm, eps)
+    c_q = times(rmsnorm(jnp.einsum("bsd,dr->bsr", y, wq_a.astype(dt)),
+                         q_norm, eps), scale)
     q = jnp.einsum("bsr,rhk->bshk", c_q, wq_b.astype(dt))
     return q[..., :nope], rotate(q[..., nope:]), c_q
+
+
+def times(x: jnp.ndarray, by: float) -> jnp.ndarray:
+    """``x`` times a model's fixed multiplier, in float32 and back; 1 is no
+    multiplier and no instruction."""
+    if by == 1.0:
+        return x
+    return (x.astype(jnp.float32) * by).astype(x.dtype)
 
 
 def no_turn(t: jnp.ndarray) -> jnp.ndarray:
@@ -103,13 +122,14 @@ def direct_queries(y: jnp.ndarray, wq, *, nope: int, rotate: Rotate
 
 @jax.named_scope("projections")
 def latents(y: jnp.ndarray, wkv_a, kv_norm, *, kv_lora: int, eps: float,
-            rotate: Rotate) -> jnp.ndarray:
+            rotate: Rotate, scale: float = 1.0) -> jnp.ndarray:
     """Normed input ``y`` [b, s, d] -> [b, s, kv_lora + rope]: the normed
-    key-value latent beside the rotated shared key.  This, and nothing
-    else, is what a cache of this attention kind holds."""
+    key-value latent (times a model's fixed ``scale``) beside the rotated
+    shared key.  This, and nothing else, is what a cache of this attention
+    kind holds."""
     dt = y.dtype
     ckv = jnp.einsum("bsd,dr->bsr", y, wkv_a.astype(dt))
-    c = rmsnorm(ckv[..., :kv_lora], kv_norm, eps)
+    c = times(rmsnorm(ckv[..., :kv_lora], kv_norm, eps), scale)
     k_r = rotate(ckv[..., None, kv_lora:])[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
 
@@ -118,11 +138,16 @@ def latents(y: jnp.ndarray, wkv_a, kv_norm, *, kv_lora: int, eps: float,
 def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                  latent: jnp.ndarray, wkv_b, wo, *, causal: bool = True,
                  impl: str = "auto",
-                 selection: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 selection: Optional[jnp.ndarray] = None,
+                 window: Optional[int] = None,
+                 gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Every head's keys and values built from ``latent`` [b, s, kv_lora +
     rope], ordinary attention over them -> [b, s, d].  ``selection`` [b, s,
     s] bool (a model with an indexer): the positions each query attends,
-    which then IS the mask (a selection is causal by how it was made)."""
+    which then IS the mask (a selection is causal by how it was made).
+    ``window`` (a window layer): position t attends j with ``0 <= t - j <
+    window``.  ``gate`` [b, s, h] (`transformer.head_gate`): a head's output
+    times its value, before the output projection."""
     dt = q_nope.dtype
     nope, rope = q_nope.shape[-1], q_rope.shape[-1]
     kv_lora, h = wkv_b.shape[0], wkv_b.shape[1]
@@ -145,7 +170,10 @@ def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
         attn = jnp.einsum("bhst,bthv->bshv", probs.astype(dt), v)
     else:
         attn = multi_head_attention(q, k, v, causal=causal, impl=impl,
-                                    sm_scale=1.0 / math.sqrt(nope + rope))
+                                    sm_scale=1.0 / math.sqrt(nope + rope),
+                                    window=window)
+    if gate is not None:
+        attn = attn * gate[..., None]
     with jax.named_scope("projections"):
         return jnp.einsum("bshk,hkd->bsd", attn, wo.astype(dt))
 
@@ -153,7 +181,8 @@ def attend_plain(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
 @jax.named_scope("attention")
 def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                     cached: jnp.ndarray, wkv_b, wo, mask: jnp.ndarray,
-                    key_block: int = 0) -> jnp.ndarray:
+                    key_block: int = 0,
+                    gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """``cached`` [b, kv_lora + rope, T] is one layer of a latent cache,
     ``mask`` [b|1, s, T] which positions each query may see -> [b, s, d].
     The probabilities meet all ``kv_lora + rope`` cached rows (the rotary
@@ -165,7 +194,7 @@ def attend_absorbed(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     nope = q_nope.shape[-1]
     o_lat = attend_latents(absorb(q_nope, q_rope, wkv_b), cached, mask,
                            math.sqrt(nope + q_rope.shape[-1]), key_block)
-    return unabsorb(o_lat, wkv_b, wo, nope)
+    return unabsorb(o_lat, wkv_b, wo, nope, gate=gate)
 
 
 @jax.named_scope("attention")
@@ -469,14 +498,18 @@ def attend_cache(q_abs: jnp.ndarray, kv_all: jnp.ndarray, l,
 
 @jax.named_scope("attention")
 def unabsorb(o_lat: jnp.ndarray, wkv_b, wo, nope: int,
-             heads_major: bool = False) -> jnp.ndarray:
+             heads_major: bool = False,
+             gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """`attend_latents`' [b, s, h, kv_lora + rope] (``heads_major``:
     `attend_cache`'s [b, h, s, kv_lora]) -> the block's [b, s, d]: the value
-    up-projection on the latent rows, then the output's."""
+    up-projection on the latent rows, a head's ``gate`` [b, s, h] where the
+    model has one, then the output's."""
     dt = o_lat.dtype
     kv_lora = wkv_b.shape[0]
     attn = jnp.einsum("bhsr,rhv->bshv" if heads_major else "bshr,rhv->bshv",
                       o_lat[..., :kv_lora], wkv_b.astype(dt)[..., nope:])
+    if gate is not None:
+        attn = attn * gate[..., None]
     with jax.named_scope("projections"):
         return jnp.einsum("bshv,hvd->bsd", attn, wo.astype(dt))
 

@@ -265,6 +265,13 @@ class _LoopLock:
         self.eng._lock.release()
 
 
+def _ring_rows(cfg, batch) -> int:
+    """Ring rows the live slots of ``batch`` attend a step, summed over the
+    model's window layers: ``min(pos + 1, window)`` a slot a layer."""
+    return cfg.kinds.count("window") * sum(
+        min(s.pos + 1, cfg.sliding_window) for s in batch)
+
+
 class ContinuousBatchingEngine:
     """Replica-resident continuous-batching decode loop.
 
@@ -483,6 +490,7 @@ class ContinuousBatchingEngine:
         # wrote them (one an array a layer where the kernel engages)
         self.rows = dict.fromkeys(
             ("steps",) + self._ROW_SUMS + self._INDEX_SUMS
+            + self._RING_LATENT_SUMS
             + self._STATE_SUMS + self._FETCH_SUMS + self._WRITE_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
         from ..models.generate import position_bytes
@@ -1490,6 +1498,7 @@ class ContinuousBatchingEngine:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
         rows = self._rows_of(batch) + self._index_rows_of(batch) \
+            + (self._ring_latent_bytes(batch),) \
             + self._state_rows_of(batch) \
             + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
         flight = self._flight
@@ -1574,6 +1583,9 @@ class ContinuousBatchingEngine:
     #: ... and what `_index_rows_of` does: the index keys an indexer scored
     #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
     _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
+    #: ... and, of `bytes_read`, the part that is a window LATENT layer's
+    #: ring rows (a latent model with window layers; else 0)
+    _RING_LATENT_SUMS = ("ring_latent_bytes_read",)
     #: ... and what a delta or a state-space state costs a step: the (slot,
     #: layer that carries one) states advanced, their bytes READ AND WRITTEN
     #: (a layer that holds rows beside its state has those among
@@ -1611,7 +1623,7 @@ class ContinuousBatchingEngine:
         is counted beside them: each indexing layer reads ONE index key of
         every position at or before the query (``index_rows_read``; their
         bytes are among ``bytes_read`` too)."""
-        if self.cfg.index_topk:     # every layer is under the choice
+        if self.cfg.index_topk:     # its full layers are under the choice
             return self._chosen_rows_of(batch)
         eva = self._eva_layers
         full = self.cfg.n_layers - self._window_layers - self._conv_layers \
@@ -1639,16 +1651,31 @@ class ContinuousBatchingEngine:
                 self.cfg.n_layers * depth * widest, pooled, pooled_bytes)
 
     def _chosen_rows_of(self, batch) -> Tuple[int, ...]:
-        """`_rows_of` for a model with an indexer (all its layers are
-        indexing or shared ones, `models.transformer.check_kinds`)."""
-        layers, per = self.cfg.n_layers, self._row_bytes["full"]
+        """`_rows_of` for a model with an indexer: its indexing and shared
+        layers attend the ``min(t + 1, index_topk)`` latents chosen, and
+        the window layers that may stand among them
+        (`models.transformer.check_kinds`) the ``min(t + 1, window)`` rows
+        of their ring, each kind's row at its own width."""
+        cfg = self.cfg
+        windows = cfg.kinds.count("window")
+        layers, per = cfg.n_layers - windows, self._row_bytes["full"]
         depth = sum(s.pos + 1 for s in batch)
-        chosen = layers * sum(min(s.pos + 1, self.cfg.index_topk)
-                              for s in batch)
-        return (chosen, layers * depth,
-                chosen * per
+        chosen = layers * sum(min(s.pos + 1, cfg.index_topk) for s in batch)
+        ring = _ring_rows(cfg, batch)
+        per_ring = self._row_bytes["ring"] if windows else 0
+        return (chosen + ring, cfg.n_layers * depth,
+                chosen * per + ring * per_ring
                 + self._index_layers * depth * self._row_bytes["index"],
-                layers * depth * per, 0, 0)
+                cfg.n_layers * depth * max(per, per_ring), 0, 0)
+
+    def _ring_latent_bytes(self, batch) -> int:
+        """`_RING_LATENT_SUMS` of a decode step about to be dispatched: a
+        live slot reads ``min(t + 1, window)`` ring rows on each window
+        layer of a LATENT model, at the ring's row width; 0 for any other
+        model."""
+        if not self.cfg.window_latent:
+            return 0
+        return _ring_rows(self.cfg, batch) * self._row_bytes["ring"]
 
     def _index_rows_of(self, batch) -> Tuple[int, int]:
         """`_INDEX_SUMS` of a decode step about to be dispatched: each
@@ -1680,6 +1707,7 @@ class ContinuousBatchingEngine:
         with self._loop_lock:   # stats() reads these
             self.rows["steps"] += 1
             for k, n in zip(self._ROW_SUMS + self._INDEX_SUMS
+                            + self._RING_LATENT_SUMS
                             + self._STATE_SUMS + self._FETCH_SUMS
                             + self._WRITE_SUMS, rows):
                 self.rows[k] += n

@@ -339,7 +339,11 @@ MODEL_PARTS = ("embed", "norm", "projections", "attention", "cache_write",
 # scores, the choice), inside ``attention``; ``kda``, what a gated delta rule
 # adds to a layer beside its projections (`models/transformer.py`
 # `kda_operator`, `ops/delta_rule.py`), inside ``attention`` too, but for its
-# convolutions, which keep ``conv``.  Not parts of their own while
+# convolutions, which keep ``conv``.  Or AROUND parts: ``ssm``, all of a
+# state-space mixer (`transformer.ssm_operator`), and ``window_latent``, all
+# of a window layer's operator over a latent cache
+# (`transformer.latent_scope`), whose projections, ring write and attention
+# keep their parts' names inside it.  Not parts of their own while
 # the benchmark's list (`perfbench/parts.py` ``PARTS``, held equal to
 # `MODEL_PARTS` by its tests) has ten.
 

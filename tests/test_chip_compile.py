@@ -780,6 +780,75 @@ def test_thinking_cells_latent_layers_read_the_blocks_they_see(topo):
         assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+def test_notes_cells_two_latent_shapes_read_the_blocks_they_see(topo):
+    """`dots3-note-prev` at the notes cell's real shapes, for the described
+    v5e (no chip time): the fused slot step over 16 slots x 17,408 and the
+    four-lane chunk program read BOTH latent arrays through
+    `latent_attention_cache` where they lie, one call a loop segment: the
+    three full layers' ``kv`` of 576-value rows (one layer in the dense run,
+    two segments of the expert run) and the six sliding layers' RING of 768
+    rows of 1088 values (two segments of three), the mask a row a slot (a
+    step) or a row a query (a chunk); no lane's layer and no ring is cut out
+    of its array, and the programs' scratch stays far under what is left
+    beside 10.5 GB of arguments."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import init_slot_cache
+    from ray_tpu.models.generate import _decode_step_slots
+    c = mf.Manifest().config("dots3-note-prev")
+    model = mf.family_of(c).model
+    cfg = model.model_config(c, "serve")
+    assert (cfg.kinds.count("index"), cfg.kinds.count("window")) == (3, 6)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: model.make(k, c, jnp.bfloat16), jax.random.PRNGKey(0)))
+    slots, max_len, ring = 16, 17408, 768
+    cache = described(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)))
+    assert {n: a.shape for n, a in cache.items() if n != "pos"} == {
+        "kv": (3, slots, 1, 576, max_len), "kv_win": (6, slots, 1, 1088, ring),
+        "k_idx": (3, slots, 1, 128, max_len)}
+
+    def fused_step(params, tok, cache, active):
+        logits, cache, _ = _decode_step_slots(params, tok, cache, active,
+                                              cfg)
+        return jnp.argmax(logits[..., :cfg.vocab_size], axis=-1), cache
+    step = jax.jit(fused_step, donate_argnums=(2,)).lower(
+        params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    ).compile()
+    _, lanes, n_lanes, _ = _lower_lanes(described, params, cfg,
+                                        "prefill_lanes_4x128", max_len)
+    for compiled, rows, mask_rows in ((step, slots, 1),
+                                      (lanes.compile(), n_lanes, 128)):
+        text = compiled.as_text()
+        calls = [x for x in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+                 if "latent_attention_cache" in x]
+        full = [x for x in calls if f"bf16[3,{rows},1,576,{max_len}]" in x]
+        rings = [x for x in calls if f"bf16[6,{rows},1,1088,{ring}]" in x]
+        assert (len(calls), len(full), len(rings)) == (5, 3, 2), len(calls)
+        for call in full:
+            assert f"s8[{rows},{mask_rows},{max_len}]" in call, call[:300]
+        for call in rings:
+            assert f"s8[{rows},{mask_rows},{ring}]" in call, call[:300]
+        # no lane's layer and no ring cut out of its array (what stands
+        # over all of a row's positions is the INDEXER's: one key of 128 a
+        # position and its 64 heads' scores, XLA's dots)
+        assert not re.findall(rf"= bf16\[(?:1,)*576,{max_len}\]", text)
+        assert not re.findall(rf"= bf16\[(?:1,)*1088,{ring}\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
 def _lower_lanes(described, params, cfg, program, max_len):
     """``prefill_lanes_<P>x<C>``: the chunk program over P lanes of C rows
     (`models.generate.prefill_lanes`, what the engine runs while two or more
