@@ -3,7 +3,8 @@
 The reference framework has no fused attention of its own — it delegates all
 model math to torch (SURVEY.md §2.3); in a TPU-native stack the attention
 inner loop is the single hottest op, so it gets hand-written kernels: a
-softmax forward and a custom-VJP backward of two kernels (dq; dk, dv).
+softmax forward and a custom-VJP backward, ONE kernel (dq, dk, dv) where the
+plan holds a head block's whole query side, two (dq; dk, dv) elsewhere.
 
 **Layout.**  Inputs are ``[batch, seq, heads, head_dim]``, the framework's
 activation layout, and the kernels read them where they lie: that array IS
@@ -27,9 +28,9 @@ the array, gets a kernel body of its own for the heads inside (`_inside`):
 nothing is computed from what lies outside, Pallas drops what would be
 written there, and no operand is padded or copied.
 
-**Blocks.**  The grid walks q blocks (forward, dq) or k blocks (dkv) of
-``block_q`` / ``block_k`` rows; the OTHER operand arrives as one major block,
-the whole sequence when `_VMEM_BLOCK_BUDGET` allows (then it is fetched once
+**Blocks.**  The grid walks q blocks (forward, dq) or k blocks (dkv, with
+or without dq) of ``block_q`` / ``block_k`` rows; the OTHER operand arrives
+as one major block, the whole sequence when `_VMEM_BLOCK_BUDGET` allows (then it is fetched once
 a head block, not once a q block), else the largest multiple of the tile
 that fits.  Inside, a loop walks tiles of ``block_q x block_k`` over the part
 of the major block that causality leaves live, each with one compare and
@@ -49,6 +50,39 @@ transposed, then exponentials and sums against them; the dkv kernel computes
 its scores transposed too (``k q^T``), so its statistics are rows as stored
 and none of its four matmuls transposes a score tile; dq takes the
 statistics as columns, transposed once a q block.
+
+**One backward kernel** (`one_backward`, a function of the plan alone).  The
+dq and the dkv kernel walk the same live tiles and each computes a tile's
+scores, exponentials, ``dp`` and ``ds``: seven matmuls and two passes of
+exponentials where the mathematics has five and one.  Where the whole query
+side is resident (``major_q == s_q``), a kv head block meets all its query
+heads in one grid step (``q_steps == 1``) and the accumulator fits, the dkv
+kernel accumulates dq as well (``with_dq``, call name
+``flash_attention_bwd``): a float32 ``dq^T [hq x d, s_q]`` that lives
+across a head block's k steps (so the k axis is ``arbitrary``), added to at
+the tile's columns on every live tile, written as a third result at the last
+k block.  It costs 2 MB of VMEM at 8 heads of 64 over 1024 rows beside 0.25
+of the transposed k block and 2 of the dq block (`_block_bytes`).  Same
+operands, same float32 statistics and accumulators, the same ``ds`` cast
+before its matmuls, dq summed over k tiles in ascending order: dk and dv are
+the dkv kernel's to the bit, dq the dq kernel's to float32 rounding.  Plans
+with major blocks smaller than the sequence, or a kv head whose query heads
+take several grid steps, keep the two kernels.
+
+The fifth matmul contracts the tile's FIRST dimension (``dq = ds^T k`` with
+scores held ``[block_k, block_q]``).  Measured on one v5e chip, a call of the
+backward alone, the kernels' own ms at ``[8, 1024, 16, 64]`` /
+``[2, 1024, 25, 64]`` (PERF.md, PR 56): the two kernels 1.082 / 0.451; one
+kernel with `dot_general` contracting dimension 0 of both (Mosaic transposes
+the ``ds`` tile) 0.800 / 0.331, the same into a lane-dense accumulator
+``[s_q, hq x d]`` 0.798 / 0.330, ``ds`` transposed by hand in bfloat16 0.798
+/ 0.330 and in float32 0.772 / 0.321; ``dq^T += k^T ds``, the tile as it lies
+against the k block transposed once a grid step, one transpose of the
+accumulator at the end, **0.715 / 0.299** (kept); no fifth matmul at all
+0.612 / 0.257.  The one kernel also won at every other plan tried (4 heads
+of 64 over 4096 rows 2.504 -> 1.766, GQA 8 over 2 at 2048 rows 1.722 ->
+1.127, 4 heads of 128 over one 0.900 -> 0.690, ViT's one tile of 197 rows
+0.388 -> 0.237), so no plan that can hold the accumulator takes the two.
 
 The default tile (`DEFAULT_BLOCK_Q` x `DEFAULT_BLOCK_K`), `_MAX_HEADS` and the
 two-pass forward were measured on one v5e chip on the three kernels alone at
@@ -123,7 +157,7 @@ def tile_ok(block: int, s: int) -> bool:
 
 
 class Plan(NamedTuple):
-    """The static shape of one call's three kernels."""
+    """The static shape of one call's kernels."""
     h: int            # query heads
     h_kv: int
     d: int
@@ -133,19 +167,34 @@ class Plan(NamedTuple):
     block_k: int      # tile columns
     major_k: int      # k, v rows resident in the forward and dq kernels
     major_q: int      # q, do rows resident in the dkv kernel
+    s_q: int          # query rows of the call
+    itemsize: int     # bytes of an operand's element
 
     @property
     def head_blocks(self) -> int:
         return -(-self.h // self.hq)
+
+    @property
+    def q_steps(self) -> int:
+        """Query head blocks a kv head block meets, one a grid step of the
+        kernels that walk k blocks."""
+        return 1 if self.hk > 1 else self.h // self.h_kv // self.hq
 
 
 def _lane_ok(n: int, d: int, total: int) -> bool:
     return n == total or (n * d) % _LANES == 0
 
 
-def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize) -> int:
+def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize,
+                 with_dq: bool = False) -> int:
     """VMEM of the hungrier of the forward / dq and the dkv kernel: double
-    buffered blocks, scratch, and the float32 tiles of one head."""
+    buffered blocks, scratch, and the float32 tiles of one head.
+    ``with_dq``: of the dkv kernel where it accumulates dq as well
+    (`one_backward`; ``major_q`` is then the whole query side): beside its
+    own blocks the float32 accumulator ``[hq x d, major_q]`` (lane-dense:
+    the sequence on the lanes, no head padded to 128), the k block
+    transposed, and the dq block, double buffered: 2 + 0.25 + 2 MB over the
+    dkv kernel's 10.4 at the train cells' plan (8 heads of 64, 1024 rows)."""
     dp = -(-d // _LANES) * _LANES
     tiles = 6 * bq * bk * 4
     walk_q = (2 * 3 * bq * hq * d * itemsize            # q, do / o, dq
@@ -155,7 +204,27 @@ def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize) -> int:
               + 2 * 4 * bk * hk * d * itemsize          # k, v, dk, dv
               + 2 * 2 * hq * major_q * 4                # lse, delta
               + hk * bk * dp * (8 + itemsize))
+    if with_dq:
+        return (walk_k + tiles
+                + hq * d * major_q * 4                  # dq^T, float32
+                + hk * d * bk * itemsize                # k^T
+                + 2 * major_q * hq * d * itemsize)      # dq
     return max(walk_q, walk_k) + tiles
+
+
+def one_backward(p: Plan) -> bool:
+    """Whether the call's backward is ONE kernel (dq accumulated in the dkv
+    kernel, ``flash_attention_bwd``) and not two: where the plan holds the
+    whole query side, a kv head block meets all its query heads in one grid
+    step, and the accumulator fits half of what the compiler is given
+    (`_block_bytes` leaves the compiler's own temporaries out; measured
+    faster than the two kernels at every plan tried, up to 20.1 MB: 4 heads
+    of 64 over 4096 rows).  A plan made under `_VMEM_BLOCK_BUDGET` that
+    holds its query side seldom comes near the bound."""
+    return (p.major_q == p.s_q and p.q_steps == 1
+            and _block_bytes(p.hq, p.hk, p.d, p.block_q, p.block_k,
+                             p.major_k, p.major_q, p.itemsize,
+                             with_dq=True) <= _VMEM_LIMIT // 2)
 
 
 def _major(block: int, s: int, fits) -> int:
@@ -192,13 +261,15 @@ def make_plan(h: int, h_kv: int, d: int, s_q: int, s_kv: int, itemsize: int,
 
     for hq, hk in reversed(capped):
         if bytes_of(hq, hk, s_kv, s_q) <= _VMEM_BLOCK_BUDGET:
-            return Plan(h, h_kv, d, hq, hk, block_q, block_k, s_kv, s_q)
+            return Plan(h, h_kv, d, hq, hk, block_q, block_k, s_kv, s_q,
+                        s_q, itemsize)
     hq, hk = capped[0]
     major_k = _major(block_k, s_kv, lambda m: bytes_of(
         hq, hk, m, block_q) <= _VMEM_BLOCK_BUDGET)
     major_q = _major(block_q, s_q, lambda m: bytes_of(
         hq, hk, block_k, m) <= _VMEM_BLOCK_BUDGET)
-    return Plan(h, h_kv, d, hq, hk, block_q, block_k, major_k, major_q)
+    return Plan(h, h_kv, d, hq, hk, block_q, block_k, major_k, major_q,
+                s_q, itemsize)
 
 
 def _folds(sm_scale: float) -> bool:
@@ -604,14 +675,25 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, ks_ref, dk_acc, dv_acc, *, p: Plan,
-                    sm_scale, causal, q_offset):
+                    dk_ref, dv_ref, *rest, p: Plan, sm_scale, causal,
+                    q_offset, with_dq):
     """Scores transposed, ``[block_k, block_q]``: the statistics are rows
-    as stored, and dv = p^T do, dk = ds^T q are plain matmuls."""
+    as stored, and dv = p^T do, dk = ds^T q are plain matmuls.
+
+    ``with_dq`` (`one_backward`: the whole query side resident, so the grid
+    walks k blocks alone): dq is accumulated beside them, TRANSPOSED, in a
+    float32 ``[hq x d, s_q]`` that lives across the k steps of a head block:
+    ``dq^T[:, tile] += k^T ds``, a plain matmul of the tile as it lies
+    against the k block transposed once a grid step.  Zeroed at the first k
+    block, transposed back, scaled and written at the last."""
     ki, gi, qm = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     last_step = (gi == pl.num_programs(3) - 1) & (qm == pl.num_programs(4) - 1)
     bq, bk, d = p.block_q, p.block_k, p.d
     per_kv = p.hq // p.hk
+    if with_dq:
+        dq_ref, ks_ref, dk_acc, dv_acc, dq_acc, kt_ref = rest
+    else:
+        ks_ref, dk_acc, dv_acc = rest
 
     def run(nq):
         @pl.when((gi == 0) & (qm == 0))
@@ -619,6 +701,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
             _fill_scaled(ks_ref, k_ref, nq // per_kv, d, sm_scale)
+            if with_dq:     # through float32: exact, and any tile transposes
+                kt_ref[...] = jnp.transpose(
+                    k_ref[0].astype(jnp.float32)).astype(kt_ref.dtype)
+
+        if with_dq:
+            @pl.when(ki == 0)
+            def _init_dq():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
 
         rel = _query_minus_key((bk, bq), 1) if causal else None
 
@@ -636,6 +726,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dp = _nt(_heads(v_ref, slice(None), n, d), do)
                 ds = (e * (dp - delta_ref[0, 0, g:g + 1, rows])).astype(q.dtype)
                 dk_acc[n] += _nn(ds, q)
+                if with_dq:
+                    dq_acc[g * d:(g + 1) * d, rows] += _nn(
+                        kt_ref[n * d:(n + 1) * d, :], ds)
 
         _for_tiles(_first_q_tile(ki, qm, p, causal, q_offset),
                    p.major_q // bq, tile)
@@ -647,24 +740,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref.dtype)
                 dv_ref[0, :, n * d:(n + 1) * d] = dv_acc[n].astype(dv_ref.dtype)
 
+        if with_dq:
+            @pl.when(ki == pl.num_programs(2) - 1)
+            def _finish_dq():
+                # the heads of a last block that lie outside the array were
+                # zeroed and never added to; Pallas drops them
+                dq_ref[0] = (jnp.transpose(dq_acc[...]) * sm_scale).astype(
+                    dq_ref.dtype)
+
     _inside(pl.program_id(1), p, run)
 
 
-def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, p: Plan):
+def _bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, p: Plan):
     b, s_q, _ = q.shape
     s_kv = k.shape[1]
-    q_offset = s_kv - s_q
-    # the row sums of do * o, laid out like lse: [b, head blocks, hq, s_q]
-    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
-                    .reshape(b, s_q, p.h, p.d), axis=-1)
-    delta = jnp.pad(jnp.swapaxes(delta, 1, 2),
-                    ((0, 0), (0, p.head_blocks * p.hq - p.h), (0, 0)))
-    delta = delta.reshape(lse.shape)
-
-    q_spec, kv_spec, row_spec = _specs_walk_q(p, causal, q_offset)
-    dq = pl.pallas_call(
+    q_spec, kv_spec, row_spec = _specs_walk_q(p, causal, s_kv - s_q)
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, p=p, sm_scale=sm_scale,
-                          causal=causal, q_offset=q_offset),
+                          causal=causal, q_offset=s_kv - s_q),
         name="flash_attention_dq",
         grid=(b, p.head_blocks, s_q // p.block_q, s_kv // p.major_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
@@ -680,13 +773,24 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, p: Plan):
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
-    # grid (batch, kv head block, k block, q head blocks of it, major q)
-    q_steps = 1 if p.hk > 1 else p.h // p.h_kv // p.hq
 
+def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, p: Plan):
+    b, s_q, _ = q.shape
+    s_kv = k.shape[1]
+    q_offset = s_kv - s_q
+    # the row sums of do * o, laid out like lse: [b, head blocks, hq, s_q]
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(b, s_q, p.h, p.d), axis=-1)
+    delta = jnp.pad(jnp.swapaxes(delta, 1, 2),
+                    ((0, 0), (0, p.head_blocks * p.hq - p.h), (0, 0)))
+    delta = delta.reshape(lse.shape)
+    one = one_backward(p)
+
+    # grid (batch, kv head block, k block, q head blocks of it, major q)
     def q_index(b_, h2, ki, g_, qm):
         if causal:      # a dead block costs no transfer
             qm = jnp.maximum(qm, _first_live_q(ki, p, q_offset))
-        return b_, qm, h2 * q_steps + g_
+        return b_, qm, h2 * p.q_steps + g_
 
     def row_index(b_, h2, ki, g_, qm):
         b_, qm, hb = q_index(b_, h2, ki, g_, qm)
@@ -696,25 +800,38 @@ def _flash_bwd(q, k, v, o, lse, do, causal, sm_scale, p: Plan):
     k_spec = pl.BlockSpec((1, p.block_k, p.hk * p.d),
                           lambda b_, h2, ki, g_, qm: (b_, ki, h2))
     rows_spec = pl.BlockSpec((1, 1, p.hq, p.major_q), row_index)
-    dk, dv = pl.pallas_call(
+    out_specs = [k_spec, k_spec]
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    scratch = [pltpu.VMEM((p.hk, p.block_k, p.d), k.dtype),
+               pltpu.VMEM((p.hk, p.block_k, p.d), jnp.float32),
+               pltpu.VMEM((p.hk, p.block_k, p.d), jnp.float32)]
+    if one:
+        # the whole query side of the head block, the same block at every k
+        # step: written once a head block
+        out_specs.append(pl.BlockSpec(
+            (1, s_q, p.hq * p.d), lambda b_, h2, ki, g_, qm: (b_, 0, h2)))
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch += [pltpu.VMEM((p.hq * p.d, s_q), jnp.float32),
+                    pltpu.VMEM((p.hk * p.d, p.block_k), k.dtype)]
+    out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, p=p, sm_scale=sm_scale,
-                          causal=causal, q_offset=q_offset),
-        name="flash_attention_dkv",
-        grid=(b, -(-p.h_kv // p.hk), s_kv // p.block_k, q_steps,
+                          causal=causal, q_offset=q_offset, with_dq=one),
+        name="flash_attention_bwd" if one else "flash_attention_dkv",
+        grid=(b, -(-p.h_kv // p.hk), s_kv // p.block_k, p.q_steps,
               s_q // p.major_q),
         in_specs=[qd_spec, k_spec, k_spec, qd_spec, rows_spec, rows_spec],
-        out_specs=[k_spec, k_spec],
-        scratch_shapes=[
-            pltpu.VMEM((p.hk, p.block_k, p.d), k.dtype),
-            pltpu.VMEM((p.hk, p.block_k, p.d), jnp.float32),
-            pltpu.VMEM((p.hk, p.block_k, p.d), jnp.float32),
-        ],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=_params(5, 2),
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        out_shape=out_shape,
+        # dq is summed along the k axis
+        compiler_params=_params(5, 3 if one else 2),
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    if one:
+        dk, dv, dq = out
+        return dq, dk, dv
+    return (_bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, p), *out)
 
 
 # ---------------------------------------------------------------------------

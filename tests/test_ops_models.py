@@ -16,17 +16,21 @@ from ray_tpu.parallel import (FSDP_TP_RULES, MeshSpec, create_mesh,
                               pytree_shardings)
 
 
-def _output_and_grads(attend, q, k, v, live=slice(None)):
+def _output_and_grads(attend, q, k, v, live=slice(None), kernels=None):
     """``attend(q, k, v)`` and the gradients of the tests' loss (its squares
     summed over the ``live`` rows), from ONE compiled run: eager, the
     forward ran once for the output and again inside the gradient, every
-    operation (or interpreted kernel) a compile of its own."""
+    operation (or interpreted kernel) a compile of its own.  ``kernels``, a
+    dict, takes the program's Pallas calls by name (`_pallas_calls`)."""
     def loss(*a):
         out = attend(*a)
         return (out[:, live].astype(jnp.float32) ** 2).sum(), out
 
-    (_, out), grads = jax.jit(jax.value_and_grad(
-        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    program = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)).trace(q, k, v)
+    if kernels is not None:
+        kernels.update(_pallas_calls(program.jaxpr.jaxpr))
+    (_, out), grads = program.lower().compile()(q, k, v)
     return out, grads
 
 
@@ -166,51 +170,91 @@ def test_flash_kernel_interpret_mode_parity(monkeypatch):
                                    atol=5e-4, rtol=5e-4)
 
 
+# `one_backward` held false: the dq and dkv kernels at a plan the one kernel
+# would take
+_TWO_KERNELS = {"one_backward": lambda plan: False}
+
+
 # (batch, s_q, s_kv, heads, kv heads, head size, causal, block_q, block_k,
-#  dtype, the module's constants held otherwise) -> (query heads, kv heads)
-# a grid step
+#  dtype, the module's names held otherwise) -> (query heads, kv heads) a
+# grid step, and the backward the plan takes: the ONE kernel, or the TWO
 _FLASH_CASES = {
     "one-head-a-step-d128": ((1, 256, 256, 1, 1, 128, True, 128, 128,
-                              "float32", {}), (1, 1)),
+                              "float32", {}), (1, 1), "one"),
     "four-heads-a-step": ((1, 256, 256, 4, 4, 64, True, 128, 128,
-                           "float32", {}), (4, 4)),
+                           "float32", {}), (4, 4), "one"),
     # 5 heads under a limit of 4 stand for gpt2-xl's 25 under 8: a block of
     # four, then one whose last three heads lie outside the array
     "odd-heads-5-for-25": ((1, 256, 256, 5, 5, 64, True, 128, 128,
-                            "float32", {"_MAX_HEADS": 4}), (4, 4)),
+                            "float32", {"_MAX_HEADS": 4}), (4, 4), "one"),
     "odd-heads-5-in-pairs": ((1, 256, 256, 5, 5, 64, True, 128, 128,
-                              "float32", {"_MAX_HEADS": 2}), (2, 2)),
+                              "float32", {"_MAX_HEADS": 2}), (2, 2), "one"),
     "odd-heads-all-5-in-a-step": ((1, 256, 256, 5, 5, 64, True, 128, 128,
-                                   "float32", {}), (5, 5)),
+                                   "float32", {}), (5, 5), "one"),
     "gqa-8-2": ((1, 256, 256, 8, 2, 64, True, 128, 128, "float32", {}),
-                (8, 2)),
+                (8, 2), "one"),
     "mqa-16-1-two-q-steps": ((1, 128, 128, 16, 1, 64, True, 128, 128,
-                              "float32", {}), (8, 1)),
+                              "float32", {}), (8, 1), "two"),
     "gqa-4-2-d128": ((1, 256, 256, 4, 2, 128, True, 128, 128, "float32",
-                      {}), (4, 2)),
+                      {}), (4, 2), "one"),
     "non-causal": ((1, 256, 256, 2, 2, 64, False, 128, 128, "float32",
-                    {}), (2, 2)),
+                    {}), (2, 2), "one"),
     # all 3 heads in one block: 192 lanes, the array's whole width
     "non-causal-all-heads-192-lanes": ((1, 128, 256, 3, 3, 64, False, 128,
-                                        128, "float32", {}), (3, 3)),
+                                        128, "float32", {}), (3, 3), "one"),
     "s_q-less-than-s_kv": ((1, 128, 256, 2, 2, 64, True, 128, 128,
-                            "float32", {}), (2, 2)),
+                            "float32", {}), (2, 2), "one"),
     "s_q-more-than-s_kv": ((1, 256, 128, 2, 2, 64, True, 128, 128,
-                            "float32", {}), (2, 2)),
+                            "float32", {}), (2, 2), "one"),
     "s_q-more-odd-heads": ((1, 256, 128, 5, 5, 64, True, 128, 128,
-                            "float32", {"_MAX_HEADS": 4}), (4, 4)),
+                            "float32", {"_MAX_HEADS": 4}), (4, 4), "one"),
     "tile-128x256-batch-2": ((2, 256, 256, 4, 1, 64, True, 128, 256,
-                              "float32", {}), (4, 1)),
+                              "float32", {}), (4, 1), "one"),
     # k, v (dkv: q, do) in major blocks smaller than the sequence: dead
     # blocks' indices are clamped and the state crosses grid steps
     "major-blocks-not-resident": ((1, 512, 512, 2, 2, 64, True, 128, 128,
-                                   "float32", {"_VMEM_BLOCK_BUDGET": 1 << 20}), (2, 2)),
+                                   "float32",
+                                   {"_VMEM_BLOCK_BUDGET": 1 << 20}),
+                                  (2, 2), "two"),
     "major-blocks-s_q-more": ((1, 512, 256, 2, 2, 64, True, 128, 128,
-                               "float32", {"_VMEM_BLOCK_BUDGET": 1 << 20}), (2, 2)),
+                               "float32", {"_VMEM_BLOCK_BUDGET": 1 << 20}),
+                              (2, 2), "two"),
     "bfloat16-gqa": ((1, 256, 256, 4, 2, 64, True, 128, 128, "bfloat16",
-                      {}), (4, 2)),
+                      {}), (4, 2), "one"),
     "bfloat16-odd-heads-d128-scale": ((1, 256, 256, 3, 3, 128, True, 128,
-                                       128, "bfloat16", {}), (3, 3)),
+                                       128, "bfloat16", {}), (3, 3), "one"),
+    # the one kernel at 8 heads a step, and where the whole sequence is one
+    # tile of no hardware multiple (ViT's 197 rows, not causal)
+    "eight-heads-a-step": ((1, 256, 256, 8, 8, 64, True, 128, 128,
+                            "float32", {}), (8, 8), "one"),
+    "non-causal-197-rows": ((2, 197, 197, 4, 4, 64, False, 256, 256,
+                             "float32", {}), (4, 4), "one"),
+    "bfloat16-eight-heads-s_q-less": ((1, 128, 256, 8, 8, 64, True, 128, 128,
+                                       "bfloat16", {}), (8, 8), "one"),
+    # the two kernels where the one would run: they stay for the plans that
+    # do not hold the query side (above), so they are held to the reference
+    # over the same range of shapes
+    "two-kernels-one-head-d128": ((1, 256, 256, 1, 1, 128, True, 128, 128,
+                                   "float32", _TWO_KERNELS), (1, 1), "two"),
+    "two-kernels-eight-heads": ((1, 256, 256, 8, 8, 64, True, 128, 128,
+                                 "float32", _TWO_KERNELS), (8, 8), "two"),
+    "two-kernels-odd-heads-5-for-25": ((1, 256, 256, 5, 5, 64, True, 128, 128,
+                                        "float32",
+                                        {"_MAX_HEADS": 4, **_TWO_KERNELS}),
+                                       (4, 4), "two"),
+    "two-kernels-gqa-8-2": ((1, 256, 256, 8, 2, 64, True, 128, 128,
+                             "float32", _TWO_KERNELS), (8, 2), "two"),
+    "two-kernels-s_q-less": ((1, 128, 256, 2, 2, 64, True, 128, 128,
+                              "float32", _TWO_KERNELS), (2, 2), "two"),
+    "two-kernels-s_q-more-odd-heads": ((1, 256, 128, 5, 5, 64, True, 128, 128,
+                                        "float32",
+                                        {"_MAX_HEADS": 4, **_TWO_KERNELS}),
+                                       (4, 4), "two"),
+    "two-kernels-non-causal-197-rows": ((2, 197, 197, 4, 4, 64, False, 256,
+                                         256, "float32", _TWO_KERNELS),
+                                        (4, 4), "two"),
+    "two-kernels-bfloat16-gqa": ((1, 256, 256, 4, 2, 64, True, 128, 128,
+                                  "bfloat16", _TWO_KERNELS), (4, 2), "two"),
 }
 
 
@@ -222,7 +266,12 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     maps, head sizes 64 and 128 (a scale folded into the operand, and one
     that is not a power of two left on the scores), causal and not, s_q <,
     = and > s_kv, tiles the diagonal crosses beside tiles it does not, and
-    major blocks that are not the whole sequence."""
+    major blocks that are not the whole sequence.  Each case names the
+    backward its plan takes, and the program holds those kernels and no
+    other: the one kernel wherever the whole query side is resident and a
+    kv head block meets its query heads in one step (`one_backward`), the
+    dq and dkv kernels elsewhere and, the predicate held false, over the
+    one kernel's own range of shapes."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     import importlib
 
@@ -230,13 +279,15 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     import jax.numpy as jnp
 
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
-    (b, s_q, s_kv, h, h_kv, d, causal, bq, bk, dtype, held), heads = \
-        _FLASH_CASES[case]
+    (b, s_q, s_kv, h, h_kv, d, causal, bq, bk, dtype, held), heads, \
+        backward = _FLASH_CASES[case]
     for name, value in held.items():
         monkeypatch.setattr(fa, name, value)
     dt = jnp.dtype(dtype)
+    bq, bk = fa.fit_block(bq, s_q), fa.fit_block(bk, s_kv)
     plan = fa.make_plan(h, h_kv, d, s_q, s_kv, dt.itemsize, bq, bk)
     assert (plan.hq, plan.hk) == heads
+    assert fa.one_backward(plan) == (backward == "one")
     assert (plan.major_k < s_kv and plan.major_q < s_q) == (
         "_VMEM_BLOCK_BUDGET" in held)
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
@@ -254,7 +305,10 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     # the three kernels in one program (the forward that is no gradient's:
     # `test_flash_takes_a_whole_short_sequence_as_one_tile` and the parity
     # tests above)
-    out, g_f = _output_and_grads(flash, q, k, v, live)
+    kernels = {}
+    out, g_f = _output_and_grads(flash, q, k, v, live, kernels)
+    assert kernels == {f"flash_attention_{k}": 1 for k in (
+        ("fwd", "bwd") if backward == "one" else ("fwd", "dq", "dkv"))}
     assert out.dtype == dt and out.shape == q.shape
     assert not np.asarray(out[:, :live.start], np.float32).any()
     tol_o, tol_g = (2e-5, 5e-4) if dtype == "float32" else (3e-2, 0.25)
@@ -265,6 +319,50 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
         assert a.dtype == dt
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r),
                                    atol=tol_g, rtol=tol_g)
+
+
+@pytest.mark.parametrize("case", [
+    "four-heads-a-step", "odd-heads-5-for-25", "gqa-8-2",
+    "s_q-less-than-s_kv", "s_q-more-odd-heads", "tile-128x256-batch-2",
+    "non-causal-197-rows"])
+def test_flash_one_backward_is_the_two_kernels_arithmetic(monkeypatch, case):
+    """BOTH backwards on the same inputs, residuals and cotangent (the
+    predicate held each way): the one kernel walks the dkv kernel's tiles
+    and sums dq over the k tiles in the dq kernel's order, so dk and dv are
+    its to the bit and dq to float32 rounding."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    import importlib
+
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    (b, s_q, s_kv, h, h_kv, d, causal, bq, bk, dtype, held), _, backward = \
+        _FLASH_CASES[case]
+    assert dtype == "float32" and backward == "one"
+    for name, value in held.items():
+        monkeypatch.setattr(fa, name, value)
+    bq, bk = fa.fit_block(bq, s_q), fa.fit_block(bk, s_kv)
+    plan = fa.make_plan(h, h_kv, d, s_q, s_kv, 4, bq, bk)
+    keys = jax.random.split(jax.random.PRNGKey(13), 4)
+    q, do = (jax.random.normal(key, (b, s_q, h * d), jnp.float32)
+             for key in keys[:2])
+    k, v = (jax.random.normal(key, (b, s_kv, h_kv * d), jnp.float32)
+            for key in keys[2:])
+    scale = d ** -0.5
+    o, lse = fa._flash_fwd(q, k, v, causal, scale, plan)
+
+    def backward_of(one):
+        monkeypatch.setattr(fa, "one_backward", lambda p: one)
+        program = jax.jit(lambda *a: fa._flash_bwd(
+            *a, causal, scale, plan)).trace(q, k, v, o, lse, do)
+        assert sorted(_pallas_calls(program.jaxpr.jaxpr)) == (
+            ["flash_attention_bwd"] if one
+            else ["flash_attention_dkv", "flash_attention_dq"])
+        return program.lower().compile()(q, k, v, o, lse, do)
+
+    for name, a, r in zip(("dq", "dk", "dv"), backward_of(True),
+                          backward_of(False)):
+        assert float(jnp.abs(r).max()) > 0.1, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5,
+                                   atol=1e-5, err_msg=name)
 
 
 def _pallas_calls(jaxpr, times=1, found=None):
@@ -327,7 +425,7 @@ def test_full_remat_runs_the_flash_forward_once_a_layer(monkeypatch, case):
             return
         programs = {remat: traced(remat) for remat in (True, False, "dots")}
         assert ("shard_map" in str(programs[True].jaxpr)) == (case == "fsdp-4")
-        want = {f"flash_attention_{k}": layers for k in ("fwd", "dq", "dkv")}
+        want = {f"flash_attention_{k}": layers for k in ("fwd", "bwd")}
         for remat, program in programs.items():
             assert _pallas_calls(program.jaxpr.jaxpr) == want, remat
         assert _pallas_calls(parent().jaxpr.jaxpr) == {
@@ -457,6 +555,43 @@ def test_flash_plan_follows_the_shape(shape, want):
         p.hk == 1 and (h // h_kv) % p.hq == 0)
     assert fa._block_bytes(p.hq, p.hk, d, 256, 256, p.major_k, p.major_q,
                            2) <= fa._VMEM_BLOCK_BUDGET
+
+
+@pytest.mark.parametrize("shape,held,want", [
+    # (heads, kv heads, head size, s_q, s_kv), the module's names held
+    # otherwise -> the one kernel?  `tests/test_chip_compile.py`'s shapes:
+    ((16, 16, 64, 1024, 1024), {}, True),       # gpt2-medium, both batches
+    ((25, 25, 64, 1024, 1024), {}, True),       # gpt2-xl a chip
+    ((12, 12, 64, 4096, 4096), {}, True),       # 4 heads a step, 20.1 MB
+    ((32, 8, 64, 2048, 2048), {}, True),
+    ((16, 4, 128, 2048, 2048), {}, True),
+    ((12, 12, 64, 197, 197), {}, True),         # ViT: one tile
+    # the dq and dkv kernels stay where a kv head meets its query heads in
+    # two grid steps, where the query side is not resident, and where the
+    # accumulator would not fit (14.4 MB at gpt2-medium's plan)
+    ((16, 1, 64, 1024, 1024), {}, False),
+    ((12, 12, 64, 65536, 65536), {}, False),
+    ((16, 16, 64, 1024, 1024), {"_VMEM_LIMIT": 28 << 20}, False),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else None)
+def test_flash_one_backward_follows_the_plan(monkeypatch, shape, held, want):
+    """Which backward a call takes is a function of its plan alone
+    (`one_backward`): the one kernel where the whole query side is resident,
+    a kv head block meets all its query heads in one grid step, and the
+    float32 accumulator of dq fits beside the dkv kernel's blocks."""
+    import importlib
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    for name, value in held.items():
+        monkeypatch.setattr(fa, name, value)
+    h, h_kv, d, s_q, s_kv = shape
+    p = fa.make_plan(h, h_kv, d, s_q, s_kv, 2,
+                     fa.fit_block(fa.DEFAULT_BLOCK_Q, s_q),
+                     fa.fit_block(fa.DEFAULT_BLOCK_K, s_kv))
+    assert fa.one_backward(p) == want
+    fits = fa._block_bytes(p.hq, p.hk, d, p.block_q, p.block_k, p.major_k,
+                           p.major_q, 2, with_dq=True) <= fa._VMEM_LIMIT // 2
+    # each case that keeps the two kernels keeps them for ONE reason
+    assert (p.major_q < s_q, p.q_steps > 1, not fits).count(True) == (
+        0 if want else 1)
 
 
 def test_flash_kernel_runs_per_shard_under_a_mesh(monkeypatch):
