@@ -8,8 +8,10 @@ Pallas attention (`ray_tpu.ops`).
 """
 
 from .generate import (  # noqa: F401
+    CacheTraffic,
     cache_gather_slot,
     cache_insert_slot,
+    chunk_room,
     decode_step,
     decode_step_slots,
     generate,
@@ -21,6 +23,7 @@ from .generate import (  # noqa: F401
     prefill_chunked,
     prefill_lanes,
     prefill_lanes_jit,
+    prefix_holds,
 )
 from .transformer import (  # noqa: F401
     TransformerConfig,

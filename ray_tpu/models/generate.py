@@ -128,7 +128,7 @@ state in its layer of the donated array in place: where the states are
 whole 128 x 128 tiles and the program is lowered for a TPU, in ONE kernel
 call a layer over the stacked array (`delta_rule.step_in_place`: no cut of
 the layer before it, no placement after it; a live slot's states read once
-and written once, a standing slot's not at all; `state_fetched` counts it).
+and written once, a standing slot's not at all; `CacheTraffic` counts it).
 
 A SEVENTH KIND OF STATE STANDS BESIDE ROWS IN ONE LAYER: an ``"ssm+full"``
 layer (`transformer.ssm_operator`, `ops/ssd.py`) runs a state-space mixer AND
@@ -143,7 +143,7 @@ inputs advance by a row's VALID tokens only, and `_check_state_rewind`
 refuses a chunk window set back although the rows alone could run it again.
 The fused step advances the state where it lies in one kernel call a layer
 (`ssd.step_in_place`) wherever `delta_rule.step_in_place` would its own, and
-`state_fetched` counts both.  Such a layer may not share a model with plain
+`CacheTraffic` counts both.  Such a layer may not share a model with plain
 ``"full"`` layers (their rows would share an array under two counters:
 `_check_decodable`).
 
@@ -177,8 +177,8 @@ meet the cached latents directly, and wherever the program is lowered for a
 TPU and the shapes are whole tiles they read them through ONE kernel call a
 layer that walks the blocks a lane's chunk or a slot's one query may see,
 where they lie (`attend_cache`, `_key_block`: a chunk, the lanes program and
-the decode step alike; `rows_fetched` and `chunk_rows_fetched` count what
-that moves).  A model with no-drop routed experts
+the decode step alike; `CacheTraffic.step` and `.chunk` count what that
+moves).  A model with no-drop routed experts
 returns, beside its logits, what its expert layers routed (the ``load``
 of `_prefill_chunk` and `_decode_step_slots`); the serve engine counts
 the decode steps'.
@@ -191,7 +191,7 @@ users bring via vLLM/TGI — sized to the in-tree transformer family.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -372,179 +372,6 @@ def cache_bytes(cache: KVCache) -> Dict[str, int]:
     return out
 
 
-def column_write_counts(cache: KVCache) -> Tuple[int, int]:
-    """A decode step over this slot cache (one fed token a slot) → (the
-    columns it writes: a slot a layer of every array that holds positions,
-    summary rows among them; the device calls that write them on this
-    process's backend, `ops.cache_write.device_calls`): host counts from
-    shapes."""
-    arrays = [a.shape for name, a in cache_arrays(cache).items()
-              if _state_kind(name) not in _NO_POSITIONS]
-    return (sum(shape[0] * shape[1] for shape in arrays),
-            sum(shape[0] * device_calls(shape) for shape in arrays))
-
-
-def rows_fetched(cache: KVCache, cfg: TransformerConfig):
-    """A decode step over this slot cache → ``count(positions)``: the cache
-    rows its attention MOVES from memory for live slots that stand at
-    ``positions`` (a key and the value beside it are one row, as the serve
-    engine's ``rows_read`` counts them; an indexer's keys are counted by
-    neither).  Dense dots under a mask move every row of every slot's arrays,
-    whatever the positions and live or not; where a kernel engages on this
-    process's backend a live slot's blocks alone, the host's count of the
-    kernel's work list: `ops/cache_attention.py`'s for a summary layer's step
-    (`cache_attention.fetched_blocks`), `ops/latent_attention.py`
-    `attend_cache`'s for a latent layer's (`_latent_tiles`: the tiles up to
-    the slot's position; under an indexer's choice the kernel stops at the
-    last CHOSEN row, which the host does not know: the count is then the
-    most it moves).  Host counts from shapes."""
-    arrays = cache_arrays(cache)
-    names = _latent_names(arrays) if cfg.attention == "mla" else tuple(
-        _kv_names(kind)[0] for kind in ATTENTION_KINDS if kind in cfg.kinds) \
-        + (_SUM_NAMES[:1] if "eva" in cfg.kinds else ())
-    rows = {name: arrays[name].shape[-1] for name in names}
-    if _CONV_STATE in arrays:       # a state's rows are its taps
-        rows[_CONV_STATE] = arrays[_CONV_STATE].shape[-2]
-    blocked = ()
-    if "eva" in cfg.kinds:
-        kn = _kv_names("eva")[0]
-        if cache_attention.engages(*_chunk_sets(cfg, arrays, "eva", 1, 1)):
-            blocked = (rows.pop(kn), rows.pop(_SUM_NAMES[0]))
-    # a latent array the kernel reads: (its layers, the kernel's tile, the
-    # kind whose rows it holds)
-    tiled = [(arrays[name].shape[0], tile, kind, rows.pop(name))
-             for kind, name, tile in _latent_tiles(cfg, arrays, 1) if tile]
-    dense = sum(arrays[name].shape[0] * arrays[name].shape[1] * n
-                for name, n in rows.items())
-    if tiled:
-        return lambda positions: dense + sum(
-            layers * mla.fetched_rows(
-                _latent_seen(cfg, kind, pos, 1, size), tile)
-            for layers, tile, kind, size in tiled for pos in positions)
-    if not blocked:
-        return lambda positions: dense
-    layers, (ring, sums) = arrays[kn].shape[0], blocked
-    window, chunk = cfg.sliding_window, cfg.summary_chunk
-
-    def count(positions) -> int:
-        blocks = 0
-        for pos in positions:
-            first = pos // window * window
-            blocks += cache_attention.fetched_blocks(
-                first % ring, pos - first + 1, ring) \
-                + cache_attention.fetched_blocks(0, first // chunk, sums)
-        return dense + layers * blocks * cache_attention.BLOCK
-
-    return count
-
-
-def _latent_names(arrays: Arrays) -> Tuple[str, ...]:
-    """A latent cache's arrays of latents: the full layers' and, where the
-    model has window layers, their ring."""
-    return tuple(n for n in map(_latent_name, ("full", "window"))
-                 if n in arrays)
-
-
-def _latent_tiles(cfg: TransformerConfig, arrays: Arrays, c: int):
-    """(kind, its latents' array, the cached rows a grid step of
-    `ops/latent_attention.py` `attend_cache` takes of it) for each array of
-    a latent cache (none for any other), where a program that feeds ``c``
-    tokens a row reads the kind's layers through the kernel ON THIS
-    PROCESS'S BACKEND (`mla.engages`, what `_key_block` and
-    `mla.on_the_chip` decide where the program is lowered); the tile is 0
-    where the layers read through XLA's forms: every row of the array
-    then."""
-    out = []
-    for kind in ("full", "window") if cfg.attention == "mla" else ():
-        name, ck = _latent_name(kind), cfg.latent_of(kind)
-        if name in arrays:
-            kv = arrays[name]
-            q_shape = (kv.shape[1], c, ck.n_heads, kv.shape[-2])
-            block = _key_block(q_shape, ck.kv_lora_rank, kv.shape[-1])
-            out.append((kind, name, mla.row_tile(q_shape, block)
-                        if mla.engages(q_shape, ck.kv_lora_rank, block)
-                        else 0))
-    return out
-
-
-def _latent_seen(cfg: TransformerConfig, kind: str, pos: int, n: int,
-                 rows: int) -> int:
-    """One past the last column of a latent array of ``rows`` rows that
-    some query of the ``n`` tokens fed from ``pos`` may see (what
-    `sparse_index.rows_seen` reads off their mask): the positions up to the
-    last one fed, or, of a window layer's ring, the column of the last one
-    fed unless the window wraps the seam."""
-    top = pos + n - 1
-    if kind != "window":
-        return top + 1
-    first = max(0, pos - cfg.sliding_window + 1)
-    return top % rows + 1 if first // rows == top // rows else rows
-
-
-def chunk_rows_fetched(cache: KVCache, cfg: TransformerConfig, chunk: int):
-    """A chunk program of ``chunk`` rows a lane over a cache of these arrays
-    → ``count(pos, n_valid) -> (fetched, read)`` for ONE lane that feeds
-    ``n_valid`` real tokens from position ``pos``: the cache rows the
-    lane's attention MOVES from memory, and those of them that SOME REAL
-    query of the chunk sees (a key and the value beside it one row, summed
-    over the layers, as `rows_fetched` counts a step's).  Dense dots under a
-    mask move every row of the lane's arrays a layer; where
-    `ops/cache_attention.py`'s chunk kernel engages on this process's
-    backend, a layer kind by its shapes (`_chunk_sets`), the 128-row blocks
-    in which some query of the WHOLE chunk, padded rows too, has a visible
-    row: the host's count of the kernel's work list from positions.  A model
-    of latent layers likewise through `ops/latent_attention.py`
-    `attend_cache` (`_latent_tiles`): the tiles up to the padded chunk's last
-    row where it engages, every row of the lane's layer where it does not;
-    under an indexer's choice both sums are of the rows the queries MAY see,
-    the most a choice reaches."""
-    arrays = cache_arrays(cache)
-    if cfg.attention == "mla":
-        latent = [(kind, arrays[name].shape[0], arrays[name].shape[-1], tile)
-                  for kind, name, tile in _latent_tiles(cfg, arrays, chunk)]
-        return lambda pos, n_valid: (
-            sum(layers * (mla.fetched_rows(
-                _latent_seen(cfg, kind, pos, chunk, rows), tile)
-                if tile else rows) for kind, layers, rows, tile in latent),
-            sum(layers * (pos + n_valid - (
-                max(0, pos - cfg.sliding_window + 1)
-                if kind == "window" else 0))
-                for kind, layers, _, _ in latent))
-    window, pooled = cfg.sliding_window, cfg.summary_chunk
-    kinds = []      # (kind, its layers, rows of each set, kernel engages)
-    for kind in ATTENTION_KINDS:
-        if kind in cfg.kinds and kind not in SPARSE_KINDS:
-            q_shape, sets = _chunk_sets(cfg, arrays, kind, 1, chunk)
-            kinds.append((kind, sets[0][0].shape[0],
-                          [k.shape[-1] for k, _, _ in sets],
-                          cache_attention.engages(
-                              q_shape, sets, kind in cfg.sink_kinds)))
-
-    def seen(kind, pos, n):
-        """(first row, rows) of each set that a query of ``pos .. pos + n
-        - 1`` sees: a range, which wraps where the set is a ring."""
-        if kind in _ROW_KINDS:
-            return [(0, pos + n)]
-        if kind == "window":
-            first = max(0, pos - window + 1)
-            return [(first, pos + n - first)]
-        first = pos // window * window
-        return [(first, pos + n - first),
-                (0, (pos + n - 1) // window * window // pooled)]
-
-    def count(pos: int, n_valid: int) -> Tuple[int, int]:
-        fetched = read = 0
-        for kind, layers, rows, engaged in kinds:
-            read += layers * sum(n for _, n in seen(kind, pos, n_valid))
-            fetched += layers * (cache_attention.BLOCK * sum(
-                cache_attention.fetched_blocks(first % size, n, size)
-                for (first, n), size in zip(seen(kind, pos, chunk), rows))
-                if engaged else sum(rows))
-        return fetched, read
-
-    return count
-
-
 def _chunk_sets(cfg: TransformerConfig, arrays: Arrays, kind: str, b: int,
                 c: int):
     """What `ops/cache_attention.py` `kernel_shape` is asked about a layer
@@ -558,32 +385,325 @@ def _chunk_sets(cfg: TransformerConfig, arrays: Arrays, kind: str, b: int,
     return (b, c, hk, cfg.n_heads // hk, cfg.head_dim), sets
 
 
-def state_fetched(cache: KVCache, cfg: TransformerConfig):
-    """A decode step over this slot cache → ``count(live slots)``: the bytes
-    of delta and state-space state its program MOVES (`position_bytes`'
-    ``delta`` / ``ssm`` a slot a layer that carries one, the convolutions'
-    inputs counted at the states' passes, as the serve engine's
-    ``state_bytes_moved`` counts them): where the rule's kernel
-    (`ops/delta_rule.py`, `ops/ssd.py`) engages on this process's backend a
-    live slot's states once read and once written and a standing slot's not
-    at all; in XLA's form every slot's, live or not, read twice (as it
-    lowered the delta rule: the products, then the decay and the write) and
-    written once.  Host counts from shapes; 0 for a model without such
-    layers."""
-    arrays = cache_arrays(cache)
-    kernel = {_DELTA_STATE: delta_rule.engages,
-              _SSM_STATE: functools.partial(ssd.engages,
-                                            groups=cfg.ssm_groups)}
-    in_place = standing = 0
-    for name, engages in kernel.items():
-        if name in arrays:
-            layers, slots = arrays[name].shape[:2]
-            per = layers * position_bytes(cfg)[_state_kind(name)]
-            if engages(1, arrays[name]):
-                in_place += 2 * per
-            else:
-                standing += 3 * slots * per
-    return lambda live: live * in_place + standing
+def _blocks_moved(cfg: TransformerConfig, arrays: Arrays, kind: str, c: int,
+                  size: int):
+    """Where a program that feeds ``c`` tokens a row attends the MHA/GQA
+    layers of attention kind ``kind`` through `ops/cache_attention.py`'s
+    kernel ON THIS PROCESS'S BACKEND (what `_attend_cached` decides by the
+    shapes where the program is lowered: a summary layer's step and chunks,
+    a full or a window layer's chunks alone) → ``moved(first, n)``: the rows
+    it moves of a set of ``size`` rows to attend the ``n`` from ``first`` on,
+    the whole blocks that hold one, the host's count of the kernel's work
+    list; None where dense dots read the set."""
+    if (c > 1 or kind == "eva") and cache_attention.engages(
+            *_chunk_sets(cfg, arrays, kind, 1, c), kind in cfg.sink_kinds):
+        return lambda first, n: cache_attention.BLOCK \
+            * cache_attention.fetched_blocks(first % size, n, size)
+    return None
+
+
+def _tiles_moved(cfg: TransformerConfig, kv, kind: str, c: int):
+    """Where a program that feeds ``c`` tokens a row reads the latents ``kv``
+    of the layers of kind ``kind`` through `ops/latent_attention.py`
+    `attend_cache` ON THIS PROCESS'S BACKEND (`mla.engages`, what
+    `_key_block` and `mla.on_the_chip` decide where the program is lowered) →
+    ``moved(first, n)``: the rows it moves to attend the ``n`` from ``first``
+    on, the kernel's tiles up to the last column some query sees (what
+    `sparse_index.rows_seen` reads off the program's mask: the position, or
+    `_ring_end`); None where XLA's forms read every row."""
+    ck, size = cfg.latent_of(kind), kv.shape[-1]
+    q_shape = (kv.shape[1], c, ck.n_heads, kv.shape[-2])
+    block = _key_block(q_shape, ck.kv_lora_rank, size)
+    if not mla.engages(q_shape, ck.kv_lora_rank, block):
+        return None
+    tile = mla.row_tile(q_shape, block)
+    return lambda first, n: mla.fetched_rows(
+        _ring_end(first, n, size) if kind == "window" else first + n, tile)
+
+
+def _ring_end(first: int, n: int, size: int) -> int:
+    """One past the last column of a ring of ``size`` rows that holds one of
+    the ``n`` positions from ``first`` on: the last one's, unless the range
+    lies astride the seam: the ring's end then."""
+    last = first + n - 1
+    return last % size + 1 if first // size == last // size else size
+
+
+class _RowSet(NamedTuple):
+    """ONE set of rows a layer kind's queries attend: an array of keys with
+    the values beside it, of latents, of an indexer's keys, a state's taps."""
+    kind: str       # `position_bytes`' state kind of a row of it
+    sees: str       # which of its rows a query sees (`CacheTraffic._seen`)
+    layers: int
+    size: int       # rows a slot a layer
+    most: int       # ... of which a query attends no more than (a choice)
+    # rows a kernel moves of it for what a step's query / a chunk's queries
+    # see (`_blocks_moved`, `_tiles_moved`); None: dense dots, every row
+    step: Optional[Callable[[int, int], int]]
+    chunk: Optional[Callable[[int, int], int]]
+
+
+#: layer kind → the sets of rows it attends, each (state kind of the row,
+#: what a query at position ``t`` sees of the set, `CacheTraffic._seen`):
+#: every position up to its own (``context``; under an indexer the
+#: ``index_topk`` chosen of them, but ONE index key of each), the last
+#: ``sliding_window`` (``window``), its own block of ``sliding_window``
+#: (``block``) and a summary a ``summary_chunk`` of every block before
+#: (``pooled``), a state's taps whatever the position (``taps``).  A delta
+#: or a state-space STATE is no rows: `CacheTraffic.step`'s ``state_*``.
+_ATTENDS = {
+    **dict.fromkeys(_ROW_KINDS, (("full", "context"),)),
+    "index": (("full", "context"), ("index", "context")),
+    "window": (("ring", "window"),),
+    "eva": (("ring", "block"), ("summary", "pooled")),
+    "conv": (("state", "taps"),),
+    "kda": (),
+}
+
+
+class StepSums(NamedTuple):
+    """What ONE fused decode step reads, moves and writes of the cache
+    (`CacheTraffic.step`)."""
+    # the cache rows the live slots attend, each at its position BEFORE the
+    # step, its own new row included, beside what they would attend were
+    # every layer a full one
+    rows_read: int
+    rows_if_full: int
+    # the same in bytes: a row at what its layer's kind holds a position (an
+    # indexer's keys among them), beside every layer's rows at the widest of
+    # the model's (a model of one kind of row reads 100 %)
+    bytes_read: int
+    bytes_if_uniform: int
+    # of both, the part that is summary rows
+    summary_rows_read: int
+    summary_bytes_read: int
+    # the index keys an indexer scored (NOT among ``rows_read``), their bytes
+    index_rows_read: int
+    index_bytes_read: int
+    # of ``bytes_read``, a LATENT model's ring rows
+    ring_latent_bytes_read: int
+    # what a delta or a state-space state costs: the (slot, layer) states
+    # advanced, their bytes read AND written (not among ``bytes_read``: no
+    # position is attended; a layer that holds rows beside its state has
+    # those above), and what the rule's form on this backend MOVES for that
+    state_rows: int
+    state_bytes_moved: int
+    state_bytes_fetched: int
+    # the rows the attention MOVES from memory to attend ``rows_read`` of
+    # them (an indexer's keys are counted by neither)
+    rows_fetched: int
+    # what a step WRITES, whatever its positions: a column a slot, live or
+    # not, a layer of every array that holds positions, and the device calls
+    # that write them (one kernel call an array a layer, or a slice a column)
+    column_writes: int
+    column_write_calls: int
+
+
+class CacheTraffic:
+    """What the programs over ONE slot cache read, move and write of it, by
+    state kind: host counts from shapes and positions (``cache`` may be the
+    arrays or, `jax.eval_shape`, their shapes), made where the cache's layout
+    is known so that the serve engine, whose ``cache:rows`` and
+    ``engine:lanes`` spans carry them, knows none of it.  ``chunk``: the rows
+    a chunk program feeds a lane.
+
+    A table with a row a SET of rows a layer kind attends (`_ATTENDS`,
+    `_RowSet`): its layers, which of its rows a query sees (`_seen`: ONE rule
+    for a step's rows, a chunk's rows and what a kernel moves for either),
+    `position_bytes`' width of a row, and the kernel that reads it ON THIS
+    PROCESS'S BACKEND, if one does: a program's choice by shape and platform,
+    asked here of the same functions."""
+
+    #: what `step` sums, in its order
+    STEP_SUMS = StepSums._fields
+
+    def __init__(self, cache: KVCache, cfg: TransformerConfig, chunk: int):
+        arrays, per = cache_arrays(cache), position_bytes(cfg)
+        latent = cfg.attention == "mla"
+        self._window, self._pooled = cfg.sliding_window, cfg.summary_chunk
+        self._chunk, self._layers = chunk, cfg.n_layers
+        self._slots = next(iter(arrays.values())).shape[1]
+        self._widest = max(per.get(k, 0) for k in ("full", "ring", "summary"))
+        self._latent = latent
+        self._sets: Dict[str, _RowSet] = {}     # by the array that holds it
+        self._row_bytes: Dict[str, int] = {}    # by state kind
+        for kind in dict.fromkeys(cfg.kinds):
+            rows_in = _latent_name(kind) if latent else _kv_names(kind)[0]
+            for state, sees in _ATTENDS[kind]:
+                rows = state in ("full", "ring")
+                name = rows_in if rows else {
+                    "summary": _SUM_NAMES[0], "state": _CONV_STATE,
+                    "index": _INDEX_ARRAY}[state]
+                if name in self._sets:      # (an array the row kinds share)
+                    continue
+                a = arrays[name]
+                size = a.shape[-2 if sees == "taps" else -1]
+                if latent and rows:     # a kind's own latent sizes
+                    moved = [_tiles_moved(
+                        cfg, a, "window" if state == "ring" else "full", c)
+                        for c in (1, chunk)]
+                elif rows or state == "summary":
+                    moved = [_blocks_moved(cfg, arrays, kind, c, size)
+                             for c in (1, chunk)]
+                else:
+                    moved = [None, None]
+                self._sets[name] = _RowSet(
+                    state, sees, a.shape[0], size,
+                    cfg.index_topk if state == "full" and cfg.index_topk
+                    else size, *moved)
+                # (a state's bytes are its taps' together)
+                self._row_bytes[state] = per[state] // (
+                    size if sees == "taps" else 1)
+        # a matrix state a head and its convolutions' inputs, read whole and
+        # written whole whatever the position: (its layers, bytes a slot a
+        # layer).  Where the rule's kernel (`ops/delta_rule.py`, `ops/ssd.py`)
+        # engages on this process's backend a live slot's states are moved
+        # once read and once written and a standing slot's not at all; in
+        # XLA's form every slot's, live or not, is read twice (as it lowered
+        # the delta rule: the products, then the decay and the write) and
+        # written once
+        self._state_layers = self._state_bytes = 0
+        self._in_place = self._standing = 0
+        for name, engages in ((_DELTA_STATE, delta_rule.engages), (
+                _SSM_STATE, functools.partial(ssd.engages,
+                                              groups=cfg.ssm_groups))):
+            if name in arrays:
+                layers = arrays[name].shape[0]
+                held = layers * per[_state_kind(name)]
+                self._state_layers += layers
+                self._state_bytes += held
+                if engages(1, arrays[name]):
+                    self._in_place += 2 * held
+                else:
+                    self._standing += 3 * self._slots * held
+        # one fed token a slot: the columns a step writes, and the device
+        # calls that write them on this process's backend
+        shapes = [a.shape for name, a in arrays.items()
+                  if _state_kind(name) not in _NO_POSITIONS]
+        self._writes = (
+            sum(shape[0] * shape[1] for shape in shapes),
+            sum(shape[0] * device_calls(shape) for shape in shapes))
+
+    def _seen(self, s: _RowSet, positions, n: int):
+        """(first rows, row counts), one of each a position: the range of
+        the set ``s`` that SOME query of the ``n`` tokens fed from that
+        position sees (it wraps where the set is a ring)."""
+        window = self._window
+        if s.sees == "window":
+            firsts = [max(0, pos - window + 1) for pos in positions]
+        elif s.sees == "block":
+            firsts = [pos // window * window for pos in positions]
+        else:
+            return [0] * len(positions), [
+                pos + n if s.sees == "context" else s.size if s.sees == "taps"
+                else (pos + n - 1) // window * window // self._pooled
+                for pos in positions]
+        return firsts, [pos + n - first
+                        for pos, first in zip(positions, firsts)]
+
+    def step(self, positions) -> StepSums:
+        """ONE fused decode step whose live slots stand at ``positions``."""
+        live = len(positions)
+        depth = sum(positions) + live
+        read, fetched = {}, 0     # (rows attended by state kind: a set each)
+        for s in self._sets.values():
+            firsts, counts = self._seen(s, positions, 1)
+            read[s.kind] = s.layers * (
+                sum(counts) if s.most == s.size
+                else sum(min(n, s.most) for n in counts))
+            if s.kind != "index":
+                fetched += s.layers * (
+                    self._slots * s.size if s.step is None
+                    else sum(map(s.step, firsts, counts)))
+        nbytes = {kind: n * self._row_bytes[kind] for kind, n in read.items()}
+        scored = read.pop("index", 0)
+        return StepSums(
+            sum(read.values()), self._layers * depth,
+            sum(nbytes.values()), self._layers * depth * self._widest,
+            read.get("summary", 0), nbytes.get("summary", 0),
+            scored, nbytes.get("index", 0),
+            nbytes.get("ring", 0) if self._latent else 0,
+            live * self._state_layers, 2 * live * self._state_bytes,
+            live * self._in_place + self._standing, fetched, *self._writes)
+
+    def chunk(self, pos: int, n_valid: int) -> Tuple[int, int]:
+        """ONE lane of a chunk program that feeds ``n_valid`` real tokens
+        from position ``pos`` → (the cache rows its attention MOVES from
+        memory: every row of the lane's arrays a layer under dense dots,
+        what the kernel's work list holds for the WHOLE chunk, padded rows
+        too, where one engages; those of them that some REAL query of the
+        chunk sees), a key and the value beside it one row, summed over the
+        layers as `step` counts them.  Under an indexer's choice both are of
+        the rows the queries MAY see, the most a choice reaches."""
+        fetched = read = 0
+        for s in self._sets.values():
+            if s.kind in ("full", "ring", "summary"):
+                read += s.layers * self._seen(s, (pos,), n_valid)[1][0]
+                (first,), (n,) = self._seen(s, (pos,), self._chunk)
+                fetched += s.layers * (
+                    s.size if s.chunk is None else s.chunk(first, n))
+        return fetched, read
+
+
+def chunk_room(cfg: TransformerConfig, max_len: int) -> Tuple[int, int]:
+    """(the positions a chunk program's window may cover: the cache's, and
+    no more than a learned position table; the widest chunk a program may
+    feed: no more than the room a ring leaves beside its window)."""
+    capacity = min(max_len, cfg.max_seq_len) \
+        if cfg.pos_emb == "learned" else max_len
+    return capacity, min(capacity, cfg.window_chunk) \
+        if {"window", "eva"} & set(cfg.kinds) else capacity
+
+
+def prefix_holds(cfg: TransformerConfig, donor_pos: Optional[int],
+                 depth: int, n: int, chunk: int, capacity: int) -> bool:
+    """Whether a slot whose session stands at ``donor_pos`` (None: nobody
+    knows) still holds what a session of ``n`` prompt tokens, seeded with the
+    slot's first ``depth`` positions and fed ``chunk`` tokens a program over
+    ``capacity`` positions, attends.
+
+    A FULL layer's rows below ``depth`` are never rewritten: any donor
+    serves.  A WINDOW layer's ring has moved on with the donor: whatever was
+    written there (ahead of its position included) left the positions ``>=
+    pos - sliding_window`` intact.  So a donor whose whole context still fits
+    its window serves any prefix, and one that stands at the prefix (it has
+    decoded no more than one token past it) serves a session whose chunk
+    windows start at ``depth`` or later: the seeded session's first query
+    needs the positions from ``depth - sliding_window + 1`` on, and a window
+    set back at the capacity edge (`chunk_window`) would need earlier ones.
+    A STATE without positions (a conv layer's, a KDA layer's, a state-space
+    mixer's whatever rows its layer holds beside it) is the donor's at its
+    LAST token and nothing of it can be masked: such a donor serves a prefix
+    only while it STANDS at it (it has decoded nothing past it), and none of
+    the seeded session's chunk windows, which start at ``depth`` and not at a
+    multiple of the chunk, may be set back (a state cannot run tokens twice).
+    A SUMMARY layer has both: the summaries of the chunks below ``depth`` are
+    never rewritten (a donor writes the row of the chunk its newest token
+    lies in and no earlier one), and the rows from ``depth``'s chunk on are
+    the seeded session's own to write before any of its queries sees them;
+    its ring holds the rows of the prefix's LAST block (what the seeded
+    session's first queries attend exactly, and what its first summary is
+    pooled from) only while the donor still stands in that block, and a
+    prefix that ends on a block's edge needs no ring row at all; either way
+    the first chunk window must not be set back before ``depth``: it would
+    need the block before.  Any other donor is refused, and the prompt
+    prefills from its start."""
+    kinds = set(cfg.kinds)
+    window = cfg.sliding_window if "window" in kinds else 0
+    stateful = kinds & {"conv", "kda", *SSM_KINDS}
+    if not window and not stateful and "eva" not in kinds:
+        return True
+    if donor_pos is None:
+        return False
+    if "eva" in kinds:
+        block = cfg.sliding_window
+        return depth + chunk <= capacity and (
+            depth % block == 0 or donor_pos // block == depth // block)
+    if stateful and (donor_pos != depth or
+                     depth + -(-(n - depth) // chunk) * chunk > capacity):
+        return False
+    return not window or donor_pos <= window or (
+        donor_pos <= depth + 1 and depth + chunk <= capacity)
 
 
 def _state_kind(name: str) -> str:

@@ -228,7 +228,7 @@ class _Step(NamedTuple):
     batch: List[Tuple[_EngineSession, int]]   # its live sessions, each
     #                                           with the slot it held THEN
     out: Any          # [slots (+3)] int32 on the device: tokens (+ routing)
-    rows: Tuple[int, ...]     # `_rows_of` its batch .. `_WRITE_SUMS`
+    rows: Tuple[int, ...]     # `CacheTraffic.step` of its batch
     # when the chip started on it, where the host can tell: a
     # ``perf_counter`` reading (nothing was queued before it), `_BEHIND`
     # (queued right behind the step before it: when that one's read
@@ -265,13 +265,6 @@ class _LoopLock:
         self.eng._lock.release()
 
 
-def _ring_rows(cfg, batch) -> int:
-    """Ring rows the live slots of ``batch`` attend a step, summed over the
-    model's window layers: ``min(pos + 1, window)`` a slot a layer."""
-    return cfg.kinds.count("window") * sum(
-        min(s.pos + 1, cfg.sliding_window) for s in batch)
-
-
 class ContinuousBatchingEngine:
     """Replica-resident continuous-batching decode loop.
 
@@ -297,7 +290,8 @@ class ContinuousBatchingEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models import (cache_gather_slot, cache_insert_slot,
+        from ..models import (CacheTraffic, cache_gather_slot,
+                              cache_insert_slot, chunk_room,
                               prefill_chunk_jit, prefill_lanes_jit)
         from ..models.generate import (_decode_step_slots, cache_arrays,
                                        cache_bytes, greedy_tokens)
@@ -389,15 +383,10 @@ class ContinuousBatchingEngine:
         # name in the profiler, the compile ledger and a trace
         self._chunk_lanes = self._prof.wrap(
             "prefill_chunk", self._counting_copies(prefill_lanes_jit, 2))
-        # the positions a chunk program's window may cover: the cache's,
-        # and no more than a learned position table; the ONE chunk width
+        # the positions a chunk program's window may cover and the widest
+        # chunk the cache's state kinds leave room for; the ONE chunk width
         # follows, and `ecfg` holds it resolved
-        self._capacity = min(max_len, cfg.max_seq_len) \
-            if cfg.pos_emb == "learned" else max_len
-        # ... and no wider than the room a window layer's ring leaves
-        # beside its window
-        room = min(self._capacity, cfg.window_chunk) \
-            if {"window", "eva"} & set(cfg.kinds) else self._capacity
+        self._capacity, room = chunk_room(cfg, max_len)
         self.ecfg = dataclasses.replace(
             engine_cfg, prefill_chunk_tokens=prefill_chunk_width(
                 engine_cfg.prefill_chunk_tokens, params, room))
@@ -410,28 +399,6 @@ class ContinuousBatchingEngine:
         self._pool: Any = None
         self._lane_sess: List[Optional[_EngineSession]] = \
             [None] * self._n_lanes
-        self._window = cfg.sliding_window if "window" in cfg.kinds else 0
-        self._window_layers = cfg.kinds.count("window")
-        # layers whose state is no positions: the last conv_kernel - 1
-        # inputs of a convolution, whatever the context
-        self._conv_layers = cfg.kinds.count("conv")
-        # ... or a matrix a head that a gated delta rule reads and writes
-        # WHOLE every step (beside its convolutions' last inputs)
-        self._kda_layers = cfg.kinds.count("kda")
-        # ... or a state-space mixer does, in a layer that ALSO attends
-        # rows of the full kind: counted once among each
-        from ..models.transformer import SSM_KINDS
-        self._ssm_layers = sum(k in SSM_KINDS for k in cfg.kinds)
-        # layers that attend their own BLOCK of `sliding_window` rows (a
-        # ring) and every earlier block through a summary row a
-        # `summary_chunk` positions
-        self._eva_layers = cfg.kinds.count("eva")
-        self._block, self._chunk_rows = cfg.sliding_window, \
-            cfg.summary_chunk
-        # of a model whose layers attend the `index_topk` positions an
-        # indexer chose, the layers that run the indexer: each reads ONE
-        # key of every position at or before a query
-        self._index_layers = cfg.kinds.count("index")
         self._cache = None            # allocated lazily on first start
         self._shapes: set = set()     # distinct compiled program shapes
         # ONE lock.  `_cond` is what the engine thread and the callers in
@@ -461,9 +428,8 @@ class ContinuousBatchingEngine:
         self.prefill_programs = 0
         # ... and the cache rows those programs' attention moved from
         # memory, beside the rows a real query of theirs saw
-        # (`models.generate.chunk_rows_fetched`)
+        # (`CacheTraffic.chunk`)
         self.chunk_rows_fetched = self.chunk_rows_read = 0
-        self._chunk_fetched = None      # the counter, set by the loop
         self._lanes_span = dict(self._lane_sums(), t=time.time())
         # ... of them those with fewer real tokens than the chunk holds
         # (a prompt's remainder), and the padding rows those carried
@@ -476,25 +442,14 @@ class ContinuousBatchingEngine:
         self.moe = dict.fromkeys(
             ("steps", "experts_touched", "pairs", "load_max"), 0)
         self._moe_span = dict(self.moe, t=time.time())
-        # cache rows the live slots' decode steps attended, summed over
-        # layers (a window layer stops at its window), beside what they
-        # would have attended were every layer full: `stats()["cache"]`
-        # and, as `moe:load`, one ring span `cache:rows` every
-        # `_MOE_SPAN_S` seconds with the sums since the last; and the
-        # same in BYTES, a row costing what its layer's kind holds a
-        # position (`_row_bytes`), beside what the rows would cost were
-        # every layer a full one at the model's widest key-value heads;
-        # and the rows the steps' attention MOVED to attend those (every
-        # row of every slot where dense dots read the arrays whole);
-        # and the columns the steps WROTE beside the device calls that
-        # wrote them (one an array a layer where the kernel engages)
-        self.rows = dict.fromkeys(
-            ("steps",) + self._ROW_SUMS + self._INDEX_SUMS
-            + self._RING_LATENT_SUMS
-            + self._STATE_SUMS + self._FETCH_SUMS + self._WRITE_SUMS, 0)
+        # what the decode steps read, moved and wrote of the cache, by state
+        # kind (`models.CacheTraffic.STEP_SUMS`, which says what each sum
+        # is): `stats()["cache"]` and, as `moe:load`, one ring span
+        # `cache:rows` every `_MOE_SPAN_S` seconds with the sums since the
+        # last
+        self._traffic: Any = None     # the cache's, built beside it
+        self.rows = dict.fromkeys(("steps",) + CacheTraffic.STEP_SUMS, 0)
         self._rows_span = dict(self.rows, t=time.time())
-        from ..models.generate import position_bytes
-        self._row_bytes = position_bytes(cfg)
         # fused steps dispatched, and of them those dispatched while the
         # step before them had not been read: `stats()["steps_ahead"]`
         # and one ring span `engine:ahead` every `_MOE_SPAN_S` seconds
@@ -789,9 +744,9 @@ class ContinuousBatchingEngine:
         mixers', where the model has them),
         what ONE further position of a slot costs (the full arrays' bytes
         a row: a ring and a state grow with nothing), and the rows and
-        bytes the decode steps read (`_ROW_SUMS`) and the columns they wrote
-        (`_WRITE_SUMS`); zeros until the first
-        session allocates the cache."""
+        bytes the decode steps read, moved and wrote
+        (`models.CacheTraffic.STEP_SUMS`); zeros until the first session
+        allocates the cache."""
         kinds = self._cache_bytes(self._cache or {})
         return {"bytes": sum(kinds.values()),
                 **{"bytes_" + kind: n for kind, n in kinds.items()},
@@ -1033,61 +988,14 @@ class ContinuousBatchingEngine:
 
     def _prefix_exact(self, donor: int, depth: int, n: int) -> bool:
         """Whether slot ``donor`` still holds what a session of ``n``
-        prompt tokens seeded with its first ``depth`` positions attends.
-        A full layer's rows below ``depth`` are never rewritten; a window layer's ring has moved on
-        with the donor: whatever was written there (ahead of its ``pos``
-        included) left the positions ``>= pos - sliding_window`` intact.
-        So a donor whose whole context still fits its window serves any
-        prefix, and one that stands at the prefix (it has decoded no more
-        than one token past it) serves a session whose chunk windows
-        start at ``depth`` or later: the seeded session's first query
-        needs the positions from ``depth - sliding_window + 1`` on, and a
-        window set back at the capacity edge (`chunk_window`) would need
-        earlier ones.  A conv layer's state is the donor's at its LAST
-        token and nothing of it can be masked: such a donor serves a
-        prefix only while it STANDS at it (``pos == depth``: it has
-        decoded nothing past it), and none of the seeded session's chunk
-        windows, which start at ``depth`` and not at a multiple of the
-        chunk, may be set back (a state cannot run tokens twice).  Any
-        other donor is refused, and the prompt prefills from its start.
-        A layer with a state-space mixer BESIDE its attention is such a
-        state layer, although it has rows too.
-
-        A SUMMARY layer (`models/generate.py`, the fourth state kind) has
-        both: summary rows, one a chunk of positions, and a ring of its
-        block.  The summaries of the chunks below ``depth`` are never
-        rewritten (a donor writes the row of the chunk its newest token
-        lies in and no earlier one), so every donor serves them, and the
-        rows from ``depth``'s chunk on are the seeded session's own to
-        write before any of its queries sees them.  The ring holds the
-        rows of the prefix's LAST block (``depth // sliding_window``; what
-        the seeded session's first queries attend exactly, and what its
-        first summary is pooled from) only while the donor still STANDS in
-        that block: once it has passed the block's end its ring has moved
-        on.  A prefix that ends on a block's edge needs no ring row at all.
-        Either way the first chunk window must not be set back before
-        ``depth``: it would need the block before."""
-        if not self._window and not self._conv_layers \
-                and not self._eva_layers and not self._kda_layers \
-                and not self._ssm_layers:
-            return True
+        prompt tokens seeded with its first ``depth`` positions attends:
+        `models.prefix_holds`' rule, by the cache's state kinds, of where
+        the session the prefix index advertises there stands now."""
+        from ..models import prefix_holds
         sess = self._donors.get(donor)
-        if sess is None:
-            return False
-        chunk = self.ecfg.prefill_chunk_tokens
-        if self._eva_layers:
-            return depth + chunk <= self._capacity and (
-                depth % self._block == 0
-                or sess.pos // self._block == depth // self._block)
-        # (a KDA layer's state as a conv layer's: the donor's at its last
-        # token; a state-space mixer's too, whatever rows its layer holds
-        # beside it: those any donor would serve, the state only this one)
-        if (self._conv_layers or self._kda_layers or self._ssm_layers) and (
-                sess.pos != depth or
-                depth + -(-(n - depth) // chunk) * chunk > self._capacity):
-            return False
-        return not self._window or sess.pos <= self._window or (
-            sess.pos <= depth + 1 and depth + chunk <= self._capacity)
+        return prefix_holds(
+            self.cfg, None if sess is None else sess.pos, depth, n,
+            self.ecfg.prefill_chunk_tokens, self._capacity)
 
     def _seed_cache(self, sess: _EngineSession) -> None:
         """The batch-1 cache a session's prompt starts from: the longest
@@ -1152,7 +1060,7 @@ class ContinuousBatchingEngine:
         self.phase_s["prefill_tail"] += wall * len(tails) / len(riders)
         self._prof.note_tokens("prefill_chunk", sum(n for _, n in riders))
         # (a rider's `poff` is already past the chunk it rode)
-        moved = [self._chunk_fetched(sess.poff - n, n) for sess, n in riders]
+        moved = [self._traffic.chunk(sess.poff - n, n) for sess, n in riders]
         with self._loop_lock:   # stats() reads these counters
             self.prefill_programs += 1
             self.prefill_chunks += len(riders)
@@ -1358,23 +1266,16 @@ class ContinuousBatchingEngine:
     def _loop(self) -> None:
         import numpy as np
 
-        from ..models import init_slot_cache
-        from ..models.generate import (chunk_rows_fetched,
-                                       column_write_counts, rows_fetched,
-                                       state_fetched)
+        from ..models import CacheTraffic, init_slot_cache
         from ..util import fault_injection as fi
         from ..util import tracing
         if self._cache is None:
             self._cache = init_slot_cache(self.cfg, self.ecfg.max_slots,
                                           self.max_len)
-        # `_WRITE_SUMS` of one fused step: the cache's shapes say it
-        self._writes_a_step = column_write_counts(self._cache)
-        # `_FETCH_SUMS` of one, from its live slots' positions
-        self._fetched = rows_fetched(self._cache, self.cfg)
-        self._state_fetched = state_fetched(self._cache, self.cfg)
-        # `_lane_sums`' rows of one chunk a lane, from its position
-        self._chunk_fetched = chunk_rows_fetched(
-            self._cache, self.cfg, self.ecfg.prefill_chunk_tokens)
+        # what a fused step and a chunk a lane read, move and write of it,
+        # from positions: the cache's shapes and this backend's kernels say
+        self._traffic = CacheTraffic(self._cache, self.cfg,
+                                     self.ecfg.prefill_chunk_tokens)
         slots = self.ecfg.max_slots
         self._carry = self._fresh_carry()
         self._warm_lanes()
@@ -1497,10 +1398,7 @@ class ContinuousBatchingEngine:
         if key != self._active_key:
             self._active_dev, self._active_key = jnp.asarray(active), key
         # at the positions BEFORE this step; and what it writes
-        rows = self._rows_of(batch) + self._index_rows_of(batch) \
-            + (self._ring_latent_bytes(batch),) \
-            + self._state_rows_of(batch) \
-            + (self._fetched([s.pos for s in batch]),) + self._writes_a_step
+        rows = self._traffic.step([s.pos for s in batch])
         flight = self._flight
         with self._loop_lock:
             for s in batch:
@@ -1575,141 +1473,12 @@ class ContinuousBatchingEngine:
             "moe:load", "moe", self.moe, self._moe_span,
             layers=self._moe_layers, experts=self.cfg.n_experts_held)
 
-    #: what `_rows_of` counts a step, in its order (the last two: of
-    #: `rows_read` and `bytes_read`, the part that is summary rows)
-    _ROW_SUMS = ("rows_read", "rows_if_full", "bytes_read",
-                 "bytes_if_uniform", "summary_rows_read",
-                 "summary_bytes_read")
-    #: ... and what `_index_rows_of` does: the index keys an indexer scored
-    #: (NOT among `rows_read`) and their bytes (which ARE among `bytes_read`)
-    _INDEX_SUMS = ("index_rows_read", "index_bytes_read")
-    #: ... and, of `bytes_read`, the part that is a window LATENT layer's
-    #: ring rows (a latent model with window layers; else 0)
-    _RING_LATENT_SUMS = ("ring_latent_bytes_read",)
-    #: ... and what a delta or a state-space state costs a step: the (slot,
-    #: layer that carries one) states advanced, their bytes READ AND WRITTEN
-    #: (a layer that holds rows beside its state has those among
-    #: `_ROW_SUMS`, neither twice; NOT among
-    #: `bytes_read`: no position is attended), and the bytes of state the
-    #: step's program MOVES to do that (`models.generate.state_fetched`)
-    _STATE_SUMS = ("state_rows", "state_bytes_moved", "state_bytes_fetched")
-    #: ... and the rows the step's attention MOVES from the cache to attend
-    #: `rows_read` of them (`models.generate.rows_fetched`)
-    _FETCH_SUMS = ("rows_fetched",)
-    #: ... and beside them what a step WRITES, whatever its positions
-    #: (`models.generate.column_write_counts`): a column a slot, live or
-    #: not, a layer of every array that holds positions, and the device
-    #: calls that write them (one kernel call an array a layer, or a slice
-    #: a column)
-    _WRITE_SUMS = ("column_writes", "column_write_calls")
-
-    def _rows_of(self, batch) -> Tuple[int, ...]:
-        """`_ROW_SUMS` of a decode step about to be dispatched: the cache
-        rows its live slots attend (each at its position before the step,
-        its own new row included; a conv layer reads the ``conv_kernel -
-        1`` rows of its state whatever the position), beside what they
-        would attend were every layer a full one; then the same in bytes:
-        a full layer's row and a window layer's at what each holds a
-        position (they differ where the kinds' key-value heads do), a conv
-        layer's state whole, beside every layer's rows at the widest of
-        the model's rows (a model of one kind of row reads 100 %).  A
-        SUMMARY layer's slot at position ``t`` reads the ``t % block + 1``
-        ring rows of its own block and a summary row for each of the ``(t
-        // block) * block / chunk`` chunks before it, where a full layer
-        would read a row a position: the last two sums are those summary
-        rows and their bytes.  A layer under an INDEXER's choice (every
-        layer of a model that has one) attends the ``min(t + 1,
-        index_topk)`` latents chosen, not ``t + 1``; what the choice costs
-        is counted beside them: each indexing layer reads ONE index key of
-        every position at or before the query (``index_rows_read``; their
-        bytes are among ``bytes_read`` too)."""
-        if self.cfg.index_topk:     # its full layers are under the choice
-            return self._chosen_rows_of(batch)
-        eva = self._eva_layers
-        full = self.cfg.n_layers - self._window_layers - self._conv_layers \
-            - eva - self.cfg.kinds.count("kda")     # (`_state_rows_of`)
-        depth = sum(s.pos + 1 for s in batch)
-        seen = sum(min(s.pos + 1, self._window) for s in batch)
-        per_full, per_ring, state = (
-            self._row_bytes[k] for k in ("full", "ring", "state"))
-        rows = full * depth + self._window_layers * seen \
-            + self._conv_layers * (self.cfg.conv_kernel - 1) * len(batch)
-        nbytes = full * depth * per_full + self._window_layers * seen \
-            * per_ring + self._conv_layers * state * len(batch)
-        widest = max(per_full, per_ring)
-        pooled = pooled_bytes = 0
-        if eva:
-            block, per_sum = self._block, self._row_bytes["summary"]
-            own = eva * sum(s.pos % block + 1 for s in batch)
-            pooled = eva * sum(s.pos // block for s in batch) \
-                * (block // self._chunk_rows)
-            pooled_bytes = pooled * per_sum
-            rows += own + pooled
-            nbytes += own * per_ring + pooled_bytes
-            widest = max(widest, per_sum)
-        return (rows, self.cfg.n_layers * depth, nbytes,
-                self.cfg.n_layers * depth * widest, pooled, pooled_bytes)
-
-    def _chosen_rows_of(self, batch) -> Tuple[int, ...]:
-        """`_rows_of` for a model with an indexer: its indexing and shared
-        layers attend the ``min(t + 1, index_topk)`` latents chosen, and
-        the window layers that may stand among them
-        (`models.transformer.check_kinds`) the ``min(t + 1, window)`` rows
-        of their ring, each kind's row at its own width."""
-        cfg = self.cfg
-        windows = cfg.kinds.count("window")
-        layers, per = cfg.n_layers - windows, self._row_bytes["full"]
-        depth = sum(s.pos + 1 for s in batch)
-        chosen = layers * sum(min(s.pos + 1, cfg.index_topk) for s in batch)
-        ring = _ring_rows(cfg, batch)
-        per_ring = self._row_bytes["ring"] if windows else 0
-        return (chosen + ring, cfg.n_layers * depth,
-                chosen * per + ring * per_ring
-                + self._index_layers * depth * self._row_bytes["index"],
-                cfg.n_layers * depth * max(per, per_ring), 0, 0)
-
-    def _ring_latent_bytes(self, batch) -> int:
-        """`_RING_LATENT_SUMS` of a decode step about to be dispatched: a
-        live slot reads ``min(t + 1, window)`` ring rows on each window
-        layer of a LATENT model, at the ring's row width; 0 for any other
-        model."""
-        if not self.cfg.window_latent:
-            return 0
-        return _ring_rows(self.cfg, batch) * self._row_bytes["ring"]
-
-    def _index_rows_of(self, batch) -> Tuple[int, int]:
-        """`_INDEX_SUMS` of a decode step about to be dispatched: each
-        indexing layer scores ONE index key of every position at or before
-        a live slot's query; zeros for a model without an indexer."""
-        if not self.cfg.index_topk:
-            return (0, 0)
-        scored = self._index_layers * sum(s.pos + 1 for s in batch)
-        return scored, scored * self._row_bytes["index"]
-
-    def _state_rows_of(self, batch) -> Tuple[int, int, int]:
-        """`_STATE_SUMS` of a decode step about to be dispatched: every
-        live slot's matrix of state and convolution inputs on every KDA
-        layer and every layer with a state-space mixer are read whole AND
-        written whole, whatever the slot's position, and the program moves
-        what its form of the rule on this backend does; zeros for a model
-        without such layers."""
-        live = len(batch)
-        return ((self._kda_layers + self._ssm_layers) * live,
-                2 * live * (
-                    self._kda_layers * self._row_bytes.get("delta", 0)
-                    + self._ssm_layers * self._row_bytes.get("ssm", 0)),
-                self._state_fetched(live))
-
     def _count_rows(self, rows: Tuple[int, ...]) -> None:
-        """A read step's `_rows_of` and column writes into the counters,
-        and the sums since the last `cache:rows` span into the next when
-        due."""
+        """A read step's `CacheTraffic.step` into the counters, and the sums
+        since the last `cache:rows` span into the next when due."""
         with self._loop_lock:   # stats() reads these
             self.rows["steps"] += 1
-            for k, n in zip(self._ROW_SUMS + self._INDEX_SUMS
-                            + self._RING_LATENT_SUMS
-                            + self._STATE_SUMS + self._FETCH_SUMS
-                            + self._WRITE_SUMS, rows):
+            for k, n in zip(self._traffic.STEP_SUMS, rows):
                 self.rows[k] += n
         self._rows_span = self._sums_span(
             "cache:rows", "cache", self.rows, self._rows_span,

@@ -117,6 +117,7 @@ _flusher_claimed = False
 _host = {"gc_s": 0.0, "gc_collections": 0,
          "late_wakeup_s": 0.0, "late_wakeups": 0}
 _host_owed: deque = deque()   # collections the ring has yet to be given
+_drain_lock = threading.Lock()          # one drainer of it at a time
 _gc_open: Optional[tuple] = None        # (perf_counter, wall, annotation)
 _watch: Optional[Tuple[threading.Thread, threading.Event]] = None
 
@@ -189,14 +190,15 @@ def _on_gc(phase: str, info: Dict[str, int]) -> None:
 def _drain_host() -> None:
     """The collections `_on_gc` left, into the ring as ``host:gc`` spans
     (the watch thread every tick; every flush and the span file too, so a
-    collection of a process's last second is kept)."""
-    while _host_owed:
-        try:
+    collection of a process's last second is kept).  One drainer at a time:
+    a collection the watch thread has taken and not yet recorded (it waits
+    for `_span_lock`) is in neither place, and a flush that found nothing
+    owed would ship a batch without it."""
+    with _drain_lock:
+        while _host_owed:
             t0, t1, generation, collected = _host_owed.popleft()
-        except IndexError:
-            return
-        record_span("host:gc", "host", t0, t1, generation=generation,
-                    collected=collected)
+            record_span("host:gc", "host", t0, t1, generation=generation,
+                        collected=collected)
 
 
 def _watch_loop(stop: threading.Event) -> None:

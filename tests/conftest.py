@@ -53,7 +53,10 @@ import pytest  # noqa: E402
 #: other file, none over 25 s, follows where it was.  A new file of
 #: compile-heavy model tests belongs here, by its weight (PR 46's two by
 #: their times alone: `test_sparse_index.py` 142 s,
-#: `test_perfbench_family_glm_moe_dsa.py` 117 s).  `test_scale.py`
+#: `test_perfbench_family_glm_moe_dsa.py` 117 s; PR 55's two, entered by
+#: PR 57 beside the neighbours it timed in one process: `test_ssd.py` 104 s
+#: then `test_perfbench_family_dots3_note.py` 99 s, `test_window_latent.py`
+#: 67 s then `test_short_conv_state.py` 56 s).  `test_scale.py`
 #: (230-290 s) stands later than its weight: its floor on tasks a second
 #: (400; 480 read beside the runtime's own tests, 365 and 378 beside five
 #: workers compiling) wants the light end of the run, where it still ends
@@ -66,9 +69,11 @@ _LONGEST_FIRST = (
     "test_perfbench_family_glm_moe_dsa.py",
     "test_perfbench_family_kimi_linear.py", "test_delta_rule.py",
     "test_perfbench_family_falcon_h1.py", "test_ssd.py",
+    "test_perfbench_family_dots3_note.py",
     "test_perfbench_family_mimo_v2_flash.py", "test_mixed_kv_heads.py",
     "test_serve_decode_engine.py", "test_prefill_padded_tail.py",
-    "test_window_ring.py", "test_short_conv_state.py", "test_gbdt.py",
+    "test_window_ring.py", "test_window_latent.py",
+    "test_short_conv_state.py", "test_gbdt.py",
     "test_rl.py", "test_latent_moe.py", "test_dt.py",
     "test_multi_agent.py", "test_grouped_matmul.py",
     "test_perfbench_family_evabyte.py", "test_generate.py",

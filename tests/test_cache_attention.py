@@ -6,7 +6,7 @@ positions), and the work list against the masks it is built from; then the
 same for a CHUNK's 128 queries a lane (`attend_chunk_blocks`: two sets as a
 summary layer hands them, one set as `attend_mha` does, against its `heads`),
 and the host's count of the rows a chunk program fetches
-(`models/generate.py` `chunk_rows_fetched`) against the kernel's own list."""
+(`models/generate.py` `CacheTraffic.chunk`) against the kernel's own list."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import TransformerConfig, init_kv_cache
-from ray_tpu.models.generate import _ring_mask, chunk_rows_fetched
+from ray_tpu.models.generate import CacheTraffic, _ring_mask
 from ray_tpu.ops import cache_attention as ca
 from ray_tpu.ops.eva_attention import attend_two, summary_mask
 
@@ -296,7 +296,7 @@ def _tiny(kinds, **more):
 @pytest.mark.parametrize("kinds", ["summaries", "window_and_full"])
 def test_the_hosts_count_of_a_chunks_rows_is_the_kernels_own_list(
         kinds, monkeypatch):
-    """`chunk_rows_fetched` from positions: where the kernel engages, the
+    """`CacheTraffic.chunk` from positions: where the kernel engages, the
     length of the work list the same masks give, x 128, summed over the
     layers; where it does not, every row of the lane's arrays.  Read rows
     count the REAL queries' alone."""
@@ -314,9 +314,9 @@ def test_the_hosts_count_of_a_chunks_rows_is_the_kernels_own_list(
     cache = init_kv_cache(cfg, 1, 1024)
     assert {a.shape[-1] for a in cache_arrays(cache).values()} <= {
         RING, SUMS, 1024}
-    dense = chunk_rows_fetched(cache, cfg, C)
+    dense = CacheTraffic(cache, cfg, C).chunk
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    engaged = chunk_rows_fetched(cache, cfg, C)
+    engaged = CacheTraffic(cache, cfg, C).chunk
     rng = np.random.default_rng(5)
     drawn = [0, 128, 255, 256, 383, 384, 896] + rng.integers(
         0, 1024 - C, 12).tolist()
@@ -346,5 +346,5 @@ def test_latent_layers_chunks_are_counted_dense_where_no_kernel_reads_them():
         max_seq_len=64, pos_emb="rope", attention="mla", q_lora_rank=8,
         kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
         v_head_dim=8, dtype=jnp.float32, attention_impl="reference")
-    count = chunk_rows_fetched(init_kv_cache(cfg, 1, 64), cfg, 32)
+    count = CacheTraffic(init_kv_cache(cfg, 1, 64), cfg, 32).chunk
     assert count(0, 32) == (64, 32) and count(32, 5) == (64, 37)

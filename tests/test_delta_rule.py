@@ -31,16 +31,16 @@ import pytest
 
 from perfbench import manifest as mf
 from perfbench.tools import rehearse
-from ray_tpu.models import (TransformerConfig, cache_gather_slot,
-                            cache_insert_slot, decode_step_slots, forward,
-                            init_kv_cache, init_params, init_slot_cache,
-                            lm_loss, prefill, prefill_chunk_jit,
-                            prefill_lanes_jit)
+from ray_tpu.models import (CacheTraffic, TransformerConfig,
+                            cache_gather_slot, cache_insert_slot,
+                            decode_step_slots, forward, init_kv_cache,
+                            init_params, init_slot_cache, lm_loss, prefill,
+                            prefill_chunk_jit, prefill_lanes_jit,
+                            prefix_holds)
 from ray_tpu.models.generate import (_state_kind, array_dtype, cache_bytes,
                                      cache_capacity, cache_rows,
-                                     column_write_counts, position_bytes,
-                                     prefill_chunk_step, prefill_lanes_step,
-                                     state_fetched)
+                                     position_bytes, prefill_chunk_step,
+                                     prefill_lanes_step)
 from ray_tpu.models.transformer import (count_params, decode_flops_per_token,
                                         stack_kinds)
 from ray_tpu.ops import delta_rule
@@ -352,24 +352,17 @@ def test_a_cache_has_a_sixth_kind_of_state_of_a_type_of_its_own(world):
         "delta": 4 * 3 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 2)}
     assert cache_capacity(held, bf16) == MAX_LEN
     # a step writes a column a slot on the full layers alone
-    assert column_write_counts(held)[0] == 2 * 3
+    assert CacheTraffic(held, bf16, CHUNK).step(()).column_writes == 2 * 3
 
 
 def test_rows_a_step_attends_and_the_state_it_moves(world, monkeypatch):
-    eng = types.SimpleNamespace(
-        cfg=world.cfg, _window=0, _window_layers=0, _conv_layers=0,
-        _eva_layers=0, _kda_layers=4, _ssm_layers=0,
-        _row_bytes=position_bytes(world.cfg))
-    batch = [types.SimpleNamespace(pos=9), types.SimpleNamespace(pos=99)]
-    rows = ContinuousBatchingEngine._rows_of(eng, batch)
-    assert rows == (2 * 110, 6 * 110, 2 * 110 * 96, 6 * 110 * 96, 0, 0)
+    step = CacheTraffic(init_slot_cache(world.cfg, 3, MAX_LEN), world.cfg,
+                        CHUNK).step((9, 99))
+    assert step[:6] == (2 * 110, 6 * 110, 2 * 110 * 96, 6 * 110 * 96, 0, 0)
     per = 4 * 16 * 16 * 4 + 3 * 3 * 64 * 4          # float32 model
     # states of 16 x 16: XLA's form, three passes over all 3 slots' states
-    eng._state_fetched = state_fetched(
-        init_slot_cache(world.cfg, 3, MAX_LEN), world.cfg)
-    assert ContinuousBatchingEngine._state_rows_of(eng, batch) == (
-        4 * 2, 2 * 4 * 2 * per, 3 * 4 * 3 * per)
-    assert ContinuousBatchingEngine._STATE_SUMS == (
+    assert step[9:12] == (4 * 2, 2 * 4 * 2 * per, 3 * 4 * 3 * per)
+    assert CacheTraffic.STEP_SUMS[9:12] == (
         "state_rows", "state_bytes_moved", "state_bytes_fetched")
     # states of 128 x 128 where the kernel runs: the live slots' alone,
     # twice; on this backend without the interpreter XLA's form again
@@ -377,13 +370,14 @@ def test_rows_a_step_attends_and_the_state_it_moves(world, monkeypatch):
     cache = init_slot_cache(wide, 3, MAX_LEN)
     per = position_bytes(wide)["delta"]
     assert per == 4 * 128 * 128 * 4 + 3 * 3 * 4 * 128 * 4
-    assert state_fetched(cache, wide)(2) == 3 * 4 * 3 * per
+    fetched = lambda cache, cfg, live: CacheTraffic(cache, cfg, CHUNK).step(
+        (5,) * live).state_bytes_fetched
+    assert fetched(cache, wide, 2) == 3 * 4 * 3 * per
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    assert [state_fetched(cache, wide)(n) for n in (2, 0)] == [
-        2 * 4 * 2 * per, 0]
+    assert [fetched(cache, wide, n) for n in (2, 0)] == [2 * 4 * 2 * per, 0]
     # no KDA layer, no state
     plain = dataclasses.replace(world.cfg, layer_kinds=("full",) * 6)
-    assert state_fetched(init_slot_cache(plain, 2, 64), plain)(2) == 0
+    assert fetched(init_slot_cache(plain, 2, 64), plain, 2) == 0
 
 
 def test_the_kda_scope_stands_inside_attention_and_conv(world):
@@ -642,18 +636,13 @@ def test_a_slot_reused_after_another_session_starts_from_zeros(world):
 
 
 def test_prefix_exact_serves_only_a_donor_that_stands_at_the_prefix(world):
-    eng = types.SimpleNamespace(
-        _window=0, _conv_layers=0, _kda_layers=4, _eva_layers=0,
-        _capacity=MAX_LEN, _block=0,
-        ecfg=types.SimpleNamespace(prefill_chunk_tokens=CHUNK),
-        _donors={0: types.SimpleNamespace(pos=40),
-                 1: types.SimpleNamespace(pos=41)})
-    exact = functools.partial(ContinuousBatchingEngine._prefix_exact, eng)
-    assert exact(0, 40, 100)
-    assert not exact(1, 40, 100)        # it has decoded one token past
-    assert not exact(2, 40, 100)        # no such donor
+    exact = functools.partial(prefix_holds, world.cfg, chunk=CHUNK,
+                              capacity=MAX_LEN)
+    assert exact(40, 40, 100)
+    assert not exact(41, 40, 100)       # it has decoded one token past
+    assert not exact(None, 40, 100)     # no such donor
     # a chunk window that would be set back at the cache's end
-    assert not exact(0, 40, MAX_LEN - 1)
+    assert not exact(40, 40, MAX_LEN - 1)
     gathered = jax.jit(cache_gather_slot)(
         _two_slots(world, (40, 30)), jnp.int32(0), jnp.int32(40))
     assert set(gathered) == {"kv", "s_delta", "conv_delta", "pos"}

@@ -9,9 +9,9 @@ program's code) on seeded random weights at a tiny size (window 32, chunk 4,
 chunk programs of 8): `forward`; whole-prompt `prefill`; chunked prefill whose
 chunks straddle a window's edge and whose prompt ends mid-chunk; the lanes
 program with a lane that stands; slot decode through three windows beside a
-slot that is not live; slot insert and gather; `_prefix_exact`'s cases.  The two limits of the equations
+slot that is not live; slot insert and gather; `prefix_holds`' cases.  The two limits of the equations
 against `ops/attention.py`'s plain attention.  And every configuration the
-benchmark already had: its `cache_rows` and its engine's `_rows_of` as they
+benchmark already had: its `cache_rows` and its `CacheTraffic.step` as they
 were.
 """
 
@@ -28,10 +28,11 @@ import pytest
 
 from perfbench import manifest as mf
 from perfbench.tools import rehearse
-from ray_tpu.models import (cache_gather_slot, cache_insert_slot,
-                            decode_step_slots, forward, init_kv_cache,
-                            init_slot_cache, prefill, prefill_chunk_jit,
-                            prefill_lanes_jit)
+from ray_tpu.models import (CacheTraffic, cache_gather_slot,
+                            cache_insert_slot, decode_step_slots, forward,
+                            init_kv_cache, init_slot_cache, prefill,
+                            prefill_chunk_jit, prefill_lanes_jit,
+                            prefix_holds)
 from ray_tpu.models.generate import (_state_kind, cache_bytes,
                                      cache_capacity, cache_rows,
                                      greedy_tokens, position_bytes,
@@ -39,7 +40,6 @@ from ray_tpu.models.generate import (_state_kind, cache_bytes,
                                      prefill_lanes_step, window_ring)
 from ray_tpu.ops.attention import reference_attention
 from ray_tpu.ops.eva_attention import eva_attention
-from ray_tpu.serve.decode_session import ContinuousBatchingEngine
 
 T, MAX_LEN, CHUNK = 100, 128, 8
 TOL = dict(atol=3e-4, rtol=0)
@@ -248,14 +248,12 @@ def test_a_step_through_the_block_kernel_is_the_dense_step(world,
         np.testing.assert_allclose(l_k[[0, 2]], l_d[[0, 2]], **TOL)
 
 
-def test_prefix_exact_says_which_donor_still_holds_a_prefix():
+def test_prefix_exact_says_which_donor_still_holds_a_prefix(world):
     """Summaries below the depth: always.  Ring rows: only while the donor
     stands in the prefix's last window; none are needed where the prefix
     ends on a window's edge.  And no first chunk window set back."""
-    eng = types.SimpleNamespace(
-        _window=0, _conv_layers=0, _eva_layers=2, _block=32, _capacity=128,
-        ecfg=types.SimpleNamespace(prefill_chunk_tokens=8), _donors={})
-    exact = functools.partial(ContinuousBatchingEngine._prefix_exact, eng)
+    exact = functools.partial(prefix_holds, world.cfg, chunk=CHUNK,
+                              capacity=MAX_LEN)
     for pos, depth, want in (
             (50, 45, True),       # donor in the prefix's window [32, 64)
             (63, 33, True),
@@ -265,9 +263,8 @@ def test_prefix_exact_says_which_donor_still_holds_a_prefix():
             (40, 32, True),
             (127, 124, False),    # the first chunk would be set back
             (127, 96, True)):
-        eng._donors = {3: types.SimpleNamespace(pos=pos)}
-        assert exact(3, depth, depth + 20) is want, (pos, depth)
-    assert exact(4, 45, 60) is False      # no such donor
+        assert exact(pos, depth, depth + 20) is want, (pos, depth)
+    assert exact(None, 45, 60) is False     # no such donor
 
 
 @pytest.mark.parametrize("limit", ["window_holds_all", "chunk_of_one"])
@@ -321,36 +318,32 @@ def test_existing_caches_and_row_counts_are_unchanged(config):
     cfg = _served(config)
     assert cache_rows(cfg) == ROWS[config]
     assert set(position_bytes(cfg)) == {"full", "ring", "state"}
-    eng = types.SimpleNamespace(
-        cfg=cfg, _window=cfg.sliding_window if "window" in cfg.kinds else 0,
-        _window_layers=cfg.kinds.count("window"),
-        _conv_layers=cfg.kinds.count("conv"),
-        _eva_layers=cfg.kinds.count("eva"), _block=cfg.sliding_window,
-        _chunk_rows=cfg.summary_chunk, _row_bytes=position_bytes(cfg))
-    batch = [types.SimpleNamespace(pos=p) for p in (0, 100, 5000, 9000)]
-    got = ContinuousBatchingEngine._rows_of(eng, batch)
+    # (the served size's SHAPES: the counts read nothing else of a cache)
+    traffic = CacheTraffic(jax.eval_shape(
+        lambda: init_slot_cache(cfg, 4, 9216)), cfg, 128)
+    batch = (0, 100, 5000, 9000)
+    got = traffic.step(batch)[:6]
     # the sums as they were counted before there was a fourth state kind
-    full = cfg.n_layers - eng._window_layers - eng._conv_layers
-    depth = sum(s.pos + 1 for s in batch)
-    seen = sum(min(s.pos + 1, eng._window) for s in batch)
-    b = eng._row_bytes
+    windows, convs = (cfg.kinds.count(k) for k in ("window", "conv"))
+    window = cfg.sliding_window if windows else 0
+    full = cfg.n_layers - windows - convs
+    depth = sum(pos + 1 for pos in batch)
+    seen = sum(min(pos + 1, window) for pos in batch)
+    b = position_bytes(cfg)
     assert got == (
-        full * depth + eng._window_layers * seen
-        + eng._conv_layers * (cfg.conv_kernel - 1) * len(batch),
+        full * depth + windows * seen
+        + convs * (cfg.conv_kernel - 1) * len(batch),
         cfg.n_layers * depth,
-        full * depth * b["full"] + eng._window_layers * seen * b["ring"]
-        + eng._conv_layers * b["state"] * len(batch),
+        full * depth * b["full"] + windows * seen * b["ring"]
+        + convs * b["state"] * len(batch),
         cfg.n_layers * depth * max(b["full"], b["ring"]), 0, 0)
 
 
 def test_rows_of_counts_ring_and_summary_rows_apart(world):
     cfg = world.cfg
-    eng = types.SimpleNamespace(
-        cfg=cfg, _window=0, _window_layers=0, _conv_layers=0, _eva_layers=2,
-        _block=32, _chunk_rows=4, _row_bytes=position_bytes(cfg))
-    batch = [types.SimpleNamespace(pos=p) for p in (3, 32, 99)]
+    traffic = CacheTraffic(init_slot_cache(cfg, 3, MAX_LEN), cfg, CHUNK)
     ring, pooled = (4 + 1 + 4), (0 + 8 + 24)    # a layer
     row = position_bytes(cfg)["ring"]
-    assert ContinuousBatchingEngine._rows_of(eng, batch) == (
+    assert traffic.step((3, 32, 99))[:6] == (
         2 * (ring + pooled), 2 * (4 + 33 + 100), 2 * (ring + pooled) * row,
         2 * (4 + 33 + 100) * row, 2 * pooled, 2 * pooled * row)

@@ -174,6 +174,38 @@ def test_a_collection_inside_record_span_does_not_take_its_lock(
     assert len(_spans("host:gc")) >= 1
 
 
+def test_a_flush_waits_for_a_drain_that_holds_a_collection(
+        watch, monkeypatch):
+    """A drainer that has taken a collection and not yet recorded it (it
+    waits for `_span_lock`, say) holds it where neither `_host_owed` nor the
+    ring shows it: a flush beside it waits for that drain, and ships the
+    collection."""
+    record, taken, go = tracing.record_span, threading.Event(), \
+        threading.Event()
+
+    def held_record(*args, **kwargs):
+        taken.set()
+        assert go.wait(10.0)
+        record(*args, **kwargs)
+
+    monkeypatch.setattr(tracing, "record_span", held_record)
+    tracing._host_owed.append((1.0, 1.5, 2, 7))
+    batch = []
+    drain = threading.Thread(target=tracing._drain_host, daemon=True)
+    flush = threading.Thread(
+        target=lambda: batch.append(tracing.flush_batch()), daemon=True)
+    drain.start()
+    assert taken.wait(10.0) and not tracing._host_owed
+    flush.start()
+    flush.join(timeout=0.2)
+    assert flush.is_alive() and not batch   # behind the drain, not past it
+    go.set()
+    for t in (drain, flush):
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+    assert [e["name"] for e in batch[0]["spans"]] == ["host:gc"]
+
+
 def test_the_last_seconds_collection_reaches_the_span_file(
         watch, tmp_path):
     """The hook's deque is drained by the span file's writer too: a

@@ -396,6 +396,25 @@ def test_engine_writes_the_states_bytes_into_its_cache_rows_span(
     assert args["rows_read"] == args["rows_if_full"] // 5 + 4 * 2
 
 
+def test_the_engines_cache_sums_are_the_traffics_by_name(core):
+    """`CacheTraffic.STEP_SUMS`: the fifteen names the readers of the
+    ``cache:rows`` span know, in their order; a real engine's
+    ``stats()["cache"]`` holds them behind ``steps``, after its ``bytes*``
+    keys."""
+    from ray_tpu.models import CacheTraffic
+    assert CacheTraffic.STEP_SUMS == (
+        "rows_read", "rows_if_full", "bytes_read", "bytes_if_uniform",
+        "summary_rows_read", "summary_bytes_read", "index_rows_read",
+        "index_bytes_read", "ring_latent_bytes_read", "state_rows",
+        "state_bytes_moved", "state_bytes_fetched", "rows_fetched",
+        "column_writes", "column_write_calls")
+    keys = tuple(core.engine.stats()["cache"])
+    sums = ("steps",) + CacheTraffic.STEP_SUMS
+    assert keys[-len(sums):] == sums
+    assert keys[:-len(sums)] and all(
+        k.startswith("bytes") for k in keys[:-len(sums)])
+
+
 def test_two_sessions_side_by_side_and_a_slot_reused(model, core):
     """Slots at different depths step together, and a slot taken again
     after a longer session starts from its own prompt's state."""

@@ -32,11 +32,11 @@ import pytest
 
 from perfbench import manifest as mf
 from perfbench.tools import rehearse
-from ray_tpu.models import (TransformerConfig, cache_gather_slot,
-                            cache_insert_slot, decode_step_slots, forward,
-                            init_kv_cache, init_params, init_slot_cache,
-                            lm_loss, prefill, prefill_chunk_jit,
-                            prefill_lanes_jit)
+from ray_tpu.models import (CacheTraffic, TransformerConfig,
+                            cache_gather_slot, cache_insert_slot,
+                            decode_step_slots, forward, init_kv_cache,
+                            init_params, init_slot_cache, lm_loss, prefill,
+                            prefill_chunk_jit, prefill_lanes_jit)
 from ray_tpu.models.generate import (_state_kind, cache_bytes, cache_rows,
                                      position_bytes, prefill_chunk_step,
                                      prefill_lanes_step)
@@ -312,12 +312,11 @@ def test_what_chooses_the_kernel_is_the_shape(cell, heads, rows, step, chunk):
 @pytest.mark.parametrize("program", ["step", "chunk"])
 def test_the_hosts_count_of_latent_rows_is_the_kernels_own_list(
         monkeypatch, program):
-    """`rows_fetched` and `chunk_rows_fetched` of a latent model from
-    positions: where `attend_cache` engages on this process's backend, the
+    """`CacheTraffic`'s ``rows_fetched`` a step and rows a chunk of a latent
+    model from positions: where `attend_cache` engages on this process's backend, the
     rows of `_cache_work`'s items for the same masks (a lane that stands
     has one item that moves nothing), summed over the layers; where it does
     not, every row of every slot's (of the lane's) layer."""
-    generate = importlib.import_module("ray_tpu.models.generate")
     cfg = TransformerConfig(
         vocab_size=64, d_model=64, n_layers=3, n_heads=4, d_ff=64,
         max_seq_len=1536, pos_emb="rope", attention="mla", q_lora_rank=8,
@@ -325,9 +324,11 @@ def test_the_hosts_count_of_latent_rows_is_the_kernels_own_list(
         v_head_dim=16, dtype=jnp.float32, attention_impl="reference")
     slots, rows, c = 5, 1536, 16 if program == "chunk" else 1
     cache = init_slot_cache(cfg, slots, rows)
-    count = {"step": lambda: generate.rows_fetched(cache, cfg),
-             "chunk": lambda: generate.chunk_rows_fetched(cache, cfg, c)}[
-                 program]
+    def count():
+        traffic = CacheTraffic(cache, cfg, c)
+        return traffic.chunk if program == "chunk" else \
+            lambda positions: traffic.step(positions).rows_fetched
+
     dense = count()
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     engaged = count()
@@ -371,7 +372,8 @@ def test_the_latent_share_reads_steps_and_chunk_programs(counted, want):
     """`perfbench/metrics/cache.latent_rows_fetched_share.batch.py` on
     hand-made ring spans: rows seen over rows moved, the window's
     ``cache:rows`` and ``engine:lanes`` spans summed; its entry in the root
-    manifest is the last, and lists the three cells of latent models."""
+    manifest (found by its name: an entry is only ever appended, so none
+    stays the last) lists the three cells of latent models."""
     read = mf.metric_reader(_SHARE)
     run = lambda events: types.SimpleNamespace(
         stamps={"open": 10.0, "close": 55.0}, _ring_spans=events)
@@ -390,7 +392,8 @@ def test_the_latent_share_reads_steps_and_chunk_programs(counted, want):
     got = read(run(events))
     assert got is None if want is None else got == pytest.approx(want)
     root = mf.Manifest()
-    assert root.data["per_layer"][-1] == {
+    mine, = (m for m in root.data["per_layer"] if m["name"] == _SHARE)
+    assert mine == {
         "name": _SHARE, "unit": "%", "better": "higher",
         "source": "program_span", "layer": "kernels",
         "moves": "serve_tok_s", "workloads": [
@@ -399,8 +402,7 @@ def test_the_latent_share_reads_steps_and_chunk_programs(counted, want):
             "glm-5.2.serve-longdoc-closed"]}
     e2e = next(e for e in root.data["end_to_end"]
                if e["name"] == "serve_tok_s")
-    assert set(root.data["per_layer"][-1]["workloads"]) <= set(
-        e2e["workloads"])
+    assert set(mine["workloads"]) <= set(e2e["workloads"])
 
 
 @pytest.mark.parametrize("path", ["kernel", "xla"])
@@ -515,17 +517,11 @@ def test_a_cache_has_a_fifth_kind_of_state(world):
 
 def test_rows_a_step_attends_and_what_the_choice_costs(world):
     cfg = world.cfg
-    eng = types.SimpleNamespace(
-        cfg=cfg, _index_layers=2, _row_bytes=position_bytes(cfg),
-        _chosen_rows_of=None, _index_rows_of=None)
-    for name in ("_chosen_rows_of", "_index_rows_of"):
-        setattr(eng, name, functools.partial(
-            getattr(ContinuousBatchingEngine, name), eng))
-    batch = [types.SimpleNamespace(pos=p) for p in (3, 7, 50)]
+    step = CacheTraffic(init_slot_cache(cfg, 3, MAX_LEN), cfg, CHUNK).step(
+        (3, 7, 50))
     depth, chosen = 4 + 8 + 51, 4 + 8 + 8
-    assert ContinuousBatchingEngine._index_rows_of(eng, batch) == (
-        2 * depth, 2 * depth * 64)
-    assert ContinuousBatchingEngine._rows_of(eng, batch) == (
+    assert step[6:8] == (2 * depth, 2 * depth * 64)
+    assert step[:6] == (
         5 * chosen, 5 * depth, 5 * chosen * 96 + 2 * depth * 64,
         5 * depth * 96, 0, 0)
 

@@ -37,15 +37,15 @@ import pytest
 
 from perfbench import manifest as mf
 from perfbench.tools import rehearse
-from ray_tpu.models import (cache_gather_slot, cache_insert_slot,
-                            decode_step_slots, forward, init_kv_cache,
-                            init_params, init_slot_cache, prefill,
-                            prefill_chunk_jit, prefill_lanes_jit)
+from ray_tpu.models import (CacheTraffic, cache_gather_slot,
+                            cache_insert_slot, decode_step_slots, forward,
+                            init_kv_cache, init_params, init_slot_cache,
+                            prefill, prefill_chunk_jit, prefill_lanes_jit,
+                            prefix_holds)
 from ray_tpu.models.generate import (_state_kind, array_dtype, cache_bytes,
                                      cache_capacity, cache_rows,
-                                     column_write_counts, position_bytes,
-                                     prefill_chunk_step, prefill_lanes,
-                                     prefill_lanes_step, state_fetched)
+                                     position_bytes, prefill_chunk_step,
+                                     prefill_lanes, prefill_lanes_step)
 from ray_tpu.models.transformer import (count_params, decode_flops_per_token,
                                         stack_kinds)
 from ray_tpu.ops import ssd
@@ -385,19 +385,13 @@ def test_a_cache_has_a_seventh_kind_beside_rows_in_one_layer(world):
     assert cache_bytes(cache) == {"full": 3 * 3 * 256 * MAX_LEN, "ring": 0,
                                   "state": 0, "ssm": 3 * 3 * state}
     assert cache_capacity(cache, cfg) == MAX_LEN
-    assert column_write_counts(cache)[0] == 2 * 3 * 3    # rows only
-    # XLA's form on this backend: three passes over all 3 slots' states
-    assert state_fetched(cache, cfg)(2) == 3 * 3 * 3 * state
-    eng = types.SimpleNamespace(
-        cfg=cfg, _window=0, _window_layers=0, _conv_layers=0, _eva_layers=0,
-        _kda_layers=0, _ssm_layers=3, _row_bytes=position_bytes(cfg),
-        _state_fetched=state_fetched(cache, cfg))
-    batch = [types.SimpleNamespace(pos=9), types.SimpleNamespace(pos=99)]
+    step = CacheTraffic(cache, cfg, CHUNK).step((9, 99))
+    assert step.column_writes == 2 * 3 * 3      # rows only
     # the SAME three layers once among the rows, once among the states
-    assert ContinuousBatchingEngine._rows_of(eng, batch) == (
+    assert step[:6] == (
         3 * 110, 3 * 110, 3 * 110 * 256, 3 * 110 * 256, 0, 0)
-    assert ContinuousBatchingEngine._state_rows_of(eng, batch) == (
-        3 * 2, 2 * 3 * 2 * state, 3 * 3 * 3 * state)
+    # (XLA's form on this backend: three passes over all 3 slots' states)
+    assert step[9:12] == (3 * 2, 2 * 3 * 2 * state, 3 * 3 * 3 * state)
 
 
 def test_the_kernel_counts_the_live_slots_states_alone(monkeypatch):
@@ -408,10 +402,11 @@ def test_the_kernel_counts_the_live_slots_states_alone(monkeypatch):
     cache = jax.eval_shape(functools.partial(init_slot_cache, wide, 3, 64))
     per = position_bytes(wide)["ssm"]
     assert per == 16 * 128 * 128 * 4 + 3 * (16 * 128 + 2 * 2 * 128) * 2
-    assert state_fetched(cache, wide)(2) == 3 * 3 * 3 * per
+    fetched = lambda live: CacheTraffic(cache, wide, CHUNK).step(
+        (5,) * live).state_bytes_fetched
+    assert fetched(2) == 3 * 3 * 3 * per
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    assert [state_fetched(cache, wide)(n) for n in (2, 0)] == [
-        2 * 3 * 2 * per, 0]
+    assert [fetched(n) for n in (2, 0)] == [2 * 3 * 2 * per, 0]
 
 
 def test_the_ssm_scope_stands_around_parts_of_the_model(world):
@@ -626,17 +621,12 @@ def test_prefix_replay_and_a_full_cache_with_a_layer_that_has_both(world):
     programs goes on with the tokens it would have had; a prompt the cache
     cannot hold, and one that ends within a chunk of its end, are refused
     with what is the matter."""
-    eng = types.SimpleNamespace(
-        _window=0, _conv_layers=0, _kda_layers=0, _ssm_layers=3,
-        _eva_layers=0, _capacity=MAX_LEN, _block=0,
-        ecfg=types.SimpleNamespace(prefill_chunk_tokens=CHUNK),
-        _donors={0: types.SimpleNamespace(pos=40),
-                 1: types.SimpleNamespace(pos=41)})
-    exact = functools.partial(ContinuousBatchingEngine._prefix_exact, eng)
-    assert exact(0, 40, 100)
-    assert not exact(1, 40, 100)        # it has decoded one token past
-    assert not exact(2, 40, 100)        # no such donor
-    assert not exact(0, 40, MAX_LEN - 1)    # a chunk window set back
+    exact = functools.partial(prefix_holds, world.cfg, chunk=CHUNK,
+                              capacity=MAX_LEN)
+    assert exact(40, 40, 100)
+    assert not exact(41, 40, 100)       # it has decoded one token past
+    assert not exact(None, 40, 100)     # no such donor
+    assert not exact(40, 40, MAX_LEN - 1)   # a chunk window set back
     w = world
     core = _core(w, max_slots=1, prefix_cache_min_tokens=4,
                  token_queue_depth=2)

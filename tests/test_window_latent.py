@@ -37,17 +37,15 @@ import pytest
 
 from perfbench import manifest as mf
 from perfbench.tools import rehearse
-from ray_tpu.models import (TransformerConfig, cache_gather_slot,
-                            cache_insert_slot, decode_step_slots, forward,
-                            init_kv_cache, init_params, init_slot_cache,
-                            prefill, prefill_chunk_jit,
-                            prefill_lanes_jit)
+from ray_tpu.models import (CacheTraffic, TransformerConfig,
+                            cache_gather_slot, cache_insert_slot,
+                            decode_step_slots, forward, init_kv_cache,
+                            init_params, init_slot_cache, prefill,
+                            prefill_chunk_jit, prefill_lanes_jit)
 from ray_tpu.models.generate import (_check_decodable, _state_kind,
-                                     cache_bytes, cache_rows,
-                                     chunk_rows_fetched, position_bytes,
+                                     cache_bytes, cache_rows, position_bytes,
                                      prefill_chunk_step, prefill_lanes,
-                                     prefill_lanes_step, rows_fetched,
-                                     window_ring)
+                                     prefill_lanes_step, window_ring)
 from ray_tpu.models.transformer import (count_params, decode_flops_per_token,
                                         flops_per_token, stack_kinds)
 from ray_tpu.ops import latent_attention as mla
@@ -319,31 +317,38 @@ def test_the_kernel_attends_a_ring_by_the_position_a_column_holds(
 
 
 def test_the_hosts_counts_of_ring_rows_are_the_masks_own(world, monkeypatch):
-    """`_latent_seen` (what `rows_fetched` and `chunk_rows_fetched` count a
-    window layer's kernel by) is `sparse_index.rows_seen` of the mask the
-    program builds, for steps and chunks, before, at and past the seam; and
-    with the kernel engaged the counters add the two kinds' tiles."""
+    """The last column `CacheTraffic` counts a window layer's kernel by
+    (`_ring_end` of what `_seen` says a step's or a chunk's queries see) is
+    `sparse_index.rows_seen` of the mask the program builds, for steps and
+    chunks, before, at and past the seam; and with the kernel engaged the
+    counters add the two kinds' tiles."""
     cfg = dataclasses.replace(world.cfg, sliding_window=70)
+    traffic = CacheTraffic(jax.eval_shape(
+        lambda: init_slot_cache(cfg, 2, MAX_LEN)), cfg, CHUNK)
+    ring = traffic._sets["kv_win"]
+    assert ring.size == RING
     for pos in (0, 5, 69, 70, 200, 255, 256, 300, 320, 511, 512, 700):
         for n in (1, 32):
             mask = generate._ring_mask(jnp.asarray(pos), n, RING, 70)
-            assert int(sparse_index.rows_seen(mask)) == \
-                generate._latent_seen(cfg, "window", pos, n, RING), (pos, n)
-    assert generate._latent_seen(cfg, "index", 41, 32, MAX_LEN) == 73
+            (first,), (rows,) = traffic._seen(ring, (pos,), n)
+            assert int(sparse_index.rows_seen(mask)) == generate._ring_end(
+                first, rows, RING), (pos, n)
+    assert traffic._seen(traffic._sets["kv"], (41,), 32) == ([0], [73])
     # latents of whole lanes and a ring of three blocks (a ring of ONE
     # block of the kernel's is read whole by XLA's forms), so that the
     # kernel takes both kinds' arrays
     cfg = _kernel_shapes(world.cfg)
     cache = init_slot_cache(cfg, 2, MAX_LEN_K)
     assert cache["kv_win"].shape[-1] == RING_K
-    dense = rows_fetched(cache, cfg)
-    assert dense([7, 300]) == 2 * (3 * MAX_LEN_K + 3 * RING_K)
-    lanes_dense = chunk_rows_fetched(cache, cfg, CHUNK)
-    assert lanes_dense(300, 20) == (3 * MAX_LEN_K + 3 * RING_K,
+    dense = CacheTraffic(cache, cfg, CHUNK)
+    assert dense.step([7, 300]).rows_fetched == 2 * (3 * MAX_LEN_K
+                                                     + 3 * RING_K)
+    assert dense.chunk(300, 20) == (3 * MAX_LEN_K + 3 * RING_K,
                                     3 * 320 + 3 * (20 + WINDOW - 1))
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    step, chunk = rows_fetched(cache, cfg), chunk_rows_fetched(cache, cfg,
-                                                               CHUNK)
+    engaged = CacheTraffic(cache, cfg, CHUNK)
+    step = lambda positions: engaged.step(positions).rows_fetched
+    chunk = engaged.chunk
     # a slot at 7 moves a tile of each array a layer; one at 300 three
     # tiles of 128 of each; at 390 its window lies astride the ring's seam
     # (378 .. 390: the whole ring), at 400 past it (the first tile)
@@ -518,11 +523,13 @@ def test_a_shared_prefix_from_a_donor_inside_and_past_its_window(world):
     decoded on past it is refused (its ring has moved on) and the prompt
     prefills from its start; either way the tokens are the reference's."""
     w = world
+    # (a queue of 2: the donor stands where its CALLER left it, at most 10 +
+    # the token drained + the two queued, whatever the engine thread's lead)
     core = _core(w, max_slots=2, prefix_cache=True,
-                 prefix_cache_min_tokens=4)
+                 prefix_cache_min_tokens=4, token_queue_depth=2)
     try:
         short = np.asarray(w.toks[0, :10]).tolist()
-        got = _stream(core, short, 2)           # stands at 11: inside 13
+        got = _stream(core, short, 2)           # stands at 11-13: inside 13
         assert got == _forced(w, short, got)
         hits = core.engine.stats()["prefix"]["applied_hits"]
         fork = short[:8] + np.asarray(w.toks[1, 30:50]).tolist()
