@@ -19,6 +19,10 @@ def train_loop(config):
                                  attention_impl="reference",
                                  dtype=jnp.float32)
     params, axes = init_params(jax.random.PRNGKey(0), cfg)
+    # the rules cut every matrix (embed -> fsdp, heads / mlp / vocab -> tp)
+    # and leave every vector whole on each chip: a norm's scale or bias cut
+    # four ways saves a few KB and costs a blocking gather at every use and
+    # a blocking sum of its gradient in every layer (parallel/sharding.py)
     params = jax.device_put(params,
                             pytree_shardings(axes, mesh, FSDP_TP_RULES))
     opt = optax.adamw(1e-3)

@@ -1380,8 +1380,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
             functools.partial(lm_loss, cfg=cfg))(params, batch)
 
     def step(params, opt_state, batch):
-        import optax
-
+        # (parameters and state go back laid out as they came: `_stepped`,
+        # at the file's end for the sake of every line number below)
         if accum_steps > 1:
             full = batch["tokens"].shape[0]
             if full % accum_steps:
@@ -1433,8 +1433,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, accum_steps: int = 1):
         else:
             loss, grads = grad_fn(params, batch)
         with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            updates, state = optimizer.update(grads, opt_state, params)
+            params, opt_state = _stepped(params, updates, state, opt_state)
             gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                                  for g in jax.tree_util.tree_leaves(grads)))
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
@@ -1988,3 +1988,25 @@ def ssm_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
             return (mla.times(jnp.einsum("bse,ed->bsd", o.astype(dt),
                                        lp["ssm_out"].astype(dt)),
                             cfg.ssm_out_scale), state, conv)
+
+
+def _stepped(params, updates, state, given):
+    """A step's results: ``params`` with ``updates`` applied and the
+    optimizer's new ``state``, each leaf held to the sharding of the leaf it
+    replaces (of ``params``, of the state ``given``).  A jit with no
+    ``out_shardings`` leaves the partitioner free to return a leaf cut
+    another way than it came (a replicated norm scale, updated elementwise,
+    comes back cut over ``fsdp`` on the CPU's eight devices), and a loop
+    that calls the compiled step on its own results is then refused, or
+    compiles a second program.  With no mesh (`jax.set_mesh` around the
+    jit, as for `_flash_per_shard`) there is one layout, and nothing is
+    added to the program.  (Down here, and two lines where two stood in
+    `make_train_step`: `scan_layer_runs`' note on the line numbers.)"""
+    import optax
+    new = (optax.apply_updates(params, updates), state)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or math.prod(dict(mesh.shape).values() or (1,)) == 1:
+        return new
+    from jax.experimental.shard_alike import shard_alike
+    return jax.tree_util.tree_map(lambda n, g: shard_alike(n, g)[0],
+                                  new, (params, given))

@@ -7,6 +7,19 @@ a first-class framework service.  Model code annotates every parameter with
 `ShardingRules` table maps logical names → mesh axes.  Swapping DP for FSDP
 for 2D FSDP×TP is a rules change, not a model change — the idiomatic
 pjit/GSPMD recipe from the scaling playbook.
+
+What the rules leave WHOLE: a parameter that is a vector (`pytree_shardings`:
+a leaf whose logical axes, a leading "layers" apart, name ONE dimension: a
+norm's scale and bias, ``("layers", "embed")`` / ``("embed",)``, a sink, a
+router's bias) is replicated whatever its axis maps to.  Cutting 1600 floats
+four ways saves 4.8 KB a chip, and the partitioner then has to gather the
+vector at every use, forward and recomputed, in every layer of every
+micro-batch, each a blocking collective inside the layer loop (two of them
+stood 47 ms in gpt2-xl's 1115 ms step under fsdp=4 for under 1 MB of
+payload; most of that was the wait for the slowest chip, which the next
+collective inherited when they went: PERF.md, PR 58).  Matrices
+(``("embed", None)``, ``("vocab", "embed")``, ...) are cut as the table says;
+the stacking axis keeps what the rules give it (``layers="pp"``).
 """
 
 from __future__ import annotations
@@ -129,15 +142,29 @@ def _drop_nondividing_axes(spec: P, mesh: Mesh, shape) -> P:
     return P(*(fix(e, d) for e, d in zip(entries, shape)))
 
 
+def _whole_vectors(logical_axes: Optional[Sequence[str]]
+                   ) -> Optional[Sequence[str]]:
+    """A parameter's logical axes with a vector's one dimension unnamed (the
+    module's note on what the rules leave whole); any other leaf's as they
+    are."""
+    if logical_axes is None \
+            or sum(a != "layers" for a in logical_axes) != 1:
+        return logical_axes
+    return tuple(a if a == "layers" else None for a in logical_axes)
+
+
 def pytree_shardings(params_axes: Any, mesh: Mesh,
                      rules: Mapping[str, MeshAxis],
                      params: Any = None) -> Any:
-    """Map a tree of logical-axis tuples → tree of NamedShardings.
+    """Map a tree of logical-axis tuples → tree of NamedShardings.  A
+    vector leaf comes back replicated (`_whole_vectors`).
 
     With ``params`` given, each leaf's sharding is validated against its
     shape and non-dividing mesh axes degrade to replication (GQA kv heads
     under tp>n_kv_heads, odd vocab under wide tp, …)."""
     is_axes_leaf = lambda x: x is None or isinstance(x, tuple)
+    params_axes = jax.tree_util.tree_map(_whole_vectors, params_axes,
+                                         is_leaf=is_axes_leaf)
     if params is None:
         return jax.tree_util.tree_map(
             lambda ax: named_sharding(mesh, ax, rules),

@@ -256,6 +256,166 @@ def test_flash_kernel_compiles_per_shard_on_the_2x2(topo):
     assert text.count("tpu_custom_call") >= 2          # fwd, bwd
 
 
+def _computations(text):
+    """HLO text -> {computation: its instruction lines}."""
+    import re
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+_CALLS = r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)"
+_COLLECTIVE = (r"= (\S.*?) (all-reduce|all-gather|reduce-scatter|"
+               r"collective-permute|all-to-all)(?:-start)?\((.*?)\)")
+
+
+def _loop_bodies(text):
+    """{while body: the lines of it and of what it calls, fusions included,
+    an inner loop's body excluded (it is a body of its own)}."""
+    import re
+    comps = _computations(text)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+
+    def lines_of(name, seen):
+        out = list(comps.get(name, ()))
+        for line in comps.get(name, ()):
+            for callee in re.findall(_CALLS, line):
+                if callee not in seen and callee not in bodies:
+                    seen.add(callee)
+                    out += lines_of(callee, seen)
+        return out
+
+    return {b: lines_of(b, {b}) for b in bodies}
+
+
+def _gpt2_step_text(topo, mesh_spec):
+    """The train step of a small GPT-2 shape (layernorm with biases,
+    learned positions, tied embedding, full remat, the flash kernel, 2
+    micro-batches) as the chip's compiler leaves it: under ``mesh_spec`` on
+    the described 2x2, or with None on one chip."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import (TransformerConfig, init_params,
+                                make_train_step)
+    from ray_tpu.parallel import (FSDP_TP_RULES, MeshSpec, batch_sharding,
+                                  create_mesh, pytree_shardings)
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=4, n_heads=4, max_seq_len=256,
+        pos_emb="learned", activation="gelu", norm="layernorm",
+        tie_embeddings=True, remat=True, attention_impl="flash")
+    noted = {}
+
+    def note():
+        p, noted["axes"] = init_params(jax.random.PRNGKey(0), cfg)
+        return p
+
+    shapes = jax.eval_shape(note)
+    axes = noted["axes"]
+    if mesh_spec is None:
+        mesh, one = None, SingleDeviceSharding(topo.devices[0])
+        sh = jax.tree.map(lambda _: one, shapes)
+        rep = batch = one
+    else:
+        mesh = create_mesh(MeshSpec.parse(mesh_spec), devices=topo.devices)
+        sh = pytree_shardings(axes, mesh, FSDP_TP_RULES)
+        rep, batch = NamedSharding(mesh, P()), \
+            batch_sharding(mesh, FSDP_TP_RULES)
+    params = jax.tree.map(lambda s, d: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=d), shapes, sh)
+    opt = optax.adamw(3e-4, weight_decay=0.1)
+    adam, *rest = jax.eval_shape(opt.init, params)
+    # a moment lies as its parameter does (an eager `opt.init` on placed
+    # parameters, as the benchmark's), the count on every chip
+    opt_state = (type(adam)(
+        count=jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+        mu=params, nu=params), *rest)
+    tokens = jax.ShapeDtypeStruct((16, 256), jnp.int32, sharding=batch)
+    step = jax.jit(make_train_step(cfg, opt, accum_steps=2),
+                   donate_argnums=(0, 1))
+    with jax.set_mesh(mesh) if mesh is not None \
+            else contextlib.nullcontext():
+        compiled = step.lower(params, opt_state, {"tokens": tokens}).compile()
+    return compiled, cfg
+
+
+def _vector_gathers(text, d):
+    """The collectives inside loop bodies that GATHER a vector of ``d``
+    numbers from its shards: an all-gather of one, or the partitioner's
+    form for a small array, an all-reduce over shards each written into
+    zeros (`dynamic-update-slice`).  (A gradient's sum over the chips is an
+    all-reduce of a whole vector, and is not one of these.)"""
+    import re
+    found = []
+    for body, lines in _loop_bodies(text).items():
+        made = {m.group(1): m.group(2) for m in (
+            re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(", ln)
+            for ln in lines) if m}
+        for ln in lines:
+            m = re.search(_COLLECTIVE, ln)
+            if not m:
+                continue
+            sizes = {math.prod(int(n) for n in dims.split(","))
+                     for dims in re.findall(r"\[([\d,]+)\]", m.group(1))}
+            if sizes != {d}:
+                continue
+            operands = re.findall(r"%([\w.\-]+)", m.group(3))
+            if m.group(2) == "all-gather" or any(
+                    made.get(o) == "dynamic-update-slice" for o in operands):
+                found.append(ln.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("cut_vectors", [False, True],
+                         ids=["vectors-whole", "control-vectors-cut"])
+def test_sharded_train_step_gathers_no_vector_in_its_loops(topo, monkeypatch,
+                                                           cut_vectors):
+    """Under fsdp=4 on the described 2x2 no loop body of the train step
+    (micro-batches, layers forward, layers backward) gathers a norm's scale
+    or bias: `pytree_shardings` leaves a vector whole on every chip.  The
+    control cuts them as the rules did before PR 58 (like a matrix's rows)
+    and finds the gathers (`all-reduce.92` / `.93` of gpt2-xl's step;
+    PERF.md, PR 58)."""
+    import jax
+
+    from ray_tpu.parallel import sharding
+    if cut_vectors:
+        monkeypatch.setattr(sharding, "_whole_vectors", lambda ax: ax)
+    compiled, cfg = _gpt2_step_text(topo, "fsdp=4")
+    found = _vector_gathers(compiled.as_text(), cfg.d_model)
+    assert bool(found) == cut_vectors, found
+    if not cut_vectors:
+        # the step hands back every parameter and moment as it was given
+        # it: the benchmark calls the compiled step on its own results
+        ins, outs = compiled.input_shardings[0], compiled.output_shardings
+        for given, made in zip(ins[:2], outs[:2]):
+            assert [s.is_fully_replicated for s in jax.tree.leaves(given)] \
+                == [s.is_fully_replicated for s in jax.tree.leaves(made)]
+
+
+def test_train_step_with_no_mesh_holds_no_layout_and_no_collective(topo):
+    """With no mesh there is one layout: the step on one chip gets no
+    sharding annotation from `_stepped` and no collective (the one-chip
+    train cell's program is the one it was)."""
+    compiled, _ = _gpt2_step_text(topo, None)
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "all-gather" not in text
+    assert 'custom_call_target="Sharding"' not in text
+    assert text.count("tpu_custom_call") >= 2
+
+
 def test_create_mesh_tpu_branch_on_the_described_2x2(topo):
     """`mesh_utils.create_device_mesh` with all six named axes, four of
     them trivial, on real (described) v5e coordinates."""
