@@ -9,17 +9,19 @@ into two XLA programs (`prefill`, `lax.scan` of `decode_step`).  The
 cache is a pytree of layer-stacked arrays, so pjit shards it with the
 same logical rules as the parameters (heads → tp, batch → dp).
 
-A cache holds up to SEVEN KINDS OF STATE behind the same functions
+A cache holds up to EIGHT KINDS OF STATE behind the same functions
 (`cache_rows`, `position_bytes`, `cache_bytes`, the slot insert and gather):
 rows for the whole context (``full``), a ring of a window's rows (``ring``),
 a conv layer's last inputs (``state``), a row a chunk of positions
 (``summary``), an indexer's keys on the indexing layers alone (``index``), a
 KDA layer's matrix of state a head beside its convolutions' last inputs
-(``delta``) and a state-space mixer's matrix of state a head beside its
+(``delta``), a state-space mixer's matrix of state a head beside its
 convolution's (``ssm``), which ONE LAYER holds together with rows of the
-first kind; they follow one by one below.  A cache's arrays may differ in
-TYPE (`array_dtype`): a delta state and a state-space state are float32
-whatever the model computes in.
+first kind, and a selective scan's state beside its convolution's
+(``mamba``); they follow one by one below.  A cache's arrays may differ in
+TYPE (`array_dtype`): a delta state, a state-space state and a selective
+scan's are float32 whatever the model computes in.  And a LAYER MAY HOLD
+NOTHING: it reads what another layer holds (the last paragraphs).
 
 What a cache holds is a property of the model's attention kind
 (`cache_rows`): keys and values of ``kv_heads x head_dim`` (``"k"``,
@@ -147,6 +149,50 @@ The fused step advances the state where it lies in one kernel call a layer
 ``"full"`` layers (their rows would share an array under two counters:
 `_check_decodable`).
 
+AN EIGHTH KIND OF STATE IS A DECAY A CHANNEL A COLUMN: a ``"mamba"`` layer
+(`transformer.mamba_operator`, `ops/selective_scan.py`: Mamba-1) carries
+``s_mamba`` ``[L_mamba, batch, 1, state, channels]`` in FLOAT32 (channels
+last, where the device wants its lanes) and the last ``taps - 1`` inputs of
+its convolution, ``conv_mamba`` ``[L_mamba, batch, 1, taps - 1, channels]``
+in the model's type: 328 KB and 31 KB a slot a layer at 5120 channels with a
+state of 16, whatever ``max_len``.  It lives by the conv state's rules (a
+row advances by its VALID tokens only, a standing slot keeps both arrays bit
+for bit, `_check_state_rewind`, `prefix_holds` only from a donor that stands
+AT the prefix).  A chunk scans by a kernel that holds the state on the chip
+across the chunk's tokens (`selective_scan.chunk`), a step is an elementwise
+update of the layer of the stack where it lies.  Beside its state the layer
+makes a VALUE THAT LATER LAYERS CONSUME: its scan output ``m`` (before the
+gate) rides down `_scan_cached`'s carry (`transformer.handed`) as an
+indexer's choice does, and every ``"gmu"`` layer behind it gates it, position
+by position: a gated memory unit reads no cache at all.
+
+A ROW SET MAY HAVE MORE READERS THAN HOLDERS: a ``"cross"`` layer projects
+queries of its own and attends the keys and values of the LAST FULL LAYER
+before it, as that layer wrote them in the same program (its own new row
+included): it holds no array, writes no column, and its layer counter in
+`_scan_cached` is that full layer's.  So ``k`` / ``v`` have a leading axis
+of ONE for eight readers, and `CacheTraffic` tells the layers that HOLD a
+row set (its array's leading axis: capacity, the step's column writes) from
+the layers that READ it (`_RowSet.layers`: a step's rows and bytes, the
+blocks a kernel moves; ``shared_bytes_read``).  A step of such a model reads
+the one array a reading layer each, so its one query a slot goes through
+`ops/cache_attention.py` `attend_blocks` (`_shared_rows`): a slot's visible
+blocks alone, where dense dots would read ``max_len`` rows of every slot
+eight times.  Where an MHA/GQA block is DIFFERENTIAL (``diff_attn``) a cached
+row holds a PAIR's two keys side by side (`TransformerConfig.key_dim`) and
+its value is as wide: both softmax maps of a pair and its values come from
+ONE pass over the blocks, the queries zero-padded each to its half of the
+row (`transformer._pair_queries`) under the score scale of one head.
+
+THE STATELESS TAIL: the layers BEHIND the last one that holds state
+(`TransformerConfig.stateless_tail`: trailing ``"gmu"`` and ``"cross"``
+layers) write nothing a later token reads.  A chunk program wants ONE row's
+logits a lane (row ``n_valid - 1``), so `_prefill_chunk` and `_prefill_lanes`
+run the layers up to the last state on all ``C`` rows and the tail on that
+one row of the stream and of the memory alone (`_tail_on_one_row`: two spans
+of the one layer loop): 14 of 32 layers on 1 row where they ran on 128.
+Exact, and it follows from the layer kinds, not from a model's name.
+
 A MODEL NEED HAVE NO FULL LAYER: where none holds ``max_len`` rows, the
 summary arrays say it (`cache_capacity`, which then needs the model's
 ``summary_chunk``), and nothing stands in for a full layer.  What still
@@ -207,12 +253,14 @@ from ..ops.attention import sink_softmax
 from ..ops.cache_write import device_calls, write_columns
 from ..ops.rotary import apply_rotary, rotary_angles
 from ..ops.short_conv import conv_block, conv_inputs, short_conv
-from .transformer import (ATTENTION_KINDS, SPARSE_KINDS, SSM_KINDS,
-                          TransformerConfig, _attn_out, _ffn, _layer, _norm,
-                          _post, _qkv, _scale_embedding,
-                          _ssm_widths, _unembed, check_kinds, head_gate,
-                          index_inputs, kda_operator, latent_queries,
-                          latent_rows, latent_scope, latent_weights, norm_eps,
+from .transformer import (ATTENTION_KINDS, READER_KINDS, SPARSE_KINDS,
+                          SSM_KINDS, TransformerConfig, _attn_out, _ffn,
+                          _layer, _norm, _post, _qkv, _scale_embedding,
+                          _ssm_widths, _unembed, attention_scale, check_kinds,
+                          cross_scope, gmu_operator, hand_on, handed,
+                          head_gate, index_inputs, kda_operator,
+                          latent_queries, latent_rows, latent_scope,
+                          latent_weights, mamba_operator, norm_eps,
                           rope_tables, scan_layer_runs, ssm_operator)
 
 Params = Any
@@ -233,8 +281,11 @@ _SSM = "_ssm"       # suffix of a state-space mixer's arrays: no positions
 _SSM_STATE = "s" + _SSM             # a matrix a head, float32
 _SSM_CONV = "conv" + _SSM           # its convolution's last inputs
 _SSM_ARRAYS = (_SSM_STATE, _SSM_CONV)
+_MAMBA = "_mamba"   # suffix of a selective scan's arrays: no positions
+_MAMBA_STATE = "s" + _MAMBA         # columns x channels a sequence, float32
+_MAMBA_CONV = "conv" + _MAMBA       # its convolution's last inputs
 #: the kinds of state that hold no positions: a sequence's whatever its length
-_NO_POSITIONS = ("state", "delta", "ssm")
+_NO_POSITIONS = ("state", "delta", "ssm", "mamba")
 _SUM_NAMES = ("k" + _SUMMARY, "v" + _SUMMARY)
 #: rows a ring of latents is a whole number of (`window_ring`)
 LATENT_RING_BLOCK = 128
@@ -242,8 +293,11 @@ _INDEX_ARRAY = "k" + _INDEX
 #: the kinds of layer whose arrays hold a row a position for the whole
 #: context, written and masked alike
 _ROW_KINDS = ("full",) + SPARSE_KINDS + SSM_KINDS
+#: ... and those that attend such rows under the same mask, their own or (a
+#: ``"cross"`` layer) the last full layer's
+_CONTEXT_KINDS = _ROW_KINDS + ("cross",)
 #: the arrays that are a float32 matrix a head, whatever the model's type
-_MATRIX_STATES = (_DELTA_STATE, _SSM_STATE)
+_MATRIX_STATES = (_DELTA_STATE, _SSM_STATE, _MAMBA_STATE)
 
 
 def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
@@ -262,6 +316,9 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
     if set(cfg.kinds) & set(SSM_KINDS):     # (state, dims last) a head; taps
         state.update({_SSM_STATE: (cfg.ssm_heads, cfg.ssm_state),
                       _SSM_CONV: (1, cfg.ssm_conv_kernel - 1)})
+    if "mamba" in cfg.kinds:    # (columns, channels last); taps
+        state.update({_MAMBA_STATE: (1, cfg.mamba_state),
+                      _MAMBA_CONV: (1, cfg.mamba_conv_kernel - 1)})
     if cfg.attention == "mla":
         rows = dict(state)
         for kind in ("full", "window"):     # a kind's own latent beside
@@ -274,9 +331,10 @@ def cache_rows(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
         return rows
     rows = {}
     for kind in ATTENTION_KINDS:
-        if kind in cfg.kinds:
+        if kind in cfg.kinds and kind not in READER_KINDS:  # (a reader
+            # holds nothing: it attends the rows a full layer holds)
             hk = cfg.kv_heads_of(kind)
-            row = ((hk, cfg.head_dim), (hk, cfg.value_dim))
+            row = ((hk, cfg.key_dim), (hk, cfg.value_dim))
             rows.update(zip(_kv_names(kind), row))
             if kind == "eva":   # a pooled key and value a chunk, as wide
                 rows.update(zip(_SUM_NAMES, row))
@@ -315,7 +373,9 @@ def _own_rows(cfg: TransformerConfig, name: str) -> Optional[int]:
     return {_CONV_STATE: cfg.d_model, _DELTA_STATE: cfg.kda_head_dim,
             _DELTA_CONV: 3 * cfg.kda_heads * cfg.kda_head_dim,
             _SSM_STATE: cfg.ssm_head_dim,
-            _SSM_CONV: _ssm_widths(cfg)[1]}.get(name)
+            _SSM_CONV: _ssm_widths(cfg)[1],
+            _MAMBA_STATE: cfg.mamba_inner,
+            _MAMBA_CONV: cfg.mamba_inner}.get(name)
 
 
 def _latent_name(kind: str) -> str:
@@ -326,7 +386,8 @@ def _latent_name(kind: str) -> str:
 
 def _kv_names(kind: str) -> Tuple[str, str]:
     """The key and value arrays of a layer of attention kind ``kind``."""
-    return ("k", "v") if kind in _ROW_KINDS else ("k" + _RING, "v" + _RING)
+    return ("k", "v") if kind in _CONTEXT_KINDS \
+        else ("k" + _RING, "v" + _RING)
 
 
 def window_ring(cfg: TransformerConfig, max_len: int) -> int:
@@ -382,7 +443,7 @@ def _chunk_sets(cfg: TransformerConfig, arrays: Arrays, kind: str, b: int,
     sets = [tuple(arrays[n] for n in _kv_names(kind)) + (None,)]
     if kind == "eva":
         sets.append(tuple(arrays[n] for n in _SUM_NAMES) + (None,))
-    return (b, c, hk, cfg.n_heads // hk, cfg.head_dim), sets
+    return (b, c, hk, cfg.n_heads // hk, cfg.key_dim), sets
 
 
 def _blocks_moved(cfg: TransformerConfig, arrays: Arrays, kind: str, c: int,
@@ -395,11 +456,22 @@ def _blocks_moved(cfg: TransformerConfig, arrays: Arrays, kind: str, c: int,
     it moves of a set of ``size`` rows to attend the ``n`` from ``first`` on,
     the whole blocks that hold one, the host's count of the kernel's work
     list; None where dense dots read the set."""
-    if (c > 1 or kind == "eva") and cache_attention.engages(
-            *_chunk_sets(cfg, arrays, kind, 1, c), kind in cfg.sink_kinds):
+    if (c > 1 or kind == "eva" or kind in _shared_rows(cfg)) \
+            and cache_attention.engages(
+                *_chunk_sets(cfg, arrays, kind, 1, c),
+                kind in cfg.sink_kinds):
         return lambda first, n: cache_attention.BLOCK \
             * cache_attention.fetched_blocks(first % size, n, size)
     return None
+
+
+def _shared_rows(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The kinds of layer whose rows MORE LAYERS READ THAN HOLD (a full
+    layer's, where ``"cross"`` layers attend them too): a decode step of
+    such a model reads them a reading layer each, so its one query a slot
+    goes through the kernel that fetches a slot's visible blocks alone
+    (`ops/cache_attention.py` `attend_blocks`), as a summary layer's does."""
+    return ("full", "cross") if "cross" in cfg.kinds else ()
 
 
 def _tiles_moved(cfg: TransformerConfig, kv, kind: str, c: int):
@@ -434,7 +506,7 @@ class _RowSet(NamedTuple):
     the values beside it, of latents, of an indexer's keys, a state's taps."""
     kind: str       # `position_bytes`' state kind of a row of it
     sees: str       # which of its rows a query sees (`CacheTraffic._seen`)
-    layers: int
+    layers: int     # the layers that READ it (those that hold it: its array)
     size: int       # rows a slot a layer
     most: int       # ... of which a query attends no more than (a choice)
     # rows a kernel moves of it for what a step's query / a chunk's queries
@@ -458,6 +530,11 @@ _ATTENDS = {
     "eva": (("ring", "block"), ("summary", "pooled")),
     "conv": (("state", "taps"),),
     "kda": (),
+    # a selective scan's state is no rows either; a gated memory unit reads
+    # no cache at all; a cross layer attends the rows a FULL layer holds
+    "mamba": (),
+    "gmu": (),
+    "cross": (("full", "context"),),
 }
 
 
@@ -497,6 +574,9 @@ class StepSums(NamedTuple):
     # that write them (one kernel call an array a layer, or a slice a column)
     column_writes: int
     column_write_calls: int
+    # of ``bytes_read``, the rows of a set that more layers READ than HOLD (a
+    # full layer's rows that ``"cross"`` layers attend too), a reader each
+    shared_bytes_read: int
 
 
 class CacheTraffic:
@@ -522,11 +602,14 @@ class CacheTraffic:
         latent = cfg.attention == "mla"
         self._window, self._pooled = cfg.sliding_window, cfg.summary_chunk
         self._chunk, self._layers = chunk, cfg.n_layers
+        self._tail = cfg.stateless_tail
         self._slots = next(iter(arrays.values())).shape[1]
         self._widest = max(per.get(k, 0) for k in ("full", "ring", "summary"))
         self._latent = latent
         self._sets: Dict[str, _RowSet] = {}     # by the array that holds it
         self._row_bytes: Dict[str, int] = {}    # by state kind
+        self._shared: Tuple[str, ...] = ()      # state kinds of the sets
+        #   that more layers read than hold
         for kind in dict.fromkeys(cfg.kinds):
             rows_in = _latent_name(kind) if latent else _kv_names(kind)[0]
             for state, sees in _ATTENDS[kind]:
@@ -534,7 +617,13 @@ class CacheTraffic:
                 name = rows_in if rows else {
                     "summary": _SUM_NAMES[0], "state": _CONV_STATE,
                     "index": _INDEX_ARRAY}[state]
-                if name in self._sets:      # (an array the row kinds share)
+                if name in self._sets:      # (an array the row kinds share:
+                    # one more kind's layers read it)
+                    s = self._sets[name]
+                    self._sets[name] = s._replace(
+                        layers=s.layers + cfg.kinds.count(kind))
+                    if self._sets[name].layers > arrays[name].shape[0]:
+                        self._shared += (state,)
                     continue
                 a = arrays[name]
                 size = a.shape[-2 if sees == "taps" else -1]
@@ -548,7 +637,7 @@ class CacheTraffic:
                 else:
                     moved = [None, None]
                 self._sets[name] = _RowSet(
-                    state, sees, a.shape[0], size,
+                    state, sees, cfg.kinds.count(kind), size,
                     cfg.index_topk if state == "full" and cfg.index_topk
                     else size, *moved)
                 # (a state's bytes are its taps' together)
@@ -566,7 +655,8 @@ class CacheTraffic:
         self._in_place = self._standing = 0
         for name, engages in ((_DELTA_STATE, delta_rule.engages), (
                 _SSM_STATE, functools.partial(ssd.engages,
-                                              groups=cfg.ssm_groups))):
+                                              groups=cfg.ssm_groups)),
+                (_MAMBA_STATE, lambda *_: False)):  # (XLA's form alone)
             if name in arrays:
                 layers = arrays[name].shape[0]
                 held = layers * per[_state_kind(name)]
@@ -624,7 +714,8 @@ class CacheTraffic:
             scored, nbytes.get("index", 0),
             nbytes.get("ring", 0) if self._latent else 0,
             live * self._state_layers, 2 * live * self._state_bytes,
-            live * self._in_place + self._standing, fetched, *self._writes)
+            live * self._in_place + self._standing, fetched, *self._writes,
+            sum(nbytes.get(kind, 0) for kind in self._shared))
 
     def chunk(self, pos: int, n_valid: int) -> Tuple[int, int]:
         """ONE lane of a chunk program that feeds ``n_valid`` real tokens
@@ -643,6 +734,16 @@ class CacheTraffic:
                 fetched += s.layers * (
                     s.size if s.chunk is None else s.chunk(first, n))
         return fetched, read
+
+
+    def tail_rows(self, lanes: int) -> int:
+        """The rows on which ONE chunk program of ``lanes`` lanes runs the
+        model's stateless tail (`TransformerConfig.stateless_tail`: the
+        layers behind the last one that holds state): one a lane, the row
+        whose logits the program hands out, of the ``lanes x chunk`` it
+        feeds; 0 for a model without such a tail."""
+        return lanes * (1 if self._chunk > 1 else self._chunk) \
+            if self._tail else 0
 
 
 def chunk_room(cfg: TransformerConfig, max_len: int) -> Tuple[int, int]:
@@ -690,7 +791,7 @@ def prefix_holds(cfg: TransformerConfig, donor_pos: Optional[int],
     prefills from its start."""
     kinds = set(cfg.kinds)
     window = cfg.sliding_window if "window" in kinds else 0
-    stateful = kinds & {"conv", "kda", *SSM_KINDS}
+    stateful = kinds & {"conv", "kda", "mamba", *SSM_KINDS}
     if not window and not stateful and "eva" not in kinds:
         return True
     if donor_pos is None:
@@ -711,7 +812,8 @@ def _state_kind(name: str) -> str:
     ``delta`` | ``ssm``: what kind of state an array is."""
     for suffix, kind in ((_RING, "ring"), (_STATE, "state"),
                          (_SUMMARY, "summary"), (_INDEX, "index"),
-                         (_DELTA, "delta"), (_SSM, "ssm")):
+                         (_DELTA, "delta"), (_SSM, "ssm"),
+                         (_MAMBA, "mamba")):
         if name.endswith(suffix):
             return kind
     return "full"
@@ -728,7 +830,7 @@ def _init_cache(cfg: TransformerConfig, batch: int, max_len: int,
               "index": (("index",), max_len),
               "ring": (("window", "eva"), window_ring(cfg, max_len)),
               "state": (("conv",), None), "delta": (("kda",), None),
-              "ssm": (SSM_KINDS, None),
+              "ssm": (SSM_KINDS, None), "mamba": (("mamba",), None),
               "summary": (("eva",), max_len // max(1, cfg.summary_chunk))}
     for name, (heads, width) in cache_rows(cfg).items():
         kinds, rows = stacks[_state_kind(name)]
@@ -760,12 +862,12 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "table")
     kinds = set(cfg.kinds)
     if len(cfg.kinds) != cfg.n_layers or \
-            kinds - {"full", "window", "conv", "eva", "kda", *SPARSE_KINDS,
-                     *SSM_KINDS}:
+            kinds - {"full", "window", "conv", "eva", "kda", "mamba",
+                     *READER_KINDS, *SPARSE_KINDS, *SSM_KINDS}:
         raise ValueError(f"layer_kinds {cfg.layer_kinds!r}: expected "
                          f"{cfg.n_layers} of 'full' | 'window' | 'conv' | "
-                         f"'eva' | 'kda' | 'ssm+full', or of 'index' | "
-                         f"'shared'")
+                         f"'eva' | 'kda' | 'ssm+full' | 'mamba' | 'gmu' | "
+                         f"'cross', or of 'index' | 'shared'")
     if kinds & set(SSM_KINDS) and "full" in kinds:
         raise NotImplementedError(
             "a model that mixes 'full' and 'ssm+full' layers is not served: "
@@ -803,7 +905,7 @@ def _check_decodable(cfg: TransformerConfig) -> None:
             "key-value heads by layer kind, a sink, a value scale and a "
             "rotated share of a head are MHA/GQA's; latent attention has "
             "none of them")
-    for kind in kinds - {"conv", "kda"}:
+    for kind in kinds - {"conv", "kda", "mamba", "gmu"}:
         if cfg.attention == "mha" and cfg.n_heads % cfg.kv_heads_of(kind):
             raise ValueError(
                 f"{cfg.n_heads} query heads over {cfg.kv_heads_of(kind)} "
@@ -834,9 +936,10 @@ def _check_state_rewind(cfg: TransformerConfig, what: str) -> None:
     no position to mask and no later write that repairs it, so tokens fed
     twice (a chunk window set back at the cache's end) have already shifted
     it; the rows a layer may hold beside such a state do not help it."""
-    if {"conv", "kda", *SSM_KINDS} & set(cfg.kinds):
+    if {"conv", "kda", "mamba", *SSM_KINDS} & set(cfg.kinds):
         raise ValueError(
-            f"{what} over conv, KDA or state-space layers: their state "
+            f"{what} over conv, KDA, state-space or selective-scan layers: "
+            f"their state "
             f"cannot be taken back to an earlier token (models/generate.py)")
 
 
@@ -1032,7 +1135,7 @@ def _ring_write_chunk(pos, c: int, ring: int, lane=0, live=None):
 
 
 def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
-                 cache: KVCache, layer_fn, sel=()):
+                 cache: KVCache, layer_fn, sel=(), span=None):
     """THE layer loop of every program that writes a KV cache.
 
     The whole stacked cache (each array ``[L, B, heads, width, rows]``)
@@ -1053,8 +1156,13 @@ def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     gives loop state the layout its rows are produced in (``head_dim``
     minor) and converts the whole cache before and after the loop.  The
     loop itself is the model's declared pattern (`scan_layer_runs`): the
-    counter runs on from one run of layers into the next.
-    → (x, arrays, load summed over layers)."""
+    counter runs on from one run of layers into the next.  A ``"cross"``
+    layer is handed the count of the LAST FULL layer before it: it reads that
+    layer's rows and has none.  ``span`` (first layer, one past the last;
+    None: all) runs that part of the loop alone, every counter standing where
+    the layers before left it (`_tail_on_one_row`) and what the layers hand
+    on handed back too.
+    → (x, arrays, load summed over layers[, sel])."""
     arrays = cache_arrays(cache)
     row_major = Layout(major_to_minor=tuple(range(5)))
 
@@ -1063,12 +1171,21 @@ def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         arrs = {n: with_layout_constraint(a, row_major)
                 for n, a in arrs.items()}
         l = sum(seen[k] for k in SPARSE_KINDS if k in seen) \
-            if kind in SPARSE_KINDS else seen[kind]
+            if kind in SPARSE_KINDS else seen["full"] - 1 \
+            if kind == "cross" else seen[kind]
         xc, arrs, load_l, sel = layer_fn(xc, lp, arrs, l, kind, sel)
         return (xc, arrs, dict(seen, **{kind: seen[kind] + 1}),
                 tuple(a + b for a, b in zip(load, load_l)), sel)
 
     zero = jnp.zeros((), jnp.int32)
+    if span is not None:    # the layers before the span, by kind
+        before = cfg.kinds[:span[0]]
+        x, arrays, _, load, sel = scan_layer_runs(
+            cfg, params,
+            (x, arrays, {k: zero + before.count(k)
+                         for k in sorted(set(cfg.kinds))}, (zero,) * 3, sel),
+            step, whole_expert_stacks=True, span=span)
+        return x, arrays, load, sel
     x, arrays, _, load, _ = scan_layer_runs(
         cfg, params,
         (x, arrays, dict.fromkeys(sorted(set(cfg.kinds)), zero),
@@ -1165,7 +1282,7 @@ def _by_lane(live: jnp.ndarray, attend, operands) -> jnp.ndarray:
 
 def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
                    cache: KVCache, *, rotate, write, mask, valid=None,
-                   n_new=None, lanes=None):
+                   n_new=None, lanes=None, span=None, sel=None):
     """Run ``x`` [B, C, D] (C new tokens per row) through every layer
     against the cache: each attention layer writes the new tokens' columns
     (``write[kind](c_all, l, cols [B, heads, width, C]) -> c_all``) into
@@ -1192,26 +1309,41 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     `attend_chunk_blocks`, a latent layer's blocked read
     `ops/latent_attention.py` `attend_cache`; `_by_lane` elsewhere);
     everything that reads a weight still runs once over the ``B x C``
-    stacked rows.
-    → (final-norm activations, arrays, load)."""
+    stacked rows.  A ``"mamba"`` layer advances its state as a conv layer
+    its own and hands its scan output down the loop to the ``"gmu"`` layers
+    behind it; a ``"cross"`` layer writes nothing and attends layer ``l`` of
+    the FULL layers' arrays, the last full layer's (`_scan_cached`).
+    ``span`` runs a part of the layers alone, from what the part before
+    handed on (``sel``), and hands back its own: `_tail_on_one_row`.
+    → (final-norm activations, arrays, load) or, of a ``span``, (the stream
+    behind it, final-normed behind the last layer; arrays; load; sel)."""
     dt = cfg.dtype
     b, c, _ = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
+    h, hd, kd = cfg.n_heads, cfg.head_dim, cfg.key_dim
     eps = norm_eps(cfg)
+    shared = _shared_rows(cfg)
+    # (a differential pair's key row is two heads wide, a score one head's)
+    scaled = {} if attention_scale(cfg) is None \
+        else {"scale": attention_scale(cfg)}
 
-    def attend_mha(y, lp, arrs, l, kind):
+    def attend_mha(y, lp, arrs, l, kind, depth=None):
         kn, vn = _kv_names(kind)
         hk = cfg.kv_heads_of(kind)      # the layer's kind's, as its arrays'
         q, k_new, v_new = _qkv(cfg, y, lp, rotate.get(kind), kind)
-        k_all = write[kind](arrs[kn], l, _as_columns(k_new, arrs[kn].dtype))
-        v_all = write[kind](arrs[vn], l, _as_columns(v_new, arrs[vn].dtype))
+        if kind == "cross":     # the rows as the last full layer wrote them
+            k_all, v_all = arrs[kn], arrs[vn]
+        else:
+            k_all = write[kind](arrs[kn], l,
+                                _as_columns(k_new, arrs[kn].dtype))
+            v_all = write[kind](arrs[vn], l,
+                                _as_columns(v_new, arrs[vn].dtype))
         sink = lp["sink"].reshape(hk, h // hk, 1) \
             if kind in cfg.sink_kinds else None
 
         @jax.named_scope("attention")
         def heads(q, ck, cv, m):     # the batch's rows, or one of them
             # GQA: group query heads over kv heads
-            qh = q.reshape(-1, c, hk, h // hk, hd)
+            qh = q.reshape(-1, c, hk, h // hk, kd)
             scores = jnp.einsum("bskgd,bkdt->bskgt", qh,
                                 ck.astype(dt)) / jnp.sqrt(float(hd))
             scores = jnp.where(m[:, :, None, None, :], scores, -1e30)
@@ -1223,7 +1355,7 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         def dense(q, k_all, v_all, m, *live):
             # every row of the arrays, under the mask: a lane cut out at a
             # time (`_by_lane`), or the batch whole
-            if not live:
+            if lanes is None:
                 return heads(q, _layer_of(k_all, l), _layer_of(v_all, l), m)
             return _by_lane(live[0], heads, lambda p: (
                 q[p:p + 1], _lane_of(k_all, l, p), _lane_of(v_all, l, p),
@@ -1233,17 +1365,31 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             # a chunk's queries a lane: the blocks some query of the lane
             # sees, where they lie
             return cache_attention.attend_chunk_blocks(
-                q.reshape(-1, c, hk, h // hk, hd), [(k_all, v_all, m)], l,
-                *live).reshape(-1, c, h, v_all.shape[-2])
+                q.reshape(-1, c, hk, h // hk, kd), [(k_all, v_all, m)], l,
+                *live, **scaled).reshape(-1, c, h, v_all.shape[-2])
+
+        def one_query(q, k_all, v_all, m, *live):
+            # one query a row over rows that several layers read: the blocks
+            # the row sees, where they lie
+            return cache_attention.attend_blocks(
+                q.reshape(-1, 1, hk, h // hk, kd), [(k_all, v_all, m)], l,
+                *live, **scaled).reshape(-1, 1, h, v_all.shape[-2])
 
         operands = (q, k_all, v_all, mask[kind]) \
             + (() if lanes is None else (lanes,))
         if c > 1 and cache_attention.kernel_shape(
                 *_chunk_sets(cfg, arrs, kind, b, c), sink is not None):
             attn = mla.on_the_chip(in_place, dense, *operands)
+        elif c == 1 and kind in shared and cache_attention.kernel_shape(
+                *_chunk_sets(cfg, arrs, kind, b, c)):
+            # (a row whose token is not real stands: its result is thrown
+            # away, so nothing of its cache is fetched for it)
+            live = (lanes,) if lanes is not None else \
+                (valid[:, 0],) if valid is not None else ()
+            attn = mla.on_the_chip(one_query, dense, *operands[:4], *live)
         else:
             attn = dense(*operands)
-        return (_attn_out(cfg, y, attn, lp),
+        return (_attn_out(cfg, y, attn, lp, depth),
                 dict(arrs, **{kn: k_all, vn: v_all}))
 
     def attend_eva(y, lp, arrs, l, kind):
@@ -1403,11 +1549,38 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
     ssm = matrix_state(ssm_operator, _SSM_ARRAYS, functools.partial(
         ssd.kernel_shape, groups=cfg.ssm_groups))
 
+    def mamba(y, lp, arrs, l):
+        # the state [L, B, 1, columns, channels] advanced by the rows' valid
+        # tokens: one token a row in the stack where it lies, a chunk's
+        # between a cut of the layer and its placement back -> the memory too
+        s_all, taps = arrs[_MAMBA_STATE], \
+            _layer_of(arrs[_MAMBA_CONV], l)[:, 0]
+        if c == 1:
+            delta, m, s_all, taps = mamba_operator(cfg, y, lp, s_all, taps,
+                                                   n_new, layer=l)
+        else:
+            delta, m, state, taps = mamba_operator(
+                cfg, y, lp, _layer_of(s_all, l)[:, 0], taps, n_new)
+            with jax.named_scope("cache_write"):
+                s_all = jax.lax.dynamic_update_slice(
+                    s_all, state[None, :, None], (l, 0, 0, 0, 0))
+        return delta, dict(arrs, **{
+            _MAMBA_STATE: s_all,
+            _MAMBA_CONV: _place_state(arrs[_MAMBA_CONV], l, taps)}), m
+
     operator = {"conv": conv, "eva": attend_eva, "kda": kda}
 
     def layer(xc, lp, arrs, l, kind, sel):
         y = _norm(cfg, xc, lp["attn_norm"], lp.get("attn_norm_b"))
-        if kind not in operator and cfg.attention == "mla":
+        made = {}       # what this layer hands the layers behind it
+        if kind == "mamba":
+            delta, arrs, made["m"] = mamba(y, lp, arrs, l)
+        elif kind == "gmu":
+            delta = gmu_operator(cfg, y, lp, sel["m"])
+        elif cfg.hands_down:
+            with cross_scope(kind):
+                delta, arrs = attend_mha(y, lp, arrs, l, kind, sel["depth"])
+        elif kind not in operator and cfg.attention == "mla":
             with latent_scope(cfg, kind):
                 delta, arrs, sel = attend_mla(y, lp, arrs, l, kind, sel)
         else:
@@ -1419,14 +1592,58 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
-        return xc + _post(cfg, z, lp, "post_mlp_norm"), arrs, load, sel
+        return (xc + _post(cfg, z, lp, "post_mlp_norm"), arrs, load,
+                hand_on(sel, **made))
 
-    # of a model with an indexer: no position chosen yet, no indexing layer
-    sel = (jnp.zeros((b, c, cache["kv"].shape[-1]), bool),
-           jnp.zeros((), jnp.int32)) if cfg.index_topk else ()
+    if sel is None:
+        # of a model with an indexer: no position chosen yet, no indexing
+        # layer; of one whose layers hand values on: none made yet
+        sel = (jnp.zeros((b, c, cache["kv"].shape[-1]), bool),
+               jnp.zeros((), jnp.int32)) if cfg.index_topk else \
+            handed(cfg, b, c) if cfg.hands_down else ()
+    if span is not None:
+        x, arrays, load, sel = _scan_cached(cfg, params, x, cache, layer,
+                                            sel, span)
+        if span[1] == cfg.n_layers:
+            x = _norm(cfg, x, params["final_norm"],
+                      params.get("final_norm_b"))
+        return x, arrays, load, sel
     x, arrays, load = _scan_cached(cfg, params, x, cache, layer, sel)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return x, arrays, load
+
+
+def _tail_on_one_row(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
+                     cache: KVCache, row: jnp.ndarray, *, mask, valid=None,
+                     n_new=None, lanes=None, **kw):
+    """`_attend_cached` for a program that wants ONE row's logits a batch
+    row (``row`` [B]: a chunk's last real token) of a model with a STATELESS
+    TAIL (`TransformerConfig.stateless_tail`): the layers up to the last one
+    that holds state run on all ``C`` rows, as they must (their rows and
+    states are what later tokens read); the layers behind it write nothing,
+    so they run on that one row of the stream and of the memory alone,
+    against the rows the layers before them just wrote.  Exact: a tail layer
+    is elementwise in the position but for its reads of the cache.
+    → (final-norm activations [B, D] of the rows ``row``, arrays, load)."""
+    cut = cfg.n_layers - cfg.stateless_tail
+    x, arrays, load, sel = _attend_cached(
+        cfg, params, x, cache, mask=mask, valid=valid, n_new=n_new,
+        lanes=lanes, span=(0, cut), **kw)
+    b, c, _ = x.shape
+
+    def one(t):     # [B | 1, C, ...] -> [B, 1, ...]: the row ``row``
+        t = jnp.broadcast_to(t, (b,) + t.shape[1:])
+        return jnp.take_along_axis(
+            t, row.reshape((b, 1) + (1,) * (t.ndim - 2)), axis=1)
+
+    x, arrays, more, _ = _attend_cached(
+        cfg, params, one(x), dict(arrays, pos=cache["pos"]), rotate={},
+        write={}, mask={k: one(m) for k, m in mask.items()},
+        valid=None if valid is None else one(valid),
+        n_new=None if n_new is None else jnp.minimum(n_new, 1), lanes=lanes,
+        span=(cut, cfg.n_layers),
+        sel={k: one(v) if v.ndim else v for k, v in sel.items()})
+    return x[:, 0], arrays, tuple(a + b for a, b in zip(load, more))
 
 
 def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
@@ -1505,6 +1722,15 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         elif kind == "kda":     # ... and its delta state, from zeros
             arrs = from_zeros(kda_operator, (_DELTA_STATE, _DELTA_CONV), y,
                               lp, arrs, l)
+        elif kind == "mamba":   # ... a selective scan's, likewise
+            _, _, state, taps = mamba_operator(cfg, y, lp)
+            arrs = dict(arrs, **{
+                _MAMBA_STATE: jax.lax.dynamic_update_slice(
+                    arrs[_MAMBA_STATE], state[None, :, None],
+                    (l, 0, 0, 0, 0)),
+                _MAMBA_CONV: _place_state(arrs[_MAMBA_CONV], l, taps)})
+        elif kind in READER_KINDS:  # (nothing of its own to hold)
+            pass
         else:
             new = columns(y, lp, kind)
             arrs = dict(arrs, **{
@@ -1522,6 +1748,9 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
             arrs = dict(arrs, **{_INDEX_ARRAY: place(
                 arrs[_INDEX_ARRAY], sel[1], _as_columns(
                     k_i[:, :, None, :], arrs[_INDEX_ARRAY].dtype))})
+        if cfg.hands_down:      # (what the plain layers hand each other)
+            h, _, sel = _layer(cfg, h, lp, angles, kind, sel)
+            return h, arrs, (0, 0, 0), sel
         h, _, chosen = _layer(cfg, h, lp, angles, kind,
                               sel[0] if sel else None)
         if sel:
@@ -1529,7 +1758,8 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
         return h, arrs, (0, 0, 0), sel
 
     sel = (jnp.zeros((b, s, s), bool), jnp.zeros((), jnp.int32)) \
-        if cfg.index_topk else ()
+        if cfg.index_topk else handed(cfg, b, s, rows=True) \
+        if cfg.hands_down else ()
     x, arrays, _ = _scan_cached(cfg, params, x, cache, layer, sel)
     x = _norm(cfg, x, params["final_norm"], params.get("final_norm_b"))
     return _last_logits(params, x[:, -1], cfg), dict(
@@ -1566,8 +1796,8 @@ def next_token_logits(logits: jnp.ndarray,
 
 
 def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                  cfg: TransformerConfig, n_valid: Optional[jnp.ndarray] = None
-                  ) -> Tuple[jnp.ndarray, KVCache]:
+                  cfg: TransformerConfig, n_valid: Optional[jnp.ndarray] = None,
+                  tail: bool = True) -> Tuple[jnp.ndarray, KVCache]:
     """Extend the cache with a CHUNK of prompt tokens [B, C] starting at
     ``cache['pos']`` → (logits of the chunk's last position, cache').
 
@@ -1585,14 +1815,21 @@ def prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     ``n_valid`` (int32 scalar, TRACED, 1 <= n_valid <= C) makes the chunk
     a PADDED one: only its first ``n_valid`` tokens are real (see
     :func:`_prefill_chunk`), so a prompt's remainder is one program of
-    the chunk's own shape, whatever its length."""
-    logits, cache, _ = _prefill_chunk(params, tokens, cache, cfg, n_valid)
+    the chunk's own shape, whatever its length.
+
+    ``tail`` False (static; a model with a stateless tail only,
+    `TransformerConfig.stateless_tail`) is the program of a chunk that is
+    NOT a prompt's last: it ends behind the last layer that holds state and
+    hands out no logits (zeros), so it reads neither the tail's weights nor
+    the head's (`prefill_chunk_step` chooses it)."""
+    logits, cache, _ = _prefill_chunk(params, tokens, cache, cfg, n_valid,
+                                      tail)
     return logits, cache
 
 
 def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
                    cfg: TransformerConfig,
-                   n_valid: Optional[jnp.ndarray] = None):
+                   n_valid: Optional[jnp.ndarray] = None, tail: bool = True):
     """:func:`prefill_chunk` → (logits, cache', load): beside them what
     the chunk's expert layers routed (zeros for a model without).
 
@@ -1629,7 +1866,7 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
     # within the chunk, everything before it fully visible)
     with jax.named_scope("attention"):
         mask = jnp.arange(max_len)[None, :] <= (pos + jnp.arange(c))[:, None]
-    mask = dict.fromkeys(_ROW_KINDS, mask[None])
+    mask = dict.fromkeys(_CONTEXT_KINDS, mask[None])
 
     write = dict.fromkeys(_ROW_KINDS, _full_write_chunk(pos))
     ring = window_ring(cfg, max_len)
@@ -1643,20 +1880,41 @@ def _prefill_chunk(params: Params, tokens: jnp.ndarray, cache: KVCache,
                      summary=[_summary_write(cfg, pos, c, ring)])
     valid = None if n_valid is None else \
         jnp.broadcast_to(jnp.arange(c) < n_valid, (b, c))
-    x, arrays, load = _attend_cached(
-        cfg, params, x, cache,
+    layers = dict(
         rotate=_rotators(apply_rotary, angles),
         write=write, mask=mask, valid=valid,
         n_new=None if n_valid is None else
         jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), (b,)))
-    if n_valid is None:
+    if cfg.stateless_tail and c > 1:    # the tail on the last real row alone
+        step = c if n_valid is None else jnp.asarray(n_valid, pos.dtype)
+        if not tail:                    # ... or, of no last chunk, not at all
+            return _no_tail(cfg, params, x, cache, layers, pos + step)
+        last, arrays, load = _tail_on_one_row(
+            cfg, params, x, cache,
+            jnp.broadcast_to(jnp.asarray(step - 1, jnp.int32), (b,)),
+            **layers)
+    elif n_valid is None:
+        x, arrays, load = _attend_cached(cfg, params, x, cache, **layers)
         last, step = x[:, -1], c
     else:
+        x, arrays, load = _attend_cached(cfg, params, x, cache, **layers)
         last = jax.lax.dynamic_index_in_dim(x, n_valid - 1, axis=1,
                                             keepdims=False)
         step = jnp.asarray(n_valid, pos.dtype)
     return _last_logits(params, last, cfg), dict(arrays,
                                                  pos=pos + step), load
+
+
+def _no_tail(cfg: TransformerConfig, params, x, cache, layers, pos):
+    """A chunk program that is NOT a prompt's last, of a model with a
+    stateless tail: the layers up to the last one that holds state
+    (``layers``: `_attend_cached`'s words), no tail, no head -> (zeros for
+    logits [b, columns], cache' at ``pos``, load)."""
+    _, arrays, load, _ = _attend_cached(
+        cfg, params, x, cache, span=(0, cfg.n_layers - cfg.stateless_tail),
+        **layers)
+    return (jnp.zeros((x.shape[0], cfg.logit_size), jnp.float32),
+            dict(arrays, pos=pos), load)
 
 
 def chunk_window(off: int, n: int, chunk: int,
@@ -1714,7 +1972,7 @@ def padded_chunk(tokens, start: int, n_valid: int, chunk: int):
 #: `_verify`) walks a prompt through this handle on its own, without
 #: ``n_valid``: the unpadded program of the shape it is given, which is
 #: also what :func:`decode_step` traces (a chunk of one).
-prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg",),
+prefill_chunk_jit = jax.jit(prefill_chunk, static_argnames=("cfg", "tail"),
                             donate_argnames=("cache",))
 
 
@@ -1733,8 +1991,21 @@ def prefill_chunk_step(fn, params: Params, tokens: np.ndarray, off: int,
     if start != off:
         cache = dict(cache, pos=np.int32(start))
     logits, cache = fn(params, padded_chunk(tokens, start, n_valid, chunk),
-                       cache, cfg=cfg, n_valid=np.int32(n_valid))
+                       cache, cfg=cfg, n_valid=np.int32(n_valid),
+                       **_tail_of(cfg, chunk, [
+                           start + n_valid >= tokens.shape[1]]))
     return logits, cache, start + n_valid, n_valid
+
+
+def _tail_of(cfg: TransformerConfig, chunk: int, final) -> Dict[str, bool]:
+    """What a host walk tells a chunk program about its tail: nothing for a
+    model that has none (the program takes no such word), and ``tail=False``
+    where no row of the program is its prompt's LAST chunk (``final``: a
+    bool a row that advances), so that only a prompt's last chunk pays for
+    the stateless tail's and the head's weights."""
+    if not cfg.stateless_tail or chunk < 2 or any(final):
+        return {}
+    return {"tail": False}
 
 
 def prefill_chunked(params: Params, tokens: jnp.ndarray,
@@ -1865,7 +2136,8 @@ def _row_inputs(params: Params, tokens: jnp.ndarray, pos: jnp.ndarray,
             t[posm][:, :, None, :]
             for t in rotary_angles(max_len, cfg.rope_dim, base)))
     with jax.named_scope("attention"):
-        mask = dict.fromkeys(_ROW_KINDS, jnp.arange(max_len)[None, None, :]
+        mask = dict.fromkeys(_CONTEXT_KINDS,
+                             jnp.arange(max_len)[None, None, :]
                              <= posm[:, :, None])
     if "window" in cfg.kinds:
         mask["window"] = _ring_mask(pos, c, window_ring(cfg, max_len),
@@ -1934,7 +2206,8 @@ def _forward_slots(params: Params, token: jnp.ndarray, cache: KVCache,
 
 
 def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                   cfg: TransformerConfig, n_valid: jnp.ndarray):
+                   cfg: TransformerConfig, n_valid: jnp.ndarray,
+                   tail: bool = True):
     """`_prefill_chunk` for up to P SESSIONS AT ONCE: ``tokens`` [P, C], a
     padded chunk a LANE, over a lane cache that is a slot cache of P rows
     (`init_slot_cache`; per-lane ``pos`` [P], each lane's first position),
@@ -1983,27 +2256,35 @@ def _prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
             pos[p], c, ring, p, live[p]))
         write["summary"] = [_summary_write(cfg, pos[p], c, ring, p, live[p])
                             for p in range(lanes)]
-    x, arrays, load = _attend_cached(
-        cfg, params, x, cache, rotate=_rotators(_rotate_slots, angles),
-        write=write, mask=mask,
+    layers = dict(
+        rotate=_rotators(_rotate_slots, angles), write=write, mask=mask,
         valid=jnp.arange(c)[None, :] < n_valid[:, None],
         n_new=n_valid, lanes=live)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
+    if cfg.stateless_tail and c > 1 and not tail:   # (`prefill_chunk`'s)
+        return _no_tail(cfg, params, x, cache, layers,
+                        pos + n_valid.astype(pos.dtype))
+    if cfg.stateless_tail and c > 1:    # the tail on the last real row alone
+        last, arrays, load = _tail_on_one_row(
+            cfg, params, x, cache, jnp.maximum(n_valid - 1, 0), **layers)
+    else:
+        x, arrays, load = _attend_cached(cfg, params, x, cache, **layers)
+        last = jnp.take_along_axis(
+            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
     return _last_logits(params, last, cfg), dict(
         arrays, pos=pos + n_valid.astype(pos.dtype)), load
 
 
 def prefill_lanes(params: Params, tokens: jnp.ndarray, cache: KVCache,
-                  cfg: TransformerConfig, n_valid: jnp.ndarray
-                  ) -> Tuple[jnp.ndarray, KVCache]:
+                  cfg: TransformerConfig, n_valid: jnp.ndarray,
+                  tail: bool = True) -> Tuple[jnp.ndarray, KVCache]:
     """:func:`_prefill_lanes` → (logits [P, vocab], cache')."""
-    logits, cache, _ = _prefill_lanes(params, tokens, cache, cfg, n_valid)
+    logits, cache, _ = _prefill_lanes(params, tokens, cache, cfg, n_valid,
+                                      tail)
     return logits, cache
 
 
-def _lanes_program(params, tokens, cache, cfg, n_valid):
-    return prefill_lanes(params, tokens, cache, cfg, n_valid)
+def _lanes_program(params, tokens, cache, cfg, n_valid, tail=True):
+    return prefill_lanes(params, tokens, cache, cfg, n_valid, tail)
 
 
 # A trace and the compile ledger know a program by its function's name
@@ -2014,7 +2295,7 @@ _lanes_program.__name__ = "prefill_chunk"
 #: The chunk program of SEVERAL sessions (serve/decode_session.py, while two
 #: or more prompts prefill): module-level and donating as
 #: :data:`prefill_chunk_jit`, and under its name in a trace.
-prefill_lanes_jit = jax.jit(_lanes_program, static_argnames=("cfg",),
+prefill_lanes_jit = jax.jit(_lanes_program, static_argnames=("cfg", "tail"),
                             donate_argnames=("cache",))
 
 
@@ -2039,8 +2320,16 @@ def prefill_lanes_step(fn, params: Params, prompts, cache: KVCache,
         starts[p], n_valid[p] = _window_of(cfg, tokens.shape[1], off, chunk,
                                            capacity)
         buf[p] = padded_chunk(tokens, starts[p], n_valid[p], chunk)
+    final = [int(starts[p] + n_valid[p]) >= lane[0].shape[1]
+             for p, lane in enumerate(prompts) if lane is not None]
+    if not final and _tail_of(cfg, chunk, final):
+        # (no lane advances: a warm-up, which changes nothing; it warms the
+        # program without a tail too)
+        _, cache = fn(params, buf, dict(cache, pos=starts), cfg=cfg,
+                      n_valid=n_valid, tail=False)
+        final = [True]
     logits, cache = fn(params, buf, dict(cache, pos=starts), cfg=cfg,
-                       n_valid=n_valid)
+                       n_valid=n_valid, **_tail_of(cfg, chunk, final))
     return logits, cache, [
         None if lane is None else (int(starts[p] + n_valid[p]),
                                    int(n_valid[p]))

@@ -33,9 +33,9 @@ Design (idiomatic JAX, not a torch translation):
     block adds to the plain one is a property each: ``qk_norm``,
     ``attn_gate``, ``sandwich_norm``, ``embed_scale``.
   * a layer's KIND (``layer_kinds``: ``"window"`` | ``"full"`` | ``"conv"``
-    | ``"eva"`` | ``"kda"`` | ``"ssm+full"``, or of a model with an indexer
-    ``"index"`` | ``"shared"``) may change from layer to layer and repeat
-    inside a run: a
+    | ``"eva"`` | ``"kda"`` | ``"ssm+full"`` | ``"mamba"`` | ``"gmu"`` |
+    ``"cross"``, or of a model with an indexer ``"index"`` | ``"shared"``)
+    may change from layer to layer and repeat inside a run: a
     window layer sees the last ``sliding_window`` positions (``rope_layers
     = "window"``: only those are rotated); a conv layer's operator is no
     attention at all but a gated short convolution (`ops/short_conv.py`);
@@ -118,6 +118,44 @@ Design (idiomatic JAX, not a torch translation):
     layers').  Its parameter and FLOP counts are both operators'; a served
     cache holds for it a state WITHOUT positions and rows WITH them: the
     seventh kind beside the first (`models/generate.py`).
+
+  * a DECODER THAT READS WHAT AN EARLIER LAYER MADE (`HANDING_KINDS`): a
+    ``"mamba"`` layer's operator is a Mamba-1 SELECTIVE SCAN
+    (`mamba_operator`, `ops/selective_scan.py`: ``mamba_expand x d_model``
+    channels through a depthwise causal convolution with a bias, a step of
+    rank ``mamba_dt_rank``, a float32 state of ``mamba_state`` columns a
+    channel under a decay a channel A COLUMN, so no matmul chunk form as
+    `ops/ssd.py`'s); a ``"gmu"`` layer's is a GATED MEMORY UNIT
+    (`gmu_operator`: ``W_2 (m * silu(y W_1))``, ``m`` the scan output of the
+    LAST mamba layer before it at the same position); a ``"cross"`` layer
+    projects queries of its own and attends the keys and values of the LAST
+    FULL layer before it.  So TWO values that one layer makes are consumed by
+    layers behind it, carried down `scan_layer_runs` beside the stream as an
+    indexer's choice is (`handed`, `hand_on`): the memory ``m`` and, in the
+    plain form, the full layer's rows; the layer's index among all rides
+    with them.  Each operator's weights are stacked over ITS layers alone
+    (a cross layer has ``wq`` / ``wo`` with the attention layers' and no
+    ``wk`` / ``wv``).  A gmu and a cross layer hold NO state
+    (`READER_KINDS`): a served cache has nothing for them, and where they
+    are the model's last layers (`TransformerConfig.stateless_tail`) a
+    cached program that wants one row's logits runs them on that row alone
+    (`models/generate.py`).  `check_kinds` refuses a reader with no maker
+    before it.
+
+  * an MHA/GQA block may be DIFFERENTIAL, a property (``diff_attn``) that
+    costs nothing when off: query heads ``(2p, 2p + 1)`` are a PAIR, the
+    pair's two softmax maps are taken against the two halves of ONE key row
+    (``n_kv_heads`` counts key-value PAIRS: a row is `key_dim` = 2 x
+    ``head_dim`` wide, its value as wide) and subtracted under a norm:
+    ``(1 - lambda_init) rmsnorm(o_1 - lambda o_2)``, ``lambda = exp(lq1 .
+    lk1) - exp(lq2 . lk2) + lambda_init``, ``lambda_init = 0.8 - 0.6 exp(-0.3
+    l)`` by the layer's index among all (`diff_pairs`, scope ``diff``).  It
+    runs as ONE grouped-query attention over rows of `key_dim`: head 2p's
+    query is padded to ``[q | 0]``, head 2p + 1's to ``[0 | q]``
+    (`_pair_queries`), the scores scaled by one head's ``head_dim ** -0.5``
+    (`attention_scale`), so every implementation (flash, reference, the cache
+    kernels) takes it as it is and a cached row is fetched once for both
+    maps.  ``attn_bias`` puts biases on the four projections.
 
   * a model may state FIXED MULTIPLIERS at named places (muP), each a field
     that defaults to 1 and costs nothing there: the embedding's
@@ -241,9 +279,9 @@ class TransformerConfig:
     embed_scale: float = 1.0          # multiplies the token embedding
     # -- kinds of layer mixed -----------------------------------------------
     layer_kinds: Optional[Tuple[str, ...]] = None  # a layer "window" |
-    #   "full" | "conv" | "eva" | "kda" | "ssm+full", or of a model with an
-    #   indexer "index" | "shared", in model order (None → all full); may
-    #   repeat inside a run
+    #   "full" | "conv" | "eva" | "kda" | "ssm+full" | "mamba" | "gmu" |
+    #   "cross", or of a model with an indexer "index" | "shared", in model
+    #   order (None → all full); may repeat inside a run
     conv_kernel: int = 3              # a conv layer's taps; its state is
     #   the last conv_kernel - 1 inputs of the convolution a sequence
     sliding_window: int = 0           # a window layer's position i sees
@@ -320,6 +358,19 @@ class TransformerConfig:
     pred_heads: int = 1               # the unembedding has pred_heads x
     #   vocab_size columns: head p predicts the token at t + 1 + p, and a
     #   served token is drawn from head 0
+    # -- layers that read what an EARLIER layer made (ops/selective_scan.py) --
+    mamba_state: int = 0              # a "mamba" layer's state columns (0:
+    #   none): a sequence carries mamba_state x mamba_inner float32 a layer
+    mamba_expand: int = 2             # its channels over d_model
+    mamba_conv_kernel: int = 4        # taps of its depthwise convolution
+    #   (with a bias, SiLU after)
+    mamba_dt_rank: int = 0            # rank of the step's two-step projection
+    diff_attn: bool = False           # an MHA/GQA block subtracts two softmax
+    #   maps under a norm: query heads (2p, 2p + 1) are a PAIR; n_kv_heads
+    #   counts key-value PAIRS, a pair's two keys of head_dim side by side
+    #   ONE cached row of 2 x head_dim and its value as wide
+    attn_bias: bool = False           # biases on an MHA/GQA block's query,
+    #   key, value and output projections
 
     @property
     def head_dim(self) -> int:
@@ -366,9 +417,44 @@ class TransformerConfig:
         return self.kv_heads_of("window") != self.kv_heads
 
     @property
+    def key_dim(self) -> int:
+        """Width of an MHA/GQA key ROW as cached and attended: a head's, or
+        a differential pair's two keys side by side."""
+        return self.head_dim * (2 if self.diff_attn else 1)
+
+    @property
     def value_dim(self) -> int:
-        """An MHA/GQA value head's width (the key's unless stated)."""
-        return self.v_head_dim or self.head_dim
+        """An MHA/GQA value head's width (the key row's unless stated)."""
+        return self.v_head_dim or self.key_dim
+
+    @property
+    def out_heads(self) -> int:
+        """Heads the output projection reads: the query heads, or their
+        pairs where two maps are subtracted."""
+        return self.n_heads // 2 if self.diff_attn else self.n_heads
+
+    @property
+    def mamba_inner(self) -> int:
+        """A ``"mamba"`` layer's channels."""
+        return self.mamba_expand * self.d_model
+
+    @property
+    def hands_down(self) -> bool:
+        """Whether the layer loop carries values that one layer makes for the
+        layers behind it (`handed`): a memory, a full layer's rows, the
+        layer's index."""
+        return self.diff_attn or bool(set(self.kinds) & set(HANDING_KINDS))
+
+    @property
+    def stateless_tail(self) -> int:
+        """The layers BEHIND the last one that holds state of its own: the
+        trailing `READER_KINDS` layers, which write nothing a later token
+        reads (a cached program that wants one row's logits runs them on
+        that row alone)."""
+        n = 0
+        while n < self.n_layers and self.kinds[-1 - n] in READER_KINDS:
+            n += 1
+        return n
 
     @property
     def window_latent(self) -> bool:
@@ -503,7 +589,9 @@ def _attn_matmul_params(cfg: TransformerConfig, kind: str = "full") -> int:
                 + (d * h if cfg.head_gate else 0))
     hd, vd, hk = cfg.head_dim, cfg.value_dim, cfg.kv_heads_of(kind)
     gate = d * h * vd if cfg.attn_gate else 0
-    return d * h * hd + d * hk * (hd + vd) + h * vd * d + gate
+    # (a "cross" layer projects queries alone: it reads a full layer's rows)
+    kv = 0 if kind == "cross" else d * hk * (cfg.key_dim + vd)
+    return d * h * hd + kv + cfg.out_heads * vd * d + gate
 
 
 def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
@@ -532,6 +620,8 @@ def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
                for run, n in cfg.layer_runs) + sum(
         4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
         else _kda_matmul_params(cfg) if kind == "kda"
+        else _mamba_matmul_params(cfg) if kind == "mamba"
+        else 2 * cfg.d_model * cfg.mamba_inner if kind == "gmu"
         else _attn_matmul_params(cfg, kind)
         + (_ssm_matmul_params(cfg) if kind in SSM_KINDS else 0)
         for kind in cfg.kinds) \
@@ -597,7 +687,7 @@ def _attended(cfg: TransformerConfig, context_len: float,
 
     # (a conv and a KDA layer attend nothing; a layer with a state-space
     # mixer BESIDE attention attends as a full one)
-    return sum(rows(kind) for kind in cfg.kinds if kind not in _STATE_LAYERS
+    return sum(rows(kind) for kind in cfg.kinds if kind not in _NO_ATTENTION
                and (kinds is None or kind in kinds))
 
 
@@ -610,14 +700,16 @@ def count_params(cfg: TransformerConfig) -> int:
     e = cfg.kda_heads * cfg.kda_head_dim    # a KDA layer's own: three
     #   convolutions, a decay a head, its bias, the heads' norm
     kda = 3 * e * cfg.kda_conv_kernel + cfg.kda_heads + e + cfg.kda_head_dim
-    def own(kind):      # an attention layer's: the latents' norms, or a
-        #   head's query and key norms
-        ck = cfg.latent_of(kind)
+    def own(k):         # an attention layer's: the latents' norms, or a
+        #   head's query and key norms, its biases, a differential pair's
+        ck = cfg.latent_of(k)
         return ck.q_lora_rank + ck.kv_lora_rank if cfg.attention == "mla" \
-            else 2 * cfg.head_dim if cfg.qk_norm else 0
+            else (2 * cfg.head_dim if cfg.qk_norm else 0) \
+            + _attn_own_params(cfg, k)
 
     layers = _matmul_params(cfg, active=False) + cfg.n_layers * norms \
-        + sum(own(k) for k in cfg.kinds if k not in _STATE_LAYERS) \
+        + sum(own(k) for k in cfg.kinds if k not in _NO_ATTENTION) \
+        + cfg.kinds.count("mamba") * _mamba_own_params(cfg) \
         + n_kda * kda \
         + sum(k in SSM_KINDS for k in cfg.kinds) * _ssm_own_params(cfg) \
         + n_conv * d * cfg.conv_kernel \
@@ -648,7 +740,8 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     attn += attn_factor // 2 * _index_flops_dim(cfg) * seq_len
     # a delta state's decay, read, correction and write a token: 7 a float
     return 6 * n_matmul + attn + 3 * 7 * _kda_state_size(cfg) \
-        + 3 * _SSM_STATE_OPS * _ssm_state_size(cfg)
+        + 3 * _SSM_STATE_OPS * _ssm_state_size(cfg) \
+        + 3 * _MAMBA_STATE_OPS * _mamba_state_size(cfg)
 
 
 def _index_flops_dim(cfg: TransformerConfig) -> int:
@@ -677,7 +770,8 @@ def decode_flops_per_token(cfg: TransformerConfig,
     return 2 * n_matmul + _attention_work(
         cfg, lambda kind: 2 * per_pos(kind), context_len) \
         + 2 * _index_flops_dim(cfg) * context_len + 7 * _kda_state_size(cfg) \
-        + _SSM_STATE_OPS * _ssm_state_size(cfg)
+        + _SSM_STATE_OPS * _ssm_state_size(cfg) \
+        + _MAMBA_STATE_OPS * _mamba_state_size(cfg)
 
 
 def engine_flops_table(cfg: TransformerConfig, max_len: int) -> dict:
@@ -735,6 +829,9 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
     if kind_layers(cfg, run, SSM_KINDS):    # the state-space mixer, likewise
         _init_ssm(add, p, ax, cfg, kind_layers(cfg, run, SSM_KINDS),
                   next(keys))
+    if kind_layers(cfg, run, _MEMORY_LAYERS):   # a selective scan and the
+        # gated units that read its memory, likewise
+        _init_memory(add, p, ax, cfg, run, next(keys))
     if La and cfg.attention == "mla":
         n_win = kind_layers(cfg, run, ("window",)) if cfg.window_latent else 0
         ql = cfg.q_lora_rank
@@ -770,9 +867,12 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
             n, hk = kind_layers(cfg, run, stack_kinds(cfg, kn)), \
                 cfg.kv_heads_of(kind)
             if n:
-                add(kn, (d, hk, hd), d, ("embed", "heads", "kv"), n)
+                add(kn, (d, hk, cfg.key_dim), d, ("embed", "heads", "kv"), n)
                 add(vn, (d, hk, vd), d, ("embed", "heads", "kv"), n)
-        add("wo", (h, vd, d), h * vd, ("heads", "kv", "embed"), La)
+        add("wo", (cfg.out_heads, vd, d), cfg.out_heads * vd,
+            ("heads", "kv", "embed"), La)
+        if cfg.diff_attn or cfg.attn_bias:      # ONE of the run's keys
+            _init_attn_own(p, ax, cfg, run, La, next(keys))
         if cfg.attn_gate:
             add("wg", (d, h, vd), d, ("embed", "heads", "kv"), La)
         n_sink = kind_layers(cfg, run, cfg.sink_kinds)
@@ -924,7 +1024,7 @@ _EXPERT_STACKS = ("w_in", "w_gate", "w_out")
 
 
 def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
-                    whole_expert_stacks: bool = False):
+                    whole_expert_stacks: bool = False, span=None):
     """THE layer loop: ``body(carry, lp, kind) -> carry`` over every layer
     of the declared pattern, one `lax.scan` per segment of identical layers
     (`TransformerConfig.layer_segments`: a run, cut where the kind
@@ -939,9 +1039,18 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
     weights are not scanned over.  The grouped matmul is a kernel call, and
     a layer's ``[E, d, f]`` slice of the stack would be COPIED out for it.
     The body gets ``(stack [L, E, d, f], layer)`` instead and
-    `ops.moe.routed_ffn` hands the kernel the whole stack and the layer."""
+    `ops.moe.routed_ffn` hands the kernel the whole stack and the layer.
+
+    ``span`` (first layer, one past the last, of the model's; None: all)
+    runs the segments in it alone; its ends are ends of segments."""
     run_len = dict(cfg.layer_runs)
+    at = 0      # the segment's first layer among the model's
     for run, first, n, kind in cfg.layer_segments:
+        at += n
+        if span is not None and not span[0] <= at - n < at <= span[1]:
+            if span[0] < at and at - n < span[1]:
+                raise ValueError(f"span {span} cuts a segment of layers")
+            continue
         tree = params[run]
         whole = {k: tree[k] for k in _EXPERT_STACKS
                  if whole_expert_stacks and cfg.router == "sigmoid"
@@ -970,21 +1079,32 @@ def _qkv(cfg: TransformerConfig, y: jnp.ndarray, lp: Params, rotate,
          kind: str = "full"):
     """Normed input [b, s, d] -> (q [b, s, h, hd], k [b, s, hk, hd], v [b,
     s, hk, vd]) of an MHA/GQA block of kind ``kind``: the three
-    projections (the value's scaled where the model scales it), the
-    per-head RMS norms where the model has them, then ``rotate`` over a
-    head's first `rope_dim` dims (None: this layer turns nothing)."""
+    projections (the value's scaled where the model scales it; each with its
+    bias where the model has them), the per-head RMS norms where the model
+    has them, then ``rotate`` over a head's first `rope_dim` dims (None:
+    this layer turns nothing).  A ``"cross"`` layer has queries alone (k and
+    v None: it attends a full layer's rows), and a differential model's
+    queries and keys come as PAIRS (`_pair_queries`, `key_dim`)."""
     dt = cfg.dtype
     kn, vn = kv_weight_names(cfg, kind)
     y = mla.times(y, cfg.attn_in_scale)
     q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dt))
+    if cfg.attn_bias:
+        q = q + lp["bq"].astype(dt)
+    if kind == "cross":
+        return _pair_queries(cfg, q, rotate), None, None
     k = mla.times(jnp.einsum("bsd,dhk->bshk", y, lp[kn].astype(dt)),
                 cfg.key_scale)
     v = jnp.einsum("bsd,dhk->bshk", y, lp[vn].astype(dt))
+    if cfg.attn_bias:
+        k, v = k + lp["bk"].astype(dt), v + lp["bv"].astype(dt)
     if cfg.value_scale != 1.0:
         v = (v.astype(jnp.float32) * cfg.value_scale).astype(dt)
     if cfg.qk_norm:
         q = rmsnorm(q, lp["q_norm"], norm_eps(cfg))
         k = rmsnorm(k, lp["k_norm"], norm_eps(cfg))
+    if cfg.diff_attn:
+        return _pair_queries(cfg, q, rotate), _pair_keys(cfg, k, rotate), v
     if rotate is not None:
         q, k = _rotate_heads(cfg, rotate, q), _rotate_heads(cfg, rotate, k)
     return q, k, v
@@ -1018,15 +1138,21 @@ def rope_tables(cfg: TransformerConfig, make) -> Dict[str, Any]:
 
 @jax.named_scope("projections")
 def _attn_out(cfg: TransformerConfig, y: jnp.ndarray, attn: jnp.ndarray,
-              lp: Params) -> jnp.ndarray:
+              lp: Params, depth=None) -> jnp.ndarray:
     """Heads' output [b, s, h, vd] -> the block's [b, s, d]: gated by
-    ``sigmoid(y W_g)`` where the model gates, then the output projection."""
+    ``sigmoid(y W_g)`` where the model gates, a pair's two maps subtracted
+    under their norm where it is differential (``depth``: the layer's index,
+    `diff_pairs`), then the output projection."""
     dt = cfg.dtype
     if cfg.attn_gate:
         gate = jnp.einsum("bsd,dhk->bshk", y, lp["wg"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-    return mla.times(jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt)),
-                   cfg.attn_out_scale)
+    if cfg.diff_attn:
+        attn = diff_pairs(cfg, attn, lp, depth)
+    out = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dt))
+    if cfg.attn_bias:
+        out = out + lp["bo"].astype(dt)
+    return mla.times(out, cfg.attn_out_scale)
 
 
 def _post(cfg: TransformerConfig, delta: jnp.ndarray, lp: Params,
@@ -1047,6 +1173,8 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
     cos, sin = angles.get(kind, (None, None))
     if kind in _STATE_LAYERS:
         return _state_layer(cfg, x, lp, kind) + (sel,)
+    if kind in _MEMORY_LAYERS:
+        return _memory_layer(cfg, x, lp, kind, sel)
     norm = functools.partial(_norm, cfg)
     if cfg.norm_remat:
         norm = jax.checkpoint(
@@ -1080,17 +1208,17 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
         q, k, v = _qkv(cfg, y, lp, functools.partial(
             apply_rotary, cos=cos, sin=sin) if kind in angles else None,
             kind)
-        if kind == "eva":       # the plain form: dense over the sequence
-            attn = eva_attention(q, k, v, lp["adaptive_phi"],
-                                 lp["adaptive_mu_k"],
-                                 window=cfg.sliding_window,
-                                 chunk=cfg.summary_chunk)
+        if cfg.hands_down:      # a "cross" layer's rows are handed to it
+            delta, sel = _handing_attention(cfg, y, q, k, v, lp, kind, sel)
+        elif kind == "eva":     # the plain form: dense over the sequence
+            delta = _attn_out(cfg, y, eva_attention(
+                q, k, v, lp["adaptive_phi"], lp["adaptive_mu_k"],
+                window=cfg.sliding_window, chunk=cfg.summary_chunk), lp)
         else:
-            attn = multi_head_attention(
+            delta = _attn_out(cfg, y, multi_head_attention(
                 q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
                 window=cfg.sliding_window if kind == "window" else None,
-                sink=lp["sink"] if kind in cfg.sink_kinds else None)
-        delta = _attn_out(cfg, y, attn, lp)
+                sink=lp["sink"] if kind in cfg.sink_kinds else None), lp)
         if kind in SSM_KINDS:   # the mixer off the same norm: the sum
             delta = delta + ssm_operator(cfg, y, lp)[0]
         x = x + _post(cfg, delta, lp, "post_attn_norm")
@@ -1205,6 +1333,8 @@ def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
     else:
         b, s = tokens.shape     # the selection an indexing layer hands on
         sel = jnp.zeros((b, s, s), bool) if cfg.index_topk else None
+        if cfg.hands_down:      # ... or what a memory model's layers hand on
+            sel = handed(cfg, b, s, rows=True)
         x, aux, _ = scan_layer_runs(
             cfg, params, (x, jnp.zeros((), jnp.float32), sel), body)
         aux = aux / cfg.n_layers
@@ -1466,12 +1596,33 @@ _SSM_KEYS = ("ssm_in", "ssm_conv", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
              "ssm_d", "ssm_norm", "ssm_out")
 #: the kinds of layer whose operator is no attention and carries a state
 _STATE_LAYERS = ("conv", "kda")
+#: a selective scan's weights (`_init_memory`), and a gated memory unit's
+_MAMBA_KEYS = ("mamba_in", "mamba_conv", "mamba_conv_b", "mamba_x",
+               "mamba_dt", "mamba_dt_b", "mamba_a_log", "mamba_d",
+               "mamba_out")
+_GMU_KEYS = ("gmu_in", "gmu_out")
+#: the kinds of layer around a MEMORY: a selective scan that makes one (and
+#: carries a state), a gated unit that reads the last one made
+_MEMORY_LAYERS = ("mamba", "gmu")
+#: ... and the kinds that hold NO state of their own: a gated memory unit
+#: reads the scan output of the last ``"mamba"`` layer before it, a
+#: ``"cross"`` layer attends the rows of the last ``"full"`` layer before it
+READER_KINDS = ("gmu", "cross")
+#: the kinds whose model hands values down the layer loop (`handed`)
+HANDING_KINDS = ("mamba",) + READER_KINDS
+#: the kinds of layer that attend nothing
+_NO_ATTENTION = _STATE_LAYERS + _MEMORY_LAYERS
 #: the kinds of layer with a state-space mixer BESIDE their attention: two
 #: operators off one norm, a state and rows at once
 SSM_KINDS = ("ssm+full",)
 #: an attention layer's (MHA/GQA and latent), of whatever attention kind
 _ATTN_KEYS = ("wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm", "wq_a",
-              "wq_b", "wkv_a", "wkv_b", "kv_norm")
+              "wq_b", "wkv_a", "wkv_b", "kv_norm", "bq", "bk", "bv", "bo",
+              "diff_lq1", "diff_lk1", "diff_lq2", "diff_lk2", "diff_norm")
+#: a differential layer's four ``lambda`` vectors
+_LAMBDA_KEYS = ("diff_lq1", "diff_lk1", "diff_lq2", "diff_lk2")
+#: ... of which a layer that reads another layer's rows has none
+_ROW_WEIGHTS = ("wk", "wv", "bk", "bv")
 _WINDOW_KV = ("wk_win", "wv_win")
 _WIN = "_win"
 #: a latent layer's weights (`_init_latent`, the gate a head), and a window
@@ -1487,7 +1638,8 @@ _INDEX_KEYS = ("wi_q", "wi_k", "wi_w", "ik_norm", "ik_norm_b")
 #: scores and chooses, one that attends the choice of the last such before it
 SPARSE_KINDS = ("index", "shared")
 #: the kinds of layer whose operator is attention
-ATTENTION_KINDS = ("full", "window", "eva") + SPARSE_KINDS + SSM_KINDS
+ATTENTION_KINDS = ("full", "window", "eva") + SPARSE_KINDS + SSM_KINDS \
+    + ("cross",)
 
 
 def check_kinds(cfg: TransformerConfig) -> None:
@@ -1511,6 +1663,7 @@ def check_kinds(cfg: TransformerConfig) -> None:
             f"layer_kinds {cfg.layer_kinds!r}: a 'kda' layer needs kda_heads, "
             f"kda_head_dim and kda_gate_rank of at least 1 and a "
             f"kda_conv_kernel of at least 2")
+    _check_memory(cfg)
     sparse = set(cfg.kinds) & set(SPARSE_KINDS)
     if not sparse and not cfg.index_topk:
         return
@@ -1576,14 +1729,19 @@ def stack_kinds(cfg: TransformerConfig, key: str
         return ("eva",)
     if key in _INDEX_KEYS:
         return ("index",)
+    if key in _MAMBA_KEYS:
+        return ("mamba",)
+    if key in _GMU_KEYS:
+        return ("gmu",)
     if key in _ATTN_KEYS:
         if cfg.split_kv and key in ("wk", "wv"):
             return ("full",)
-        # (a summary, indexing or shared layer is named only by a model
-        # that has one; a latent model's window layers have stacks of
-        # their own)
+        # (a summary, indexing, shared or cross layer is named only by a
+        # model that has one; a latent model's window layers have stacks of
+        # their own; a cross layer has no rows to project)
         return ("full",) + (() if cfg.window_latent else ("window",)) \
-            + tuple(k for k in ATTENTION_KINDS[2:] if k in cfg.kinds)
+            + tuple(k for k in ATTENTION_KINDS[2:] if k in cfg.kinds
+                    and not (k == "cross" and key in _ROW_WEIGHTS))
     return None
 
 
@@ -1988,6 +2146,349 @@ def ssm_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
             return (mla.times(jnp.einsum("bse,ed->bsd", o.astype(dt),
                                        lp["ssm_out"].astype(dt)),
                             cfg.ssm_out_scale), state, conv)
+
+
+# ---------------------------------------------------------------------------
+# layers that read what an earlier layer made (`HANDING_KINDS`), and two
+# softmax maps subtracted under a norm (``diff_attn``)
+# ---------------------------------------------------------------------------
+
+#: operations a float of selective-scan state a token: the decay's product
+#: and exponential, the state's multiply-add, the query's product summed
+_MAMBA_STATE_OPS = 6
+
+
+def _mamba_matmul_params(cfg: TransformerConfig) -> int:
+    """A ``"mamba"`` layer's projections: in (input | gate), the input's
+    (step | key | query), the step's second, out."""
+    d, e, n, r = (cfg.d_model, cfg.mamba_inner, cfg.mamba_state,
+                  cfg.mamba_dt_rank)
+    return d * 2 * e + e * (r + 2 * n) + r * e + e * d
+
+
+def _mamba_own_params(cfg: TransformerConfig) -> int:
+    """... and what it holds beside them: the convolution's taps and bias,
+    the step's bias, a decay a channel a column, a skip a channel."""
+    return cfg.mamba_inner * (cfg.mamba_conv_kernel + 3 + cfg.mamba_state)
+
+
+def _mamba_state_size(cfg: TransformerConfig) -> int:
+    """Floats of selective-scan state a sequence, summed over the layers."""
+    return cfg.kinds.count("mamba") * cfg.mamba_state * cfg.mamba_inner
+
+
+def _attn_own_params(cfg: TransformerConfig, kind: str) -> int:
+    """What an MHA/GQA layer of ``kind`` holds beside its matrices: the
+    projections' biases, a differential layer's four vectors and the scale
+    of its pairs' norm."""
+    rows = 0 if kind == "cross" else cfg.kv_heads_of(kind) * (
+        cfg.key_dim + cfg.value_dim)
+    return (cfg.n_heads * cfg.head_dim + rows + cfg.d_model
+            if cfg.attn_bias else 0) \
+        + (4 * cfg.head_dim + cfg.value_dim if cfg.diff_attn else 0)
+
+
+def _check_memory(cfg: TransformerConfig) -> None:
+    """What a memory model's layers need of a configuration, refused with a
+    message where it lacks it."""
+    kinds = cfg.kinds
+    if "mamba" in kinds and (
+            min(cfg.mamba_state, cfg.mamba_expand, cfg.mamba_dt_rank) < 1
+            or cfg.mamba_conv_kernel < 2):
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r}: a 'mamba' layer needs "
+            f"mamba_state, mamba_expand and mamba_dt_rank of at least 1 and "
+            f"a mamba_conv_kernel of at least 2")
+    for reader, maker in (("gmu", "mamba"), ("cross", "full")):
+        if reader in kinds and maker not in kinds[:kinds.index(reader)]:
+            raise ValueError(
+                f"layer_kinds {cfg.layer_kinds!r}: a {reader!r} layer reads "
+                f"what the last {maker!r} layer before it made, and none "
+                f"stands before the first")
+    if set(kinds) & set(HANDING_KINDS) and (
+            cfg.attention != "mha" or cfg.pos_emb == "rope"
+            or cfg.index_topk or cfg.pp_stages > 1):
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r}: 'mamba', 'gmu' and 'cross' "
+            f"layers stand in an MHA/GQA model that turns nothing (a cross "
+            f"layer's queries meet another layer's keys as they were "
+            f"cached), has no indexer and is no pipeline")
+    if cfg.diff_attn and (cfg.n_heads % 2 or cfg.qk_norm or cfg.sink_kinds
+                          or cfg.attention != "mha" or cfg.pp_stages > 1
+                          or set(kinds) & ({"eva"} | set(SSM_KINDS))):
+        raise ValueError(
+            "diff_attn subtracts the maps of PAIRS of query heads of an "
+            "MHA/GQA block (an even n_heads; n_kv_heads counts pairs): no "
+            "per-head norm, sink, summary layer, mixer beside it or pipeline")
+
+
+def _init_memory(add, p: Params, ax: Params, cfg: TransformerConfig,
+                 run: str, key) -> None:
+    """A run's selective-scan weights, stacked over its ``"mamba"`` layers
+    (in: input | gate; the convolution and its bias; the input's step | key |
+    query; the step's second projection and bias; the decay a column a
+    channel, channels LAST as the state lies; the skip; out), and its gated
+    memory units', over its ``"gmu"`` layers.  What decides how long a state
+    remembers is drawn as Mamba draws it: ``A_log = log(1 .. n)`` a channel,
+    ``dt = exp U(log 0.001, log 0.1)``, ``dt_bias = dt + log(-expm1(-dt))``."""
+    d, e, n, r = (cfg.d_model, cfg.mamba_inner, cfg.mamba_state,
+                  cfg.mamba_dt_rank)
+    taps, pt = cfg.mamba_conv_kernel, cfg.param_dtype
+    ks = iter(jax.random.split(key, 8))
+    nm = kind_layers(cfg, run, ("mamba",))
+    if nm:
+        add("mamba_in", (d, 2 * e), d, ("embed", None), nm, next(ks))
+        add("mamba_conv", (e, taps), taps, (None, None), nm, next(ks))
+        add("mamba_x", (e, r + 2 * n), e, (None, None), nm, next(ks))
+        add("mamba_dt", (r, e), r, (None, None), nm, next(ks))
+        add("mamba_out", (e, d), e, (None, "embed"), nm, next(ks))
+        dt = jnp.exp(jax.random.uniform(next(ks), (nm, e), pt,
+                                        math.log(1e-3), math.log(1e-1)))
+        p["mamba_dt_b"] = dt + jnp.log(-jnp.expm1(-dt))
+        p["mamba_a_log"] = jnp.broadcast_to(jnp.log(jnp.arange(
+            1, n + 1, dtype=pt))[None, :, None], (nm, n, e))
+        p["mamba_conv_b"] = jnp.zeros((nm, e), pt)
+        p["mamba_d"] = jnp.ones((nm, e), pt)
+        ax["mamba_a_log"] = ("layers", None, None)
+        ax["mamba_dt_b"] = ax["mamba_conv_b"] = ax["mamba_d"] = (
+            "layers", None)
+    ng = kind_layers(cfg, run, ("gmu",))
+    if ng:
+        add("gmu_in", (d, e), d, ("embed", None), ng, next(ks))
+        add("gmu_out", (e, d), e, (None, "embed"), ng, next(ks))
+
+
+def _init_attn_own(p: Params, ax: Params, cfg: TransformerConfig, run: str,
+                   n: int, key) -> None:
+    """What a run's ``n`` MHA/GQA layers hold beside their matrices: zero
+    biases (the rows' over the layers that project rows), a differential
+    layer's four vectors (normal, 0.1) and its pairs' norm."""
+    pt = cfg.param_dtype
+    rows = kind_layers(cfg, run, stack_kinds(cfg, "bk"))
+    if cfg.attn_bias:
+        for name, count, shape in (
+                ("bq", n, (cfg.n_heads, cfg.head_dim)),
+                ("bk", rows, (cfg.kv_heads, cfg.key_dim)),
+                ("bv", rows, (cfg.kv_heads, cfg.value_dim)),
+                ("bo", n, (cfg.d_model,))):
+            p[name] = jnp.zeros((count,) + shape, pt)
+            ax[name] = ("layers",) + (None,) * len(shape)
+    if cfg.diff_attn:
+        for name, k in zip(_LAMBDA_KEYS, jax.random.split(key, 4)):
+            p[name] = 0.1 * jax.random.normal(k, (n, cfg.head_dim), pt)
+        p["diff_norm"] = jnp.ones((n, cfg.value_dim), pt)
+        for name in _LAMBDA_KEYS + ("diff_norm",):
+            ax[name] = ("layers", None)
+
+
+def handed(cfg: TransformerConfig, b: int, s: int, rows: bool = False
+           ) -> Dict[str, jnp.ndarray]:
+    """What the layer loop of a model that `hands_down` carries beside the
+    stream, for ``b`` rows of ``s`` tokens, before the first layer:
+    ``depth`` (the layer's index among all: a differential layer's
+    ``lambda_init``), ``m`` (the last ``"mamba"`` layer's scan output [b, s,
+    mamba_inner], BEFORE its gate: the memory every ``"gmu"`` layer behind
+    it reads) and, with ``rows`` (the plain form: nothing is cached), ``k``
+    and ``v``, the last ``"full"`` layer's rows for the ``"cross"`` layers
+    behind it."""
+    out = {"depth": jnp.zeros((), jnp.int32)}
+    if "gmu" in cfg.kinds:
+        out["m"] = jnp.zeros((b, s, cfg.mamba_inner), cfg.dtype)
+    if rows and "cross" in cfg.kinds:
+        out.update(
+            k=jnp.zeros((b, s, cfg.kv_heads, cfg.key_dim), cfg.dtype),
+            v=jnp.zeros((b, s, cfg.kv_heads, cfg.value_dim), cfg.dtype))
+    return out
+
+
+def hand_on(sel, **made):
+    """`handed` behind one more layer, which made ``made`` (the keys the
+    loop carries alone are kept: a loop's carry keeps its shape)."""
+    if not isinstance(sel, dict):
+        return sel
+    return dict(sel, depth=sel["depth"] + 1,
+                **{k: v.astype(sel[k].dtype) for k, v in made.items()
+                   if k in sel})
+
+
+def _pair_queries(cfg: TransformerConfig, q: jnp.ndarray, rotate
+                  ) -> jnp.ndarray:
+    """Queries [b, s, h, hd], turned where the layer turns -> what attends:
+    the same, or, of a differential model, each head against its HALF of the
+    pair's key row: head 2p as ``[q | 0]``, head 2p + 1 as ``[0 | q]`` [b,
+    s, h, 2 hd], so that the pair's two maps and its 2 hd values are ONE
+    grouped-query attention over rows of `key_dim` (a cached row is fetched
+    once for both maps)."""
+    if rotate is not None:
+        q = _rotate_heads(cfg, rotate, q)
+    if not cfg.diff_attn:
+        return q
+    b, s, h, hd = q.shape
+    pairs = q.reshape(b, s, h // 2, 2, hd)
+    zero = jnp.zeros((b, s, h // 2, hd), q.dtype)
+    return jnp.stack(
+        [jnp.concatenate([pairs[..., 0, :], zero], axis=-1),
+         jnp.concatenate([zero, pairs[..., 1, :]], axis=-1)],
+        axis=3).reshape(b, s, h, 2 * hd)
+
+
+def _pair_keys(cfg: TransformerConfig, k: jnp.ndarray, rotate
+               ) -> jnp.ndarray:
+    """A differential model's key rows [b, s, pairs, 2 hd]: each half turned
+    as the head it is."""
+    if rotate is None:
+        return k
+    b, s, hk, kd = k.shape
+    return _rotate_heads(cfg, rotate, k.reshape(
+        b, s, 2 * hk, kd // 2)).reshape(k.shape)
+
+
+def lambda_init(depth) -> jnp.ndarray:
+    """A differential layer's fixed part of ``lambda`` by its index among
+    all the model's layers: ``0.8 - 0.6 exp(-0.3 depth)``, float32."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
+
+@jax.named_scope("attention")
+@jax.named_scope("diff")
+def diff_pairs(cfg: TransformerConfig, attn: jnp.ndarray, lp: Params,
+               depth) -> jnp.ndarray:
+    """The two maps of each PAIR of heads [b, s, h, vd] -> [b, s, h / 2,
+    vd]: ``(1 - lambda_init) rmsnorm(o_1 - lambda o_2)`` with ``lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init(depth)``, float32, the norm
+    over the pair's ``vd`` values with a learned scale."""
+    b, s, h, vd = attn.shape
+    f32 = jnp.float32
+    dots = [jnp.sum(lp["diff_lq" + i].astype(f32)
+                    * lp["diff_lk" + i].astype(f32)) for i in "12"]
+    fixed = lambda_init(depth)
+    lam = jnp.exp(dots[0]) - jnp.exp(dots[1]) + fixed
+    o = attn.astype(f32).reshape(b, s, h // 2, 2, vd)
+    o = o[..., 0, :] - lam * o[..., 1, :]
+    return ((1.0 - fixed) * rmsnorm(o, lp["diff_norm"], norm_eps(cfg))
+            ).astype(attn.dtype)
+
+
+def attention_scale(cfg: TransformerConfig) -> Optional[float]:
+    """What an MHA/GQA block's scores are multiplied by where that is not
+    the attended row's ``width ** -0.5`` (None: it is): a differential
+    pair's key row is two heads wide, a score one head's."""
+    return cfg.head_dim ** -0.5 if cfg.diff_attn else None
+
+
+def cross_scope(kind: str):
+    """The scope ``cross`` AROUND all of a cross layer's operator (its parts
+    keep their own names inside it), nothing for any other layer."""
+    return jax.named_scope("cross") if kind == "cross" \
+        else contextlib.nullcontext()
+
+
+def _handing_attention(cfg: TransformerConfig, y, q, k, v, lp: Params,
+                       kind: str, sel):
+    """`_layer`'s MHA/GQA operator of a model that `hands_down`, the plain
+    form -> (what the layer adds to the residual, what it hands on): a
+    ``"cross"`` layer attends the rows it is handed, a ``"full"`` layer hands
+    its own on."""
+    with cross_scope(kind):
+        if kind == "cross":
+            k, v = sel["k"], sel["v"]
+        attn = multi_head_attention(
+            q, k, v, causal=cfg.causal, impl=cfg.attention_impl,
+            sm_scale=attention_scale(cfg),
+            window=cfg.sliding_window if kind == "window" else None)
+        delta = _attn_out(cfg, y, attn, lp, sel["depth"])
+    return delta, hand_on(sel, **({"k": k, "v": v} if kind == "full"
+                                  else {}))
+
+
+def _memory_layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
+                  kind: str, sel):
+    """`_layer` for a ``"mamba"`` or a ``"gmu"`` layer over a whole sequence
+    (no state carried in) -> (x, aux, what it hands on: a mamba layer its
+    scan output)."""
+    y = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_b"))
+    if kind == "mamba":
+        delta, m, _, _ = mamba_operator(cfg, y, lp)
+        sel = hand_on(sel, m=m)
+    else:
+        delta, sel = gmu_operator(cfg, y, lp, sel["m"]), hand_on(sel)
+    x = x + _post(cfg, delta, lp, "post_attn_norm")
+    y = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_b"))
+    z, aux, _ = _ffn(cfg, y, lp)
+    return x + _post(cfg, z, lp, "post_mlp_norm"), aux, sel
+
+
+def mamba_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+                   state: Optional[jnp.ndarray] = None,
+                   conv: Optional[jnp.ndarray] = None,
+                   n_new: Optional[jnp.ndarray] = None, layer=None):
+    """A selective-scan mixer on a normed input ``y`` [b, s, d] -> (what it
+    adds to the residual [b, s, d], its MEMORY ``m`` [b, s, mamba_inner] in
+    the compute type: the scan's output BEFORE the gate, what the ``"gmu"``
+    layers behind it read, the state' [b, state, inner] float32, the
+    convolution's last inputs' [b, taps - 1, inner]).  ``state`` None is the
+    PLAIN form over a whole sequence from a zero state
+    (`selective_scan.sequence`); with a carried ``state`` and ``conv`` one
+    token a row is `selective_scan.step` and a chunk `selective_scan.chunk`,
+    both advancing a row by its ``n_new`` [b] valid tokens only (None: all).
+    With ``layer`` (one token a row only) ``state`` is the STACK of every such
+    layer's states [L, b, 1, state, inner] and so is the state handed back
+    (`selective_scan.step_in_place`).
+
+    ALL of it stands under ``ssm``, as a state-space mixer's: inside, the
+    projections under ``projections``, the convolution under ``conv``, the
+    rest under ``attention``, the recurrence itself under
+    ``selective_scan``."""
+    from ..ops import selective_scan as scan
+    from ..ops.short_conv import short_conv
+    dt = cfg.dtype
+    e, n, r = cfg.mamba_inner, cfg.mamba_state, cfg.mamba_dt_rank
+    s = y.shape[1]
+    with jax.named_scope("ssm"):
+        with jax.named_scope("projections"):
+            u = jnp.einsum("bsd,de->bse", y, lp["mamba_in"].astype(dt))
+        a, z = u[..., :e], u[..., e:]
+        a, conv = short_conv(a, lp["mamba_conv"], conv, n_new,
+                             activation=jax.nn.silu, bias=lp["mamba_conv_b"])
+        with jax.named_scope("projections"):
+            low = jnp.einsum("bse,er->bsr", a, lp["mamba_x"].astype(dt))
+        with jax.named_scope("attention"):
+            B, C = low[..., r:r + n], low[..., r + n:]
+            step, A = scan.gates(low[..., :r], lp["mamba_dt"],
+                                 lp["mamba_dt_b"], lp["mamba_a_log"])
+            if state is None:
+                m, state = scan.sequence(a, B, C, step, A, lp["mamba_d"])
+            elif s == 1:
+                rule = scan.step if layer is None else functools.partial(
+                    scan.step_in_place, l=layer)
+                m, state = rule(
+                    a[:, 0], B[:, 0], C[:, 0], step[:, 0], A, lp["mamba_d"],
+                    state, live=None if n_new is None else n_new > 0)
+                m = m[:, None]
+            else:
+                m, state = scan.chunk(a, B, C, step, A, lp["mamba_d"], state,
+                                      n_new)
+            o = (m * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+        with jax.named_scope("projections"):
+            return (jnp.einsum("bse,ed->bsd", o, lp["mamba_out"].astype(dt)),
+                    m.astype(dt), state, conv)
+
+
+def gmu_operator(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+                 m: jnp.ndarray) -> jnp.ndarray:
+    """A gated memory unit on a normed input ``y`` [b, s, d] and the memory
+    ``m`` [b, s, mamba_inner] of the SAME positions -> what it adds to the
+    residual: ``(m * silu(y W_1)) W_2``, elementwise in the position (a
+    decode step needs this step's memory alone).  All under ``gmu``."""
+    dt = cfg.dtype
+    with jax.named_scope("gmu"):
+        with jax.named_scope("projections"):
+            g = jnp.einsum("bsd,de->bse", y, lp["gmu_in"].astype(dt))
+        with jax.named_scope("attention"):
+            o = (m.astype(jnp.float32)
+                 * jax.nn.silu(g.astype(jnp.float32))).astype(dt)
+        with jax.named_scope("projections"):
+            return jnp.einsum("bse,ed->bsd", o, lp["gmu_out"].astype(dt))
 
 
 def _stepped(params, updates, state, given):

@@ -27,8 +27,15 @@ repeats the block it named last, which moves nothing.  A slot with nothing
 to see, or that ``live`` says stands, is one item that moves nothing and
 writes zeros.  Inside an item the mask is still applied row by row.
 
+A set's VALUE heads are as many as its key rows whatever either's width
+(``hk`` of ``hd`` and of ``vd``): where a model's key heads and value heads
+differ in COUNT (a differential pair's two keys of 64 beside ONE value of
+128) a key ROW holds what one value head's queries meet, the pair's two keys
+side by side, each query padded to its half, and ``scale`` says what a score
+is multiplied by (one head's ``head_dim ** -0.5``, not the row's).
+
 The arithmetic is `attend_two`'s: operands in the compute type, float32
-scores x ``head_dim ** -0.5``, ``-1e30`` under the mask, float32 running
+scores x ``head_dim ** -0.5`` (or ``scale``), ``-1e30`` under the mask, float32 running
 maximum and sum across all sets, probabilities cast to the compute type for
 the value product, float32 accumulation, normalised after the values are
 summed.
@@ -259,12 +266,16 @@ def _kernel(l_ref, item_ref, runs_ref, held_ref, q_ref, m_ref, *refs,
 
 @jax.named_scope("attention")
 def attend_blocks(q: jnp.ndarray, sets: Sequence[RowSet], l,
-                  live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                  live: Optional[jnp.ndarray] = None,
+                  scale: Optional[float] = None) -> jnp.ndarray:
     """``q`` [S, 1, hk, g, hd] (ONE query a slot) over layer ``l`` of the
     row ``sets`` (`kernel_shape` accepted them) under ONE softmax, only where
     ``live`` [S] is set (None: every slot; zeros elsewhere) -> [S, 1, hk, g,
     vd] in ``q``'s type: `ops.eva_attention.attend_two`'s result over the
-    same rows, moving only the blocks `block_work` lists."""
+    same rows, moving only the blocks `block_work` lists.  ``scale`` (None:
+    ``hd ** -0.5``) multiplies the scores: a row that holds a differential
+    pair's two keys side by side is two heads wide, a score one head's, and
+    its value heads are as many as its key rows whatever their width."""
     slots, _, hk, g, hd = q.shape
     vd = sets[0][1].shape[-2]
     masks = [jnp.broadcast_to(m, (slots, 1, m.shape[-1])) for _, _, m in sets]
@@ -279,7 +290,8 @@ def attend_blocks(q: jnp.ndarray, sets: Sequence[RowSet], l,
 
     by_slot = lambda w, l, item, runs, held: (item[w] // nb, 0, 0, 0)
     out = pl.pallas_call(
-        functools.partial(_kernel, nb=nb, starts=starts, scale=hd ** -0.5),
+        functools.partial(_kernel, nb=nb, starts=starts,
+                          scale=hd ** -0.5 if scale is None else scale),
         name="cache_block_attention",
         out_shape=jax.ShapeDtypeStruct((slots, hk, g, vd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -371,7 +383,8 @@ def _chunk_kernel(l_ref, item_ref, runs_ref, held_ref, q_ref, m_ref, *refs,
 
 @jax.named_scope("attention")
 def attend_chunk_blocks(q: jnp.ndarray, sets: Sequence[RowSet], l,
-                        live: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                        live: Optional[jnp.ndarray] = None,
+                        scale: Optional[float] = None) -> jnp.ndarray:
     """`attend_blocks` for the ``C`` queries of a CHUNK a lane: ``q`` [P, C,
     hk, g, hd] over layer ``l`` of the row ``sets`` (masks ``[P | 1, C, T]``;
     `kernel_shape` accepted them) under ONE softmax a query, only where
@@ -406,7 +419,8 @@ def attend_chunk_blocks(q: jnp.ndarray, sets: Sequence[RowSet], l,
     cols = jnp.transpose(q, (0, 2, 4, 3, 1)).reshape(lanes, hk, hd, g * c)
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, nb=nb, starts=starts,
-                          scale=hd ** -0.5, group=g),
+                          scale=hd ** -0.5 if scale is None else scale,
+                          group=g),
         name="cache_chunk_attention",
         out_shape=jax.ShapeDtypeStruct((lanes, hk, vd, g * c), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -430,6 +430,9 @@ class ContinuousBatchingEngine:
         # memory, beside the rows a real query of theirs saw
         # (`CacheTraffic.chunk`)
         self.chunk_rows_fetched = self.chunk_rows_read = 0
+        # ... the rows those programs fed (lanes x chunk), and the rows the
+        # model's stateless tail ran of them (`CacheTraffic.tail_rows`)
+        self.rows_fed = self.tail_rows = 0
         self._lanes_span = dict(self._lane_sums(), t=time.time())
         # ... of them those with fewer real tokens than the chunk holds
         # (a prompt's remainder), and the padding rows those carried
@@ -1042,12 +1045,14 @@ class ContinuousBatchingEngine:
         return {"programs": self.prefill_programs,
                 "chunks": self.prefill_chunks,
                 "chunk_rows_fetched": self.chunk_rows_fetched,
-                "chunk_rows_read": self.chunk_rows_read}
+                "chunk_rows_read": self.chunk_rows_read,
+                "rows_fed": self.rows_fed, "tail_rows": self.tail_rows}
 
     def _count_chunks(self, riders: List[Tuple[_EngineSession, int]],
-                      wall: float) -> None:
-        """ONE chunk program that took ``wall`` seconds of the engine
-        thread and carried ``riders``: (session, real rows of its chunk)."""
+                      wall: float, lanes: int = 1) -> None:
+        """ONE chunk program of ``lanes`` lanes that took ``wall`` seconds of
+        the engine thread and carried ``riders``: (session, real rows of its
+        chunk)."""
         from ..core.runtime_metrics import SERVE_PREFILL_CHUNKS
         chunk = self.ecfg.prefill_chunk_tokens
         now = time.monotonic()
@@ -1068,6 +1073,8 @@ class ContinuousBatchingEngine:
             self.prefill_pad_tokens += sum(chunk - n for n in tails)
             self.chunk_rows_fetched += sum(f for f, _ in moved)
             self.chunk_rows_read += sum(r for _, r in moved)
+            self.rows_fed += lanes * chunk
+            self.tail_rows += self._traffic.tail_rows(lanes)
         self._lanes_span = self._sums_span(
             "engine:lanes", "lanes", self._lane_sums(), self._lanes_span)
         SERVE_PREFILL_CHUNKS.inc(len(riders),
@@ -1197,7 +1204,8 @@ class ContinuousBatchingEngine:
         for sess in riders:
             sess.poff = moved[sess.lane][0]
         self._count_chunks([(s, moved[s.lane][1]) for s in riders],
-                           self._prof.wall_of("prefill_chunk") - wall0)
+                           self._prof.wall_of("prefill_chunk") - wall0,
+                           self._n_lanes)
         done = [s for s in riders if s.poff >= int(s.prompt.shape[1])]
         if not done:
             return []

@@ -70,10 +70,11 @@ _LONGEST_FIRST = (
     "test_perfbench_family_kimi_linear.py", "test_delta_rule.py",
     "test_perfbench_family_falcon_h1.py", "test_ssd.py",
     "test_perfbench_family_dots3_note.py",
+    "test_perfbench_family_phi4flash.py",
     "test_perfbench_family_mimo_v2_flash.py", "test_mixed_kv_heads.py",
     "test_serve_decode_engine.py", "test_prefill_padded_tail.py",
     "test_window_ring.py", "test_window_latent.py",
-    "test_short_conv_state.py", "test_gbdt.py",
+    "test_short_conv_state.py", "test_shared_cache.py", "test_gbdt.py",
     "test_rl.py", "test_latent_moe.py", "test_dt.py",
     "test_multi_agent.py", "test_grouped_matmul.py",
     "test_perfbench_family_evabyte.py", "test_generate.py",
@@ -81,7 +82,8 @@ _LONGEST_FIRST = (
     "test_external_env.py", "test_perfbench_engine_ahead.py",
     "test_pipeline_moe.py", "test_serve_failover.py",
     "test_program_parts.py", "test_perfbench_reference.py",
-    "test_apex.py", "test_hf_trainer.py", "test_eva_attention.py",
+    "test_apex.py", "test_hf_trainer.py", "test_diff_attention.py",
+    "test_eva_attention.py", "test_selective_scan.py",
     "test_cache_attention.py", "test_cache_in_place.py",
     "test_pixel_pong.py", "test_scale.py",
     "test_offline_rl.py", "test_rl_plumbing.py", "test_tune.py",

@@ -1585,3 +1585,119 @@ def test_two_cache_shapes_by_layer_kind_copy_no_cache_no_weights(topo,
         for c in calls), calls
     assert not re.findall(
         r"= bf16\[(?:\d+,)?16,(?:4096,2048|2048,4096)\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["fused_step", "prefill_padded_128",
+                                     "prefill_lanes_4x128"])
+def test_layers_that_read_another_layers_cache_copy_nothing_on_the_v5e(
+        topo, program):
+    """A Phi-4-mini-flash-shaped model (six layers at the published widths:
+    ``mamba, window, mamba, full, gmu, cross``; 40 query heads of 64 in pairs
+    over 10 key rows of 128; a selective scan of 5120 channels with a state
+    of 16) compiled for the described v5e: every cache array goes aliased
+    from argument to result; NO copy of the stacked float32 states
+    (``s_mamba``), of the one layer of rows or of a ring; the fused step
+    attends the shared rows by ONE kernel call a READING layer that fetches
+    a slot's visible blocks (`cache_block_attention`: the full layer and the
+    cross layer, each rows and values from one pass); the chunk programs
+    scan by the selective-scan kernel, a call a mamba layer, attend their
+    chunk's blocks by the chunk kernel on the layers that run the chunk's
+    rows, and run the stateless tail's cross layer on ONE row a lane through
+    the step's kernel."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perfbench import manifest as mf
+    from ray_tpu.models import (init_kv_cache, init_params, init_slot_cache,
+                                prefill_chunk)
+    from ray_tpu.models.generate import _decode_step_slots
+    c = mf.Manifest().config("phi-4-mini-flash")
+    kinds = ["mamba", "window", "mamba", "full", "gmu", "cross"]
+    c = dict(c, num_hidden_layers=len(kinds),
+             assumed=dict(c["assumed"], layer_kinds=kinds))
+    cfg = mf.family_of(c).model.model_config(c, "serve")
+    assert cfg.stateless_tail == 2
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 8, 2048
+    if program == "fused_step":
+        cache = described(jax.eval_shape(
+            lambda: init_slot_cache(cfg, slots, max_len)))
+        assert cache["k"].shape == (1, slots, 10, 128, max_len)
+        assert cache["k_win"].shape == (1, slots, 10, 128, 640)
+        assert cache["s_mamba"].shape == (2, slots, 1, 16, 5120)
+
+        def fused_step(params, tok, cache, active):
+            logits, cache, _ = _decode_step_slots(params, tok, cache,
+                                                  active, cfg)
+            return jnp.argmax(logits, axis=-1), cache
+        lowered = jax.jit(fused_step, donate_argnums=(2,)).lower(
+            params, described(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+            cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+        rows = slots
+    elif program == "prefill_padded_128":
+        cache = described(jax.eval_shape(
+            lambda: init_kv_cache(cfg, 1, max_len)))
+        lowered = jax.jit(prefill_chunk, static_argnames=("cfg",),
+                          donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, 128), jnp.int32)),
+            cache, cfg=cfg,
+            n_valid=described(jax.ShapeDtypeStruct((), jnp.int32)))
+        rows = 1
+    else:
+        cache, lowered, rows, _ = _lower_lanes(described, params, cfg,
+                                               program, max_len)
+    compiled = lowered.compile()
+    arrays = [a for name, a in cache.items() if name != "pos"]
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in arrays)
+    text = compiled.as_text()
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    held = {f"{'f32' if a.dtype == jnp.float32 else 'bf16'}"
+            f"[{','.join(map(str, shape))}]"
+            for name, a in cache.items() if name not in ("pos",
+                                                         "conv_mamba")
+            for shape in (a.shape, a.shape[1:])}
+    assert not held & set(copies), held & set(copies)
+    calls = [x for x in re.findall(r"= [^\n]* custom-call\([^\n]*", text)
+             if "tpu_custom_call" in x]
+
+    def named(name):
+        return sum(name in x for x in calls)
+    if program == "fused_step":
+        # the full layer and the cross layer, a call each; no chunk kernel
+        assert named("cache_block_attention") == 2
+        assert named("selective_scan_chunk") == 0
+        assert named("cache_chunk_attention") == 0
+    else:
+        assert named("selective_scan_chunk") == 2       # a mamba layer each
+        # the window and the full layer attend the chunk's blocks; the tail's
+        # cross layer one row a lane
+        assert named("cache_chunk_attention") == 2
+        assert named("cache_block_attention") == 1
+        # the tail's feed-forwards run on one row a lane: a dot of `rows`
+        # rows against the feed-forward's width
+        one_row = "10240" if rows == 1 else f"{rows},(?:1,)?10240"
+        assert re.search(rf"\[{one_row}\]", text)
+    if program == "prefill_padded_128":
+        # the program of a chunk that is not its prompt's last: no tail, no
+        # head (the embedding is read for its 128 rows alone)
+        short = jax.jit(prefill_chunk, static_argnames=("cfg", "tail"),
+                        donate_argnames=("cache",)).lower(
+            params, described(jax.ShapeDtypeStruct((1, 128), jnp.int32)),
+            cache, cfg=cfg, tail=False,
+            n_valid=described(jax.ShapeDtypeStruct((), jnp.int32))
+        ).compile().as_text()
+        assert "200064]" in text and not re.search(
+            r"= \w+\[(?:\d+,)*200064\]\S* (?:convolution|dot|fusion)\(", short)
+        assert short.count("cache_block_attention") == 0
+        assert short.count("selective_scan_chunk") >= 2

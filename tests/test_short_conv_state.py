@@ -397,7 +397,7 @@ def test_engine_writes_the_states_bytes_into_its_cache_rows_span(
 
 
 def test_the_engines_cache_sums_are_the_traffics_by_name(core):
-    """`CacheTraffic.STEP_SUMS`: the fifteen names the readers of the
+    """`CacheTraffic.STEP_SUMS`: the sixteen names the readers of the
     ``cache:rows`` span know, in their order; a real engine's
     ``stats()["cache"]`` holds them behind ``steps``, after its ``bytes*``
     keys."""
@@ -407,7 +407,7 @@ def test_the_engines_cache_sums_are_the_traffics_by_name(core):
         "summary_rows_read", "summary_bytes_read", "index_rows_read",
         "index_bytes_read", "ring_latent_bytes_read", "state_rows",
         "state_bytes_moved", "state_bytes_fetched", "rows_fetched",
-        "column_writes", "column_write_calls")
+        "column_writes", "column_write_calls", "shared_bytes_read")
     keys = tuple(core.engine.stats()["cache"])
     sums = ("steps",) + CacheTraffic.STEP_SUMS
     assert keys[-len(sums):] == sums
