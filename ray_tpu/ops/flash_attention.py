@@ -36,20 +36,60 @@ that fits.  Inside, a loop walks tiles of ``block_q x block_k`` over the part
 of the major block that causality leaves live, each with one compare and
 select against a hoisted iota.  (A second, unmasked body for the tiles the
 diagonal does not cross was built and measured: it saves 0.1-0.4 % of the
-kernels' time, the vector units are not what binds, and costs a second copy
+backward kernels' time and 3 % of the forward's, and costs a second copy
 of every unrolled body to trace and lower at each start of a program and to
-compile; PERF.md, PR 35.  Without ``causal`` nothing is masked.)  Major
+compile; PERF.md, PRs 35 and 61.  Without ``causal`` nothing is masked.)  Major
 blocks that are wholly dead have their index clamped to the last live one,
 so they cost no transfer.  The softmax scale is folded into the resident operand where that
 is exact (a power of two: head sizes 64 and 256), and the dead-row select
 exists only where ``s_q > s_kv`` makes dead rows possible.
 
-**No reduction across lanes in a tile.**  The forward makes two passes over
-a grid step's live tiles (`_fwd_kernel`): row maxima from the scores computed
-transposed, then exponentials and sums against them; the dkv kernel computes
-its scores transposed too (``k q^T``), so its statistics are rows as stored
-and none of its four matmuls transposes a score tile; dq takes the
-statistics as columns, transposed once a q block.
+**No reduction across lanes in a tile.**  The forward and the dkv kernel
+hold a tile's scores transposed, ``[block_k, block_q]`` (``k q^T``): the keys
+run down the sublanes, so the forward's maxima and sums fold vector against
+vector and its statistics, like the dkv kernel's, are rows as stored; no
+matmul of either transposes a score tile.  dq takes the statistics as
+columns, transposed once a q block.
+
+**One forward pass** (`_fwd_kernel`): a tile's scores are computed once,
+the online softmax rescales a transposed accumulator (``acc^T = acc^T alpha
++ v^T e``), and all heads' FIRST matmul is issued before any head's
+exponentials.  Measured on one v5e chip, the forward alone, the kernel's own
+ms at ``[8, 1024, 16, 64]`` / ``[2, 1024, 25, 64]`` (PERF.md, PR 61).  The
+two passes it replaces (row maxima from ``k q^T``, then ``q k^T`` again,
+exponentials, lane-folded sums and a float32 `HIGHEST` product to sum them;
+PR 35): 0.671 / 0.283.  One pass, HEAD BY HEAD (a head's two matmuls and
+its exponentials in turn): ``v^T`` held in scratch for the resident rows
+0.626 / 0.258 (the sums folded to one row a tile 0.625 / 0.256; all heads'
+``acc^T`` transposed at once at the end 0.605 / 0.249), ``v^T`` made every
+grid step 0.653 / 0.271, the contraction over the v tile's rows left to
+Mosaic 0.608 / 0.247 (0.587 / 0.242 with the one transpose at the end), the
+accumulator untransposed and ``e`` transposed by Mosaic 0.667 / 0.277, ``q^T``
+made once a grid step for the first matmul 0.627 / 0.258: one matmul and
+the reductions fewer bought 7-13 %.  Taking pieces out of the 0.605 form said
+why: without the mask 0.601, without the exponential 0.605, without the sum
+0.584, without the maximum 0.496, but without the SECOND matmul 0.289,
+without the first 0.251, without both 0.242: matmuls are issued in program
+order, so head by head the vector units wait for a head's scores and the MXU
+for its exponentials.  ALL HEADS' SCORES FIRST, then each head's softmax
+and second matmul: **0.319 / 0.140** (kept: -52 / -51 %); the same with
+``v^T`` held 0.325 / 0.144, ``o`` written a head 0.340 / 0.148, in three
+phases 0.319 / 0.142, one head ahead 0.370 / 0.159, in halves of four
+0.326 / 0.144; a second, unmasked body for the tiles under the diagonal
+0.309 / 0.138 (not kept: a second copy of the body to trace and lower at
+every start for 3 %).  Tiles (the plan's 8 heads, the forward's own tile;
+kept order): 512 x 128 0.313 / 0.136, 512 x 256 0.340 / 0.144, 256 x 128
+0.308 / 0.142, 128 x 256 0.367 / 0.169: none gains at both shapes, the tile
+stays the plan's.  Head by head a wider q tile gained most (512 x 512 0.421),
+the same waiting seen from the other side.  Every other plan, two passes ->
+one: 4 heads of 64 over 4096 rows 1.471 -> 0.786, GQA 8 over 2 at 2048 rows
+0.987 -> 0.529, 4 heads of 128 over one kv head 0.571 -> 0.355, 16 heads
+over ONE kv head 0.331 -> 0.158, ViT's one tile of 197 rows at batch 32
+0.291 -> 0.096, 1024 queries on 2048 keys 0.394 -> 0.207, 2048 on 1024
+(dead rows) 0.267 -> 0.120, 16384 rows in major blocks of 8192 (k, v not
+resident) 13.76 -> 8.10: one algorithm for all, nothing keeps the two
+passes.  The dq and dkv bodies still run head by head: the same reordering
+is theirs to try (ROADMAP.md [train attention]).
 
 **One backward kernel** (`one_backward`, a function of the plan alone).  The
 dq and the dkv kernel walk the same live tiles and each computes a tile's
@@ -84,10 +124,10 @@ of 64 over 4096 rows 2.504 -> 1.766, GQA 8 over 2 at 2048 rows 1.722 ->
 1.127, 4 heads of 128 over one 0.900 -> 0.690, ViT's one tile of 197 rows
 0.388 -> 0.237), so no plan that can hold the accumulator takes the two.
 
-The default tile (`DEFAULT_BLOCK_Q` x `DEFAULT_BLOCK_K`), `_MAX_HEADS` and the
-two-pass forward were measured on one v5e chip on the three kernels alone at
+The default tile (`DEFAULT_BLOCK_Q` x `DEFAULT_BLOCK_K`) and `_MAX_HEADS`
+were measured on one v5e chip on the kernels alone at
 ``[8, 1024, 16, 64]`` and ``[2, 1024, 25, 64]`` (device time from a profiler
-trace; PERF.md, PR 35); ``block_q`` / ``block_k`` stay as arguments for tests
+trace; PERF.md, PRs 35, 56 and 61); ``block_q`` / ``block_k`` stay as arguments for tests
 and sweeps.  bf16 in and out (operands enter the MXU in their storage
 dtype), float32 scores, statistics and accumulators.  A tile divides its
 sequence and is a multiple of 128 rows, or it is the whole sequence, of any
@@ -187,8 +227,15 @@ def _lane_ok(n: int, d: int, total: int) -> bool:
 
 def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize,
                  with_dq: bool = False) -> int:
-    """VMEM of the hungrier of the forward / dq and the dkv kernel: double
-    buffered blocks, scratch, and the float32 tiles of one head.
+    """VMEM of the hungriest of the forward, the dq and the dkv kernel:
+    double buffered blocks, scratch, and the float32 tiles of one head.  The
+    forward and the dq kernel take the same blocks; the forward's scratch is
+    the scaled q, the float32 ``acc^T``, row statistics of 9 sublanes a head
+    and every head's tile of scores beside the one head's ``tiles`` (it
+    issues all heads' first matmul before any exponential: 0.5 + 0.5 + 0.07
+    + 2 MB at 8 heads of 64 over 1024 rows), the dq kernel's the scaled q, a
+    float32 accumulator a head padded to the lanes and two columns of
+    statistics (3.5 MB there).
     ``with_dq``: of the dkv kernel where it accumulates dq as well
     (`one_backward`; ``major_q`` is then the whole query side): beside its
     own blocks the float32 accumulator ``[hq x d, major_q]`` (lane-dense:
@@ -197,9 +244,11 @@ def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize,
     dkv kernel's 10.4 at the train cells' plan (8 heads of 64, 1024 rows)."""
     dp = -(-d // _LANES) * _LANES
     tiles = 6 * bq * bk * 4
+    forward = hq * bq * (dp * itemsize + (d + 9 + bk) * 4)
+    dq = hq * bq * (dp * (4 + itemsize) + 2 * _LANES * 4)
     walk_q = (2 * 3 * bq * hq * d * itemsize            # q, do / o, dq
               + 2 * 2 * major_k * hk * d * itemsize     # k, v
-              + hq * bq * (dp * (4 + itemsize) + 4 * _LANES * 4))
+              + max(forward, dq))
     walk_k = (2 * 2 * major_q * hq * d * itemsize       # q, do
               + 2 * 4 * bk * hk * d * itemsize          # k, v, dk, dv
               + 2 * 2 * hq * major_q * 4                # lse, delta
@@ -293,6 +342,12 @@ def _nn(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _tn(a, b):
+    """``a.T @ b``: Mosaic transposes ``a``."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _scores(a, b, sm_scale, seen):
     """A tile of scores ``a @ b.T``: scaled here unless the scale is folded
     into an operand (`_fill_scaled`), and `_NEG_INF` outside ``seen`` (None:
@@ -324,15 +379,6 @@ def _row_to_col(row):
     """``(1, n)`` -> ``(n, 128)``, the column on every lane, through a
     whole-tile transpose."""
     return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
-
-
-def _wide(col, like):
-    """A lane-replicated column ``(n, 128)`` against tiles ``(n, w)``, any
-    ``w``."""
-    w = like.shape[1]
-    if w > _LANES:
-        col = jnp.concatenate([col] * -(-w // _LANES), axis=1)
-    return col[:, :w]
 
 
 def _div(a, b: int):
@@ -418,22 +464,6 @@ def _for_tiles(lo, hi, body):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fold_width(n: int) -> int:
-    """Columns `_fold_lanes` leaves of ``n``: the 128 lanes, or all of a
-    tile that is not whole lanes (a short sequence that is one tile)."""
-    return n if n % _LANES else _LANES
-
-
-def _fold_lanes(x, op):
-    """``[rows, n]`` -> ``[rows, _fold_width(n)]``: ``op`` over the columns
-    that share a lane.  Vector work only: a reduction ACROSS the lanes of
-    every row's vector is a chain of lane rotations, the slowest thing a
-    tile can ask for."""
-    w = _fold_width(x.shape[1])
-    return functools.reduce(
-        op, [x[:, c:c + w] for c in range(0, x.shape[1], w)])
-
-
 def _fold_rows(x, op, reduce_rows):
     """``[n, cols]`` -> ``[8, cols]``: ``op`` over the rows that share a
     sublane, vector against vector, by halves (a 256-row tile is 15
@@ -449,32 +479,31 @@ def _fold_rows(x, op, reduce_rows):
     return functools.reduce(op, [x[r:r + 8] for r in range(0, x.shape[0], 8)])
 
 
-def _lane_sums(x):
-    """``[rows, w]`` float32 -> ``[rows, 128]``, each row's sum on every
-    lane: a product with ones on the MXU, which has the room (float32 at
-    `HIGHEST`: the summands are not rounded)."""
-    return jax.lax.dot_general(
-        x, jnp.ones((x.shape[1], _LANES), x.dtype), (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
+def _fold_height(n: int) -> int:
+    """Rows `_fold_rows` leaves of ``n``."""
+    return 1 if n % 8 else 8
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, acc_ref, m_ref,
-                l_ref, mt_ref, lw_ref, *, p: Plan, sm_scale, causal,
-                q_offset, dead_rows):
-    """Two passes over the live tiles of the resident k, v rows, and no
-    reduction across lanes in either.  The first finds each query's
-    maximum from the scores TRANSPOSED (``k q^T``: the keys run down the
-    sublanes, so the maximum folds vector against vector into 8 rows);
-    the second exponentiates the scores against it and accumulates, the
-    row sums folded lane-wise and summed by the MXU (`_lane_sums`).  No
-    tile rescales the accumulator; across major blocks (a sequence that
-    is not resident) the usual online rescale happens once a grid step.
-    Measured on the v5e (PERF.md, PR 35): the one-pass online softmax, two
-    lane reductions a tile, held the forward at 1.05 ms where dq, with
-    more matmuls, takes 0.45; two passes with the reductions once a grid
-    step 0.73, of which 0.29 were those reductions.  The scores are
-    computed twice; the MXU has the room."""
+                l_ref, *, p: Plan, sm_scale, causal, q_offset, dead_rows):
+    """ONE pass over the live tiles of the resident k, v rows, the scores
+    held ``[block_k, block_q]`` as the dkv kernel holds them: the keys run
+    down the sublanes, so a tile's maxima and sums fold vector against
+    vector into 8 rows (`_fold_rows`) and the statistics are rows
+    ``[1, block_q]``, broadcast down the sublanes: no reduction across the
+    lanes, no second pass, no sum on the MXU.  The accumulator is kept
+    transposed, ``acc^T [d, block_q] = acc^T alpha + v^T e``, the tile as it
+    lies under a contraction over the v tile's rows (Mosaic transposes the
+    ``[block_k, d]`` tile on the transpose unit, which has nothing else to
+    do; a ``v^T`` held in scratch was slower by what making it costs).  One
+    transpose of all heads' ``acc^T`` at the end gives ``o``; ``lse`` is
+    the row the result wants.  Every tile rescales by ``alpha``, across
+    major blocks too.
+
+    A tile's matmuls are issued in program order, so the FIRST matmul of
+    every head comes before any head's exponentials: head by head the MXU
+    and the vector units wait for each other, and the whole one-pass gain
+    is in this order (module docstring: 0.587 -> 0.319 ms)."""
     qi, ki = pl.program_id(2), pl.program_id(3)
     last_k = pl.num_programs(3) - 1
     bq, bk, d = p.block_q, p.block_k, p.d
@@ -485,64 +514,48 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, acc_ref, m_ref,
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
+            # all of it: a last block's heads outside the array stay zero,
+            # and Pallas drops them
             acc_ref[...] = jnp.zeros_like(acc_ref)
             _fill_scaled(qs_ref, q_ref, nq, d, sm_scale)
 
-        if causal:
-            rel = _query_minus_key((bq, bk), 0)
-            rel_t = _query_minus_key((bk, bq), 1)
+        rel = _query_minus_key((bk, bq), 1) if causal else None
 
-        def seen(t, rel):
-            # visible: q_offset + qi bq + r >= (ki n + t) bk + c
-            return rel >= (ki * p.major_k + t * bk) - qi * bq - q_offset
-
-        def row_max(t):
+        def tile(t):
             rows = _tile_rows(t, bk, p.major_k)
-            vis = seen(t, rel_t) if causal else None
-            for g in range(nq):
-                st = _scores(_heads(k_ref, rows, g // per_kv, d), qs_ref[g],
-                             sm_scale, vis)
-                mt_ref[g] = jnp.maximum(mt_ref[g],
-                                        _fold_rows(st, jnp.maximum, jnp.max))
-
-        def accumulate(t):
-            rows = _tile_rows(t, bk, p.major_k)
-            vis = seen(t, rel) if causal else None
-            for g in range(nq):
-                s = _scores(qs_ref[g], _heads(k_ref, rows, g // per_kv, d),
-                            sm_scale, vis)
-                m = _wide(m_ref[g], s)
-                e = jnp.exp(s - m)
+            # visible: q_offset + qi bq + c >= (ki n + t) bk + r
+            seen = (rel >= (ki * p.major_k + t * bk) - qi * bq - q_offset) \
+                if causal else None
+            scores = [_scores(_heads(k_ref, rows, g // per_kv, d), qs_ref[g],
+                              sm_scale, seen) for g in range(nq)]  # [bk, bq]
+            for g, st in enumerate(scores):
+                head = slice(g * d, (g + 1) * d)
+                m_prev = m_ref[g:g + 1, :]
+                m_new = jnp.maximum(m_prev, jnp.max(
+                    _fold_rows(st, jnp.maximum, jnp.max), axis=0,
+                    keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                e = jnp.exp(st - m_new)
                 if dead_rows:
-                    # A row that sees no key so far (causal with s_q > s_kv:
-                    # rows above the diagonal of their first k tile) has m ==
-                    # _NEG_INF, making exp(s - m) == 1 for every masked column
-                    # — zero those rows instead of averaging V uniformly.
-                    e = jnp.where(m <= _NEG_INF * 0.5, 0.0, e)
-                lw_ref[g] += _fold_lanes(e, jnp.add)
-                acc_ref[g] += _nn(e.astype(v_ref.dtype),
-                                  _heads(v_ref, rows, g // per_kv, d))
+                    # A query that sees no key so far (causal with s_q > s_kv:
+                    # queries above the diagonal of their first k tile) has m
+                    # == _NEG_INF, making exp(s - m) == 1 for every masked key
+                    # — zero those instead of averaging V uniformly.
+                    e = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, e)
+                m_ref[g:g + 1, :] = m_new
+                l_ref[g] = l_ref[g] * alpha + _fold_rows(e, jnp.add, jnp.sum)
+                acc_ref[head, :] = acc_ref[head, :] * alpha + _tn(
+                    _heads(v_ref, rows, g // per_kv, d),
+                    e.astype(v_ref.dtype))
 
-        live = _k_tiles(qi, ki, p, causal, q_offset)
-        mt_ref[...] = jnp.full_like(mt_ref, _NEG_INF)
-        lw_ref[...] = jnp.zeros_like(lw_ref)
-        _for_tiles(0, live, row_max)
-        for g in range(nq):
-            m_prev = m_ref[g]
-            m_new = jnp.maximum(m_prev, _row_to_col(
-                jnp.max(mt_ref[g], axis=0, keepdims=True)))
-            alpha = jnp.exp(m_prev - m_new)
-            m_ref[g] = m_new
-            l_ref[g] = l_ref[g] * alpha
-            acc_ref[g] = acc_ref[g] * _wide(alpha, acc_ref[g])
-        _for_tiles(0, live, accumulate)
-        for g in range(nq):
-            l_ref[g] += _lane_sums(lw_ref[g])
+        _for_tiles(0, _k_tiles(qi, ki, p, causal, q_offset), tile)
 
         @pl.when(ki == last_k)
         def _finish():
             for g in range(nq):
-                m, l = m_ref[g], l_ref[g]
+                head = slice(g * d, (g + 1) * d)
+                m = m_ref[g:g + 1, :]
+                l = jnp.sum(l_ref[g], axis=0, keepdims=True)
                 if dead_rows:
                     # Dead rows (m still _NEG_INF) get lse = 0 so the backward
                     # kernels' exp(s - lse) = exp(_NEG_INF) underflows to zero
@@ -553,9 +566,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_ref, acc_ref, m_ref,
                     l = jnp.where(l == 0.0, 1.0, l)
                 else:
                     lse = m + jnp.log(l)
-                o_ref[0, :, g * d:(g + 1) * d] = (
-                    acc_ref[g] / _wide(l, acc_ref[g])).astype(o_ref.dtype)
-                lse_ref[0, 0, g:g + 1, :] = jnp.transpose(lse)[:1]
+                lse_ref[0, 0, g:g + 1, :] = lse
+                acc_ref[head, :] = acc_ref[head, :] / l
+            o_ref[0] = jnp.transpose(acc_ref[...]).astype(o_ref.dtype)
 
     _inside(pl.program_id(1), p, run)
 
@@ -611,12 +624,11 @@ def _flash_fwd(q, k, v, causal, sm_scale, p: Plan):
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
         scratch_shapes=[
-            pltpu.VMEM((p.hq, p.block_q, p.d), q.dtype),
-            pltpu.VMEM((p.hq, p.block_q, p.d), jnp.float32),
-            pltpu.VMEM((p.hq, p.block_q, _LANES), jnp.float32),
-            pltpu.VMEM((p.hq, p.block_q, _LANES), jnp.float32),
-            pltpu.VMEM((p.hq, 8, p.block_q), jnp.float32),
-            pltpu.VMEM((p.hq, p.block_q, _fold_width(p.block_k)), jnp.float32),
+            pltpu.VMEM((p.hq, p.block_q, p.d), q.dtype),            # q scaled
+            pltpu.VMEM((p.hq * p.d, p.block_q), jnp.float32),       # acc^T
+            pltpu.VMEM((p.hq, p.block_q), jnp.float32),             # m
+            pltpu.VMEM((p.hq, _fold_height(p.block_k), p.block_q),
+                       jnp.float32),                                # l
         ],
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),

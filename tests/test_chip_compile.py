@@ -10,6 +10,7 @@ that is handed this file loads it, and it compiles in its own process.
 """
 
 import math
+import re
 
 import pytest
 
@@ -93,8 +94,34 @@ def test_flash_kernel_compiles_for_v5e(topo, shape, kernel):
         fn = lambda q, k, v, o, lse, do: fa._flash_bwd(
             q, k, v, o, lse, do, True, scale, plan)[pick]
         args = (q, kv, kv, q, row, q)
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    traced = jax.jit(fn).trace(*args)
+    text = traced.lower().compile().as_text()
     assert text.count("tpu_custom_call") == 1, text[:2000]
+    if kernel == "fwd":
+        # one pass, the sums folded on the vector units: every product of
+        # the forward takes the operands' bfloat16 at the default precision
+        # (the two-pass forward summed its exponentials with a float32
+        # `HIGHEST` product), and what the compiler scoped of VMEM for the
+        # call stays under the limit the kernels ask for
+        products = _dots(traced.jaxpr.jaxpr)
+        assert products
+        for eqn in products:
+            assert eqn.params["precision"] in (None, (None, None)), eqn
+            assert {v.aval.dtype.name for v in eqn.invars} == {"bfloat16"}
+        used = re.search(r'"used_scoped_memory_configs":\[\{"memory_space":'
+                         r'"1","offset":"0","size":"(\d+)"', text)
+        assert 0 < int(used.group(1)) <= fa._VMEM_LIMIT // 2, used.group(0)
+
+
+def _dots(jaxpr):
+    """The `dot_general` equations of a jaxpr and of every jaxpr in it (a
+    kernel's body, its loops and branches)."""
+    import jax
+    found = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _dots(sub)
+    return found
 
 
 @pytest.mark.parametrize("shape", [(8, 1024, 16, 16, 64),

@@ -255,7 +255,52 @@ _FLASH_CASES = {
                                         (4, 4), "two"),
     "two-kernels-bfloat16-gqa": ((1, 256, 256, 4, 2, 64, True, 128, 128,
                                   "bfloat16", _TWO_KERNELS), (4, 2), "two"),
+    # what only a ONE-pass forward can get wrong: scores that rise along the
+    # keys (`_rising`), so every tile after a query's first raises its
+    # maximum and rescales what it holds: within a grid step, across major
+    # blocks (k, v not resident), under dead rows that share the first tile
+    # with live ones, and in the block of one head that 9 heads leave over
+    # a block of 8 (gpt2-xl's 25 = 3 x 8 + 1)
+    "rising-every-tile-rescales": ((1, 512, 512, 2, 2, 64, True, 128, 128,
+                                    "float32", {}), (2, 2), "one"),
+    "rising-major-blocks-not-resident": ((1, 512, 512, 2, 2, 64, True, 128,
+                                          128, "float32",
+                                          {"_VMEM_BLOCK_BUDGET": 1 << 20}),
+                                         (2, 2), "two"),
+    "rising-s_q-more-dead-rows-in-the-first-tile": (
+        (1, 512, 256, 2, 2, 64, True, 256, 128, "float32", {}), (2, 2),
+        "one"),
+    "rising-odd-heads-9-a-block-of-one": ((1, 256, 256, 9, 9, 64, True, 128,
+                                           128, "float32", {}), (8, 8),
+                                          "one"),
+    "rising-gqa-8-2-d128-bfloat16": ((1, 512, 512, 8, 2, 128, True, 128, 128,
+                                      "bfloat16", {}), (8, 2), "one"),
 }
+
+
+def _rising(key, b, s_q, s_kv, h, h_kv, d, dt):
+    """q and k whose scores rise along the keys, by about 6 every 128 of
+    512 keys at head size 64: each new tile holds every query's new
+    maximum, by far (``alpha`` near exp(-6))."""
+    k1, k2 = jax.random.split(key)
+    q = 1.0 + 0.1 * jax.random.normal(k1, (b, s_q, h, d), jnp.float32)
+    ramp = (jnp.arange(1, s_kv + 1, dtype=jnp.float32) / s_kv)[:, None, None]
+    k = 3.0 * (64 / d) ** 0.5 * ramp * (s_kv / 512) + 0.1 * jax.random.normal(
+        k2, (b, s_kv, h_kv, d), jnp.float32)
+    return q.astype(dt), k.astype(dt)
+
+
+def _reference_lse(q, k, causal):
+    """``[b, heads, s_q]``: the logarithm of each query's summed
+    exponentials of its scaled scores, the last query on the last key."""
+    (b, s_q, h, d), (s_kv, h_kv) = q.shape, k.shape[1:3]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, h // h_kv, axis=2),
+                   precision=jax.lax.Precision.HIGHEST) * d ** -0.5
+    if causal:
+        sees = (jnp.arange(s_q)[:, None] + (s_kv - s_q)
+                >= jnp.arange(s_kv)[None])
+        s = jnp.where(sees, s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
 
 
 @pytest.mark.parametrize("case", sorted(_FLASH_CASES))
@@ -271,7 +316,10 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     other: the one kernel wherever the whole query side is resident and a
     kv head block meets its query heads in one step (`one_backward`), the
     dq and dkv kernels elsewhere and, the predicate held false, over the
-    one kernel's own range of shapes."""
+    one kernel's own range of shapes.  The forward's row statistics
+    (``lse``, what the backward exponentiates against) are held to the
+    reference too, not only ``o``; the ``rising-`` cases are the ones a
+    one-pass forward has to rescale in every tile."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     import importlib
 
@@ -291,15 +339,31 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
     assert (plan.major_k < s_kv and plan.major_q < s_q) == (
         "_VMEM_BLOCK_BUDGET" in held)
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(k1, (b, s_q, h, d), dt)
-    k = jax.random.normal(k2, (b, s_kv, h_kv, d), dt)
     v = jax.random.normal(k3, (b, s_kv, h_kv, d), dt)
+    if case.startswith("rising-"):
+        q, k = _rising(k1, b, s_q, s_kv, h, h_kv, d, dt)
+    else:
+        q = jax.random.normal(k1, (b, s_q, h, d), dt)
+        k = jax.random.normal(k2, (b, s_kv, h_kv, d), dt)
     # rows that see no key (causal, s_q > s_kv): the kernel gives 0, the
     # reference a mean of v; compared on the rows that attend
     live = slice(max(s_q - s_kv, 0) if causal else 0, None)
 
     flash = lambda *a: fa.flash_attention(*a, causal=causal, block_q=bq,
                                           block_k=bk)
+    # the statistics, from the forward alone (the gradient's program below
+    # hands out `o` only): [b, head blocks, hq, s_q], 0 on rows that see no key
+    _, lse = fa._flash_fwd(q.reshape(b, s_q, h * d),
+                           k.reshape(b, s_kv, h_kv * d),
+                           v.reshape(b, s_kv, h_kv * d), causal, d ** -0.5,
+                           plan)
+    lse = lse.reshape(b, -1, s_q)[:, :h]
+    assert not np.asarray(lse[..., :live.start]).any()
+    tol_lse = 2e-5 if dtype == "float32" else 2e-3
+    np.testing.assert_allclose(
+        np.asarray(lse[..., live]), np.asarray(_reference_lse(
+            q.astype(jnp.float32), k.astype(jnp.float32), causal)[..., live]),
+        atol=tol_lse, rtol=tol_lse)
     out_r, g_r = _reference_output_and_grads(
         *(x.astype(jnp.float32) for x in (q, k, v)), causal, live)
     # the three kernels in one program (the forward that is no gradient's:
