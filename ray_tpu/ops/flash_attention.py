@@ -88,8 +88,7 @@ over ONE kv head 0.331 -> 0.158, ViT's one tile of 197 rows at batch 32
 0.291 -> 0.096, 1024 queries on 2048 keys 0.394 -> 0.207, 2048 on 1024
 (dead rows) 0.267 -> 0.120, 16384 rows in major blocks of 8192 (k, v not
 resident) 13.76 -> 8.10: one algorithm for all, nothing keeps the two
-passes.  The dq and dkv bodies still run head by head: the same reordering
-is theirs to try (ROADMAP.md [train attention]).
+passes.
 
 **One backward kernel** (`one_backward`, a function of the plan alone).  The
 dq and the dkv kernel walk the same live tiles and each computes a tile's
@@ -123,6 +122,53 @@ accumulator at the end, **0.715 / 0.299** (kept); no fifth matmul at all
 of 64 over 4096 rows 2.504 -> 1.766, GQA 8 over 2 at 2048 rows 1.722 ->
 1.127, 4 heads of 128 over one 0.900 -> 0.690, ViT's one tile of 197 rows
 0.388 -> 0.237), so no plan that can hold the accumulator takes the two.
+
+**The order of a head's products in the backward** (`_one_ahead`, in
+`_bwd_dkv_kernel` and `_bwd_dq_kernel`).  The chain as first written, a
+head: scores, ``exp``, ``dv +=``, ``dp``, ``ds``, ``dk +=``, ``dq^T +=``.
+Matmuls go to the MXU in program order, and ``dp``, which waits for nothing,
+stood behind ``dv``'s product and in front of ``ds``, which needs it.
+Measured as above (PERF.md, PR 62), the chain: 0.7147 / 0.2999.  Pieces out
+of it (results wrong, times only): no ``exp`` 0.7150 / 0.2999, no mask
+0.7116 / 0.2985, neither 0.7112 / 0.2971 (the vector work is hidden as it
+is: unlike the forward's, this body waits for the MXU); no ``dp`` 0.6121 /
+0.2569, no ``dq^T`` 0.6073 / 0.2596, no ``dv`` 0.5875 / 0.2482, none of the
+three accumulations 0.3609 / 0.1594 (no ``dk`` alone: SLOWER, 0.8025 /
+0.3334), nothing but the loop, the loads and the ends 0.1890 / 0.0877: a
+product's absence frees its own 0.10-0.13 ms of five, the products run end
+to end.  Orders (the same operations; dq, dk and dv the chain's to the bit
+in every one): ALL heads' scores and ``dp`` before any head's exponentials,
+as the forward has them, 0.6585 / 0.2745 (the mask and the scale moved
+behind them 0.6585 / 0.2745, in three phases 0.6585 / 0.2745, the
+accumulations one head late 0.6585 / 0.2745, scores and ``dp`` in turn
+0.6464 / 0.2699; without ``exp`` 0.6592, without the mask 0.6566, without
+``dq^T`` 0.5951), all scores first and ``dp`` beside each head's
+exponentials 0.7064 / 0.2968, halves of four heads 0.6594 / 0.2748, pairs
+0.6592 / 0.2747, three heads ahead 0.6593 / 0.2742, two ahead 0.6593 /
+0.2748, **ONE head ahead 0.6460 / 0.2695 (kept: -9.6 / -10.1 %)**: the next
+head's two products issued after this head's ``exp`` 0.6461 / 0.2695, its
+``dp`` after this head's ``dv`` 0.6462 / 0.2698, its scores after ``dv``
+and its ``dp`` after ``dk`` 0.6458 / 0.2685, the accumulations in the seven
+other orders that keep ``ds`` before ``dk`` and ``dq^T`` 0.6455-0.6470 /
+0.2690-0.2737; the scores one head ahead and ``dp`` where the chain had it
+0.7105 / 0.2984; a head's scores and ``dp`` both before ITS exponentials,
+nothing ahead, 0.6454 / 0.2696.  One thing was lost, ``dp``'s place; two
+heads' tiles or more held across the body cost 2 % (0.25 MB a tile, spilled
+and read back).  Nothing ahead is as fast at this tile and loses elsewhere
+(256 x 128 0.789 for 0.689, 128 x 128 0.953 for 0.778, and the dq kernel,
+where ``dp`` already followed ``exp``, 9.76 for 8.63 at 16384 rows), so one
+head ahead is the module's one order.  Tiles with the plan re-made (chain
+/ all first / one ahead): 256 x 128 0.924 / 0.708 / 0.689, 128 x 256 0.817
+/ 0.695 / 0.716, 512 x 256 (4 and 6 heads) 0.773 / 0.773 / 0.772, 512 x
+128 0.896 / 0.833 / 0.833, 128 x 128 1.104 / 0.710 / 0.778 at
+``[8, 1024, 16, 64]`` and the same ranking at 25 heads: the small tiles
+lost most of their waiting and still none reaches 256 x 256, which stands.
+Every other plan, chain -> kept: 4 heads of 64 over 4096 rows 1.766 ->
+1.486, GQA 8 over 2 at 2048 rows 1.127 -> 1.003, 4 heads of 128 over one kv
+head 0.690 -> 0.591, ViT's one tile of 197 rows 0.237 -> 0.185, 1024
+queries on 2048 keys 0.492 -> 0.421, and the two kernels where they stay:
+12 heads over 16384 rows in major blocks of 8192 dq 9.76 -> 8.63, dkv 13.43
+-> 11.01, 16 heads over ONE kv head dq 0.249 -> 0.239, dkv 0.261 -> 0.246.
 
 The default tile (`DEFAULT_BLOCK_Q` x `DEFAULT_BLOCK_K`) and `_MAX_HEADS`
 were measured on one v5e chip on the kernels alone at
@@ -228,7 +274,13 @@ def _lane_ok(n: int, d: int, total: int) -> bool:
 def _block_bytes(hq, hk, d, bq, bk, major_k, major_q, itemsize,
                  with_dq: bool = False) -> int:
     """VMEM of the hungriest of the forward, the dq and the dkv kernel:
-    double buffered blocks, scratch, and the float32 tiles of one head.  The
+    double buffered blocks, scratch, and the float32 tiles of one head
+    (``tiles``: the backward bodies hold the next head's scores and ``dp``
+    beside this head's ``e``, ``dp``, ``ds``, their bfloat16 casts and the
+    hoisted iota, `_one_ahead`; the compiler scopes 1.3 MB for them at 256 x
+    256 where this counts 1.5, and 13.6 MB for the whole one-kernel call at
+    the train cells' plan where this says 14.4: described v5e, CPU, PR 62).
+    The
     forward and the dq kernel take the same blocks; the forward's scratch is
     the scaled q, the float32 ``acc^T``, row statistics of 9 sublanes a head
     and every head's tile of scores beside the one head's ``tiles`` (it
@@ -460,6 +512,18 @@ def _for_tiles(lo, hi, body):
     jax.lax.fori_loop(lo, hi, step, 0)
 
 
+def _one_ahead(n: int, products):
+    """``(g, *products(g))`` for each head ``g < n`` of a backward body,
+    ``products(g + 1)`` issued before head ``g``'s are handed out.  A body's
+    matmuls go to the MXU in program order: a head's scores and ``dp`` wait
+    for nothing, so they stand in front of the head BEFORE's exponentials
+    and accumulations, which then find them done (module docstring)."""
+    ahead = products(0)
+    for g in range(n):
+        now, ahead = ahead, products(g + 1) if g + 1 < n else None
+        yield (g, *now)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -666,12 +730,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             rows = _tile_rows(t, bk, p.major_k)
             seen = (rel >= (ki * p.major_k + t * bk) - qi * bq - q_offset) \
                 if causal else None
-            for g in range(nq):
+
+            def products(g):    # a head's scores and dp, [bq, bk]
+                n = g // per_kv
+                return (_scores(qs_ref[g], _heads(k_ref, rows, n, d),
+                                sm_scale, seen),
+                        _nt(_heads(do_ref, slice(None), g, d),
+                            _heads(v_ref, rows, n, d)))
+
+            for g, s, dp in _one_ahead(nq, products):
                 k = _heads(k_ref, rows, g // per_kv, d)
-                e = jnp.exp(_scores(qs_ref[g], k, sm_scale, seen)
-                            - lse_col[g])
-                dp = _nt(_heads(do_ref, slice(None), g, d),
-                         _heads(v_ref, rows, g // per_kv, d))
+                e = jnp.exp(s - lse_col[g])
                 ds = (e * (dp - delta_col[g])).astype(k.dtype)
                 dq_acc[g] += _nn(ds, k)
 
@@ -697,7 +766,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     float32 ``[hq x d, s_q]`` that lives across the k steps of a head block:
     ``dq^T[:, tile] += k^T ds``, a plain matmul of the tile as it lies
     against the k block transposed once a grid step.  Zeroed at the first k
-    block, transposed back, scaled and written at the last."""
+    block, transposed back, scaled and written at the last.
+
+    A head's two products that wait for nothing (its scores and its ``dp``)
+    are issued ONE HEAD AHEAD (`_one_ahead`), its exponentials and its three
+    accumulations in the chain's order: a body's matmuls go to the MXU in
+    program order, and ``dp`` between ``dv +=`` and ``ds`` made the vector
+    units wait for it behind ``dv``'s product (module docstring: 0.715 ->
+    0.646 ms, all of the gain any order had).  Live at once: the next head's
+    scores and ``dp`` beside this head's ``e``, ``dp`` and ``ds``, the six
+    tiles `_block_bytes` counts; the accumulations run head by head, so the
+    query heads of a kv head add to ``dk`` and ``dv`` in the order of
+    ``g``."""
     ki, gi, qm = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     last_step = (gi == pl.num_programs(3) - 1) & (qm == pl.num_programs(4) - 1)
     bq, bk, d = p.block_q, p.block_k, p.d
@@ -728,14 +808,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             rows = _tile_rows(t, bq, p.major_q)
             seen = (rel >= ki * bk - (qm * p.major_q + t * bq) - q_offset) \
                 if causal else None
-            for g in range(nq):
+
+            def products(g):    # a head's scores and dp, [bk, bq]
+                n = g // per_kv
+                return (_scores(ks_ref[n], _heads(q_ref, rows, g, d),
+                                sm_scale, seen),
+                        _nt(_heads(v_ref, slice(None), n, d),
+                            _heads(do_ref, rows, g, d)))
+
+            for g, s, dp in _one_ahead(nq, products):
                 n = g // per_kv
                 q = _heads(q_ref, rows, g, d)
                 do = _heads(do_ref, rows, g, d)
-                e = jnp.exp(_scores(ks_ref[n], q, sm_scale, seen)
-                            - lse_ref[0, 0, g:g + 1, rows])      # [bk, bq]
+                e = jnp.exp(s - lse_ref[0, 0, g:g + 1, rows])
                 dv_acc[n] += _nn(e.astype(do.dtype), do)
-                dp = _nt(_heads(v_ref, slice(None), n, d), do)
                 ds = (e * (dp - delta_ref[0, 0, g:g + 1, rows])).astype(q.dtype)
                 dk_acc[n] += _nn(ds, q)
                 if with_dq:

@@ -96,21 +96,22 @@ def test_flash_kernel_compiles_for_v5e(topo, shape, kernel):
         args = (q, kv, kv, q, row, q)
     traced = jax.jit(fn).trace(*args)
     text = traced.lower().compile().as_text()
-    assert text.count("tpu_custom_call") == 1, text[:2000]
-    if kernel == "fwd":
-        # one pass, the sums folded on the vector units: every product of
-        # the forward takes the operands' bfloat16 at the default precision
-        # (the two-pass forward summed its exponentials with a float32
-        # `HIGHEST` product), and what the compiler scoped of VMEM for the
-        # call stays under the limit the kernels ask for
-        products = _dots(traced.jaxpr.jaxpr)
-        assert products
-        for eqn in products:
-            assert eqn.params["precision"] in (None, (None, None)), eqn
-            assert {v.aval.dtype.name for v in eqn.invars} == {"bfloat16"}
-        used = re.search(r'"used_scoped_memory_configs":\[\{"memory_space":'
-                         r'"1","offset":"0","size":"(\d+)"', text)
-        assert 0 < int(used.group(1)) <= fa._VMEM_LIMIT // 2, used.group(0)
+    (call,) = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    # every product of every kernel takes the operands' bfloat16 at the
+    # default precision (the two-pass forward summed its exponentials with a
+    # float32 `HIGHEST` product; the backward's five are the same five in
+    # whatever order they are issued), and what the compiler scoped of VMEM
+    # for the call stays under half the limit the kernels ask for, the bound
+    # `one_backward` puts on `_block_bytes`' count (the hungriest: the one
+    # backward kernel at 4 heads over 4096 rows, 20.25 MB)
+    products = _dots(traced.jaxpr.jaxpr)
+    assert len(products) >= (2 if kernel == "fwd" else 5)
+    for eqn in products:
+        assert eqn.params["precision"] in (None, (None, None)), eqn
+        assert {v.aval.dtype.name for v in eqn.invars} == {"bfloat16"}
+    used = re.search(r'"used_scoped_memory_configs":\[\{"memory_space":'
+                     r'"1","offset":"0","size":"(\d+)"', call)
+    assert 0 < int(used.group(1)) <= fa._VMEM_LIMIT // 2, used.group(0)
 
 
 def _dots(jaxpr):

@@ -193,6 +193,10 @@ _FLASH_CASES = {
                                    "float32", {}), (5, 5), "one"),
     "gqa-8-2": ((1, 256, 256, 8, 2, 64, True, 128, 128, "float32", {}),
                 (8, 2), "one"),
+    # two kv head blocks of 2, each kv head added to by its 4 query heads in
+    # turn, tile after tile
+    "gqa-16-4-two-head-blocks": ((1, 256, 256, 16, 4, 64, True, 128, 128,
+                                  "float32", {}), (8, 2), "one"),
     "mqa-16-1-two-q-steps": ((1, 128, 128, 16, 1, 64, True, 128, 128,
                               "float32", {}), (8, 1), "two"),
     "gqa-4-2-d128": ((1, 256, 256, 4, 2, 128, True, 128, 128, "float32",
@@ -387,8 +391,8 @@ def test_flash_kernel_cases_match_reference(monkeypatch, case):
 
 @pytest.mark.parametrize("case", [
     "four-heads-a-step", "odd-heads-5-for-25", "gqa-8-2",
-    "s_q-less-than-s_kv", "s_q-more-odd-heads", "tile-128x256-batch-2",
-    "non-causal-197-rows"])
+    "gqa-16-4-two-head-blocks", "s_q-less-than-s_kv", "s_q-more-odd-heads",
+    "tile-128x256-batch-2", "non-causal-197-rows"])
 def test_flash_one_backward_is_the_two_kernels_arithmetic(monkeypatch, case):
     """BOTH backwards on the same inputs, residuals and cotangent (the
     predicate held each way): the one kernel walks the dkv kernel's tiles
@@ -427,6 +431,55 @@ def test_flash_one_backward_is_the_two_kernels_arithmetic(monkeypatch, case):
         assert float(jnp.abs(r).max()) > 0.1, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5,
                                    atol=1e-5, err_msg=name)
+
+
+def _eqns(jaxpr):
+    """The equations of ``jaxpr`` in program order, those of the jaxprs
+    inside one (a kernel's body, a branch, a loop's body) right after it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (8, 2)],
+                         ids=["8-heads-of-64", "gqa-8-over-2"])
+def test_flash_backward_issues_a_heads_products_one_head_ahead(
+        heads, kv_heads):
+    """The order of the one backward kernel's tile loop, which is all of its
+    measured gain (module docstring): a body's matmuls go to the MXU in
+    program order, so the two products of a head that wait for nothing (its
+    scores and its ``dp``) are issued before the exponentials of the head
+    BEFORE it (`_one_ahead`), and a head's three accumulations come after
+    its own.  Before head ``k``'s `exp` the loop holds the two products of
+    heads ``0 .. k + 1`` and the three accumulations of heads ``0 .. k - 1``;
+    the chain head by head (scores, `exp`, ``dv +=``, ``dp``, ``ds``, ...)
+    holds ``5 k + 1`` there.  An edit that puts the chain back fails here."""
+    import importlib
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    d, s = 64, 512
+    plan = fa.make_plan(heads, kv_heads, d, s, s, 2, 256, 256)
+    assert (plan.hq, plan.hk) == (heads, kv_heads) and fa.one_backward(plan)
+    q = jax.ShapeDtypeStruct((1, s, heads * d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, s, kv_heads * d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, plan.head_blocks, plan.hq, s), jnp.float32)
+    program = jax.jit(lambda *a: fa._flash_bwd(
+        *a, True, d ** -0.5, plan)).trace(q, kv, kv, q, lse, q)
+    (call,) = [e for e in _eqns(program.jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "flash_attention_bwd"
+    # the tile loop: the one loop of the kernel's body
+    (loop,) = [e for e in call.params["jaxpr"].eqns
+               if e.primitive.name in ("while", "scan")]
+    order = [e.primitive.name
+             for sub in jax.core.jaxprs_in_params(loop.params)
+             for e in _eqns(sub) if e.primitive.name in ("dot_general", "exp")]
+    assert order.count("exp") == heads
+    assert order.count("dot_general") == 5 * heads
+    before = [order[:i].count("dot_general")
+              for i, name in enumerate(order) if name == "exp"]
+    assert before == [2 * min(k + 2, heads) + 3 * k
+                      for k in range(heads)], order
 
 
 def _pallas_calls(jaxpr, times=1, found=None):
