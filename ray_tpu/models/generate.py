@@ -260,8 +260,9 @@ from .transformer import (ATTENTION_KINDS, READER_KINDS, SPARSE_KINDS,
                           cross_scope, gmu_operator, hand_on, handed,
                           head_gate, index_inputs, kda_operator,
                           latent_queries, latent_rows, latent_scope,
-                          latent_weights, mamba_operator, norm_eps,
-                          rope_tables, scan_layer_runs, ssm_operator)
+                          latent_weights, mamba_operator, no_load, norm_eps,
+                          rope_tables, scan_layer_runs, shortcut,
+                          ssm_operator)
 
 Params = Any
 # {<array>: [L, B, heads, width, max_len] for each of `cache_rows`, "pos"}
@@ -1183,13 +1184,14 @@ def _scan_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         x, arrays, _, load, sel = scan_layer_runs(
             cfg, params,
             (x, arrays, {k: zero + before.count(k)
-                         for k in sorted(set(cfg.kinds))}, (zero,) * 3, sel),
+                         for k in sorted(set(cfg.kinds))},
+             (zero,) * cfg.load_counts, sel),
             step, whole_expert_stacks=True, span=span)
         return x, arrays, load, sel
     x, arrays, _, load, _ = scan_layer_runs(
         cfg, params,
         (x, arrays, dict.fromkeys(sorted(set(cfg.kinds)), zero),
-         (zero,) * 3, sel), step, whole_expert_stacks=True)
+         (zero,) * cfg.load_counts, sel), step, whole_expert_stacks=True)
     return x, arrays, load
 
 
@@ -1577,7 +1579,7 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
             delta, arrs, made["m"] = mamba(y, lp, arrs, l)
         elif kind == "gmu":
             delta = gmu_operator(cfg, y, lp, sel["m"])
-        elif cfg.hands_down:
+        elif cfg.hands_down and cfg.attention == "mha":
             with cross_scope(kind):
                 delta, arrs = attend_mha(y, lp, arrs, l, kind, sel["depth"])
         elif kind not in operator and cfg.attention == "mla":
@@ -1592,6 +1594,8 @@ def _attend_cached(cfg: TransformerConfig, params: Params, x: jnp.ndarray,
         xc = xc + _post(cfg, delta, lp, "post_attn_norm")
         y2 = _norm(cfg, xc, lp["mlp_norm"], lp.get("mlp_norm_b"))
         z, _, load = _ffn(cfg, y2, lp, valid)
+        if cfg.shortcut_moe:    # the routed branch leaves or rejoins here
+            z, load, sel = shortcut(cfg, y2, z, lp, sel, valid)
         return (xc + _post(cfg, z, lp, "post_mlp_norm"), arrs, load,
                 hand_on(sel, **made))
 
@@ -1750,12 +1754,12 @@ def prefill(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig,
                     k_i[:, :, None, :], arrs[_INDEX_ARRAY].dtype))})
         if cfg.hands_down:      # (what the plain layers hand each other)
             h, _, sel = _layer(cfg, h, lp, angles, kind, sel)
-            return h, arrs, (0, 0, 0), sel
+            return h, arrs, no_load(cfg), sel
         h, _, chosen = _layer(cfg, h, lp, angles, kind,
                               sel[0] if sel else None)
         if sel:
             sel = (chosen, sel[1] + (kind == "index"))
-        return h, arrs, (0, 0, 0), sel
+        return h, arrs, no_load(cfg), sel
 
     sel = (jnp.zeros((b, s, s), bool), jnp.zeros((), jnp.int32)) \
         if cfg.index_topk else handed(cfg, b, s, rows=True) \

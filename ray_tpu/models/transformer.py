@@ -23,12 +23,34 @@ Design (idiomatic JAX, not a torch translation):
     and one rotary key shared by all heads; `ops/latent_attention.py`).
   * the router kind decides the expert layer: ``"softmax"`` is the
     capacity einsum of `ops/moe.py` that trains over an ``ep`` mesh (and
-    drops over capacity), ``"sigmoid"`` the bias-corrected choice with
-    shared experts that drops nothing (`ops/moe.routed_ffn`).  A sigmoid
-    model may HOLD a share of its experts (``experts_held`` of
-    ``n_experts`` from ``expert_offset``: one chip's part of an expert-
-    parallel layer): the router stays ``n_experts`` wide, the layer
-    computes its own experts' part, and nothing stands in for the rest.
+    drops over capacity); ``"sigmoid"`` and ``"softmax_bias"``
+    (`NO_DROP_ROUTERS`) are the bias-corrected choice that drops nothing
+    (`routed_branch`, `ops/moe.routed_ffn`): sigmoid scores over exactly the
+    experts that exist, normalised over the chosen, beside shared experts; or
+    a softmax over the experts AND ``zero_experts`` IDENTITY experts that
+    compute nothing, the raw scores the weights (one of them chosen adds its
+    weight times the token's own row and joins no matmul).  Such a model may
+    HOLD a share of its experts (``experts_held`` of ``n_experts`` from
+    ``expert_offset``: one chip's part of an expert-parallel layer): the
+    router keeps its width, the layer computes its own experts' part (and,
+    every chip alike, the identity part), and nothing stands in for the rest.
+    How many layers route and how many sums they report is the
+    configuration's to say (`TransformerConfig.expert_layers`,
+    ``reports_load``, ``load_counts``): the serve engine asks, and knows no
+    router.
+  * a SHORTCUT-CONNECTED layer (``shortcut_moe``) is TWO sublayers, each an
+    attention operator and a dense feed-forward with one add each, and ONE
+    routed branch wired across them: ``n_layers`` counts SUBLAYERS, so every
+    count by layer (parameters, FLOPs, a cache's rows: TWO latents a published
+    layer) and every cached program's layer body stand as they are;
+    sublayers 0, 2, ... ALSO hold the router, its bias and the experts, in
+    stacks over those sublayers alone (`_ROUTING_KEYS`, `_init_shortcut`),
+    compute the routed sum off the normed input their dense feed-forward
+    reads and HAND IT ON (`shortcut`; ``handed``'s ``routed``), and sublayers
+    1, 3, ... add it with their feed-forward's output.  The layer loop runs a
+    PAIR a scan step (`_scan_pairs`), so the branch stands in one body with
+    the second attention and the first dense feed-forward, neither of which
+    it depends on.
   * a head's width is its own (``head_size``), and what an attention
     block adds to the plain one is a property each: ``qk_norm``,
     ``attn_gate``, ``sandwich_norm``, ``embed_scale``.
@@ -242,7 +264,10 @@ class TransformerConfig:
     router: str = "softmax"           # "softmax": capacity einsum, aux
     #   loss, drops over capacity (trains over the ep mesh) | "sigmoid":
     #   choice by sigmoid score + correction bias, weights the chosen
-    #   scores normalised, no token dropped (ops/moe.routed_ffn)
+    #   scores normalised, no token dropped (ops/moe.routed_ffn) |
+    #   "softmax_bias": a softmax over the experts AND ``zero_experts``
+    #   identity outputs, choice by score + correction bias, weights the raw
+    #   scores times the scaling factor, no token dropped (the same layer)
     moe_d_ff: Optional[int] = None    # a routed expert's width (None → ff_dim)
     n_shared_experts: int = 0         # always-on experts of width moe_d_ff
     routed_scaling_factor: float = 1.0
@@ -294,6 +319,16 @@ class TransformerConfig:
     # -- a share of the experts (one chip of an expert-parallel layer) ------
     experts_held: Optional[int] = None  # None → all n_experts
     expert_offset: int = 0            # the first expert held
+    zero_experts: int = 0             # IDENTITY experts behind the n_experts
+    #   in a "softmax_bias" router's outputs: one of them chosen adds its
+    #   weight times the token's own row and computes nothing
+    shortcut_moe: bool = False        # a published layer is TWO sublayers
+    #   (``n_layers`` counts SUBLAYERS, each latent or MHA/GQA attention and
+    #   a dense feed-forward of ``ff_dim``) and ONE routed branch beside
+    #   them: sublayers 0, 2, 4, ... ALSO hold a router and the experts
+    #   (stacked over those sublayers alone), compute the routed sum off the
+    #   normed input their dense feed-forward reads and HAND IT ON; sublayers
+    #   1, 3, ... add it with their own feed-forward's output
     # -- what an MHA/GQA head may differ in, by the layer's kind -------------
     # (``v_head_dim`` above, where set, is an MHA/GQA value head's width too)
     window_kv_heads: Optional[int] = None  # a window layer's key-value
@@ -391,6 +426,34 @@ class TransformerConfig:
         return self.experts_held or self.n_experts
 
     @property
+    def expert_layers(self) -> int:
+        """The layers that ROUTE (hold a router and routed experts): every
+        layer of the run behind the leading dense ones, or of a
+        shortcut-connected model every second sublayer."""
+        if not self.n_experts:
+            return 0
+        return self.n_layers // 2 if self.shortcut_moe \
+            else dict(self.layer_runs)["layers"]
+
+    @property
+    def reports_load(self) -> bool:
+        """Whether the expert layers drop nothing and say what they routed
+        (`ops.moe.Load`): the serve engine's ``moe`` counters."""
+        return bool(self.n_experts) and self.router in NO_DROP_ROUTERS
+
+    @property
+    def load_counts(self) -> int:
+        """How many of `ops.moe.Load`'s sums a layer hands the loop: the
+        identity pairs only where the router has identity experts."""
+        return 4 if self.zero_experts else 3
+
+    @property
+    def expert_stacks(self) -> Tuple[str, ...]:
+        """The routed experts' stacks in a run's tree: beside a dense
+        feed-forward of the same layer they have names of their own."""
+        return _SHORTCUT_STACKS if self.shortcut_moe else _EXPERT_STACKS
+
+    @property
     def kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, in model order."""
         return self.layer_kinds or ("full",) * self.n_layers
@@ -443,7 +506,8 @@ class TransformerConfig:
         """Whether the layer loop carries values that one layer makes for the
         layers behind it (`handed`): a memory, a full layer's rows, the
         layer's index."""
-        return self.diff_attn or bool(set(self.kinds) & set(HANDING_KINDS))
+        return self.diff_attn or self.shortcut_moe \
+            or bool(set(self.kinds) & set(HANDING_KINDS))
 
     @property
     def stateless_tail(self) -> int:
@@ -601,13 +665,8 @@ def _run_matmul_params(cfg: TransformerConfig, run: str, active: bool) -> int:
     ``active=False`` every expert held (the memory count)."""
     d = cfg.d_model
     per = 3 if cfg.activation == "swiglu" else 2
-    if cfg.n_experts and run == "layers":
-        routed = cfg.n_experts_held
-        if active:   # of a token's top-k experts, the share held here
-            routed = cfg.expert_top_k if routed == cfg.n_experts \
-                else cfg.expert_top_k * routed / cfg.n_experts
-        mlp = (routed + cfg.n_shared_experts) * d * cfg.expert_ff_dim * per \
-            + d * cfg.n_experts                                  # + router
+    if cfg.n_experts and run == "layers" and not cfg.shortcut_moe:
+        mlp = _routed_matmul_params(cfg, active)
     else:
         mlp = d * cfg.ff_dim * per
     return mlp
@@ -617,7 +676,9 @@ def _matmul_params(cfg: TransformerConfig, active: bool) -> int:
     """Matmul parameters of all layers, over the declared pattern: each
     layer's feed-forward and its kind's operator."""
     return sum(n * _run_matmul_params(cfg, run, active)
-               for run, n in cfg.layer_runs) + sum(
+               for run, n in cfg.layer_runs) + (
+        cfg.expert_layers * _routed_matmul_params(cfg, active)
+        if cfg.shortcut_moe else 0) + sum(
         4 * cfg.d_model ** 2 if kind == "conv"      # in [d, 3d], out [d, d]
         else _kda_matmul_params(cfg) if kind == "kda"
         else _mamba_matmul_params(cfg) if kind == "mamba"
@@ -716,8 +777,8 @@ def count_params(cfg: TransformerConfig) -> int:
         + sum(k in cfg.sink_kinds for k in cfg.kinds) * cfg.n_heads \
         + cfg.kinds.count("eva") * 2 * cfg.kv_heads * cfg.head_dim \
         + cfg.kinds.count("index") * 2 * cfg.index_head_dim  # the key's norm
-    if cfg.n_experts and cfg.router == "sigmoid":   # the correction bias
-        layers += dict(cfg.layer_runs)["layers"] * cfg.n_experts
+    if cfg.reports_load:    # the correction bias, an output of the router
+        layers += cfg.expert_layers * (cfg.n_experts + cfg.zero_experts)
     emb = cfg.vocab_size * d
     if cfg.pos_emb == "learned":
         emb += cfg.max_seq_len * d
@@ -896,7 +957,9 @@ def _init_run(keys, cfg: TransformerConfig, run: str, L: int
             jnp.ones((L, d), pt)
         ax["post_attn_norm"] = ax["post_mlp_norm"] = ("layers", "embed")
     gated = cfg.activation == "swiglu"
-    if cfg.n_experts and run == "layers":
+    if cfg.shortcut_moe:    # the routed branch BESIDE the dense feed-forwards
+        _init_shortcut(add, p, ax, cfg, L, next(keys))
+    if cfg.n_experts and run == "layers" and not cfg.shortcut_moe:
         # the router scores ALL experts; the stacks hold this chip's
         E, held, f = cfg.n_experts, cfg.n_experts_held, cfg.expert_ff_dim
         add("router", (d, E), d, ("embed", "expert"))
@@ -1021,6 +1084,12 @@ def _norm(cfg, x, scale, bias):
 
 
 _EXPERT_STACKS = ("w_in", "w_gate", "w_out")
+#: ... of a shortcut-connected model, whose layers hold a dense feed-forward
+#: under those names too; and what else its routing sublayers alone hold
+_SHORTCUT_STACKS = ("we_in", "we_gate", "we_out")
+_ROUTING_KEYS = _SHORTCUT_STACKS + ("router", "router_bias")
+#: the routers whose expert layer drops no token (`ops.moe.routed_ffn`)
+NO_DROP_ROUTERS = ("sigmoid", "softmax_bias")
 
 
 def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
@@ -1052,8 +1121,8 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
                 raise ValueError(f"span {span} cuts a segment of layers")
             continue
         tree = params[run]
-        whole = {k: tree[k] for k in _EXPERT_STACKS
-                 if whole_expert_stacks and cfg.router == "sigmoid"
+        whole = {k: tree[k] for k in cfg.expert_stacks
+                 if whole_expert_stacks and cfg.reports_load
                  and "router" in tree and k in tree}
         xs = {k: v for k, v in tree.items() if k not in whole}
 
@@ -1061,7 +1130,9 @@ def scan_layer_runs(cfg: TransformerConfig, params: Params, carry, body,
             return body(c, dict(lp, **{k: (v, i) for k, v in whole.items()}),
                         kind)
 
-        if n == run_len[run]:
+        if cfg.shortcut_moe:    # a scan step a PAIR of sublayers
+            carry = _scan_pairs(n, xs, layer, carry)
+        elif n == run_len[run]:
             carry, _ = jax.lax.scan(
                 lambda c, x: (layer(c, *x), None), carry,
                 (xs, jnp.arange(n)))
@@ -1225,6 +1296,9 @@ def _layer(cfg: TransformerConfig, x: jnp.ndarray, lp: Params,
 
     y = norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"))
     z, aux, _ = _ffn(cfg, y, lp)
+    if cfg.shortcut_moe:    # the routed branch leaves here or rejoins here
+        z, _, sel = shortcut(cfg, y, z, lp, sel)
+        sel = hand_on(sel)
     return x + _post(cfg, z, lp, "post_mlp_norm"), aux, sel
 
 
@@ -1243,7 +1317,9 @@ def _glu(cfg: TransformerConfig, y, w_in, w_gate, w_out) -> jnp.ndarray:
                    cfg.ffn_out_scale)
 
 
-_NO_LOAD = (0, 0, 0)
+def no_load(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """What a layer that routes nothing hands the loop for `ops.moe.Load`."""
+    return (0,) * cfg.load_counts
 
 
 def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
@@ -1252,38 +1328,28 @@ def _ffn(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
     by training/prefill (`_layer`) and KV-cache decode
     (`models/generate.py`), so the architectures can't desynchronize.
     Which FFN a layer has follows from its run's tree (a router or none),
-    which expert layer from the model's router kind.  ``valid`` [b, s]
+    which expert layer from the model's router kind (a shortcut-connected
+    model's every sublayer has the dense one HERE and its routed branch
+    beside it, `shortcut`).  ``valid`` [b, s]
     marks the rows that count (a decode batch's live slots); only the
     no-drop expert layer, whose cost follows the rows routed, looks at it.
     → (residual delta, router aux loss, (experts touched, largest expert
     load, pairs that landed on an expert held here) of this layer, zeros
     where it routes nothing)."""
     aux = jnp.zeros((), jnp.float32)
-    if "router" not in lp:
+    if "router" not in lp or cfg.shortcut_moe:
         return (_glu(cfg, y, lp["w_in"], lp.get("w_gate"), lp["w_out"]),
-                aux, _NO_LOAD)
+                aux, no_load(cfg))
     if cfg.router == "softmax":
         from ..ops.moe import moe_ffn
         z, aux = moe_ffn(
             y, lp["router"], lp["w_in"], lp["w_out"], lp.get("w_gate"),
             top_k=cfg.expert_top_k, capacity_factor=cfg.capacity_factor)
-        return z, aux, _NO_LOAD
-    if cfg.router != "sigmoid":
-        raise ValueError(f"router={cfg.router!r}: expected 'softmax' or "
-                         f"'sigmoid'")
-    from ..ops.moe import routed_ffn, sigmoid_route
-    b, s, d = y.shape
-    flat = y.reshape(b * s, d)
-    idx, w = sigmoid_route(flat, lp["router"], lp["router_bias"],
-                           cfg.expert_top_k, cfg.routed_scaling_factor)
-    z, load = routed_ffn(flat, idx, w, lp["w_in"], lp["w_out"],
-                         lp.get("w_gate"),
-                         None if valid is None else valid.reshape(b * s),
-                         expert_offset=cfg.expert_offset)
-    z = z.reshape(b, s, d)
+        return z, aux, no_load(cfg)
+    z, load = routed_branch(cfg, y, lp, valid)
     if cfg.n_shared_experts:
         z = z + _glu(cfg, y, lp["ws_in"], lp.get("ws_gate"), lp["ws_out"])
-    return z, aux, tuple(load)
+    return z, aux, load
 
 
 def _trunk(params: Params, tokens: jnp.ndarray, cfg: TransformerConfig
@@ -1664,6 +1730,7 @@ def check_kinds(cfg: TransformerConfig) -> None:
             f"kda_head_dim and kda_gate_rank of at least 1 and a "
             f"kda_conv_kernel of at least 2")
     _check_memory(cfg)
+    _check_routing(cfg)
     sparse = set(cfg.kinds) & set(SPARSE_KINDS)
     if not sparse and not cfg.index_topk:
         return
@@ -2286,12 +2353,16 @@ def handed(cfg: TransformerConfig, b: int, s: int, rows: bool = False
     """What the layer loop of a model that `hands_down` carries beside the
     stream, for ``b`` rows of ``s`` tokens, before the first layer:
     ``depth`` (the layer's index among all: a differential layer's
-    ``lambda_init``), ``m`` (the last ``"mamba"`` layer's scan output [b, s,
+    ``lambda_init``), ``routed`` (a shortcut-connected layer's routed sum [b,
+    s, d_model] on its way from the first sublayer to the second), ``m`` (the
+    last ``"mamba"`` layer's scan output [b, s,
     mamba_inner], BEFORE its gate: the memory every ``"gmu"`` layer behind
     it reads) and, with ``rows`` (the plain form: nothing is cached), ``k``
     and ``v``, the last ``"full"`` layer's rows for the ``"cross"`` layers
     behind it."""
     out = {"depth": jnp.zeros((), jnp.int32)}
+    if cfg.shortcut_moe:    # the routed branch between its two sublayers
+        out["routed"] = jnp.zeros((b, s, cfg.d_model), cfg.dtype)
     if "gmu" in cfg.kinds:
         out["m"] = jnp.zeros((b, s, cfg.mamba_inner), cfg.dtype)
     if rows and "cross" in cfg.kinds:
@@ -2511,3 +2582,125 @@ def _stepped(params, updates, state, given):
     from jax.experimental.shard_alike import shard_alike
     return jax.tree_util.tree_map(lambda n, g: shard_alike(n, g)[0],
                                   new, (params, given))
+
+
+def _check_routing(cfg: TransformerConfig) -> None:
+    """What identity experts and a shortcut-connected layer need of a
+    configuration, refused with a message where it lacks it."""
+    if cfg.n_experts and cfg.router not in ("softmax",) + NO_DROP_ROUTERS:
+        raise ValueError(f"router={cfg.router!r}: expected 'softmax', "
+                         f"'sigmoid' or 'softmax_bias'")
+    if cfg.zero_experts and cfg.router != "softmax_bias":
+        raise ValueError("zero_experts are outputs of a 'softmax_bias' "
+                         "router")
+    if cfg.shortcut_moe and (
+            cfg.n_layers % 2 or not cfg.reports_load or cfg.n_shared_experts
+            or cfg.first_dense_layers or cfg.index_topk or cfg.pp_stages > 1
+            or cfg.sandwich_norm or len(set(cfg.kinds)) > 1
+            or cfg.kinds[0] not in ("full", "window")):
+        raise ValueError(
+            "shortcut_moe: n_layers counts SUBLAYERS, two a published layer, "
+            "all of one attention kind ('full' or 'window'); the routed "
+            "branch drops no token ('sigmoid' or 'softmax_bias'), stands "
+            "beside dense feed-forwards (no shared expert, no leading dense "
+            "layers, no norm behind a block), and the model has no indexer "
+            "and is no pipeline")
+
+
+def _routed_matmul_params(cfg: TransformerConfig, active: bool) -> float:
+    """Matmul parameters of ONE routing layer's routed feed-forward: the
+    router (its identity outputs too), the shared experts and the routed
+    experts HELD, or with ``active`` the share of a token's top-k choices
+    that lands on an expert held here (an identity expert has no matmul: of
+    ``n_experts + zero_experts`` outputs chosen alike, ``held`` are)."""
+    per = 3 if cfg.activation == "swiglu" else 2
+    routed = cfg.n_experts_held
+    if active:
+        routed = cfg.expert_top_k \
+            if routed == cfg.n_experts and not cfg.zero_experts \
+            else cfg.expert_top_k * routed / (cfg.n_experts
+                                              + cfg.zero_experts)
+    return (routed + cfg.n_shared_experts) * cfg.d_model \
+        * cfg.expert_ff_dim * per \
+        + cfg.d_model * (cfg.n_experts + cfg.zero_experts)
+
+
+def _init_shortcut(add, p: Params, ax: Params, cfg: TransformerConfig,
+                   L: int, key) -> None:
+    """A shortcut-connected run's routed branch, stacked over its ROUTING
+    sublayers alone (every second one): the router over the experts and the
+    identity experts, its correction bias, this chip's experts.  ONE of the
+    run's keys."""
+    d, f = cfg.d_model, cfg.expert_ff_dim
+    n, outs, held = L // 2, cfg.n_experts + cfg.zero_experts, \
+        cfg.n_experts_held
+    ks = iter(jax.random.split(key, 4))
+    add("router", (d, outs), d, ("embed", "expert"), n, next(ks))
+    add("we_in", (held, d, f), d, ("expert", "embed", "mlp"), n, next(ks))
+    add("we_out", (held, f, d), f, ("expert", "mlp", "embed"), n, next(ks))
+    if cfg.activation == "swiglu":
+        add("we_gate", (held, d, f), d, ("expert", "embed", "mlp"), n,
+            next(ks))
+    p["router_bias"] = jnp.zeros((n, outs), cfg.param_dtype)
+    ax["router_bias"] = ("layers", "expert")
+
+
+def _scan_pairs(n: int, xs: Params, layer, carry):
+    """`scan_layer_runs` over a run of ``n`` SUBLAYERS of a shortcut-connected
+    model: ONE scan step is a published layer, sublayer ``2j`` with the
+    routing weights ``j`` (`_ROUTING_KEYS`: stacks over the routing sublayers
+    alone) and then sublayer ``2j + 1`` without, each an index INTO the stacks
+    (`_scan_part`'s reason).  Both stand in one loop body with the routed
+    branch between them (`shortcut`), which depends on neither the second
+    attention nor the first dense feed-forward: the compiler may order them as
+    it likes, and a deployment hides the experts' exchange behind them."""
+    def at(stack, i):
+        return jax.lax.dynamic_index_in_dim(stack, i, 0, keepdims=False)
+
+    def step(c, j):
+        for i in (0, 1):
+            lp = {k: at(v, 2 * j + i) for k, v in xs.items()
+                  if k not in _ROUTING_KEYS}
+            if i == 0:
+                lp.update({k: at(v, j) for k, v in xs.items()
+                           if k in _ROUTING_KEYS})
+            c = layer(c, lp, j)
+        return c, None
+
+    carry, _ = jax.lax.scan(step, carry, jnp.arange(n // 2))
+    return carry
+
+
+def routed_branch(cfg: TransformerConfig, y: jnp.ndarray, lp: Params,
+                  valid: Optional[jnp.ndarray] = None):
+    """The no-drop routed sum of a normed input ``y`` [b, s, d] -> (out [b, s,
+    d], `ops.moe.Load` as the loop carries it): the choice by the model's
+    router kind, then `ops.moe.routed_ffn` over the experts held here."""
+    from ..ops.moe import routed_ffn, sigmoid_route, softmax_route
+    route = {"sigmoid": sigmoid_route, "softmax_bias": softmax_route}[
+        cfg.router]         # (`_check_routing` has refused any other)
+    b, s, d = y.shape
+    flat = y.reshape(b * s, d)
+    idx, w = route(flat, lp["router"], lp["router_bias"], cfg.expert_top_k,
+                   cfg.routed_scaling_factor)
+    w_in, w_gate, w_out = (lp.get(k) for k in cfg.expert_stacks)
+    z, load = routed_ffn(
+        flat, idx, w, w_in, w_out, w_gate,
+        None if valid is None else valid.reshape(b * s),
+        expert_offset=cfg.expert_offset,
+        identity_from=cfg.n_experts if cfg.zero_experts else None)
+    return z.reshape(b, s, d), tuple(load)[:cfg.load_counts]
+
+
+def shortcut(cfg: TransformerConfig, y: jnp.ndarray, z: jnp.ndarray,
+             lp: Params, sel, valid: Optional[jnp.ndarray] = None):
+    """A shortcut-connected sublayer's feed-forward half BESIDE its dense
+    feed-forward's output ``z`` -> (what the sublayer adds to the stream,
+    load, sel).  A sublayer that holds a router computes the routed sum off
+    ``y``, the normed input its dense feed-forward read, and HANDS IT ON
+    (``sel["routed"]``: it joins the stream a whole sublayer later); the
+    sublayer behind adds what it is handed."""
+    if "router" in lp:
+        m, load = routed_branch(cfg, y, lp, valid)
+        return z, load, dict(sel, routed=m.astype(sel["routed"].dtype))
+    return z + sel["routed"].astype(z.dtype), no_load(cfg), sel

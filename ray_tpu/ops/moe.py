@@ -1,9 +1,13 @@
-"""Mixture-of-experts FFNs: two expert layers, chosen by a model's router
-kind (`models/transformer.py` `_ffn`), never by a switch.
+"""Mixture-of-experts FFNs: two expert layers and three ways to choose,
+each by a model's router kind (`models/transformer.py` `routed_branch`,
+`_ffn`), never by a switch.
 
-**Sigmoid router, no token dropped** (`sigmoid_route`, `routed_ffn`): the
-layer of models that route by a sigmoid score plus a correction bias.  It
-is TOLD which experts it holds (the stacks it is given, from
+**No token dropped** (`sigmoid_route` | `softmax_route`, then `routed_ffn`):
+the layer of models that route by a score plus a correction bias, the score
+a sigmoid over exactly the experts that exist (weights normalised over the
+chosen) or a SOFTMAX over the experts AND a number of IDENTITY experts that
+compute nothing (`softmax_route`: weights the raw scores, not normalised).
+It is TOLD which experts it holds (the stacks it is given, from
 ``expert_offset``), takes the router's choice over ALL experts, and
 computes its own experts' part of the result: a pair whose expert lives on
 another chip joins no group here and adds nothing, and no code stands in
@@ -14,7 +18,11 @@ weights; on a TPU this repo's kernel, which reads each touched expert's
 weights once, elsewhere `jax.lax.ragged_dot`), so the cost follows the
 experts the pairs touch, not the number of experts; nothing of size
 tokens x experts x capacity is built and no load, however uneven, loses a
-token.  It returns what it routed (`Load`) for the serve engine's counters.
+token.  A pair whose expert is an IDENTITY expert (``identity_from``)
+joins no group either: it is sorted behind the last block with the other
+chips' pairs, no matmul sees it, and its weight times the token's own row
+is added in the float32 combine (scope ``zero_experts``).  It returns what
+it routed (`Load`) for the serve engine's counters.
 
 **Softmax router with capacity** (`route`, `moe_ffn`): the GShard/Switch
 einsum formulation for the softmax presets that TRAIN over the mesh's
@@ -40,7 +48,7 @@ standard Switch-Transformer overflow policy.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,10 +59,12 @@ from .grouped_matmul import grouped_matmul
 class Load(NamedTuple):
     """What one `routed_ffn` call routed (int32 scalars), of the experts
     HELD: those that got at least one pair, the pairs of the fullest, and
-    the pairs that landed on any of them."""
+    the pairs that landed on any of them; and the pairs of rows that count
+    that chose an IDENTITY expert (the int 0 where the router has none)."""
     experts_touched: jnp.ndarray
     load_max: jnp.ndarray
     pairs: jnp.ndarray
+    zero_pairs: Any = 0
 
 
 @jax.named_scope("experts")
@@ -76,11 +86,32 @@ def sigmoid_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
 
 
 @jax.named_scope("experts")
+def softmax_route(y: jnp.ndarray, router_w: jnp.ndarray, bias: jnp.ndarray,
+                  top_k: int, scaling: float = 1.0
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """y [n, d] -> (experts [n, k] int32, weights [n, k] float32).
+
+    Scores are ``softmax(y W_r)`` in float32 over ALL the router's outputs
+    (the experts and, behind them, the identity experts).  The ``top_k`` are
+    chosen by score PLUS ``bias``; a chosen output's weight is its score
+    alone times ``scaling``, NOT normalised over the chosen: the bias moves
+    the choice and never the weight."""
+    scores = jax.nn.softmax(jnp.einsum(
+        "nd,de->ne", y.astype(jnp.float32), router_w.astype(jnp.float32)),
+        axis=-1)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1) * scaling
+    return idx.astype(jnp.int32), w
+
+
+@jax.named_scope("experts")
 def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
                w_in: jnp.ndarray, w_out: jnp.ndarray,
                w_gate: Optional[jnp.ndarray] = None,
                valid: Optional[jnp.ndarray] = None, *,
-               expert_offset: int = 0) -> Tuple[jnp.ndarray, Load]:
+               expert_offset: int = 0,
+               identity_from: Optional[int] = None
+               ) -> Tuple[jnp.ndarray, Load]:
     """Every token through each of its chosen experts that is held here,
     none dropped.
 
@@ -94,8 +125,11 @@ def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     and which of them this is (a slice of the stack would be copied out
     for the kernel; the kernel indexes the stack where it lies); ``valid``
     [n] bool marks the rows that count (None: all): a row that does not is
-    routed nowhere, touches no expert and gets zeros.  -> (out [n, d],
-    `Load`).
+    routed nowhere, touches no expert and gets zeros.  ``identity_from``
+    (None: the router has none): a pair whose expert is numbered
+    ``identity_from`` or above chose an IDENTITY expert, which has no
+    weights and no home: it joins no group on any chip, and ``w`` times the
+    token's own row is its part of the result.  -> (out [n, d], `Load`).
 
     The n*k pairs are sorted by expert, so expert e's rows are one block;
     rows past the last block (the invalid ones, sorted to the end under
@@ -128,8 +162,17 @@ def routed_ffn(y: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     # rows of no group hold nothing defined
     out = jnp.where(here.reshape(n, k, 1), out, 0)
     out = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), w)
+    zero_pairs = 0
+    if identity_from is not None:
+        with jax.named_scope("zero_experts"):
+            identity = idx >= identity_from                       # [n, k]
+            if valid is not None:
+                identity &= valid[:, None]
+            out = out + jnp.where(identity, w, 0.0).sum(
+                -1, keepdims=True) * y.astype(jnp.float32)
+            zero_pairs = identity.sum().astype(jnp.int32)
     return out.astype(dt), Load((sizes > 0).sum().astype(jnp.int32),
-                                sizes.max(), sizes.sum())
+                                sizes.max(), sizes.sum(), zero_pairs)
 
 
 def expert_capacity(seq_tokens: int, n_experts: int, top_k: int,

@@ -307,11 +307,13 @@ class ContinuousBatchingEngine:
 
         # a model whose expert layers drop nothing reports what a step
         # routed: (experts touched, largest expert load, pairs that
-        # landed on an expert held here), each summed over the expert
-        # layers, ride BEHIND the tokens in the step's one int32 vector,
-        # so the loop still makes one read per step
-        self._moe_layers = moe_layers = dict(cfg.layer_runs)["layers"] \
-            if cfg.n_experts and cfg.router == "sigmoid" else 0
+        # landed on an expert held here[, pairs that chose an identity
+        # expert]), each summed over the layers that ROUTE (the
+        # configuration says how many and how many sums: the engine
+        # knows no router), ride BEHIND the tokens in the step's one
+        # int32 vector, so the loop still makes one read per step
+        self._moe_layers = moe_layers = cfg.expert_layers \
+            if cfg.reports_load else 0
         slots = engine_cfg.max_slots
 
         def fused_step(params, tok, cache, active, *, cfg):
@@ -443,7 +445,8 @@ class ContinuousBatchingEngine:
         # `_MOE_SPAN_S` seconds one ring span `moe:load` with the sums
         # since the last (a span a step cost too much: PERF.md, PR 25)
         self.moe = dict.fromkeys(
-            ("steps", "experts_touched", "pairs", "load_max"), 0)
+            ("steps", "experts_touched", "pairs", "load_max")
+            + (("zero_pairs", "chosen") if cfg.zero_experts else ()), 0)
         self._moe_span = dict(self.moe, t=time.time())
         # what the decode steps read, moved and wrote of the cache, by state
         # kind (`models.CacheTraffic.STEP_SUMS`, which says what each sum
@@ -1360,7 +1363,7 @@ class ContinuousBatchingEngine:
                     with phase("readback"):
                         new_toks = self._read(step, fi)
                     if self._moe_layers:
-                        self._count_moe(new_toks[slots:])
+                        self._count_moe(new_toks[slots:], len(step.batch))
                     self._count_rows(step.rows)
             except Exception as e:
                 # seen at the dispatch or one read late: either way the
@@ -1376,8 +1379,8 @@ class ContinuousBatchingEngine:
         slot's first token is written into it; a step's routing counts
         ride behind its tokens, `fused_step`)."""
         import jax.numpy as jnp
-        return jnp.zeros(self.ecfg.max_slots
-                         + (3 if self._moe_layers else 0), jnp.int32)
+        return jnp.zeros(self.ecfg.max_slots + (
+            self.cfg.load_counts if self._moe_layers else 0), jnp.int32)
 
     def _dispatch(self, batch, active, admitted, alone: bool) -> _Step:
         """Queue one fused step over ``batch`` and keep, at DISPATCH
@@ -1466,14 +1469,21 @@ class ContinuousBatchingEngine:
 
     _MOE_SPAN_S = 2.0
 
-    def _count_moe(self, load) -> None:
-        """One decode step's routing into the counters, and the sums since
-        the last `moe:load` span into the next one when it is due."""
+    def _count_moe(self, load, live: int) -> None:
+        """One decode step's routing (``live`` rows' worth) into the
+        counters, and the sums since the last `moe:load` span into the
+        next one when it is due.  A router with identity experts counts
+        the pairs that chose one (``zero_pairs``) beside all the pairs its
+        live rows ``chosen``: rows x experts a token x layers that route."""
         with self._loop_lock:   # stats() reads these
             self.moe["steps"] += 1
             self.moe["experts_touched"] += int(load[0])
             self.moe["load_max"] += int(load[1])
             self.moe["pairs"] += int(load[2])
+            if self.cfg.zero_experts:
+                self.moe["zero_pairs"] += int(load[3])
+                self.moe["chosen"] += live * self.cfg.expert_top_k \
+                    * self._moe_layers
         # a category of its own: the ring keeps a bound a category, and
         # the two `serve` spans of every `next_chunk` call push a span
         # out of a full ring within seconds of a busy window

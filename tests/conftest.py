@@ -56,7 +56,9 @@ import pytest  # noqa: E402
 #: `test_perfbench_family_glm_moe_dsa.py` 117 s; PR 55's two, entered by
 #: PR 57 beside the neighbours it timed in one process: `test_ssd.py` 104 s
 #: then `test_perfbench_family_dots3_note.py` 99 s, `test_window_latent.py`
-#: 67 s then `test_short_conv_state.py` 56 s).  `test_scale.py`
+#: 67 s then `test_short_conv_state.py` 56 s; PR 63's two by their times
+#: alone in one process: `test_perfbench_family_longcat_flash.py` 52 s,
+#: `test_shortcut_moe.py` 33 s).  `test_scale.py`
 #: (230-290 s) stands later than its weight: its floor on tasks a second
 #: (400; 480 read beside the runtime's own tests, 365 and 378 beside five
 #: workers compiling) wants the light end of the run, where it still ends
@@ -74,10 +76,12 @@ _LONGEST_FIRST = (
     "test_perfbench_family_mimo_v2_flash.py", "test_mixed_kv_heads.py",
     "test_serve_decode_engine.py", "test_prefill_padded_tail.py",
     "test_window_ring.py", "test_window_latent.py",
-    "test_short_conv_state.py", "test_shared_cache.py", "test_gbdt.py",
+    "test_short_conv_state.py", "test_perfbench_family_longcat_flash.py",
+    "test_shared_cache.py", "test_gbdt.py",
     "test_rl.py", "test_latent_moe.py", "test_dt.py",
     "test_multi_agent.py", "test_grouped_matmul.py",
-    "test_perfbench_family_evabyte.py", "test_generate.py",
+    "test_perfbench_family_evabyte.py", "test_shortcut_moe.py",
+    "test_generate.py",
     "test_dreamer.py", "test_rl_breadth.py", "test_prefill_lanes.py",
     "test_external_env.py", "test_perfbench_engine_ahead.py",
     "test_pipeline_moe.py", "test_serve_failover.py",

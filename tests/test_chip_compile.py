@@ -1729,3 +1729,78 @@ def test_layers_that_read_another_layers_cache_copy_nothing_on_the_v5e(
             r"= \w+\[(?:\d+,)*200064\]\S* (?:convolution|dot|fusion)\(", short)
         assert short.count("cache_block_attention") == 0
         assert short.count("selective_scan_chunk") >= 2
+
+
+def test_pair_body_with_identity_experts_copies_no_cache_and_no_stack(topo):
+    """The fused decode step of a SHORTCUT-CONNECTED model at the published
+    widths of `longcat-flash-chat` (two published layers: four sublayers, two
+    routers 768 wide over 512 experts, 16 held, and 256 identity experts):
+    the donated program aliases its one cache of FOUR latent rows a position
+    and copies none of its shape; a pair body holds three grouped matmuls,
+    this repo's kernel, each given the whole stack of 2 x 16 experts where it
+    lies (an identity pair joins no group: the stacks are all the kernel
+    multiplies by), two blocked latent reads and two column writes; no
+    sublayer's slice of a dense feed-forward or of the experts is copied out
+    (the pair's sublayers are indices INTO the stacks); and FOUR routing sums
+    ride behind the tokens."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import TransformerConfig, init_params, init_slot_cache
+    from ray_tpu.models.generate import _decode_step_slots
+    cfg = TransformerConfig(
+        vocab_size=16384, d_model=6144, n_layers=4, n_heads=64, d_ff=12288,
+        max_seq_len=131072, pos_emb="rope", rope_base=1e7,
+        activation="swiglu", norm="rmsnorm", norm_eps=1e-5,
+        tie_embeddings=False, attention="mla", q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, latent_rescale=True, shortcut_moe=True,
+        n_experts=512, experts_held=16, zero_experts=256, expert_top_k=12,
+        router="softmax_bias", moe_d_ff=2048, routed_scaling_factor=6.0,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+    params = described(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    slots, max_len = 64, 3584
+    cache = described(jax.eval_shape(
+        lambda: init_slot_cache(cfg, slots, max_len)))
+    counts = []
+
+    def fused_step(params, tok, cache, active):
+        logits, cache, load = _decode_step_slots(params, tok[:slots], cache,
+                                                 active, cfg)
+        counts.append(len(load))
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jnp.concatenate([jnp.where(active, nxt, tok[:slots]),
+                                jnp.stack(load)]), cache
+    compiled = jax.jit(fused_step, donate_argnums=(2,)).lower(
+        params, described(jax.ShapeDtypeStruct((slots + 4,), jnp.int32)),
+        cache, described(jax.ShapeDtypeStruct((slots,), jnp.bool_))).compile()
+    assert counts == [4] == [cfg.load_counts]
+    ma = compiled.memory_analysis()
+    assert set(cache) == {"kv", "pos"} and cache["kv"].shape[0] == 4
+    assert ma.alias_size_in_bytes >= cache["kv"].size * 2
+    assert ma.temp_size_in_bytes < (256 << 20), ma.temp_size_in_bytes
+    text = compiled.as_text()
+    _one_write_an_array(text, cache)
+    shape = ",".join(map(str, cache["kv"].shape))
+    assert not re.findall(rf"= bf16\[{shape}\]\S* copy\(", text)
+    calls = _grouped_calls(text)
+    assert len(calls) == 3 and all(
+        "bf16[2,16,6144,2048]" in c or "bf16[2,16,2048,6144]" in c
+        for c in calls), calls
+    # no sublayer's dense feed-forward (151 MB a matrix) and no layer's
+    # experts copied out of their stacks
+    assert not re.findall(r"= bf16\[(?:\d+,)?(?:6144,12288|12288,6144)\]\S*"
+                          r" copy\(", text)
+    assert not re.findall(r"= bf16\[(?:\d+,)?16,(?:6144,2048|2048,6144)\]\S*"
+                          r" copy\(", text)
+    assert "zero_experts" in text
